@@ -293,9 +293,8 @@ class CompiledProgram:
         if multi is None:
             from .executor import _jit
 
-            # raw jitted step — see Executor.run_repeated (the wrapper's
-            # one-shot trace timer must not fire on the scan-body trace)
-            step_fn = getattr(compiled, "jit_fn", compiled.fn)
+            # the step's nested jit — see Executor.run_repeated
+            step_fn = compiled.nested_fn
 
             def multi(state, feeds, counter):
                 rng0 = jax.random.key(base)

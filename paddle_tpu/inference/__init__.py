@@ -43,7 +43,10 @@ class AnalysisConfig:
     def __init__(self, model_dir=None, params_file=None):
         self._model_dir = model_dir
         self._params_file = params_file
-        self._use_tpu = True
+        # False: run on whatever backend the process has (the chip, on a
+        # machine with one). enable_use_gpu() makes the accelerator a
+        # requirement the predictor checks.
+        self._use_tpu = False
         self._ir_optim = True
         self._memory_optim = True
         self._cpu_math_threads = 1
@@ -59,7 +62,8 @@ class AnalysisConfig:
 
     # -- device ----------------------------------------------------------
     def enable_use_gpu(self, memory_pool_init_size_mb=100, device_id=0):
-        # GPU knob from reference scripts: the TPU/XLA backend serves
+        # GPU knob from reference scripts: asks for the accelerator, and
+        # the predictor raises if the process's backend is not the TPU
         self._use_tpu = True
 
     def disable_gpu(self):

@@ -300,6 +300,9 @@ class Replica:
         self.routed = 0  # total requests the router sent here
         self.restarts = 0  # completed respawns (not the initial spawn)
         self.warmup_ms = None
+        # what the worker's ready handshake said JAX initialised there
+        self.platform = None
+        self.device_kind = None
         self.live_since = None
         self.confirmed = False  # stayed live past min_uptime once
         # role-scheduler state (router-side, guarded by sup._lock):
@@ -343,6 +346,8 @@ class Replica:
             "routed": self.routed,
             "restarts": self.restarts,
             "warmup_ms": self.warmup_ms,
+            "platform": self.platform,
+            "device_kind": self.device_kind,
             "route_breaker_open": self.route_breaker.open,
             "queued_tokens": self.queued_tokens,
             "kv_free_pages": self.kv_free_pages,
@@ -627,6 +632,8 @@ class FleetSupervisor:
                 rep.pid = int(info["pid"])
                 rep.port = int(info["port"])
                 rep.warmup_ms = info.get("warmup_ms")
+                rep.platform = info.get("platform")
+                rep.device_kind = info.get("device_kind")
                 rep.live_since = time.monotonic()
                 rep.confirmed = False
                 self._set_status(rep, LIVE)

@@ -22,7 +22,8 @@ a fresh OS process and serves HTTP:
                     bench scrapes ONE endpoint instead of reaching into
                     the in-process profiler.
 
-Handshake: `--ready-file PATH` writes {"port", "pid", "warmup_ms"} via
+Handshake: `--ready-file PATH` writes {"port", "pid", "warmup_ms",
+"platform", "device_kind"} via
 temp + os.replace once the listener is bound and warmup has run — a
 machine-readable signal for supervisors (inference/fleet.py) instead of
 parsing the human `serving ... on http://...` stdout line.
@@ -599,6 +600,14 @@ class InferenceServer:
         self._predictor = create_paddle_predictor(config)
         self._feed_names = list(self._predictor.get_input_names())
         self._fetch_names = list(self._predictor.get_output_names())
+        # the device the predictor actually initialised, as JAX reports
+        # it: published in the ready file and on /healthz so a supervisor
+        # can tell a replica on the chip from one that is not
+        import jax
+
+        dev = jax.devices()[0]
+        self.device_info = {"platform": dev.platform,
+                            "device_kind": dev.device_kind}
         # an int8 quantize-on-export bundle (streaming/export_int8.py)
         # ships a quant manifest next to __model__.json; surfacing it on
         # /healthz lets operators confirm WHICH face (int8 vs fp32) a
@@ -1006,6 +1015,7 @@ class InferenceServer:
             "batch_window_ms": (self.batch_window_ms
                                 if self._coalescer is not None else 0),
             "counters": self.counters(),
+            **self.device_info,
         }
         if self.backend_class is not None:
             payload["backend_class"] = self.backend_class
@@ -1589,6 +1599,7 @@ def write_ready_file(path, srv):
         "port": srv.port,
         "pid": os.getpid(),
         "warmup_ms": srv.counters().get("serve_warmup_ms", 0),
+        **srv.device_info,
     }
     if getattr(srv, "backend_class", None):
         payload["backend_class"] = srv.backend_class
@@ -1629,8 +1640,8 @@ def main(argv=None):
     ap.add_argument("--port", type=int, default=0,
                     help="TCP port (0 = auto)")
     ap.add_argument("--device", default=None, choices=[None, "cpu", "tpu"],
-                    help="force a backend (cpu useful for tests/CI hosts "
-                    "without the accelerator)")
+                    help="the backend this worker must run on: cpu selects "
+                    "the XLA CPU backend, tpu fails unless JAX found a TPU")
     ap.add_argument("--max-queue", type=int, default=16,
                     help="in-flight request cap; excess sheds with 503")
     ap.add_argument("--deadline-ms", type=float, default=0,
@@ -1652,8 +1663,9 @@ def main(argv=None):
                     help="per-connection socket deadline (slow clients "
                     "time out instead of pinning admission slots)")
     ap.add_argument("--ready-file", default=None,
-                    help="atomically write {port, pid, warmup_ms} JSON "
-                    "here once bound + warm (supervisor handshake)")
+                    help="atomically write {port, pid, warmup_ms, platform, "
+                    "device_kind} JSON here once bound + warm (supervisor "
+                    "handshake)")
     ap.add_argument("--batch-window-ms", type=float, default=2.0,
                     help="request-coalescing admission window: batchable "
                     "/predict requests wait up to this long to merge "
@@ -1711,11 +1723,13 @@ def main(argv=None):
     if args.device == "cpu":
         import jax
 
+        # importing this module initialises no backend, so the platform
+        # can still be chosen here
         jax.config.update("jax_platforms", "cpu")
-        from jax._src import xla_bridge
+    elif args.device == "tpu":
+        from ..place import TPUPlace
 
-        if xla_bridge.backends_are_initialized():
-            xla_bridge._clear_backends()
+        TPUPlace().require_backend()  # raises, naming what JAX found
     serve(
         args.model_dir, port=args.port,
         ready_file=args.ready_file,

@@ -1,6 +1,8 @@
 """Install sanity check (reference: python/paddle/fluid/install_check.py
 run_check — trains a tiny fc model single-device and, when multiple devices
-exist, data-parallel, then prints a success banner)."""
+exist, data-parallel, then prints a success banner). Like the reference,
+which picks CUDAPlace or CPUPlace by what the build has, it checks the
+backend JAX found and names it in the banner."""
 
 from __future__ import annotations
 
@@ -37,6 +39,8 @@ def run_check():
         optimizer.SGD(0.01).minimize(loss)
         return loss
 
+    dev = jax.devices()[0]
+    place = TPUPlace() if dev.platform == "tpu" else CPUPlace()
     n_dev = len(jax.devices())
     # a multiple of the device count >= 16 so the dp mesh divides evenly
     bs = n_dev * max(2, -(-16 // n_dev))
@@ -48,7 +52,7 @@ def run_check():
     with program_guard(main, startup):
         with unique_name.guard():
             loss = _build()
-    exe = Executor(TPUPlace())
+    exe = Executor(place)
     scope = Scope()
     with scope_guard(scope):
         exe.run(startup)
@@ -61,7 +65,7 @@ def run_check():
         with program_guard(main2, startup2):
             with unique_name.guard():
                 loss2 = _build()
-        exe2 = Executor(TPUPlace())
+        exe2 = Executor(place)
         scope2 = Scope()
         with scope_guard(scope2):
             exe2.run(startup2)
@@ -70,7 +74,9 @@ def run_check():
             exe2.run(cp, feed={"install_check_x": xv,
                                "install_check_y": yv},
                      fetch_list=[loss2], scope=scope2)
-        print(f"Your paddle_tpu works well on {n} devices (mesh dp={n}).")
+        print(f"Your paddle_tpu works well on {n} {dev.platform} devices "
+              f"({dev.device_kind}, mesh dp={n}).")
     else:
-        print("Your paddle_tpu works well on SINGLE device.")
+        print(f"Your paddle_tpu works well on SINGLE {dev.platform} device "
+              f"({dev.device_kind}).")
     print("paddle_tpu is installed successfully!")

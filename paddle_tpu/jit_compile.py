@@ -9,93 +9,34 @@ tuning surface the reference exposes as FLAGS_* gflags
 
 from __future__ import annotations
 
-import hashlib
 import os
 
 import jax
 
-__all__ = [
-    "xla_jit",
-    "parse_xla_options",
-    "enable_compile_cache",
-    "compile_cache_key",
-    "sync_compile_cache_dir",
-]
+__all__ = ["xla_jit", "parse_xla_options", "COMPILE_CACHE_DIR"]
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def compile_cache_key(base_dir: str, build_strategy=None) -> str:
-    """The on-disk directory the persistent XLA cache uses under
-    `base_dir`: a subdirectory named by a hash of the pass-manager cache
-    signature (passes.cache_signature() — resolved pass set + per-pass
-    versions). HLO-derived keys alone are NOT a sufficient guard: two
-    pass sets can lower the same program to byte-identical HLO modules
-    in one region while diverging in semantics the executor layers on
-    top (e.g. fuse_conv_bn's scope-side folded weights), and a pass
-    VERSION bump must invalidate old entries even when the lowering
-    happens to match. A pass-set flip therefore lands in a different
-    directory — a guaranteed miss, never a stale deserialize (the
-    ROADMAP cache-keying item; unit-tested in tests/test_passes.py)."""
-    from .passes import cache_signature
-
-    sig = cache_signature(build_strategy)
-    digest = hashlib.sha256(sig.encode()).hexdigest()[:16]
-    return os.path.join(base_dir, f"passes-{digest}")
-
-
-def enable_compile_cache(cache_dir: str | None = None,
-                         build_strategy=None) -> str | None:
-    """Persistent XLA compilation cache: PADDLE_TPU_COMPILE_CACHE=<dir>
-    (or an explicit `cache_dir`) routes every compiled step — static
-    executor, CompiledProgram mesh path, dygraph JIT bridge — through
-    jax's on-disk cache, so a process restart pays a cache READ instead
-    of the 37-94 s cold XLA compile (ROADMAP MFU item: compile time is a
-    production cold-start cost).
-
-    Keying: entries inside a directory are keyed by optimized HLO +
-    compile options (mesh signature included — shardings are part of
-    the module); the DIRECTORY itself is keyed by the pass-manager
-    cache signature (compile_cache_key), so flipping PADDLE_TPU_PASSES
-    or bumping a pass version can never deserialize an executable
-    lowered under different rewrite semantics. The executor re-points
-    the directory before every compile (sync_compile_cache_dir).
-    Thresholds are zeroed so small test-sized programs cache too.
-    Returns the active dir or None.
-
-    Caveat: on this jaxlib's CPU backend, deserializing cached
-    executables can corrupt the process (observed segfaults under the
-    test suite) — treat the cache as a TPU-backend production knob, not
-    a CPU-test accelerant."""
-    global _COMPILE_CACHE_BASE
-    cache_dir = cache_dir or os.environ.get("PADDLE_TPU_COMPILE_CACHE")
-    if not cache_dir:
-        return None
-    _COMPILE_CACHE_BASE = cache_dir
-    keyed = compile_cache_key(cache_dir, build_strategy)
-    os.makedirs(keyed, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", keyed)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    return keyed
+def _place_compile_cache() -> str:
+    """JAX's persistent compilation cache, placed once at import (JAX
+    builds its cache object on the first compile and never looks at the
+    directory again). Where JAX_COMPILATION_CACHE_DIR is set JAX reads it
+    itself and nothing is set here; otherwise the cache is the fixed
+    `<checkout>/.jax_cache` — the path is part of how a later process
+    finds the entries, so it is never derived from a pid, a time or a
+    temporary name. Child processes (fleet workers, launchers) resolve
+    the same directory the same way. JAX keys each entry on the HLO and
+    the compile options; its own size and compile-time thresholds stay."""
+    from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if from_env:
+        return from_env
+    cache_dir = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
 
 
-_COMPILE_CACHE_BASE: str | None = None
-
-
-def sync_compile_cache_dir(build_strategy=None) -> str | None:
-    """Re-point the persistent cache at the directory matching the
-    CURRENT pass signature (PADDLE_TPU_PASSES can flip between
-    compiles within one process). No-op when no cache is configured."""
-    base = _COMPILE_CACHE_BASE or os.environ.get("PADDLE_TPU_COMPILE_CACHE")
-    if not base:
-        return None
-    keyed = compile_cache_key(base, build_strategy)
-    if jax.config.jax_compilation_cache_dir != keyed:
-        os.makedirs(keyed, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", keyed)
-    return keyed
-
-
-_COMPILE_CACHE_DIR = enable_compile_cache()
+COMPILE_CACHE_DIR = _place_compile_cache()
 
 
 def parse_xla_options(opts: str) -> dict:
@@ -122,8 +63,7 @@ def xla_jit(fun, **kwargs):
     compiler options ("k=v,k=v" -> env_option_overrides). Backend-
     specific knobs like xla_tpu_scoped_vmem_limit_kib are NOT parseable
     from XLA_FLAGS by the local client, but CompileOptions overrides
-    travel with the compile request (including to a remote/tunneled
-    compiler)."""
+    travel with the compile request."""
     opts = os.environ.get("PADDLE_TPU_XLA_OPTIONS", "").strip()
     if opts:
         parsed = parse_xla_options(opts)

@@ -1,4 +1,4 @@
-"""Reference workload models (BASELINE.md configs + the reference's
+"""Reference workload models (BASELINE.json configs + the reference's
 test model zoo), built through the framework's own layers API —
 LeNet-5 (MNIST), ResNet (ImageNet), SE-ResNeXt, VGG, Transformer/BERT
 (WMT16 / pretrain), DeepFM (CTR)."""

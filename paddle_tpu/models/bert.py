@@ -1,4 +1,4 @@
-"""BERT-base pretrain model — the flagship workload (BASELINE.md: BERT-base
+"""BERT-base pretrain model — the flagship workload (BASELINE.json: BERT-base
 tokens/sec/chip, ≥50% MFU north star). Built entirely through the framework's
 layers API; tensor-parallel PartitionSpecs annotate attention/FFN weights
 along "tp" (Megatron-style column→row split), consumed by the GSPMD compile

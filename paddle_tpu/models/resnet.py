@@ -1,4 +1,4 @@
-"""ResNet-50 (the reference's image_classification workload; BASELINE.md
+"""ResNet-50 (the reference's image_classification workload; BASELINE.json
 ResNet-50 ImageNet config). NCHW, bottleneck-v1 like the reference model zoo.
 """
 

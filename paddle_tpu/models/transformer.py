@@ -1,4 +1,4 @@
-"""Transformer-base encoder-decoder for WMT16 en-de (BASELINE.md config;
+"""Transformer-base encoder-decoder for WMT16 en-de (BASELINE.json config;
 reference workload: tests' dist_transformer.py / the Fluid transformer
 model). Shares the attention building blocks with BERT; adds causal self-
 attention + cross attention in the decoder."""

@@ -17,7 +17,11 @@ import jax
 
 from .. import profiler
 from ..analysis.artifacts import load_artifact
-from .pallas.flash_attention import _xla_attention, flash_attention
+from .pallas.flash_attention import (
+    _use_pallas,
+    _xla_attention,
+    flash_attention,
+)
 from .pallas.mha_short import (
     short_attention,
     short_attention_bshd,
@@ -40,7 +44,7 @@ _logger = logging.getLogger(__name__)
 #
 # Env surface:
 #   PADDLE_TPU_ATTN_DISPATCH = auto (default) | xla | flash — force a
-#       path; "flash" on a CPU backend falls back to XLA LOUDLY.
+#       path; "flash" on a backend that cannot compile the kernel raises.
 #   PADDLE_TPU_FLASH_SCORE_BYTES — override the score-bytes knee.
 #   PADDLE_TPU_SP_MODE = ring | ulysses | off — sequence parallelism
 #       over the mesh 'model' axis; unset means AUTO (ring above the
@@ -91,13 +95,6 @@ def _flash_score_bytes() -> int:
 # legacy alias read by older tools; the env override is authoritative
 FLASH_SCORE_BYTES = _flash_score_bytes()
 
-_warned_cpu_fallback = False
-
-
-def _pallas_backend() -> bool:
-    return (jax.default_backend() == "tpu"
-            or bool(os.environ.get("PADDLE_TPU_PALLAS_INTERPRET")))
-
 
 def _use_flash(q, k):
     """Score-bytes knee OR the table's measured seq floor — the
@@ -114,33 +111,18 @@ def _use_flash(q, k):
 
 
 def _flash_dispatch(qb, kb) -> str:
-    """Resolve the flash-vs-XLA decision for bhsd-shaped q/k, honoring
-    the PADDLE_TPU_ATTN_DISPATCH override, with a LOUD one-time fallback
-    when the Pallas path is selected on a non-TPU backend."""
-    global _warned_cpu_fallback
+    """Resolve the flash-vs-XLA decision for bhsd-shaped q/k. `auto`
+    chooses from what it can observe — the shape against the table's
+    thresholds, and whether a Pallas kernel can run on this backend at
+    all. PADDLE_TPU_ATTN_DISPATCH=flash asks for the kernel by name:
+    flash_attention then raises on a backend that cannot compile it."""
     mode = os.environ.get("PADDLE_TPU_ATTN_DISPATCH", "auto").strip().lower()
     if mode not in ("auto", "xla", "flash"):
         raise ValueError(
             f"PADDLE_TPU_ATTN_DISPATCH={mode!r}: expected auto|xla|flash")
-    if mode == "xla":
-        return "xla"
-    want_flash = mode == "flash" or _use_flash(qb, kb)
-    if not want_flash:
-        return "xla"
-    if not _pallas_backend():
-        if not _warned_cpu_fallback:
-            _warned_cpu_fallback = True
-            _logger.warning(
-                "attention dispatch selected the Pallas flash path "
-                "(seq=%d, score bytes=%d) but the backend is %r — "
-                "falling back to XLA attention. This is expected on "
-                "CPU; on TPU it means Pallas is unavailable.",
-                qb.shape[2],
-                qb.shape[0] * qb.shape[1] * qb.shape[2] * kb.shape[2] * 4,
-                jax.default_backend(),
-            )
-        return "xla"
-    return "flash"
+    if mode == "auto":
+        return "flash" if _use_flash(qb, kb) and _use_pallas() else "xla"
+    return mode
 
 
 def _use_short(q, k):
@@ -155,8 +137,7 @@ def _use_short(q, k):
     mode = os.environ.get("PADDLE_TPU_SHORT_ATTN", "0")
     if mode in ("0", ""):
         return None
-    if not (jax.default_backend() == "tpu"
-            or os.environ.get("PADDLE_TPU_PALLAS_INTERPRET")):
+    if not _use_pallas():
         return None
     if not short_attention_viable(q.shape[2], k.shape[2]):
         return None

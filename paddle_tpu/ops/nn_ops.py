@@ -563,11 +563,17 @@ def _layer_norm_grad(ctx, op):
     begin = op.attr("begin_norm_axis", 1)
     n = int(np.prod(x.shape[:begin] or (1,)))
     k = int(np.prod(x.shape[begin:]))
+    from .pallas.flash_attention import _use_pallas
     from .pallas.layer_norm import ln_bwd, ln_bwd_viable
 
-    use_kernel = ln_bwd_viable(n, k) and (
-        jax.default_backend() == "tpu"
-        or os.environ.get("PADDLE_TPU_PALLAS_INTERPRET")
+    # same rule as fused_multihead_attention: a Pallas custom call is
+    # something GSPMD cannot partition, so a multi-device mesh keeps
+    # the XLA formulation, which shards by propagation
+    mesh = ctx.mesh
+    use_kernel = (
+        ln_bwd_viable(n, k)
+        and _use_pallas()
+        and (mesh is None or mesh.devices.size == 1)
     )
     if use_kernel:
         rstd = jax.lax.rsqrt(var.reshape(-1).astype(jnp.float32) + eps)

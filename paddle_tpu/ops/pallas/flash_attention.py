@@ -12,9 +12,9 @@ Layout: q [b, h, sq, d], k/v [b, h, sk, d], optional additive key bias
 to a lane multiple (128); sequence dims are padded to block multiples
 with fully-masked keys.
 
-On non-TPU backends (the CPU test mesh) the same math runs as a plain
-XLA reference path; PADDLE_TPU_PALLAS_INTERPRET=1 forces the Pallas
-kernel in interpreter mode so tests exercise the real kernel body.
+Mosaic compiles the kernel on a TPU. PADDLE_TPU_PALLAS_INTERPRET=1 runs
+it in the Pallas interpreter so CPU tests exercise the real kernel body;
+asked for on any other backend without that variable, it raises.
 """
 
 from __future__ import annotations
@@ -32,16 +32,26 @@ NEG_INF = -1e30
 LANE = 128
 
 
-def _use_pallas():
-    if os.environ.get("PADDLE_TPU_PALLAS_INTERPRET"):
-        return True
-    return jax.default_backend() == "tpu"
-
-
 def _interpret():
-    return bool(os.environ.get("PADDLE_TPU_PALLAS_INTERPRET")) or (
-        jax.default_backend() != "tpu"
-    )
+    """Interpreter mode is the environment variable and nothing else."""
+    return bool(os.environ.get("PADDLE_TPU_PALLAS_INTERPRET"))
+
+
+def _use_pallas():
+    """True where a Pallas kernel can run at all: compiled by Mosaic on a
+    TPU, or interpreted. Dispatch reads this to choose a path."""
+    return _interpret() or jax.default_backend() == "tpu"
+
+
+def require_pallas(kernel: str) -> None:
+    """A kernel called on a backend that cannot compile it raises; it is
+    never swapped for other math behind the caller's back."""
+    if not _use_pallas():
+        raise RuntimeError(
+            f"Pallas kernel {kernel!r} was asked for on the "
+            f"{jax.default_backend()!r} backend: Mosaic compiles only for "
+            "a TPU (PADDLE_TPU_PALLAS_INTERPRET=1 runs the interpreter)"
+        )
 
 
 def _ceil_to(x, m):
@@ -211,6 +221,7 @@ def _fwd_pallas(q, k, v, bias, seed, h, *, sm_scale, causal, causal_offset, drop
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(seed, q, k, v, *bias_args)
     return out, lse
 
@@ -417,6 +428,7 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal, causa
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(seed, q, k, v, do, lse, delta, *bias_in)
 
     kq = lambda i, kb, j: (i, j, 0)
@@ -450,6 +462,7 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal, causa
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(seed, q, k, v, do, lse, delta, *bias_in)
     return dq, dk, dv
 
@@ -623,11 +636,7 @@ def flash_attention(
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(d))
 
-    if not _use_pallas():
-        if dropout > 0.0 and rng_key is None:
-            raise ValueError("dropout requires rng_key")
-        return _reference_attention(q, k, v, bias, causal, sm_scale, dropout, rng_key)
-
+    require_pallas("flash_attention")
     if dropout > 0.0 and rng_key is None:
         raise ValueError("dropout requires rng_key")
     if dropout > 0.0:

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _ceil_to, _interpret
+from .flash_attention import _ceil_to, _interpret, require_pallas
 
 
 def _kernel(x_ref, dy_ref, mean_ref, rstd_ref, scale_ref, dx_ref, dg_ref,
@@ -44,6 +44,7 @@ def ln_bwd(x2, dy2, mean, rstd, scale, block_rows=None):
     """x2/dy2: [n, k]; mean/rstd: [n] fp32; scale: [k] fp32 (ones when the
     LN has no scale). Returns (dx [n, k] in x2's dtype, dscale [k] f32,
     dbias [k] f32)."""
+    require_pallas("ln_bwd")
     n, k = x2.shape
     if block_rows is None:
         # ~5 fp32 row-blocks live in the kernel; keep them within ~5 MB of
@@ -87,5 +88,6 @@ def ln_bwd(x2, dy2, mean, rstd, scale, block_rows=None):
             jax.ShapeDtypeStruct((nb, 1, k), jnp.float32),
         ],
         interpret=_interpret(),
+        name="ln_bwd",
     )(x2, dy2, mean, rstd, scale.reshape(1, k).astype(jnp.float32))
     return dx[:n], jnp.sum(dg[:, 0], axis=0), jnp.sum(db[:, 0], axis=0)

@@ -70,7 +70,7 @@ def ulysses_attention(q, k, v, axis_name="model", axis_size=None, bias=None,
     kh = _constrain(_constrain(k, seq_spec, mesh), head_spec, mesh)
     vh = _constrain(_constrain(v, seq_spec, mesh), head_spec, mesh)
 
-    from ..ops.fused_ops import _use_flash
+    from ..ops.fused_ops import _flash_dispatch
     from ..ops.pallas.flash_attention import (
         _reference_attention,
         flash_attention,
@@ -80,7 +80,7 @@ def ulysses_attention(q, k, v, axis_name="model", axis_size=None, bias=None,
         sm_scale = 1.0 / float(d) ** 0.5
     if bias is not None:
         bias = jnp.asarray(bias, jnp.float32)
-    if _use_flash(qh, kh):
+    if _flash_dispatch(qh, kh) == "flash":
         out = flash_attention(qh, kh, vh, bias=bias, causal=causal,
                               sm_scale=sm_scale, dropout=dropout,
                               rng_key=rng_key)

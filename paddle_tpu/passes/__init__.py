@@ -63,10 +63,8 @@ counters (pass_<name>_us, pass_<name>_ops_removed, program_ops_before/
 _after) in the style of the dygraph_jit_* counters.
 
 `cache_signature()` names the resolved pass set plus each pass's
-implementation version — the persistent XLA compile cache
-(jit_compile.enable_compile_cache) keys its directory on it so a
-pass-set flip (or a semantics-changing pass upgrade) MISSES the on-disk
-cache instead of deserializing a stale executable.
+implementation version — bench.py keys its resumable partial results on
+it, so numbers measured under different rewrite semantics never merge.
 
 Verifier contract (PADDLE_TPU_VERIFY): when the env var is truthy
 (default-on under pytest via tests/conftest.py; any of ""/"0"/"off"/
@@ -218,11 +216,7 @@ def resolve_pass_names(build_strategy=None) -> tuple:
 def cache_signature(build_strategy=None) -> str:
     """Stable name of the resolved pass configuration: ordered pass
     names, each with its implementation version ("const_fold:1,dce:2").
-    The persistent XLA compile cache keys a subdirectory on this string
-    (jit_compile.enable_compile_cache): a pass-set flip or a pass
-    version bump must MISS the on-disk cache rather than deserialize an
-    executable lowered under different rewrite semantics. An empty pass
-    set signs as "nopass"."""
+    An empty pass set signs as "nopass"."""
     names = resolve_pass_names(build_strategy)
     if not names:
         return "nopass"

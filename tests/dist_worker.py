@@ -11,8 +11,7 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # virtual 2-device CPU host: must land in XLA_FLAGS BEFORE the backend
-# initializes (the jax_num_cpu_devices config knob does not exist on this
-# jax line)
+# initializes
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -25,11 +24,6 @@ def main():
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from jax._src import xla_bridge
-
-    if xla_bridge.backends_are_initialized():
-        xla_bridge._clear_backends()
-        xla_bridge.get_backend.cache_clear()
     # multi-process collectives on the CPU backend need the gloo
     # transport selected before backend init (the default 'none' raises
     # "Multiprocess computations aren't implemented on the CPU backend")

@@ -23,10 +23,6 @@ import time
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-from jax._src import xla_bridge  # noqa: E402
-
-if xla_bridge.backends_are_initialized():
-    xla_bridge._clear_backends()
 
 import numpy as np  # noqa: E402
 

@@ -364,7 +364,7 @@ def _tiny_train_setup(seed=7):
     loss = fluid.layers.mean(fluid.layers.cross_entropy(pred, y))
     fluid.default_main_program().random_seed = seed
     fluid.optimizer.Adam(1e-2).minimize(loss)
-    exe = fluid.Executor(fluid.TPUPlace())
+    exe = fluid.Executor(fluid.CPUPlace())
     exe.run(fluid.default_startup_program())
     feed = {
         "x": np.random.RandomState(1).rand(8, 16).astype("float32"),

@@ -275,6 +275,33 @@ def test_run_repeated_compiled_program_mesh():
     np.testing.assert_allclose(stacked.reshape(5), seq, rtol=1e-6)
 
 
+def test_run_repeated_under_xla_options(monkeypatch):
+    """PADDLE_TPU_XLA_OPTIONS reaches every top-level jit, and JAX 0.9.0
+    refuses compiler_options on a nested one: run_repeated traces the
+    step inside its own jit, on the plain and on the mesh path (bench.py
+    runs every timed window this way with TPU options set)."""
+    import numpy as np
+
+    import paddle_tpu as fluid
+
+    monkeypatch.setenv("PADDLE_TPU_XLA_OPTIONS",
+                       "xla_cpu_enable_fast_math=false")
+    x = fluid.layers.data("x", [8, 4], append_batch_size=False)
+    loss = fluid.layers.reduce_mean(
+        fluid.layers.square(fluid.layers.fc(x, 8, act="relu")))
+    fluid.optimizer.SGD(0.05).minimize(loss)
+    feed = {"x": np.random.RandomState(1).randn(8, 4).astype("float32")}
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    exe.run(feed=feed, fetch_list=[loss])
+    (plain,) = exe.run_repeated(feed=feed, fetch_list=[loss], steps=3)
+    cp = fluid.CompiledProgram(
+        fluid.default_main_program()).with_data_parallel(loss_name=loss.name)
+    (mesh,) = exe.run_repeated(cp, feed=feed, fetch_list=[loss], steps=3)
+    assert np.isfinite(plain).all() and np.isfinite(mesh).all()
+    assert mesh.reshape(-1)[0] < plain.reshape(-1)[0]  # it kept training
+
+
 def test_run_repeated_microbatched_program():
     """run_repeated composes with PipelineOptimizer gradient-merge
     microbatching (the scan wraps the microbatched step fn)."""

@@ -194,7 +194,7 @@ def test_fused_mha_layer_in_program(rng):
         cfg.use_flash_attention = use_flash
         np.random.seed(0)
         handles = build_bert_pretrain(cfg, batch_size=2, seq_len=32, is_test=True)
-        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe = fluid.Executor(fluid.CPUPlace())
         exe.run(fluid.default_startup_program())
         rs = np.random.RandomState(3)
         feed = {
@@ -341,21 +341,22 @@ def test_dispatch_score_bytes_env_is_a_force(monkeypatch):
     assert fused_ops._use_flash(q[:, :, :8], q[:, :, :8])
 
 
-def test_dispatch_cpu_fallback_is_loud(monkeypatch, caplog):
-    import logging
-
+def test_dispatch_never_swaps_a_kernel_that_was_asked_for(monkeypatch):
     from paddle_tpu.ops import fused_ops
 
-    # force the flash path on a non-Pallas backend: must fall back to
-    # XLA with a WARNING, not crash and not silently
+    # no interpreter, no TPU: `auto` observes that no Pallas kernel can
+    # run here and picks XLA; `flash` asks for the kernel by name, and the
+    # kernel raises rather than run other math in its place
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.delenv("PADDLE_TPU_ATTN_DISPATCH", raising=False)
+    s = int(fused_ops.attn_dispatch_thresholds()["flash_min_seq"])
+    long_q = jnp.zeros((1, 1, s, 64))
+    assert fused_ops._flash_dispatch(long_q, long_q) == "xla"
     monkeypatch.setenv("PADDLE_TPU_ATTN_DISPATCH", "flash")
-    monkeypatch.setattr(fused_ops, "_warned_cpu_fallback", False)
     q = jnp.zeros((1, 1, 16, 64))
-    with caplog.at_level(logging.WARNING,
-                         logger="paddle_tpu.ops.fused_ops"):
-        assert fused_ops._flash_dispatch(q, q) == "xla"
-    assert any("falling back to XLA" in r.message for r in caplog.records)
+    assert fused_ops._flash_dispatch(q, q) == "flash"
+    with pytest.raises(RuntimeError, match="'cpu' backend"):
+        fa.flash_attention(q, q, q)
     # env validation is strict
     monkeypatch.setenv("PADDLE_TPU_ATTN_DISPATCH", "nope")
     with pytest.raises(ValueError, match="PADDLE_TPU_ATTN_DISPATCH"):

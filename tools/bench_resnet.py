@@ -14,7 +14,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from paddle_tpu.models.resnet import (  # noqa: E402
     RESNET50_TRAIN_FLOPS_PER_IMG as TRAIN_FLOPS_PER_IMG,
 )
-from paddle_tpu.place import V5E_BF16_PEAK_FLOPS as V5E_BF16_PEAK  # noqa: E402
+from paddle_tpu.place import peak_bf16_flops  # noqa: E402
 
 
 def log(*a):
@@ -69,7 +69,8 @@ def main():
     np.asarray(out[0])
     dt = time.time() - t0
     dev_ips = b * steps / dt
-    mfu = dev_ips * TRAIN_FLOPS_PER_IMG / V5E_BF16_PEAK
+    mfu = (dev_ips * TRAIN_FLOPS_PER_IMG
+           / peak_bf16_flops(jax.devices()[0].device_kind))
     log(f"device-staged: {dev_ips:,.0f} img/s ({dt / steps * 1e3:.1f} ms"
         f"/step, MFU~{mfu * 100:.1f}%)")
 

@@ -1,7 +1,7 @@
-"""Transformer-base WMT16 train throughput on the real chip (the
-BASELINE.md row this updates). Thin delegate: the canonical workload
-body lives in bench.py (bench_transformer); the FLOPs accounting lives
-in paddle_tpu.models.transformer.transformer_flops_per_trg_token.
+"""Transformer-base WMT16 train throughput on the chip. Thin delegate:
+the canonical workload body lives in bench.py (bench_transformer); the
+FLOPs accounting lives in
+paddle_tpu.models.transformer.transformer_flops_per_trg_token.
 
 Prints the transformer metric as ONE stdout JSON line (this tool's own
 contract — bench.py's stdout headline stays BERT).
@@ -25,13 +25,7 @@ from paddle_tpu.models.transformer import (  # noqa: F401,E402 (back-compat)
 def main():
     import bench
 
-    err = bench._probe_device()
-    if err:
-        print(json.dumps({
-            "metric": "transformer_base_wmt16_tokens_per_sec_per_chip",
-            "value": 0.0, "unit": "tokens/s/chip", "error": err,
-        }))
-        return
+    bench.require_tpu()
     bench.bench_transformer()
     payload = bench._EXTRA["transformer_base_wmt16_tokens_per_sec_per_chip"]
     print(json.dumps({

@@ -214,12 +214,8 @@ if [ "$1" != "quick" ]; then
   python __graft_entry__.py 8
 
   echo "== entry() single-chip jit trace check (CPU abstract eval) =="
-  python - << 'EOF'
+  JAX_PLATFORMS=cpu python - << 'EOF'
 import jax
-jax.config.update("jax_platforms", "cpu")
-from jax._src import xla_bridge
-if xla_bridge.backends_are_initialized():
-    xla_bridge._clear_backends()
 from __graft_entry__ import entry
 fn, args = entry()
 out = jax.eval_shape(fn, *args)

@@ -1,12 +1,11 @@
 #!/usr/bin/env python
-"""Multichip dryrun CLI: runs the hermetic virtual-mesh dryrun
-(__graft_entry__.dryrun_multichip) and writes a MULTICHIP_rXX-style JSON
-report with the per-config HBM + collective evidence lines, so rounds
-stay comparable (r01-r05 carried the ZeRO-1 106 MB vs 424 MB numbers;
-the mesh path reports hbm_state_mb_per_device / _replicated and
+"""Multichip dryrun CLI: runs the virtual-CPU-mesh dryrun
+(__graft_entry__.dryrun_multichip — forced-CPU virtual devices, never
+chips) and writes a JSON report with the per-config HBM + collective
+evidence lines (hbm_state_mb_per_device / _replicated and
 collective_bytes_estimate per config).
 
-    python tools/dryrun_multichip.py [n_devices] [--out MULTICHIP_r06.json]
+    python tools/dryrun_multichip.py [n_devices] [--out report.json]
     python tools/dryrun_multichip.py 8 --static
 
 --static consumes the STATIC analysis layer instead of tracing: the

@@ -18,10 +18,7 @@ def main():
     os.environ.setdefault("TF_STEPS", "5")
     import bench
 
-    err = bench._probe_device()
-    if err:
-        print(f"ABORT: {err}", file=sys.stderr)
-        return
+    bench.require_tpu()
     # run the canonical workload once to compile + warm, then trace the
     # timing windows
     import json

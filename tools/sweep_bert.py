@@ -78,11 +78,8 @@ def run_config(name: str, extra_env: dict) -> dict:
                 continue
             out["tok_s"] = j.get("value")
             out["vs_baseline"] = j.get("vs_baseline")
-            out["calib_frac"] = (
-                j.get("extra", {}).get("calibration", {}).get("frac_of_peak")
-            )
-            # watchdog partials look like value 0.0 rc 0 — carry the
-            # error fields so a failed probe never reads as "0 tok/s"
+            # carry the error fields so a failed run never reads as
+            # "0 tok/s"
             for k in ("error", "secondary_errors"):
                 if j.get(k):
                     out[k] = j[k]

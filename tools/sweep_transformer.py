@@ -66,7 +66,6 @@ def run_config(name: str, extra_env: dict) -> dict:
                 "transformer_base_wmt16_tokens_per_sec_per_chip", {})
             out["tok_s"] = tf.get("value")
             out["mfu"] = tf.get("mfu")
-            out["calib"] = j.get("extra", {}).get("calibration")
     m = re.search(r"window times: (\[[^\]]*\])", p.stderr)
     if m:
         out["windows"] = m.group(1)
