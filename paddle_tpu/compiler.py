@@ -22,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from .framework import Program
+from .profiler import RecordEvent
 from .scope import global_scope
 
 __all__ = ["CompiledProgram", "BuildStrategy", "ExecutionStrategy"]
@@ -216,49 +217,17 @@ class CompiledProgram:
             executor, feed, fetch_list, scope
         )
 
-        # counter advances only after a successful dispatch (same
-        # contract as Executor.run / run_repeated): a failed/retried
-        # step replays the same PRNG tick
-        base = program.random_seed or 42
-        rng = jax.random.fold_in(jax.random.key(base),
-                                 executor._seed_counter + 1)
-        from .executor import fault_point
-
-        fault_point("executor.dispatch")
-        result = compiled.fn(state, feeds, rng)
-        executor._seed_counter += 1
-        if len(result) == 3:  # PADDLE_TPU_CHECK_NAN_INF=1 debug mode
-            from .executor import check_nan_result
-
-            fetches, new_state = check_nan_result(result, compiled, scope)
-        else:
-            fetches, new_state = result
-        for n, v in new_state.items():
-            scope.set(n, v)
-
-        # step boundary on the mesh path: chaos anchor + heartbeat BEFORE
-        # the checkpoint hook, same contract and ordering as Executor.run
-        # — a supervised multi-rank job (the TrainSupervisor's main
-        # customer) dispatches HERE, and without this hook the watchdog
-        # would read a healthy fleet job as hung
-        from .executor import _trainer_heartbeat
-
-        mgr = (getattr(program, "_ckpt_manager", None)
-               or getattr(self, "_ckpt_manager", None))
-        executor._dispatch_count += 1
-        fault_point("trainer.step")
-        _trainer_heartbeat(None if mgr is None else mgr._auto_step,
-                           executor._dispatch_count)
-
-        # resilience attach-cadence fires on the mesh path too (same hook
-        # as Executor.run — a CheckpointManager attached to either the
-        # CompiledProgram or its underlying Program auto-snapshots here)
-        if mgr is not None:
-            mgr._on_executor_step(program, scope, executor)
-
-        if return_numpy:
-            return [np.asarray(f) for f in fetches]
-        return list(fetches)
+        result = executor._dispatch(program, compiled, state, feeds)
+        del state  # dead once donated: released by the write-back
+        # the step boundary (write-back, chaos anchor, heartbeat, then the
+        # checkpoint hook) is Executor.run's own: a supervised multi-rank
+        # job dispatches HERE, and a CheckpointManager attached to either
+        # the CompiledProgram or its underlying Program snapshots there
+        with RecordEvent("pt.exe.writeback"):
+            return executor._write_back(
+                program, compiled, result, scope, return_numpy,
+                getattr(program, "_ckpt_manager", None)
+                or getattr(self, "_ckpt_manager", None))
 
     def _run_repeated(self, executor, feed, fetch_list, steps, scope,
                       return_numpy):
@@ -345,8 +314,17 @@ class CompiledProgram:
         return list(stacked)
 
     def _prepare_mesh_run(self, executor, feed, fetch_list, scope):
-        import jax.numpy as jnp
+        with RecordEvent("pt.exe.prepare"):
+            compiled, feed_items, mesh = self._lookup_mesh_step(
+                executor, feed, fetch_list, scope)
+            feeds = self._mesh_feeds(feed_items, mesh)
+        with RecordEvent("pt.exe.state"):
+            state = self._mesh_state(compiled, scope, mesh)
+        return compiled, state, feeds, self._program
 
+    def _lookup_mesh_step(self, executor, feed, fetch_list, scope):
+        """Feed normalization + compile-cache lookup under the mesh.
+        Returns (compiled, [(name, host array)], mesh)."""
         from .executor import _as_feed_array
         from .framework import Variable
 
@@ -398,28 +376,53 @@ class CompiledProgram:
             # microbatch schedule); plain forward-only programs keep
             # train-mode semantics, same as exe.run(program)
             is_test = bool(getattr(program, "_is_test_clone", False))
-            compiled = executor._compile(
-                program,
-                block,
-                feed_sig,
-                fetch_names,
-                scope,
-                is_test=is_test,
-                mesh=mesh,
-                sharding_specs=program._sharding_specs,
-                build_strategy=self._build_strategy,
-                zero1=bool(getattr(self, "_zero1", False)),
-            )
+            with RecordEvent("pt.exe.compile"):
+                compiled = executor._compile(
+                    program,
+                    block,
+                    feed_sig,
+                    fetch_names,
+                    scope,
+                    is_test=is_test,
+                    mesh=mesh,
+                    sharding_specs=program._sharding_specs,
+                    build_strategy=self._build_strategy,
+                    zero1=bool(getattr(self, "_zero1", False)),
+                )
             executor._cache[key] = compiled
+        return compiled, feed_items, mesh
 
+    @staticmethod
+    def _mesh_feeds(feed_items, mesh):
+        import jax.numpy as jnp
+
+        if jax.process_count() == 1:
+            return {name: jnp.asarray(arr) for name, arr in feed_items}
+        # multi-process (fleet) execution: each trainer feeds its
+        # process-LOCAL batch shard (the reference's trainers read
+        # disjoint file splits); assemble global arrays spanning all
+        # processes
+        return {
+            name: jax.make_array_from_process_local_data(
+                NamedSharding(
+                    mesh,
+                    P("batch", *([None] * (arr.ndim - 1)))
+                    if arr.ndim else P(),
+                ),
+                np.asarray(arr),
+            )
+            for name, arr in feed_items
+        }
+
+    @staticmethod
+    def _mesh_state(compiled, scope, mesh):
+        import jax.numpy as jnp
+
+        state = {}
         if jax.process_count() > 1:
-            # multi-process (fleet) execution: each trainer feeds its
-            # process-LOCAL batch shard (the reference's trainers read
-            # disjoint file splits); assemble global arrays spanning all
-            # processes. State is replicated — every process initialized
-            # identically from the seeded startup program.
+            # state is replicated — every process initialized identically
+            # from the seeded startup program
             rep = NamedSharding(mesh, P())
-            state = {}
             for n in compiled.state_names:
                 val = scope.get(n) if scope.has(n) else None
                 if isinstance(val, jax.Array) and not val.is_fully_addressable:
@@ -430,33 +433,19 @@ class CompiledProgram:
                     state[n] = jax.make_array_from_process_local_data(
                         rep, np.asarray(val if val is not None else 0.0)
                     )
-            feeds = {
-                name: jax.make_array_from_process_local_data(
-                    NamedSharding(
-                        mesh,
-                        P("batch", *([None] * (arr.ndim - 1)))
-                        if arr.ndim else P(),
-                    ),
-                    np.asarray(arr),
-                )
-                for name, arr in feed_items
-            }
-        else:
-            state_sh = getattr(compiled, "state_shardings", {}) or {}
-            state = {}
-            for n in compiled.state_names:
-                val = scope.get(n) if scope.has(n) else None
-                if not isinstance(val, jax.Array):
-                    val = jnp.asarray(val if val is not None else 0.0)
-                else:
-                    want = state_sh.get(n)
-                    if want is not None and val.sharding != want:
-                        # one-time reshard: a committed layout from an
-                        # earlier compile (different zero1/pipe specs)
-                        # moves onto this compile's assignment; steady
-                        # state re-enters already matching (out_shardings)
-                        val = jax.device_put(val, want)
-                state[n] = val
-            feeds = {name: jnp.asarray(arr) for name, arr in feed_items}
-
-        return compiled, state, feeds, program
+            return state
+        state_sh = getattr(compiled, "state_shardings", {}) or {}
+        for n in compiled.state_names:
+            val = scope.get(n) if scope.has(n) else None
+            if not isinstance(val, jax.Array):
+                val = jnp.asarray(val if val is not None else 0.0)
+            else:
+                want = state_sh.get(n)
+                if want is not None and val.sharding != want:
+                    # one-time reshard: a committed layout from an
+                    # earlier compile (different zero1/pipe specs)
+                    # moves onto this compile's assignment; steady
+                    # state re-enters already matching (out_shardings)
+                    val = jax.device_put(val, want)
+            state[n] = val
+        return state

@@ -32,6 +32,7 @@ from .framework import (
 )
 from .ops.registry import JNP_DTYPE, LoweringContext, lower_block, lower_op
 from .place import CPUPlace, Place, TPUPlace
+from .profiler import RecordEvent
 from .resilience.faults import fault_point
 from .scope import Scope, global_scope
 
@@ -871,25 +872,50 @@ class Executor:
             return cp._run(self, feed, fetch_list, scope, return_numpy)
 
         scope = scope or global_scope()
-        compiled, feeds, fetch_names = self._prepare_run(
-            program, feed, fetch_list, scope
-        )
-        state = self._assemble_state(compiled, scope)
+        # the pt.exe.* spans are the same on the mesh path
+        # (CompiledProgram._run); PERF.md lists what reads them
+        with RecordEvent("pt.exe.prepare"):
+            compiled, feeds, fetch_names = self._prepare_run(
+                program, feed, fetch_list, scope
+            )
+        with RecordEvent("pt.exe.state"):
+            state = self._assemble_state(compiled, scope)
 
-        # functional PRNG: fold in a per-run counter so randomness varies
-        # across steps; with program.random_seed set the whole sequence is
-        # reproducible from run 0 (reference: Program.random_seed semantics)
-        base = program.random_seed or 42
-        rng = jax.random.fold_in(jax.random.key(base),
-                                 self._seed_counter + 1)
+        result = self._dispatch(program, compiled, state, feeds)
+        # the donated inputs are dead: the write-back, which replaces them
+        # in the scope, releases them (1,010 arrays for BERT-base), and
+        # not the return from this frame
+        del state
+        with RecordEvent("pt.exe.writeback"):
+            return self._write_back(
+                program, compiled, result, scope, return_numpy,
+                getattr(program, "_ckpt_manager", None))
 
-        # chaos site: a raise here is a device/runtime failure at the
-        # dispatch boundary (before any executor-visible mutation — the
-        # seed counter only advances once the step actually dispatched,
-        # so a caught-and-retried failure replays the same PRNG tick)
-        fault_point("executor.dispatch")
-        result = compiled.fn(state, feeds, rng)
+    def _dispatch(self, program, compiled, state, feeds):
+        """Enqueue one step, on the mesh path too."""
+        with RecordEvent("pt.exe.dispatch"):
+            # functional PRNG: fold in a per-run counter so randomness
+            # varies across steps; with program.random_seed set the whole
+            # sequence is reproducible from run 0 (reference:
+            # Program.random_seed semantics)
+            base = program.random_seed or 42
+            rng = jax.random.fold_in(jax.random.key(base),
+                                     self._seed_counter + 1)
+
+            # chaos site: a raise here is a device/runtime failure at the
+            # dispatch boundary (before any executor-visible mutation — the
+            # seed counter only advances once the step actually dispatched,
+            # so a caught-and-retried failure replays the same PRNG tick)
+            fault_point("executor.dispatch")
+            result = compiled.fn(state, feeds, rng)
         self._seed_counter += 1
+        return result
+
+    def _write_back(self, program, compiled, result, scope, return_numpy,
+                    mgr):
+        """What follows a step's dispatch, on the mesh path too: the new
+        state into the scope, the step boundary's hooks (`mgr` is the
+        attached CheckpointManager or None), the fetches."""
         if len(result) == 3:  # PADDLE_TPU_CHECK_NAN_INF=1 debug mode
             fetches, new_state = check_nan_result(result, compiled, scope)
         else:
@@ -906,7 +932,6 @@ class Executor:
         # resuming past a step nobody observed complete. A hold also
         # keeps THIS step's heartbeat from landing — the watchdog sees
         # progress stuck at N-1.
-        mgr = getattr(program, "_ckpt_manager", None)
         self._dispatch_count += 1
         fault_point("trainer.step")
         _trainer_heartbeat(None if mgr is None else mgr._auto_step,
@@ -965,9 +990,11 @@ class Executor:
         )
         compiled = self._cache.get(key)
         if compiled is None:
-            compiled = self._compile(
-                program, block, feed_sig, fetch_names, scope, is_test=False
-            )
+            with RecordEvent("pt.exe.compile"):
+                compiled = self._compile(
+                    program, block, feed_sig, fetch_names, scope,
+                    is_test=False
+                )
             self._cache[key] = compiled
             from . import profiler
             from .dygraph.jit import _jit_cache_cap

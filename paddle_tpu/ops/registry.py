@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..framework import convert_dtype, is_float_dtype
+from ..framework import convert_dtype, core_op_role, is_float_dtype
 
 __all__ = [
     "OpDef",
@@ -300,14 +300,34 @@ def _amp_precast(ctx, op):
     return saved
 
 
+def op_scope(op) -> str:
+    """`phase/name` of a Program op, the jax.named_scope its lowering runs
+    under: every HLO instruction it traces carries it in its metadata, so
+    a device trace reads in Program vocabulary. Phase from `op_role`:
+    `fwd` (Forward, Loss), `bwd` (Backward), `opt` (Optimize, LRSched and
+    anything else); an `__auto_grad__` is named after its forward op."""
+    role = op.attr("op_role", 0) or 0
+    if role & core_op_role.Backward:
+        phase = "bwd"
+    elif role & ~core_op_role.Loss:
+        phase = "opt"
+    else:
+        phase = "fwd"
+    if op.type == "__auto_grad__":
+        return f"{phase}/{op.attr('fwd_type')}_grad"
+    return f"{phase}/{op.type}"
+
+
 def lower_op(ctx: LoweringContext, op):
     try:
-        saved = _amp_precast(ctx, op)
-        try:
-            get_op(op.type).lower(ctx, op)
-        finally:
-            for _n, _v in saved.items():
-                ctx.values[_n] = _v
+        # names only: HLO metadata, not the computation
+        with jax.named_scope(op_scope(op)):
+            saved = _amp_precast(ctx, op)
+            try:
+                get_op(op.type).lower(ctx, op)
+            finally:
+                for _n, _v in saved.items():
+                    ctx.values[_n] = _v
         return
     except Exception as e:
         # op_call_stack.cc analog: a failing lowering names the op AND the

@@ -1,22 +1,29 @@
 """Profiler (reference: python/paddle/fluid/profiler.py:225,127,168 and
 platform/profiler.h:81 RecordEvent spans, profiler.cc:322 tables).
 
-TPU-native design: host-side RAII spans aggregate into the reference-style
-sorted table; device-side tracing delegates to jax.profiler (XPlane →
-TensorBoard / Perfetto), replacing the reference's CUPTI DeviceTracer
-(platform/device_tracer.h:41)."""
+A span (`RecordEvent`) is a `jax.profiler.TraceAnnotation`: while a
+`jax.profiler` trace is being taken it lands in the trace's host plane, on
+the clock the device's operations are on; between `start_profiler` and
+`stop_profiler` it is also aggregated into the reference-style table.
+To trace a running job, wrap some steps in
+`profiler.profiler(trace_dir=...)` and open the `.xplane.pb` it leaves
+there (TensorBoard, Perfetto, or `benchmark/harness/trace_reduce.py`);
+the device side replaces the reference's CUPTI DeviceTracer
+(platform/device_tracer.h:41). The `pt.*` spans the Executor and the
+reader write, and the `fwd/ bwd/ opt/` scopes on device operations, are
+listed in `PERF.md`."""
 
 from __future__ import annotations
 
 import contextlib
-import os
 import threading
 import time
 from collections import defaultdict
 
+import jax
+
 __all__ = [
     "profiler",
-    "export_chrome_tracing",
     "start_profiler",
     "stop_profiler",
     "reset_profiler",
@@ -29,7 +36,6 @@ __all__ = [
 ]
 
 _events: dict[str, list[float]] = defaultdict(list)
-_spans: list[tuple[str, float, float]] = []  # (name, start, dur) timeline
 _counters: dict[str, int] = defaultdict(int)  # monotonic named counts
 # serving handler threads (server + fleet router) bump concurrently:
 # the read-modify-write below is not atomic under the GIL, and a lost
@@ -246,21 +252,24 @@ def time_counter(name: str):
 
 
 class RecordEvent:
-    """RAII span (reference: platform/profiler.h:81)."""
+    """RAII span (reference: platform/profiler.h:81): a TraceMe in the
+    `jax.profiler` trace (an atomic load when none is being taken), and a
+    row of the table while the profiler is started."""
 
     def __init__(self, name):
         self.name = name
         self._t0 = None
+        self._annotation = jax.profiler.TraceAnnotation(name)
 
     def __enter__(self):
         self._t0 = time.perf_counter()
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._annotation.__exit__(*exc)
         if _active:
-            t1 = time.perf_counter()
-            _events[self.name].append(t1 - self._t0)
-            _spans.append((self.name, self._t0, t1 - self._t0))
+            _events[self.name].append(time.perf_counter() - self._t0)
 
 
 record_event = RecordEvent
@@ -272,8 +281,6 @@ def start_profiler(state="All", tracer_option=None, trace_dir=None):
     global _active, _trace_dir
     _active = True
     if trace_dir:
-        import jax
-
         _trace_dir = trace_dir
         jax.profiler.start_trace(trace_dir)
 
@@ -283,8 +290,6 @@ def stop_profiler(sorted_key="total", profile_path=None):
     global _active, _trace_dir
     _active = False
     if _trace_dir:
-        import jax
-
         jax.profiler.stop_trace()
         _trace_dir = None
     rows = []
@@ -321,33 +326,8 @@ def stop_profiler(sorted_key="total", profile_path=None):
 def reset_profiler():
     """reference: profiler.py:105."""
     _events.clear()
-    _spans.clear()
     with _counters_lock:
         _counters.clear()
-
-
-def export_chrome_tracing(path):
-    """Write the host-span timeline as chrome://tracing JSON (the role of
-    the reference's tools/timeline.py converting profiler.proto). Open in
-    chrome://tracing or Perfetto; device-side kernels come from the
-    jax.profiler trace_dir instead."""
-    import json
-
-    events = [
-        {
-            "name": name,
-            "ph": "X",
-            "ts": start * 1e6,
-            "dur": dur * 1e6,
-            "pid": 0,
-            "tid": 0,
-            "cat": "host",
-        }
-        for name, start, dur in _spans
-    ]
-    with open(path, "w") as f:
-        json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
-    return path
 
 
 @contextlib.contextmanager

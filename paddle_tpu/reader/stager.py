@@ -65,7 +65,8 @@ class DeviceStager:
             for item in self._source:
                 if self._stop.is_set():
                     return
-                staged = self._stage(item)
+                with profiler.RecordEvent("pt.reader.stage"):
+                    staged = self._stage(item)
                 profiler.bump_counter("reader_staged_batches")
                 if not self._put(staged):
                     return
@@ -77,7 +78,8 @@ class DeviceStager:
     def __iter__(self):
         try:
             while True:
-                item = self._q.get()
+                with profiler.RecordEvent("pt.reader.wait"):
+                    item = self._q.get()
                 if item is _DONE:
                     return
                 if isinstance(item, _StageError):

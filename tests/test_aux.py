@@ -1,9 +1,8 @@
 """Aux subsystem tests: EMA/ModelAverage/Lookahead wrappers, quantization
-(QAT rewrite), profiler timeline export, sync BN, DGC/LocalSGD fallbacks
-(reference: optimizer.py:2263,2453,2976,805; contrib/slim/quantization;
-tools/timeline.py; SURVEY.md §5)."""
+(QAT rewrite), profiler spans in the jax.profiler trace, sync BN,
+DGC/LocalSGD fallbacks (reference: optimizer.py:2263,2453,2976,805;
+contrib/slim/quantization; SURVEY.md §5)."""
 
-import json
 import warnings
 
 import numpy as np
@@ -174,22 +173,34 @@ def test_dgc_tolerates_reference_kwargs():
     assert opt._momentum == 0.9
 
 
-def test_profiler_chrome_trace(tmp_path):
+def test_profiler_record_event_lands_in_the_jax_trace(tmp_path):
+    """A RecordEvent span is a row of the table and, under
+    `profiler(trace_dir=...)`, an event of the jax.profiler trace, nested
+    as it was entered."""
+    import glob
+
+    from jax.profiler import ProfileData
+
     import paddle_tpu.profiler as prof
 
     prof.reset_profiler()
-    prof.start_profiler()
-    with prof.RecordEvent("step"):
-        with prof.RecordEvent("forward"):
-            sum(range(1000))
-    prof.stop_profiler(profile_path=str(tmp_path / "table.txt"))
+    with prof.profiler(profile_path=str(tmp_path / "table.txt"),
+                       trace_dir=str(tmp_path / "trace")):
+        with prof.RecordEvent("step"):
+            with prof.RecordEvent("forward"):
+                sum(range(1000))
     table = (tmp_path / "table.txt").read_text()
     assert "step" in table and "forward" in table
-    path = prof.export_chrome_tracing(str(tmp_path / "trace.json"))
-    trace = json.loads(open(path).read())
-    names = {e["name"] for e in trace["traceEvents"]}
-    assert {"step", "forward"} <= names
-    assert all(e["ph"] == "X" for e in trace["traceEvents"])
+    (path,) = glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"),
+                        recursive=True)
+    spans = {ev.name: (ev.start_ns, ev.start_ns + ev.duration_ns)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events
+             if ev.name in ("step", "forward")}
+    assert set(spans) == {"step", "forward"}
+    assert spans["step"][0] <= spans["forward"][0]
+    assert spans["forward"][1] <= spans["step"][1]
 
 
 def test_sync_batch_norm_is_batch_norm():
