@@ -11,42 +11,43 @@ import functools
 import logging
 import os
 
-import jax.numpy as jnp
-
 import jax
+import jax.numpy as jnp
+import numpy as np
 
 from .. import profiler
 from ..analysis.artifacts import load_artifact
-from .pallas.flash_attention import (
-    _use_pallas,
-    _xla_attention,
-    flash_attention,
-)
-from .pallas.mha_short import (
-    short_attention,
-    short_attention_bshd,
-    short_attention_viable,
-)
+from .pallas.flash_attention import _xla_attention, flash_attention
+from .pallas.mha_short import mha_short, mha_short_viable
 from .registry import register_op
 
 _logger = logging.getLogger(__name__)
 
-# attention kernel selection: sequences short enough that a whole score
-# row fits VMEM use the head-batched short-seq kernel (mha_short.py);
-# above that the blocked flash kernel takes over once the [b, h, sq, sk]
-# fp32 score tensor stops fitting comfortably in HBM (measured on v5e at
-# s=512: XLA 299ms/step vs blocked Pallas 2069ms — blocked kernel only
-# pays off beyond the HBM knee). Cutover is by score-tensor MEMORY
-# (batch matters as much as seq), not seq alone — PLUS a measured
-# seq-length floor from the checked-in dispatch table
-# (ops/pallas/attn_dispatch_table.json, the tools/longseq_study.py
-# decision): above `flash_min_seq` the Pallas path is the DEFAULT.
+# Attention kernel selection, from what the lowering can observe:
+#   short  ops/pallas/mha_short.py, where a few batch rows of whole score
+#          rows fit VMEM: one device, a backend that runs Pallas, layout
+#          "bshd", head_dim 64 or 128 with heads*head_dim a multiple of
+#          128, and sq, sk up to mha_short.MAX_SHORT_SEQ (set from chip
+#          runs of the benchmark's one-chip cells, PERF.md). Its operands
+#          are the [b, s, heads*dh] arrays the projections write, so the
+#          head relayout copies XLA puts around its own batched products
+#          are not in the step.
+#   flash  ops/pallas/flash_attention.py, once the [b, h, sq, sk] float32
+#          scores stop fitting HBM comfortably (by score-tensor memory,
+#          batch counts as much as length) or above the `flash_min_seq`
+#          of the checked-in table (ops/pallas/attn_dispatch_table.json).
+#          Measured on v5e at s=512: XLA 299 ms a step, the blocked kernel
+#          2,069: it pays only beyond the HBM knee.
+#   xla    _xla_attention everywhere else: the "bhsd" layout, the CPU, and
+#          any mesh of several devices (GSPMD cannot partition a custom
+#          call; past the knee sequence parallelism takes over there).
 #
 # Env surface:
-#   PADDLE_TPU_ATTN_DISPATCH = auto (default) | xla | flash — force a
-#       path; "flash" on a backend that cannot compile the kernel raises.
-#   PADDLE_TPU_FLASH_SCORE_BYTES — override the score-bytes knee.
-#   PADDLE_TPU_SP_MODE = ring | ulysses | off — sequence parallelism
+#   PADDLE_TPU_ATTN_DISPATCH = auto (default) | xla | flash: force a
+#       path; "xla" means no Pallas anywhere, "flash" on a backend that
+#       cannot compile the kernel raises.
+#   PADDLE_TPU_FLASH_SCORE_BYTES: override the score-bytes knee.
+#   PADDLE_TPU_SP_MODE = ring | ulysses | off: sequence parallelism
 #       over the mesh 'model' axis; unset means AUTO (ring above the
 #       table's ring_min_seq when the sequence divides the axis).
 _TABLE_PATH = os.path.join(
@@ -110,38 +111,49 @@ def _use_flash(q, k):
     return min(sq, sk) >= int(attn_dispatch_thresholds()["flash_min_seq"])
 
 
+def _use_pallas() -> bool:
+    # asked of the module at each call, as nn_ops asks it: the v5e compile
+    # test steers the answer there
+    from .pallas.flash_attention import _use_pallas as can_run
+
+    return can_run()
+
+
+def _dispatch_mode() -> str:
+    mode = os.environ.get("PADDLE_TPU_ATTN_DISPATCH", "auto").strip().lower()
+    if mode not in ("auto", "xla", "flash"):
+        raise ValueError(
+            f"PADDLE_TPU_ATTN_DISPATCH={mode!r}: expected auto|xla|flash")
+    return mode
+
+
 def _flash_dispatch(qb, kb) -> str:
     """Resolve the flash-vs-XLA decision for bhsd-shaped q/k. `auto`
     chooses from what it can observe — the shape against the table's
     thresholds, and whether a Pallas kernel can run on this backend at
     all. PADDLE_TPU_ATTN_DISPATCH=flash asks for the kernel by name:
     flash_attention then raises on a backend that cannot compile it."""
-    mode = os.environ.get("PADDLE_TPU_ATTN_DISPATCH", "auto").strip().lower()
-    if mode not in ("auto", "xla", "flash"):
-        raise ValueError(
-            f"PADDLE_TPU_ATTN_DISPATCH={mode!r}: expected auto|xla|flash")
+    mode = _dispatch_mode()
     if mode == "auto":
         return "flash" if _use_flash(qb, kb) and _use_pallas() else "xla"
     return mode
 
 
-def _use_short(q, k):
-    """Returns the short-kernel mode: "bshd" (the [b,s,h,d]-native
-    layout), "bhsd" (the head-major grid, round-2 layout), or None (XLA
-    attention — the DEFAULT; see the measured numbers below). Opt in via
-    PADDLE_TPU_SHORT_ATTN=bshd|bhsd."""
-    # default OFF: measured r3 on v5e, the bshd-native kernel LOSES
-    # end-to-end (128.6k vs 180k tok/s) — the [1, s, h, d] blocks tile
-    # badly (h=12 pads to 16 sublanes, d=64 half-fills lanes) and the
-    # in-kernel relayouts cost more than the HBM transposes they replace
-    mode = os.environ.get("PADDLE_TPU_SHORT_ATTN", "0")
-    if mode in ("0", ""):
-        return None
-    if not _use_pallas():
-        return None
-    if not short_attention_viable(q.shape[2], k.shape[2]):
-        return None
-    return "bhsd" if mode in ("1", "bhsd") else "bshd"
+def _attn_dispatch(q, k, bshd) -> str:
+    """"short", "flash" or "xla" for the op's q/k on one device: the
+    short-sequence kernel takes from `auto`'s XLA side the shapes it is
+    built for, in the layout whose operands it can read in place."""
+    def bhsd(t):
+        b, s, h, d = t.shape
+        return jax.ShapeDtypeStruct((b, h, s, d), t.dtype) if bshd else t
+
+    qb, kb = bhsd(q), bhsd(k)
+    path = _flash_dispatch(qb, kb)
+    _, nh, sq, dh = qb.shape
+    if (path == "xla" and bshd and _dispatch_mode() == "auto"
+            and _use_pallas() and mha_short_viable(sq, kb.shape[2], nh, dh)):
+        return "short"
+    return path
 
 
 @register_op("fused_multihead_attention", no_grad_inputs=("KeyBias",))
@@ -175,60 +187,36 @@ def _fused_mha(ctx, op):
     rng = ctx.rng_for(op.output("Out")[0]) if dropout > 0.0 else None
 
     def attend(q, k, v, bias, rng, allow_pallas=True):
-        # kernel/cutover decisions are phrased over bhsd shapes
-        qb = jnp.transpose(q, (0, 2, 1, 3)) if bshd else q
-        kb = jnp.transpose(k, (0, 2, 1, 3)) if bshd else k
-        if not allow_pallas:
-            # multi-device mesh without an explicit sequence-parallel
-            # mode: the Pallas kernels are custom calls GSPMD cannot
-            # partition (the reason the legacy code wrapped them in a
-            # manual per-device program) — use the XLA formulation,
-            # which shards by propagation like the rest of the graph.
-            # Past the HBM knee where flash wins, sequence parallelism
-            # (PADDLE_TPU_SP_MODE / the ring_min_seq auto-default)
-            # takes over instead.
-            import numpy as _np
-
-            profiler.bump_counter("attn_dispatch_xla")
-            scale = sm_scale or 1.0 / float(_np.sqrt(q.shape[-1]))
-            return _xla_attention(q, k, v, bias, causal, scale, dropout,
-                                  rng, layout=layout)
-        short_mode = _use_short(qb, kb)
-        if short_mode == "bshd":
-            # the kernel's native layout IS [b, s, h, d]: in bshd mode it
-            # takes the inputs directly; in bhsd the transposes cancel
-            # against the model's head-split/merge transposes
-            profiler.bump_counter("attn_dispatch_flash")
-            out = short_attention_bshd(
-                q if bshd else qb.transpose(0, 2, 1, 3),
-                k if bshd else kb.transpose(0, 2, 1, 3),
-                v if bshd else jnp.transpose(v, (0, 2, 1, 3)),
-                bias=bias, causal=causal, sm_scale=sm_scale,
-                dropout=dropout, rng_key=rng,
-            )
-            return out if bshd else jnp.transpose(out, (0, 2, 1, 3))
-        if short_mode == "bhsd":
-            profiler.bump_counter("attn_dispatch_flash")
-            vb = jnp.transpose(v, (0, 2, 1, 3)) if bshd else v
-            out = short_attention(
-                qb, kb, vb, bias=bias, causal=causal, sm_scale=sm_scale,
-                dropout=dropout, rng_key=rng,
-            )
-            return jnp.transpose(out, (0, 2, 1, 3)) if bshd else out
-        path = _flash_dispatch(qb, kb)
+        # a mesh of several devices without a sequence-parallel mode takes
+        # the XLA formulation, which shards by propagation like the rest
+        # of the graph: the Pallas kernels are custom calls GSPMD cannot
+        # partition. Past the HBM knee where flash wins, sequence
+        # parallelism (PADDLE_TPU_SP_MODE / the ring_min_seq auto-default)
+        # takes over instead.
+        path = _attn_dispatch(q, k, bshd) if allow_pallas else "xla"
         profiler.bump_counter(f"attn_dispatch_{path}")
+        if path == "short":
+            # [b, s, nh, dh] back to the [b, s, nh*dh] the projection
+            # wrote: XLA folds this with the Program's reshape2 into nothing
+            b, sq, nh, dh = q.shape
+            out = mha_short(
+                q.reshape(b, sq, nh * dh), k.reshape(b, -1, nh * dh),
+                v.reshape(b, -1, nh * dh), nh, bias=bias, causal=causal,
+                sm_scale=sm_scale, dropout=dropout, rng_key=rng,
+            )
+            return out.reshape(b, sq, nh, dh)
         if path == "xla":
-            import numpy as _np
-
-            scale = sm_scale or 1.0 / float(_np.sqrt(q.shape[-1]))
+            scale = sm_scale or 1.0 / float(np.sqrt(q.shape[-1]))
             return _xla_attention(q, k, v, bias, causal, scale, dropout,
                                   rng, layout=layout)
-        vb = jnp.transpose(v, (0, 2, 1, 3)) if bshd else v
-        out = flash_attention(
-            qb, kb, vb, bias=bias, causal=causal, sm_scale=sm_scale,
-            dropout=dropout, rng_key=rng,
-        )
-        return jnp.transpose(out, (0, 2, 1, 3)) if bshd else out
+
+        def swap(t):  # bshd <-> bhsd; the flash kernel is head-major
+            return jnp.transpose(t, (0, 2, 1, 3)) if bshd else t
+
+        return swap(flash_attention(
+            swap(q), swap(k), swap(v), bias=bias, causal=causal,
+            sm_scale=sm_scale, dropout=dropout, rng_key=rng,
+        ))
 
     mesh = ctx.mesh
     model_n = (

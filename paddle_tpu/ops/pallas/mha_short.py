@@ -1,22 +1,30 @@
-"""Short-sequence fused attention as a Pallas TPU kernel.
+"""Short-sequence attention on the layout the projections write.
 
-The blocked flash kernel (flash_attention.py) is built for long
-sequences: its grid iterates (batch*heads, q-blocks, k-blocks), which at
-BERT-scale shapes (b=256, h=12, s=128) degenerates to 3072 grid steps of
-one tiny [128, 128] tile each — per-step pipeline overhead dominates and
-the kernel loses to plain XLA. This kernel is the short-seq design
-point: the WHOLE [s, s] score row fits in VMEM, so softmax needs no
-online rescaling, the backward is ONE kernel (no cross-grid
-accumulators), and G heads are processed per grid step to amortize
-pipeline overhead (grid = b*h/G steps).
+Q, K, V and the output are `[b, s, heads*dh]`: the array the `fc` before
+attention writes and the `fc` after it reads. XLA's own lowering wants its
+batched products head-major and relays every operand out, and the context
+back, with standalone copies (a third of the transformer's device time);
+a kernel whose operands are head-major keeps those copies and pins them.
+Here the heads are taken inside the kernel, on 128-lane boundaries, so
+there is nothing to relay on either side of the call.
 
-Semantics match flash_attention: q [b, h, sq, d], k/v [b, h, sk, d],
-optional additive key bias [b, sk], bottom-right-aligned causal mask,
-in-kernel hash dropout regenerated (never stored) in the backward.
-The reference's unfused chain is matmul -> softmax -> dropout -> matmul
-(e.g. paddle/fluid/operators/softmax_op.cu + matmul_op); measured here
-vs that chain as XLA emits it: 8.3 ms -> ~2 ms per BERT-base layer
-fwd+bwd (b=256, s=128, dropout on, v5e).
+One grid step holds one 128-lane slice of a few batch rows, `[bb, s, 128]`
+of each operand (whole tiles of the array's HBM layout, so the DMA moves
+4 KB runs), and the whole score rows of its heads: they fit VMEM, so
+softmax needs no online rescaling and the backward is one kernel. The
+heads of a slice (two at dh=64, one at dh=128) share every product: their
+queries are stacked along the rows with the other head's lanes zeroed, so
+that a contraction over all 128 lanes adds exact zeros (a v5e MXU is 128
+deep either way), and each head's lanes of the stacked result are selected
+back.
+
+Mathematics as `_xla_attention`, at no lower precision: products
+accumulate in float32; scale, key bias `[b, sk]`, the bottom-right-aligned
+causal mask and softmax in float32; probabilities cast to the input dtype
+for `P @ V`. Dropout comes from a hash of (head, query, key, seed),
+regenerated and never stored. The backward recomputes the probabilities,
+row maxima and sums included, from Q and K: its residuals are the
+operands, and no `[b, h, sq, sk]` array reaches HBM.
 """
 
 from __future__ import annotations
@@ -29,634 +37,272 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import NEG_INF, _ceil_to, _interpret
+from .flash_attention import LANE, NEG_INF, _ceil_to, _interpret, require_pallas
+
+# The longest sequence that takes this kernel. Set from chip runs of the
+# one-chip cells, each with the kernel against XLA's lowering (PERF.md,
+# Findings, PR 25): a length is inside only if its cell gained.
+MAX_SHORT_SEQ = 512
+
+_VMEM_LIMIT = 64 << 20  # of v5e's 128 MiB; the default is 16 MiB
+_VMEM_BUDGET = 24 << 20  # what _pick_bb counts: blocks and score tiles
 
 
-def _mask_scores(s, skp, sk, causal, causal_offset):
-    """Key-padding and causal masks on [G, sqp, skp] scores."""
-    if sk != skp:
-        ki = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(ki < sk, s, NEG_INF)
-    if causal:
-        qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        ki = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(qi + causal_offset >= ki, s, NEG_INF)
-    return s
+def mha_short_viable(sq, sk, num_heads, head_dim):
+    """The shapes the kernel is built for: heads that tile 128 lanes, and
+    score rows short enough that a few batch rows of them fit VMEM."""
+    return (head_dim in (64, 128) and (num_heads * head_dim) % LANE == 0
+            and max(sq, sk) <= MAX_SHORT_SEQ)
 
 
-def _keep3(seed, bh0, shape, dropout):
-    """Hash keep-mask over [G, sq, sk]: same murmur generator as
-    flash_attention._dropout_keep with the head index folded in along
-    axis 0 (fwd and bwd regenerate identical masks)."""
-    u32 = lambda x: jax.lax.convert_element_type(x, jnp.uint32)
-    gi = u32(bh0) + jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
-    qi = jax.lax.broadcasted_iota(jnp.uint32, shape, 1)
-    ki = jax.lax.broadcasted_iota(jnp.uint32, shape, 2)
-    h = (
-        qi * jnp.uint32(0x9E3779B1)
-        ^ ki * jnp.uint32(0x85EBCA6B)
-        ^ (u32(seed) + gi * jnp.uint32(0xC2B2AE35))
-    )
+def _u32(x):
+    return jax.lax.convert_element_type(x, jnp.uint32)
+
+
+def _keep3(seed, head, hqk, dropout):
+    """Hash keep-mask over [bb, rows, sk]: the murmur generator of
+    flash_attention._dropout_keep, with `head` the global batch*heads
+    index of each row and `hqk` the query and key part of the hash. The
+    forward and the backward regenerate identical masks."""
+    h = hqk ^ (_u32(seed) + head * jnp.uint32(0xC2B2AE35))
     h = h ^ (h >> 16)
     h = h * jnp.uint32(0x85EBCA6B)
     h = h ^ (h >> 13)
     h = h * jnp.uint32(0xC2B2AE35)
     h = h ^ (h >> 16)
-    thresh = jnp.uint32(min(int(dropout * 2**32), 2**32 - 1))
-    return h >= thresh
+    return h >= jnp.uint32(min(int(dropout * 2**32), 2**32 - 1))
 
 
-# batched (G-head) dot shorthands; all accumulate fp32 on the MXU
-def _bdot_qkT(a, b):  # [G, m, d] x [G, n, d] -> [G, m, n]
+# batched dot shorthands over a block's batch rows; all accumulate float32
+def _bdot_qkT(a, b):  # [B, m, d] x [B, n, d] -> [B, m, n]
     return jax.lax.dot_general(
         a, b, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     )
 
 
-def _bdot_pv(p, v):  # [G, m, n] x [G, n, d] -> [G, m, d]
+def _bdot_pv(p, v):  # [B, m, n] x [B, n, d] -> [B, m, d]
     return jax.lax.dot_general(
         p, v, (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     )
 
 
-def _bdot_pTv(p, v):  # [G, n, m] x [G, n, d] -> [G, m, d]
+def _bdot_pTv(p, v):  # [B, n, m] x [B, n, d] -> [B, m, d]
     return jax.lax.dot_general(
         p, v, (((1,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     )
 
 
-def _fwd_math(q, k, v, bias_vec, seed, bh0, *, sm_scale, causal,
-              causal_offset, dropout, sk):
-    """Shared forward math on [G, sqp, d] / [G, skp, d] tiles; bias_vec is
-    a [skp] (or [G, skp]) additive key bias or None. Returns (o, lse3)."""
-    skp = k.shape[1]
-    s = _bdot_qkT(q, k) * sm_scale
-    if bias_vec is not None:
-        b3 = bias_vec.astype(jnp.float32)
-        s = s + (b3[:, None, :] if b3.ndim == 2 else b3[None, None, :])
-    s = _mask_scores(s, skp, sk, causal, causal_offset)
-    # clamp m so fully-masked rows underflow to p == 0 instead of the
-    # uniform-garbage exp(NEG_INF - NEG_INF); partially-masked entries
-    # underflow naturally (exp(-1e30 - finite) == 0), no select needed
+def _segment(shape, axis, size, n):
+    """Index // size along `axis`, for n segments, by comparisons (Mosaic
+    has no vector integer division)."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+    seg = jnp.zeros(shape, jnp.int32)
+    for h in range(1, n):
+        seg = seg + (idx >= h * size).astype(jnp.int32)
+    return idx, seg
+
+
+def _stack_heads(x, hp):
+    """[bb, s, 128] -> [bb, hp*s, 128]: one copy of the slice per head in
+    it, the other heads' lanes zeroed."""
+    if hp == 1:
+        return x
+    _, head = _segment((1, *x.shape[1:]), 2, LANE // hp, hp)  # over the batch
+    return jnp.concatenate(
+        [jnp.where(head == h, x, jnp.zeros((), x.dtype)) for h in range(hp)],
+        axis=1)
+
+
+def _unstack_heads(y, hp):
+    """[bb, hp*s, 128] -> [bb, s, 128]: head h's lanes from its rows."""
+    if hp == 1:
+        return y
+    s = y.shape[1] // hp
+    _, head = _segment((1, s, LANE), 2, LANE // hp, hp)
+    out = y[:, :s]
+    for h in range(1, hp):
+        out = jnp.where(head == h, y[:, h * s:(h + 1) * s], out)
+    return out
+
+
+def _probs(seed_ref, q_ref, k_ref, bias_ref, *, num_heads, hp, sm_scale,
+           causal, causal_offset, dropout):
+    """One 128-lane slice of a block: the stacked heads' queries
+    [bb, hp*sq, 128], the softmax of their scores [bb, hp*sq, sk] in
+    float32, and the dropout keep-mask (None without dropout)."""
+    bb, sq, _ = q_ref.shape
+    sk = k_ref.shape[1]
+    qs = _stack_heads(q_ref[...], hp)
+    s = _bdot_qkT(qs, k_ref[...]) * sm_scale
+    if bias_ref is not None:
+        s = s + bias_ref[...]  # [bb, 1, sk] over the rows
+    # what depends on the row and the key alone is computed once, [hp*sq,
+    # sk], and broadcast over the block's batch rows
+    row, sub = _segment((hp * sq, sk), 0, sq, hp)  # sub: the head in the slice
+    ki = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    qi = row - sub * sq
+    if causal:
+        s = s + jnp.where(qi + causal_offset >= ki, 0.0, NEG_INF)
+    # m is clamped so that a fully masked row underflows to p == 0 and not
+    # to the uniform exp(NEG_INF - NEG_INF); partly masked entries
+    # underflow by themselves
     m = jnp.maximum(jnp.max(s, axis=2, keepdims=True), NEG_INF / 8)
-    p = jnp.exp(s - m)
-    l = jnp.sum(p, axis=2, keepdims=True)
+    e = jnp.exp(s - m)
+    l = jnp.sum(e, axis=2, keepdims=True)
+    p = e * (1.0 / jnp.where(l == 0.0, 1.0, l))
+    keep = None
     if dropout > 0.0:
-        keep = _keep3(seed, bh0, s.shape, dropout)
-        p_use = jnp.where(keep, p * (1.0 / (1.0 - dropout)), 0.0)
-    else:
-        p_use = p
-    acc = _bdot_pv(p_use.astype(v.dtype), v)
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    return acc / l_safe, m + jnp.log(l_safe)
+        bi = pl.program_id(0) * bb + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0)
+        head = _u32(bi * num_heads + pl.program_id(1) * hp) + _u32(sub)
+        hqk = (_u32(qi) * jnp.uint32(0x9E3779B1)
+               ^ _u32(ki) * jnp.uint32(0x85EBCA6B))
+        keep = _keep3(seed_ref[0], head, hqk, dropout)
+    return qs, p, keep
 
 
-def _fwd_kernel(
-    seed_ref,
-    q_ref,
-    k_ref,
-    v_ref,
-    bias_ref,
-    o_ref,
-    lse_ref,
-    *,
-    G,
-    sm_scale,
-    causal,
-    causal_offset,
-    dropout,
-    sk,
-):
-    blk = pl.program_id(0)
-    o, lse = _fwd_math(
-        q_ref[...], k_ref[...], v_ref[...],
-        bias_ref[...] if bias_ref is not None else None,
-        seed_ref[0], blk * G,
-        sm_scale=sm_scale, causal=causal, causal_offset=causal_offset,
-        dropout=dropout, sk=sk,
-    )
-    o_ref[...] = o.astype(o_ref.dtype)
-    lse_ref[...] = lse.astype(jnp.float32)
+def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, has_bias, hp, dropout,
+                **static):
+    bias_ref, o_ref = rest if has_bias else (None, *rest)
+    _, p, keep = _probs(seed_ref, q_ref, k_ref, bias_ref, hp=hp,
+                        dropout=dropout, **static)
+    if keep is not None:
+        p = jnp.where(keep, p * (1.0 / (1.0 - dropout)), 0.0)
+    o = _bdot_pv(p.astype(v_ref.dtype), v_ref[...])
+    o_ref[...] = _unstack_heads(o, hp).astype(o_ref.dtype)
 
 
-def _fwd_nobias(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, **kw):
-    _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, None, o_ref, lse_ref, **kw)
-
-
-def _bwd_math(q, k, v, bias_vec, do, lse, delta, seed, bh0, *, sm_scale,
-              causal, causal_offset, dropout, sk):
-    """Shared backward math on [G, ...] tiles; lse/delta are [G, sqp, 1].
-    Returns (dq, dk, dv)."""
-    skp = k.shape[1]
-    s = _bdot_qkT(q, k) * sm_scale
-    if bias_vec is not None:
-        b3 = bias_vec.astype(jnp.float32)
-        s = s + (b3[:, None, :] if b3.ndim == 2 else b3[None, None, :])
-    s = _mask_scores(s, skp, sk, causal, causal_offset)
-    # normalized probs, fp32; lse was clamped in the forward so masked
-    # entries (and fully-masked rows) underflow to exactly 0
-    p = jnp.exp(s - lse)
-
-    dp = _bdot_qkT(do, v)
-    if dropout > 0.0:
+def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, has_bias, hp, dropout,
+                **static):
+    bias_ref, do_ref, dq_ref, dk_ref, dv_ref = (
+        rest if has_bias else (None, *rest))
+    qs, p, keep = _probs(seed_ref, q_ref, k_ref, bias_ref, hp=hp,
+                         dropout=dropout, **static)
+    dos = _stack_heads(do_ref[...], hp)
+    dp = _bdot_qkT(dos, v_ref[...])
+    p_drop = p
+    if keep is not None:
         inv = 1.0 / (1.0 - dropout)
-        keep = _keep3(seed, bh0, p.shape, dropout)
         p_drop = jnp.where(keep, p * inv, 0.0)
         dp = jnp.where(keep, dp * inv, 0.0)
-    else:
-        p_drop = p
-    dv = _bdot_pTv(p_drop.astype(do.dtype), do)
-    # delta = rowsum(dp * p) == rowsum(do * out), precomputed outside the
-    # kernel on the d-wide tensors (s-wide mul+reduce saved)
-    ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
-    dq = _bdot_pv(ds, k)
-    dk = _bdot_pTv(ds, q)
-    return dq, dk, dv
+    # the stacked rows of each head carry zeros in the other head's lanes,
+    # so the products over the rows give dV and dK whole
+    dv_ref[...] = _bdot_pTv(p_drop.astype(dos.dtype), dos).astype(dv_ref.dtype)
+    delta = jnp.sum(p * dp, axis=2, keepdims=True)  # rowsum(dO * O)
+    ds = (p * (dp - delta) * static["sm_scale"]).astype(qs.dtype)
+    dq_ref[...] = _unstack_heads(_bdot_pv(ds, k_ref[...]), hp).astype(
+        dq_ref.dtype)
+    dk_ref[...] = _bdot_pTv(ds, qs).astype(dk_ref.dtype)
 
 
-def _bwd_kernel(
-    seed_ref,
-    q_ref,
-    k_ref,
-    v_ref,
-    bias_ref,
-    do_ref,
-    lse_ref,
-    delta_ref,
-    dq_ref,
-    dk_ref,
-    dv_ref,
-    *,
-    G,
-    sm_scale,
-    causal,
-    causal_offset,
-    dropout,
-    sk,
-):
-    blk = pl.program_id(0)
-    dq, dk, dv = _bwd_math(
-        q_ref[...], k_ref[...], v_ref[...],
-        bias_ref[...] if bias_ref is not None else None,
-        do_ref[...], lse_ref[...].astype(jnp.float32),
-        delta_ref[...].astype(jnp.float32),
-        seed_ref[0], blk * G,
-        sm_scale=sm_scale, causal=causal, causal_offset=causal_offset,
-        dropout=dropout, sk=sk,
-    )
-    dq_ref[...] = dq.astype(dq_ref.dtype)
-    dk_ref[...] = dk.astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
+def _pick_bb(b, sq, sk, hp, itemsize):
+    """Largest divisor of b whose grid step fits the VMEM budget: the
+    backward's seven double-buffered [bb, s, 128] blocks and about eight
+    live float32 score tiles of the hp stacked heads."""
+    per_row = 2 * 7 * max(sq, sk) * LANE * itemsize + 8 * hp * sq * sk * 4
+    cap = max(1, _VMEM_BUDGET // per_row)
+    return max(d for d in range(1, min(b, cap) + 1) if b % d == 0)
 
 
-def _bwd_nobias(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, **kw):
-    _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, None, do_ref, lse_ref,
-                delta_ref, dq_ref, dk_ref, dv_ref, **kw)
+@functools.partial(jax.jit, static_argnames=("statics",))
+def _call(seed, q, k, v, bias, do, *, statics):
+    """One pallas_call, the forward without `do` and the backward with it,
+    over a grid of (batch blocks, 128-lane slices): [bb, s, 128] blocks of
+    every operand, which the array's (sublanes, 128) tiling keeps as whole
+    tiles in HBM; the seed in SMEM, the bias as [b, 1, sk]. Jitted so that
+    the calls of one shape in a step (twelve in BERT) are traced and
+    lowered once."""
+    num_heads, sm_scale, causal, causal_offset, dropout, bb, interpret = statics
+    backward = do is not None
+
+    def spec(x):
+        if x is bias:
+            return pl.BlockSpec((bb, *x.shape[1:]), lambda i, j: (i, 0, 0),
+                                memory_space=pltpu.VMEM)
+        return pl.BlockSpec((bb, x.shape[1], LANE), lambda i, j: (i, 0, j),
+                            memory_space=pltpu.VMEM)
+
+    args = [x for x in (q, k, v, bias, do) if x is not None]
+    outs = [q, k, v] if backward else [q]
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_kernel if backward else _fwd_kernel,
+            has_bias=bias is not None, num_heads=num_heads,
+            hp=LANE * num_heads // q.shape[2], sm_scale=sm_scale,
+            causal=causal, causal_offset=causal_offset, dropout=dropout),
+        grid=(q.shape[0] // bb, q.shape[2] // LANE),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+        + [spec(x) for x in args],
+        out_specs=[spec(x) for x in outs],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in outs],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="mha_short_bwd" if backward else "mha_short_fwd",
+    )(seed, *args)
 
 
-def _pick_g(bh, sqp, skp, d):
-    """Largest divisor of b*h whose per-step VMEM footprint — the
-    [G, sqp, skp] fp32 score tile plus up to 8 double-buffered
-    [G, s, d] in/out blocks — fits a 16 MB budget. The backward holds
-    ~6 score-sized temporaries live, so _COMPILER_PARAMS raises the
-    scoped-VMEM limit to 64 MB (the default 16 MB OOMs at G >= 8 inside
-    the full BERT program; v5e has 128 MB of VMEM). At BERT-base shapes
-    (bh=3072, s=128, d=64) this picks G=64: ~48 grid steps, measured on
-    par with G=8..32 and well clear of the per-head grid (G=1) whose
-    step overhead dominates."""
-    budget = 16 << 20
-    per_g = sqp * skp * 4 + 8 * max(sqp, skp) * d * 2
-    cap = max(1, budget // per_g)
-    g = 1
-    for cand in range(1, min(bh, cap) + 1):
-        if bh % cand == 0:
-            g = cand
-    return g
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _core(q, k, v, bias, seed, statics):
+    return _call(seed, q, k, v, bias, None, statics=statics)[0]
 
 
-# the default 16 MB scoped-VMEM budget is too tight for the G-batched
-# score temporaries; v5e has 128 MB of VMEM (older jax spells the class
-# TPUCompilerParams)
-_params_cls = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-if _params_cls is None:
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; this jax version is not supported by "
-        "mha_short"
-    )
-_COMPILER_PARAMS = _params_cls(vmem_limit_bytes=64 << 20)
+def _core_fwd(q, k, v, bias, seed, statics):
+    # the output is no residual: the backward recomputes what it needs
+    out, = _call(seed, q, k, v, bias, None, statics=statics)
+    return out, (q, k, v, bias, seed)
 
 
-def _qkv_spec(G, s, d):
-    return pl.BlockSpec((G, s, d), lambda i: (i, 0, 0),
-                        memory_space=pltpu.VMEM)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _short_core(q, k, v, bias, seed, G, sm_scale, causal, causal_offset,
-                dropout, sk):
-    out, _ = _short_fwd_pallas(q, k, v, bias, seed, G, sm_scale, causal,
-                               causal_offset, dropout, sk)
-    return out
-
-
-def _short_fwd_pallas(q, k, v, bias, seed, G, sm_scale, causal,
-                      causal_offset, dropout, sk):
-    bh, sqp, d = q.shape
-    skp = k.shape[1]
-    kernel = functools.partial(
-        _fwd_kernel if bias is not None else _fwd_nobias,
-        G=G, sm_scale=sm_scale, causal=causal,
-        causal_offset=causal_offset, dropout=dropout,
-        sk=skp if bias is not None else sk,
-    )
-    bias_spec = []
-    bias_args = []
-    if bias is not None:
-        bias_spec = [pl.BlockSpec((G, skp), lambda i: (i, 0),
-                                  memory_space=pltpu.VMEM)]
-        bias_args = [bias]
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(bh // G,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            _qkv_spec(G, sqp, d),
-            _qkv_spec(G, skp, d),
-            _qkv_spec(G, skp, d),
-            *bias_spec,
-        ],
-        out_specs=[
-            _qkv_spec(G, sqp, d),
-            pl.BlockSpec((G, sqp, 1), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sqp, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sqp, 1), jnp.float32),
-        ],
-        compiler_params=_COMPILER_PARAMS,
-        interpret=_interpret(),
-    )(seed, q, k, v, *bias_args)
-    return out, lse
-
-
-def _short_core_fwd(q, k, v, bias, seed, G, sm_scale, causal, causal_offset,
-                    dropout, sk):
-    out, lse = _short_fwd_pallas(q, k, v, bias, seed, G, sm_scale, causal,
-                                 causal_offset, dropout, sk)
-    return out, (q, k, v, bias, seed, out, lse)
-
-
-def _short_core_bwd(G, sm_scale, causal, causal_offset, dropout, sk, res,
-                    do):
-    q, k, v, bias, seed, out, lse = res
-    bh, sqp, d = q.shape
-    skp = k.shape[1]
-    delta = jnp.sum(
-        out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1,
-        keepdims=True,
-    )
-    kernel = functools.partial(
-        _bwd_kernel if bias is not None else _bwd_nobias,
-        G=G, sm_scale=sm_scale, causal=causal,
-        causal_offset=causal_offset, dropout=dropout,
-        sk=skp if bias is not None else sk,
-    )
-    bias_spec = []
-    bias_args = []
-    if bias is not None:
-        bias_spec = [pl.BlockSpec((G, skp), lambda i: (i, 0),
-                                  memory_space=pltpu.VMEM)]
-        bias_args = [bias]
-    dq, dk, dv = pl.pallas_call(
-        kernel,
-        grid=(bh // G,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            _qkv_spec(G, sqp, d),
-            _qkv_spec(G, skp, d),
-            _qkv_spec(G, skp, d),
-            *bias_spec,
-            _qkv_spec(G, sqp, d),
-            pl.BlockSpec((G, sqp, 1), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((G, sqp, 1), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            _qkv_spec(G, sqp, d),
-            _qkv_spec(G, skp, d),
-            _qkv_spec(G, skp, d),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sqp, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, skp, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, skp, d), v.dtype),
-        ],
-        compiler_params=_COMPILER_PARAMS,
-        interpret=_interpret(),
-    )(seed, q, k, v, *bias_args, do, lse, delta)
+def _core_bwd(statics, res, do):
+    q, k, v, bias, seed = res
+    dq, dk, dv = _call(seed, q, k, v, bias, do, statics=statics)
     dbias = None if bias is None else jnp.zeros_like(bias)
-    dseed = np.zeros((1,), dtype=jax.dtypes.float0)
-    return dq, dk, dv, dbias, dseed
+    return dq, dk, dv, dbias, np.zeros((1,), dtype=jax.dtypes.float0)
 
 
-_short_core.defvjp(_short_core_fwd, _short_core_bwd)
+_core.defvjp(_core_fwd, _core_bwd)
 
 
-# ---------------------------------------------------------------------------
-# [b, s, h, d]-native variant: q/k/v arrive in the layout the QKV matmuls
-# produce (reshape of [b, s, h*d]), so XLA cancels the model's transpose
-# pairs instead of materializing [b, h, s, d] copies at the custom-call
-# boundary (measured round 2: those copies ate the kernel's fusion win).
-# The head-major relayout happens INSIDE the kernel on VMEM tiles.
-# ---------------------------------------------------------------------------
-
-
-def _fwd_kernel_bshd(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
-                     lse_ref, *, G, H, sm_scale, causal, causal_offset,
-                     dropout, sk):
-    bi = pl.program_id(0)
-    hj = pl.program_id(1)
-    q = jnp.transpose(q_ref[0], (1, 0, 2))  # [sqp, G, d] -> [G, sqp, d]
-    k = jnp.transpose(k_ref[0], (1, 0, 2))
-    v = jnp.transpose(v_ref[0], (1, 0, 2))
-    o, lse = _fwd_math(
-        q, k, v, bias_ref[bi] if bias_ref is not None else None,
-        seed_ref[0], bi * H + hj * G,
-        sm_scale=sm_scale, causal=causal, causal_offset=causal_offset,
-        dropout=dropout, sk=sk,
-    )
-    o_ref[0] = jnp.transpose(o, (1, 0, 2)).astype(o_ref.dtype)
-    lse_ref[0] = jnp.transpose(lse[..., 0], (1, 0)).astype(jnp.float32)
-
-
-def _fwd_bshd_nobias(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, **kw):
-    _fwd_kernel_bshd(seed_ref, q_ref, k_ref, v_ref, None, o_ref, lse_ref,
-                     **kw)
-
-
-def _bwd_kernel_bshd(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
-                     lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, *, G, H,
-                     sm_scale, causal, causal_offset, dropout, sk):
-    bi = pl.program_id(0)
-    hj = pl.program_id(1)
-    q = jnp.transpose(q_ref[0], (1, 0, 2))
-    k = jnp.transpose(k_ref[0], (1, 0, 2))
-    v = jnp.transpose(v_ref[0], (1, 0, 2))
-    do = jnp.transpose(do_ref[0], (1, 0, 2))
-    lse = jnp.transpose(lse_ref[0], (1, 0))[..., None].astype(jnp.float32)
-    delta = jnp.transpose(delta_ref[0], (1, 0))[..., None].astype(
-        jnp.float32)
-    dq, dk, dv = _bwd_math(
-        q, k, v, bias_ref[bi] if bias_ref is not None else None,
-        do, lse, delta, seed_ref[0], bi * H + hj * G,
-        sm_scale=sm_scale, causal=causal, causal_offset=causal_offset,
-        dropout=dropout, sk=sk,
-    )
-    dq_ref[0] = jnp.transpose(dq, (1, 0, 2)).astype(dq_ref.dtype)
-    dk_ref[0] = jnp.transpose(dk, (1, 0, 2)).astype(dk_ref.dtype)
-    dv_ref[0] = jnp.transpose(dv, (1, 0, 2)).astype(dv_ref.dtype)
-
-
-def _bwd_bshd_nobias(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                     delta_ref, dq_ref, dk_ref, dv_ref, **kw):
-    _bwd_kernel_bshd(seed_ref, q_ref, k_ref, v_ref, None, do_ref, lse_ref,
-                     delta_ref, dq_ref, dk_ref, dv_ref, **kw)
-
-
-def _bshd_spec(s, G, d):
-    return pl.BlockSpec((1, s, G, d), lambda i, j: (i, 0, j, 0),
-                        memory_space=pltpu.VMEM)
-
-
-def _bshd_vec_spec(s, G):
-    return pl.BlockSpec((1, s, G), lambda i, j: (i, 0, j),
-                        memory_space=pltpu.VMEM)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
-def _short_core_bshd(q, k, v, bias, seed, G, H, sm_scale, causal,
-                     causal_offset, dropout, sk):
-    out, _ = _short_fwd_bshd(q, k, v, bias, seed, G, H, sm_scale, causal,
-                             causal_offset, dropout, sk)
-    return out
-
-
-def _short_fwd_bshd(q, k, v, bias, seed, G, H, sm_scale, causal,
-                    causal_offset, dropout, sk):
-    b, sqp, h, d = q.shape
-    skp = k.shape[1]
-    kernel = functools.partial(
-        _fwd_kernel_bshd if bias is not None else _fwd_bshd_nobias,
-        G=G, H=H, sm_scale=sm_scale, causal=causal,
-        causal_offset=causal_offset, dropout=dropout,
-        sk=skp if bias is not None else sk,
-    )
-    bias_spec = []
-    bias_args = []
-    if bias is not None:
-        bias_spec = [pl.BlockSpec((b, skp), lambda i, j: (0, 0),
-                                  memory_space=pltpu.VMEM)]
-        bias_args = [bias]
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(b, h // G),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            _bshd_spec(sqp, G, d),
-            _bshd_spec(skp, G, d),
-            _bshd_spec(skp, G, d),
-            *bias_spec,
-        ],
-        out_specs=[
-            _bshd_spec(sqp, G, d),
-            _bshd_vec_spec(sqp, G),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, sqp, h, d), q.dtype),
-            jax.ShapeDtypeStruct((b, sqp, h), jnp.float32),
-        ],
-        compiler_params=_COMPILER_PARAMS,
-        interpret=_interpret(),
-    )(seed, q, k, v, *bias_args)
-    return out, lse
-
-
-def _short_core_bshd_fwd(q, k, v, bias, seed, G, H, sm_scale, causal,
-                         causal_offset, dropout, sk):
-    out, lse = _short_fwd_bshd(q, k, v, bias, seed, G, H, sm_scale, causal,
-                               causal_offset, dropout, sk)
-    return out, (q, k, v, bias, seed, out, lse)
-
-
-def _short_core_bshd_bwd(G, H, sm_scale, causal, causal_offset, dropout,
-                         sk, res, do):
-    q, k, v, bias, seed, out, lse = res
-    b, sqp, h, d = q.shape
-    skp = k.shape[1]
-    delta = jnp.sum(
-        out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
-    )  # [b, sqp, h]
-    kernel = functools.partial(
-        _bwd_kernel_bshd if bias is not None else _bwd_bshd_nobias,
-        G=G, H=H, sm_scale=sm_scale, causal=causal,
-        causal_offset=causal_offset, dropout=dropout,
-        sk=skp if bias is not None else sk,
-    )
-    bias_spec = []
-    bias_args = []
-    if bias is not None:
-        bias_spec = [pl.BlockSpec((b, skp), lambda i, j: (0, 0),
-                                  memory_space=pltpu.VMEM)]
-        bias_args = [bias]
-    dq, dk, dv = pl.pallas_call(
-        kernel,
-        grid=(b, h // G),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            _bshd_spec(sqp, G, d),
-            _bshd_spec(skp, G, d),
-            _bshd_spec(skp, G, d),
-            *bias_spec,
-            _bshd_spec(sqp, G, d),
-            _bshd_vec_spec(sqp, G),
-            _bshd_vec_spec(sqp, G),
-        ],
-        out_specs=[
-            _bshd_spec(sqp, G, d),
-            _bshd_spec(skp, G, d),
-            _bshd_spec(skp, G, d),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, sqp, h, d), q.dtype),
-            jax.ShapeDtypeStruct((b, skp, h, d), k.dtype),
-            jax.ShapeDtypeStruct((b, skp, h, d), v.dtype),
-        ],
-        compiler_params=_COMPILER_PARAMS,
-        interpret=_interpret(),
-    )(seed, q, k, v, *bias_args, do, lse, delta)
-    dbias = None if bias is None else jnp.zeros_like(bias)
-    dseed = np.zeros((1,), dtype=jax.dtypes.float0)
-    return dq, dk, dv, dbias, dseed
-
-
-_short_core_bshd.defvjp(_short_core_bshd_fwd, _short_core_bshd_bwd)
-
-
-def short_attention_bshd(q, k, v, bias=None, causal=False, sm_scale=None,
-                         dropout=0.0, rng_key=None, heads_per_block=None):
-    """Fused short-seq attention, [b, s, h, d]-native. q: [b, sq, h, d];
-    k, v: [b, sk, h, d]; bias: [b, sk] additive key bias or None. Returns
-    [b, sq, h, d] in q's dtype. Identical math to short_attention — the
-    dropout hash streams differ only in head indexing, which both derive
-    from the same (batch*h + head) base."""
-    b, sq, h, d = q.shape
+def mha_short(q, k, v, num_heads, bias=None, causal=False, sm_scale=None,
+              dropout=0.0, rng_key=None):
+    """Fused multi-head attention for short sequences. q: [b, sq, h*dh];
+    k, v: [b, sk, h*dh]; bias: [b, sk] additive key bias or None. Returns
+    [b, sq, h*dh] in q's dtype."""
+    require_pallas("mha_short")
+    b, sq, width = q.shape
     sk = k.shape[1]
+    dh = width // num_heads
+    if not mha_short_viable(sq, sk, num_heads, dh) or dh * num_heads != width:
+        raise ValueError(
+            f"mha_short: q {q.shape}, k {k.shape}, {num_heads} heads: needs "
+            f"head_dim 64 or 128, heads*head_dim a multiple of {LANE} and "
+            f"sequences up to {MAX_SHORT_SEQ}")
     if sm_scale is None:
-        sm_scale = 1.0 / float(np.sqrt(d))
+        sm_scale = 1.0 / float(np.sqrt(dh))
     if dropout > 0.0 and rng_key is None:
         raise ValueError("dropout requires rng_key")
     if dropout > 0.0:
         seed = jax.random.randint(
-            rng_key, (1,), 0, np.iinfo(np.int32).max, jnp.int32
-        )
+            rng_key, (1,), 0, np.iinfo(np.int32).max, jnp.int32)
     else:
         seed = jnp.zeros((1,), jnp.int32)
 
-    causal_offset = sk - sq
-    sqp = _ceil_to(max(sq, 8), 8)
-    skp = _ceil_to(max(sk, 128), 128)
+    # rows are stacked and sliced by whole sublane tiles (16 in bfloat16):
+    # other lengths are padded, queries with rows that are cut off again,
+    # keys with positions the bias masks
+    sqp, skp = _ceil_to(sq, 16), _ceil_to(sk, 16)
     if sqp != sq:
-        q = jnp.pad(q, [(0, 0), (0, sqp - sq), (0, 0), (0, 0)])
+        q = jnp.pad(q, [(0, 0), (0, sqp - sq), (0, 0)])
     if skp != sk:
-        k = jnp.pad(k, [(0, 0), (0, skp - sk), (0, 0), (0, 0)])
-        v = jnp.pad(v, [(0, 0), (0, skp - sk), (0, 0), (0, 0)])
-    biasf = None
+        k = jnp.pad(k, [(0, 0), (0, skp - sk), (0, 0)])
+        v = jnp.pad(v, [(0, 0), (0, skp - sk), (0, 0)])
+        bias = jnp.zeros((b, sk), jnp.float32) if bias is None else bias
     if bias is not None:
-        biasf = jnp.pad(
-            bias.astype(jnp.float32), [(0, 0), (0, skp - sk)],
-            constant_values=NEG_INF,
-        )
-    if heads_per_block:
-        G = heads_per_block
-    else:
-        # largest divisor of h whose [G, sqp, skp] fp32 score tile (x ~6
-        # live temporaries in the backward) fits the scoped-VMEM budget —
-        # same bound _pick_g enforces for the bhsd grid
-        budget = (64 << 20) // 8
-        G = 1
-        for cand in range(1, h + 1):
-            if h % cand == 0 and cand * sqp * skp * 4 <= budget:
-                G = cand
-    if h % G:
-        raise ValueError(f"heads_per_block {G} must divide h {h}")
-    out = _short_core_bshd(q, k, v, biasf, seed, G, h, sm_scale, causal,
-                           causal_offset, dropout, sk)
-    return out[:, :sq]
-
-
-# score-row bytes per head must fit VMEM comfortably: [sqp, skp] fp32 plus
-# a handful of same-size temporaries in the backward (16 MB scoped limit)
-MAX_SHORT_SEQ = 512
-
-
-def short_attention_viable(sq, sk):
-    return sq <= MAX_SHORT_SEQ and sk <= MAX_SHORT_SEQ
-
-
-def short_attention(q, k, v, bias=None, causal=False, sm_scale=None,
-                    dropout=0.0, rng_key=None, heads_per_block=None):
-    """Fused short-seq multi-head attention. q: [b, h, sq, d]; k, v:
-    [b, h, sk, d]; bias: [b, sk] additive key bias or None. Returns
-    [b, h, sq, d] in q's dtype."""
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    if sm_scale is None:
-        sm_scale = 1.0 / float(np.sqrt(d))
-    if dropout > 0.0 and rng_key is None:
-        raise ValueError("dropout requires rng_key")
-    if dropout > 0.0:
-        seed = jax.random.randint(
-            rng_key, (1,), 0, np.iinfo(np.int32).max, jnp.int32
-        )
-    else:
-        seed = jnp.zeros((1,), jnp.int32)
-
-    causal_offset = sk - sq  # bottom-right aligned, as flash_attention
-    bh = b * h
-    sqp = _ceil_to(max(sq, 8), 8)
-    skp = _ceil_to(max(sk, 128), 128)
-    qf = q.reshape(bh, sq, d)
-    kf = k.reshape(bh, sk, d)
-    vf = v.reshape(bh, sk, d)
-    if sqp != sq:
-        qf = jnp.pad(qf, [(0, 0), (0, sqp - sq), (0, 0)])
-    if skp != sk:
-        kf = jnp.pad(kf, [(0, 0), (0, skp - sk), (0, 0)])
-        vf = jnp.pad(vf, [(0, 0), (0, skp - sk), (0, 0)])
-    biasf = None
-    if bias is not None:
-        biasf = jnp.pad(
-            bias.astype(jnp.float32), [(0, 0), (0, skp - sk)],
-            constant_values=NEG_INF,
-        )
-        # broadcast over heads so G needn't divide h; [bh, skp] fp32 is
-        # tiny next to the score traffic this kernel removes
-        biasf = jnp.broadcast_to(biasf[:, None, :], (b, h, skp)).reshape(
-            bh, skp
-        )
-
-    G = heads_per_block or _pick_g(bh, sqp, skp, d)
-    if bh % G:
-        raise ValueError(f"heads_per_block {G} must divide b*h {bh}")
-    out = _short_core(qf, kf, vf, biasf, seed, G, sm_scale, causal,
-                      causal_offset, dropout, sk)
-    return out[:, :sq].reshape(b, h, sq, d)
+        bias = jnp.pad(bias.astype(jnp.float32), [(0, 0), (0, skp - sk)],
+                       constant_values=NEG_INF)[:, None, :]
+    bb = _pick_bb(b, sqp, skp, LANE // dh, q.dtype.itemsize)
+    statics = (num_heads, float(sm_scale), bool(causal), sk - sq,
+               float(dropout), bb, _interpret())
+    return _core(q, k, v, bias, seed, statics)[:, :sq]

@@ -300,6 +300,121 @@ def test_fused_mha_bshd_layout_matches_bhsd(rng):
         np.testing.assert_allclose(x, y, rtol=1e-5, atol=1e-5)
 
 
+def _attn_program(b, sq, sk, nh, dh, layout, causal=False, dropout=0.0):
+    """q [b, sq, nh*dh] and k, v [b, sk, nh*dh] as the projections write
+    them, head-split by reshape (and transposed for "bhsd") as the models
+    do, through the op, with gradients. Returns (main, startup, fetches)."""
+    import paddle_tpu as fluid
+    from paddle_tpu.framework import Program
+
+    main, startup = Program(), Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        feeds = [fluid.layers.data(n, [b, s, nh * dh], append_batch_size=False)
+                 for n, s in (("q", sq), ("k", sk), ("v", sk))]
+        for t in feeds:
+            t.stop_gradient = False
+        heads = [fluid.layers.reshape(t, [b, -1, nh, dh]) for t in feeds]
+        if layout == "bhsd":
+            heads = [fluid.layers.transpose(t, [0, 2, 1, 3]) for t in heads]
+        bias = fluid.layers.data("bias", [b, sk], append_batch_size=False)
+        out = fluid.layers.fused_multihead_attention(
+            *heads, key_bias=bias, causal=causal, attn_dropout=dropout,
+            layout=layout)
+        if layout == "bhsd":
+            out = fluid.layers.transpose(out, [0, 2, 1, 3])
+        out = fluid.layers.reshape(out, [b, -1, nh * dh])
+        loss = fluid.layers.reduce_sum(fluid.layers.elementwise_mul(out, out))
+        grads = fluid.backward.calc_gradient(loss, feeds)
+    return main, startup, [out, *grads]
+
+
+def _run_attn_program(rng, shape, layout="bshd", compiled=False, **kw):
+    import paddle_tpu as fluid
+    from paddle_tpu import profiler
+
+    b, sq, sk, nh, dh = shape
+    main, startup, fetches = _attn_program(*shape, layout, **kw)
+    feed = {"q": rng.randn(b, sq, nh * dh).astype("float32"),
+            "k": rng.randn(b, sk, nh * dh).astype("float32"),
+            "v": rng.randn(b, sk, nh * dh).astype("float32"),
+            "bias": np.where(rng.rand(b, sk) > 0.2, 0.0, -1e9).astype(
+                "float32")}
+    feed["bias"][:, 0] = 0.0
+    exe = fluid.Executor(fluid.CPUPlace())
+    profiler.reset_profiler()
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        program = main
+        if compiled:  # two of the virtual devices: a mesh
+            program = fluid.CompiledProgram(main).with_data_parallel(places=2)
+        vals = exe.run(program, feed=feed, fetch_list=fetches)
+    c = profiler.counters()
+    paths = {p for p in ("short", "flash", "xla")
+             if c.get(f"attn_dispatch_{p}", 0)}
+    return [np.asarray(x) for x in vals], paths
+
+
+@pytest.mark.parametrize("shape,causal", [
+    ((2, 64, 64, 8, 64), False),    # the transformer's encoder self-attention
+    ((2, 64, 64, 8, 64), True),     # its decoder's
+    ((2, 32, 64, 8, 64), False),    # cross attention, sq != sk
+    ((2, 128, 128, 12, 64), False),  # BERT phase 1
+    ((2, 16, 16, 2, 128), True),    # dh = 128
+])
+def test_dispatch_takes_the_short_kernel_for_the_models_shapes(
+        shape, causal, monkeypatch):
+    """One device, "bshd", dh 64 or 128, short rows: the Program's op
+    lowers to the kernel, bumps `attn_dispatch_short`, and gives what
+    XLA's lowering gives, gradients included."""
+    monkeypatch.delenv("PADDLE_TPU_ATTN_DISPATCH", raising=False)
+    got, paths = _run_attn_program(np.random.RandomState(3), shape,
+                                   causal=causal)
+    assert paths == {"short"}
+    monkeypatch.setenv("PADDLE_TPU_ATTN_DISPATCH", "xla")
+    want, paths = _run_attn_program(np.random.RandomState(3), shape,
+                                    causal=causal)
+    assert paths == {"xla"}
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(a, b_, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("case", ["bhsd", "mesh", "above_the_bound",
+                                  "head_dim_32", "dispatch_xla"])
+def test_dispatch_leaves_xla_what_the_short_kernel_is_not_built_for(
+        case, monkeypatch):
+    from paddle_tpu.ops.pallas.mha_short import MAX_SHORT_SEQ
+
+    monkeypatch.delenv("PADDLE_TPU_ATTN_DISPATCH", raising=False)
+    shape, kw = (2, 32, 32, 2, 64), {}
+    if case == "bhsd":
+        kw["layout"] = "bhsd"
+    elif case == "mesh":
+        kw["compiled"] = True
+    elif case == "above_the_bound":
+        shape = (1, 16, MAX_SHORT_SEQ + 16, 2, 64)
+    elif case == "head_dim_32":
+        shape = (2, 32, 32, 4, 32)
+    else:
+        monkeypatch.setenv("PADDLE_TPU_ATTN_DISPATCH", "xla")
+    vals, paths = _run_attn_program(np.random.RandomState(4), shape, **kw)
+    assert paths == {"xla"}
+    assert all(np.isfinite(v).all() for v in vals)
+
+
+def test_short_kernel_dropout_trains_through_the_program(monkeypatch):
+    """Dropout on, through the executor: the op's rng seeds the kernel,
+    the same step twice from one seed agrees, and the rate is applied."""
+    monkeypatch.delenv("PADDLE_TPU_ATTN_DISPATCH", raising=False)
+    shape = (2, 32, 32, 2, 64)
+    a, paths = _run_attn_program(np.random.RandomState(5), shape, dropout=0.5)
+    assert paths == {"short"}
+    b_, _ = _run_attn_program(np.random.RandomState(5), shape, dropout=0.5)
+    plain, _ = _run_attn_program(np.random.RandomState(5), shape)
+    np.testing.assert_array_equal(a[0], b_[0])
+    assert np.abs(a[0] - plain[0]).max() > 1e-2
+    assert all(np.isfinite(v).all() for v in a)
+
+
 # -------------------------------------------- dispatch table (round 12)
 
 

@@ -1,6 +1,7 @@
-"""Short-seq fused attention kernel (ops/pallas/mha_short.py) vs the plain
-XLA reference path, in Pallas interpret mode on CPU (same harness pattern
-as tests/test_flash_attention.py)."""
+"""Short-sequence attention kernel (ops/pallas/mha_short.py), whose
+operands are the [b, s, heads*dh] arrays the projections write, against the
+float32 reference, in the Pallas interpreter on the CPU (same harness
+pattern as tests/test_flash_attention.py)."""
 
 import os
 
@@ -11,90 +12,157 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.ops.pallas import mha_short as kernel_module
 from paddle_tpu.ops.pallas.flash_attention import _reference_attention
-from paddle_tpu.ops.pallas.mha_short import _pick_g, short_attention
+from paddle_tpu.ops.pallas.mha_short import (
+    MAX_SHORT_SEQ,
+    _pick_bb,
+    mha_short,
+    mha_short_viable,
+)
 
 KEY = jax.random.key(0)
 
 
-def _mk(b, h, sq, sk, d, use_bias, causal=False):
-    q = jax.random.normal(jax.random.fold_in(KEY, 1), (b, h, sq, d))
-    k = jax.random.normal(jax.random.fold_in(KEY, 2), (b, h, sk, d))
-    v = jax.random.normal(jax.random.fold_in(KEY, 3), (b, h, sk, d))
+def _mk(b, h, sq, sk, d, use_bias, causal=False, dtype=jnp.float32):
+    shape = lambda s: (b, s, h * d)  # noqa: E731
+    q = jax.random.normal(jax.random.fold_in(KEY, 1), shape(sq)).astype(dtype)
+    k = jax.random.normal(jax.random.fold_in(KEY, 2), shape(sk)).astype(dtype)
+    v = jax.random.normal(jax.random.fold_in(KEY, 3), shape(sk)).astype(dtype)
     bias = None
     if use_bias:
         bias = jnp.where(
             jax.random.uniform(jax.random.fold_in(KEY, 4), (b, sk)) > 0.2,
             0.0, -1e30,
         ).astype(jnp.float32)
-        if causal:
-            # a causal row whose only visible key is padded out is
-            # undefined in softmax; keep key 0 live
-            bias = bias.at[:, 0].set(0.0)
+        # a row whose only visible key is masked out is undefined in
+        # softmax; keep key 0 live
+        bias = bias.at[:, 0].set(0.0)
     return q, k, v, bias
 
 
-@pytest.mark.parametrize(
-    "b,h,sq,sk,d,use_bias,causal",
-    [
-        (2, 3, 128, 128, 64, False, False),
-        (2, 3, 100, 100, 64, True, False),
-        (1, 2, 64, 128, 32, False, True),
-        (2, 2, 128, 128, 64, True, True),
-    ],
-)
+def _heads(x, h):
+    b, s, w = x.shape
+    return x.reshape(b, s, h, w // h).transpose(0, 2, 1, 3)
+
+
+def _reference(q, k, v, bias, causal, h):
+    """The float32 reference on the merged layout."""
+    d = q.shape[-1] // h
+    out = _reference_attention(
+        _heads(q.astype(jnp.float32), h), _heads(k.astype(jnp.float32), h),
+        _heads(v.astype(jnp.float32), h), bias, causal, 1.0 / np.sqrt(d),
+        0.0, None)
+    b, _, s, _ = out.shape
+    return out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+SHAPES = [
+    # b, h, sq, sk, d, bias, causal
+    (2, 2, 64, 64, 64, False, False),   # the transformer's, two heads a slice
+    (2, 4, 32, 32, 64, True, False),    # two 128-lane slices
+    (2, 2, 32, 48, 64, True, False),    # cross attention, sq != sk
+    (3, 2, 20, 20, 64, True, True),     # no multiple of 8: padded
+    (1, 2, 100, 100, 64, False, True),
+    (2, 2, 1, 24, 64, True, False),     # the decode step
+    (2, 1, 16, 64, 128, False, True),   # dh=128, one head a slice, sq < sk
+    (3, 2, 32, 32, 128, True, False),
+]
+
+
+@pytest.mark.parametrize("b,h,sq,sk,d,use_bias,causal", SHAPES)
 def test_matches_reference(b, h, sq, sk, d, use_bias, causal):
     q, k, v, bias = _mk(b, h, sq, sk, d, use_bias, causal)
-    scale = 1.0 / np.sqrt(d)
-    ref = _reference_attention(q, k, v, bias, causal, scale, 0.0, None)
-    out = short_attention(q, k, v, bias=bias, causal=causal)
-    np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=1e-2)
+    out = mha_short(q, k, v, h, bias=bias, causal=causal)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_reference(q, k, v, bias, causal, h)),
+        atol=2e-5)
 
 
-@pytest.mark.parametrize("use_bias,causal", [(False, False), (True, True)])
-def test_grads_match_reference(use_bias, causal):
-    b, h, s, d = 2, 2, 128, 64
-    q, k, v, bias = _mk(b, h, s, s, d, use_bias, causal)
-    scale = 1.0 / np.sqrt(d)
+def _grads(fn, q, k, v):
+    return jax.grad(
+        lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v).astype(jnp.float32))),
+        argnums=(0, 1, 2))(q, k, v)
 
-    def grads(fn):
-        return jax.grad(
-            lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v))), argnums=(0, 1, 2)
-        )(q, k, v)
 
-    gref = grads(
-        lambda q, k, v: _reference_attention(
-            q, k, v, bias, causal, scale, 0.0, None
-        )
-    )
-    gout = grads(
-        lambda q, k, v: short_attention(q, k, v, bias=bias, causal=causal)
-    )
-    for a, b_ in zip(gref, gout):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-2)
+@pytest.mark.parametrize("b,h,sq,sk,d,use_bias,causal", [
+    SHAPES[0], SHAPES[2], SHAPES[3], SHAPES[6], SHAPES[7]])
+def test_grads_match_reference(b, h, sq, sk, d, use_bias, causal):
+    q, k, v, bias = _mk(b, h, sq, sk, d, use_bias, causal)
+    want = _grads(lambda q, k, v: _reference(q, k, v, bias, causal, h),
+                  q, k, v)
+    got = _grads(lambda q, k, v: mha_short(q, k, v, h, bias=bias,
+                                           causal=causal), q, k, v)
+    for a, b_ in zip(want, got):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-5)
+
+
+@pytest.mark.parametrize("d,causal", [(64, True), (128, False)])
+def test_bf16_forward_and_grads(d, causal):
+    """bfloat16 operands, float32 inside: as close to the float32
+    reference on the same rounded operands as bfloat16's own step."""
+    b, h, s = 2, 2, 32
+    q, k, v, bias = _mk(b, h, s, s, d, True, causal, dtype=jnp.bfloat16)
+    out = mha_short(q, k, v, h, bias=bias, causal=causal)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32),
+        np.asarray(_reference(q, k, v, bias, causal, h)), atol=3e-2)
+    want = _grads(lambda q, k, v: _reference(q, k, v, bias, causal, h),
+                  q, k, v)
+    got = _grads(lambda q, k, v: mha_short(q, k, v, h, bias=bias,
+                                           causal=causal), q, k, v)
+    for a, b_ in zip(want, got):
+        assert b_.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b_, np.float32), atol=5e-2)
 
 
 def test_dropout_deterministic_and_unbiased():
-    b, h, s, d = 2, 4, 128, 64
+    b, h, s, d = 4, 4, 64, 64
     q, k, v, _ = _mk(b, h, s, s, d, False)
     v = jnp.ones_like(v)
     rng = jax.random.fold_in(KEY, 7)
-    o1 = short_attention(q, k, v, dropout=0.3, rng_key=rng)
-    o2 = short_attention(q, k, v, dropout=0.3, rng_key=rng)
+    o1 = mha_short(q, k, v, h, dropout=0.3, rng_key=rng)
+    o2 = mha_short(q, k, v, h, dropout=0.3, rng_key=rng)
     assert bool(jnp.all(o1 == o2))
-    o3 = short_attention(q, k, v, dropout=0.3, rng_key=jax.random.fold_in(KEY, 8))
+    o3 = mha_short(q, k, v, h, dropout=0.3,
+                   rng_key=jax.random.fold_in(KEY, 8))
     assert not bool(jnp.all(o1 == o3))
-    # v == ones: output rows are l_drop/l ~ 1 in expectation
-    assert abs(float(jnp.mean(o1)) - 1.0) < 0.05
+    # v == ones: an output row is the kept probability mass over the keep
+    # probability, 1 in expectation
+    assert abs(float(jnp.mean(o1)) - 1.0) < 0.02
+    # every head and every batch row draws a mask of its own
+    per_head = np.asarray(o1).reshape(b, s, h, d)[..., 0]
+    assert len({per_head[i, :, j].tobytes()
+                for i in range(b) for j in range(h)}) == b * h
 
 
-def test_dropout_grad_uses_same_mask():
-    b, h, s, d = 1, 2, 128, 32
+def test_dropout_mask_does_not_depend_on_the_block():
+    """The hash is over global (batch, head, query, key) indices: the
+    batch rows a grid step holds do not change the mask."""
+    b, h, s, d = 4, 2, 32, 64
+    q, k, v, _ = _mk(b, h, s, s, d, False)
+    rng = jax.random.fold_in(KEY, 5)
+    whole = mha_short(q, k, v, h, dropout=0.4, rng_key=rng)
+    pick = kernel_module._pick_bb
+    try:
+        kernel_module._pick_bb = lambda *a: 1
+        by_row = mha_short(q, k, v, h, dropout=0.4, rng_key=rng)
+    finally:
+        kernel_module._pick_bb = pick
+    np.testing.assert_allclose(np.asarray(whole), np.asarray(by_row),
+                               atol=1e-6)
+
+
+def test_dropout_grad_uses_the_forwards_mask():
+    b, h, s, d = 1, 2, 32, 64
     q, k, v, _ = _mk(b, h, s, s, d, False)
     rng = jax.random.fold_in(KEY, 9)
 
     def loss(q):
-        o = short_attention(q, k, v, dropout=0.5, rng_key=rng)
+        o = mha_short(q, k, v, h, dropout=0.5, rng_key=rng)
         return jnp.sum(o.astype(jnp.float64) ** 2)
 
     g = jax.grad(loss)(q)
@@ -103,93 +171,32 @@ def test_dropout_grad_uses_same_mask():
     u = jax.random.normal(jax.random.fold_in(KEY, 11), q.shape)
     eps = 1e-2
     fd = (loss(q + eps * u) - loss(q - eps * u)) / (2 * eps)
-    np.testing.assert_allclose(
-        float(jnp.vdot(g, u)), float(fd), rtol=5e-2
-    )
+    np.testing.assert_allclose(float(jnp.vdot(g, u)), float(fd), rtol=5e-2)
 
 
-def test_pick_g_divides_and_bounds():
-    g = _pick_g(3072, 128, 128, 64)
-    assert 3072 % g == 0
-    assert g * (128 * 128 * 4 + 8 * 128 * 64 * 2) <= 16 << 20
-    assert _pick_g(7, 128, 128, 64) == 7
-    assert _pick_g(12, 512, 512, 64) == 6
+def test_pick_bb_divides_the_batch_and_keeps_the_budget():
+    # the transformer's, BERT phase 1's and phase 2's blocks, bfloat16
+    for b, s, most in [(256, 64, 32), (256, 128, 16), (48, 512, 1)]:
+        bb = _pick_bb(b, s, s, 2, 2)
+        assert b % bb == 0 and bb == most
+    assert _pick_bb(7, 16, 16, 1, 4) == 7  # a prime batch, whole
 
 
-# -- [b, s, h, d]-native variant ------------------------------------------
+def test_viable_is_the_shape_rule():
+    assert mha_short_viable(64, 64, 8, 64)      # the transformer's
+    assert mha_short_viable(128, 128, 12, 64)   # BERT phase 1
+    assert mha_short_viable(1, 64, 8, 64)       # the decode step
+    assert mha_short_viable(16, 16, 2, 128)
+    assert not mha_short_viable(64, 64, 8, 32)  # four heads a slice
+    assert not mha_short_viable(64, 64, 3, 64)  # 192 lanes
+    assert not mha_short_viable(MAX_SHORT_SEQ + 1, 64, 8, 64)
+    assert not mha_short_viable(64, MAX_SHORT_SEQ + 1, 8, 64)
 
 
-def _to_bshd(t):
-    return jnp.transpose(t, (0, 2, 1, 3))
-
-
-@pytest.mark.parametrize(
-    "b,h,sq,sk,d,use_bias,causal",
-    [
-        (2, 3, 128, 128, 64, False, False),
-        (2, 3, 100, 100, 64, True, False),
-        (1, 2, 64, 128, 32, False, True),
-        (2, 2, 128, 128, 64, True, True),
-    ],
-)
-def test_bshd_matches_reference(b, h, sq, sk, d, use_bias, causal):
-    from paddle_tpu.ops.pallas.mha_short import short_attention_bshd
-
-    q, k, v, bias = _mk(b, h, sq, sk, d, use_bias, causal)
-    scale = 1.0 / np.sqrt(d)
-    ref = _reference_attention(q, k, v, bias, causal, scale, 0.0, None)
-    out = short_attention_bshd(
-        _to_bshd(q), _to_bshd(k), _to_bshd(v), bias=bias, causal=causal
-    )
-    np.testing.assert_allclose(
-        np.asarray(_to_bshd(out)), np.asarray(ref), atol=1e-2
-    )
-
-
-@pytest.mark.parametrize("use_bias,causal", [(False, False), (True, True)])
-def test_bshd_grads_match_reference(use_bias, causal):
-    from paddle_tpu.ops.pallas.mha_short import short_attention_bshd
-
-    b, h, s, d = 2, 2, 128, 64
-    q, k, v, bias = _mk(b, h, s, s, d, use_bias, causal)
-    scale = 1.0 / np.sqrt(d)
-
-    def loss_ref(q, k, v):
-        return jnp.sum(
-            jnp.square(
-                _reference_attention(q, k, v, bias, causal, scale, 0.0,
-                                     None)
-            )
-        )
-
-    def loss_kernel(q, k, v):
-        out = short_attention_bshd(
-            _to_bshd(q), _to_bshd(k), _to_bshd(v), bias=bias,
-            causal=causal,
-        )
-        return jnp.sum(jnp.square(_to_bshd(out)))
-
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    gk = jax.grad(loss_kernel, argnums=(0, 1, 2))(q, k, v)
-    for a, b_ in zip(gr, gk):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b_), atol=5e-2, rtol=1e-2
-        )
-
-
-def test_bshd_dropout_masks_match_bhsd():
-    """Same seed -> identical hash-dropout masks in both layouts (the
-    flattened batch*heads index streams are equal)."""
-    b, h, s, d = 2, 4, 128, 64
-    q, k, v, _ = _mk(b, h, s, s, d, False, False)
-    key = jax.random.fold_in(KEY, 9)
-    from paddle_tpu.ops.pallas.mha_short import short_attention_bshd
-
-    a = short_attention(q, k, v, dropout=0.3, rng_key=key)
-    bshd = short_attention_bshd(
-        _to_bshd(q), _to_bshd(k), _to_bshd(v), dropout=0.3, rng_key=key,
-        heads_per_block=h,
-    )
-    np.testing.assert_allclose(
-        np.asarray(a), np.asarray(_to_bshd(bshd)), atol=1e-5
-    )
+def test_shapes_outside_the_rule_raise():
+    q, k, v, _ = _mk(1, 3, 16, 16, 64, False)
+    with pytest.raises(ValueError, match="head_dim 64 or 128"):
+        mha_short(q, k, v, 3)
+    q, k, v, _ = _mk(1, 2, 16, 16, 64, False)
+    with pytest.raises(ValueError, match="rng_key"):
+        mha_short(q, k, v, 2, dropout=0.1)
