@@ -810,33 +810,17 @@ class Executor:
             compiled.written_only = written_only
             return _instrument_compiled(compiled, block)
 
-        auto_fmt = None
-        if (
-            os.environ.get("PADDLE_TPU_AUTO_LAYOUT", "1") == "1"
-            and os.environ.get("PADDLE_TPU_CHECK_NAN_INF") != "1"
-        ):
-            # Let XLA pick the layout of every persistable (params, opt
-            # state): the state round-trips scope -> donated arg -> scope,
-            # so a compiler-chosen layout sticks across steps and the
-            # per-step relayout copies disappear (measured on ResNet-50:
-            # the wgrad copy_subtract_fusion family). jax relayouts the
-            # startup-program values once on the first dispatch.
-            from jax.experimental.layout import Format, Layout
-
-            auto_fmt = Format(Layout.AUTO)
+        # The state keeps the default layout. `Layout.AUTO` on it does
+        # not survive the persistent compile cache: an executable read
+        # back reports default parameter layouts, so `jit` relays every
+        # convolution filter out on the host in each dispatch, and an
+        # array left in a compiler-chosen layout in the scope is misread
+        # by the next `jit` that takes it (PERF.md, PR 27).
         jit_kwargs = dict(donate_argnums=(0,))
-        if auto_fmt is not None:
-            # AUTO on every output too: donation aliases inputs to outputs
-            # by value, so a donated AUTO input must meet an AUTO output
-            jit_kwargs.update(
-                in_shardings=({n: auto_fmt for n in state_names}, None, None),
-                out_shardings=auto_fmt,
-            )
         compiled = _CompiledStep(step, jit_kwargs, state_names, feed_names,
                                  fetch_names)
         compiled.nan_names = getattr(step, "_nan_names", None)
         compiled.written_only = written_only
-        compiled.auto_layout = auto_fmt is not None
         return _instrument_compiled(compiled, block)
 
     # ------------------------------------------------------------------
@@ -1033,13 +1017,6 @@ class Executor:
             else:
                 if not isinstance(val, jax.Array):
                     val = jnp.asarray(val)
-                elif (
-                    getattr(compiled, "auto_layout", False)
-                    and len(getattr(val.sharding, "device_set", [0])) > 1
-                ):
-                    # a multi-device (e.g. pp-sharded) array can't meet an
-                    # AUTO-layout jit parameter: normalize through host
-                    val = jnp.asarray(np.asarray(val))
                 state[n] = val
         return state
 

@@ -1,17 +1,25 @@
 """ResNet-50 (the reference's image_classification workload; BASELINE.json
-ResNet-50 ImageNet config). NCHW, bottleneck-v1 like the reference model zoo.
+ResNet-50 ImageNet config). NCHW; bottlenecks with the stride on the 3x3
+convolution ("v1.5"), like the reference model zoo.
 """
 
 from __future__ import annotations
 
+import math
+
 from .. import layers
+from ..initializer import Constant, Uniform
 from ..param_attr import ParamAttr
 
 __all__ = ["resnet50", "resnet", "RESNET50_TRAIN_FLOPS_PER_IMG"]
 
-# fwd ~4.1 GFLOP @224, x3 for fwd+bwd (the MFU accounting both
-# bench.py and tools/bench_resnet.py use)
-RESNET50_TRAIN_FLOPS_PER_IMG = 3 * 4.1e9
+# FLOPs of one training step an image at 224x224. The 53 convolutions and
+# the classifier are 4.09e9 multiply-adds forward with a bottleneck's
+# stride on its 3x3 convolution (He et al. 2015, table 1, has it on the
+# first 1x1 and counts 3.8e9); a multiply-add is 2 FLOPs, and the backward
+# pass is twice the forward. `tests/test_resnet_reference.py` holds this
+# to the count from the shapes (`benchmark/models/resnet50.py`, 24.535e9).
+RESNET50_TRAIN_FLOPS_PER_IMG = 3 * 2 * 4.09e9
 
 _DEPTH_CFG = {
     18: ([2, 2, 2, 2], False),
@@ -23,7 +31,7 @@ _DEPTH_CFG = {
 
 
 def _conv_bn(x, num_filters, filter_size, stride=1, act=None, name=None,
-             groups=1):
+             groups=1, bn_scale=1.0):
     conv = layers.conv2d(
         x,
         num_filters=num_filters,
@@ -34,7 +42,9 @@ def _conv_bn(x, num_filters, filter_size, stride=1, act=None, name=None,
         bias_attr=False,
         name=name,
     )
-    return layers.batch_norm(conv, act=act, name=name + "_bn" if name else None)
+    return layers.batch_norm(
+        conv, act=act, name=name + "_bn" if name else None,
+        param_attr=ParamAttr(initializer=Constant(bn_scale)))
 
 
 def _shortcut(x, num_filters, stride, name):
@@ -43,10 +53,11 @@ def _shortcut(x, num_filters, stride, name):
     return x
 
 
-def _bottleneck(x, num_filters, stride, name):
+def _bottleneck(x, num_filters, stride, name, last_bn_scale):
     c1 = _conv_bn(x, num_filters, 1, act="relu", name=name + "_a")
     c2 = _conv_bn(c1, num_filters, 3, stride=stride, act="relu", name=name + "_b")
-    c3 = _conv_bn(c2, num_filters * 4, 1, name=name + "_c")
+    c3 = _conv_bn(c2, num_filters * 4, 1, name=name + "_c",
+                  bn_scale=last_bn_scale)
     sc = _shortcut(x, num_filters * 4, stride, name)
     return layers.elementwise_add(sc, c3, act="relu")
 
@@ -58,7 +69,16 @@ def _basic(x, num_filters, stride, name):
     return layers.elementwise_add(sc, c2, act="relu")
 
 
-def resnet(img, label=None, depth=50, class_num=1000):
+def resnet(img, label=None, depth=50, class_num=1000,
+           bottleneck_last_bn_scale=1.0):
+    """`bottleneck_last_bn_scale` seeds the scale of the last batch
+    normalisation of every bottleneck block (depth 50 and up; basic
+    blocks keep 1). He et al. 2015 start it at 1, the default; Goyal et
+    al. 2017 (arXiv:1706.02677, section 5.1) at 0, so that every block
+    starts as the identity. An evaluation before any training reads moving
+    statistics of 0 and 1, that is, does not normalise, so this scale
+    alone decides how fast the residual stream grows through the blocks
+    there (PERF.md, PR 27)."""
     blocks, use_bottleneck = _DEPTH_CFG[depth]
     x = _conv_bn(img, 64, 7, stride=2, act="relu", name="conv1")
     x = layers.pool2d(x, pool_size=3, pool_type="max", pool_stride=2,
@@ -69,15 +89,12 @@ def resnet(img, label=None, depth=50, class_num=1000):
             stride = 2 if blk == 0 and stage > 0 else 1
             name = f"res{stage + 2}{chr(ord('a') + blk)}"
             if use_bottleneck:
-                x = _bottleneck(x, num_filters[stage], stride, name)
+                x = _bottleneck(x, num_filters[stage], stride, name,
+                                bottleneck_last_bn_scale)
             else:
                 x = _basic(x, num_filters[stage], stride, name)
     pool = layers.pool2d(x, pool_type="avg", global_pooling=True)
-    import math
-
     stdv = 1.0 / math.sqrt(float(pool.shape[1]))
-    from ..initializer import Uniform
-
     pred = layers.fc(
         pool,
         class_num,

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import profiler
 from .registry import JNP_DTYPE, register_op
 
 # ---------------------------------------------------------------------------
@@ -89,8 +90,12 @@ def _conv2d(ctx, op):
         and (x.shape[2 if nhwc else 3] + pad[1][0] + pad[1][1]) % 2 == 0
         and os.environ.get("PADDLE_TPU_S2D_STEM", "1") == "1"
     ):
+        # which lowering the op took, counted where it is traced (as
+        # `attn_dispatch_*` is); nothing is counted when the step runs
+        profiler.bump_counter("conv_dispatch_s2d_stem")
         out = _s2d_stem_conv(x, w, pad, nhwc)
     else:
+        profiler.bump_counter("conv_dispatch_nhwc")
         # compute in NHWC — the TPU-native conv layout (channels ride the
         # lanes; NCHW convs measured ~2x slower on v5e). With the default
         # NCHW IR, XLA cancels the transpose pairs between adjacent

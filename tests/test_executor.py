@@ -379,3 +379,49 @@ def test_executor_compile_cache_lru_eviction_recompiles(monkeypatch):
     ya2 = run(xa)  # recompiles (it was evicted), bitwise-equal
     assert profiler.counters()["program_compile_count"] == c0 + 1
     np.testing.assert_array_equal(ya2, ya)
+
+
+def test_state_keeps_the_default_layout_and_a_second_jit_reads_it(monkeypatch):
+    """The executor asks `jit` for no layout on the step's state (once it
+    asked for `Layout.AUTO`: an executable read back from the persistent
+    compile cache then reported default parameter layouts, so every
+    dispatch relaid the filters out on the host, and a scope array left in
+    a compiler-chosen layout was misread by the next `jit` that took it).
+    So the step is jitted with donation alone, every array the step leaves
+    in the scope has the layout a new array of its shape has, and another
+    `jit` reads from it what NumPy reads."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import executor as executor_mod
+
+    asked = []
+    real_jit = executor_mod._jit
+
+    def spy(fn, **kwargs):
+        asked.append(kwargs)
+        return real_jit(fn, **kwargs)
+
+    monkeypatch.setattr(executor_mod, "_jit", spy)
+    x = fluid.layers.data("x", [3, 8, 8])
+    y = fluid.layers.batch_norm(fluid.layers.conv2d(x, 4, 3, padding=1))
+    loss = fluid.layers.mean(y)
+    fluid.optimizer.Momentum(0.01, 0.9).minimize(loss)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    feed = {"x": np.random.RandomState(0).randn(2, 3, 8, 8).astype("float32")}
+    for _ in range(2):
+        exe.run(feed=feed, fetch_list=[loss])
+
+    assert len(asked) == 2  # the startup program and the train step
+    assert all(kw == {"donate_argnums": (0,)} for kw in asked), asked
+    scope = fluid.global_scope()
+    double = jax.jit(lambda a: a * 2)
+    filters = [n for n in scope.local_names() if n.endswith(".w_0")
+               and np.ndim(scope.get(n)) == 4]
+    assert filters
+    for n in scope.local_names():
+        arr = scope.get(n)
+        assert arr.format == jnp.zeros(arr.shape, arr.dtype).format, n
+        np.testing.assert_array_equal(np.asarray(double(arr)),
+                                      np.asarray(arr) * 2)
