@@ -17,6 +17,7 @@ import numpy as np
 
 from .. import profiler
 from ..analysis.artifacts import load_artifact
+from .pallas import on_mesh
 from .pallas.flash_attention import _xla_attention, flash_attention
 from .pallas.mha_short import mha_short, mha_short_viable
 from .registry import register_op
@@ -25,13 +26,16 @@ _logger = logging.getLogger(__name__)
 
 # Attention kernel selection, from what the lowering can observe:
 #   short  ops/pallas/mha_short.py, where a few batch rows of whole score
-#          rows fit VMEM: one device, a backend that runs Pallas, layout
-#          "bshd", head_dim 64 or 128 with heads*head_dim a multiple of
-#          128, and sq, sk up to mha_short.MAX_SHORT_SEQ (set from chip
-#          runs of the benchmark's one-chip cells, PERF.md). Its operands
-#          are the [b, s, heads*dh] arrays the projections write, so the
-#          head relayout copies XLA puts around its own batched products
-#          are not in the step.
+#          rows fit VMEM: a backend that runs Pallas, layout "bshd",
+#          head_dim 64 or 128 with heads*head_dim a multiple of 128, and
+#          sq, sk up to mha_short.MAX_SHORT_SEQ (set from chip runs of the
+#          benchmark's one-chip cells, PERF.md). Its operands are the
+#          [b, s, heads*dh] arrays the projections write, so the head
+#          relayout copies XLA puts around its own batched products are
+#          not in the step. On one device, and on a mesh that shards the
+#          `batch` axis alone and divides the batch: there each chip calls
+#          the kernel on its own rows (ops/pallas/on_mesh.py), which are a
+#          whole problem of the kernel's shape, so nothing is partitioned.
 #   flash  ops/pallas/flash_attention.py, once the [b, h, sq, sk] float32
 #          scores stop fitting HBM comfortably (by score-tensor memory,
 #          batch counts as much as length) or above the `flash_min_seq`
@@ -39,8 +43,10 @@ _logger = logging.getLogger(__name__)
 #          Measured on v5e at s=512: XLA 299 ms a step, the blocked kernel
 #          2,069: it pays only beyond the HBM knee.
 #   xla    _xla_attention everywhere else: the "bhsd" layout, the CPU, and
-#          any mesh of several devices (GSPMD cannot partition a custom
-#          call; past the knee sequence parallelism takes over there).
+#          every other mesh of several devices (tensor or pipeline
+#          parallel, a batch the axis does not divide: GSPMD cannot
+#          partition a custom call; past the knee sequence parallelism
+#          takes over there).
 #
 # Env surface:
 #   PADDLE_TPU_ATTN_DISPATCH = auto (default) | xla | flash: force a
@@ -139,13 +145,16 @@ def _flash_dispatch(qb, kb) -> str:
     return mode
 
 
-def _attn_dispatch(q, k, bshd) -> str:
-    """"short", "flash" or "xla" for the op's q/k on one device: the
-    short-sequence kernel takes from `auto`'s XLA side the shapes it is
-    built for, in the layout whose operands it can read in place."""
+def _attn_dispatch(q, k, bshd, shards=1) -> str:
+    """"short", "flash" or "xla" for the rows of the op's q/k that one
+    device holds, a `shards`-th of the batch: the short-sequence kernel
+    takes from `auto`'s XLA side the shapes it is built for, in the layout
+    whose operands it can read in place."""
     def bhsd(t):
-        b, s, h, d = t.shape
-        return jax.ShapeDtypeStruct((b, h, s, d), t.dtype) if bshd else t
+        b, h, s, d = t.shape
+        if bshd:
+            h, s = s, h
+        return jax.ShapeDtypeStruct((b // shards, h, s, d), t.dtype)
 
     qb, kb = bhsd(q), bhsd(k)
     path = _flash_dispatch(qb, kb)
@@ -186,23 +195,30 @@ def _fused_mha(ctx, op):
         dropout = 0.0
     rng = ctx.rng_for(op.output("Out")[0]) if dropout > 0.0 else None
 
-    def attend(q, k, v, bias, rng, allow_pallas=True):
-        # a mesh of several devices without a sequence-parallel mode takes
-        # the XLA formulation, which shards by propagation like the rest
-        # of the graph: the Pallas kernels are custom calls GSPMD cannot
-        # partition. Past the HBM knee where flash wins, sequence
-        # parallelism (PADDLE_TPU_SP_MODE / the ring_min_seq auto-default)
-        # takes over instead.
-        path = _attn_dispatch(q, k, bshd) if allow_pallas else "xla"
+    def attend(q, k, v, bias, rng, shards):
+        # `shards` is on_mesh.batch_shards' answer. The Pallas kernels are
+        # custom calls GSPMD cannot partition, so on a mesh of several
+        # devices only what runs per shard of the batch is a kernel, and
+        # that is mha_short; everything else there takes the XLA
+        # formulation, which shards by propagation like the rest of the
+        # graph. Past the HBM knee where flash wins, sequence parallelism
+        # (PADDLE_TPU_SP_MODE / the ring_min_seq auto-default) takes over
+        # instead.
+        path = _attn_dispatch(q, k, bshd, shards) if shards else "xla"
+        if shards > 1 and path != "short":
+            path = "xla"
         profiler.bump_counter(f"attn_dispatch_{path}")
         if path == "short":
             # [b, s, nh, dh] back to the [b, s, nh*dh] the projection
             # wrote: XLA folds this with the Program's reshape2 into nothing
             b, sq, nh, dh = q.shape
+            if shards > 1:
+                profiler.bump_counter("pallas_on_mesh_calls")
             out = mha_short(
                 q.reshape(b, sq, nh * dh), k.reshape(b, -1, nh * dh),
                 v.reshape(b, -1, nh * dh), nh, bias=bias, causal=causal,
                 sm_scale=sm_scale, dropout=dropout, rng_key=rng,
+                mesh=mesh,
             )
             return out.reshape(b, sq, nh, dh)
         if path == "xla":
@@ -333,15 +349,11 @@ def _fused_mha(ctx, op):
                 sm_scale=sm_scale, dropout=dropout, rng_key=rng,
             ).astype(q.dtype)))
     else:
-        # batch ('batch') and head ('model') parallelism need no special
-        # handling: the lowering is plain traced code, so GSPMD
-        # partitions it from the feed/param shardings (the legacy
-        # shard-map wrapper existed only because manual per-device code
-        # couldn't mix with the auto-sharded graph) — but the Pallas
-        # kernels themselves cannot be partitioned by GSPMD, so
-        # multi-device meshes stick to the XLA attention formulation
-        out = attend(
-            q, k, v, bias, rng,
-            allow_pallas=(mesh is None or mesh.devices.size == 1),
-        )
+        # head ('model') parallelism needs no special handling: the XLA
+        # lowering is plain traced code, so GSPMD partitions it from the
+        # feed/param shardings. Pure batch parallelism needs no
+        # partitioning at all: each chip's rows are a whole attention
+        # problem, so the kernel runs per shard of 'batch'
+        out = attend(q, k, v, bias, rng,
+                     shards=on_mesh.batch_shards(mesh, q.shape[0], k.shape[0]))
     ctx.out(op, "Out", out)

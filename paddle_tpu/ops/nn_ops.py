@@ -568,25 +568,26 @@ def _layer_norm_grad(ctx, op):
     begin = op.attr("begin_norm_axis", 1)
     n = int(np.prod(x.shape[:begin] or (1,)))
     k = int(np.prod(x.shape[begin:]))
+    from .pallas import on_mesh
     from .pallas.flash_attention import _use_pallas
     from .pallas.layer_norm import ln_bwd, ln_bwd_viable
 
     # same rule as fused_multihead_attention: a Pallas custom call is
-    # something GSPMD cannot partition, so a multi-device mesh keeps
-    # the XLA formulation, which shards by propagation
-    mesh = ctx.mesh
-    use_kernel = (
-        ln_bwd_viable(n, k)
-        and _use_pallas()
-        and (mesh is None or mesh.devices.size == 1)
-    )
-    if use_kernel:
+    # something GSPMD cannot partition, so a mesh of several devices
+    # keeps the XLA formulation, which shards by propagation, unless it
+    # shards the batch alone: there the rows are batch-major, each chip's
+    # are a whole problem, and the kernel runs per shard
+    shards = on_mesh.batch_shards(ctx.mesh, x.shape[0])
+    if shards and ln_bwd_viable(n // shards, k) and _use_pallas():
+        if shards > 1:
+            profiler.bump_counter("pallas_on_mesh_calls")
         rstd = jax.lax.rsqrt(var.reshape(-1).astype(jnp.float32) + eps)
         sc = (scale if scale is not None
               else jnp.ones((k,), jnp.float32)).reshape(-1)
         dx, dscale, dbias = ln_bwd(
             x.reshape(n, k), dy.reshape(n, k),
             mean.reshape(-1).astype(jnp.float32), rstd, sc,
+            mesh=ctx.mesh,
         )
         ctx.out(op, "IGRAD_X", dx.reshape(x.shape))
         if scale is not None and op.output("IGRAD_Scale"):

@@ -37,6 +37,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import on_mesh
 from .flash_attention import LANE, NEG_INF, _ceil_to, _interpret, require_pallas
 
 # The longest sequence that takes this kernel. Set from chip runs of the
@@ -155,7 +156,9 @@ def _probs(seed_ref, q_ref, k_ref, bias_ref, *, num_heads, hp, sm_scale,
     p = e * (1.0 / jnp.where(l == 0.0, 1.0, l))
     keep = None
     if dropout > 0.0:
-        bi = pl.program_id(0) * bb + jax.lax.broadcasted_iota(
+        # seed_ref[1]: the global index of the call's first batch row, so
+        # that the shards of a mesh draw the masks of the whole batch
+        bi = seed_ref[1] + pl.program_id(0) * bb + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 0)
         head = _u32(bi * num_heads + pl.program_id(1) * hp) + _u32(sub)
         hqk = (_u32(qi) * jnp.uint32(0x9E3779B1)
@@ -212,36 +215,46 @@ def _call(seed, q, k, v, bias, do, *, statics):
     """One pallas_call, the forward without `do` and the backward with it,
     over a grid of (batch blocks, 128-lane slices): [bb, s, 128] blocks of
     every operand, which the array's (sublanes, 128) tiling keeps as whole
-    tiles in HBM; the seed in SMEM, the bias as [b, 1, sk]. Jitted so that
-    the calls of one shape in a step (twelve in BERT) are traced and
-    lowered once."""
-    num_heads, sm_scale, causal, causal_offset, dropout, bb, interpret = statics
+    tiles in HBM; the seed and the first row's global index in SMEM, the
+    bias as [b, 1, sk]. On a `mesh` (on_mesh.batch_shards said so) each
+    shard of `batch` makes the call on its rows. Jitted so that the calls
+    of one shape in a step (twelve in BERT) are traced and lowered once."""
+    (num_heads, sm_scale, causal, causal_offset, dropout, bb, interpret,
+     mesh) = statics
     backward = do is not None
+    has_bias = bias is not None
 
-    def spec(x):
-        if x is bias:
-            return pl.BlockSpec((bb, *x.shape[1:]), lambda i, j: (i, 0, 0),
+    def run(seed, *args):
+        q = args[0]
+
+        def spec(x, is_bias=False):
+            if is_bias:
+                return pl.BlockSpec((bb, *x.shape[1:]), lambda i, j: (i, 0, 0),
+                                    memory_space=pltpu.VMEM)
+            return pl.BlockSpec((bb, x.shape[1], LANE), lambda i, j: (i, 0, j),
                                 memory_space=pltpu.VMEM)
-        return pl.BlockSpec((bb, x.shape[1], LANE), lambda i, j: (i, 0, j),
-                            memory_space=pltpu.VMEM)
+
+        row0 = on_mesh.first_row(mesh, q.shape[0])
+        outs = args[:3] if backward else args[:1]
+        return pl.pallas_call(
+            functools.partial(
+                _bwd_kernel if backward else _fwd_kernel,
+                has_bias=has_bias, num_heads=num_heads,
+                hp=LANE * num_heads // q.shape[2], sm_scale=sm_scale,
+                causal=causal, causal_offset=causal_offset, dropout=dropout),
+            grid=(q.shape[0] // bb, q.shape[2] // LANE),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+            + [spec(x, has_bias and i == 3) for i, x in enumerate(args)],
+            out_specs=[spec(x) for x in outs],
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in outs],
+            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+            interpret=interpret,
+            name="mha_short_bwd" if backward else "mha_short_fwd",
+        )(jnp.stack([seed[0], jnp.int32(row0)]), *args)
 
     args = [x for x in (q, k, v, bias, do) if x is not None]
-    outs = [q, k, v] if backward else [q]
-    return pl.pallas_call(
-        functools.partial(
-            _bwd_kernel if backward else _fwd_kernel,
-            has_bias=bias is not None, num_heads=num_heads,
-            hp=LANE * num_heads // q.shape[2], sm_scale=sm_scale,
-            causal=causal, causal_offset=causal_offset, dropout=dropout),
-        grid=(q.shape[0] // bb, q.shape[2] // LANE),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
-        + [spec(x) for x in args],
-        out_specs=[spec(x) for x in outs],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in outs],
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret,
-        name="mha_short_bwd" if backward else "mha_short_fwd",
-    )(seed, *args)
+    return on_mesh.per_shard(run, mesh, [False] + [True] * len(args))(
+        seed, *args)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -266,10 +279,12 @@ _core.defvjp(_core_fwd, _core_bwd)
 
 
 def mha_short(q, k, v, num_heads, bias=None, causal=False, sm_scale=None,
-              dropout=0.0, rng_key=None):
+              dropout=0.0, rng_key=None, mesh=None):
     """Fused multi-head attention for short sequences. q: [b, sq, h*dh];
     k, v: [b, sk, h*dh]; bias: [b, sk] additive key bias or None. Returns
-    [b, sq, h*dh] in q's dtype."""
+    [b, sq, h*dh] in q's dtype. `mesh`: a mesh whose `batch` axis alone
+    shards the rows and divides b; the kernels then run per shard, with
+    the dropout masks of the one-device call on the whole batch."""
     require_pallas("mha_short")
     b, sq, width = q.shape
     sk = k.shape[1]
@@ -302,7 +317,13 @@ def mha_short(q, k, v, num_heads, bias=None, causal=False, sm_scale=None,
     if bias is not None:
         bias = jnp.pad(bias.astype(jnp.float32), [(0, 0), (0, skp - sk)],
                        constant_values=NEG_INF)[:, None, :]
-    bb = _pick_bb(b, sqp, skp, LANE // dh, q.dtype.itemsize)
+    # the block is a rule of what one chip holds: its rows of the batch
+    shards = on_mesh.batch_shards(mesh, b)
+    if not shards:
+        raise ValueError(
+            f"mha_short: a batch of {b} on {mesh}: the mesh has to shard "
+            "`batch` alone and divide the batch")
+    bb = _pick_bb(b // shards, sqp, skp, LANE // dh, q.dtype.itemsize)
     statics = (num_heads, float(sm_scale), bool(causal), sk - sq,
-               float(dropout), bb, _interpret())
+               float(dropout), bb, _interpret(), mesh)
     return _core(q, k, v, bias, seed, statics)[:, :sq]

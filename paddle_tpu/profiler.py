@@ -133,7 +133,9 @@ def set_counter(name: str, value: int) -> int:
     step's activation-transpose count under NCHW IR vs after the pass,
     most recent compile; attn_dispatch_xla / _flash / _ring / _ulysses
     via bump = attention path chosen at trace time, fwd + grad replay
-    each count; reader_staged_batches via bump = batches the shared
+    each count; pallas_on_mesh_calls via bump = lowerings that run
+    their Pallas kernel per shard of a data-parallel mesh
+    (ops/pallas/on_mesh.py); reader_staged_batches via bump = batches the shared
     DeviceStager converted + device_put ahead of the consumer), and the
     round-15 static-analysis timer (pass_verify_us via time_counter =
     wall time the PADDLE_TPU_VERIFY IR-verifier hook spent across the

@@ -92,12 +92,17 @@ def test_interpret_follows_only_the_variable(monkeypatch):
                           jnp.ones(128))
 
 
-def test_ln_bwd_kernel_leaves_a_multi_device_mesh_to_xla(monkeypatch):
+def test_ln_bwd_kernel_on_a_mesh_runs_per_shard_of_the_batch_or_not_at_all(
+        monkeypatch):
     """The rule attention follows: GSPMD cannot partition a Pallas custom
-    call, so layer_norm_grad picks the kernel on one device only."""
+    call, so on a mesh of several devices layer_norm_grad picks the kernel
+    only where each chip's rows are a whole problem of its own: the mesh
+    shards the batch alone, and a shard holds rows enough."""
+    import jax
     import numpy as np
 
     from paddle_tpu.ops.pallas import layer_norm
+    from paddle_tpu.parallel.mesh import build_mesh
 
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
     used = []
@@ -117,13 +122,25 @@ def test_ln_bwd_kernel_leaves_a_multi_device_mesh_to_xla(monkeypatch):
     feed = {"x": np.random.RandomState(0).randn(2048, 128).astype("float32")}
     main = fluid.default_main_program()
 
-    (one,) = exe.run(main, feed=feed, fetch_list=[loss])
-    assert used == [(2048, 128)]
-    del used[:]
-    cp = fluid.CompiledProgram(main).with_data_parallel(loss_name=loss.name)
-    (many,) = exe.run(cp, feed=feed, fetch_list=[loss])
-    assert used == []
-    assert np.isfinite(one).all() and np.isfinite(many).all()
+    def step(places=None, mesh=None):
+        del used[:]
+        program = main
+        if places or mesh:
+            program = fluid.CompiledProgram(main).with_data_parallel(
+                loss_name=loss.name, places=places)
+            program._mesh = mesh
+        (value,) = exe.run(program, feed=feed, fetch_list=[loss])
+        assert np.isfinite(value).all()
+        return list(used)
+
+    assert step() == [(2048, 128)]
+    # two shards of 1,024 rows: the kernel, called on the global arrays
+    assert step(places=2) == [(2048, 128)]
+    # eight shards of 256 rows are under the kernel's 1,024: XLA's
+    assert step(places=8) == []
+    # tensor parallelism beside the batch axis: XLA's
+    assert step(mesh=build_mesh(batch=2, model=2,
+                                devices=jax.devices()[:4])) == []
 
 
 # ---------------------------------------------------- imports and the cache

@@ -388,8 +388,8 @@ def test_dispatch_leaves_xla_what_the_short_kernel_is_not_built_for(
     shape, kw = (2, 32, 32, 2, 64), {}
     if case == "bhsd":
         kw["layout"] = "bhsd"
-    elif case == "mesh":
-        kw["compiled"] = True
+    elif case == "mesh":  # two devices and a batch they do not divide
+        shape, kw["compiled"] = (3, 32, 32, 2, 64), True
     elif case == "above_the_bound":
         shape = (1, 16, MAX_SHORT_SEQ + 16, 2, 64)
     elif case == "head_dim_32":
