@@ -101,7 +101,7 @@ def cache_entries():
 
 
 def build_train_program(size, batch):
-    """BERT pretraining exactly as bench.py's headline workload builds it."""
+    """BERT pretraining as the benchmark's bert_base cells build it."""
     import paddle_tpu as fluid
     from paddle_tpu.contrib import mixed_precision as mp
     from paddle_tpu.models.bert import BertConfig, build_bert_pretrain
@@ -316,9 +316,10 @@ def phase_dp4(size, first_loss_one_chip, rehearse):
 
     # where the step's arguments live: the state as the scope now holds
     # it, the feeds as the step was jitted to take them
-    compiled, state, _, _ = cp._prepare_mesh_run(
-        exe, feed, [loss_name], fluid.global_scope())
-    mesh = cp._get_mesh()
+    scope = fluid.global_scope()
+    compiled, _, _ = exe._prepare_run(main, feed, [loss_name], scope, cp)
+    state = exe._assemble_state(compiled, scope)
+    mesh = compiled.mesh
     say(f"[dp4] mesh {dict(mesh.shape)} over "
         f"{[d.id for d in mesh.devices.flat]}")
     on_one = [n for n, v in state.items()

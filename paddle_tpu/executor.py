@@ -22,7 +22,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
+from .compiler import CompiledProgram
 from .framework import (
     GRAD_SUFFIX,
     Program,
@@ -82,6 +85,13 @@ def _trainer_heartbeat(step, tick: int) -> None:
         os.replace(tmp, path)
     except Exception:  # noqa: BLE001 — heartbeat loss must never kill
         pass           # training; prolonged absence is the watchdog's job
+
+
+def _ckpt_manager(program, cp):
+    """The CheckpointManager attached (manager.attach) to the Program or,
+    failing that, to the CompiledProgram it was run through; else None."""
+    return (getattr(program, "_ckpt_manager", None)
+            or getattr(cp, "_ckpt_manager", None))
 
 
 def _as_feed_array(value, dtype=None):
@@ -159,8 +169,8 @@ def _instrument_compiled(compiled, block):
 
 
 def check_nan_result(result, compiled, scope):
-    """Shared PADDLE_TPU_CHECK_NAN_INF result handling for Executor.run
-    and CompiledProgram._run: one stacked host fetch of all flags (per-op
+    """PADDLE_TPU_CHECK_NAN_INF result handling for Executor.run: one
+    stacked host fetch of all flags (per-op
     bool() reads would cost a device round-trip each), offender naming in
     execution order, and state persistence so the scope stays debuggable
     after the donated buffers are gone."""
@@ -824,6 +834,30 @@ class Executor:
         return _instrument_compiled(compiled, block)
 
     # ------------------------------------------------------------------
+    def _unwrap(self, program):
+        """What `run` and `run_repeated` were handed, as (the Program to
+        step, the CompiledProgram that says how to lay it over a mesh, or
+        None). Every stage below takes the pair; there is no other path."""
+        if program is None:
+            from .framework import default_main_program
+
+            program = default_main_program()
+        if isinstance(program, CompiledProgram):
+            return program._program, program
+        # fleet collective path: a program minimized through
+        # fleet.distributed_optimizer carries its DistributedStrategy —
+        # run it over the strategy's mesh (all chips) transparently
+        strategy = getattr(program, "_fleet_strategy", None)
+        if strategy is None or len(jax.devices()) == 1:
+            return program, None
+        cp = getattr(program, "_fleet_compiled", None)
+        if cp is None:
+            cp = CompiledProgram(program).with_data_parallel(
+                zero1=bool(getattr(strategy, "zero1", False)))
+            cp._mesh = strategy.build_mesh()
+            program._fleet_compiled = cp
+        return program, cp
+
     def run(
         self,
         program: Program = None,
@@ -833,34 +867,12 @@ class Executor:
         return_numpy: bool = True,
         use_program_cache: bool = True,
     ):
-        from .compiler import CompiledProgram  # lazy: avoid import cycle
-
-        if program is None:
-            from .framework import default_main_program
-
-            program = default_main_program()
-        if isinstance(program, CompiledProgram):
-            return program._run(self, feed, fetch_list, scope, return_numpy)
-
-        # fleet collective path: a program minimized through
-        # fleet.distributed_optimizer carries its DistributedStrategy —
-        # run it over the strategy's mesh (all chips) transparently
-        strategy = getattr(program, "_fleet_strategy", None)
-        if strategy is not None and len(jax.devices()) > 1:
-            cp = getattr(program, "_fleet_compiled", None)
-            if cp is None:
-                cp = CompiledProgram(program).with_data_parallel(
-                    zero1=bool(getattr(strategy, "zero1", False)))
-                cp._mesh = strategy.build_mesh()
-                program._fleet_compiled = cp
-            return cp._run(self, feed, fetch_list, scope, return_numpy)
-
+        program, cp = self._unwrap(program)
         scope = scope or global_scope()
-        # the pt.exe.* spans are the same on the mesh path
-        # (CompiledProgram._run); PERF.md lists what reads them
+        # PERF.md lists what reads the pt.exe.* spans
         with RecordEvent("pt.exe.prepare"):
             compiled, feeds, fetch_names = self._prepare_run(
-                program, feed, fetch_list, scope
+                program, feed, fetch_list, scope, cp
             )
         with RecordEvent("pt.exe.state"):
             state = self._assemble_state(compiled, scope)
@@ -873,10 +885,10 @@ class Executor:
         with RecordEvent("pt.exe.writeback"):
             return self._write_back(
                 program, compiled, result, scope, return_numpy,
-                getattr(program, "_ckpt_manager", None))
+                _ckpt_manager(program, cp))
 
     def _dispatch(self, program, compiled, state, feeds):
-        """Enqueue one step, on the mesh path too."""
+        """Enqueue one step."""
         with RecordEvent("pt.exe.dispatch"):
             # functional PRNG: fold in a per-run counter so randomness
             # varies across steps; with program.random_seed set the whole
@@ -895,49 +907,58 @@ class Executor:
         self._seed_counter += 1
         return result
 
+    def _step_boundary(self, program, scope, new_state, mgr, steps=1):
+        """What follows a dispatch of `steps` training steps: the new
+        state into the scope, then the step boundary's hooks (`mgr` is the
+        attached CheckpointManager or None)."""
+        for n, v in new_state.items():
+            scope.set(n, v)
+
+        # state written back: trainer.step is the chaos anchor for
+        # "crash/wedge at step N", then the heartbeat publishes the
+        # supervised rank's progress (of a run_repeated window, its final
+        # step). BOTH run before the checkpoint hook below on purpose — a
+        # crash or hold here leaves the newest snapshot at step N-1, so
+        # the respawned attempt RETRAINS step N (and re-emits its
+        # fetches/logs) instead of resuming past a step nobody observed
+        # complete. A hold also keeps THIS step's heartbeat from landing —
+        # the watchdog sees progress stuck at N-1.
+        self._dispatch_count += 1
+        fault_point("trainer.step")
+        _trainer_heartbeat(
+            None if mgr is None else mgr._auto_step + steps - 1,
+            self._dispatch_count)
+
+        # resilience wiring: a CheckpointManager attached to this program
+        # (manager.attach) counts each dispatch as `steps` steps and
+        # snapshots the persistable state on its cadence (of a window, the
+        # final state: the intermediate ones lived only inside the scan).
+        # The host pull happens here at the step boundary (the donated
+        # state buffers die on the next dispatch); serialization + file
+        # I/O flush on the engine's background thread, overlapping the
+        # next step.
+        if mgr is not None:
+            mgr._on_executor_step(program, scope, self, steps=steps)
+
     def _write_back(self, program, compiled, result, scope, return_numpy,
                     mgr):
-        """What follows a step's dispatch, on the mesh path too: the new
-        state into the scope, the step boundary's hooks (`mgr` is the
-        attached CheckpointManager or None), the fetches."""
+        """What follows a step's dispatch: the new state and the step
+        boundary's hooks, then the fetches."""
         if len(result) == 3:  # PADDLE_TPU_CHECK_NAN_INF=1 debug mode
             fetches, new_state = check_nan_result(result, compiled, scope)
         else:
             fetches, new_state = result
-        for n, v in new_state.items():
-            scope.set(n, v)
-
-        # step boundary, state written back: trainer.step is the chaos
-        # anchor for "crash/wedge at step N", then the heartbeat
-        # publishes the supervised rank's progress. BOTH run before the
-        # checkpoint hook below on purpose — a crash or hold here leaves
-        # the newest snapshot at step N-1, so the respawned attempt
-        # RETRAINS step N (and re-emits its fetches/logs) instead of
-        # resuming past a step nobody observed complete. A hold also
-        # keeps THIS step's heartbeat from landing — the watchdog sees
-        # progress stuck at N-1.
-        self._dispatch_count += 1
-        fault_point("trainer.step")
-        _trainer_heartbeat(None if mgr is None else mgr._auto_step,
-                           self._dispatch_count)
-
-        # resilience wiring: a CheckpointManager attached to this program
-        # (manager.attach) counts each run as one step and snapshots the
-        # persistable state on its cadence. The host pull happens here at
-        # the step boundary (the donated state buffers die on the next
-        # dispatch); serialization + file I/O flush on the engine's
-        # background thread, overlapping the next step.
-        if mgr is not None:
-            mgr._on_executor_step(program, scope, self)
-
+        self._step_boundary(program, scope, new_state, mgr)
         if return_numpy:
             return [np.asarray(f) for f in fetches]
         return list(fetches)
 
     # ------------------------------------------------------------------
-    def _prepare_run(self, program, feed, fetch_list, scope):
-        """Shared run() prelude: feed normalization + compile-cache
-        lookup. Returns (compiled, device feeds dict, fetch_names)."""
+    def _prepare_run(self, program, feed, fetch_list, scope, cp=None):
+        """run()'s prelude: feed normalization, the compile-cache lookup
+        (the one key, the one LRU cap) and the device feeds. `cp` is the
+        CompiledProgram `_unwrap` found, or None for one device. Returns
+        (compiled, device feeds dict, fetch_names)."""
         feed = feed or {}
         fetch_list = fetch_list or []
         fetch_names = [
@@ -955,6 +976,18 @@ class Executor:
             (name, arr.shape, str(arr.dtype)) for name, arr in feed_items
         )
 
+        mesh = strategy = placement = None
+        zero1 = False
+        if cp is not None:
+            from .parallel.mesh import mesh_signature
+
+            mesh, strategy, zero1 = (
+                cp._get_mesh(), cp._build_strategy, cp._zero1)
+            # mesh shape + spec assignment: flipping a shard_parameter
+            # annotation (or the zero1 flag) must recompile, not serve
+            # the stale executable
+            placement = (
+                mesh_signature(mesh, program._sharding_specs), zero1)
         key = (
             self._program_key(program),
             feed_sig,
@@ -970,15 +1003,29 @@ class Executor:
             os.environ.get("PADDLE_TPU_CHECK_NAN_INF") == "1",
             # flipping PADDLE_TPU_PASSES between runs must recompile —
             # a stale step would keep the old pass set's graph
-            _resolve_pass_names(None),
+            _resolve_pass_names(strategy),
+            placement,
         )
         compiled = self._cache.get(key)
         if compiled is None:
             with RecordEvent("pt.exe.compile"):
                 compiled = self._compile(
                     program, block, feed_sig, fetch_names, scope,
-                    is_test=False
+                    # on a mesh an explicit for_test clone compiles as
+                    # eval (on pp meshes this folds pp into data
+                    # parallelism instead of running the microbatch
+                    # schedule); any other program keeps train-mode
+                    # semantics
+                    is_test=mesh is not None
+                    and bool(getattr(program, "_is_test_clone", False)),
+                    mesh=mesh,
+                    sharding_specs=program._sharding_specs,
+                    build_strategy=strategy,
+                    zero1=zero1,
                 )
+            # _assemble_state lays replicated state over it on a fleet of
+            # processes
+            compiled.mesh = mesh
             self._cache[key] = compiled
             from . import profiler
             from .dygraph.jit import _jit_cache_cap
@@ -990,18 +1037,44 @@ class Executor:
                 profiler.bump_counter("executor_cache_evictions")
         else:
             self._cache.move_to_end(key)
-        feeds = {name: jnp.asarray(arr) for name, arr in feed_items}
+        if mesh is None or jax.process_count() == 1:
+            feeds = {name: jnp.asarray(arr) for name, arr in feed_items}
+        else:
+            # multi-process (fleet) execution: each trainer feeds its
+            # process-LOCAL batch shard (the reference's trainers read
+            # disjoint file splits); assemble global arrays spanning all
+            # processes
+            feeds = {
+                name: jax.make_array_from_process_local_data(
+                    NamedSharding(
+                        mesh,
+                        P("batch", *([None] * (arr.ndim - 1)))
+                        if arr.ndim else P(),
+                    ),
+                    np.asarray(arr),
+                )
+                for name, arr in feed_items
+            }
         return compiled, feeds, fetch_names
 
     def _assemble_state(self, compiled, scope, placeholders=None):
         """Build the state dict for compiled.fn. `placeholders`, when a
         set is passed, collects the names that received the zero-scalar
         written-only placeholder (no settled scope value yet)."""
+        mesh = compiled.mesh
+        state_sh = fleet_rep = None
+        if mesh is not None and jax.process_count() > 1:
+            # on a fleet of processes the state is replicated — every
+            # process initialized identically from the seeded startup
+            # program
+            fleet_rep = NamedSharding(mesh, P())
+        elif mesh is not None:
+            state_sh = compiled.state_shardings
         state = {}
         for n in compiled.state_names:
             val = scope.get(n) if scope.has(n) else None
             if val is None:
-                if n not in getattr(compiled, "written_only", frozenset()):
+                if n not in compiled.written_only:
                     # a READ state var with no value would silently become
                     # a zero scalar — the reference errors instead
                     # (executor.cc var-init check)
@@ -1011,13 +1084,26 @@ class Executor:
                         "checkpointed state) first"
                     )
                 # written-only state (e.g. startup program creating params)
-                state[n] = jnp.zeros((), dtype=jnp.float32)
+                val = jnp.zeros((), dtype=jnp.float32)
                 if placeholders is not None:
                     placeholders.add(n)
-            else:
-                if not isinstance(val, jax.Array):
-                    val = jnp.asarray(val)
-                state[n] = val
+            elif not isinstance(val, jax.Array):
+                val = jnp.asarray(val)
+            elif state_sh:
+                want = state_sh.get(n)
+                if want is not None and val.sharding != want:
+                    # one-time reshard: a committed layout from an
+                    # earlier compile (different zero1/pipe specs)
+                    # moves onto this compile's assignment; steady
+                    # state re-enters already matching (out_shardings)
+                    val = jax.device_put(val, want)
+            if fleet_rep is not None and val.is_fully_addressable:
+                # anything else is already a global (possibly sharded)
+                # array from a previous step — pass through, never fetch
+                # to host
+                val = jax.make_array_from_process_local_data(
+                    fleet_rep, np.asarray(val))
+            state[n] = val
         return state
 
     def run_repeated(
@@ -1030,7 +1116,8 @@ class Executor:
         return_numpy: bool = True,
     ):
         """Run the SAME program `steps` times with the SAME feed in ONE
-        device dispatch: the persistable state threads through an
+        device dispatch: the persistable state (on a mesh, sharded and
+        multi-process global arrays included) threads through an
         on-device lax.scan, the functional PRNG folds the same per-run
         counters run() would, and each fetch comes back stacked with a
         leading [steps] axis (last element == what the final run() would
@@ -1042,8 +1129,6 @@ class Executor:
         consecutive run() calls exactly (same PRNG fold sequence).
         Constant-feed only by construction; for real data pipelines use
         run() per batch."""
-        from .compiler import CompiledProgram  # lazy: avoid import cycle
-
         if steps < 1:
             raise ValueError(f"steps must be >= 1, got {steps}")
         if os.environ.get("PADDLE_TPU_CHECK_NAN_INF") == "1":
@@ -1051,23 +1136,10 @@ class Executor:
                 "run_repeated does not support PADDLE_TPU_CHECK_NAN_INF "
                 "(per-op flag shapes vary per step); use run()"
             )
-        if program is None:
-            from .framework import default_main_program
-
-            program = default_main_program()
-        if isinstance(program, CompiledProgram):
-            return program._run_repeated(self, feed, fetch_list, steps,
-                                         scope, return_numpy)
-        if getattr(program, "_fleet_strategy", None) is not None:
-            raise TypeError(
-                "run_repeated does not route the fleet-collective mesh "
-                "path; run() dispatches fleet programs over the strategy "
-                "mesh"
-            )
-
+        program, cp = self._unwrap(program)
         scope = scope or global_scope()
         compiled, feeds, fetch_names = self._prepare_run(
-            program, feed, fetch_list, scope
+            program, feed, fetch_list, scope, cp
         )
         placeholders: set = set()
         state = self._assemble_state(compiled, scope,
@@ -1117,25 +1189,8 @@ class Executor:
         # advance only on success: a failed trace must not skip PRNG
         # counters (the N-consecutive-run() equivalence contract)
         self._seed_counter += steps
-        for n, v in new_state.items():
-            scope.set(n, v)
-
-        # chaos anchor + heartbeat BEFORE the snapshot hook (see run():
-        # a crash here resumes by retraining the window, never skipping
-        # past it); the step reported is the window's final step
-        mgr = getattr(program, "_ckpt_manager", None)
-        self._dispatch_count += 1
-        fault_point("trainer.step")
-        _trainer_heartbeat(
-            None if mgr is None else mgr._auto_step + steps - 1,
-            self._dispatch_count)
-
-        # attach-cadence over the whole scan window: the counter advances
-        # by `steps`, one snapshot of the final state if a cadence
-        # boundary fell inside (intermediate states lived only on device)
-        if mgr is not None:
-            mgr._on_executor_step(program, scope, self, steps=steps)
-
+        self._step_boundary(program, scope, new_state,
+                            _ckpt_manager(program, cp), steps=steps)
         if return_numpy:
             return [np.asarray(f) for f in stacked]
         return list(stacked)
@@ -1161,17 +1216,13 @@ class Executor:
         # dataset path.
         import jax.numpy as _jnp
 
-        from .compiler import CompiledProgram as _CP
-        from .framework import default_main_program as _dmp
         from .reader.stager import DeviceStager
 
-        base_prog = (program._program if isinstance(program, _CP)
-                     else (program or _dmp()))
-        block = base_prog.global_block()
+        block = self._unwrap(program)[0].global_block()
 
         # multi-process fleet programs rebuild feeds with
         # make_array_from_process_local_data from HOST arrays
-        # (compiler.py) — device-staging there would force a download
+        # (_prepare_run) — device-staging there would force a download
         # per step; stage to device only in the single-process case
         to_device = jax.process_count() == 1
 

@@ -1,5 +1,5 @@
-"""BERT-base pretrain model — the flagship workload (BASELINE.json: BERT-base
-tokens/sec/chip, ≥50% MFU north star). Built entirely through the framework's
+"""BERT-base pretrain model — the flagship workload (the benchmark's
+bert_base cells: examples/sec/chip). Built entirely through the framework's
 layers API; tensor-parallel PartitionSpecs annotate attention/FFN weights
 along "tp" (Megatron-style column→row split), consumed by the GSPMD compile
 path. Reference capability: the fleet-collective BERT config (SURVEY.md §3.3);
@@ -36,8 +36,6 @@ class BertConfig:
         use_flash_attention=True,
         recompute=False,
         tie_mlm_weights=True,
-        fused_qkv=None,
-        attn_layout=None,
     ):
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
@@ -55,25 +53,7 @@ class BertConfig:
         # embedding table transposed — halves the vocab-sized params and
         # removes one [h, V] Adam update per step)
         self.tie_mlm_weights = tie_mlm_weights
-        # one [h, 3h] projection + split instead of three [h, h] matmuls.
-        # default OFF: measured r3 on v5e it LOSES (168.3k vs 188.2k
-        # tok/s) — the 3-way split materializes layout copies that the
-        # separate matmuls' outputs avoid (XLA fuses each directly into
-        # the head-split transpose)
-        import os as _os
-
-        # explicit constructor arg wins; the env var only fills the
-        # default (same precedence as attn_layout below). Default OFF:
-        # measured r3 it LOSES under default layouts (split copies)
-        if fused_qkv is None:
-            fused_qkv = _os.environ.get("PADDLE_TPU_FUSED_QKV") == "1"
-        self.fused_qkv = bool(fused_qkv)
         self.recompute = recompute
-        # attention op layout: "bshd" (default — zero head transposes in
-        # the graph) or "bhsd"; PADDLE_TPU_ATTN_LAYOUT overrides for A/B
-        self.attn_layout = (
-            attn_layout or _os.environ.get("PADDLE_TPU_ATTN_LAYOUT")
-            or "bshd")
 
     @staticmethod
     def base():
@@ -116,46 +96,28 @@ def _attention(x, attn_bias, cfg, name, is_test=False):
     b, s, h = x.shape
     nh = cfg.num_heads
     dh = cfg.hidden_size // nh
-    if getattr(cfg, "fused_qkv", False):
-        qkv = _fc(x, 3 * cfg.hidden_size, name + ".qkv", cfg,
-                  tp_spec=P(None, "tp"), bias_tp=P("tp"))
-        q, k, v = layers.split(qkv, 3, dim=2)
-    else:
-        q = _fc(x, cfg.hidden_size, name + ".q", cfg,
-                tp_spec=P(None, "tp"), bias_tp=P("tp"))
-        k = _fc(x, cfg.hidden_size, name + ".k", cfg,
-                tp_spec=P(None, "tp"), bias_tp=P("tp"))
-        v = _fc(x, cfg.hidden_size, name + ".v", cfg,
-                tp_spec=P(None, "tp"), bias_tp=P("tp"))
+    q = _fc(x, cfg.hidden_size, name + ".q", cfg,
+            tp_spec=P(None, "tp"), bias_tp=P("tp"))
+    k = _fc(x, cfg.hidden_size, name + ".k", cfg,
+            tp_spec=P(None, "tp"), bias_tp=P("tp"))
+    v = _fc(x, cfg.hidden_size, name + ".v", cfg,
+            tp_spec=P(None, "tp"), bias_tp=P("tp"))
 
     if cfg.use_flash_attention:
         # bshd layout: the fused op consumes the head-split RESHAPE
         # directly, so the graph has zero head transposes — the round-4
         # xplane showed each [b,s,h,d] transpose materializes as an HBM
         # relayout copy (~0.15 ms x 3 tensors x 12 layers on BERT-base)
-        layout = getattr(cfg, "attn_layout", "bshd")
-        if layout == "bshd":
-            qh = layers.reshape(q, [b, s, nh, dh])
-            kh = layers.reshape(k, [b, s, nh, dh])
-            vh = layers.reshape(v, [b, s, nh, dh])
-        else:
-            qh = layers.transpose(
-                layers.reshape(q, [b, s, nh, dh]), [0, 2, 1, 3])
-            kh = layers.transpose(
-                layers.reshape(k, [b, s, nh, dh]), [0, 2, 1, 3])
-            vh = layers.transpose(
-                layers.reshape(v, [b, s, nh, dh]), [0, 2, 1, 3])
+        qh = layers.reshape(q, [b, s, nh, dh])
+        kh = layers.reshape(k, [b, s, nh, dh])
+        vh = layers.reshape(v, [b, s, nh, dh])
         # one Pallas kernel: scores/softmax/dropout never hit HBM
         ctxv = layers.fused_multihead_attention(
             qh, kh, vh, key_bias=attn_bias, sm_scale=1.0 / math.sqrt(dh),
             attn_dropout=cfg.attention_dropout if not is_test else 0.0,
-            is_test=is_test, layout=layout,
+            is_test=is_test, layout="bshd",
         )
-        if layout == "bshd":
-            merged = layers.reshape(ctxv, [b, s, h])
-        else:
-            merged = layers.reshape(
-                layers.transpose(ctxv, [0, 2, 1, 3]), [b, s, h])
+        merged = layers.reshape(ctxv, [b, s, h])
     else:
         def heads(t):
             r = layers.reshape(t, [b, s, nh, dh])

@@ -1,6 +1,5 @@
 """DeepFM + Wide&Deep CTR models (the reference's CTR workloads:
-`unittests/dist_ctr.py`, `incubate/fleet/tests/fleet_deep_ctr.py`;
-BASELINE.json DeepFM config).
+`unittests/dist_ctr.py`, `incubate/fleet/tests/fleet_deep_ctr.py`).
 
 Sparse slots are dense [batch, max_len] int64 id arrays (padding id 0 —
 LoD → padded, SURVEY.md §5); embedding bags are mean-pooled over the slot

@@ -1,5 +1,5 @@
-"""ResNet-50 (the reference's image_classification workload; BASELINE.json
-ResNet-50 ImageNet config). NCHW; bottlenecks with the stride on the 3x3
+"""ResNet-50 (the reference's image_classification workload; the
+benchmark's resnet50_b128 cell). NCHW; bottlenecks with the stride on the 3x3
 convolution ("v1.5"), like the reference model zoo.
 """
 

@@ -1,5 +1,5 @@
-"""Transformer-base encoder-decoder for WMT16 en-de (BASELINE.json config;
-reference workload: tests' dist_transformer.py / the Fluid transformer
+"""Transformer-base encoder-decoder for WMT16 en-de (the benchmark's
+transformer_base_s64 cell; reference workload: tests' dist_transformer.py / the Fluid transformer
 model). Shares the attention building blocks with BERT; adds causal self-
 attention + cross attention in the decoder."""
 
@@ -53,13 +53,6 @@ class TransformerConfig:
                 f"(got {src_vocab} vs {trg_vocab})"
             )
         self.weight_sharing = weight_sharing
-        # attention op layout (see models/bert.py): "bshd" keeps the
-        # graph free of head transposes; PADDLE_TPU_ATTN_LAYOUT overrides
-        import os as _os
-
-        self.attn_layout = _os.environ.get(
-            "PADDLE_TPU_ATTN_LAYOUT") or "bshd"
-
     @staticmethod
     def base():
         return TransformerConfig()
@@ -107,29 +100,16 @@ def _mha(q_in, kv_in, bias, cfg, name, is_test, key_bias=None, causal=False,
         # bshd: the fused op takes the head-split reshape directly — no
         # head transposes in the graph (the round-4 xplane showed 26% of
         # transformer device time in exactly these relayout copies)
-        layout = getattr(cfg, "attn_layout", "bshd")
-        if layout == "bshd":
-            qh = layers.reshape(q, [b, sq, nh, dh])
-            kh = layers.reshape(k, [b, sk, nh, dh])
-            vh = layers.reshape(v, [b, sk, nh, dh])
-        else:
-            qh = layers.transpose(
-                layers.reshape(q, [b, sq, nh, dh]), [0, 2, 1, 3])
-            kh = layers.transpose(
-                layers.reshape(k, [b, sk, nh, dh]), [0, 2, 1, 3])
-            vh = layers.transpose(
-                layers.reshape(v, [b, sk, nh, dh]), [0, 2, 1, 3])
+        qh = layers.reshape(q, [b, sq, nh, dh])
+        kh = layers.reshape(k, [b, sk, nh, dh])
+        vh = layers.reshape(v, [b, sk, nh, dh])
         out = layers.fused_multihead_attention(
             qh, kh, vh, key_bias=key_bias, causal=causal,
             sm_scale=1.0 / math.sqrt(dh),
             attn_dropout=cfg.dropout if not is_test else 0.0,
-            is_test=is_test, layout=layout,
+            is_test=is_test, layout="bshd",
         )
-        if layout == "bshd":
-            merged = layers.reshape(out, [b, sq, cfg.d_model])
-        else:
-            merged = layers.reshape(
-                layers.transpose(out, [0, 2, 1, 3]), [b, sq, cfg.d_model])
+        merged = layers.reshape(out, [b, sq, cfg.d_model])
     else:
         def split(t, s):
             return layers.transpose(

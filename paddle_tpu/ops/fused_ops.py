@@ -99,10 +99,6 @@ def _flash_score_bytes() -> int:
     return int(attn_dispatch_thresholds()["flash_min_score_bytes"])
 
 
-# legacy alias read by older tools; the env override is authoritative
-FLASH_SCORE_BYTES = _flash_score_bytes()
-
-
 def _use_flash(q, k):
     """Score-bytes knee OR the table's measured seq floor — the
     longseq_study decision: default-ON above the threshold. An explicit

@@ -10,8 +10,6 @@ chains fuse into the surrounding matmuls (HBM-bandwidth-friendly).
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -209,8 +207,6 @@ _simple_unary("softshrink", lambda x: jnp.sign(x) * jnp.maximum(jnp.abs(x) - 0.5
 def _gelu(ctx, op):
     x = ctx.in_(op, "X")
     approximate = bool(op.attr("approximate", False))
-    if os.environ.get("PADDLE_TPU_GELU_TANH") == "1":
-        approximate = True
     ctx.out(op, "Out", jax.nn.gelu(x, approximate=approximate))
 
 

@@ -63,8 +63,8 @@ counters (pass_<name>_us, pass_<name>_ops_removed, program_ops_before/
 _after) in the style of the dygraph_jit_* counters.
 
 `cache_signature()` names the resolved pass set plus each pass's
-implementation version — bench.py keys its resumable partial results on
-it, so numbers measured under different rewrite semantics never merge.
+implementation version, so that numbers measured under different rewrite
+semantics can be told apart.
 
 Verifier contract (PADDLE_TPU_VERIFY): when the env var is truthy
 (default-on under pytest via tests/conftest.py; any of ""/"0"/"off"/

@@ -38,9 +38,8 @@ inference programs, where only the exact forward runs).
 Stats ride on the program as `program._layout_opt_stats`
 {removed, inserted, remaining, converted_ops} and the always-on
 counters `pass_layout_opt_transposes_removed`, `transpose_ops_before`,
-`transpose_ops_after` (bench.py reports them per workload;
-tools/bench_passes.py --guard pins the elimination fraction >= 80% on a
-canned ResNet block).
+`transpose_ops_after` (tools/bench_passes.py --guard pins the
+elimination fraction >= 80% on a canned ResNet block).
 """
 
 from __future__ import annotations

@@ -78,7 +78,7 @@ installed, fires deterministic faults at those sites:
                                degraded, serve from the overflow
                                class, and recover when the primary
                                respawns)
-      trainer.step             executor.py/compiler.py, once per
+      trainer.step             executor.py, once per
                                completed EXECUTOR DISPATCH (state
                                written back, before the snapshot hook)
                                — startup and eval programs hit it too,

@@ -486,8 +486,8 @@ class CheckpointManager:
         bumps a per-manager counter and snapshots on the should_save
         cadence — training loops need no checkpoint code at all. Covers
         Executor.run, run_repeated (counter advances by the whole scan
-        window), and the CompiledProgram/fleet mesh paths (compiler.py);
-        `program` may be a Program or a CompiledProgram. Returns self
+        window), over one device and over a CompiledProgram's or a fleet
+        strategy's mesh alike (one step path, executor.py); `program` may be a Program or a CompiledProgram. Returns self
         (chainable after restore_or_initialize)."""
         program._ckpt_manager = self
         return self
@@ -506,8 +506,8 @@ class CheckpointManager:
         self._autosave_suspended = False
 
     def _on_executor_step(self, program, scope, executor, steps=1):
-        """Called by the executor after state write-back (executor.py run,
-        run_repeated, and the CompiledProgram path in compiler.py).
+        """Called by the executor after state write-back (executor.py
+        `_step_boundary`, for run and run_repeated, mesh or not).
         `steps` > 1 covers one dispatch that advanced several training
         steps (run_repeated's on-device scan): the counter advances by
         all of them and one snapshot of the FINAL state lands if any

@@ -43,7 +43,7 @@ _ENDPOINTS — single- or multi-process):
 
 Chaos sites (resilience.faults; seed-pinned, cross-process):
 
-- `trainer.step` (worker, executor.py/compiler.py): fires once per
+- `trainer.step` (worker, executor.py): fires once per
   completed executor DISPATCH (startup/eval included — `nth=` counts
   dispatches, not training steps; use fleet.kill_trainer below to pin
   a training step) — `raises=` is a crash there, `hold=` wedges the

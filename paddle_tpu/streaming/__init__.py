@@ -13,7 +13,7 @@ predictor bundle.
   OnlineTrainer        — the click-stream device-worker loop with the
                          stream.click chaos site (online_trainer.py)
   zipf_ids/click_stream— THE seeded Zipf id/click generators every
-                         streaming drill shares (bench.py delegates)
+                         streaming drill shares
   export_int8_model    — QAT/PTQ/plain program -> int8 predictor
                          bundle, self-verifying (export_int8.py)
 """

@@ -24,8 +24,8 @@ worker loop) and adds the streaming contract:
   train-while-serve arrangement (training on a background thread while
   the caller measures the serving side).
 
-`zipf_ids` is THE seeded Zipf id generator for every streaming drill
-(bench.py `_zipf_ids` delegates here): ids are drawn by inverse-CDF
+`zipf_ids` is THE seeded Zipf id generator for every streaming drill:
+ids are drawn by inverse-CDF
 over the truncated zipf(s) mass on [0, vocab), so the same
 (seed, vocab, s) always yields the same hot set — rank r has mass
 proportional to 1/(r+1)^s, id 0 hottest.

@@ -207,11 +207,12 @@ def test_server_device_tpu_fails_without_a_tpu(tmp_path):
     assert "default JAX backend is 'cpu'" in p.stderr
 
 
-def test_bench_fails_without_a_tpu():
-    p = _run(["bench.py"])
+def test_benchmark_run_fails_without_a_tpu():
+    p = _run(["benchmark/run.py", "--workload", "bert_base_s128",
+              "--seed", "1", "--seconds", "1"])
     assert p.returncode != 0
-    line = json.loads(p.stdout.strip().splitlines()[-1])
-    assert line["value"] == 0.0 and "'cpu'" in line["error"]
+    assert "there is no CPU fallback" in p.stderr
+    assert not p.stdout.strip().splitlines()[-1].startswith("{")
 
 
 def test_chip_smoke_fails_without_a_chip_and_prints_no_result():
@@ -238,3 +239,25 @@ def test_chip_smoke_rehearsal_passes_and_fills_the_named_cache(tmp_path):
         assert any(ln.startswith(phase) for ln in lines), phase
     # entries land where the environment said, not in the checkout
     assert len(os.listdir(cache)) > 0
+
+
+# ------------------------------------------------------------ the switches
+
+
+def test_readme_table_names_every_paddle_tpu_variable_of_the_package():
+    """A `PADDLE_TPU_*` name under `paddle_tpu/` is a switch someone can
+    flip: README's table lists each one, so a new one shows in review."""
+    import re
+
+    found = set()
+    for dirpath, _, files in os.walk(os.path.join(REPO, "paddle_tpu")):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(dirpath, f)) as fh:
+                    found.update(re.findall(r"PADDLE_TPU_[A-Z0-9_]+",
+                                            fh.read()))
+    with open(os.path.join(REPO, "README.md")) as fh:
+        listed = re.findall(r"^\| `(PADDLE_TPU_[A-Z0-9_]+)` \|", fh.read(),
+                            re.M)
+    assert len(listed) == len(set(listed))
+    assert set(listed) == found

@@ -275,11 +275,47 @@ def test_run_repeated_compiled_program_mesh():
     np.testing.assert_allclose(stacked.reshape(5), seq, rtol=1e-6)
 
 
+def test_run_repeated_fleet_strategy_mesh():
+    """A program that carries a fleet strategy is unwrapped onto the
+    strategy's mesh by run_repeated as it is by run(): one window equals
+    the sequential mesh runs."""
+    from paddle_tpu.incubate.fleet.base.role_maker import (
+        Role,
+        UserDefinedRoleMaker,
+    )
+    from paddle_tpu.incubate.fleet.collective import (
+        DistributedStrategy,
+        fleet,
+    )
+
+    fleet.init(UserDefinedRoleMaker(0, Role.WORKER, worker_num=1))
+    x = fluid.layers.data("x", [8, 4], append_batch_size=False)
+    loss = fluid.layers.reduce_mean(
+        fluid.layers.square(fluid.layers.fc(x, 8, act="relu")))
+    fleet.distributed_optimizer(
+        fluid.optimizer.SGD(0.05), DistributedStrategy()).minimize(loss)
+    main = fluid.default_main_program()
+    startup = fluid.default_startup_program()
+    feed = {"x": np.random.RandomState(1).randn(8, 4).astype("float32")}
+
+    seq_scope, win_scope = fluid.Scope(), fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=seq_scope)
+    seq = [exe.run(main, feed=feed, fetch_list=[loss], scope=seq_scope)[0]
+           for _ in range(4)]
+    exe2 = fluid.Executor(fluid.CPUPlace())
+    exe2.run(startup, scope=win_scope)
+    (stacked,) = exe2.run_repeated(main, feed=feed, fetch_list=[loss],
+                                   steps=4, scope=win_scope)
+    assert list(exe2._cache.values())[-1].mesh.devices.size == 8
+    np.testing.assert_allclose(
+        stacked.reshape(4), np.asarray(seq).reshape(4), rtol=1e-6)
+
+
 def test_run_repeated_under_xla_options(monkeypatch):
     """PADDLE_TPU_XLA_OPTIONS reaches every top-level jit, and JAX 0.9.0
     refuses compiler_options on a nested one: run_repeated traces the
-    step inside its own jit, on the plain and on the mesh path (bench.py
-    runs every timed window this way with TPU options set)."""
+    step inside its own jit, on the plain and on the mesh path."""
     import numpy as np
 
     import paddle_tpu as fluid
@@ -346,7 +382,26 @@ def test_run_repeated_microbatched_program():
     np.testing.assert_allclose(stacked.reshape(4), seq, rtol=1e-6)
 
 
-def test_executor_compile_cache_lru_eviction_recompiles(monkeypatch):
+# One step path (PR 29): what Executor.run does for a Program it does for
+# a CompiledProgram over a mesh, in the same functions. Each case below
+# runs both ways; the mesh is two CPU devices on the batch axis.
+PATHS = ["plain", "mesh"]
+
+
+def _on(path, program):
+    if path == "plain":
+        return program
+    return fluid.CompiledProgram(program).with_data_parallel(places=2)
+
+
+def _compiles():
+    from paddle_tpu import profiler
+
+    return profiler.counters().get("program_compile_count", 0)
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_executor_compile_cache_lru_eviction_recompiles(monkeypatch, path):
     """The executor's compiled-program cache — which holds the serving
     coalescer's one-warm-executable-per-shape-bucket set — is LRU-
     bounded by the same PADDLE_TPU_JIT_CACHE_CAP knob as the dygraph
@@ -360,11 +415,11 @@ def test_executor_compile_cache_lru_eviction_recompiles(monkeypatch):
     y = fluid.layers.fc(x, 3, act="softmax")
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(fluid.default_startup_program())
-    prog = fluid.default_main_program().clone(for_test=True)
+    prog = _on(path, fluid.default_main_program().clone(for_test=True))
 
     rng = np.random.RandomState(0)
     xa = rng.rand(2, 4).astype("float32")
-    xb = rng.rand(5, 4).astype("float32")
+    xb = rng.rand(6, 4).astype("float32")
 
     def run(arr):
         return np.asarray(
@@ -375,10 +430,87 @@ def test_executor_compile_cache_lru_eviction_recompiles(monkeypatch):
     run(xb)  # cap 1 -> evicts the shape-A executable
     assert len(exe._cache) == 1
     assert profiler.counters()["executor_cache_evictions"] >= e0 + 1
-    c0 = profiler.counters().get("program_compile_count", 0)
+    c0 = _compiles()
     ya2 = run(xa)  # recompiles (it was evicted), bitwise-equal
-    assert profiler.counters()["program_compile_count"] == c0 + 1
+    assert _compiles() == c0 + 1
     np.testing.assert_array_equal(ya2, ya)
+
+
+def _small_train_step():
+    x = fluid.layers.data("x", [4])
+    loss = fluid.layers.reduce_mean(
+        fluid.layers.square(fluid.layers.fc(x, 3)))
+    fluid.optimizer.SGD(0.1).minimize(loss)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    feed = {"x": np.random.RandomState(0).rand(4, 4).astype("float32")}
+    return exe, fluid.default_main_program(), feed, loss
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_amp_dtype_flipped_after_a_run_recompiles(path):
+    """`_amp_dtype` rides on the program without bumping its version:
+    it is part of the one cache key, so a program flipped to bf16 after
+    a float32 run is not served the float32 step."""
+    exe, main, feed, loss = _small_train_step()
+    prog = _on(path, main)
+    exe.run(prog, feed=feed, fetch_list=[loss])
+    c0 = _compiles()
+    exe.run(prog, feed=feed, fetch_list=[loss])
+    assert _compiles() == c0  # the same key: served from the cache
+    main._amp_dtype = "bfloat16"
+    exe.run(prog, feed=feed, fetch_list=[loss])
+    assert _compiles() == c0 + 1
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_check_nan_inf_flipped_between_runs_recompiles(monkeypatch, path):
+    """The flag changes the step's outputs (one flag per op), so it is in
+    the key; and the recompiled step does name the op that made the NaN."""
+    exe, main, feed, loss = _small_train_step()
+    prog = _on(path, main)
+    exe.run(prog, feed=feed, fetch_list=[loss])
+    c0 = _compiles()
+    monkeypatch.setenv("PADDLE_TPU_CHECK_NAN_INF", "1")
+    exe.run(prog, feed=feed, fetch_list=[loss])
+    assert _compiles() == c0 + 1
+    bad = {"x": np.full((4, 4), np.nan, "float32")}
+    with pytest.raises(RuntimeError, match="nan/inf detected"):
+        exe.run(prog, feed=bad, fetch_list=[loss])
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_read_persistable_without_a_value_raises(path):
+    """A persistable the step reads and the scope no longer holds is an
+    error that names the startup program, never a silent scalar zero."""
+    exe, main, feed, loss = _small_train_step()
+    prog = _on(path, main)
+    exe.run(prog, feed=feed, fetch_list=[loss])
+    fluid.global_scope().set(main.all_parameters()[0].name, None)
+    with pytest.raises(RuntimeError, match="run the startup program"):
+        exe.run(prog, feed=feed, fetch_list=[loss])
+
+
+def test_compiler_module_knows_nothing_of_the_executor():
+    """The arrow points one way: executor.py imports compiler.py at the
+    top, and compiler.py neither imports the executor nor reaches into
+    one."""
+    import ast
+    import inspect
+
+    from paddle_tpu import compiler, executor
+
+    src = inspect.getsource(compiler)
+    for node in ast.walk(ast.parse(src)):
+        names = []
+        if isinstance(node, ast.ImportFrom):
+            names = [node.module or ""] + [a.name for a in node.names]
+        elif isinstance(node, ast.Import):
+            names = [a.name for a in node.names]
+        assert not any("executor" in n for n in names), ast.dump(node)
+    assert "executor._" not in src and "executor import" not in src
+    assert executor.CompiledProgram is compiler.CompiledProgram
+    assert not hasattr(compiler.CompiledProgram, "_run")
 
 
 def test_state_keeps_the_default_layout_and_a_second_jit_reads_it(monkeypatch):
