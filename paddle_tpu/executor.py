@@ -35,6 +35,7 @@ from .framework import (
 )
 from .ops.registry import JNP_DTYPE, LoweringContext, lower_block, lower_op
 from .place import CPUPlace, Place, TPUPlace
+from . import profiler
 from .profiler import RecordEvent
 from .resilience.faults import fault_point
 from .scope import Scope, global_scope
@@ -120,9 +121,35 @@ def _as_feed_array(value, dtype=None):
     return arr
 
 
+def _seed_words(program, counter) -> np.ndarray:
+    """What a dispatch hands the step for its PRNG key: `[base, counter]`
+    as two uint32 words of HOST memory. `jit` copies them to every device
+    of the step; an array made with `jnp` or `jax.random` would be born
+    on device 0, behind the step running there, and on a mesh the
+    dispatch would wait for it (PERF.md, Findings, PR 30)."""
+    base = program.random_seed or 42
+    return np.array([base & 0xFFFFFFFF, counter & 0xFFFFFFFF], np.uint32)
+
+
+def _step_key(seed):
+    """Inside the compiled step, the key of tick `counter`:
+    `fold_in(key(base), counter)`, bit for bit what the executor used to
+    fold before the dispatch (`key` of a Python int keeps its low 32
+    bits). A typed key is taken as it is: callers that lower a step ahead
+    of time (tools, the benchmark's compile test) hand it one."""
+    if jnp.issubdtype(seed.dtype, jax.dtypes.prng_key):
+        return seed
+    return jax.random.fold_in(jax.random.key(seed[0]), seed[1])
+
+
 class _CompiledStep:
-    def __init__(self, step, jit_kwargs, state_names, feed_names,
+    def __init__(self, lowered, jit_kwargs, state_names, feed_names,
                  fetch_names):
+        # named `step`: the trace's `jit(step)/<phase>/<op>` scopes and
+        # its `PjitFunction(step)` host event carry the name
+        def step(state, feeds, seed):
+            return lowered(state, feeds, _step_key(seed))
+
         self.fn = _jit(step, **jit_kwargs)
         # the same step for tracing inside another jit (run_repeated's
         # scan): JAX accepts compiler_options on a top-level jit only, so
@@ -131,6 +158,9 @@ class _CompiledStep:
         self.state_names = state_names
         self.feed_names = feed_names
         self.fetch_names = fetch_names
+        # name -> the NamedSharding the step reads that feed with; a mesh
+        # compile fills it, one device has none to state
+        self.feed_shardings: dict = {}
 
 
 def _instrument_compiled(compiled, block):
@@ -140,8 +170,6 @@ def _instrument_compiled(compiled, block):
     pays trace+lower+XLA-compile — lands its wall time in
     program_trace_ms. Steady-state calls pay one flag check."""
     import time as _time
-
-    from . import profiler
 
     profiler.bump_counter("program_compile_count")
     profiler.bump_counter("program_traced_ops", len(block.ops))
@@ -739,8 +767,6 @@ class Executor:
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as P
 
-            from . import profiler
-
             extra_specs = dict(pipe_specs)
             if zero1:
                 extra_specs.update(mesh_mod.zero1_accumulators(
@@ -890,20 +916,20 @@ class Executor:
     def _dispatch(self, program, compiled, state, feeds):
         """Enqueue one step."""
         with RecordEvent("pt.exe.dispatch"):
-            # functional PRNG: fold in a per-run counter so randomness
-            # varies across steps; with program.random_seed set the whole
-            # sequence is reproducible from run 0 (reference:
-            # Program.random_seed semantics)
-            base = program.random_seed or 42
-            rng = jax.random.fold_in(jax.random.key(base),
-                                     self._seed_counter + 1)
+            # functional PRNG: the step folds a per-run counter into the
+            # program's seed so randomness varies across steps; with
+            # program.random_seed set the whole sequence is reproducible
+            # from run 0 (reference: Program.random_seed semantics). The
+            # seed is read at each dispatch and travels as an argument: a
+            # seed changed between two runs meets no stale executable
+            seed = _seed_words(program, self._seed_counter + 1)
 
             # chaos site: a raise here is a device/runtime failure at the
             # dispatch boundary (before any executor-visible mutation — the
             # seed counter only advances once the step actually dispatched,
             # so a caught-and-retried failure replays the same PRNG tick)
             fault_point("executor.dispatch")
-            result = compiled.fn(state, feeds, rng)
+            result = compiled.fn(state, feeds, seed)
         self._seed_counter += 1
         return result
 
@@ -1027,7 +1053,6 @@ class Executor:
             # processes
             compiled.mesh = mesh
             self._cache[key] = compiled
-            from . import profiler
             from .dygraph.jit import _jit_cache_cap
 
             while len(self._cache) > _jit_cache_cap(256):
@@ -1038,7 +1063,22 @@ class Executor:
         else:
             self._cache.move_to_end(key)
         if mesh is None or jax.process_count() == 1:
-            feeds = {name: jnp.asarray(arr) for name, arr in feed_items}
+            # where the next batches are best put: the reader's stager
+            # reads it at each batch (reader/stager.py::stage_feed). A
+            # hint, so whatever arrives laid out otherwise is resharded
+            # here, and counted: on a mesh that reshard runs on device 0
+            # behind the step before, and the dispatch waits for it
+            program._feed_shardings = want = compiled.feed_shardings
+            feeds = {}
+            for name, arr in feed_items:
+                sharding = want.get(name)
+                if not isinstance(arr, jax.Array):
+                    arr = (jnp.asarray(arr) if sharding is None
+                           else jax.device_put(arr, sharding))
+                elif sharding is not None and arr.sharding != sharding:
+                    profiler.bump_counter("feed_reshard_at_dispatch")
+                    arr = jax.device_put(arr, sharding)
+                feeds[name] = arr
         else:
             # multi-process (fleet) execution: each trainer feeds its
             # process-LOCAL batch shard (the reference's trainers read
@@ -1151,10 +1191,7 @@ class Executor:
                 "(the scan carry needs stable shapes)"
             )
 
-        base = program.random_seed or 42
-        counter0 = self._seed_counter + 1
-
-        multi_key = (id(compiled), steps, base)
+        multi_key = (id(compiled), steps)
         multi = self._multi_cache.get(multi_key)
         if multi is None:
             # the step's nested jit (inlines under the outer one), never
@@ -1163,12 +1200,10 @@ class Executor:
             # the real first dispatch
             step_fn = compiled.nested_fn
 
-            def multi(state, feeds, counter):
-                rng0 = jax.random.key(base)
-
+            def multi(state, feeds, seed):
                 def body(st, i):
                     fetches, new_state = step_fn(
-                        st, feeds, jax.random.fold_in(rng0, counter + i)
+                        st, feeds, seed.at[1].add(i.astype(seed.dtype))
                     )
                     return new_state, tuple(fetches)
 
@@ -1184,7 +1219,7 @@ class Executor:
             self._multi_cache[multi_key] = multi
 
         stacked, new_state = multi(
-            state, feeds, jnp.asarray(counter0, jnp.int32)
+            state, feeds, _seed_words(program, self._seed_counter + 1)
         )
         # advance only on success: a failed trace must not skip PRNG
         # counters (the N-consecutive-run() equivalence contract)
@@ -1214,11 +1249,10 @@ class Executor:
         # transfer overlaps compute — the role of the reference's
         # buffered_reader (operators/reader/buffered_reader.cc) on the
         # dataset path.
-        import jax.numpy as _jnp
+        from .reader.stager import DeviceStager, stage_feed
 
-        from .reader.stager import DeviceStager
-
-        block = self._unwrap(program)[0].global_block()
+        stepped = self._unwrap(program)[0]  # the Program the feeds belong to
+        block = stepped.global_block()
 
         # multi-process fleet programs rebuild feeds with
         # make_array_from_process_local_data from HOST arrays
@@ -1230,13 +1264,10 @@ class Executor:
             out = {}
             for k, v in feed.items():
                 var = block._find_var_recursive(k)
-                arr = _as_feed_array(
+                out[k] = _as_feed_array(
                     v, var.dtype if var is not None else None
                 )
-                if to_device and not isinstance(arr, jax.Array):
-                    arr = jax.device_put(_jnp.asarray(arr))
-                out[k] = arr
-            return out
+            return stage_feed(out, stepped) if to_device else out
 
         stager = DeviceStager(dataset.batches(num_threads), _stage, depth=2)
         try:

@@ -329,14 +329,17 @@ class DataLoader:
             return
 
         # device double-buffer via the shared stager thread
-        # (reader/stager.py): the producer converts, the stager
-        # device_puts `depth` batches ahead, and the consumer thread only
-        # dispatches — host convert AND the H2D transfer overlap the
-        # running step (the old in-loop device_put serialized the put
-        # with the step dispatch on the consumer thread)
-        import jax
+        # (reader/stager.py): the producer converts, the stager puts
+        # `depth` batches ahead where the step compiled for the feed
+        # variables' Program will read them (`stage_feed`: the default
+        # device, or each shard's device once a mesh step was compiled),
+        # and the consumer thread only dispatches. Host convert AND the
+        # H2D transfers overlap the running step, and the dispatch finds
+        # every array laid out as the step wants it.
+        from .stager import DeviceStager, stage_feed
 
-        from .stager import DeviceStager
+        program = (self._feeder.feed_vars[0].block.program
+                   if self._feed_list else None)
 
         def _source():
             while True:
@@ -349,7 +352,7 @@ class DataLoader:
 
         def _to_device(item):
             idx, feed = item
-            return idx, {k: jax.device_put(v) for k, v in feed.items()}
+            return idx, stage_feed(feed, program)
 
         stager = DeviceStager(_source(), _to_device, depth=2)
         try:
