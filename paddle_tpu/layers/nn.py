@@ -10,7 +10,7 @@ from __future__ import annotations
 import numpy as np
 
 from ..framework import Variable, unique_name
-from ..initializer import Constant, Normal, Xavier
+from ..initializer import Constant, Normal, Uniform, Xavier
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
@@ -69,6 +69,10 @@ __all__ = [
     "crop",
     "fc",
     "moe",
+    "moe_experts",
+    "rms_norm",
+    "short_conv1d",
+    "kda_attention",
     "embedding",
     "conv2d",
     "conv2d_transpose",
@@ -247,6 +251,20 @@ def fc(
     return helper.append_activation(pre_act)
 
 
+def _suffixed_attr(param_attr, suffix, **overrides):
+    """One param_attr names several parameters: a copy for each with the
+    name suffixed, so a named ParamAttr doesn't silently alias them onto
+    one variable, and with `overrides` set on it."""
+    import copy
+
+    a = copy.copy(ParamAttr._to_attr(param_attr))
+    if a and a.name:
+        a.name = f"{a.name}.{suffix}"
+    for key, value in overrides.items():
+        setattr(a, key, value)
+    return a
+
+
 def moe(
     input,
     num_experts,
@@ -268,15 +286,7 @@ def moe(
     d = int(input.shape[-1])
 
     def pattr(suffix):
-        # one param_attr names FIVE parameters: suffix each so a named
-        # ParamAttr doesn't silently alias them onto one variable
-        a = ParamAttr._to_attr(param_attr)
-        if a and a.name:
-            import copy
-
-            a = copy.copy(a)
-            a.name = f"{a.name}.{suffix}"
-        return a
+        return _suffixed_attr(param_attr, suffix)
 
     gate = helper.create_parameter(pattr("gate"), [d, num_experts],
                                    dtype=input.dtype)
@@ -640,6 +650,107 @@ def layer_norm(
         attrs={"epsilon": epsilon, "begin_norm_axis": begin_norm_axis},
     )
     return helper.append_activation(out)
+
+
+def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
+             name=None):
+    """`x / sqrt(mean(x^2) + epsilon) * scale` over the axes from
+    `begin_norm_axis` on, with a learned scale seeded at 1 and no shift
+    (arXiv:1910.07467)."""
+    helper = LayerHelper("rms_norm", name=name)
+    scale = helper.create_parameter(
+        param_attr, [int(np.prod(input.shape[begin_norm_axis:]))],
+        dtype="float32", default_initializer=Constant(1.0))
+    return _single_out(
+        helper, "rms_norm", {"X": [input], "Scale": [scale]},
+        {"epsilon": epsilon, "begin_norm_axis": begin_norm_axis},
+        dtype=input.dtype, shape=input.shape, out_slot="Y")
+
+
+def short_conv1d(input, width=4, param_attr=None, name=None):
+    """Causal depthwise convolution over time, zero state at the start of
+    a sequence, no bias, then SiLU: input [b, s, c], filter [c, width],
+    `out_t = SiLU(sum_i filter[:, i] * input_{t-width+1+i})`."""
+    helper = LayerHelper("short_conv1d", name=name)
+    w = helper.create_parameter(
+        param_attr, [int(input.shape[-1]), width], dtype="float32")
+    return _single_out(
+        helper, "short_conv1d", {"X": [input], "Filter": [w]}, {},
+        dtype=input.dtype, shape=input.shape)
+
+
+def kda_attention(q, k, v, g, beta, num_heads, l2norm_epsilon=1e-6,
+                  a_log_attr=None, dt_bias_attr=None, name=None):
+    """Kimi Delta Attention over one sequence a row (ops/linear_attn_ops.py
+    has the equations): q, k, g [b, s, h*dk], v [b, s, h*dv], beta
+    [b, s, h]. `q` and `k` are L2-normalised per head, `g` goes through
+    `-exp(A_log) * softplus(g + dt_bias)` to the log of the per-channel
+    decay and `beta` through a sigmoid, all in float32 inside the op; the
+    output is scaled by `dk^-1/2` and the state is zero at the start of a
+    row. Creates `A_log` [h] and `dt_bias`
+    [h*dk]. Returns [b, s, h*dv]."""
+    helper = LayerHelper("kda_attention", name=name)
+    # the public KDA layer's seeding: A in [1, 16] and the step
+    # softplus(dt_bias) in [0.001, 0.1], here both log-uniform
+    a_log = helper.create_parameter(
+        a_log_attr, [num_heads], dtype="float32",
+        default_initializer=Uniform(0.0, float(np.log(16.0))))
+    dt_bias = helper.create_parameter(
+        dt_bias_attr, [int(g.shape[-1])], dtype="float32",
+        default_initializer=Uniform(-6.9, -2.25))
+    return _single_out(
+        helper, "kda_attention",
+        {"Q": [q], "K": [k], "V": [v], "GRaw": [g], "BetaRaw": [beta],
+         "ALog": [a_log], "DtBias": [dt_bias]},
+        {"num_heads": num_heads, "l2norm_epsilon": l2norm_epsilon},
+        dtype=v.dtype, shape=v.shape)
+
+
+def moe_experts(input, experts_total, experts_held, d_ff, k, held_from=0,
+                scaling=1.0, renormalize=True, bias_scale=0.0,
+                param_attr=None, name=None):
+    """The held experts' part of a dropless expert layer (SiLU-gated
+    FFNs of width `d_ff`): a sigmoid router over all `experts_total`
+    picks `k` a token by `score + bias`, weights them
+    `scaling * score / sum of the selected scores`, and the assignments
+    to the `experts_held` experts from `held_from` on run through one
+    grouped product, every one of them, whatever the skew. What experts
+    held elsewhere would add is left out. `bias` is the router's
+    correction: persistable, seeded Normal(0, bias_scale), never
+    trained. Returns (out like input, load [experts_held] int32)."""
+    helper = LayerHelper("moe_experts", name=name)
+    d = int(input.shape[-1])
+
+    def pattr(suffix, **overrides):
+        return _suffixed_attr(param_attr, suffix, **overrides)
+
+    gate = helper.create_parameter(pattr("gate"), [d, experts_total],
+                                   dtype="float32")
+    bias = helper.create_parameter(
+        pattr("bias", trainable=False,
+              initializer=Normal(0.0, bias_scale) if bias_scale
+              else Constant(0.0)),
+        [experts_total], dtype="float32")
+    w_gate = helper.create_parameter(pattr("w_gate"), [experts_held, d, d_ff],
+                                     dtype="float32")
+    w_up = helper.create_parameter(pattr("w_up"), [experts_held, d, d_ff],
+                                   dtype="float32")
+    w_down = helper.create_parameter(pattr("w_down"), [experts_held, d_ff, d],
+                                     dtype="float32")
+    out = helper.create_variable_for_type_inference(input.dtype, input.shape)
+    load = helper.create_variable_for_type_inference(
+        "int32", (experts_held,), stop_gradient=True)
+    helper.append_op(
+        type="moe_experts",
+        inputs={"X": [input], "Gate": [gate], "Bias": [bias],
+                "WGate": [w_gate], "WUp": [w_up], "WDown": [w_down]},
+        outputs={"Out": [out], "Load": [load]},
+        attrs={"experts_total": int(experts_total),
+               "experts_held": int(experts_held), "held_from": int(held_from),
+               "k": int(k), "scaling": float(scaling),
+               "renormalize": bool(renormalize)},
+    )
+    return out, load
 
 
 def group_norm(
@@ -1147,6 +1258,9 @@ def fused_multihead_attention(
     the QKV head-split reshape produces, so the model graph carries NO
     head transposes (they otherwise materialize as HBM relayout copies).
 
+    `v`'s last dim may be narrower than `q`'s and `k`'s (latent attention:
+    192-wide scores, 128-wide values) and is then the output's.
+
     `key_bias` is an additive [b, sv_len] bias (0 keep / large-negative
     mask). The unfused equivalent is matmul+softmax+dropout+matmul — this
     layer replaces that chain with one kernel so the [s, s] scores never
@@ -1170,7 +1284,7 @@ def fused_multihead_attention(
             "layout": layout,
         },
         dtype=q.dtype,
-        shape=list(q.shape),
+        shape=list(q.shape[:-1]) + [v.shape[-1]],
     )
 
 

@@ -1,14 +1,17 @@
 """Reference workload models (the benchmark's configurations + the
 reference's test model zoo), built through the framework's own layers API —
 LeNet-5 (MNIST), ResNet (ImageNet), SE-ResNeXt, VGG, Transformer/BERT
-(WMT16 / pretrain), DeepFM (CTR)."""
+(WMT16 / pretrain), DeepFM (CTR), Kimi Linear (a share of an
+expert-parallel decoder)."""
 
 from . import (  # noqa: F401
     bert,
     deepfm,
+    kimi_linear,
     lenet,
     resnet,
     se_resnext,
     transformer,
     vgg,
 )
+from .kimi_linear import KimiLinearConfig, build_kimi_linear  # noqa: E402,F401

@@ -12,6 +12,7 @@ from . import (  # noqa: F401
     detection_ops,
     detection_train_ops,
     fused_ops,
+    linear_attn_ops,
     loss_ops,
     math_ops,
     misc_ops,

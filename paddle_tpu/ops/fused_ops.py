@@ -41,7 +41,16 @@ _logger = logging.getLogger(__name__)
 #          batch counts as much as length) or above the `flash_min_seq`
 #          of the checked-in table (ops/pallas/attn_dispatch_table.json).
 #          Measured on v5e at s=512: XLA 299 ms a step, the blocked kernel
-#          2,069: it pays only beyond the HBM knee.
+#          2,069: it pays only beyond the HBM knee. Measured at s=4,096 in
+#          a train step (Kimi Linear's latent layer, b=1, 32 heads, keys
+#          192 and values 128 wide, both padded to 256 lanes, causal): the
+#          kernel's four calls a step (forward twice, the gradient op runs
+#          it again; dq; dk and dv) take 20.7 ms, about 120 TFLOP/s on
+#          the work it does, which counts the masked half of the causal
+#          blocks, skipped by nothing, and the padding. XLA's path was not
+#          run there: its float32 scores of one row are 2.1 GB.
+#          Values narrower than the keys are zero-padded to the keys'
+#          width for this kernel alone (the others take them as they are).
 #   xla    _xla_attention everywhere else: the "bhsd" layout, the CPU, and
 #          every other mesh of several devices (tensor or pipeline
 #          parallel, a batch the axis does not divide: GSPMD cannot
@@ -141,11 +150,12 @@ def _flash_dispatch(qb, kb) -> str:
     return mode
 
 
-def _attn_dispatch(q, k, bshd, shards=1) -> str:
+def _attn_dispatch(q, k, bshd, shards=1, same_width=True) -> str:
     """"short", "flash" or "xla" for the rows of the op's q/k that one
     device holds, a `shards`-th of the batch: the short-sequence kernel
     takes from `auto`'s XLA side the shapes it is built for, in the layout
-    whose operands it can read in place."""
+    whose operands it can read in place, where the values are as wide as
+    the keys (`same_width`)."""
     def bhsd(t):
         b, h, s, d = t.shape
         if bshd:
@@ -156,6 +166,7 @@ def _attn_dispatch(q, k, bshd, shards=1) -> str:
     path = _flash_dispatch(qb, kb)
     _, nh, sq, dh = qb.shape
     if (path == "xla" and bshd and _dispatch_mode() == "auto"
+            and same_width
             and _use_pallas() and mha_short_viable(sq, kb.shape[2], nh, dh)):
         return "short"
     return path
@@ -167,6 +178,8 @@ def _fused_mha(ctx, op):
     [b, s, nh, dh] ("bshd" — the shape the model's QKV reshape produces,
     no head transposes anywhere in the graph); optional KeyBias: [b, sk]
     additive (0 keep, large-negative drop). Out matches the input layout.
+    V's last dim may be narrower than Q's and K's (latent attention), and
+    is then Out's.
 
     Replaces the unfused matmul->softmax->dropout->matmul chain
     (reference model pattern, e.g. the Fluid transformer/BERT models) with
@@ -200,7 +213,9 @@ def _fused_mha(ctx, op):
         # graph. Past the HBM knee where flash wins, sequence parallelism
         # (PADDLE_TPU_SP_MODE / the ring_min_seq auto-default) takes over
         # instead.
-        path = _attn_dispatch(q, k, bshd, shards) if shards else "xla"
+        dv = v.shape[-1]
+        path = (_attn_dispatch(q, k, bshd, shards, dv == q.shape[-1])
+                if shards else "xla")
         if shards > 1 and path != "short":
             path = "xla"
         profiler.bump_counter(f"attn_dispatch_{path}")
@@ -225,10 +240,18 @@ def _fused_mha(ctx, op):
         def swap(t):  # bshd <-> bhsd; the flash kernel is head-major
             return jnp.transpose(t, (0, 2, 1, 3)) if bshd else t
 
+        if dv != q.shape[-1]:
+            # the kernel has one head width: pad the values with zeros up
+            # to the keys' and cut the output back (the block comment above)
+            if dv > q.shape[-1]:
+                raise ValueError(
+                    f"fused_multihead_attention: values of width {dv} "
+                    f"wider than the keys ({q.shape[-1]})")
+            v = jnp.pad(v, [(0, 0)] * 3 + [(0, q.shape[-1] - dv)])
         return swap(flash_attention(
             swap(q), swap(k), swap(v), bias=bias, causal=causal,
             sm_scale=sm_scale, dropout=dropout, rng_key=rng,
-        ))
+        ))[..., :dv]
 
     mesh = ctx.mesh
     model_n = (

@@ -1,5 +1,7 @@
-"""Mixture-of-Experts op lowering: the `moe_ffn` IR op dispatches to the
-GShard dense-dispatch math in parallel/moe.py. Under a mesh whose 'ep'
+"""Mixture-of-Experts op lowerings, the math in parallel/moe.py: `moe_ffn`
+dispatches to the GShard dense-dispatch formulation, which drops past an
+expert's capacity; `moe_experts` to the dropless grouped product over the
+experts held here. Under a mesh whose 'ep'
 axis shards the expert (leading) dim of the expert parameters, GSPMD
 lowers the dispatch/combine einsums to the all-to-all over ICI — the
 lowering itself stays pure jnp (SURVEY.md §2.8 expert parallel; no
@@ -34,3 +36,35 @@ def _moe_ffn(ctx, op):
     )
     ctx.out(op, "Out", y)
     ctx.out(op, "AuxLoss", aux.reshape(1))
+
+
+@register_op("moe_experts", no_grad_inputs=("Bias",))
+def _moe_experts(ctx, op):
+    """X: [..., D]; Gate: [D, experts_total]; Bias: [experts_total], the
+    router's correction (selection only, no gradient); WGate, WUp:
+    [experts_held, D, F]; WDown: [experts_held, F, D]. Out like X: what
+    the held experts add. Load: [experts_held] int32."""
+    from .. import profiler
+    from ..parallel.moe import moe_experts
+
+    held, total = op.attr("experts_held"), op.attr("experts_total")
+    gate = ctx.in_(op, "Gate")
+    if gate.shape[1] != total:
+        raise ValueError(
+            f"moe_experts: Gate has {gate.shape[1]} columns, experts_total "
+            f"is {total}")
+    profiler.bump_counter("moe_dispatch_grouped")
+    profiler.set_counter("moe_experts_held", held)
+    profiler.set_counter("moe_experts_total", total)
+    x = ctx.in_(op, "X")
+    # the router is float32 inside moe_route; the grouped products ride
+    # the amp dtype, cast inside (both operands), as in moe_ffn
+    y, load = moe_experts(
+        x, gate, ctx.in_(op, "Bias"), ctx.in_(op, "WGate"),
+        ctx.in_(op, "WUp"), ctx.in_(op, "WDown"),
+        k=op.attr("k"), scaling=op.attr("scaling", 1.0),
+        experts_held=held, held_from=op.attr("held_from", 0),
+        renormalize=op.attr("renormalize", True),
+        compute_dtype=ctx.amp_dtype_for(op))
+    ctx.out(op, "Out", y)
+    ctx.out(op, "Load", load)

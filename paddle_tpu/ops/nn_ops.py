@@ -553,6 +553,23 @@ def _layer_norm(ctx, op):
     ctx.out(op, "Variance", var.reshape(lead))
 
 
+@register_op("rms_norm")
+def _rms_norm(ctx, op):
+    """`y = x / sqrt(mean(x^2) + epsilon) * scale` over the axes from
+    `begin_norm_axis` on (Zhang and Sennrich 2019, arXiv:1910.07467): no
+    mean is subtracted and there is no shift. The statistics and the
+    product with the scale are float32; Y has X's dtype."""
+    x = ctx.in_(op, "X")
+    scale = ctx.in_(op, "Scale")
+    begin = op.attr("begin_norm_axis", 1)
+    axes = tuple(range(begin, x.ndim))
+    xf = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(
+        jnp.mean(xf * xf, axis=axes, keepdims=True) + op.attr("epsilon", 1e-5))
+    y = xf * inv * scale.reshape(x.shape[begin:]).astype(jnp.float32)
+    ctx.out(op, "Y", y.astype(x.dtype))
+
+
 @register_op("layer_norm_grad", differentiable=False)
 def _layer_norm_grad(ctx, op):
     """dX, dScale, dBias from the saved per-row stats; the normalized

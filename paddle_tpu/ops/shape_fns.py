@@ -849,7 +849,31 @@ def _shape_dropout(ictx, op):
 
 @register_shape("fused_multihead_attention")
 def _shape_fused_mha(ictx, op):
-    ictx.out(op, "Out", _m(ictx.in_(op, "Q")))
+    q, v = _m(ictx.in_(op, "Q")), _m(ictx.in_(op, "V"))
+    if q.shape is not None and v.shape is not None:
+        q = VarMeta(tuple(q.shape[:-1]) + (v.shape[-1],), q.dtype)
+    ictx.out(op, "Out", q)
+
+
+@register_shape("rms_norm")
+def _shape_rms_norm(ictx, op):
+    ictx.out(op, "Y", _m(ictx.in_(op, "X")))
+
+
+@register_shape("short_conv1d")
+def _shape_short_conv1d(ictx, op):
+    ictx.out(op, "Out", _m(ictx.in_(op, "X")))
+
+
+@register_shape("kda_attention")
+def _shape_kda_attention(ictx, op):
+    ictx.out(op, "Out", _m(ictx.in_(op, "V")))
+
+
+@register_shape("moe_experts")
+def _shape_moe_experts(ictx, op):
+    ictx.out(op, "Out", _m(ictx.in_(op, "X")))
+    ictx.out(op, "Load", VarMeta((op.attr("experts_held"),), "int32"))
 
 
 # ---------------------------------------------------------------------------

@@ -1,21 +1,32 @@
 """Mixture-of-Experts with expert parallelism (SURVEY.md §2.8 'Expert
 parallel (EP/MoE)' — absent from the reference; built TPU-first as a new
-capability per the build plan).
+capability per the build plan). Two formulations:
 
-GShard/Mesh-TF dense-dispatch formulation: tokens route to experts through
-one-hot dispatch/combine einsums, so under pjit with the expert dim sharded
-over the `ep` mesh axis XLA lowers the dispatch einsum to the all-to-all
-over ICI — no hand-written collectives. Gradients flow through the combine
-weights (gating is differentiable); capacity overflow drops tokens the way
-GShard does, and the standard load-balancing auxiliary loss is returned for
-the trainer to add."""
+`moe_ffn` (op `moe_ffn`) **drops**: the GShard/Mesh-TF dense dispatch.
+Tokens route to experts through one-hot dispatch/combine einsums over a
+`[N, E, capacity]` tensor, so under pjit with the expert dim sharded over
+the `ep` mesh axis XLA lowers the dispatch einsum to the all-to-all over
+ICI — no hand-written collectives. Softmax top-k; an assignment past an
+expert's capacity is dropped the way GShard drops it, and the standard
+load-balancing auxiliary loss is returned for the trainer to add.
+
+`moe_experts` (op `moe_experts`) is **dropless**, and is told which
+experts it holds: a sigmoid router over all `experts_total` experts picks
+k a token, the assignments to the `experts_held` experts from `held_from`
+on are sorted by expert and run through one grouped product
+(`jax.lax.ragged_dot`), whatever the skew, at static shapes. What the
+experts held elsewhere would add is left out: on one chip the layer runs
+without its exchange. Gradients flow through the combine weights in both."""
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["MoEParams", "init_moe_params", "moe_ffn", "moe_shardings"]
+__all__ = ["MoEParams", "init_moe_params", "moe_ffn", "moe_shardings",
+           "moe_route", "moe_experts"]
 
 
 def init_moe_params(rng, d_model, d_ff, num_experts, dtype=jnp.float32):
@@ -131,3 +142,142 @@ def moe_ffn(params, x, capacity_factor=1.25, k=2, compute_dtype=None):
     mean_prob = jnp.mean(probs, axis=0)
     aux = e * jnp.sum((frac_routed / k) * mean_prob)
     return y.reshape(orig_shape), aux
+
+
+# ---------------------------------------------------------------------------
+# dropless experts, a share of them held here
+# ---------------------------------------------------------------------------
+
+# Rows of one grouped product: the sorted assignments go through their
+# experts a block of this share of the N*k at a time, as many blocks as the
+# assignments to the held experts fill (a `while` with a trip count read
+# from the load). A balanced router sends 8 experts of 256 a 32nd of the
+# assignments, but a freshly seeded one is skewed and Adam moves it towards
+# the experts it has: at 4,096 tokens a layer's load passed an eighth (two
+# blocks then, 6 ms more a layer) in two seeds of nine, one that started
+# at 4,000 of 32,768 and one that started at 2,700 and grew by 150 a step
+# (PERF.md, PR 31). A quarter keeps
+# one block the rule, so a step's time does not follow the seed; a skew
+# past it costs blocks, not tokens: both are static shapes, and nothing is
+# dropped.
+BLOCK_SHARE = 4  # 1/4 of the N*k assignments
+
+
+def moe_route(x, gate, bias, k, scaling, renormalize=True):
+    """Sigmoid router over all the experts `gate` has columns for.
+    x: [N, D]; gate: [D, E]; bias: [E], the correction that enters the
+    selection and not the weights. Returns (idx [N, k] int32, weights
+    [N, k] float32): the k largest of `sigmoid(x gate) + bias`, weighted
+    `scaling * s_i / sum_selected s_j` (without `renormalize`,
+    `scaling * s_i`). float32 throughout."""
+    scores = jax.nn.sigmoid(
+        jnp.dot(x.astype(jnp.float32), gate.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(
+        jax.lax.stop_gradient(scores + bias.astype(jnp.float32)), k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if renormalize:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), scaling * w
+
+
+def _block_rows(total):
+    return max(total // BLOCK_SHARE, 1)
+
+
+def _block_count(sizes, rows):
+    return (jnp.sum(sizes) + rows - 1) // rows
+
+
+def _block(j, rows, x, w_gate, w_up, w_down, token, weight, sizes, dtype):
+    """Rows [j*rows, (j+1)*rows) of the sorted assignments through their
+    experts' SiLU-gated FFN, weighted and summed onto their tokens:
+    [N, D] float32. Rows past the held assignments carry a zero input and
+    weight; the last group is stretched over them so that every row lies
+    in a group."""
+    lo = j * rows
+    ends = jnp.cumsum(sizes)
+    # this block's part of each group
+    part = (jnp.clip(ends - lo, 0, rows)
+            - jnp.clip(ends - sizes - lo, 0, rows)).astype(jnp.int32)
+    live = (lo + jnp.arange(rows) < ends[-1])[:, None]
+    token = jax.lax.dynamic_slice_in_dim(token, lo, rows)
+    weight = jax.lax.dynamic_slice_in_dim(weight, lo, rows)
+    xs = jnp.where(live, x[token], jnp.zeros((), x.dtype)).astype(dtype)
+    part = part.at[-1].add(rows - jnp.sum(part))
+
+    def dot(a, w):
+        return jax.lax.ragged_dot(a, w.astype(dtype), part,
+                                  preferred_element_type=jnp.float32)
+
+    h = jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up)
+    y = dot(h.astype(dtype), w_down) * weight[:, None]
+    return jnp.zeros(x.shape, jnp.float32).at[token].add(
+        jnp.where(live, y, 0.0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _held_experts(x, w_gate, w_up, w_down, token, weight, sizes, dtype):
+    rows = _block_rows(token.shape[0])
+    return jax.lax.fori_loop(
+        0, _block_count(sizes, rows),
+        lambda j, out: out + _block(j, rows, x, w_gate, w_up, w_down, token,
+                                    weight, sizes, dtype),
+        jnp.zeros(x.shape, jnp.float32))
+
+
+def _held_experts_fwd(x, w_gate, w_up, w_down, token, weight, sizes, dtype):
+    out = _held_experts(x, w_gate, w_up, w_down, token, weight, sizes, dtype)
+    return out, (x, w_gate, w_up, w_down, token, weight, sizes)
+
+
+def _held_experts_bwd(dtype, res, g):
+    # A loop of its own, block by block as the forward: the trip count is
+    # data, which jax.vjp cannot take through a `while`, and a block's
+    # intermediates are rebuilt here and not kept from the forward.
+    x, w_gate, w_up, w_down, token, weight, sizes = res
+    rows = _block_rows(token.shape[0])
+    diff = (x, w_gate, w_up, w_down, weight)
+
+    def body(j, grads):
+        _, pull = jax.vjp(
+            lambda x, a, b, c, w: _block(j, rows, x, a, b, c, token, w,
+                                         sizes, dtype), *diff)
+        return jax.tree.map(jnp.add, grads, pull(g))
+
+    zeros = jax.tree.map(lambda t: jnp.zeros(t.shape, t.dtype), diff)
+    dx, da, db, dc, dw = jax.lax.fori_loop(
+        0, _block_count(sizes, rows), body, zeros)
+    return dx, da, db, dc, None, dw, None
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def moe_experts(x, gate, bias, w_gate, w_up, w_down, k, scaling,
+                experts_held, held_from, renormalize=True,
+                compute_dtype=None):
+    """The part of a dropless expert layer that the experts held here
+    give. x: [..., D]; gate: [D, experts_total]; bias: [experts_total];
+    w_gate, w_up: [experts_held, D, F]; w_down: [experts_held, F, D].
+    Returns (y like x, load [experts_held] int32: assignments per held
+    expert).
+
+    The N*k assignments are sorted by held expert, those to experts held
+    elsewhere last, and the sorted rows go through `jax.lax.ragged_dot`
+    with the load as the group sizes, `BLOCK_SHARE`-th of them a time: no
+    `[N, E, capacity]` tensor, no capacity, no loop over k."""
+    shape = x.shape
+    tokens = x.reshape(-1, shape[-1])
+    idx, weights = moe_route(tokens, gate, bias, k, scaling, renormalize)
+    local = idx.reshape(-1) - held_from
+    held = (local >= 0) & (local < experts_held)
+    key = jnp.where(held, local, experts_held)
+    order = jnp.argsort(key, stable=True)
+    load = jnp.bincount(key, length=experts_held + 1)[:experts_held].astype(
+        jnp.int32)
+    token = (order // k).astype(jnp.int32)
+    weight = jnp.where(held, weights.reshape(-1), 0.0)[order]
+    y = _held_experts(tokens, w_gate, w_up, w_down, token, weight, load,
+                      compute_dtype or tokens.dtype)
+    return y.astype(x.dtype).reshape(shape), load
