@@ -21,9 +21,19 @@ state the chunk starts from:
     O   = dk^{-1/2} ((Q * exp(G)) S_0 + Aq U)
     S_C = Diag(exp(G_C)) S_0 + (K * exp(G_C - G))^T U
 
-`[Wv, Wk]` (the WY representation), `A` and `Aq` depend on no state, so
-they are computed for every chunk at once; only the three products with
-`S` run under the `lax.scan` that carries the state from chunk to chunk.
+**Two lowerings of one algorithm**, chosen by what `kda_mixer_core` can
+observe (`ops/pallas/kda_chunk.py::kda_chunk_viable`: heads of 128 lanes
+and a backend that runs Pallas kernels, so a TPU, or the interpreter in
+tests): the kernel pair `kda_chunk` of `ops/pallas/kda_chunk.py` (PR 32),
+which holds a chunk and the state in VMEM and reads the `[b, s, h*d]`
+arrays as they arrive; and `kda_chunked` below, plain XLA, which runs
+everywhere else (the CPU, the rehearsal's heads of 16) and is the
+kernel's test oracle. No switch selects between them.
+
+In `kda_chunked`, `[Wv, Wk]` (the WY representation), `A` and `Aq` depend
+on no state, so they are computed for every chunk at once; only the three
+products with `S` run under the `lax.scan` that carries the state from
+chunk to chunk.
 
 **Keeping the cumulative decay finite.** `exp(G_i - G_j)` is at most 1,
 but the factorisation `(k_i exp(G_i)) . (k_j exp(-G_j))` that turns `A`
@@ -47,10 +57,9 @@ import jax
 import jax.numpy as jnp
 
 from .. import profiler
+from .pallas import kda_chunk as kda_kernel
+from .pallas.kda_chunk import CHUNK, SUB  # SUB: see the module docstring
 from .registry import register_op
-
-CHUNK = 64
-SUB = 16  # sub-chunk: see the module docstring
 
 
 @jax.checkpoint
@@ -174,24 +183,55 @@ def kda_chunked(q, k, v, g, beta):
     return dk ** -0.5 * o[:, :s]
 
 
-@functools.partial(jax.checkpoint, static_argnums=(7, 8))
-def kda_mixer_core(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps):
-    """From the convolved projections to the heads' outputs, float32
-    inside. Under `jax.checkpoint`: the backward keeps the arguments alone
-    and rebuilds the chunk states, the WY factors and the decays, which
-    are several times the arguments' size. The products inside run at the
-    backend's default precision (on a TPU a float32 product reads bf16):
-    at `float32` the step measured 18 ms longer (378 -> 396 ms) and the
-    logits 0.15 points nearer the reference (2.23 -> 2.09%), PERF.md PR 31."""
+def _prologue(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps):
+    """The float32 part in front of the chunks: q and k L2-normalised per
+    head, the log decay, beta; v as it arrived. All [b, s, h, ...]."""
     b, s, _ = q.shape
 
     def heads(t):
         return t.reshape(b, s, num_heads, -1)
 
-    g = kda_gate(g_raw, a_log, dt_bias, num_heads)
-    beta = jax.nn.sigmoid(beta_raw.astype(jnp.float32))
-    o = kda_chunked(l2norm(heads(q), eps), l2norm(heads(k), eps),
-                    heads(v).astype(jnp.float32), g, beta)
+    return (l2norm(heads(q), eps), l2norm(heads(k), eps), heads(v),
+            kda_gate(g_raw, a_log, dt_bias, num_heads),
+            jax.nn.sigmoid(beta_raw.astype(jnp.float32)))
+
+
+@functools.partial(jax.checkpoint, static_argnums=(7, 8))
+def _mixer_plain(*args):
+    q, k, v, g, beta = _prologue(*args)
+    return kda_chunked(q, k, v.astype(jnp.float32), g, beta)
+
+
+def kda_mixer_core(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps):
+    """From the convolved projections to the heads' outputs, float32
+    inside: the L2 norms, the decay, beta, then the chunked delta rule,
+    in the Pallas kernels where `kda_chunk_viable` admits the shape and
+    the backend and in `kda_chunked` otherwise; a counter says which, once
+    a lowering (`kda_dispatch_pallas`, `kda_dispatch_chunked`).
+
+    What the backward keeps. With the kernels: their float32 operands
+    (q, k, g: 67 MB each a layer at 4,096 tokens; v stays bf16) and the
+    state each chunk starts from, which `kda_fwd` writes (134 MB a
+    layer); `kda_bwd` rebuilds the chunk's `G`, `A`, `Aq`, `T` and WY
+    factors in VMEM. No `jax.checkpoint` there: it cost 14.5 ms of a
+    263 ms step to save 1.4 GB that the cell has (PERF.md, PR 32). With
+    `kda_chunked`, under `jax.checkpoint`, the arguments alone: the chunk
+    states, the WY factors and the decays it would keep are several
+    times their size.
+
+    The products inside run at the backend's default precision (on a TPU
+    a float32 product reads bf16, in the kernels too): at `float32` the
+    XLA step measured 18 ms longer (378 -> 396 ms) and the logits 0.15
+    points nearer the reference (2.23 -> 2.09%), PERF.md PR 31."""
+    args = (q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps)
+    b, s, _ = q.shape
+    if kda_kernel.kda_chunk_viable(s, q.shape[2] // num_heads,
+                                   v.shape[2] // num_heads):
+        profiler.bump_counter("kda_dispatch_pallas")
+        o = kda_kernel.kda_chunk(*_prologue(*args))
+    else:
+        profiler.bump_counter("kda_dispatch_chunked")
+        o = _mixer_plain(*args)
     return o.reshape(b, s, -1)
 
 
@@ -203,10 +243,8 @@ def _kda_attention(ctx, op):
     in V's dtype. The L2 norm of q and k, the decay and beta are computed
     here in float32, whatever the AMP dtype of the inputs."""
     q, k, v = ctx.in_(op, "Q"), ctx.in_(op, "K"), ctx.in_(op, "V")
-    h = op.attr("num_heads")
-    profiler.bump_counter("kda_dispatch_chunked")
     out = kda_mixer_core(
         q, k, v, ctx.in_(op, "GRaw"), ctx.in_(op, "BetaRaw"),
-        ctx.in_(op, "ALog"), ctx.in_(op, "DtBias"), h,
+        ctx.in_(op, "ALog"), ctx.in_(op, "DtBias"), op.attr("num_heads"),
         op.attr("l2norm_epsilon", 1e-6))
     ctx.out(op, "Out", out.astype(v.dtype))

@@ -1,0 +1,457 @@
+"""The KDA chunk as a Pallas kernel pair: the gated delta rule's work
+inside a chunk and the state it carries from chunk to chunk, in VMEM.
+
+The mathematics is `ops/linear_attn_ops.py`'s module docstring, term for
+term; `kda_chunked` there is the plain path and this file's test oracle.
+What differs is where things live. XLA's lowering relays q, k, v, g out
+to `[b, h, n, c, d]`, runs a dozen float32 passes and small batched
+products over them, a triangular-solve custom call, and a 64-step
+`lax.scan` for the state. Here one grid step holds two chunks of one
+head, `[2*C, 128]` blocks cut from the `[b, s, h*128]` arrays the op's
+inputs arrive in (no relayout on either side of the call), and the state
+`S^T` `[dv, dk]` float32 in a VMEM scratch that lives across the
+sequential chunk axis of the grid and is zeroed at chunk 0.
+
+Per chunk, all in VMEM:
+
+- `A` and `Aq` by the sub-chunk scheme: a pair of sub-chunks I > J as a
+  product whose exponents are split at `Gs_I`; the four diagonal 16x16
+  blocks directly, all four and all their columns at once
+  (`exp(G_i - G_j)` masked to j <= i *before* the `exp`, times `k_j`,
+  summed over the lanes: a [16, 4, 16, 128] array in VMEM), as the
+  public flash-linear-attention kernels do a column at a time. Nothing
+  is clamped.
+- `T = (I + Diag(beta) A)^-1` by block forward substitution, doubling
+  the block from 2 to 64 (`_inverse`: ten 64x64 products, no loop over
+  rows). `[Wv, Wk] = T Diag(beta) [V, K exp(G)]`.
+- `U`, `O` and the next state from the state in the scratch. The state
+  is kept transposed so that its decay `Diag(exp(G_C))` is a product
+  with a row over the lanes and no product needs a transpose.
+
+The backward is a reverse sweep over the chunks with `dS^T` in the
+scratch. It rebuilds the chunk's `G`, `A`, `Aq`, `T`, `W`, `U` from q, k,
+v, g, beta and the state the chunk started from, which the forward wrote
+(`[b*h, n, dv, dk]` float32, 134 MB a layer at 4,096 tokens and 32
+heads), and writes dq, dk, dv, dg and dbeta. The gradient of the solve
+is the transposed solve, `Lambda = T^T [dWv, dWk]`, with the `T` just
+rebuilt; the gradients of `A` and `Aq` go back through the same
+sub-chunk scheme, with the decays the rebuilt forward kept.
+
+Precision is the op's: every `exp`, mask, sum and the state are float32.
+The cumulative log-decay `G` is summed in the kernel in float32, by
+shifted adds over the chunk's rows (and `dG` back by the same adds
+reversed), never by a triangular product. A product reads its operands
+as the backend's default precision reads a float32 product: bf16 on a
+TPU, float32 elsewhere and wherever `jax.default_matmul_precision` asks
+for more; it accumulates in float32.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import LANE, _interpret, _use_pallas, require_pallas
+
+CHUNK = 64
+SUB = 16  # sub-chunk: ops/linear_attn_ops.py's module docstring
+# Chunks a grid step, each a copy of the chunk's code in the kernel: two
+# overlap one chunk's state-free work with the other's chain of products.
+# Four measured 3 ms of a 237 ms step faster and 2.5 s of set-up slower at
+# every start of a job (the host lowers each copy; PERF.md, PR 32).
+CHUNKS_PER_STEP = 2
+
+_NN = ((1,), (0,))  # [m, k] x [k, n]
+_NT = ((1,), (1,))  # [m, k] x [n, k]
+_TN = ((0,), (0,))  # [k, m] x [k, n]
+
+
+def kda_chunk_viable(s, d_k, d_v):
+    """The shapes and backends the kernels are built for: a head is one
+    128-lane slice of q, k, g and one of v, and Mosaic (or the
+    interpreter) is there to run them. Any length: rows are padded to
+    whole grid steps."""
+    return s >= 1 and d_k == LANE and d_v == LANE and _use_pallas()
+
+
+def _product_dtype():
+    """What a float32 product reads at the backend's default precision."""
+    asked = jax.config.jax_default_matmul_precision
+    if jax.default_backend() == "tpu" and asked in (None, "default",
+                                                    "bfloat16", "fastest"):
+        return jnp.bfloat16
+    return jnp.float32
+
+
+def _mm(a, b, dims, dtype):
+    return jax.lax.dot_general(
+        a.astype(dtype), b.astype(dtype), (dims, ((), ())),
+        precision=(jax.lax.Precision.HIGHEST if dtype == jnp.float32
+                   else None),
+        preferred_element_type=jnp.float32)
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _cumsum(x, reverse=False):
+    """Along the chunk's rows, inclusive, in float32: log2(C) shifted
+    adds on the sublanes (`reverse`: from the last row back, which is the
+    sum's transpose)."""
+    c = x.shape[0]
+    row = _iota(x.shape, 0)
+    shift = 1
+    while shift < c:
+        if reverse:  # x_i += x_(i + shift)
+            keep, by = row < c - shift, c - shift
+        else:  # x_i += x_(i - shift)
+            keep, by = row >= shift, shift
+        x = x + jnp.where(keep, pltpu.roll(x, by, 0), 0.0)
+        shift *= 2
+    return x
+
+
+def _diagonal(q, k, G):
+    """For the diagonal blocks, all of them and all of their columns at
+    once: q and k as [C/SUB, SUB, dk], and as [SUB, C/SUB, SUB, dk], the
+    column j inside the block first, `decay` = exp(G_i - G_j) for the
+    rows i >= j and 0 above (masked before the exp), and `k_j * decay`."""
+    c, dk = q.shape
+    q3, k3, G3 = (t.reshape(c // SUB, SUB, dk) for t in (q, k, G))
+
+    def row(t3):  # row j of every sub-chunk: [SUB, C/SUB, 1, dk]
+        return jnp.swapaxes(t3[:, :, None, :], 0, 1)
+
+    shape = (SUB, c // SUB, SUB, dk)
+    decay = jnp.exp(jnp.where(_iota(shape, 2) >= _iota(shape, 0),
+                              G3[None] - row(G3), -jnp.inf))
+    return q3, k3, decay, row(k3) * decay
+
+
+def _scores(q, k, G, dtype):
+    """A (j < i) and Aq (j <= i) of one chunk, [C, C] each, and what the
+    backward uses again: the sub-chunks' factors off the diagonal, and
+    `_diagonal`'s arrays."""
+    c, dk = q.shape
+    a_rows = aq_rows = (jnp.zeros((SUB, c), jnp.float32),)
+    factors = ()
+    for lo in range(SUB, c, SUB):
+        q_sub, k_sub, g_sub = (t[lo:lo + SUB] for t in (q, k, G))
+        g_split = G[lo - 1:lo]  # Gs_I
+        inner = jnp.exp(g_sub - g_split)  # exp(G_i - Gs_I) <= 1
+        # exp(Gs_I - G_j) <= 1 for the earlier rows j, 0 from lo on
+        outer = jnp.exp(jnp.where(_iota((c, dk), 0) < lo, g_split - G,
+                                  -jnp.inf))
+        x_in = jnp.concatenate([k_sub * inner, q_sub * inner], axis=0)
+        k_out = k * outer
+        off = _mm(x_in, k_out, _NT, dtype)  # [2*SUB, C], 0 from column lo
+        a_rows += (off[:SUB],)
+        aq_rows += (off[SUB:],)
+        factors += ((inner, outer, x_in, k_out),)
+    diagonal = q3, k3, _, kj = _diagonal(q, k, G)
+    # column j of block I goes to column SUB*I + j of the chunk's rows
+    shape = (SUB, c // SUB, SUB, c)
+    here = _iota(shape, 3) == SUB * _iota(shape, 1) + _iota(shape, 0)
+
+    def place(x3):  # sum over dk, [j, C/SUB, i, 1], then over j: [C, C]
+        cols = jnp.sum(x3[None] * kj, 3, keepdims=True)
+        return jnp.sum(jnp.where(here, cols, 0.0), 0).reshape(c, c)
+
+    A = jnp.concatenate(a_rows, axis=0) + place(k3)
+    A = jnp.where(_iota(A.shape, 0) > _iota(A.shape, 1), A, 0.0)
+    return (A, jnp.concatenate(aq_rows, axis=0) + place(q3),
+            (factors, diagonal))
+
+
+def _scores_grad(dA, dAq, kept, dtype):
+    """The gradients of `_scores`, from what it `kept`: of q (`dq`), of k
+    as the row operand of A (`dk_row`) and of k as the column operand of
+    both (`dk_col`). dG's share is q*dq + k*dk_row - k*dk_col."""
+    factors, (q3, k3, decay, kj) = kept
+    c, dk = dA.shape[0], q3.shape[2]
+    zeros = jnp.zeros((SUB, dk), jnp.float32)
+    dq_rows, dkl_rows, dk_col = (zeros,), (zeros,), 0.0
+    for n, lo in enumerate(range(SUB, c, SUB)):
+        inner, outer, x_in, k_out = factors[n]
+        d_off = jnp.concatenate([dA[lo:lo + SUB], dAq[lo:lo + SUB]], axis=0)
+        # columns from lo on meet k_out's zero rows, outer's zeros
+        d_in = _mm(d_off, k_out, _NN, dtype)
+        dkl_rows += (d_in[:SUB] * inner,)
+        dq_rows += (d_in[SUB:] * inner,)
+        dk_col = dk_col + outer * _mm(d_off, x_in, _TN, dtype)
+
+    def columns(d):  # of d's diagonal blocks: [j, C/SUB, i, 1]
+        blocks = jnp.concatenate(
+            [d[lo:lo + SUB, lo:lo + SUB] for lo in range(0, c, SUB)],
+            axis=0).reshape(c // SUB, SUB, SUB)
+        return jnp.stack([blocks[:, :, j:j + 1] for j in range(SUB)], axis=0)
+
+    ca, cq = columns(dA), columns(dAq)
+    dq = jnp.sum(cq * kj, 0).reshape(c, dk)
+    dkl = jnp.sum(ca * kj, 0).reshape(c, dk)
+    # row j of every sub-chunk, [j, C/SUB, 1, dk], back to its place
+    dkr = jnp.sum((ca * k3[None] + cq * q3[None]) * decay, 2, keepdims=True)
+    dkr = jnp.swapaxes(dkr, 0, 1).reshape(c, dk)
+    return (jnp.concatenate(dq_rows, axis=0) + dq,
+            jnp.concatenate(dkl_rows, axis=0) + dkl, dk_col + dkr)
+
+
+def _inverse(N, dtype):
+    """(I + N)^-1 for a strictly lower [C, C] N, by halves: on 2x2
+    diagonal blocks it is I - N exactly, and the inverse on blocks of 2m
+    follows from the one on blocks of m, `T`, and the part `L` of N inside
+    the blocks of 2m and outside those of m as T - T L T (the block formula
+    [[a, 0], [l, b]]^-1 = [[a^-1, 0], [-b^-1 l a^-1, b^-1]], on every
+    diagonal block at once). Block forward substitution, so as stable as
+    the system: keys that are nearly parallel make N's entries near 1, and
+    a series in N's powers, however short, then sums terms of 1e8."""
+    c = N.shape[0]
+    r, l = _iota(N.shape, 0), _iota(N.shape, 1)
+
+    def same_block(bits):  # blocks of 2**bits rows
+        return (r >> bits) == (l >> bits)
+
+    inv = (r == l).astype(jnp.float32) - jnp.where(same_block(1), N, 0.0)
+    for bits in range(1, c.bit_length() - 1):
+        L = jnp.where(same_block(bits + 1) & ~same_block(bits), N, 0.0)
+        inv = inv - _mm(_mm(inv, L, _NN, dtype), inv, _NN, dtype)
+    return inv
+
+
+def _state_free(q, k, v, g, beta, dtype):
+    """What one chunk computes from no state: from the cumulative
+    log-decay G the decays, A, Aq (and what `_scores` kept), T and the WY
+    factors [Wv, Wk]."""
+    G = _cumsum(g)
+    E = jnp.exp(G)
+    e_end = jnp.exp(G[-1:] - G)  # exp(G_C - G_i) <= 1
+    A, Aq, kept = _scores(q, k, G, dtype)
+    T = _inverse(beta * A, dtype)
+    W = _mm(T, jnp.concatenate([beta * v, beta * (k * E)], axis=1), _NN,
+            dtype)
+    return E, e_end, jnp.exp(G[-1:]), A, Aq, kept, T, W
+
+
+@functools.partial(jax.jit, static_argnames=("dtype",))
+def _chunk_fwd(q, k, v, g, beta, St, *, dtype):
+    """One chunk from the state it starts with, `St` = S^T [dv, dk]: its
+    outputs [C, dv] and the state it leaves. (Jitted, as `_chunk_bwd`, so
+    that a kernel's copies of the chunk are traced once: the host pays
+    for every jnp call at each start of a job, compile cache or not.)"""
+    c, dv = v.shape
+    E, e_end, decay_end, _, Aq, _, _, W = _state_free(q, k, v, g, beta, dtype)
+    # [Wk; Q exp(G)] S in one product
+    by_state = _mm(jnp.concatenate([W[:, dv:], q * E], axis=0), St, _NT,
+                   dtype)
+    U = W[:, :dv] - by_state[:c]
+    o = q.shape[1] ** -0.5 * (by_state[c:] + _mm(Aq, U, _NN, dtype))
+    return o, St * decay_end + _mm(U, k * e_end, _TN, dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("dtype",))
+def _chunk_bwd(q, k, v, g, beta, St, dSt, dO, *, dtype):
+    """One chunk of the reverse sweep: from the state the chunk started
+    with, the gradient `dSt` of the state it left and of its outputs,
+    the gradients of q, k, v, g, of beta (as a row [1, C]) and of the
+    state it started with."""
+    mm = functools.partial(_mm, dtype=dtype)
+    c, dv = v.shape
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    E, e_end, decay_end, A, Aq, kept, T, W = _state_free(q, k, v, g, beta,
+                                                         dtype)
+    ke, kd, Wk = k * e_end, k * E, W[:, dv:]
+    U = W[:, :dv] - mm(Wk, St, _NT)
+    dO = q.shape[1] ** -0.5 * dO
+    dU = mm(Aq, dO, _TN) + mm(ke, dSt, _NT)
+    by_state = mm(jnp.concatenate([dO, dU], axis=0), St, _NN)
+    d_qd, dWk = by_state[:c], -by_state[c:]
+    d_ke = mm(U, dSt, _NN)
+    dAq = jnp.where(row >= col, mm(dO, U, _NT), 0.0)
+    # the solve's gradient is the transposed solve
+    lam = mm(T, jnp.concatenate([dU, dWk], axis=1), _TN)
+    lam_w = jnp.where(row > col, mm(lam, W, _NT), 0.0)
+    d_kd = beta * lam[:, dv:]
+    dbeta = (jnp.sum(lam[:, :dv] * v + lam[:, dv:] * kd, 1, keepdims=True)
+             - jnp.sum(lam_w * A, 1, keepdims=True))
+    dSt_new = dSt * decay_end + mm(dO, q * E, _TN) - mm(dU, Wk, _TN)
+    dg_end = (jnp.sum(dSt * St, 0, keepdims=True) * decay_end
+              + jnp.sum(d_ke * ke, 0, keepdims=True))
+    dq, dkl, dkr = _scores_grad(-beta * lam_w, dAq, kept, dtype)
+    dq = dq + d_qd * E
+    dkl = dkl + d_kd * E
+    dkr = dkr + d_ke * e_end
+    dG = q * dq + k * (dkl - dkr)
+    dG = dG + jnp.where(_iota(dG.shape, 0) == c - 1, dg_end, 0.0)
+    # the column as a row, for a lane-dense store
+    dbeta = jnp.sum(jnp.where(row == col, dbeta, 0.0), 0, keepdims=True)
+    return (dq, dkl + dkr, beta * lam[:, :dv], _cumsum(dG, reverse=True),
+            dbeta, dSt_new)
+
+
+def _operands(q_ref, k_ref, v_ref, g_ref, beta_ref, rows, heads):
+    """The chunk at `rows` of the grid step's block, float32, and this
+    head's column of the [C, h] block of beta."""
+    blk = beta_ref[0, rows, :]
+    head = pl.program_id(0) % heads
+    beta = jnp.sum(jnp.where(_iota(blk.shape, 1) == head, blk, 0.0), 1,
+                   keepdims=True)
+    return (*(r[0, rows, :].astype(jnp.float32)
+              for r in (q_ref, k_ref, v_ref, g_ref)), beta)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref, s_ref, *,
+                heads, steps, dtype):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    # copies of the chunk and not a loop: a `pl.loop` over four chunks
+    # measured 0.8 ms a call slower than four copies (1.3 ms backward)
+    for t in range(steps):
+        rows = pl.ds(t * CHUNK, CHUNK)
+        St = st_ref[0, t] = s_ref[...]  # the state the chunk starts from
+        o, s_ref[...] = _chunk_fwd(
+            *_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, rows, heads), St,
+            dtype=dtype)
+        o_ref[0, rows, :] = o.astype(o_ref.dtype)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref, dq_ref,
+                dk_ref, dv_ref, dg_ref, db_ref, ds_ref, *, heads, steps,
+                dtype):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    for t in reversed(range(steps)):
+        rows = pl.ds(t * CHUNK, CHUNK)
+        dq, dk, dv, dg, db_ref[0, t], ds_ref[...] = _chunk_bwd(
+            *_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, rows, heads),
+            st_ref[0, t], ds_ref[...], do_ref[0, rows, :].astype(jnp.float32),
+            dtype=dtype)
+        for ref, d in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv), (dg_ref, dg)):
+            ref[0, rows, :] = d.astype(ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("statics",))
+def _call_fwd(q, k, v, g, beta, *, statics):
+    """q, k, g: [b, S, h*dk]; v: [b, S, h*dv]; beta: [b, S, h]; S whole
+    grid steps. Returns o [b, S, h*dv] in v's dtype and the state each
+    chunk starts from, [b*h, S/C, dv, dk] float32. One call for a
+    forward that is differentiated and one that is not: a Program's
+    gradient op lowers its forward op again, and XLA merges the two calls
+    only if they are the same call."""
+    heads, steps, dtype, interpret = statics
+    b, S, _ = q.shape
+    dk, dv = q.shape[2] // heads, v.shape[2] // heads
+    rows = steps * CHUNK
+
+    def spec(d):
+        return pl.BlockSpec((1, rows, d), lambda i, j: (i // heads, j,
+                                                        i % heads))
+
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, heads=heads, steps=steps, dtype=dtype),
+        grid=(b * heads, S // rows),
+        in_specs=[spec(dk), spec(dk), spec(dv), spec(dk),
+                  pl.BlockSpec((1, rows, heads),
+                               lambda i, j: (i // heads, j, 0))],
+        out_specs=[spec(dv), pl.BlockSpec((1, steps, dv, dk),
+                                          lambda i, j: (i, j, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((b * heads, S // CHUNK, dv, dk),
+                                        jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="kda_fwd",
+    )(q, k, v, g, beta)
+
+
+@functools.partial(jax.jit, static_argnames=("statics",))
+def _call_bwd(q, k, v, g, beta, states, do, *, statics):
+    """The reverse sweep: grid step j holds the chunks of step last - j."""
+    heads, steps, dtype, interpret = statics
+    b, S, _ = q.shape
+    dk, dv = q.shape[2] // heads, v.shape[2] // heads
+    rows = steps * CHUNK
+    last = S // rows - 1
+
+    def spec(d):
+        return pl.BlockSpec((1, rows, d), lambda i, j: (i // heads, last - j,
+                                                        i % heads))
+
+    dq, dk_, dv_, dg, dbeta = pl.pallas_call(
+        functools.partial(_bwd_kernel, heads=heads, steps=steps, dtype=dtype),
+        grid=(b * heads, last + 1),
+        in_specs=[spec(dk), spec(dk), spec(dv), spec(dk),
+                  pl.BlockSpec((1, rows, heads),
+                               lambda i, j: (i // heads, last - j, 0)),
+                  pl.BlockSpec((1, steps, dv, dk),
+                               lambda i, j: (i, last - j, 0, 0)),
+                  spec(dv)],
+        out_specs=[spec(dk), spec(dk), spec(dv), spec(dk),
+                   pl.BlockSpec((1, steps, 1, CHUNK),
+                                lambda i, j: (i, last - j, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(g.shape, g.dtype),
+                   jax.ShapeDtypeStruct((b * heads, S // CHUNK, 1, CHUNK),
+                                        jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="kda_bwd",
+    )(q, k, v, g, beta, states, do)
+    # [b*h, n, 1, C] -> [b, S, h]
+    dbeta = dbeta.reshape(b, heads, S).transpose(0, 2, 1)
+    return dq, dk_, dv_, dg, dbeta
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _core(q, k, v, g, beta, statics):
+    return _call_fwd(q, k, v, g, beta, statics=statics)[0]
+
+
+def _core_fwd(q, k, v, g, beta, statics):
+    o, states = _call_fwd(q, k, v, g, beta, statics=statics)
+    return o, (q, k, v, g, beta, states)
+
+
+def _core_bwd(statics, res, do):
+    return _call_bwd(*res, do, statics=statics)
+
+
+_core.defvjp(_core_fwd, _core_bwd)
+
+
+def kda_chunk(q, k, v, g, beta):
+    """`kda_chunked`'s contract, in the kernels. q, k, g: [b, s, h, dk]
+    float32, `g` the log decay; v: [b, s, h, dv] in the dtype it arrives
+    in (the kernels read and write it as it is and compute in float32);
+    beta: [b, s, h] float32. Returns o: [b, s, h, dv] in v's dtype."""
+    require_pallas("kda_chunk")
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    if not kda_chunk_viable(s, dk, dv):
+        raise ValueError(
+            f"kda_chunk: q {q.shape}, v {v.shape}: needs head widths of "
+            f"{LANE}")
+    steps = min(CHUNKS_PER_STEP, -(-s // CHUNK))
+    pad = -s % (steps * CHUNK)
+    # heads side by side on the lanes, as the projections write them
+    q, k, v, g = (t.reshape(b, s, -1) for t in (q, k, v, g))
+    if pad:  # k = v = beta = 0 and no decay: padded tokens change nothing
+        q, k, v, g = (jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
+                      for t in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    o = _core(q, k, v, g, beta, (h, steps, _product_dtype(), _interpret()))
+    return o[:, :s].reshape(b, s, h, dv)

@@ -249,3 +249,34 @@ def test_kernels_per_shard_compile_for_four_v5e_chips(topo, monkeypatch):
         sds((n,), jnp.float32), sds((n,), jnp.float32),
         sds((k,), jnp.float32, rep)).compile().as_text()
     assert "ln_bwd" in text and "all-reduce" in text
+
+
+def test_kda_kernels_compile_for_a_v5e_chip_at_the_published_widths(topo):
+    """The KDA chunk kernels (ops/pallas/kda_chunk.py) at the shape of the
+    cell that runs them: one 4,096-token row, 32 heads of 128, bf16
+    products and values, forward and backward. Here with this file's
+    topology because one process of a test run can describe it (the
+    kernels' mathematics is tests/test_kda_kernel.py's). Nothing runs."""
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas import kda_chunk
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    b, s, h, d = 1, 4096, 32, 128
+    statics = (h, kda_chunk.CHUNKS_PER_STEP, jnp.bfloat16, False)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    x, v = sds((b, s, h * d)), sds((b, s, h * d), jnp.bfloat16)
+
+    def both(q, k, v, g, beta):
+        o, pull = jax.vjp(
+            lambda *a: kda_chunk._core(*a, statics), q, k, v, g, beta)
+        return o, pull(o)
+
+    compiled = jax.jit(both).lower(x, x, v, x, sds((b, s, h))).compile()
+    text = compiled.as_text()
+    assert "kda_fwd" in text and "kda_bwd" in text
+    # the operands, their gradients and the 134 MB of chunk states
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
