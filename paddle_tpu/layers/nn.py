@@ -127,6 +127,7 @@ __all__ = [
     "soft_relu",
     "maxout",
     "fused_multihead_attention",
+    "rotary_embedding",
     "topk",
     "accuracy",
     "auc",
@@ -665,6 +666,16 @@ def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
         helper, "rms_norm", {"X": [input], "Scale": [scale]},
         {"epsilon": epsilon, "begin_norm_axis": begin_norm_axis},
         dtype=input.dtype, shape=input.shape, out_slot="Y")
+
+
+def rotary_embedding(input, theta=10000.0, name=None):
+    """Rotary positions 0..s-1 on `input` [b, s, heads, d] in the
+    rotate-half convention, base `theta`; float32 inside the op, the
+    output in `input`'s dtype (ops/nn_ops.py `rotate_half`)."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    return _single_out(
+        helper, "rotary_embedding", {"X": [input]}, {"theta": float(theta)},
+        dtype=input.dtype, shape=input.shape)
 
 
 def short_conv1d(input, width=4, param_attr=None, name=None):
@@ -1252,6 +1263,7 @@ def fused_multihead_attention(
     is_test=False,
     layout="bhsd",
     name=None,
+    window=0,
 ):
     """Flash attention over q/k/v (Pallas kernel on TPU). layout="bhsd"
     (default): [b, nh, s, dh]; layout="bshd": [b, s, nh, dh] — the shape
@@ -1259,7 +1271,11 @@ def fused_multihead_attention(
     head transposes (they otherwise materialize as HBM relayout copies).
 
     `v`'s last dim may be narrower than `q`'s and `k`'s (latent attention:
-    192-wide scores, 128-wide values) and is then the output's.
+    192-wide scores, 128-wide values) and is then the output's. `k` and
+    `v` may have fewer heads than `q`, a divisor of its count (grouped
+    key/value heads): query head n reads key/value head n // group.
+    `window` > 0, with `causal`, admits only the last `window` keys a
+    query may see (key j for query i iff 0 <= i - j < window).
 
     `key_bias` is an additive [b, sv_len] bias (0 keep / large-negative
     mask). The unfused equivalent is matmul+softmax+dropout+matmul — this
@@ -1282,6 +1298,7 @@ def fused_multihead_attention(
             "sm_scale": float(sm_scale or 0.0),
             "is_test": is_test,
             "layout": layout,
+            "window": int(window),
         },
         dtype=q.dtype,
         shape=list(q.shape[:-1]) + [v.shape[-1]],

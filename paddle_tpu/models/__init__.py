@@ -1,8 +1,8 @@
 """Reference workload models (the benchmark's configurations + the
 reference's test model zoo), built through the framework's own layers API —
 LeNet-5 (MNIST), ResNet (ImageNet), SE-ResNeXt, VGG, Transformer/BERT
-(WMT16 / pretrain), DeepFM (CTR), Kimi Linear (a share of an
-expert-parallel decoder)."""
+(WMT16 / pretrain), DeepFM (CTR), Kimi Linear and Trinity (each a share
+of an expert-parallel decoder)."""
 
 from . import (  # noqa: F401
     bert,
@@ -12,6 +12,8 @@ from . import (  # noqa: F401
     resnet,
     se_resnext,
     transformer,
+    trinity,
     vgg,
 )
 from .kimi_linear import KimiLinearConfig, build_kimi_linear  # noqa: E402,F401
+from .trinity import TrinityConfig, build_trinity  # noqa: E402,F401

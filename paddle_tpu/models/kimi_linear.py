@@ -57,6 +57,8 @@ import math
 from .. import layers
 from ..initializer import Normal
 from ..param_attr import ParamAttr
+from .decoder_parts import (attr as _attr, expert_ffn as _expert_ffn,
+                            ffn as _ffn, norm as _norm, proj as _proj)
 
 __all__ = ["KimiLinearConfig", "build_kimi_linear"]
 
@@ -109,27 +111,6 @@ class KimiLinearConfig:
         self.l2norm_epsilon = l2norm_epsilon
 
 
-def _attr(name, cfg):
-    return ParamAttr(name=name, initializer=Normal(0.0, cfg.initializer_range))
-
-
-def _proj(x, size, name, cfg):
-    return layers.fc(x, size, num_flatten_dims=2,
-                     param_attr=_attr(name + ".w_0", cfg), bias_attr=False)
-
-
-def _norm(x, name, cfg, axis=2):
-    return layers.rms_norm(x, begin_norm_axis=axis, epsilon=cfg.rms_norm_eps,
-                           param_attr=ParamAttr(name=name + ".w_0"))
-
-
-def _ffn(u, width, name, cfg):
-    gate = layers.swish(_proj(u, width, name + ".gate", cfg))
-    up = _proj(u, width, name + ".up", cfg)
-    return _proj(layers.elementwise_mul(gate, up), cfg.hidden_size,
-                 name + ".down", cfg)
-
-
 def _kda_mixer(u, cfg, name):
     b, s, _ = u.shape
     h, d = cfg.num_heads, cfg.kda_head_dim
@@ -176,21 +157,6 @@ def _latent_mixer(u, cfg, name):
         layout="bshd")
     return _proj(layers.reshape(o, [b, s, h * dv]), cfg.hidden_size,
                  name + ".o", cfg)
-
-
-def _expert_ffn(u, cfg, name):
-    """Returns (what the shared expert and the held experts add, load)."""
-    routed, load = layers.moe_experts(
-        u, experts_total=cfg.num_experts, experts_held=cfg.experts_held,
-        d_ff=cfg.moe_intermediate_size, k=cfg.num_experts_per_token,
-        held_from=cfg.held_from, scaling=cfg.routed_scaling_factor,
-        renormalize=cfg.moe_renormalize, bias_scale=cfg.router_bias_scale,
-        param_attr=_attr(name + ".moe", cfg))
-    if not cfg.num_shared_experts:
-        return routed, load
-    shared = _ffn(u, cfg.moe_intermediate_size * cfg.num_shared_experts,
-                  name + ".shared", cfg)
-    return layers.elementwise_add(shared, routed), load
 
 
 def build_kimi_linear(cfg, batch_size, seq_len):

@@ -41,14 +41,25 @@ _logger = logging.getLogger(__name__)
 #          batch counts as much as length) or above the `flash_min_seq`
 #          of the checked-in table (ops/pallas/attn_dispatch_table.json).
 #          Measured on v5e at s=512: XLA 299 ms a step, the blocked kernel
-#          2,069: it pays only beyond the HBM knee. Measured at s=4,096 in
-#          a train step (Kimi Linear's latent layer, b=1, 32 heads, keys
-#          192 and values 128 wide, both padded to 256 lanes, causal): the
-#          kernel's four calls a step (forward twice, the gradient op runs
-#          it again; dq; dk and dv) take 20.7 ms, about 120 TFLOP/s on
-#          the work it does, which counts the masked half of the causal
-#          blocks, skipped by nothing, and the padding. XLA's path was not
-#          run there: its float32 scores of one row are 2.1 GB.
+#          2,069: it pays only beyond the HBM knee. The kernels visit only
+#          the blocks of scores in which `causal` and `window` admit a
+#          pair (a band; the masked part of a visited block is computed
+#          and thrown away), index the K and V blocks of a query head by
+#          `head // group` where K and V have fewer heads, and the forward
+#          op and its gradient op share one `flash_fwd` call. At Kimi
+#          Linear's latent layer (s=4,096, b=1, 32 heads, keys 192 and
+#          values 128 wide, both padded to 256 lanes, causal) the three
+#          calls a step now visit 36 of 64 blocks a head; when no block was
+#          skipped and the forward ran twice they took 20.7 ms (PERF.md has
+#          what they take now). At Trinity's layers (s=8,192, 32 query
+#          heads over 4 key/value heads of 128, three layers with a
+#          2,048-key window to one full) the fifteen calls a step take
+#          73 ms, 47% of peak on the pairs the masks admit; the kernel
+#          stays head-major there too: cutting a head's blocks from the
+#          [b, s, heads*128] arrays the projections write was built and
+#          measured 4.8% slower end to end (PERF.md, PR 33). XLA's path
+#          was run at neither: its float32 scores of one row are 2.1 GB
+#          and 8.6 GB.
 #          Values narrower than the keys are zero-padded to the keys'
 #          width for this kernel alone (the others take them as they are).
 #   xla    _xla_attention everywhere else: the "bhsd" layout, the CPU, and
@@ -150,12 +161,13 @@ def _flash_dispatch(qb, kb) -> str:
     return mode
 
 
-def _attn_dispatch(q, k, bshd, shards=1, same_width=True) -> str:
+def _attn_dispatch(q, k, bshd, shards=1, plain=True) -> str:
     """"short", "flash" or "xla" for the rows of the op's q/k that one
     device holds, a `shards`-th of the batch: the short-sequence kernel
     takes from `auto`'s XLA side the shapes it is built for, in the layout
-    whose operands it can read in place, where the values are as wide as
-    the keys (`same_width`)."""
+    whose operands it can read in place, where the attention is `plain`:
+    values as wide as the keys, as many key/value heads as query heads,
+    no window."""
     def bhsd(t):
         b, h, s, d = t.shape
         if bshd:
@@ -166,7 +178,7 @@ def _attn_dispatch(q, k, bshd, shards=1, same_width=True) -> str:
     path = _flash_dispatch(qb, kb)
     _, nh, sq, dh = qb.shape
     if (path == "xla" and bshd and _dispatch_mode() == "auto"
-            and same_width
+            and plain
             and _use_pallas() and mha_short_viable(sq, kb.shape[2], nh, dh)):
         return "short"
     return path
@@ -179,7 +191,10 @@ def _fused_mha(ctx, op):
     no head transposes anywhere in the graph); optional KeyBias: [b, sk]
     additive (0 keep, large-negative drop). Out matches the input layout.
     V's last dim may be narrower than Q's and K's (latent attention), and
-    is then Out's.
+    is then Out's. K and V may have fewer heads than Q, a divisor of its
+    count: query head n reads key/value head n // group. Attr `window`
+    (0: none; needs `causal`) admits only the last `window` keys a query
+    may see: key j for query i iff 0 <= i - j < window.
 
     Replaces the unfused matmul->softmax->dropout->matmul chain
     (reference model pattern, e.g. the Fluid transformer/BERT models) with
@@ -195,6 +210,11 @@ def _fused_mha(ctx, op):
     sm_scale = op.attr("sm_scale", 0.0) or None
     layout = op.attr("layout", "bhsd") or "bhsd"
     bshd = layout == "bshd"
+    window = int(op.attr("window", 0) or 0)
+    h_ax = 2 if bshd else 1
+    group = q.shape[h_ax] // k.shape[h_ax]
+    if window and not causal:
+        raise ValueError("fused_multihead_attention: a window needs causal")
 
     q, k, v = ctx.amp_cast(op, q, k, v)
     if bias is not None:
@@ -214,11 +234,15 @@ def _fused_mha(ctx, op):
         # (PADDLE_TPU_SP_MODE / the ring_min_seq auto-default) takes over
         # instead.
         dv = v.shape[-1]
-        path = (_attn_dispatch(q, k, bshd, shards, dv == q.shape[-1])
+        plain = dv == q.shape[-1] and group == 1 and not window
+        path = (_attn_dispatch(q, k, bshd, shards, plain)
                 if shards else "xla")
         if shards > 1 and path != "short":
             path = "xla"
         profiler.bump_counter(f"attn_dispatch_{path}")
+        if path == "flash" and window:
+            profiler.bump_counter("attn_dispatch_flash_window")
+        profiler.set_counter("attn_kv_group", group)
         if path == "short":
             # [b, s, nh, dh] back to the [b, s, nh*dh] the projection
             # wrote: XLA folds this with the Program's reshape2 into nothing
@@ -235,8 +259,7 @@ def _fused_mha(ctx, op):
         if path == "xla":
             scale = sm_scale or 1.0 / float(np.sqrt(q.shape[-1]))
             return _xla_attention(q, k, v, bias, causal, scale, dropout,
-                                  rng, layout=layout)
-
+                                  rng, layout=layout, window=window)
         def swap(t):  # bshd <-> bhsd; the flash kernel is head-major
             return jnp.transpose(t, (0, 2, 1, 3)) if bshd else t
 
@@ -250,7 +273,7 @@ def _fused_mha(ctx, op):
             v = jnp.pad(v, [(0, 0)] * 3 + [(0, q.shape[-1] - dv)])
         return swap(flash_attention(
             swap(q), swap(k), swap(v), bias=bias, causal=causal,
-            sm_scale=sm_scale, dropout=dropout, rng_key=rng,
+            sm_scale=sm_scale, dropout=dropout, rng_key=rng, window=window,
         ))[..., :dv]
 
     mesh = ctx.mesh
@@ -312,6 +335,10 @@ def _fused_mha(ctx, op):
             "sequence or resize the mesh for "
             f"PADDLE_TPU_SP_MODE={sp_mode}"
         )
+    if sp_mode and model_n > 1 and (window or group != 1):
+        raise ValueError(
+            "fused_multihead_attention: ring and ulysses sequence "
+            "parallelism take neither a window nor grouped key/value heads")
     if sp_mode and model_n > 1:
         # sequence parallelism over the unified mesh's 'model' axis: the
         # attention runs on GLOBAL arrays and GSPMD places the

@@ -570,6 +570,38 @@ def _rms_norm(ctx, op):
     ctx.out(op, "Y", y.astype(x.dtype))
 
 
+def rotate_half(x, theta):
+    """Rotary positions on [b, s, h, d], positions 0..s-1, the rotate-half
+    convention (Su et al. 2021, arXiv:2104.09864, as the public
+    `transformers` code lays it out): with `a_i = p * theta^(-2i/d)` for
+    i < d/2, `y[..., i] = x[..., i] cos a_i - x[..., i + d/2] sin a_i` and
+    `y[..., i + d/2] = x[..., i + d/2] cos a_i + x[..., i] sin a_i`.
+    float32 inside whatever x arrives in: a bf16 angle at position 8,191
+    is off by whole turns."""
+    s, d = x.shape[1], x.shape[3]
+    # the published form, 1 / theta^(2i/d) in float32: another way round
+    # the power differs by an ulp, which position 8,191 makes 4e-4 rad
+    freq = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * freq[None, :]
+    cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[None, :, None, :]
+    # the half-turn's sign rides the sine: rotate_half(x) = [-x2, x1]
+    sin = jnp.concatenate([-jnp.sin(angle), jnp.sin(angle)], -1)[
+        None, :, None, :]
+    xf = x.astype(jnp.float32)
+    return (xf * cos + jnp.roll(xf, d // 2, axis=-1) * sin).astype(x.dtype)
+
+
+@register_op("rotary_embedding")
+def _rotary_embedding(ctx, op):
+    """X: [b, s, heads, d], d even; attr `theta`. Out has X's shape and
+    dtype (`rotate_half`)."""
+    x = ctx.in_(op, "X")
+    if x.ndim != 4 or x.shape[3] % 2:
+        raise ValueError(
+            f"rotary_embedding: X {x.shape}: expected [b, s, heads, d], d even")
+    ctx.out(op, "Out", rotate_half(x, float(op.attr("theta", 10000.0))))
+
+
 @register_op("layer_norm_grad", differentiable=False)
 def _layer_norm_grad(ctx, op):
     """dX, dScale, dBias from the saved per-row stats; the normalized

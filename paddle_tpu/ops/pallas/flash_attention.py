@@ -7,10 +7,28 @@ designed MXU/VMEM-first instead: blocked online-softmax so the [s, s]
 score matrix never hits HBM, fp32 accumulation, optional in-kernel
 dropout regenerated (not stored) in the backward pass.
 
-Layout: q [b, h, sq, d], k/v [b, h, sk, d], optional additive key bias
-[b, sk] (the padding-mask case), `causal` flag. Head dim is zero-padded
-to a lane multiple (128); sequence dims are padded to block multiples
-with fully-masked keys.
+Layout: q [b, h, sq, d], k/v [b, hkv, sk, d] with `h` a multiple of
+`hkv` (grouped key/value heads: the K and V blocks of query head `n` are
+indexed `n // group`, `flash_bwd_dkv` runs over the key/value heads and
+sums over its group's query heads, and K and V are never repeated),
+optional additive key bias [b, sk] (the padding-mask case), `causal`
+flag, `window` (with `causal`: the last `window` keys a query may see).
+Head dim is zero-padded to a lane multiple (128); sequence dims are
+padded to block multiples with fully-masked keys. The arrays are
+head-major: cutting a head's blocks from the lanes of [b, s, heads*128]
+arrays as the projections write them was built and measured at s=8,192
+(PERF.md, PR 33): the strided blocks cost the kernels 8% and the step as
+a whole ran 4.8% slower than with the four transposes, which XLA folds
+into the relayouts it makes around the per-head norms anyway.
+
+What the grids skip: a block of scores in which the masks admit no pair
+(above the causal diagonal, below the window's edge) is neither copied
+nor computed, in `flash_fwd`, `flash_bwd_dq` and `flash_bwd_dkv` alike
+(`_key_band`, `_query_band`). Inside a visited block masked pairs are
+still computed and thrown away: with blocks of 512 the causal kernel
+visits 53% of the rectangle at 16 x 16 blocks where the mask admits 50%,
+and a 2,048-key window on 8,192 tokens 27.3% where it admits 21.9%.
+Without `causal` every block is visited, as before.
 
 Mosaic compiles the kernel on a TPU. PADDLE_TPU_PALLAS_INTERPRET=1 runs
 it in the Pallas interpreter so CPU tests exercise the real kernel body;
@@ -21,12 +39,15 @@ from __future__ import annotations
 
 import functools
 import os
+import typing
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ... import profiler
 
 NEG_INF = -1e30
 LANE = 128
@@ -82,6 +103,115 @@ def _dropout_keep(seed, bh_idx, q0, k0, shape, dropout):
 
 
 # ---------------------------------------------------------------------------
+# which blocks a grid visits
+# ---------------------------------------------------------------------------
+#
+# One predicate, "this [block_q, block_k] block of scores can hold a pair
+# the masks admit", from `causal`, `causal_offset` and `window`. A query
+# `qi` admits the key `ki` iff `ki <= qi + causal_offset` (causal) and
+# `qi + causal_offset - ki < window` (window; 0: none), so the blocks a
+# query block can see are a run of key blocks, and the blocks that can see
+# a key block a run of query blocks: a band. The two functions below give
+# the run's ends; they are the same predicate read along either axis.
+# Each kernel's innermost grid axis is as long as the longest run, a step
+# past a run's end points at the run's last block again (the pipeline
+# copies nothing when the block index stays) and computes nothing. `xp`
+# is `jnp` inside an index map or a kernel and `np` where blocks are
+# counted.
+
+
+def _key_band(j, m, xp=jnp):
+    """(first, last) key block of query block `j`."""
+    first, last = 0 * j, 0 * j + (m.nk - 1)
+    if m.window:
+        first = xp.minimum(xp.maximum(
+            j * m.block_q + m.causal_offset - m.window + 1, 0) // m.block_k,
+            m.nk - 1)
+    if m.causal:
+        last = xp.minimum(xp.maximum(
+            (j + 1) * m.block_q - 1 + m.causal_offset, 0) // m.block_k,
+            m.nk - 1)
+    return first, xp.maximum(last, first)
+
+
+def _query_band(kb, m, xp=jnp):
+    """(first, last) query block of key block `kb`."""
+    first, last = 0 * kb, 0 * kb + (m.nq - 1)
+    if m.causal:
+        first = xp.minimum(xp.maximum(
+            kb * m.block_k - m.causal_offset, 0) // m.block_q, m.nq - 1)
+    if m.window:
+        last = xp.minimum(xp.maximum(
+            (kb + 1) * m.block_k - 1 - m.causal_offset + m.window - 1, 0)
+            // m.block_q, m.nq - 1)
+    return first, xp.maximum(last, first)
+
+
+class _Masks(typing.NamedTuple):
+    """One call's masks and blocks: static and hashable, so a jitted call
+    takes it as a static argument."""
+
+    causal: bool
+    causal_offset: int
+    window: int
+    block_q: int
+    block_k: int
+    nq: int
+    nk: int
+
+    @classmethod
+    def of(cls, sq, sk, *, causal, causal_offset, window, block_q, block_k):
+        return cls(causal, causal_offset, window, block_q, block_k,
+                   sq // block_q, sk // block_k)
+
+    def key_steps(self):
+        """Length of the key axis of `flash_fwd` and `flash_bwd_dq`."""
+        first, last = _key_band(np.arange(self.nq), self, np)
+        return int((last - first).max()) + 1
+
+    def query_steps(self):
+        """Query blocks a key block's run holds at most (`flash_bwd_dkv`)."""
+        first, last = _query_band(np.arange(self.nk), self, np)
+        return int((last - first).max()) + 1
+
+    def visited(self):
+        """Blocks one head's three grids compute, and the rectangles'."""
+        first, last = _key_band(np.arange(self.nq), self, np)
+        fwd = int((last - first + 1).sum())
+        first, last = _query_band(np.arange(self.nk), self, np)
+        return 2 * fwd + int((last - first + 1).sum()), 3 * self.nq * self.nk
+
+
+def _block_of(band, i, t, m):
+    """The `t`-th block of `i`'s run, or the run's last block past its
+    end: the index stays, so the pipeline copies nothing."""
+    first, last = band(i, m)
+    return jnp.minimum(first + t, last)
+
+
+def _admitted(s, j, kb, m):
+    """The scores of block (j, kb) with what the masks refuse at NEG_INF."""
+    if not m.causal:
+        return s
+    qi = j * m.block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    ki = kb * m.block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    # bottom-right aligned: query row qi sees keys up to qi + offset
+    keep = qi + m.causal_offset >= ki
+    if m.window:
+        keep = keep & (qi + m.causal_offset - ki < m.window)
+    return jnp.where(keep, s, NEG_INF)
+
+
+def _head_maps(group):
+    """Block index maps over [heads over the batch, s, d] arrays: a query
+    head's block, a key/value head's, and the key/value block that query
+    head `n` reads, which is head `n // group`'s."""
+    q_at = lambda n, j: (n, j, 0)
+    kv_at = lambda i, kb: (i, kb, 0)
+    return q_at, kv_at, (lambda n, kb: (n // group, kb, 0))
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
@@ -99,68 +229,66 @@ def _fwd_kernel(
     acc_scr,
     *,
     sm_scale,
-    causal,
-    causal_offset,
     dropout,
-    block_q,
-    block_k,
-    nk,
+    masks,
+    steps,
 ):
     j = pl.program_id(1)
-    kb = pl.program_id(2)
+    t = pl.program_id(2)
+    first, last = _key_band(j, masks)
+    kb = first + t
+    block_q, block_k = masks.block_q, masks.block_k
 
-    @pl.when(kb == 0)
+    @pl.when(t == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # dots run in the input dtype (bf16 on the MXU) accumulating fp32;
-    # only the softmax math stays fp32
-    q = q_ref[0]
-    k = k_ref[0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    s = s * sm_scale
-    if bias_ref is not None:
-        s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
-    if causal:
-        # bottom-right aligned: query row qi sees keys up to qi + offset
-        qi = j * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
+    def _visit():
+        # dots run in the input dtype (bf16 on the MXU) accumulating fp32;
+        # only the softmax math stays fp32
+        q = q_ref[0]
+        k = k_ref[0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        ki = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        s = jnp.where(qi + causal_offset >= ki, s, NEG_INF)
+        s = s * sm_scale
+        if bias_ref is not None:
+            s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
+        s = _admitted(s, j, kb, masks)
 
-    m_prev = m_scr[:, :1]
-    l_prev = l_scr[:, :1]
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        m_prev = m_scr[:, :1]
+        l_prev = l_scr[:, :1]
+        m_cur = jnp.max(s, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
 
-    if dropout > 0.0:
-        keep = _dropout_keep(
-            seed_ref[0], pl.program_id(0), j * block_q, kb * block_k,
-            p.shape, dropout,
+        if dropout > 0.0:
+            keep = _dropout_keep(
+                seed_ref[0], pl.program_id(0), j * block_q, kb * block_k,
+                p.shape, dropout,
+            )
+            p_use = jnp.where(keep, p / (1.0 - dropout), 0.0)
+        else:
+            p_use = p
+
+        v = v_ref[0]
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p_use.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
-        p_use = jnp.where(keep, p / (1.0 - dropout), 0.0)
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    if masks.causal:
+        pl.when(kb <= last)(_visit)
     else:
-        p_use = p
+        _visit()
 
-    v = v_ref[0]
-    acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-        p_use.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(kb == nk - 1)
+    @pl.when(t == steps - 1)
     def _finalize():
         l = l_scr[:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -168,19 +296,30 @@ def _fwd_kernel(
         lse_ref[0, 0] = (m_scr[:, 0] + jnp.log(l_safe[:, 0])).astype(jnp.float32)
 
 
-def _fwd_pallas(q, k, v, bias, seed, h, *, sm_scale, causal, causal_offset, dropout, block_q, block_k):
+def _fwd_pallas(q, k, v, bias, seed, h, *, sm_scale, causal, causal_offset,
+                dropout, block_q, block_k, window=0):
+    """q: [b*h, sq, d] and k, v: [b*hkv, sk, d], whole blocks. Returns the
+    output like q and the log-sum-exp rows, [b*h, 1, sq] float32."""
     bh, sq, d = q.shape
     sk = k.shape[1]
-    nq, nk = sq // block_q, sk // block_k
+    masks = _Masks.of(sq, sk, causal=causal, causal_offset=causal_offset,
+                      window=window, block_q=block_q, block_k=block_k)
+    steps = masks.key_steps()
+    q_at, _, k_at = _head_maps(bh // k.shape[0])
+
+    qspec = lambda n, j, t: q_at(n, j)
+    kspec = lambda n, j, t: k_at(n, _block_of(_key_band, j, t, masks))
 
     bias_spec = []
     bias_args = []
     if bias is not None:
-        # bias is [bh, 1, sk]: 3-D so the block's trailing dims obey the
+        # bias is [b, 1, sk]: 3-D so the block's trailing dims obey the
         # (8, 128) tiling rule (middle dim 1 == array dim)
         bias_spec = [
             pl.BlockSpec(
-                (1, 1, block_k), lambda i, j, kb: (i // h, 0, kb),
+                (1, 1, block_k),
+                lambda n, j, t: (n // h, 0,
+                                 _block_of(_key_band, j, t, masks)),
                 memory_space=pltpu.VMEM,
             )
         ]
@@ -189,30 +328,27 @@ def _fwd_pallas(q, k, v, bias, seed, h, *, sm_scale, causal, causal_offset, drop
     kernel = functools.partial(
         _fwd_kernel if bias is not None else _fwd_kernel_nobias,
         sm_scale=sm_scale,
-        causal=causal,
-        causal_offset=causal_offset,
         dropout=dropout,
-        block_q=block_q,
-        block_k=block_k,
-        nk=nk,
+        masks=masks,
+        steps=steps,
     )
 
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh, nq, nk),
+        grid=(bh, masks.nq, steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # seed
-            pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_q, d), qspec, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, d), kspec, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, d), kspec, memory_space=pltpu.VMEM),
             *bias_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_q), lambda i, j, kb: (i, 0, j), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_q, d), qspec, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, block_q), lambda n, j, t: (n, 0, j), memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
@@ -248,54 +384,56 @@ def _bwd_dq_kernel(
     dq_scr,
     *,
     sm_scale,
-    causal,
-    causal_offset,
     dropout,
-    block_q,
-    block_k,
-    nk,
+    masks,
+    steps,
 ):
     j = pl.program_id(1)
-    kb = pl.program_id(2)
+    t = pl.program_id(2)
+    first, last = _key_band(j, masks)
+    kb = first + t
 
-    @pl.when(kb == 0)
+    @pl.when(t == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0, 0].astype(jnp.float32)[:, None]
-    delta = delta_ref[0, 0].astype(jnp.float32)[:, None]
+    def _visit():
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]
+        do = do_ref[0]
+        lse = lse_ref[0, 0].astype(jnp.float32)[:, None]
+        delta = delta_ref[0, 0].astype(jnp.float32)[:, None]
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale
-    if bias_ref is not None:
-        s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
-    if causal:
-        qi = j * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        ki = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(qi + causal_offset >= ki, s, NEG_INF)
-    p = jnp.exp(s - lse)  # normalized probs (fp32)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * sm_scale
+        if bias_ref is not None:
+            s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
+        s = _admitted(s, j, kb, masks)
+        p = jnp.exp(s - lse)  # normalized probs (fp32)
 
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    if dropout > 0.0:
-        keep = _dropout_keep(
-            seed_ref[0], pl.program_id(0), j * block_q, kb * block_k,
-            dp.shape, dropout,
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        dp = jnp.where(keep, dp / (1.0 - dropout), 0.0)
-    ds = p * (dp - delta) * sm_scale
-    dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+        if dropout > 0.0:
+            keep = _dropout_keep(
+                seed_ref[0], pl.program_id(0), j * masks.block_q,
+                kb * masks.block_k, dp.shape, dropout,
+            )
+            dp = jnp.where(keep, dp / (1.0 - dropout), 0.0)
+        ds = p * (dp - delta) * sm_scale
+        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
-    @pl.when(kb == nk - 1)
+    if masks.causal:
+        pl.when(kb <= last)(_visit)
+    else:
+        _visit()
+
+    @pl.when(t == steps - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
@@ -321,62 +459,68 @@ def _bwd_dkv_kernel(
     dv_scr,
     *,
     sm_scale,
-    causal,
-    causal_offset,
     dropout,
-    block_q,
-    block_k,
-    nq,
+    masks,
+    steps,
+    group,
 ):
+    # one key/value head and one key block a (i, kb); the innermost axis
+    # runs over the group's query heads and, for each, the run of query
+    # blocks that can see this key block
     kb = pl.program_id(1)
-    j = pl.program_id(2)
+    r = pl.program_id(2)
+    first, last = _query_band(kb, masks)
+    j = first + r % steps
 
-    @pl.when(j == 0)
+    @pl.when(r == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0, 0].astype(jnp.float32)[:, None]
-    delta = delta_ref[0, 0].astype(jnp.float32)[:, None]
+    def _visit():
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]
+        do = do_ref[0]
+        lse = lse_ref[0, 0].astype(jnp.float32)[:, None]
+        delta = delta_ref[0, 0].astype(jnp.float32)[:, None]
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale
-    if bias_ref is not None:
-        s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
-    if causal:
-        qi = j * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        ki = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(qi + causal_offset >= ki, s, NEG_INF)
-    p = jnp.exp(s - lse)  # [bq, bk]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * sm_scale
+        if bias_ref is not None:
+            s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
+        s = _admitted(s, j, kb, masks)
+        p = jnp.exp(s - lse)  # [bq, bk]
 
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    if dropout > 0.0:
-        keep = _dropout_keep(
-            seed_ref[0], pl.program_id(0), j * block_q, kb * block_k,
-            p.shape, dropout,
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        p_drop = jnp.where(keep, p / (1.0 - dropout), 0.0)
-        dp = jnp.where(keep, dp / (1.0 - dropout), 0.0)
-    else:
-        p_drop = p
-    dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-        p_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    ds = p * (dp - delta) * sm_scale
-    dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+        if dropout > 0.0:
+            keep = _dropout_keep(
+                seed_ref[0], pl.program_id(0) * group + r // steps,
+                j * masks.block_q, kb * masks.block_k, p.shape, dropout,
+            )
+            p_drop = jnp.where(keep, p / (1.0 - dropout), 0.0)
+            dp = jnp.where(keep, dp / (1.0 - dropout), 0.0)
+        else:
+            p_drop = p
+        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
+            p_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        ds = p * (dp - delta) * sm_scale
+        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
-    @pl.when(j == nq - 1)
+    if masks.causal:
+        pl.when(j <= last)(_visit)
+    else:
+        _visit()
+
+    @pl.when(r == group * steps - 1)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -388,32 +532,46 @@ def _bwd_dkv_nobias(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, d
     )
 
 
-def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal, causal_offset, dropout, block_q, block_k, delta=None):
+def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
+                causal_offset, dropout, block_q, block_k, delta=None,
+                window=0):
     bh, sq, d = q.shape
-    sk = k.shape[1]
-    nq, nk = sq // block_q, sk // block_k
+    bhkv, sk = k.shape[0], k.shape[1]
+    group = bh // bhkv
+    hkv = h // group
+    masks = _Masks.of(sq, sk, causal=causal, causal_offset=causal_offset,
+                      window=window, block_q=block_q, block_k=block_k)
+    q_at, kv_at, k_at = _head_maps(group)
 
     if delta is None:
         delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)[:, None, :]
 
-    common = dict(sm_scale=sm_scale, causal=causal,
-                  causal_offset=causal_offset, dropout=dropout,
-                  block_q=block_q, block_k=block_k)
-    qspec = lambda i, j, kb: (i, j, 0)
-    kspec = lambda i, j, kb: (i, kb, 0)
-    rowspec = lambda i, j, kb: (i, 0, j)
+    common = dict(sm_scale=sm_scale, dropout=dropout, masks=masks)
+
+    # ---- dq: the forward's grid --------------------------------------
+    steps = masks.key_steps()
+
+    qspec = lambda n, j, t: q_at(n, j)
+    kspec = lambda n, j, t: k_at(n, _block_of(_key_band, j, t, masks))
+    rowspec = lambda n, j, t: (n, 0, j)
 
     bias_in, bias_specs_q, bias_specs_k = [], [], []
     if bias is not None:
         bias_in = [bias]
-        bias_specs_q = [pl.BlockSpec((1, 1, block_k), lambda i, j, kb: (i // h, 0, kb), memory_space=pltpu.VMEM)]
-        bias_specs_k = [pl.BlockSpec((1, 1, block_k), lambda i, kb, j: (i // h, 0, kb), memory_space=pltpu.VMEM)]
+        bias_specs_q = [pl.BlockSpec(
+            (1, 1, block_k),
+            lambda n, j, t: (n // h, 0, _block_of(_key_band, j, t, masks)),
+            memory_space=pltpu.VMEM)]
+        bias_specs_k = [pl.BlockSpec(
+            (1, 1, block_k), lambda i, kb, r: (i // hkv, 0, kb),
+            memory_space=pltpu.VMEM)]
 
     dq = pl.pallas_call(
         functools.partial(
-            _bwd_dq_kernel if bias is not None else _bwd_dq_nobias, nk=nk, **common
+            _bwd_dq_kernel if bias is not None else _bwd_dq_nobias,
+            steps=steps, **common
         ),
-        grid=(bh, nq, nk),
+        grid=(bh, masks.nq, steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), qspec, memory_space=pltpu.VMEM),
@@ -425,20 +583,27 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal, causa
             *bias_specs_q,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), qspec, memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
         name="flash_bwd_dq",
     )(seed, q, k, v, do, lse, delta, *bias_in)
 
-    kq = lambda i, kb, j: (i, j, 0)
-    kk = lambda i, kb, j: (i, kb, 0)
-    krow = lambda i, kb, j: (i, 0, j)
+    # ---- dk, dv: a key/value head a row of the grid -------------------
+    steps = masks.query_steps()
+
+    def query_block(kb, r):
+        return _block_of(_query_band, kb, r % steps, masks)
+
+    kq = lambda i, kb, r: q_at(i * group + r // steps, query_block(kb, r))
+    kk = lambda i, kb, r: kv_at(i, kb)
+    krow = lambda i, kb, r: (i * group + r // steps, 0, query_block(kb, r))
     dk, dv = pl.pallas_call(
         functools.partial(
-            _bwd_dkv_kernel if bias is not None else _bwd_dkv_nobias, nq=nq, **common
+            _bwd_dkv_kernel if bias is not None else _bwd_dkv_nobias,
+            steps=steps, group=group, **common
         ),
-        grid=(bh, nk, nq),
+        grid=(bhkv, masks.nk, group * steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), kq, memory_space=pltpu.VMEM),
@@ -454,8 +619,8 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal, causa
             pl.BlockSpec((1, block_k, d), kk, memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -471,36 +636,30 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal, causa
 # public entry: custom_vjp over padded/flattened layout
 # ---------------------------------------------------------------------------
 
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
-def _flash_core(q, k, v, bias, seed, h, sm_scale, causal, causal_offset,
-                dropout, block_q, block_k):
-    out, _ = _fwd_pallas(
-        q, k, v, bias, seed, h,
-        sm_scale=sm_scale, causal=causal, causal_offset=causal_offset,
-        dropout=dropout, block_q=block_q, block_k=block_k,
-    )
-    return out
+# One jitted call for a forward that is differentiated and one that is
+# not: a Program's gradient op lowers its forward op again, and XLA merges
+# the two custom calls only if they are the same call (a kernel traced
+# under the vjp rule is named `jvp_flash_fwd_` and is another).
+_STATICS = ("sm_scale", "causal", "causal_offset", "dropout", "block_q",
+            "block_k", "window")
+_fwd_call = jax.jit(_fwd_pallas, static_argnums=(5,), static_argnames=_STATICS)
+_bwd_call = jax.jit(_bwd_pallas, static_argnums=(8,), static_argnames=_STATICS)
 
 
-def _flash_core_fwd(q, k, v, bias, seed, h, sm_scale, causal, causal_offset,
-                    dropout, block_q, block_k):
-    out, lse = _fwd_pallas(
-        q, k, v, bias, seed, h,
-        sm_scale=sm_scale, causal=causal, causal_offset=causal_offset,
-        dropout=dropout, block_q=block_q, block_k=block_k,
-    )
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash_core(q, k, v, bias, seed, h, statics):
+    return _fwd_call(q, k, v, bias, seed, h, **dict(statics))[0]
+
+
+def _flash_core_fwd(q, k, v, bias, seed, h, statics):
+    out, lse = _fwd_call(q, k, v, bias, seed, h, **dict(statics))
     return out, (q, k, v, bias, seed, out, lse)
 
 
-def _flash_core_bwd(h, sm_scale, causal, causal_offset, dropout, block_q,
-                    block_k, res, do):
+def _flash_core_bwd(h, statics, res, do):
     q, k, v, bias, seed, out, lse = res
-    dq, dk, dv = _bwd_pallas(
-        q, k, v, bias, seed, out, lse, do, h,
-        sm_scale=sm_scale, causal=causal, causal_offset=causal_offset,
-        dropout=dropout, block_q=block_q, block_k=block_k,
-    )
+    dq, dk, dv = _bwd_call(q, k, v, bias, seed, out, lse, do, h,
+                           **dict(statics))
     dbias = None if bias is None else jnp.zeros_like(bias)
     dseed = np.zeros((1,), dtype=jax.dtypes.float0)
     return dq, dk, dv, dbias, dseed
@@ -510,23 +669,22 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
 def _pad_inputs(q, k, v, bias, block_q, block_k):
-    """Flatten [b, h, s, d] -> [b*h, s_p, d_p] with lane/sublane padding for
-    the kernels: block sizes sublane-aligned (16 covers bf16's (16, 128) min
-    tile), head dim padded to a lane multiple, sequence dims padded to block
-    multiples with padded keys masked via NEG_INF bias. Shared by the flash
-    and ring entry points so their layouts (and dropout-mask coordinates)
-    stay bit-compatible. Returns (qf, kf, vf, biasf, bq, bk); biasf is
+    """Flatten [b, h, s, d] -> [b*h, s_p, d_p] (k and v may have fewer
+    heads than q) with lane/sublane padding for the kernels: block sizes
+    sublane-aligned (16 covers bf16's (16, 128) min tile), head dim padded
+    to a lane multiple, sequence dims padded to block multiples with
+    padded keys masked via NEG_INF bias. Shared by the flash and ring
+    entry points so their layouts (and dropout-mask coordinates) stay
+    bit-compatible. Returns (qf, kf, vf, biasf, bq, bk); biasf is
     [b, 1, sk_p] or None."""
-    b, h, sq, d = q.shape
+    b, _, sq, d = q.shape
     sk = k.shape[2]
     bq = min(block_q or 512, _ceil_to(max(LANE, sq), 16))
     bk = min(block_k or 512, _ceil_to(max(LANE, sk), 16))
     bq, bk = _ceil_to(bq, 16), _ceil_to(bk, 16)
     sq_p, sk_p, d_p = _ceil_to(sq, bq), _ceil_to(sk, bk), _ceil_to(d, LANE)
 
-    qf = q.reshape(b * h, sq, d)
-    kf = k.reshape(b * h, sk, d)
-    vf = v.reshape(b * h, sk, d)
+    qf, kf, vf = (t.reshape(-1, t.shape[2], d) for t in (q, k, v))
     if d_p != d:
         pad = [(0, 0), (0, 0), (0, d_p - d)]
         qf, kf, vf = (jnp.pad(x, pad) for x in (qf, kf, vf))
@@ -547,10 +705,11 @@ def _pad_inputs(q, k, v, bias, block_q, block_k):
 
 
 def _attention_unfused(q, k, v, bias, causal, sm_scale, dropout, rng_key,
-                       f32_residuals, layout="bhsd"):
+                       f32_residuals, layout="bhsd", window=0):
     """One implementation of the plain-XLA attention semantics (bias /
-    bottom-right-aligned causal mask / murmur-hash dropout — the contract
-    the Pallas kernels are validated against), with the dtype discipline
+    bottom-right-aligned causal mask, with `window` > 0 only the last
+    `window` keys of it / murmur-hash dropout — the contract the Pallas
+    kernels are validated against), with the dtype discipline
     parameterized:
 
     f32_residuals=True — the all-f32 gold (_reference_attention): scores
@@ -569,8 +728,26 @@ def _attention_unfused(q, k, v, bias, causal, sm_scale, dropout, rng_key,
     BATCH dims instead of an explicit [b, h, s, d] transpose — the
     round-4 xplane showed those transposes materialize as ~0.15 ms HBM
     relayout copies per q/k/v per layer on BERT (and 26% of device time
-    on Transformer-base)."""
-    if layout == "bshd":
+    on Transformer-base).
+
+    K and V may have fewer heads than Q: query head `n` reads key/value
+    head `n // group`. The group is a batch dimension of the products, so
+    K and V are not repeated."""
+    bshd = layout == "bshd"
+    h_ax = 2 if bshd else 1
+    h, hkv = q.shape[h_ax], k.shape[h_ax]
+    grouped = h != hkv
+    if grouped:
+        if h % hkv:
+            raise ValueError(
+                f"attention: {h} query heads over {hkv} key/value heads")
+        shape = list(q.shape)
+        shape[h_ax:h_ax + 1] = [hkv, h // hkv]
+        qg = q.reshape(shape)
+        s = jnp.einsum("bqngd,bknd->bngqk" if bshd else "bngqd,bnkd->bngqk",
+                       qg, k)
+        s = s.reshape(s.shape[0], h, *s.shape[3:])
+    elif bshd:
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k)
     else:
         s = jnp.einsum("bhqd,bhkd->bhqk", q, k)
@@ -582,7 +759,12 @@ def _attention_unfused(q, k, v, bias, causal, sm_scale, dropout, rng_key,
     if causal:
         sq, sk = sf.shape[-2], sf.shape[-1]
         mask = np.tril(np.ones((sq, sk), np.bool_), k=sk - sq)
+        if window:
+            mask &= np.triu(np.ones((sq, sk), np.bool_),
+                            k=sk - sq - window + 1)
         sf = jnp.where(mask, sf, NEG_INF)
+    elif window:
+        raise ValueError("attention: a window needs causal=True")
     p = jax.nn.softmax(sf, axis=-1)
     if not f32_residuals:
         p = p.astype(q.dtype)
@@ -594,24 +776,32 @@ def _attention_unfused(q, k, v, bias, causal, sm_scale, dropout, rng_key,
         keep, keep_prob = _dropout_keep_mask(rng_key, dropout, p.shape)
         p = jnp.where(keep, p / jnp.asarray(keep_prob, p.dtype),
                       jnp.zeros((), p.dtype))
-    if layout == "bshd":
-        out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(p.dtype))
+    v = v.astype(p.dtype)
+    if grouped:
+        pg = p.reshape(p.shape[0], hkv, h // hkv, *p.shape[2:])
+        out = jnp.einsum("bngqk,bknd->bqngd" if bshd else "bngqk,bnkd->bngqd",
+                         pg, v)
+        out = out.reshape(q.shape[:-1] + (v.shape[-1],))
+    elif bshd:
+        out = jnp.einsum("bhqk,bkhd->bqhd", p, v)
     else:
-        out = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(p.dtype))
+        out = jnp.einsum("bhqk,bhkd->bhqd", p, v)
     return out.astype(q.dtype)
 
 
-def _reference_attention(q, k, v, bias, causal, sm_scale, dropout, rng_key):
+def _reference_attention(q, k, v, bias, causal, sm_scale, dropout, rng_key,
+                         window=0):
     """All-f32 gold (CPU tests / kernel validation / ragged shapes)."""
     return _attention_unfused(q, k, v, bias, causal, sm_scale, dropout,
-                              rng_key, f32_residuals=True)
+                              rng_key, f32_residuals=True, window=window)
 
 
 def _xla_attention(q, k, v, bias, causal, sm_scale, dropout, rng_key,
-                   layout="bhsd"):
+                   layout="bhsd", window=0):
     """Production below-cutover fallback: input-dtype HBM discipline."""
     return _attention_unfused(q, k, v, bias, causal, sm_scale, dropout,
-                              rng_key, f32_residuals=False, layout=layout)
+                              rng_key, f32_residuals=False, layout=layout,
+                              window=window)
 
 
 def flash_attention(
@@ -625,11 +815,15 @@ def flash_attention(
     rng_key=None,
     block_q=None,
     block_k=None,
+    window=0,
 ):
     """Fused multi-head attention.
 
-    q: [b, h, sq, d]; k, v: [b, h, sk, d]; bias: additive key bias [b, sk]
-    (0 keep / -inf drop) or None. Returns [b, h, sq, d] in q's dtype.
+    q: [b, h, sq, d]; k, v: [b, hkv, sk, d], `h` a multiple of `hkv`
+    (query head `n` reads key/value head `n // (h // hkv)`); bias:
+    additive key bias [b, sk] (0 keep / -inf drop) or None. `window` > 0
+    (with `causal`) admits only the last `window` keys a query may see.
+    Returns [b, h, sq, d] in q's dtype.
     """
     b, h, sq, d = q.shape
     sk = k.shape[2]
@@ -637,6 +831,11 @@ def flash_attention(
         sm_scale = 1.0 / float(np.sqrt(d))
 
     require_pallas("flash_attention")
+    if h % k.shape[1]:
+        raise ValueError(f"flash_attention: {h} query heads over "
+                         f"{k.shape[1]} key/value heads")
+    if window and not causal:
+        raise ValueError("flash_attention: a window needs causal=True")
     if dropout > 0.0 and rng_key is None:
         raise ValueError("dropout requires rng_key")
     if dropout > 0.0:
@@ -651,10 +850,15 @@ def flash_attention(
     # padded q rows are sliced away and padded keys are bias-masked
     causal_offset = sk - sq
     qf, kf, vf, biasf, bq, bk = _pad_inputs(q, k, v, bias, block_q, block_k)
+    masks = _Masks.of(qf.shape[1], kf.shape[1], causal=bool(causal),
+                      causal_offset=causal_offset, window=int(window),
+                      block_q=bq, block_k=bk)
+    visited, total = masks.visited()
+    profiler.bump_counter("flash_blocks_visited", b * h * visited)
+    profiler.bump_counter("flash_blocks_total", b * h * total)
 
-    out = _flash_core(
-        qf, kf, vf, biasf, seed, h, sm_scale, causal, causal_offset,
-        float(dropout), bq, bk,
-    )
-    out = out[:, :sq, :d].reshape(b, h, sq, d)
-    return out
+    statics = (("sm_scale", float(sm_scale)), ("causal", bool(causal)),
+               ("causal_offset", causal_offset), ("dropout", float(dropout)),
+               ("block_q", bq), ("block_k", bk), ("window", int(window)))
+    out = _flash_core(qf, kf, vf, biasf, seed, h, statics)
+    return out[:, :sq, :d].reshape(b, h, sq, d)

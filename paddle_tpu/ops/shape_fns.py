@@ -860,6 +860,11 @@ def _shape_rms_norm(ictx, op):
     ictx.out(op, "Y", _m(ictx.in_(op, "X")))
 
 
+@register_shape("rotary_embedding")
+def _shape_rotary_embedding(ictx, op):
+    ictx.out(op, "Out", _m(ictx.in_(op, "X")))
+
+
 @register_shape("short_conv1d")
 def _shape_short_conv1d(ictx, op):
     ictx.out(op, "Out", _m(ictx.in_(op, "X")))

@@ -117,6 +117,218 @@ def test_causal_cross_length_alignment(rng):
     assert np.abs(np.asarray(out) - np.asarray(ref)).max() < 2e-2
 
 
+# ------------------------------------ the band and the key/value group
+
+
+def _band_case(rng, b, h, hkv, sq, sk, d, layout="bhsd"):
+    def shape(n, s):
+        return (b, s, n, d) if layout == "bshd" else (b, n, s, d)
+
+    q, k, v, w = (jnp.asarray(rng.randn(*shape(n, s)), jnp.float32)
+                  for n, s in ((h, sq), (hkv, sk), (hkv, sk), (h, sq)))
+    return q, k, v, w
+
+
+def _out_and_grads(fn, q, k, v, w):
+    out = fn(q, k, v)
+    grads = jax.grad(lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    return (out, *grads)
+
+
+@pytest.mark.parametrize("case", [
+    # (b, h, hkv, sq, sk, d), window, layout
+    ((1, 2, 2, 384, 384, 64), 50, "bhsd"),    # smaller than a block
+    ((1, 2, 2, 384, 384, 64), 200, "bhsd"),   # no multiple of the block
+    ((1, 2, 2, 256, 256, 64), 256, "bhsd"),   # at least s: the causal mask
+    ((1, 2, 2, 128, 384, 64), 150, "bhsd"),   # sq != sk, with the offset
+    ((1, 2, 2, 300, 300, 64), 130, "bhsd"),   # a length that is no block's
+    ((1, 8, 1, 256, 256, 64), 0, "bhsd"),     # a group of 8, causal alone
+    ((2, 8, 2, 300, 300, 128), 130, "bhsd"),  # groups of 4 and a window
+    ((1, 4, 2, 128, 384, 64), 0, "bhsd"),     # sq != sk, a group, no window
+    ((1, 4, 4, 256, 256, 128), 0, "bhsd"),    # group 1, a head of 128 lanes
+], ids=lambda c: f"{c[0]}-w{c[1]}-{c[2]}")
+def test_band_and_group_match_the_plain_path(rng, case):
+    """out, dq, dk, dv of the kernels, interpreted, with a window and with
+    fewer key/value heads than query heads, against `_attention_unfused`
+    in float32."""
+    dims, window, _ = case
+    q, k, v, w = _band_case(rng, *dims)
+    sm = 1.0 / np.sqrt(dims[-1])
+    got = _out_and_grads(
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, window=window, block_q=128, block_k=128),
+        q, k, v, w)
+    want = _out_and_grads(
+        lambda q, k, v: fa._attention_unfused(
+            q, k, v, None, True, sm, 0.0, None, True, window=window),
+        q, k, v, w)
+    for a, b_, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        assert a.shape == b_.shape
+        scale = max(1.0, float(jnp.abs(b_).max()))
+        assert float(jnp.abs(a - b_).max()) / scale < 1e-5, name
+
+
+def test_a_window_of_at_least_s_is_bitwise_the_causal_kernel(rng):
+    q, k, v, w = _band_case(rng, 1, 2, 2, 300, 300, 64)
+    run = lambda window: _out_and_grads(  # noqa: E731
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, window=window, block_q=128, block_k=128),
+        q, k, v, w)
+    for a, b_ in zip(run(0), run(300)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+
+
+@pytest.mark.parametrize("window", [0, 130])
+def test_skipping_blocks_changes_no_bit(rng, window, monkeypatch):
+    """Group 1: the grids that visit only the band give bitwise what the
+    whole rectangles give (which is what the kernels computed before they
+    skipped anything): a block no pair is admitted in adds exact zeros."""
+    q, k, v, w = _band_case(rng, 1, 2, 2, 384, 384, 64)
+    run = lambda: _out_and_grads(  # noqa: E731
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, window=window, block_q=128, block_k=128),
+        q, k, v, w)
+    banded = run()
+    monkeypatch.setattr(fa, "_key_band", lambda j, m, xp=jnp: (
+        0 * j, 0 * j + (m.nk - 1)))
+    monkeypatch.setattr(fa, "_query_band", lambda kb, m, xp=jnp: (
+        0 * kb, 0 * kb + (m.nq - 1)))
+    jax.clear_caches()  # the jitted calls traced the banded grids
+    try:
+        whole = run()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    for a, b_ in zip(banded, whole):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+
+
+def test_a_group_is_what_repeated_heads_give(rng):
+    """8 query heads over 1 key/value head against 8 over 8 copies: the
+    same out and dq to the bit, and dk, dv the sum over the copies'."""
+    q, k, v, w = _band_case(rng, 1, 8, 1, 256, 256, 64)
+    fn = lambda q, k, v: fa.flash_attention(  # noqa: E731
+        q, k, v, causal=True, window=100, block_q=128, block_k=128)
+    got = _out_and_grads(fn, q, k, v, w)
+    rep = _out_and_grads(fn, q, jnp.repeat(k, 8, 1), jnp.repeat(v, 8, 1), w)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(rep[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(rep[1]))
+    for a, b_ in zip(got[2:], rep[2:]):
+        np.testing.assert_allclose(a, b_.sum(1, keepdims=True), atol=2e-5)
+
+
+@pytest.mark.parametrize("dims,window,by_hand", [
+    # 3 x 3 blocks of 128: causal alone visits the lower triangle, 6
+    ((1, 2, 2, 384, 384, 64), 0, (3 * 6, 3 * 9)),
+    # window 200: query block 2 (rows 256-383) sees keys 57-383: blocks
+    # 0, 1, 2; block 1: keys from -71: blocks 0, 1; block 0: block 0
+    ((1, 2, 2, 384, 384, 64), 200, (3 * 6, 3 * 9)),
+    # window 50 < a block: each query block sees its own and the one before
+    ((1, 2, 2, 384, 384, 64), 50, (3 * 5, 3 * 9)),
+    # sq 128, sk 384 (offset 256), window 150: keys 107-383: blocks 0, 1, 2
+    ((1, 2, 2, 128, 384, 64), 150, (3 * 3, 3 * 3)),
+    # the cell's shape: 16 x 16 blocks of 512, window 2048: 1+2+3+4+12*5
+    ((1, 32, 4, 8192, 8192, 128), 2048, (3 * 70, 3 * 256)),
+    ((1, 32, 4, 8192, 8192, 128), 0, (3 * 136, 3 * 256)),
+])
+def test_blocks_visited_against_a_count_by_hand(dims, window, by_hand):
+    """`flash_blocks_visited` and `_total`: what a call's three grids
+    compute and the whole rectangles, a head; the counters take them
+    times the heads and the batch."""
+    from paddle_tpu import profiler
+
+    b, h, _, sq, sk, d = dims
+    block = 512 if sq > 1024 else 128
+    masks = fa._Masks(True, sk - sq, window, block, block, sq // block,
+                      sk // block)
+    assert masks.visited() == by_hand
+    # the same count with the predicate asked of every block
+    first, last = fa._key_band(np.arange(masks.nq), masks, np)
+    qfirst, qlast = fa._query_band(np.arange(masks.nk), masks, np)
+    for j in range(masks.nq):
+        for kb in range(masks.nk):
+            qi = np.arange(j * block, (j + 1) * block)[:, None]
+            ki = np.arange(kb * block, (kb + 1) * block)[None, :]
+            keep = (ki <= qi + sk - sq)
+            if window:
+                keep &= qi + sk - sq - ki < window
+            assert keep.any() == (first[j] <= kb <= last[j]) == (
+                qfirst[kb] <= j <= qlast[kb]), (j, kb)
+    if sq > 1024:
+        return
+    c0 = profiler.counters()
+    rng = np.random.RandomState(0)
+    q, k, v, _ = _band_case(rng, *dims)
+    fa.flash_attention(q, k, v, causal=True, window=window, block_q=block,
+                       block_k=block)
+    c1 = profiler.counters()
+    assert (c1["flash_blocks_visited"] - c0.get("flash_blocks_visited", 0),
+            c1["flash_blocks_total"] - c0.get("flash_blocks_total", 0)) == (
+                b * h * by_hand[0], b * h * by_hand[1])
+
+
+def test_window_and_group_are_refused_where_they_cannot_run(rng):
+    q, k, v, _ = _band_case(rng, 1, 4, 3, 128, 128, 64)
+    with pytest.raises(ValueError, match="4 query heads over 3"):
+        fa.flash_attention(q, k, v, causal=True)
+    q, k, v, _ = _band_case(rng, 1, 2, 2, 128, 128, 64)
+    with pytest.raises(ValueError, match="window needs causal"):
+        fa.flash_attention(q, k, v, window=64)
+    with pytest.raises(ValueError, match="window needs causal"):
+        fa._attention_unfused(q, k, v, None, False, 0.125, 0.0, None, True,
+                              window=64)
+
+
+@pytest.mark.parametrize("window,group,layout", [
+    (0, 2, "bshd"), (24, 1, "bshd"), (24, 2, "bshd"), (24, 2, "bhsd")])
+def test_op_takes_a_window_and_a_group_on_the_plain_path(
+        rng, window, group, layout):
+    """Through the Program on the CPU (the XLA path, the oracle): Out and
+    the three gradients against a float64 softmax over explicit masks."""
+    import paddle_tpu as fluid
+    from paddle_tpu import profiler
+
+    b, s, nh, dh = 2, 40, 4, 8
+    g = nh // group
+
+    def shape(n):
+        return [b, s, n, dh] if layout == "bshd" else [b, n, s, dh]
+
+    feeds = [fluid.layers.data(n, shape(heads), append_batch_size=False)
+             for n, heads in (("q", nh), ("k", g), ("v", g))]
+    for t in feeds:
+        t.stop_gradient = False
+    out = fluid.layers.fused_multihead_attention(
+        *feeds, causal=True, layout=layout, window=window)
+    assert list(out.shape) == shape(nh)
+    w = rng.randn(*shape(nh)).astype("float32")
+    loss = fluid.layers.reduce_sum(
+        fluid.layers.elementwise_mul(out, fluid.layers.assign(w)))
+    grads = fluid.backward.calc_gradient(loss, feeds)
+    data = {n: rng.randn(*shape(heads)).astype("float32")
+            for n, heads in (("q", nh), ("k", g), ("v", g))}
+    exe = fluid.Executor(fluid.CPUPlace())
+    got = exe.run(feed=data, fetch_list=[out, *grads])
+    assert profiler.counters()["attn_kv_group"] == group
+
+    def gold(q, k, v):
+        if layout == "bshd":
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        k, v = (jnp.repeat(t, group, 1) for t in (k, v))
+        sc = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(dh)
+        behind = np.arange(s)[:, None] - np.arange(s)[None, :]
+        keep = (behind >= 0) & ((behind < window) if window else True)
+        p = jax.nn.softmax(jnp.where(keep, sc, -jnp.inf), -1)
+        o = jnp.einsum("bhqk,bhkd->bhqd", p, v)
+        return o.transpose(0, 2, 1, 3) if layout == "bshd" else o
+
+    args = [jnp.asarray(data[n]) for n in "qkv"]
+    want = [gold(*args), *jax.grad(
+        lambda *a: jnp.sum(gold(*a) * w), argnums=(0, 1, 2))(*args)]
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(a, b_, rtol=2e-4, atol=2e-5)
+
+
 def test_dropout_deterministic_and_consistent(rng):
     """In-kernel dropout: same key -> same output; fwd/bwd agree exactly
     with a pure-XLA attention using the identical (reconstructed) mask."""

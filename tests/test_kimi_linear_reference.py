@@ -278,16 +278,17 @@ def _grad_of_reference(before, batch, model):
 ROUTED = ("router", "experts")
 
 
-def check_gradients(got, want, before, limit, routed_limit=None):
-    """Worst relative error by kind of parameter; `routed_limit` for the
+def check_gradients(got, want, before, limit, routed_limit=None, kinds=None):
+    """Worst relative error by kind of parameter (`kinds`: this model's
+    `KINDS` unless another model's are given); `routed_limit` for the
     router and the experts, whose gradients change by a whole token's
     worth where rounding flips a selection (at 512 tokens an expert sees
     about 16). A gradient read as
     `before - after` carries float32's rounding of the parameter itself
     (6e-8 of a norm's weight of 1 under a gradient of 1e-4), which is
     taken off the error before it is held to `limit`."""
-    worst = {}
-    for kind, endings in KINDS.items():
+    worst, kinds = {}, kinds or KINDS
+    for kind, endings in kinds.items():
         names = [n for n in want if n.endswith(endings)]
         assert names, kind
         for n in names:
@@ -296,7 +297,7 @@ def check_gradients(got, want, before, limit, routed_limit=None):
             err = np.sqrt(np.mean((got[n] - want[n]) ** 2))
             err = max(err - rounding, 0.0) / np.sqrt(np.mean(want[n] ** 2))
             worst[kind] = max(worst.get(kind, 0.0), float(err))
-    classed = {n for n in want if any(n.endswith(e) for e in KINDS.values())}
+    classed = {n for n in want if any(n.endswith(e) for e in kinds.values())}
     untrained = sorted(set(want) - classed)
     assert all(n.endswith(".moe.bias") for n in untrained), untrained
     for n in untrained:  # the router's correction is not the optimizer's
