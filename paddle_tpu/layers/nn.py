@@ -1264,6 +1264,10 @@ def fused_multihead_attention(
     layout="bhsd",
     name=None,
     window=0,
+    q_norm_attr=None,
+    k_norm_attr=None,
+    qk_norm_epsilon=1e-5,
+    rope_theta=0.0,
 ):
     """Flash attention over q/k/v (Pallas kernel on TPU). layout="bhsd"
     (default): [b, nh, s, dh]; layout="bshd": [b, s, nh, dh] — the shape
@@ -1281,13 +1285,28 @@ def fused_multihead_attention(
     mask). The unfused equivalent is matmul+softmax+dropout+matmul — this
     layer replaces that chain with one kernel so the [s, s] scores never
     reach HBM.
+
+    `q_norm_attr` and `k_norm_attr`, given together, add QK-norm: `q` and
+    `k` are normed head by head over `dh` as `rms_norm` does, each with a
+    learned `[dh]` weight seeded at 1 and `qk_norm_epsilon`. With them,
+    `rope_theta` > 0 (layout "bshd") then turns q and k by
+    `rotary_embedding`'s positions 0..s-1. Inside the op the two share
+    one pass over q and k with the kernel's head-major write, where the
+    kernel runs.
     """
     if layout not in ("bhsd", "bshd"):
         raise ValueError(f"layout must be 'bhsd' or 'bshd', got {layout!r}")
+    if (q_norm_attr is None) != (k_norm_attr is None):
+        raise ValueError("q_norm_attr and k_norm_attr come together")
     helper = LayerHelper("fused_multihead_attention", name=name)
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if key_bias is not None:
         inputs["KeyBias"] = [key_bias]
+    if q_norm_attr is not None:
+        for slot, attr in (("QNorm", q_norm_attr), ("KNorm", k_norm_attr)):
+            inputs[slot] = [helper.create_parameter(
+                attr, [int(q.shape[-1])], dtype="float32",
+                default_initializer=Constant(1.0))]
     return _single_out(
         helper,
         "fused_multihead_attention",
@@ -1299,6 +1318,10 @@ def fused_multihead_attention(
             "is_test": is_test,
             "layout": layout,
             "window": int(window),
+            # on a Program that asks for neither, the op is as it was
+            **({"qk_norm_epsilon": float(qk_norm_epsilon),
+                "rope_theta": float(rope_theta)}
+               if q_norm_attr is not None else {}),
         },
         dtype=q.dtype,
         shape=list(q.shape[:-1]) + [v.shape[-1]],

@@ -35,7 +35,7 @@ The router's correction `b` is persistable, seeded, and not trained (the
 published model moves it by a load-balancing rule outside the gradient,
 which this repo lacks). The expert layer is the op `moe_experts`, the
 attention `fused_multihead_attention` with `window` and four key/value
-heads, the positions `rotary_embedding`.
+heads, which also norms q and k and gives them their positions.
 """
 
 from __future__ import annotations
@@ -43,6 +43,7 @@ from __future__ import annotations
 import math
 
 from .. import layers
+from ..param_attr import ParamAttr
 from .decoder_parts import attr, expert_ffn, ffn, norm, proj
 
 __all__ = ["TrinityConfig", "build_trinity"]
@@ -101,14 +102,14 @@ def _attention(u, cfg, name, window):
     k = layers.reshape(proj(u, g * d, name + ".k", cfg), [b, s, g, d])
     v = layers.reshape(proj(u, g * d, name + ".v", cfg), [b, s, g, d])
     gate = layers.sigmoid(proj(u, h * d, name + ".gate", cfg))
-    q = norm(q, name + ".q_norm", cfg, axis=3)
-    k = norm(k, name + ".k_norm", cfg, axis=3)
-    if window:
-        q = layers.rotary_embedding(q, theta=cfg.rope_theta)
-        k = layers.rotary_embedding(k, theta=cfg.rope_theta)
+    # QK-norm and the positions inside the attention op, where they and
+    # the kernel's head-major write are one pass over q and k
     a = layers.fused_multihead_attention(
         q, k, v, causal=True, sm_scale=1.0 / math.sqrt(d), layout="bshd",
-        window=window)
+        window=window, q_norm_attr=ParamAttr(name=name + ".q_norm.w_0"),
+        k_norm_attr=ParamAttr(name=name + ".k_norm.w_0"),
+        qk_norm_epsilon=cfg.rms_norm_eps,
+        rope_theta=cfg.rope_theta if window else 0.0)
     a = layers.elementwise_mul(layers.reshape(a, [b, s, h * d]), gate)
     return proj(a, cfg.hidden_size, name + ".o", cfg)
 

@@ -17,9 +17,11 @@ import numpy as np
 
 from .. import profiler
 from ..analysis.artifacts import load_artifact
+from .nn_ops import rms_norm, rotate_half
 from .pallas import on_mesh
 from .pallas.flash_attention import _xla_attention, flash_attention
 from .pallas.mha_short import mha_short, mha_short_viable
+from .pallas.qk_prep import qk_prep, qk_prep_viable
 from .registry import register_op
 
 _logger = logging.getLogger(__name__)
@@ -59,7 +61,11 @@ _logger = logging.getLogger(__name__)
 #          [b, s, heads*128] arrays the projections write was built and
 #          measured 4.8% slower end to end (PERF.md, PR 33). XLA's path
 #          was run at neither: its float32 scores of one row are 2.1 GB
-#          and 8.6 GB.
+#          and 8.6 GB. Where the op is given QK-norm weights (and
+#          `rope_theta`), this path's way into the kernels, the norm of a
+#          head's lanes, the positions and the head-major write, is one
+#          kernel pair over q, k and v (ops/pallas/qk_prep.py; PERF.md,
+#          PR 34); the other paths run `rms_norm` and `rotate_half` first.
 #          Values narrower than the keys are zero-padded to the keys'
 #          width for this kernel alone (the others take them as they are).
 #   xla    _xla_attention everywhere else: the "bhsd" layout, the CPU, and
@@ -199,11 +205,22 @@ def _fused_mha(ctx, op):
     Replaces the unfused matmul->softmax->dropout->matmul chain
     (reference model pattern, e.g. the Fluid transformer/BERT models) with
     one Pallas kernel; in-kernel dropout is regenerated in the backward.
+
+    Optional QNorm, KNorm ([dh] each, together): q and k are first normed
+    head by head as the op `rms_norm` norms the last axis, with attr
+    `qk_norm_epsilon`; attr `rope_theta` > 0 then turns them by the op
+    `rotary_embedding`'s positions. On the flash path with layout "bshd"
+    and heads of whole 128-lane slices, that and the head-major write the
+    kernel wants are one kernel pair (ops/pallas/qk_prep.py); on every
+    other path the two ops' own functions run first, in `jnp`.
     """
     q = ctx.in_(op, "Q")
     k = ctx.in_(op, "K")
     v = ctx.in_(op, "V")
     bias = ctx.in_(op, "KeyBias")
+    q_norm, k_norm = ctx.in_(op, "QNorm"), ctx.in_(op, "KNorm")
+    norm_eps = float(op.attr("qk_norm_epsilon", 1e-5))
+    rope_theta = float(op.attr("rope_theta", 0.0) or 0.0)
     causal = op.attr("causal", False)
     dropout = float(op.attr("attn_dropout", 0.0))
     is_test = op.attr("is_test", False) or ctx.is_test
@@ -215,6 +232,27 @@ def _fused_mha(ctx, op):
     group = q.shape[h_ax] // k.shape[h_ax]
     if window and not causal:
         raise ValueError("fused_multihead_attention: a window needs causal")
+    if (q_norm is None) != (k_norm is None):
+        raise ValueError(
+            "fused_multihead_attention: QNorm and KNorm come together")
+    if rope_theta and (q_norm is None or not bshd):
+        raise ValueError(
+            "fused_multihead_attention: rope_theta needs QNorm and KNorm, "
+            "and layout \"bshd\", whose axis 1 the positions count")
+
+    prepare = q_norm is not None
+    if prepare:
+        raw = q, k, v
+
+        def prepared():
+            """q and k as the ops `rms_norm` and `rotary_embedding` leave
+            them, then in the attention's dtype."""
+            q, k, _ = raw
+            q = rms_norm(q, q_norm, norm_eps, 3)
+            k = rms_norm(k, k_norm, norm_eps, 3)
+            if rope_theta:
+                q, k = rotate_half(q, rope_theta), rotate_half(k, rope_theta)
+            return ctx.amp_cast(op, q, k)
 
     q, k, v = ctx.amp_cast(op, q, k, v)
     if bias is not None:
@@ -243,6 +281,10 @@ def _fused_mha(ctx, op):
         if path == "flash" and window:
             profiler.bump_counter("attn_dispatch_flash_window")
         profiler.set_counter("attn_kv_group", group)
+        fused = (prepare and path == "flash" and bshd
+                 and qk_prep_viable(q.shape[-1], dv))
+        if prepare and not fused:
+            q, k = prepared()
         if path == "short":
             # [b, s, nh, dh] back to the [b, s, nh*dh] the projection
             # wrote: XLA folds this with the Program's reshape2 into nothing
@@ -263,6 +305,15 @@ def _fused_mha(ctx, op):
         def swap(t):  # bshd <-> bhsd; the flash kernel is head-major
             return jnp.transpose(t, (0, 2, 1, 3)) if bshd else t
 
+        if fused:
+            # from the arrays as they came: the kernel norms and rotates
+            # in float32 and writes the attention's dtype, head-major
+            profiler.bump_counter("attn_qk_prep_fused")
+            return swap(flash_attention(
+                *qk_prep(*raw, q_norm, k_norm, epsilon=norm_eps,
+                         theta=rope_theta, out_dtype=q.dtype),
+                bias=bias, causal=causal, sm_scale=sm_scale, dropout=dropout,
+                rng_key=rng, window=window))
         if dv != q.shape[-1]:
             # the kernel has one head width: pad the values with zeros up
             # to the keys' and cut the output back (the block comment above)
@@ -340,6 +391,8 @@ def _fused_mha(ctx, op):
             "fused_multihead_attention: ring and ulysses sequence "
             "parallelism take neither a window nor grouped key/value heads")
     if sp_mode and model_n > 1:
+        if prepare:
+            q, k = prepared()
         # sequence parallelism over the unified mesh's 'model' axis: the
         # attention runs on GLOBAL arrays and GSPMD places the
         # collectives (the legacy version hand-wrote them under

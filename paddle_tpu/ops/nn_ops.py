@@ -559,15 +559,32 @@ def _rms_norm(ctx, op):
     `begin_norm_axis` on (Zhang and Sennrich 2019, arXiv:1910.07467): no
     mean is subtracted and there is no shift. The statistics and the
     product with the scale are float32; Y has X's dtype."""
-    x = ctx.in_(op, "X")
-    scale = ctx.in_(op, "Scale")
-    begin = op.attr("begin_norm_axis", 1)
+    ctx.out(op, "Y", rms_norm(
+        ctx.in_(op, "X"), ctx.in_(op, "Scale"), op.attr("epsilon", 1e-5),
+        op.attr("begin_norm_axis", 1)))
+
+
+def rms_norm(x, scale, epsilon, begin):
+    """The op `rms_norm` on arrays: over the axes from `begin` on."""
     axes = tuple(range(begin, x.ndim))
     xf = x.astype(jnp.float32)
     inv = jax.lax.rsqrt(
-        jnp.mean(xf * xf, axis=axes, keepdims=True) + op.attr("epsilon", 1e-5))
+        jnp.mean(xf * xf, axis=axes, keepdims=True) + epsilon)
     y = xf * inv * scale.reshape(x.shape[begin:]).astype(jnp.float32)
-    ctx.out(op, "Y", y.astype(x.dtype))
+    return y.astype(x.dtype)
+
+
+def rotary_tables(s, d, theta):
+    """`cos a` and the signed `sin a` of `rotate_half`, [s, d] float32:
+    `y = x * cos + roll(x, d/2) * sin` along the last axis."""
+    # the published form, 1 / theta^(2i/d) in float32: another way round
+    # the power differs by an ulp, which position 8,191 makes 4e-4 rad
+    freq = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * freq[None, :]
+    cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)
+    # the half-turn's sign rides the sine: rotate_half(x) = [-x2, x1]
+    sin = jnp.concatenate([-jnp.sin(angle), jnp.sin(angle)], -1)
+    return cos, sin
 
 
 def rotate_half(x, theta):
@@ -579,14 +596,7 @@ def rotate_half(x, theta):
     float32 inside whatever x arrives in: a bf16 angle at position 8,191
     is off by whole turns."""
     s, d = x.shape[1], x.shape[3]
-    # the published form, 1 / theta^(2i/d) in float32: another way round
-    # the power differs by an ulp, which position 8,191 makes 4e-4 rad
-    freq = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * freq[None, :]
-    cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[None, :, None, :]
-    # the half-turn's sign rides the sine: rotate_half(x) = [-x2, x1]
-    sin = jnp.concatenate([-jnp.sin(angle), jnp.sin(angle)], -1)[
-        None, :, None, :]
+    cos, sin = (t[None, :, None, :] for t in rotary_tables(s, d, theta))
     xf = x.astype(jnp.float32)
     return (xf * cos + jnp.roll(xf, d // 2, axis=-1) * sin).astype(x.dtype)
 

@@ -280,3 +280,69 @@ def test_kda_kernels_compile_for_a_v5e_chip_at_the_published_widths(topo):
     assert "kda_fwd" in text and "kda_bwd" in text
     # the operands, their gradients and the 134 MB of chunk states
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("theta", [0.0, 10000.0],
+                         ids=["no_positions", "rope"])
+def test_qk_prep_kernels_compile_for_a_v5e_chip_at_the_published_widths(
+        topo, theta):
+    """The kernel pair between the projections and the flash kernels
+    (ops/pallas/qk_prep.py) at the shape of the cell that runs it: one
+    8,192-token row, 32 query heads over 4 key/value heads of 128, bf16 in
+    and out, a full layer's call and a window layer's. Nothing runs."""
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas import qk_prep
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    b, s, h, g, d = 1, 8192, 32, 4, 128
+    bf16 = jnp.dtype(jnp.bfloat16)
+    statics = (h, g, 1e-5, theta, qk_prep.ROWS, bf16, bf16, False)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def both(q, k, v, wq, wk):
+        o, pull = jax.vjp(lambda *a: qk_prep._core(*a, statics),
+                          q, k, v, wq, wk)
+        return o, pull(o)
+
+    compiled = jax.jit(both).lower(
+        sds((b, s, h * d)), sds((b, s, g * d)), sds((b, s, g * d)),
+        sds((d,), jnp.float32), sds((d,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "qk_prep_fwd" in text and "qk_prep_bwd" in text
+    # q, k, v in, out and back, and nothing float32 of their size between
+    assert "f32[1,8192" not in text and "f32[1,32,8192" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_trinity_step_compiled_for_v5e_holds_no_float32_array_of_q(
+        topo, monkeypatch):
+    """The cell's whole train step, built as its runner builds it, for a
+    described chip: the kernel pair is in it five times each way, and
+    the float32 `[1, 8192, 32, 128]` and `[1, 8192, 4, 128]` views of
+    QK-norm and rotation that XLA laid out its own way and relaid (215 and
+    231 mentions in the parent's step, 28 copies) are gone, in any layout
+    and under either shape. Nothing runs."""
+    import importlib
+    import re
+
+    from benchmark.harness import spec
+    from benchmark.tests.test_compile_v5e import lower_train_step
+
+    module = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET")
+    monkeypatch.setattr(module, "_use_pallas", lambda: True)  # as on the chip
+    before = profiler.counters().get("attn_qk_prep_fused", 0)
+    text = lower_train_step(spec.cell("trinity_mini_ep16_s8192"),
+                            topo.devices).compile().as_text()
+    # the forward op and the gradient op's replay, five layers
+    assert profiler.counters()["attn_qk_prep_fused"] == before + 10
+    calls = re.findall(r"^\s*%?(qk_prep_\w+?|flash_\w+?)[.\d]* = .* custom-call\(",
+                       text, re.M)
+    assert calls.count("qk_prep_fwd") == 5 and calls.count("qk_prep_bwd") == 5
+    assert calls.count("flash_fwd") == 5  # still one a layer, still shared
+    for shape in ("1,8192,32,128", "1,8192,4096", "1,8192,4,128",
+                  "1,8192,512"):
+        assert f"f32[{shape}]" not in text, shape

@@ -1,0 +1,184 @@
+"""The kernel pair between the projections and the flash kernels
+(`ops/pallas/qk_prep.py`) under the Pallas interpreter: the head-major q,
+k, v and every gradient (the projections' outputs and both norm weights)
+against `jax.grad` of the chain it replaces, `rms_norm`, `rotate_half` and
+a transpose in float32 at `highest`; with and without positions, grouped
+and equal head counts, a row count that is no multiple of the block, a
+block that holds position 8,191, and the published widths."""
+
+import numpy as np
+import pytest
+
+EPS, THETA = 1e-5, 10000.0
+
+
+@pytest.fixture(autouse=True)
+def interpreter(monkeypatch):
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+
+
+def rel(got, want):
+    """The largest difference over the largest value: position 8,191's
+    lanes are not lost among the others, as they would be in a norm."""
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def chain(q, k, v, wq, wk, theta):
+    """What the Program ran before: float32 throughout, head-major out."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.nn_ops import rms_norm, rotate_half
+
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    q, k = rms_norm(q, wq, EPS, 3), rms_norm(k, wk, EPS, 3)
+    if theta:
+        q, k = rotate_half(q, theta), rotate_half(k, theta)
+    return tuple(jnp.transpose(t, (0, 2, 1, 3)) for t in (q, k, v))
+
+
+def _args(b, s, h, g, d, dtype, seed=0):
+    import jax.numpy as jnp
+
+    r = np.random.RandomState(seed)
+    acts = [jnp.asarray(r.randn(b, s, n, d) * (1 + r.rand(1, 1, n, 1)), dtype)
+            for n in (h, g, g)]
+    weights = [jnp.asarray(1 + 0.2 * r.randn(d), jnp.float32)
+               for _ in range(2)]
+    # what flows back into a bf16 output is bf16: the same on both sides
+    cotangents = [jnp.asarray(r.randn(b, n, s, d), dtype).astype(jnp.float32)
+                  for n in (h, g, g)]
+    return acts + weights, cotangents
+
+
+def _both(fn, args, cotangents):
+    """Outputs, and the gradients of sum(out * cotangent) in every input:
+    jitted, as the step is (XLA folds the frequencies' constant one way
+    inside a jit and another outside: 5e-4 rad at position 8,191)."""
+    import jax
+    import jax.numpy as jnp
+
+    def loss(*a):
+        return sum(jnp.sum(o.astype(jnp.float32) * c)
+                   for o, c in zip(fn(*a), cotangents))
+
+    with jax.default_matmul_precision("highest"):
+        return (jax.jit(fn)(*args),
+                jax.jit(jax.grad(loss, argnums=range(5)))(*args))
+
+
+# b, s, heads, key/value heads, d, rows a block
+SHAPES = [
+    pytest.param(2, 128, 4, 2, 128, 64, id="grouped"),
+    pytest.param(1, 96, 2, 2, 128, 32, id="equal_heads"),
+    pytest.param(2, 200, 4, 1, 128, 64, id="rows_not_a_multiple_of_the_block"),
+    pytest.param(1, 8192, 2, 1, 128, 1024, id="position_8191"),
+    pytest.param(1, 50, 2, 1, 256, 1024, id="two_lane_slices_a_head"),
+]
+
+
+@pytest.mark.parametrize("theta", [0.0, THETA], ids=["no_positions", "rope"])
+@pytest.mark.parametrize("b,s,h,g,d,rows", SHAPES)
+def test_float32_equals_the_chain_and_its_gradients(b, s, h, g, d, rows,
+                                                    theta):
+    from paddle_tpu.ops.pallas.qk_prep import qk_prep
+
+    args, cotangents = _args(b, s, h, g, d, "float32")
+    got, got_grads = _both(
+        lambda *a: qk_prep(*a, epsilon=EPS, theta=theta, rows=rows),
+        args, cotangents)
+    want, want_grads = _both(lambda *a: chain(*a, theta), args, cotangents)
+    for name, a, w in zip("qkv", got, want):
+        assert a.shape == w.shape and a.dtype == w.dtype
+        assert rel(a, w) < 1e-5, name
+    np.testing.assert_array_equal(got[2], want[2])  # v is only moved
+    for name, a, w in zip(("dq", "dk", "dv", "dwq", "dwk"), got_grads,
+                          want_grads):
+        assert a.shape == w.shape and a.dtype == w.dtype
+        assert rel(a, w) < 1e-5, name
+
+
+@pytest.mark.parametrize("theta", [0.0, THETA], ids=["no_positions", "rope"])
+def test_bf16_in_and_out_is_one_rounding_from_the_float32_chain(theta):
+    """bf16 as the projections write it under AMP, at the published
+    widths (32 query heads over 4 key/value heads of 128; the row cut to
+    256): float32 inside, so the output is the float32 chain's rounded
+    once, and the gradients the chain's rounded where they are written."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.qk_prep import qk_prep
+
+    args, cotangents = _args(1, 256, 32, 4, 128, jnp.bfloat16, seed=1)
+    got, got_grads = _both(
+        lambda *a: qk_prep(*a, epsilon=EPS, theta=theta, rows=128),
+        args, cotangents)
+    want, want_grads = _both(lambda *a: chain(*a, theta), args, cotangents)
+    for a, w in zip(got, want):
+        assert a.dtype == jnp.bfloat16
+        # half a bf16 ulp is 2^-9 of the value's power of two
+        assert rel(a.astype(jnp.float32), w) < 2.0 ** -8
+    for a, w in zip(got_grads[:3], want_grads[:3]):
+        # both sides rounded to bf16 where they are written: one ulp apart
+        assert a.dtype == w.dtype == jnp.bfloat16
+        assert rel(a.astype(jnp.float32), w.astype(jnp.float32)) < 2.0 ** -7
+    for a, w in zip(got_grads[3:], want_grads[3:]):  # summed in float32
+        assert a.dtype == jnp.float32 and rel(a, w) < 1e-5
+
+
+def test_float32_in_bf16_out_as_the_attention_casts():
+    """Without a bf16 projection in front (an fc kept in float32 under
+    AMP) the kernel reads float32 and writes the attention's dtype; the
+    gradients come back in float32."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.qk_prep import qk_prep
+
+    args, cotangents = _args(1, 64, 2, 1, 128, "float32", seed=2)
+    got, got_grads = _both(
+        lambda *a: qk_prep(*a, epsilon=EPS, theta=THETA,
+                           out_dtype=jnp.bfloat16), args, cotangents)
+    want, _ = _both(lambda *a: chain(*a, THETA), args, cotangents)
+    for a, w in zip(got, want):
+        assert a.dtype == jnp.bfloat16
+        assert rel(a.astype(jnp.float32), w) < 2.0 ** -8
+    assert all(g.dtype == jnp.float32 for g in got_grads)
+
+
+def test_a_head_that_is_no_lane_slice_is_refused():
+    from paddle_tpu.ops.pallas.qk_prep import qk_prep, qk_prep_viable
+
+    assert qk_prep_viable(128, 128) and qk_prep_viable(256, 256)
+    assert not qk_prep_viable(64, 64) and not qk_prep_viable(192, 128)
+    args, _ = _args(1, 32, 2, 1, 64, "float32")
+    with pytest.raises(ValueError, match="lane"):
+        qk_prep(*args, epsilon=EPS)
+
+
+def test_the_kernels_names_are_not_the_flash_kernels():
+    """`flash_gqa_ms_per_step` and `flash_gqa_roofline_pct` match
+    `^%?flash_`; `qk_prep_ms_per_step` matches these two and nothing of
+    theirs."""
+    import json
+    import os
+    import re
+
+    import jax
+
+    from paddle_tpu.ops.pallas.qk_prep import qk_prep
+
+    args, cotangents = _args(1, 32, 2, 1, 128, "float32")
+    text = str(jax.make_jaxpr(lambda *a: jax.vjp(
+        lambda *a: qk_prep(*a, epsilon=EPS, theta=THETA), *a)[1](
+            tuple(cotangents)))(*args))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           "qk_prep_ms_per_step.json")) as f:
+        mine = json.load(f)["args"]["name"]
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           "flash_gqa_ms_per_step.json")) as f:
+        theirs = json.load(f)["args"]["name"]
+    for name in ("qk_prep_fwd", "qk_prep_bwd"):
+        assert name in text
+        assert re.search(mine, name) and not re.search(theirs, name)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert not re.search(mine, name)

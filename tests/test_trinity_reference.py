@@ -3,8 +3,9 @@ the rehearsal size of the cell `trinity_mini_ep16_s8192`: the attention
 mixer, window and full, and for the whole model; one train step's
 gradients for every kind of parameter; the expert layer's shares against
 the uncut layer at this router's scale; that each wrong model is caught by
-the cell's tolerance; the op `rotary_embedding`; the cell's counters and
-FLOPs.
+the cell's tolerance; the op `rotary_embedding`; QK-norm and the positions
+inside the attention op against the model built from the separate ops;
+the cell's counters and FLOPs.
 
 Run as a script, the gradient comparison is made at the published widths
 on one 1,024-token row on the attached TPU, outside any timed window:
@@ -322,6 +323,169 @@ def test_one_train_steps_gradients_equal_jax_grad_of_the_reference():
     model, traffic = cell(precision="float32", router_bias_scale=0.02)
     check_gradients(*_gradients(model, dict(traffic, seq_len=80)), 2e-4,
                     kinds=KINDS)
+
+
+# ------------------------- QK-norm and positions inside the attention op
+
+
+def _attention_separate(u, cfg, name, window):
+    """`models/trinity.py::_attention` as it stood before the attention op
+    took QK-norm and the positions: an op each, which is what the fused
+    op has to mean everywhere."""
+    import math
+
+    from paddle_tpu import layers
+    from paddle_tpu.models.decoder_parts import norm, proj
+
+    b, s, _ = u.shape
+    h, g, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    q = layers.reshape(proj(u, h * d, name + ".q", cfg), [b, s, h, d])
+    k = layers.reshape(proj(u, g * d, name + ".k", cfg), [b, s, g, d])
+    v = layers.reshape(proj(u, g * d, name + ".v", cfg), [b, s, g, d])
+    gate = layers.sigmoid(proj(u, h * d, name + ".gate", cfg))
+    q = norm(q, name + ".q_norm", cfg, axis=3)
+    k = norm(k, name + ".k_norm", cfg, axis=3)
+    if window:
+        q = layers.rotary_embedding(q, theta=cfg.rope_theta)
+        k = layers.rotary_embedding(k, theta=cfg.rope_theta)
+    a = layers.fused_multihead_attention(
+        q, k, v, causal=True, sm_scale=1.0 / math.sqrt(d), layout="bshd",
+        window=window)
+    a = layers.elementwise_mul(layers.reshape(a, [b, s, h * d]), gate)
+    return proj(a, cfg.hidden_size, name + ".o", cfg)
+
+
+def _loss_and_gradients(model, traffic, seed=3):
+    """(loss, {name: gradient}, the forward's count of fused lowerings,
+    the train program's op types) in programs and a scope of its own."""
+    import paddle_tpu as fluid
+    from benchmark.models import trinity as adapter
+    from benchmark.runners import train_loop
+    from paddle_tpu import profiler
+
+    with fluid.program_guard(fluid.Program(), fluid.Program()), \
+            fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
+        model = dict(model, optimizer={"type": "SGD", "learning_rate": 1.0})
+        main, startup, built, eval_prog = train_loop.build_programs(
+            fluid, adapter, model, traffic, seed)
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        names = [p.name for p in main.global_block().all_parameters()]
+        before = state(names)
+        batch = batch_for(model, traffic)
+        count = profiler.counters().get("attn_qk_prep_fused", 0)
+        (loss,) = exe.run(eval_prog, feed=batch, fetch_list=[built["loss"]])
+        count = profiler.counters().get("attn_qk_prep_fused", 0) - count
+        exe.run(main, feed=batch, fetch_list=[built["loss"]])
+        grads = {n: before[n] - v for n, v in state(names).items()}
+        ops = [op.type for op in main.global_block().ops]
+    return float(np.asarray(loss)), grads, count, ops
+
+
+@pytest.mark.parametrize("kernels", [True, False],
+                         ids=["flash_interpreted", "plain_path"])
+def test_fused_qk_prep_is_the_separate_ops_model(kernels, monkeypatch):
+    """One window layer and one full layer at a head of 128 lanes: the
+    model whose attention op norms and rotates q and k gives the loss and
+    every parameter's gradient of the model built from `rms_norm` and
+    `rotary_embedding`, under the same names; through the kernel pair
+    where the flash path runs (interpreted here, forced by name), through
+    the two ops' own functions on the plain path."""
+    from paddle_tpu.models import trinity as zoo
+
+    if kernels:
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+        monkeypatch.setenv("PADDLE_TPU_ATTN_DISPATCH", "flash")
+    model, traffic = cell(precision="float32", head_dim=128,
+                          num_hidden_layers=2, first_layer_held=2,
+                          router_bias_scale=0.02)
+    traffic = dict(traffic, seq_len=48)
+    assert [w for _, w, _ in ref.held_layers(model)] == [16, 0]
+    loss, grads, count, ops = _loss_and_gradients(model, traffic)
+    assert count == (2 if kernels else 0)
+    assert "rotary_embedding" not in ops
+    assert ops.count("rms_norm") == 2 * 4 + 1  # no QK-norm among them
+    monkeypatch.setattr(zoo, "_attention", _attention_separate)
+    want_loss, want, none, want_ops = _loss_and_gradients(model, traffic)
+    assert none == 0 and want_ops.count("rotary_embedding") == 2
+    assert want_ops.count("rms_norm") == 2 * 6 + 1
+    assert sorted(grads) == sorted(want)
+    assert abs(loss - want_loss) < 1e-5 * abs(want_loss)
+    for name in sorted(want):
+        if name.endswith(".moe.bias"):  # seeded, never trained
+            assert not np.abs(grads[name]).any()
+            continue
+        assert np.abs(want[name]).max() > 0, name
+        # a gradient read as before - after carries the parameter's own
+        # float32 rounding (test_kimi_linear_reference.check_gradients)
+        room = 1.2e-7 * (1 + np.abs(want[name]).max())
+        err = np.abs(grads[name] - want[name]).max()
+        assert max(err - room, 0.0) < 1e-5 * np.abs(want[name]).max(), name
+
+
+def test_attention_without_the_new_inputs_is_the_op_it_was():
+    """Kimi's latent attention and BERT's pass no QNorm, KNorm or
+    `rope_theta`: their op carries the slots and attributes it carried,
+    their Programs the ops they had, and no lowering of theirs counts a
+    fused one."""
+    import paddle_tpu as fluid
+    from benchmark.harness import spec
+    from paddle_tpu import profiler
+
+    attrs = {"causal", "attn_dropout", "sm_scale", "is_test", "layout",
+             "window"}
+    # the forward ops at the rehearsal size, as the parent of PR 34 builds
+    # them (147 and 80)
+    before = profiler.counters().get("attn_qk_prep_fused", 0)
+    for cell_name, n_ops, n_attn in (("kimi_linear_ep32_s4096", 147, 1),
+                                     ("bert_base_s128", 80, 2)):
+        c = spec.cell(cell_name, rehearse=True)
+        adapter = spec.plugin("models", c["config"]["adapter"])
+        with fluid.program_guard(fluid.Program(), fluid.Program()), \
+                fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
+            built = adapter.build(c["config"], c["traffic"])
+            main = fluid.default_main_program()
+            ops = main.global_block().ops
+            attn = [op for op in ops
+                    if op.type == "fused_multihead_attention"]
+            assert (len(ops), len(attn)) == (n_ops, n_attn), (
+                cell_name, len(ops), len(attn))
+            for op in attn:
+                assert set(op.inputs) <= {"Q", "K", "V", "KeyBias"}
+                assert attrs <= set(op.attrs)
+                assert not {"qk_norm_epsilon", "rope_theta"} & set(op.attrs)
+            exe = fluid.Executor(fluid.CPUPlace())
+            exe.run(fluid.default_startup_program())
+            batch = adapter.make_batch(np.random.RandomState(0), c["config"],
+                                       c["traffic"])
+            exe.run(main, feed=batch, fetch_list=[built["loss"]])
+    assert profiler.counters().get("attn_qk_prep_fused", 0) == before
+
+
+def test_the_cell_declares_the_kernel_pairs_metric():
+    """`qk_prep_ms_per_step` in `BENCHMARK.json` and beside the other
+    metrics' files, for this cell and the Trinity adapter alone."""
+    import json
+
+    from benchmark.harness import spec
+
+    with open(os.path.join(os.path.dirname(spec.BENCH_DIR),
+                           "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    declared = {m["name"]: m for m in bench["per_layer"]}
+    assert bench["per_layer"][-1]["name"] == "qk_prep_ms_per_step"
+    assert declared["qk_prep_ms_per_step"] == {
+        "name": "qk_prep_ms_per_step", "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "Pallas kernels",
+        "moves": "train_examples_per_s", "workloads": [CELL]}
+    m = spec.load("layer_metrics", "qk_prep_ms_per_step")
+    assert (m["kind"], m["where"]) == ("trace_kernel",
+                                       {"config.adapter": ["trinity"]})
+    assert "qk_prep_ms_per_step" in {
+        x["name"] for x in spec.layer_metrics(spec.cell(CELL))}
+    for other in ("kimi_linear_ep32_s4096", "bert_base_s128"):
+        assert "qk_prep_ms_per_step" not in {
+            x["name"] for x in spec.layer_metrics(spec.cell(other))}
 
 
 # -------------------------------------------------- the expert layer
