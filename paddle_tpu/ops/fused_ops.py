@@ -314,18 +314,12 @@ def _fused_mha(ctx, op):
                          theta=rope_theta, out_dtype=q.dtype),
                 bias=bias, causal=causal, sm_scale=sm_scale, dropout=dropout,
                 rng_key=rng, window=window))
-        if dv != q.shape[-1]:
-            # the kernel has one head width: pad the values with zeros up
-            # to the keys' and cut the output back (the block comment above)
-            if dv > q.shape[-1]:
-                raise ValueError(
-                    f"fused_multihead_attention: values of width {dv} "
-                    f"wider than the keys ({q.shape[-1]})")
-            v = jnp.pad(v, [(0, 0)] * 3 + [(0, q.shape[-1] - dv)])
+        # values narrower than the keys: the kernel pads them up to the
+        # keys' width and cuts the output back (the block comment above)
         return swap(flash_attention(
             swap(q), swap(k), swap(v), bias=bias, causal=causal,
             sm_scale=sm_scale, dropout=dropout, rng_key=rng, window=window,
-        ))[..., :dv]
+        ))
 
     mesh = ctx.mesh
     model_n = (

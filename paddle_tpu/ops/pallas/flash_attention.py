@@ -48,6 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ... import profiler
+from . import cost
 
 NEG_INF = -1e30
 LANE = 128
@@ -212,6 +213,47 @@ def _head_maps(group):
 
 
 # ---------------------------------------------------------------------------
+# what a call declares
+# ---------------------------------------------------------------------------
+
+# products a pair the masks admit, as (over the keys' lanes, over the
+# values' lanes): `flash_fwd` q.k and p.v; `flash_bwd_dq` q.k again, dS.k
+# and dO.v; `flash_bwd_dkv` q.k, dS^T.q and dO.v, p^T.dO. At one head
+# width that is 4, 6 and 8 FLOPs a pair a lane, the benchmark's own count.
+_PRODUCTS = {"flash_fwd": (1, 1), "flash_bwd_dq": (2, 1),
+             "flash_bwd_dkv": (2, 2)}
+
+
+def _cost(kernel, q, k, bias, masks, dims):
+    """What the call named `kernel` declares (`cost.py` has the
+    convention) from the flattened, padded operands `q` [b*h, ., .] and
+    `k` [b*hkv, ., .] and `dims`, the lengths and head widths before any
+    padding, `(sq, sk, d, dv)` (None: the arrays' own). FLOPs follow the
+    pairs `causal` and `window` admit, so a window counts fewer than its
+    causal twin by the arithmetic and not by block rounding; masked pairs
+    inside a visited block and the padded lanes do not count. One
+    exponential a pair; `flash_fwd` a reciprocal and a logarithm a row."""
+    bh, bhkv = q.shape[0], k.shape[0]
+    sq, sk, d, dv = dims or (q.shape[1], k.shape[1], q.shape[2], q.shape[2])
+    pairs = bh * cost.admitted_pairs(
+        sq, sk, causal=masks.causal, causal_offset=masks.causal_offset,
+        window=masks.window)
+    over_d, over_dv = _PRODUCTS[kernel]
+    queries, outputs = ((bh, sq, d), q.dtype), ((bh, sq, dv), q.dtype)
+    keys, values = ((bhkv, sk, d), k.dtype), ((bhkv, sk, dv), k.dtype)
+    row = ((bh, sq), jnp.float32)  # log-sum-exp, delta
+    moved = {"flash_fwd": [outputs, row],  # written
+             "flash_bwd_dq": [outputs, row, row, queries],  # dO ... dq
+             "flash_bwd_dkv": [outputs, row, row, keys, values]}[kernel]
+    if bias is not None:
+        moved.append(((bias.shape[0], sk), bias.dtype))
+    return cost.estimate(
+        2 * pairs * (over_d * d + over_dv * dv),
+        pairs + (2 * bh * sq if kernel == "flash_fwd" else 0),
+        queries, keys, values, *moved)
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
@@ -297,9 +339,10 @@ def _fwd_kernel(
 
 
 def _fwd_pallas(q, k, v, bias, seed, h, *, sm_scale, causal, causal_offset,
-                dropout, block_q, block_k, window=0):
-    """q: [b*h, sq, d] and k, v: [b*hkv, sk, d], whole blocks. Returns the
-    output like q and the log-sum-exp rows, [b*h, 1, sq] float32."""
+                dropout, block_q, block_k, window=0, dims=None):
+    """q: [b*h, sq, d] and k, v: [b*hkv, sk, d], whole blocks; `dims`:
+    what `_cost` reads. Returns the output like q and the log-sum-exp
+    rows, [b*h, 1, sq] float32."""
     bh, sq, d = q.shape
     sk = k.shape[1]
     masks = _Masks.of(sq, sk, causal=causal, causal_offset=causal_offset,
@@ -358,6 +401,7 @@ def _fwd_pallas(q, k, v, bias, seed, h, *, sm_scale, causal, causal_offset,
         ],
         interpret=_interpret(),
         name="flash_fwd",
+        cost_estimate=_cost("flash_fwd", q, k, bias, masks, dims),
     )(seed, q, k, v, *bias_args)
     return out, lse
 
@@ -534,7 +578,7 @@ def _bwd_dkv_nobias(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, d
 
 def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
                 causal_offset, dropout, block_q, block_k, delta=None,
-                window=0):
+                window=0, dims=None):
     bh, sq, d = q.shape
     bhkv, sk = k.shape[0], k.shape[1]
     group = bh // bhkv
@@ -587,6 +631,7 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
         name="flash_bwd_dq",
+        cost_estimate=_cost("flash_bwd_dq", q, k, bias, masks, dims),
     )(seed, q, k, v, do, lse, delta, *bias_in)
 
     # ---- dk, dv: a key/value head a row of the grid -------------------
@@ -628,6 +673,7 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
         ],
         interpret=_interpret(),
         name="flash_bwd_dkv",
+        cost_estimate=_cost("flash_bwd_dkv", q, k, bias, masks, dims),
     )(seed, q, k, v, do, lse, delta, *bias_in)
     return dq, dk, dv
 
@@ -641,7 +687,7 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
 # the two custom calls only if they are the same call (a kernel traced
 # under the vjp rule is named `jvp_flash_fwd_` and is another).
 _STATICS = ("sm_scale", "causal", "causal_offset", "dropout", "block_q",
-            "block_k", "window")
+            "block_k", "window", "dims")
 _fwd_call = jax.jit(_fwd_pallas, static_argnums=(5,), static_argnames=_STATICS)
 _bwd_call = jax.jit(_bwd_pallas, static_argnums=(8,), static_argnames=_STATICS)
 
@@ -684,10 +730,13 @@ def _pad_inputs(q, k, v, bias, block_q, block_k):
     bq, bk = _ceil_to(bq, 16), _ceil_to(bk, 16)
     sq_p, sk_p, d_p = _ceil_to(sq, bq), _ceil_to(sk, bk), _ceil_to(d, LANE)
 
-    qf, kf, vf = (t.reshape(-1, t.shape[2], d) for t in (q, k, v))
-    if d_p != d:
-        pad = [(0, 0), (0, 0), (0, d_p - d)]
-        qf, kf, vf = (jnp.pad(x, pad) for x in (qf, kf, vf))
+    # the kernels have one head width: values narrower than the keys are
+    # padded with zeros up to it like the rest (their output columns stay
+    # zero, and their gradient's are cut off again)
+    qf, kf, vf = (
+        t if t.shape[2] == d_p else jnp.pad(
+            t, [(0, 0), (0, 0), (0, d_p - t.shape[2])])
+        for t in (t.reshape(-1, *t.shape[2:]) for t in (q, k, v)))
     if sq_p != sq:
         qf = jnp.pad(qf, [(0, 0), (0, sq_p - sq), (0, 0)])
     biasf = bias
@@ -819,14 +868,14 @@ def flash_attention(
 ):
     """Fused multi-head attention.
 
-    q: [b, h, sq, d]; k, v: [b, hkv, sk, d], `h` a multiple of `hkv`
-    (query head `n` reads key/value head `n // (h // hkv)`); bias:
-    additive key bias [b, sk] (0 keep / -inf drop) or None. `window` > 0
-    (with `causal`) admits only the last `window` keys a query may see.
-    Returns [b, h, sq, d] in q's dtype.
+    q: [b, h, sq, d]; k: [b, hkv, sk, d]; v: [b, hkv, sk, dv], `dv` up
+    to `d` and `h` a multiple of `hkv` (query head `n` reads key/value
+    head `n // (h // hkv)`); bias: additive key bias [b, sk] (0 keep /
+    -inf drop) or None. `window` > 0 (with `causal`) admits only the last
+    `window` keys a query may see. Returns [b, h, sq, dv] in q's dtype.
     """
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(d))
 
@@ -836,6 +885,9 @@ def flash_attention(
                          f"{k.shape[1]} key/value heads")
     if window and not causal:
         raise ValueError("flash_attention: a window needs causal=True")
+    if dv > d:
+        raise ValueError(f"flash_attention: values of width {dv} wider "
+                         f"than the keys ({d})")
     if dropout > 0.0 and rng_key is None:
         raise ValueError("dropout requires rng_key")
     if dropout > 0.0:
@@ -859,6 +911,7 @@ def flash_attention(
 
     statics = (("sm_scale", float(sm_scale)), ("causal", bool(causal)),
                ("causal_offset", causal_offset), ("dropout", float(dropout)),
-               ("block_q", bq), ("block_k", bk), ("window", int(window)))
+               ("block_q", bq), ("block_k", bk), ("window", int(window)),
+               ("dims", (sq, sk, d, dv)))
     out = _flash_core(qf, kf, vf, biasf, seed, h, statics)
-    return out[:, :sq, :d].reshape(b, h, sq, d)
+    return out[:, :sq, :dv].reshape(b, h, sq, dv)

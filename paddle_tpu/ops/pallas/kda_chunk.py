@@ -55,6 +55,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import cost
 from .flash_attention import LANE, _interpret, _use_pallas, require_pallas
 
 CHUNK = 64
@@ -338,6 +339,49 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref, dq_ref,
             ref[0, rows, :] = d.astype(ref.dtype)
 
 
+def _cost(backward, b, s, heads, dk, dv, dtypes):
+    """What one call declares (`cost.py` has the convention) for `s`
+    unpadded tokens a row; `dtypes`: of q, k, g and of v, o. Products
+    counted a chunk of `c` rows (the last may be short) of one head, in
+    multiply-adds, with `lower` = c(c+1)/2 pairs j <= i, `strict` =
+    c(c-1)/2 and `state` = c*dk*dv:
+
+    - `kda_fwd`: A and Aq over the keys' lanes (c*c*dk, the two triangles
+      together); [Wv, Wk] as the triangular solve it is (lower*(dk+dv));
+      Wk.S, Q.S and the state's update U^T.K (3 state); Aq.U (lower*dv).
+    - `kda_bwd`: A, Aq, W and Wk.S again (the reverse sweep keeps only
+      the chunks' states); dU = Aq^T.dO + K.dS (lower*dv + state);
+      dO.S, dU.S, U.dS, dO^T.Q, dU^T.Wk (5 state); dAq = dO.U^T
+      (lower*dv); the transposed solve and its product with W
+      ((lower+strict)*(dk+dv)); dA and dAq back to q and to k as row and
+      as column (2*c*c*dk).
+
+    Not counted: the ten [c, c] products that build the inverse (a solve
+    needs none), the diagonal blocks' sums over the lanes beyond their
+    pairs. Exponentials as the sub-chunk scheme evaluates them a chunk:
+    exp(G), exp(G_c - G), the sub-chunks' inner and outer factors and the
+    diagonal blocks', over the keys' lanes."""
+    def chunk(c):
+        lower, strict, state = c * (c + 1) // 2, c * (c - 1) // 2, c * dk * dv
+        if backward:
+            return (3 * c * c * dk + (2 * lower + strict) * (dk + dv)
+                    + 2 * lower * dv + 7 * state)
+        return c * c * dk + lower * (dk + dv) + lower * dv + 3 * state
+
+    chunks = -(-s // CHUNK)
+    macs = (s // CHUNK) * chunk(CHUNK) + chunk(s % CHUNK)
+    exps = chunks * dk * (2 * CHUNK + 1 + (CHUNK - SUB)
+                          + (CHUNK // SUB - 1) * CHUNK + SUB * CHUNK)
+    wide, narrow = dtypes
+    keys, values = ((b, s, heads * dk), wide), ((b, s, heads * dv), narrow)
+    beta = ((b, s, heads), jnp.float32)
+    states = ((b * heads, chunks, dv, dk), jnp.float32)
+    moved = [keys] * 3 + [values, beta, states, values]  # ..., o or dO
+    if backward:  # dq, dk, dg, dv, dbeta
+        moved += [keys] * 3 + [values, beta]
+    return cost.estimate(2 * b * heads * macs, b * heads * exps, *moved)
+
+
 @functools.partial(jax.jit, static_argnames=("statics",))
 def _call_fwd(q, k, v, g, beta, *, statics):
     """q, k, g: [b, S, h*dk]; v: [b, S, h*dv]; beta: [b, S, h]; S whole
@@ -346,7 +390,7 @@ def _call_fwd(q, k, v, g, beta, *, statics):
     forward that is differentiated and one that is not: a Program's
     gradient op lowers its forward op again, and XLA merges the two calls
     only if they are the same call."""
-    heads, steps, dtype, interpret = statics
+    heads, steps, dtype, interpret, s = statics
     b, S, _ = q.shape
     dk, dv = q.shape[2] // heads, v.shape[2] // heads
     rows = steps * CHUNK
@@ -371,13 +415,14 @@ def _call_fwd(q, k, v, g, beta, *, statics):
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="kda_fwd",
+        cost_estimate=_cost(False, b, s, heads, dk, dv, (q.dtype, v.dtype)),
     )(q, k, v, g, beta)
 
 
 @functools.partial(jax.jit, static_argnames=("statics",))
 def _call_bwd(q, k, v, g, beta, states, do, *, statics):
     """The reverse sweep: grid step j holds the chunks of step last - j."""
-    heads, steps, dtype, interpret = statics
+    heads, steps, dtype, interpret, s = statics
     b, S, _ = q.shape
     dk, dv = q.shape[2] // heads, v.shape[2] // heads
     rows = steps * CHUNK
@@ -410,6 +455,7 @@ def _call_bwd(q, k, v, g, beta, states, do, *, statics):
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="kda_bwd",
+        cost_estimate=_cost(True, b, s, heads, dk, dv, (q.dtype, v.dtype)),
     )(q, k, v, g, beta, states, do)
     # [b*h, n, 1, C] -> [b, S, h]
     dbeta = dbeta.reshape(b, heads, S).transpose(0, 2, 1)
@@ -453,5 +499,6 @@ def kda_chunk(q, k, v, g, beta):
         q, k, v, g = (jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
                       for t in (q, k, v, g))
         beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
-    o = _core(q, k, v, g, beta, (h, steps, _product_dtype(), _interpret()))
+    o = _core(q, k, v, g, beta,
+              (h, steps, _product_dtype(), _interpret(), s))
     return o[:, :s].reshape(b, s, h, dv)

@@ -79,6 +79,14 @@ def _call(x2, dy2, mean, rstd, scale, *, block_rows, interpret, mesh):
             ],
             interpret=interpret,
             name="ln_bwd",
+            # The one call of this package that declares no cost
+            # (`cost.py`). With 13 FLOPs an element and x, dy, dx, the
+            # statistics and the partial rows once (152 MB a call at
+            # BERT's [32768, 768]) declared, `bert_base_s128` ran 1.0 to
+            # 2.7% slower in every pair on the chip, and at the parent's
+            # rate with this one call left out: XLA reads a custom call's
+            # cost where it places arrays in VMEM, and the step compiled
+            # with the declaration keeps fewer there (PERF.md, PR 35).
         )(x2, dy2, mean.reshape(np_, 1), rstd.reshape(np_, 1), scale)
         return dx[:n], dg, db
 

@@ -37,7 +37,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import on_mesh
+from . import cost, on_mesh
 from .flash_attention import LANE, NEG_INF, _ceil_to, _interpret, require_pallas
 
 # The longest sequence that takes this kernel. Set from chip runs of the
@@ -210,6 +210,26 @@ def _pick_bb(b, sq, sk, hp, itemsize):
     return max(d for d in range(1, min(b, cap) + 1) if b % d == 0)
 
 
+def _cost(b, sq, sk, num_heads, width, dtype, *, causal, causal_offset,
+          has_bias, backward):
+    """What one call declares (`cost.py` has the convention), for `b`
+    batch rows of unpadded lengths `sq`, `sk`. Products counted a pair the
+    mask admits, a head, over the head's `dh` lanes: forward q.k and p.v
+    (4 FLOPs a pair a lane); backward q.k again (its residuals are the
+    operands), dO.v, p^T.dO, dS.k and dS^T.q (10). The stacked heads'
+    products over the other head's zeroed lanes are not counted. One
+    exponential a pair and one reciprocal a row."""
+    pairs = b * num_heads * cost.admitted_pairs(
+        sq, sk, causal=causal, causal_offset=causal_offset)
+    rows, keys = ((b, sq, width), dtype), ((b, sk, width), dtype)
+    return cost.estimate(
+        (10 if backward else 4) * pairs * (width // num_heads),
+        pairs + b * num_heads * sq,
+        rows, keys, keys, *([((b, sk), jnp.float32)] if has_bias else []),
+        # forward: the output; backward: dO in, dq, dk, dv out
+        *([rows, rows, keys, keys] if backward else [rows]))
+
+
 @functools.partial(jax.jit, static_argnames=("statics",))
 def _call(seed, q, k, v, bias, do, *, statics):
     """One pallas_call, the forward without `do` and the backward with it,
@@ -219,8 +239,9 @@ def _call(seed, q, k, v, bias, do, *, statics):
     bias as [b, 1, sk]. On a `mesh` (on_mesh.batch_shards said so) each
     shard of `batch` makes the call on its rows. Jitted so that the calls
     of one shape in a step (twelve in BERT) are traced and lowered once."""
-    (num_heads, sm_scale, causal, causal_offset, dropout, bb, interpret,
+    (num_heads, sm_scale, causal, (sq, sk), dropout, bb, interpret,
      mesh) = statics
+    causal_offset = sk - sq  # of the lengths before padding
     backward = do is not None
     has_bias = bias is not None
 
@@ -250,6 +271,11 @@ def _call(seed, q, k, v, bias, do, *, statics):
             compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
             interpret=interpret,
             name="mha_short_bwd" if backward else "mha_short_fwd",
+            # per shard on a mesh: the rows this call holds
+            cost_estimate=_cost(
+                q.shape[0], sq, sk, num_heads, q.shape[2], q.dtype,
+                causal=causal, causal_offset=causal_offset,
+                has_bias=has_bias, backward=backward),
         )(jnp.stack([seed[0], jnp.int32(row0)]), *args)
 
     args = [x for x in (q, k, v, bias, do) if x is not None]
@@ -324,6 +350,6 @@ def mha_short(q, k, v, num_heads, bias=None, causal=False, sm_scale=None,
             f"mha_short: a batch of {b} on {mesh}: the mesh has to shard "
             "`batch` alone and divide the batch")
     bb = _pick_bb(b // shards, sqp, skp, LANE // dh, q.dtype.itemsize)
-    statics = (num_heads, float(sm_scale), bool(causal), sk - sq,
+    statics = (num_heads, float(sm_scale), bool(causal), (sq, sk),
                float(dropout), bb, _interpret(), mesh)
     return _core(q, k, v, bias, seed, statics)[:, :sq]

@@ -47,6 +47,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..nn_ops import rotary_tables
+from . import cost
 from .flash_attention import LANE, _interpret, require_pallas
 
 ROWS = 1024  # rows of one head a grid step
@@ -157,7 +158,33 @@ def _specs(rows, d, heads, kv_heads):
             pl.BlockSpec((1, 1, SUBLANES, d), lambda b, i, t: (b, i, 0, 0)))
 
 
-def _call(kernel, name, statics, s, specs_in, specs_out, shapes_out, *args):
+def _cost(backward, b, s, d, statics, q_dtype):
+    """What one call declares (`cost.py` has the convention). No product:
+    FLOPs an element of q and k, forward 4 (the mean of squares 2, times
+    the inverse norm, times the weight) and 3 more where positions turn
+    it; backward 11 (the norm rebuilt 3, dn, its mean with n 2, dx 3, the
+    weight's gradient 2) and the same 3. One rsqrt a row of a head of q
+    and k. Moved once: q, k and v in and out, the two weights and, with
+    positions, the two tables; backward also q and k again and the
+    weights' partial sums, `[8, d]` a block of rows."""
+    heads, kv_heads, _, theta, rows, out_dtype, v_dtype = statics[:7]
+    normed = b * s * (heads + kv_heads)  # rows of one head of q and k
+    flat = [((b, s, n * d), t) for n, t in (
+        (heads, q_dtype), (kv_heads, q_dtype), (kv_heads, v_dtype))]
+    major = [((b, n, s, d), out_dtype) for n in (heads, kv_heads, kv_heads)]
+    moved = flat + major + [((d,), jnp.float32)] * 2
+    if theta:
+        moved += [((s, d), jnp.float32)] * 2
+    if backward:
+        moved += flat[:2] + [((b, pl.cdiv(s, rows), SUBLANES, d),
+                              jnp.float32)] * 2
+    return cost.estimate(
+        ((11 if backward else 4) + (3 if theta else 0)) * normed * d,
+        normed, *moved)
+
+
+def _call(kernel, name, statics, s, declared, specs_in, specs_out, shapes_out,
+          *args):
     """One of the two calls: the grid over (batch, row blocks, head
     slots), the slots sequential because an output block waits, unmoved,
     through the slots of the other arrays."""
@@ -172,6 +199,7 @@ def _call(kernel, name, statics, s, specs_in, specs_out, shapes_out, *args):
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=statics[-1],
         name=name,
+        cost_estimate=declared,
     )(*args)
 
 
@@ -183,6 +211,7 @@ def _fwd_pallas(q, k, v, wq, wk, statics):
     tables = rotary_tables(s, d, theta) if theta else ()
     return _call(
         _fwd_kernel, "qk_prep_fwd", statics, s,
+        _cost(False, b, s, d, statics, q.dtype),
         [*flat, weight, weight, *[table] * len(tables)], major,
         [jax.ShapeDtypeStruct((b, n, s, d), out_dtype)
          for n in (heads, kv_heads, kv_heads)],
@@ -199,7 +228,7 @@ def _bwd_pallas(dqo, dko, dvo, q, k, wq, wk, statics):
                                 jnp.float32)
     dq, dk, dv, dwq, dwk = _call(
         functools.partial(_bwd_kernel, s=s, rows=rows), "qk_prep_bwd",
-        statics, s,
+        statics, s, _cost(True, b, s, d, statics, q.dtype),
         [*major, *flat[:2], weight, weight, *[table] * len(tables)],
         [*flat, partial, partial],
         [jax.ShapeDtypeStruct(q.shape, q.dtype),

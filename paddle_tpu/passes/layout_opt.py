@@ -37,8 +37,8 @@ inference programs, where only the exact forward runs).
 
 Stats ride on the program as `program._layout_opt_stats`
 {removed, inserted, remaining, converted_ops} and the always-on
-counters `pass_layout_opt_transposes_removed`, `transpose_ops_before`,
-`transpose_ops_after` (tools/bench_passes.py --guard pins the
+gauges `transpose_ops_before`, `transpose_ops_after` (their difference
+is what the pass removed; tools/bench_passes.py --guard pins the
 elimination fraction >= 80% on a canned ResNet block).
 """
 
@@ -594,8 +594,6 @@ def propagate_layout(program, block, feed_names, fetch_names, ctx=None):
         "converted_ops": rw.converted_ops,
     }
     program._layout_opt_stats = stats
-    profiler.bump_counter("pass_layout_opt_transposes_removed",
-                          max(rw.removed - rw.inserted, 0))
     # bench-facing gauges: activation transposes the traced step pays,
     # NCHW-IR baseline vs after this pass (boundary transposes included)
     profiler.set_counter("transpose_ops_before", rw.removed + rw.remaining)

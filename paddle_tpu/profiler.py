@@ -11,7 +11,9 @@ there (TensorBoard, Perfetto, or `benchmark/harness/trace_reduce.py`);
 the device side replaces the reference's CUPTI DeviceTracer
 (platform/device_tracer.h:41). The `pt.*` spans the Executor and the
 reader write, and the `fwd/ bwd/ opt/` scopes on device operations, are
-listed in `PERF.md`."""
+listed in `PERF.md`. Each device operation also carries its FLOPs and
+bytes, the Pallas kernels' as they declare them (`ops/pallas/cost.py`):
+README, Profiling, says how to read them against the chip's peaks."""
 
 from __future__ import annotations
 
@@ -127,11 +129,10 @@ def set_counter(name: str, value: int) -> int:
     collective_bytes_estimate = crude per-step wire-traffic estimate;
     sharding_recompiles rides bump_counter — a program recompiling
     under a different mesh/spec signature), and the round-12 layout/
-    dispatch counters (pass_layout_opt_transposes_removed via bump = net
-    activation transposes layout_opt eliminated per compile;
-    transpose_ops_before / transpose_ops_after as gauges = the traced
-    step's activation-transpose count under NCHW IR vs after the pass,
-    most recent compile; attn_dispatch_xla / _flash / _ring / _ulysses
+    dispatch counters (transpose_ops_before / transpose_ops_after as
+    gauges = the traced step's activation-transpose count under NCHW IR
+    vs after the pass, most recent compile: their difference is what
+    layout_opt removed; attn_dispatch_xla / _flash / _ring / _ulysses
     via bump = attention path chosen at trace time, fwd + grad replay
     each count; pallas_on_mesh_calls via bump = lowerings that run
     their Pallas kernel per shard of a data-parallel mesh

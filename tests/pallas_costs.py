@@ -1,0 +1,41 @@
+"""What the Pallas calls of a traced function declare: the tests of the
+kernels' `cost_estimate` (ops/pallas/cost.py has the convention) read the
+`pallas_call` equations of a jaxpr, nested ones included, and hold them
+to counts written out by hand."""
+
+import jax
+from jax.extend import core as jex_core
+
+
+def _jaxprs(value):
+    if isinstance(value, jex_core.ClosedJaxpr):
+        yield value.jaxpr
+    elif isinstance(value, jex_core.Jaxpr):
+        yield value
+    elif isinstance(value, (tuple, list)):
+        for item in value:
+            yield from _jaxprs(item)
+
+
+def _walk(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.setdefault(eqn.params["name"],
+                             []).append(eqn.params["cost_estimate"])
+            continue  # the kernel's own body holds no call
+        for value in eqn.params.values():
+            for inner in _jaxprs(value):
+                _walk(inner, found)
+
+
+def declared(fn, *args) -> dict:
+    """Kernel name -> the `pl.CostEstimate` of each of its calls in
+    `fn(*args)`, in the order they are traced. Nothing runs."""
+    found = {}
+    _walk(jax.make_jaxpr(fn)(*args).jaxpr, found)
+    return found
+
+
+def numbers(estimate) -> tuple:
+    return (estimate.flops, estimate.transcendentals,
+            estimate.bytes_accessed)

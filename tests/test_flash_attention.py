@@ -267,6 +267,90 @@ def test_blocks_visited_against_a_count_by_hand(dims, window, by_hand):
                 b * h * by_hand[0], b * h * by_hand[1])
 
 
+# the cost each kernel declares (ops/pallas/cost.py has the convention):
+# (b, h, hkv, s, d, dv), window, pairs a head the masks admit, by hand
+_COST_CASES = {
+    # every key up to the query's own: 1 + 2 + ... + 1024
+    "causal": ((1, 2, 2, 1024, 128, 128), 0, 1024 * 1025 // 2),
+    # 2,048 keys at most, over a row twice as long: the first 2,048 rows
+    # see 1 .. 2,048 keys, the other 2,048 rows 2,048 each
+    "window": ((1, 2, 2, 4096, 128, 128), 2048,
+               2048 * 2049 // 2 + 2048 * 2048),
+    "window_s_causal_twin": ((1, 2, 2, 4096, 128, 128), 0, 4096 * 4097 // 2),
+    # eight query heads a key/value head: K and V are moved once, not eight
+    # times
+    "group_of_8": ((1, 8, 1, 512, 128, 128), 0, 512 * 513 // 2),
+    # the latent layer: keys 192 wide, values 128, both padded to 256
+    # lanes inside; the count is at 192 and 128
+    "latent_values_narrower": ((1, 2, 2, 512, 192, 128), 0, 512 * 513 // 2),
+}
+
+
+def _declared_by_flash(dims, window):
+    from pallas_costs import declared
+
+    b, h, hkv, s, d, dv = dims
+    q = jnp.zeros((b, h, s, d), jnp.bfloat16)
+    k = jnp.zeros((b, hkv, s, d), jnp.bfloat16)
+    v = jnp.zeros((b, hkv, s, dv), jnp.bfloat16)
+    found = declared(jax.grad(lambda *a: jnp.sum(fa.flash_attention(
+        *a, causal=True, window=window).astype(jnp.float32)),
+        argnums=(0, 1, 2)), q, k, v)
+    assert {n: len(c) for n, c in found.items()} == {
+        "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    return {name: calls[0] for name, calls in found.items()}
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv"])
+@pytest.mark.parametrize("case", list(_COST_CASES))
+def test_declared_cost_against_a_count_by_hand(case, kernel):
+    """Useful FLOPs on the pairs the masks admit at the model's widths,
+    one exponential a pair, and every operand and output moved once at
+    its unpadded shape (bf16: 2 bytes; the log-sum-exp and delta rows
+    float32)."""
+    dims, window, pairs = _COST_CASES[case]
+    b, h, hkv, s, d, dv = dims
+    got = _declared_by_flash(dims, window)[kernel]
+    pairs *= b * h
+    q_bytes, o_bytes = 2 * b * h * s * d, 2 * b * h * s * dv
+    k_bytes, v_bytes = 2 * b * hkv * s * d, 2 * b * hkv * s * dv
+    row_bytes = 4 * b * h * s
+    flops, exps, moved = {
+        # q.k over d lanes, p.v over dv: 2 FLOPs a multiply-add
+        "flash_fwd": (2 * pairs * (d + dv), pairs + 2 * b * h * s,
+                      q_bytes + k_bytes + v_bytes + o_bytes + row_bytes),
+        # q.k, dS.k over d; dO.v over dv. In: q, k, v, dO, lse, delta
+        "flash_bwd_dq": (2 * pairs * (2 * d + dv), pairs,
+                         q_bytes + k_bytes + v_bytes + o_bytes
+                         + 2 * row_bytes + q_bytes),
+        # q.k, dS^T.q over d; dO.v, p^T.dO over dv. Out: dk, dv
+        "flash_bwd_dkv": (2 * pairs * (2 * d + 2 * dv), pairs,
+                          q_bytes + k_bytes + v_bytes + o_bytes
+                          + 2 * row_bytes + k_bytes + v_bytes),
+    }[kernel]
+    assert (got.flops, got.transcendentals, got.bytes_accessed) == (
+        flops, exps, moved)
+    if d == dv:  # the benchmark adapter's 4, 6 and 8 FLOPs a pair a lane
+        assert got.flops == {"flash_fwd": 4, "flash_bwd_dq": 6,
+                             "flash_bwd_dkv": 8}[kernel] * pairs * d
+
+
+def test_a_window_declares_fewer_flops_than_its_causal_twin_by_the_pairs():
+    """The window's count differs from the causal one's by the pairs the
+    window refuses, (s - w)(s - w + 1) / 2 a head, and not by what whole
+    512 x 512 blocks would give (8 x 8 blocks: 36 against 26 visited)."""
+    (b, h, _, s, d, _), window, _ = _COST_CASES["window"]
+    causal = _declared_by_flash(*_COST_CASES["window_s_causal_twin"][:2])
+    banded = _declared_by_flash(*_COST_CASES["window"][:2])
+    refused = b * h * (s - window) * (s - window + 1) // 2
+    for kernel, per_pair in (("flash_fwd", 4), ("flash_bwd_dq", 6),
+                             ("flash_bwd_dkv", 8)):
+        assert (causal[kernel].flops - banded[kernel].flops
+                == per_pair * d * refused)
+        assert causal[kernel].bytes_accessed == banded[kernel].bytes_accessed
+
+
 def test_window_and_group_are_refused_where_they_cannot_run(rng):
     q, k, v, _ = _band_case(rng, 1, 4, 3, 128, 128, 64)
     with pytest.raises(ValueError, match="4 query heads over 3"):

@@ -178,3 +178,51 @@ def test_dispatch_takes_the_kernel_at_width_128_and_the_plain_path_at_16(
     # (another length: a path is chosen when a program is lowered)
     assert build_and_run(128, seq=9).shape == (1, 9, 256)
     assert calls == ["pallas", "chunked", "chunked"]
+
+
+@pytest.mark.parametrize("kernel", ["kda_fwd", "kda_bwd"])
+@pytest.mark.parametrize("length", [128, 100])
+def test_declared_cost_against_a_count_by_hand(interpreter, kernel, length):
+    """ops/pallas/cost.py's convention at dk = dv = 128: the multiply-adds
+    a chunk of c rows needs, listed in `kda_chunk._cost`, here written out
+    for c = 64 and for the 36 rows of a short last chunk (the rows padded
+    up to whole grid steps count nothing); float32 q, k, g and beta,
+    values and output bf16, the chunks' states float32."""
+    import jax
+    import jax.numpy as jnp
+    from pallas_costs import declared
+
+    from paddle_tpu.ops.pallas.kda_chunk import kda_chunk
+
+    q, k, v, g, beta = _args(length, -1.0, -0.01)
+    v = v.astype(jnp.bfloat16)
+    found = declared(jax.grad(lambda *a: jnp.sum(
+        kda_chunk(*a).astype(jnp.float32)), argnums=range(5)),
+        q, k, v, g, beta)
+    (got,) = found[kernel]
+
+    def macs(c):
+        lower, strict, state = c * (c + 1) // 2, c * (c - 1) // 2, c * D * D
+        if kernel == "kda_fwd":
+            # A and Aq; the solve for [Wv, Wk]; Wk.S, Q.S, U^T.K; Aq.U
+            return c * c * D + lower * 2 * D + 3 * state + lower * D
+        # A, Aq, the solve and Wk.S again; dU (Aq^T.dO, K.dS); dO.S, dU.S,
+        # U.dS, dO^T.Q, dU^T.Wk; dAq; the transposed solve and lam.W^T; dA
+        # and dAq back to q and to k (as row and as column)
+        return (c * c * D + lower * 2 * D + state + lower * D + state
+                + 5 * state + lower * D + (lower + strict) * 2 * D
+                + 2 * c * c * D)
+
+    chunks = {128: [64, 64], 100: [64, 36]}[length]
+    assert got.flops == 2 * B * H * sum(macs(c) for c in chunks)
+    # a chunk's exponentials over the 128 lanes: exp(G) and exp(G_c - G)
+    # 64 rows each, exp(G_c) 1, three sub-chunks' inner (16 rows) and outer
+    # (64 rows) factors, the diagonal blocks 16 x 64
+    assert got.transcendentals == B * H * 2 * D * (
+        64 + 64 + 1 + 3 * 16 + 3 * 64 + 16 * 64)
+    wide, narrow = 4 * B * length * H * D, 2 * B * length * H * D
+    beta_bytes, states = 4 * B * length * H, 4 * B * H * 2 * D * D
+    moved = 3 * wide + narrow + beta_bytes + states + narrow  # ..., o or dO
+    if kernel == "kda_bwd":  # dq, dk, dg, dv, dbeta
+        moved += 3 * wide + narrow + beta_bytes
+    assert got.bytes_accessed == moved

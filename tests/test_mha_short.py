@@ -200,3 +200,35 @@ def test_shapes_outside_the_rule_raise():
     q, k, v, _ = _mk(1, 2, 16, 16, 64, False)
     with pytest.raises(ValueError, match="rng_key"):
         mha_short(q, k, v, 2, dropout=0.1)
+
+
+@pytest.mark.parametrize("kernel", ["mha_short_fwd", "mha_short_bwd"])
+@pytest.mark.parametrize("b,h,sq,sk,d,use_bias,causal,pairs", [
+    # the transformer's decoder: every key up to the query's own
+    (4, 4, 64, 64, 64, False, True, 64 * 65 // 2),
+    # cross attention with a key bias, padded inside to 32 and 48: the
+    # count is at 20 and 40, every pair (a bias's refusals are data)
+    (2, 2, 20, 40, 128, True, False, 20 * 40),
+])
+def test_declared_cost_against_a_count_by_hand(kernel, b, h, sq, sk, d,
+                                               use_bias, causal, pairs):
+    """ops/pallas/cost.py's convention: forward q.k and p.v, backward q.k
+    again, dO.v, p^T.dO, dS.k, dS^T.q, each 2 FLOPs a pair a lane of the
+    head; an exponential a pair and a reciprocal a row; every operand and
+    output once (bf16 here, the bias float32)."""
+    from pallas_costs import declared
+
+    q, k, v, bias = _mk(b, h, sq, sk, d, use_bias, dtype=jnp.bfloat16)
+    found = declared(jax.grad(lambda q, k, v: jnp.sum(mha_short(
+        q, k, v, h, bias=bias, causal=causal).astype(jnp.float32)),
+        argnums=(0, 1, 2)), q, k, v)
+    (got,) = found[kernel]
+    pairs *= b * h
+    q_bytes, k_bytes = 2 * b * sq * h * d, 2 * b * sk * h * d
+    read = q_bytes + 2 * k_bytes + (4 * b * sk if use_bias else 0)
+    if kernel == "mha_short_fwd":
+        want = (2 * 2 * pairs * d, read + q_bytes)
+    else:  # dO in; dq, dk, dv out
+        want = (5 * 2 * pairs * d, read + q_bytes + q_bytes + 2 * k_bytes)
+    assert (got.flops, got.bytes_accessed) == want
+    assert got.transcendentals == pairs + b * h * sq

@@ -147,6 +147,33 @@ def test_ln_bwd_per_shard_equals_one_device(n, k):
                                    atol=1e-4)
 
 
+# ------------------------------------------------- what a shard declares
+
+
+@pytest.mark.parametrize("kernel", ["mha_short_fwd", "mha_short_bwd"])
+@pytest.mark.parametrize("use_bias", [False, True])
+def test_a_quarter_of_the_batch_declares_a_quarter_of_the_cost(kernel,
+                                                               use_bias):
+    """The `cost_estimate` is computed inside the manual region from the
+    rows the shard holds, so each of four chips' custom calls carries its
+    own work: a quarter of the one-device call's FLOPs, transcendentals
+    and bytes (every operand is split along the batch)."""
+    from pallas_costs import declared, numbers
+
+    b, h, s, d = 8, 2, 32, 64
+    q, k, v, bias = _qkv(b, h, s, s, d, use_bias)
+
+    def call(mesh):
+        return jax.grad(lambda q, k, v: jnp.sum(mha_short(
+            q, k, v, h, bias=bias, causal=True, mesh=mesh)),
+            argnums=(0, 1, 2))
+
+    (one,) = declared(call(None), q, k, v)[kernel]
+    (shard,) = declared(call(_mesh()), q, k, v)[kernel]
+    assert all(n % 4 == 0 for n in numbers(one))
+    assert numbers(shard) == tuple(n // 4 for n in numbers(one))
+
+
 # ------------------------------------------------- through the lowering
 
 
@@ -263,7 +290,7 @@ def test_kda_kernels_compile_for_a_v5e_chip_at_the_published_widths(topo):
 
     chip = SingleDeviceSharding(topo.devices[0])
     b, s, h, d = 1, 4096, 32, 128
-    statics = (h, kda_chunk.CHUNKS_PER_STEP, jnp.bfloat16, False)
+    statics = (h, kda_chunk.CHUNKS_PER_STEP, jnp.bfloat16, False, s)
 
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)

@@ -530,7 +530,6 @@ def test_layout_opt_stats_and_counters():
     assert frac >= 0.8, lo  # the ISSUE-9 acceptance floor
     assert lo["converted_ops"] > 0
     c = profiler.counters()
-    assert c.get("pass_layout_opt_transposes_removed", 0) > 0
     assert c["transpose_ops_before"] > c["transpose_ops_after"]
     # every conv/pool/bn in the rewritten block runs NHWC
     for op in b2.ops:

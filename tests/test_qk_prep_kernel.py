@@ -182,3 +182,35 @@ def test_the_kernels_names_are_not_the_flash_kernels():
         assert re.search(mine, name) and not re.search(theirs, name)
     for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert not re.search(mine, name)
+
+
+@pytest.mark.parametrize("kernel", ["qk_prep_fwd", "qk_prep_bwd"])
+@pytest.mark.parametrize("theta", [0.0, THETA], ids=["no_positions", "rope"])
+def test_declared_cost_against_a_count_by_hand(kernel, theta):
+    """ops/pallas/cost.py's convention: no product, so FLOPs an element of
+    q and k (4 forward, 11 backward, 3 more with positions) and an rsqrt
+    a row of a head; q, k, v once in (bf16) and once out, the weights and
+    the tables float32, and backward q and k again and the weights'
+    partial sums, [8, d] a block of 64 rows."""
+    import jax
+    import jax.numpy as jnp
+    from pallas_costs import declared, numbers
+
+    from paddle_tpu.ops.pallas.qk_prep import qk_prep
+
+    b, s, h, g, d, rows = 2, 200, 4, 1, 128, 64
+    args, _ = _args(b, s, h, g, d, jnp.bfloat16)
+    found = declared(jax.grad(lambda *a: sum(
+        jnp.sum(o.astype(jnp.float32)) for o in qk_prep(
+            *a, epsilon=EPS, theta=theta, rows=rows)), argnums=range(5)),
+        *args)
+    (got,) = found[kernel]
+    backward, rope = kernel == "qk_prep_bwd", bool(theta)
+    normed = b * s * (h + g)  # rows of one head of q and k
+    qkv = 2 * b * s * (h + 2 * g) * d  # bytes of q, k and v together
+    moved = 2 * qkv + 2 * 4 * d + (2 * 4 * s * d if rope else 0)
+    if backward:
+        moved += 2 * b * s * (h + g) * d + 2 * 4 * b * 4 * 8 * d
+    assert numbers(got) == (
+        ((11 if backward else 4) + (3 if rope else 0)) * normed * d,
+        normed, moved)

@@ -233,3 +233,69 @@ def test_record_event_keeps_the_table_without_a_trace(tmp_path):
             pass
     rows = profiler.stop_profiler(profile_path=str(tmp_path / "table.txt"))
     assert [(r[0], r[1]) for r in rows] == [("inside", 2)]
+
+
+# ---------------------------------------- what reads the declared costs
+
+EXPERT_CELLS = ["kimi_linear_ep32_s4096", "trinity_mini_ep16_s8192"]
+FC_CELLS = ["bert_base_s128", "bert_base_s128_dp4", "bert_base_s512",
+            "kimi_linear_ep32_s4096", "transformer_base_s64",
+            "trinity_mini_ep16_s8192"]
+FLASH_CALL = ("%flash_fwd.12 = (bf16[32,8192,128]{2,1,0}, f32[32,1,8192]) "
+              "custom-call(%seed, %q, %k, %v)")
+
+
+@pytest.mark.parametrize("metric,layer,key,bound,cells,hits,misses", [
+    ("fc_roofline_pct", "Op lowerings", "scope", "bf16_flops", FC_CELLS,
+     ["fwd/mul/dot_general", "bwd/matmul_grad/transpose(jvp())/dot_general"],
+     ["fwd/elementwise_mul/mul", "opt/fused_adam/mul", ""]),
+    ("conv_roofline_pct", "Op lowerings", "scope", "bf16_flops",
+     ["resnet50_b128"],
+     ["fwd/conv2d/conv_general_dilated", "bwd/conv2d_grad/transpose(jvp())"],
+     ["fwd/batch_norm/mul"]),
+    ("moe_grouped_roofline_pct", "Op lowerings", "name", "bf16_flops",
+     EXPERT_CELLS, ["%ragged-dot-none.37 = f32[8,2048,1024]{2,1,0}"],
+     ["%fusion.3 = f32[8] fusion(%ragged-dot-none.37)"]),
+    ("attn_short_roofline_pct", "Pallas kernels", "name", "bf16_flops",
+     ["bert_base_s128", "bert_base_s128_dp4", "bert_base_s512",
+      "transformer_base_s64"],
+     ["%mha_short_bwd.7 = (bf16[256,128,768]) custom-call(%a)",
+      "mha_short_fwd = bf16[256,128,768] custom-call(%a)"],
+     ["%copy.1 = bf16[256,128,768] copy(%mha_short_fwd.3)"]),
+    ("flash_roofline_pct", "Pallas kernels", "name", "bf16_flops",
+     EXPERT_CELLS, [FLASH_CALL, FLASH_CALL.replace("fwd", "bwd_dkv")],
+     ["%fusion.9 = bf16[8,8] fusion(%flash_fwd.12), kind=kLoop"]),
+    ("kda_roofline_pct", "Pallas kernels", "name", "bf16_flops",
+     ["kimi_linear_ep32_s4096"], ["%kda_bwd.2 = (f32[1,4096,4096])"],
+     ["%fusion.1 = f32[8] fusion(%kda_fwd.2)"]),
+    ("qk_prep_hbm_pct", "Pallas kernels", "name", "hbm_bytes_per_s",
+     ["trinity_mini_ep16_s8192"], ["%qk_prep_fwd.13 = (bf16[1,32,8192,128])"],
+     [FLASH_CALL]),
+])
+def test_a_roofline_metric_names_what_it_reads_and_the_cells_that_have_it(
+        metric, layer, key, bound, cells, hits, misses):
+    """PR 35's per-layer metrics: declared in `BENCHMARK.json` for the
+    cells whose traces hold what they read, read by `trace_roofline` from
+    the events' own `flops` or `bytes_accessed`, by an expression that
+    finds the kernel (or the Program op's scope) and not what reads its
+    output."""
+    import json
+
+    from benchmark.harness import spec
+
+    with open(os.path.join(os.path.dirname(spec.BENCH_DIR),
+                           "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    (declared,) = [m for m in bench["per_layer"] if m["name"] == metric]
+    assert declared == {
+        "name": metric, "unit": "%", "better": "higher",
+        "source": "device_trace", "layer": layer,
+        "moves": "train_examples_per_s", "workloads": cells}
+    m = spec.load("layer_metrics", metric)
+    assert m["kind"] == "trace_roofline" and m["args"]["bound"] == bound
+    assert sorted(
+        w["name"] for w in bench["workloads"]
+        if metric in {x["name"] for x in spec.layer_metrics(
+            spec.cell(w["name"]))}) == cells
+    assert all(re.search(m["args"][key], text) for text in hits)
+    assert not any(re.search(m["args"][key], text) for text in misses)

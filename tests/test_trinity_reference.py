@@ -473,7 +473,6 @@ def test_the_cell_declares_the_kernel_pairs_metric():
                            "BENCHMARK.json")) as f:
         bench = json.load(f)
     declared = {m["name"]: m for m in bench["per_layer"]}
-    assert bench["per_layer"][-1]["name"] == "qk_prep_ms_per_step"
     assert declared["qk_prep_ms_per_step"] == {
         "name": "qk_prep_ms_per_step", "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": "Pallas kernels",
