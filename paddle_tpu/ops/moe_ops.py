@@ -54,6 +54,9 @@ def _moe_experts(ctx, op):
             f"moe_experts: Gate has {gate.shape[1]} columns, experts_total "
             f"is {total}")
     profiler.bump_counter("moe_dispatch_grouped")
+    # the first block is straight-line in every lowering, so that XLA merges
+    # the forward op's with the one the gradient op replays
+    profiler.bump_counter("moe_first_block_shared")
     profiler.set_counter("moe_experts_held", held)
     profiler.set_counter("moe_experts_total", total)
     x = ctx.in_(op, "X")

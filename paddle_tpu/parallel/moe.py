@@ -149,9 +149,10 @@ def moe_ffn(params, x, capacity_factor=1.25, k=2, compute_dtype=None):
 # ---------------------------------------------------------------------------
 
 # Rows of one grouped product: the sorted assignments go through their
-# experts a block of this share of the N*k at a time, as many blocks as the
-# assignments to the held experts fill (a `while` with a trip count read
-# from the load). A balanced router sends 8 experts of 256 a 32nd of the
+# experts a block of this share of the N*k at a time: the first block
+# always, straight-line, and as many more as the assignments to the held
+# experts fill past it (a `while` with a trip count read from the load).
+# A balanced router sends 8 experts of 256 a 32nd of the
 # assignments, but a freshly seeded one is skewed and Adam moves it towards
 # the experts it has: at 4,096 tokens a layer's load passed an eighth (two
 # blocks then, 6 ms more a layer) in two seeds of nine, one that started
@@ -159,7 +160,10 @@ def moe_ffn(params, x, capacity_factor=1.25, k=2, compute_dtype=None):
 # (PERF.md, PR 31). A quarter keeps
 # one block the rule, so a step's time does not follow the seed; a skew
 # past it costs blocks, not tokens: both are static shapes, and nothing is
-# dropped.
+# dropped. The rule is also what is cheap (PERF.md, PR 36): the first
+# block's gathered rows, its two float32 products, `h` and the down product
+# are made once and read by its backward, where a block past it makes its
+# three forward products again inside the backward's loop.
 BLOCK_SHARE = 4  # 1/4 of the N*k assignments
 
 
@@ -206,7 +210,11 @@ def _block(j, rows, x, w_gate, w_up, w_down, token, weight, sizes, dtype):
     xs = jnp.where(live, x[token], jnp.zeros((), x.dtype)).astype(dtype)
     part = part.at[-1].add(rows - jnp.sum(part))
 
+    @jax.checkpoint
     def dot(a, w):
+        # a weight's cast is made again for the backward's transposed
+        # product and not kept from the forward: the first block's
+        # backward would hold 0.1 GB a weight a layer at the cells' widths
         return jax.lax.ragged_dot(a, w.astype(dtype), part,
                                   preferred_element_type=jnp.float32)
 
@@ -216,25 +224,44 @@ def _block(j, rows, x, w_gate, w_up, w_down, token, weight, sizes, dtype):
         jnp.where(live, y, 0.0))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
 def _held_experts(x, w_gate, w_up, w_down, token, weight, sizes, dtype):
+    """The sorted assignments through the experts held here: [N, D]
+    float32. The first block is straight-line JAX that `jax.vjp` goes
+    through like any other op's lowering, so the forward op and the replay
+    inside its gradient op emit one block and XLA merges them; the blocks a
+    skewed load fills past it are added by `_overflow`."""
+    rows = _block_rows(token.shape[0])
+    first = _block(0, rows, x, w_gate, w_up, w_down, token, weight, sizes,
+                   dtype)
+    return _overflow(first, x, w_gate, w_up, w_down, token, weight, sizes,
+                     dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def _overflow(first, x, w_gate, w_up, w_down, token, weight, sizes, dtype):
+    """`first` and the blocks from the second on, as many as the load
+    fills: none in a layer whose load stays under a block."""
     rows = _block_rows(token.shape[0])
     return jax.lax.fori_loop(
-        0, _block_count(sizes, rows),
+        1, _block_count(sizes, rows),
         lambda j, out: out + _block(j, rows, x, w_gate, w_up, w_down, token,
                                     weight, sizes, dtype),
-        jnp.zeros(x.shape, jnp.float32))
+        first)
 
 
-def _held_experts_fwd(x, w_gate, w_up, w_down, token, weight, sizes, dtype):
-    out = _held_experts(x, w_gate, w_up, w_down, token, weight, sizes, dtype)
+def _overflow_fwd(first, x, w_gate, w_up, w_down, token, weight, sizes, dtype):
+    out = _overflow(first, x, w_gate, w_up, w_down, token, weight, sizes,
+                    dtype)
     return out, (x, w_gate, w_up, w_down, token, weight, sizes)
 
 
-def _held_experts_bwd(dtype, res, g):
+def _overflow_bwd(dtype, res, g):
     # A loop of its own, block by block as the forward: the trip count is
-    # data, which jax.vjp cannot take through a `while`, and a block's
-    # intermediates are rebuilt here and not kept from the forward.
+    # data, which jax.vjp cannot take through a `while`, so an overflow
+    # block's intermediates are rebuilt here and not kept from the forward
+    # (nine grouped products a trip where the first block's backward makes
+    # six). XLA's CSE does not look into a `while` body either: what runs
+    # every step has to stay out of one.
     x, w_gate, w_up, w_down, token, weight, sizes = res
     rows = _block_rows(token.shape[0])
     diff = (x, w_gate, w_up, w_down, weight)
@@ -247,11 +274,11 @@ def _held_experts_bwd(dtype, res, g):
 
     zeros = jax.tree.map(lambda t: jnp.zeros(t.shape, t.dtype), diff)
     dx, da, db, dc, dw = jax.lax.fori_loop(
-        0, _block_count(sizes, rows), body, zeros)
-    return dx, da, db, dc, None, dw, None
+        1, _block_count(sizes, rows), body, zeros)
+    return g, dx, da, db, dc, None, dw, None
 
 
-_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+_overflow.defvjp(_overflow_fwd, _overflow_bwd)
 
 
 def moe_experts(x, gate, bias, w_gate, w_up, w_down, k, scaling,

@@ -502,7 +502,11 @@ def test_counters_and_flops_of_the_cell():
     loads = exe.run(main, feed=batch, fetch_list=built["loads"])
     c1 = profiler.counters()
     assert c1["kda_dispatch_chunked"] - c0.get("kda_dispatch_chunked", 0) >= 4
-    assert c1["moe_dispatch_grouped"] - c0.get("moe_dispatch_grouped", 0) >= 4
+    grouped = c1["moe_dispatch_grouped"] - c0.get("moe_dispatch_grouped", 0)
+    assert grouped >= 4
+    # every lowering of the layer takes its first block straight-line
+    assert c1["moe_first_block_shared"] - c0.get(
+        "moe_first_block_shared", 0) == grouped
     assert (c1["moe_experts_held"], c1["moe_experts_total"]) == (2, 8)
     assert len(loads) == 4 and all(x.shape == (2,) for x in loads)
 

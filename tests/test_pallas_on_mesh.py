@@ -344,28 +344,40 @@ def test_qk_prep_kernels_compile_for_a_v5e_chip_at_the_published_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-def test_trinity_step_compiled_for_v5e_holds_no_float32_array_of_q(
-        topo, monkeypatch):
-    """The cell's whole train step, built as its runner builds it, for a
-    described chip: the kernel pair is in it five times each way, and
-    the float32 `[1, 8192, 32, 128]` and `[1, 8192, 4, 128]` views of
-    QK-norm and rotation that XLA laid out its own way and relaid (215 and
-    231 mentions in the parent's step, 28 copies) are gone, in any layout
-    and under either shape. Nothing runs."""
+@pytest.fixture(scope="module")
+def trinity_step(topo):
+    """The cell's whole train step, built as its runner builds it and
+    compiled for a described chip, once for the tests that read it: the
+    optimised HLO, and what the lowering added to each counter. Nothing
+    runs."""
     import importlib
-    import re
 
     from benchmark.harness import spec
     from benchmark.tests.test_compile_v5e import lower_train_step
 
     module = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
-    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET")
-    monkeypatch.setattr(module, "_use_pallas", lambda: True)  # as on the chip
-    before = profiler.counters().get("attn_qk_prep_fused", 0)
-    text = lower_train_step(spec.cell("trinity_mini_ep16_s8192"),
-                            topo.devices).compile().as_text()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+        patch.setattr(module, "_use_pallas", lambda: True)  # as on the chip
+        before = profiler.counters()
+        text = lower_train_step(spec.cell("trinity_mini_ep16_s8192"),
+                                topo.devices).compile().as_text()
+        after = profiler.counters()
+    return text, {n: v - before.get(n, 0) for n, v in after.items()}
+
+
+def test_trinity_step_compiled_for_v5e_holds_no_float32_array_of_q(
+        trinity_step):
+    """The kernel pair is in the step five times each way, and
+    the float32 `[1, 8192, 32, 128]` and `[1, 8192, 4, 128]` views of
+    QK-norm and rotation that XLA laid out its own way and relaid (215 and
+    231 mentions in the parent's step, 28 copies) are gone, in any layout
+    and under either shape."""
+    import re
+
+    text, bumped = trinity_step
     # the forward op and the gradient op's replay, five layers
-    assert profiler.counters()["attn_qk_prep_fused"] == before + 10
+    assert bumped["attn_qk_prep_fused"] == 10
     calls = re.findall(r"^\s*%?(qk_prep_\w+?|flash_\w+?)[.\d]* = .* custom-call\(",
                        text, re.M)
     assert calls.count("qk_prep_fwd") == 5 and calls.count("qk_prep_bwd") == 5
@@ -373,3 +385,21 @@ def test_trinity_step_compiled_for_v5e_holds_no_float32_array_of_q(
     for shape in ("1,8192,32,128", "1,8192,4096", "1,8192,4,128",
                   "1,8192,512"):
         assert f"f32[{shape}]" not in text, shape
+
+
+def test_trinity_step_compiled_for_v5e_makes_each_first_block_once(
+        trinity_step):
+    """Four expert layers: the 36 `ragged-dot` calls that run every step
+    lie outside every `while` (a layer's first block: gate, up and down
+    once for the forward op and the gradient op's replay, and the six of
+    its backward); the overflow loops hold 3 and 9 a layer, for the trips
+    a load past a quarter of the assignments costs."""
+    import re
+
+    from test_moe_experts import in_and_out_of_whiles
+
+    text, bumped = trinity_step
+    assert bumped["moe_first_block_shared"] == 8  # forward and replay
+    assert bumped["moe_dispatch_grouped"] == 8
+    assert in_and_out_of_whiles(text, re.compile(
+        r"%ragged-dot-none[.\d]* = ").search) == (36, 48)

@@ -587,7 +587,11 @@ def test_counters_and_flops_of_the_cell():
     batch = batch_for(small, small_traffic)
     loads = exe.run(main, feed=batch, fetch_list=built["loads"])
     c1 = profiler.counters()
-    assert c1["moe_dispatch_grouped"] - c0.get("moe_dispatch_grouped", 0) >= 4
+    grouped = c1["moe_dispatch_grouped"] - c0.get("moe_dispatch_grouped", 0)
+    assert grouped >= 4
+    # every lowering of the layer takes its first block straight-line
+    assert c1["moe_first_block_shared"] - c0.get(
+        "moe_first_block_shared", 0) == grouped
     assert (c1["moe_experts_held"], c1["moe_experts_total"]) == (2, 8)
     assert c1["attn_kv_group"] == 2
     assert c1["attn_dispatch_xla"] - c0.get("attn_dispatch_xla", 0) >= 5
