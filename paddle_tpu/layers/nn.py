@@ -7,6 +7,8 @@ framework/operator.h:455)."""
 
 from __future__ import annotations
 
+import math
+
 import numpy as np
 
 from ..framework import Variable, unique_name
@@ -668,13 +670,37 @@ def rms_norm(input, begin_norm_axis=1, epsilon=1e-5, param_attr=None,
         dtype=input.dtype, shape=input.shape, out_slot="Y")
 
 
-def rotary_embedding(input, theta=10000.0, name=None):
+def _yarn_attr(rope_scaling):
+    """YaRN's five numbers as an op attribute, from the keys a published
+    `rope_parameters` group gives them under: factor,
+    original_max_position_embeddings, beta_fast (32), beta_slow (1),
+    attention_factor (0.1 ln(factor) + 1). None or `rope_type` "default":
+    no scaling, and no attribute."""
+    kind = (rope_scaling or {}).get("rope_type", "yarn")
+    if not rope_scaling or kind == "default":
+        return None
+    if kind != "yarn":
+        raise ValueError(f"rope_scaling: rope_type {kind!r}: only 'yarn' "
+                         "and 'default' are built")
+    factor = float(rope_scaling["factor"])
+    return [factor, float(rope_scaling["original_max_position_embeddings"]),
+            float(rope_scaling.get("beta_fast") or 32.0),
+            float(rope_scaling.get("beta_slow") or 1.0),
+            float(rope_scaling.get("attention_factor")
+                  or 0.1 * math.log(factor) + 1.0)]
+
+
+def rotary_embedding(input, theta=10000.0, name=None, rope_scaling=None):
     """Rotary positions 0..s-1 on `input` [b, s, heads, d] in the
     rotate-half convention, base `theta`; float32 inside the op, the
-    output in `input`'s dtype (ops/nn_ops.py `rotate_half`)."""
+    output in `input`'s dtype (ops/nn_ops.py `rotate_half`).
+    `rope_scaling`: a published YaRN group (`_yarn_attr`), whose blended
+    frequencies and factor the tables then carry."""
     helper = LayerHelper("rotary_embedding", name=name)
+    scaling = _yarn_attr(rope_scaling)
     return _single_out(
-        helper, "rotary_embedding", {"X": [input]}, {"theta": float(theta)},
+        helper, "rotary_embedding", {"X": [input]},
+        {"theta": float(theta), **({"scaling": scaling} if scaling else {})},
         dtype=input.dtype, shape=input.shape)
 
 
@@ -719,16 +745,20 @@ def kda_attention(q, k, v, g, beta, num_heads, l2norm_epsilon=1e-6,
 
 def moe_experts(input, experts_total, experts_held, d_ff, k, held_from=0,
                 scaling=1.0, renormalize=True, bias_scale=0.0,
-                param_attr=None, name=None):
+                param_attr=None, name=None, score_func="sigmoid"):
     """The held experts' part of a dropless expert layer (SiLU-gated
-    FFNs of width `d_ff`): a sigmoid router over all `experts_total`
-    picks `k` a token by `score + bias`, weights them
-    `scaling * score / sum of the selected scores`, and the assignments
+    FFNs of width `d_ff`): a router over all `experts_total` (`score_func`
+    "sigmoid": each expert's own sigmoid; "softmax": the probabilities
+    over all of them, float32) picks `k` a token by `score + bias`, weights
+    them `scaling * score / sum of the selected scores`, and the assignments
     to the `experts_held` experts from `held_from` on run through one
     grouped product, every one of them, whatever the skew. What experts
     held elsewhere would add is left out. `bias` is the router's
     correction: persistable, seeded Normal(0, bias_scale), never
     trained. Returns (out like input, load [experts_held] int32)."""
+    if score_func not in ("sigmoid", "softmax"):
+        raise ValueError(
+            f"score_func must be 'sigmoid' or 'softmax', got {score_func!r}")
     helper = LayerHelper("moe_experts", name=name)
     d = int(input.shape[-1])
 
@@ -759,7 +789,7 @@ def moe_experts(input, experts_total, experts_held, d_ff, k, held_from=0,
         attrs={"experts_total": int(experts_total),
                "experts_held": int(experts_held), "held_from": int(held_from),
                "k": int(k), "scaling": float(scaling),
-               "renormalize": bool(renormalize)},
+               "renormalize": bool(renormalize), "score_func": score_func},
     )
     return out, load
 
@@ -1268,6 +1298,7 @@ def fused_multihead_attention(
     k_norm_attr=None,
     qk_norm_epsilon=1e-5,
     rope_theta=0.0,
+    rope_scaling=None,
 ):
     """Flash attention over q/k/v (Pallas kernel on TPU). layout="bhsd"
     (default): [b, nh, s, dh]; layout="bshd": [b, s, nh, dh] — the shape
@@ -1290,14 +1321,19 @@ def fused_multihead_attention(
     `k` are normed head by head over `dh` as `rms_norm` does, each with a
     learned `[dh]` weight seeded at 1 and `qk_norm_epsilon`. With them,
     `rope_theta` > 0 (layout "bshd") then turns q and k by
-    `rotary_embedding`'s positions 0..s-1. Inside the op the two share
-    one pass over q and k with the kernel's head-major write, where the
-    kernel runs.
+    `rotary_embedding`'s positions 0..s-1, with a window or without
+    one; `rope_scaling` (a published YaRN group, as `rotary_embedding`
+    takes it) scales the tables. Inside the op the two share one pass
+    over q and k with the kernel's head-major write, where the kernel
+    runs.
     """
     if layout not in ("bhsd", "bshd"):
         raise ValueError(f"layout must be 'bhsd' or 'bshd', got {layout!r}")
     if (q_norm_attr is None) != (k_norm_attr is None):
         raise ValueError("q_norm_attr and k_norm_attr come together")
+    scaling = _yarn_attr(rope_scaling)
+    if scaling and not (q_norm_attr is not None and rope_theta):
+        raise ValueError("rope_scaling needs rope_theta and the QK-norms")
     helper = LayerHelper("fused_multihead_attention", name=name)
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if key_bias is not None:
@@ -1322,6 +1358,7 @@ def fused_multihead_attention(
             **({"qk_norm_epsilon": float(qk_norm_epsilon),
                 "rope_theta": float(rope_theta)}
                if q_norm_attr is not None else {}),
+            **({"rope_scaling": scaling} if scaling else {}),
         },
         dtype=q.dtype,
         shape=list(q.shape[:-1]) + [v.shape[-1]],

@@ -1,14 +1,15 @@
 """Reference workload models (the benchmark's configurations + the
 reference's test model zoo), built through the framework's own layers API —
 LeNet-5 (MNIST), ResNet (ImageNet), SE-ResNeXt, VGG, Transformer/BERT
-(WMT16 / pretrain), DeepFM (CTR), Kimi Linear and Trinity (each a share
-of an expert-parallel decoder)."""
+(WMT16 / pretrain), DeepFM (CTR), Kimi Linear, Trinity and Mellum (each a
+share of an expert-parallel decoder)."""
 
 from . import (  # noqa: F401
     bert,
     deepfm,
     kimi_linear,
     lenet,
+    mellum,
     resnet,
     se_resnext,
     transformer,
@@ -16,4 +17,5 @@ from . import (  # noqa: F401
     vgg,
 )
 from .kimi_linear import KimiLinearConfig, build_kimi_linear  # noqa: E402,F401
+from .mellum import MellumConfig, build_mellum  # noqa: E402,F401
 from .trinity import TrinityConfig, build_trinity  # noqa: E402,F401

@@ -68,6 +68,8 @@ class KimiLinearConfig:
     of the model is held: `experts_held` of `num_experts` from `held_from`
     on, and `vocab_size` rows of the vocabulary."""
 
+    score_func = "sigmoid"  # the router's; `decoder_parts.expert_ffn` reads it
+
     def __init__(self, vocab_size=163840, hidden_size=2304,
                  num_hidden_layers=27, kda_layers=None, num_heads=32,
                  kda_head_dim=128, short_conv_kernel_size=4, kda_rank=None,

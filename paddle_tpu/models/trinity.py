@@ -43,8 +43,7 @@ from __future__ import annotations
 import math
 
 from .. import layers
-from ..param_attr import ParamAttr
-from .decoder_parts import attr, expert_ffn, ffn, norm, proj
+from .decoder_parts import attention, attr, expert_ffn, ffn, norm, proj
 
 __all__ = ["TrinityConfig", "build_trinity"]
 
@@ -57,6 +56,8 @@ class TrinityConfig:
     parameters), `dense_layers` of them leading with a dense
     feed-forward, `experts_held` of `num_experts` from `held_from` on, and
     `vocab_size` rows of the vocabulary."""
+
+    score_func = "sigmoid"  # the router's; `decoder_parts.expert_ffn` reads it
 
     def __init__(self, vocab_size=200192, hidden_size=2048, layer_types=None,
                  first_layer=0, dense_layers=2, num_attention_heads=32,
@@ -96,22 +97,8 @@ class TrinityConfig:
 
 def _attention(u, cfg, name, window):
     """`window` 0: a full layer, which has no positions."""
-    b, s, _ = u.shape
-    h, g, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    q = layers.reshape(proj(u, h * d, name + ".q", cfg), [b, s, h, d])
-    k = layers.reshape(proj(u, g * d, name + ".k", cfg), [b, s, g, d])
-    v = layers.reshape(proj(u, g * d, name + ".v", cfg), [b, s, g, d])
-    gate = layers.sigmoid(proj(u, h * d, name + ".gate", cfg))
-    # QK-norm and the positions inside the attention op, where they and
-    # the kernel's head-major write are one pass over q and k
-    a = layers.fused_multihead_attention(
-        q, k, v, causal=True, sm_scale=1.0 / math.sqrt(d), layout="bshd",
-        window=window, q_norm_attr=ParamAttr(name=name + ".q_norm.w_0"),
-        k_norm_attr=ParamAttr(name=name + ".k_norm.w_0"),
-        qk_norm_epsilon=cfg.rms_norm_eps,
-        rope_theta=cfg.rope_theta if window else 0.0)
-    a = layers.elementwise_mul(layers.reshape(a, [b, s, h * d]), gate)
-    return proj(a, cfg.hidden_size, name + ".o", cfg)
+    return attention(u, cfg, name, window,
+                     rope_theta=cfg.rope_theta if window else 0.0, gated=True)
 
 
 def build_trinity(cfg, batch_size, seq_len):

@@ -17,7 +17,7 @@ import numpy as np
 
 from .. import profiler
 from ..analysis.artifacts import load_artifact
-from .nn_ops import rms_norm, rotate_half
+from .nn_ops import rms_norm, rope_scaling_attr, rotate_half
 from .pallas import on_mesh
 from .pallas.flash_attention import _xla_attention, flash_attention
 from .pallas.mha_short import mha_short, mha_short_viable
@@ -209,7 +209,9 @@ def _fused_mha(ctx, op):
     Optional QNorm, KNorm ([dh] each, together): q and k are first normed
     head by head as the op `rms_norm` norms the last axis, with attr
     `qk_norm_epsilon`; attr `rope_theta` > 0 then turns them by the op
-    `rotary_embedding`'s positions. On the flash path with layout "bshd"
+    `rotary_embedding`'s positions, under attr `rope_scaling` (YaRN's five
+    numbers, `nn_ops.yarn_frequencies`) by its scaled tables, whether the
+    layer has a window or none. On the flash path with layout "bshd"
     and heads of whole 128-lane slices, that and the head-major write the
     kernel wants are one kernel pair (ops/pallas/qk_prep.py); on every
     other path the two ops' own functions run first, in `jnp`.
@@ -221,6 +223,7 @@ def _fused_mha(ctx, op):
     q_norm, k_norm = ctx.in_(op, "QNorm"), ctx.in_(op, "KNorm")
     norm_eps = float(op.attr("qk_norm_epsilon", 1e-5))
     rope_theta = float(op.attr("rope_theta", 0.0) or 0.0)
+    rope_scaling = rope_scaling_attr(op, "rope_scaling")
     causal = op.attr("causal", False)
     dropout = float(op.attr("attn_dropout", 0.0))
     is_test = op.attr("is_test", False) or ctx.is_test
@@ -239,6 +242,9 @@ def _fused_mha(ctx, op):
         raise ValueError(
             "fused_multihead_attention: rope_theta needs QNorm and KNorm, "
             "and layout \"bshd\", whose axis 1 the positions count")
+    if rope_scaling and not rope_theta:
+        raise ValueError(
+            "fused_multihead_attention: rope_scaling needs rope_theta")
 
     prepare = q_norm is not None
     if prepare:
@@ -251,7 +257,8 @@ def _fused_mha(ctx, op):
             q = rms_norm(q, q_norm, norm_eps, 3)
             k = rms_norm(k, k_norm, norm_eps, 3)
             if rope_theta:
-                q, k = rotate_half(q, rope_theta), rotate_half(k, rope_theta)
+                q = rotate_half(q, rope_theta, rope_scaling)
+                k = rotate_half(k, rope_theta, rope_scaling)
             return ctx.amp_cast(op, q, k)
 
     q, k, v = ctx.amp_cast(op, q, k, v)
@@ -281,6 +288,8 @@ def _fused_mha(ctx, op):
         if path == "flash" and window:
             profiler.bump_counter("attn_dispatch_flash_window")
         profiler.set_counter("attn_kv_group", group)
+        if rope_scaling:
+            profiler.bump_counter("attn_rope_scaled")
         fused = (prepare and path == "flash" and bshd
                  and qk_prep_viable(q.shape[-1], dv))
         if prepare and not fused:
@@ -311,7 +320,8 @@ def _fused_mha(ctx, op):
             profiler.bump_counter("attn_qk_prep_fused")
             return swap(flash_attention(
                 *qk_prep(*raw, q_norm, k_norm, epsilon=norm_eps,
-                         theta=rope_theta, out_dtype=q.dtype),
+                         theta=rope_theta, scaling=rope_scaling,
+                         out_dtype=q.dtype),
                 bias=bias, causal=causal, sm_scale=sm_scale, dropout=dropout,
                 rng_key=rng, window=window))
         # values narrower than the keys: the kernel pads them up to the

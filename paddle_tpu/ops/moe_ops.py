@@ -41,11 +41,12 @@ def _moe_ffn(ctx, op):
 @register_op("moe_experts", no_grad_inputs=("Bias",))
 def _moe_experts(ctx, op):
     """X: [..., D]; Gate: [D, experts_total]; Bias: [experts_total], the
-    router's correction (selection only, no gradient); WGate, WUp:
+    router's correction (selection only, no gradient); attr `score_func`
+    "sigmoid" (the default) or "softmax"; WGate, WUp:
     [experts_held, D, F]; WDown: [experts_held, F, D]. Out like X: what
     the held experts add. Load: [experts_held] int32."""
     from .. import profiler
-    from ..parallel.moe import moe_experts
+    from ..parallel.moe import _block_rows, moe_experts
 
     held, total = op.attr("experts_held"), op.attr("experts_total")
     gate = ctx.in_(op, "Gate")
@@ -59,15 +60,23 @@ def _moe_experts(ctx, op):
     profiler.bump_counter("moe_first_block_shared")
     profiler.set_counter("moe_experts_held", held)
     profiler.set_counter("moe_experts_total", total)
+    score_func = op.attr("score_func", "sigmoid")
+    if score_func == "softmax":
+        profiler.bump_counter("moe_route_softmax")
     x = ctx.in_(op, "X")
+    k = op.attr("k")
+    # rows of the first, straight-line block of this layer's N*k sorted
+    # assignments: it follows the share held
+    profiler.set_counter(
+        "moe_block_rows", _block_rows(x.size // x.shape[-1] * k, held / total))
     # the router is float32 inside moe_route; the grouped products ride
     # the amp dtype, cast inside (both operands), as in moe_ffn
     y, load = moe_experts(
         x, gate, ctx.in_(op, "Bias"), ctx.in_(op, "WGate"),
         ctx.in_(op, "WUp"), ctx.in_(op, "WDown"),
-        k=op.attr("k"), scaling=op.attr("scaling", 1.0),
+        k=k, scaling=op.attr("scaling", 1.0),
         experts_held=held, held_from=op.attr("held_from", 0),
         renormalize=op.attr("renormalize", True),
-        compute_dtype=ctx.amp_dtype_for(op))
+        compute_dtype=ctx.amp_dtype_for(op), score_func=score_func)
     ctx.out(op, "Out", y)
     ctx.out(op, "Load", load)

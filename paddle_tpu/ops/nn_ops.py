@@ -11,6 +11,7 @@ vjp scatter-add — the dense equivalent of the reference's SelectedRows rows
 
 from __future__ import annotations
 
+import math
 import os
 
 import jax
@@ -574,42 +575,87 @@ def rms_norm(x, scale, epsilon, begin):
     return y.astype(x.dtype)
 
 
-def rotary_tables(s, d, theta):
+def yarn_frequencies(d, theta, scaling):
+    """YaRN's blended frequencies (Peng et al. 2023, arXiv:2309.00071, as
+    the public `transformers` code computes them) and the factor on cos
+    and sin. `scaling` is (factor, original_max_position_embeddings,
+    beta_fast, beta_slow, attention_factor). With `e_i = theta^(-2i/d)`
+    and `c(r) = d ln(original / (2 pi r)) / (2 ln theta)`, the index whose
+    wave turns `r` times over the original context: below
+    `low = floor(c(beta_fast))` the frequencies stay `e_i`, above
+    `high = ceil(c(beta_slow))` they are `e_i / factor`, and between the
+    two they blend linearly in `i`. Returns ([d/2] float32, factor)."""
+    factor, original, beta_fast, beta_slow, attention_factor = scaling
+
+    def turns(r):
+        return d * math.log(original / (r * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(turns(beta_fast)), 0)
+    high = min(math.ceil(turns(beta_slow)), d - 1)
+    if low == high:
+        high += 0.001  # the published guard against a ramp of no width
+    extrapolated = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ramp = jnp.clip(
+        (jnp.arange(d // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    freq = extrapolated / factor * ramp + extrapolated * (1 - ramp)
+    return freq, attention_factor
+
+
+def rotary_tables(s, d, theta, scaling=None):
     """`cos a` and the signed `sin a` of `rotate_half`, [s, d] float32:
-    `y = x * cos + roll(x, d/2) * sin` along the last axis."""
+    `y = x * cos + roll(x, d/2) * sin` along the last axis. `scaling`:
+    None, or YaRN's five numbers (`yarn_frequencies`), which blend the
+    frequencies and scale both tables."""
     # the published form, 1 / theta^(2i/d) in float32: another way round
     # the power differs by an ulp, which position 8,191 makes 4e-4 rad
-    freq = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if scaling is None:
+        freq = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    else:
+        freq, factor = yarn_frequencies(d, theta, scaling)
     angle = jnp.arange(s, dtype=jnp.float32)[:, None] * freq[None, :]
     cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)
     # the half-turn's sign rides the sine: rotate_half(x) = [-x2, x1]
     sin = jnp.concatenate([-jnp.sin(angle), jnp.sin(angle)], -1)
+    if scaling is not None:
+        cos, sin = cos * factor, sin * factor
     return cos, sin
 
 
-def rotate_half(x, theta):
+def rotate_half(x, theta, scaling=None):
     """Rotary positions on [b, s, h, d], positions 0..s-1, the rotate-half
     convention (Su et al. 2021, arXiv:2104.09864, as the public
     `transformers` code lays it out): with `a_i = p * theta^(-2i/d)` for
     i < d/2, `y[..., i] = x[..., i] cos a_i - x[..., i + d/2] sin a_i` and
-    `y[..., i + d/2] = x[..., i + d/2] cos a_i + x[..., i] sin a_i`.
+    `y[..., i + d/2] = x[..., i + d/2] cos a_i + x[..., i] sin a_i`; with
+    `scaling`, `rotary_tables`' scaled angles and factor.
     float32 inside whatever x arrives in: a bf16 angle at position 8,191
     is off by whole turns."""
     s, d = x.shape[1], x.shape[3]
-    cos, sin = (t[None, :, None, :] for t in rotary_tables(s, d, theta))
+    cos, sin = (t[None, :, None, :]
+                for t in rotary_tables(s, d, theta, scaling))
     xf = x.astype(jnp.float32)
     return (xf * cos + jnp.roll(xf, d // 2, axis=-1) * sin).astype(x.dtype)
 
 
+def rope_scaling_attr(op, name):
+    """An op's YaRN attribute as `rotary_tables` takes it: None where the
+    attribute is absent or empty."""
+    scaling = op.attr(name, None)
+    return tuple(float(v) for v in scaling) if scaling else None
+
+
 @register_op("rotary_embedding")
 def _rotary_embedding(ctx, op):
-    """X: [b, s, heads, d], d even; attr `theta`. Out has X's shape and
-    dtype (`rotate_half`)."""
+    """X: [b, s, heads, d], d even; attrs `theta` and, optionally,
+    `scaling` (YaRN's five numbers). Out has X's shape and dtype
+    (`rotate_half`)."""
     x = ctx.in_(op, "X")
     if x.ndim != 4 or x.shape[3] % 2:
         raise ValueError(
             f"rotary_embedding: X {x.shape}: expected [b, s, heads, d], d even")
-    ctx.out(op, "Out", rotate_half(x, float(op.attr("theta", 10000.0))))
+    ctx.out(op, "Out", rotate_half(x, float(op.attr("theta", 10000.0)),
+                                   rope_scaling_attr(op, "scaling")))
 
 
 @register_op("layer_norm_grad", differentiable=False)

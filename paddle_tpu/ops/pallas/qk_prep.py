@@ -204,11 +204,11 @@ def _call(kernel, name, statics, s, declared, specs_in, specs_out, shapes_out,
 
 
 def _fwd_pallas(q, k, v, wq, wk, statics):
-    heads, kv_heads, _, theta, rows, out_dtype = statics[:6]
+    heads, kv_heads, _, theta, rows, out_dtype, _, scaling = statics[:8]
     b, s, _ = q.shape
     d = q.shape[2] // heads
     flat, major, weight, table, _ = _specs(rows, d, heads, kv_heads)
-    tables = rotary_tables(s, d, theta) if theta else ()
+    tables = rotary_tables(s, d, theta, scaling) if theta else ()
     return _call(
         _fwd_kernel, "qk_prep_fwd", statics, s,
         _cost(False, b, s, d, statics, q.dtype),
@@ -219,11 +219,11 @@ def _fwd_pallas(q, k, v, wq, wk, statics):
 
 
 def _bwd_pallas(dqo, dko, dvo, q, k, wq, wk, statics):
-    heads, kv_heads, _, theta, rows, _, v_dtype = statics[:7]
+    heads, kv_heads, _, theta, rows, _, v_dtype, scaling = statics[:8]
     b, s, _ = q.shape
     d = q.shape[2] // heads
     flat, major, weight, table, partial = _specs(rows, d, heads, kv_heads)
-    tables = rotary_tables(s, d, theta) if theta else ()
+    tables = rotary_tables(s, d, theta, scaling) if theta else ()
     sums = jax.ShapeDtypeStruct((b, pl.cdiv(s, rows), SUBLANES, d),
                                 jnp.float32)
     dq, dk, dv, dwq, dwk = _call(
@@ -262,13 +262,14 @@ _core.defvjp(_core_fwd, _core_bwd)
 
 
 def qk_prep(q, k, v, q_weight, k_weight, *, epsilon, theta=0.0,
-            out_dtype=None, rows=ROWS):
+            scaling=None, out_dtype=None, rows=ROWS):
     """q: [b, s, h, d]; k, v: [b, s, g, d], as the projections' outputs
     are reshaped; `q_weight`, `k_weight`: [d]. Returns q, k, v head-major,
     [b, h, s, d] and [b, g, s, d] in `out_dtype` (q's own by default): q
     and k normed over `d` with `epsilon` and their weight and, where
-    `theta` is not 0, turned by `rotate_half`'s positions 0..s-1; v as it
-    came."""
+    `theta` is not 0, turned by `rotate_half`'s positions 0..s-1 (under
+    `scaling`, `rotary_tables`' scaled ones: the tables are the kernels'
+    inputs, which are the same kernels either way); v as it came."""
     require_pallas("qk_prep")
     b, s, h, d = q.shape
     g = k.shape[2]
@@ -279,7 +280,8 @@ def qk_prep(q, k, v, q_weight, k_weight, *, epsilon, theta=0.0,
     # whole (16, 128) tiles of bf16, and whole [8, d] partial sums
     rows = min(rows, -(-s // 16) * 16)
     statics = (h, g, float(epsilon), float(theta), rows,
-               jnp.dtype(out_dtype or q.dtype), v.dtype, _interpret())
+               jnp.dtype(out_dtype or q.dtype), v.dtype,
+               tuple(scaling) if scaling else None, _interpret())
     flat = lambda t: t.reshape(b, s, -1)
     return _core(flat(q), flat(k), flat(v),
                  q_weight.astype(jnp.float32), k_weight.astype(jnp.float32),

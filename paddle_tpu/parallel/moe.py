@@ -11,7 +11,8 @@ expert's capacity is dropped the way GShard drops it, and the standard
 load-balancing auxiliary loss is returned for the trainer to add.
 
 `moe_experts` (op `moe_experts`) is **dropless**, and is told which
-experts it holds: a sigmoid router over all `experts_total` experts picks
+experts it holds: a router over all `experts_total` experts (sigmoid
+scores with a correction, or softmax probabilities: `score_func`) picks
 k a token, the assignments to the `experts_held` experts from `held_from`
 on are sorted by expert and run through one grouped product
 (`jax.lax.ragged_dot`), whatever the skew, at static shapes. What the
@@ -21,6 +22,7 @@ without its exchange. Gradients flow through the combine weights in both."""
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -149,34 +151,52 @@ def moe_ffn(params, x, capacity_factor=1.25, k=2, compute_dtype=None):
 # ---------------------------------------------------------------------------
 
 # Rows of one grouped product: the sorted assignments go through their
-# experts a block of this share of the N*k at a time: the first block
-# always, straight-line, and as many more as the assignments to the held
-# experts fill past it (a `while` with a trip count read from the load).
-# A balanced router sends 8 experts of 256 a 32nd of the
+# experts a block at a time: the first block always, straight-line, and as
+# many more as the assignments to the held experts fill past it (a `while`
+# with a trip count read from the load). The first block is also what is
+# cheap (PERF.md, PR 36): its gathered rows, its two float32 products, `h`
+# and the down product are made once and read by its backward, where a
+# block past it makes its three forward products again inside the
+# backward's loop. So one block has to be the rule, and a step's time must
+# not follow the seed; a skew past it costs blocks, not tokens: both are
+# static shapes, and nothing is dropped.
+#
+# Its size follows the share of the experts held (`_block_rows`): room for
+# `BLOCK_ROOM` times the load a balanced router sends here, and never under
+# a `BLOCK_FLOOR`-th of the N*k assignments. The floor is what the small
+# shares need: a balanced router sends 8 experts of 256 a 32nd of the
 # assignments, but a freshly seeded one is skewed and Adam moves it towards
-# the experts it has: at 4,096 tokens a layer's load passed an eighth (two
-# blocks then, 6 ms more a layer) in two seeds of nine, one that started
-# at 4,000 of 32,768 and one that started at 2,700 and grew by 150 a step
-# (PERF.md, PR 31). A quarter keeps
-# one block the rule, so a step's time does not follow the seed; a skew
-# past it costs blocks, not tokens: both are static shapes, and nothing is
-# dropped. The rule is also what is cheap (PERF.md, PR 36): the first
-# block's gathered rows, its two float32 products, `h` and the down product
-# are made once and read by its backward, where a block past it makes its
-# three forward products again inside the backward's loop.
-BLOCK_SHARE = 4  # 1/4 of the N*k assignments
+# the experts it has: at 4,096 tokens a layer's load passed an eighth in
+# two seeds of nine, one that started at 4,000 of 32,768 and one that
+# started at 2,700 and grew by 150 a step (PERF.md, PR 31); a quarter holds
+# 8 and 4 balanced loads at the shares of 1/32 and 1/16. A chip of a
+# four-chip host holds a quarter of the experts: there the balanced load
+# *is* a quarter, and the room above it is what the loads called for on
+# the chip (PERF.md, PR 37): with a router that tells tokens apart, 16 of
+# 64 experts drew 0.195 to 0.305 of the assignments at the first step over
+# 12 seeds and 4 layers and 0.385 at most over 44 steps of Adam, so 7/16
+# holds them with a standard deviation or two to spare and 3/8 did not.
+BLOCK_FLOOR = 4  # at least 1/4 of the N*k assignments
+BLOCK_ROOM = 1.75  # times the balanced load of the share held
 
 
-def moe_route(x, gate, bias, k, scaling, renormalize=True):
-    """Sigmoid router over all the experts `gate` has columns for.
-    x: [N, D]; gate: [D, E]; bias: [E], the correction that enters the
-    selection and not the weights. Returns (idx [N, k] int32, weights
-    [N, k] float32): the k largest of `sigmoid(x gate) + bias`, weighted
+def moe_route(x, gate, bias, k, scaling, renormalize=True,
+              score_func="sigmoid"):
+    """Router over all the experts `gate` has columns for. x: [N, D];
+    gate: [D, E]; bias: [E], the correction that enters the selection and
+    not the weights. The scores `s` are `sigmoid(x gate)`, each expert's
+    own, or with `score_func` "softmax" the probabilities
+    `softmax(x gate)` over all E. Returns (idx [N, k] int32, weights
+    [N, k] float32): the k largest of `s + bias`, weighted
     `scaling * s_i / sum_selected s_j` (without `renormalize`,
     `scaling * s_i`). float32 throughout."""
-    scores = jax.nn.sigmoid(
-        jnp.dot(x.astype(jnp.float32), gate.astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST))
+    if score_func not in ("sigmoid", "softmax"):
+        raise ValueError(f"moe_route: score_func {score_func!r}: expected "
+                         "'sigmoid' or 'softmax'")
+    logits = jnp.dot(x.astype(jnp.float32), gate.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = (jax.nn.sigmoid(logits) if score_func == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
     _, idx = jax.lax.top_k(
         jax.lax.stop_gradient(scores + bias.astype(jnp.float32)), k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
@@ -185,8 +205,12 @@ def moe_route(x, gate, bias, k, scaling, renormalize=True):
     return idx.astype(jnp.int32), scaling * w
 
 
-def _block_rows(total):
-    return max(total // BLOCK_SHARE, 1)
+def _block_rows(total, share):
+    """Rows of a block of `total` sorted assignments where `share` of the
+    experts are held (`experts_held / experts_total`): 8,192 of 32,768 at
+    1/32, 16,384 of 65,536 at 1/16, 28,672 of 65,536 at 1/4."""
+    room = math.ceil(BLOCK_ROOM * share * total)
+    return min(max(total // BLOCK_FLOOR, room, 1), total)
 
 
 def _block_count(sizes, rows):
@@ -224,24 +248,30 @@ def _block(j, rows, x, w_gate, w_up, w_down, token, weight, sizes, dtype):
         jnp.where(live, y, 0.0))
 
 
-def _held_experts(x, w_gate, w_up, w_down, token, weight, sizes, dtype):
-    """The sorted assignments through the experts held here: [N, D]
-    float32. The first block is straight-line JAX that `jax.vjp` goes
-    through like any other op's lowering, so the forward op and the replay
-    inside its gradient op emit one block and XLA merges them; the blocks a
-    skewed load fills past it are added by `_overflow`."""
-    rows = _block_rows(token.shape[0])
+def _held_experts(x, w_gate, w_up, w_down, token, weight, sizes, dtype,
+                  rows):
+    """The sorted assignments through the experts held here, `rows` of
+    them a block: [N, D] float32. The first block is straight-line JAX
+    that `jax.vjp` goes through like any other op's lowering, so the
+    forward op and the replay inside its gradient op emit one block and
+    XLA merges them; the blocks a skewed load fills past it are added by
+    `_overflow`."""
+    # whole blocks: a last block that ran past the assignments would have
+    # its slice moved back over rows already done
+    short = -token.shape[0] % rows
+    if short:
+        token, weight = jnp.pad(token, (0, short)), jnp.pad(weight, (0, short))
     first = _block(0, rows, x, w_gate, w_up, w_down, token, weight, sizes,
                    dtype)
     return _overflow(first, x, w_gate, w_up, w_down, token, weight, sizes,
-                     dtype)
+                     dtype, rows)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
-def _overflow(first, x, w_gate, w_up, w_down, token, weight, sizes, dtype):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _overflow(first, x, w_gate, w_up, w_down, token, weight, sizes, dtype,
+              rows):
     """`first` and the blocks from the second on, as many as the load
     fills: none in a layer whose load stays under a block."""
-    rows = _block_rows(token.shape[0])
     return jax.lax.fori_loop(
         1, _block_count(sizes, rows),
         lambda j, out: out + _block(j, rows, x, w_gate, w_up, w_down, token,
@@ -249,13 +279,14 @@ def _overflow(first, x, w_gate, w_up, w_down, token, weight, sizes, dtype):
         first)
 
 
-def _overflow_fwd(first, x, w_gate, w_up, w_down, token, weight, sizes, dtype):
+def _overflow_fwd(first, x, w_gate, w_up, w_down, token, weight, sizes, dtype,
+                  rows):
     out = _overflow(first, x, w_gate, w_up, w_down, token, weight, sizes,
-                    dtype)
+                    dtype, rows)
     return out, (x, w_gate, w_up, w_down, token, weight, sizes)
 
 
-def _overflow_bwd(dtype, res, g):
+def _overflow_bwd(dtype, rows, res, g):
     # A loop of its own, block by block as the forward: the trip count is
     # data, which jax.vjp cannot take through a `while`, so an overflow
     # block's intermediates are rebuilt here and not kept from the forward
@@ -263,7 +294,6 @@ def _overflow_bwd(dtype, res, g):
     # six). XLA's CSE does not look into a `while` body either: what runs
     # every step has to stay out of one.
     x, w_gate, w_up, w_down, token, weight, sizes = res
-    rows = _block_rows(token.shape[0])
     diff = (x, w_gate, w_up, w_down, weight)
 
     def body(j, grads):
@@ -283,7 +313,7 @@ _overflow.defvjp(_overflow_fwd, _overflow_bwd)
 
 def moe_experts(x, gate, bias, w_gate, w_up, w_down, k, scaling,
                 experts_held, held_from, renormalize=True,
-                compute_dtype=None):
+                compute_dtype=None, score_func="sigmoid"):
     """The part of a dropless expert layer that the experts held here
     give. x: [..., D]; gate: [D, experts_total]; bias: [experts_total];
     w_gate, w_up: [experts_held, D, F]; w_down: [experts_held, F, D].
@@ -292,11 +322,12 @@ def moe_experts(x, gate, bias, w_gate, w_up, w_down, k, scaling,
 
     The N*k assignments are sorted by held expert, those to experts held
     elsewhere last, and the sorted rows go through `jax.lax.ragged_dot`
-    with the load as the group sizes, `BLOCK_SHARE`-th of them a time: no
-    `[N, E, capacity]` tensor, no capacity, no loop over k."""
+    with the load as the group sizes, a block of `_block_rows` of them a
+    time: no `[N, E, capacity]` tensor, no capacity, no loop over k."""
     shape = x.shape
     tokens = x.reshape(-1, shape[-1])
-    idx, weights = moe_route(tokens, gate, bias, k, scaling, renormalize)
+    idx, weights = moe_route(tokens, gate, bias, k, scaling, renormalize,
+                             score_func)
     local = idx.reshape(-1) - held_from
     held = (local >= 0) & (local < experts_held)
     key = jnp.where(held, local, experts_held)
@@ -306,5 +337,7 @@ def moe_experts(x, gate, bias, w_gate, w_up, w_down, k, scaling,
     token = (order // k).astype(jnp.int32)
     weight = jnp.where(held, weights.reshape(-1), 0.0)[order]
     y = _held_experts(tokens, w_gate, w_up, w_down, token, weight, load,
-                      compute_dtype or tokens.dtype)
+                      compute_dtype or tokens.dtype,
+                      _block_rows(token.shape[0],
+                                  experts_held / gate.shape[1]))
     return y.astype(x.dtype).reshape(shape), load

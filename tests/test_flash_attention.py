@@ -230,6 +230,9 @@ def test_a_group_is_what_repeated_heads_give(rng):
     # the cell's shape: 16 x 16 blocks of 512, window 2048: 1+2+3+4+12*5
     ((1, 32, 4, 8192, 8192, 128), 2048, (3 * 70, 3 * 256)),
     ((1, 32, 4, 8192, 8192, 128), 0, (3 * 136, 3 * 256)),
+    # Mellum's window layers: 1,024 keys are two blocks, so a query block
+    # reads its own and the two before, three of the sixteen: 1+2+14*3
+    ((1, 32, 4, 8192, 8192, 128), 1024, (3 * 45, 3 * 256)),
 ])
 def test_blocks_visited_against_a_count_by_hand(dims, window, by_hand):
     """`flash_blocks_visited` and `_total`: what a call's three grids

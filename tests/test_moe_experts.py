@@ -96,10 +96,12 @@ TOTAL, K, TOKENS = 16, 2, 64
 
 @pytest.mark.parametrize("held,correction,blocks", [
     (2, -10.0, 0),   # nothing routed here: one block of dead rows
-    (4, 0.0, 1),     # under a quarter of the assignments: the first block
-    (5, 0.0, 2),     # just over a quarter: one trip of the overflow loop
-    (9, 0.0, 3),     # over a half: two
-    (16, 0.0, 4),    # all of them: three
+    (4, 0.0, 1),     # a quarter held, a block of 7/16: the first block
+    (16, 0.0, 1),    # all of them held: one block holds every assignment
+    (8, 10.0, 2),    # every assignment lands on the half held: a block of
+                     # 7/8, and one trip of the overflow loop
+    (4, 10.0, 3),    # ... on the quarter held, a block of 7/16: two trips
+    (2, 10.0, 4),    # ... on the eighth held, the floor of 1/4: three
 ])
 def test_value_and_gradients_equal_the_reference_at_every_block_count(
         held, correction, blocks):
@@ -141,7 +143,7 @@ def test_value_and_gradients_equal_the_reference_at_every_block_count(
         ours, grad, has_aux=True))(*args)
     (_, want_y), want = highest(jax.jit(jax.value_and_grad(
         theirs, grad, has_aux=True)), *args)
-    rows = moe._block_rows(TOKENS * K)
+    rows = moe._block_rows(TOKENS * K, held / TOTAL)
     assert -(-int(np.sum(load)) // rows) == blocks, load
     if blocks == 0:  # dead rows give exactly nothing
         assert not np.abs(y).any() and not np.abs(want_y).any()

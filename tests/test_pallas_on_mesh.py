@@ -309,14 +309,18 @@ def test_kda_kernels_compile_for_a_v5e_chip_at_the_published_widths(topo):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
-@pytest.mark.parametrize("theta", [0.0, 10000.0],
-                         ids=["no_positions", "rope"])
+@pytest.mark.parametrize("theta,scaling", [
+    (0.0, None), (10000.0, None),
+    (500000.0, (16.0, 8192.0, 32.0, 1.0, 1.2772588722239782))],
+    ids=["no_positions", "rope", "yarn"])
 def test_qk_prep_kernels_compile_for_a_v5e_chip_at_the_published_widths(
-        topo, theta):
+        topo, theta, scaling):
     """The kernel pair between the projections and the flash kernels
-    (ops/pallas/qk_prep.py) at the shape of the cell that runs it: one
+    (ops/pallas/qk_prep.py) at the shape of the cells that run it: one
     8,192-token row, 32 query heads over 4 key/value heads of 128, bf16 in
-    and out, a full layer's call and a window layer's. Nothing runs."""
+    and out; Trinity's full layer's call and its window layer's, and
+    Mellum's full layer's, whose tables are YaRN's (the same kernels: the
+    tables are inputs). Nothing runs."""
     from jax.sharding import SingleDeviceSharding
 
     from paddle_tpu.ops.pallas import qk_prep
@@ -324,7 +328,7 @@ def test_qk_prep_kernels_compile_for_a_v5e_chip_at_the_published_widths(
     chip = SingleDeviceSharding(topo.devices[0])
     b, s, h, g, d = 1, 8192, 32, 4, 128
     bf16 = jnp.dtype(jnp.bfloat16)
-    statics = (h, g, 1e-5, theta, qk_prep.ROWS, bf16, bf16, False)
+    statics = (h, g, 1e-5, theta, qk_prep.ROWS, bf16, bf16, scaling, False)
 
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
