@@ -690,17 +690,21 @@ def _yarn_attr(rope_scaling):
                   or 0.1 * math.log(factor) + 1.0)]
 
 
-def rotary_embedding(input, theta=10000.0, name=None, rope_scaling=None):
-    """Rotary positions 0..s-1 on `input` [b, s, heads, d] in the
-    rotate-half convention, base `theta`; float32 inside the op, the
-    output in `input`'s dtype (ops/nn_ops.py `rotate_half`).
-    `rope_scaling`: a published YaRN group (`_yarn_attr`), whose blended
-    frequencies and factor the tables then carry."""
+def rotary_embedding(input, theta=10000.0, name=None, rope_scaling=None,
+                     interleaved=False):
+    """Rotary positions 0..s-1 on `input` [b, s, heads, d], base `theta`;
+    float32 inside the op, the output in `input`'s dtype. The lanes pair
+    in the rotate-half convention, `(i, i + d/2)` (ops/nn_ops.py
+    `rotate_half`), or with `interleaved` as neighbours, `(2i, 2i+1)`
+    (`rotate_pairs`). `rope_scaling`: a published YaRN group
+    (`_yarn_attr`), whose blended frequencies and factor the tables then
+    carry."""
     helper = LayerHelper("rotary_embedding", name=name)
     scaling = _yarn_attr(rope_scaling)
     return _single_out(
         helper, "rotary_embedding", {"X": [input]},
-        {"theta": float(theta), **({"scaling": scaling} if scaling else {})},
+        {"theta": float(theta), **({"scaling": scaling} if scaling else {}),
+         **({"interleaved": True} if interleaved else {})},
         dtype=input.dtype, shape=input.shape)
 
 
@@ -1299,6 +1303,7 @@ def fused_multihead_attention(
     qk_norm_epsilon=1e-5,
     rope_theta=0.0,
     rope_scaling=None,
+    q_lora_rank=0,
 ):
     """Flash attention over q/k/v (Pallas kernel on TPU). layout="bhsd"
     (default): [b, nh, s, dh]; layout="bshd": [b, s, nh, dh] — the shape
@@ -1326,6 +1331,11 @@ def fused_multihead_attention(
     takes it) scales the tables. Inside the op the two share one pass
     over q and k with the kernel's head-major write, where the kernel
     runs.
+
+    `q_lora_rank` > 0 says that `q` came through a compressed query of
+    that rank (latent attention, `decoder_parts.latent_attention`). The
+    op computes nothing differently; its lowering counts the call
+    (`attn_latent_q_lora`).
     """
     if layout not in ("bhsd", "bshd"):
         raise ValueError(f"layout must be 'bhsd' or 'bshd', got {layout!r}")
@@ -1359,6 +1369,7 @@ def fused_multihead_attention(
                 "rope_theta": float(rope_theta)}
                if q_norm_attr is not None else {}),
             **({"rope_scaling": scaling} if scaling else {}),
+            **({"q_lora_rank": int(q_lora_rank)} if q_lora_rank else {}),
         },
         dtype=q.dtype,
         shape=list(q.shape[:-1]) + [v.shape[-1]],

@@ -1,12 +1,13 @@
 """Reference workload models (the benchmark's configurations + the
 reference's test model zoo), built through the framework's own layers API —
 LeNet-5 (MNIST), ResNet (ImageNet), SE-ResNeXt, VGG, Transformer/BERT
-(WMT16 / pretrain), DeepFM (CTR), Kimi Linear, Trinity and Mellum (each a
-share of an expert-parallel decoder)."""
+(WMT16 / pretrain), DeepFM (CTR), Kimi Linear, Trinity, Mellum and JoyAI
+Flash (each a share of an expert-parallel decoder)."""
 
 from . import (  # noqa: F401
     bert,
     deepfm,
+    joyai_flash,
     kimi_linear,
     lenet,
     mellum,
@@ -16,6 +17,7 @@ from . import (  # noqa: F401
     trinity,
     vgg,
 )
+from .joyai_flash import JoyAIFlashConfig, build_joyai_flash  # noqa: E402,F401
 from .kimi_linear import KimiLinearConfig, build_kimi_linear  # noqa: E402,F401
 from .mellum import MellumConfig, build_mellum  # noqa: E402,F401
 from .trinity import TrinityConfig, build_trinity  # noqa: E402,F401

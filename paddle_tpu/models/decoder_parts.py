@@ -2,10 +2,11 @@
 `mellum`) build their layers from: bias-free projections seeded Normal(0,
 `initializer_range`), RMSNorm with a learned weight, the SiLU-gated
 feed-forward, attention over grouped key/value heads with QK-norm and
-rotary positions, and the expert layer that holds a share of the experts.
-A `cfg` gives `hidden_size`, `initializer_range`, `rms_norm_eps`, for
-`attention` the heads, and for `expert_ffn` the router's keys as
-`KimiLinearConfig` names them."""
+rotary positions, latent attention (`kimi_linear`, `joyai_flash`), and the
+expert layer that holds a share of the experts. A `cfg` gives
+`hidden_size`, `initializer_range`, `rms_norm_eps`, for `attention` the
+heads, for `latent_attention` the keys its docstring lists, and for
+`expert_ffn` the router's keys as `KimiLinearConfig` names them."""
 
 from __future__ import annotations
 
@@ -65,6 +66,58 @@ def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
     if gated:
         a = layers.elementwise_mul(a, gate)
     return proj(a, cfg.hidden_size, name + ".o", cfg)
+
+
+def latent_attention(u, cfg, name):
+    """Causal latent attention (DeepSeek-V2/V3's MLA, arXiv:2412.19437
+    section 2.1.1) as it trains, u [b, s, hidden] to [b, s, hidden]:
+    `num_attention_heads` heads whose queries and keys are
+    `qk_nope_head_dim + qk_rope_head_dim` wide and whose values are
+    `v_head_dim` wide. Keys and values come up from one `kv_lora_rank`
+    latent through an RMSNorm (`.kv_a`, `.kv_a_norm`, `.kv_b`); the
+    `qk_rope_head_dim` lanes of the key that `.kv_a` writes beside the
+    latent are one part for all the heads. Where `cfg.q_lora_rank` is
+    set the query is compressed too (`.q_a`, `.q_a_norm`, `.q_b`),
+    otherwise it is one projection (`.q`). Where `cfg.rope_theta` is not
+    0 the last `qk_rope_head_dim` lanes of each query head and the shared
+    key part are turned by rotary positions 0..s-1, by pairs of
+    neighbouring lanes if `cfg.rope_interleave`; the other lanes carry no
+    position. Scores scale by the whole width's root."""
+    b, s, _ = u.shape
+    h = cfg.num_attention_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+
+    def turned(t):
+        return layers.rotary_embedding(t, theta=cfg.rope_theta,
+                                       interleaved=cfg.rope_interleave)
+
+    if cfg.q_lora_rank:
+        c_q = norm(proj(u, cfg.q_lora_rank, name + ".q_a", cfg),
+                   name + ".q_a_norm", cfg)
+        q = proj(c_q, h * (dn + dr), name + ".q_b", cfg)
+    else:
+        q = proj(u, h * (dn + dr), name + ".q", cfg)
+    q = layers.reshape(q, [b, s, h, dn + dr])
+    if cfg.rope_theta:
+        q_n, q_r = layers.split(q, [dn, dr], dim=3)
+        q = layers.concat([q_n, turned(q_r)], axis=3)
+    c, k_r = layers.split(proj(u, cfg.kv_lora_rank + dr, name + ".kv_a", cfg),
+                          [cfg.kv_lora_rank, dr], dim=2)
+    kv = layers.reshape(
+        proj(norm(c, name + ".kv_a_norm", cfg), h * (dn + dv),
+             name + ".kv_b", cfg), [b, s, h, dn + dv])
+    k_n, v = layers.split(kv, [dn, dv], dim=3)
+    # the one dr-wide key part, the same for every head: turned once, as
+    # one head, and its gradient comes back summed over the heads
+    k_r = layers.reshape(k_r, [b, s, 1, dr])
+    if cfg.rope_theta:
+        k_r = turned(k_r)
+    k = layers.concat([k_n, layers.expand(k_r, [1, 1, h, 1])], axis=3)
+    o = layers.fused_multihead_attention(
+        q, k, v, causal=True, sm_scale=1.0 / math.sqrt(dn + dr),
+        layout="bshd", q_lora_rank=cfg.q_lora_rank or 0)
+    return proj(layers.reshape(o, [b, s, h * dv]), cfg.hidden_size,
+                name + ".o", cfg)
 
 
 def expert_ffn(u, cfg, name):

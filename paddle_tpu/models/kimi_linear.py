@@ -32,7 +32,8 @@ KDA mixer, `h` heads of `d` (= `d_k` = `d_v`):
   The op `kda_attention` computes the recurrence chunk by chunk
   (ops/linear_attn_ops.py).
 
-Latent mixer, without positions (`mla_use_nope`):
+Latent mixer (`decoder_parts.latent_attention`), without positions
+(`mla_use_nope`) and with one query projection (`q_lora_rank` null):
   `q_t = W_q u` as `h` heads of `d_n + d_r`; `[c_t, k^R_t] = W_kva u`
   (`kv_lora_rank` + `d_r`); `[k^N_t, v_t] = W_kvb RMSNorm(c_t)` as `h` heads
   of `d_n + d_v`; `k_t^h = [k^{N,h}_t ; k^R_t]`, the `d_r`-wide part shared
@@ -52,13 +53,12 @@ a held expert is computed (op `moe_experts`).
 
 from __future__ import annotations
 
-import math
-
 from .. import layers
 from ..initializer import Normal
 from ..param_attr import ParamAttr
 from .decoder_parts import (attr as _attr, expert_ffn as _expert_ffn,
-                            ffn as _ffn, norm as _norm, proj as _proj)
+                            ffn as _ffn, latent_attention as _latent_mixer,
+                            norm as _norm, proj as _proj)
 
 __all__ = ["KimiLinearConfig", "build_kimi_linear"]
 
@@ -69,6 +69,11 @@ class KimiLinearConfig:
     on, and `vocab_size` rows of the vocabulary."""
 
     score_func = "sigmoid"  # the router's; `decoder_parts.expert_ffn` reads it
+    # what `decoder_parts.latent_attention` reads beside the widths: one
+    # query projection (`q_lora_rank` null) and no positions (`mla_use_nope`)
+    q_lora_rank = None
+    rope_theta = 0.0
+    rope_interleave = False
 
     def __init__(self, vocab_size=163840, hidden_size=2304,
                  num_hidden_layers=27, kda_layers=None, num_heads=32,
@@ -137,28 +142,6 @@ def _kda_mixer(u, cfg, name):
               name + ".g_b", cfg))
     o = layers.elementwise_mul(layers.reshape(o, [b, s, h * d]), gate)
     return _proj(o, cfg.hidden_size, name + ".o", cfg)
-
-
-def _latent_mixer(u, cfg, name):
-    b, s, _ = u.shape
-    h = cfg.num_attention_heads
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    q = layers.reshape(_proj(u, h * (dn + dr), name + ".q", cfg),
-                       [b, s, h, dn + dr])
-    c, k_r = layers.split(_proj(u, cfg.kv_lora_rank + dr, name + ".kv_a", cfg),
-                          [cfg.kv_lora_rank, dr], dim=2)
-    kv = layers.reshape(
-        _proj(_norm(c, name + ".kv_a_norm", cfg), h * (dn + dv),
-              name + ".kv_b", cfg), [b, s, h, dn + dv])
-    k_n, v = layers.split(kv, [dn, dv], dim=3)
-    # the one dr-wide key part, the same for every head; no rotation
-    k_r = layers.expand(layers.reshape(k_r, [b, s, 1, dr]), [1, 1, h, 1])
-    k = layers.concat([k_n, k_r], axis=3)
-    o = layers.fused_multihead_attention(
-        q, k, v, causal=True, sm_scale=1.0 / math.sqrt(dn + dr),
-        layout="bshd")
-    return _proj(layers.reshape(o, [b, s, h * dv]), cfg.hidden_size,
-                 name + ".o", cfg)
 
 
 def build_kimi_linear(cfg, batch_size, seq_len):

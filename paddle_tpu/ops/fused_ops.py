@@ -215,6 +215,10 @@ def _fused_mha(ctx, op):
     and heads of whole 128-lane slices, that and the head-major write the
     kernel wants are one kernel pair (ops/pallas/qk_prep.py); on every
     other path the two ops' own functions run first, in `jnp`.
+
+    Attr `q_lora_rank` (optional, > 0) labels a latent-attention call
+    whose query came through a compressed latent; it changes nothing
+    computed and counts `attn_latent_q_lora` once a lowering.
     """
     q = ctx.in_(op, "Q")
     k = ctx.in_(op, "K")
@@ -290,6 +294,8 @@ def _fused_mha(ctx, op):
         profiler.set_counter("attn_kv_group", group)
         if rope_scaling:
             profiler.bump_counter("attn_rope_scaled")
+        if op.attr("q_lora_rank", 0):
+            profiler.bump_counter("attn_latent_q_lora")
         fused = (prepare and path == "flash" and bshd
                  and qk_prep_viable(q.shape[-1], dv))
         if prepare and not fused:

@@ -602,11 +602,13 @@ def yarn_frequencies(d, theta, scaling):
     return freq, attention_factor
 
 
-def rotary_tables(s, d, theta, scaling=None):
+def rotary_tables(s, d, theta, scaling=None, interleaved=False):
     """`cos a` and the signed `sin a` of `rotate_half`, [s, d] float32:
     `y = x * cos + roll(x, d/2) * sin` along the last axis. `scaling`:
     None, or YaRN's five numbers (`yarn_frequencies`), which blend the
-    frequencies and scale both tables."""
+    frequencies and scale both tables. `interleaved`: the tables of
+    `rotate_pairs`, angle `a_i` on lanes 2i and 2i+1 and the sign on the
+    even lane's sine."""
     # the published form, 1 / theta^(2i/d) in float32: another way round
     # the power differs by an ulp, which position 8,191 makes 4e-4 rad
     if scaling is None:
@@ -614,9 +616,13 @@ def rotary_tables(s, d, theta, scaling=None):
     else:
         freq, factor = yarn_frequencies(d, theta, scaling)
     angle = jnp.arange(s, dtype=jnp.float32)[:, None] * freq[None, :]
-    cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)
-    # the half-turn's sign rides the sine: rotate_half(x) = [-x2, x1]
-    sin = jnp.concatenate([-jnp.sin(angle), jnp.sin(angle)], -1)
+    if interleaved:
+        cos = jnp.repeat(jnp.cos(angle), 2, -1)
+        sin = jnp.stack([-jnp.sin(angle), jnp.sin(angle)], -1).reshape(s, d)
+    else:
+        cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)
+        # the half-turn's sign rides the sine: rotate_half(x) = [-x2, x1]
+        sin = jnp.concatenate([-jnp.sin(angle), jnp.sin(angle)], -1)
     if scaling is not None:
         cos, sin = cos * factor, sin * factor
     return cos, sin
@@ -638,6 +644,21 @@ def rotate_half(x, theta, scaling=None):
     return (xf * cos + jnp.roll(xf, d // 2, axis=-1) * sin).astype(x.dtype)
 
 
+def rotate_pairs(x, theta, scaling=None):
+    """`rotate_half`'s positions with the lanes paired as the paper pairs
+    them (`rope_interleave` in the DeepSeek-V3 family's configs): lanes
+    2i and 2i+1 are one plane, `y[..., 2i] = x[..., 2i] cos a_i -
+    x[..., 2i+1] sin a_i` and `y[..., 2i+1] = x[..., 2i+1] cos a_i +
+    x[..., 2i] sin a_i`, the output in the input's lane order. float32
+    inside."""
+    s, d = x.shape[1], x.shape[3]
+    cos, sin = (t[None, :, None, :]
+                for t in rotary_tables(s, d, theta, scaling, interleaved=True))
+    xf = x.astype(jnp.float32)
+    swapped = xf.reshape(*xf.shape[:-1], d // 2, 2)[..., ::-1].reshape(xf.shape)
+    return (xf * cos + swapped * sin).astype(x.dtype)
+
+
 def rope_scaling_attr(op, name):
     """An op's YaRN attribute as `rotary_tables` takes it: None where the
     attribute is absent or empty."""
@@ -648,14 +669,18 @@ def rope_scaling_attr(op, name):
 @register_op("rotary_embedding")
 def _rotary_embedding(ctx, op):
     """X: [b, s, heads, d], d even; attrs `theta` and, optionally,
-    `scaling` (YaRN's five numbers). Out has X's shape and dtype
-    (`rotate_half`)."""
+    `scaling` (YaRN's five numbers) and `interleaved` (pairs of
+    neighbouring lanes, `rotate_pairs`; absent: `rotate_half`). Out has
+    X's shape and dtype."""
     x = ctx.in_(op, "X")
     if x.ndim != 4 or x.shape[3] % 2:
         raise ValueError(
             f"rotary_embedding: X {x.shape}: expected [b, s, heads, d], d even")
-    ctx.out(op, "Out", rotate_half(x, float(op.attr("theta", 10000.0)),
-                                   rope_scaling_attr(op, "scaling")))
+    interleaved = bool(op.attr("interleaved", False))
+    if interleaved:
+        profiler.bump_counter("rope_interleaved")
+    ctx.out(op, "Out", (rotate_pairs if interleaved else rotate_half)(
+        x, float(op.attr("theta", 10000.0)), rope_scaling_attr(op, "scaling")))
 
 
 @register_op("layer_norm_grad", differentiable=False)
