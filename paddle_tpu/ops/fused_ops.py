@@ -66,8 +66,10 @@ _logger = logging.getLogger(__name__)
 #          head's lanes, the positions and the head-major write, is one
 #          kernel pair over q, k and v (ops/pallas/qk_prep.py; PERF.md,
 #          PR 34); the other paths run `rms_norm` and `rotate_half` first.
-#          Values narrower than the keys are zero-padded to the keys'
-#          width for this kernel alone (the others take them as they are).
+#          Values narrower than the keys travel through this kernel at
+#          their own width in whole lanes (128 beside the keys' 256 at
+#          latent attention's 192 and 128; PERF.md, PR 40); the other
+#          paths take them as they are.
 #   xla    _xla_attention everywhere else: the "bhsd" layout, the CPU, and
 #          every other mesh of several devices (tensor or pipeline
 #          parallel, a batch the axis does not divide: GSPMD cannot
@@ -330,8 +332,8 @@ def _fused_mha(ctx, op):
                          out_dtype=q.dtype),
                 bias=bias, causal=causal, sm_scale=sm_scale, dropout=dropout,
                 rng_key=rng, window=window))
-        # values narrower than the keys: the kernel pads them up to the
-        # keys' width and cuts the output back (the block comment above)
+        # values narrower than the keys: the kernel takes them at their own
+        # width in whole lanes, and so writes the output
         return swap(flash_attention(
             swap(q), swap(k), swap(v), bias=bias, causal=causal,
             sm_scale=sm_scale, dropout=dropout, rng_key=rng, window=window,

@@ -7,19 +7,23 @@ designed MXU/VMEM-first instead: blocked online-softmax so the [s, s]
 score matrix never hits HBM, fp32 accumulation, optional in-kernel
 dropout regenerated (not stored) in the backward pass.
 
-Layout: q [b, h, sq, d], k/v [b, hkv, sk, d] with `h` a multiple of
-`hkv` (grouped key/value heads: the K and V blocks of query head `n` are
-indexed `n // group`, `flash_bwd_dkv` runs over the key/value heads and
-sums over its group's query heads, and K and V are never repeated),
-optional additive key bias [b, sk] (the padding-mask case), `causal`
-flag, `window` (with `causal`: the last `window` keys a query may see).
-Head dim is zero-padded to a lane multiple (128); sequence dims are
-padded to block multiples with fully-masked keys. The arrays are
-head-major: cutting a head's blocks from the lanes of [b, s, heads*128]
-arrays as the projections write them was built and measured at s=8,192
-(PERF.md, PR 33): the strided blocks cost the kernels 8% and the step as
-a whole ran 4.8% slower than with the four transposes, which XLA folds
-into the relayouts it makes around the per-head norms anyway.
+Layout: q [b, h, sq, d], k [b, hkv, sk, d], v [b, hkv, sk, dv] with `h`
+a multiple of `hkv` (grouped key/value heads: the K and V blocks of query
+head `n` are indexed `n // group`, `flash_bwd_dkv` runs over the
+key/value heads and sums over its group's query heads, and K and V are
+never repeated), optional additive key bias [b, sk] (the padding-mask
+case), `causal` flag, `window` (with `causal`: the last `window` keys a
+query may see). Each head width is zero-padded to a lane multiple (128)
+of its own: q, k, dq and dk travel at the keys' padded width, v, the
+output, dO and dv at the values', so values narrower than the keys
+(latent attention's 128 beside 192) cost P.V, dO.V^T and P^T.dO no lane
+they do not fill. Sequence dims are padded to block multiples with
+fully-masked keys. The arrays are head-major: cutting a head's blocks
+from the lanes of [b, s, heads*128] arrays as the projections write them
+was built and measured at s=8,192 (PERF.md, PR 33): the strided blocks
+cost the kernels 8% and the step as a whole ran 4.8% slower than with
+the four transposes, which XLA folds into the relayouts it makes around
+the per-head norms anyway.
 
 What the grids skip: a block of scores in which the masks admit no pair
 (above the causal diagonal, below the window's edge) is neither copied
@@ -340,11 +344,12 @@ def _fwd_kernel(
 
 def _fwd_pallas(q, k, v, bias, seed, h, *, sm_scale, causal, causal_offset,
                 dropout, block_q, block_k, window=0, dims=None):
-    """q: [b*h, sq, d] and k, v: [b*hkv, sk, d], whole blocks; `dims`:
-    what `_cost` reads. Returns the output like q and the log-sum-exp
-    rows, [b*h, 1, sq] float32."""
+    """q: [b*h, sq, d], k: [b*hkv, sk, d] and v: [b*hkv, sk, dv], whole
+    blocks and whole lanes; `dims`: what `_cost` reads. Returns the
+    output, [b*h, sq, dv] in q's dtype, and the log-sum-exp rows,
+    [b*h, 1, sq] float32."""
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
     masks = _Masks.of(sq, sk, causal=causal, causal_offset=causal_offset,
                       window=window, block_q=block_q, block_k=block_k)
     steps = masks.key_steps()
@@ -383,21 +388,21 @@ def _fwd_pallas(q, k, v, bias, seed, h, *, sm_scale, causal, causal_offset,
             pl.BlockSpec(memory_space=pltpu.SMEM),  # seed
             pl.BlockSpec((1, block_q, d), qspec, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_k, d), kspec, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), kspec, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, dv), kspec, memory_space=pltpu.VMEM),
             *bias_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), qspec, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_q, dv), qspec, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, block_q), lambda n, j, t: (n, 0, j), memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, LANE), jnp.float32),
             pltpu.VMEM((block_q, LANE), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=_interpret(),
         name="flash_fwd",
@@ -580,7 +585,7 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
                 causal_offset, dropout, block_q, block_k, delta=None,
                 window=0, dims=None):
     bh, sq, d = q.shape
-    bhkv, sk = k.shape[0], k.shape[1]
+    bhkv, sk, dv = k.shape[0], k.shape[1], v.shape[2]
     group = bh // bhkv
     hkv = h // group
     masks = _Masks.of(sq, sk, causal=causal, causal_offset=causal_offset,
@@ -620,8 +625,8 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), qspec, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_k, d), kspec, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), kspec, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, d), qspec, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, dv), kspec, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_q, dv), qspec, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, block_q), rowspec, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, block_q), rowspec, memory_space=pltpu.VMEM),
             *bias_specs_q,
@@ -653,15 +658,15 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), kq, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_k, d), kk, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), kk, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, d), kq, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, dv), kk, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_q, dv), kq, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, block_q), krow, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, block_q), krow, memory_space=pltpu.VMEM),
             *bias_specs_k,
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), kk, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), kk, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, dv), kk, memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -669,7 +674,7 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=_interpret(),
         name="flash_bwd_dkv",
@@ -716,26 +721,27 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 def _pad_inputs(q, k, v, bias, block_q, block_k):
     """Flatten [b, h, s, d] -> [b*h, s_p, d_p] (k and v may have fewer
-    heads than q) with lane/sublane padding for the kernels: block sizes
-    sublane-aligned (16 covers bf16's (16, 128) min tile), head dim padded
-    to a lane multiple, sequence dims padded to block multiples with
-    padded keys masked via NEG_INF bias. Shared by the flash and ring
+    heads than q, and v its own width) with lane/sublane padding for the
+    kernels: block sizes sublane-aligned (16 covers bf16's (16, 128) min
+    tile), each array's head dim padded to a lane multiple, sequence dims
+    padded to block multiples with padded keys masked via NEG_INF bias
+    (`vf` is [b*hkv, sk_p, dv_p]). Shared by the flash and ring
     entry points so their layouts (and dropout-mask coordinates) stay
     bit-compatible. Returns (qf, kf, vf, biasf, bq, bk); biasf is
     [b, 1, sk_p] or None."""
-    b, _, sq, d = q.shape
+    b, _, sq, _ = q.shape
     sk = k.shape[2]
     bq = min(block_q or 512, _ceil_to(max(LANE, sq), 16))
     bk = min(block_k or 512, _ceil_to(max(LANE, sk), 16))
     bq, bk = _ceil_to(bq, 16), _ceil_to(bk, 16)
-    sq_p, sk_p, d_p = _ceil_to(sq, bq), _ceil_to(sk, bk), _ceil_to(d, LANE)
+    sq_p, sk_p = _ceil_to(sq, bq), _ceil_to(sk, bk)
 
-    # the kernels have one head width: values narrower than the keys are
-    # padded with zeros up to it like the rest (their output columns stay
-    # zero, and their gradient's are cut off again)
+    # each array to whole lanes of its own width: values narrower than the
+    # keys stay narrower (the kernels read the widths off their operands),
+    # and the zero lanes' output columns and gradients are cut off again
     qf, kf, vf = (
-        t if t.shape[2] == d_p else jnp.pad(
-            t, [(0, 0), (0, 0), (0, d_p - t.shape[2])])
+        t if t.shape[2] % LANE == 0 else jnp.pad(
+            t, [(0, 0), (0, 0), (0, _ceil_to(t.shape[2], LANE) - t.shape[2])])
         for t in (t.reshape(-1, *t.shape[2:]) for t in (q, k, v)))
     if sq_p != sq:
         qf = jnp.pad(qf, [(0, 0), (0, sq_p - sq), (0, 0)])
@@ -908,6 +914,8 @@ def flash_attention(
     visited, total = masks.visited()
     profiler.bump_counter("flash_blocks_visited", b * h * visited)
     profiler.bump_counter("flash_blocks_total", b * h * total)
+    if vf.shape[2] < kf.shape[2]:  # the values travel at a width of their own
+        profiler.bump_counter("flash_narrow_value_calls")
 
     statics = (("sm_scale", float(sm_scale)), ("causal", bool(causal)),
                ("causal_offset", causal_offset), ("dropout", float(dropout)),

@@ -1,7 +1,8 @@
 """What the Pallas calls of a traced function declare: the tests of the
 kernels' `cost_estimate` (ops/pallas/cost.py has the convention) read the
 `pallas_call` equations of a jaxpr, nested ones included, and hold them
-to counts written out by hand."""
+to counts written out by hand. `operand_shapes` reads the same equations'
+operands and results."""
 
 import jax
 from jax.extend import core as jex_core
@@ -20,20 +21,31 @@ def _jaxprs(value):
 def _walk(jaxpr, found):
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            found.setdefault(eqn.params["name"],
-                             []).append(eqn.params["cost_estimate"])
+            found.setdefault(eqn.params["name"], []).append(eqn)
             continue  # the kernel's own body holds no call
         for value in eqn.params.values():
             for inner in _jaxprs(value):
                 _walk(inner, found)
 
 
+def _calls(fn, args, read) -> dict:
+    found = {}
+    _walk(jax.make_jaxpr(fn)(*args).jaxpr, found)
+    return {name: [read(eqn) for eqn in eqns] for name, eqns in found.items()}
+
+
 def declared(fn, *args) -> dict:
     """Kernel name -> the `pl.CostEstimate` of each of its calls in
     `fn(*args)`, in the order they are traced. Nothing runs."""
-    found = {}
-    _walk(jax.make_jaxpr(fn)(*args).jaxpr, found)
-    return found
+    return _calls(fn, args, lambda eqn: eqn.params["cost_estimate"])
+
+
+def operand_shapes(fn, *args) -> dict:
+    """Kernel name -> (operands' shapes, results' shapes) of each of its
+    calls in `fn(*args)`: the arrays the custom call reads and writes."""
+    return _calls(fn, args, lambda eqn: (
+        [v.aval.shape for v in eqn.invars],
+        [v.aval.shape for v in eqn.outvars]))
 
 
 def numbers(estimate) -> tuple:

@@ -120,12 +120,16 @@ def test_causal_cross_length_alignment(rng):
 # ------------------------------------ the band and the key/value group
 
 
-def _band_case(rng, b, h, hkv, sq, sk, d, layout="bhsd"):
-    def shape(n, s):
-        return (b, s, n, d) if layout == "bshd" else (b, n, s, d)
+def _band_case(rng, b, h, hkv, sq, sk, d, layout="bhsd", dv=None,
+               dtype=jnp.float32):
+    """q, k, v and a weight for the output; `dv`: values (and so the
+    output) of another width than the keys'."""
+    def shape(n, s, width):
+        return (b, s, n, width) if layout == "bshd" else (b, n, s, width)
 
-    q, k, v, w = (jnp.asarray(rng.randn(*shape(n, s)), jnp.float32)
-                  for n, s in ((h, sq), (hkv, sk), (hkv, sk), (h, sq)))
+    q, k, v, w = (jnp.asarray(rng.randn(*shape(n, s, width)), dtype)
+                  for n, s, width in ((h, sq, d), (hkv, sk, d),
+                                      (hkv, sk, dv or d), (h, sq, dv or d)))
     return q, k, v, w
 
 
@@ -217,6 +221,117 @@ def test_a_group_is_what_repeated_heads_give(rng):
         np.testing.assert_allclose(a, b_.sum(1, keepdims=True), atol=2e-5)
 
 
+# ---------------------------------------------- values narrower than the keys
+
+
+_NARROW_CASES = {
+    # (b, h, hkv, sq, sk, d, dv), causal, window, key bias, dtype
+    "causal": ((1, 2, 2, 256, 256, 192, 128), True, 0, False, jnp.float32),
+    "key_bias": ((2, 2, 2, 256, 256, 192, 128), False, 0, True, jnp.float32),
+    "causal_key_bias": ((2, 2, 2, 200, 200, 192, 128), True, 0, True,
+                        jnp.float32),
+    "group_of_2": ((1, 4, 2, 256, 256, 192, 128), True, 0, False,
+                   jnp.float32),
+    "window": ((1, 2, 2, 384, 384, 192, 128), True, 130, False, jnp.float32),
+    "group_window_bias": ((1, 4, 1, 128, 384, 192, 128), True, 150, True,
+                          jnp.float32),
+    "bf16": ((1, 2, 2, 256, 256, 192, 128), True, 0, False, jnp.bfloat16),
+    # two lane tiles of values under three of keys
+    "wider": ((1, 2, 2, 128, 128, 320, 200), True, 0, False, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", list(_NARROW_CASES))
+def test_narrow_values_are_bitwise_the_padded_call(rng, case):
+    """Values of 128 lanes under keys of 192 (256 padded) travel through
+    the three kernels at 128: out, dq, dk and dv equal to the bit what the
+    call gives when the values come padded with zeros to the keys' width
+    (every operand then at 256 lanes, which is what the kernels ran before
+    they had a value width) and the results are cut back; and they agree
+    with the plain path in float32 like every other case."""
+    dims, causal, window, with_bias, dtype = _NARROW_CASES[case]
+    b, sk, d, dv = dims[0], dims[4], dims[5], dims[6]
+    q, k, v, w = _band_case(rng, *dims[:6], dv=dv, dtype=dtype)
+    bias = None
+    if with_bias:
+        bias = jnp.where(jnp.arange(sk)[None, :] < sk - 37, 0.0,
+                         fa.NEG_INF) * jnp.ones((b, 1))
+    flash = lambda q, k, v: fa.flash_attention(  # noqa: E731
+        q, k, v, bias=bias, causal=causal, window=window, block_q=128,
+        block_k=128)
+    got = _out_and_grads(flash, q, k, v, w)
+    assert got[0].shape == w.shape and got[3].shape == v.shape
+
+    grow = lambda t: jnp.pad(t, [(0, 0)] * 3 + [(0, d - dv)])  # noqa: E731
+    padded = _out_and_grads(flash, q, k, grow(v), grow(w))
+    for a, b_, name in zip(got, padded, ("out", "dq", "dk", "dv")):
+        if name in ("out", "dv"):
+            assert not np.asarray(b_[..., dv:]).any(), name
+            b_ = b_[..., :dv]
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_), name)
+
+    if dtype != jnp.float32:
+        return
+    want = _out_and_grads(
+        lambda q, k, v: fa._attention_unfused(
+            q, k, v, bias, causal, 1.0 / np.sqrt(d), 0.0, None, True,
+            window=window),
+        q, k, v, w)
+    for a, b_, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        scale = max(1.0, float(jnp.abs(b_).max()))
+        assert float(jnp.abs(a - b_).max()) / scale < 1e-5, name
+
+
+# (b, h, hkv, s, d, dv) -> the lanes q, k, dq, dk and v, out, dO, dv travel
+# at, and `flash_narrow_value_calls`. Where both widths round to the same
+# lanes the arrays are what they were before the values had a width of
+# their own (the second and third rows: every operand at the one width)
+_WIDTH_CASES = {
+    "latent_192_128": ((1, 4, 2, 256, 192, 128), 256, 128, 1),
+    "one_width_128": ((1, 4, 2, 256, 128, 128), 128, 128, 0),
+    "one_width_192": ((1, 4, 2, 256, 192, 192), 256, 256, 0),
+    "narrower_in_the_same_lanes": ((1, 4, 2, 256, 128, 64), 128, 128, 0),
+    "half_lanes_64": ((1, 2, 2, 256, 64, 64), 128, 128, 0),
+    "latent_320_200": ((1, 2, 2, 256, 320, 200), 384, 256, 1),
+}
+
+
+@pytest.mark.parametrize("with_bias", [False, True], ids=["nobias", "bias"])
+@pytest.mark.parametrize("case", list(_WIDTH_CASES))
+def test_operand_widths_of_the_three_calls(case, with_bias):
+    """The arrays the custom calls read and write: v, the output, dO and
+    dv at the values' lanes, q, k, dq and dk at the keys'; and the counter
+    that says a call's values travelled narrower than its keys."""
+    from pallas_costs import operand_shapes
+
+    from paddle_tpu import profiler
+
+    (b, h, hkv, s, d, dv), d_p, dv_p, narrow = _WIDTH_CASES[case]
+    q = jnp.zeros((b, h, s, d), jnp.bfloat16)
+    k = jnp.zeros((b, hkv, s, d), jnp.bfloat16)
+    v = jnp.zeros((b, hkv, s, dv), jnp.bfloat16)
+    bias = jnp.zeros((b, s), jnp.float32) if with_bias else None
+    before = profiler.counters().get("flash_narrow_value_calls", 0)
+    found = operand_shapes(jax.grad(lambda *a: jnp.sum(fa.flash_attention(
+        *a, bias=bias, causal=True).astype(jnp.float32)),
+        argnums=(0, 1, 2)), q, k, v)
+    # the forward is traced once for the output and the residuals alike
+    assert (profiler.counters().get("flash_narrow_value_calls", 0) - before
+            == narrow)
+
+    seed, rows = (1,), (b * h, 1, s)
+    qs, ks, vs = (b * h, s, d_p), (b * hkv, s, d_p), (b * hkv, s, dv_p)
+    outs = (b * h, s, dv_p)
+    biases = [(b, 1, s)] if with_bias else []
+    assert found == {
+        "flash_fwd": [([seed, qs, ks, vs, *biases], [outs, rows])],
+        "flash_bwd_dq": [([seed, qs, ks, vs, outs, rows, rows, *biases],
+                          [qs])],
+        "flash_bwd_dkv": [([seed, qs, ks, vs, outs, rows, rows, *biases],
+                           [ks, vs])],
+    }
+
+
 @pytest.mark.parametrize("dims,window,by_hand", [
     # 3 x 3 blocks of 128: causal alone visits the lower triangle, 6
     ((1, 2, 2, 384, 384, 64), 0, (3 * 6, 3 * 9)),
@@ -283,8 +398,8 @@ _COST_CASES = {
     # eight query heads a key/value head: K and V are moved once, not eight
     # times
     "group_of_8": ((1, 8, 1, 512, 128, 128), 0, 512 * 513 // 2),
-    # the latent layer: keys 192 wide, values 128, both padded to 256
-    # lanes inside; the count is at 192 and 128
+    # the latent layer: keys 192 wide, values 128, padded inside to 256
+    # and 128 lanes; the count is at 192 and 128
     "latent_values_narrower": ((1, 2, 2, 512, 192, 128), 0, 512 * 513 // 2),
 }
 

@@ -350,6 +350,59 @@ def test_qk_prep_kernels_compile_for_a_v5e_chip_at_the_published_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+@pytest.mark.parametrize("shape,window,lanes", [
+    # JoyAI's and Kimi's latent layer: keys of 192 and values of 128
+    ((1, 32, 32, 4096, 192, 128), 0, (256, 128)),
+    # Trinity's window layer: one width, 32 query heads over 4
+    ((1, 32, 4, 8192, 128, 128), 2048, (128, 128)),
+], ids=["latent_192_128", "gqa_window_128"])
+def test_flash_kernels_compile_for_a_v5e_chip_at_the_published_widths(
+        topo, shape, window, lanes):
+    """The three blocked attention kernels at the shapes of the cells that
+    run them, bf16, through `jax.vjp`: Mosaic takes q, k, dq and dk at the
+    keys' lanes and v, the output, dO and dv at the values' (the shapes
+    the custom calls are held to in the compiled module). Nothing runs."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas import flash_attention
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    b, h, hkv, s, d, dv = shape
+    d_p, dv_p = lanes
+
+    def sds(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=chip)
+
+    def both(q, k, v):
+        o, pull = jax.vjp(lambda *a: flash_attention(
+            *a, causal=True, window=window), q, k, v)
+        return o, pull(o)
+
+    with _as_on_the_chip():
+        text = jax.jit(both).lower(
+            sds(b, h, s, d), sds(b, hkv, s, d), sds(b, hkv, s, dv)
+        ).compile().as_text()
+    qs, ks = f"bf16[{b * h},{s},{d_p}]", f"bf16[{b * hkv},{s},{d_p}]"
+    vs, outs = f"bf16[{b * hkv},{s},{dv_p}]", f"bf16[{b * h},{s},{dv_p}]"
+    rows = f"f32[{b * h},1,{s}]"
+    operands = {
+        "flash_fwd": ["s32[1]", qs, ks, vs],
+        "flash_bwd_dq": ["s32[1]", qs, ks, vs, outs, rows, rows],
+        "flash_bwd_dkv": ["s32[1]", qs, ks, vs, outs, rows, rows],
+    }
+    results = {"flash_fwd": [outs, rows], "flash_bwd_dq": [qs],
+               "flash_bwd_dkv": [ks, vs]}
+    shapes = re.compile(r"\w+\[[\d,]*\]").findall
+    for name, want in operands.items():
+        ((written, read),) = re.findall(
+            rf"^\s*%?{name}[.\d]* = (.*?) custom-call\(.*?"
+            r"operand_layout_constraints=\{(.*?\})\}, ", text, re.M)
+        assert shapes(read) == want, name
+        assert shapes(written) == results[name], name
+
+
 @contextlib.contextmanager
 def _as_on_the_chip():
     """The program asks whether Pallas can run, and here the answer is the
