@@ -25,14 +25,23 @@ cost the kernels 8% and the step as a whole ran 4.8% slower than with
 the four transposes, which XLA folds into the relayouts it makes around
 the per-head norms anyway.
 
+Blocks: the two backward kernels share one pair, 512 x 512 unless the
+caller names another, and the sequence dims are padded to it. `flash_fwd`
+has a pair of its own where the caller names none (`_fwd_blocks`: up to
+1,024 x 1,024, multiples of the backward's that divide the padded
+lengths and fit VMEM): what bounds it is paid a row of a block, the
+backward pair is indifferent, and the output and log-sum-exp rows the
+backward reads do not depend on the blocks that made them.
+
 What the grids skip: a block of scores in which the masks admit no pair
 (above the causal diagonal, below the window's edge) is neither copied
 nor computed, in `flash_fwd`, `flash_bwd_dq` and `flash_bwd_dkv` alike
 (`_key_band`, `_query_band`). Inside a visited block masked pairs are
 still computed and thrown away: with blocks of 512 the causal kernel
 visits 53% of the rectangle at 16 x 16 blocks where the mask admits 50%,
-and a 2,048-key window on 8,192 tokens 27.3% where it admits 21.9%.
-Without `causal` every block is visited, as before.
+and a 2,048-key window on 8,192 tokens 27.3% where it admits 21.9%; the
+forward at blocks of 1,024 56% and 32.8%. Without `causal` every block
+is visited, as before.
 
 Mosaic compiles the kernel on a TPU. PADDLE_TPU_PALLAS_INTERPRET=1 runs
 it in the Pallas interpreter so CPU tests exercise the real kernel body;
@@ -153,8 +162,10 @@ def _query_band(kb, m, xp=jnp):
 
 
 class _Masks(typing.NamedTuple):
-    """One call's masks and blocks: static and hashable, so a jitted call
-    takes it as a static argument."""
+    """The masks of one call and the blocks of one of its grids: the
+    forward builds its own at its blocks, the two backward kernels share
+    one at theirs. Static and hashable, so a jitted call takes it as a
+    static argument."""
 
     causal: bool
     causal_offset: int
@@ -179,12 +190,26 @@ class _Masks(typing.NamedTuple):
         first, last = _query_band(np.arange(self.nk), self, np)
         return int((last - first).max()) + 1
 
-    def visited(self):
-        """Blocks one head's three grids compute, and the rectangles'."""
-        first, last = _key_band(np.arange(self.nq), self, np)
-        fwd = int((last - first + 1).sum())
-        first, last = _query_band(np.arange(self.nk), self, np)
-        return 2 * fwd + int((last - first + 1).sum()), 3 * self.nq * self.nk
+    def at(self, block_q, block_k):
+        """The same masks over the same rows at other blocks."""
+        return self.of(self.nq * self.block_q, self.nk * self.block_k,
+                       causal=self.causal, causal_offset=self.causal_offset,
+                       window=self.window, block_q=block_q, block_k=block_k)
+
+    def visited(self, fwd_blocks=None):
+        """Blocks one head's three grids compute, and the rectangles', in
+        units of this, the backward's, block: score area. `fwd_blocks`:
+        the forward's where they are multiples of these; one of its
+        blocks counts as the blocks of this size it covers."""
+        def run(band, n, m):
+            first, last = band(np.arange(n), m, np)
+            return int((last - first + 1).sum())
+
+        fwd = self.at(*fwd_blocks) if fwd_blocks else self
+        covers = (fwd.block_q // self.block_q) * (fwd.block_k // self.block_k)
+        return (covers * run(_key_band, fwd.nq, fwd)
+                + run(_key_band, self.nq, self)
+                + run(_query_band, self.nk, self)), 3 * self.nq * self.nk
 
 
 def _block_of(band, i, t, m):
@@ -697,26 +722,78 @@ _fwd_call = jax.jit(_fwd_pallas, static_argnums=(5,), static_argnames=_STATICS)
 _bwd_call = jax.jit(_bwd_pallas, static_argnums=(8,), static_argnames=_STATICS)
 
 
+def _statics_of(statics):
+    """(`flash_fwd`'s statics, the backward pair's) from the one tuple a
+    call carries: the same but for the blocks, the forward's own under
+    `fwd_blocks`. The output and the log-sum-exp rows do not depend on
+    the blocks that made them, so the backward reads them at its own."""
+    bwd = dict(statics)
+    block_q, block_k = bwd.pop("fwd_blocks")
+    return {**bwd, "block_q": block_q, "block_k": block_k}, bwd
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _flash_core(q, k, v, bias, seed, h, statics):
-    return _fwd_call(q, k, v, bias, seed, h, **dict(statics))[0]
+    return _fwd_call(q, k, v, bias, seed, h, **_statics_of(statics)[0])[0]
 
 
 def _flash_core_fwd(q, k, v, bias, seed, h, statics):
-    out, lse = _fwd_call(q, k, v, bias, seed, h, **dict(statics))
+    out, lse = _fwd_call(q, k, v, bias, seed, h, **_statics_of(statics)[0])
     return out, (q, k, v, bias, seed, out, lse)
 
 
 def _flash_core_bwd(h, statics, res, do):
     q, k, v, bias, seed, out, lse = res
     dq, dk, dv = _bwd_call(q, k, v, bias, seed, out, lse, do, h,
-                           **dict(statics))
+                           **_statics_of(statics)[1])
     dbias = None if bias is None else jnp.zeros_like(bias)
     dseed = np.zeros((1,), dtype=jax.dtypes.float0)
     return dq, dk, dv, dbias, dseed
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
+
+
+# What `flash_fwd` may hold in VMEM: Mosaic's scoped default on a v5e. The
+# two backward kernels at 512 x 512 stay far under it at every width.
+_FWD_VMEM_BYTES = 16 << 20
+
+
+def _fwd_vmem_bytes(block_q, block_k, d_p, dv_p, itemsize):
+    """What a grid step of `flash_fwd` keeps in VMEM, from above: q, k, v
+    and the output's blocks twice (the pipeline's two buffers), the
+    float32 accumulator, `m` and `l`, and a block of scores in float32,
+    its exponentials, and those again in the values' dtype for P.V."""
+    blocks = block_q * (d_p + dv_p) + block_k * (d_p + dv_p)
+    scratch = 4 * block_q * (dv_p + 2 * LANE)
+    return (2 * itemsize * blocks + scratch
+            + (8 + itemsize) * block_q * block_k)
+
+
+def _fwd_blocks(sq_p, sk_p, block_q, block_k, d_p, dv_p, itemsize):
+    """`flash_fwd`'s own (block_q, block_k) for a call whose backward runs
+    at `block_q` x `block_k` over `sq_p` x `sk_p` padded rows: twice each
+    where they divide the padded lengths (so the padding, `causal_offset`
+    and the residuals' shapes are the backward's) and the step fits VMEM,
+    else the key block alone doubled, else the backward's.
+
+    Measured alone on a v5e (PERF.md, PR 41), ms a call at 512 x 512 /
+    512 x 1,024 / 1,024 x 1,024: latent attention at s=4,096 (keys in 256
+    lanes, values in 128) 3.39 / 2.39 / 2.01; 32 heads over 4 at s=8,192
+    and 128 lanes, full causal 10.47 / 6.17 / 5.18, a 2,048-key window
+    5.46 / 3.72 / 3.22, a 1,024-key window 3.70 / 2.80 / 2.45. What bounds
+    the kernel is paid a row of a block (the two lane reductions, the
+    column arithmetic on `m`, `l` and `alpha`, their broadcasts), so a
+    block twice as wide halves it a key; walking a wide block in 512-key
+    parts inside one grid step gave nothing back. A band pays for larger
+    blocks in masked area (1.20 and 1.33 times under those windows) and
+    still gains; 2,048 keys and more lose to it. The backward pair is
+    indifferent to the key block and keeps its own."""
+    for bq, bk in ((2 * block_q, 2 * block_k), (block_q, 2 * block_k)):
+        if (sq_p % bq == 0 and sk_p % bk == 0 and _fwd_vmem_bytes(
+                bq, bk, d_p, dv_p, itemsize) <= _FWD_VMEM_BYTES):
+            return bq, bk
+    return block_q, block_k
 
 
 def _pad_inputs(q, k, v, bias, block_q, block_k):
@@ -727,7 +804,9 @@ def _pad_inputs(q, k, v, bias, block_q, block_k):
     padded to block multiples with padded keys masked via NEG_INF bias
     (`vf` is [b*hkv, sk_p, dv_p]). Shared by the flash and ring
     entry points so their layouts (and dropout-mask coordinates) stay
-    bit-compatible. Returns (qf, kf, vf, biasf, bq, bk); biasf is
+    bit-compatible. Returns (qf, kf, vf, biasf, bq, bk): the blocks of the
+    backward pair, and of the forward too unless `_fwd_blocks` gives it
+    multiples of them, which need no other padding; biasf is
     [b, 1, sk_p] or None."""
     b, _, sq, _ = q.shape
     sk = k.shape[2]
@@ -879,6 +958,9 @@ def flash_attention(
     head `n // (h // hkv)`); bias: additive key bias [b, sk] (0 keep /
     -inf drop) or None. `window` > 0 (with `causal`) admits only the last
     `window` keys a query may see. Returns [b, h, sq, dv] in q's dtype.
+    `block_q`, `block_k`: a block named here serves all three kernels;
+    with neither, the backward pair runs at 512 x 512 and the forward at
+    blocks chosen from the call's shape (`_fwd_blocks`).
     """
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
@@ -908,18 +990,24 @@ def flash_attention(
     # padded q rows are sliced away and padded keys are bias-masked
     causal_offset = sk - sq
     qf, kf, vf, biasf, bq, bk = _pad_inputs(q, k, v, bias, block_q, block_k)
+    fwd_blocks = bq, bk  # blocks passed by hand serve all three kernels
+    if block_q is None and block_k is None:
+        fwd_blocks = _fwd_blocks(qf.shape[1], kf.shape[1], bq, bk,
+                                 kf.shape[2], vf.shape[2], qf.dtype.itemsize)
     masks = _Masks.of(qf.shape[1], kf.shape[1], causal=bool(causal),
                       causal_offset=causal_offset, window=int(window),
                       block_q=bq, block_k=bk)
-    visited, total = masks.visited()
+    visited, total = masks.visited(fwd_blocks)
     profiler.bump_counter("flash_blocks_visited", b * h * visited)
     profiler.bump_counter("flash_blocks_total", b * h * total)
     if vf.shape[2] < kf.shape[2]:  # the values travel at a width of their own
         profiler.bump_counter("flash_narrow_value_calls")
+    if fwd_blocks[1] != bk:  # the forward walks the keys at a block of its own
+        profiler.bump_counter("flash_fwd_wide_key_calls")
 
     statics = (("sm_scale", float(sm_scale)), ("causal", bool(causal)),
                ("causal_offset", causal_offset), ("dropout", float(dropout)),
-               ("block_q", bq), ("block_k", bk), ("window", int(window)),
-               ("dims", (sq, sk, d, dv)))
+               ("block_q", bq), ("block_k", bk), ("fwd_blocks", fwd_blocks),
+               ("window", int(window)), ("dims", (sq, sk, d, dv)))
     out = _flash_core(qf, kf, vf, biasf, seed, h, statics)
     return out[:, :sq, :dv].reshape(b, h, sq, dv)

@@ -2,7 +2,7 @@
 kernels' `cost_estimate` (ops/pallas/cost.py has the convention) read the
 `pallas_call` equations of a jaxpr, nested ones included, and hold them
 to counts written out by hand. `operand_shapes` reads the same equations'
-operands and results."""
+operands and results, `block_shapes` their grids and blocks."""
 
 import jax
 from jax.extend import core as jex_core
@@ -46,6 +46,18 @@ def operand_shapes(fn, *args) -> dict:
     return _calls(fn, args, lambda eqn: (
         [v.aval.shape for v in eqn.invars],
         [v.aval.shape for v in eqn.outvars]))
+
+
+def block_shapes(fn, *args) -> dict:
+    """Kernel name -> (grid, the block of each operand and result in
+    turn) of each of its calls in `fn(*args)`."""
+    def read(eqn):
+        mapping = eqn.params["grid_mapping"]
+        return mapping.grid, [
+            tuple(getattr(dim, "block_size", dim) for dim in m.block_shape)
+            for m in mapping.block_mappings]
+
+    return _calls(fn, args, read)
 
 
 def numbers(estimate) -> tuple:

@@ -282,6 +282,213 @@ def test_narrow_values_are_bitwise_the_padded_call(rng, case):
         assert float(jnp.abs(a - b_).max()) / scale < 1e-5, name
 
 
+# --------------------------------------------- the forward's own blocks
+
+
+def _flash_at(q, k, v, fwd_blocks, block=128, bias=None, causal=True,
+              window=0, dropout=0.0):
+    """`flash_attention` with the backward at `block` x `block` and the
+    forward at `fwd_blocks`, set where the call carries them: in the
+    statics (no argument of the entry point picks the forward's blocks).
+    Returns the call and the forward alone, for its log-sum-exp rows."""
+    b, h, sq, d = q.shape
+    sk, dv = k.shape[2], v.shape[3]
+    qf, kf, vf, biasf, bq, bk = fa._pad_inputs(q, k, v, bias, block, block)
+    assert qf.shape[1] % fwd_blocks[0] == kf.shape[1] % fwd_blocks[1] == 0
+    seed = jnp.full((1,), 11, jnp.int32)
+    statics = (("sm_scale", d ** -0.5), ("causal", causal),
+               ("causal_offset", sk - sq), ("dropout", dropout),
+               ("block_q", bq), ("block_k", bk), ("fwd_blocks", fwd_blocks),
+               ("window", window), ("dims", (sq, sk, d, dv)))
+
+    def call(q, k, v):
+        qf, kf, vf = fa._pad_inputs(q, k, v, bias, block, block)[:3]
+        out = fa._flash_core(qf, kf, vf, biasf, seed, h, statics)
+        return out[:, :sq, :dv].reshape(b, h, sq, dv)
+
+    out, lse = fa._fwd_call(qf, kf, vf, biasf, seed, h,
+                            **fa._statics_of(statics)[0])
+    return call, (out[:, :sq, :dv].reshape(b, h, sq, dv),
+                  lse[:, 0, :sq].reshape(b, h, sq))
+
+
+_OWN_BLOCK_CASES = {
+    # (b, h, hkv, sq, sk, d, dv), the forward's blocks (the backward's:
+    # 128 x 128), causal, window, key bias, dtype
+    "causal": ((1, 2, 2, 512, 512, 64, 64), (256, 256), True, 0, False,
+               jnp.float32),
+    "key_block_alone": ((1, 2, 2, 384, 512, 64, 64), (128, 256), True, 0,
+                        False, jnp.float32),
+    "window_below_the_block": ((1, 2, 2, 512, 512, 64, 64), (256, 256), True,
+                               100, False, jnp.float32),
+    "window_the_block": ((1, 2, 2, 512, 512, 64, 64), (256, 256), True, 256,
+                         False, jnp.float32),
+    "window_twice_the_block": ((1, 2, 2, 1024, 1024, 64, 64), (256, 256),
+                               True, 512, False, jnp.float32),
+    "group_of_8": ((1, 8, 1, 512, 512, 64, 64), (256, 256), True, 0, False,
+                   jnp.float32),
+    "narrow_values": ((1, 2, 2, 512, 512, 192, 128), (256, 256), True, 0,
+                      False, jnp.float32),
+    "sq_is_not_sk": ((1, 4, 2, 256, 768, 64, 64), (256, 256), True, 300,
+                     False, jnp.float32),
+    "key_bias_ragged": ((2, 2, 2, 200, 470, 64, 64), (256, 256), False, 0,
+                        True, jnp.float32),
+    "causal_key_bias": ((2, 2, 2, 500, 500, 64, 64), (128, 256), True, 0,
+                        True, jnp.float32),
+    "bf16": ((1, 2, 2, 512, 512, 192, 128), (256, 256), True, 0, False,
+             jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(_OWN_BLOCK_CASES))
+def test_forward_at_blocks_of_its_own(rng, case):
+    """`flash_fwd` at larger blocks than the backward pair's: its output
+    and log-sum-exp rows within rounding of the same call at equal blocks
+    (the online softmax sums in another order, nothing else), and out, dq,
+    dk, dv, the gradients computed by the backward at its own blocks from
+    the wide forward's residuals, against `_attention_unfused` in float32
+    like every other case."""
+    dims, fwd_blocks, causal, window, with_bias, dtype = _OWN_BLOCK_CASES[case]
+    b, sk, d, dv = dims[0], dims[4], dims[5], dims[6]
+    q, k, v, w = _band_case(rng, *dims[:6], dv=dv, dtype=dtype)
+    bias = None
+    if with_bias:
+        bias = jnp.where(jnp.arange(sk)[None, :] < sk - 37, 0.0,
+                         fa.NEG_INF) * jnp.ones((b, 1))
+    kw = dict(bias=bias, causal=causal, window=window)
+    wide, (out_w, lse_w) = _flash_at(q, k, v, fwd_blocks, **kw)
+    equal, (out_e, lse_e) = _flash_at(q, k, v, (128, 128), **kw)
+    rounding = 1e-5 if dtype == jnp.float32 else 2 ** -7  # a step of bf16
+    for a, b_, name in ((out_w, out_e, "out"), (lse_w, lse_e, "lse")):
+        scale = max(1.0, float(jnp.abs(b_.astype(jnp.float32)).max()))
+        assert float(jnp.abs(a.astype(jnp.float32) - b_.astype(jnp.float32))
+                     .max()) / scale <= rounding, name
+    got = _out_and_grads(wide, q, k, v, w)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(out_w))
+    if dtype != jnp.float32:
+        ours = _out_and_grads(equal, q, k, v, w)
+        for a, b_, name in zip(got, ours, ("out", "dq", "dk", "dv")):
+            scale = max(1.0, float(jnp.abs(b_.astype(jnp.float32)).max()))
+            assert float(jnp.abs(a.astype(jnp.float32) - b_.astype(
+                jnp.float32)).max()) / scale < 2 ** -5, name
+        return
+    want = _out_and_grads(
+        lambda q, k, v: fa._attention_unfused(
+            q, k, v, bias, causal, d ** -0.5, 0.0, None, True, window=window),
+        q, k, v, w)
+    for a, b_, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        assert a.shape == b_.shape
+        scale = max(1.0, float(jnp.abs(b_).max()))
+        assert float(jnp.abs(a - b_).max()) / scale < 1e-5, name
+
+
+def test_dropout_keeps_the_same_pairs_at_any_forward_block(rng):
+    """`_dropout_keep` hashes global coordinates: with one-hot values the
+    output *is* the kept probabilities, and the forward at 256 x 256 keeps
+    to the bit the pairs it keeps at the backward's 128 x 128, which are
+    the pairs the backward kernels regenerate."""
+    s = 256
+    q, k, _, w = _band_case(rng, 1, 2, 2, s, s, 64, dv=s)
+    v = jnp.broadcast_to(jnp.eye(s, dtype=jnp.float32), (1, 2, s, s))
+    wide, (p_wide, _) = _flash_at(q, k, v, (256, 256), causal=False,
+                                  dropout=0.3)
+    equal, (p_equal, _) = _flash_at(q, k, v, (128, 128), causal=False,
+                                    dropout=0.3)
+    kept = np.asarray(p_equal) != 0
+    assert 0.68 < kept.mean() < 0.72
+    np.testing.assert_array_equal(np.asarray(p_wide) != 0, kept)
+    np.testing.assert_allclose(p_wide, p_equal, rtol=1e-5, atol=1e-7)
+    for a, b_ in zip(_out_and_grads(wide, q, k, v, w)[1:],
+                     _out_and_grads(equal, q, k, v, w)[1:]):
+        np.testing.assert_allclose(a, b_, rtol=1e-4, atol=1e-5)
+
+
+def test_a_ragged_key_length_keeps_the_backwards_blocks(rng):
+    """sk = 1,100 pads to three blocks of 512, which no block of 1,024
+    divides: the chooser falls back, the call is the parent's to the bit
+    (the same blocks passed by hand) and the counter stays."""
+    from paddle_tpu import profiler
+
+    q, k, v, w = _band_case(rng, 1, 1, 1, 1100, 1100, 64)
+    bias = jnp.where(jnp.arange(1100)[None, :] < 1000, 0.0, fa.NEG_INF)
+    before = profiler.counters().get("flash_fwd_wide_key_calls", 0)
+    chosen = _out_and_grads(lambda *a: fa.flash_attention(
+        *a, bias=bias, causal=True), q, k, v, w)
+    assert profiler.counters().get("flash_fwd_wide_key_calls", 0) == before
+    by_hand = _out_and_grads(lambda *a: fa.flash_attention(
+        *a, bias=bias, causal=True, block_q=512, block_k=512), q, k, v, w)
+    for a, b_ in zip(chosen, by_hand):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+
+
+# The forward's blocks by shape: (sq_p, sk_p, d_p, dv_p, itemsize) at the
+# backward's 512 x 512 -> what the measurement on the chip settled
+# (PERF.md, PR 41). The first four are the six calls the cells make.
+_FWD_BLOCK_TABLE = {
+    "joyai_and_kimi_latent_s4096": ((4096, 4096, 256, 128, 2), (1024, 1024)),
+    "trinity_window_2048_and_full_s8192": ((8192, 8192, 128, 128, 2),
+                                           (1024, 1024)),
+    "mellum_window_1024_and_full_s8192": ((8192, 8192, 128, 128, 2),
+                                          (1024, 1024)),
+    "one_width_256_bf16": ((2048, 2048, 256, 256, 2), (1024, 1024)),
+    "sk_no_multiple_of_1024": ((4096, 1536, 128, 128, 2), (512, 512)),
+    "sq_no_multiple_of_1024": ((1536, 4096, 128, 128, 2), (512, 1024)),
+    "one_block": ((512, 512, 128, 128, 2), (512, 512)),
+    # VMEM: Mosaic refuses 1,024 x 1,024 at these for a v5e (PR 41's probe)
+    "float32_256_256": ((2048, 2048, 256, 256, 4), (512, 1024)),
+    "bf16_512_512": ((2048, 2048, 512, 512, 2), (512, 1024)),
+    "bf16_384_256": ((2048, 2048, 384, 256, 2), (512, 1024)),
+    "bf16_1024_1024": ((2048, 2048, 1024, 1024, 2), (512, 512)),
+    "float32_512_512": ((2048, 2048, 512, 512, 4), (512, 512)),
+}
+
+
+@pytest.mark.parametrize("case", list(_FWD_BLOCK_TABLE))
+def test_forward_blocks_by_shape(case):
+    (sq_p, sk_p, d_p, dv_p, itemsize), want = _FWD_BLOCK_TABLE[case]
+    assert fa._fwd_blocks(sq_p, sk_p, 512, 512, d_p, dv_p, itemsize) == want
+
+
+@pytest.mark.parametrize("by_hand", [
+    {}, {"block_k": 512}, {"block_q": 512}, {"block_q": 256, "block_k": 512}],
+    ids=["default", "block_k", "block_q", "both"])
+def test_blocks_passed_by_hand_serve_all_three_kernels(by_hand):
+    """The chooser acts on the default alone: a call that names a block
+    gets it in `flash_fwd`, `flash_bwd_dq` and `flash_bwd_dkv` alike (ring
+    attention's chunks, the tests at 128), and bumps no counter."""
+    from pallas_costs import block_shapes
+
+    from paddle_tpu import profiler
+
+    b, h, hkv, s, d, dv = 1, 4, 2, 2048, 192, 128
+    q = jnp.zeros((b, h, s, d), jnp.bfloat16)
+    k = jnp.zeros((b, hkv, s, d), jnp.bfloat16)
+    v = jnp.zeros((b, hkv, s, dv), jnp.bfloat16)
+    before = profiler.counters().get("flash_fwd_wide_key_calls", 0)
+    found = block_shapes(jax.grad(lambda *a: jnp.sum(fa.flash_attention(
+        *a, causal=True, **by_hand).astype(jnp.float32)),
+        argnums=(0, 1, 2)), q, k, v)
+    bq, bk = by_hand.get("block_q", 512), by_hand.get("block_k", 512)
+    fq, fk = (bq, bk) if by_hand else (1024, 1024)
+    assert (profiler.counters().get("flash_fwd_wide_key_calls", 0) - before
+            == (not by_hand))
+
+    def blocks(bq, bk):
+        qs, ks, vs = (1, bq, 256), (1, bk, 256), (1, bk, 128)
+        return [(1,), qs, ks, vs], (1, bq, 128), (1, 1, bq), qs, ks, vs
+
+    ins, outs, rows, *_ = blocks(fq, fk)
+    ((grid, found_fwd),) = found["flash_fwd"]
+    assert grid[:2] == (b * h, s // fq) and found_fwd == [*ins, outs, rows]
+    ins, outs, rows, qs, ks, vs = blocks(bq, bk)
+    ((grid, found_dq),) = found["flash_bwd_dq"]
+    assert grid[:2] == (b * h, s // bq)
+    assert found_dq == [*ins, outs, rows, rows, qs]
+    ((grid, found_dkv),) = found["flash_bwd_dkv"]
+    assert grid[:2] == (b * hkv, s // bk)
+    assert found_dkv == [*ins, outs, rows, rows, ks, vs]
+
+
 # (b, h, hkv, s, d, dv) -> the lanes q, k, dq, dk and v, out, dO, dv travel
 # at, and `flash_narrow_value_calls`. Where both widths round to the same
 # lanes the arrays are what they were before the values had a width of
@@ -293,6 +500,10 @@ _WIDTH_CASES = {
     "narrower_in_the_same_lanes": ((1, 4, 2, 256, 128, 64), 128, 128, 0),
     "half_lanes_64": ((1, 2, 2, 256, 64, 64), 128, 128, 0),
     "latent_320_200": ((1, 2, 2, 256, 320, 200), 384, 256, 1),
+    # long enough for the forward's own blocks: 1,024 x 1,024 over the
+    # backward's 512 x 512 (at s=256 every kernel has the one block)
+    "latent_192_128_s2048": ((1, 4, 2, 2048, 192, 128), 256, 128, 1),
+    "one_width_128_s2048": ((1, 4, 2, 2048, 128, 128), 128, 128, 0),
 }
 
 
@@ -300,9 +511,11 @@ _WIDTH_CASES = {
 @pytest.mark.parametrize("case", list(_WIDTH_CASES))
 def test_operand_widths_of_the_three_calls(case, with_bias):
     """The arrays the custom calls read and write: v, the output, dO and
-    dv at the values' lanes, q, k, dq and dk at the keys'; and the counter
-    that says a call's values travelled narrower than its keys."""
-    from pallas_costs import operand_shapes
+    dv at the values' lanes, q, k, dq and dk at the keys'; the blocks the
+    forward cuts them in; and the counters that say a call's values
+    travelled narrower than its keys and its forward at wider blocks than
+    its backward."""
+    from pallas_costs import block_shapes, operand_shapes
 
     from paddle_tpu import profiler
 
@@ -311,13 +524,23 @@ def test_operand_widths_of_the_three_calls(case, with_bias):
     k = jnp.zeros((b, hkv, s, d), jnp.bfloat16)
     v = jnp.zeros((b, hkv, s, dv), jnp.bfloat16)
     bias = jnp.zeros((b, s), jnp.float32) if with_bias else None
-    before = profiler.counters().get("flash_narrow_value_calls", 0)
-    found = operand_shapes(jax.grad(lambda *a: jnp.sum(fa.flash_attention(
-        *a, bias=bias, causal=True).astype(jnp.float32)),
-        argnums=(0, 1, 2)), q, k, v)
+    before = profiler.counters()
+    loss = jax.grad(lambda *a: jnp.sum(fa.flash_attention(
+        *a, bias=bias, causal=True).astype(jnp.float32)), argnums=(0, 1, 2))
+    found = operand_shapes(loss, q, k, v)
     # the forward is traced once for the output and the residuals alike
-    assert (profiler.counters().get("flash_narrow_value_calls", 0) - before
-            == narrow)
+    bumped = {name: profiler.counters().get(name, 0) - before.get(name, 0)
+              for name in ("flash_narrow_value_calls",
+                           "flash_fwd_wide_key_calls")}
+    wide = s == 2048
+    assert bumped == {"flash_narrow_value_calls": narrow,
+                      "flash_fwd_wide_key_calls": int(wide)}
+    fq = fk = 1024 if wide else s
+    ((grid, blocks),) = block_shapes(loss, q, k, v)["flash_fwd"]
+    assert grid[:2] == (b * h, s // fq)
+    assert blocks == [(1,), (1, fq, d_p), (1, fk, d_p), (1, fk, dv_p),
+                      *([(1, 1, fk)] if with_bias else []),
+                      (1, fq, dv_p), (1, 1, fq)]
 
     seed, rows = (1,), (b * h, 1, s)
     qs, ks, vs = (b * h, s, d_p), (b * hkv, s, d_p), (b * hkv, s, dv_p)
@@ -332,57 +555,85 @@ def test_operand_widths_of_the_three_calls(case, with_bias):
     }
 
 
-@pytest.mark.parametrize("dims,window,by_hand", [
+@pytest.mark.parametrize("dims,window,by_hand,fwd_blocks", [
     # 3 x 3 blocks of 128: causal alone visits the lower triangle, 6
-    ((1, 2, 2, 384, 384, 64), 0, (3 * 6, 3 * 9)),
+    ((1, 2, 2, 384, 384, 64), 0, (3 * 6, 3 * 9), None),
     # window 200: query block 2 (rows 256-383) sees keys 57-383: blocks
     # 0, 1, 2; block 1: keys from -71: blocks 0, 1; block 0: block 0
-    ((1, 2, 2, 384, 384, 64), 200, (3 * 6, 3 * 9)),
+    ((1, 2, 2, 384, 384, 64), 200, (3 * 6, 3 * 9), None),
     # window 50 < a block: each query block sees its own and the one before
-    ((1, 2, 2, 384, 384, 64), 50, (3 * 5, 3 * 9)),
+    ((1, 2, 2, 384, 384, 64), 50, (3 * 5, 3 * 9), None),
     # sq 128, sk 384 (offset 256), window 150: keys 107-383: blocks 0, 1, 2
-    ((1, 2, 2, 128, 384, 64), 150, (3 * 3, 3 * 3)),
+    ((1, 2, 2, 128, 384, 64), 150, (3 * 3, 3 * 3), None),
     # the cell's shape: 16 x 16 blocks of 512, window 2048: 1+2+3+4+12*5
-    ((1, 32, 4, 8192, 8192, 128), 2048, (3 * 70, 3 * 256)),
-    ((1, 32, 4, 8192, 8192, 128), 0, (3 * 136, 3 * 256)),
+    ((1, 32, 4, 8192, 8192, 128), 2048, (3 * 70, 3 * 256), None),
+    ((1, 32, 4, 8192, 8192, 128), 0, (3 * 136, 3 * 256), None),
     # Mellum's window layers: 1,024 keys are two blocks, so a query block
     # reads its own and the two before, three of the sixteen: 1+2+14*3
-    ((1, 32, 4, 8192, 8192, 128), 1024, (3 * 45, 3 * 256)),
+    ((1, 32, 4, 8192, 8192, 128), 1024, (3 * 45, 3 * 256), None),
+    # the forward at 1,024 x 1,024, each of its blocks four of the
+    # backward's: under the 2,048-key window a query block reads its own
+    # and the two before, 1+2+6*3 = 21 blocks; the backward pair as above
+    ((1, 32, 4, 8192, 8192, 128), 2048, (4 * 21 + 2 * 70, 3 * 256),
+     (1024, 1024)),
+    # full causal: 8 x 8 blocks, 36 of them
+    ((1, 32, 4, 8192, 8192, 128), 0, (4 * 36 + 2 * 136, 3 * 256),
+     (1024, 1024)),
+    # 1,024 keys: its own and the one before, 1+7*2
+    ((1, 32, 4, 8192, 8192, 128), 1024, (4 * 15 + 2 * 45, 3 * 256),
+     (1024, 1024)),
+    # the key block alone doubled under the 2,048-key window: query blocks
+    # 0 to 3 read 1, 1, 2, 2 blocks of 1,024 keys, the other twelve 3
+    ((1, 32, 4, 8192, 8192, 128), 2048, (2 * 42 + 2 * 70, 3 * 256),
+     (512, 1024)),
+    # the latent cells' call: 4 x 4 blocks, 10; the backward 8 x 8, 36
+    ((1, 32, 32, 4096, 4096, 192), 0, (4 * 10 + 2 * 36, 3 * 64),
+     (1024, 1024)),
+    # what the chooser gives s=1,024: one forward block over the
+    # backward's 2 x 2, of which causal visits 3
+    ((1, 2, 2, 1024, 1024, 64), 0, (4 * 1 + 2 * 3, 3 * 4), (1024, 1024)),
 ])
-def test_blocks_visited_against_a_count_by_hand(dims, window, by_hand):
+def test_blocks_visited_against_a_count_by_hand(dims, window, by_hand,
+                                                fwd_blocks):
     """`flash_blocks_visited` and `_total`: what a call's three grids
-    compute and the whole rectangles, a head; the counters take them
-    times the heads and the batch."""
+    compute and the whole rectangles, a head, in units of the backward's
+    block (score area: a forward block twice as wide and twice as tall
+    counts four); the counters take them times the heads and the batch."""
     from paddle_tpu import profiler
 
     b, h, _, sq, sk, d = dims
-    block = 512 if sq > 1024 else 128
+    block = 512 if sq >= 1024 else 128
     masks = fa._Masks(True, sk - sq, window, block, block, sq // block,
                       sk // block)
-    assert masks.visited() == by_hand
+    fwd = masks.at(*fwd_blocks) if fwd_blocks else None
+    assert masks.visited(fwd_blocks) == by_hand
     # the same count with the predicate asked of every block
-    first, last = fa._key_band(np.arange(masks.nq), masks, np)
-    qfirst, qlast = fa._query_band(np.arange(masks.nk), masks, np)
-    for j in range(masks.nq):
-        for kb in range(masks.nk):
-            qi = np.arange(j * block, (j + 1) * block)[:, None]
-            ki = np.arange(kb * block, (kb + 1) * block)[None, :]
-            keep = (ki <= qi + sk - sq)
-            if window:
-                keep &= qi + sk - sq - ki < window
-            assert keep.any() == (first[j] <= kb <= last[j]) == (
-                qfirst[kb] <= j <= qlast[kb]), (j, kb)
+    for m in filter(None, (masks, fwd)):
+        first, last = fa._key_band(np.arange(m.nq), m, np)
+        qfirst, qlast = fa._query_band(np.arange(m.nk), m, np)
+        for j in range(m.nq):
+            for kb in range(m.nk):
+                qi = np.arange(j * m.block_q, (j + 1) * m.block_q)[:, None]
+                ki = np.arange(kb * m.block_k, (kb + 1) * m.block_k)[None, :]
+                keep = (ki <= qi + sk - sq)
+                if window:
+                    keep &= qi + sk - sq - ki < window
+                assert keep.any() == (first[j] <= kb <= last[j]) == (
+                    qfirst[kb] <= j <= qlast[kb]), (j, kb)
     if sq > 1024:
         return
     c0 = profiler.counters()
     rng = np.random.RandomState(0)
     q, k, v, _ = _band_case(rng, *dims)
-    fa.flash_attention(q, k, v, causal=True, window=window, block_q=block,
-                       block_k=block)
+    # the chooser acts where no block is named: s=1,024 at the defaults
+    by_hand_blocks = {} if fwd else {"block_q": block, "block_k": block}
+    fa.flash_attention(q, k, v, causal=True, window=window, **by_hand_blocks)
     c1 = profiler.counters()
     assert (c1["flash_blocks_visited"] - c0.get("flash_blocks_visited", 0),
             c1["flash_blocks_total"] - c0.get("flash_blocks_total", 0)) == (
                 b * h * by_hand[0], b * h * by_hand[1])
+    assert (c1.get("flash_fwd_wide_key_calls", 0)
+            - c0.get("flash_fwd_wide_key_calls", 0)) == bool(fwd)
 
 
 # the cost each kernel declares (ops/pallas/cost.py has the convention):

@@ -355,17 +355,22 @@ def test_qk_prep_kernels_compile_for_a_v5e_chip_at_the_published_widths(
     ((1, 32, 32, 4096, 192, 128), 0, (256, 128)),
     # Trinity's window layer: one width, 32 query heads over 4
     ((1, 32, 4, 8192, 128, 128), 2048, (128, 128)),
-], ids=["latent_192_128", "gqa_window_128"])
+    # Mellum's: a band as wide as one of the forward's blocks
+    ((1, 32, 4, 8192, 128, 128), 1024, (128, 128)),
+], ids=["latent_192_128", "gqa_window_128", "gqa_window_1024"])
 def test_flash_kernels_compile_for_a_v5e_chip_at_the_published_widths(
         topo, shape, window, lanes):
     """The three blocked attention kernels at the shapes of the cells that
     run them, bf16, through `jax.vjp`: Mosaic takes q, k, dq and dk at the
     keys' lanes and v, the output, dO and dv at the values' (the shapes
-    the custom calls are held to in the compiled module). Nothing runs."""
+    the custom calls are held to in the compiled module), the forward at
+    the 1,024 x 1,024 blocks the call picks for itself (their VMEM fits
+    the chip's). Nothing runs."""
     import re
 
     from jax.sharding import SingleDeviceSharding
 
+    from paddle_tpu import profiler
     from paddle_tpu.ops.pallas import flash_attention
 
     chip = SingleDeviceSharding(topo.devices[0])
@@ -380,10 +385,12 @@ def test_flash_kernels_compile_for_a_v5e_chip_at_the_published_widths(
             *a, causal=True, window=window), q, k, v)
         return o, pull(o)
 
+    wide = profiler.counters().get("flash_fwd_wide_key_calls", 0)
     with _as_on_the_chip():
         text = jax.jit(both).lower(
             sds(b, h, s, d), sds(b, hkv, s, d), sds(b, hkv, s, dv)
         ).compile().as_text()
+    assert profiler.counters()["flash_fwd_wide_key_calls"] == wide + 1
     qs, ks = f"bf16[{b * h},{s},{d_p}]", f"bf16[{b * hkv},{s},{d_p}]"
     vs, outs = f"bf16[{b * hkv},{s},{dv_p}]", f"bf16[{b * h},{s},{dv_p}]"
     rows = f"f32[{b * h},1,{s}]"
@@ -401,6 +408,41 @@ def test_flash_kernels_compile_for_a_v5e_chip_at_the_published_widths(
             r"operand_layout_constraints=\{(.*?\})\}, ", text, re.M)
         assert shapes(read) == want, name
         assert shapes(written) == results[name], name
+
+
+@pytest.mark.parametrize("dtype,d_p,dv_p,blocks", [
+    (jnp.bfloat16, 256, 256, (1024, 1024)),
+    (jnp.bfloat16, 512, 512, (512, 1024)),
+    (jnp.float32, 256, 128, (512, 1024)),
+    (jnp.float32, 384, 256, (512, 1024)),
+], ids=["bf16_256_256", "bf16_512_512", "float32_256_128",
+        "float32_384_256"])
+def test_flash_fwd_fits_vmem_at_the_largest_blocks_the_chooser_gives(
+        topo, dtype, d_p, dv_p, blocks):
+    """`_fwd_blocks` bounds the forward's blocks by an estimate of the
+    step's VMEM: at the widths where the estimate lets the most through,
+    Mosaic takes the kernel with everything a call can add (a key bias,
+    dropout's hash of the block, no mask to skip a block by)."""
+    import importlib
+
+    from jax.sharding import SingleDeviceSharding
+
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    chip = SingleDeviceSharding(topo.devices[0])
+    s, heads = 2048, 4
+    assert fa._fwd_blocks(s, s, 512, 512, d_p, dv_p,
+                          jnp.dtype(dtype).itemsize) == blocks
+
+    def sds(*dims, dtype=dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    text = jax.jit(lambda q, k, v, bias, seed: fa._fwd_pallas(
+        q, k, v, bias, seed, heads, sm_scale=0.1, causal=False,
+        causal_offset=0, dropout=0.1, block_q=blocks[0], block_k=blocks[1])
+    ).lower(sds(heads, s, d_p), sds(heads, s, d_p), sds(heads, s, dv_p),
+            sds(1, 1, s, dtype=jnp.float32), sds(1, dtype=jnp.int32)
+            ).compile().as_text()
+    assert "flash_fwd" in text
 
 
 @contextlib.contextmanager
