@@ -210,7 +210,9 @@ def phase_kernels(exe, main, feed, loss_name, size, rehearse):
     q, k, v, w = (jnp.asarray(rng.randn(b, h, s, d), jnp.bfloat16)
                   for _ in range(4))
     bias = jnp.asarray(np.where(rng.rand(b, s) < 0.9, 0.0, -1e4), jnp.float32)
-    if not rehearse and fused_ops._flash_dispatch(q, k) != "flash":
+    if not rehearse and fused_ops.attention_path(
+            q.shape, k.shape, v.shape, layout="bhsd", causal=False, window=0,
+            group=1, mesh=None) != "flash":
         raise SystemExit(f"[kernels] FAIL: dispatch does not pick the flash "
                          f"kernel at s={s}")
     scale = 1.0 / math.sqrt(d)

@@ -1,12 +1,12 @@
 """Keyed accessor for checked-in tuning artifacts.
 
 The repo ships data files that steer backend-specific decisions at
-runtime — ops/pallas/attn_dispatch_table.json (attention kernel
-cutovers), the serving shape-bucket table, the shape-coverage ratchet.
+runtime — the serving shape-bucket table, the paged KV cache's page
+table, the model registry, the shape-coverage ratchet.
 A bare ``json.load`` answers *what does the file say* but never *which
 (backend, signature) asked*, so when a deploy drifts from the artifact
-(table tuned on v5e, serving on CPU; bucket table tuned for one feed
-set, serving another) nothing observes the mismatch.
+(bucket table tuned for one feed set, serving another) nothing observes
+the mismatch.
 
 ``load_artifact`` is the one sanctioned loader (enforced by the
 provlint ``no-unkeyed-artifact-lookup`` rule): every load records the
@@ -14,8 +14,7 @@ artifact's content hash plus the caller's (backend, signature) key in a
 process-global registry and the profiler counters, so /healthz-style
 observers and tests can assert which artifact content actually fed
 which backend. Fallback behavior stays with the caller: pass
-``default=`` to never raise (dispatch tables must not crash a training
-step over a data file), omit it to propagate errors (serving refuses to
+``default=`` to never raise, omit it to propagate errors (serving refuses to
 start on a corrupt bucket table).
 """
 

@@ -127,7 +127,10 @@ class ZeroCopyTensor:
         self._value = None  # jax.Array on device
 
     def copy_from_cpu(self, arr):
-        self._value = jnp.asarray(arr)
+        # a copy, as the name says: jnp.asarray aliases an aligned numpy
+        # buffer on the CPU backend, and the caller may refill `arr` for
+        # its next batch while a zero_copy_run() is still in flight
+        self._value = jnp.array(arr, copy=True)
 
     def copy_to_cpu(self):
         v = self._pred._outputs.get(self.name, self._value)

@@ -254,10 +254,14 @@ class DecodeStepBatcher:
         # slot must interleave BETWEEN steps, never mid-donation
         with c._array_lock:
             mask = c.active_mask()
+            # `lengths` goes in as a copy: on the CPU backend jnp.asarray
+            # of an aligned numpy array aliases its buffer, the dispatch is
+            # asynchronous, and the increment below would then reach a
+            # step still in flight (one ring position too many admitted)
             out, k_new, v_new = self._fn(
                 jnp.asarray(np.asarray(tokens)),
                 c.k, c.v,
-                jnp.asarray(c.lengths),
+                jnp.array(c.lengths, copy=True),
                 jnp.asarray(mask),
             )
             c.k, c.v = k_new, v_new
@@ -576,11 +580,15 @@ class PagedDecodeStepBatcher:
         with c._array_lock:
             m = (c.active_mask() if mask is None
                  else np.asarray(mask, bool))
+            # copies, as in DecodeStepBatcher.step: the host mirrors are
+            # written (the increment below, an acquire or a release on
+            # another thread) while the step that aliased them may still
+            # be in flight
             out, k_new, v_new = self._fn(
                 jnp.asarray(np.asarray(tokens)),
                 c.k, c.v,
-                jnp.asarray(c.page_table),
-                jnp.asarray(c.lengths),
+                jnp.array(c.page_table, copy=True),
+                jnp.array(c.lengths, copy=True),
                 jnp.asarray(m),
             )
             c.k, c.v = k_new, v_new

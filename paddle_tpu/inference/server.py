@@ -35,8 +35,7 @@ window, buckets them by their per-feed non-batch shapes, merges each
 bucket into ONE padded batched predictor dispatch (pad rows join the
 dispatch, never a reply), and fans the per-request row slices back out
 on each request's own connection. Padded shapes come from the
-checked-in bucket table (`bucket_table.json` next to this module, the
-serving analog of ops/pallas/attn_dispatch_table.json), so the
+checked-in bucket table (`bucket_table.json` next to this module), so the
 executor's shape-keyed compile cache holds one warm executable per
 bucket instead of one per client batch size. Deadline interaction is
 strict: a request whose remaining X-Deadline-Ms budget cannot afford

@@ -31,7 +31,6 @@ from .pipeline import (  # noqa: F401
     gpipe,
     stack_stage_params,
 )
-from .ulysses import ulysses_attention  # noqa: F401
 from .moe import (  # noqa: F401
     init_moe_params,
     moe_ffn,

@@ -3,7 +3,7 @@ parallelism flavor in the tree.
 
 This is the GSPMD-native substrate that replaced the legacy
 ``shard-map``/``p-map`` layer (removed from modern JAX): every multi-device
-path — data parallel, tensor parallel, sequence parallel (Ulysses/ring),
+path — data parallel, tensor parallel, sequence parallel (ring),
 expert parallel, pipeline microbatching, ZeRO-1 optimizer-state sharding —
 is expressed as a PartitionSpec assignment over ONE mesh and compiled with
 plain ``jax.jit(..., in_shardings=..., out_shardings=...,
@@ -16,7 +16,7 @@ Axis contract:
   all-reduce over this axis is GSPMD-inserted. ZeRO-1 shards optimizer
   accumulators along it.
 - ``model``  — everything intra-layer: Megatron column/row tensor
-  parallelism, Ulysses/ring sequence parallelism (sequence or head dims),
+  parallelism, ring sequence parallelism (the sequence dim),
   MoE expert sharding. One axis, one vocabulary — the search space the
   auto-placement pass (ROADMAP) optimizes over.
 - ``pipe``   — pipeline stages: the microbatch schedule runs along it and

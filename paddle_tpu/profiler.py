@@ -132,7 +132,7 @@ def set_counter(name: str, value: int) -> int:
     dispatch counters (transpose_ops_before / transpose_ops_after as
     gauges = the traced step's activation-transpose count under NCHW IR
     vs after the pass, most recent compile: their difference is what
-    layout_opt removed; attn_dispatch_xla / _flash / _ring / _ulysses
+    layout_opt removed; attn_dispatch_short / _xla / _flash / _ring
     via bump = attention path chosen at trace time, fwd + grad replay
     each count; pallas_on_mesh_calls via bump = lowerings that run
     their Pallas kernel per shard of a data-parallel mesh

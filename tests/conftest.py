@@ -63,3 +63,18 @@ def fresh_programs():
 @pytest.fixture
 def rng():
     return np.random.RandomState(42)
+
+
+@pytest.fixture
+def attn_path(monkeypatch):
+    """`attn_path("flash")`: until the test ends, every attention lowering
+    takes the named path whatever `fused_ops.attention_path` would choose
+    from the call. How a test reaches a kernel below its threshold; a
+    kernel this backend cannot run still raises where it is called."""
+    from paddle_tpu.ops import fused_ops
+
+    def force(path):
+        monkeypatch.setattr(fused_ops, "attention_path",
+                            lambda *shapes, **call: path)
+
+    return force

@@ -106,7 +106,6 @@ def _bert_steps(monkeypatch, places, zero1=False, steps=3):
     from paddle_tpu.models.bert import BertConfig, build_bert_pretrain
 
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    monkeypatch.delenv("PADDLE_TPU_ATTN_DISPATCH", raising=False)
     cfg = BertConfig(vocab_size=128, hidden_size=128, num_layers=2,
                      num_heads=2, intermediate_size=256, max_position=64,
                      hidden_dropout=0.0, attention_dropout=0.0)

@@ -1027,15 +1027,14 @@ def _run_attn_program(rng, shape, layout="bshd", compiled=False, **kw):
     ((2, 16, 16, 2, 128), True),    # dh = 128
 ])
 def test_dispatch_takes_the_short_kernel_for_the_models_shapes(
-        shape, causal, monkeypatch):
+        shape, causal, attn_path):
     """One device, "bshd", dh 64 or 128, short rows: the Program's op
     lowers to the kernel, bumps `attn_dispatch_short`, and gives what
     XLA's lowering gives, gradients included."""
-    monkeypatch.delenv("PADDLE_TPU_ATTN_DISPATCH", raising=False)
     got, paths = _run_attn_program(np.random.RandomState(3), shape,
                                    causal=causal)
     assert paths == {"short"}
-    monkeypatch.setenv("PADDLE_TPU_ATTN_DISPATCH", "xla")
+    attn_path("xla")
     want, paths = _run_attn_program(np.random.RandomState(3), shape,
                                     causal=causal)
     assert paths == {"xla"}
@@ -1044,12 +1043,11 @@ def test_dispatch_takes_the_short_kernel_for_the_models_shapes(
 
 
 @pytest.mark.parametrize("case", ["bhsd", "mesh", "above_the_bound",
-                                  "head_dim_32", "dispatch_xla"])
+                                  "head_dim_32", "no_pallas"])
 def test_dispatch_leaves_xla_what_the_short_kernel_is_not_built_for(
         case, monkeypatch):
     from paddle_tpu.ops.pallas.mha_short import MAX_SHORT_SEQ
 
-    monkeypatch.delenv("PADDLE_TPU_ATTN_DISPATCH", raising=False)
     shape, kw = (2, 32, 32, 2, 64), {}
     if case == "bhsd":
         kw["layout"] = "bhsd"
@@ -1059,17 +1057,16 @@ def test_dispatch_leaves_xla_what_the_short_kernel_is_not_built_for(
         shape = (1, 16, MAX_SHORT_SEQ + 16, 2, 64)
     elif case == "head_dim_32":
         shape = (2, 32, 32, 4, 32)
-    else:
-        monkeypatch.setenv("PADDLE_TPU_ATTN_DISPATCH", "xla")
+    else:  # the CPU as it is: no interpreter, so no Pallas kernel runs
+        monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET")
     vals, paths = _run_attn_program(np.random.RandomState(4), shape, **kw)
     assert paths == {"xla"}
     assert all(np.isfinite(v).all() for v in vals)
 
 
-def test_short_kernel_dropout_trains_through_the_program(monkeypatch):
+def test_short_kernel_dropout_trains_through_the_program():
     """Dropout on, through the executor: the op's rng seeds the kernel,
     the same step twice from one seed agrees, and the rate is applied."""
-    monkeypatch.delenv("PADDLE_TPU_ATTN_DISPATCH", raising=False)
     shape = (2, 32, 32, 2, 64)
     a, paths = _run_attn_program(np.random.RandomState(5), shape, dropout=0.5)
     assert paths == {"short"}
@@ -1080,67 +1077,146 @@ def test_short_kernel_dropout_trains_through_the_program(monkeypatch):
     assert all(np.isfinite(v).all() for v in a)
 
 
-# -------------------------------------------- dispatch table (round 12)
+# ------------------------------------------------- the chooser, as a table
+
+# (q, k, v shapes in the layout; layout; causal; window; group;
+#  mesh as (batch, model) or None; Pallas runs; path)
+_DEFAULTS = dict(layout="bshd", causal=False, window=0, group=1, mesh=None,
+             pallas=True)
 
 
-def test_dispatch_table_loads_with_thresholds():
+def _row(name, path, q, k=None, v=None, **kw):
+    k = q if k is None else k
+    return pytest.param(q, k, k if v is None else v, {**_DEFAULTS, **kw}, path,
+                        id=name)
+
+
+_LONG = (1, 4096, 32, 192)  # one document, 32 heads, keys of 192 lanes
+_GQA_Q, _GQA_KV = (1, 8192, 32, 128), (1, 8192, 4, 128)
+
+
+@pytest.mark.parametrize("q,k,v,call,want", [
+    # the benchmark's cells (resnet50_b128 lowers no attention), with the
+    # path the ledger's per-layer lines show for each
+    _row("bert_base_s128", "short", (256, 128, 12, 64)),
+    _row("bert_base_s512", "short", (48, 512, 12, 64)),
+    _row("bert_base_s128_dp4", "short", (1024, 128, 12, 64), mesh=(4, 1)),
+    _row("transformer_base_s64_self", "short", (256, 64, 8, 64)),
+    _row("transformer_base_s64_causal", "short", (256, 64, 8, 64),
+         causal=True),
+    _row("kimi_linear_ep32_s4096", "flash", _LONG, v=(1, 4096, 32, 128),
+         causal=True),
+    _row("joyai_flash_ep32_s4096", "flash", _LONG, v=(1, 4096, 32, 128),
+         causal=True),
+    _row("trinity_mini_ep16_s8192_window", "flash", _GQA_Q, _GQA_KV,
+         causal=True, window=2048, group=8),
+    _row("trinity_mini_ep16_s8192_full", "flash", _GQA_Q, _GQA_KV,
+         causal=True, group=8),
+    _row("mellum2_ep4_s8192_window", "flash", _GQA_Q, _GQA_KV,
+         causal=True, window=1024, group=8),
+    # FLASH_MIN_SEQ: both lengths at it, one below it
+    _row("at_flash_min_seq", "flash", (1, 2048, 1, 64)),
+    _row("below_flash_min_seq", "xla", (1, 2047, 1, 64)),
+    _row("keys_below_flash_min_seq", "xla", (1, 2048, 1, 64),
+         (1, 1024, 1, 64)),
+    _row("at_flash_min_seq_bhsd", "flash", (1, 1, 2048, 64), layout="bhsd"),
+    _row("at_flash_min_seq_no_pallas", "xla", (1, 2048, 1, 64),
+         pallas=False),
+    # FLASH_MIN_SCORE_BYTES: 4,096 rows of 12 heads of 128 x 128 float32
+    # scores are 3 GiB; 2,730 rows fall just under 2 GiB, 2,731 just over
+    _row("score_bytes_above", "flash", (4096, 128, 12, 64)),
+    _row("score_bytes_just_above", "flash", (2731, 128, 12, 64)),
+    _row("score_bytes_just_below", "short", (2730, 128, 12, 64)),
+    _row("score_bytes_above_per_shard", "xla", (16384, 128, 12, 64),
+         mesh=(4, 1)),
+    _row("score_bytes_below_per_shard", "short", (4096, 128, 12, 64),
+         mesh=(4, 1)),
+    # the short kernel's own bounds, and what makes a call not plain
+    _row("at_max_short_seq", "short", (2, 512, 2, 64)),
+    _row("above_max_short_seq", "xla", (2, 528, 2, 64)),
+    _row("keys_above_max_short_seq", "xla", (2, 16, 2, 64), (2, 528, 2, 64)),
+    _row("short_bhsd", "xla", (2, 2, 64, 64), layout="bhsd"),
+    _row("head_dim_32", "xla", (2, 64, 4, 32)),
+    _row("head_dim_128", "short", (2, 64, 2, 128)),
+    _row("values_narrower", "xla", (2, 64, 2, 128), v=(2, 64, 2, 64)),
+    _row("grouped_heads", "xla", (2, 64, 4, 64), (2, 64, 2, 64), group=2),
+    _row("window", "xla", (2, 64, 2, 64), causal=True, window=16),
+    _row("short_no_pallas", "xla", (2, 64, 2, 64), pallas=False),
+    # meshes: only `batch` alone, dividing the batch, runs a kernel
+    _row("batch_undivided", "xla", (6, 64, 2, 64), mesh=(4, 1)),
+    _row("model_axis_2_short_seq", "xla", (8, 64, 2, 64), mesh=(2, 2)),
+    _row("model_axis_2_at_flash_min_seq", "xla", (2, 2048, 2, 64),
+         mesh=(1, 2)),
+    # RING_MIN_SEQ on a `model` axis of 2
+    _row("ring_at_min_seq", "ring", (2, 4096, 2, 64), mesh=(1, 2)),
+    _row("ring_below_min_seq", "xla", (2, 4094, 2, 64), mesh=(1, 2)),
+    _row("ring_no_pallas", "ring", (2, 4096, 2, 64), mesh=(2, 2),
+         pallas=False),
+    _row("ring_seq_undivided", "xla", (2, 4097, 2, 64), mesh=(1, 2)),
+    _row("ring_keys_undivided", "xla", (2, 4096, 2, 64), (2, 4097, 2, 64),
+         mesh=(1, 2)),
+    _row("ring_needs_a_model_axis", "flash", (1, 4096, 2, 64)),
+    _row("long_on_a_batch_mesh", "xla", (4, 4096, 2, 64), mesh=(4, 1)),
+])
+def test_attention_path_decision_table(q, k, v, call, want, monkeypatch):
+    """`fused_ops.attention_path` row by row: the shape of each benchmark
+    cell's attention calls with the path the ledger shows for it, and both
+    sides of every threshold and of every kernel's own bounds."""
+    from paddle_tpu.ops import fused_ops
+    from paddle_tpu.parallel.mesh import build_mesh
+
+    call = dict(call)
+    pallas = call.pop("pallas")
+    module = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(module, "_use_pallas", lambda: pallas)
+    if call["mesh"]:
+        batch, model = call["mesh"]
+        call["mesh"] = build_mesh(batch=batch, model=model,
+                                  devices=jax.devices()[:batch * model])
+    assert fused_ops.attention_path(q, k, v, **call) == want
+
+
+def test_dispatch_seq_floor_defaults_flash_on():
+    # at FLASH_MIN_SEQ the blocked kernel is chosen even when the score
+    # tensor is small (tiny batch); the interpreter counts as a backend
+    # that runs Pallas
     from paddle_tpu.ops import fused_ops
 
-    t = fused_ops.attn_dispatch_thresholds()
-    assert t["flash_min_score_bytes"] > 0
-    assert t["flash_min_seq"] > 0
-    assert t["ring_min_seq"] >= t["flash_min_seq"]
+    def path(s):
+        shape = (1, 1, s, 64)
+        return fused_ops.attention_path(
+            shape, shape, shape, layout="bhsd", causal=False, window=0,
+            group=1, mesh=None)
 
-
-def test_dispatch_seq_floor_defaults_flash_on(monkeypatch):
-    # above the table's flash_min_seq the Pallas path is the DEFAULT
-    # even when the score tensor is small (tiny batch)
-    from paddle_tpu.ops import fused_ops
-
-    monkeypatch.delenv("PADDLE_TPU_FLASH_SCORE_BYTES", raising=False)
-    monkeypatch.delenv("PADDLE_TPU_ATTN_DISPATCH", raising=False)
-    s = int(fused_ops.attn_dispatch_thresholds()["flash_min_seq"])
-    q = jnp.zeros((1, 1, s, 64))
-    k = jnp.zeros((1, 1, s, 64))
-    assert fused_ops._use_flash(q, k)
-    assert not fused_ops._use_flash(q[:, :, : s // 2], k[:, :, : s // 2])
-    # interpret mode counts as a Pallas backend -> flash chosen
-    assert fused_ops._flash_dispatch(q, k) == "flash"
-
-
-def test_dispatch_score_bytes_env_is_a_force(monkeypatch):
-    # the longseq study pins paths via PADDLE_TPU_FLASH_SCORE_BYTES:
-    # a huge value must force XLA even above the seq floor
-    from paddle_tpu.ops import fused_ops
-
-    monkeypatch.setenv("PADDLE_TPU_FLASH_SCORE_BYTES", str(1 << 62))
-    s = int(fused_ops.attn_dispatch_thresholds()["flash_min_seq"])
-    q = jnp.zeros((1, 1, s, 64))
-    assert not fused_ops._use_flash(q, q)
-    monkeypatch.setenv("PADDLE_TPU_FLASH_SCORE_BYTES", "0")
-    assert fused_ops._use_flash(q[:, :, :8], q[:, :, :8])
+    assert path(fused_ops.FLASH_MIN_SEQ) == "flash"
+    assert path(fused_ops.FLASH_MIN_SEQ // 2) == "xla"
 
 
 def test_dispatch_never_swaps_a_kernel_that_was_asked_for(monkeypatch):
     from paddle_tpu.ops import fused_ops
 
-    # no interpreter, no TPU: `auto` observes that no Pallas kernel can
-    # run here and picks XLA; `flash` asks for the kernel by name, and the
-    # kernel raises rather than run other math in its place
+    # no interpreter, no TPU: the chooser observes that no Pallas kernel
+    # can run here and picks XLA; a kernel called by name raises rather
+    # than run other math in its place
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
-    monkeypatch.delenv("PADDLE_TPU_ATTN_DISPATCH", raising=False)
-    s = int(fused_ops.attn_dispatch_thresholds()["flash_min_seq"])
-    long_q = jnp.zeros((1, 1, s, 64))
-    assert fused_ops._flash_dispatch(long_q, long_q) == "xla"
-    monkeypatch.setenv("PADDLE_TPU_ATTN_DISPATCH", "flash")
+    long = (1, 1, fused_ops.FLASH_MIN_SEQ, 64)
+    assert fused_ops.attention_path(
+        long, long, long, layout="bhsd", causal=False, window=0, group=1,
+        mesh=None) == "xla"
     q = jnp.zeros((1, 1, 16, 64))
-    assert fused_ops._flash_dispatch(q, q) == "flash"
     with pytest.raises(RuntimeError, match="'cpu' backend"):
         fa.flash_attention(q, q, q)
-    # env validation is strict
-    monkeypatch.setenv("PADDLE_TPU_ATTN_DISPATCH", "nope")
-    with pytest.raises(ValueError, match="PADDLE_TPU_ATTN_DISPATCH"):
-        fused_ops._flash_dispatch(q, q)
+
+
+def test_forced_path_on_a_backend_without_pallas_raises(monkeypatch,
+                                                        attn_path):
+    """The tests' way to a named path patches the chooser only: the
+    lowering then calls the kernel, and the kernel still refuses a backend
+    that cannot compile it."""
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET")
+    attn_path("flash")
+    with pytest.raises(RuntimeError, match="'cpu' backend"):
+        _run_attn_program(np.random.RandomState(6), (2, 32, 32, 2, 64))
 
 
 def test_dispatch_counters_bump(rng):
@@ -1174,56 +1250,4 @@ def test_dispatch_counters_bump(rng):
     exe.run(feed=feed, fetch_list=[o])
     c = profiler.counters()
     assert sum(c.get(f"attn_dispatch_{p}", 0)
-               for p in ("xla", "flash", "ring", "ulysses")) > 0
-
-
-def test_longseq_table_merges_partial_sessions_with_provenance(tmp_path):
-    """Round 20: `longseq_study.py table` folds partial/merged sweep
-    JSONLs (multiple chip sessions concatenated) and records the
-    regeneration through the keyed artifacts accessor."""
-    import json
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    from tools.longseq_study import emit_table
-
-    from paddle_tpu.analysis import artifacts
-
-    def row(s, mode, ms):
-        return json.dumps({"s": s, "mode": mode, "ms_step": ms, "b": 64})
-
-    # session 1 died mid-sweep: s=512 complete, s=1024 only has its xla
-    # half
-    sess1 = tmp_path / "sweep_r1.jsonl"
-    sess1.write_text("\n".join([
-        row(512, "xla", 10.0), row(512, "flash", 12.0),
-        row(1024, "xla", 30.0),
-    ]) + "\n")
-    out = tmp_path / "table.json"
-    emit_table([str(sess1)], str(out))
-    t = json.loads(out.read_text())
-    assert [r["s"] for r in t["measured"]] == [512]  # unmatched half waits
-    assert t["measured"][0]["winner"] == "xla"
-    assert "flash_min_seq" not in t.get("thresholds", {})
-
-    # session 2 (a later chip session, concatenated file): retries the
-    # 1024 xla half (the retry supersedes) and adds flash + s=2048
-    sess2 = tmp_path / "sweep_r2.jsonl"
-    sess2.write_text("\n".join([
-        row(1024, "xla", 31.0), row(1024, "flash", 25.0),
-        row(2048, "xla", 90.0), row(2048, "flash", 50.0),
-    ]) + "\n")
-    artifacts.reset_records()
-    emit_table([str(sess1), str(sess2)], str(out))
-    t = json.loads(out.read_text())
-    # previously measured s=512 persisted, new rows merged in order
-    assert [r["s"] for r in t["measured"]] == [512, 1024, 2048]
-    assert t["measured"][1]["xla_ms_step"] == 31.0  # last row wins
-    assert t["thresholds"]["flash_min_seq"] == 1024
-    assert t["provenance"]["sources"] == ["sweep_r1.jsonl", "sweep_r2.jsonl"]
-    assert t["provenance"]["last_regen"] == "regen:sweep_r1.jsonl+sweep_r2.jsonl"
-    # the regeneration went through the keyed accessor
-    recs = artifacts.records()
-    (rec,) = [r for k, r in recs.items() if k.startswith("table.json@")]
-    assert rec["last_signature"] == "regen:sweep_r1.jsonl+sweep_r2.jsonl"
+               for p in ("short", "xla", "flash", "ring")) > 0

@@ -69,6 +69,22 @@ def test_predictor_zero_copy_api(tmp_path):
     assert not np.allclose(out2, ref)
 
 
+def test_zero_copy_input_is_a_copy_of_the_callers_buffer(tmp_path):
+    """`copy_from_cpu` keeps what the buffer held when it was called: a
+    caller refills its array for the next batch while a run may still be
+    in flight, and on the CPU backend jnp.asarray of an aligned numpy
+    array shares its memory (several arrays, kept alive, so that one of
+    them is aligned)."""
+    d, xv, _ = _train_and_export(tmp_path)
+    predictor = create_paddle_predictor(AnalysisConfig(model_dir=d))
+    inp = predictor.get_input_handle("x")
+    buffers = [xv.copy() for _ in range(16)]
+    for buf in buffers:
+        inp.copy_from_cpu(buf)
+        buf += 1.0
+        np.testing.assert_array_equal(np.asarray(inp._value), xv)
+
+
 def test_predictor_dict_api_and_clone(tmp_path):
     d, xv, ref = _train_and_export(tmp_path)
     predictor = create_paddle_predictor(AnalysisConfig(model_dir=d))

@@ -277,12 +277,12 @@ def test_program_mixer_equals_reference(which):
     assert rel(got, want) < 2e-5
 
 
-def test_latent_attention_through_the_flash_kernel(monkeypatch):
+def test_latent_attention_through_the_flash_kernel(monkeypatch, attn_path):
     """The blocked kernel, interpreted, at the published head widths
     (192-wide keys, 128-wide values, padded inside), forced by name since
     the CPU's dispatch never chooses it."""
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    monkeypatch.setenv("PADDLE_TPU_ATTN_DISPATCH", "flash")
+    attn_path("flash")
     from paddle_tpu import profiler
 
     model, _ = cell(qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,

@@ -182,11 +182,11 @@ def test_program_mixer_equals_reference(which):
     assert rel(got, want) < 2e-5
 
 
-def test_latent_attention_through_the_flash_kernel(monkeypatch):
+def test_latent_attention_through_the_flash_kernel(monkeypatch, attn_path):
     """The blocked kernel, interpreted, with values narrower than the
     keys: forced by name, since the CPU's dispatch never chooses it."""
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    monkeypatch.setenv("PADDLE_TPU_ATTN_DISPATCH", "flash")
+    attn_path("flash")
     from paddle_tpu import profiler
 
     before = profiler.counters().get("attn_dispatch_flash", 0)

@@ -598,3 +598,47 @@ def test_paged_pool_shared_across_models_bitwise_and_accounting():
     np.testing.assert_array_equal(toks["a0"][0], toks["a0"][0])
     assert not np.array_equal(outs["a0"][0], outs["b0"][0]) or \
         toks["a0"][0] != toks["b0"][0]
+
+
+@pytest.mark.parametrize("kind", ["ring", "paged"])
+def test_step_hands_the_compiled_step_copies_of_the_host_mirrors(kind):
+    """`lengths` (and the paged cache's `page_table`) are numpy mirrors
+    the batcher writes right after it dispatches the step, and on the CPU
+    backend jnp.asarray of an aligned numpy array shares its buffer: what
+    the step was given must still read as it did at dispatch once step()
+    has returned and the cache has moved on. Whether one array aliases
+    depends on how numpy aligned it, so sixteen caches are tried, all kept
+    alive so that no two have their mirrors at one address."""
+    from paddle_tpu.inference.kv_cache import PagedDecodeStepBatcher
+
+    def step_fn(tokens, k, v, lengths, active):
+        return tokens, k, v
+
+    caches = []
+    for _ in range(16):
+        if kind == "ring":
+            cache = RingKVCache(num_slots=3, max_len=8, num_heads=HEADS,
+                                head_dim=DIM)
+            batcher = DecodeStepBatcher(cache, step_fn)
+            slot = cache.acquire("a")
+        else:
+            cache = _paged()
+            batcher = PagedDecodeStepBatcher(cache, step_fn)
+            slot = cache.acquire("a", total_len=8)
+        caches.append(cache)
+        kept = {}
+
+        def recording(tokens, k, v, *mirrors, kept=kept):
+            kept["mirrors"] = mirrors[:-1]  # the last one is the mask
+            return tokens, k, v
+
+        batcher._fn = recording
+        before = [cache.lengths.copy()]
+        if kind == "paged":
+            before.insert(0, cache.page_table.copy())
+        batcher.step(np.zeros((len(cache.lengths),), np.int32))
+        assert cache.lengths[slot] == before[-1][slot] + 1
+        cache.release(slot)  # the paged cache clears the table's row
+        assert len(kept["mirrors"]) == len(before)
+        for given, was in zip(kept["mirrors"], before):
+            np.testing.assert_array_equal(np.asarray(given), was)

@@ -295,13 +295,14 @@ def test_program_mixer_equals_reference(which):
 
 
 @pytest.mark.parametrize("which", ["window", "full"])
-def test_attention_through_the_flash_and_qk_prep_kernels(which, monkeypatch):
+def test_attention_through_the_flash_and_qk_prep_kernels(which, monkeypatch,
+                                                         attn_path):
     """The blocked kernel and the kernel pair before it, interpreted, at a
     head of 128 lanes over two key/value heads, forced by name since the
     CPU's dispatch never chooses them: the full layer's kernels take
     YaRN's tables as the window layer's take the plain ones."""
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    monkeypatch.setenv("PADDLE_TPU_ATTN_DISPATCH", "flash")
+    attn_path("flash")
     from paddle_tpu import profiler
 
     before = profiler.counters()

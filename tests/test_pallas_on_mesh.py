@@ -27,8 +27,6 @@ KEY = jax.random.key(0)
 @pytest.fixture(autouse=True)
 def _interpret_mode(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    monkeypatch.delenv("PADDLE_TPU_ATTN_DISPATCH", raising=False)
-    monkeypatch.delenv("PADDLE_TPU_SP_MODE", raising=False)
 
 
 def _mesh(batch=4, model=1):
@@ -211,18 +209,17 @@ def test_the_op_on_a_batch_mesh_takes_the_kernel_per_shard():
         np.testing.assert_allclose(a, b_, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("case", ["model_2", "batch_undivided", "ulysses",
-                                  "ring"])
-def test_every_other_mesh_keeps_its_path(case, monkeypatch):
+@pytest.mark.parametrize("case", ["model_2", "batch_undivided", "ring"])
+def test_every_other_mesh_keeps_its_path(case, attn_path):
     """Tensor parallelism beside the batch axis, a batch of 6 on four
-    shards, and both sequence-parallel modes: the counters of before,
-    and no per-shard call."""
+    shards, and ring sequence parallelism (below its threshold, so by
+    name): the counters of before, and no per-shard call."""
     b, mesh, want = 8, _mesh(2, 2), "attn_dispatch_xla"
     if case == "batch_undivided":
         b, mesh = 6, _mesh()
-    elif case != "model_2":
-        monkeypatch.setenv("PADDLE_TPU_SP_MODE", case)
-        want = f"attn_dispatch_{case}"
+    elif case == "ring":
+        attn_path("ring")
+        want = "attn_dispatch_ring"
     vals, seen = _run_attn_program(b, mesh)
     assert set(seen) == {want}
     assert all(np.isfinite(v).all() for v in vals)

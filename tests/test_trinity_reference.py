@@ -192,12 +192,12 @@ def test_program_mixer_equals_reference(which):
 
 
 @pytest.mark.parametrize("which", ["window", "full"])
-def test_attention_through_the_flash_kernel(which, monkeypatch):
+def test_attention_through_the_flash_kernel(which, monkeypatch, attn_path):
     """The blocked kernel, interpreted, over two key/value heads with a
     window that is no multiple of anything: forced by name, since the
     CPU's dispatch never chooses it."""
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    monkeypatch.setenv("PADDLE_TPU_ATTN_DISPATCH", "flash")
+    attn_path("flash")
     from paddle_tpu import profiler
 
     before = profiler.counters()
@@ -384,7 +384,8 @@ def _loss_and_gradients(model, traffic, seed=3):
 
 @pytest.mark.parametrize("kernels", [True, False],
                          ids=["flash_interpreted", "plain_path"])
-def test_fused_qk_prep_is_the_separate_ops_model(kernels, monkeypatch):
+def test_fused_qk_prep_is_the_separate_ops_model(kernels, monkeypatch,
+                                                 attn_path):
     """One window layer and one full layer at a head of 128 lanes: the
     model whose attention op norms and rotates q and k gives the loss and
     every parameter's gradient of the model built from `rms_norm` and
@@ -395,7 +396,7 @@ def test_fused_qk_prep_is_the_separate_ops_model(kernels, monkeypatch):
 
     if kernels:
         monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-        monkeypatch.setenv("PADDLE_TPU_ATTN_DISPATCH", "flash")
+        attn_path("flash")
     model, traffic = cell(precision="float32", head_dim=128,
                           num_hidden_layers=2, first_layer_held=2,
                           router_bias_scale=0.02)

@@ -598,8 +598,8 @@ class ThreadSharedWriteUnguarded(Rule):
 
 
 class NoUnkeyedArtifactLookup(Rule):
-    """Checked-in tuning artifacts (attn_dispatch_table.json,
-    bucket_table.json, shape_coverage.json, kv_page_table.json,
+    """Checked-in tuning artifacts (bucket_table.json,
+    shape_coverage.json, kv_page_table.json,
     model_registry.json) feed backend-specific
     decisions: a bare json.load answers 'what does the file say' but
     not 'which (backend, signature) asked', so drift between the
@@ -611,9 +611,8 @@ class NoUnkeyedArtifactLookup(Rule):
     doc = ("tuning-artifact json loads must go through "
            "analysis/artifacts.load_artifact (records backend+signature)")
     scope = ("paddle_tpu/",)
-    _ARTIFACTS = ("attn_dispatch_table.json", "bucket_table.json",
-                  "shape_coverage.json", "kv_page_table.json",
-                  "model_registry.json")
+    _ARTIFACTS = ("bucket_table.json", "shape_coverage.json",
+                  "kv_page_table.json", "model_registry.json")
 
     def _artifact_consts(self, tree):
         """Module-level names bound to strings mentioning an artifact."""
