@@ -74,6 +74,7 @@ __all__ = [
     "moe_experts",
     "rms_norm",
     "short_conv1d",
+    "selective_scan",
     "kda_attention",
     "embedding",
     "conv2d",
@@ -708,16 +709,46 @@ def rotary_embedding(input, theta=10000.0, name=None, rope_scaling=None,
         dtype=input.dtype, shape=input.shape)
 
 
-def short_conv1d(input, width=4, param_attr=None, name=None):
+def short_conv1d(input, width=4, param_attr=None, name=None, bias_attr=None):
     """Causal depthwise convolution over time, zero state at the start of
-    a sequence, no bias, then SiLU: input [b, s, c], filter [c, width],
-    `out_t = SiLU(sum_i filter[:, i] * input_{t-width+1+i})`."""
+    a sequence, then SiLU: input [b, s, c], filter [c, width],
+    `out_t = SiLU(sum_i filter[:, i] * input_{t-width+1+i} + bias)`. No
+    bias unless `bias_attr`, a `ParamAttr`, is given: a [c] parameter
+    inside the SiLU, seeded 0 unless the attribute says otherwise."""
     helper = LayerHelper("short_conv1d", name=name)
-    w = helper.create_parameter(
-        param_attr, [int(input.shape[-1]), width], dtype="float32")
-    return _single_out(
-        helper, "short_conv1d", {"X": [input], "Filter": [w]}, {},
-        dtype=input.dtype, shape=input.shape)
+    channels = int(input.shape[-1])
+    w = helper.create_parameter(param_attr, [channels, width],
+                                dtype="float32")
+    inputs = {"X": [input], "Filter": [w]}
+    if bias_attr:
+        inputs["Bias"] = [helper.create_parameter(
+            bias_attr, [channels], dtype="float32", is_bias=True)]
+    return _single_out(helper, "short_conv1d", inputs, {},
+                       dtype=input.dtype, shape=input.shape)
+
+
+def selective_scan(x, delta, a, b, c, d, name=None):
+    """Mamba-1's selective scan over one sequence a row
+    (ops/ssm_ops.py has the equations): `x` and the step sizes `delta`
+    (after their softplus) [b, s, d_inner], `a` [d_inner, d_state]
+    (negative), `b` and `c` [b, s, d_state], `d` [d_inner] the skip's
+    weight. float32 inside the op, whatever the inputs arrive in; the
+    state is zero at the start of a row. Returns y [b, s, d_inner] in
+    `x`'s dtype."""
+    from ..ops.ssm_ops import n_chunks
+
+    helper = LayerHelper("selective_scan", name=name)
+    y = helper.create_variable_for_type_inference(x.dtype, x.shape)
+    # the states the chunks start from: what the gradient op keeps
+    starts = helper.create_variable_for_type_inference(
+        "float32", (n_chunks(int(x.shape[1])), int(x.shape[0]),
+                    int(a.shape[1]), int(x.shape[2])), stop_gradient=True)
+    helper.append_op(
+        type="selective_scan",
+        inputs={"X": [x], "Delta": [delta], "A": [a], "B": [b], "C": [c],
+                "D": [d]},
+        outputs={"Y": [y], "Starts": [starts]})
+    return y
 
 
 def kda_attention(q, k, v, g, beta, num_heads, l2norm_epsilon=1e-6,
@@ -1311,7 +1342,8 @@ def fused_multihead_attention(
     head transposes (they otherwise materialize as HBM relayout copies).
 
     `v`'s last dim may be narrower than `q`'s and `k`'s (latent attention:
-    192-wide scores, 128-wide values) and is then the output's. `k` and
+    192-wide scores, 128-wide values) or wider (a differential head: 64-wide
+    scores over a pair of value heads, 128) and is then the output's. `k` and
     `v` may have fewer heads than `q`, a divisor of its count (grouped
     key/value heads): query head n reads key/value head n // group.
     `window` > 0, with `causal`, admits only the last `window` keys a

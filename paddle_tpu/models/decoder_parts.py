@@ -1,10 +1,13 @@
-"""What the expert-parallel decoders of the zoo (`kimi_linear`, `trinity`,
-`mellum`) build their layers from: bias-free projections seeded Normal(0,
-`initializer_range`), RMSNorm with a learned weight, the SiLU-gated
-feed-forward, attention over grouped key/value heads with QK-norm and
-rotary positions, latent attention (`kimi_linear`, `joyai_flash`), and the
-expert layer that holds a share of the experts. A `cfg` gives
-`hidden_size`, `initializer_range`, `rms_norm_eps`, for `attention` the
+"""What the decoders of the zoo (`kimi_linear`, `trinity`, `mellum`,
+`joyai_flash`, `phi4_flash`) build their layers from: projections seeded
+Normal(0, `initializer_range`), with a bias where asked, RMSNorm with a
+learned weight and LayerNorm with weight and bias, the SiLU-gated
+feed-forward as three products or with gate and up in one, attention over
+grouped key/value heads with QK-norm and rotary positions, latent
+attention (`kimi_linear`, `joyai_flash`), differential attention
+(`phi4_flash`), and the expert layer that holds a share of the experts. A
+`cfg` gives `hidden_size`, `initializer_range`, `rms_norm_eps` (or
+`layer_norm_eps`), for `attention` and `differential_attention` the
 heads, for `latent_attention` the keys its docstring lists, and for
 `expert_ffn` the router's keys as `KimiLinearConfig` names them."""
 
@@ -21,9 +24,10 @@ def attr(name, cfg):
     return ParamAttr(name=name, initializer=Normal(0.0, cfg.initializer_range))
 
 
-def proj(x, size, name, cfg):
+def proj(x, size, name, cfg, bias=False):
     return layers.fc(x, size, num_flatten_dims=2,
-                     param_attr=attr(name + ".w_0", cfg), bias_attr=False)
+                     param_attr=attr(name + ".w_0", cfg),
+                     bias_attr=ParamAttr(name=name + ".b_0") if bias else False)
 
 
 def norm(x, name, cfg, axis=2):
@@ -31,11 +35,84 @@ def norm(x, name, cfg, axis=2):
                            param_attr=ParamAttr(name=name + ".w_0"))
 
 
+def layer_norm(x, name, cfg, axis=2):
+    return layers.layer_norm(
+        x, begin_norm_axis=axis, epsilon=cfg.layer_norm_eps,
+        param_attr=ParamAttr(name=name + ".w_0"),
+        bias_attr=ParamAttr(name=name + ".b_0"))
+
+
 def ffn(u, width, name, cfg):
     gate = layers.swish(proj(u, width, name + ".gate", cfg))
     up = proj(u, width, name + ".up", cfg)
     return proj(layers.elementwise_mul(gate, up), cfg.hidden_size,
                 name + ".down", cfg)
+
+
+def fused_ffn(u, width, name, cfg):
+    """`W_fc2 (y * silu(g))` with `[g ; y] = W_fc1 u`: gate and up come
+    from one product, the gate first."""
+    gate, up = layers.split(proj(u, 2 * width, name + ".fc1", cfg), 2, dim=2)
+    return proj(layers.elementwise_mul(up, layers.swish(gate)),
+                cfg.hidden_size, name + ".fc2", cfg)
+
+
+def _by_pairs(t, b, s, pairs, d):
+    """[b, s, pairs * 2 * d], pair n heads 2n and 2n + 1, to the first and
+    the second head of every pair, [b, s, pairs, d] each."""
+    first, second = layers.split(
+        layers.reshape(t, [b, s, pairs, 2, d]), 2, dim=3)
+    return (layers.reshape(first, [b, s, pairs, d]),
+            layers.reshape(second, [b, s, pairs, d]))
+
+
+def differential_attention(u, cfg, name, window=0, kv=None, lam0=0.8):
+    """Causal differential attention (arXiv:2410.05258), u [b, s, hidden]
+    to [b, s, hidden]: `num_attention_heads` query heads and
+    `num_key_value_heads` key/value heads of `head_dim` taken in pairs.
+    Each pair has two softmax maps, one a head of the pair, over the
+    pair's two value heads side by side (`2 * head_dim` wide), and gives
+    `(1 - lam0) RMSNorm(a_1 - lam a_2)` with `lam = exp(lq1 . lk1) -
+    exp(lq2 . lk2) + lam0` (four learned vectors of `head_dim` a layer)
+    and one norm weight of `2 * head_dim` a layer. Query pair n reads
+    key/value pair n // group. Projections have a bias. `window` keys
+    wide where it is not 0. `kv`: another layer's keys and values as this
+    function returned them, and then only the query is projected here
+    (`.q`; otherwise `.qkv`, one product). Returns (out, kv).
+
+    The two maps are two `fused_multihead_attention` calls over the first
+    and the second heads of the pairs, values at `2 * head_dim` beside
+    keys of `head_dim`; the difference, the norm and `lam` are ordinary
+    ops, in float32."""
+    b, s, _ = u.shape
+    h, g, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    if kv is None:
+        q, k, v = layers.split(
+            proj(u, (h + 2 * g) * d, name + ".qkv", cfg, bias=True),
+            [h * d, g * d, g * d], dim=2)
+        kv = (*_by_pairs(k, b, s, g // 2, d),
+              layers.reshape(v, [b, s, g // 2, 2 * d]))
+    else:
+        q = proj(u, h * d, name + ".q", cfg, bias=True)
+    k1, k2, v = kv
+    maps = [layers.cast(layers.fused_multihead_attention(
+        q_c, k_c, v, causal=True, sm_scale=1.0 / math.sqrt(d), layout="bshd",
+        window=window), "float32")
+        for q_c, k_c in zip(_by_pairs(q, b, s, h // 2, d), (k1, k2))]
+
+    def dot(c):
+        lq, lk = (layers.create_parameter(
+            [d], "float32", attr=ParamAttr(
+                name=f"{name}.lambda_{x}{c}", initializer=Normal(0.0, 0.1)))
+            for x in "qk")
+        return layers.exp(layers.reduce_sum(layers.elementwise_mul(lq, lk)))
+
+    lam = layers.scale(layers.elementwise_sub(dot(1), dot(2)), bias=lam0)
+    a = layers.elementwise_sub(maps[0], layers.elementwise_mul(maps[1], lam))
+    a = layers.rms_norm(a, begin_norm_axis=3, epsilon=cfg.layer_norm_eps,
+                        param_attr=ParamAttr(name=name + ".subln.w_0"))
+    a = layers.reshape(layers.scale(a, scale=1.0 - lam0), [b, s, h * d])
+    return proj(a, cfg.hidden_size, name + ".o", cfg, bias=True), kv
 
 
 def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,

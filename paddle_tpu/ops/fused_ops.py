@@ -49,7 +49,7 @@ def attention_path(q, k, v, *, layout, causal, window, group, mesh) -> str:
                `FLASH_MIN_SCORE_BYTES`, or both lengths are at least
                `FLASH_MIN_SEQ`. It visits only the blocks `causal` and
                `window` admit, reads grouped heads in place and takes
-               values narrower than the keys. The same size per shard of a
+               values narrower or wider than the keys. The same size per shard of a
                mesh takes "xla": GSPMD cannot partition a custom call.
       "short"  ops/pallas/mha_short.py, where Pallas runs and whole score
                rows fit VMEM (`mha_short_viable`: heads of 64 or 128 that
@@ -100,8 +100,8 @@ def _fused_mha(ctx, op):
     [b, s, nh, dh] ("bshd" — the shape the model's QKV reshape produces,
     no head transposes anywhere in the graph); optional KeyBias: [b, sk]
     additive (0 keep, large-negative drop). Out matches the input layout.
-    V's last dim may be narrower than Q's and K's (latent attention), and
-    is then Out's. K and V may have fewer heads than Q, a divisor of its
+    V's last dim may be narrower than Q's and K's (latent attention) or
+    wider (a differential head's pair of value heads), and is then Out's. K and V may have fewer heads than Q, a divisor of its
     count: query head n reads key/value head n // group. Attr `window`
     (0: none; needs `causal`) admits only the last `window` keys a query
     may see: key j for query i iff 0 <= i - j < window.
@@ -232,8 +232,8 @@ def _fused_mha(ctx, op):
                                out_dtype=q.dtype)
         else:
             operands = swap(q), swap(k), swap(v)
-        # values narrower than the keys: the kernel takes them at their own
-        # width in whole lanes, and so writes the output
+        # values narrower or wider than the keys: the kernel takes them at
+        # their own width in whole lanes, and so writes the output
         out = swap(flash_attention(
             *operands, bias=bias, causal=causal, sm_scale=sm_scale,
             dropout=dropout, rng_key=rng, window=window))

@@ -63,10 +63,10 @@ from .registry import register_op
 
 
 @jax.checkpoint
-def short_conv(x, w):
+def short_conv(x, w, bias=None):
     """Causal depthwise convolution over time with zero left state, then
-    SiLU. x: [b, s, c]; w: [c, width];
-    `out_t = SiLU(sum_i w[:, i] x_{t-width+1+i})`."""
+    SiLU. x: [b, s, c]; w: [c, width]; bias: [c] or None;
+    `out_t = SiLU(sum_i w[:, i] x_{t-width+1+i} + bias)`."""
     width = w.shape[1]
     s = x.shape[1]
     # float32 inside, whatever x arrives in: four products, three sums and
@@ -76,14 +76,17 @@ def short_conv(x, w):
     xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (width - 1, 0), (0, 0)))
     out = sum(xp[:, i:i + s, :] * w[:, i].astype(jnp.float32)
               for i in range(width))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     return (out * jax.nn.sigmoid(out)).astype(x.dtype)
 
 
 @register_op("short_conv1d")
 def _short_conv1d(ctx, op):
-    x = ctx.in_(op, "X")
-    w = ctx.in_(op, "Filter")
-    ctx.out(op, "Out", short_conv(x, w))
+    # without a `Bias` input the traced jaxpr is what it was before the op
+    # took one (tests/test_kimi_linear_reference.py compares the two)
+    ctx.out(op, "Out", short_conv(ctx.in_(op, "X"), ctx.in_(op, "Filter"),
+                                  ctx.in_(op, "Bias")))
 
 
 def kda_gate(g_raw, a_log, dt_bias, num_heads):

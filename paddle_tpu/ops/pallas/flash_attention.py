@@ -17,7 +17,8 @@ query may see). Each head width is zero-padded to a lane multiple (128)
 of its own: q, k, dq and dk travel at the keys' padded width, v, the
 output, dO and dv at the values', so values narrower than the keys
 (latent attention's 128 beside 192) cost P.V, dO.V^T and P^T.dO no lane
-they do not fill. Sequence dims are padded to block multiples with
+they do not fill, and values wider than the keys (a differential head's
+128 beside 64) cost q.k and dS.k none either. Sequence dims are padded to block multiples with
 fully-masked keys. The arrays are head-major: cutting a head's blocks
 from the lanes of [b, s, heads*128] arrays as the projections write them
 was built and measured at s=8,192 (PERF.md, PR 33): the strided blocks
@@ -953,9 +954,9 @@ def flash_attention(
 ):
     """Fused multi-head attention.
 
-    q: [b, h, sq, d]; k: [b, hkv, sk, d]; v: [b, hkv, sk, dv], `dv` up
-    to `d` and `h` a multiple of `hkv` (query head `n` reads key/value
-    head `n // (h // hkv)`); bias: additive key bias [b, sk] (0 keep /
+    q: [b, h, sq, d]; k: [b, hkv, sk, d]; v: [b, hkv, sk, dv], `dv`
+    narrower than `d`, as wide or wider, and `h` a multiple of `hkv`
+    (query head `n` reads key/value head `n // (h // hkv)`); bias: additive key bias [b, sk] (0 keep /
     -inf drop) or None. `window` > 0 (with `causal`) admits only the last
     `window` keys a query may see. Returns [b, h, sq, dv] in q's dtype.
     `block_q`, `block_k`: a block named here serves all three kernels;
@@ -973,9 +974,6 @@ def flash_attention(
                          f"{k.shape[1]} key/value heads")
     if window and not causal:
         raise ValueError("flash_attention: a window needs causal=True")
-    if dv > d:
-        raise ValueError(f"flash_attention: values of width {dv} wider "
-                         f"than the keys ({d})")
     if dropout > 0.0 and rng_key is None:
         raise ValueError("dropout requires rng_key")
     if dropout > 0.0:
@@ -1002,6 +1000,8 @@ def flash_attention(
     profiler.bump_counter("flash_blocks_total", b * h * total)
     if vf.shape[2] < kf.shape[2]:  # the values travel at a width of their own
         profiler.bump_counter("flash_narrow_value_calls")
+    if dv > d:  # ... or at more lanes than the keys fill of theirs
+        profiler.bump_counter("flash_wide_value_calls")
     if fwd_blocks[1] != bk:  # the forward walks the keys at a block of its own
         profiler.bump_counter("flash_fwd_wide_key_calls")
 

@@ -870,6 +870,24 @@ def _shape_short_conv1d(ictx, op):
     ictx.out(op, "Out", _m(ictx.in_(op, "X")))
 
 
+@register_shape("selective_scan")
+def _shape_selective_scan(ictx, op):
+    from .ssm_ops import n_chunks
+
+    x, a = ictx.in_(op, "X"), ictx.in_(op, "A")
+    ictx.out(op, "Y", _m(x))
+    if _known(x, a):
+        b, s, d = x.shape
+        ictx.out(op, "Starts",
+                 VarMeta((n_chunks(s), b, a.shape[1], d), "float32"))
+
+
+@register_shape("selective_scan_grad")
+def _shape_selective_scan_grad(ictx, op):
+    for slot in ("X", "Delta", "A", "B", "C", "D"):
+        ictx.out(op, "IGRAD_" + slot, _m(ictx.in_(op, slot)))
+
+
 @register_shape("kda_attention")
 def _shape_kda_attention(ictx, op):
     ictx.out(op, "Out", _m(ictx.in_(op, "V")))

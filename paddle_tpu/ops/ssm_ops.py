@@ -1,0 +1,283 @@
+"""State-space op lowerings: the selective scan of Mamba-1 (Gu and Dao,
+arXiv:2312.00752, section 3 and algorithm 2). No reference counterpart:
+Fluid ~1.5 has no recurrence over time but its RNN ops.
+
+Per channel `d` of `d_inner` and state lane `n` of `d_state`, with the
+state zero at the start of a row and everything float32:
+
+    h_t[n, d] = exp(Delta_t[d] A[d, n]) h_{t-1}[n, d] + Delta_t[d] x_t[d] B_t[n]
+    y_t[d]    = sum_n C_t[n] h_t[n, d] + D[d] x_t[d]
+
+The decay depends on the input (through `Delta`), on the channel and on
+the lane, so the recurrence is no matrix product: the work is
+`s x d_inner x d_state` multiply-adds and as many exponentials, on the
+vector units.
+
+**One lowering, in chunks of `CHUNK` tokens.** `A` does not depend on the
+time, so inside a chunk the decay from token j to token t is
+`exp(A (S_t - S_j))` with `S` the running sum of `Delta` from the chunk's
+start: an exponent that is at most 0 whatever the step sizes, because `A`
+is negative and `Delta` is not. With `u_j = Delta_j x_j` and `h_0` the
+state the chunk starts from:
+
+    E[t, j] = exp(A (S_t - S_j))                       (j <= t, else 0)
+    h_t     = exp(A S_t) h_0 + sum_j E[t, j] B_j u_j   (a chunk's trajectory)
+
+The sum over j is one fused reduction (`[c, c, n, d]` is its operand and
+is not stored); what a chunk writes is its trajectory `[c, n, d]`, read
+once for `y`, and the state it ends in. A `lax.scan` carries the state
+from chunk to chunk. The trajectory of the whole row, `[s, n, d]`
+(1.34 GB in float32 at 4,096 tokens and 5,120 x 16), is never in memory,
+forward or backward.
+
+**The gradient** is an op of its own, `selective_scan_grad`: it reads the
+state each chunk starts from (`[s / c, n, d]`, the forward op's second
+output) and runs no forward again. It walks the chunks backwards with the
+adjoint of the state, `lam`. A
+chunk's trajectory is rebuilt from its start, and the adjoint's
+trajectory is the same reduction read along its other axis:
+
+    lam_j = sum_{t >= j} E[t, j] C_t dy_t + exp(A (S_c - S_j)) lam_next
+
+From the two, with `w_t = lam_t * (h_t - B_t u_t)` (the adjoint times
+the decayed last state, which is what the decay's own gradient needs):
+
+    dC_t = sum_d dy_t h_t        dB_t = sum_d lam_t u_t
+    du_t = sum_n lam_t B_t       dDelta_t = sum_n A w_t + du_t x_t
+    dA   = sum_t Delta_t w_t     dx_t = du_t Delta_t + D dy_t
+
+Inside, the state's lanes are the second-minor axis and the channels the
+minor one (`[n, d]`, 16 x 5,120: whole vector registers), which is why
+`A` arrives `[d, n]` and is turned once.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .. import profiler
+from .registry import register_op
+
+# Tokens a chunk. A token costs `CHUNK` exponentials a lane of the state in
+# each of the three reductions and the loop one step a chunk: forward and
+# backward of one layer at [1, 4096, 5120] x 16 took 44.6, 25.4, 20.4 and
+# 23.0 ms at 16, 8, 4 and 2 on a v5e (PERF.md, PR 44). 8 and not 4: the
+# backward keeps a state a chunk, 0.17 GB a layer at 8.
+CHUNK = 8
+
+
+def _chunked(t, n_chunks):
+    """[b, n_chunks * c, w] -> [n_chunks, b, c, w] float32."""
+    b, _, w = t.shape
+    return jnp.moveaxis(
+        t.astype(jnp.float32).reshape(b, n_chunks, -1, w), 1, 0)
+
+
+def _unchunked(t):
+    """[n_chunks, b, c, w] -> [b, n_chunks * c, w]."""
+    t = jnp.moveaxis(t, 0, 1)
+    return t.reshape(t.shape[0], -1, t.shape[3])
+
+
+def _decays(a, s, towards="t"):
+    """a: [n, d]; s: [b, c, d], `Delta`'s running sum. `E` with 0 where
+    j > t, as [b, t, j, n, d] (`towards` "t": summed over axis 2 it
+    gathers what earlier tokens left at t) or as [b, j, t, n, d] ("j":
+    what later tokens ask of j)."""
+    c = s.shape[1]
+    later, earlier = ((s[:, :, None, :], s[:, None, :, :]) if towards == "t"
+                      else (s[:, None, :, :], s[:, :, None, :]))
+    admitted = np.tril(np.ones((c, c), bool))
+    if towards == "j":
+        admitted = admitted.T
+    return jnp.exp(jnp.where(admitted[None, :, :, None, None],
+                             a * (later - earlier)[:, :, :, None, :],
+                             -jnp.inf))
+
+
+def _trajectory(a, s, bu, h0):
+    """The states of one chunk, [b, c, n, d], from the state it starts
+    from and `bu[b, j, n, d] = B_j u_j`."""
+    return (jnp.exp(a * s[:, :, None, :]) * h0[:, None]
+            + jnp.sum(_decays(a, s) * bu[:, None], axis=2))
+
+
+def _bu(bm, delta, x):
+    return bm[:, :, :, None] * (delta * x)[:, :, None, :]
+
+
+def _scan_fwd(x, delta, a, bm, cm, chunk):
+    """x, delta: [b, s, d]; bm, cm: [b, s, n]; a: [n, d] float32, `s` a
+    multiple of `chunk`. Returns `sum_n C h` [b, s, d] float32 and the
+    state each chunk starts from, [s / chunk, b, n, d]."""
+    b, s, d = x.shape
+    n_chunks = s // chunk
+
+    def step(h0, xs):
+        x, delta, bm, cm = xs
+        h = _trajectory(a, jnp.cumsum(delta, axis=1), _bu(bm, delta, x), h0)
+        return h[:, -1], (jnp.sum(cm[:, :, :, None] * h, axis=2), h0)
+
+    _, (y, starts) = jax.lax.scan(
+        step, jnp.zeros((b, a.shape[0], d), jnp.float32),
+        tuple(_chunked(t, n_chunks) for t in (x, delta, bm, cm)))
+    return _unchunked(y), starts
+
+
+def _scan_bwd(x, delta, a, bm, cm, starts, dy, chunk):
+    """The gradients of `_scan_fwd`'s first output with respect to x,
+    delta, a, bm and cm (the module docstring has the equations)."""
+    b, s, d = x.shape
+    n_chunks = s // chunk
+
+    def step(carry, xs):
+        lam_next, da = carry
+        x, delta, bm, cm, h0, dy = xs
+        bu = _bu(bm, delta, x)
+        s = jnp.cumsum(delta, axis=1)
+        h = _trajectory(a, s, bu, h0)
+        # the same decays read along the other axis, built apart from the
+        # trajectory's so that neither reduction stores its operand
+        lam = (jnp.sum(_decays(a, s, "j")
+                       * (cm[:, :, :, None] * dy[:, :, None, :])[:, None],
+                       axis=2)
+               + jnp.exp(a * (s[:, -1:] - s)[:, :, None, :]) * lam_next[:, None])
+        w = lam * (h - bu)
+        du = jnp.sum(lam * bm[:, :, :, None], axis=2)
+        grads = (du * delta,  # dx, without the skip's part
+                 jnp.sum(a * w, axis=2) + du * x,  # ddelta
+                 jnp.sum(lam * (delta * x)[:, :, None, :], axis=3),  # dB
+                 jnp.sum(dy[:, :, None, :] * h, axis=3))  # dC
+        da = da + jnp.sum(delta[:, :, None, :] * w, axis=(0, 1))
+        # what the chunk before sees of its last state: S_1 = Delta_1
+        return (jnp.exp(a * delta[:, 0, None, :]) * lam[:, 0], da), grads
+
+    (_, da), grads = jax.lax.scan(
+        step, (jnp.zeros((b, a.shape[0], d), jnp.float32), jnp.zeros_like(a)),
+        (*(_chunked(t, n_chunks) for t in (x, delta, bm, cm)), starts,
+         _chunked(dy, n_chunks)), reverse=True)
+    dx, ddelta, dbm, dcm = (_unchunked(t) for t in grads)
+    return dx, ddelta, da, dbm, dcm
+
+
+def _primal(x, delta, a, bm, cm, dskip, chunk):
+    """(y in x's dtype, the states the chunks start from). The second
+    output is what the gradient keeps; it carries no gradient itself."""
+    y, starts = _scan_fwd(x, delta, a, bm, cm, chunk)
+    return (y + dskip * x.astype(jnp.float32)).astype(x.dtype), starts
+
+
+_selective_scan = jax.custom_vjp(_primal, nondiff_argnums=(6,))
+
+
+def _selective_scan_fwd(x, delta, a, bm, cm, dskip, chunk):
+    y, starts = _primal(x, delta, a, bm, cm, dskip, chunk)
+    return (y, starts), (x, delta, a, bm, cm, dskip, starts)
+
+
+def _selective_scan_bwd(chunk, res, cts):
+    x, delta, a, bm, cm, dskip, starts = res
+    dy = cts[0].astype(jnp.float32)
+    dx, ddelta, da, dbm, dcm = _scan_bwd(x, delta, a, bm, cm, starts, dy,
+                                         chunk)
+    xf = x.astype(jnp.float32)
+    return ((dx + dskip * dy).astype(x.dtype), ddelta.astype(delta.dtype), da,
+            dbm.astype(bm.dtype), dcm.astype(cm.dtype),
+            jnp.sum(dy * xf, axis=(0, 1)))
+
+
+_selective_scan.defvjp(_selective_scan_fwd, _selective_scan_bwd)
+
+
+def n_chunks(s, chunk=CHUNK):
+    """Chunks a row of `s` tokens is walked in: a row shorter than one
+    chunk is one chunk of its own length."""
+    return -(-s // min(chunk, s))
+
+
+def _whole_chunks(s, chunk, *rows):
+    """`rows` ([b, s, .]) padded to whole chunks with steps of size 0,
+    which change no state, and the chunk's length."""
+    chunk = min(chunk, s)
+    pad = -s % chunk
+    if pad:
+        rows = tuple(jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in rows)
+    return chunk, rows
+
+
+def selective_scan_with_starts(x, delta, a, bm, cm, dskip, chunk=CHUNK):
+    """x, delta: [b, s, d]; a: [d, n], negative; bm, cm: [b, s, n];
+    dskip: [d]. Returns y [b, s, d] in x's dtype and the state each chunk
+    starts from, [n_chunks, b, n, d] float32, which `selective_scan_grads`
+    takes; float32 inside, whatever the operands arrive in."""
+    s = x.shape[1]
+    chunk, (x, delta, bm, cm) = _whole_chunks(s, chunk, x, delta, bm, cm)
+    y, starts = _selective_scan(x, delta, a.astype(jnp.float32).T, bm, cm,
+                                dskip.astype(jnp.float32), chunk)
+    return y[:, :s], starts
+
+
+def selective_scan(x, delta, a, bm, cm, dskip, chunk=CHUNK):
+    return selective_scan_with_starts(x, delta, a, bm, cm, dskip, chunk)[0]
+
+
+def selective_scan_grads(x, delta, a, bm, cm, dskip, starts, dy, chunk=CHUNK):
+    """The gradients of `selective_scan`'s output with respect to its six
+    operands, from the states `selective_scan_with_starts` kept: the
+    forward does not run again."""
+    s = x.shape[1]
+    chunk, (x_p, delta_p, bm_p, cm_p, dy_p) = _whole_chunks(
+        s, chunk, x, delta, bm, cm, dy)
+    dx, ddelta, da, dbm, dcm, dd = _selective_scan_bwd(
+        chunk, (x_p, delta_p, a.astype(jnp.float32).T, bm_p, cm_p,
+                dskip.astype(jnp.float32), starts), (dy_p, None))
+    return (dx[:, :s], ddelta[:, :s], da.T.astype(a.dtype), dbm[:, :s],
+            dcm[:, :s], dd.astype(dskip.dtype))
+
+
+_SLOTS = ("X", "Delta", "A", "B", "C", "D")
+
+
+def _grad_maker(op, grad_out_names, block, helpers):
+    """`selective_scan_grad` reads the forward op's `Starts` and so runs
+    no forward of its own. A gradient into `Starts` itself, which nothing
+    in the zoo asks for, goes through `jax.vjp` of the lowering."""
+    if (grad_out_names.get("Y", [None])[0] is None
+            or grad_out_names.get("Starts", [None])[0] is not None):
+        return None
+    return [{
+        "type": "selective_scan_grad",
+        "inputs": {**{slot: op.input(slot) for slot in _SLOTS},
+                   "Starts": op.output("Starts"),
+                   "GRAD_Y": [grad_out_names["Y"][0]]},
+        "outputs": {f"IGRAD_{slot}": [helpers.grad_name(op.input(slot)[0])]
+                    for slot in _SLOTS},
+        "attrs": {},
+    }]
+
+
+@register_op("selective_scan", grad=_grad_maker)
+def _selective_scan_op(ctx, op):
+    """X, Delta: [b, s, d_inner], Delta the step sizes (after their
+    softplus); A: [d_inner, d_state], negative; B, C: [b, s, d_state];
+    D: [d_inner]. Y: [b, s, d_inner] in X's dtype; Starts:
+    [n_chunks, b, d_state, d_inner] float32, for the gradient op."""
+    a = ctx.in_(op, "A")
+    profiler.bump_counter("ssm_dispatch_chunked")
+    profiler.set_counter("ssm_state_size", int(a.shape[1]))
+    profiler.set_counter("ssm_chunk_len", CHUNK)
+    y, starts = selective_scan_with_starts(
+        *(ctx.in_(op, slot) for slot in _SLOTS))
+    ctx.out(op, "Y", y)
+    ctx.out(op, "Starts", starts)
+
+
+@register_op("selective_scan_grad", differentiable=False)
+def _selective_scan_grad_op(ctx, op):
+    grads = selective_scan_grads(
+        *(ctx.in_(op, slot) for slot in _SLOTS), ctx.in_(op, "Starts"),
+        ctx.in_(op, "GRAD_Y"))
+    for slot, g in zip(_SLOTS, grads):
+        ctx.out(op, f"IGRAD_{slot}", g)

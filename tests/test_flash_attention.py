@@ -172,6 +172,35 @@ def test_band_and_group_match_the_plain_path(rng, case):
         assert float(jnp.abs(a - b_).max()) / scale < 1e-5, name
 
 
+@pytest.mark.parametrize("case", [
+    # (b, h, hkv, sq, sk, d, dv), window: a differential head's calls, heads
+    # of 64 under values of 128, two query heads a key/value head
+    ((1, 4, 2, 384, 384, 64, 128), 0),     # causal alone
+    ((1, 4, 2, 384, 384, 64, 128), 130),   # a window that is no block's
+    ((2, 4, 2, 128, 384, 64, 128), 150),   # sq != sk, with the offset
+    ((1, 2, 2, 300, 300, 64, 256), 0),     # group 1, values four times as wide
+    ((1, 4, 4, 256, 256, 128, 192), 50),   # keys of whole lanes, values of 1.5
+], ids=lambda c: f"{c[0]}-w{c[1]}")
+def test_values_wider_than_the_keys_match_the_plain_path(rng, case):
+    """out, dq, dk, dv of the three kernels, interpreted, where `dv > d`
+    (and heads of 64 lanes), against `_attention_unfused` in float32."""
+    dims, window = case
+    q, k, v, w = _band_case(rng, *dims[:6], dv=dims[6])
+    sm = 1.0 / np.sqrt(dims[5])
+    got = _out_and_grads(
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, window=window, block_q=128, block_k=128),
+        q, k, v, w)
+    want = _out_and_grads(
+        lambda q, k, v: fa._attention_unfused(
+            q, k, v, None, True, sm, 0.0, None, True, window=window),
+        q, k, v, w)
+    for a, b_, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        assert a.shape == b_.shape
+        scale = max(1.0, float(jnp.abs(b_).max()))
+        assert float(jnp.abs(a - b_).max()) / scale < 1e-5, name
+
+
 def test_a_window_of_at_least_s_is_bitwise_the_causal_kernel(rng):
     q, k, v, w = _band_case(rng, 1, 2, 2, 300, 300, 64)
     run = lambda window: _out_and_grads(  # noqa: E731
@@ -504,6 +533,11 @@ _WIDTH_CASES = {
     # backward's 512 x 512 (at s=256 every kernel has the one block)
     "latent_192_128_s2048": ((1, 4, 2, 2048, 192, 128), 256, 128, 1),
     "one_width_128_s2048": ((1, 4, 2, 2048, 128, 128), 128, 128, 0),
+    # values wider than the keys (`flash_wide_value_calls`): a differential
+    # head's 64 and 128 travel in the same lanes; 128 and 192 do not
+    "differential_64_128": ((1, 4, 2, 256, 64, 128), 128, 128, 0),
+    "differential_64_128_s2048": ((1, 4, 2, 2048, 64, 128), 128, 128, 0),
+    "wider_128_192": ((1, 4, 2, 256, 128, 192), 128, 256, 0),
 }
 
 
@@ -513,8 +547,8 @@ def test_operand_widths_of_the_three_calls(case, with_bias):
     """The arrays the custom calls read and write: v, the output, dO and
     dv at the values' lanes, q, k, dq and dk at the keys'; the blocks the
     forward cuts them in; and the counters that say a call's values
-    travelled narrower than its keys and its forward at wider blocks than
-    its backward."""
+    travelled narrower than its keys, or were wider than they, and its
+    forward at wider blocks than its backward."""
     from pallas_costs import block_shapes, operand_shapes
 
     from paddle_tpu import profiler
@@ -531,9 +565,11 @@ def test_operand_widths_of_the_three_calls(case, with_bias):
     # the forward is traced once for the output and the residuals alike
     bumped = {name: profiler.counters().get(name, 0) - before.get(name, 0)
               for name in ("flash_narrow_value_calls",
+                           "flash_wide_value_calls",
                            "flash_fwd_wide_key_calls")}
     wide = s == 2048
     assert bumped == {"flash_narrow_value_calls": narrow,
+                      "flash_wide_value_calls": int(dv > d),
                       "flash_fwd_wide_key_calls": int(wide)}
     fq = fk = 1024 if wide else s
     ((grid, blocks),) = block_shapes(loss, q, k, v)["flash_fwd"]
@@ -652,6 +688,12 @@ _COST_CASES = {
     # the latent layer: keys 192 wide, values 128, padded inside to 256
     # and 128 lanes; the count is at 192 and 128
     "latent_values_narrower": ((1, 2, 2, 512, 192, 128), 0, 512 * 513 // 2),
+    # a differential head's call: keys 64 wide and values 128, both in 128
+    # lanes inside; the count is at 64 and 128. Two query heads a key head
+    "differential_values_wider": ((1, 4, 2, 512, 64, 128), 0, 512 * 513 // 2),
+    # ... under a 512-key window over a row twice as long
+    "differential_window": ((1, 4, 2, 1024, 64, 128), 512,
+                            512 * 513 // 2 + 512 * 512),
 }
 
 

@@ -482,6 +482,70 @@ def test_layers_build_the_block_without_the_zoo():
     assert 0 < counts.sum() <= 2 * 70 * 2
 
 
+@pytest.mark.parametrize("bias", [False, True], ids=["no_bias", "bias"])
+def test_short_conv1d_with_and_without_its_bias(bias):
+    """The layer with `bias_attr` (a [c] parameter inside the SiLU: Mamba's
+    convolution) and without it, value and gradients against four shifted
+    products written out; without a bias the op has no `Bias` input and
+    its lowering traces what it traced before the bias existed."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.initializer import Uniform
+    from paddle_tpu.ops import linear_attn_ops
+    from paddle_tpu.param_attr import ParamAttr
+    from tools.verify_bench_programs import compare_static_vs_traced
+
+    L = fluid.layers
+    x = L.data("x", [2, 21, 6], append_batch_size=False)
+    x.stop_gradient = False
+    y = L.short_conv1d(
+        x, 4, param_attr=ParamAttr(name="f", initializer=Uniform(-0.5, 0.5)),
+        bias_attr=ParamAttr(name="b", initializer=Uniform(-0.5, 0.5))
+        if bias else None)
+    main = fluid.default_main_program()
+    (op,) = [o for o in main.global_block().ops if o.type == "short_conv1d"]
+    assert sorted(op.inputs) == (["Bias", "Filter", "X"] if bias
+                                 else ["Filter", "X"])
+    w = np.random.RandomState(1).randn(2, 21, 6).astype(np.float32)
+    loss = L.reduce_sum(L.elementwise_mul(y, L.assign(w)))
+    params = ["f", "b"] if bias else ["f"]
+    grads = fluid.backward.calc_gradient(
+        loss, [x] + [main.global_block().var(n) for n in params])
+    n, mismatches, unknown = compare_static_vs_traced(
+        main, {"x": ((2, 21, 6), "float32")})
+    assert n >= 2 and mismatches == [] and unknown == []
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    data = np.random.RandomState(0).randn(2, 21, 6).astype(np.float32)
+    got = exe.run(feed={"x": data}, fetch_list=[y, *grads])
+    p = state(params)
+    assert not bias or np.abs(p["b"]).max() > 0.05
+
+    def by_hand(a, f, b):
+        padded = jnp.pad(a, ((0, 0), (3, 0), (0, 0)))
+        out = sum(padded[:, i:i + 21] * f[:, i] for i in range(4)) + b
+        return out * jax.nn.sigmoid(out)
+
+    b = p["b"] if bias else jnp.zeros(6)
+    np.testing.assert_allclose(got[0], by_hand(data, p["f"], b), atol=1e-6)
+    want = jax.grad(lambda a, f, b: jnp.sum(by_hand(a, f, b) * w),
+                    argnums=(0, 1, 2))(jnp.asarray(data), p["f"], b)
+    for g, g_want in zip(got[1:], want):
+        np.testing.assert_allclose(g, g_want, atol=1e-5)
+    # the bias moved the output, and the first token sees it alone
+    if bias:
+        first = p["f"][:, 3] * data[:, 0] + p["b"]
+        np.testing.assert_allclose(got[0][:, 0], first / (1 + np.exp(-first)),
+                                   atol=1e-6)
+    else:  # the jaxpr of the call without a bias is the one-argument call's
+        a, f = jnp.asarray(data), jnp.asarray(p["f"])
+        assert str(jax.make_jaxpr(linear_attn_ops.short_conv)(a, f)) == str(
+            jax.make_jaxpr(lambda a, f: linear_attn_ops.short_conv(
+                a, f, None))(a, f))
+
+
 def test_counters_and_flops_of_the_cell():
     from benchmark.models import kimi_linear as adapter
     from paddle_tpu import profiler

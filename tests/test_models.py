@@ -156,3 +156,37 @@ def test_se_resnext_trains_and_dp_equivalence():
     np.testing.assert_allclose(single[:3], parallel[:3], rtol=2e-3,
                                atol=1e-5)
     np.testing.assert_allclose(single, parallel, rtol=8e-2, atol=1e-4)
+
+
+def test_phi4_flash_tiny_builds_and_trains():
+    """The stage that straddles Phi-4-mini-flash's two decoders at a tiny
+    size: a Mamba layer, window attention, the Mamba layer whose scan is
+    the memory, the full layer whose keys and values are shared, a memory
+    unit and a cross layer, through `Executor.run`."""
+    from paddle_tpu.models import Phi4FlashConfig, build_phi4_flash
+
+    cfg = Phi4FlashConfig(
+        vocab_size=96, hidden_size=32, first_layer=14, layers_held=6,
+        num_attention_heads=4, num_key_value_heads=2, intermediate_size=64,
+        sliding_window=8, mamba_d_state=4)
+    assert [cfg.layer_kind(l) for l in range(14, 20)] == [
+        "mamba", "window", "mamba", "full", "gmu", "cross"]
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 7
+    with fluid.program_guard(main, startup):
+        handles = build_phi4_flash(cfg, 2, 24)
+        fluid.optimizer.Adam(learning_rate=1e-2).minimize(handles["loss"])
+    assert handles["feeds"] == ["tokens", "labels"] and handles["loads"] == []
+    assert tuple(handles["logits"].shape) == (2, 24, 96)
+    ops = [op.type for op in main.global_block().ops]
+    assert ops.count("selective_scan") == 2
+    assert ops.count("fused_multihead_attention") == 6
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        doc = np.random.RandomState(0).randint(0, 96, (2, 25))
+        feed = {"tokens": doc[:, :-1], "labels": doc[:, 1:]}
+        losses = [float(np.asarray(exe.run(
+            main, feed=feed, fetch_list=[handles["loss"]])[0]).reshape(-1)[0])
+            for _ in range(8)]
+    assert np.isfinite(losses).all() and losses[-1] < losses[0] - 0.5
