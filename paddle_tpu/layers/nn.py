@@ -741,7 +741,8 @@ def selective_scan(x, delta, a, b, c, d, name=None):
     y = helper.create_variable_for_type_inference(x.dtype, x.shape)
     # the states the chunks start from: what the gradient op keeps
     starts = helper.create_variable_for_type_inference(
-        "float32", (n_chunks(int(x.shape[1])), int(x.shape[0]),
+        "float32", (n_chunks(int(x.shape[1]), int(x.shape[2]),
+                             int(a.shape[1])), int(x.shape[0]),
                     int(a.shape[1]), int(x.shape[2])), stop_gradient=True)
     helper.append_op(
         type="selective_scan",

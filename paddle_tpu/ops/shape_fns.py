@@ -879,7 +879,8 @@ def _shape_selective_scan(ictx, op):
     if _known(x, a):
         b, s, d = x.shape
         ictx.out(op, "Starts",
-                 VarMeta((n_chunks(s), b, a.shape[1], d), "float32"))
+                 VarMeta((n_chunks(s, d, a.shape[1]), b, a.shape[1], d),
+                         "float32"))
 
 
 @register_shape("selective_scan_grad")

@@ -13,7 +13,33 @@ the lane, so the recurrence is no matrix product: the work is
 `s x d_inner x d_state` multiply-adds and as many exponentials, on the
 vector units.
 
-**One lowering, in chunks of `CHUNK` tokens.** `A` does not depend on the
+**Two lowerings, and what picks one is in the call.** Where
+`ops/pallas/selective_scan.py::selective_scan_viable` admits the shapes,
+the mesh and the backend (`d_inner` in whole groups of 128 lanes, a state
+of whole eights, one device or a mesh that shards `batch` alone, a TPU or
+the interpreter), both ops run that module's kernel pair, `ssm_scan_fwd`
+and `ssm_scan_bwd`: the recurrence one token at a time with the state in
+VMEM, one exponential a state element a pass. Everywhere else (another
+mesh, a CPU without the interpreter, `d_state` 4) they run the chunked
+form below, which shares the equations with the kernels and no code. No
+variable, attribute or table chooses; a counter says which ran, once a
+lowering of the forward op (`ssm_dispatch_pallas`,
+`ssm_dispatch_chunked`), and the gauge `ssm_chunk_len` the tokens between
+two states kept.
+
+**`Starts`**, the forward op's second output, is what ties the two ops to
+one path: the state each block of `kept_len` tokens starts from, and
+`kept_len` is the path's (`CHUNK` here, the kernels' `BLOCK` there). Its
+declared shape (`n_chunks`, which `layers/nn.py` and `shape_fns.py` call)
+is a function of the operands' shapes and the backend alone: `n_chunks`
+asks the same `selective_scan_viable`, with no mesh, because a Program
+is declared before it meets one. On a mesh that refuses the kernels at
+shapes that admit them, the forward op thins its chunked states to the
+declared blocks and the gradient op rebuilds the chunks' own by running
+the chunked forward again: the declaration holds on every path, and the
+cost falls on that mesh alone.
+
+**The chunked form, in chunks of `CHUNK` tokens.** `A` does not depend on the
 time, so inside a chunk the decay from token j to token t is
 `exp(A (S_t - S_j))` with `S` the running sum of `Delta` from the chunk's
 start: an exponent that is at most 0 whatever the step sizes, because `A`
@@ -58,6 +84,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import profiler
+from .pallas import selective_scan as scan_kernel
 from .registry import register_op
 
 # Tokens a chunk. A token costs `CHUNK` exponentials a lane of the state in
@@ -191,10 +218,20 @@ def _selective_scan_bwd(chunk, res, cts):
 _selective_scan.defvjp(_selective_scan_fwd, _selective_scan_bwd)
 
 
-def n_chunks(s, chunk=CHUNK):
-    """Chunks a row of `s` tokens is walked in: a row shorter than one
-    chunk is one chunk of its own length."""
-    return -(-s // min(chunk, s))
+def kept_len(s, d_inner, d_state):
+    """Tokens between two states of `Starts` as the op declares it: the
+    kernels' block where the shapes and the backend admit them (the mesh
+    is not known where a Program is declared), else a chunk; a row
+    shorter than either is one block of its own length."""
+    if scan_kernel.selective_scan_viable(s, d_inner, d_state, None):
+        return scan_kernel.block_len(s)
+    return min(CHUNK, s)
+
+
+def n_chunks(s, d_inner, d_state):
+    """Blocks a row of `s` tokens keeps a state of: `Starts`' leading
+    dimension."""
+    return -(-s // kept_len(s, d_inner, d_state))
 
 
 def _whole_chunks(s, chunk, *rows):
@@ -258,26 +295,50 @@ def _grad_maker(op, grad_out_names, block, helpers):
     }]
 
 
+def _in_kernels(ctx, x, a):
+    b, s, d = x.shape
+    return scan_kernel.selective_scan_viable(s, d, a.shape[1], ctx.mesh, b)
+
+
 @register_op("selective_scan", grad=_grad_maker)
 def _selective_scan_op(ctx, op):
     """X, Delta: [b, s, d_inner], Delta the step sizes (after their
     softplus); A: [d_inner, d_state], negative; B, C: [b, s, d_state];
     D: [d_inner]. Y: [b, s, d_inner] in X's dtype; Starts:
     [n_chunks, b, d_state, d_inner] float32, for the gradient op."""
-    a = ctx.in_(op, "A")
-    profiler.bump_counter("ssm_dispatch_chunked")
-    profiler.set_counter("ssm_state_size", int(a.shape[1]))
-    profiler.set_counter("ssm_chunk_len", CHUNK)
-    y, starts = selective_scan_with_starts(
-        *(ctx.in_(op, slot) for slot in _SLOTS))
+    operands = [ctx.in_(op, slot) for slot in _SLOTS]
+    x, a = operands[0], operands[2]
+    s, d, n = x.shape[1], x.shape[2], a.shape[1]
+    profiler.set_counter("ssm_state_size", int(n))
+    if _in_kernels(ctx, x, a):
+        profiler.bump_counter("ssm_dispatch_pallas")
+        profiler.set_counter("ssm_chunk_len", scan_kernel.block_len(s))
+        y, starts = scan_kernel.selective_scan(*operands, ctx.mesh)
+    else:
+        profiler.bump_counter("ssm_dispatch_chunked")
+        profiler.set_counter("ssm_chunk_len", CHUNK)
+        y, starts = selective_scan_with_starts(*operands)
+        # a mesh refused the kernels at shapes that admit them: the
+        # states of the declared blocks (the module docstring, `Starts`)
+        starts = starts[::max(kept_len(s, d, n) // CHUNK, 1)][
+            :n_chunks(s, d, n)]
     ctx.out(op, "Y", y)
     ctx.out(op, "Starts", starts)
 
 
 @register_op("selective_scan_grad", differentiable=False)
 def _selective_scan_grad_op(ctx, op):
-    grads = selective_scan_grads(
-        *(ctx.in_(op, slot) for slot in _SLOTS), ctx.in_(op, "Starts"),
-        ctx.in_(op, "GRAD_Y"))
+    operands = [ctx.in_(op, slot) for slot in _SLOTS]
+    x, a = operands[0], operands[2]
+    starts, dy = ctx.in_(op, "Starts"), ctx.in_(op, "GRAD_Y")
+    if _in_kernels(ctx, x, a):
+        grads = scan_kernel.selective_scan_grads(*operands, starts, dy,
+                                                 ctx.mesh)
+    else:
+        s = x.shape[1]
+        if kept_len(s, x.shape[2], a.shape[1]) != min(CHUNK, s):
+            # the forward op thinned them (the module docstring, `Starts`)
+            starts = selective_scan_with_starts(*operands)[1]
+        grads = selective_scan_grads(*operands, starts, dy)
     for slot, g in zip(_SLOTS, grads):
         ctx.out(op, f"IGRAD_{slot}", g)

@@ -578,6 +578,57 @@ def test_mellum_step_compiled_for_v5e_makes_each_first_block_once(topo):
     assert memory.temp_size_in_bytes <= 8.448e9
 
 
+def test_scan_kernels_compile_for_a_v5e_chip_at_the_published_widths(topo):
+    """The selective scan's kernel pair (ops/pallas/selective_scan.py) at
+    the shape of the cell that runs it: one 4,096-token row, 5,120
+    channels, a state of 16, bf16 rows with float32 steps, forward and
+    backward (the backward's four `[64, 16, 8, 128]` float32 arrays in
+    VMEM past Mosaic's 16 MiB default: the call raises its own limit).
+    The kernels' mathematics is tests/test_selective_scan_kernel.py's.
+    Nothing runs."""
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas import selective_scan as scan
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    b, s, d, n = 1, 4096, 5120, 16
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def both(*operands):
+        y, pull = jax.vjp(lambda *a: scan.selective_scan(*a)[0], *operands)
+        return y, pull(y)
+
+    with _as_on_the_chip():
+        compiled = jax.jit(both).lower(
+            sds((b, s, d)), sds((b, s, d), jnp.float32),
+            sds((d, n), jnp.float32), sds((b, s, n)), sds((b, s, n)),
+            sds((d,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
+    # the rows turned to [s, 40, 128] and back, the 21 MB of states kept
+    # and the partial sums: nothing of the trajectory's 1.34 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 29
+
+
+def test_phi4_step_compiled_for_v5e_runs_both_scans_in_the_kernels(topo):
+    """`phi4_mini_flash_vp8_longdoc`'s whole train step: the two Mamba
+    layers' forward and gradient ops lower to the kernel pair and no
+    `while` is left in the step (the chunked form's loops were the only
+    ones), `Starts` is kept every 64 tokens, and the temporaries are
+    under the 3.727 GB this compile gave the chunked form (PR 44's
+    tree)."""
+    text, bumped, memory = _step_for_v5e(topo, "phi4_mini_flash_vp8_longdoc")
+    assert bumped["ssm_dispatch_pallas"] == 2
+    assert "ssm_dispatch_chunked" not in bumped or not bumped[
+        "ssm_dispatch_chunked"]
+    assert profiler.counters()["ssm_chunk_len"] == 64
+    assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
+    assert " while(" not in text
+    assert memory.temp_size_in_bytes < 3.4e9
+
+
 @pytest.mark.parametrize("kernel", [True, False], ids=["kernels", "plain"])
 def test_one_layers_step_compiled_for_v5e_makes_nine_products_and_twelve(
         topo, kernel):

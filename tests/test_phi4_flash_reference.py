@@ -443,8 +443,11 @@ def test_gauges_and_counters_at_the_rehearsal_size():
         return after.get(name, 0) - before.get(name, 0)
 
     # the scans' gradient ops read the states kept and replay nothing; the
-    # attention's lower their forward again
+    # attention's lower their forward again. A state of 4 keeps the chunked
+    # form whatever the backend (on the chip at the published 16: the
+    # kernels, 2 and 0, `falls` prints them)
     assert bumped("ssm_dispatch_chunked") == 2
+    assert bumped("ssm_dispatch_pallas") == 0
     assert bumped("attn_dispatch_xla") == 2 * 6
     assert bumped("attn_dispatch_flash") == 0
     ops = [op.type for op in main.global_block().ops]
@@ -593,8 +596,9 @@ def loss_falls(seeds, steps=44, rate=None):
     c1 = profiler.counters()
     print("counters of", len(seeds), "train steps' traces:", {
         n: c1.get(n, 0) - c0.get(n, 0) for n in (
-            "ssm_dispatch_chunked", "attn_dispatch_flash",
-            "attn_dispatch_flash_window", "flash_wide_value_calls",
+            "ssm_dispatch_pallas", "ssm_dispatch_chunked",
+            "attn_dispatch_flash", "attn_dispatch_flash_window",
+            "flash_wide_value_calls",
             "flash_narrow_value_calls", "flash_fwd_wide_key_calls",
             "attn_dispatch_xla")},
         {n: c1.get(n) for n in (
