@@ -58,27 +58,78 @@ import jax.numpy as jnp
 
 from .. import profiler
 from .pallas import kda_chunk as kda_kernel
+from .pallas import short_conv as conv_kernel
 from .pallas.kda_chunk import CHUNK, SUB  # SUB: see the module docstring
 from .registry import register_op
 
 
-@jax.checkpoint
-def short_conv(x, w, bias=None):
-    """Causal depthwise convolution over time with zero left state, then
-    SiLU. x: [b, s, c]; w: [c, width]; bias: [c] or None;
-    `out_t = SiLU(sum_i w[:, i] x_{t-width+1+i} + bias)`."""
+def _conv_taps(x, w, bias):
+    """The convolution before the SiLU, float32 whatever x arrives in:
+    four products, three sums and the SiLU would each round to bf16 under
+    AMP, and XLA fuses them all."""
     width = w.shape[1]
     s = x.shape[1]
-    # float32 inside, whatever x arrives in: four products, three sums and
-    # the SiLU would each round to bf16 under AMP, and XLA fuses them all.
-    # Under `jax.checkpoint`, so that the backward keeps x as it arrived
-    # and not its float32 copy (67 MB a convolution at 4,096 tokens).
     xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (width - 1, 0), (0, 0)))
     out = sum(xp[:, i:i + s, :] * w[:, i].astype(jnp.float32)
               for i in range(width))
     if bias is not None:
         out = out + bias.astype(jnp.float32)
+    return xp, out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def short_conv(x, w, bias=None, mesh=None):
+    """Causal depthwise convolution over time with zero left state, then
+    SiLU. x: [b, s, c]; w: [c, width]; bias: [c] or None;
+    `out_t = SiLU(sum_i w[:, i] x_{t-width+1+i} + bias)`. `mesh` is the
+    Program's, for the backward's kernel.
+
+    The backward keeps x as it arrived and not its float32 copy (67 MB a
+    convolution at 4,096 tokens) and makes the taps again. It is written
+    out, not left to `jax.vjp`: the transpose of a shifted slice is a pad,
+    and XLA kept the four padded float32 products of dx as arrays of their
+    own (0.27 GB written and read again a convolution at 4,096 x 4,096).
+    Where `ops/pallas/short_conv.py::short_conv_viable` admits the call it
+    is that module's one kernel (counter `short_conv_dispatch_pallas`);
+    elsewhere the same formulas in XLA, dx from four shifted slices of the
+    one float32 `dpre` (counter `short_conv_dispatch_xla`)."""
+    _, out = _conv_taps(x, w, bias)
     return (out * jax.nn.sigmoid(out)).astype(x.dtype)
+
+
+def _short_conv_fwd(x, w, bias, mesh):
+    return short_conv(x, w, bias, mesh), (x, w, bias)
+
+
+def _short_conv_bwd(mesh, res, dout):
+    x, w, bias = res
+    width = w.shape[1]
+    s = x.shape[1]
+    # tied to the cotangent, as `jax.checkpoint` ties a replay: XLA would
+    # else take the forward's float32 taps for these and hold them from
+    # forward to backward
+    x, dout = jax.lax.optimization_barrier((x, dout))
+    if conv_kernel.short_conv_viable(x.shape[0], s, x.shape[2], width, mesh):
+        profiler.bump_counter("short_conv_dispatch_pallas")
+        dx, dw, dbias = conv_kernel.short_conv_bwd(x, w, bias, dout, mesh)
+    else:
+        profiler.bump_counter("short_conv_dispatch_xla")
+        xp, out = _conv_taps(x, w, bias)
+        sig = jax.nn.sigmoid(out)
+        dpre = dout.astype(jnp.float32) * (sig * (1 + out * (1 - sig)))
+        dw = jnp.stack([jnp.sum(dpre * xp[:, i:i + s, :], axis=(0, 1))
+                        for i in range(width)], axis=1)
+        # x_t is tap i of the outputs t + width-1-i that exist
+        later = jnp.pad(dpre, ((0, 0), (0, width - 1), (0, 0)))
+        dx = sum(later[:, width - 1 - i:width - 1 - i + s, :]
+                 * w[:, i].astype(jnp.float32)
+                 for i in range(width)).astype(x.dtype)
+        dbias = None if bias is None else jnp.sum(dpre, axis=(0, 1))
+    return (dx, dw.astype(w.dtype),
+            None if bias is None else dbias.astype(bias.dtype))
+
+
+short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)
 
 
 @register_op("short_conv1d")
@@ -86,7 +137,7 @@ def _short_conv1d(ctx, op):
     # without a `Bias` input the traced jaxpr is what it was before the op
     # took one (tests/test_kimi_linear_reference.py compares the two)
     ctx.out(op, "Out", short_conv(ctx.in_(op, "X"), ctx.in_(op, "Filter"),
-                                  ctx.in_(op, "Bias")))
+                                  ctx.in_(op, "Bias"), ctx.mesh))
 
 
 def kda_gate(g_raw, a_log, dt_bias, num_heads):

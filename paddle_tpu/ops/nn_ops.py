@@ -4,13 +4,17 @@ Capability parity with the dense-op core of reference
 paddle/fluid/operators/ (conv_op.cc, pool_op.cc, batch_norm_op.cc,
 layer_norm_op.cc, dropout_op.cc, softmax_op.cc,
 softmax_with_cross_entropy_op.cc, cross_entropy_op.cc, lookup_table_op.cc).
-Convs lower to lax.conv_general_dilated (MXU path); the embedding grad is the
-vjp scatter-add — the dense equivalent of the reference's SelectedRows rows
-(framework/selected_rows.h:32), per SURVEY.md §7 hard-part 3.
+Convs lower to lax.conv_general_dilated (MXU path); the embedding grad is
+dense, the equivalent of the reference's SelectedRows rows
+(framework/selected_rows.h:32), per SURVEY.md §7 hard-part 3: grouped
+products over the tokens sorted by id where
+`ops/pallas/embedding_grad.py::embedding_grad_viable` admits the call, the
+vjp's scatter-add everywhere else (the section comment at `lookup_table`).
 """
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 
@@ -1221,11 +1225,56 @@ def _kldiv_loss(ctx, op):
 
 # ---------------------------------------------------------------------------
 # embedding (reference: operators/lookup_table_op.cc)
+#
+# The forward is `take(W, ids)` cast to the AMP dtype. Its gradient op is the
+# generic `__auto_grad__`, `jax.vjp` of this lowering, on one of two paths
+# that the lowering picks from what it can see (the tokens, the table's
+# shape, the cotangent's dtype, the mesh, the table's stated sharding):
+# - `embedding_grad_viable`: a `jax.custom_vjp` round the gather and the cast,
+#   whose backward is `ops/pallas/embedding_grad.py`'s grouped products over
+#   the tokens sorted by id (a bf16 cotangent, rows of 768 lanes or more in
+#   whole 128s, a TPU or the interpreter, one device or a mesh that shards
+#   `batch` alone with the table whole); counter
+#   `embed_grad_dispatch_grouped`;
+# - everywhere else `jnp.take`'s own gradient, XLA's sorted scatter-add;
+#   counter `embed_grad_dispatch_scatter`.
+# The clamp of ids below 0 and `padding_idx` lie outside the `custom_vjp`,
+# so both behave alike on either path. Nothing but shapes chooses.
+# The counters count lowerings of this op, like `moe_dispatch_*` and
+# `ssm_dispatch_*`: a train step lowers it twice a table (the forward op and
+# the gradient op's replay of it), a forward-only Program once, where the
+# counter says which path a gradient would take and none is built.
 # ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _take_rows_grouped(w, idx, dtype, mesh, like):
+    """`take(w, idx).astype(dtype)` whose gradient for the table is
+    `ops/pallas/embedding_grad.py`'s grouped products. `like` is the
+    table's `(rows, dtype)`, which is all the backward needs of it."""
+    return jnp.take(w, idx, axis=0).astype(dtype)
+
+
+def _take_rows_fwd(w, idx, dtype, mesh, like):
+    return _take_rows_grouped(w, idx, dtype, mesh, like), idx
+
+
+def _take_rows_bwd(dtype, mesh, like, idx, dy):
+    from .pallas.embedding_grad import embedding_grad
+
+    vocab, table_dtype = like
+    dw = embedding_grad(idx.reshape(-1), dy.reshape(-1, dy.shape[-1]),
+                        vocab, mesh)
+    return dw.astype(table_dtype), None
+
+
+_take_rows_grouped.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
 @register_op("lookup_table", no_grad_inputs=("Ids",))
 def _lookup_table(ctx, op):
+    from .pallas.embedding_grad import embedding_grad_viable, run_rows
+
     w = ctx.in_(op, "W")
     ids = ctx.in_(op, "Ids")
     padding_idx = op.attr("padding_idx", -1)
@@ -1233,9 +1282,22 @@ def _lookup_table(ctx, op):
     squeeze_last = idx.ndim >= 2 and idx.shape[-1] == 1
     if squeeze_last:
         idx = idx.squeeze(-1)
-    out = jnp.take(w, jnp.maximum(idx, 0), axis=0)
+    rows = jnp.maximum(idx, 0)
     # AMP: cast the gathered rows, not the whole table (HBM traffic)
-    (out,) = ctx.amp_cast(op, out)
+    amp = ctx.amp_dtype_for(op)
+    floating = jnp.issubdtype(w.dtype, jnp.floating)
+    dtype = jnp.dtype(w.dtype if amp is None or not floating else amp)
+    specs = getattr(ctx.program, "_sharding_specs", None) or {}
+    if w.ndim == 2 and embedding_grad_viable(
+            rows.size, w.shape[0], w.shape[1], dtype, ctx.mesh,
+            specs.get(op.input("W")[0])):
+        profiler.bump_counter("embed_grad_dispatch_grouped")
+        profiler.set_counter("embed_grad_run_rows", run_rows(w.shape[0]))
+        out = _take_rows_grouped(w, rows, dtype, ctx.mesh,
+                                 (w.shape[0], jnp.dtype(w.dtype)))
+    else:
+        profiler.bump_counter("embed_grad_dispatch_scatter")
+        out = jnp.take(w, rows, axis=0).astype(dtype)
     if padding_idx is not None and padding_idx != -1:
         out = jnp.where((idx == padding_idx)[..., None], 0.0, out)
     ctx.out(op, "Out", out)

@@ -282,7 +282,7 @@ def _gmm_pallas(x, w, sizes, statics):
 
 
 def _tgmm_pallas(x, dy, sizes, statics):
-    (tm, tk, tn), interpret = statics
+    (tm, tk, tn), interpret, name, declare = statics
     rows, k = x.shape
     n, groups = dy.shape[1], sizes.shape[0]
     group, tile, _, offsets, counts = _visits(sizes, rows, tm,
@@ -308,10 +308,10 @@ def _tgmm_pallas(x, dy, sizes, statics):
             out_specs=pl.BlockSpec((None, tk, tn), o_map)),
         compiler_params=_params(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="moe_tgmm",
-        cost_estimate=_cost(rows, k, n, (x.shape, x.dtype),
-                            (dy.shape, dy.dtype),
-                            ((groups, k, n), jnp.float32)),
+        name=name,
+        cost_estimate=_cost(
+            rows, k, n, (x.shape, x.dtype), (dy.shape, dy.dtype),
+            ((groups, k, n), jnp.float32)) if declare else None,
     )(group, tile, offsets, counts, x, dy)
 
 
@@ -361,21 +361,24 @@ def moe_gmm(x, w, sizes, *, tiling=None):
     return _gmm_call(x, w, sizes, (tiling, _interpret()))
 
 
-def moe_tgmm(x, dy, sizes, *, tiling=None):
+def moe_tgmm(x, dy, sizes, *, tiling=None, name="moe_tgmm", declare=True):
     """x: [rows, K] and dy: [rows, N], of one dtype, sorted by group;
     sizes as `moe_gmm`'s. Returns [G, K, N] float32: `x^T dy` over the
     rows of each group, zeros for a group with none; rows past the groups
-    are not read into any sum."""
+    are not read into any sum. A caller of another layer
+    (`embedding_grad.py`) gives the `name` its events carry, so that the
+    expert layer's metrics do not count them, and says whether the call
+    declares its cost."""
     rows, k = x.shape
     n = dy.shape[1]
-    _check("moe_tgmm", x, sizes, k, n)
+    _check(name, x, sizes, k, n)
     if dy.dtype != x.dtype or dy.shape[0] != rows:
-        raise ValueError(f"moe_tgmm: x {x.shape} {x.dtype}, dy {dy.shape} "
+        raise ValueError(f"{name}: x {x.shape} {x.dtype}, dy {dy.shape} "
                          f"{dy.dtype}")
-    tiling = _whole_tiles("moe_tgmm", tiling or _tiling(
+    tiling = _whole_tiles(name, tiling or _tiling(
         rows, k, n, lambda tm, tk, tn: (  # x and dy, the float32 gradient
             2 * tm * (tk + tn) * x.dtype.itemsize + 2 * 4 * tk * tn)), k, n)
-    return _tgmm_call(x, dy, sizes, (tiling, _interpret()))
+    return _tgmm_call(x, dy, sizes, (tiling, _interpret(), name, declare))
 
 
 @jax.custom_vjp

@@ -546,6 +546,45 @@ def test_short_conv1d_with_and_without_its_bias(bias):
                 a, f, None))(a, f))
 
 
+@pytest.mark.parametrize("bias", [False, True], ids=["no_bias", "bias"])
+def test_short_conv_backward_written_out_is_the_vjp_of_the_taps(bias):
+    """`short_conv`'s backward (a `jax.custom_vjp`) against `jax.vjp` of
+    the same taps with bf16 rows, as AMP gives them: dx, dw and dbias to
+    float32 rounding, each in its operand's dtype. And its shape: the
+    cotangent is padded once and sliced, where the transpose of the
+    forward's slices pads each tap's float32 product (four arrays of
+    `[b, s + 3, c]` that XLA kept in HBM: PERF.md, PR 46)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import linear_attn_ops as ops
+
+    rng = np.random.RandomState(3)
+    x = jnp.asarray(rng.randn(2, 37, 256), jnp.bfloat16)
+    w = jnp.asarray(rng.randn(256, 4) * 0.5, jnp.float32)
+    b = jnp.asarray(rng.randn(256) * 0.5, jnp.float32) if bias else None
+    dy = jnp.asarray(rng.randn(2, 37, 256), jnp.bfloat16)
+
+    def plain(x, w, b=None):
+        out = ops._conv_taps(x, w, b)[1]
+        return (out * jax.nn.sigmoid(out)).astype(x.dtype)
+
+    operands = (x, w, b) if bias else (x, w)
+    y, pull = jax.vjp(lambda *a: ops.short_conv(*a), *operands)
+    y_want, pull_want = jax.vjp(plain, *operands)
+    np.testing.assert_array_equal(np.asarray(y, np.float32),
+                                  np.asarray(y_want, np.float32))
+    for got, want, like in zip(pull(dy), pull_want(dy), operands):
+        assert got.dtype == like.dtype and got.shape == like.shape
+        got, want = (np.asarray(t, np.float32) for t in (got, want))
+        tol = 2e-2 if like is x else 1e-4  # dx is rounded to bf16
+        np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    text = str(jax.make_jaxpr(lambda *a: jax.vjp(
+        lambda *o: ops.short_conv(*o), *a)[1](dy))(*operands))
+    assert text.count(" pad[") == 3  # x forward and again, the cotangent once
+    assert "optimization_barrier" in text
+
+
 def test_counters_and_flops_of_the_cell():
     from benchmark.models import kimi_linear as adapter
     from paddle_tpu import profiler

@@ -612,14 +612,20 @@ def test_scan_kernels_compile_for_a_v5e_chip_at_the_published_widths(topo):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 29
 
 
-def test_phi4_step_compiled_for_v5e_runs_both_scans_in_the_kernels(topo):
+@pytest.fixture(scope="module")
+def phi4_step(topo):
+    """Once for the tests that read it."""
+    return _step_for_v5e(topo, "phi4_mini_flash_vp8_longdoc")
+
+
+def test_phi4_step_compiled_for_v5e_runs_both_scans_in_the_kernels(phi4_step):
     """`phi4_mini_flash_vp8_longdoc`'s whole train step: the two Mamba
     layers' forward and gradient ops lower to the kernel pair and no
     `while` is left in the step (the chunked form's loops were the only
     ones), `Starts` is kept every 64 tokens, and the temporaries are
     under the 3.727 GB this compile gave the chunked form (PR 44's
     tree)."""
-    text, bumped, memory = _step_for_v5e(topo, "phi4_mini_flash_vp8_longdoc")
+    text, bumped, memory = phi4_step
     assert bumped["ssm_dispatch_pallas"] == 2
     assert "ssm_dispatch_chunked" not in bumped or not bumped[
         "ssm_dispatch_chunked"]
@@ -627,6 +633,49 @@ def test_phi4_step_compiled_for_v5e_runs_both_scans_in_the_kernels(topo):
     assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
     assert " while(" not in text
     assert memory.temp_size_in_bytes < 3.4e9
+
+
+def test_phi4_step_compiled_for_v5e_sums_the_tables_gradient_as_products(
+        phi4_step):
+    """The same step: the tied table's gradient from the embedding is the
+    grouped products (`ops/pallas/embedding_grad.py`; the forward op's
+    lowering and the gradient op's replay of it bump the counter), 49 runs
+    of 512 rows under the kernel's own name, no `scatter` is left under
+    `bwd/lookup_table_grad`, and the temporaries are no larger than the
+    3.218 GB this compile gave the scatter (PR 45's tree)."""
+    text, bumped, memory = phi4_step
+    assert bumped["embed_grad_dispatch_grouped"] == 2
+    assert not bumped.get("embed_grad_dispatch_scatter")
+    assert profiler.counters()["embed_grad_run_rows"] == 512
+    under = [line for line in text.splitlines()
+             if "bwd/lookup_table_grad" in line]
+    assert under and not any(" scatter(" in line for line in under)
+    assert any("embed_tgmm" in line and "f32[49,512,2560]" in line
+               for line in under)
+    # the expert layer's name is on no call (the text's table of stack
+    # frames does hold the function `moe_tgmm`, which the embedding calls)
+    assert " custom-call(" in text and "%moe_tgmm" not in text
+    assert memory.temp_size_in_bytes <= 3.218e9
+
+
+def test_phi4_step_compiled_for_v5e_runs_each_conv_backward_in_one_call(
+        phi4_step):
+    """The same step: each of the two Mamba layers' `short_conv1d_grad` is
+    one `short_conv_bwd` call (`ops/pallas/short_conv.py`) over the row's
+    4,096 tokens and 5,120 channels as they arrive, and no float32 array
+    of that shape is left under the scope (XLA's backward kept dpre, and
+    before PR 46 four padded products of it)."""
+    text, bumped, _ = phi4_step
+    assert bumped["short_conv_dispatch_pallas"] == 2
+    assert not bumped.get("short_conv_dispatch_xla")
+    under = [line for line in text.splitlines()
+             if "bwd/short_conv1d_grad" in line]
+    calls = [line for line in under if "short_conv_bwd" in line
+             and " custom-call(" in line]
+    assert len(calls) == 2
+    assert all("bf16[1,4096,5120]" in line and "f32[1,8,5120]" in line
+               for line in calls)
+    assert not any(" = f32[1,4096,5120]" in line for line in under)
 
 
 @pytest.mark.parametrize("kernel", [True, False], ids=["kernels", "plain"])
