@@ -709,12 +709,17 @@ def rotary_embedding(input, theta=10000.0, name=None, rope_scaling=None,
         dtype=input.dtype, shape=input.shape)
 
 
-def short_conv1d(input, width=4, param_attr=None, name=None, bias_attr=None):
+def short_conv1d(input, width=4, param_attr=None, name=None, bias_attr=None,
+                 act="silu"):
     """Causal depthwise convolution over time, zero state at the start of
     a sequence, then SiLU: input [b, s, c], filter [c, width],
     `out_t = SiLU(sum_i filter[:, i] * input_{t-width+1+i} + bias)`. No
     bias unless `bias_attr`, a `ParamAttr`, is given: a [c] parameter
-    inside the SiLU, seeded 0 unless the attribute says otherwise."""
+    inside the SiLU, seeded 0 unless the attribute says otherwise. `act`
+    None: the sum itself, no activation (the op then carries the
+    attribute `activation` "none")."""
+    if act not in ("silu", None):
+        raise ValueError(f"short_conv1d: act {act!r}: expected 'silu' or None")
     helper = LayerHelper("short_conv1d", name=name)
     channels = int(input.shape[-1])
     w = helper.create_parameter(param_attr, [channels, width],
@@ -723,7 +728,8 @@ def short_conv1d(input, width=4, param_attr=None, name=None, bias_attr=None):
     if bias_attr:
         inputs["Bias"] = [helper.create_parameter(
             bias_attr, [channels], dtype="float32", is_bias=True)]
-    return _single_out(helper, "short_conv1d", inputs, {},
+    return _single_out(helper, "short_conv1d", inputs,
+                       {} if act else {"activation": "none"},
                        dtype=input.dtype, shape=input.shape)
 
 
@@ -781,15 +787,16 @@ def kda_attention(q, k, v, g, beta, num_heads, l2norm_epsilon=1e-6,
 
 def moe_experts(input, experts_total, experts_held, d_ff, k, held_from=0,
                 scaling=1.0, renormalize=True, bias_scale=0.0,
-                param_attr=None, name=None, score_func="sigmoid"):
+                param_attr=None, name=None, score_func="sigmoid",
+                norm_eps=0.0):
     """The held experts' part of a dropless expert layer (SiLU-gated
     FFNs of width `d_ff`): a router over all `experts_total` (`score_func`
     "sigmoid": each expert's own sigmoid; "softmax": the probabilities
     over all of them, float32) picks `k` a token by `score + bias`, weights
-    them `scaling * score / sum of the selected scores`, and the assignments
-    to the `experts_held` experts from `held_from` on run through one
-    grouped product, every one of them, whatever the skew. What experts
-    held elsewhere would add is left out. `bias` is the router's
+    them `scaling * score / (sum of the selected scores + norm_eps)`, and
+    the assignments to the `experts_held` experts from `held_from` on run
+    through one grouped product, every one of them, whatever the skew.
+    What experts held elsewhere would add is left out. `bias` is the router's
     correction: persistable, seeded Normal(0, bias_scale), never
     trained. Returns (out like input, load [experts_held] int32)."""
     if score_func not in ("sigmoid", "softmax"):
@@ -825,7 +832,8 @@ def moe_experts(input, experts_total, experts_held, d_ff, k, held_from=0,
         attrs={"experts_total": int(experts_total),
                "experts_held": int(experts_held), "held_from": int(held_from),
                "k": int(k), "scaling": float(scaling),
-               "renormalize": bool(renormalize), "score_func": score_func},
+               "renormalize": bool(renormalize), "score_func": score_func,
+               **({"norm_eps": float(norm_eps)} if norm_eps else {})},
     )
     return out, load
 

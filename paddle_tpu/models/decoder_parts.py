@@ -1,22 +1,24 @@
 """What the decoders of the zoo (`kimi_linear`, `trinity`, `mellum`,
-`joyai_flash`, `phi4_flash`) build their layers from: projections seeded
-Normal(0, `initializer_range`), with a bias where asked, RMSNorm with a
-learned weight and LayerNorm with weight and bias, the SiLU-gated
+`joyai_flash`, `phi4_flash`, `lfm2`) build their layers from: projections
+seeded Normal(0, `initializer_range`), with a bias where asked, RMSNorm
+with a learned weight and LayerNorm with weight and bias, the SiLU-gated
 feed-forward as three products or with gate and up in one, attention over
 grouped key/value heads with QK-norm and rotary positions, latent
 attention (`kimi_linear`, `joyai_flash`), differential attention
-(`phi4_flash`), and the expert layer that holds a share of the experts. A
-`cfg` gives `hidden_size`, `initializer_range`, `rms_norm_eps` (or
-`layer_norm_eps`), for `attention` and `differential_attention` the
-heads, for `latent_attention` the keys its docstring lists, and for
-`expert_ffn` the router's keys as `KimiLinearConfig` names them."""
+(`phi4_flash`), the double-gated short convolution (`lfm2`), and the
+expert layer that holds a share of the experts. A `cfg` gives
+`hidden_size`, `initializer_range`, `rms_norm_eps` (or `layer_norm_eps`),
+for `attention` and `differential_attention` the heads, for
+`latent_attention` the keys its docstring lists, for `gated_short_conv`
+`conv_L_cache`, and for `expert_ffn` the router's keys as
+`KimiLinearConfig` names them."""
 
 from __future__ import annotations
 
 import math
 
 from .. import layers
-from ..initializer import Normal
+from ..initializer import Normal, Uniform
 from ..param_attr import ParamAttr
 
 
@@ -55,6 +57,25 @@ def fused_ffn(u, width, name, cfg):
     gate, up = layers.split(proj(u, 2 * width, name + ".fc1", cfg), 2, dim=2)
     return proj(layers.elementwise_mul(up, layers.swish(gate)),
                 cfg.hidden_size, name + ".fc2", cfg)
+
+
+def gated_short_conv(u, cfg, name):
+    """LFM2's mixer, u [b, s, hidden] to [b, s, hidden]:
+    `W_out (C * conv(B * x))` with `[B ; C ; x] = W_in u`, three chunks of
+    `hidden` in this order, and `conv` the causal depthwise convolution
+    over `conv_L_cache` taps from a zero state, with no bias and no
+    activation: the two gates are what is non-linear. The filter is
+    seeded uniform in +-`conv_L_cache`^-1/2 (a depthwise filter's fan-in
+    is its width). Products and gates are the Program's ordinary ops."""
+    b_gate, c_gate, xs = layers.split(
+        proj(u, 3 * cfg.hidden_size, name + ".in_proj", cfg), 3, dim=2)
+    edge = cfg.conv_L_cache ** -0.5
+    conv = layers.short_conv1d(
+        layers.elementwise_mul(b_gate, xs), cfg.conv_L_cache,
+        param_attr=ParamAttr(name=name + ".conv.w_0",
+                             initializer=Uniform(-edge, edge)), act=None)
+    return proj(layers.elementwise_mul(c_gate, conv), cfg.hidden_size,
+                name + ".out_proj", cfg)
 
 
 def _by_pairs(t, b, s, pairs, d):
@@ -197,15 +218,16 @@ def latent_attention(u, cfg, name):
                 name + ".o", cfg)
 
 
-def expert_ffn(u, cfg, name):
-    """Returns (what the shared expert and the held experts add, load)."""
+def expert_ffn(u, cfg, name, norm_eps=0.0):
+    """Returns (what the shared expert and the held experts add, load).
+    `norm_eps`: added to the sum the selected scores are divided by."""
     routed, load = layers.moe_experts(
         u, experts_total=cfg.num_experts, experts_held=cfg.experts_held,
         d_ff=cfg.moe_intermediate_size, k=cfg.num_experts_per_token,
         held_from=cfg.held_from, scaling=cfg.routed_scaling_factor,
         renormalize=cfg.moe_renormalize, bias_scale=cfg.router_bias_scale,
         param_attr=attr(name + ".moe", cfg),
-        score_func=cfg.score_func)
+        score_func=cfg.score_func, norm_eps=norm_eps)
     if not cfg.num_shared_experts:
         return routed, load
     shared = ffn(u, cfg.moe_intermediate_size * cfg.num_shared_experts,

@@ -63,8 +63,11 @@ from .pallas.kda_chunk import CHUNK, SUB  # SUB: see the module docstring
 from .registry import register_op
 
 
+ACTIVATIONS = ("silu", "none")  # what follows the short convolution's taps
+
+
 def _conv_taps(x, w, bias):
-    """The convolution before the SiLU, float32 whatever x arrives in:
+    """The convolution before its activation, float32 whatever x arrives in:
     four products, three sums and the SiLU would each round to bf16 under
     AMP, and XLA fuses them all."""
     width = w.shape[1]
@@ -77,12 +80,13 @@ def _conv_taps(x, w, bias):
     return xp, out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def short_conv(x, w, bias=None, mesh=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def short_conv(x, w, bias=None, mesh=None, activation="silu"):
     """Causal depthwise convolution over time with zero left state, then
     SiLU. x: [b, s, c]; w: [c, width]; bias: [c] or None;
-    `out_t = SiLU(sum_i w[:, i] x_{t-width+1+i} + bias)`. `mesh` is the
-    Program's, for the backward's kernel.
+    `out_t = SiLU(sum_i w[:, i] x_{t-width+1+i} + bias)`, or with
+    `activation` "none" the sum itself (a convolution between two gates).
+    `mesh` is the Program's, for the backward's kernel.
 
     The backward keeps x as it arrived and not its float32 copy (67 MB a
     convolution at 4,096 tokens) and makes the taps again. It is written
@@ -92,16 +96,22 @@ def short_conv(x, w, bias=None, mesh=None):
     Where `ops/pallas/short_conv.py::short_conv_viable` admits the call it
     is that module's one kernel (counter `short_conv_dispatch_pallas`);
     elsewhere the same formulas in XLA, dx from four shifted slices of the
-    one float32 `dpre` (counter `short_conv_dispatch_xla`)."""
+    one float32 `dpre` (counter `short_conv_dispatch_xla`). Without the
+    SiLU `dpre` is the cotangent (counter `short_conv_linear_calls`)."""
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"short_conv: activation {activation!r}: expected "
+                         f"one of {ACTIVATIONS}")
     _, out = _conv_taps(x, w, bias)
-    return (out * jax.nn.sigmoid(out)).astype(x.dtype)
+    if activation == "silu":
+        out = out * jax.nn.sigmoid(out)
+    return out.astype(x.dtype)
 
 
-def _short_conv_fwd(x, w, bias, mesh):
-    return short_conv(x, w, bias, mesh), (x, w, bias)
+def _short_conv_fwd(x, w, bias, mesh, activation):
+    return short_conv(x, w, bias, mesh, activation), (x, w, bias)
 
 
-def _short_conv_bwd(mesh, res, dout):
+def _short_conv_bwd(mesh, activation, res, dout):
     x, w, bias = res
     width = w.shape[1]
     s = x.shape[1]
@@ -109,14 +119,21 @@ def _short_conv_bwd(mesh, res, dout):
     # else take the forward's float32 taps for these and hold them from
     # forward to backward
     x, dout = jax.lax.optimization_barrier((x, dout))
+    silu = activation == "silu"
+    if not silu:
+        profiler.bump_counter("short_conv_linear_calls")
     if conv_kernel.short_conv_viable(x.shape[0], s, x.shape[2], width, mesh):
         profiler.bump_counter("short_conv_dispatch_pallas")
-        dx, dw, dbias = conv_kernel.short_conv_bwd(x, w, bias, dout, mesh)
+        dx, dw, dbias = conv_kernel.short_conv_bwd(x, w, bias, dout, mesh,
+                                                   activation)
     else:
         profiler.bump_counter("short_conv_dispatch_xla")
         xp, out = _conv_taps(x, w, bias)
-        sig = jax.nn.sigmoid(out)
-        dpre = dout.astype(jnp.float32) * (sig * (1 + out * (1 - sig)))
+        if silu:
+            sig = jax.nn.sigmoid(out)
+            dpre = dout.astype(jnp.float32) * (sig * (1 + out * (1 - sig)))
+        else:
+            dpre = dout.astype(jnp.float32)
         dw = jnp.stack([jnp.sum(dpre * xp[:, i:i + s, :], axis=(0, 1))
                         for i in range(width)], axis=1)
         # x_t is tap i of the outputs t + width-1-i that exist
@@ -135,9 +152,11 @@ short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)
 @register_op("short_conv1d")
 def _short_conv1d(ctx, op):
     # without a `Bias` input the traced jaxpr is what it was before the op
-    # took one (tests/test_kimi_linear_reference.py compares the two)
+    # took one (tests/test_kimi_linear_reference.py compares the two),
+    # and without an `activation` attribute what it was before that
     ctx.out(op, "Out", short_conv(ctx.in_(op, "X"), ctx.in_(op, "Filter"),
-                                  ctx.in_(op, "Bias"), ctx.mesh))
+                                  ctx.in_(op, "Bias"), ctx.mesh,
+                                  op.attr("activation", "silu")))
 
 
 def kda_gate(g_raw, a_log, dt_bias, num_heads):

@@ -42,7 +42,8 @@ def _moe_ffn(ctx, op):
 def _moe_experts(ctx, op):
     """X: [..., D]; Gate: [D, experts_total]; Bias: [experts_total], the
     router's correction (selection only, no gradient); attr `score_func`
-    "sigmoid" (the default) or "softmax"; WGate, WUp:
+    "sigmoid" (the default) or "softmax"; attr `norm_eps` (optional, 0)
+    added to the renormalisation's sum; WGate, WUp:
     [experts_held, D, F]; WDown: [experts_held, F, D]. Out like X: what
     the held experts add. Load: [experts_held] int32."""
     from .. import profiler
@@ -88,6 +89,7 @@ def _moe_experts(ctx, op):
         k=k, scaling=op.attr("scaling", 1.0),
         experts_held=held, held_from=op.attr("held_from", 0),
         renormalize=op.attr("renormalize", True),
-        compute_dtype=compute_dtype, score_func=score_func, kernel=kernel)
+        compute_dtype=compute_dtype, score_func=score_func, kernel=kernel,
+        norm_eps=op.attr("norm_eps", 0.0))
     ctx.out(op, "Out", y)
     ctx.out(op, "Load", load)

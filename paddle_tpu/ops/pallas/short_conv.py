@@ -1,7 +1,8 @@
 """The short causal convolution's backward in one Pallas TPU kernel.
 
 `ops/linear_attn_ops.py::short_conv` is `SiLU(sum_i w[:, i] x_{t-width+1+i}
-+ bias)` over `[b, s, c]`, depthwise. Its backward in XLA is, a
++ bias)` over `[b, s, c]`, depthwise, or that sum with no activation. Its
+backward in XLA is, a
 convolution, a float32 copy of x, one fusion that makes the taps again,
 writes the float32 `dpre` and reduces the taps' gradients, and one more
 that reads `dpre` at four offsets for dx: 0.3 GB through HBM a
@@ -53,7 +54,7 @@ def short_conv_viable(batch, s, c, width, mesh):
             and on_mesh.batch_shards(mesh, batch) > 0)
 
 
-def _kernel(x_ref, w_ref, b_ref, dy_ref, dx_ref, part_ref, *, width):
+def _kernel(x_ref, w_ref, b_ref, dy_ref, dx_ref, part_ref, *, width, silu):
     x = x_ref[...].astype(jnp.float32)  # [s, 128]
     dy = dy_ref[...].astype(jnp.float32)
     s = x.shape[0]
@@ -68,9 +69,12 @@ def _kernel(x_ref, w_ref, b_ref, dy_ref, dx_ref, part_ref, *, width):
             row < s - k, pltpu.roll(t, s - k, 0), 0.0)
 
     taps = [back(x, width - 1 - i) for i in range(width)]
-    pre = b_ref[...] + sum(taps[i] * w_ref[i:i + 1, :] for i in range(width))
-    sig = jax.nn.sigmoid(pre)
-    dpre = dy * (sig * (1 + pre * (1 - sig)))
+    dpre = dy
+    if silu:
+        pre = b_ref[...] + sum(taps[i] * w_ref[i:i + 1, :]
+                               for i in range(width))
+        sig = jax.nn.sigmoid(pre)
+        dpre = dy * (sig * (1 + pre * (1 - sig)))
     part_ref[...] = jnp.zeros(part_ref.shape, jnp.float32)
     for i in range(width):
         part_ref[i:i + 1, :] = jnp.sum(dpre * taps[i], axis=0, keepdims=True)
@@ -80,22 +84,25 @@ def _kernel(x_ref, w_ref, b_ref, dy_ref, dx_ref, part_ref, *, width):
     dx_ref[...] = dx.astype(dx_ref.dtype)
 
 
-def _cost(b, s, c, width, x_dtype, dy_dtype):
+def _cost(b, s, c, width, x_dtype, dy_dtype, silu):
     """`cost.py`'s convention for a kernel with no product, one FLOP an
     arithmetic operation of the formulas an element: the taps made again
     (`2 width`), the bias, the SiLU's derivative (8), the taps' gradients
     and dx (`2 width` each) and the bias's: `6 width + 10`; the logistic
-    one exponential and one reciprocal; x, the cotangent and dx once, the
-    taps, the bias and the partial rows beside them."""
+    one exponential and one reciprocal. Without the SiLU the taps'
+    gradients, dx and the bias's alone: `4 width + 1`, no transcendental.
+    x, the cotangent and dx once, the taps, the bias and the partial rows
+    beside them."""
     return cost.estimate(
-        b * s * c * (6 * width + 10), 2 * b * s * c,
+        b * s * c * (6 * width + 10 if silu else 4 * width + 1),
+        2 * b * s * c if silu else 0,
         ((b, s, c), x_dtype), ((b, s, c), dy_dtype), ((b, s, c), x_dtype),
         ((width + 1, c), jnp.float32),
         ((b, PARTIAL_ROWS, c), jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "mesh"))
-def _call(x, w_rows, b_row, dy, *, interpret, mesh):
+@functools.partial(jax.jit, static_argnames=("interpret", "mesh", "silu"))
+def _call(x, w_rows, b_row, dy, *, interpret, mesh, silu):
     """One jitted call, as the other kernels have: the convolutions of
     one shape in a step are traced and lowered once."""
     width, c = w_rows.shape
@@ -104,7 +111,7 @@ def _call(x, w_rows, b_row, dy, *, interpret, mesh):
         b, s, _ = x.shape
         rows = pl.BlockSpec((None, s, LANE), lambda i, j: (i, 0, j))
         return pl.pallas_call(
-            functools.partial(_kernel, width=width),
+            functools.partial(_kernel, width=width, silu=silu),
             grid=(b, c // LANE),
             in_specs=[rows,
                       pl.BlockSpec((width, LANE), lambda i, j: (0, j)),
@@ -120,18 +127,19 @@ def _call(x, w_rows, b_row, dy, *, interpret, mesh):
                 vmem_limit_bytes=_VMEM_LIMIT),
             interpret=interpret,
             name="short_conv_bwd",
-            cost_estimate=_cost(b, s, c, width, x.dtype, dy.dtype),
+            cost_estimate=_cost(b, s, c, width, x.dtype, dy.dtype, silu),
         )(x, w_rows, b_row, dy)
 
     return on_mesh.per_shard(run, mesh, (True, False, False, True))(
         x, w_rows, b_row, dy)
 
 
-def short_conv_bwd(x, w, bias, dy, mesh=None):
+def short_conv_bwd(x, w, bias, dy, mesh=None, activation="silu"):
     """x, dy: [b, s, c]; w: [c, width]; bias: [c] or None. Returns dx in
     x's dtype, dw [c, width] and dbias [c] (None without a bias) in
-    float32: the gradients of `SiLU(taps(x, w) + bias)` under the
-    cotangent dy, everything inside in float32."""
+    float32: the gradients of `SiLU(taps(x, w) + bias)`, or with
+    `activation` "none" of `taps(x, w) + bias`, under the cotangent dy,
+    everything inside in float32."""
     require_pallas("short_conv_bwd")
     b, s, c = x.shape
     width = w.shape[1]
@@ -145,7 +153,8 @@ def short_conv_bwd(x, w, bias, dy, mesh=None):
     b_row = (jnp.zeros((1, c), jnp.float32) if bias is None
              else bias.astype(jnp.float32)[None, :])
     dx, part = _call(x, w.astype(jnp.float32).T, b_row, dy,
-                     interpret=_interpret(), mesh=mesh)
+                     interpret=_interpret(), mesh=mesh,
+                     silu=activation == "silu")
     part = jnp.sum(part, axis=0)
     return (dx, part[:width].T,
             None if bias is None else part[width])

@@ -184,15 +184,15 @@ BLOCK_ROOM = 1.75  # times the balanced load of the share held
 
 
 def moe_route(x, gate, bias, k, scaling, renormalize=True,
-              score_func="sigmoid"):
+              score_func="sigmoid", norm_eps=0.0):
     """Router over all the experts `gate` has columns for. x: [N, D];
     gate: [D, E]; bias: [E], the correction that enters the selection and
     not the weights. The scores `s` are `sigmoid(x gate)`, each expert's
     own, or with `score_func` "softmax" the probabilities
     `softmax(x gate)` over all E. Returns (idx [N, k] int32, weights
     [N, k] float32): the k largest of `s + bias`, weighted
-    `scaling * s_i / sum_selected s_j` (without `renormalize`,
-    `scaling * s_i`). float32 throughout."""
+    `scaling * s_i / (sum_selected s_j + norm_eps)` (without
+    `renormalize`, `scaling * s_i`). float32 throughout."""
     if score_func not in ("sigmoid", "softmax"):
         raise ValueError(f"moe_route: score_func {score_func!r}: expected "
                          "'sigmoid' or 'softmax'")
@@ -204,7 +204,8 @@ def moe_route(x, gate, bias, k, scaling, renormalize=True,
         jax.lax.stop_gradient(scores + bias.astype(jnp.float32)), k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if renormalize:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
+        total = jnp.sum(w, axis=-1, keepdims=True)
+        w = w / (total + norm_eps if norm_eps else total)
     return idx.astype(jnp.int32), scaling * w
 
 
@@ -331,7 +332,8 @@ _overflow.defvjp(_overflow_fwd, _overflow_bwd)
 
 def moe_experts(x, gate, bias, w_gate, w_up, w_down, k, scaling,
                 experts_held, held_from, renormalize=True,
-                compute_dtype=None, score_func="sigmoid", kernel=False):
+                compute_dtype=None, score_func="sigmoid", kernel=False,
+                norm_eps=0.0):
     """The part of a dropless expert layer that the experts held here
     give. x: [..., D]; gate: [D, experts_total]; bias: [experts_total];
     w_gate, w_up: [experts_held, D, F]; w_down: [experts_held, F, D].
@@ -348,7 +350,7 @@ def moe_experts(x, gate, bias, w_gate, w_up, w_down, k, scaling,
     shape = x.shape
     tokens = x.reshape(-1, shape[-1])
     idx, weights = moe_route(tokens, gate, bias, k, scaling, renormalize,
-                             score_func)
+                             score_func, norm_eps)
     local = idx.reshape(-1) - held_from
     held = (local >= 0) & (local < experts_held)
     key = jnp.where(held, local, experts_held)

@@ -2,7 +2,12 @@
 kernels' `cost_estimate` (ops/pallas/cost.py has the convention) read the
 `pallas_call` equations of a jaxpr, nested ones included, and hold them
 to counts written out by hand. `operand_shapes` reads the same equations'
-operands and results, `block_shapes` their grids and blocks."""
+operands and results, `block_shapes` their grids and blocks;
+`jaxpr_digest` hashes a traced jaxpr's text, for the tests that hold a
+function's default path to what a parent commit traced."""
+
+import hashlib
+import re
 
 import jax
 from jax.extend import core as jex_core
@@ -63,3 +68,11 @@ def block_shapes(fn, *args) -> dict:
 def numbers(estimate) -> tuple:
     return (estimate.flops, estimate.transcendentals,
             estimate.bytes_accessed)
+
+
+def jaxpr_digest(fn, *args) -> str:
+    """sha256 (16 hex digits) of the text of `fn(*args)`'s jaxpr, object
+    addresses blanked: equal where two trees trace the same equations
+    under one JAX."""
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]

@@ -150,6 +150,7 @@ def _out_and_grads(fn, q, k, v, w):
     ((2, 8, 2, 300, 300, 128), 130, "bhsd"),  # groups of 4 and a window
     ((1, 4, 2, 128, 384, 64), 0, "bhsd"),     # sq != sk, a group, no window
     ((1, 4, 4, 256, 256, 128), 0, "bhsd"),    # group 1, a head of 128 lanes
+    ((1, 32, 8, 256, 256, 64), 0, "bhsd"),    # 32 over 8 heads of 64 lanes
 ], ids=lambda c: f"{c[0]}-w{c[1]}-{c[2]}")
 def test_band_and_group_match_the_plain_path(rng, case):
     """out, dq, dk, dv of the kernels, interpreted, with a window and with
@@ -538,6 +539,10 @@ _WIDTH_CASES = {
     "differential_64_128": ((1, 4, 2, 256, 64, 128), 128, 128, 0),
     "differential_64_128_s2048": ((1, 4, 2, 2048, 64, 128), 128, 128, 0),
     "wider_128_192": ((1, 4, 2, 256, 128, 192), 128, 256, 0),
+    # 32 query heads over 8 key/value heads of 64 lanes, keys and values
+    # alike: every operand travels padded to 128
+    "gqa_32_over_8_at_64": ((1, 32, 8, 256, 64, 64), 128, 128, 0),
+    "gqa_32_over_8_at_64_s2048": ((1, 32, 8, 2048, 64, 64), 128, 128, 0),
 }
 
 
@@ -694,6 +699,10 @@ _COST_CASES = {
     # ... under a 512-key window over a row twice as long
     "differential_window": ((1, 4, 2, 1024, 64, 128), 512,
                             512 * 513 // 2 + 512 * 512),
+    # four query heads a key/value head at 64 lanes, keys and values alike
+    # (both in 128 lanes inside): the count is at 64, half of what the
+    # calls execute, and K and V are moved once for the four
+    "gqa_32_over_8_at_64": ((1, 32, 8, 512, 64, 64), 0, 512 * 513 // 2),
 }
 
 

@@ -546,6 +546,74 @@ def test_short_conv1d_with_and_without_its_bias(bias):
                 a, f, None))(a, f))
 
 
+@pytest.mark.parametrize("width,bias", [(3, False), (4, True)],
+                         ids=["three_taps", "four_taps_bias"])
+def test_short_conv1d_without_its_activation(width, bias):
+    """The layer with `act=None` (LFM2's convolution, between two gates):
+    the op carries `activation` "none", its value and gradients are those
+    of the shifted products written out with nothing after them, and the
+    lowering's backward counts itself; with the default `act` the op has
+    no such attribute, as before it could."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu import profiler
+    from paddle_tpu.initializer import Uniform
+    from paddle_tpu.param_attr import ParamAttr
+    from tools.verify_bench_programs import compare_static_vs_traced
+
+    L = fluid.layers
+    x = L.data("x", [2, 21, 6], append_batch_size=False)
+    x.stop_gradient = False
+    y = L.short_conv1d(
+        x, width, param_attr=ParamAttr(name="f", initializer=Uniform(-0.5, 0.5)),
+        bias_attr=ParamAttr(name="b", initializer=Uniform(-0.5, 0.5))
+        if bias else None, act=None)
+    with_silu = L.short_conv1d(x, width, param_attr=ParamAttr(name="f"))
+    main = fluid.default_main_program()
+    linear, default = [o for o in main.global_block().ops
+                       if o.type == "short_conv1d"]
+    assert linear.attrs["activation"] == "none"
+    assert "activation" not in default.attrs
+    with pytest.raises(ValueError, match="act"):
+        L.short_conv1d(x, width, act="relu")
+    w = np.random.RandomState(1).randn(2, 21, 6).astype(np.float32)
+    loss = L.reduce_sum(L.elementwise_mul(y, L.assign(w)))
+    params = ["f", "b"] if bias else ["f"]
+    grads = fluid.backward.calc_gradient(
+        loss, [x] + [main.global_block().var(n) for n in params])
+    n, mismatches, unknown = compare_static_vs_traced(
+        main, {"x": ((2, 21, 6), "float32")})
+    assert n >= 3 and mismatches == [] and unknown == []
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    data = np.random.RandomState(0).randn(2, 21, 6).astype(np.float32)
+    before = profiler.counters().get("short_conv_linear_calls", 0)
+    got = exe.run(feed={"x": data}, fetch_list=[y, with_silu, *grads])
+    assert profiler.counters()["short_conv_linear_calls"] == before + 1
+    p = state(params)
+
+    def by_hand(a, f, b):
+        padded = jnp.pad(a, ((0, 0), (width - 1, 0), (0, 0)))
+        return sum(padded[:, i:i + 21] * f[:, i] for i in range(width)) + b
+
+    b = p["b"] if bias else jnp.zeros(6)
+    want = by_hand(data, p["f"], b)
+    np.testing.assert_allclose(got[0], want, atol=1e-6)
+    if not bias:  # the default's output is the SiLU of this one's
+        np.testing.assert_allclose(got[1], want * jax.nn.sigmoid(want),
+                                   atol=1e-6)
+    want_grads = jax.grad(lambda a, f, b: jnp.sum(by_hand(a, f, b) * w),
+                          argnums=(0, 1, 2))(jnp.asarray(data), p["f"], b)
+    for g, g_want in zip(got[2:], want_grads):
+        np.testing.assert_allclose(g, g_want, atol=1e-5)
+    # the first token sees the last tap alone, and nothing squashes it
+    np.testing.assert_allclose(
+        got[0][:, 0], p["f"][:, width - 1] * data[:, 0] + np.asarray(b),
+        atol=1e-6)
+
+
 @pytest.mark.parametrize("bias", [False, True], ids=["no_bias", "bias"])
 def test_short_conv_backward_written_out_is_the_vjp_of_the_taps(bias):
     """`short_conv`'s backward (a `jax.custom_vjp`) against `jax.vjp` of

@@ -190,3 +190,48 @@ def test_phi4_flash_tiny_builds_and_trains():
             main, feed=feed, fetch_list=[handles["loss"]])[0]).reshape(-1)[0])
             for _ in range(8)]
     assert np.isfinite(losses).all() and losses[-1] < losses[0] - 0.5
+
+
+def test_lfm2_tiny_builds_and_trains():
+    """One chip's share of LFM2 at a tiny size: the second leading dense
+    layer (a convolution layer), then an attention layer and three
+    convolution layers with experts, through `Executor.run`; the tied
+    table is one parameter."""
+    from paddle_tpu.models import Lfm2Config, build_lfm2
+
+    cfg = Lfm2Config(
+        vocab_size=96, hidden_size=32, first_layer=1, dense_layers=1,
+        layer_types=["conv", "full_attention", "conv", "conv", "conv"],
+        num_attention_heads=4, num_key_value_heads=2, intermediate_size=64,
+        moe_intermediate_size=16, num_experts=8, experts_held=4,
+        num_experts_per_token=2, router_bias_scale=0.02)
+    assert (cfg.head_dim, cfg.conv_L_cache) == (8, 3)
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 7
+    with fluid.program_guard(main, startup):
+        handles = build_lfm2(cfg, 2, 24)
+        fluid.optimizer.Adam(learning_rate=1e-2).minimize(handles["loss"])
+    assert handles["feeds"] == ["tokens", "labels"]
+    assert len(handles["loads"]) == 4
+    assert tuple(handles["logits"].shape) == (2, 24, 96)
+    ops = [op.type for op in main.global_block().ops]
+    assert ops.count("short_conv1d") == 4
+    assert ops.count("fused_multihead_attention") == 1
+    assert ops.count("moe_experts") == 4
+    names = [p.name for p in main.global_block().all_parameters()]
+    assert names.count("lfm2.embed") == 1 and "lfm2.head.w_0" not in names
+    assert "lfm2.layer1.mlp.gate.w_0" in names  # the dense layer held
+    assert "lfm2.layer2.attn.q_norm.w_0" in names
+    assert "lfm2.layer5.conv.conv.w_0" in names
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        doc = np.random.RandomState(0).randint(0, 96, (2, 25))
+        feed = {"tokens": doc[:, :-1], "labels": doc[:, 1:]}
+        out = [exe.run(main, feed=feed,
+                       fetch_list=[handles["loss"]] + handles["loads"])
+               for _ in range(8)]
+    losses = [float(np.asarray(o[0]).reshape(-1)[0]) for o in out]
+    assert np.isfinite(losses).all() and losses[-1] < losses[0] - 0.5
+    # 2 x 24 tokens x 2 a token over 4 of 8 experts: some are held here
+    assert all(0 < int(np.sum(x)) <= 96 for x in out[0][1:])

@@ -159,3 +159,106 @@ def test_value_and_gradients_equal_the_reference_at_every_block_count(
     for name, g, w in zip(("x", *trained), got, want):
         assert np.abs(w).max() > 0, name
         assert rel(g, w) < 1e-5, name
+
+
+# --------------------------- the epsilon beside the renormalisation's sum
+
+
+def test_the_renormalisations_epsilon_against_a_hand_count():
+    """Two tokens over four experts, two a token, each expert's own
+    sigmoid: scores 0.8, 0.6, 0.2, 0.1 give the two largest 0.8 and 0.6,
+    renormalised 4/7 and 3/7; over `sum + 0.1` they are 0.8/1.5 and
+    0.6/1.5, over `sum + 1e-6` a millionth of 1.4 less than 4/7 and 3/7;
+    without renormalisation the epsilon changes nothing. The op carries
+    the attribute only where it is not 0."""
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.parallel.moe import moe_route
+
+    def logit(p):
+        return np.log(np.asarray(p) / (1 - np.asarray(p)))
+
+    x = jnp.eye(2, dtype=jnp.float32)
+    gate = jnp.asarray([logit([0.8, 0.6, 0.2, 0.1]),
+                        logit([0.1, 0.2, 0.6, 0.8])], jnp.float32)
+    none = jnp.zeros(4)
+    idx, w = moe_route(x, gate, none, 2, 1.0, True)
+    assert idx.tolist() == [[0, 1], [3, 2]]
+    np.testing.assert_allclose(w, [[4 / 7, 3 / 7]] * 2, rtol=1e-6)
+    _, w = moe_route(x, gate, none, 2, 1.0, True, "sigmoid", 0.1)
+    np.testing.assert_allclose(w, [[0.8 / 1.5, 0.6 / 1.5]] * 2, rtol=1e-6)
+    _, w = moe_route(x, gate, none, 2, 3.0, True, norm_eps=1e-6)
+    np.testing.assert_allclose(
+        w, [[2.4 / 1.400001, 1.8 / 1.400001]] * 2, rtol=1e-6)
+    assert float(w[0, 0]) < 3 * 4 / 7
+    _, w = moe_route(x, gate, none, 2, 2.0, False, norm_eps=0.1)
+    np.testing.assert_allclose(w, [[1.6, 1.2]] * 2, rtol=1e-6)
+
+    u = fluid.layers.data("u", [1, 2, 2], append_batch_size=False)
+    outs = [fluid.layers.moe_experts(
+        u, experts_total=4, experts_held=4, d_ff=8, k=2,
+        param_attr=fluid.ParamAttr(name=f"m{i}"), **kw)[0]
+        for i, kw in enumerate(({}, {"norm_eps": 0.1}))]
+    plain, with_eps = [op for op in fluid.default_main_program()
+                       .global_block().ops if op.type == "moe_experts"]
+    assert "norm_eps" not in plain.attrs and with_eps.attr("norm_eps") == 0.1
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    scope = fluid.global_scope()
+    for n in ("gate", "w_gate", "w_up", "w_down"):
+        scope.set("m1." + n, np.asarray(scope.get("m0." + n)))
+    scope.set("m0.gate", np.asarray(gate))
+    scope.set("m1.gate", np.asarray(gate))
+    a, b = exe.run(feed={"u": np.eye(2, dtype=np.float32)[None]},
+                   fetch_list=outs)
+    # every weight shrinks by 1.4 / 1.5, and so the layer's output
+    assert np.abs(a).max() > 1e-4
+    np.testing.assert_allclose(b, a * (1.4 / 1.5), rtol=1e-5, atol=1e-8)
+
+
+def _route_digests(route=None):
+    """sha256 of the jaxpr's text of the router and of `moe_experts`,
+    value and gradients, on fixed operands."""
+    import jax
+    import jax.numpy as jnp
+
+    from pallas_costs import jaxpr_digest as digest
+    from paddle_tpu.parallel import moe
+
+    r = np.random.RandomState(9)
+    x = jnp.asarray(r.randn(2, 12, 16), jnp.float32)
+    gate = jnp.asarray(r.randn(16, 8), jnp.float32)
+    bias = jnp.asarray(r.randn(8), jnp.float32)
+    w_gate, w_up = (jnp.asarray(r.randn(2, 16, 8), jnp.float32)
+                    for _ in range(2))
+    w_down = jnp.asarray(r.randn(2, 8, 16), jnp.float32)
+    kw = route or {}
+
+    def layer(x, gate, w_gate, w_up, w_down):
+        return moe.moe_experts(x, gate, bias, w_gate, w_up, w_down, k=2,
+                               scaling=2.0, experts_held=2, held_from=2,
+                               **kw)[0]
+
+    operands = (x, gate, w_gate, w_up, w_down)
+    return (digest(lambda x, g: moe.moe_route(x.reshape(-1, 16), g, bias, 2,
+                                              2.0, **kw), x, gate),
+            digest(layer, *operands),
+            digest(jax.grad(lambda *a: jnp.sum(layer(*a)),
+                            argnums=(0, 1, 2, 3, 4)), *operands))
+
+
+# as the parent of PR 47 traces them under jax 0.9.0 (taken by running
+# `_route_digests` against a copy of that commit)
+PARENTS_JAXPRS = ("c9ab9fc20ef9c8b6", "c5dbb24e9790627b", "d5e703e7acf42309")
+
+
+def test_the_default_epsilons_jaxpr_is_the_parents():
+    """With `norm_eps` 0, by default or named, the router and the expert
+    layer trace, value and gradients, what they traced before the
+    attribute existed: the four expert decoders' steps compile to what
+    they compiled to. An epsilon adds its one `add`."""
+    assert _route_digests() == PARENTS_JAXPRS
+    assert _route_digests({"norm_eps": 0.0}) == PARENTS_JAXPRS
+    assert all(a != b for a, b in zip(_route_digests({"norm_eps": 1e-6}),
+                                      PARENTS_JAXPRS))

@@ -720,3 +720,57 @@ def test_one_layers_step_compiled_for_v5e_makes_nine_products_and_twelve(
                       "moe_dispatch_gmm": 2 if kernel else 0}
     assert _grouped_products(text) == (
         ((6, 9), (3, 3)) if kernel else ((0, 0), (9, 12)))
+
+
+@pytest.fixture(scope="module")
+def lfm2_step(topo):
+    """Once for the tests that read it."""
+    return _step_for_v5e(topo, "lfm2_24b_ep8_longdoc")
+
+
+def test_lfm2_step_compiled_for_v5e_runs_each_conv_backward_in_one_call(
+        lfm2_step):
+    """`lfm2_24b_ep8_longdoc`'s whole train step: each of the four gated
+    convolution layers' `short_conv1d_grad` is one `short_conv_bwd` call
+    without the SiLU over the row's 8,192 tokens (the kernel's longest
+    row) and 2,048 channels at three taps, and no float32 array of that
+    shape is left under the scope."""
+    text, bumped, _ = lfm2_step
+    assert bumped["short_conv_linear_calls"] == 4
+    assert bumped["short_conv_dispatch_pallas"] == 4
+    assert not bumped.get("short_conv_dispatch_xla")
+    under = [line for line in text.splitlines()
+             if "bwd/short_conv1d_grad" in line]
+    calls = [line for line in under if "short_conv_bwd" in line
+             and " custom-call(" in line]
+    assert len(calls) == 4
+    assert all("bf16[1,8192,2048]" in line and "f32[1,8,2048]" in line
+               for line in calls)
+    assert not any(" = f32[1,8192,2048]" in line for line in under)
+
+
+def test_lfm2_step_compiled_for_v5e_takes_the_kernels_it_can_and_fits(
+        lfm2_step):
+    """The same step: the one attention layer's 64-lane heads go through
+    the flash kernels (forward lowering and the gradient op's replay) and
+    not through `qk_prep`, which takes whole 128-lane heads; the four
+    expert layers' grouped products at 2,048 x 1,536 are the Pallas pair;
+    the tied table's gradient is the grouped products; and the step is
+    under 11 GB of a chip's 16.9 by the compiler's count (5.63 of them
+    the 469.3M parameters and their two moments)."""
+    text, bumped, memory = lfm2_step
+    assert bumped["attn_dispatch_flash"] == 2
+    assert not bumped.get("attn_qk_prep_fused")
+    assert not bumped.get("attn_dispatch_xla")
+    assert profiler.counters()["attn_kv_group"] == 4
+    assert bumped["moe_dispatch_gmm"] == bumped["moe_dispatch_grouped"] == 8
+    assert profiler.counters()["moe_block_rows"] == 8192
+    assert bumped["embed_grad_dispatch_grouped"] == 2
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm",
+                   "moe_tgmm", "embed_tgmm"):
+        assert kernel in text, kernel
+    assert "qk_prep" not in text
+    assert abs(memory.argument_size_in_bytes / 1e9 - 5.63) < 0.01
+    need = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            + memory.temp_size_in_bytes - memory.alias_size_in_bytes)
+    assert need < 11e9
