@@ -9,40 +9,63 @@ dropout regenerated (not stored) in the backward pass.
 
 Layout: q [b, h, sq, d], k [b, hkv, sk, d], v [b, hkv, sk, dv] with `h`
 a multiple of `hkv` (grouped key/value heads: the K and V blocks of query
-head `n` are indexed `n // group`, `flash_bwd_dkv` runs over the
-key/value heads and sums over its group's query heads, and K and V are
-never repeated), optional additive key bias [b, sk] (the padding-mask
-case), `causal` flag, `window` (with `causal`: the last `window` keys a
-query may see). Each head width is zero-padded to a lane multiple (128)
-of its own: q, k, dq and dk travel at the keys' padded width, v, the
-output, dO and dv at the values', so values narrower than the keys
-(latent attention's 128 beside 192) cost P.V, dO.V^T and P^T.dO no lane
-they do not fill, and values wider than the keys (a differential head's
-128 beside 64) cost q.k and dS.k none either. Sequence dims are padded to block multiples with
-fully-masked keys. The arrays are head-major: cutting a head's blocks
+head `n` are indexed `n // group`, the backward kernels that write dk and
+dv run over the key/value heads and sum over each one's group of query
+heads, and K and V are never repeated), optional additive key bias
+[b, sk] (the padding-mask case), `causal` flag, `window` (with `causal`:
+the last `window` keys a query may see). Each head width is zero-padded
+to a lane multiple (128) of its own: q, k, dq and dk travel at the keys'
+padded width, v, the output, dO and dv at the values', so values narrower
+than the keys (latent attention's 128 beside 192) cost P.V, dO.V^T and
+P^T.dO no lane they do not fill, and values wider than the keys (a
+differential head's 128 beside 64) cost q.k and dS.k none either.
+Sequence dims are padded to block multiples with fully-masked keys. The
+arrays are head-major: cutting a head's blocks
 from the lanes of [b, s, heads*128] arrays as the projections write them
 was built and measured at s=8,192 (PERF.md, PR 33): the strided blocks
 cost the kernels 8% and the step as a whole ran 4.8% slower than with
 the four transposes, which XLA folds into the relayouts it makes around
 the per-head norms anyway.
 
-Blocks: the two backward kernels share one pair, 512 x 512 unless the
-caller names another, and the sequence dims are padded to it. `flash_fwd`
-has a pair of its own where the caller names none (`_fwd_blocks`: up to
-1,024 x 1,024, multiples of the backward's that divide the padded
-lengths and fit VMEM): what bounds it is paid a row of a block, the
-backward pair is indifferent, and the output and log-sum-exp rows the
-backward reads do not depend on the blocks that made them.
+The backward is one kernel, `flash_bwd_dkv_dq`, wherever a key/value
+head's dk and dv fit VMEM (`_bwd_fused_viable`: from the padded key
+length, the padded widths and the item size; every call of the
+benchmark's cells, up to 16,384 keys at 128 and 128 lanes in bf16). It
+walks a key/value head a row of the grid, under it the group's query
+heads and their query blocks, under each the run of key blocks the band
+admits, forms `p` and `dS` of a block of scores once, adds dS.k to the
+query block's dq and P^T.dO and dS^T.q to the head's dv and dk, which
+stay in VMEM in float32 until the head's last block is done: five
+products a block. A call the rule refuses runs the pair `flash_bwd_dq`
+and `flash_bwd_dkv`, which keep a block of dq, or of dk and dv, and form
+every block's `p` and `dS` twice, seven products; ring attention's
+chunks call the pair themselves (`_bwd_pallas`). The three share one
+body for a block (`_block_backward`), and the fused call adds each
+block's terms in the order the pair does, so it returns the pair's
+dq, dk, dv to the bit, in the interpreter and on a v5e alike.
+
+Blocks: the backward runs at 512 x 512 unless the caller names another
+pair, and the sequence dims are padded to it. `flash_fwd` has a pair of
+its own where the caller names none (`_fwd_blocks`: up to 1,024 x 1,024,
+multiples of the backward's that divide the padded lengths and fit
+VMEM): what bounds it is paid a row of a block, and the output and
+log-sum-exp rows the backward reads do not depend on the blocks that
+made them. The backward was swept too (`_bwd_fused_viable`'s
+docstring): at 1,024 x 1,024 a call without a window ran 4 to 11%
+shorter and every call with one longer, so it keeps 512 x 512.
 
 What the grids skip: a block of scores in which the masks admit no pair
 (above the causal diagonal, below the window's edge) is neither copied
-nor computed, in `flash_fwd`, `flash_bwd_dq` and `flash_bwd_dkv` alike
-(`_key_band`, `_query_band`). Inside a visited block masked pairs are
-still computed and thrown away: with blocks of 512 the causal kernel
-visits 53% of the rectangle at 16 x 16 blocks where the mask admits 50%,
-and a 2,048-key window on 8,192 tokens 27.3% where it admits 21.9%; the
-forward at blocks of 1,024 56% and 32.8%. Without `causal` every block
-is visited, as before.
+nor computed, in `flash_fwd`, `flash_bwd_dkv_dq`, `flash_bwd_dq` and
+`flash_bwd_dkv` alike (`_key_band`, `_query_band`). Inside a visited
+block masked pairs are still computed and thrown away: with blocks of
+512 the causal kernel visits 53% of the rectangle at 16 x 16 blocks
+where the mask admits 50%, and a 2,048-key window on 8,192 tokens 27.3%
+where it admits 21.9%; the forward at blocks of 1,024 56% and 32.8%.
+Leaving the mask's arithmetic out of the blocks that lie wholly inside
+the band was measured in `flash_bwd_dkv_dq` and gave nothing (10.95 and
+11.04 ms at 8,192 causal tokens: PERF.md, PR 48). Without `causal`
+every block is visited, as before.
 
 Mosaic compiles the kernel on a TPU. PADDLE_TPU_PALLAS_INTERPRET=1 runs
 it in the Pallas interpreter so CPU tests exercise the real kernel body;
@@ -164,7 +187,7 @@ def _query_band(kb, m, xp=jnp):
 
 class _Masks(typing.NamedTuple):
     """The masks of one call and the blocks of one of its grids: the
-    forward builds its own at its blocks, the two backward kernels share
+    forward builds its own at its blocks, the backward kernels share
     one at theirs. Static and hashable, so a jitted call takes it as a
     static argument."""
 
@@ -197,20 +220,23 @@ class _Masks(typing.NamedTuple):
                        causal=self.causal, causal_offset=self.causal_offset,
                        window=self.window, block_q=block_q, block_k=block_k)
 
-    def visited(self, fwd_blocks=None):
-        """Blocks one head's three grids compute, and the rectangles', in
-        units of this, the backward's, block: score area. `fwd_blocks`:
-        the forward's where they are multiples of these; one of its
-        blocks counts as the blocks of this size it covers."""
+    def visited(self, fwd_blocks=None, fused=False):
+        """Blocks one head's grids compute, and the rectangles', in units
+        of this, the backward's, block: score area. `fwd_blocks`: the
+        forward's where they are multiples of these; one of its blocks
+        counts as the blocks of this size it covers. `fused`: the
+        backward is one grid, `flash_bwd_dkv_dq`'s, and not two."""
         def run(band, n, m):
             first, last = band(np.arange(n), m, np)
             return int((last - first + 1).sum())
 
         fwd = self.at(*fwd_blocks) if fwd_blocks else self
         covers = (fwd.block_q // self.block_q) * (fwd.block_k // self.block_k)
-        return (covers * run(_key_band, fwd.nq, fwd)
-                + run(_key_band, self.nq, self)
-                + run(_query_band, self.nk, self)), 3 * self.nq * self.nk
+        bwd = run(_key_band, self.nq, self)
+        if not fused:
+            bwd += run(_query_band, self.nk, self)
+        return (covers * run(_key_band, fwd.nq, fwd) + bwd,
+                (2 if fused else 3) * self.nq * self.nk)
 
 
 def _block_of(band, i, t, m):
@@ -248,10 +274,12 @@ def _head_maps(group):
 
 # products a pair the masks admit, as (over the keys' lanes, over the
 # values' lanes): `flash_fwd` q.k and p.v; `flash_bwd_dq` q.k again, dS.k
-# and dO.v; `flash_bwd_dkv` q.k, dS^T.q and dO.v, p^T.dO. At one head
-# width that is 4, 6 and 8 FLOPs a pair a lane, the benchmark's own count.
+# and dO.v; `flash_bwd_dkv` q.k, dS^T.q and dO.v, p^T.dO; the one-visit
+# backward `flash_bwd_dkv_dq` q.k, dS.k, dS^T.q and dO.v, p^T.dO. At one
+# head width that is 4, 6, 8 and 10 FLOPs a pair a lane: the pair
+# declares 14 where the mathematics needs the fused call's 10.
 _PRODUCTS = {"flash_fwd": (1, 1), "flash_bwd_dq": (2, 1),
-             "flash_bwd_dkv": (2, 2)}
+             "flash_bwd_dkv": (2, 2), "flash_bwd_dkv_dq": (3, 2)}
 
 
 def _cost(kernel, q, k, bias, masks, dims):
@@ -274,7 +302,9 @@ def _cost(kernel, q, k, bias, masks, dims):
     row = ((bh, sq), jnp.float32)  # log-sum-exp, delta
     moved = {"flash_fwd": [outputs, row],  # written
              "flash_bwd_dq": [outputs, row, row, queries],  # dO ... dq
-             "flash_bwd_dkv": [outputs, row, row, keys, values]}[kernel]
+             "flash_bwd_dkv": [outputs, row, row, keys, values],
+             "flash_bwd_dkv_dq": [outputs, row, row, queries, keys,
+                                  values]}[kernel]
     if bias is not None:
         moved.append(((bias.shape[0], sk), bias.dtype))
     return cost.estimate(
@@ -305,6 +335,7 @@ def _fwd_kernel(
     masks,
     steps,
 ):
+    n = pl.program_id(0)  # read here: the interpreter has none in a branch
     j = pl.program_id(1)
     t = pl.program_id(2)
     first, last = _key_band(j, masks)
@@ -340,8 +371,7 @@ def _fwd_kernel(
 
         if dropout > 0.0:
             keep = _dropout_keep(
-                seed_ref[0], pl.program_id(0), j * block_q, kb * block_k,
-                p.shape, dropout,
+                seed_ref[0], n, j * block_q, kb * block_k, p.shape, dropout,
             )
             p_use = jnp.where(keep, p / (1.0 - dropout), 0.0)
         else:
@@ -446,23 +476,49 @@ def _fwd_kernel_nobias(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *scr, **kw
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(
-    seed_ref,
-    q_ref,
-    k_ref,
-    v_ref,
-    do_ref,
-    lse_ref,
-    delta_ref,
-    bias_ref,
-    dq_ref,
-    dq_scr,
-    *,
-    sm_scale,
-    dropout,
-    masks,
-    steps,
-):
+def _dot_f32(a, b, contract):
+    """a . b over `contract` = (a's axis, b's axis), accumulated float32."""
+    return jax.lax.dot_general(
+        a, b, (((contract[0],), (contract[1],)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _block_backward(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, bias_ref, head, j, kb, *, sm_scale, dropout,
+                    masks):
+    """What every backward kernel forms of block (j, kb) of query head
+    `head` (its index over the batch, which the dropout mask hashes):
+    q, k, dO, `p` as P^T.dO takes it (with the dropout the forward
+    applied) and `dS`, both float32 [block_q, block_k]."""
+    q = q_ref[0]
+    k = k_ref[0]
+    v = v_ref[0]
+    do = do_ref[0]
+    lse = lse_ref[0, 0].astype(jnp.float32)[:, None]
+    delta = delta_ref[0, 0].astype(jnp.float32)[:, None]
+
+    s = _dot_f32(q, k, (1, 1)) * sm_scale
+    if bias_ref is not None:
+        s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
+    s = _admitted(s, j, kb, masks)
+    p = jnp.exp(s - lse)  # normalized probs (fp32)
+
+    dp = _dot_f32(do, v, (1, 1))
+    p_drop = p
+    if dropout > 0.0:
+        keep = _dropout_keep(
+            seed_ref[0], head, j * masks.block_q, kb * masks.block_k,
+            dp.shape, dropout,
+        )
+        p_drop = jnp.where(keep, p / (1.0 - dropout), 0.0)
+        dp = jnp.where(keep, dp / (1.0 - dropout), 0.0)
+    ds = p * (dp - delta) * sm_scale
+    return q, k, do, p_drop, ds
+
+
+def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   bias_ref, dq_ref, dq_scr, *, steps, masks, **kw):
+    n = pl.program_id(0)
     j = pl.program_id(1)
     t = pl.program_id(2)
     first, last = _key_band(j, masks)
@@ -473,35 +529,10 @@ def _bwd_dq_kernel(
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
     def _visit():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, 0].astype(jnp.float32)[:, None]
-        delta = delta_ref[0, 0].astype(jnp.float32)[:, None]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        if bias_ref is not None:
-            s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
-        s = _admitted(s, j, kb, masks)
-        p = jnp.exp(s - lse)  # normalized probs (fp32)
-
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        if dropout > 0.0:
-            keep = _dropout_keep(
-                seed_ref[0], pl.program_id(0), j * masks.block_q,
-                kb * masks.block_k, dp.shape, dropout,
-            )
-            dp = jnp.where(keep, dp / (1.0 - dropout), 0.0)
-        ds = p * (dp - delta) * sm_scale
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        _, k, _, _, ds = _block_backward(
+            seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            bias_ref, n, j, kb, masks=masks, **kw)
+        dq_scr[:] = dq_scr[:] + _dot_f32(ds.astype(k.dtype), k, (1, 0))
 
     if masks.causal:
         pl.when(kb <= last)(_visit)
@@ -513,32 +544,9 @@ def _bwd_dq_kernel(
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _bwd_dq_nobias(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *scr, **kw):
-    _bwd_dq_kernel(
-        seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None, dq_ref, *scr, **kw
-    )
-
-
-def _bwd_dkv_kernel(
-    seed_ref,
-    q_ref,
-    k_ref,
-    v_ref,
-    do_ref,
-    lse_ref,
-    delta_ref,
-    bias_ref,
-    dk_ref,
-    dv_ref,
-    dk_scr,
-    dv_scr,
-    *,
-    sm_scale,
-    dropout,
-    masks,
-    steps,
-    group,
-):
+def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    bias_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, steps, group,
+                    masks, **kw):
     # one key/value head and one key block a (i, kb); the innermost axis
     # runs over the group's query heads and, for each, the run of query
     # blocks that can see this key block
@@ -546,6 +554,7 @@ def _bwd_dkv_kernel(
     r = pl.program_id(2)
     first, last = _query_band(kb, masks)
     j = first + r % steps
+    n = pl.program_id(0) * group + r // steps
 
     @pl.when(r == 0)
     def _init():
@@ -553,42 +562,11 @@ def _bwd_dkv_kernel(
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     def _visit():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, 0].astype(jnp.float32)[:, None]
-        delta = delta_ref[0, 0].astype(jnp.float32)[:, None]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        if bias_ref is not None:
-            s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
-        s = _admitted(s, j, kb, masks)
-        p = jnp.exp(s - lse)  # [bq, bk]
-
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        if dropout > 0.0:
-            keep = _dropout_keep(
-                seed_ref[0], pl.program_id(0) * group + r // steps,
-                j * masks.block_q, kb * masks.block_k, p.shape, dropout,
-            )
-            p_drop = jnp.where(keep, p / (1.0 - dropout), 0.0)
-            dp = jnp.where(keep, dp / (1.0 - dropout), 0.0)
-        else:
-            p_drop = p
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta) * sm_scale
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        q, _, do, p, ds = _block_backward(
+            seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            bias_ref, n, j, kb, masks=masks, **kw)
+        dv_scr[:] = dv_scr[:] + _dot_f32(p.astype(do.dtype), do, (0, 0))
+        dk_scr[:] = dk_scr[:] + _dot_f32(ds.astype(q.dtype), q, (0, 0))
 
     if masks.causal:
         pl.when(j <= last)(_visit)
@@ -601,10 +579,64 @@ def _bwd_dkv_kernel(
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd_dkv_nobias(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *scr, **kw):
-    _bwd_dkv_kernel(
-        seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None, dk_ref, dv_ref, *scr, **kw
-    )
+def _bwd_fused_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                      delta_ref, bias_ref, dq_ref, dk_ref, dv_ref, dq_scr,
+                      dk_scr, dv_scr, *, steps, group, masks, **kw):
+    # one key/value head a row of the grid; axis 1 runs over the group's
+    # query heads and, for each, its query blocks; the innermost axis over
+    # the run of key blocks that query block can see. dk and dv of the
+    # whole head stay in `dk_scr`, `dv_scr` while its blocks are visited,
+    # and a key block receives its terms in `flash_bwd_dkv`'s order
+    g = pl.program_id(1)
+    t = pl.program_id(2)
+    j = g % masks.nq
+    n = pl.program_id(0) * group + g // masks.nq
+    first, last = _key_band(j, masks)
+    kb = first + t
+
+    @pl.when((g == 0) & (t == 0))
+    def _init_head():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when(t == 0)
+    def _init():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    def _visit():
+        q, k, do, p, ds = _block_backward(
+            seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            bias_ref, n, j, kb, masks=masks, **kw)
+        rows = pl.ds(pl.multiple_of(kb * masks.block_k, masks.block_k),
+                     masks.block_k)
+        ds = ds.astype(q.dtype)
+        dv_scr[rows, :] = dv_scr[rows, :] + _dot_f32(
+            p.astype(do.dtype), do, (0, 0))
+        dk_scr[rows, :] = dk_scr[rows, :] + _dot_f32(ds, q, (0, 0))
+        dq_scr[:] = dq_scr[:] + _dot_f32(ds, k, (1, 0))
+
+    if masks.causal:
+        pl.when(kb <= last)(_visit)
+    else:
+        _visit()
+
+    @pl.when(t == steps - 1)
+    def _finalize():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+    @pl.when((g == group * masks.nq - 1) & (t == steps - 1))
+    def _finalize_head():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _without_bias(kernel):
+    """`kernel` for a call that passes no bias operand."""
+    def nobias(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               *refs, **kw):
+        kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               None, *refs, **kw)
+    return nobias
 
 
 def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
@@ -643,7 +675,8 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
 
     dq = pl.pallas_call(
         functools.partial(
-            _bwd_dq_kernel if bias is not None else _bwd_dq_nobias,
+            _bwd_dq_kernel if bias is not None else _without_bias(
+                _bwd_dq_kernel),
             steps=steps, **common
         ),
         grid=(bh, masks.nq, steps),
@@ -676,7 +709,8 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
     krow = lambda i, kb, r: (i * group + r // steps, 0, query_block(kb, r))
     dk, dv = pl.pallas_call(
         functools.partial(
-            _bwd_dkv_kernel if bias is not None else _bwd_dkv_nobias,
+            _bwd_dkv_kernel if bias is not None else _without_bias(
+                _bwd_dkv_kernel),
             steps=steps, group=group, **common
         ),
         grid=(bhkv, masks.nk, group * steps),
@@ -709,6 +743,79 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
     return dq, dk, dv
 
 
+def _bwd_fused_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale,
+                      causal, causal_offset, dropout, block_q, block_k,
+                      window=0, dims=None):
+    """`_bwd_pallas`' dq, dk, dv from one call that visits a block of
+    scores once: `flash_bwd_dq`'s walk, a query block and its run of key
+    blocks, under a key/value head's row of the grid, with that head's dk
+    and dv held in VMEM in float32 until its last block is done. For the
+    calls `_bwd_fused_viable` admits."""
+    bh, sq, d = q.shape
+    bhkv, sk, dv = k.shape[0], k.shape[1], v.shape[2]
+    group = bh // bhkv
+    hkv = h // group
+    masks = _Masks.of(sq, sk, causal=causal, causal_offset=causal_offset,
+                      window=window, block_q=block_q, block_k=block_k)
+    steps, nq = masks.key_steps(), masks.nq
+    delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
+                    axis=-1)[:, None, :]
+
+    def key_block(g, t):
+        return _block_of(_key_band, g % nq, t, masks)
+
+    qspec = lambda i, g, t: (i * group + g // nq, g % nq, 0)
+    kspec = lambda i, g, t: (i, key_block(g, t), 0)
+    rowspec = lambda i, g, t: (i * group + g // nq, 0, g % nq)
+    headspec = lambda i, g, t: (i, 0, 0)
+
+    bias_in, bias_spec = [], []
+    if bias is not None:
+        bias_in = [bias]
+        bias_spec = [pl.BlockSpec(
+            (1, 1, block_k), lambda i, g, t: (i // hkv, 0, key_block(g, t)),
+            memory_space=pltpu.VMEM)]
+
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_fused_kernel if bias is not None else _without_bias(
+                _bwd_fused_kernel),
+            steps=steps, group=group, sm_scale=sm_scale, dropout=dropout,
+            masks=masks),
+        grid=(bhkv, group * nq, steps),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, block_q, d), qspec, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, d), kspec, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, dv), kspec, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_q, dv), qspec, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, block_q), rowspec, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, block_q), rowspec, memory_space=pltpu.VMEM),
+            *bias_spec,
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_q, d), qspec, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, sk, d), headspec, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, sk, dv), headspec, memory_space=pltpu.VMEM),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((sk, d), jnp.float32),
+            pltpu.VMEM((sk, dv), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_BWD_FUSED_VMEM_LIMIT),
+        interpret=_interpret(),
+        name="flash_bwd_dkv_dq",
+        cost_estimate=_cost("flash_bwd_dkv_dq", q, k, bias, masks, dims),
+    )(seed, q, k, v, do, lse, delta, *bias_in)
+
+
 # ---------------------------------------------------------------------------
 # public entry: custom_vjp over padded/flattened layout
 # ---------------------------------------------------------------------------
@@ -721,10 +828,12 @@ _STATICS = ("sm_scale", "causal", "causal_offset", "dropout", "block_q",
             "block_k", "window", "dims")
 _fwd_call = jax.jit(_fwd_pallas, static_argnums=(5,), static_argnames=_STATICS)
 _bwd_call = jax.jit(_bwd_pallas, static_argnums=(8,), static_argnames=_STATICS)
+_bwd_fused_call = jax.jit(_bwd_fused_pallas, static_argnums=(8,),
+                          static_argnames=_STATICS)
 
 
 def _statics_of(statics):
-    """(`flash_fwd`'s statics, the backward pair's) from the one tuple a
+    """(`flash_fwd`'s statics, the backward's) from the one tuple a
     call carries: the same but for the blocks, the forward's own under
     `fwd_blocks`. The output and the log-sum-exp rows do not depend on
     the blocks that made them, so the backward reads them at its own."""
@@ -745,8 +854,10 @@ def _flash_core_fwd(q, k, v, bias, seed, h, statics):
 
 def _flash_core_bwd(h, statics, res, do):
     q, k, v, bias, seed, out, lse = res
-    dq, dk, dv = _bwd_call(q, k, v, bias, seed, out, lse, do, h,
-                           **_statics_of(statics)[1])
+    fused = _bwd_fused_viable(k.shape[1], k.shape[2], v.shape[2],
+                              k.dtype.itemsize)
+    dq, dk, dv = (_bwd_fused_call if fused else _bwd_call)(
+        q, k, v, bias, seed, out, lse, do, h, **_statics_of(statics)[1])
     dbias = None if bias is None else jnp.zeros_like(bias)
     dseed = np.zeros((1,), dtype=jax.dtypes.float0)
     return dq, dk, dv, dbias, dseed
@@ -756,7 +867,8 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
 # What `flash_fwd` may hold in VMEM: Mosaic's scoped default on a v5e. The
-# two backward kernels at 512 x 512 stay far under it at every width.
+# pair's two backward kernels at 512 x 512 stay far under it at every width;
+# `flash_bwd_dkv_dq` asks for more (`_BWD_FUSED_VMEM_LIMIT`).
 _FWD_VMEM_BYTES = 16 << 20
 
 
@@ -788,13 +900,55 @@ def _fwd_blocks(sq_p, sk_p, block_q, block_k, d_p, dv_p, itemsize):
     block twice as wide halves it a key; walking a wide block in 512-key
     parts inside one grid step gave nothing back. A band pays for larger
     blocks in masked area (1.20 and 1.33 times under those windows) and
-    still gains; 2,048 keys and more lose to it. The backward pair is
-    indifferent to the key block and keeps its own."""
+    still gains; 2,048 keys and more lose to it. The backward is
+    indifferent to the key block and keeps its own (`_bwd_fused_viable`
+    has its sweep)."""
     for bq, bk in ((2 * block_q, 2 * block_k), (block_q, 2 * block_k)):
         if (sq_p % bq == 0 and sk_p % bk == 0 and _fwd_vmem_bytes(
                 bq, bk, d_p, dv_p, itemsize) <= _FWD_VMEM_BYTES):
             return bq, bk
     return block_q, block_k
+
+
+# What `flash_bwd_dkv_dq` may keep of a key/value head from one grid step to
+# the next, and what the call asks Mosaic for (of a v5e's 128 MiB; the
+# default is 16 MiB): the rest of a step, its operands' blocks twice and a
+# few blocks of scores in float32, is under 10 MiB at 512 x 512.
+_BWD_FUSED_VMEM_BYTES = 32 << 20
+_BWD_FUSED_VMEM_LIMIT = 64 << 20
+
+
+def _bwd_fused_viable(sk_p, d_p, dv_p, itemsize):
+    """Whether a call's backward is the one-visit `flash_bwd_dkv_dq`: where
+    a key/value head's dk and dv fit VMEM, as the two float32 accumulators
+    and the two buffers of each output block, from the padded key length,
+    the padded widths and the item size alone. The cells' calls keep 8 to
+    16 MiB (8,192 keys at 128 and 128 lanes: 16; 4,096 at 256 and 128:
+    12); 32,768 keys at 128 and 128 lanes would keep 64 and take the pair.
+    Bias, dropout, windows, groups and blocks passed by hand are served
+    with the pair's arithmetic, so nothing else of a call is asked.
+
+    Measured alone on a v5e (PERF.md, PR 48), ms a call at 512 x 512, the
+    pair and the fused call, each with the XLA pass that makes the delta
+    rows: latent attention, 32 heads at s=4,096 (keys in 256 lanes, values
+    in 128) 6.21 / 4.42; 32 heads over 4 at s=8,192 and 128 lanes, full
+    causal 14.41 / 10.95, a 2,048-key window 7.32 / 5.47, a 1,024-key
+    window 4.80 / 3.62; 32 over 8 heads of 64 in 128 lanes at s=8,192
+    14.44 / 10.99; 40 over 20 differential heads at s=4,096 (keys of 64,
+    values of 128) 5.06 / 3.76, under a 512-key window 2.22 / 1.65: 24 to
+    29% under the pair everywhere, and the same dq, dk, dv to the bit. A
+    visit takes 2.5 us where its five products at 128 lanes need 1.7 at
+    the peak. Tried in the body and not kept: no mask arithmetic in the
+    blocks wholly inside the band (11.04 against 10.95 at 8,192 causal
+    tokens), and the scores formed transposed, k.q^T, so that P^T.dO and
+    dS^T.q need no transposed operand (10.91, and 11.37 with dq
+    transposed as well). Other blocks, fused call alone, the same seven
+    calls: 1,024 x 1,024 4.26, 9.69, 5.67, 4.16, 9.69, 3.53, 2.51, so 4
+    to 12% shorter without a window and 4 to 52% longer with one;
+    1,024 x 512 and 512 x 1,024 gain less without a window and lose
+    with one as well; 256 either way 26 to 50% longer."""
+    return ((4 + 2 * itemsize) * sk_p * (d_p + dv_p)
+            <= _BWD_FUSED_VMEM_BYTES)
 
 
 def _pad_inputs(q, k, v, bias, block_q, block_k):
@@ -806,7 +960,7 @@ def _pad_inputs(q, k, v, bias, block_q, block_k):
     (`vf` is [b*hkv, sk_p, dv_p]). Shared by the flash and ring
     entry points so their layouts (and dropout-mask coordinates) stay
     bit-compatible. Returns (qf, kf, vf, biasf, bq, bk): the blocks of the
-    backward pair, and of the forward too unless `_fwd_blocks` gives it
+    backward, and of the forward too unless `_fwd_blocks` gives it
     multiples of them, which need no other padding; biasf is
     [b, 1, sk_p] or None."""
     b, _, sq, _ = q.shape
@@ -959,9 +1113,11 @@ def flash_attention(
     (query head `n` reads key/value head `n // (h // hkv)`); bias: additive key bias [b, sk] (0 keep /
     -inf drop) or None. `window` > 0 (with `causal`) admits only the last
     `window` keys a query may see. Returns [b, h, sq, dv] in q's dtype.
-    `block_q`, `block_k`: a block named here serves all three kernels;
-    with neither, the backward pair runs at 512 x 512 and the forward at
-    blocks chosen from the call's shape (`_fwd_blocks`).
+    `block_q`, `block_k`: a block named here serves every kernel; with
+    neither, the backward runs at 512 x 512 and the forward at blocks
+    chosen from the call's shape (`_fwd_blocks`). The backward is one
+    kernel where a key/value head's dk and dv fit VMEM and the pair
+    elsewhere (`_bwd_fused_viable`: the shape decides, no argument).
     """
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
@@ -988,14 +1144,16 @@ def flash_attention(
     # padded q rows are sliced away and padded keys are bias-masked
     causal_offset = sk - sq
     qf, kf, vf, biasf, bq, bk = _pad_inputs(q, k, v, bias, block_q, block_k)
-    fwd_blocks = bq, bk  # blocks passed by hand serve all three kernels
+    fwd_blocks = bq, bk  # blocks passed by hand serve every kernel
     if block_q is None and block_k is None:
         fwd_blocks = _fwd_blocks(qf.shape[1], kf.shape[1], bq, bk,
                                  kf.shape[2], vf.shape[2], qf.dtype.itemsize)
     masks = _Masks.of(qf.shape[1], kf.shape[1], causal=bool(causal),
                       causal_offset=causal_offset, window=int(window),
                       block_q=bq, block_k=bk)
-    visited, total = masks.visited(fwd_blocks)
+    fused = _bwd_fused_viable(kf.shape[1], kf.shape[2], vf.shape[2],
+                              kf.dtype.itemsize)
+    visited, total = masks.visited(fwd_blocks, fused)
     profiler.bump_counter("flash_blocks_visited", b * h * visited)
     profiler.bump_counter("flash_blocks_total", b * h * total)
     if vf.shape[2] < kf.shape[2]:  # the values travel at a width of their own
@@ -1004,6 +1162,8 @@ def flash_attention(
         profiler.bump_counter("flash_wide_value_calls")
     if fwd_blocks[1] != bk:  # the forward walks the keys at a block of its own
         profiler.bump_counter("flash_fwd_wide_key_calls")
+    if fused:  # the backward visits a block of scores once, in one kernel
+        profiler.bump_counter("flash_bwd_fused_calls")
 
     statics = (("sm_scale", float(sm_scale)), ("causal", bool(causal)),
                ("causal_offset", causal_offset), ("dropout", float(dropout)),

@@ -5,6 +5,7 @@ included; every other mesh keeps XLA's lowering. In the Pallas interpreter
 on four of the suite's eight virtual CPU devices."""
 
 import contextlib
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -347,22 +348,38 @@ def test_qk_prep_kernels_compile_for_a_v5e_chip_at_the_published_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-@pytest.mark.parametrize("shape,window,lanes", [
+@pytest.mark.parametrize("shape,window,lanes,backward", [
     # JoyAI's and Kimi's latent layer: keys of 192 and values of 128
-    ((1, 32, 32, 4096, 192, 128), 0, (256, 128)),
+    ((1, 32, 32, 4096, 192, 128), 0, (256, 128), "fused"),
     # Trinity's window layer: one width, 32 query heads over 4
-    ((1, 32, 4, 8192, 128, 128), 2048, (128, 128)),
+    ((1, 32, 4, 8192, 128, 128), 2048, (128, 128), "fused"),
     # Mellum's: a band as wide as one of the forward's blocks
-    ((1, 32, 4, 8192, 128, 128), 1024, (128, 128)),
-], ids=["latent_192_128", "gqa_window_128", "gqa_window_1024"])
+    ((1, 32, 4, 8192, 128, 128), 1024, (128, 128), "fused"),
+    # their full layers: the longest run of key blocks, all sixteen
+    ((1, 32, 4, 8192, 128, 128), 0, (128, 128), "fused"),
+    # LFM2's: heads of 64 in 128 lanes, 32 over 8
+    ((1, 32, 8, 8192, 64, 64), 0, (128, 128), "fused"),
+    # Phi-4's differential pairs: keys of 64 under values of 128
+    ((1, 40, 20, 4096, 64, 128), 512, (128, 128), "fused"),
+    # the pair, as a call whose key/value head does not fit VMEM gets it
+    ((1, 32, 32, 4096, 192, 128), 0, (256, 128), "pair"),
+    ((1, 32, 4, 8192, 128, 128), 2048, (128, 128), "pair"),
+    # ... and a call that does not: 32,768 keys at 128 and 128 lanes
+    ((1, 8, 1, 32768, 128, 128), 2048, (128, 128), "refused"),
+], ids=["latent_192_128", "gqa_window_128", "gqa_window_1024", "gqa_full",
+        "gqa_32_over_8_at_64", "differential_64_128", "latent_192_128_pair",
+        "gqa_window_128_pair", "s32768_refused"])
 def test_flash_kernels_compile_for_a_v5e_chip_at_the_published_widths(
-        topo, shape, window, lanes):
-    """The three blocked attention kernels at the shapes of the cells that
-    run them, bf16, through `jax.vjp`: Mosaic takes q, k, dq and dk at the
+        topo, shape, window, lanes, backward, monkeypatch):
+    """The blocked attention kernels at the shapes of the cells that run
+    them, bf16, through `jax.vjp`: Mosaic takes q, k, dq and dk at the
     keys' lanes and v, the output, dO and dv at the values' (the shapes
     the custom calls are held to in the compiled module), the forward at
     the 1,024 x 1,024 blocks the call picks for itself (their VMEM fits
-    the chip's). Nothing runs."""
+    the chip's), and the backward as `flash_bwd_dkv_dq` with a key/value
+    head's dk and dv resident (16 MiB at 8,192 keys and 256 lanes, under
+    the 64 MiB the call asks for) or, where the rule refuses, as the pair.
+    Nothing runs."""
     import re
 
     from jax.sharding import SingleDeviceSharding
@@ -373,6 +390,10 @@ def test_flash_kernels_compile_for_a_v5e_chip_at_the_published_widths(
     chip = SingleDeviceSharding(topo.devices[0])
     b, h, hkv, s, d, dv = shape
     d_p, dv_p = lanes
+    if backward == "pair":
+        monkeypatch.setattr(importlib.import_module(
+            "paddle_tpu.ops.pallas.flash_attention"),
+            "_BWD_FUSED_VMEM_BYTES", 0)
 
     def sds(*dims):
         return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=chip)
@@ -382,22 +403,29 @@ def test_flash_kernels_compile_for_a_v5e_chip_at_the_published_widths(
             *a, causal=True, window=window), q, k, v)
         return o, pull(o)
 
+    fused = profiler.counters().get("flash_bwd_fused_calls", 0)
     wide = profiler.counters().get("flash_fwd_wide_key_calls", 0)
     with _as_on_the_chip():
         text = jax.jit(both).lower(
             sds(b, h, s, d), sds(b, hkv, s, d), sds(b, hkv, s, dv)
         ).compile().as_text()
     assert profiler.counters()["flash_fwd_wide_key_calls"] == wide + 1
+    assert (profiler.counters().get("flash_bwd_fused_calls", 0) - fused
+            == (backward == "fused"))
     qs, ks = f"bf16[{b * h},{s},{d_p}]", f"bf16[{b * hkv},{s},{d_p}]"
     vs, outs = f"bf16[{b * hkv},{s},{dv_p}]", f"bf16[{b * h},{s},{dv_p}]"
     rows = f"f32[{b * h},1,{s}]"
-    operands = {
-        "flash_fwd": ["s32[1]", qs, ks, vs],
-        "flash_bwd_dq": ["s32[1]", qs, ks, vs, outs, rows, rows],
-        "flash_bwd_dkv": ["s32[1]", qs, ks, vs, outs, rows, rows],
-    }
-    results = {"flash_fwd": [outs, rows], "flash_bwd_dq": [qs],
-               "flash_bwd_dkv": [ks, vs]}
+    read = ["s32[1]", qs, ks, vs, outs, rows, rows]
+    operands = {"flash_fwd": ["s32[1]", qs, ks, vs]}
+    results = {"flash_fwd": [outs, rows]}
+    if backward == "fused":
+        operands["flash_bwd_dkv_dq"] = read
+        results["flash_bwd_dkv_dq"] = [qs, ks, vs]
+        assert "flash_bwd_dq" not in text
+    else:
+        operands.update(flash_bwd_dq=read, flash_bwd_dkv=read)
+        results.update(flash_bwd_dq=[qs], flash_bwd_dkv=[ks, vs])
+        assert "flash_bwd_dkv_dq" not in text
     shapes = re.compile(r"\w+\[[\d,]*\]").findall
     for name, want in operands.items():
         ((written, read),) = re.findall(
@@ -766,9 +794,10 @@ def test_lfm2_step_compiled_for_v5e_takes_the_kernels_it_can_and_fits(
     assert bumped["moe_dispatch_gmm"] == bumped["moe_dispatch_grouped"] == 8
     assert profiler.counters()["moe_block_rows"] == 8192
     assert bumped["embed_grad_dispatch_grouped"] == 2
-    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm",
-                   "moe_tgmm", "embed_tgmm"):
+    for kernel in ("flash_fwd", "flash_bwd_dkv_dq", "moe_gmm", "moe_tgmm",
+                   "embed_tgmm"):
         assert kernel in text, kernel
+    assert "flash_bwd_dq" not in text  # the backward is the one kernel
     assert "qk_prep" not in text
     assert abs(memory.argument_size_in_bytes / 1e9 - 5.63) < 0.01
     need = (memory.argument_size_in_bytes + memory.output_size_in_bytes

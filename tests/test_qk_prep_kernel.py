@@ -180,8 +180,9 @@ def test_the_kernels_names_are_not_the_flash_kernels():
     for name in ("qk_prep_fwd", "qk_prep_bwd"):
         assert name in text
         assert re.search(mine, name) and not re.search(theirs, name)
-    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
-        assert not re.search(mine, name)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                 "flash_bwd_dkv_dq"):
+        assert re.search(theirs, name) and not re.search(mine, name)
 
 
 @pytest.mark.parametrize("kernel", ["qk_prep_fwd", "qk_prep_bwd"])
