@@ -16,7 +16,9 @@ API (executor.py:619,730).
 
 from __future__ import annotations
 
+import contextlib
 import os
+import time
 
 import numpy as np
 
@@ -46,7 +48,7 @@ __all__ = ["Executor"]
 # one shared jit wrapper for BOTH execution modes (static executor here,
 # the dygraph JIT bridge in dygraph/jit.py): PADDLE_TPU_XLA_OPTIONS set
 # once applies to every compiled step in the process
-from .jit_compile import xla_jit as _jit  # noqa: E402
+from .jit_compile import compile_owner, xla_jit as _jit  # noqa: E402
 from .passes import resolve_pass_names as _resolve_pass_names  # noqa: E402
 
 # step-progress heartbeat for the elastic TrainSupervisor
@@ -163,16 +165,40 @@ class _CompiledStep:
         self.feed_shardings: dict = {}
 
 
+def _step_owner(block) -> str:
+    """Whom a step's compile is filed under (`jit_compile.compile_owner`):
+    "train" where the block has a Backward or Optimize op, "forward" for
+    any other Program (a startup program, a `for_test` clone, a
+    predictor's)."""
+    trains = core_op_role.Backward | core_op_role.Optimize
+    if any((op.attrs.get("op_role") or 0) & trains for op in block.ops):
+        return "train"
+    return "forward"
+
+
+@contextlib.contextmanager
+def _first_call(owner):
+    """Round the first call of a step's jit, the one that traces, lowers
+    and compiles (or reads the persistent cache): the one place the
+    compile path's owner is set. What the call paid by stage lands in
+    `compile_*.<owner>` (`jit_compile`), each op's lowering in
+    `trace_op_us.<owner>.<scope>` (`ops/registry.py::lower_op`), and its
+    whole wall time, if it returns, in `program_first_call_us.<owner>`."""
+    t0 = time.perf_counter()
+    with RecordEvent("pt.exe.first_call"), compile_owner(owner):
+        yield
+    profiler.bump_counter(f"program_first_call_us.{owner}",
+                          int((time.perf_counter() - t0) * 1e6))
+
+
 def _instrument_compiled(compiled, block):
     """Always-on compile-path counters (style of dygraph_jit_*): every
     cache miss bumps program_compile_count and program_traced_ops (ops
-    the jit trace will lower), and the first dispatch — the one that
-    pays trace+lower+XLA-compile — lands its wall time in
-    program_trace_ms. Steady-state calls pay one flag check."""
-    import time as _time
-
+    the jit trace will lower), and the first dispatch runs under
+    `_first_call`. Steady-state calls pay one flag check."""
     profiler.bump_counter("program_compile_count")
     profiler.bump_counter("program_traced_ops", len(block.ops))
+    compiled.owner = owner = _step_owner(block)
     inner = compiled.fn
     compiled.jit_fn = inner  # raw jax.jit callable: .lower() = AOT
     # trace+StableHLO without XLA compile (tools/bench_passes.py times
@@ -182,14 +208,9 @@ def _instrument_compiled(compiled, block):
     def fn(*args, **kwargs):
         if not pending:
             return inner(*args, **kwargs)
-        t0 = _time.perf_counter()
-        result = inner(*args, **kwargs)
-        if pending:
-            pending.clear()
-            profiler.bump_counter(
-                "program_trace_ms",
-                int((_time.perf_counter() - t0) * 1000),
-            )
+        with _first_call(owner):
+            result = inner(*args, **kwargs)
+        pending.clear()
         return result
 
     compiled.fn = fn
@@ -1193,11 +1214,13 @@ class Executor:
 
         multi_key = (id(compiled), steps)
         multi = self._multi_cache.get(multi_key)
-        if multi is None:
+        first = multi is None
+        if first:
             # the step's nested jit (inlines under the outer one), never
-            # the instrumented wrapper: that would burn the one-shot
-            # program_trace_ms timer on the scan-body trace instead of
-            # the real first dispatch
+            # the instrumented wrapper: the scan's jit is the one that
+            # compiles here, so its first call below is the one that sets
+            # the step's owner, and the wrapper's stays for the first
+            # `run` of the step alone
             step_fn = compiled.nested_fn
 
             def multi(state, feeds, seed):
@@ -1216,11 +1239,15 @@ class Executor:
             # the scope's arrays alive for the caller — donation would
             # delete them
             multi = _jit(multi)
-            self._multi_cache[multi_key] = multi
 
-        stacked, new_state = multi(
-            state, feeds, _seed_words(program, self._seed_counter + 1)
-        )
+        with (_first_call(compiled.owner) if first
+              else contextlib.nullcontext()):
+            stacked, new_state = multi(
+                state, feeds, _seed_words(program, self._seed_counter + 1)
+            )
+        if first:  # kept once it has run: a scan whose first call raised
+            # is built, and filed under its owner, again
+            self._multi_cache[multi_key] = multi
         # advance only on success: a failed trace must not skip PRNG
         # counters (the N-consecutive-run() equivalence contract)
         self._seed_counter += steps

@@ -14,11 +14,16 @@ grad makers/lowerings.
 
 from __future__ import annotations
 
+import threading
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import profiler
 from ..framework import convert_dtype, core_op_role, is_float_dtype
+from ..jit_compile import current_owner
 
 __all__ = [
     "OpDef",
@@ -318,10 +323,22 @@ def op_scope(op) -> str:
     return f"{phase}/{op.type}"
 
 
+class _LoweringClock(threading.local):
+    inner = 0.0  # seconds the ops lowered inside the open one have taken
+
+
+_clock = _LoweringClock()
+
+
 def lower_op(ctx: LoweringContext, op):
+    scope = op_scope(op)
+    # the op's own lowering time, at trace time only: what `layer_scan`'s
+    # block or a `while` body lowers through here inside it is theirs
+    t0 = time.perf_counter()
+    outer, _clock.inner = _clock.inner, 0.0
     try:
         # names only: HLO metadata, not the computation
-        with jax.named_scope(op_scope(op)):
+        with jax.named_scope(scope):
             saved = _amp_precast(ctx, op)
             try:
                 get_op(op.type).lower(ctx, op)
@@ -351,6 +368,12 @@ def lower_op(ctx: LoweringContext, op):
                 except (AttributeError, TypeError):
                     pass
         raise
+    finally:
+        whole = time.perf_counter() - t0
+        profiler.bump_counter(
+            f"trace_op_us.{current_owner()}.{scope}",
+            int((whole - _clock.inner) * 1e6))
+        _clock.inner = outer + whole
 
 
 def lower_block(ctx: LoweringContext, block):

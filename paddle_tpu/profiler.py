@@ -60,7 +60,9 @@ def bump_counter(name: str, amount: int = 1) -> int:
 
 
 def set_counter(name: str, value: int) -> int:
-    """Gauge-style counter assignment (always on, like bump_counter):
+    """Gauge-style counter assignment (always on, like bump_counter;
+    the compile path's counters by stage and owner, and every counter a
+    benchmark metric reads, are listed in `PERF.md`, section 3, not here):
     for values that REPLACE rather than accumulate — resilience sets
     `resume_step` to the step a restore landed on, so observers read the
     resume point, not a meaningless sum of resume points; the inference

@@ -424,7 +424,10 @@ def test_profiler_counters_present():
     c = profiler.counters()
     assert c.get("program_compile_count", 0) >= 2  # startup + main
     assert c.get("program_traced_ops", 0) > 0
-    assert "program_trace_ms" in c
+    assert "program_trace_ms" not in c  # PR 49: by stage and by owner
+    # the startup program, and a main program with no backward: forward
+    assert c["program_first_call_us.forward"] >= c["compile_trace_us.forward"]
+    assert "program_first_call_us.train" not in c
     assert "pass_manager_us" in c
     assert c.get("program_ops_before", 0) >= c.get("program_ops_after", 0)
 

@@ -2,12 +2,17 @@
 Program op on the device side (`ops/registry.py::lower_op`), the
 `pt.exe.*` spans inside `Executor.run` / `CompiledProgram._run`, and the
 reader's `pt.reader.*` spans (`profiler.RecordEvent`, a TraceMe on the
-trace's clock). `PERF.md` lists which benchmark metric reads which."""
+trace's clock); and the compile path's always-on counters, by stage and
+by owner (`jit_compile.py`). `PERF.md` lists which benchmark metric reads
+which."""
 
 import contextlib
 import glob
+import json
 import os
 import re
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -15,7 +20,7 @@ import pytest
 import jax
 from jax._src.lib import _jax
 import paddle_tpu as fluid
-from paddle_tpu import profiler
+from paddle_tpu import jit_compile, profiler
 from paddle_tpu.ops import registry
 
 EXE_SPANS = ("pt.exe.prepare", "pt.exe.state", "pt.exe.dispatch",
@@ -198,6 +203,14 @@ def test_exe_spans_nest_in_the_callers_span_once_a_step(places, tmp_path):
                       if n == "pt.exe.prepare")
     assert len(compiles) == 1  # nested in the first step's prepare
     assert prepares[0][0] <= compiles[0][0] and compiles[0][1] <= prepares[0][1]
+    # ... and what it built traced, lowered and compiled in the first
+    # step's dispatch: a recompile reads `pt.exe.compile`, `pt.exe.first_call`
+    first_calls = [(s, e) for n, s, e, _ in events if n == "pt.exe.first_call"]
+    dispatches = sorted((s, e) for n, s, e, _ in events
+                        if n == "pt.exe.dispatch")
+    assert len(first_calls) == 1 and compiles[0][1] <= first_calls[0][0]
+    assert (dispatches[0][0] <= first_calls[0][0]
+            and first_calls[0][1] <= dispatches[0][1])
 
 
 def test_reader_stages_once_a_batch_on_the_stagers_thread(tmp_path):
@@ -233,6 +246,290 @@ def test_record_event_keeps_the_table_without_a_trace(tmp_path):
             pass
     rows = profiler.stop_profiler(profile_path=str(tmp_path / "table.txt"))
     assert [(r[0], r[1]) for r in rows] == [("inside", 2)]
+
+
+# ------------------------------------------- the compile path, by stage
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TRACE, LOWER, BACKEND = jit_compile._STAGE_COUNTERS  # JAX's three events
+STAGES = tuple(jit_compile._STAGE_COUNTERS.values())
+COMPILE_METRICS = [
+    "step_trace_s", "step_lower_s", "step_backend_s", "step_cache_read_s",
+    "step_first_call_s", "step_kernel_ops_trace_s",
+    "setup_forward_compile_s", "setup_other_compile_s",
+    "setup_other_compiles", "setup_uncached_compiles"]
+
+
+def _reads(metric):
+    """The counters the benchmark's metric `metric` adds up."""
+    from benchmark.harness import spec
+
+    return spec.load("layer_metrics", metric)["args"]["counters"]
+
+
+def _filed(prefix=("compile_", "program_first_call_us", "trace_op_us"),
+           since=None):
+    """The compile path's counters (those with an owner), less `since`."""
+    since = since or {}
+    return {k: v - since.get(k, 0) for k, v in profiler.counters().items()
+            if k.startswith(prefix) and v != since.get(k, 0)}
+
+
+def _mlp_feed():
+    rng = np.random.RandomState(0)
+    return {"x": rng.randn(8, 16).astype("float32"),
+            "y": rng.randn(8, 1).astype("float32")}
+
+
+def test_a_train_step_files_each_stage_of_its_compile_under_train():
+    prog, loss = _mlp(None)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    before = profiler.counters()
+    exe.run(prog, feed=_mlp_feed(), fetch_list=[loss])
+    filed = _filed(since=before)
+    stages = [filed[f"{stage}.train"] for stage in STAGES]
+    assert all(us > 0 for us in stages)
+    assert sum(stages) <= filed["program_first_call_us.train"]
+    assert filed["compile_requests.train"] == 1
+    # what the benchmark's five `step_*` metrics read is what was filed
+    for metric in COMPILE_METRICS[:5]:
+        (counter,) = _reads(metric)
+        assert counter.endswith(".train")
+        assert (counter in filed) == (metric != "step_cache_read_s"), metric
+    # nothing of another owner's: the feeds went to the device as they were
+    assert not any(".forward" in k or ".other" in k for k in filed), filed
+    after = profiler.counters()
+    exe.run(prog, feed=_mlp_feed(), fetch_list=[loss])
+    assert profiler.counters() == after  # the second run compiles nothing
+
+
+def test_a_forward_only_program_files_under_forward():
+    x = fluid.layers.data("x", [16])
+    out = fluid.layers.fc(x, 4)
+    exe = fluid.Executor(fluid.CPUPlace())
+    before = profiler.counters()
+    exe.run(fluid.default_startup_program())
+    startup = _filed(since=before)
+    assert startup["compile_requests.forward"] == 1
+    assert startup["program_first_call_us.forward"] > 0
+    before = profiler.counters()
+    exe.run(fluid.default_main_program().clone(for_test=True),
+            feed={"x": np.ones((2, 16), "float32")}, fetch_list=[out])
+    clone = _filed(since=before)
+    assert clone["compile_requests.forward"] == 1
+    assert sum(clone[f"{s}.forward"] for s in STAGES) <= clone[
+        "program_first_call_us.forward"]
+    assert set(_reads("setup_forward_compile_s")) == {
+        f"{s}.forward" for s in STAGES} <= set(clone)
+    assert not any(".train" in k for k in {**startup, **clone})
+    assert {k for k in clone if k.startswith("trace_op_us.")} == {
+        "trace_op_us.forward.fwd/mul", "trace_op_us.forward.fwd/elementwise_add"}
+
+
+def test_a_bare_jit_outside_any_executor_files_under_other():
+    before = profiler.counters()
+    jax.jit(lambda a: a * 2 + 1)(np.arange(3, dtype="float32"))
+    filed = _filed(since=before)
+    assert set(filed) == {f"{s}.other" for s in STAGES} | {
+        "compile_requests.other"}
+    assert filed["compile_requests.other"] == 1
+    assert _reads("setup_other_compiles") == ["compile_requests.other"]
+    assert set(_reads("setup_other_compile_s")) == {
+        f"{s}.other" for s in STAGES}
+    assert jit_compile.current_owner() == "other"
+
+
+def test_run_repeated_files_its_scan_under_the_steps_owner():
+    prog, loss = _mlp(None)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    before = profiler.counters()
+    exe.run_repeated(prog, feed=_mlp_feed(), fetch_list=[loss], steps=3)
+    filed = _filed(since=before)
+    assert filed["compile_requests.train"] == 1
+    assert sum(filed[f"{s}.train"] for s in STAGES) <= filed[
+        "program_first_call_us.train"]
+    assert "compile_requests.other" not in filed
+    after = profiler.counters()
+    exe.run_repeated(prog, feed=_mlp_feed(), fetch_list=[loss], steps=3)
+    assert profiler.counters() == after
+
+
+def test_a_stage_is_filed_once_whatever_is_traced_inside_it():
+    """JAX reports a trace event for every jitted `jnp` function traced
+    under the step's and for the functions its lowering rules trace; the
+    step's trace stage is the outermost event alone."""
+    compiled, args = _tiny_bert_step()
+    traces = []
+
+    def listen(event, duration_secs, **kwargs):
+        if event == TRACE:
+            traces.append(duration_secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    before = profiler.counters()
+    try:
+        compiled.fn(*args)  # the wrapper's first call
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    filed = _filed(since=before)
+    assert len(traces) > 1 and sum(traces) > max(traces)
+    assert filed["compile_trace_us.train"] == int(max(traces) * 1e6)
+    assert filed["compile_trace_us.train"] < filed[
+        "program_first_call_us.train"]
+    assert sum(filed[f"{s}.train"] for s in STAGES) <= filed[
+        "program_first_call_us.train"]
+
+    # and by hand: a lowering and a trace inside an open trace add nothing
+    record, close = (jax.monitoring.record_scalar,
+                     jax.monitoring.record_event_duration_secs)
+    before = profiler.counters()
+    record(TRACE, 0.0, fun_name="outer")
+    record(TRACE, 0.0, fun_name="inner")
+    close(TRACE, 5.0, fun_name="inner")
+    record(LOWER, 0.0, fun_name="inner")
+    close(LOWER, 7.0, fun_name="inner")
+    close(TRACE, 1.0, fun_name="outer")
+    assert _filed(since=before) == {"compile_trace_us.other": 1_000_000}
+
+
+@pytest.mark.parametrize("owner", ["train", "forward", "other"])
+def test_the_cache_counters_follow_jaxs_events_under_each_owner(owner):
+    """The CPU has no usable persistent cache, so JAX's events by hand,
+    in the order `compile_or_get_cached` emits them."""
+    record, event, close = (jax.monitoring.record_scalar,
+                            jax.monitoring.record_event,
+                            jax.monitoring.record_event_duration_secs)
+    lookup, hit, write, read = (jit_compile._CACHE_LOOKUP,
+                                jit_compile._CACHE_HIT,
+                                jit_compile._CACHE_WRITE,
+                                jit_compile._CACHE_READ)
+    before = profiler.counters()
+    with (contextlib.nullcontext() if owner == "other"
+          else jit_compile.compile_owner(owner)):
+        record(BACKEND, 0.0, fun_name="hit")
+        event(lookup)
+        event(hit)
+        close(read, 0.25)
+        close(BACKEND, 0.5, fun_name="hit")
+        record(BACKEND, 0.0, fun_name="compiled and written")
+        event(lookup)
+        event(write)
+        close(BACKEND, 2.0, fun_name="compiled and written")
+        record(BACKEND, 0.0, fun_name="under the thresholds: never kept")
+        event(lookup)
+        close(BACKEND, 0.125, fun_name="under the thresholds: never kept")
+        record(BACKEND, 0.0, fun_name="the cache is off")
+        close(BACKEND, 0.0625, fun_name="the cache is off")
+    assert jit_compile.current_owner() == "other"
+    assert _filed(since=before) == {
+        f"compile_backend_us.{owner}": 2_687_500,
+        f"compile_cache_read_us.{owner}": 250_000,
+        f"compile_requests.{owner}": 4,
+        f"compile_cache_hits.{owner}": 1,
+        f"compile_cache_compiled.{owner}": 2,
+        f"compile_cache_writes.{owner}": 1,
+    }
+    assert f"compile_cache_compiled.{owner}" in _reads(
+        "setup_uncached_compiles")
+    assert _reads("step_cache_read_s") == ["compile_cache_read_us.train"]
+
+
+def test_each_ops_lowering_time_is_filed_under_its_scope(monkeypatch):
+    """`trace_op_us.<owner>.<scope>`: the names the device trace carries,
+    one key a scope the step lowered, and together inside the trace
+    stage, which also holds the jit's own work on 160 state arrays."""
+    compiled, args = _tiny_bert_step()
+    lowered_ops, op_scope = set(), registry.op_scope
+    monkeypatch.setattr(registry, "op_scope", lambda op: (
+        lowered_ops.add(op_scope(op)) or op_scope(op)))
+    before = profiler.counters()
+    compiled.fn(*args)
+    filed = _filed("trace_op_us.", since=before)
+    assert set(filed) == {f"trace_op_us.train.{s}" for s in lowered_ops}
+    trace = _filed("compile_trace_us.", since=before)["compile_trace_us.train"]
+    assert 0.5 * trace < sum(filed.values()) <= trace
+    # the ops whose lowering can reach a kernel, forward and gradient
+    # (this step's attention is written out in matmuls and a softmax)
+    kernel_ops = set(_reads("step_kernel_ops_trace_s"))
+    assert {"trace_op_us.train.fwd/layer_norm",
+            "trace_op_us.train.bwd/layer_norm_grad",
+            "trace_op_us.train.fwd/lookup_table",
+            "trace_op_us.train.bwd/lookup_table_grad",
+            } == kernel_ops & set(filed)
+
+
+def test_an_op_lowered_inside_another_is_not_counted_twice():
+    """A `while` body's ops are lowered through `lower_op` inside the
+    `while` op's own lowering: each files its own time, and the sum stays
+    inside the trace stage."""
+    i = fluid.layers.fill_constant([1], "int64", 0)
+    limit = fluid.layers.fill_constant([1], "int64", 5)
+    total = fluid.layers.fill_constant([1], "float32", 0.0)
+    cond = fluid.layers.less_than(i, limit)
+    loop = fluid.layers.While(cond)
+    with loop.block():
+        fluid.layers.assign(fluid.layers.scale(total, 1.0, bias=2.0), total)
+        fluid.layers.increment(i, 1.0, in_place=True)
+        fluid.layers.less_than(i, limit, cond=cond)
+    exe = fluid.Executor(fluid.CPUPlace())
+    before = profiler.counters()
+    (got,) = exe.run(fetch_list=[total])
+    assert float(got[0]) == 10.0
+    filed = _filed(since=before)
+    ops = {k: v for k, v in filed.items() if k.startswith("trace_op_us.")}
+    assert {"trace_op_us.forward.fwd/while", "trace_op_us.forward.fwd/scale",
+            "trace_op_us.forward.fwd/increment"} <= set(ops)
+    assert sum(ops.values()) <= filed["compile_trace_us.forward"]
+
+
+@pytest.mark.parametrize("metric", COMPILE_METRICS)
+def test_a_compile_metric_reads_counters_the_program_files(metric):
+    """PR 49's ten per-layer metrics: a file each, the same fields in
+    `BENCHMARK.json`, and counters of the families `jit_compile`,
+    `_first_call` and `lower_op` file (the tests above see each bumped)."""
+    from benchmark.harness import spec
+
+    m = spec.load("layer_metrics", metric)
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        (declared,) = [x for x in json.load(f)["per_layer"]
+                       if x["name"] == metric]
+    count = metric.endswith("compiles")
+    assert declared == {
+        "name": metric, "unit": "count" if count else "s", "better": "lower",
+        "source": "program_counter" if count else "program_span",
+        "layer": "L0 compile path", "moves": "setup_s"}
+    assert {k: m[k] for k in declared} == declared
+    assert m["kind"] == "counter_delta" and "where" not in m
+    assert m["args"].get("scale", 1) == (1 if count else 1e-6)
+    assert m["args"]["phase"] == "setup"
+    owners = ("train", "forward", "other")
+    families = STAGES + ("compile_cache_read_us", "compile_requests",
+                         "compile_cache_compiled", "program_first_call_us")
+    filed = {f"{family}.{owner}" for family in families for owner in owners}
+    filed |= {f"trace_op_us.train.{phase}/{op}{grad}"
+              for op in registry.all_op_types()
+              for phase, grad in (("fwd", ""), ("bwd", "_grad"))}
+    counters = m["args"]["counters"]
+    assert counters and set(counters) <= filed, set(counters) - filed
+    assert len(set(counters)) == len(counters)
+
+
+def test_a_rehearsal_line_carries_the_two_compile_counts():
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmark", "run.py"),
+         "--workload", "bert_base_s128", "--seed", "5", "--seconds", "1",
+         "--trace", "1", "--rehearse"],
+        cwd=REPO, env=dict(os.environ, PYTHONPATH=REPO), capture_output=True,
+        text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    metrics = json.loads(proc.stdout.strip().splitlines()[-1])["metrics"]
+    # the reference's jit and the placeholder of a state nothing has set
+    assert metrics["setup_other_compiles"]["value"] >= 1
+    # the rehearsal turns the persistent cache off: nothing is looked up
+    assert metrics["setup_uncached_compiles"] == {"value": 0, "unit": "count"}
+    assert not any(name.endswith("_s") for name in metrics)  # no time
 
 
 # ---------------------------------------- what reads the declared costs
