@@ -38,13 +38,16 @@ chunk to chunk.
 **Keeping the cumulative decay finite.** `exp(G_i - G_j)` is at most 1,
 but the factorisation `(k_i exp(G_i)) . (k_j exp(-G_j))` that turns `A`
 into a matrix product is not: a decay of 0.01 a token makes `exp(-G_j)`
-overflow float32 after twenty tokens. So a chunk is cut into sub-chunks
-of 16. For a pair of sub-chunks I > J the exponent is split at the last
-position before I, `Gs_I`: `exp(G_i - Gs_I)` and `exp(Gs_I - G_j)` are
-both at most 1, whatever the decay, and an underflow to 0 is the right
-answer to float32's precision. The four diagonal blocks (I = J) have no
-such point between i and j, and are computed directly from the
-[16, 16, dk] tensor of exponents, masked to j <= i before the `exp`.
+overflow float32 after twenty tokens. So `kda_chunked` cuts a chunk into
+sub-chunks of `SUB` = 16 (its own constant: the kernels split at every
+level from the chunk down to blocks of four rows instead, by the same
+argument, and are a different evaluation of the same sums). For a pair of sub-chunks
+I > J the exponent is split at the last position before I, `Gs_I`:
+`exp(G_i - Gs_I)` and `exp(Gs_I - G_j)` are both at most 1, whatever the
+decay, and an underflow to 0 is the right answer to float32's precision.
+The four diagonal blocks (I = J) have no such point between i and j, and
+are computed directly from the [16, 16, dk] tensor of exponents, masked
+to j <= i before the `exp`.
 Everything that multiplies `S_0` carries an exponent `G_i` or
 `G_C - G_i`, at most 0 too. Nothing is clamped.
 """
@@ -59,10 +62,11 @@ import jax.numpy as jnp
 from .. import profiler
 from .pallas import kda_chunk as kda_kernel
 from .pallas import short_conv as conv_kernel
-from .pallas.kda_chunk import CHUNK, SUB  # SUB: see the module docstring
+from .pallas.kda_chunk import CHUNK
 from .registry import register_op
 
 
+SUB = 16  # `kda_chunked`'s sub-chunk: see the module docstring
 ACTIVATIONS = ("silu", "none")  # what follows the short convolution's taps
 
 

@@ -14,13 +14,23 @@ sequential chunk axis of the grid and is zeroed at chunk 0.
 
 Per chunk, all in VMEM:
 
-- `A` and `Aq` by the sub-chunk scheme: a pair of sub-chunks I > J as a
-  product whose exponents are split at `Gs_I`; the four diagonal 16x16
-  blocks directly, all four and all their columns at once
-  (`exp(G_i - G_j)` masked to j <= i *before* the `exp`, times `k_j`,
-  summed over the lanes: a [16, 4, 16, 128] array in VMEM), as the
-  public flash-linear-attention kernels do a column at a time. Nothing
-  is clamped.
+- `A` and `Aq` as masked products, one a level of a split carried
+  down from the chunk to blocks of LEAF = 4 rows (`_scores`,
+  `_query_scores`). At the level of blocks of m rows (m = 64, 32, 16,
+  8) a pair i > j whose rows lie in the two halves of one block has its
+  exponent split at the last row of the lower half: `exp(G_i - G_ref)`
+  and `exp(G_ref - G_j)` are both at most 1, as in `kda_chunked`'s
+  sub-chunks, and here at every distance down to the blocks of 4. A row
+  is in one half a level, so a level is one [64, 128] array of
+  exponentials and one product `(k*e) (k*e)^T` (and `(q*e) (k*e)^T`),
+  masked to its own pairs; the levels' products do not depend on one
+  another, and each pair j < i outside the blocks of 4 belongs to one.
+  The six pairs inside a block of 4, three distances, are formed
+  directly, `exp(G_i - G_j)` masked before the `exp`, times `k_j`,
+  summed over the lanes: three [64, 128] arrays (four levels are one
+  product a unit of the MXU; a fifth and a sixth, down to pairs of
+  rows, waited behind them and measured slower: PERF.md, PR 50). `Aq`'s
+  diagonal is a sum over the lanes. Nothing is clamped.
 - `T = (I + Diag(beta) A)^-1` by block forward substitution, doubling
   the block from 2 to 64 (`_inverse`: ten 64x64 products, no loop over
   rows). `[Wv, Wk] = T Diag(beta) [V, K exp(G)]`.
@@ -34,8 +44,9 @@ v, g, beta and the state the chunk started from, which the forward wrote
 (`[b*h, n, dv, dk]` float32, 134 MB a layer at 4,096 tokens and 32
 heads), and writes dq, dk, dv, dg and dbeta. The gradient of the solve
 is the transposed solve, `Lambda = T^T [dWv, dWk]`, with the `T` just
-rebuilt; the gradients of `A` and `Aq` go back through the same
-sub-chunk scheme, with the decays the rebuilt forward kept.
+rebuilt; the gradients of `A` and `Aq` go back level by level, two
+products a level, and through the blocks of 4 directly, with the factors
+the rebuilt forward kept.
 
 Precision is the op's: every `exp`, mask, sum and the state are float32.
 The cumulative log-decay `G` is summed in the kernel in float32, by
@@ -59,11 +70,15 @@ from . import cost
 from .flash_attention import LANE, _interpret, _use_pallas, require_pallas
 
 CHUNK = 64
-SUB = 16  # sub-chunk: ops/linear_attn_ops.py's module docstring
+LEAF = 4  # rows of the blocks whose pairs `_leaf_pairs` forms directly
+TILE = 8  # sublanes of a float32 register: a rotation stays inside one
 # Chunks a grid step, each a copy of the chunk's code in the kernel: two
 # overlap one chunk's state-free work with the other's chain of products.
 # Four measured 3 ms of a 237 ms step faster and 2.5 s of set-up slower at
-# every start of a job (the host lowers each copy; PERF.md, PR 32).
+# every start of a job (the host lowers each copy; PERF.md, PR 32). The
+# overlap is small: a unit of the MXU takes its products in the order the
+# program states them, so the copies' chains run one after the other
+# (PERF.md section 7, PR 50).
 CHUNKS_PER_STEP = 2
 
 _NN = ((1,), (0,))  # [m, k] x [k, n]
@@ -117,89 +132,142 @@ def _cumsum(x, reverse=False):
     return x
 
 
-def _diagonal(q, k, G):
-    """For the diagonal blocks, all of them and all of their columns at
-    once: q and k as [C/SUB, SUB, dk], and as [SUB, C/SUB, SUB, dk], the
-    column j inside the block first, `decay` = exp(G_i - G_j) for the
-    rows i >= j and 0 above (masked before the exp), and `k_j * decay`."""
-    c, dk = q.shape
-    q3, k3, G3 = (t.reshape(c // SUB, SUB, dk) for t in (q, k, G))
-
-    def row(t3):  # row j of every sub-chunk: [SUB, C/SUB, 1, dk]
-        return jnp.swapaxes(t3[:, :, None, :], 0, 1)
-
-    shape = (SUB, c // SUB, SUB, dk)
-    decay = jnp.exp(jnp.where(_iota(shape, 2) >= _iota(shape, 0),
-                              G3[None] - row(G3), -jnp.inf))
-    return q3, k3, decay, row(k3) * decay
+def _block_reference(G, m):
+    """At every row of a block of `m` rows, the block's reference row:
+    the last of its lower half, `mid - 1`. Blocks are whole sublane
+    tiles (m >= 8), so it is a slice and a broadcast."""
+    c, dk = G.shape
+    ref = G.reshape(c // m, m, dk)[:, m // 2 - 1:m // 2]
+    return jnp.broadcast_to(ref, (c // m, m, dk)).reshape(c, dk)
 
 
-def _scores(q, k, G, dtype):
-    """A (j < i) and Aq (j <= i) of one chunk, [C, C] each, and what the
-    backward uses again: the sub-chunks' factors off the diagonal, and
-    `_diagonal`'s arrays."""
-    c, dk = q.shape
-    a_rows = aq_rows = (jnp.zeros((SUB, c), jnp.float32),)
-    factors = ()
-    for lo in range(SUB, c, SUB):
-        q_sub, k_sub, g_sub = (t[lo:lo + SUB] for t in (q, k, G))
-        g_split = G[lo - 1:lo]  # Gs_I
-        inner = jnp.exp(g_sub - g_split)  # exp(G_i - Gs_I) <= 1
-        # exp(Gs_I - G_j) <= 1 for the earlier rows j, 0 from lo on
-        outer = jnp.exp(jnp.where(_iota((c, dk), 0) < lo, g_split - G,
+def _levels(c):
+    """The blocks a chunk of `c` rows is split at: c, c/2, ..., 2 * LEAF
+    rows."""
+    return tuple(c >> n for n in range((c // LEAF).bit_length() - 1))
+
+
+def _pair_masks(c):
+    """Which pairs (i, j) of a [C, C] each step of `_scores` forms, from
+    i ^ j: its highest bit says at which level the pair is split, m / 2
+    <= i ^ j < m for blocks of m rows, and under LEAF both rows lie in
+    one block of LEAF rows, i - j apart. Made once a chunk (the host pays
+    for every jnp call of the kernel's body at each start of a job)."""
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    level = row ^ col
+    levels = {m: level >> (m.bit_length() - 2) == 1 for m in _levels(c)}
+    leaves = tuple((level < LEAF) & (row - col == o) for o in range(1, LEAF))
+    return levels, leaves, row == col, row > col
+
+
+def _leaf_pairs(k, G):
+    """The pairs inside a block of LEAF rows, formed directly, by their
+    distance o = i - j: `decay` = exp(G_i - G_(i-o)) at the rows i that
+    have a row i - o in their block and 0 at the others (masked before
+    the exp), and k_(i-o) * decay. The earlier row comes by a rotation
+    of each tile's sublanes."""
+    c, dk = k.shape
+    tiles = (c // TILE, TILE, dk)
+    G, k = G.reshape(tiles), k.reshape(tiles)
+    place = _iota(tiles, 1) & (LEAF - 1)
+    leaves = ()
+    for o in range(1, LEAF):
+        decay = jnp.exp(jnp.where(place >= o, G - pltpu.roll(G, o, 1),
                                   -jnp.inf))
-        x_in = jnp.concatenate([k_sub * inner, q_sub * inner], axis=0)
-        k_out = k * outer
-        off = _mm(x_in, k_out, _NT, dtype)  # [2*SUB, C], 0 from column lo
-        a_rows += (off[:SUB],)
-        aq_rows += (off[SUB:],)
-        factors += ((inner, outer, x_in, k_out),)
-    diagonal = q3, k3, _, kj = _diagonal(q, k, G)
-    # column j of block I goes to column SUB*I + j of the chunk's rows
-    shape = (SUB, c // SUB, SUB, c)
-    here = _iota(shape, 3) == SUB * _iota(shape, 1) + _iota(shape, 0)
-
-    def place(x3):  # sum over dk, [j, C/SUB, i, 1], then over j: [C, C]
-        cols = jnp.sum(x3[None] * kj, 3, keepdims=True)
-        return jnp.sum(jnp.where(here, cols, 0.0), 0).reshape(c, c)
-
-    A = jnp.concatenate(a_rows, axis=0) + place(k3)
-    A = jnp.where(_iota(A.shape, 0) > _iota(A.shape, 1), A, 0.0)
-    return (A, jnp.concatenate(aq_rows, axis=0) + place(q3),
-            (factors, diagonal))
+        leaves += ((decay.reshape(c, dk),
+                    (pltpu.roll(k, o, 1) * decay).reshape(c, dk)),)
+    return leaves
 
 
-def _scores_grad(dA, dAq, kept, dtype):
-    """The gradients of `_scores`, from what it `kept`: of q (`dq`), of k
-    as the row operand of A (`dk_row`) and of k as the column operand of
-    both (`dk_col`). dG's share is q*dq + k*dk_row - k*dk_col."""
-    factors, (q3, k3, decay, kj) = kept
-    c, dk = dA.shape[0], q3.shape[2]
-    zeros = jnp.zeros((SUB, dk), jnp.float32)
-    dq_rows, dkl_rows, dk_col = (zeros,), (zeros,), 0.0
-    for n, lo in enumerate(range(SUB, c, SUB)):
-        inner, outer, x_in, k_out = factors[n]
-        d_off = jnp.concatenate([dA[lo:lo + SUB], dAq[lo:lo + SUB]], axis=0)
-        # columns from lo on meet k_out's zero rows, outer's zeros
-        d_in = _mm(d_off, k_out, _NN, dtype)
-        dkl_rows += (d_in[:SUB] * inner,)
-        dq_rows += (d_in[SUB:] * inner,)
-        dk_col = dk_col + outer * _mm(d_off, x_in, _TN, dtype)
+def _scores(k, G, dtype):
+    """A (j < i) of one chunk, [C, C], and what `_query_scores` and the
+    backward use again: the pairs' masks, a level's `e` and `k*e` as the
+    product read it, and `_leaf_pairs`' arrays.
 
-    def columns(d):  # of d's diagonal blocks: [j, C/SUB, i, 1]
-        blocks = jnp.concatenate(
-            [d[lo:lo + SUB, lo:lo + SUB] for lo in range(0, c, SUB)],
-            axis=0).reshape(c // SUB, SUB, SUB)
-        return jnp.stack([blocks[:, :, j:j + 1] for j in range(SUB)], axis=0)
+    Level m = C, C/2, ..., 2 * LEAF gives the pairs whose rows lie in
+    the two halves of one block of m rows, i in the upper and j in the
+    lower: with `ref` the lower half's last row, exp(G_i - G_j) =
+    exp(G_i - G_ref) exp(G_ref - G_j), both exponents <= 0 as G does not
+    rise along the rows. A row has one role a level, so one `e` =
+    exp(-|G - G_ref|) serves all rows and one product (k*e) (k*e)^T
+    all blocks; what it holds outside "same block, i upper, j lower" is
+    masked away. The levels' pairs are disjoint, and with the pairs
+    inside the blocks of LEAF rows they are all j < i."""
+    c, dk = k.shape
+    masks = at_level, at_leaf, _, below = _pair_masks(c)
+    upper = _iota((c, dk), 0)
+    A, levels = 0.0, ()
+    for m in at_level:
+        d = G - _block_reference(G, m)
+        e = jnp.exp(jnp.where(upper & (m // 2) != 0, d, -d))
+        ke = (k * e).astype(dtype)
+        A = jnp.where(at_level[m], _mm(ke, ke, _NT, dtype), A)
+        levels += ((m, e, ke),)
+    leaves = _leaf_pairs(k, G)
+    for here, (_, kj) in zip(at_leaf, leaves):
+        A = jnp.where(here, jnp.sum(k * kj, 1, keepdims=True), A)
+    return jnp.where(below, A, 0.0), (masks, levels, leaves)
 
-    ca, cq = columns(dA), columns(dAq)
-    dq = jnp.sum(cq * kj, 0).reshape(c, dk)
-    dkl = jnp.sum(ca * kj, 0).reshape(c, dk)
-    # row j of every sub-chunk, [j, C/SUB, 1, dk], back to its place
-    dkr = jnp.sum((ca * k3[None] + cq * q3[None]) * decay, 2, keepdims=True)
-    dkr = jnp.swapaxes(dkr, 0, 1).reshape(c, dk)
-    return (jnp.concatenate(dq_rows, axis=0) + dq,
-            jnp.concatenate(dkl_rows, axis=0) + dkl, dk_col + dkr)
+
+def _query_scores(q, k, kept, dtype, transposed=False):
+    """Aq (j <= i) as `_scores` forms A, with q for the rows, or its
+    transpose (the levels' products the other way round: the backward
+    reads Aq as Aq^T alone), and the levels' [k*e; q*e] for the
+    backward. Apart from `_scores`, because nothing before U reads Aq:
+    its products queue behind the solve's."""
+    masks, levels, leaves = kept
+    at_level, at_leaf, diagonal, below = masks
+    Aq = jnp.where(diagonal, jnp.sum(q * k, 1, keepdims=True), 0.0)  # j = i
+    for here, (_, kj) in zip(at_leaf, leaves):
+        Aq = jnp.where(here, jnp.sum(q * kj, 1, keepdims=True), Aq)
+    if transposed:  # the levels' masks are their own transposes
+        Aq = Aq.T
+    levels_q = ()
+    for m, e, ke in levels:
+        qe = (q * e).astype(dtype)
+        pairs = _mm(ke, qe, _NT, dtype) if transposed else _mm(qe, ke, _NT,
+                                                               dtype)
+        Aq = jnp.where(at_level[m], pairs, Aq)
+        levels_q += ((m, e, jnp.concatenate([ke, qe], axis=0)),)
+    # a level's product holds the pairs i < j too
+    return (jnp.where(~below if transposed else below | diagonal, Aq, 0.0),
+            (masks, levels_q, leaves))
+
+
+def _scores_grad(dA, dAq, q, k, kept, dtype):
+    """The gradients of `_scores` and `_query_scores`, from what they
+    `kept`: of q (`dq`), of k as the row operand of A (`dk_row`) and of k
+    as the column operand of both (`dk_col`). dG's share is q*dq +
+    k*dk_row - k*dk_col. `dA` is 0 from the diagonal up and `dAq` above
+    it."""
+    c, dk = q.shape
+    (at_level, at_leaf, diagonal, _), levels, leaves = kept
+    d_both = jnp.concatenate([dA, dAq], axis=0)
+
+    def both(mask):  # of dA and of dAq, [2C, C]
+        return jnp.where(jnp.concatenate([mask, mask], axis=0), d_both, 0.0)
+
+    # Aq's diagonal carries no decay: its k goes with the columns', so
+    # that dG's share of it is q*k - k*q
+    on_diagonal = jnp.sum(jnp.where(diagonal, dAq, 0.0), 1, keepdims=True)
+    dq, dk_row, dk_col = on_diagonal * k, 0.0, on_diagonal * q
+    for m, e, x in levels:
+        d = both(at_level[m])
+        d_in = _mm(d, x[:c], _NN, dtype)  # to the rows i of the upper halves
+        dk_row = dk_row + d_in[:c] * e
+        dq = dq + d_in[c:] * e
+        dk_col = dk_col + _mm(d, x, _TN, dtype) * e  # to the lower halves' j
+    tiles = (c // TILE, TILE, dk)
+    for o, (here, (decay, kj)) in enumerate(zip(at_leaf, leaves), 1):
+        d = jnp.sum(both(here), 1, keepdims=True)
+        da, daq = d[:c], d[c:]
+        dk_row = dk_row + da * kj
+        dq = dq + daq * kj
+        # to the rows i - o: the tiles' sublanes rotated back
+        back = pltpu.roll(((da * k + daq * q) * decay).reshape(tiles),
+                          TILE - o, 1)
+        dk_col = dk_col + back.reshape(c, dk)
+    return dq, dk_row, dk_col
 
 
 def _inverse(N, dtype):
@@ -224,15 +292,17 @@ def _inverse(N, dtype):
     return inv
 
 
-def _state_free(q, k, v, g, beta, dtype):
+def _state_free(q, k, v, g, beta, dtype, transposed_aq=False):
     """What one chunk computes from no state: from the cumulative
-    log-decay G the decays, A, Aq (and what `_scores` kept), T and the WY
-    factors [Wv, Wk]."""
+    log-decay G the decays, A, Aq or with `transposed_aq` its transpose
+    (and what `_scores` and `_query_scores` kept), T and the WY factors
+    [Wv, Wk]."""
     G = _cumsum(g)
     E = jnp.exp(G)
     e_end = jnp.exp(G[-1:] - G)  # exp(G_C - G_i) <= 1
-    A, Aq, kept = _scores(q, k, G, dtype)
+    A, kept = _scores(k, G, dtype)
     T = _inverse(beta * A, dtype)
+    Aq, kept = _query_scores(q, k, kept, dtype, transposed_aq)
     W = _mm(T, jnp.concatenate([beta * v, beta * (k * E)], axis=1), _NN,
             dtype)
     return E, e_end, jnp.exp(G[-1:]), A, Aq, kept, T, W
@@ -263,12 +333,12 @@ def _chunk_bwd(q, k, v, g, beta, St, dSt, dO, *, dtype):
     mm = functools.partial(_mm, dtype=dtype)
     c, dv = v.shape
     row, col = _iota((c, c), 0), _iota((c, c), 1)
-    E, e_end, decay_end, A, Aq, kept, T, W = _state_free(q, k, v, g, beta,
-                                                         dtype)
+    E, e_end, decay_end, A, AqT, kept, T, W = _state_free(q, k, v, g, beta,
+                                                          dtype, True)
     ke, kd, Wk = k * e_end, k * E, W[:, dv:]
     U = W[:, :dv] - mm(Wk, St, _NT)
     dO = q.shape[1] ** -0.5 * dO
-    dU = mm(Aq, dO, _TN) + mm(ke, dSt, _NT)
+    dU = mm(AqT, dO, _NN) + mm(ke, dSt, _NT)
     by_state = mm(jnp.concatenate([dO, dU], axis=0), St, _NN)
     d_qd, dWk = by_state[:c], -by_state[c:]
     d_ke = mm(U, dSt, _NN)
@@ -282,7 +352,7 @@ def _chunk_bwd(q, k, v, g, beta, St, dSt, dO, *, dtype):
     dSt_new = dSt * decay_end + mm(dO, q * E, _TN) - mm(dU, Wk, _TN)
     dg_end = (jnp.sum(dSt * St, 0, keepdims=True) * decay_end
               + jnp.sum(d_ke * ke, 0, keepdims=True))
-    dq, dkl, dkr = _scores_grad(-beta * lam_w, dAq, kept, dtype)
+    dq, dkl, dkr = _scores_grad(-beta * lam_w, dAq, q, k, kept, dtype)
     dq = dq + d_qd * E
     dkl = dkl + d_kd * E
     dkr = dkr + d_ke * e_end
@@ -357,10 +427,12 @@ def _cost(backward, b, s, heads, dk, dv, dtypes):
       as column (2*c*c*dk).
 
     Not counted: the ten [c, c] products that build the inverse (a solve
-    needs none), the diagonal blocks' sums over the lanes beyond their
-    pairs. Exponentials as the sub-chunk scheme evaluates them a chunk:
-    exp(G), exp(G_c - G), the sub-chunks' inner and outer factors and the
-    diagonal blocks', over the keys' lanes."""
+    needs none), and what the four levels' products hold outside their
+    own pairs (each is a whole [c, c] of which a level keeps c*c/4 entries
+    or fewer). Exponentials as the kernels evaluate them a chunk: exp(G),
+    exp(G_c - G), exp(G_c), the rows' factors at each of the four levels
+    and the decays at the three distances inside a block of LEAF rows,
+    over the keys' lanes."""
     def chunk(c):
         lower, strict, state = c * (c + 1) // 2, c * (c - 1) // 2, c * dk * dv
         if backward:
@@ -370,8 +442,8 @@ def _cost(backward, b, s, heads, dk, dv, dtypes):
 
     chunks = -(-s // CHUNK)
     macs = (s // CHUNK) * chunk(CHUNK) + chunk(s % CHUNK)
-    exps = chunks * dk * (2 * CHUNK + 1 + (CHUNK - SUB)
-                          + (CHUNK // SUB - 1) * CHUNK + SUB * CHUNK)
+    exps = chunks * dk * (2 * CHUNK + 1
+                          + (len(_levels(CHUNK)) + LEAF - 1) * CHUNK)
     wide, narrow = dtypes
     keys, values = ((b, s, heads * dk), wide), ((b, s, heads * dv), narrow)
     beta = ((b, s, heads), jnp.float32)

@@ -17,10 +17,14 @@ def rel(got, want):
     return float(np.linalg.norm(got - want) / np.linalg.norm(want))
 
 
-def _args(length, g_lo, g_hi, seed=None, parallel=False):
+def _args(length, g_lo, g_hi, seed=None, parallel=False, alternate=False):
     """`parallel`: every q and k is one direction a head plus 0.3 of
     noise, and beta is near 1, as a trained or freshly SiLU-ed projection
-    gives them: A's entries are then near 1, not near 128^-1/2."""
+    gives them: A's entries are then near 1, not near 128^-1/2.
+    `alternate`: the log decay is `g_lo` or `g_hi` and nothing between,
+    changing from each row to the next and from each channel to the next,
+    so every split of the kernel's levels, and every pair of rows inside
+    its smallest blocks, has both extremes on both of its sides."""
     import jax.numpy as jnp
 
     r = np.random.RandomState(length if seed is None else seed)
@@ -32,9 +36,12 @@ def _args(length, g_lo, g_hi, seed=None, parallel=False):
         noise = r.randn(B, length, H, D)
         return unit(r.randn(1, 1, H, D) + 0.3 * noise if parallel else noise)
 
+    g = r.uniform(g_lo, g_hi, (B, length, H, D))
+    if alternate:
+        even = (np.arange(length)[:, None, None] + np.arange(D)) % 2 == 0
+        g = np.broadcast_to(np.where(even, g_lo, g_hi), g.shape)
     return [jnp.asarray(t, jnp.float32) for t in (
-        direction(), direction(),
-        r.randn(B, length, H, D), r.uniform(g_lo, g_hi, (B, length, H, D)),
+        direction(), direction(), r.randn(B, length, H, D), g,
         r.uniform(0.9 if parallel else 0, 1, (B, length, H)))]
 
 
@@ -50,29 +57,38 @@ def _loss_grads(fn, args):
     return jax.grad(lambda *a: jnp.sum(fn(*a) ** 2), argnums=range(5))(*args)
 
 
-# the regimes of test_chunked_kda_equals_the_token_recurrence, and one more
+# the regimes of test_chunked_kda_equals_the_token_recurrence, and three more
 REGIMES = [
-    (64, -0.1, -0.001, False),  # one whole chunk, mild decay
-    (128, -1.0, -0.01, False),  # two whole chunks, one grid step
-    (100, -8.0, -3.0, False),  # decays near 0: exp(-G) would overflow
-    (200, -1e-4, -1e-6, False),  # decays near 1: the state forgets nothing
-    (37, -20.0, 0.0, False),  # shorter than a chunk, both extremes in a row
-    (130, -2.0, -0.01, False),  # two tokens into a third chunk and grid step
+    (64, -0.1, -0.001, ""),  # one whole chunk, mild decay
+    (128, -1.0, -0.01, ""),  # two whole chunks, one grid step
+    (100, -8.0, -3.0, ""),  # decays near 0: exp(-G) would overflow
+    (200, -1e-4, -1e-6, ""),  # decays near 1: the state forgets nothing
+    (37, -20.0, 0.0, ""),  # shorter than a chunk, both extremes in a row
+    (130, -2.0, -0.01, ""),  # two tokens into a third chunk and grid step
     # nearly parallel keys, beta near 1, hardly any decay: the system
     # I + Diag(beta) A is far from I, and a series in A's powers diverges
-    (127, -0.01, -1e-4, True),
+    (127, -0.01, -1e-4, "parallel"),
+    # both extremes on both sides of every split of the kernel's levels
+    # and between any two rows of its blocks of 4: a factor that left its
+    # split's side would read exp(+20) there
+    (64, -20.0, 0.0, "alternate"),
+    # A's entries near 1 where they are not 0: the pairs a few rows apart
+    # are all that is left of them, the ones the lowest levels and the
+    # blocks of 4 form
+    (100, -8.0, -3.0, "parallel"),
 ]
 
 
-@pytest.mark.parametrize("length,g_lo,g_hi,parallel", REGIMES)
+@pytest.mark.parametrize("length,g_lo,g_hi,kind", REGIMES)
 def test_kernel_equals_the_recurrence_and_the_plain_path(
-        interpreter, length, g_lo, g_hi, parallel):
+        interpreter, length, g_lo, g_hi, kind):
     import jax
 
     from paddle_tpu.ops.linear_attn_ops import kda_chunked
     from paddle_tpu.ops.pallas.kda_chunk import kda_chunk
 
-    args = _args(length, g_lo, g_hi, parallel=parallel)
+    args = _args(length, g_lo, g_hi, parallel=kind == "parallel",
+                 alternate=kind == "alternate")
     with jax.default_matmul_precision("highest"):
         got = kda_chunk(*args)
         want = ref.kda_recurrence(*args)
@@ -216,13 +232,59 @@ def test_declared_cost_against_a_count_by_hand(interpreter, kernel, length):
     chunks = {128: [64, 64], 100: [64, 36]}[length]
     assert got.flops == 2 * B * H * sum(macs(c) for c in chunks)
     # a chunk's exponentials over the 128 lanes: exp(G) and exp(G_c - G)
-    # 64 rows each, exp(G_c) 1, three sub-chunks' inner (16 rows) and outer
-    # (64 rows) factors, the diagonal blocks 16 x 64
-    assert got.transcendentals == B * H * 2 * D * (
-        64 + 64 + 1 + 3 * 16 + 3 * 64 + 16 * 64)
+    # 64 rows each, exp(G_c) 1, the rows' factors at each of the four
+    # levels (blocks of 64, 32, 16, 8 rows) and the decays at the three
+    # distances inside a block of 4 rows, 64 rows each
+    assert got.transcendentals == B * H * 2 * D * (64 + 64 + 1 + 4 * 64
+                                                   + 3 * 64)
     wide, narrow = 4 * B * length * H * D, 2 * B * length * H * D
     beta_bytes, states = 4 * B * length * H, 4 * B * H * 2 * D * D
     moved = 3 * wide + narrow + beta_bytes + states + narrow  # ..., o or dO
     if kernel == "kda_bwd":  # dq, dk, dg, dv, dbeta
         moved += 3 * wide + narrow + beta_bytes
     assert got.bytes_accessed == moved
+
+
+def test_bf16_products_against_the_float32_recurrence(interpreter):
+    """What the chip runs: `_chunk_fwd` and `_chunk_bwd` with
+    `dtype=bfloat16`, every product's operands rounded to bf16 and summed
+    in float32, here under the interpreter on the CPU, against the float32
+    token recurrence. The regime is the one that rounding hurts most:
+    nearly parallel keys, so A's entries are near 1 and the solve
+    amplifies what they lost.
+
+    Distances read here, relative, in the order o, dq, dk, dv, dg, dbeta.
+    The sub-chunk scheme of PR 32 to 49, whose diagonal blocks of 16 were
+    float32 sums: 0.0115, 0.0099, 0.0101, 0.0138, 0.0097, 0.0103. The
+    levels, where every pair outside a block of 4 is a product: 0.0116,
+    0.0106, 0.0108, 0.0139, 0.0105, 0.0099. The limit is twice the
+    largest."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import kda_chunk as kernel
+
+    q, k, v, g, beta = _args(127, -0.01, -1e-4, parallel=True)
+    flat = [t.reshape(B, 127, -1) for t in (q, k, v, g)]
+    flat = [jnp.pad(t, ((0, 0), (0, 1), (0, 0))) for t in flat]
+    beta_p = jnp.pad(beta, ((0, 0), (0, 1), (0, 0)))
+    statics = (H, 2, jnp.bfloat16, True, 127)
+
+    def bf16(q, k, v, g, beta):
+        return kernel._core(q, k, v, g, beta, statics)
+
+    def grads(fn, args):
+        return jax.grad(lambda *a: jnp.sum(fn(*a) ** 2),
+                        argnums=range(5))(*args)
+
+    got = (bf16(*flat, beta_p), *grads(bf16, (*flat, beta_p)))
+    with jax.default_matmul_precision("highest"):
+        want = (ref.kda_recurrence(q, k, v, g, beta),
+                *_loss_grads(ref.kda_recurrence, (q, k, v, g, beta)))
+    read = []
+    for a, w in zip(got, want):
+        a = np.asarray(a)[:, :127].reshape(w.shape)
+        assert np.isfinite(a).all()
+        read.append(rel(a, w))
+    for name, distance in zip("o q k v g beta".split(), read):
+        assert distance < 0.028, (name, read)
