@@ -759,15 +759,19 @@ def selective_scan(x, delta, a, b, c, d, name=None):
 
 
 def kda_attention(q, k, v, g, beta, num_heads, l2norm_epsilon=1e-6,
-                  a_log_attr=None, dt_bias_attr=None, name=None):
-    """Kimi Delta Attention over one sequence a row (ops/linear_attn_ops.py
-    has the equations): q, k, g [b, s, h*dk], v [b, s, h*dv], beta
-    [b, s, h]. `q` and `k` are L2-normalised per head, `g` goes through
-    `-exp(A_log) * softplus(g + dt_bias)` to the log of the per-channel
-    decay and `beta` through a sigmoid, all in float32 inside the op; the
+                  a_log_attr=None, dt_bias_attr=None, name=None,
+                  num_key_heads=None):
+    """The gated delta rule over one sequence a row (ops/linear_attn_ops.py
+    has the equations): Kimi Delta Attention with q, k, g [b, s, h*dk], a
+    decay a channel, or Gated DeltaNet with g [b, s, h], a decay a head;
+    v [b, s, h*dv], beta [b, s, h]. With `num_key_heads` = h_k, a divisor
+    of h, q and k are [b, s, h_k*dk] and value head n reads key head
+    n // (h / h_k). `q` and `k` are L2-normalised per head, `g` goes
+    through `-exp(A_log) * softplus(g + dt_bias)` to the log of the decay
+    and `beta` through a sigmoid, all in float32 inside the op; the
     output is scaled by `dk^-1/2` and the state is zero at the start of a
-    row. Creates `A_log` [h] and `dt_bias`
-    [h*dk]. Returns [b, s, h*dv]."""
+    row. Creates `A_log` [h] and `dt_bias`, as wide as g. Returns
+    [b, s, h*dv]."""
     helper = LayerHelper("kda_attention", name=name)
     # the public KDA layer's seeding: A in [1, 16] and the step
     # softplus(dt_bias) in [0.001, 0.1], here both log-uniform
@@ -781,7 +785,10 @@ def kda_attention(q, k, v, g, beta, num_heads, l2norm_epsilon=1e-6,
         helper, "kda_attention",
         {"Q": [q], "K": [k], "V": [v], "GRaw": [g], "BetaRaw": [beta],
          "ALog": [a_log], "DtBias": [dt_bias]},
-        {"num_heads": num_heads, "l2norm_epsilon": l2norm_epsilon},
+        {"num_heads": num_heads, "l2norm_epsilon": l2norm_epsilon,
+         # on a Program with a key head a value head the op is as it was
+         **({"num_key_heads": int(num_key_heads)}
+            if num_key_heads and num_key_heads != num_heads else {})},
         dtype=v.dtype, shape=v.shape)
 
 
@@ -1344,6 +1351,7 @@ def fused_multihead_attention(
     rope_theta=0.0,
     rope_scaling=None,
     q_lora_rank=0,
+    rotary_dim=0,
 ):
     """Flash attention over q/k/v (Pallas kernel on TPU). layout="bhsd"
     (default): [b, nh, s, dh]; layout="bshd": [b, s, nh, dh] — the shape
@@ -1369,7 +1377,10 @@ def fused_multihead_attention(
     `rope_theta` > 0 (layout "bshd") then turns q and k by
     `rotary_embedding`'s positions 0..s-1, with a window or without
     one; `rope_scaling` (a published YaRN group, as `rotary_embedding`
-    takes it) scales the tables. Inside the op the two share one pass
+    takes it) scales the tables; `rotary_dim` fewer than `dh` (0: the
+    whole head) turns only the first `rotary_dim` lanes of a head, as a
+    head of that width, and passes the rest as normed
+    (`partial_rotary_factor`). Inside the op the two share one pass
     over q and k with the kernel's head-major write, where the kernel
     runs.
 
@@ -1411,6 +1422,8 @@ def fused_multihead_attention(
                if q_norm_attr is not None else {}),
             **({"rope_scaling": scaling} if scaling else {}),
             **({"q_lora_rank": int(q_lora_rank)} if q_lora_rank else {}),
+            **({"rotary_dim": int(rotary_dim)}
+               if rotary_dim and rotary_dim != q.shape[-1] else {}),
         },
         dtype=q.dtype,
         shape=list(q.shape[:-1]) + [v.shape[-1]],

@@ -2,8 +2,8 @@
 reference's test model zoo), built through the framework's own layers API —
 LeNet-5 (MNIST), ResNet (ImageNet), SE-ResNeXt, VGG, Transformer/BERT
 (WMT16 / pretrain), DeepFM (CTR), Kimi Linear, Trinity, Mellum, JoyAI
-Flash and LFM2 (each a share of an expert-parallel decoder), and
-Phi-4-mini-flash (a pipeline stage's share of a decoder-hybrid-decoder)."""
+Flash, LFM2 and Qwen3-Next (each a share of an expert-parallel decoder),
+and Phi-4-mini-flash (a pipeline stage's share of a decoder-hybrid-decoder)."""
 
 from . import (  # noqa: F401
     bert,
@@ -14,6 +14,7 @@ from . import (  # noqa: F401
     lfm2,
     mellum,
     phi4_flash,
+    qwen3_next,
     resnet,
     se_resnext,
     transformer,
@@ -25,4 +26,5 @@ from .kimi_linear import KimiLinearConfig, build_kimi_linear  # noqa: E402,F401
 from .lfm2 import Lfm2Config, build_lfm2  # noqa: E402,F401
 from .mellum import MellumConfig, build_mellum  # noqa: E402,F401
 from .phi4_flash import Phi4FlashConfig, build_phi4_flash  # noqa: E402,F401
+from .qwen3_next import Qwen3NextConfig, build_qwen3_next  # noqa: E402,F401
 from .trinity import TrinityConfig, build_trinity  # noqa: E402,F401

@@ -1,23 +1,26 @@
 """What the decoders of the zoo (`kimi_linear`, `trinity`, `mellum`,
-`joyai_flash`, `phi4_flash`, `lfm2`) build their layers from: projections
-seeded Normal(0, `initializer_range`), with a bias where asked, RMSNorm
-with a learned weight and LayerNorm with weight and bias, the SiLU-gated
-feed-forward as three products or with gate and up in one, attention over
-grouped key/value heads with QK-norm and rotary positions, latent
-attention (`kimi_linear`, `joyai_flash`), differential attention
-(`phi4_flash`), the double-gated short convolution (`lfm2`), and the
-expert layer that holds a share of the experts. A `cfg` gives
-`hidden_size`, `initializer_range`, `rms_norm_eps` (or `layer_norm_eps`),
-for `attention` and `differential_attention` the heads, for
-`latent_attention` the keys its docstring lists, for `gated_short_conv`
-`conv_L_cache`, and for `expert_ffn` the router's keys as
-`KimiLinearConfig` names them."""
+`joyai_flash`, `phi4_flash`, `lfm2`, `qwen3_next`) build their layers
+from: projections seeded Normal(0, `initializer_range`), with a bias where
+asked, RMSNorm with a learned weight and LayerNorm with weight and bias,
+the SiLU-gated feed-forward as three products or with gate and up in one,
+attention over grouped key/value heads with QK-norm and rotary positions
+on a head or on its first lanes, latent attention (`kimi_linear`,
+`joyai_flash`), differential attention (`phi4_flash`), the double-gated
+short convolution (`lfm2`), Gated DeltaNet (`qwen3_next`: the delta rule
+with a decay a head and key heads shared by groups of value heads), and
+the expert layer that holds a share of the experts, with a shared expert
+that a token may gate. A `cfg` gives `hidden_size`, `initializer_range`,
+`rms_norm_eps` (or `layer_norm_eps`), for `attention` and
+`differential_attention` the heads, for `latent_attention` the keys its
+docstring lists, for `gated_short_conv` `conv_L_cache`, for
+`gated_delta_net` the `linear_*` keys, and for `expert_ffn` the router's
+keys as `KimiLinearConfig` names them."""
 
 from __future__ import annotations
 
 import math
 
-from .. import layers
+from .. import layers, profiler
 from ..initializer import Normal, Uniform
 from ..param_attr import ParamAttr
 
@@ -76,6 +79,46 @@ def gated_short_conv(u, cfg, name):
                              initializer=Uniform(-edge, edge)), act=None)
     return proj(layers.elementwise_mul(c_gate, conv), cfg.hidden_size,
                 name + ".out_proj", cfg)
+
+
+def gated_delta_net(u, cfg, name):
+    """Qwen3-Next's linear mixer (Gated DeltaNet, arXiv:2412.06464), u
+    [b, s, hidden] to [b, s, hidden]: `linear_num_key_heads` heads of
+    `linear_key_head_dim` for q and k under `linear_num_value_heads` heads
+    of `linear_value_head_dim` for v and the output gate z, value head n
+    reading key head n // group; `[q ; k ; v ; z] = W_qkvz u` and
+    `[b ; a] = W_ba u`, one number a value head each; q, k and v pass
+    together through one causal depthwise convolution of
+    `linear_conv_kernel_dim` taps and a SiLU (the filter uniform in
+    +-taps^-1/2, no bias); the op `kda_attention` norms q and k, makes
+    `beta = sigmoid(b)` and the head's log decay `-exp(A_log) *
+    softplus(a + dt_bias)`, and runs the delta rule; each head's output
+    is RMS-normed over its lanes with one learned weight of
+    `linear_value_head_dim` and multiplied by `SiLU(z)` before `W_out`.
+    Every piece is one of the Program's ordinary ops."""
+    b, s, _ = u.shape
+    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    qkv, z = layers.split(
+        proj(u, 2 * hk * dk + 2 * hv * dv, name + ".in_proj_qkvz", cfg),
+        [2 * hk * dk + hv * dv, hv * dv], dim=2)
+    beta, a = layers.split(proj(u, 2 * hv, name + ".in_proj_ba", cfg), 2,
+                           dim=2)
+    edge = cfg.linear_conv_kernel_dim ** -0.5
+    q, k, v = layers.split(layers.short_conv1d(
+        qkv, cfg.linear_conv_kernel_dim,
+        param_attr=ParamAttr(name=name + ".conv.w_0",
+                             initializer=Uniform(-edge, edge))),
+        [hk * dk, hk * dk, hv * dv], dim=2)
+    o = layers.kda_attention(
+        q, k, v, a, beta, num_heads=hv, num_key_heads=hk,
+        l2norm_epsilon=cfg.l2norm_epsilon,
+        a_log_attr=ParamAttr(name=name + ".A_log"),
+        dt_bias_attr=ParamAttr(name=name + ".dt_bias"))
+    o = norm(layers.reshape(o, [b, s, hv, dv]), name + ".norm", cfg, axis=3)
+    o = layers.elementwise_mul(layers.reshape(o, [b, s, hv * dv]),
+                               layers.swish(z))
+    return proj(o, cfg.hidden_size, name + ".out_proj", cfg)
 
 
 def _by_pairs(t, b, s, pairs, d):
@@ -137,12 +180,13 @@ def differential_attention(u, cfg, name, window=0, kv=None, lam0=0.8):
 
 
 def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
-              gated=False):
+              gated=False, rotary_dim=0):
     """Causal attention of `num_attention_heads` query heads over
     `num_key_value_heads` key/value heads of `head_dim`, u [b, s, hidden]
     to [b, s, hidden]: q and k normed over a head's width (one weight of
     `head_dim` each), turned by rotary positions where `rope_theta` is
-    not 0 (`rope_scaling`: a YaRN group), `window` keys wide where it is
+    not 0 (`rope_scaling`: a YaRN group; `rotary_dim` not 0: the first
+    `rotary_dim` lanes of a head alone), `window` keys wide where it is
     not 0, and with `gated` the output times `sigmoid(W_g u)` before the
     output projection."""
     b, s, _ = u.shape
@@ -159,7 +203,7 @@ def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
         window=window, q_norm_attr=ParamAttr(name=name + ".q_norm.w_0"),
         k_norm_attr=ParamAttr(name=name + ".k_norm.w_0"),
         qk_norm_epsilon=cfg.rms_norm_eps, rope_theta=rope_theta,
-        rope_scaling=rope_scaling)
+        rope_scaling=rope_scaling, rotary_dim=rotary_dim)
     a = layers.reshape(a, [b, s, h * d])
     if gated:
         a = layers.elementwise_mul(a, gate)
@@ -220,7 +264,11 @@ def latent_attention(u, cfg, name):
 
 def expert_ffn(u, cfg, name, norm_eps=0.0):
     """Returns (what the shared expert and the held experts add, load).
-    `norm_eps`: added to the sum the selected scores are divided by."""
+    `norm_eps`: added to the sum the selected scores are divided by.
+    Where `cfg.shared_expert_gate` is set (absent: not) the shared
+    expert's output is multiplied by `sigmoid(w_sg . u)`, one number a
+    token from a projection of width 1 (`.shared_gate`; counter
+    `moe_shared_expert_gated`, once a layer built)."""
     routed, load = layers.moe_experts(
         u, experts_total=cfg.num_experts, experts_held=cfg.experts_held,
         d_ff=cfg.moe_intermediate_size, k=cfg.num_experts_per_token,
@@ -232,4 +280,8 @@ def expert_ffn(u, cfg, name, norm_eps=0.0):
         return routed, load
     shared = ffn(u, cfg.moe_intermediate_size * cfg.num_shared_experts,
                  name + ".shared", cfg)
+    if getattr(cfg, "shared_expert_gate", False):
+        profiler.bump_counter("moe_shared_expert_gated")
+        shared = layers.elementwise_mul(
+            shared, layers.sigmoid(proj(u, 1, name + ".shared_gate", cfg)))
     return layers.elementwise_add(shared, routed), load

@@ -115,7 +115,10 @@ def _fused_mha(ctx, op):
     `qk_norm_epsilon`; attr `rope_theta` > 0 then turns them by the op
     `rotary_embedding`'s positions, under attr `rope_scaling` (YaRN's five
     numbers, `nn_ops.yarn_frequencies`) by its scaled tables, whether the
-    layer has a window or none. On the flash path with layout "bshd"
+    layer has a window or none. Attr `rotary_dim` (absent: the whole
+    head) turns the first `rotary_dim` lanes of a head as a head of that
+    width and passes the rest as normed (`partial_rotary_factor`); gauge
+    `attn_rotary_lanes` then holds it. On the flash path with layout "bshd"
     and heads of whole 128-lane slices, that and the head-major write the
     kernel wants are one kernel pair (ops/pallas/qk_prep.py); on every
     other path the two ops' own functions run first, in `jnp`.
@@ -132,6 +135,7 @@ def _fused_mha(ctx, op):
     norm_eps = float(op.attr("qk_norm_epsilon", 1e-5))
     rope_theta = float(op.attr("rope_theta", 0.0) or 0.0)
     rope_scaling = rope_scaling_attr(op, "rope_scaling")
+    rotary_dim = int(op.attr("rotary_dim", 0) or 0)
     causal = op.attr("causal", False)
     dropout = float(op.attr("attn_dropout", 0.0))
     is_test = op.attr("is_test", False) or ctx.is_test
@@ -153,6 +157,14 @@ def _fused_mha(ctx, op):
     if rope_scaling and not rope_theta:
         raise ValueError(
             "fused_multihead_attention: rope_scaling needs rope_theta")
+    if rotary_dim == q.shape[-1]:
+        rotary_dim = 0
+    if rotary_dim and (not rope_theta or rotary_dim % 2
+                       or rotary_dim > q.shape[-1]):
+        raise ValueError(
+            f"fused_multihead_attention: rotary_dim {rotary_dim} needs "
+            f"rope_theta, and to be even and at most the head's "
+            f"{q.shape[-1]} lanes")
 
     prepare = q_norm is not None
     if prepare:
@@ -165,8 +177,8 @@ def _fused_mha(ctx, op):
             q = rms_norm(q, q_norm, norm_eps, 3)
             k = rms_norm(k, k_norm, norm_eps, 3)
             if rope_theta:
-                q = rotate_half(q, rope_theta, rope_scaling)
-                k = rotate_half(k, rope_theta, rope_scaling)
+                q = rotate_half(q, rope_theta, rope_scaling, rotary_dim)
+                k = rotate_half(k, rope_theta, rope_scaling, rotary_dim)
             return ctx.amp_cast(op, q, k)
 
     q, k, v = ctx.amp_cast(op, q, k, v)
@@ -191,6 +203,8 @@ def _fused_mha(ctx, op):
     profiler.set_counter("attn_kv_group", group)
     if rope_scaling:
         profiler.bump_counter("attn_rope_scaled")
+    if rotary_dim:
+        profiler.set_counter("attn_rotary_lanes", rotary_dim)
     if op.attr("q_lora_rank", 0):
         profiler.bump_counter("attn_latent_q_lora")
     fused = (prepare and path == "flash" and bshd
@@ -229,7 +243,7 @@ def _fused_mha(ctx, op):
             profiler.bump_counter("attn_qk_prep_fused")
             operands = qk_prep(*raw, q_norm, k_norm, epsilon=norm_eps,
                                theta=rope_theta, scaling=rope_scaling,
-                               out_dtype=q.dtype)
+                               out_dtype=q.dtype, rotary_dim=rotary_dim)
         else:
             operands = swap(q), swap(k), swap(v)
         # values narrower or wider than the keys: the kernel takes them at

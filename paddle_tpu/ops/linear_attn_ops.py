@@ -1,14 +1,32 @@
-"""Linear-attention op lowerings: Kimi Delta Attention (KDA, a gated delta
-rule with a per-channel decay; Kimi Linear, arXiv:2510.26692, and the
-public flash-linear-attention `kda` layer) and the short causal depthwise
-convolution in front of it. No reference counterpart: Fluid ~1.5 has no
-recurrence over time but its RNN ops.
+"""Linear-attention op lowerings: the gated delta rule as Kimi Delta
+Attention (KDA, a per-channel decay; Kimi Linear, arXiv:2510.26692, and
+the public flash-linear-attention `kda` layer) and as Gated DeltaNet (one
+decay a head, key heads shared by groups of value heads; arXiv:2412.06464,
+Qwen3-Next's linear layers), and the short causal depthwise convolution in
+front of it. No reference counterpart: Fluid ~1.5 has no recurrence over
+time but its RNN ops.
 
-Per head, with state `S` in R^{dk x dv}, zero at the start of a sequence:
+Per value head n, with state `S` in R^{dk x dv}, zero at the start of a
+sequence, and q, k those of key head n // group (group = value heads to a
+key head, 1 for KDA):
 
     S' = Diag(alpha_t) S_{t-1}
     S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
     o_t = dk^{-1/2} S_t^T q_t
+
+`alpha_t` is a vector of dk decays (KDA: the op's `GRaw` is
+`[b, s, h*dk]`) or one number for the head (Gated DeltaNet: `GRaw` is
+`[b, s, h]`, and `Diag(alpha_t)` is `alpha_t I`). Everything below is
+written for the vector; a head's one decay is the vector with equal
+entries, and that is how both lowerings compute it: `kda_chunked` writes
+it out in HBM (it is the oracle), the kernels in VMEM, from the
+`[b, s, h]` array (a `[64, 1]` column fills as many registers as
+`[64, 128]`, so nothing is spent on the copies). With grouped key heads
+the kernels' index maps read key head n // group for value head n, so q
+and k stay `[b, s, h_k*dk]` in HBM, are normed once a key head, and the
+backward keeps them at that size (34 MB each a layer at 4,096 tokens and
+16 key heads, where 32 repeated heads would be 67); the kernels write dq
+and dk a value head and XLA adds each group's.
 
 `kda_chunked` computes it chunk by chunk. Inside a chunk of C = 64 tokens, with
 `G_t = sum_{i<=t} log alpha_i` (per channel, <= 0, falling) and `S_0` the
@@ -165,10 +183,13 @@ def _short_conv1d(ctx, op):
 
 def kda_gate(g_raw, a_log, dt_bias, num_heads):
     """`g_t = -exp(A_log^h) * softplus(g_raw + dt_bias)`: the log of the
-    per-channel decay, float32 whatever `g_raw` arrives in.
-    g_raw: [b, s, h*dk] -> [b, s, h, dk]."""
+    decay, float32 whatever `g_raw` arrives in. g_raw: [b, s, h*dk], a
+    decay a channel, -> [b, s, h, dk]; or [b, s, h], a decay a head (Gated
+    DeltaNet), -> [b, s, h]."""
     b, s, hd = g_raw.shape
     x = g_raw.astype(jnp.float32) + dt_bias.astype(jnp.float32)
+    if hd == num_heads:
+        return -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(x)
     g = jax.nn.softplus(x).reshape(b, s, num_heads, hd // num_heads)
     return -jnp.exp(a_log.astype(jnp.float32))[None, None, :, None] * g
 
@@ -179,11 +200,18 @@ def l2norm(x, eps):
 
 
 def kda_chunked(q, k, v, g, beta):
-    """The chunked form of the module docstring. q, k, g: [b, s, h, dk];
-    v: [b, s, h, dv]; beta: [b, s, h]; all float32, `g` the log decay.
-    Returns o: [b, s, h, dv] float32."""
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
+    """The chunked form of the module docstring. q, k: [b, s, h_k, dk];
+    v: [b, s, h, dv]; g, the log decay: [b, s, h, dk], or [b, s, h] where
+    a head has one; beta: [b, s, h]; all float32. Returns o:
+    [b, s, h, dv] float32. This path, the oracle, writes a head's decay
+    out over its channels and a key head out for each of its value heads
+    (n reads n // group) and goes on as it did; the kernels do neither."""
+    h, dv = v.shape[2:]
+    if g.ndim == 3:
+        g = jnp.broadcast_to(g[..., None], g.shape + q.shape[3:])
+    if q.shape[2] != h:
+        q, k = (jnp.repeat(t, h // t.shape[2], axis=2) for t in (q, k))
+    b, s, _, dk = q.shape
     c = CHUNK
     n = -(-s // c)
     pad = n * c - s
@@ -260,31 +288,39 @@ def kda_chunked(q, k, v, g, beta):
     return dk ** -0.5 * o[:, :s]
 
 
-def _prologue(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps):
+def _prologue(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps,
+              key_heads=None):
     """The float32 part in front of the chunks: q and k L2-normalised per
-    head, the log decay, beta; v as it arrived. All [b, s, h, ...]."""
+    key head, [b, s, h_k, dk], the log decay, beta; v as it arrived,
+    [b, s, h, dv]."""
     b, s, _ = q.shape
 
-    def heads(t):
-        return t.reshape(b, s, num_heads, -1)
+    def heads(t, n=num_heads):
+        return t.reshape(b, s, n, -1)
 
-    return (l2norm(heads(q), eps), l2norm(heads(k), eps), heads(v),
-            kda_gate(g_raw, a_log, dt_bias, num_heads),
+    key_heads = key_heads or num_heads
+    return (l2norm(heads(q, key_heads), eps), l2norm(heads(k, key_heads), eps),
+            heads(v), kda_gate(g_raw, a_log, dt_bias, num_heads),
             jax.nn.sigmoid(beta_raw.astype(jnp.float32)))
 
 
-@functools.partial(jax.checkpoint, static_argnums=(7, 8))
+@functools.partial(jax.checkpoint, static_argnums=(7, 8, 9))
 def _mixer_plain(*args):
     q, k, v, g, beta = _prologue(*args)
     return kda_chunked(q, k, v.astype(jnp.float32), g, beta)
 
 
-def kda_mixer_core(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps):
+def kda_mixer_core(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps,
+                   key_heads=None):
     """From the convolved projections to the heads' outputs, float32
     inside: the L2 norms, the decay, beta, then the chunked delta rule,
     in the Pallas kernels where `kda_chunk_viable` admits the shape and
     the backend and in `kda_chunked` otherwise; a counter says which, once
-    a lowering (`kda_dispatch_pallas`, `kda_dispatch_chunked`).
+    a lowering (`kda_dispatch_pallas`, `kda_dispatch_chunked`). `g_raw`
+    [b, s, h] is a decay a head (counter `kda_decay_per_head`, once a
+    lowering); `key_heads` fewer than `num_heads`: q and k arrive
+    [b, s, key_heads*dk] and value head n reads key head n // group
+    (gauge `kda_key_group`).
 
     What the backward keeps. With the kernels: their float32 operands
     (q, k, g: 67 MB each a layer at 4,096 tokens; v stays bf16) and the
@@ -300,9 +336,17 @@ def kda_mixer_core(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps):
     a float32 product reads bf16, in the kernels too): at `float32` the
     XLA step measured 18 ms longer (378 -> 396 ms) and the logits 0.15
     points nearer the reference (2.23 -> 2.09%), PERF.md PR 31."""
-    args = (q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps)
+    key_heads = key_heads or num_heads
+    if num_heads % key_heads:
+        raise ValueError(f"kda_attention: {key_heads} key heads do not "
+                         f"divide {num_heads} value heads")
+    args = (q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps,
+            key_heads)
+    if g_raw.shape[2] == num_heads:
+        profiler.bump_counter("kda_decay_per_head")
+    profiler.set_counter("kda_key_group", num_heads // key_heads)
     b, s, _ = q.shape
-    if kda_kernel.kda_chunk_viable(s, q.shape[2] // num_heads,
+    if kda_kernel.kda_chunk_viable(s, q.shape[2] // key_heads,
                                    v.shape[2] // num_heads):
         profiler.bump_counter("kda_dispatch_pallas")
         o = kda_kernel.kda_chunk(*_prologue(*args))
@@ -314,14 +358,17 @@ def kda_mixer_core(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps):
 
 @register_op("kda_attention")
 def _kda_attention(ctx, op):
-    """Q, K: [b, s, h*dk] and V: [b, s, h*dv], after the short
-    convolution; GRaw: [b, s, h*dk], the decay projection's output;
-    BetaRaw: [b, s, h] logits; ALog: [h]; DtBias: [h*dk]. Out: [b, s, h*dv]
-    in V's dtype. The L2 norm of q and k, the decay and beta are computed
-    here in float32, whatever the AMP dtype of the inputs."""
+    """Q, K: [b, s, h_k*dk] and V: [b, s, h*dv], after the short
+    convolution; GRaw, the decay projection's output, and DtBias:
+    [b, s, h*dk] and [h*dk], a decay a channel, or [b, s, h] and [h], a
+    decay a head; BetaRaw: [b, s, h] logits; ALog: [h]. Attr `num_heads`
+    is h, the value heads; `num_key_heads` (absent: h) is h_k, a divisor
+    of h, and value head n reads key head n // (h / h_k). Out:
+    [b, s, h*dv] in V's dtype. The L2 norm of q and k, the decay and beta
+    are computed here in float32, whatever the AMP dtype of the inputs."""
     q, k, v = ctx.in_(op, "Q"), ctx.in_(op, "K"), ctx.in_(op, "V")
     out = kda_mixer_core(
         q, k, v, ctx.in_(op, "GRaw"), ctx.in_(op, "BetaRaw"),
         ctx.in_(op, "ALog"), ctx.in_(op, "DtBias"), op.attr("num_heads"),
-        op.attr("l2norm_epsilon", 1e-6))
+        op.attr("l2norm_epsilon", 1e-6), op.attr("num_key_heads", None))
     ctx.out(op, "Out", out.astype(v.dtype))

@@ -632,15 +632,41 @@ def rotary_tables(s, d, theta, scaling=None, interleaved=False):
     return cos, sin
 
 
-def rotate_half(x, theta, scaling=None):
+def partial_rotary_tables(s, d, rotary_dim, theta, scaling=None):
+    """`rotary_tables` of a head of `d` lanes whose first `rotary_dim`
+    turn, in the rotate-half convention within them, and whose other
+    lanes pass (`partial_rotary_factor` in published configs): three
+    [s, d] float32 tables for
+    `y = x * cos + roll(x, d - r/2) * sin_lower + roll(x, r/2) * sin_upper`,
+    r = `rotary_dim`. Lane i < r/2 reads lane i + r/2 (a roll by d - r/2)
+    with `-sin a_i`, lane r/2 <= i < r reads lane i - r/2 with `sin
+    a_(i-r/2)`; `cos` is 1 and both sines 0 from lane r on. With the whole
+    head the two rolls are one and `sin_lower + sin_upper` is
+    `rotary_tables`' sine."""
+    r = rotary_dim
+    cos, sin = rotary_tables(s, r, theta, scaling)
+    rest = jnp.zeros((s, d - r), jnp.float32)
+    half = jnp.zeros((s, r // 2), jnp.float32)
+    return (jnp.concatenate([cos, rest + 1.0], -1),
+            jnp.concatenate([sin[:, :r // 2], half, rest], -1),
+            jnp.concatenate([half, sin[:, r // 2:], rest], -1))
+
+
+def rotate_half(x, theta, scaling=None, rotary_dim=None):
     """Rotary positions on [b, s, h, d], positions 0..s-1, the rotate-half
     convention (Su et al. 2021, arXiv:2104.09864, as the public
     `transformers` code lays it out): with `a_i = p * theta^(-2i/d)` for
     i < d/2, `y[..., i] = x[..., i] cos a_i - x[..., i + d/2] sin a_i` and
     `y[..., i + d/2] = x[..., i + d/2] cos a_i + x[..., i] sin a_i`; with
-    `scaling`, `rotary_tables`' scaled angles and factor.
+    `scaling`, `rotary_tables`' scaled angles and factor. `rotary_dim`
+    fewer than d: the first `rotary_dim` lanes turn as a head of that
+    width and the rest pass as they came.
     float32 inside whatever x arrives in: a bf16 angle at position 8,191
     is off by whole turns."""
+    if rotary_dim and rotary_dim != x.shape[3]:
+        return jnp.concatenate(
+            [rotate_half(x[..., :rotary_dim], theta, scaling),
+             x[..., rotary_dim:]], -1)
     s, d = x.shape[1], x.shape[3]
     cos, sin = (t[None, :, None, :]
                 for t in rotary_tables(s, d, theta, scaling))

@@ -48,6 +48,31 @@ rebuilt; the gradients of `A` and `Aq` go back level by level, two
 products a level, and through the blocks of 4 directly, with the factors
 the rebuilt forward kept.
 
+**A decay a head, key heads by groups** (Gated DeltaNet; the kernels are
+then named `gdn_fwd` and `gdn_bwd` in a trace). `g` arrives `[b, s, h]`
+and its block is beta's, `[2*C, h]`: this head's column is taken out by a
+mask and a sum over the lanes and written along the 128 lanes in VMEM
+(`_operands`), after which a chunk is computed exactly as above, levels,
+blocks of four and all; its gradient is summed over the lanes before the
+reversed adds and leaves as a row a chunk, as beta's does. A `[64, 1]`
+column fills the registers `[64, 128]` fills, so the exponentials cost
+what they would on the column. With `h_k` key heads under `h` value
+heads the blocks of q and k are cut at lane slice `(n % h) // group` of
+`[b, s, h_k*128]` arrays: a key head's block is read once for each of its
+value heads and nothing is repeated in HBM. dq and dk are written a value
+head, `[b, s, h*128]` float32, and XLA adds each group's (measured
+against nothing: accumulating them in VMEM across a group's grid steps
+would want the group innermost in the grid and a state a group member in
+the scratch; the two arrays are 0.27 GB written and read once a layer at
+4,096 tokens, a third of a millisecond at the chip's bandwidth). With
+one decay a head `exp(G_i - G_j)` is one `[64, 64]` array a chunk and `A`
+and `Aq` could each be one masked product times it, with no levels and
+no blocks of four; PERF.md section 7 (PR 50) sized that at 1.3 ms a step
+of three layers at most, the levels stubbed, because the chain of
+dependent 64-row products bounds the kernels either way, and it is not
+built. With a decay a channel and a key head a value head the calls, the
+kernels and their declarations are what they were.
+
 Precision is the op's: every `exp`, mask, sum and the state are float32.
 The cumulative log-decay `G` is summed in the kernel in float32, by
 shifted adds over the chunk's rows (and `dG` back by the same adds
@@ -59,6 +84,7 @@ for more; it accumulates in float32.
 
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -324,12 +350,13 @@ def _chunk_fwd(q, k, v, g, beta, St, *, dtype):
     return o, St * decay_end + _mm(U, k * e_end, _TN, dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("dtype",))
-def _chunk_bwd(q, k, v, g, beta, St, dSt, dO, *, dtype):
+@functools.partial(jax.jit, static_argnames=("dtype", "per_head"))
+def _chunk_bwd(q, k, v, g, beta, St, dSt, dO, *, dtype, per_head=False):
     """One chunk of the reverse sweep: from the state the chunk started
     with, the gradient `dSt` of the state it left and of its outputs,
     the gradients of q, k, v, g, of beta (as a row [1, C]) and of the
-    state it started with."""
+    state it started with. `per_head`: `g` is one decay a row written
+    along the lanes, and its gradient the sum over them, as a row."""
     mm = functools.partial(_mm, dtype=dtype)
     c, dv = v.shape
     row, col = _iota((c, c), 0), _iota((c, c), 1)
@@ -358,25 +385,41 @@ def _chunk_bwd(q, k, v, g, beta, St, dSt, dO, *, dtype):
     dkr = dkr + d_ke * e_end
     dG = q * dq + k * (dkl - dkr)
     dG = dG + jnp.where(_iota(dG.shape, 0) == c - 1, dg_end, 0.0)
-    # the column as a row, for a lane-dense store
-    dbeta = jnp.sum(jnp.where(row == col, dbeta, 0.0), 0, keepdims=True)
-    return (dq, dkl + dkr, beta * lam[:, :dv], _cumsum(dG, reverse=True),
-            dbeta, dSt_new)
+
+    def as_row(column):  # for a lane-dense store
+        return jnp.sum(jnp.where(row == col, column, 0.0), 0, keepdims=True)
+
+    dbeta = as_row(dbeta)
+    dk, dv_ = dkl + dkr, beta * lam[:, :dv]
+    if per_head:  # the sum over the lanes first: the adds are linear
+        dG = jnp.sum(dG, 1, keepdims=True)
+    dg = _cumsum(dG, reverse=True)
+    return dq, dk, dv_, as_row(dg) if per_head else dg, dbeta, dSt_new
 
 
-def _operands(q_ref, k_ref, v_ref, g_ref, beta_ref, rows, heads):
+def _operands(q_ref, k_ref, v_ref, g_ref, beta_ref, rows, heads,
+              per_head=False):
     """The chunk at `rows` of the grid step's block, float32, and this
-    head's column of the [C, h] block of beta."""
-    blk = beta_ref[0, rows, :]
-    head = pl.program_id(0) % heads
-    beta = jnp.sum(jnp.where(_iota(blk.shape, 1) == head, blk, 0.0), 1,
-                   keepdims=True)
-    return (*(r[0, rows, :].astype(jnp.float32)
-              for r in (q_ref, k_ref, v_ref, g_ref)), beta)
+    head's column of the [C, h] block of beta. `per_head`: `g_ref` is a
+    [C, h] block too, and this head's column is written along the lanes
+    here, in VMEM: a [C, 1] column fills as many registers as [C, 128]."""
+    def column(ref):
+        blk = ref[0, rows, :]
+        head = pl.program_id(0) % heads
+        return jnp.sum(jnp.where(_iota(blk.shape, 1) == head, blk, 0.0), 1,
+                       keepdims=True)
+
+    beta = column(beta_ref)
+    if not per_head:
+        return (*(r[0, rows, :].astype(jnp.float32)
+                  for r in (q_ref, k_ref, v_ref, g_ref)), beta)
+    q, k, v = (r[0, rows, :].astype(jnp.float32)
+               for r in (q_ref, k_ref, v_ref))
+    return q, k, v, jnp.broadcast_to(column(g_ref), q.shape), beta
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref, s_ref, *,
-                heads, steps, dtype):
+                heads, steps, dtype, per_head=False):
     @pl.when(pl.program_id(1) == 0)
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
@@ -387,14 +430,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref, s_ref, *,
         rows = pl.ds(t * CHUNK, CHUNK)
         St = st_ref[0, t] = s_ref[...]  # the state the chunk starts from
         o, s_ref[...] = _chunk_fwd(
-            *_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, rows, heads), St,
-            dtype=dtype)
+            *_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, rows, heads,
+                       per_head), St, dtype=dtype)
         o_ref[0, rows, :] = o.astype(o_ref.dtype)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref, dq_ref,
                 dk_ref, dv_ref, dg_ref, db_ref, ds_ref, *, heads, steps,
-                dtype):
+                dtype, per_head=False):
     @pl.when(pl.program_id(1) == 0)
     def _():
         ds_ref[...] = jnp.zeros_like(ds_ref)
@@ -402,16 +445,27 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref, dq_ref,
     for t in reversed(range(steps)):
         rows = pl.ds(t * CHUNK, CHUNK)
         dq, dk, dv, dg, db_ref[0, t], ds_ref[...] = _chunk_bwd(
-            *_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, rows, heads),
+            *_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, rows, heads,
+                       per_head),
             st_ref[0, t], ds_ref[...], do_ref[0, rows, :].astype(jnp.float32),
-            dtype=dtype)
-        for ref, d in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv), (dg_ref, dg)):
+            dtype=dtype, per_head=per_head)
+        wide = [(dq_ref, dq), (dk_ref, dk), (dv_ref, dv)]
+        if per_head:  # a row a chunk, as beta's
+            dg_ref[0, t] = dg
+        else:
+            wide.append((dg_ref, dg))
+        for ref, d in wide:
             ref[0, rows, :] = d.astype(ref.dtype)
 
 
-def _cost(backward, b, s, heads, dk, dv, dtypes):
+def _cost(backward, b, s, heads, dk, dv, dtypes, group=1, per_head=False):
     """What one call declares (`cost.py` has the convention) for `s`
-    unpadded tokens a row; `dtypes`: of q, k, g and of v, o. Products
+    unpadded tokens a row; `dtypes`: of q, k, g and of v, o. With `group`
+    value heads to a key head q and k are moved once a key head in, and
+    their gradients leave a value head each (XLA adds the group's);
+    `per_head`: g and its gradient are `[b, s, h]` float32, as beta. The
+    products and the exponentials are a value head's either way: the
+    decay is written along the lanes in VMEM and evaluated there. Products
     counted a chunk of `c` rows (the last may be short) of one head, in
     multiply-adds, with `lower` = c(c+1)/2 pairs j <= i, `strict` =
     c(c-1)/2 and `state` = c*dk*dv:
@@ -448,35 +502,72 @@ def _cost(backward, b, s, heads, dk, dv, dtypes):
     keys, values = ((b, s, heads * dk), wide), ((b, s, heads * dv), narrow)
     beta = ((b, s, heads), jnp.float32)
     states = ((b * heads, chunks, dv, dk), jnp.float32)
-    moved = [keys] * 3 + [values, beta, states, values]  # ..., o or dO
+    held = ((b, s, heads // group * dk), wide)  # q, k as they arrive
+    decay = beta if per_head else keys
+    moved = [held] * 2 + [decay, values, beta, states, values]  # .., o or dO
     if backward:  # dq, dk, dg, dv, dbeta
-        moved += [keys] * 3 + [values, beta]
+        moved += [keys] * 2 + [decay, values, beta]
     return cost.estimate(2 * b * heads * macs, b * heads * exps, *moved)
+
+
+# What a call is built from beside its operands: the value heads, the
+# chunks a grid step, what a product reads, the interpreter, the unpadded
+# length, the value heads to a key head, and whether the decay is a head's.
+_Statics = collections.namedtuple(
+    "_Statics", "heads steps dtype interpret s group per_head",
+    defaults=(1, False))
+
+
+def _specs(statics, chunk_of):
+    """(of a value head's [rows, 128] block, of its key head's, of the
+    [rows, h] block all the heads of a row share), at the chunk step
+    `chunk_of(j)` of grid step j. A value head n reads key head
+    n // group: the index map, and nothing copied."""
+    heads, rows, group = statics.heads, statics.steps * CHUNK, statics.group
+
+    def head(d):
+        return pl.BlockSpec((1, rows, d), lambda i, j: (
+            i // heads, chunk_of(j), i % heads))
+
+    def key_head(d):
+        if group == 1:
+            return head(d)
+        return pl.BlockSpec((1, rows, d), lambda i, j: (
+            i // heads, chunk_of(j), i % heads // group))
+
+    shared = pl.BlockSpec((1, rows, heads),
+                          lambda i, j: (i // heads, chunk_of(j), 0))
+    return head, key_head, shared
+
+
+def _names(statics):
+    """The pair's names in a trace: a decay a head has its own, so that
+    the metrics of one never read the other's events."""
+    return ("gdn_fwd", "gdn_bwd") if statics.per_head else ("kda_fwd",
+                                                            "kda_bwd")
 
 
 @functools.partial(jax.jit, static_argnames=("statics",))
 def _call_fwd(q, k, v, g, beta, *, statics):
-    """q, k, g: [b, S, h*dk]; v: [b, S, h*dv]; beta: [b, S, h]; S whole
-    grid steps. Returns o [b, S, h*dv] in v's dtype and the state each
-    chunk starts from, [b*h, S/C, dv, dk] float32. One call for a
-    forward that is differentiated and one that is not: a Program's
-    gradient op lowers its forward op again, and XLA merges the two calls
-    only if they are the same call."""
-    heads, steps, dtype, interpret, s = statics
+    """q, k: [b, S, h_k*dk]; v: [b, S, h*dv]; g: [b, S, h*dk], or
+    [b, S, h] with a decay a head; beta: [b, S, h]; S whole grid steps.
+    Returns o [b, S, h*dv] in v's dtype and the state each chunk starts
+    from, [b*h, S/C, dv, dk] float32. One call for a forward that is
+    differentiated and one that is not: a Program's gradient op lowers
+    its forward op again, and XLA merges the two calls only if they are
+    the same call."""
+    statics = _Statics(*statics)  # a caller may pass the first five alone
+    heads, steps, dtype, interpret, s = statics[:5]
     b, S, _ = q.shape
-    dk, dv = q.shape[2] // heads, v.shape[2] // heads
-    rows = steps * CHUNK
-
-    def spec(d):
-        return pl.BlockSpec((1, rows, d), lambda i, j: (i // heads, j,
-                                                        i % heads))
+    dk, dv = q.shape[2] * statics.group // heads, v.shape[2] // heads
+    spec, key_spec, shared = _specs(statics, lambda j: j)
 
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=heads, steps=steps, dtype=dtype),
-        grid=(b * heads, S // rows),
-        in_specs=[spec(dk), spec(dk), spec(dv), spec(dk),
-                  pl.BlockSpec((1, rows, heads),
-                               lambda i, j: (i // heads, j, 0))],
+        functools.partial(_fwd_kernel, heads=heads, steps=steps, dtype=dtype,
+                          per_head=statics.per_head),
+        grid=(b * heads, S // (steps * CHUNK)),
+        in_specs=[key_spec(dk), key_spec(dk), spec(dv),
+                  shared if statics.per_head else spec(dk), shared],
         out_specs=[spec(dv), pl.BlockSpec((1, steps, dv, dk),
                                           lambda i, j: (i, j, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -486,52 +577,67 @@ def _call_fwd(q, k, v, g, beta, *, statics):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name="kda_fwd",
-        cost_estimate=_cost(False, b, s, heads, dk, dv, (q.dtype, v.dtype)),
+        name=_names(statics)[0],
+        cost_estimate=_cost(False, b, s, heads, dk, dv, (q.dtype, v.dtype),
+                            statics.group, statics.per_head),
     )(q, k, v, g, beta)
 
 
 @functools.partial(jax.jit, static_argnames=("statics",))
 def _call_bwd(q, k, v, g, beta, states, do, *, statics):
-    """The reverse sweep: grid step j holds the chunks of step last - j."""
-    heads, steps, dtype, interpret, s = statics
+    """The reverse sweep: grid step j holds the chunks of step last - j.
+    The kernel writes dq and dk a value head, `[b, S, h*dk]`; with grouped
+    key heads XLA adds each group's to the `[b, S, h_k*dk]` the op
+    returns (two float32 arrays of q's width times the group written and
+    read once: 0.27 GB a layer at 4,096 tokens, 32 heads and groups of 2).
+    With a decay a head dg leaves as beta's gradient does, a row a
+    chunk."""
+    statics = _Statics(*statics)
+    heads, steps, dtype, interpret, s = statics[:5]
+    group = statics.group
     b, S, _ = q.shape
-    dk, dv = q.shape[2] // heads, v.shape[2] // heads
-    rows = steps * CHUNK
-    last = S // rows - 1
-
-    def spec(d):
-        return pl.BlockSpec((1, rows, d), lambda i, j: (i // heads, last - j,
-                                                        i % heads))
+    dk, dv = q.shape[2] * group // heads, v.shape[2] // heads
+    last = S // (steps * CHUNK) - 1
+    spec, key_spec, shared = _specs(statics, lambda j: last - j)
+    row = pl.BlockSpec((1, steps, 1, CHUNK), lambda i, j: (i, last - j, 0, 0))
+    rows = jax.ShapeDtypeStruct((b * heads, S // CHUNK, 1, CHUNK),
+                                jnp.float32)
+    wide = jax.ShapeDtypeStruct((b, S, heads * dk), q.dtype)
 
     dq, dk_, dv_, dg, dbeta = pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads, steps=steps, dtype=dtype),
+        functools.partial(_bwd_kernel, heads=heads, steps=steps, dtype=dtype,
+                          per_head=statics.per_head),
         grid=(b * heads, last + 1),
-        in_specs=[spec(dk), spec(dk), spec(dv), spec(dk),
-                  pl.BlockSpec((1, rows, heads),
-                               lambda i, j: (i // heads, last - j, 0)),
+        in_specs=[key_spec(dk), key_spec(dk), spec(dv),
+                  shared if statics.per_head else spec(dk), shared,
                   pl.BlockSpec((1, steps, dv, dk),
                                lambda i, j: (i, last - j, 0, 0)),
                   spec(dv)],
-        out_specs=[spec(dk), spec(dk), spec(dv), spec(dk),
-                   pl.BlockSpec((1, steps, 1, CHUNK),
-                                lambda i, j: (i, last - j, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+        out_specs=[spec(dk), spec(dk), spec(dv),
+                   row if statics.per_head else spec(dk), row],
+        out_shape=[wide, wide,
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
-                   jax.ShapeDtypeStruct(g.shape, g.dtype),
-                   jax.ShapeDtypeStruct((b * heads, S // CHUNK, 1, CHUNK),
-                                        jnp.float32)],
+                   rows if statics.per_head
+                   else jax.ShapeDtypeStruct(g.shape, g.dtype),
+                   rows],
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name="kda_bwd",
-        cost_estimate=_cost(True, b, s, heads, dk, dv, (q.dtype, v.dtype)),
+        name=_names(statics)[1],
+        cost_estimate=_cost(True, b, s, heads, dk, dv, (q.dtype, v.dtype),
+                            statics.group, statics.per_head),
     )(q, k, v, g, beta, states, do)
-    # [b*h, n, 1, C] -> [b, S, h]
-    dbeta = dbeta.reshape(b, heads, S).transpose(0, 2, 1)
-    return dq, dk_, dv_, dg, dbeta
+
+    def by_token(t):  # [b*h, n, 1, C] -> [b, S, h]
+        return t.reshape(b, heads, S).transpose(0, 2, 1)
+
+    if group > 1:
+        dq, dk_ = (t.reshape(b, S, heads // group, group, dk).sum(3)
+                   .reshape(q.shape) for t in (dq, dk_))
+    if statics.per_head:
+        dg = by_token(dg)
+    return dq, dk_, dv_, dg, by_token(dbeta)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -552,17 +658,23 @@ _core.defvjp(_core_fwd, _core_bwd)
 
 
 def kda_chunk(q, k, v, g, beta):
-    """`kda_chunked`'s contract, in the kernels. q, k, g: [b, s, h, dk]
-    float32, `g` the log decay; v: [b, s, h, dv] in the dtype it arrives
-    in (the kernels read and write it as it is and compute in float32);
-    beta: [b, s, h] float32. Returns o: [b, s, h, dv] in v's dtype."""
+    """`kda_chunked`'s contract, in the kernels. q, k: [b, s, h_k, dk]
+    float32; g, the log decay: [b, s, h, dk] float32, or [b, s, h] where
+    a head has one decay; v: [b, s, h, dv] in the dtype it arrives in
+    (the kernels read and write it as it is and compute in float32);
+    beta: [b, s, h] float32; `h_k` divides `h`, and value head n reads key
+    head n // (h / h_k). Returns o: [b, s, h, dv] in v's dtype. Nothing is
+    repeated or written out in front of the kernels: they read a key
+    head's block for each of its value heads and a head's decay as a
+    column (`gdn_fwd`, `gdn_bwd` in a trace; with a decay a channel
+    `kda_fwd`, `kda_bwd`)."""
     require_pallas("kda_chunk")
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
-    if not kda_chunk_viable(s, dk, dv):
+    b, s, h_k, dk = q.shape
+    h, dv = v.shape[2:]
+    if not kda_chunk_viable(s, dk, dv) or h % h_k:
         raise ValueError(
             f"kda_chunk: q {q.shape}, v {v.shape}: needs head widths of "
-            f"{LANE}")
+            f"{LANE} and key heads that divide the value heads")
     steps = min(CHUNKS_PER_STEP, -(-s // CHUNK))
     pad = -s % (steps * CHUNK)
     # heads side by side on the lanes, as the projections write them
@@ -571,6 +683,7 @@ def kda_chunk(q, k, v, g, beta):
         q, k, v, g = (jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
                       for t in (q, k, v, g))
         beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
-    o = _core(q, k, v, g, beta,
-              (h, steps, _product_dtype(), _interpret(), s))
+    o = _core(q, k, v, g, beta, _Statics(
+        h, steps, _product_dtype(), _interpret(), s, h // h_k,
+        beta.shape == g.shape))
     return o[:, :s].reshape(b, s, h, dv)

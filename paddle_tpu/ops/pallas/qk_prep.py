@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..nn_ops import rotary_tables
+from ..nn_ops import partial_rotary_tables, rotary_tables
 from . import cost
 from .flash_attention import LANE, _interpret, require_pallas
 
@@ -71,17 +71,35 @@ def _inverse_norm(x, eps):
     return jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
 
 
+def _turn(y, tables, turned, back=False):
+    """The rotation of `rotate_half` on a [rows, d] block: over the whole
+    head one roll by d/2, which is its own inverse; over the first
+    `turned` lanes `partial_rotary_tables`' two rolls. `back`: the
+    transpose, which is the rotation by the negative angle."""
+    d = y.shape[-1]
+    if not turned:
+        cos_ref, sin_ref = tables
+        if back:
+            return y * cos_ref[...] + pltpu.roll(y * sin_ref[...], d // 2, 1)
+        return y * cos_ref[...] + pltpu.roll(y, d // 2, 1) * sin_ref[...]
+    cos_ref, lower_ref, upper_ref = tables
+    up, down = d - turned // 2, turned // 2
+    if back:
+        return (y * cos_ref[...] + pltpu.roll(y * lower_ref[...], down, 1)
+                + pltpu.roll(y * upper_ref[...], up, 1))
+    return (y * cos_ref[...] + pltpu.roll(y, up, 1) * lower_ref[...]
+            + pltpu.roll(y, down, 1) * upper_ref[...])
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, wq_ref, wk_ref, *rest, heads, kv_heads,
-                eps, rope):
+                eps, rope, turned=0):
     tables, (qo_ref, ko_ref, vo_ref) = rest[:-3], rest[-3:]
 
     def norm_rotate(x_ref, w_ref, o_ref):
         x = x_ref[0].astype(jnp.float32)
         y = x * _inverse_norm(x, eps) * w_ref[...]
         if rope:
-            cos_ref, sin_ref = tables
-            y = y * cos_ref[...] + pltpu.roll(
-                y, y.shape[-1] // 2, 1) * sin_ref[...]
+            y = _turn(y, tables, turned)
         o_ref[0, 0] = y.astype(o_ref.dtype)
 
     is_q, is_k, is_v = _slots(pl.program_id(2), heads, kv_heads)
@@ -94,7 +112,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, wq_ref, wk_ref, *rest, heads, kv_heads,
 
 
 def _bwd_kernel(dqo_ref, dko_ref, dvo_ref, q_ref, k_ref, wq_ref, wk_ref,
-                *rest, heads, kv_heads, eps, rope, s, rows):
+                *rest, heads, kv_heads, eps, rope, s, rows, turned=0):
     tables = rest[:-5]
     dq_ref, dk_ref, dv_ref, dwq_ref, dwk_ref = rest[-5:]
     slot = pl.program_id(2)
@@ -103,9 +121,7 @@ def _bwd_kernel(dqo_ref, dko_ref, dvo_ref, q_ref, k_ref, wq_ref, wk_ref,
     def grads(do_ref, x_ref, w_ref, dx_ref, dw_ref, first):
         dy = do_ref[0, 0].astype(jnp.float32)
         if rope:
-            cos_ref, sin_ref = tables
-            dy = dy * cos_ref[...] + pltpu.roll(
-                dy * sin_ref[...], dy.shape[-1] // 2, 1)
+            dy = _turn(dy, tables, turned, back=True)
         x = x_ref[0].astype(jnp.float32)
         inv = _inverse_norm(x, eps)
         n = x * inv
@@ -162,25 +178,28 @@ def _cost(backward, b, s, d, statics, q_dtype):
     """What one call declares (`cost.py` has the convention). No product:
     FLOPs an element of q and k, forward 4 (the mean of squares 2, times
     the inverse norm, times the weight) and 3 more where positions turn
-    it; backward 11 (the norm rebuilt 3, dn, its mean with n 2, dx 3, the
-    weight's gradient 2) and the same 3. One rsqrt a row of a head of q
-    and k. Moved once: q, k and v in and out, the two weights and, with
-    positions, the two tables; backward also q and k again and the
-    weights' partial sums, `[8, d]` a block of rows."""
+    it (5 and three tables where only part of the head turns: a second
+    roll's product and sum); backward 11 (the norm rebuilt 3, dn, its
+    mean with n 2, dx 3, the weight's gradient 2) and the same 3 or 5.
+    One rsqrt a row of a head of q and k. Moved once: q, k and v in and
+    out, the two weights and, with positions, the tables; backward also q
+    and k again and the weights' partial sums, `[8, d]` a block of
+    rows."""
     heads, kv_heads, _, theta, rows, out_dtype, v_dtype = statics[:7]
+    turned = statics[8]
     normed = b * s * (heads + kv_heads)  # rows of one head of q and k
     flat = [((b, s, n * d), t) for n, t in (
         (heads, q_dtype), (kv_heads, q_dtype), (kv_heads, v_dtype))]
     major = [((b, n, s, d), out_dtype) for n in (heads, kv_heads, kv_heads)]
     moved = flat + major + [((d,), jnp.float32)] * 2
     if theta:
-        moved += [((s, d), jnp.float32)] * 2
+        moved += [((s, d), jnp.float32)] * (3 if turned else 2)
     if backward:
         moved += flat[:2] + [((b, pl.cdiv(s, rows), SUBLANES, d),
                               jnp.float32)] * 2
+    turning = (5 if turned else 3) if theta else 0
     return cost.estimate(
-        ((11 if backward else 4) + (3 if theta else 0)) * normed * d,
-        normed, *moved)
+        ((11 if backward else 4) + turning) * normed * d, normed, *moved)
 
 
 def _call(kernel, name, statics, s, declared, specs_in, specs_out, shapes_out,
@@ -192,7 +211,7 @@ def _call(kernel, name, statics, s, declared, specs_in, specs_out, shapes_out,
     b = args[0].shape[0]
     return pl.pallas_call(
         functools.partial(kernel, heads=heads, kv_heads=kv_heads, eps=eps,
-                          rope=bool(theta)),
+                          rope=bool(theta), turned=statics[8]),
         grid=(b, pl.cdiv(s, rows), heads + 2 * kv_heads),
         in_specs=specs_in, out_specs=specs_out, out_shape=shapes_out,
         compiler_params=pltpu.CompilerParams(
@@ -203,12 +222,21 @@ def _call(kernel, name, statics, s, declared, specs_in, specs_out, shapes_out,
     )(*args)
 
 
+def _tables(s, d, statics):
+    theta, scaling, turned = statics[3], statics[7], statics[8]
+    if not theta:
+        return ()
+    if turned:
+        return partial_rotary_tables(s, d, turned, theta, scaling)
+    return rotary_tables(s, d, theta, scaling)
+
+
 def _fwd_pallas(q, k, v, wq, wk, statics):
     heads, kv_heads, _, theta, rows, out_dtype, _, scaling = statics[:8]
     b, s, _ = q.shape
     d = q.shape[2] // heads
     flat, major, weight, table, _ = _specs(rows, d, heads, kv_heads)
-    tables = rotary_tables(s, d, theta, scaling) if theta else ()
+    tables = _tables(s, d, statics)
     return _call(
         _fwd_kernel, "qk_prep_fwd", statics, s,
         _cost(False, b, s, d, statics, q.dtype),
@@ -223,7 +251,7 @@ def _bwd_pallas(dqo, dko, dvo, q, k, wq, wk, statics):
     b, s, _ = q.shape
     d = q.shape[2] // heads
     flat, major, weight, table, partial = _specs(rows, d, heads, kv_heads)
-    tables = rotary_tables(s, d, theta, scaling) if theta else ()
+    tables = _tables(s, d, statics)
     sums = jax.ShapeDtypeStruct((b, pl.cdiv(s, rows), SUBLANES, d),
                                 jnp.float32)
     dq, dk, dv, dwq, dwk = _call(
@@ -262,14 +290,16 @@ _core.defvjp(_core_fwd, _core_bwd)
 
 
 def qk_prep(q, k, v, q_weight, k_weight, *, epsilon, theta=0.0,
-            scaling=None, out_dtype=None, rows=ROWS):
+            scaling=None, out_dtype=None, rows=ROWS, rotary_dim=0):
     """q: [b, s, h, d]; k, v: [b, s, g, d], as the projections' outputs
     are reshaped; `q_weight`, `k_weight`: [d]. Returns q, k, v head-major,
     [b, h, s, d] and [b, g, s, d] in `out_dtype` (q's own by default): q
     and k normed over `d` with `epsilon` and their weight and, where
     `theta` is not 0, turned by `rotate_half`'s positions 0..s-1 (under
     `scaling`, `rotary_tables`' scaled ones: the tables are the kernels'
-    inputs, which are the same kernels either way); v as it came."""
+    inputs, which are the same kernels either way); with `rotary_dim`
+    fewer than `d`, only the first `rotary_dim` lanes of a head turn
+    (`partial_rotary_tables`); v as it came."""
     require_pallas("qk_prep")
     b, s, h, d = q.shape
     g = k.shape[2]
@@ -281,7 +311,9 @@ def qk_prep(q, k, v, q_weight, k_weight, *, epsilon, theta=0.0,
     rows = min(rows, -(-s // 16) * 16)
     statics = (h, g, float(epsilon), float(theta), rows,
                jnp.dtype(out_dtype or q.dtype), v.dtype,
-               tuple(scaling) if scaling else None, _interpret())
+               tuple(scaling) if scaling else None,
+               int(rotary_dim) if rotary_dim and rotary_dim != d else 0,
+               _interpret())
     flat = lambda t: t.reshape(b, s, -1)
     return _core(flat(q), flat(k), flat(v),
                  q_weight.astype(jnp.float32), k_weight.astype(jnp.float32),

@@ -76,3 +76,43 @@ def jaxpr_digest(fn, *args) -> str:
     under one JAX."""
     text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
     return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def program_digest(build, amp=False) -> str:
+    """`jaxpr_digest` of a Program and its backward: `build()` declares
+    layers under fresh Programs and returns the loss; the global block is
+    lowered as the Executor's step lowers it, every var an op reads and
+    none writes (parameters and data) an argument by its declared shape
+    and dtype. Nothing runs and no scope is filled."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    import paddle_tpu as fluid
+    from paddle_tpu.ops.registry import LoweringContext, lower_block
+
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()), \
+            fluid.unique_name.guard():
+        loss = build()
+        pairs = fluid.backward.append_backward(loss)
+    if amp:
+        main._amp_dtype = "bfloat16"
+    block = main.global_block()
+    written, read = set(), []
+    for op in block.ops:
+        for n in op.input_arg_names():
+            if n and n not in written and n not in read:
+                read.append(n)
+        written.update(n for n in op.output_arg_names() if n)
+    shapes = [jax.ShapeDtypeStruct(tuple(block.var(n).shape),
+                                   jnp.dtype(np.dtype(block.var(n).dtype)))
+              for n in read]
+    fetch = [loss.name] + [g.name for _, g in pairs]
+
+    def step(*values):
+        ctx = LoweringContext(main, rng_key=jax.random.PRNGKey(0))
+        ctx.values.update(zip(read, values))
+        lower_block(ctx, block)
+        return [ctx.get(n) for n in fetch]
+
+    return jaxpr_digest(step, *shapes)

@@ -1499,3 +1499,83 @@ def test_dispatch_counters_bump(rng):
     c = profiler.counters()
     assert sum(c.get(f"attn_dispatch_{p}", 0)
                for p in ("short", "xla", "flash", "ring")) > 0
+
+
+@pytest.mark.parametrize("path,d,lanes", [
+    ("xla", 64, 16), ("flash", 64, 16), ("flash", 128, 32),
+    ("flash", 256, 64)], ids=lambda v: str(v))
+def test_rotary_dim_turns_the_first_lanes_and_passes_the_rest(
+        monkeypatch, attn_path, path, d, lanes):
+    """The op with QK-norm, `rope_theta` and `rotary_dim`: the first
+    `rotary_dim` lanes of every head of q and k turn as a head of that
+    width and the others pass as normed, on the plain path, through the
+    flash kernel with the two ops' own functions in front (heads of 64)
+    and through `qk_prep` (heads of 128 and of 256 lanes), output and the
+    gradients of q, k and v, against plain softmax attention over a norm
+    and a rotation written out with a concatenation."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu import profiler
+    from test_qk_prep_kernel import written_out
+
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    attn_path(path)
+    b, s, h, g, theta, eps = 1, 96, 4, 2, 1e4, 1e-6
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        feeds = [fluid.layers.data(n, [b, s, heads * d],
+                                   append_batch_size=False)
+                 for n, heads in (("q", h), ("k", g), ("v", g))]
+        for t in feeds:
+            t.stop_gradient = False
+        q, k, v = (fluid.layers.reshape(t, [b, s, -1, d]) for t in feeds)
+        out = fluid.layers.fused_multihead_attention(
+            q, k, v, causal=True, sm_scale=d ** -0.5, layout="bshd",
+            q_norm_attr=fluid.ParamAttr(name="qn"),
+            k_norm_attr=fluid.ParamAttr(name="kn"), qk_norm_epsilon=eps,
+            rope_theta=theta, rotary_dim=lanes)
+        loss = fluid.layers.reduce_sum(fluid.layers.elementwise_mul(out, out))
+        grads = fluid.backward.calc_gradient(loss, feeds)
+    r = np.random.RandomState(d + lanes)
+    feed = {n: r.randn(b, s, heads * d).astype("float32")
+            for n, heads in (("q", h), ("k", g), ("v", g))}
+    weights = {n: r.uniform(0.5, 1.5, d).astype("float32")
+               for n in ("qn", "kn")}
+    exe = fluid.Executor(fluid.CPUPlace())
+    before = profiler.counters()
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        for n, w in weights.items():
+            fluid.global_scope().set(n, w)
+        got = exe.run(main, feed=feed, fetch_list=[out, *grads])
+    after = profiler.counters()
+    assert after["attn_rotary_lanes"] == lanes
+    assert after.get("attn_qk_prep_fused", 0) - before.get(
+        "attn_qk_prep_fused", 0) == (2 if d % 128 == 0 else 0)
+
+    def plain(q, k, v, lanes):
+        def normed(t, w):
+            t = t.reshape(b, s, -1, d)
+            t = t / jnp.sqrt(jnp.mean(t * t, -1, keepdims=True) + eps) * w
+            return written_out(t, theta, lanes)
+
+        q, k = normed(q, weights["qn"]), normed(k, weights["kn"])
+        v = v.reshape(b, s, g, d)
+        of = jnp.arange(h) // (h // g)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k[:, :, of]) * d ** -0.5
+        scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1),
+                          v[:, :, of])
+
+    args = [jnp.asarray(feed[n]) for n in "qkv"]
+    with jax.default_matmul_precision("highest"):
+        want = plain(*args, lanes)
+        want_grads = jax.grad(lambda *a: jnp.sum(plain(*a, lanes) ** 2),
+                              argnums=(0, 1, 2))(*args)
+        whole = plain(*args, d)
+    np.testing.assert_allclose(got[0], want, atol=2e-5)
+    for a, w in zip(got[1:], want_grads):
+        np.testing.assert_allclose(a, w, atol=2e-4, rtol=2e-4)
+    assert np.abs(np.asarray(whole) - got[0]).max() > 0.05

@@ -235,3 +235,56 @@ def test_lfm2_tiny_builds_and_trains():
     assert np.isfinite(losses).all() and losses[-1] < losses[0] - 0.5
     # 2 x 24 tokens x 2 a token over 4 of 8 experts: some are held here
     assert all(0 < int(np.sum(x)) <= 96 for x in out[0][1:])
+
+
+def test_qwen3_next_tiny_builds_and_trains():
+    """One chip's share of Qwen3-Next at a tiny size: published layers 4
+    to 7 (three Gated DeltaNet layers over two key heads and four value
+    heads, then gated attention with a quarter of each head turned), every
+    layer an expert layer with a gated shared expert, through
+    `Executor.run`; embedding and head are two parameters."""
+    from paddle_tpu.models import Qwen3NextConfig, build_qwen3_next
+
+    cfg = Qwen3NextConfig(
+        vocab_size=96, hidden_size=32, num_hidden_layers=4, first_layer=4,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        linear_num_key_heads=2, linear_num_value_heads=4,
+        linear_key_head_dim=8, linear_value_head_dim=8,
+        moe_intermediate_size=16, shared_expert_intermediate_size=16,
+        num_experts=8, experts_held=4, num_experts_per_token=2)
+    assert cfg.rotary_dim == 4 and cfg.num_shared_experts == 1
+    assert [k for _, k in cfg.layer_kinds()] == (
+        ["linear_attention"] * 3 + ["full_attention"])
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 7
+    with fluid.program_guard(main, startup):
+        handles = build_qwen3_next(cfg, 2, 24)
+        fluid.optimizer.Adam(learning_rate=1e-2).minimize(handles["loss"])
+    assert handles["feeds"] == ["tokens", "labels"]
+    assert len(handles["loads"]) == 4
+    assert tuple(handles["logits"].shape) == (2, 24, 96)
+    ops = [op.type for op in main.global_block().ops]
+    assert ops.count("short_conv1d") == 3  # q, k and v in one call a layer
+    assert ops.count("kda_attention") == 3
+    assert ops.count("fused_multihead_attention") == 1
+    assert ops.count("moe_experts") == 4
+    names = [p.name for p in main.global_block().all_parameters()]
+    assert "qwen3next.embed" in names and "qwen3next.head.w_0" in names
+    assert "qwen3next.layer4.gdn.A_log" in names
+    assert "qwen3next.layer7.attn.gate.w_0" in names
+    assert "qwen3next.layer5.shared_gate.w_0" in names
+    block = main.global_block()
+    assert tuple(block.var("qwen3next.layer4.gdn.dt_bias").shape) == (4,)
+    assert tuple(block.var("qwen3next.layer4.gdn.conv.w_0").shape) == (64, 4)
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        doc = np.random.RandomState(0).randint(0, 96, (2, 25))
+        feed = {"tokens": doc[:, :-1], "labels": doc[:, 1:]}
+        out = [exe.run(main, feed=feed,
+                       fetch_list=[handles["loss"]] + handles["loads"])
+               for _ in range(8)]
+    losses = [float(np.asarray(o[0]).reshape(-1)[0]) for o in out]
+    assert np.isfinite(losses).all() and losses[-1] < losses[0] - 0.5
+    # 2 x 24 tokens x 2 a token over 4 of 8 experts: some are held here
+    assert all(0 < int(np.sum(x)) <= 96 for x in out[0][1:])

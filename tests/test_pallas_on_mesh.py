@@ -278,33 +278,43 @@ def test_kernels_per_shard_compile_for_four_v5e_chips(topo, monkeypatch):
     assert "ln_bwd" in text and "all-reduce" in text
 
 
-def test_kda_kernels_compile_for_a_v5e_chip_at_the_published_widths(topo):
-    """The KDA chunk kernels (ops/pallas/kda_chunk.py) at the shape of the
-    cell that runs them: one 4,096-token row, 32 heads of 128, bf16
-    products and values, forward and backward. Here with this file's
-    topology because one process of a test run can describe it (the
-    kernels' mathematics is tests/test_kda_kernel.py's). Nothing runs."""
+@pytest.mark.parametrize("hk,per_head", [(32, False), (16, True)],
+                         ids=["kda", "gdn"])
+def test_kda_kernels_compile_for_a_v5e_chip_at_the_published_widths(
+        topo, hk, per_head):
+    """The delta-rule chunk kernels (ops/pallas/kda_chunk.py) at the
+    shapes of the cells that run them: one 4,096-token row, 32 heads of
+    128, bf16 products and values, forward and backward; Kimi's call (a
+    decay a channel, a key head a value head) and Qwen3-Next's (a decay a
+    head, 16 key heads, q and k read at `[1, 4096, 2048]`). Here with this
+    file's topology because one process of a test run can describe it (the
+    kernels' mathematics is tests/test_kda_kernel.py's and
+    tests/test_gdn_kernel.py's). Nothing runs."""
     from jax.sharding import SingleDeviceSharding
 
     from paddle_tpu.ops.pallas import kda_chunk
 
     chip = SingleDeviceSharding(topo.devices[0])
     b, s, h, d = 1, 4096, 32, 128
-    statics = (h, kda_chunk.CHUNKS_PER_STEP, jnp.bfloat16, False, s)
+    statics = kda_chunk._Statics(h, kda_chunk.CHUNKS_PER_STEP, jnp.bfloat16,
+                                 False, s, h // hk, per_head)
 
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    x, v = sds((b, s, h * d)), sds((b, s, h * d), jnp.bfloat16)
+    x, v = sds((b, s, hk * d)), sds((b, s, h * d), jnp.bfloat16)
+    g = sds((b, s, h)) if per_head else sds((b, s, h * d))
 
     def both(q, k, v, g, beta):
         o, pull = jax.vjp(
             lambda *a: kda_chunk._core(*a, statics), q, k, v, g, beta)
         return o, pull(o)
 
-    compiled = jax.jit(both).lower(x, x, v, x, sds((b, s, h))).compile()
+    compiled = jax.jit(both).lower(x, x, v, g, sds((b, s, h))).compile()
     text = compiled.as_text()
-    assert "kda_fwd" in text and "kda_bwd" in text
+    names = ("gdn_fwd", "gdn_bwd") if per_head else ("kda_fwd", "kda_bwd")
+    assert all(name in text for name in names)
+    assert ("kda_fwd" in text) != per_head
     # the operands, their gradients and the 134 MB of chunk states
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
@@ -328,7 +338,17 @@ def test_qk_prep_kernels_compile_for_a_v5e_chip_at_the_published_widths(
     chip = SingleDeviceSharding(topo.devices[0])
     b, s, h, g, d = 1, 8192, 32, 4, 128
     bf16 = jnp.dtype(jnp.bfloat16)
-    statics = (h, g, 1e-5, theta, qk_prep.ROWS, bf16, bf16, scaling, False)
+    statics = (h, g, 1e-5, theta, qk_prep.ROWS, bf16, bf16, scaling, 0,
+               False)
+    text, memory = _qk_prep_compiled(chip, statics, b, s, h, g, d)
+    assert "qk_prep_fwd" in text and "qk_prep_bwd" in text
+    # q, k, v in, out and back, and nothing float32 of their size between
+    assert "f32[1,8192" not in text and "f32[1,32,8192" not in text
+    assert memory.temp_size_in_bytes < 1 << 20
+
+
+def _qk_prep_compiled(chip, statics, b, s, h, g, d):
+    from paddle_tpu.ops.pallas import qk_prep
 
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -341,11 +361,27 @@ def test_qk_prep_kernels_compile_for_a_v5e_chip_at_the_published_widths(
     compiled = jax.jit(both).lower(
         sds((b, s, h * d)), sds((b, s, g * d)), sds((b, s, g * d)),
         sds((d,), jnp.float32), sds((d,), jnp.float32)).compile()
-    text = compiled.as_text()
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+def test_qk_prep_kernels_compile_for_a_v5e_chip_with_a_part_of_the_head_turned(
+        topo):
+    """Qwen3-Next's call: one 4,096-token row, 16 query heads over 2
+    key/value heads of 256 lanes of which the first 64 turn, two rolls a
+    block and three tables. Nothing runs."""
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas import qk_prep
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    bf16 = jnp.dtype(jnp.bfloat16)
+    statics = (16, 2, 1e-6, 1e7, qk_prep.ROWS, bf16, bf16, None, 64, False)
+    text, memory = _qk_prep_compiled(chip, statics, 1, 4096, 16, 2, 256)
     assert "qk_prep_fwd" in text and "qk_prep_bwd" in text
-    # q, k, v in, out and back, and nothing float32 of their size between
-    assert "f32[1,8192" not in text and "f32[1,32,8192" not in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert "f32[1,4096,4096]" not in text and "f32[1,16,4096" not in text
+    # the three [4096, 256] float32 tables, 4 MB each, and the weights'
+    # partial sums
+    assert memory.temp_size_in_bytes < 14 << 20
 
 
 @pytest.mark.parametrize("shape,window,lanes,backward", [
@@ -803,3 +839,71 @@ def test_lfm2_step_compiled_for_v5e_takes_the_kernels_it_can_and_fits(
     need = (memory.argument_size_in_bytes + memory.output_size_in_bytes
             + memory.temp_size_in_bytes - memory.alias_size_in_bytes)
     assert need < 11e9
+
+
+@pytest.fixture(scope="module")
+def qwen3_next_step(topo):
+    """Once for the test that reads it; the delta rule's dispatch asks its
+    own module whether Pallas runs, and is steered there too."""
+    from paddle_tpu.ops.pallas import kda_chunk
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(kda_chunk, "_use_pallas", lambda: True)
+        patch.setattr(kda_chunk, "_product_dtype", lambda: jnp.bfloat16)
+        return _step_for_v5e(topo, "qwen3_next_ep16_s4096")
+
+
+def test_qwen3_next_step_compiled_for_v5e_takes_its_kernels_and_fits(
+        qwen3_next_step):
+    """`qwen3_next_ep16_s4096`'s whole train step: the three Gated
+    DeltaNet layers run `gdn_fwd` and `gdn_bwd` on q and k as the
+    convolution wrote them, `[1, 4096, 2048]` for 16 key heads, and on a
+    `[1, 4096, 32]` decay, with no float32 decay a channel anywhere in the
+    step; each layer's convolution backward is one kernel call over the
+    8,192 channels; the attention layer's 256-lane heads go through
+    `qk_prep` with 64 lanes turned and the flash kernels' one-visit
+    backward; the four expert layers' products at 2,048 x 512 are the
+    Pallas pair under a softmax router; and the step is under 14 GB of a
+    chip's 16.9 by the compiler's count (7.51 of them the 625.7M
+    parameters and their two moments)."""
+    text, bumped, memory = qwen3_next_step
+    assert bumped["kda_dispatch_pallas"] == bumped["kda_decay_per_head"] == 6
+    assert not bumped.get("kda_dispatch_chunked")
+    assert profiler.counters()["kda_key_group"] == 2
+    assert bumped["short_conv_dispatch_pallas"] == 3
+    assert not bumped.get("short_conv_dispatch_xla")
+    assert bumped["attn_dispatch_flash"] == bumped["attn_qk_prep_fused"] == 2
+    assert bumped["flash_bwd_fused_calls"] == 2
+    assert profiler.counters()["attn_rotary_lanes"] == 64
+    assert profiler.counters()["attn_kv_group"] == 8
+    assert bumped["moe_dispatch_gmm"] == bumped["moe_dispatch_grouped"] == 8
+    assert bumped["moe_route_softmax"] == 8
+    assert bumped["moe_shared_expert_gated"] == 4
+    assert (profiler.counters()["moe_experts_held"],
+            profiler.counters()["moe_experts_total"],
+            profiler.counters()["moe_block_rows"]) == (32, 512, 10240)
+    for kernel in ("gdn_fwd", "gdn_bwd", "short_conv_bwd", "qk_prep_fwd",
+                   "qk_prep_bwd", "flash_fwd", "flash_bwd_dkv_dq", "moe_gmm",
+                   "moe_tgmm", "embed_tgmm"):
+        assert kernel in text, kernel
+    assert "kda_fwd" not in text and "kda_bwd" not in text
+    assert "flash_bwd_dq" not in text  # the backward is the one kernel
+    calls = [line for line in text.splitlines()
+             if " custom-call(" in line and "gdn_" in line]
+    assert len(calls) == 6 and all("f32[1,4096,2048]" in line
+                                   and "f32[1,4096,32]" in line
+                                   for line in calls)
+    # under the op's scopes no decay a channel, as a head's array or side
+    # by side, and no repeated q or k: the only float32 arrays as wide as
+    # the value heads are dq and dk as `gdn_bwd` writes them (the gated
+    # norm after the op holds its own, under `rms_norm`)
+    under = [line for line in text.splitlines() if "kda_attention" in line]
+    assert under and not any("f32[1,4096,32,128]" in l for l in under)
+    assert all("gdn_bwd" in l for l in under if "f32[1,4096,4096]" in l)
+    convs = [line for line in text.splitlines()
+             if " custom-call(" in line and "short_conv_bwd" in line]
+    assert len(convs) == 3 and all("bf16[1,4096,8192]" in l for l in convs)
+    assert abs(memory.argument_size_in_bytes / 1e9 - 7.51) < 0.01
+    need = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            + memory.temp_size_in_bytes - memory.alias_size_in_bytes)
+    assert need < 14e9

@@ -1,0 +1,131 @@
+"""What PR 51 added to shared ops is off by default, and with the default
+the Programs of the configurations that were there trace what they traced:
+Kimi's delta-rule mixer (a decay a channel, a key head a value head),
+the attention of Trinity, Mellum and LFM2 (positions over the whole head
+or none), and the five expert cells' `expert_ffn` (no gate on the shared
+expert). Each case lowers a small Program and its backward as the
+Executor's step does and compares the sha256 of the jaxpr's text with the
+one taken by running this file against a copy of PR 51's parent commit
+(`PYTHONPATH=<parent> python tests/test_parents_jaxprs.py`), under jax
+0.9.0."""
+
+import os
+import sys
+from types import SimpleNamespace
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+BASE = dict(hidden_size=256, initializer_range=0.02, rms_norm_eps=1e-6)
+
+
+def _loss(out):
+    from paddle_tpu import layers
+
+    return layers.mean(layers.cast(out, "float32"))
+
+
+def _data(s, hidden=256):
+    from paddle_tpu import layers
+
+    return layers.data("u", [1, s, hidden], dtype="float32",
+                       append_batch_size=False)
+
+
+def kda_case():
+    """Kimi's mixer at two heads of 128: the kernels under the
+    interpreter, `kda_chunked` without."""
+    from paddle_tpu.models.kimi_linear import KimiLinearConfig, _kda_mixer
+
+    cfg = KimiLinearConfig(hidden_size=256, num_heads=2, kda_head_dim=128)
+    return _loss(_kda_mixer(_data(192), cfg, "m"))
+
+
+def attention_case(which, s):
+    """`decoder_parts.attention` as the three models call it; at 2,048
+    tokens under the interpreter the flash path and `qk_prep`, at 64 the
+    XLA path and `rotate_half`."""
+    from paddle_tpu.models.decoder_parts import attention
+
+    cfg = SimpleNamespace(num_attention_heads=4, num_key_value_heads=2,
+                          head_dim=128, **BASE)
+    kw = {
+        "trinity_full": dict(window=0, rope_theta=0.0, gated=True),
+        "trinity_window": dict(window=32, rope_theta=1e4, gated=True),
+        "mellum": dict(window=0, rope_theta=5e5,
+                       rope_scaling={"rope_type": "yarn", "factor": 8.0,
+                                     "original_max_position_embeddings":
+                                     4096}),
+        "lfm2": dict(rope_theta=1e6),
+    }[which]
+    return _loss(attention(_data(s), cfg, "a", **kw))
+
+
+def expert_case(shared):
+    from paddle_tpu.models.decoder_parts import expert_ffn
+
+    cfg = SimpleNamespace(
+        num_experts=8, experts_held=4, held_from=2, moe_intermediate_size=32,
+        num_experts_per_token=2, routed_scaling_factor=2.0,
+        moe_renormalize=True, router_bias_scale=0.0,
+        score_func="softmax" if shared else "sigmoid",
+        num_shared_experts=shared, **BASE)
+    return _loss(expert_ffn(_data(24), cfg, "e")[0])
+
+
+CASES = {
+    "kda-chunked": (kda_case, False),
+    "kda-kernels": (kda_case, True),
+    "trinity_full-xla": (lambda: attention_case("trinity_full", 64), False),
+    "trinity_window-xla": (lambda: attention_case("trinity_window", 64),
+                           False),
+    "mellum-xla": (lambda: attention_case("mellum", 64), False),
+    "lfm2-xla": (lambda: attention_case("lfm2", 64), False),
+    "trinity_full-flash": (lambda: attention_case("trinity_full", 2048),
+                           True),
+    "trinity_window-flash": (lambda: attention_case("trinity_window", 2048),
+                             True),
+    "mellum-flash": (lambda: attention_case("mellum", 2048), True),
+    "lfm2-flash": (lambda: attention_case("lfm2", 2048), True),
+    "experts-shared": (lambda: expert_case(1), False),
+    "experts-alone": (lambda: expert_case(0), False),
+}
+
+# as PR 51's parent (commit 6f8ecfe) traces them
+PARENTS_JAXPRS = {
+    "kda-chunked": "ef5c1d772c23b254",
+    "kda-kernels": "77decd6246443125",
+    "trinity_full-xla": "1160994003f6c88b",
+    "trinity_window-xla": "9e6c7e0895897a9a",
+    "mellum-xla": "9e7d571745830a91",
+    "lfm2-xla": "9f0068051ad85522",
+    "trinity_full-flash": "df9ffd967c9e7bce",
+    "trinity_window-flash": "e6a9aaad617f08aa",
+    "mellum-flash": "f164b6ef665ad574",
+    "lfm2-flash": "d98bb2942185766e",
+    "experts-shared": "0c34ee51cfcbcb61",
+    "experts-alone": "eb3b9327f05919f0",
+}
+
+
+def digest(case):
+    from pallas_costs import program_digest
+
+    build, interpret = CASES[case]
+    if interpret:
+        os.environ["PADDLE_TPU_PALLAS_INTERPRET"] = "1"
+    else:
+        os.environ.pop("PADDLE_TPU_PALLAS_INTERPRET", None)
+    return program_digest(build, amp=True)
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_defaults_jaxpr_is_the_parents(case, monkeypatch):
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "")  # restored after
+    assert digest(case) == PARENTS_JAXPRS[case]
+
+
+if __name__ == "__main__":
+    for name in CASES:
+        print(f'    "{name}": "{digest(name)}",', flush=True)

@@ -98,6 +98,96 @@ def test_float32_equals_the_chain_and_its_gradients(b, s, h, g, d, rows,
         assert rel(a, w) < 1e-5, name
 
 
+def written_out(x, theta, lanes):
+    """The rotation of the first `lanes` lanes of [b, s, n, d], the others
+    passed, with a concatenation and no roll."""
+    import jax.numpy as jnp
+
+    s = x.shape[1]
+    turning, passing = x[..., :lanes], x[..., lanes:]
+    inv = 1.0 / theta ** (jnp.arange(0, lanes, 2, dtype=jnp.float32) / lanes)
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    angle = jnp.concatenate([angle, angle], -1)[None, :, None, :]
+    swapped = jnp.concatenate(
+        [-turning[..., lanes // 2:], turning[..., :lanes // 2]], -1)
+    return jnp.concatenate(
+        [turning * jnp.cos(angle) + swapped * jnp.sin(angle), passing], -1)
+
+
+@pytest.mark.parametrize("b,s,h,g,d,rows,lanes", [
+    pytest.param(1, 96, 2, 1, 256, 32, 64, id="a_quarter_of_256"),
+    pytest.param(2, 200, 4, 2, 128, 64, 32, id="a_quarter_of_128"),
+    pytest.param(1, 64, 2, 2, 128, 64, 2, id="one_pair"),
+    pytest.param(1, 64, 2, 1, 128, 64, 128, id="the_whole_head_named"),
+])
+def test_a_part_of_the_head_turned_equals_a_written_out_rotation(
+        b, s, h, g, d, rows, lanes):
+    """`rotary_dim`: the first lanes of a head turn as a head of that
+    width and the rest pass as normed, forward and every gradient,
+    against the norm and a rotation written out with a concatenation;
+    `rotate_half(x, theta, None, rotary_dim)` is that rotation too. Named
+    as the whole head it is the default's call."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.nn_ops import rms_norm, rotate_half
+    from paddle_tpu.ops.pallas.qk_prep import qk_prep
+
+    def chain_part(q, k, v, wq, wk):
+        q, k = rms_norm(q, wq, EPS, 3), rms_norm(k, wk, EPS, 3)
+        q, k = written_out(q, THETA, lanes), written_out(k, THETA, lanes)
+        return tuple(jnp.transpose(t, (0, 2, 1, 3)) for t in (q, k, v))
+
+    args, cotangents = _args(b, s, h, g, d, "float32")
+    got, got_grads = _both(
+        lambda *a: qk_prep(*a, epsilon=EPS, theta=THETA, rows=rows,
+                           rotary_dim=lanes), args, cotangents)
+    want, want_grads = _both(chain_part, args, cotangents)
+    for name, a, w in zip("qkv", got, want):
+        assert a.shape == w.shape and rel(a, w) < 1e-5, name
+    for name, a, w in zip(("dq", "dk", "dv", "dwq", "dwk"), got_grads,
+                          want_grads):
+        assert a.shape == w.shape and rel(a, w) < 1e-5, name
+    q = args[0]
+    assert rel(rotate_half(q, THETA, None, lanes),
+               written_out(q, THETA, lanes)) < 1e-6
+    if lanes < d:  # the lanes past the turned ones are the norm's alone
+        normed = jnp.transpose(rms_norm(q, args[3], EPS, 3), (0, 2, 1, 3))
+        np.testing.assert_allclose(got[0][..., lanes:], normed[..., lanes:],
+                                   rtol=1e-6, atol=1e-6)
+        assert rel(got[0], chain(*args, THETA)[0]) > 0.05
+    else:
+        whole, _ = _both(lambda *a: qk_prep(*a, epsilon=EPS, theta=THETA,
+                                            rows=rows), args, cotangents)
+        np.testing.assert_array_equal(got[0], whole[0])
+
+
+def test_a_part_turned_declares_two_more_flops_and_a_third_table():
+    from pallas_costs import declared, numbers
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.qk_prep import qk_prep
+
+    b, s, h, g, d = 1, 64, 2, 1, 256
+    args, cotangents = _args(b, s, h, g, d, jnp.bfloat16)
+
+    def both(lanes):
+        def fn(*a):
+            out, pull = jax.vjp(lambda *a: qk_prep(
+                *a, epsilon=EPS, theta=THETA, rotary_dim=lanes), *a)
+            return pull(tuple(c.astype(jnp.bfloat16) for c in cotangents))
+        return {k: numbers(v[0]) for k, v in declared(fn, *args).items()}
+
+    part, whole = both(64), both(0)
+    elements = b * s * (h + g) * d
+    for name, base in (("qk_prep_fwd", 4), ("qk_prep_bwd", 11)):
+        assert whole[name][0] == (base + 3) * elements
+        assert part[name][0] == (base + 5) * elements
+        assert part[name][1] == whole[name][1]
+        assert part[name][2] - whole[name][2] == s * d * 4
+
+
 @pytest.mark.parametrize("theta", [0.0, THETA], ids=["no_positions", "rope"])
 def test_bf16_in_and_out_is_one_rounding_from_the_float32_chain(theta):
     """bf16 as the projections write it under AMP, at the published
