@@ -320,7 +320,8 @@ def kda_mixer_core(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps,
     [b, s, h] is a decay a head (counter `kda_decay_per_head`, once a
     lowering); `key_heads` fewer than `num_heads`: q and k arrive
     [b, s, key_heads*dk] and value head n reads key head n // group
-    (gauge `kda_key_group`).
+    (gauge `kda_key_group`). With the kernels, gauge `kda_lockstep_chunks`:
+    the chunks a grid step holds, whose solves run in lockstep.
 
     What the backward keeps. With the kernels: their float32 operands
     (q, k, g: 67 MB each a layer at 4,096 tokens; v stays bf16) and the
@@ -349,6 +350,8 @@ def kda_mixer_core(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps,
     if kda_kernel.kda_chunk_viable(s, q.shape[2] // key_heads,
                                    v.shape[2] // num_heads):
         profiler.bump_counter("kda_dispatch_pallas")
+        profiler.set_counter("kda_lockstep_chunks",
+                             kda_kernel.lockstep_chunks(s))
         o = kda_kernel.kda_chunk(*_prologue(*args))
     else:
         profiler.bump_counter("kda_dispatch_chunked")

@@ -6,11 +6,14 @@ term; `kda_chunked` there is the plain path and this file's test oracle.
 What differs is where things live. XLA's lowering relays q, k, v, g out
 to `[b, h, n, c, d]`, runs a dozen float32 passes and small batched
 products over them, a triangular-solve custom call, and a 64-step
-`lax.scan` for the state. Here one grid step holds two chunks of one
-head, `[2*C, 128]` blocks cut from the `[b, s, h*128]` arrays the op's
-inputs arrive in (no relayout on either side of the call), and the state
-`S^T` `[dv, dk]` float32 in a VMEM scratch that lives across the
-sequential chunk axis of the grid and is zeroed at chunk 0.
+`lax.scan` for the state. Here one grid step holds `CHUNKS_PER_STEP`
+chunks of one head, `[rows, 128]` blocks cut from the `[b, s, h*128]`
+arrays the op's inputs arrive in (no relayout on either side of the
+call), and the state `S^T` `[dv, dk]` float32 in a VMEM scratch that
+lives across the sequential chunk axis of the grid and is zeroed at
+chunk 0. What a chunk computes from no state (`_state_free`) is stated
+for all the step's chunks together, the solve product by product across
+them (`_inverse`); what starts from the state follows chunk by chunk.
 
 Per chunk, all in VMEM:
 
@@ -33,7 +36,8 @@ Per chunk, all in VMEM:
   diagonal is a sum over the lanes. Nothing is clamped.
 - `T = (I + Diag(beta) A)^-1` by block forward substitution, doubling
   the block from 2 to 64 (`_inverse`: ten 64x64 products, no loop over
-  rows). `[Wv, Wk] = T Diag(beta) [V, K exp(G)]`.
+  rows; each waits for the one before, and the step's other chunks'
+  fill the wait). `[Wv, Wk] = T Diag(beta) [V, K exp(G)]`.
 - `U`, `O` and the next state from the state in the scratch. The state
   is kept transposed so that its decay `Diag(exp(G_C))` is a product
   with a row over the lanes and no product needs a transpose.
@@ -50,7 +54,7 @@ the rebuilt forward kept.
 
 **A decay a head, key heads by groups** (Gated DeltaNet; the kernels are
 then named `gdn_fwd` and `gdn_bwd` in a trace). `g` arrives `[b, s, h]`
-and its block is beta's, `[2*C, h]`: this head's column is taken out by a
+and its block is beta's, `[rows, h]`: this head's column is taken out by a
 mask and a sum over the lanes and written along the 128 lanes in VMEM
 (`_operands`), after which a chunk is computed exactly as above, levels,
 blocks of four and all; its gradient is summed over the lanes before the
@@ -98,13 +102,17 @@ from .flash_attention import LANE, _interpret, _use_pallas, require_pallas
 CHUNK = 64
 LEAF = 4  # rows of the blocks whose pairs `_leaf_pairs` forms directly
 TILE = 8  # sublanes of a float32 register: a rotation stays inside one
-# Chunks a grid step, each a copy of the chunk's code in the kernel: two
-# overlap one chunk's state-free work with the other's chain of products.
-# Four measured 3 ms of a 237 ms step faster and 2.5 s of set-up slower at
-# every start of a job (the host lowers each copy; PERF.md, PR 32). The
-# overlap is small: a unit of the MXU takes its products in the order the
-# program states them, so the copies' chains run one after the other
-# (PERF.md section 7, PR 50).
+# Chunks a grid step, each a copy of the chunk's code in the kernel, and
+# the width of the lockstep (`_state_free`, `_inverse`): the ten dependent
+# products of a solve wait 125 to 131 cycles each from push to pop, and
+# the step's chunks share those waits. Four measured the pair at 16.6 ms
+# a step where two have 21.6 in Kimi's cell (14.0 against 17.3 in
+# Qwen3-Next's), 3.1 and 2.4% more documents a second, and 1.5 s more of
+# host lowering at every start of a job (`step_lower_s` 3.6 -> 5.2 and
+# 3.9 -> 5.4 s: the host lowers every copy's equations, 360 forward and
+# 620 backward a chunk), `setup_s` +3.4% and +7.3% where this constant is
+# held to 5% in both cells: two (PERF.md, PR 52, which also says what
+# would make the lowering independent of the width).
 CHUNKS_PER_STEP = 2
 
 _NN = ((1,), (0,))  # [m, k] x [k, n]
@@ -118,6 +126,13 @@ def kda_chunk_viable(s, d_k, d_v):
     interpreter) is there to run them. Any length: rows are padded to
     whole grid steps."""
     return s >= 1 and d_k == LANE and d_v == LANE and _use_pallas()
+
+
+def lockstep_chunks(s):
+    """The chunks a grid step of a call over `s` tokens a row holds and
+    solves together: 1 where the row is one chunk and nothing can be
+    hidden (gauge `kda_lockstep_chunks`)."""
+    return min(CHUNKS_PER_STEP, -(-s // CHUNK))
 
 
 def _product_dtype():
@@ -181,7 +196,7 @@ def _pair_masks(c):
     for every jnp call of the kernel's body at each start of a job)."""
     row, col = _iota((c, c), 0), _iota((c, c), 1)
     level = row ^ col
-    levels = {m: level >> (m.bit_length() - 2) == 1 for m in _levels(c)}
+    levels = tuple(level >> (m.bit_length() - 2) == 1 for m in _levels(c))
     leaves = tuple((level < LEAF) & (row - col == o) for o in range(1, LEAF))
     return levels, leaves, row == col, row > col
 
@@ -223,12 +238,12 @@ def _scores(k, G, dtype):
     masks = at_level, at_leaf, _, below = _pair_masks(c)
     upper = _iota((c, dk), 0)
     A, levels = 0.0, ()
-    for m in at_level:
+    for m, here in zip(_levels(c), at_level):
         d = G - _block_reference(G, m)
         e = jnp.exp(jnp.where(upper & (m // 2) != 0, d, -d))
         ke = (k * e).astype(dtype)
-        A = jnp.where(at_level[m], _mm(ke, ke, _NT, dtype), A)
-        levels += ((m, e, ke),)
+        A = jnp.where(here, _mm(ke, ke, _NT, dtype), A)
+        levels += ((here, e, ke),)
     leaves = _leaf_pairs(k, G)
     for here, (_, kj) in zip(at_leaf, leaves):
         A = jnp.where(here, jnp.sum(k * kj, 1, keepdims=True), A)
@@ -242,19 +257,19 @@ def _query_scores(q, k, kept, dtype, transposed=False):
     backward. Apart from `_scores`, because nothing before U reads Aq:
     its products queue behind the solve's."""
     masks, levels, leaves = kept
-    at_level, at_leaf, diagonal, below = masks
+    _, at_leaf, diagonal, below = masks
     Aq = jnp.where(diagonal, jnp.sum(q * k, 1, keepdims=True), 0.0)  # j = i
     for here, (_, kj) in zip(at_leaf, leaves):
         Aq = jnp.where(here, jnp.sum(q * kj, 1, keepdims=True), Aq)
     if transposed:  # the levels' masks are their own transposes
         Aq = Aq.T
     levels_q = ()
-    for m, e, ke in levels:
+    for here, e, ke in levels:
         qe = (q * e).astype(dtype)
         pairs = _mm(ke, qe, _NT, dtype) if transposed else _mm(qe, ke, _NT,
                                                                dtype)
-        Aq = jnp.where(at_level[m], pairs, Aq)
-        levels_q += ((m, e, jnp.concatenate([ke, qe], axis=0)),)
+        Aq = jnp.where(here, pairs, Aq)
+        levels_q += ((here, e, jnp.concatenate([ke, qe], axis=0)),)
     # a level's product holds the pairs i < j too
     return (jnp.where(~below if transposed else below | diagonal, Aq, 0.0),
             (masks, levels_q, leaves))
@@ -267,7 +282,7 @@ def _scores_grad(dA, dAq, q, k, kept, dtype):
     k*dk_row - k*dk_col. `dA` is 0 from the diagonal up and `dAq` above
     it."""
     c, dk = q.shape
-    (at_level, at_leaf, diagonal, _), levels, leaves = kept
+    (_, at_leaf, diagonal, _), levels, leaves = kept
     d_both = jnp.concatenate([dA, dAq], axis=0)
 
     def both(mask):  # of dA and of dAq, [2C, C]
@@ -277,8 +292,8 @@ def _scores_grad(dA, dAq, q, k, kept, dtype):
     # that dG's share of it is q*k - k*q
     on_diagonal = jnp.sum(jnp.where(diagonal, dAq, 0.0), 1, keepdims=True)
     dq, dk_row, dk_col = on_diagonal * k, 0.0, on_diagonal * q
-    for m, e, x in levels:
-        d = both(at_level[m])
+    for here, e, x in levels:
+        d = both(here)
         d_in = _mm(d, x[:c], _NN, dtype)  # to the rows i of the upper halves
         dk_row = dk_row + d_in[:c] * e
         dq = dq + d_in[c:] * e
@@ -296,52 +311,90 @@ def _scores_grad(dA, dAq, q, k, kept, dtype):
     return dq, dk_row, dk_col
 
 
-def _inverse(N, dtype):
-    """(I + N)^-1 for a strictly lower [C, C] N, by halves: on 2x2
-    diagonal blocks it is I - N exactly, and the inverse on blocks of 2m
-    follows from the one on blocks of m, `T`, and the part `L` of N inside
-    the blocks of 2m and outside those of m as T - T L T (the block formula
-    [[a, 0], [l, b]]^-1 = [[a^-1, 0], [-b^-1 l a^-1, b^-1]], on every
-    diagonal block at once). Block forward substitution, so as stable as
-    the system: keys that are nearly parallel make N's entries near 1, and
-    a series in N's powers, however short, then sums terms of 1e8."""
-    c = N.shape[0]
-    r, l = _iota(N.shape, 0), _iota(N.shape, 1)
+def _inverse(Ns, dtype):
+    """(I + N)^-1 for each strictly lower [C, C] N of `Ns`, by halves: on
+    2x2 diagonal blocks it is I - N exactly, and the inverse on blocks of
+    2m follows from the one on blocks of m, `T`, and the part `L` of N
+    inside the blocks of 2m and outside those of m as T - T L T (the block
+    formula [[a, 0], [l, b]]^-1 = [[a^-1, 0], [-b^-1 l a^-1, b^-1]], on
+    every diagonal block at once). Block forward substitution, so as
+    stable as the system: keys that are nearly parallel make N's entries
+    near 1, and a series in N's powers, however short, then sums terms of
+    1e8.
+
+    The ten products of one N depend each on the one before. They are
+    stated in lockstep over `Ns`, every N's `T L` of a doubling, then
+    every N's `(T L) T`: a unit of the MXU takes its products in the order
+    the program states them, so one N's wait from push to pop is the
+    others' time on the units. Each N's products and operands are what a
+    call with it alone states: the inverses are the same to the bit."""
+    c = Ns[0].shape[0]
+    r, l = _iota((c, c), 0), _iota((c, c), 1)
 
     def same_block(bits):  # blocks of 2**bits rows
         return (r >> bits) == (l >> bits)
 
-    inv = (r == l).astype(jnp.float32) - jnp.where(same_block(1), N, 0.0)
+    eye = (r == l).astype(jnp.float32)
+    invs = [eye - jnp.where(same_block(1), N, 0.0) for N in Ns]
     for bits in range(1, c.bit_length() - 1):
-        L = jnp.where(same_block(bits + 1) & ~same_block(bits), N, 0.0)
-        inv = inv - _mm(_mm(inv, L, _NN, dtype), inv, _NN, dtype)
-    return inv
-
-
-def _state_free(q, k, v, g, beta, dtype, transposed_aq=False):
-    """What one chunk computes from no state: from the cumulative
-    log-decay G the decays, A, Aq or with `transposed_aq` its transpose
-    (and what `_scores` and `_query_scores` kept), T and the WY factors
-    [Wv, Wk]."""
-    G = _cumsum(g)
-    E = jnp.exp(G)
-    e_end = jnp.exp(G[-1:] - G)  # exp(G_C - G_i) <= 1
-    A, kept = _scores(k, G, dtype)
-    T = _inverse(beta * A, dtype)
-    Aq, kept = _query_scores(q, k, kept, dtype, transposed_aq)
-    W = _mm(T, jnp.concatenate([beta * v, beta * (k * E)], axis=1), _NN,
-            dtype)
-    return E, e_end, jnp.exp(G[-1:]), A, Aq, kept, T, W
+        inside = same_block(bits + 1) & ~same_block(bits)
+        TL = [_mm(inv, jnp.where(inside, N, 0.0), _NN, dtype)
+              for inv, N in zip(invs, Ns)]
+        invs = [inv - _mm(tl, inv, _NN, dtype) for tl, inv in zip(TL, invs)]
+    return invs
 
 
 @functools.partial(jax.jit, static_argnames=("dtype",))
-def _chunk_fwd(q, k, v, g, beta, St, *, dtype):
-    """One chunk from the state it starts with, `St` = S^T [dv, dk]: its
-    outputs [C, dv] and the state it leaves. (Jitted, as `_chunk_bwd`, so
-    that a kernel's copies of the chunk are traced once: the host pays
-    for every jnp call at each start of a job, compile cache or not.)"""
-    c, dv = v.shape
-    E, e_end, decay_end, _, Aq, _, _, W = _state_free(q, k, v, g, beta, dtype)
+def _before_solve(k, g, *, dtype):
+    """What a chunk computes ahead of its solve: from the cumulative
+    log-decay G the decays exp(G), exp(G_C - G) <= 1 and exp(G_C), A and
+    what `_scores` kept. (Jitted, as every part of a chunk that the
+    kernels state once a chunk, so that the copies are traced once: the
+    host pays for every jnp call at each start of a job, compile cache or
+    not.)"""
+    G = _cumsum(g)
+    A, kept = _scores(k, G, dtype)
+    return (jnp.exp(G), jnp.exp(G[-1:] - G), jnp.exp(G[-1:])), A, kept
+
+
+# What a chunk computes from no state: the decays exp(G), exp(G_C - G) and
+# exp(G_C), A, Aq or its transpose, what `_query_scores` kept, T and the
+# WY factors [Wv, Wk].
+_StateFree = collections.namedtuple("_StateFree", "decays A Aq kept T W")
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "transposed_aq"))
+def _after_solve(q, k, v, beta, E, T, kept, *, dtype, transposed_aq):
+    """Aq (or its transpose) with what `_query_scores` kept, and the WY
+    factors [Wv, Wk] from the chunk's `T`."""
+    Aq, kept = _query_scores(q, k, kept, dtype, transposed_aq)
+    W = _mm(T, jnp.concatenate([beta * v, beta * (k * E)], axis=1), _NN,
+            dtype)
+    return Aq, kept, W
+
+
+def _state_free(chunks, dtype, transposed_aq=False):
+    """A `_StateFree` for each chunk (q, k, v, g, beta) of a grid step:
+    all of them up to their N = beta A, then the solves in lockstep
+    (`_inverse`), then each chunk's Aq, behind the solve's products where
+    nothing waits for it, and W."""
+    before = [_before_solve(k, g, dtype=dtype) for _, k, _, g, _ in chunks]
+    Ts = _inverse([beta * A for (*_, beta), (_, A, _) in zip(chunks, before)],
+                  dtype)
+    free = []
+    for (q, k, v, _, beta), (decays, A, kept), T in zip(chunks, before, Ts):
+        Aq, kept, W = _after_solve(q, k, v, beta, decays[0], T, kept,
+                                   dtype=dtype, transposed_aq=transposed_aq)
+        free.append(_StateFree(decays, A, Aq, kept, T, W))
+    return free
+
+
+@functools.partial(jax.jit, static_argnames=("dtype",))
+def _chunk_fwd(q, k, free, St, *, dtype):
+    """One chunk from the state it starts with, `St` = S^T [dv, dk], and
+    its state-free half: its outputs [C, dv] and the state it leaves."""
+    (E, e_end, decay_end), _, Aq, _, _, W = free
+    c, dv = q.shape[0], W.shape[1] - q.shape[1]
     # [Wk; Q exp(G)] S in one product
     by_state = _mm(jnp.concatenate([W[:, dv:], q * E], axis=0), St, _NT,
                    dtype)
@@ -351,17 +404,17 @@ def _chunk_fwd(q, k, v, g, beta, St, *, dtype):
 
 
 @functools.partial(jax.jit, static_argnames=("dtype", "per_head"))
-def _chunk_bwd(q, k, v, g, beta, St, dSt, dO, *, dtype, per_head=False):
-    """One chunk of the reverse sweep: from the state the chunk started
-    with, the gradient `dSt` of the state it left and of its outputs,
-    the gradients of q, k, v, g, of beta (as a row [1, C]) and of the
-    state it started with. `per_head`: `g` is one decay a row written
-    along the lanes, and its gradient the sum over them, as a row."""
+def _chunk_bwd(q, k, v, beta, free, St, dSt, dO, *, dtype, per_head=False):
+    """One chunk of the reverse sweep: from its state-free half (Aq
+    transposed), the state the chunk started with, the gradient `dSt` of
+    the state it left and of its outputs, the gradients of q, k, v, g, of
+    beta (as a row [1, C]) and of the state it started with. `per_head`:
+    `g` is one decay a row written along the lanes, and its gradient the
+    sum over them, as a row."""
     mm = functools.partial(_mm, dtype=dtype)
     c, dv = v.shape
     row, col = _iota((c, c), 0), _iota((c, c), 1)
-    E, e_end, decay_end, A, AqT, kept, T, W = _state_free(q, k, v, g, beta,
-                                                          dtype, True)
+    (E, e_end, decay_end), A, AqT, kept, T, W = free
     ke, kd, Wk = k * e_end, k * E, W[:, dv:]
     U = W[:, :dv] - mm(Wk, St, _NT)
     dO = q.shape[1] ** -0.5 * dO
@@ -418,6 +471,13 @@ def _operands(q_ref, k_ref, v_ref, g_ref, beta_ref, rows, heads,
     return q, k, v, jnp.broadcast_to(column(g_ref), q.shape), beta
 
 
+def _step_chunks(refs, steps, heads, per_head):
+    """(the rows of each chunk in the grid step's blocks, each chunk's
+    `_operands`)."""
+    rows = [pl.ds(t * CHUNK, CHUNK) for t in range(steps)]
+    return rows, [_operands(*refs, at, heads, per_head) for at in rows]
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref, s_ref, *,
                 heads, steps, dtype, per_head=False):
     @pl.when(pl.program_id(1) == 0)
@@ -426,13 +486,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref, s_ref, *,
 
     # copies of the chunk and not a loop: a `pl.loop` over four chunks
     # measured 0.8 ms a call slower than four copies (1.3 ms backward)
-    for t in range(steps):
-        rows = pl.ds(t * CHUNK, CHUNK)
+    rows, chunks = _step_chunks((q_ref, k_ref, v_ref, g_ref, beta_ref), steps,
+                                heads, per_head)
+    free = _state_free(chunks, dtype)
+    for t, (q, k, *_) in enumerate(chunks):
         St = st_ref[0, t] = s_ref[...]  # the state the chunk starts from
-        o, s_ref[...] = _chunk_fwd(
-            *_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, rows, heads,
-                       per_head), St, dtype=dtype)
-        o_ref[0, rows, :] = o.astype(o_ref.dtype)
+        o, s_ref[...] = _chunk_fwd(q, k, free[t], St, dtype=dtype)
+        o_ref[0, rows[t], :] = o.astype(o_ref.dtype)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref, dq_ref,
@@ -442,12 +502,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref, dq_ref,
     def _():
         ds_ref[...] = jnp.zeros_like(ds_ref)
 
+    rows, chunks = _step_chunks((q_ref, k_ref, v_ref, g_ref, beta_ref), steps,
+                                heads, per_head)
+    free = _state_free(chunks, dtype, transposed_aq=True)
     for t in reversed(range(steps)):
-        rows = pl.ds(t * CHUNK, CHUNK)
+        q, k, v, _, beta = chunks[t]
         dq, dk, dv, dg, db_ref[0, t], ds_ref[...] = _chunk_bwd(
-            *_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, rows, heads,
-                       per_head),
-            st_ref[0, t], ds_ref[...], do_ref[0, rows, :].astype(jnp.float32),
+            q, k, v, beta, free[t], st_ref[0, t], ds_ref[...],
+            do_ref[0, rows[t], :].astype(jnp.float32),
             dtype=dtype, per_head=per_head)
         wide = [(dq_ref, dq), (dk_ref, dk), (dv_ref, dv)]
         if per_head:  # a row a chunk, as beta's
@@ -455,7 +517,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref, dq_ref,
         else:
             wide.append((dg_ref, dg))
         for ref, d in wide:
-            ref[0, rows, :] = d.astype(ref.dtype)
+            ref[0, rows[t], :] = d.astype(ref.dtype)
 
 
 def _cost(backward, b, s, heads, dk, dv, dtypes, group=1, per_head=False):
@@ -675,7 +737,7 @@ def kda_chunk(q, k, v, g, beta):
         raise ValueError(
             f"kda_chunk: q {q.shape}, v {v.shape}: needs head widths of "
             f"{LANE} and key heads that divide the value heads")
-    steps = min(CHUNKS_PER_STEP, -(-s // CHUNK))
+    steps = lockstep_chunks(s)
     pad = -s % (steps * CHUNK)
     # heads side by side on the lanes, as the projections write them
     q, k, v, g = (t.reshape(b, s, -1) for t in (q, k, v, g))

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 import qwen3_next_reference as ref
-from test_kda_kernel import _loss_grads, rel
+from test_kda_kernel import _loss_grads, pair_at_widths, rel
 
 B, HK, HV, D = 2, 2, 4, 128
 
@@ -218,6 +218,9 @@ def test_names_declaration_and_counters(interpreter):
     found = declared(both, *args)
     after = profiler.counters()
     assert after["kda_key_group"] == 2
+    # 200 tokens are four chunks: a grid step solves as many together as
+    # it holds
+    assert after["kda_lockstep_chunks"] == min(kernel.CHUNKS_PER_STEP, 4)
     assert after["kda_decay_per_head"] - before.get(
         "kda_decay_per_head", 0) >= 1
     assert after["kda_dispatch_pallas"] - before.get(
@@ -238,3 +241,16 @@ def test_names_declaration_and_counters(interpreter):
     moved = 4 * (2 * b * s * hk * d + 2 * b * s * hv * d + 2 * b * s * hv
                  + b * hv * chunks * d * d)
     assert numbers(found["gdn_fwd"][0])[2] == moved
+
+
+@pytest.mark.parametrize("per_step", [2, 4])
+@pytest.mark.parametrize("length", [100, 193])
+def test_any_width_of_the_lockstep_is_the_pair_at_one_chunk_a_step(
+        interpreter, monkeypatch, length, per_step):
+    """As tests/test_kda_kernel.py's, with a decay a head and key groups
+    of 2: on a length that leaves a padded tail, 1, 2 or 4 chunks a grid
+    step give the outputs and the five gradients bit for bit (dq and dk
+    are summed over each key head's group after the kernel, by the same
+    adds)."""
+    pair_at_widths(_args(length, -2.0, -0.01, seed=length), per_step,
+                   monkeypatch)

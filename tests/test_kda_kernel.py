@@ -288,3 +288,104 @@ def test_bf16_products_against_the_float32_recurrence(interpreter):
         read.append(rel(a, w))
     for name, distance in zip("o q k v g beta".split(), read):
         assert distance < 0.028, (name, read)
+
+
+def _systems(n, seed):
+    """`n` strictly lower [64, 64] N = Diag(beta) A as a chunk forms them,
+    by regime in turn: nearly parallel keys under hardly any decay (N's
+    entries near 1, where a series in N's powers diverges), keys at
+    random under a mild decay, and a decay that all but erases the
+    state."""
+    r = np.random.RandomState(seed)
+    out = []
+    for at in range(n):
+        noise = r.randn(64, D)
+        k = r.randn(1, D) + 0.3 * noise if at % 3 == 0 else noise
+        k /= np.linalg.norm(k, axis=-1, keepdims=True)
+        lo, hi = [(-0.01, -1e-4), (-1.0, -0.01), (-8.0, -3.0)][at % 3]
+        G = np.cumsum(r.uniform(lo, hi, (64, D)), 0)
+        A = np.einsum("id,jd,ijd->ij", k, k, np.exp(np.minimum(
+            G[:, None] - G[None], 0.0)))
+        beta = r.uniform(0.9 if at % 3 == 0 else 0, 1, (64, 1))
+        out.append(np.tril(beta * A, -1).astype(np.float32))
+    return out
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("chunks", [1, 2, 4])
+def test_the_lockstep_solve_is_each_chunks_own_to_the_bit(chunks, dtype):
+    """`_inverse` over the chunks of a grid step states every chunk's
+    product of a doubling before any chunk's next: the same products on
+    the same operands in another order, so each inverse is the one a call
+    with that N alone gives, bit for bit, and it is an inverse."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.kda_chunk import _inverse
+
+    dtype = jnp.dtype(dtype)
+    Ns = [jnp.asarray(N) for N in _systems(chunks, seed=chunks)]
+    together = jax.jit(lambda *Ns: _inverse(list(Ns), dtype))(*Ns)
+    assert len(together) == chunks
+    for N, T in zip(Ns, together):
+        (alone,) = jax.jit(lambda N: _inverse([N], dtype))(N)
+        assert np.array_equal(np.asarray(T), np.asarray(alone))
+        if dtype == jnp.float32:
+            eye = np.eye(64)
+            np.testing.assert_allclose(
+                (eye + np.asarray(N, np.float64)) @ np.asarray(T, np.float64),
+                eye, atol=2e-4)
+
+
+def pair_at_widths(args, per_step, monkeypatch):
+    """The kernel pair's outputs and five gradients at `per_step` chunks a
+    grid step against one a step: equal bit for bit."""
+    from paddle_tpu.ops.pallas import kda_chunk as kernel
+
+    read = {}
+    for steps in (1, per_step):
+        monkeypatch.setattr(kernel, "CHUNKS_PER_STEP", steps)
+        read[steps] = (kernel.kda_chunk(*args),
+                       *_loss_grads(kernel.kda_chunk, args))
+    for name, a, w in zip("o q k v g beta".split(), read[per_step], read[1]):
+        assert a.shape == w.shape and np.isfinite(np.asarray(a)).all(), name
+        assert np.array_equal(np.asarray(a), np.asarray(w)), name
+
+
+@pytest.mark.parametrize("per_step", [2, 4])
+@pytest.mark.parametrize("length", [100, 193])
+def test_any_width_of_the_lockstep_is_the_pair_at_one_chunk_a_step(
+        interpreter, monkeypatch, length, per_step):
+    """On a length that leaves a padded tail (100: 36 rows of a second
+    chunk; 192 + 1: one row of a fourth), with 1, 2 or 4 chunks a grid
+    step the same products read the same operands: the outputs and the
+    five gradients are equal bit for bit, whichever chunks share a step
+    and however many padded ones follow."""
+    pair_at_widths(_args(length, -1.0, -0.01, seed=length), per_step,
+                   monkeypatch)
+
+
+@pytest.mark.parametrize("seq,chunks", [(8, 1), (64, 1), (65, 2), (640, 10)])
+def test_the_gauge_reads_the_chunks_a_grid_step_solves_together(
+        interpreter, monkeypatch, seq, chunks):
+    """`kda_lockstep_chunks` is `steps` of the call: the row's chunks up
+    to `CHUNKS_PER_STEP`, 1 where the row is one chunk."""
+    import jax.numpy as jnp
+
+    from paddle_tpu import profiler
+    from paddle_tpu.ops import linear_attn_ops
+    from paddle_tpu.ops.pallas import kda_chunk as kernel
+
+    seen = []
+    monkeypatch.setattr(kernel, "kda_chunk",
+                        lambda *a: seen.append(a[2].shape[1])
+                        or jnp.zeros(a[2].shape))
+    x = jnp.ones((1, seq, 2 * D), jnp.float32)
+    logits = jnp.ones((1, seq, 2), jnp.float32)
+    heads = jnp.zeros((2,), jnp.float32)
+    linear_attn_ops.kda_mixer_core(x, x, x, x, logits, heads,
+                                   jnp.zeros((2 * D,), jnp.float32), 2, 1e-6)
+    assert seen == [seq]
+    want = min(kernel.CHUNKS_PER_STEP, chunks)
+    assert kernel.lockstep_chunks(seq) == want
+    assert profiler.counters()["kda_lockstep_chunks"] == want

@@ -1,20 +1,56 @@
-"""Count what one KDA chunk asks of the vector units, without a chip: the
-jaxprs of `_chunk_fwd` and `_chunk_bwd` (ops/pallas/kda_chunk.py) at the
-cell's shapes ([64, 128] float32 operands, bf16 products), every
-equation's outputs as [8, 128] registers of 32-bit lanes, products left
-out. A count, not a timing: Mosaic folds some `iota`, `broadcast_in_dim`
-and `convert_element_type`, so those are given apart.
+"""Two counts of the KDA chunk kernels (ops/pallas/kda_chunk.py), neither
+a timing and neither needing a chip.
+
+What one chunk asks of the vector units: the jaxprs of a chunk's forward
+(`_state_free` over a list of one, then `_chunk_fwd`) and backward
+(`_state_free` with Aq transposed, then `_chunk_bwd`) at the cell's shapes
+([64, 128] float32 operands, bf16 products), every equation's outputs as
+[8, 128] registers of 32-bit lanes, products left out. Mosaic folds some
+`iota`, `broadcast_in_dim` and `convert_element_type`, so those are given
+apart.
 
     JAX_PLATFORMS=cpu python tools/kda_vreg_count.py [path/to/kda_chunk.py]
 
-(the path: another copy of the kernel file, say a parent commit's.)
+(the path: another copy of the kernel file, say a parent commit's, as long
+as it has this tree's functions.)
+
+The compiler's own schedule of a grid step, `--schedule <dir>`: the pair
+at the cells' shapes (one row of 4,096 tokens, 32 heads of 128, bf16
+values; a decay a channel, then a decay a head under 16 key heads)
+compiled for a described v5e with
+
+    LIBTPU_INIT_ARGS="--xla_jf_dump_to=<dir> --xla_jf_dump_llo_text=true"
+
+which this mode sets itself. libtpu then writes, for each `pallas_call` by
+name, `*<name>*final_bundles.txt` (the instruction bundles of a grid
+step), `*final_hlo-static-per-bundle-utilization.txt` (the units' slots a
+bundle) and `*critical-path.txt` (`Length to end`: the longest chain of
+dependent instructions in cycles, the order of a unit of the MXU
+included). Printed a kernel: the bundles and that chain. Where the chain
+is the longer of the two, a grid step's time is cycles x 0.83 to 0.89 ns
+by the wall time of `jax.jit(kda_chunk)` over fourteen builds of the pair
+(PERF.md section 7, PR 50), cycles x 0.70 to 0.80 ns by `_call_fwd` and
+`_call_bwd` alone or by a trace over ten more (PR 52: the higher figures
+where the bundles come near the chain); of two orders of the same
+products it named the faster every time it was asked.
+
+    JAX_PLATFORMS=cpu python tools/kda_vreg_count.py --schedule /tmp/llo \\
+        [--chunks 4] [path/to/kda_chunk.py]
+
+(`--chunks`: `CHUNKS_PER_STEP` for this compile; `<dir>` fresh, or it
+holds an older build's files too. The compile runs in a child process,
+because libtpu aborts inside it once the kernels' files are written; one
+process loads libtpu at a time.)
 """
 
 from __future__ import annotations
 
+import argparse
 import collections
 import importlib.util
 import os
+import re
+import subprocess
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -48,22 +84,35 @@ def count(jaxpr, into):
     return into
 
 
-def main(path=None):
+def load(path):
     if path is None:
         from paddle_tpu.ops.pallas import kda_chunk as kernel
-    else:
-        name = "paddle_tpu.ops.pallas._counted_kda_chunk"
-        spec = importlib.util.spec_from_file_location(name, path)
-        kernel = sys.modules[name] = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(kernel)
+        return kernel
+    name = "paddle_tpu.ops.pallas._counted_kda_chunk"
+    spec = importlib.util.spec_from_file_location(name, path)
+    kernel = sys.modules[name] = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(kernel)
+    return kernel
+
+
+def registers_a_chunk(kernel):
+    dtype = jnp.bfloat16
     x = jax.ShapeDtypeStruct((kernel.CHUNK, 128), jnp.float32)
     beta = jax.ShapeDtypeStruct((kernel.CHUNK, 1), jnp.float32)
     state = jax.ShapeDtypeStruct((128, 128), jnp.float32)
-    for fn, args in ((kernel._chunk_fwd, (x, x, x, x, beta, state)),
-                     (kernel._chunk_bwd, (x, x, x, x, beta, state, state, x))):
-        by = count(jax.make_jaxpr(
-            lambda *a: fn(*a, dtype=jnp.bfloat16))(*args).jaxpr,
-            collections.Counter())
+
+    def chunk_fwd(q, k, v, g, beta, St):
+        free, = kernel._state_free([(q, k, v, g, beta)], dtype)
+        return kernel._chunk_fwd(q, k, free, St, dtype=dtype)
+
+    def chunk_bwd(q, k, v, g, beta, St, dSt, dO):
+        free, = kernel._state_free([(q, k, v, g, beta)], dtype, True)
+        return kernel._chunk_bwd(q, k, v, beta, free, St, dSt, dO,
+                                 dtype=dtype)
+
+    for fn, args in ((chunk_fwd, (x, x, x, x, beta, state)),
+                     (chunk_bwd, (x, x, x, x, beta, state, state, x))):
+        by = count(jax.make_jaxpr(fn)(*args).jaxpr, collections.Counter())
         total = sum(by.values())
         folded = sum(by[p] for p in FOLDED)
         print(f"{fn.__name__}: {total} register operations, "
@@ -72,5 +121,86 @@ def main(path=None):
         print("  " + ", ".join(f"{p} {n}" for p, n in by.most_common()))
 
 
+def compile_for_v5e(kernel, chunks):
+    """The two pairs in one jit, compiled for a described v5e: libtpu's
+    dump aborts the process inside this compile, after the kernels' files
+    are written (it looks for a report template that is not installed)."""
+    jax.config.update("jax_enable_compilation_cache", False)
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    chip = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+    b, s, h, d = 1, 4096, 32, 128
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def pair(hk, per_head):
+        statics = kernel._Statics(h, chunks, jnp.bfloat16, False, s, h // hk,
+                                  per_head)
+
+        def both(q, k, v, g, beta):
+            o, pull = jax.vjp(lambda *a: kernel._core(*a, statics), q, k, v,
+                              g, beta)
+            return o, pull(o)
+
+        x = sds((b, s, hk * d))
+        return both, (x, x, sds((b, s, h * d), jnp.bfloat16),
+                      sds((b, s, h)) if per_head else sds((b, s, h * d)),
+                      sds((b, s, h)))
+
+    (kda, kda_args), (gdn, gdn_args) = pair(h, False), pair(h // 2, True)
+    jax.jit(lambda a, b: (kda(*a), gdn(*b))).lower(
+        kda_args, gdn_args).compile()
+
+
+def schedule(path, into, chunks):
+    """Compile in a child (`--compile`) with the dump on, then read what
+    it wrote whether or not it left in order."""
+    os.makedirs(into, exist_ok=True)
+    child = [sys.executable, os.path.abspath(__file__), "--compile",
+             "--chunks", str(chunks)] + ([path] if path else [])
+    subprocess.run(child, capture_output=True, env=dict(
+        os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
+        LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={into} "
+                         "--xla_jf_dump_llo_text=true"))
+    read = collections.defaultdict(dict)
+    for name in sorted(os.listdir(into)):
+        found = re.match(r"\d+-(\w+_(?:fwd|bwd))\.\d+-\d+-(.+)\.txt$", name)
+        if not found:
+            continue
+        with open(os.path.join(into, name)) as f:
+            text = f.read()
+        if found[2] == "critical-path":
+            read[found[1]]["chain"] = max(
+                int(n) for n in re.findall(r"Length to end: (\d+)", text))
+        elif found[2] == "schedule-analysis_final_bundles":
+            read[found[1]]["bundles"] = int(re.search(
+                r"total scheduled bundles:\s+(\d+)", text)[1])
+    if not read:
+        sys.exit(f"no kernel's files under {into}: was libtpu free to load?")
+    for name, numbers in read.items():
+        print(f"{name}: {numbers.get('bundles')} bundles a grid step of "
+              f"{chunks} chunks, longest chain {numbers.get('chain')} cycles")
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("path", nargs="?", help="another copy of kda_chunk.py")
+    ap.add_argument("--schedule", metavar="DIR",
+                    help="compile for a described v5e and dump into DIR")
+    ap.add_argument("--chunks", type=int, help="CHUNKS_PER_STEP to compile")
+    ap.add_argument("--compile", action="store_true", help=argparse.SUPPRESS)
+    args = ap.parse_args(argv)
+    if args.schedule:
+        chunks = args.chunks or load(args.path).CHUNKS_PER_STEP
+        schedule(args.path, os.path.abspath(args.schedule), chunks)
+    elif args.compile:
+        compile_for_v5e(load(args.path), args.chunks)
+    else:
+        registers_a_chunk(load(args.path))
+
+
 if __name__ == "__main__":
-    main(*sys.argv[1:2])
+    main()
