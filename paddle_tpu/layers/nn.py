@@ -75,6 +75,7 @@ __all__ = [
     "rms_norm",
     "short_conv1d",
     "selective_scan",
+    "ssd_scan",
     "kda_attention",
     "embedding",
     "conv2d",
@@ -758,6 +759,46 @@ def selective_scan(x, delta, a, b, c, d, name=None):
     return y
 
 
+def ssd_scan(x, dt, b, c, num_heads, n_groups=1, chunk_size=128,
+             a_log_attr=None, dt_bias_attr=None, d_attr=None, name=None):
+    """Mamba-2's recurrence over one sequence a row (ops/ssm_ops.py has
+    the equations): `x` [b, s, num_heads * P], `dt` [b, s, num_heads] the
+    step's projection, `b` and `c` [b, s, n_groups * N], head h reading
+    group h // (num_heads / n_groups). `num_heads` and `n_groups` are the
+    heads and groups held here, which may be a share of a mixer's. Inside
+    the op, in float32: the step `softplus(dt + dt_bias)`, the decay
+    `exp(step * -exp(A_log))`, one number a head and a token, and the
+    chunks of `chunk_size` tokens; the state is zero at the start of a
+    row. Creates `A_log` (exp of it log-uniform in 1 to 16), `dt_bias`
+    (the step log-uniform in 0.001 to 0.1: `kda_attention`'s seeding) and
+    the skip's weight `D` (ones), [num_heads] each. Returns y like `x`."""
+    from ..ops.ssm_ops import ssd_n_chunks
+
+    helper = LayerHelper("ssd_scan", name=name)
+    a_log = helper.create_parameter(
+        a_log_attr, [num_heads], dtype="float32",
+        default_initializer=Uniform(0.0, float(np.log(16.0))))
+    dt_bias = helper.create_parameter(
+        dt_bias_attr, [num_heads], dtype="float32",
+        default_initializer=Uniform(-6.9, -2.25))
+    d = helper.create_parameter(d_attr, [num_heads], dtype="float32",
+                                default_initializer=Constant(1.0))
+    y = helper.create_variable_for_type_inference(x.dtype, x.shape)
+    # the states the chunks start from: what the gradient op keeps
+    starts = helper.create_variable_for_type_inference(
+        "float32", (ssd_n_chunks(int(x.shape[1]), chunk_size),
+                    int(x.shape[0]), int(num_heads),
+                    int(x.shape[2]) // int(num_heads),
+                    int(b.shape[2]) // int(n_groups)), stop_gradient=True)
+    helper.append_op(
+        type="ssd_scan",
+        inputs={"X": [x], "Dt": [dt], "DtBias": [dt_bias], "ALog": [a_log],
+                "B": [b], "C": [c], "D": [d]},
+        outputs={"Y": [y], "Starts": [starts]},
+        attrs={"n_groups": int(n_groups), "chunk_size": int(chunk_size)})
+    return y
+
+
 def kda_attention(q, k, v, g, beta, num_heads, l2norm_epsilon=1e-6,
                   a_log_attr=None, dt_bias_attr=None, name=None,
                   num_key_heads=None):
@@ -795,7 +836,7 @@ def kda_attention(q, k, v, g, beta, num_heads, l2norm_epsilon=1e-6,
 def moe_experts(input, experts_total, experts_held, d_ff, k, held_from=0,
                 scaling=1.0, renormalize=True, bias_scale=0.0,
                 param_attr=None, name=None, score_func="sigmoid",
-                norm_eps=0.0):
+                norm_eps=0.0, experts_input=None, expert_form="silu_gated"):
     """The held experts' part of a dropless expert layer (SiLU-gated
     FFNs of width `d_ff`): a router over all `experts_total` (`score_func`
     "sigmoid": each expert's own sigmoid; "softmax": the probabilities
@@ -805,42 +846,61 @@ def moe_experts(input, experts_total, experts_held, d_ff, k, held_from=0,
     through one grouped product, every one of them, whatever the skew.
     What experts held elsewhere would add is left out. `bias` is the router's
     correction: persistable, seeded Normal(0, bias_scale), never
-    trained. Returns (out like input, load [experts_held] int32)."""
+    trained. `experts_input`: what the experts read where that is not the
+    router's `input` (same leading dimensions, another width: a latent of
+    the token); the experts' matrices and `out` then have its width.
+    `expert_form` "relu2": an expert is `W_down relu(W_up x)^2` and there
+    is no `w_gate`. Returns (out like what the experts read, load): the
+    op's second output, `Load`, is `load` [experts_held] int32, the
+    assignments each held expert took this step, which carries no
+    gradient and which the cells fetch to say how full the first block
+    ran."""
     if score_func not in ("sigmoid", "softmax"):
         raise ValueError(
             f"score_func must be 'sigmoid' or 'softmax', got {score_func!r}")
+    if expert_form not in ("silu_gated", "relu2"):
+        raise ValueError("expert_form must be 'silu_gated' or 'relu2', got "
+                         f"{expert_form!r}")
     helper = LayerHelper("moe_experts", name=name)
-    d = int(input.shape[-1])
+    read = input if experts_input is None else experts_input
+    d_router, d = int(input.shape[-1]), int(read.shape[-1])
 
     def pattr(suffix, **overrides):
         return _suffixed_attr(param_attr, suffix, **overrides)
 
-    gate = helper.create_parameter(pattr("gate"), [d, experts_total],
+    gate = helper.create_parameter(pattr("gate"), [d_router, experts_total],
                                    dtype="float32")
     bias = helper.create_parameter(
         pattr("bias", trainable=False,
               initializer=Normal(0.0, bias_scale) if bias_scale
               else Constant(0.0)),
         [experts_total], dtype="float32")
-    w_gate = helper.create_parameter(pattr("w_gate"), [experts_held, d, d_ff],
-                                     dtype="float32")
-    w_up = helper.create_parameter(pattr("w_up"), [experts_held, d, d_ff],
-                                   dtype="float32")
-    w_down = helper.create_parameter(pattr("w_down"), [experts_held, d_ff, d],
-                                     dtype="float32")
-    out = helper.create_variable_for_type_inference(input.dtype, input.shape)
+    inputs = {"X": [input], "Gate": [gate], "Bias": [bias]}
+    if experts_input is not None:
+        inputs["XExperts"] = [experts_input]
+    if expert_form == "silu_gated":
+        inputs["WGate"] = [helper.create_parameter(
+            pattr("w_gate"), [experts_held, d, d_ff], dtype="float32")]
+    inputs["WUp"] = [helper.create_parameter(
+        pattr("w_up"), [experts_held, d, d_ff], dtype="float32")]
+    inputs["WDown"] = [helper.create_parameter(
+        pattr("w_down"), [experts_held, d_ff, d], dtype="float32")]
+    out = helper.create_variable_for_type_inference(read.dtype, read.shape)
     load = helper.create_variable_for_type_inference(
         "int32", (experts_held,), stop_gradient=True)
     helper.append_op(
         type="moe_experts",
-        inputs={"X": [input], "Gate": [gate], "Bias": [bias],
-                "WGate": [w_gate], "WUp": [w_up], "WDown": [w_down]},
+        inputs=inputs,
         outputs={"Out": [out], "Load": [load]},
+        # on a Program of SiLU-gated experts that read the router's rows
+        # the op is as it was
         attrs={"experts_total": int(experts_total),
                "experts_held": int(experts_held), "held_from": int(held_from),
                "k": int(k), "scaling": float(scaling),
                "renormalize": bool(renormalize), "score_func": score_func,
-               **({"norm_eps": float(norm_eps)} if norm_eps else {})},
+               **({"norm_eps": float(norm_eps)} if norm_eps else {}),
+               **({"expert_form": expert_form}
+                  if expert_form != "silu_gated" else {})},
     )
     return out, load
 

@@ -2,8 +2,10 @@
 reference's test model zoo), built through the framework's own layers API —
 LeNet-5 (MNIST), ResNet (ImageNet), SE-ResNeXt, VGG, Transformer/BERT
 (WMT16 / pretrain), DeepFM (CTR), Kimi Linear, Trinity, Mellum, JoyAI
-Flash, LFM2 and Qwen3-Next (each a share of an expert-parallel decoder),
-and Phi-4-mini-flash (a pipeline stage's share of a decoder-hybrid-decoder)."""
+Flash, LFM2, Qwen3-Next and Nemotron-H (each a share of an
+expert-parallel decoder; Nemotron-H's mixers hold a share of their heads
+too), and Phi-4-mini-flash (a pipeline stage's share of a
+decoder-hybrid-decoder)."""
 
 from . import (  # noqa: F401
     bert,
@@ -13,6 +15,7 @@ from . import (  # noqa: F401
     lenet,
     lfm2,
     mellum,
+    nemotron_h,
     phi4_flash,
     qwen3_next,
     resnet,
@@ -25,6 +28,7 @@ from .joyai_flash import JoyAIFlashConfig, build_joyai_flash  # noqa: E402,F401
 from .kimi_linear import KimiLinearConfig, build_kimi_linear  # noqa: E402,F401
 from .lfm2 import Lfm2Config, build_lfm2  # noqa: E402,F401
 from .mellum import MellumConfig, build_mellum  # noqa: E402,F401
+from .nemotron_h import NemotronHConfig, build_nemotron_h  # noqa: E402,F401
 from .phi4_flash import Phi4FlashConfig, build_phi4_flash  # noqa: E402,F401
 from .qwen3_next import Qwen3NextConfig, build_qwen3_next  # noqa: E402,F401
 from .trinity import TrinityConfig, build_trinity  # noqa: E402,F401

@@ -1,20 +1,26 @@
 """What the decoders of the zoo (`kimi_linear`, `trinity`, `mellum`,
-`joyai_flash`, `phi4_flash`, `lfm2`, `qwen3_next`) build their layers
-from: projections seeded Normal(0, `initializer_range`), with a bias where
-asked, RMSNorm with a learned weight and LayerNorm with weight and bias,
-the SiLU-gated feed-forward as three products or with gate and up in one,
-attention over grouped key/value heads with QK-norm and rotary positions
-on a head or on its first lanes, latent attention (`kimi_linear`,
-`joyai_flash`), differential attention (`phi4_flash`), the double-gated
-short convolution (`lfm2`), Gated DeltaNet (`qwen3_next`: the delta rule
-with a decay a head and key heads shared by groups of value heads), and
-the expert layer that holds a share of the experts, with a shared expert
-that a token may gate. A `cfg` gives `hidden_size`, `initializer_range`,
-`rms_norm_eps` (or `layer_norm_eps`), for `attention` and
-`differential_attention` the heads, for `latent_attention` the keys its
-docstring lists, for `gated_short_conv` `conv_L_cache`, for
-`gated_delta_net` the `linear_*` keys, and for `expert_ffn` the router's
-keys as `KimiLinearConfig` names them."""
+`joyai_flash`, `phi4_flash`, `lfm2`, `qwen3_next`, `nemotron_h`) build
+their layers from: projections seeded Normal(0, `initializer_range`), with
+a bias where asked, RMSNorm with a learned weight and LayerNorm with
+weight and bias, the SiLU-gated feed-forward as three products or with
+gate and up in one, the squared-ReLU feed-forward without a gate
+(`nemotron_h`), attention over grouped key/value heads with or without
+QK-norm and with rotary positions on a head, on its first lanes or not at
+all, latent attention (`kimi_linear`, `joyai_flash`), differential
+attention (`phi4_flash`), the double-gated short convolution (`lfm2`),
+Gated DeltaNet (`qwen3_next`: the delta rule with a decay a head and key
+heads shared by groups of value heads), the Mamba-2 mixer (`nemotron_h`:
+a decay a head and a token, a norm by groups behind the gate), and the
+expert layer that holds a share of the experts, with a shared expert that
+a token may gate, and with experts that may read a latent of the token
+and have no gate. A mixer builds the heads its `cfg` counts: where a chip
+holds a share of a mixer's heads, `cfg` gives the share. A `cfg` gives
+`hidden_size`, `initializer_range`, `rms_norm_eps` (or `layer_norm_eps`),
+for `attention` and `differential_attention` the heads, for
+`latent_attention` the keys its docstring lists, for `gated_short_conv`
+`conv_L_cache`, for `gated_delta_net` the `linear_*` keys, for
+`mamba2_mixer` the `mamba_*` keys, and for `expert_ffn` the router's keys
+as `KimiLinearConfig` names them."""
 
 from __future__ import annotations
 
@@ -25,13 +31,16 @@ from ..initializer import Normal, Uniform
 from ..param_attr import ParamAttr
 
 
-def attr(name, cfg):
-    return ParamAttr(name=name, initializer=Normal(0.0, cfg.initializer_range))
+def attr(name, cfg, std=None):
+    return ParamAttr(name=name, initializer=Normal(
+        0.0, cfg.initializer_range if std is None else std))
 
 
-def proj(x, size, name, cfg, bias=False):
+def proj(x, size, name, cfg, bias=False, std=None):
+    """`std`: the seeding's deviation where it is not `initializer_range`
+    (a block's last product under `rescale_prenorm_residual`)."""
     return layers.fc(x, size, num_flatten_dims=2,
-                     param_attr=attr(name + ".w_0", cfg),
+                     param_attr=attr(name + ".w_0", cfg, std),
                      bias_attr=ParamAttr(name=name + ".b_0") if bias else False)
 
 
@@ -52,6 +61,13 @@ def ffn(u, width, name, cfg):
     up = proj(u, width, name + ".up", cfg)
     return proj(layers.elementwise_mul(gate, up), cfg.hidden_size,
                 name + ".down", cfg)
+
+
+def relu2_ffn(u, width, name, cfg, out_std=None):
+    """`W_down relu(W_up u)^2`: two products and no gate."""
+    up = layers.relu(proj(u, width, name + ".up", cfg))
+    return proj(layers.square(up), cfg.hidden_size, name + ".down", cfg,
+                std=out_std)
 
 
 def fused_ffn(u, width, name, cfg):
@@ -121,6 +137,48 @@ def gated_delta_net(u, cfg, name):
     return proj(o, cfg.hidden_size, name + ".out_proj", cfg)
 
 
+def mamba2_mixer(u, cfg, name, out_std=None):
+    """Mamba-2's mixer (arXiv:2405.21060), u [b, s, hidden] to
+    [b, s, hidden], with the H = `mamba_num_heads` heads of P =
+    `mamba_head_dim` and the G = `mamba_n_groups` groups of B and C that
+    are held here (a tensor-parallel rank's share of the published mixer
+    is whole groups: its heads, their B and C, their group of the norm):
+    `[z ; xBC ; dt] = W_in u` (hidden to H P + (H P + 2 G N) + H, N =
+    `ssm_state_size`), one product; `xBC` through one causal depthwise
+    convolution of `mamba_conv_kernel` taps with bias and a SiLU; the op
+    `ssd_scan` makes the step `softplus(dt + dt_bias)` and the decay from
+    `A_log` and runs the recurrence in chunks of `mamba_chunk_size`; the
+    output times `SiLU(z)` is RMS-normed group by group over the H P / G
+    channels of a group, each channel with its own learned weight
+    (`.norm.group{i}.w_0`), before `W_out`. The filter and its bias are
+    seeded uniform in +-taps^-1/2."""
+    h, p, g, n = (cfg.mamba_num_heads, cfg.mamba_head_dim,
+                  cfg.mamba_n_groups, cfg.ssm_state_size)
+    z, xbc, dt = layers.split(
+        proj(u, 2 * h * p + 2 * g * n + h, name + ".in_proj", cfg),
+        [h * p, h * p + 2 * g * n, h], dim=2)
+    edge = cfg.mamba_conv_kernel ** -0.5
+    xs, bm, cm = layers.split(layers.short_conv1d(
+        xbc, cfg.mamba_conv_kernel,
+        param_attr=ParamAttr(name=name + ".conv.w_0",
+                             initializer=Uniform(-edge, edge)),
+        bias_attr=ParamAttr(name=name + ".conv.b_0",
+                            initializer=Uniform(-edge, edge))),
+        [h * p, g * n, g * n], dim=2)
+    y = layers.ssd_scan(
+        xs, dt, bm, cm, num_heads=h, n_groups=g,
+        chunk_size=cfg.mamba_chunk_size,
+        a_log_attr=ParamAttr(name=name + ".A_log"),
+        dt_bias_attr=ParamAttr(name=name + ".dt_bias"),
+        d_attr=ParamAttr(name=name + ".D"))
+    gated = layers.elementwise_mul(y, layers.swish(z))
+    parts = [gated] if g == 1 else layers.split(gated, g, dim=2)
+    normed = [norm(part, f"{name}.norm.group{i}", cfg)
+              for i, part in enumerate(parts)]
+    o = normed[0] if g == 1 else layers.concat(normed, axis=2)
+    return proj(o, cfg.hidden_size, name + ".out_proj", cfg, std=out_std)
+
+
 def _by_pairs(t, b, s, pairs, d):
     """[b, s, pairs * 2 * d], pair n heads 2n and 2n + 1, to the first and
     the second head of every pair, [b, s, pairs, d] each."""
@@ -180,15 +238,17 @@ def differential_attention(u, cfg, name, window=0, kv=None, lam0=0.8):
 
 
 def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
-              gated=False, rotary_dim=0):
+              gated=False, rotary_dim=0, qk_norm=True, out_std=None):
     """Causal attention of `num_attention_heads` query heads over
     `num_key_value_heads` key/value heads of `head_dim`, u [b, s, hidden]
     to [b, s, hidden]: q and k normed over a head's width (one weight of
-    `head_dim` each), turned by rotary positions where `rope_theta` is
+    `head_dim` each; not with `qk_norm` False, and then there are no
+    positions either), turned by rotary positions where `rope_theta` is
     not 0 (`rope_scaling`: a YaRN group; `rotary_dim` not 0: the first
     `rotary_dim` lanes of a head alone), `window` keys wide where it is
     not 0, and with `gated` the output times `sigmoid(W_g u)` before the
-    output projection."""
+    output projection. The heads are the ones held here, which may be a
+    share of the model's."""
     b, s, _ = u.shape
     h, g, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     q = layers.reshape(proj(u, h * d, name + ".q", cfg), [b, s, h, d])
@@ -198,16 +258,22 @@ def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
         gate = layers.sigmoid(proj(u, h * d, name + ".gate", cfg))
     # QK-norm and the positions inside the attention op, where they and
     # the kernel's head-major write are one pass over q and k
+    prep = {}
+    if qk_norm:
+        prep = dict(q_norm_attr=ParamAttr(name=name + ".q_norm.w_0"),
+                    k_norm_attr=ParamAttr(name=name + ".k_norm.w_0"),
+                    qk_norm_epsilon=cfg.rms_norm_eps, rope_theta=rope_theta,
+                    rope_scaling=rope_scaling, rotary_dim=rotary_dim)
+    elif rope_theta:
+        raise ValueError("attention: positions are turned with the "
+                         "QK-norm's pass, and qk_norm is False")
     a = layers.fused_multihead_attention(
         q, k, v, causal=True, sm_scale=1.0 / math.sqrt(d), layout="bshd",
-        window=window, q_norm_attr=ParamAttr(name=name + ".q_norm.w_0"),
-        k_norm_attr=ParamAttr(name=name + ".k_norm.w_0"),
-        qk_norm_epsilon=cfg.rms_norm_eps, rope_theta=rope_theta,
-        rope_scaling=rope_scaling, rotary_dim=rotary_dim)
+        window=window, **prep)
     a = layers.reshape(a, [b, s, h * d])
     if gated:
         a = layers.elementwise_mul(a, gate)
-    return proj(a, cfg.hidden_size, name + ".o", cfg)
+    return proj(a, cfg.hidden_size, name + ".o", cfg, std=out_std)
 
 
 def latent_attention(u, cfg, name):
@@ -262,24 +328,45 @@ def latent_attention(u, cfg, name):
                 name + ".o", cfg)
 
 
-def expert_ffn(u, cfg, name, norm_eps=0.0):
+def expert_ffn(u, cfg, name, norm_eps=0.0, out_std=None):
     """Returns (what the shared expert and the held experts add, load).
     `norm_eps`: added to the sum the selected scores are divided by.
     Where `cfg.shared_expert_gate` is set (absent: not) the shared
     expert's output is multiplied by `sigmoid(w_sg . u)`, one number a
     token from a projection of width 1 (`.shared_gate`; counter
-    `moe_shared_expert_gated`, once a layer built)."""
+    `moe_shared_expert_gated`, once a layer built).
+
+    Where `cfg.moe_latent_size` is set (absent: not) the experts work in
+    a latent of that width: the router and the shared expert read u, the
+    experts `W_lat_in u` (`.latent_in`, `moe_experts`' second input), and
+    what they give comes back through `W_lat_out` (`.latent_out`). Where
+    `cfg.expert_form` is "relu2" (absent: SiLU-gated) the experts and the
+    shared expert are `W_down relu(W_up x)^2` with no gate (counter
+    `moe_experts_ungated`, once a layer built). `out_std` seeds the last
+    product of each path that ends outside the experts."""
+    latent = getattr(cfg, "moe_latent_size", 0)
+    form = getattr(cfg, "expert_form", "silu_gated")
+    more = {}
+    if latent:
+        more["experts_input"] = proj(u, latent, name + ".latent_in", cfg)
+    if form != "silu_gated":
+        profiler.bump_counter("moe_experts_ungated")
+        more["expert_form"] = form
     routed, load = layers.moe_experts(
         u, experts_total=cfg.num_experts, experts_held=cfg.experts_held,
         d_ff=cfg.moe_intermediate_size, k=cfg.num_experts_per_token,
         held_from=cfg.held_from, scaling=cfg.routed_scaling_factor,
         renormalize=cfg.moe_renormalize, bias_scale=cfg.router_bias_scale,
         param_attr=attr(name + ".moe", cfg),
-        score_func=cfg.score_func, norm_eps=norm_eps)
+        score_func=cfg.score_func, norm_eps=norm_eps, **more)
+    if latent:
+        routed = proj(routed, cfg.hidden_size, name + ".latent_out", cfg,
+                      std=out_std)
     if not cfg.num_shared_experts:
         return routed, load
-    shared = ffn(u, cfg.moe_intermediate_size * cfg.num_shared_experts,
-                 name + ".shared", cfg)
+    width = cfg.moe_intermediate_size * cfg.num_shared_experts
+    shared = (relu2_ffn(u, width, name + ".shared", cfg, out_std)
+              if form == "relu2" else ffn(u, width, name + ".shared", cfg))
     if getattr(cfg, "shared_expert_gate", False):
         profiler.bump_counter("moe_shared_expert_gated")
         shared = layers.elementwise_mul(

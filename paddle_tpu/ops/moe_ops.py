@@ -45,7 +45,16 @@ def _moe_experts(ctx, op):
     "sigmoid" (the default) or "softmax"; attr `norm_eps` (optional, 0)
     added to the renormalisation's sum; WGate, WUp:
     [experts_held, D, F]; WDown: [experts_held, F, D]. Out like X: what
-    the held experts add. Load: [experts_held] int32."""
+    the held experts add. Load: [experts_held] int32, the assignments
+    each held expert took.
+
+    XExperts (optional): [..., D_e], what the experts read where that is
+    not the router's X (a latent of the token: gauge `moe_latent_width`);
+    WGate, WUp and WDown are then D_e wide and Out is like XExperts.
+    Attr `expert_form` (optional): absent, the experts are SiLU-gated,
+    `W_down (silu(W_gate x) * W_up x)`; "relu2": there is no WGate and an
+    expert is `W_down relu(W_up x)^2`. `moe_assignments` counts the
+    tokens times k of each lowering."""
     from .. import profiler
     from ..parallel.moe import _block_rows, moe_experts
     from .pallas.grouped_matmul import grouped_matmul_viable
@@ -57,6 +66,23 @@ def _moe_experts(ctx, op):
         raise ValueError(
             f"moe_experts: Gate has {gate.shape[1]} columns, experts_total "
             f"is {total}")
+    x = ctx.in_(op, "X")
+    experts_x = ctx.in_(op, "XExperts") if op.input("XExperts") else None
+    form = op.attr("expert_form", "silu_gated")
+    if form not in ("silu_gated", "relu2"):
+        raise ValueError(f"moe_experts: expert_form {form!r}: expected "
+                         "'silu_gated' or 'relu2'")
+    w_gate = ctx.in_(op, "WGate") if form == "silu_gated" else None
+    w_up = ctx.in_(op, "WUp")
+    read = x if experts_x is None else experts_x
+    if gate.shape[0] != x.shape[-1]:
+        raise ValueError(
+            f"moe_experts: Gate {gate.shape} does not take the router's "
+            f"input X {x.shape}")
+    if w_up.shape[1] != read.shape[-1] or read.shape[:-1] != x.shape[:-1]:
+        raise ValueError(
+            f"moe_experts: WUp {w_up.shape} does not take the experts' "
+            f"input {read.shape} (the router's X is {x.shape})")
     profiler.bump_counter("moe_dispatch_grouped")
     # the first block is straight-line in every lowering, so that XLA merges
     # the forward op's with the one the gradient op replays
@@ -66,8 +92,10 @@ def _moe_experts(ctx, op):
     score_func = op.attr("score_func", "sigmoid")
     if score_func == "softmax":
         profiler.bump_counter("moe_route_softmax")
-    x = ctx.in_(op, "X")
     k = op.attr("k")
+    profiler.bump_counter("moe_assignments", x.size // x.shape[-1] * k)
+    if experts_x is not None:
+        profiler.set_counter("moe_latent_width", int(experts_x.shape[-1]))
     # rows of the first, straight-line block of this layer's N*k sorted
     # assignments: it follows the share held
     profiler.set_counter(
@@ -75,21 +103,19 @@ def _moe_experts(ctx, op):
     # the router is float32 inside moe_route; the grouped products ride
     # the amp dtype, cast inside (both operands), as in moe_ffn
     compute_dtype = ctx.amp_dtype_for(op)
-    w_gate = ctx.in_(op, "WGate")
     # the Pallas kernels where they can run: lane-multiple widths, Mosaic
     # or the interpreter, one device (GSPMD cannot partition a custom call,
     # and the sorted rows are no batch axis to run per shard)
     kernel = batch_shards(ctx.mesh) == 1 and grouped_matmul_viable(
-        w_gate.shape[1], w_gate.shape[2], compute_dtype or x.dtype)
+        w_up.shape[1], w_up.shape[2], compute_dtype or x.dtype)
     if kernel:
         profiler.bump_counter("moe_dispatch_gmm")
     y, load = moe_experts(
-        x, gate, ctx.in_(op, "Bias"), w_gate,
-        ctx.in_(op, "WUp"), ctx.in_(op, "WDown"),
+        x, gate, ctx.in_(op, "Bias"), w_gate, w_up, ctx.in_(op, "WDown"),
         k=k, scaling=op.attr("scaling", 1.0),
         experts_held=held, held_from=op.attr("held_from", 0),
         renormalize=op.attr("renormalize", True),
         compute_dtype=compute_dtype, score_func=score_func, kernel=kernel,
-        norm_eps=op.attr("norm_eps", 0.0))
+        norm_eps=op.attr("norm_eps", 0.0), experts_x=experts_x)
     ctx.out(op, "Out", y)
     ctx.out(op, "Load", load)

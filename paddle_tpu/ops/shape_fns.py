@@ -889,6 +889,26 @@ def _shape_selective_scan_grad(ictx, op):
         ictx.out(op, "IGRAD_" + slot, _m(ictx.in_(op, slot)))
 
 
+@register_shape("ssd_scan")
+def _shape_ssd_scan(ictx, op):
+    from .ssm_ops import ssd_n_chunks
+
+    x, a, bm = ictx.in_(op, "X"), ictx.in_(op, "ALog"), ictx.in_(op, "B")
+    ictx.out(op, "Y", _m(x))
+    if _known(x, a, bm):
+        b, s, d = x.shape
+        heads = a.shape[0]
+        ictx.out(op, "Starts", VarMeta(
+            (ssd_n_chunks(s, op.attr("chunk_size", 128)), b, heads,
+             d // heads, bm.shape[2] // op.attr("n_groups", 1)), "float32"))
+
+
+@register_shape("ssd_scan_grad")
+def _shape_ssd_scan_grad(ictx, op):
+    for slot in ("X", "Dt", "DtBias", "ALog", "B", "C", "D"):
+        ictx.out(op, "IGRAD_" + slot, _m(ictx.in_(op, slot)))
+
+
 @register_shape("kda_attention")
 def _shape_kda_attention(ictx, op):
     ictx.out(op, "Out", _m(ictx.in_(op, "V")))
@@ -896,7 +916,8 @@ def _shape_kda_attention(ictx, op):
 
 @register_shape("moe_experts")
 def _shape_moe_experts(ictx, op):
-    ictx.out(op, "Out", _m(ictx.in_(op, "X")))
+    ictx.out(op, "Out", _m(ictx.in_(
+        op, "XExperts" if op.input("XExperts") else "X")))
     ictx.out(op, "Load", VarMeta((op.attr("experts_held"),), "int32"))
 
 

@@ -1,8 +1,15 @@
-"""State-space op lowerings: the selective scan of Mamba-1 (Gu and Dao,
-arXiv:2312.00752, section 3 and algorithm 2). No reference counterpart:
-Fluid ~1.5 has no recurrence over time but its RNN ops.
+"""State-space op lowerings, two recurrences and an op each:
+`selective_scan`, the selective scan of Mamba-1 (Gu and Dao,
+arXiv:2312.00752, section 3 and algorithm 2), whose decay is a number a
+channel and a lane of the state, and `ssd_scan`, Mamba-2's (Dao and Gu,
+arXiv:2405.21060, state-space duality), whose decay is one number a head
+and a token, so that a chunk is four matrix products. What picks one is
+the op the model declares (`layers.selective_scan`, `layers.ssd_scan`):
+the two share equations' form and no code, and nothing else chooses
+between them. `ssd_scan` is the second half of this file. No reference
+counterpart: Fluid ~1.5 has no recurrence over time but its RNN ops.
 
-Per channel `d` of `d_inner` and state lane `n` of `d_state`, with the
+**`selective_scan`.** Per channel `d` of `d_inner` and state lane `n` of `d_state`, with the
 state zero at the start of a row and everything float32:
 
     h_t[n, d] = exp(Delta_t[d] A[d, n]) h_{t-1}[n, d] + Delta_t[d] x_t[d] B_t[n]
@@ -78,6 +85,8 @@ minor one (`[n, d]`, 16 x 5,120: whole vector registers), which is why
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -341,4 +350,294 @@ def _selective_scan_grad_op(ctx, op):
             starts = selective_scan_with_starts(*operands)[1]
         grads = selective_scan_grads(*operands, starts, dy)
     for slot, g in zip(_SLOTS, grads):
+        ctx.out(op, f"IGRAD_{slot}", g)
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2: one decay a head, a chunk is matrix products
+# ---------------------------------------------------------------------------
+#
+# H heads of P channels, a state of N lanes a channel, G groups of H / G
+# heads that share `B` and `C` (head h reads group h // (H / G)). Per head,
+# with the state `h` [P, N] zero at the start of a row, `a` one negative
+# number a head and `Delta` one positive number a head and a token:
+#
+#     h_t = exp(Delta_t a) h_{t-1} + Delta_t x_t B_t^T
+#     y_t = h_t C_t + D x_t
+#
+# In a chunk of `c` tokens, with `S` the running sum of `Delta` from the
+# chunk's start (the exponents below are at most 0 whatever the steps),
+# `u_j = Delta_j x_j` and `h_0` the state the chunk starts from:
+#
+#     L[t, j] = exp(a (S_t - S_j))                     (j <= t, else 0)
+#     Y       = ((C B^T) * L) u + exp(a S) * (C h_0^T)
+#     h_c     = exp(a S_c) h_0 + (exp(a (S_c - S)) * u)^T B
+#
+# `C B^T` is one product a group, the three others one a head, all of them
+# batched over the chunks; only the states go from chunk to chunk, an
+# elementwise `lax.scan` over `s / c` steps of [H, P, N]. The row's
+# trajectory `[s, H, P, N]` is never in memory. The forward op's second
+# output, `Starts`, is the state each chunk starts from
+# ([s / c, b, H, P, N] float32: 16.8 MB a layer at 4,096 tokens and 16
+# heads of 64 x 128).
+#
+# The gradient op `ssd_scan_grad` reads `Starts` and runs no forward
+# again. With `M = (C B^T) * L`, `e = exp(a S)`, `w = exp(a (S_c - S))`
+# and `lam` the adjoint of the state a chunk ends in:
+#
+#     lam_0 = exp(a S_c) lam + (e * dY)^T C            (to the chunk before)
+#     du = M^T dY + w * (B lam^T)       dM = mask(dY u^T)
+#     dC = (dM * L) B + e * (dY h_0)    dB = (dM * L)^T C + (w * u) lam
+#
+# and the exponents' adjoint `r_t`, every place `a S_t` stands in:
+#
+#     r = rowsum(dM * M) - colsum(dM * M) + sum_p dY * Y_inter - q
+#         + [t = c] (sum q + exp(a S_c) <lam, h_0>),   q_j = w_j u_j . (lam B_j)
+#     da = sum_t r_t S_t     dDelta_j = a sum_{t >= j} r_t + du_j . x_j
+#     dx = du * Delta + D dY                  dD = sum dY x
+#
+# Everything is float32 arrays; the products run at the backend's default
+# precision (on a TPU a float32 product reads bf16, as in `kda_attention`).
+
+
+def _ssd_chunks(t, n_chunks, *tail):
+    """[b, n_chunks * c, prod(tail)] -> [b, n_chunks, c, *tail] float32."""
+    b = t.shape[0]
+    return t.astype(jnp.float32).reshape(b, n_chunks, -1, *tail)
+
+
+def _ssd_decays(a, delta):
+    """a: [g, r]; delta: [b, k, c, g, r]. Returns `S`, `L` as
+    [b, k, g, r, t, j] with 0 where j > t, `e = exp(a S)` and
+    `w = exp(a (S_c - S))`, both [b, k, c, g, r]."""
+    c = delta.shape[2]
+    s = jnp.cumsum(delta, axis=2)
+    heads_first = jnp.moveaxis(s, 2, -1)  # [b, k, g, r, c]
+    gap = heads_first[..., :, None] - heads_first[..., None, :]
+    admitted = np.tril(np.ones((c, c), bool))
+    lm = jnp.exp(jnp.where(admitted, a[:, :, None, None] * gap, -jnp.inf))
+    return s, lm, jnp.exp(a * s), jnp.exp(a * (s[:, :, -1:] - s))
+
+
+def _ssd_between_chunks(e, adds, reverse=False):
+    """`h <- exp(a S_c) h + add` from chunk to chunk, from zero (`reverse`:
+    from the last chunk back, the adjoint's way). e: [b, k, c, g, r];
+    adds: [k, b, g, r, p, n]. Returns what each step starts from, like
+    `adds`."""
+    last = jnp.moveaxis(e[:, :, -1], 1, 0)[..., None, None]  # [k, b, g, r, 1, 1]
+
+    def chunk(h, xs):
+        decay, add = xs
+        return decay * h + add, h
+
+    return jax.lax.scan(chunk, jnp.zeros_like(adds[0]), (last, adds),
+                        reverse=reverse)[1]
+
+
+def _ssd_fwd(x, delta, a, bm, cm):
+    """x: [b, k, c, g, r, p]; delta: [b, k, c, g, r]; a: [g, r];
+    bm, cm: [b, k, c, g, n], float32. Returns `Y` without the skip, like
+    x, and the state each chunk starts from, [k, b, g, r, p, n]."""
+    _, lm, e, w = _ssd_decays(a, delta)
+    u = x * delta[..., None]
+    starts = _ssd_between_chunks(
+        e, jnp.einsum("bkcgrp,bkcgn->kbgrpn", u * w[..., None], bm))
+    m = jnp.einsum("bktgn,bkjgn->bkgtj", cm, bm)[:, :, :, None] * lm
+    y = (jnp.einsum("bkgrtj,bkjgrp->bktgrp", m, u)
+         + e[..., None] * jnp.einsum("bktgn,kbgrpn->bktgrp", cm, starts))
+    return y, starts
+
+
+def _ssd_bwd(x, delta, a, bm, cm, starts, dy):
+    """The gradients of `_ssd_fwd`'s first output with respect to x,
+    delta, a, bm and cm, from the states the forward kept (the equations
+    stand above)."""
+    s, lm, e, w = _ssd_decays(a, delta)
+    u = x * delta[..., None]
+    lam = _ssd_between_chunks(
+        e, jnp.einsum("bktgrp,bktgn->kbgrpn", dy * e[..., None], cm),
+        reverse=True)
+    cb = jnp.einsum("bktgn,bkjgn->bkgtj", cm, bm)[:, :, :, None]
+    m = cb * lm
+    seen = jnp.einsum("bkjgn,kbgrpn->bkjgrp", bm, lam)  # B lam^T
+    du = jnp.einsum("bkgrtj,bktgrp->bkjgrp", m, dy) + w[..., None] * seen
+    dml = jnp.einsum("bktgrp,bkjgrp->bkgrtj", dy, u) * lm  # dM * L
+    dcb = jnp.sum(dml, axis=3)  # [b, k, g, t, j]
+    inter = jnp.einsum("bktgn,kbgrpn->bktgrp", cm, starts)
+    dcm = (jnp.einsum("bkgtj,bkjgn->bktgn", dcb, bm)
+           + jnp.einsum("bktgrp,kbgrpn->bktgn", dy * e[..., None], starts))
+    dbm = (jnp.einsum("bkgtj,bktgn->bkjgn", dcb, cm)
+           + jnp.einsum("bkjgrp,kbgrpn->bkjgn", u * w[..., None], lam))
+    pairs = dml * cb  # dM * M, the adjoint of a (S_t - S_j)
+    q = w * jnp.sum(u * seen, axis=-1)
+    r = (jnp.moveaxis(jnp.sum(pairs, -1) - jnp.sum(pairs, -2), -1, 2)
+         + e * jnp.sum(dy * inter, axis=-1) - q)
+    at_end = (jnp.sum(q, axis=2)
+              + e[:, :, -1] * jnp.moveaxis(
+                  jnp.sum(lam * starts, axis=(-2, -1)), 0, 1))
+    r = r.at[:, :, -1].add(at_end)
+    da = jnp.sum(r * s, axis=(0, 1, 2))
+    ddelta = (a * jnp.flip(jnp.cumsum(jnp.flip(r, 2), axis=2), 2)
+              + jnp.sum(du * x, axis=-1))
+    return du * delta[..., None], ddelta, da, dbm, dcm
+
+
+def ssd_chunk_len(s, chunk_size):
+    """Tokens a chunk: `chunk_size`, or the row where it is shorter."""
+    return min(int(chunk_size), int(s))
+
+
+def ssd_n_chunks(s, chunk_size):
+    """`Starts`' leading dimension: the chunks of a row of `s` tokens, the
+    last one filled up with steps of size 0, which change no state."""
+    return -(-int(s) // ssd_chunk_len(s, chunk_size))
+
+
+def _ssd_operands(x, delta, a, bm, cm, groups, chunk_size, *more):
+    """The operands by chunk, group and head of the group, float32, the
+    row padded to whole chunks; `more` ([b, s, H * P]) goes with x."""
+    b, s, _ = x.shape
+    heads = a.shape[0]
+    r, n = heads // groups, bm.shape[2] // groups
+    k = ssd_n_chunks(s, chunk_size)
+    pad = k * ssd_chunk_len(s, chunk_size) - s
+    if pad:
+        x, delta, bm, cm, *more = (
+            jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
+            for t in (x, delta, bm, cm, *more))
+    return (*(_ssd_chunks(t, k, groups, r, x.shape[2] // heads)
+              for t in (x, *more)),
+            _ssd_chunks(delta, k, groups, r),
+            a.astype(jnp.float32).reshape(groups, r),
+            _ssd_chunks(bm, k, groups, n), _ssd_chunks(cm, k, groups, n))
+
+
+def ssd_scan_with_starts(x, delta, a, bm, cm, dskip, groups=1,
+                         chunk_size=128):
+    """x: [b, s, H * P]; delta: [b, s, H], the steps (after their
+    softplus); a: [H], negative; bm, cm: [b, s, G * N]; dskip: [H].
+    Returns y like x and the state each chunk starts from,
+    [n_chunks, b, H, P, N] float32, which `ssd_scan_grads` takes."""
+    b, s, _ = x.shape
+    heads = a.shape[0]
+    xc, dc, ac, bc, cc = _ssd_operands(x, delta, a, bm, cm, groups,
+                                       chunk_size)
+    y, starts = _ssd_fwd(xc, dc, ac, bc, cc)
+    y = y + dskip.astype(jnp.float32).reshape(ac.shape)[..., None] * xc
+    return (y.reshape(b, -1, x.shape[2])[:, :s].astype(x.dtype),
+            starts.reshape(starts.shape[0], b, heads, *starts.shape[-2:]))
+
+
+def ssd_scan_grads(x, delta, a, bm, cm, dskip, starts, dy, groups=1,
+                   chunk_size=128):
+    """The gradients of `ssd_scan`'s output with respect to its six
+    operands, from the states `ssd_scan_with_starts` kept."""
+    b, s, _ = x.shape
+    xc, dyc, dc, ac, bc, cc = _ssd_operands(x, delta, a, bm, cm, groups,
+                                            chunk_size, dy)
+    starts = starts.reshape(starts.shape[0], b, *ac.shape, *starts.shape[-2:])
+    dx, ddelta, da, dbm, dcm = _ssd_bwd(xc, dc, ac, bc, cc, starts, dyc)
+    dsk = dskip.astype(jnp.float32).reshape(ac.shape)
+    dx = dx + dsk[..., None] * dyc
+    dd = jnp.sum(dyc * xc, axis=(0, 1, 2, 5))
+
+    def row(t, like):
+        return t.reshape(b, -1, like.shape[2])[:, :s].astype(like.dtype)
+
+    return (row(dx, x), row(ddelta, delta), da.reshape(-1).astype(a.dtype),
+            row(dbm, bm), row(dcm, cm), dd.reshape(-1).astype(dskip.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def ssd_scan(x, delta, a, bm, cm, dskip, groups=1, chunk_size=128):
+    return ssd_scan_with_starts(x, delta, a, bm, cm, dskip, groups,
+                                chunk_size)[0]
+
+
+def _ssd_scan_vjp_fwd(x, delta, a, bm, cm, dskip, groups, chunk_size):
+    y, starts = ssd_scan_with_starts(x, delta, a, bm, cm, dskip, groups,
+                                     chunk_size)
+    return y, (x, delta, a, bm, cm, dskip, starts)
+
+
+def _ssd_scan_vjp_bwd(groups, chunk_size, res, dy):
+    return ssd_scan_grads(*res, dy, groups, chunk_size)
+
+
+ssd_scan.defvjp(_ssd_scan_vjp_fwd, _ssd_scan_vjp_bwd)
+
+_SSD_SLOTS = ("X", "Dt", "DtBias", "ALog", "B", "C", "D")
+
+
+def _ssd_grad_maker(op, grad_out_names, block, helpers):
+    """`ssd_scan_grad` reads the forward op's `Starts`; a gradient into
+    `Starts` itself goes through `jax.vjp` of the lowering."""
+    if (grad_out_names.get("Y", [None])[0] is None
+            or grad_out_names.get("Starts", [None])[0] is not None):
+        return None
+    return [{
+        "type": "ssd_scan_grad",
+        "inputs": {**{slot: op.input(slot) for slot in _SSD_SLOTS},
+                   "Starts": op.output("Starts"),
+                   "GRAD_Y": [grad_out_names["Y"][0]]},
+        "outputs": {f"IGRAD_{slot}": [helpers.grad_name(op.input(slot)[0])]
+                    for slot in _SSD_SLOTS},
+        "attrs": {"n_groups": op.attr("n_groups", 1),
+                  "chunk_size": op.attr("chunk_size", 128)},
+    }]
+
+
+def _ssd_step(ctx, op):
+    """The op's operands with the step and the decay made from what the
+    model holds, float32: `Delta = softplus(Dt + DtBias)`,
+    `a = -exp(ALog)`, and `Delta`'s derivative by `Dt`."""
+    x, dt, dt_bias, a_log, bm, cm, dskip = (
+        ctx.in_(op, slot) for slot in _SSD_SLOTS)
+    raw = dt.astype(jnp.float32) + dt_bias.astype(jnp.float32)
+    return ((x, jax.nn.softplus(raw), -jnp.exp(a_log.astype(jnp.float32)),
+             bm, cm, dskip), jax.nn.sigmoid(raw))
+
+
+@register_op("ssd_scan", grad=_ssd_grad_maker)
+def _ssd_scan_op(ctx, op):
+    """X: [b, s, H * P]; Dt: [b, s, H], the step's projection before its
+    bias and softplus; DtBias, ALog, D: [H]; B, C: [b, s, G * N]. Attrs
+    `n_groups` G (head h reads group h // (H / G)) and `chunk_size`. Y
+    like X; Starts: [n_chunks, b, H, P, N] float32, for the gradient op.
+    The step, the decay and everything inside a chunk are float32,
+    whatever the AMP dtype of the inputs."""
+    operands, _ = _ssd_step(ctx, op)
+    x, a, bm = operands[0], operands[2], operands[3]
+    groups, chunk_size = op.attr("n_groups", 1), op.attr("chunk_size", 128)
+    heads = a.shape[0]
+    if heads % groups or x.shape[2] % heads or bm.shape[2] % groups:
+        raise ValueError(
+            f"ssd_scan: {heads} heads in {groups} groups over X "
+            f"{x.shape} and B {bm.shape}: the groups divide the heads and "
+            "B's width, the heads X's")
+    profiler.bump_counter("ssd_dispatch_chunked")
+    profiler.set_counter("ssd_chunk_len", ssd_chunk_len(x.shape[1],
+                                                        chunk_size))
+    profiler.set_counter("ssd_heads", int(heads))
+    profiler.set_counter("ssd_groups", int(groups))
+    profiler.set_counter("ssd_state_size", int(bm.shape[2] // groups))
+    y, starts = ssd_scan_with_starts(*operands, groups, chunk_size)
+    ctx.out(op, "Y", y)
+    ctx.out(op, "Starts", starts)
+
+
+@register_op("ssd_scan_grad", differentiable=False)
+def _ssd_scan_grad_op(ctx, op):
+    operands, dsoftplus = _ssd_step(ctx, op)
+    dx, ddelta, da, dbm, dcm, dd = ssd_scan_grads(
+        *operands, ctx.in_(op, "Starts"), ctx.in_(op, "GRAD_Y"),
+        op.attr("n_groups", 1), op.attr("chunk_size", 128))
+    dt, dt_bias, a_log = (ctx.in_(op, slot)
+                          for slot in ("Dt", "DtBias", "ALog"))
+    ddt = ddelta * dsoftplus
+    grads = (dx, ddt.astype(dt.dtype),
+             jnp.sum(ddt, axis=(0, 1)).astype(dt_bias.dtype),
+             (da * operands[2]).astype(a_log.dtype),  # a = -exp(ALog)
+             dbm, dcm, dd)
+    for slot, g in zip(_SSD_SLOTS, grads):
         ctx.out(op, f"IGRAD_{slot}", g)

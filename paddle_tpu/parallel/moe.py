@@ -14,7 +14,9 @@ load-balancing auxiliary loss is returned for the trainer to add.
 experts it holds: a router over all `experts_total` experts (sigmoid
 scores with a correction, or softmax probabilities: `score_func`) picks
 k a token, the assignments to the `experts_held` experts from `held_from`
-on are sorted by expert and run through one grouped product
+on are sorted by expert and run through one grouped product (the experts
+SiLU-gated, or with no `w_gate` `W_down relu(W_up x)^2`; reading the
+router's rows, or rows of another width given beside them)
 (`jax.lax.ragged_dot`, or where the widths and the backend allow
 `ops/pallas/grouped_matmul.py`, whose forward product and weight
 gradient are Pallas kernels that skip the dead rows of a block),
@@ -224,7 +226,8 @@ def _block_count(sizes, rows):
 def _block(j, rows, x, w_gate, w_up, w_down, token, weight, sizes, dtype,
            kernel):
     """Rows [j*rows, (j+1)*rows) of the sorted assignments through their
-    experts' SiLU-gated FFN, weighted and summed onto their tokens:
+    experts' SiLU-gated FFN (`w_gate` None: `W_down relu(W_up x)^2`, no
+    gate), weighted and summed onto their tokens:
     [N, D] float32. Rows past the held assignments carry a zero input and
     weight. With `kernel` the three products are `grouped_matmul`'s
     (ops/pallas/grouped_matmul.py), which is told the groups' true sizes
@@ -261,7 +264,8 @@ def _block(j, rows, x, w_gate, w_up, w_down, token, weight, sizes, dtype,
             return jax.lax.ragged_dot(a, w.astype(dtype), part,
                                       preferred_element_type=jnp.float32)
 
-    h = jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up)
+    h = (jnp.square(jax.nn.relu(dot(xs, w_up))) if w_gate is None
+         else jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up))
     y = dot(h.astype(dtype), w_down) * weight[:, None]
     return jnp.zeros(x.shape, jnp.float32).at[token].add(
         jnp.where(live, y, 0.0))
@@ -333,12 +337,15 @@ _overflow.defvjp(_overflow_fwd, _overflow_bwd)
 def moe_experts(x, gate, bias, w_gate, w_up, w_down, k, scaling,
                 experts_held, held_from, renormalize=True,
                 compute_dtype=None, score_func="sigmoid", kernel=False,
-                norm_eps=0.0):
+                norm_eps=0.0, experts_x=None):
     """The part of a dropless expert layer that the experts held here
     give. x: [..., D]; gate: [D, experts_total]; bias: [experts_total];
     w_gate, w_up: [experts_held, D, F]; w_down: [experts_held, F, D].
     Returns (y like x, load [experts_held] int32: assignments per held
-    expert).
+    expert). `w_gate` None: the experts are `W_down relu(W_up x)^2`, with
+    no gate. `experts_x` [..., D_e]: what the experts read where it is
+    not what the router reads (a latent of the token); the experts'
+    matrices are then D_e wide and y is like `experts_x`.
 
     The N*k assignments are sorted by held expert, those to experts held
     elsewhere last, and the sorted rows go through the grouped product
@@ -359,6 +366,9 @@ def moe_experts(x, gate, bias, w_gate, w_up, w_down, k, scaling,
         jnp.int32)
     token = (order // k).astype(jnp.int32)
     weight = jnp.where(held, weights.reshape(-1), 0.0)[order]
+    if experts_x is not None:
+        x, shape = experts_x, experts_x.shape
+        tokens = x.reshape(-1, shape[-1])
     y = _held_experts(tokens, w_gate, w_up, w_down, token, weight, load,
                       compute_dtype or tokens.dtype,
                       _block_rows(token.shape[0],

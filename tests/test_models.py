@@ -288,3 +288,62 @@ def test_qwen3_next_tiny_builds_and_trains():
     assert np.isfinite(losses).all() and losses[-1] < losses[0] - 0.5
     # 2 x 24 tokens x 2 a token over 4 of 8 experts: some are held here
     assert all(0 < int(np.sum(x)) <= 96 for x in out[0][1:])
+
+
+def test_nemotron_h_tiny_builds_and_trains():
+    """One chip's share of Nemotron-H at a tiny size: published blocks 7
+    to 11 (`*EMEM`: attention with no positions, a latent expert layer
+    with ungated experts, Mamba-2 with two of its heads in one group),
+    each block one mixer behind one norm, through `Executor.run`;
+    embedding and head are two parameters."""
+    from paddle_tpu.models import NemotronHConfig, build_nemotron_h
+
+    cfg = NemotronHConfig(
+        vocab_size=96, hidden_size=32, hybrid_override_pattern="*EMEM",
+        first_layer=7, mamba_num_heads=2, mamba_head_dim=8, mamba_n_groups=1,
+        ssm_state_size=8, mamba_chunk_size=8, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=8, moe_intermediate_size=16,
+        moe_latent_size=16, moe_shared_expert_intermediate_size=32,
+        num_experts=8, experts_held=4, num_experts_per_token=3,
+        initializer_range=0.1, rescale_prenorm_residual=False)
+    assert cfg.num_shared_experts == 2 and cfg.out_std is None
+    assert cfg.layer_kinds() == [(7, "attention"), (8, "experts"),
+                                 (9, "mamba2"), (10, "experts"),
+                                 (11, "mamba2")]
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 7
+    with fluid.program_guard(main, startup):
+        handles = build_nemotron_h(cfg, 2, 24)
+        fluid.optimizer.Adam(learning_rate=1e-2).minimize(handles["loss"])
+    assert handles["feeds"] == ["tokens", "labels"]
+    assert len(handles["loads"]) == 2
+    assert tuple(handles["logits"].shape) == (2, 24, 96)
+    ops = [op.type for op in main.global_block().ops]
+    assert ops.count("short_conv1d") == 2  # x, B and C in one call a block
+    assert ops.count("ssd_scan") == ops.count("ssd_scan_grad") == 2
+    assert ops.count("fused_multihead_attention") == 1
+    assert ops.count("moe_experts") == 2
+    assert ops.count("rms_norm") == 5 + 2 + 1  # a block, a mixer's, final
+    names = [p.name for p in main.global_block().all_parameters()]
+    assert "nemotron.embed" in names and "nemotron.head.w_0" in names
+    assert "nemotron.layer9.mamba.A_log" in names
+    assert "nemotron.layer8.latent_in.w_0" in names
+    assert not any(n.endswith("w_gate") or "q_norm" in n for n in names)
+    block = main.global_block()
+    assert tuple(block.var("nemotron.layer9.mamba.in_proj.w_0").shape) == (
+        32, 2 * 16 + 2 * 8 + 2)
+    assert tuple(block.var("nemotron.layer9.mamba.conv.w_0").shape) == (32, 4)
+    assert tuple(block.var("nemotron.layer8.moe.gate").shape) == (32, 8)
+    assert tuple(block.var("nemotron.layer8.moe.w_up").shape) == (4, 16, 16)
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        doc = np.random.RandomState(0).randint(0, 96, (2, 25))
+        feed = {"tokens": doc[:, :-1], "labels": doc[:, 1:]}
+        out = [exe.run(main, feed=feed,
+                       fetch_list=[handles["loss"]] + handles["loads"])
+               for _ in range(8)]
+    losses = [float(np.asarray(o[0]).reshape(-1)[0]) for o in out]
+    assert np.isfinite(losses).all() and losses[-1] < losses[0] - 0.5
+    # 2 x 24 tokens x 3 a token over 4 of 8 experts: some are held here
+    assert all(0 < int(np.sum(x)) <= 144 for x in out[0][1:])

@@ -262,3 +262,101 @@ def test_the_default_epsilons_jaxpr_is_the_parents():
     assert _route_digests({"norm_eps": 0.0}) == PARENTS_JAXPRS
     assert all(a != b for a, b in zip(_route_digests({"norm_eps": 1e-6}),
                                       PARENTS_JAXPRS))
+
+
+def _latent_layer(r, tokens=40, hidden=16, latent=8, width=12, total=8,
+                  held=3, held_from=2, k=3):
+    import jax.numpy as jnp
+
+    return dict(
+        x=jnp.asarray(r.randn(tokens, hidden), jnp.float32),
+        l=jnp.asarray(r.randn(tokens, latent), jnp.float32),
+        gate=jnp.asarray(r.randn(hidden, total) * 0.5, jnp.float32),
+        bias=jnp.asarray(r.randn(total) * 0.1, jnp.float32),
+        w_up=jnp.asarray(r.randn(held, latent, width) * 0.3, jnp.float32),
+        w_down=jnp.asarray(r.randn(held, width, latent) * 0.3, jnp.float32),
+        k=k, held=held, held_from=held_from)
+
+
+@pytest.mark.parametrize("rows", [None, 16])
+def test_ungated_experts_on_a_second_input_equal_a_loop_over_the_experts(
+        rows, monkeypatch):
+    """`moe_experts` with no `w_gate` and `experts_x`: the router reads x,
+    the experts the latent, an expert is `W_down relu(W_up l)^2`; value
+    and the gradients by x, the latent, the router and both matrices
+    against a loop over the held experts with a mask, in one block and
+    (`rows` 16) through the overflow loops."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.parallel import moe
+
+    if rows:
+        monkeypatch.setattr(moe, "_block_rows", lambda total, share: rows)
+    c = _latent_layer(np.random.RandomState(11))
+
+    def layer(x, l, gate, w_up, w_down):
+        return moe.moe_experts(x, gate, c["bias"], None, w_up, w_down,
+                               k=c["k"], scaling=5.0, experts_held=c["held"],
+                               held_from=c["held_from"], experts_x=l)
+
+    def loop(x, l, gate, w_up, w_down):
+        scores = jax.nn.sigmoid(x @ gate)
+        _, chosen = jax.lax.top_k(scores + c["bias"], c["k"])
+        w = jnp.take_along_axis(scores, chosen, -1)
+        w = 5.0 * w / jnp.sum(w, -1, keepdims=True)
+        y = 0.0
+        for e in range(c["held"]):
+            here = jnp.sum(jnp.where(chosen == c["held_from"] + e, w, 0.0), -1)
+            y = y + here[:, None] * (
+                jnp.square(jax.nn.relu(l @ w_up[e])) @ w_down[e])
+        return y
+
+    args = (c["x"], c["l"], c["gate"], c["w_up"], c["w_down"])
+    with jax.default_matmul_precision("highest"):
+        (y, load), want = layer(*args), loop(*args)
+        assert y.shape == c["l"].shape and int(load.sum()) > (rows or 0)
+        assert rel(y, want) < 1e-5
+        cot = jnp.asarray(np.random.RandomState(1).randn(*y.shape), jnp.float32)
+        grads = [jax.grad(lambda *a: jnp.sum(fn(*a) * cot), argnums=range(5))(
+            *args) for fn in (lambda *a: layer(*a)[0], loop)]
+    for name, g, g_want in zip(("x", "latent", "gate", "w_up", "w_down"),
+                               *grads):
+        assert rel(g, g_want) < 1e-5, name
+
+
+def test_the_op_refuses_matrices_that_do_not_take_their_inputs():
+    """The message names the shapes: the router's matrix against the
+    router's input, `WUp` against the experts' input (a parameter of
+    another shape put into the scope behind the Program's back)."""
+    import paddle_tpu as fluid
+
+    L = fluid.layers
+    for param, shape, match in (
+            ("e.w_up", (2, 12, 8),
+             r"WUp \(2, 12, 8\) does not take the experts' input "
+             r"\(4, 6, 16\) \(the router's X is \(4, 6, 32\)\)"),
+            ("e.gate", (16, 8),
+             r"Gate \(16, 8\) does not take the router's input X "
+             r"\(4, 6, 32\)")):
+        main = fluid.Program()
+        with fluid.program_guard(main, fluid.Program()), \
+                fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
+            x = L.data("x", [4, 6, 32], append_batch_size=False)
+            latent = L.data("l", [4, 6, 16], append_batch_size=False)
+            y, _ = L.moe_experts(
+                x, experts_total=8, experts_held=2, d_ff=8, k=2,
+                experts_input=latent, expert_form="relu2",
+                param_attr=fluid.ParamAttr(name="e"))
+            assert tuple(y.shape) == (4, 6, 16)
+            exe = fluid.Executor(fluid.CPUPlace())
+            exe.run(fluid.default_startup_program())
+            fluid.global_scope().set(param, np.zeros(shape, np.float32))
+            with pytest.raises(ValueError, match=match):
+                exe.run(main, feed={"x": np.zeros((4, 6, 32), np.float32),
+                                    "l": np.zeros((4, 6, 16), np.float32)},
+                        fetch_list=[y])
+    with pytest.raises(ValueError, match="expert_form"):
+        L.moe_experts(L.data("x", [4, 16], append_batch_size=False),
+                      experts_total=8, experts_held=2, d_ff=8, k=2,
+                      expert_form="gelu")

@@ -12,7 +12,16 @@ the kernels' body (the solve in lockstep over the chunks of a grid
 step: `ops/pallas/kda_chunk.py`) and re-took that digest on its finished
 tree; its results are the parent's bit for bit
 (`tests/test_kda_kernel.py`), its jaxpr is not. `kda-chunked` and the
-ten others are PR 51's parent's."""
+ten others are PR 51's parent's.
+
+PR 53 gave `moe_experts` a second input and an expert form, `attention`
+a switch for the QK-norm, `proj` a deviation of its own and
+`ssm_ops.py` a second op, all off by default: the six cases from
+`experts-gmm` on are taken against PR 53's parent (commit 55ba533) the
+same way: the expert layer through the grouped kernels, with the
+renormalisation's epsilon (LFM2's) and with the gated shared expert
+(Qwen3-Next's), Phi-4's Mamba-1 mixer on both of its paths, and the
+convolution with bias and SiLU as the Mamba mixers call it."""
 
 import os
 import sys
@@ -79,6 +88,41 @@ def expert_case(shared):
     return _loss(expert_ffn(_data(24), cfg, "e")[0])
 
 
+def expert_case_53(which):
+    """`expert_ffn` as the six expert cells call it beyond the two cases
+    above: at widths the grouped kernels take, with an epsilon in the
+    renormalisation, and with the token's gate on the shared expert."""
+    from paddle_tpu.models.decoder_parts import expert_ffn
+
+    cfg = SimpleNamespace(
+        num_experts=8, experts_held=4, held_from=2,
+        moe_intermediate_size=128 if which == "gmm" else 32,
+        num_experts_per_token=2, routed_scaling_factor=2.0,
+        moe_renormalize=True, router_bias_scale=0.1,
+        score_func="softmax" if which == "shared_gate" else "sigmoid",
+        num_shared_experts=1, shared_expert_gate=which == "shared_gate",
+        **BASE)
+    return _loss(expert_ffn(_data(24), cfg, "e",
+                            norm_eps=1e-20 if which == "norm_eps" else 0.0)[0])
+
+
+def mamba1_case():
+    """Phi-4's Mamba-1 mixer (`selective_scan`, the convolution with its
+    bias): 128 channels, the kernels under the interpreter."""
+    from paddle_tpu.models.phi4_flash import Phi4FlashConfig, mamba
+
+    cfg = Phi4FlashConfig(hidden_size=64, mamba_expand=2, mamba_d_state=16,
+                          mamba_d_conv=4)
+    return _loss(mamba(_data(96, 64), cfg, "m")[0])
+
+
+def conv_case():
+    from paddle_tpu import ParamAttr, layers
+
+    return _loss(layers.short_conv1d(_data(96, 128), 4,
+                                     bias_attr=ParamAttr(name="c.b_0")))
+
+
 CASES = {
     "kda-chunked": (kda_case, False),
     "kda-kernels": (kda_case, True),
@@ -95,6 +139,12 @@ CASES = {
     "lfm2-flash": (lambda: attention_case("lfm2", 2048), True),
     "experts-shared": (lambda: expert_case(1), False),
     "experts-alone": (lambda: expert_case(0), False),
+    "experts-gmm": (lambda: expert_case_53("gmm"), True),
+    "experts-norm_eps": (lambda: expert_case_53("norm_eps"), False),
+    "experts-shared_gate": (lambda: expert_case_53("shared_gate"), False),
+    "mamba1-chunked": (mamba1_case, False),
+    "mamba1-kernels": (mamba1_case, True),
+    "short_conv-bias": (conv_case, False),
 }
 
 # as PR 51's parent (commit 6f8ecfe) traces them
@@ -111,6 +161,13 @@ PARENTS_JAXPRS = {
     "lfm2-flash": "d98bb2942185766e",
     "experts-shared": "0c34ee51cfcbcb61",
     "experts-alone": "eb3b9327f05919f0",
+    # as PR 53's parent (commit 55ba533) traces them
+    "experts-gmm": "884e05046e67ab0d",
+    "experts-norm_eps": "f560fc572924fc52",
+    "experts-shared_gate": "cacf6e6fdb8b2493",
+    "mamba1-chunked": "95497628e7a473b7",
+    "mamba1-kernels": "9013a4410a37b2fa",
+    "short_conv-bias": "60ee9c916a5b65eb",
 }
 
 
