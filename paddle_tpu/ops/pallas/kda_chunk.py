@@ -11,9 +11,33 @@ chunks of one head, `[rows, 128]` blocks cut from the `[b, s, h*128]`
 arrays the op's inputs arrive in (no relayout on either side of the
 call), and the state `S^T` `[dv, dk]` float32 in a VMEM scratch that
 lives across the sequential chunk axis of the grid and is zeroed at
-chunk 0. What a chunk computes from no state (`_state_free`) is stated
-for all the step's chunks together, the solve product by product across
-them (`_inverse`); what starts from the state follows chunk by chunk.
+chunk 0.
+
+A grid step is stated in three parts. **What is a row's** (`_rows`): the
+cumulative log-decay `G` (no sum crosses a chunk), `exp(G)`,
+`exp(G_C - G)`, `exp(G_C)`, each level's `e`, `k*e` and `q*e`, the blocks
+of four's decays, rotated keys and lane sums, `Aq`'s diagonal, `beta v`,
+`beta k exp(G)`, `q exp(G)`, `k exp(G_C - G)`, once over the step's blocks
+whole, `[steps*64, 128]` (beta's and a head's decay's column taken out of
+their `[steps*64, h]` blocks once): one equation for any width, and a
+row's arithmetic is what a chunk alone would do, so the bits are. **What
+is a chunk's own from no state** (`_state_free`): it cuts its 64 rows of
+those (`_chunk_rows`, where `k*e` and `q*e` are cast to what a product
+reads: cast on the stacked rows they cost the chain four cycles) and
+states the four levels' `[64, 64]` products and their `where`s, the solve,
+product by product across the step's chunks (`_inverse`), `Aq` and `W`.
+The `[64, 64]` masks are made once a kernel (`_pair_masks`). **What starts
+from the state** follows chunk by chunk, copies of the chunk's code
+(`_chunk_fwd`, `_chunk_bwd`: the order of the products with the state is
+the order of the chunks, and hoisting them across chunks was slower); what
+follows the backward sweep's last product and is sums alone (dG's last
+row, the lanes' sum, the reversed cumulative sum, the casts and stores)
+runs once over the stacked rows again (`_sweep_tail`). The host lowers
+every equation of the body at every start of a job, compile cache or not:
+a further chunk a step adds 127 equations forward and 311 backward (317
+with a decay a head) where a whole copy added 344 and 609
+(`tools/kda_vreg_count.py`; `tests/test_kda_kernel.py` holds them to 230
+and 500).
 
 Per chunk, all in VMEM:
 
@@ -102,18 +126,22 @@ from .flash_attention import LANE, _interpret, _use_pallas, require_pallas
 CHUNK = 64
 LEAF = 4  # rows of the blocks whose pairs `_leaf_pairs` forms directly
 TILE = 8  # sublanes of a float32 register: a rotation stays inside one
-# Chunks a grid step, each a copy of the chunk's code in the kernel, and
-# the width of the lockstep (`_state_free`, `_inverse`): the ten dependent
-# products of a solve wait 125 to 131 cycles each from push to pop, and
-# the step's chunks share those waits. Four measured the pair at 16.6 ms
-# a step where two have 21.6 in Kimi's cell (14.0 against 17.3 in
-# Qwen3-Next's), 3.1 and 2.4% more documents a second, and 1.5 s more of
-# host lowering at every start of a job (`step_lower_s` 3.6 -> 5.2 and
-# 3.9 -> 5.4 s: the host lowers every copy's equations, 360 forward and
-# 620 backward a chunk), `setup_s` +3.4% and +7.3% where this constant is
-# held to 5% in both cells: two (PERF.md, PR 52, which also says what
-# would make the lowering independent of the width).
-CHUNKS_PER_STEP = 2
+# Chunks a grid step, and the width of the lockstep (`_inverse`): the ten
+# dependent products of a solve wait 125 to 131 cycles each from push to
+# pop, and the step's chunks share those waits. Four where PR 52 had two:
+# the pair 16.66 ms a step where two have 21.62 in Kimi's cell and 13.19
+# where 17.34 in Qwen3-Next's, 3.0% more documents a second in both (five
+# pairs a cell, PERF.md, PR 54), the results the same to the last bit.
+# What a wider step costs is the host, which lowers every equation of the
+# body at every start of a job: with the rows' arithmetic stated once
+# (`_rows`) a further chunk is 127 equations forward and 311 backward
+# where a whole copy was 344 and 609, the backward kernel's lowering read
+# 1.20 to 1.24 s at two and 1.45 to 1.50 at four on the chip's host (the
+# plain four 2.25), and warm `setup_s` +2.3% and +1.9% (medians of five;
+# `step_lower_s` +1.1 and +0.8 s by one launch, +0.3 by another: it
+# spreads by 0.4 s from run to run). Eight measured 14.46 ms and 1.4% more
+# in Kimi's cell for `step_lower_s` 3.7 -> 7.9 s and `setup_s` +13%: four.
+CHUNKS_PER_STEP = 4
 
 _NN = ((1,), (0,))  # [m, k] x [k, n]
 _NT = ((1,), (1,))  # [m, k] x [n, k]
@@ -157,20 +185,23 @@ def _iota(shape, axis):
 
 
 def _cumsum(x, reverse=False):
-    """Along the chunk's rows, inclusive, in float32: log2(C) shifted
-    adds on the sublanes (`reverse`: from the last row back, which is the
-    sum's transpose)."""
-    c = x.shape[0]
-    row = _iota(x.shape, 0)
+    """Along the rows of each chunk of `x` (whole chunks stacked, or one),
+    inclusive, in float32: log2(C) shifted adds on the sublanes
+    (`reverse`: from a chunk's last row back, which is the sum's
+    transpose). The rows turn inside their own chunk, `[n, C, d]`, so no
+    sum crosses from one chunk into the next."""
+    rows, d = x.shape
+    x = x.reshape(rows // CHUNK, CHUNK, d)
+    row = _iota(x.shape, 1)
     shift = 1
-    while shift < c:
+    while shift < CHUNK:
         if reverse:  # x_i += x_(i + shift)
-            keep, by = row < c - shift, c - shift
+            keep, by = row < CHUNK - shift, CHUNK - shift
         else:  # x_i += x_(i - shift)
             keep, by = row >= shift, shift
-        x = x + jnp.where(keep, pltpu.roll(x, by, 0), 0.0)
+        x = x + jnp.where(keep, pltpu.roll(x, by, 1), 0.0)
         shift *= 2
-    return x
+    return x.reshape(rows, d)
 
 
 def _block_reference(G, m):
@@ -188,17 +219,34 @@ def _levels(c):
     return tuple(c >> n for n in range((c // LEAF).bit_length() - 1))
 
 
+# Which pairs (i, j) of a chunk's [C, C] are whose: a level's, a distance's
+# inside a block of LEAF rows, the diagonal, j < i, j <= i, j >= i; the
+# levels' and the distances' masks twice over, [2C, C], for `_scores_grad`;
+# and what the pairs that are nobody's read, zeros [C, C] and [2C, C] (an
+# array made once where every `where` would broadcast its own scalar).
+_Masks = collections.namedtuple(
+    "_Masks",
+    "levels leaves diagonal below lower upper levels2 leaves2 zero zero2")
+
+
 def _pair_masks(c):
-    """Which pairs (i, j) of a [C, C] each step of `_scores` forms, from
-    i ^ j: its highest bit says at which level the pair is split, m / 2
-    <= i ^ j < m for blocks of m rows, and under LEAF both rows lie in
-    one block of LEAF rows, i - j apart. Made once a chunk (the host pays
-    for every jnp call of the kernel's body at each start of a job)."""
+    """The `_Masks` of a chunk of `c` rows, from i ^ j: its highest bit
+    says at which level the pair is split, m / 2 <= i ^ j < m for blocks
+    of m rows, and under LEAF both rows lie in one block of LEAF rows,
+    i - j apart. Made once a kernel (the host pays for every jnp call of
+    the kernel's body at each start of a job)."""
     row, col = _iota((c, c), 0), _iota((c, c), 1)
     level = row ^ col
     levels = tuple(level >> (m.bit_length() - 2) == 1 for m in _levels(c))
     leaves = tuple((level < LEAF) & (row - col == o) for o in range(1, LEAF))
-    return levels, leaves, row == col, row > col
+
+    def twice(masks):
+        return tuple(jnp.concatenate([m, m], axis=0) for m in masks)
+
+    diagonal, below = row == col, row > col
+    return _Masks(levels, leaves, diagonal, below, below | diagonal, ~below,
+                  twice(levels), twice(leaves), jnp.zeros((c, c), jnp.float32),
+                  jnp.zeros((2 * c, c), jnp.float32))
 
 
 def _leaf_pairs(k, G):
@@ -211,96 +259,151 @@ def _leaf_pairs(k, G):
     tiles = (c // TILE, TILE, dk)
     G, k = G.reshape(tiles), k.reshape(tiles)
     place = _iota(tiles, 1) & (LEAF - 1)
-    leaves = ()
+    decays, earlier = (), ()
     for o in range(1, LEAF):
         decay = jnp.exp(jnp.where(place >= o, G - pltpu.roll(G, o, 1),
                                   -jnp.inf))
-        leaves += ((decay.reshape(c, dk),
-                    (pltpu.roll(k, o, 1) * decay).reshape(c, dk)),)
-    return leaves
+        decays += (decay.reshape(c, dk),)
+        earlier += ((pltpu.roll(k, o, 1) * decay).reshape(c, dk),)
+    return decays, earlier
 
 
-def _scores(k, G, dtype):
-    """A (j < i) of one chunk, [C, C], and what `_query_scores` and the
-    backward use again: the pairs' masks, a level's `e` and `k*e` as the
-    product read it, and `_leaf_pairs`' arrays.
+# What is a row's own, or its chunk's earlier rows', and no product's: the
+# grid step's rows stacked [steps*C, ...], computed once for all its chunks
+# (`_rows`). beta [R, 1]; a level's k*e and q*e, float32 until a chunk cuts
+# its rows (`_chunk_rows`); the lane sums of the pairs inside the blocks of LEAF rows, k_i.k_j (`kk`) and
+# q_i.k_j (`qk`) a distance, and Aq's diagonal q_i.k_i (`diag`), [R, 1];
+# [beta v, beta k exp(G)] side by side; q exp(G), k exp(G), k exp(G_C - G);
+# exp(G_C) a chunk, [steps, 1, dk]. `back`: what the backward alone reads.
+_Rows = collections.namedtuple(
+    "_Rows", "beta ke qe kk qk diag bvk qE kE k_end decay_end back")
+_Back = collections.namedtuple("_Back", "q k v E e_end e decay kj")
 
-    Level m = C, C/2, ..., 2 * LEAF gives the pairs whose rows lie in
-    the two halves of one block of m rows, i in the upper and j in the
-    lower: with `ref` the lower half's last row, exp(G_i - G_j) =
+
+@jax.jit
+def _rows(q, k, v, g, beta):
+    """The `_Rows` of a grid step's rows, whole chunks stacked `[R, 128]`
+    float32 (beta `[R, 1]`). One equation for any number of chunks where a
+    chunk's copy of the code would state its own: the host lowers this
+    once a kernel. A row's arithmetic is what a chunk alone would do, so
+    are the bits.
+
+    Level m = C, C/2, ..., 2 * LEAF of `_scores` gives the pairs whose
+    rows lie in the two halves of one block of m rows, i in the upper and
+    j in the lower: with `ref` the lower half's last row, exp(G_i - G_j) =
     exp(G_i - G_ref) exp(G_ref - G_j), both exponents <= 0 as G does not
     rise along the rows. A row has one role a level, so one `e` =
     exp(-|G - G_ref|) serves all rows and one product (k*e) (k*e)^T
-    all blocks; what it holds outside "same block, i upper, j lower" is
-    masked away. The levels' pairs are disjoint, and with the pairs
-    inside the blocks of LEAF rows they are all j < i."""
-    c, dk = k.shape
-    masks = at_level, at_leaf, _, below = _pair_masks(c)
-    upper = _iota((c, dk), 0)
-    A, levels = 0.0, ()
-    for m, here in zip(_levels(c), at_level):
+    all blocks."""
+    rows, dk = g.shape
+    by_chunk = (rows // CHUNK, CHUNK, dk)
+    G = _cumsum(g)
+    G_end = G.reshape(by_chunk)[:, -1:]  # a chunk's last row
+    E = jnp.exp(G)
+    e_end = jnp.exp(G_end - G.reshape(by_chunk)).reshape(rows, dk)
+    upper = _iota((rows, dk), 0)
+    e, ke, qe = (), (), ()
+    for m in _levels(CHUNK):
         d = G - _block_reference(G, m)
-        e = jnp.exp(jnp.where(upper & (m // 2) != 0, d, -d))
-        ke = (k * e).astype(dtype)
-        A = jnp.where(here, _mm(ke, ke, _NT, dtype), A)
-        levels += ((here, e, ke),)
-    leaves = _leaf_pairs(k, G)
-    for here, (_, kj) in zip(at_leaf, leaves):
-        A = jnp.where(here, jnp.sum(k * kj, 1, keepdims=True), A)
-    return jnp.where(below, A, 0.0), (masks, levels, leaves)
+        e += (jnp.exp(jnp.where(upper & (m // 2) != 0, d, -d)),)
+        ke += (k * e[-1],)
+        qe += (q * e[-1],)
+    decay, kj = _leaf_pairs(k, G)
+
+    def lanes(x):
+        return jnp.sum(x, 1, keepdims=True)
+
+    kE = k * E
+    return _Rows(
+        beta, ke, qe, tuple(lanes(k * x) for x in kj),
+        tuple(lanes(q * x) for x in kj), lanes(q * k),
+        jnp.concatenate([beta * v, beta * kE], axis=1), q * E, kE, k * e_end,
+        jnp.exp(G_end), _Back(q, k, v, E, e_end, e, decay, kj))
 
 
-def _query_scores(q, k, kept, dtype, transposed=False):
+def _cut(x, t):
+    """Chunk `t`'s 64 rows of a grid step's stacked `x`: whole tiles."""
+    return jax.lax.slice_in_dim(x, t * CHUNK, (t + 1) * CHUNK)
+
+
+def _chunk_rows(rows, t, dtype, backward=False):
+    """Chunk `t`'s 64 rows of the step's `_Rows` (and of `back` for the
+    backward). The levels' k*e and q*e become what a product reads here,
+    a chunk at a time: cast on the stacked rows and then cut, each
+    register was packed alone and packed again in pairs in front of the
+    first product (four cycles of the chain)."""
+    at = functools.partial(_cut, t=t)
+    chunk = jax.tree.map(at, rows._replace(decay_end=None, back=None))
+    return chunk._replace(
+        ke=tuple(x.astype(dtype) for x in chunk.ke),
+        qe=tuple(x.astype(dtype) for x in chunk.qe),
+        decay_end=rows.decay_end[t],
+        back=jax.tree.map(at, rows.back) if backward else None)
+
+
+@functools.partial(jax.jit, static_argnames=("dtype",))
+def _scores(ke, kk, masks, *, dtype):
+    """A (j < i) of one chunk, [C, C]: a level's pairs from the product
+    (k*e) (k*e)^T, masked to "same block of m rows, i upper, j lower"
+    (what the product holds outside them is masked away); the pairs
+    inside the blocks of LEAF rows from their lane sums. The levels' pairs
+    are disjoint, and with those they are all j < i. (Jitted, as every
+    part of a chunk that the kernels state once a chunk, so that the
+    copies are traced once: the host pays for every jnp call at each start
+    of a job, compile cache or not.)"""
+    A = masks.zero
+    for here, x in zip(masks.levels, ke):
+        A = jnp.where(here, _mm(x, x, _NT, dtype), A)
+    for here, pairs in zip(masks.leaves, kk):
+        A = jnp.where(here, pairs, A)
+    return jnp.where(masks.below, A, masks.zero)
+
+
+def _query_scores(chunk, masks, dtype, transposed=False):
     """Aq (j <= i) as `_scores` forms A, with q for the rows, or its
     transpose (the levels' products the other way round: the backward
-    reads Aq as Aq^T alone), and the levels' [k*e; q*e] for the
-    backward. Apart from `_scores`, because nothing before U reads Aq:
-    its products queue behind the solve's."""
-    masks, levels, leaves = kept
-    _, at_leaf, diagonal, below = masks
-    Aq = jnp.where(diagonal, jnp.sum(q * k, 1, keepdims=True), 0.0)  # j = i
-    for here, (_, kj) in zip(at_leaf, leaves):
-        Aq = jnp.where(here, jnp.sum(q * kj, 1, keepdims=True), Aq)
+    reads Aq as Aq^T alone). Apart from `_scores`, because nothing before
+    U reads Aq: its products queue behind the solve's."""
+    Aq = jnp.where(masks.diagonal, chunk.diag, masks.zero)  # j = i
+    for here, pairs in zip(masks.leaves, chunk.qk):
+        Aq = jnp.where(here, pairs, Aq)
     if transposed:  # the levels' masks are their own transposes
         Aq = Aq.T
-    levels_q = ()
-    for here, e, ke in levels:
-        qe = (q * e).astype(dtype)
+    for here, ke, qe in zip(masks.levels, chunk.ke, chunk.qe):
         pairs = _mm(ke, qe, _NT, dtype) if transposed else _mm(qe, ke, _NT,
                                                                dtype)
         Aq = jnp.where(here, pairs, Aq)
-        levels_q += ((here, e, jnp.concatenate([ke, qe], axis=0)),)
     # a level's product holds the pairs i < j too
-    return (jnp.where(~below if transposed else below | diagonal, Aq, 0.0),
-            (masks, levels_q, leaves))
+    return jnp.where(masks.upper if transposed else masks.lower, Aq,
+                     masks.zero)
 
 
-def _scores_grad(dA, dAq, q, k, kept, dtype):
-    """The gradients of `_scores` and `_query_scores`, from what they
-    `kept`: of q (`dq`), of k as the row operand of A (`dk_row`) and of k
-    as the column operand of both (`dk_col`). dG's share is q*dq +
-    k*dk_row - k*dk_col. `dA` is 0 from the diagonal up and `dAq` above
-    it."""
+def _scores_grad(dA, dAq, chunk, masks, dtype):
+    """The gradients of `_scores` and `_query_scores`: of q (`dq`), of k
+    as the row operand of A (`dk_row`) and of k as the column operand of
+    both (`dk_col`). dG's share is q*dq + k*dk_row - k*dk_col. `dA` is 0
+    from the diagonal up and `dAq` above it."""
+    q, k = chunk.back.q, chunk.back.k
     c, dk = q.shape
-    (_, at_leaf, diagonal, _), levels, leaves = kept
-    d_both = jnp.concatenate([dA, dAq], axis=0)
-
-    def both(mask):  # of dA and of dAq, [2C, C]
-        return jnp.where(jnp.concatenate([mask, mask], axis=0), d_both, 0.0)
-
+    d_both = jnp.concatenate([dA, dAq], axis=0)  # [2C, C]
     # Aq's diagonal carries no decay: its k goes with the columns', so
     # that dG's share of it is q*k - k*q
-    on_diagonal = jnp.sum(jnp.where(diagonal, dAq, 0.0), 1, keepdims=True)
+    on_diagonal = jnp.sum(jnp.where(masks.diagonal, dAq, masks.zero), 1,
+                          keepdims=True)
     dq, dk_row, dk_col = on_diagonal * k, 0.0, on_diagonal * q
-    for here, e, x in levels:
-        d = both(here)
-        d_in = _mm(d, x[:c], _NN, dtype)  # to the rows i of the upper halves
+    for here, e, ke, qe in zip(masks.levels2, chunk.back.e, chunk.ke,
+                               chunk.qe):
+        d = jnp.where(here, d_both, masks.zero2).astype(dtype)  # read twice
+        d_in = _mm(d, ke, _NN, dtype)  # to the rows i of the upper halves
         dk_row = dk_row + d_in[:c] * e
         dq = dq + d_in[c:] * e
-        dk_col = dk_col + _mm(d, x, _TN, dtype) * e  # to the lower halves' j
+        # to the lower halves' j
+        dk_col = dk_col + _mm(d, jnp.concatenate([ke, qe], axis=0), _TN,
+                              dtype) * e
     tiles = (c // TILE, TILE, dk)
-    for o, (here, (decay, kj)) in enumerate(zip(at_leaf, leaves), 1):
-        d = jnp.sum(both(here), 1, keepdims=True)
+    for o, (here, decay, kj) in enumerate(zip(
+            masks.leaves2, chunk.back.decay, chunk.back.kj), 1):
+        d = jnp.sum(jnp.where(here, d_both, masks.zero2), 1, keepdims=True)
         da, daq = d[:c], d[c:]
         dk_row = dk_row + da * kj
         dq = dq + daq * kj
@@ -334,148 +437,149 @@ def _inverse(Ns, dtype):
     def same_block(bits):  # blocks of 2**bits rows
         return (r >> bits) == (l >> bits)
 
-    eye = (r == l).astype(jnp.float32)
-    invs = [eye - jnp.where(same_block(1), N, 0.0) for N in Ns]
+    eye, pairs = (r == l).astype(jnp.float32), same_block(1)
+    zero = jnp.zeros((c, c), jnp.float32)
+    invs = [eye - jnp.where(pairs, N, zero) for N in Ns]
     for bits in range(1, c.bit_length() - 1):
         inside = same_block(bits + 1) & ~same_block(bits)
-        TL = [_mm(inv, jnp.where(inside, N, 0.0), _NN, dtype)
-              for inv, N in zip(invs, Ns)]
-        invs = [inv - _mm(tl, inv, _NN, dtype) for tl, inv in zip(TL, invs)]
+        read = [inv.astype(dtype) for inv in invs]  # by both products
+        TL = [_mm(inv, jnp.where(inside, N, zero), _NN, dtype)
+              for inv, N in zip(read, Ns)]
+        invs = [inv - _mm(tl, as_read, _NN, dtype)
+                for tl, inv, as_read in zip(TL, invs, read)]
     return invs
 
 
-@functools.partial(jax.jit, static_argnames=("dtype",))
-def _before_solve(k, g, *, dtype):
-    """What a chunk computes ahead of its solve: from the cumulative
-    log-decay G the decays exp(G), exp(G_C - G) <= 1 and exp(G_C), A and
-    what `_scores` kept. (Jitted, as every part of a chunk that the
-    kernels state once a chunk, so that the copies are traced once: the
-    host pays for every jnp call at each start of a job, compile cache or
-    not.)"""
-    G = _cumsum(g)
-    A, kept = _scores(k, G, dtype)
-    return (jnp.exp(G), jnp.exp(G[-1:] - G), jnp.exp(G[-1:])), A, kept
-
-
-# What a chunk computes from no state: the decays exp(G), exp(G_C - G) and
-# exp(G_C), A, Aq or its transpose, what `_query_scores` kept, T and the
-# WY factors [Wv, Wk].
-_StateFree = collections.namedtuple("_StateFree", "decays A Aq kept T W")
+# What a chunk computes from no state beside its rows of `_Rows`: A, Aq or
+# its transpose, T and the WY factors [Wv, Wk].
+_StateFree = collections.namedtuple("_StateFree", "rows A Aq T W")
 
 
 @functools.partial(jax.jit, static_argnames=("dtype", "transposed_aq"))
-def _after_solve(q, k, v, beta, E, T, kept, *, dtype, transposed_aq):
-    """Aq (or its transpose) with what `_query_scores` kept, and the WY
-    factors [Wv, Wk] from the chunk's `T`."""
-    Aq, kept = _query_scores(q, k, kept, dtype, transposed_aq)
-    W = _mm(T, jnp.concatenate([beta * v, beta * (k * E)], axis=1), _NN,
-            dtype)
-    return Aq, kept, W
+def _after_solve(chunk, T, masks, *, dtype, transposed_aq):
+    """Aq (or its transpose) and the WY factors [Wv, Wk] from the chunk's
+    `T`."""
+    return (_query_scores(chunk, masks, dtype, transposed_aq),
+            _mm(T, chunk.bvk, _NN, dtype))
 
 
-def _state_free(chunks, dtype, transposed_aq=False):
-    """A `_StateFree` for each chunk (q, k, v, g, beta) of a grid step:
-    all of them up to their N = beta A, then the solves in lockstep
-    (`_inverse`), then each chunk's Aq, behind the solve's products where
-    nothing waits for it, and W."""
-    before = [_before_solve(k, g, dtype=dtype) for _, k, _, g, _ in chunks]
-    Ts = _inverse([beta * A for (*_, beta), (_, A, _) in zip(chunks, before)],
-                  dtype)
+def _state_free(rows, masks, dtype, backward=False):
+    """A `_StateFree` for each chunk of a grid step's `_Rows`: all of
+    them up to their N = beta A, then the solves in lockstep (`_inverse`),
+    then each chunk's Aq (transposed for the backward), behind the solve's
+    products where nothing waits for it, and W."""
+    chunks = [_chunk_rows(rows, t, dtype, backward)
+              for t in range(rows.beta.shape[0] // CHUNK)]
+    As = [_scores(c.ke, c.kk, masks, dtype=dtype) for c in chunks]
+    Ts = _inverse([c.beta * A for c, A in zip(chunks, As)], dtype)
     free = []
-    for (q, k, v, _, beta), (decays, A, kept), T in zip(chunks, before, Ts):
-        Aq, kept, W = _after_solve(q, k, v, beta, decays[0], T, kept,
-                                   dtype=dtype, transposed_aq=transposed_aq)
-        free.append(_StateFree(decays, A, Aq, kept, T, W))
+    for chunk, A, T in zip(chunks, As, Ts):
+        Aq, W = _after_solve(chunk, T, masks, dtype=dtype,
+                             transposed_aq=backward)
+        free.append(_StateFree(chunk, A, Aq, T, W))
     return free
 
 
 @functools.partial(jax.jit, static_argnames=("dtype",))
-def _chunk_fwd(q, k, free, St, *, dtype):
+def _chunk_fwd(free, St, *, dtype):
     """One chunk from the state it starts with, `St` = S^T [dv, dk], and
     its state-free half: its outputs [C, dv] and the state it leaves."""
-    (E, e_end, decay_end), _, Aq, _, _, W = free
-    c, dv = q.shape[0], W.shape[1] - q.shape[1]
+    chunk, _, Aq, _, W = free
+    c, dk = chunk.qE.shape
+    dv = W.shape[1] - dk
     # [Wk; Q exp(G)] S in one product
-    by_state = _mm(jnp.concatenate([W[:, dv:], q * E], axis=0), St, _NT,
+    by_state = _mm(jnp.concatenate([W[:, dv:], chunk.qE], axis=0), St, _NT,
                    dtype)
     U = W[:, :dv] - by_state[:c]
-    o = q.shape[1] ** -0.5 * (by_state[c:] + _mm(Aq, U, _NN, dtype))
-    return o, St * decay_end + _mm(U, k * e_end, _TN, dtype)
+    o = dk ** -0.5 * (by_state[c:] + _mm(Aq, U, _NN, dtype))
+    return o, St * chunk.decay_end + _mm(U, chunk.k_end, _TN, dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("dtype", "per_head"))
-def _chunk_bwd(q, k, v, beta, free, St, dSt, dO, *, dtype, per_head=False):
+def _as_row(column, masks):
+    """A chunk's column [C, 1] as a row [1, C], for a lane-dense store."""
+    return jnp.sum(jnp.where(masks.diagonal, column, masks.zero), 0,
+                   keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("dtype",))
+def _chunk_bwd(free, masks, St, dSt, dO, *, dtype):
     """One chunk of the reverse sweep: from its state-free half (Aq
     transposed), the state the chunk started with, the gradient `dSt` of
-    the state it left and of its outputs, the gradients of q, k, v, g, of
-    beta (as a row [1, C]) and of the state it started with. `per_head`:
-    `g` is one decay a row written along the lanes, and its gradient the
-    sum over them, as a row."""
+    the state it left and of its outputs (scaled by dk^-1/2 already):
+    (the gradients of q, k and v, that of G short of its last row's share,
+    and that share, the gradient of exp(G_C), [1, 1, dk]: `_sweep_tail`
+    makes g's from the two), beta's gradient as a row [1, C], and the
+    gradient of the state the chunk started with. Every product of a
+    multiply and an add is stated here, a chunk at a time, as the plain
+    chunk states it: only sums are left to the step's tail."""
     mm = functools.partial(_mm, dtype=dtype)
-    c, dv = v.shape
-    row, col = _iota((c, c), 0), _iota((c, c), 1)
-    (E, e_end, decay_end), A, AqT, kept, T, W = free
-    ke, kd, Wk = k * e_end, k * E, W[:, dv:]
-    U = W[:, :dv] - mm(Wk, St, _NT)
-    dO = q.shape[1] ** -0.5 * dO
-    dU = mm(AqT, dO, _NN) + mm(ke, dSt, _NT)
-    by_state = mm(jnp.concatenate([dO, dU], axis=0), St, _NN)
+    chunk, A, AqT, T, W = free
+    ke, decay_end = chunk.k_end, chunk.decay_end
+    c, dv = chunk.back.v.shape
+    # as the products read them, once where two or three products do
+    Wk, St_, dSt_, dO_ = (x.astype(dtype) for x in (W[:, dv:], St, dSt, dO))
+    U = W[:, :dv] - mm(Wk, St_, _NT)
+    U_ = U.astype(dtype)
+    dU = mm(AqT, dO_, _NN) + mm(ke, dSt_, _NT)
+    by_state = mm(jnp.concatenate([dO, dU], axis=0), St_, _NN)
     d_qd, dWk = by_state[:c], -by_state[c:]
-    d_ke = mm(U, dSt, _NN)
-    dAq = jnp.where(row >= col, mm(dO, U, _NT), 0.0)
+    d_ke = mm(U_, dSt_, _NN)
+    dAq = jnp.where(masks.lower, mm(dO_, U_, _NT), masks.zero)
     # the solve's gradient is the transposed solve
     lam = mm(T, jnp.concatenate([dU, dWk], axis=1), _TN)
-    lam_w = jnp.where(row > col, mm(lam, W, _NT), 0.0)
-    d_kd = beta * lam[:, dv:]
-    dbeta = (jnp.sum(lam[:, :dv] * v + lam[:, dv:] * kd, 1, keepdims=True)
+    lam_w = jnp.where(masks.below, mm(lam, W, _NT), masks.zero)
+    dbeta = (jnp.sum(lam[:, :dv] * chunk.back.v + lam[:, dv:] * chunk.kE, 1,
+                     keepdims=True)
              - jnp.sum(lam_w * A, 1, keepdims=True))
-    dSt_new = dSt * decay_end + mm(dO, q * E, _TN) - mm(dU, Wk, _TN)
+    dSt_new = dSt * decay_end + mm(dO_, chunk.qE, _TN) - mm(dU, Wk, _TN)
     dg_end = (jnp.sum(dSt * St, 0, keepdims=True) * decay_end
               + jnp.sum(d_ke * ke, 0, keepdims=True))
-    dq, dkl, dkr = _scores_grad(-beta * lam_w, dAq, q, k, kept, dtype)
-    dq = dq + d_qd * E
-    dkl = dkl + d_kd * E
-    dkr = dkr + d_ke * e_end
-    dG = q * dq + k * (dkl - dkr)
-    dG = dG + jnp.where(_iota(dG.shape, 0) == c - 1, dg_end, 0.0)
+    dq, dkl, dkr = _scores_grad(-chunk.beta * lam_w, dAq, chunk, masks, dtype)
+    back, by_beta = chunk.back, chunk.beta * lam
+    dq = dq + d_qd * back.E
+    dkl = dkl + by_beta[:, dv:] * back.E
+    dkr = dkr + d_ke * back.e_end
+    dG = back.q * dq + back.k * (dkl - dkr)
+    return ((dq, dkl + dkr, by_beta[:, :dv], dG, dg_end[None]),
+            _as_row(dbeta, masks), dSt_new)
 
-    def as_row(column):  # for a lane-dense store
-        return jnp.sum(jnp.where(row == col, column, 0.0), 0, keepdims=True)
 
-    dbeta = as_row(dbeta)
-    dk, dv_ = dkl + dkr, beta * lam[:, :dv]
+@functools.partial(jax.jit, static_argnames=("per_head",))
+def _sweep_tail(parts, *, per_head=False):
+    """The gradients of q, k, v and g over a grid step's stacked rows
+    from what `_chunk_bwd` left of each chunk (`parts`, in the rows'
+    order). What is left is sums alone, a row's or its chunk's: dG's last
+    row takes the gradient of exp(G_C), and g's gradient is the reversed
+    `_cumsum`, stated once for the step. `per_head`: `g` is one decay a
+    row written along the lanes, and its gradient the sum over them, a
+    column [R, 1]."""
+    dq, dk, dv, dG, dg_end = (
+        jnp.concatenate(part, axis=0) for part in zip(*parts))
+    rows, d = dG.shape
+    by_chunk = (rows // CHUNK, CHUNK, d)
+    dG = dG.reshape(by_chunk) + jnp.where(
+        _iota(by_chunk, 1) == CHUNK - 1, dg_end, 0.0)
+    dG = dG.reshape(rows, d)
     if per_head:  # the sum over the lanes first: the adds are linear
         dG = jnp.sum(dG, 1, keepdims=True)
-    dg = _cumsum(dG, reverse=True)
-    return dq, dk, dv_, as_row(dg) if per_head else dg, dbeta, dSt_new
+    return dq, dk, dv, _cumsum(dG, reverse=True)
 
 
-def _operands(q_ref, k_ref, v_ref, g_ref, beta_ref, rows, heads,
-              per_head=False):
-    """The chunk at `rows` of the grid step's block, float32, and this
-    head's column of the [C, h] block of beta. `per_head`: `g_ref` is a
-    [C, h] block too, and this head's column is written along the lanes
-    here, in VMEM: a [C, 1] column fills as many registers as [C, 128]."""
+def _operands(q_ref, k_ref, v_ref, g_ref, beta_ref, heads, per_head=False):
+    """The grid step's blocks whole, float32 `[R, 128]`, and this head's
+    column `[R, 1]` of the `[R, h]` block of beta. `per_head`: `g_ref` is
+    an `[R, h]` block too, and this head's column is written along the
+    lanes here, in VMEM: a column fills as many registers as `[R, 128]`."""
     def column(ref):
-        blk = ref[0, rows, :]
+        blk = ref[0]
         head = pl.program_id(0) % heads
         return jnp.sum(jnp.where(_iota(blk.shape, 1) == head, blk, 0.0), 1,
                        keepdims=True)
 
-    beta = column(beta_ref)
-    if not per_head:
-        return (*(r[0, rows, :].astype(jnp.float32)
-                  for r in (q_ref, k_ref, v_ref, g_ref)), beta)
-    q, k, v = (r[0, rows, :].astype(jnp.float32)
-               for r in (q_ref, k_ref, v_ref))
-    return q, k, v, jnp.broadcast_to(column(g_ref), q.shape), beta
-
-
-def _step_chunks(refs, steps, heads, per_head):
-    """(the rows of each chunk in the grid step's blocks, each chunk's
-    `_operands`)."""
-    rows = [pl.ds(t * CHUNK, CHUNK) for t in range(steps)]
-    return rows, [_operands(*refs, at, heads, per_head) for at in rows]
+    q, k, v = (r[0].astype(jnp.float32) for r in (q_ref, k_ref, v_ref))
+    g = (jnp.broadcast_to(column(g_ref), q.shape) if per_head
+         else g_ref[0].astype(jnp.float32))
+    return q, k, v, g, column(beta_ref)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref, s_ref, *,
@@ -484,15 +588,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref, s_ref, *,
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    # copies of the chunk and not a loop: a `pl.loop` over four chunks
-    # measured 0.8 ms a call slower than four copies (1.3 ms backward)
-    rows, chunks = _step_chunks((q_ref, k_ref, v_ref, g_ref, beta_ref), steps,
-                                heads, per_head)
-    free = _state_free(chunks, dtype)
-    for t, (q, k, *_) in enumerate(chunks):
+    # the rows' arithmetic once over the step's stacked rows; then copies
+    # of the chunk and not a loop: a `pl.loop` over four chunks measured
+    # 0.8 ms a call slower than four copies (1.3 ms backward)
+    rows = _rows(*_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, heads,
+                            per_head))
+    free = _state_free(rows, _pair_masks(CHUNK), dtype)
+    for t in range(steps):
         St = st_ref[0, t] = s_ref[...]  # the state the chunk starts from
-        o, s_ref[...] = _chunk_fwd(q, k, free[t], St, dtype=dtype)
-        o_ref[0, rows[t], :] = o.astype(o_ref.dtype)
+        o, s_ref[...] = _chunk_fwd(free[t], St, dtype=dtype)
+        o_ref[0, pl.ds(t * CHUNK, CHUNK), :] = o.astype(o_ref.dtype)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref, dq_ref,
@@ -502,22 +607,25 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref, dq_ref,
     def _():
         ds_ref[...] = jnp.zeros_like(ds_ref)
 
-    rows, chunks = _step_chunks((q_ref, k_ref, v_ref, g_ref, beta_ref), steps,
-                                heads, per_head)
-    free = _state_free(chunks, dtype, transposed_aq=True)
+    rows = _rows(*_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, heads,
+                            per_head))
+    masks = _pair_masks(CHUNK)
+    free = _state_free(rows, masks, dtype, backward=True)
+    dO = q_ref.shape[2] ** -0.5 * do_ref[0].astype(jnp.float32)
+    parts = [None] * steps
     for t in reversed(range(steps)):
-        q, k, v, _, beta = chunks[t]
-        dq, dk, dv, dg, db_ref[0, t], ds_ref[...] = _chunk_bwd(
-            q, k, v, beta, free[t], st_ref[0, t], ds_ref[...],
-            do_ref[0, rows[t], :].astype(jnp.float32),
-            dtype=dtype, per_head=per_head)
-        wide = [(dq_ref, dq), (dk_ref, dk), (dv_ref, dv)]
-        if per_head:  # a row a chunk, as beta's
-            dg_ref[0, t] = dg
-        else:
-            wide.append((dg_ref, dg))
-        for ref, d in wide:
-            ref[0, rows[t], :] = d.astype(ref.dtype)
+        parts[t], db_ref[0, t], ds_ref[...] = _chunk_bwd(
+            free[t], masks, st_ref[0, t], ds_ref[...], _cut(dO, t),
+            dtype=dtype)
+    dq, dk, dv, dg = _sweep_tail(parts, per_head=per_head)
+    wide = [(dq_ref, dq), (dk_ref, dk), (dv_ref, dv)]
+    if per_head:  # a row a chunk, as beta's
+        for t in range(steps):
+            dg_ref[0, t] = _as_row(_cut(dg, t), masks)
+    else:
+        wide.append((dg_ref, dg))
+    for ref, d in wide:
+        ref[0] = d.astype(ref.dtype)
 
 
 def _cost(backward, b, s, heads, dk, dv, dtypes, group=1, per_head=False):

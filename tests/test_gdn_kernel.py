@@ -243,14 +243,50 @@ def test_names_declaration_and_counters(interpreter):
     assert numbers(found["gdn_fwd"][0])[2] == moved
 
 
-@pytest.mark.parametrize("per_step", [2, 4])
-@pytest.mark.parametrize("length", [100, 193])
+@pytest.mark.parametrize("length,per_step", [
+    (100, 2), (193, 2), (100, 4), (193, 4), (150, 4), (327, 4)])
 def test_any_width_of_the_lockstep_is_the_pair_at_one_chunk_a_step(
         interpreter, monkeypatch, length, per_step):
     """As tests/test_kda_kernel.py's, with a decay a head and key groups
-    of 2: on a length that leaves a padded tail, 1, 2 or 4 chunks a grid
-    step give the outputs and the five gradients bit for bit (dq and dk
-    are summed over each key head's group after the kernel, by the same
-    adds)."""
+    of 2: on a length that leaves a padded tail (150: a grid step of three
+    chunks under a width of 4; 5 x 64 + 7: two padded chunks), 1, 2 or 4
+    chunks a grid step give the outputs and the five gradients bit for
+    bit (the head's column of the decay is taken out of the step's whole
+    block; dq and dk are summed over each key head's group after the
+    kernel, by the same adds)."""
     pair_at_widths(_args(length, -2.0, -0.01, seed=length), per_step,
                    monkeypatch)
+
+
+@pytest.mark.parametrize("per_head", [False, True], ids=["kda", "gdn"])
+@pytest.mark.parametrize("chunks", [1, 2, 4])
+def test_the_sweeps_tail_is_each_chunks_own_to_the_bit(chunks, per_head):
+    """`_sweep_tail` states once, over the grid step's stacked rows, what
+    follows the reverse sweep's last product and is sums alone: dG's last
+    row, the sum over the lanes where the decay is a head's, the reversed
+    cumulative sum. No add crosses a chunk: each chunk's rows are what
+    the tail of that chunk alone gives."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.kda_chunk import _sweep_tail
+
+    r = np.random.RandomState(chunks)
+    parts = [tuple(jnp.asarray(r.randn(*shape), jnp.float32) for shape in (
+        (64, D), (64, D), (64, D), (64, D), (1, 1, D)))
+        for _ in range(chunks)]
+    together = jax.jit(lambda p: _sweep_tail(p, per_head=per_head))(parts)
+    assert together[3].shape == (chunks * 64, 1 if per_head else D)
+    for t, part in enumerate(parts):
+        alone = jax.jit(lambda p: _sweep_tail([p], per_head=per_head))(part)
+        for got, want in zip(together, alone):
+            assert np.array_equal(np.asarray(got[t * 64:(t + 1) * 64]),
+                                  np.asarray(want))
+    # and it is the sum from each row to its chunk's last, dg_end included
+    dG = np.concatenate([np.asarray(p[3], np.float64) for p in parts])
+    dG[63::64] += np.concatenate([np.asarray(p[4][0]) for p in parts])
+    if per_head:
+        dG = dG.sum(1, keepdims=True)
+    want = dG.reshape(chunks, 64, -1)[:, ::-1].cumsum(1)[:, ::-1]
+    np.testing.assert_allclose(np.asarray(together[3]),
+                               want.reshape(dG.shape), rtol=2e-5, atol=2e-5)

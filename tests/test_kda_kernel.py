@@ -2,7 +2,10 @@
 interpreter, at the published head width of 128: outputs and the five
 gradients against the token recurrence of `tests/kimi_linear_reference.py`
 and against `kda_chunked`, the plain path; the state carried over grid
-steps; the dispatch and its counters."""
+steps; a grid step's stacked rows and lockstep solve against each chunk's
+own, bit for bit, and the pair at any width against one chunk a step;
+what a further chunk of a grid step costs the host in equations; the
+dispatch and its counters."""
 
 import numpy as np
 import pytest
@@ -337,6 +340,90 @@ def test_the_lockstep_solve_is_each_chunks_own_to_the_bit(chunks, dtype):
                 eye, atol=2e-4)
 
 
+def _step_rows(chunks, seed):
+    """q, k, v, g [chunks*64, 128] and beta [chunks*64, 1] as a grid step
+    of `chunks` chunks of one head holds them."""
+    import jax.numpy as jnp
+
+    r = np.random.RandomState(seed)
+    rows = chunks * 64
+
+    def unit(t):
+        return t / np.linalg.norm(t, axis=-1, keepdims=True)
+
+    return [jnp.asarray(t, jnp.float32) for t in (
+        unit(r.randn(rows, D)), unit(r.randn(rows, D)), r.randn(rows, D),
+        r.uniform(-1.0, -0.01, (rows, D)), r.uniform(0, 1, (rows, 1)))]
+
+
+def _leaves_equal(got, want):
+    import jax
+
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for a, w in zip(got, want):
+        assert a.shape == w.shape and a.dtype == w.dtype
+        assert np.array_equal(np.asarray(a), np.asarray(w))
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("chunks", [1, 2, 4])
+def test_the_stacked_rows_are_each_chunks_own_to_the_bit(chunks, dtype,
+                                                         backward):
+    """`_rows` states a grid step's row arithmetic once over its stacked
+    rows (the cumulative sum, the exponentials, the levels' `e`, `k*e` and
+    `q*e`, the blocks of four, the lane sums, `beta v`, `k exp(G)`), and a
+    chunk cuts its 64 rows out: every array of every chunk's state-free
+    half, its rows of `_Rows`, A, Aq (or its transpose), T and W, is what
+    `_rows` over that chunk alone gives, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import kda_chunk as kernel
+
+    dtype = jnp.dtype(dtype)
+
+    @jax.jit
+    def state_free(*operands):
+        return kernel._state_free(
+            kernel._rows(*operands),
+            kernel._pair_masks(kernel.CHUNK), dtype, backward)
+
+    operands = _step_rows(chunks, seed=chunks)
+    together = state_free(*operands)
+    assert len(together) == chunks
+    for t, free in enumerate(together):
+        (alone,) = state_free(*(x[t * 64:(t + 1) * 64] for x in operands))
+        assert (free.rows.back is None) != backward
+        _leaves_equal(free, alone)
+
+
+@pytest.mark.parametrize("per_head", [False, True], ids=["kda", "gdn"])
+def test_a_further_chunk_of_a_grid_step_costs_the_host_this_much(per_head):
+    """The host lowers every equation of a kernel's body at every start
+    of a job, compile cache or not, and a grid step is so many copies of
+    the chunk's code: about 0.8 ms an equation on the chip's host (PERF.md,
+    PR 52 and PR 54). With a step's row arithmetic stated once, a further
+    chunk adds 127 equations forward and 311 backward (317 with a decay a
+    head) where it added 344 and 609 (364 and 638): held to 230 and 500,
+    so that the next change to the body sees what it costs a start
+    (`tools/kda_vreg_count.py` prints the same counts)."""
+    from tools.kda_vreg_count import kernel_equations
+
+    from paddle_tpu.ops.pallas import kda_chunk as kernel
+
+    two, four = (kernel_equations(kernel, steps, per_head)
+                 for steps in (2, 4))
+    fwd, bwd = ("gdn_fwd", "gdn_bwd") if per_head else ("kda_fwd", "kda_bwd")
+    assert set(two) == set(four) == {fwd, bwd}
+    for name, limit in ((fwd, 230), (bwd, 500)):
+        a_chunk = (four[name] - two[name]) / 2
+        assert 0 < a_chunk <= limit, (name, two[name], four[name])
+        # and what is stated once stays a small part of the whole
+        assert two[name] - 2 * a_chunk <= 400, (name, two[name], four[name])
+
+
 def pair_at_widths(args, per_step, monkeypatch):
     """The kernel pair's outputs and five gradients at `per_step` chunks a
     grid step against one a step: equal bit for bit."""
@@ -352,20 +439,24 @@ def pair_at_widths(args, per_step, monkeypatch):
         assert np.array_equal(np.asarray(a), np.asarray(w)), name
 
 
-@pytest.mark.parametrize("per_step", [2, 4])
-@pytest.mark.parametrize("length", [100, 193])
+@pytest.mark.parametrize("length,per_step", [
+    (100, 2), (193, 2), (100, 4), (193, 4), (150, 4), (327, 4)])
 def test_any_width_of_the_lockstep_is_the_pair_at_one_chunk_a_step(
         interpreter, monkeypatch, length, per_step):
     """On a length that leaves a padded tail (100: 36 rows of a second
-    chunk; 192 + 1: one row of a fourth), with 1, 2 or 4 chunks a grid
-    step the same products read the same operands: the outputs and the
-    five gradients are equal bit for bit, whichever chunks share a step
-    and however many padded ones follow."""
+    chunk; 150: three chunks, which a width of 4 takes as one grid step of
+    three; 192 + 1: one row of a fourth; 5 x 64 + 7: a sixth chunk of
+    seven rows, two grid steps of four with two padded chunks), with 1, 2
+    or 4 chunks a grid step the same products read the same operands and
+    the stacked rows are each chunk's own: the outputs and the five
+    gradients are equal bit for bit, whichever chunks share a step and
+    however many padded ones follow."""
     pair_at_widths(_args(length, -1.0, -0.01, seed=length), per_step,
                    monkeypatch)
 
 
-@pytest.mark.parametrize("seq,chunks", [(8, 1), (64, 1), (65, 2), (640, 10)])
+@pytest.mark.parametrize("seq,chunks", [(8, 1), (64, 1), (65, 2), (150, 3),
+                                        (640, 10)])
 def test_the_gauge_reads_the_chunks_a_grid_step_solves_together(
         interpreter, monkeypatch, seq, chunks):
     """`kda_lockstep_chunks` is `steps` of the call: the row's chunks up

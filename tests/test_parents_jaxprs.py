@@ -9,9 +9,10 @@ one taken by running this file against a copy of PR 51's parent commit
 (`PYTHONPATH=<parent> python tests/test_parents_jaxprs.py`), under jax
 0.9.0. One case is pinned later: `kda-kernels` at PR 52, which restated
 the kernels' body (the solve in lockstep over the chunks of a grid
-step: `ops/pallas/kda_chunk.py`) and re-took that digest on its finished
-tree; its results are the parent's bit for bit
-(`tests/test_kda_kernel.py`), its jaxpr is not. `kda-chunked` and the
+step: `ops/pallas/kda_chunk.py`), and again at PR 54 (a grid step's row
+arithmetic once over its stacked rows, four chunks a step); each re-took
+that digest on its finished tree; the results are the parent's bit for
+bit (`tests/test_kda_kernel.py`), the jaxpr is not. `kda-chunked` and the
 ten others are PR 51's parent's.
 
 PR 53 gave `moe_experts` a second input and an expert form, `attention`
@@ -150,7 +151,7 @@ CASES = {
 # as PR 51's parent (commit 6f8ecfe) traces them
 PARENTS_JAXPRS = {
     "kda-chunked": "ef5c1d772c23b254",
-    "kda-kernels": "21d5df23234f1c20",  # PR 52's tree: see the docstring
+    "kda-kernels": "481ae08507f6846f",  # PR 54's tree: see the docstring
     "trinity_full-xla": "1160994003f6c88b",
     "trinity_window-xla": "9e6c7e0895897a9a",
     "mellum-xla": "9e7d571745830a91",

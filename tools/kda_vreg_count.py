@@ -1,13 +1,24 @@
-"""Two counts of the KDA chunk kernels (ops/pallas/kda_chunk.py), neither
-a timing and neither needing a chip.
+"""Three counts of the KDA chunk kernels (ops/pallas/kda_chunk.py), none
+a timing and none needing a chip.
 
 What one chunk asks of the vector units: the jaxprs of a chunk's forward
-(`_state_free` over a list of one, then `_chunk_fwd`) and backward
-(`_state_free` with Aq transposed, then `_chunk_bwd`) at the cell's shapes
-([64, 128] float32 operands, bf16 products), every equation's outputs as
-[8, 128] registers of 32-bit lanes, products left out. Mosaic folds some
-`iota`, `broadcast_in_dim` and `convert_element_type`, so those are given
-apart.
+(`_rows` and `_state_free` over one chunk, then `_chunk_fwd`) and backward
+(`_state_free` with Aq transposed, `_chunk_bwd`, `_sweep_tail`) at the
+cell's shapes ([64, 128] float32 operands, bf16 products), every
+equation's outputs as [8, 128] registers of 32-bit lanes, products left
+out. Mosaic folds some `iota`, `broadcast_in_dim` and
+`convert_element_type`, so those are given apart.
+
+What a kernel costs the host at every start of a job: the equations of
+each kernel's body (`kda_fwd`, `kda_bwd`, `gdn_fwd`, `gdn_bwd`), the
+nested jits' bodies counted where they are called, since that is where
+Mosaic lowers them. "shared" is what the kernel states once whatever its
+width (the step's stacked rows, the masks), "a chunk" what every further
+chunk of a grid step adds (`tests/test_kda_kernel.py` holds the second
+to 230 forward and 500 backward). In a cell's real step the chip's host
+took 0.9 to 1.0 ms an equation of the backward kernel and 0.13 of the
+forward's, lowered in a process by themselves 0.15 of either (PERF.md,
+PR 54): the count ranks two bodies, it does not predict seconds.
 
     JAX_PLATFORMS=cpu python tools/kda_vreg_count.py [path/to/kda_chunk.py]
 
@@ -57,6 +68,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
 
 FOLDED = ("iota", "broadcast_in_dim", "convert_element_type")
 
@@ -72,12 +84,23 @@ def registers(aval):
     return n
 
 
+def _inner(eqn):
+    """The jaxprs an equation holds: a nested jit's, a `cond`'s branches,
+    a `pallas_call`'s body."""
+    for value in eqn.params.values():
+        for item in value if isinstance(value, (tuple, list)) else (value,):
+            if isinstance(item, jex_core.ClosedJaxpr):
+                yield item.jaxpr
+            elif isinstance(item, jex_core.Jaxpr):
+                yield item
+
+
 def count(jaxpr, into):
     for eqn in jaxpr.eqns:
-        inner = [v for v in eqn.params.values() if hasattr(v, "jaxpr")]
+        inner = list(_inner(eqn))
         if inner:
             for sub in inner:
-                count(sub.jaxpr, into)
+                count(sub, into)
         elif eqn.primitive.name != "dot_general":
             into[eqn.primitive.name] += sum(registers(v.aval)
                                             for v in eqn.outvars)
@@ -101,14 +124,21 @@ def registers_a_chunk(kernel):
     beta = jax.ShapeDtypeStruct((kernel.CHUNK, 1), jnp.float32)
     state = jax.ShapeDtypeStruct((128, 128), jnp.float32)
 
+    def state_free(q, k, v, g, beta, backward):
+        masks = kernel._pair_masks(kernel.CHUNK)
+        free, = kernel._state_free(kernel._rows(q, k, v, g, beta),
+                                   masks, dtype, backward)
+        return free, masks
+
     def chunk_fwd(q, k, v, g, beta, St):
-        free, = kernel._state_free([(q, k, v, g, beta)], dtype)
-        return kernel._chunk_fwd(q, k, free, St, dtype=dtype)
+        free, _ = state_free(q, k, v, g, beta, False)
+        return kernel._chunk_fwd(free, St, dtype=dtype)
 
     def chunk_bwd(q, k, v, g, beta, St, dSt, dO):
-        free, = kernel._state_free([(q, k, v, g, beta)], dtype, True)
-        return kernel._chunk_bwd(q, k, v, beta, free, St, dSt, dO,
-                                 dtype=dtype)
+        free, masks = state_free(q, k, v, g, beta, True)
+        parts, dbeta, dSt = kernel._chunk_bwd(free, masks, St, dSt, dO,
+                                              dtype=dtype)
+        return kernel._sweep_tail([parts]), dbeta, dSt
 
     for fn, args in ((chunk_fwd, (x, x, x, x, beta, state)),
                      (chunk_bwd, (x, x, x, x, beta, state, state, x))):
@@ -119,6 +149,63 @@ def registers_a_chunk(kernel):
               f"{total - folded} without {'/'.join(FOLDED)}; "
               f"{by['exp']} of exp, {by['reduce_sum']} of reduce_sum outputs")
         print("  " + ", ".join(f"{p} {n}" for p, n in by.most_common()))
+
+
+def equations(jaxpr):
+    """The equations the host lowers for `jaxpr`: a nested jit's body
+    counts wherever it is called, as Mosaic lowers it there."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        inner = list(_inner(eqn))
+        total += sum(equations(sub) for sub in inner) if inner else 1
+    return total
+
+
+def kernel_equations(kernel, steps, per_head=False):
+    """Kernel name -> `equations` of its body with `steps` chunks a grid
+    step: the pair traced once, a row of two grid steps, two heads (under
+    one key head where the decay is a head's). Nothing runs."""
+    heads, d = 2, 128
+    key_heads = 1 if per_head else heads
+    s = 2 * steps * kernel.CHUNK
+    statics = kernel._Statics(heads, steps, jnp.bfloat16, False, s,
+                              heads // key_heads, per_head)
+    x = jax.ShapeDtypeStruct((1, s, key_heads * d), jnp.float32)
+    column = jax.ShapeDtypeStruct((1, s, heads), jnp.float32)
+    wide = jax.ShapeDtypeStruct((1, s, heads * d), jnp.float32)
+
+    def both(*args):
+        o, pull = jax.vjp(lambda *a: kernel._core(*a, statics), *args)
+        return pull(o)
+
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = equations(eqn.params["jaxpr"])
+            else:
+                for sub in _inner(eqn):
+                    walk(sub)
+
+    walk(jax.make_jaxpr(both)(
+        x, x, wide.update(dtype=jnp.bfloat16), column if per_head else wide,
+        column).jaxpr)
+    return found
+
+
+def equations_a_start(kernel):
+    """Each kernel's equations at `CHUNKS_PER_STEP`, as what it states
+    once and what a chunk adds (from the bodies at two and four chunks)."""
+    width = kernel.CHUNKS_PER_STEP
+    for per_head in (False, True):
+        two, four = (kernel_equations(kernel, n, per_head) for n in (2, 4))
+        for name in two:
+            chunk = (four[name] - two[name]) // 2
+            shared = two[name] - 2 * chunk
+            print(f"{name}: {shared + width * chunk} equations a grid step "
+                  f"of {width} chunks: {shared} shared + {width} x {chunk} "
+                  "a chunk")
 
 
 def compile_for_v5e(kernel, chunks):
@@ -199,7 +286,9 @@ def main(argv=None):
     elif args.compile:
         compile_for_v5e(load(args.path), args.chunks)
     else:
-        registers_a_chunk(load(args.path))
+        kernel = load(args.path)
+        registers_a_chunk(kernel)
+        equations_a_start(kernel)
 
 
 if __name__ == "__main__":
