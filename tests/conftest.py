@@ -30,6 +30,9 @@ os.environ.setdefault("PADDLE_TPU_VERIFY", "1")
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+# the plain modules beside the test files hold cases and assertions too
+pytest.register_assert_rewrite("decoder_suite", "kernel_cases")
+
 
 def pytest_configure(config):
     # tier-1 runs `-m 'not slow'` (ROADMAP.md); slow-marked tests (the

@@ -9,6 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from kernel_cases import attn_program
+
 fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 
 
@@ -1213,40 +1215,12 @@ def test_fused_mha_bshd_layout_matches_bhsd(rng):
         np.testing.assert_allclose(x, y, rtol=1e-5, atol=1e-5)
 
 
-def _attn_program(b, sq, sk, nh, dh, layout, causal=False, dropout=0.0):
-    """q [b, sq, nh*dh] and k, v [b, sk, nh*dh] as the projections write
-    them, head-split by reshape (and transposed for "bhsd") as the models
-    do, through the op, with gradients. Returns (main, startup, fetches)."""
-    import paddle_tpu as fluid
-    from paddle_tpu.framework import Program
-
-    main, startup = Program(), Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        feeds = [fluid.layers.data(n, [b, s, nh * dh], append_batch_size=False)
-                 for n, s in (("q", sq), ("k", sk), ("v", sk))]
-        for t in feeds:
-            t.stop_gradient = False
-        heads = [fluid.layers.reshape(t, [b, -1, nh, dh]) for t in feeds]
-        if layout == "bhsd":
-            heads = [fluid.layers.transpose(t, [0, 2, 1, 3]) for t in heads]
-        bias = fluid.layers.data("bias", [b, sk], append_batch_size=False)
-        out = fluid.layers.fused_multihead_attention(
-            *heads, key_bias=bias, causal=causal, attn_dropout=dropout,
-            layout=layout)
-        if layout == "bhsd":
-            out = fluid.layers.transpose(out, [0, 2, 1, 3])
-        out = fluid.layers.reshape(out, [b, -1, nh * dh])
-        loss = fluid.layers.reduce_sum(fluid.layers.elementwise_mul(out, out))
-        grads = fluid.backward.calc_gradient(loss, feeds)
-    return main, startup, [out, *grads]
-
-
 def _run_attn_program(rng, shape, layout="bshd", compiled=False, **kw):
     import paddle_tpu as fluid
     from paddle_tpu import profiler
 
     b, sq, sk, nh, dh = shape
-    main, startup, fetches = _attn_program(*shape, layout, **kw)
+    main, startup, fetches = attn_program(*shape, layout, **kw)
     feed = {"q": rng.randn(b, sq, nh * dh).astype("float32"),
             "k": rng.randn(b, sk, nh * dh).astype("float32"),
             "v": rng.randn(b, sk, nh * dh).astype("float32"),
@@ -1518,7 +1492,7 @@ def test_rotary_dim_turns_the_first_lanes_and_passes_the_rest(
 
     import paddle_tpu as fluid
     from paddle_tpu import profiler
-    from test_qk_prep_kernel import written_out
+    from kernel_cases import written_out
 
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
     attn_path(path)

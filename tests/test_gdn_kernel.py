@@ -1,16 +1,18 @@
 """The delta rule with a decay a head and grouped key heads (Gated
 DeltaNet; `ops/linear_attn_ops.py`, `ops/pallas/kda_chunk.py`): the kernel
 pair under the Pallas interpreter and `kda_chunked`, the plain path,
-against the token-a-step recurrence of `tests/qwen3_next_reference.py`,
-outputs and the five gradients, with key groups of 2 and at decays down
-to 0.01 a token; that nothing is written out in front of the kernels; the
+against the token-a-step recurrence of `benchmark/models/qwen3_next.py`,
+outputs and the five gradients (each side compiled:
+`tests/kernel_cases.py`), with key groups of 2 and at decays down to 0.01
+a token; that nothing is written out in front of the kernels; the
 names, the declaration and the counters."""
 
 import numpy as np
 import pytest
 
-import qwen3_next_reference as ref
-from test_kda_kernel import _loss_grads, pair_at_widths, rel
+from kernel_cases import compiled, pair_at_widths, rel, value_and_grads
+
+from benchmark.models import qwen3_next as ref
 
 B, HK, HV, D = 2, 2, 4, 128
 
@@ -65,27 +67,21 @@ REGIMES = [
 @pytest.mark.parametrize("length,g_lo,g_hi,kind", REGIMES)
 def test_kernels_and_plain_path_equal_the_recurrence(
         interpreter, length, g_lo, g_hi, kind):
-    import jax
-
     from paddle_tpu.ops.linear_attn_ops import kda_chunked
     from paddle_tpu.ops.pallas.kda_chunk import kda_chunk
 
     args = _args(length, g_lo, g_hi, parallel=kind == "parallel")
-    with jax.default_matmul_precision("highest"):
-        got = kda_chunk(*args)
-        want = recurrence(*args)
-        plain = kda_chunked(*args)
-        assert got.shape == want.shape == (B, length, HV, D)
-        assert np.isfinite(np.asarray(got)).all()
-        np.testing.assert_allclose(got, want, atol=2e-6)
-        np.testing.assert_allclose(plain, want, atol=2e-6)
-        g_got = _loss_grads(kda_chunk, args)
-        g_want = _loss_grads(recurrence, args)
-        g_plain = _loss_grads(kda_chunked, args)
+    (got, g_got), (want, g_want), (plain, g_plain) = (
+        value_and_grads(fn, args)
+        for fn in (kda_chunk, recurrence, kda_chunked))
+    assert got.shape == want.shape == (B, length, HV, D)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    np.testing.assert_allclose(plain, want, atol=2e-6)
     for name, a, w, p, like in zip("q k v g beta".split(), g_got, g_want,
                                    g_plain, args):
         assert a.shape == like.shape == p.shape, name
-        assert np.isfinite(np.asarray(a)).all(), name
+        assert np.isfinite(a).all(), name
         # as tests/test_kda_kernel.py holds `g`: where a token all but
         # erases the state its gradient is what float32 leaves of a
         # difference, and the kernel is held to twice the plain path's
@@ -98,34 +94,27 @@ def test_kernels_and_plain_path_equal_the_recurrence(
 def test_a_key_head_serves_its_group_and_no_other(interpreter):
     """Groups of 2 and of 4: the kernel's key head is n // group. With
     `n % h_k` (the wrong map) the output is another model's."""
-    import jax
-    import jax.numpy as jnp
-
     from paddle_tpu.ops.pallas.kda_chunk import kda_chunk
 
     for hk in (1, 2, 4):
         args = _args(96, -0.5, -0.01, seed=hk, hk=hk)
-        with jax.default_matmul_precision("highest"):
-            got = kda_chunk(*args)
-            want = recurrence(*args)
-            q, k, v, g, beta = args
-            mod = jnp.arange(HV) % hk
-            wrong = ref.delta_recurrence(q[:, :, mod], k[:, :, mod], v, g,
-                                         beta)
+        got, want = compiled(kda_chunk, *args), compiled(recurrence, *args)
+        q, k, v, g, beta = args
+        mod = np.arange(HV) % hk
+        wrong = compiled(ref.delta_recurrence, q[:, :, mod], k[:, :, mod], v,
+                        g, beta)
         np.testing.assert_allclose(got, want, atol=2e-6)
         if hk == 2:
             assert rel(wrong, want) > 0.3
 
 
 def test_the_decay_is_a_heads_and_not_one_for_all(interpreter):
-    import jax
-
     from paddle_tpu.ops.pallas.kda_chunk import kda_chunk
 
     q, k, v, g, beta = _args(128, -1.0, -0.01)
-    with jax.default_matmul_precision("highest"):
-        got = kda_chunk(q, k, v, g, beta)
-        same = kda_chunk(q, k, v, np.broadcast_to(g[..., :1], g.shape), beta)
+    got = compiled(kda_chunk, q, k, v, g, beta)
+    same = compiled(kda_chunk, q, k, v,
+                   np.array(np.broadcast_to(g[..., :1], g.shape)), beta)
     np.testing.assert_allclose(got[:, :, 0], same[:, :, 0], atol=2e-6)
     assert rel(same[:, :, 1:], got[:, :, 1:]) > 0.05
 

@@ -7,43 +7,13 @@ import os
 import numpy as np
 
 import paddle_tpu as fluid
-from paddle_tpu import layers
 from paddle_tpu.framework import Program
 from paddle_tpu.incubate.fleet.parameter_server.host_table import (
     HostEmbeddingTable,
     HostTableSession,
-    host_embedding,
 )
 
-
-def _build_ctr(main, startup, dim=8, max_unique=64, slots=2):
-    """DeepFM-ish: sparse id embeddings + dense feature -> fc tower."""
-    with fluid.program_guard(main, startup):
-        with fluid.unique_name.guard():
-            ids = layers.data("ids", [16, slots], dtype="int64",
-                              append_batch_size=False)
-            dense = layers.data("dense", [16, 4], dtype="float32",
-                                append_batch_size=False)
-            label = layers.data("label", [16, 1], dtype="float32",
-                                append_batch_size=False)
-            emb = host_embedding(ids, "ctr_table", dim, max_unique)
-            emb_sum = layers.reduce_sum(emb, dim=1)  # [b, dim]
-            x = layers.concat([emb_sum, dense], axis=1)
-            h = layers.fc(x, 16, act="relu")
-            pred = layers.fc(h, 1, act="sigmoid")
-            loss = layers.mean(
-                layers.log_loss(pred, label, epsilon=1e-6)
-            )
-            fluid.optimizer.Adam(1e-2).minimize(loss)
-    return loss
-
-
-def _batch(rng, vocab, slots=2):
-    return {
-        "ids": rng.randint(0, vocab, (16, slots)).astype("int64"),
-        "dense": rng.rand(16, 4).astype("float32"),
-        "label": (rng.rand(16, 1) > 0.5).astype("float32"),
-    }
+from ctr_model import batch, build_ctr
 
 
 def test_pull_push_roundtrip():
@@ -72,7 +42,7 @@ def test_pull_overflow_raises():
 
 def test_ctr_model_trains_with_host_table():
     main, startup = Program(), Program()
-    loss = _build_ctr(main, startup)
+    loss = build_ctr(main, startup)
     table = HostEmbeddingTable(100_000, 8, lr=0.1, optimizer="adagrad",
                                seed=3)
     exe = fluid.Executor(fluid.CPUPlace())
@@ -85,7 +55,7 @@ def test_ctr_model_trains_with_host_table():
         )
         # fixed batch: loss must drop as BOTH dense tower and host rows
         # learn
-        feed = _batch(rng, 100_000)
+        feed = batch(rng, 100_000)
         losses = [
             float(np.asarray(
                 sess.run(feed, fetch_list=[loss])[0]
@@ -127,7 +97,7 @@ def test_pipelined_session_trains():
     async push) trains the same CTR model; bounded-staleness updates
     still converge and every batch's rows get pushed."""
     main, startup = Program(), Program()
-    loss = _build_ctr(main, startup)
+    loss = build_ctr(main, startup)
     table = HostEmbeddingTable(100_000, 8, lr=0.1, optimizer="adagrad",
                                seed=3)
     exe = fluid.Executor(fluid.CPUPlace())
@@ -138,7 +108,7 @@ def test_pipelined_session_trains():
         sess = HostTableSession(
             exe, main, {"ctr_table": (table, "ids", 64)}
         )
-        feed = _batch(rng, 100_000)
+        feed = batch(rng, 100_000)
         losses = [
             float(out[0].reshape(-1)[0])
             for out in sess.run_pipelined(
@@ -154,7 +124,7 @@ def test_pipelined_session_trains():
 
 def test_pipelined_session_propagates_errors():
     main, startup = Program(), Program()
-    loss = _build_ctr(main, startup)
+    loss = build_ctr(main, startup)
     table = HostEmbeddingTable(1000, 8, seed=1)
     exe = fluid.Executor(fluid.CPUPlace())
     scope = fluid.Scope()
@@ -166,7 +136,7 @@ def test_pipelined_session_propagates_errors():
         )
 
         def bad_feeds():
-            feed = _batch(rng, 1000)
+            feed = batch(rng, 1000)
             yield feed
             bad = dict(feed)
             bad["ids"] = np.full_like(feed["ids"], -5)  # negative ids

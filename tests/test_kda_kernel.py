@@ -1,7 +1,8 @@
 """The KDA chunk kernels (`ops/pallas/kda_chunk.py`) under the Pallas
 interpreter, at the published head width of 128: outputs and the five
-gradients against the token recurrence of `tests/kimi_linear_reference.py`
-and against `kda_chunked`, the plain path; the state carried over grid
+gradients against the token recurrence of `benchmark/models/kimi_linear.py`
+and against `kda_chunked`, the plain path, each side and each gradient
+compiled (`tests/kernel_cases.py`); the state carried over grid
 steps; a grid step's stacked rows and lockstep solve against each chunk's
 own, bit for bit, and the pair at any width against one chunk a step;
 what a further chunk of a grid step costs the host in equations; the
@@ -10,14 +11,11 @@ dispatch and its counters."""
 import numpy as np
 import pytest
 
-import kimi_linear_reference as ref
+from kernel_cases import compiled, pair_at_widths, rel, value_and_grads
+
+from benchmark.models import kimi_linear as ref
 
 B, H, D = 2, 2, 128
-
-
-def rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
 
 
 def _args(length, g_lo, g_hi, seed=None, parallel=False, alternate=False):
@@ -53,13 +51,6 @@ def interpreter(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
 
 
-def _loss_grads(fn, args):
-    import jax
-    import jax.numpy as jnp
-
-    return jax.grad(lambda *a: jnp.sum(fn(*a) ** 2), argnums=range(5))(*args)
-
-
 # the regimes of test_chunked_kda_equals_the_token_recurrence, and three more
 REGIMES = [
     (64, -0.1, -0.001, ""),  # one whole chunk, mild decay
@@ -85,25 +76,19 @@ REGIMES = [
 @pytest.mark.parametrize("length,g_lo,g_hi,kind", REGIMES)
 def test_kernel_equals_the_recurrence_and_the_plain_path(
         interpreter, length, g_lo, g_hi, kind):
-    import jax
-
     from paddle_tpu.ops.linear_attn_ops import kda_chunked
     from paddle_tpu.ops.pallas.kda_chunk import kda_chunk
 
     args = _args(length, g_lo, g_hi, parallel=kind == "parallel",
                  alternate=kind == "alternate")
-    with jax.default_matmul_precision("highest"):
-        got = kda_chunk(*args)
-        want = ref.kda_recurrence(*args)
-        plain = kda_chunked(*args)
-        assert got.shape == want.shape and np.isfinite(np.asarray(got)).all()
-        np.testing.assert_allclose(got, want, atol=2e-6)
-        np.testing.assert_allclose(got, plain, atol=2e-6)
-        g_got = _loss_grads(kda_chunk, args)
-        g_want = _loss_grads(ref.kda_recurrence, args)
-        g_plain = _loss_grads(kda_chunked, args)
+    (got, g_got), (want, g_want), (plain, g_plain) = (
+        value_and_grads(fn, args)
+        for fn in (kda_chunk, ref.kda_recurrence, kda_chunked))
+    assert got.shape == want.shape and np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    np.testing.assert_allclose(got, plain, atol=2e-6)
     for name, a, w, p in zip("q k v g beta".split(), g_got, g_want, g_plain):
-        assert np.isfinite(np.asarray(a)).all(), name
+        assert np.isfinite(a).all(), name
         # Where a token all but erases the state (the third regime) the
         # decay's gradient is 1e-10 to 1e-8 and is what float32 leaves of
         # q*dq + k*(dk_row - dk_col), summed back over the chunk: at this
@@ -122,20 +107,15 @@ def test_the_state_is_carried_from_chunk_to_chunk(
     almost wholly of earlier chunks' state, within a grid step and across
     grid steps; a kernel that dropped either would read off by the norm
     of the output."""
-    import jax
-
     from paddle_tpu.ops.pallas import kda_chunk as kernel
 
     monkeypatch.setattr(kernel, "CHUNKS_PER_STEP", per_step)
     args = _args(320, -1e-3, -1e-5, seed=per_step)
-    with jax.default_matmul_precision("highest"):
-        got = kernel.kda_chunk(*args)
-        want = ref.kda_recurrence(*args)
-        # the same rows with the first four chunks cut off: what a chunk
-        # that started from a zero state would compute
-        fresh = ref.kda_recurrence(*(a[:, 256:] for a in args))
-        g_got = _loss_grads(kernel.kda_chunk, args)
-        g_want = _loss_grads(ref.kda_recurrence, args)
+    got, g_got = value_and_grads(kernel.kda_chunk, args)
+    want, g_want = value_and_grads(ref.kda_recurrence, args)
+    # the same rows with the first four chunks cut off: what a chunk
+    # that started from a zero state would compute
+    fresh = compiled(ref.kda_recurrence, *(a[:, 256:] for a in args))
     np.testing.assert_allclose(got, want, atol=2e-6)
     assert rel(fresh, want[:, 256:]) > 0.3  # the state matters here
     for a, w in zip(g_got, g_want):
@@ -281,9 +261,8 @@ def test_bf16_products_against_the_float32_recurrence(interpreter):
                         argnums=range(5))(*args)
 
     got = (bf16(*flat, beta_p), *grads(bf16, (*flat, beta_p)))
-    with jax.default_matmul_precision("highest"):
-        want = (ref.kda_recurrence(q, k, v, g, beta),
-                *_loss_grads(ref.kda_recurrence, (q, k, v, g, beta)))
+    out, gradients = value_and_grads(ref.kda_recurrence, (q, k, v, g, beta))
+    want = (out, *gradients)
     read = []
     for a, w in zip(got, want):
         a = np.asarray(a)[:, :127].reshape(w.shape)
@@ -422,21 +401,6 @@ def test_a_further_chunk_of_a_grid_step_costs_the_host_this_much(per_head):
         assert 0 < a_chunk <= limit, (name, two[name], four[name])
         # and what is stated once stays a small part of the whole
         assert two[name] - 2 * a_chunk <= 400, (name, two[name], four[name])
-
-
-def pair_at_widths(args, per_step, monkeypatch):
-    """The kernel pair's outputs and five gradients at `per_step` chunks a
-    grid step against one a step: equal bit for bit."""
-    from paddle_tpu.ops.pallas import kda_chunk as kernel
-
-    read = {}
-    for steps in (1, per_step):
-        monkeypatch.setattr(kernel, "CHUNKS_PER_STEP", steps)
-        read[steps] = (kernel.kda_chunk(*args),
-                       *_loss_grads(kernel.kda_chunk, args))
-    for name, a, w in zip("o q k v g beta".split(), read[per_step], read[1]):
-        assert a.shape == w.shape and np.isfinite(np.asarray(a)).all(), name
-        assert np.array_equal(np.asarray(a), np.asarray(w)), name
 
 
 @pytest.mark.parametrize("length,per_step", [

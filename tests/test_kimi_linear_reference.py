@@ -1,254 +1,61 @@
-"""Kimi Linear against its plain reference (`tests/kimi_linear_reference.py`)
-at the rehearsal size of the cell `kimi_linear_ep32_s4096`: the program's
-logits mixer by mixer and for the whole model, one train step's gradients
-for every kind of parameter, the chunked KDA against the token recurrence,
-the expert layer's shares against the uncut layer, droplessness under
-skew, the router's correction, and that a wrong model is caught by the
-cell's tolerance.
+"""Kimi Linear against its plain reference (`benchmark/models/kimi_linear.py`)
+at the rehearsal size of the cell `kimi_linear_ep32_s4096`: what every
+decoder suite holds (`tests/decoder_suite.py`: the program's logits mixer
+by mixer and for the whole model, one train step's gradients for every
+kind of parameter, that a wrong model is caught by the cell's tolerance)
+on this model's data, and its own: the chunked KDA against the token
+recurrence, the expert layer's shares against the uncut layer,
+droplessness under skew, the router's correction, the layers as a user
+calls them and the short convolution's forms, the cell's counters.
 
-Run as a script, the gradient comparison is made at the published widths
-on one 512-token row on the attached TPU, outside any timed window:
+Run as a script on the attached TPU (`tests/decoder_suite.py` has the
+arguments; with none, the gradients at the published widths on one
+512-token row):
 
     python3 tests/test_kimi_linear_reference.py
 """
 
 from __future__ import annotations
 
-import inspect
-import os
-import sys
+import math
 
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from decoder_suite import *  # noqa: F401,F403 — the shared cases, on SUITE
+from decoder_suite import expert_params, highest, main, rel, state
+from kernel_cases import value_and_grads
 
-import kimi_linear_reference as ref  # noqa: E402 — beside this file
+from benchmark.models import kimi_linear as adapter  # noqa: E402
 
 CELL = "kimi_linear_ep32_s4096"
 
 
-def cell(rehearse=True, **config):
-    from benchmark.harness import spec
-
-    c = spec.cell(CELL, rehearse=rehearse)
-    c["config"].update(config)
-    return c["config"], c["traffic"]
-
-
-def f32(tree):
-    import jax
-
-    return jax.tree.map(lambda v: np.asarray(v, np.float32), tree)
-
-
-def highest(fn, *args, **kw):
-    import jax
-
-    with jax.default_matmul_precision("highest"):
-        return f32(fn(*args, **kw))
-
-
-def rel(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / (np.sqrt(np.mean(want ** 2)) + 1e-30))
-
-
-def built_model(model, traffic, seed=3):
-    """Programs, executor and the seeded state by name, in a scope of its
-    own (the caller holds the guards)."""
-    import paddle_tpu as fluid
-    from benchmark.models import kimi_linear as adapter
-    from benchmark.runners import train_loop
-
-    main, startup, built, eval_prog = train_loop.build_programs(
-        fluid, adapter, model, traffic, seed)
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup)
-    names = [p.name for p in main.global_block().all_parameters()]
-    return main, eval_prog, built, exe, names
-
-
-def state(names):
-    import paddle_tpu as fluid
-
-    scope = fluid.global_scope()
-    return {n: np.array(scope.get(n), np.float32) for n in names}
-
-
-def batch_for(model, traffic, seed=0):
-    from benchmark.models import kimi_linear as adapter
-
-    return adapter.make_batch(np.random.RandomState(seed), model, traffic)
-
-
-# ------------------------------------------------- the copy is a copy
-
-
-def test_reference_copy_is_the_adapters_word_for_word():
-    from benchmark.models import kimi_linear as adapter
-
-    for name in ("_rms", "_silu", "_ffn", "_conv", "kda_recurrence",
-                 "kda_mixer", "latent_mixer", "expert_ffn", "reference"):
-        assert inspect.getsource(getattr(ref, name)) == inspect.getsource(
-            getattr(adapter, name)), name
-    assert (ref.SCORED_EVERY, ref.QUERY_BLOCK) == (
-        adapter.SCORED_EVERY, adapter.QUERY_BLOCK)
-
-
-# --------------------------------------------- chunked KDA, the math
-
-
-@pytest.mark.parametrize("length,g_lo,g_hi", [
-    (64, -0.1, -0.001),  # one whole chunk, mild decay
-    (128, -1.0, -0.01),  # two whole chunks
-    (100, -8.0, -3.0),  # decays near 0: exp(-G) would overflow in a chunk
-    (200, -1e-4, -1e-6),  # decays near 1: the state forgets nothing
-    (37, -20.0, 0.0),  # shorter than a chunk, both extremes in one row
-    (130, -2.0, -0.01),  # two tokens into a third chunk
-])
-def test_chunked_kda_equals_the_token_recurrence(length, g_lo, g_hi):
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.ops.linear_attn_ops import kda_chunked
-
-    r = np.random.RandomState(length)
-    b, h, dk, dv = 2, 3, 16, 8
-
-    def unit(t):
-        return t / np.linalg.norm(t, axis=-1, keepdims=True)
-
-    args = [jnp.asarray(t, jnp.float32) for t in (
-        unit(r.randn(b, length, h, dk)), unit(r.randn(b, length, h, dk)),
-        r.randn(b, length, h, dv), r.uniform(g_lo, g_hi, (b, length, h, dk)),
-        r.uniform(0, 1, (b, length, h)))]
-    with jax.default_matmul_precision("highest"):
-        got = kda_chunked(*args)
-        want = ref.kda_recurrence(*args)
-        assert np.isfinite(np.asarray(got)).all()
-        np.testing.assert_allclose(got, want, atol=2e-6)
-        g_got = jax.grad(lambda *a: jnp.sum(
-            kda_chunked(*a) ** 2), argnums=range(5))(*args)
-        g_want = jax.grad(lambda *a: jnp.sum(
-            ref.kda_recurrence(*a) ** 2), argnums=range(5))(*args)
-    for a, w in zip(g_got, g_want):
-        assert np.isfinite(np.asarray(a)).all()
-        assert rel(a, w) < 1e-4
-
-
-# ------------------------------------------ the program, mixer by mixer
-
-
-def _mixer_program(which, model, batch=2, seq=80):
+def _mixer_program(which, model, batch, seq):
     """One mixer or feed-forward alone in a Program: `u` in, `y` out."""
     import paddle_tpu as fluid
-    from benchmark.models import kimi_linear as adapter
     from paddle_tpu.models import kimi_linear as zoo
 
     cfg = adapter.config(model)
     u = fluid.layers.data("u", [batch, seq, cfg.hidden_size],
                           append_batch_size=False)
     if which == "kda":
-        y = zoo._kda_mixer(u, cfg, "m")
-    elif which == "latent":
-        y = zoo._latent_mixer(u, cfg, "m")
-    elif which == "dense":
-        y = zoo._ffn(u, cfg.intermediate_size, "m.mlp", cfg)
-    else:
-        y, _ = zoo._expert_ffn(u, cfg, "m")
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
-    names = [p.name for p in
-             fluid.default_main_program().global_block().all_parameters()]
-    return exe, y, names
+        return zoo._kda_mixer(u, cfg, "m")
+    if which == "latent":
+        return zoo._latent_mixer(u, cfg, "m")
+    if which == "dense":
+        return zoo._ffn(u, cfg.intermediate_size, "m.mlp", cfg)
+    return zoo._expert_ffn(u, cfg, "m")[0]
 
 
-@pytest.mark.parametrize("which", ["kda", "latent", "dense", "experts"])
-def test_program_mixer_equals_reference(which):
-    model, _ = cell()
-    exe, y, names = _mixer_program(which, model)
-    u = np.random.RandomState(1).randn(2, 80, model["hidden_size"]).astype(
-        np.float32)
-    (got,) = exe.run(feed={"u": u}, fetch_list=[y])
-    p = state(names)
-    want = {"kda": lambda: ref.kda_mixer(p, u, "m", model),
-            "latent": lambda: ref.latent_mixer(p, u, "m", model),
-            "dense": lambda: ref._ffn(p, u, "m.mlp"),
-            "experts": lambda: ref.expert_ffn(p, u, "m", model)}[which]
-    want = highest(want)
-    assert np.abs(want).max() > 1e-4  # something was computed
-    assert rel(got, want) < 2e-5
+def _want_mixer(which, p, feeds, model, wrong=()):
+    u = feeds["u"]
+    if which == "dense":
+        return highest(adapter._ffn, p, u, "m.mlp")
+    fn = {"kda": adapter.kda_mixer, "latent": adapter.latent_mixer,
+          "experts": adapter.expert_ffn}[which]
+    return highest(fn, p, u, "m", model)
 
-
-def test_latent_attention_through_the_flash_kernel(monkeypatch, attn_path):
-    """The blocked kernel, interpreted, with values narrower than the
-    keys: forced by name, since the CPU's dispatch never chooses it."""
-    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    attn_path("flash")
-    from paddle_tpu import profiler
-
-    before = profiler.counters().get("attn_dispatch_flash", 0)
-    model, _ = cell()
-    exe, y, names = _mixer_program("latent", model, batch=1, seq=160)
-    u = np.random.RandomState(2).randn(1, 160, model["hidden_size"]).astype(
-        np.float32)
-    (got,) = exe.run(feed={"u": u}, fetch_list=[y])
-    assert profiler.counters()["attn_dispatch_flash"] == before + 1
-    want = highest(ref.latent_mixer, state(names), u, "m", model)
-    assert rel(got, want) < 2e-5
-
-
-# ------------------------------------------------------ the whole model
-
-
-@pytest.mark.parametrize("precision,limit", [("float32", 5e-5),
-                                             ("bf16_amp", None)])
-def test_whole_model_logits_and_loss_equal_reference(precision, limit):
-    from benchmark.models import kimi_linear as adapter
-    from benchmark.runners import train_loop
-
-    model, traffic = cell(precision=precision)
-    traffic = dict(traffic, seq_len=80)
-    _, eval_prog, built, exe, names = built_model(model, traffic)
-    batch = batch_for(model, traffic)
-    got_loss, got_logits = exe.run(eval_prog, feed=batch,
-                                   fetch_list=built["check"])
-    nll, count, want = highest(ref.reference, state(names), batch, model)
-    check = train_loop.check_reference(
-        got_loss, got_logits, nll / count, want[:adapter.SCORED_SEQUENCES],
-        adapter.TOLERANCE)
-    assert check["ok"], check
-    if limit:
-        assert check["logits_rel_rms"] < limit and check["loss_abs"] < 1e-5
-
-
-@pytest.mark.parametrize("wrong", [{"drop_layers": 1}, {"no_delta": True}])
-def test_a_wrong_model_is_caught_by_the_cells_tolerance(wrong):
-    """The reference with its last layer left out, or without the delta
-    rule's write (beta = 0), against the program in the cell's precision."""
-    from benchmark.models import kimi_linear as adapter
-    from benchmark.runners import train_loop
-
-    model, traffic = cell()
-    _, eval_prog, built, exe, names = built_model(model, traffic)
-    batch = batch_for(model, traffic)
-    got_loss, got_logits = exe.run(eval_prog, feed=batch,
-                                   fetch_list=built["check"])
-    p = state(names)
-    for kw, ok in ((wrong, False), ({}, True)):
-        nll, count, want = highest(adapter.reference, p, batch, model, **kw)
-        check = train_loop.check_reference(
-            got_loss, got_logits, nll / count,
-            want[:adapter.SCORED_SEQUENCES], adapter.TOLERANCE)
-        assert check["ok"] is ok, (kw, check)
-        if not ok:
-            assert check["logits_rel_rms"] > 2 * adapter.TOLERANCE[
-                "logits_rel_rms"]
-
-
-# ------------------------------------------------ one step's gradients
 
 KINDS = {
     "embedding": ("kimi.embed",), "head": ("kimi.head.w_0",),
@@ -266,91 +73,71 @@ KINDS = {
     "experts": (".moe.w_gate", ".moe.w_up", ".moe.w_down"),
 }
 
-
-def _grad_of_reference(before, batch, model):
-    import jax
-
-    with jax.default_matmul_precision("highest"):
-        return f32(jax.jit(jax.grad(
-            lambda p: ref.loss(p, batch, model)))(before))
-
-
-ROUTED = ("router", "experts")
-
-
-def check_gradients(got, want, before, limit, routed_limit=None, kinds=None):
-    """Worst relative error by kind of parameter (`kinds`: this model's
-    `KINDS` unless another model's are given); `routed_limit` for the
-    router and the experts, whose gradients change by a whole token's
-    worth where rounding flips a selection (at 512 tokens an expert sees
-    about 16). A gradient read as
-    `before - after` carries float32's rounding of the parameter itself
-    (6e-8 of a norm's weight of 1 under a gradient of 1e-4), which is
-    taken off the error before it is held to `limit`."""
-    worst, kinds = {}, kinds or KINDS
-    for kind, endings in kinds.items():
-        names = [n for n in want if n.endswith(endings)]
-        assert names, kind
-        for n in names:
-            assert np.abs(want[n]).max() > 0, n
-            rounding = 1.2e-7 * np.abs(before[n]).max()
-            err = np.sqrt(np.mean((got[n] - want[n]) ** 2))
-            err = max(err - rounding, 0.0) / np.sqrt(np.mean(want[n] ** 2))
-            worst[kind] = max(worst.get(kind, 0.0), float(err))
-    classed = {n for n in want if any(n.endswith(e) for e in kinds.values())}
-    untrained = sorted(set(want) - classed)
-    assert all(n.endswith(".moe.bias") for n in untrained), untrained
-    for n in untrained:  # the router's correction is not the optimizer's
-        assert not np.abs(got[n]).any(), n
-    over = {k: v for k, v in worst.items()
-            if v >= (limit if k not in ROUTED else routed_limit or limit)}
-    assert not over, (over, worst)
-    return worst
+SUITE = Suite(  # noqa: F405
+    CELL, adapter, kinds=KINDS,
+    mixers=("kda", "latent", "dense", "experts"),
+    mixer_program=_mixer_program, want_mixer=_want_mixer,
+    # the reference with its last layer left out, or without the delta
+    # rule's write (beta = 0): twice the cell's limit and more
+    wrong={"drop_layers": caught(amp=2, drop_layers=1),  # noqa: F405
+           "no_delta": caught(amp=2, no_delta=True)},  # noqa: F405
+    # under AMP the cell's own tolerance holds here, loss and all
+    amp_loss_room=1,
+    seed=31001, gradient_row=512, checkpointed="kda_recurrence",
+    step_counters=("kda_dispatch_pallas", "kda_dispatch_chunked",
+                   "short_conv_dispatch_pallas", "attn_dispatch_flash",
+                   "moe_dispatch_grouped", "moe_dispatch_gmm"),
+    gauges=("moe_block_rows", "moe_experts_held", "moe_experts_total",
+            "kda_lockstep_chunks"))
 
 
-def _gradients(model, traffic, place=None, seed=3):
-    """{name: gradient} of the program's train step (one SGD step at rate
-    1: the gradient is what the parameter lost) and of `jax.grad` of the
-    reference's loss, from the same seeded state and batch."""
-    import paddle_tpu as fluid
-    from benchmark.models import kimi_linear as adapter
-    from benchmark.runners import train_loop
-
-    model = dict(model, optimizer={"type": "SGD", "learning_rate": 1.0})
-    main, startup, built, _ = train_loop.build_programs(
-        fluid, adapter, model, traffic, seed)
-    exe = fluid.Executor(place or fluid.CPUPlace())
-    exe.run(startup)
-    names = [p.name for p in main.global_block().all_parameters()]
-    before = state(names)
-    batch = batch_for(model, traffic)
-    exe.run(main, feed=batch, fetch_list=[built["loss"]])
-    got = {n: before[n] - v for n, v in state(names).items()}
-    scope = fluid.global_scope()
-    for n in list(scope.local_names()):  # the device is the reference's now
-        scope.delete(n)
-    return got, _grad_of_reference(before, batch, model), before
+# --------------------------------------------- chunked KDA, the math
 
 
-def test_one_train_steps_gradients_equal_jax_grad_of_the_reference():
-    model, traffic = cell(precision="float32")
-    check_gradients(*_gradients(model, dict(traffic, seq_len=80)), 2e-4)
+@pytest.mark.parametrize("length,g_lo,g_hi", [
+    (64, -0.1, -0.001),  # one whole chunk, mild decay
+    (128, -1.0, -0.01),  # two whole chunks
+    (100, -8.0, -3.0),  # decays near 0: exp(-G) would overflow in a chunk
+    (200, -1e-4, -1e-6),  # decays near 1: the state forgets nothing
+    (37, -20.0, 0.0),  # shorter than a chunk, both extremes in one row
+    (130, -2.0, -0.01),  # two tokens into a third chunk
+])
+def test_chunked_kda_equals_the_token_recurrence(length, g_lo, g_hi):
+    from paddle_tpu.ops.linear_attn_ops import kda_chunked
+
+    r = np.random.RandomState(length)
+    b, h, dk, dv = 2, 3, 16, 8
+
+    def unit(t):
+        return t / np.linalg.norm(t, axis=-1, keepdims=True)
+
+    args = [np.asarray(t, np.float32) for t in (
+        unit(r.randn(b, length, h, dk)), unit(r.randn(b, length, h, dk)),
+        r.randn(b, length, h, dv), r.uniform(g_lo, g_hi, (b, length, h, dk)),
+        r.uniform(0, 1, (b, length, h)))]
+    got, g_got = value_and_grads(kda_chunked, args)
+    want, g_want = value_and_grads(adapter.kda_recurrence, args)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    for a, w in zip(g_got, g_want):
+        assert np.isfinite(a).all()
+        assert rel(a, w) < 1e-4
+
+
+# ------------------------------------------ the flash kernel, by name
+
+
+def test_latent_attention_through_the_flash_kernel(monkeypatch, attn_path):
+    """The blocked kernel, interpreted, with values narrower than the
+    keys: forced by name, since the CPU's dispatch never chooses it."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    attn_path("flash")
+    m = SUITE.mixer("latent", batch=1, seq=160, seed=2)
+    assert m.bumped("attn_dispatch_flash") == 1
+    assert rel(m.got, m.want()) < 2e-5
 
 
 # -------------------------------------------------- the expert layer
-
-
-def _expert_params(r, hidden, width, total, bias_scale=0.1):
-    return {
-        "m.moe.gate": r.randn(hidden, total).astype(np.float32) * 0.3,
-        "m.moe.bias": r.randn(total).astype(np.float32) * bias_scale,
-        "m.moe.w_gate": r.randn(total, hidden, width).astype(np.float32) * 0.2,
-        "m.moe.w_up": r.randn(total, hidden, width).astype(np.float32) * 0.2,
-        "m.moe.w_down": r.randn(total, width, hidden).astype(np.float32) * 0.2,
-        "m.shared.gate.w_0": r.randn(hidden, width).astype(np.float32) * 0.2,
-        "m.shared.up.w_0": r.randn(hidden, width).astype(np.float32) * 0.2,
-        "m.shared.down.w_0": r.randn(width, hidden).astype(np.float32) * 0.2,
-    }
 
 
 def _shares(p, u, total, held, k, bias=True):
@@ -393,17 +180,17 @@ def test_the_shares_add_up_to_the_uncut_layer(total, held, k):
     product; with 2 of 8 they need the full one."""
     r = np.random.RandomState(total)
     hidden, width = 16, 8
-    p = _expert_params(r, hidden, width, total)
+    p = expert_params(r, hidden, width, total)
     u = r.randn(2, 24, hidden).astype(np.float32)
     outs, loads = _shares(p, u, total, held, k)
     assert int(np.sum(loads)) == u.shape[0] * u.shape[1] * k
-    shared = highest(ref._ffn, p, u, "m.shared")
-    uncut = highest(ref.expert_ffn, p, u, "m", _layer_model(total, k))
+    shared = highest(adapter._ffn, p, u, "m.shared")
+    uncut = highest(adapter.expert_ffn, p, u, "m", _layer_model(total, k))
     assert rel(shared + sum(outs), uncut) < 1e-5
     # and one share alone is the reference's share
     p_share = dict(p, **{f"m.moe.{w}": p[f"m.moe.{w}"][held:2 * held]
                          for w in ("w_gate", "w_up", "w_down")})
-    one = highest(ref.expert_ffn, p_share, u, "m",
+    one = highest(adapter.expert_ffn, p_share, u, "m",
                   _layer_model(held, k, held_from=held))
     assert rel(shared + outs[1], one) < 1e-5
 
@@ -413,7 +200,7 @@ def test_dropless_under_skew():
     balanced router would send them, and nothing is dropped."""
     r = np.random.RandomState(5)
     total, held, k, hidden, width = 32, 2, 2, 16, 8
-    p = _expert_params(r, hidden, width, total)
+    p = expert_params(r, hidden, width, total)
     p["m.moe.bias"] = np.where(np.arange(total) < held, 10.0, 0.0).astype(
         np.float32)
     u = r.randn(2, 40, hidden).astype(np.float32)
@@ -421,8 +208,8 @@ def test_dropless_under_skew():
     tokens = u.shape[0] * u.shape[1]
     assert loads[0].tolist() == [tokens, tokens]  # each token, both experts
     assert all(int(np.sum(load)) == 0 for load in loads[1:])
-    want = highest(ref.expert_ffn, p, u, "m", _layer_model(held, k))
-    shared = highest(ref._ffn, p, u, "m.shared")
+    want = highest(adapter.expert_ffn, p, u, "m", _layer_model(held, k))
+    shared = highest(adapter._ffn, p, u, "m.shared")
     assert rel(shared + outs[0], want) < 1e-5
     assert all(not np.abs(o).any() for o in outs[1:])
 
@@ -654,10 +441,9 @@ def test_short_conv_backward_written_out_is_the_vjp_of_the_taps(bias):
 
 
 def test_counters_and_flops_of_the_cell():
-    from benchmark.models import kimi_linear as adapter
     from paddle_tpu import profiler
 
-    model, traffic = cell(rehearse=False)
+    model, traffic = SUITE.cell(rehearse=False)
     assert (traffic["batch"], traffic["seq_len"]) == (1, 4096)
     # ISSUE 31's arithmetic: 335.6M matrix parameters a token, 2.01 GFLOP
     # trained, 602.4M parameters held
@@ -667,11 +453,29 @@ def test_counters_and_flops_of_the_cell():
     flops = adapter.flops_per_example(model, traffic)
     assert 8.0e12 < flops < 9.5e12
     c0 = profiler.counters()
-    small, small_traffic = cell()
-    main, _, built, exe, _ = built_model(small, small_traffic)
-    batch = batch_for(small, small_traffic)
-    loads = exe.run(main, feed=batch, fetch_list=built["loads"])
+    small, small_traffic = SUITE.cell()
+    main, eval_prog, built, exe, _ = SUITE.built_model(small, small_traffic)
+    batch = SUITE.batch_for(small, small_traffic)
+    exe.run(eval_prog, feed=batch, fetch_list=built["check"])
+    _, *loads = exe.run(main, feed=batch,
+                        fetch_list=[built["loss"]] + built["loads"])
     c1 = profiler.counters()
+    # a set-up traces 1,010 ops (startup, the `for_test` clone, the train
+    # step: `traced_ops` on the chip), and bumps no counter that is another
+    # decoder's: no softmax router, no scaled or paired positions, no
+    # compressed query, no second loss term, no convolution without a SiLU
+    assert c1["program_traced_ops"] - c0.get("program_traced_ops", 0) == 1010
+    for other in ("moe_route_softmax", "attn_rope_scaled", "mtp_depth",
+                  "attn_latent_q_lora", "rope_interleaved", "loss_terms",
+                  "short_conv_linear_calls"):
+        assert c1.get(other, 0) == c0.get(other, 0), other
+    experts = [op for op in main.global_block().ops
+               if op.type == "moe_experts"]
+    assert all(op.attr("score_func") == "sigmoid" for op in experts)
+    # the rehearsal's shares are 1/4 (2 of 8 experts): a block of 7/16
+    tokens = small_traffic["batch"] * small_traffic["seq_len"]
+    assert c1["moe_block_rows"] == math.ceil(
+        1.75 * 0.25 * tokens * experts[0].attr("k"))
     assert c1["kda_dispatch_chunked"] - c0.get("kda_dispatch_chunked", 0) >= 4
     grouped = c1["moe_dispatch_grouped"] - c0.get("moe_dispatch_grouped", 0)
     assert grouped >= 4
@@ -686,35 +490,4 @@ def test_counters_and_flops_of_the_cell():
 
 
 if __name__ == "__main__":
-    # On the attached TPU: the gradients of every kind of parameter at the
-    # published widths, float32 program against jax.grad of the reference,
-    # on one 512-token row.
-    import jax
-
-    import paddle_tpu as fluid
-
-    assert jax.devices()[0].platform == "tpu", jax.devices()
-    # How the reference is differentiated, not what it computes: the token
-    # recurrence keeps a [32, 128, 128] state a token for its backward,
-    # 12 GB over four layers of 512 tokens; rebuilt a layer at a time it
-    # is 3 GB.
-    ref.kda_recurrence = jax.checkpoint(ref.kda_recurrence)
-    model, traffic = cell(rehearse=False, precision="float32")
-    traffic = dict(traffic, seq_len=512)
-    # float32 on a TPU is a bf16 pass a product unless told otherwise, so
-    # the "float32" program is held to 5%, the AMP one to 20%
-    for precision, limit, routed in (("float32", 0.05, 0.3),
-                                     ("bf16_amp", 0.2, 0.6)):
-        with fluid.program_guard(fluid.Program(), fluid.Program()), \
-                fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
-            got, want, before = _gradients(
-                dict(model, precision=precision), traffic,
-                place=fluid.TPUPlace())
-        try:
-            worst = check_gradients(got, want, before, limit, routed)
-        except AssertionError as e:
-            print(f"FAIL {precision}: {e}", flush=True)
-            raise
-        print(f"gradients at the published widths, s=512, {precision}: "
-              "worst relative error by kind "
-              + ", ".join(f"{k} {v:.2e}" for k, v in worst.items()), flush=True)
+    main(SUITE)

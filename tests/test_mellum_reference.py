@@ -1,18 +1,17 @@
-"""Mellum 2 against its plain reference (`tests/mellum_reference.py`) at the
-rehearsal size of the cell `mellum2_ep4_s8192`: the attention mixer, window
-and full (YaRN), and the whole model; one train step's gradients for every
-kind of parameter; that each wrong model is caught by the cell's tolerance;
-YaRN's tables against the formulas written out by hand; the softmax router
-against a hand count and the sigmoid router against the parent's lines; the
-four shares against the uncut layer; the block that follows the share held,
-and a load past it; Trinity's and Kimi's Programs held to their op lists;
+"""Mellum 2 against its plain reference (`benchmark/models/mellum.py`) at the
+rehearsal size of the cell `mellum2_ep4_s8192`: what every decoder suite
+holds (`tests/decoder_suite.py`: the attention mixer, window and full
+(YaRN), the expert layer and the whole model; one train step's gradients
+for every kind of parameter; that each wrong model is caught by the cell's
+tolerance) on this model's data, and its own: YaRN's tables against the
+formulas written out by hand; the softmax router against a hand count and
+the sigmoid router against the parent's lines; the four shares against the
+uncut layer; the block that follows the share held, and a load past it;
 the cell's counters and FLOPs.
 
-Run as a script, on the attached TPU at the published widths and outside
-any timed window: the program against the reference, against each wrong
-model and against the reference with fp8 matrices (the readings that place
-`TOLERANCE`); the share of the assignments each layer's held experts take,
-step by step, beside the first block's rows (what sized `_block_rows`); the
+Run as a script on the attached TPU (`tests/decoder_suite.py` has the
+arguments): the readings that place `TOLERANCE`, the share of the
+assignments each layer's held experts take (what sized `_block_rows`), the
 gradient comparison on one 1,024-token row:
 
     python3 tests/test_mellum_reference.py readings [seed ...]
@@ -22,51 +21,17 @@ gradient comparison on one 1,024-token row:
 
 from __future__ import annotations
 
-import inspect
 import math
-import os
-import sys
 
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from decoder_suite import *  # noqa: F401,F403 — the shared cases, on SUITE
+from decoder_suite import highest, main, rel
 
-import mellum_reference as ref  # noqa: E402 — beside this file
-from test_kimi_linear_reference import (  # noqa: E402 — the shared helpers
-    check_gradients, f32, highest, rel, state)
+from benchmark.models import mellum as adapter  # noqa: E402
 
 CELL = "mellum2_ep4_s8192"
-
-
-def cell(rehearse=True, **config):
-    from benchmark.harness import spec
-
-    c = spec.cell(CELL, rehearse=rehearse)
-    c["config"].update(config)
-    return c["config"], c["traffic"]
-
-
-def built_model(model, traffic, seed=3):
-    """Programs, executor and the seeded state by name, in a scope of its
-    own (the caller holds the guards)."""
-    import paddle_tpu as fluid
-    from benchmark.models import mellum as adapter
-    from benchmark.runners import train_loop
-
-    main, startup, built, eval_prog = train_loop.build_programs(
-        fluid, adapter, model, traffic, seed)
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup)
-    names = [p.name for p in main.global_block().all_parameters()]
-    return main, eval_prog, built, exe, names
-
-
-def batch_for(model, traffic, seed=0):
-    from benchmark.models import mellum as adapter
-
-    return adapter.make_batch(np.random.RandomState(seed), model, traffic)
-
 
 # At 64 wide, seeded as the cell is (matrices Normal(0, 0.02), the
 # embedding Normal(0, 2)), a layer adds a thousandth of the residual stream
@@ -78,18 +43,67 @@ def batch_for(model, traffic, seed=0):
 AS_AT_WIDTH = {"initializer_range": 0.1, "embedding_initializer_range": 0.3}
 
 
-# ------------------------------------------------- the copy is a copy
+
+def _mixer_program(which, model, batch, seq):
+    """The attention mixer or the expert layer alone in a Program: `u` in,
+    `y` out."""
+    import paddle_tpu as fluid
+    from paddle_tpu.models import decoder_parts
+
+    cfg = adapter.config(model)
+    u = fluid.layers.data("u", [batch, seq, cfg.hidden_size],
+                          append_batch_size=False)
+    if which == "experts":
+        return decoder_parts.expert_ffn(u, cfg, "m")[0]
+    kind = {"window": "sliding_attention", "full": "full_attention"}[which]
+    rope = cfg.rope_parameters[kind]
+    return decoder_parts.attention(
+        u, cfg, "m", window=cfg.sliding_window if which == "window" else 0,
+        rope_theta=rope["rope_theta"], rope_scaling=rope)
 
 
-def test_reference_copy_is_the_adapters_word_for_word():
-    from benchmark.models import mellum as adapter
+def _want_mixer(which, p, feeds, model, wrong=()):
+    u, rope = feeds["u"], model["rope_parameters"]
+    if which == "experts":
+        return highest(adapter.expert_ffn, p, u, "m", model)
+    if which == "window":
+        return highest(adapter.attention_mixer, p, u, "m", model,
+                       model["sliding_window"], rope["sliding_attention"])
+    return highest(adapter.attention_mixer, p, u, "m", model, 0,
+                   rope["full_attention"])
 
-    for name in ("held_layers", "_rms", "_silu", "yarn", "_rope",
-                 "attention_mixer", "expert_ffn", "reference"):
-        assert inspect.getsource(getattr(ref, name)) == inspect.getsource(
-            getattr(adapter, name)), name
-    assert (ref.SCORED_EVERY, ref.QUERY_BLOCK) == (
-        adapter.SCORED_EVERY, adapter.QUERY_BLOCK)
+
+KINDS = {
+    "embedding": ("mellum.embed",), "head": ("mellum.head.w_0",),
+    "rms_norm": (".input_norm.w_0", ".post_attn_norm.w_0", "final_norm.w_0"),
+    "qk_norm": (".q_norm.w_0", ".k_norm.w_0"),
+    "attention": (".attn.q.w_0", ".attn.k.w_0", ".attn.v.w_0",
+                  ".attn.o.w_0"),
+    "router": (".moe.gate",),
+    "experts": (".moe.w_gate", ".moe.w_up", ".moe.w_down"),
+}
+
+SUITE = Suite(  # noqa: F405
+    CELL, adapter, kinds=KINDS, as_at_width=AS_AT_WIDTH,
+    mixers=("window", "full", "experts"),
+    mixer_program=_mixer_program, want_mixer=_want_mixer,
+    # the reference with its last layer left out, default tables on the
+    # full layer, YaRN's tables without their factor, a sigmoid router, no
+    # renormalisation, every layer full, or no QK-norm: refused by the
+    # cell's tolerance
+    wrong={"drop_layers": caught(amp=0, drop_layers=1),  # noqa: F405
+           **{w: caught(amp=0, wrong=(w,))  # noqa: F405
+              for w in adapter.WRONG}},
+    # under AMP the cell's own tolerance holds here, loss and all
+    amp_loss_room=1,
+    seed=37001,
+    step_counters=("attn_dispatch_flash", "attn_dispatch_flash_window",
+                   "attn_qk_prep_fused", "attn_rope_scaled",
+                   "moe_dispatch_grouped", "moe_dispatch_gmm",
+                   "moe_route_softmax"),
+    gauges=("attn_kv_group", "moe_block_rows", "moe_experts_held",
+            "moe_experts_total", "flash_blocks_visited",
+            "flash_blocks_total"))
 
 
 # ------------------------------------------------------- YaRN's tables
@@ -123,7 +137,7 @@ def test_yarn_tables_equal_the_formulas_written_out_by_hand():
     freq, factor = yarn_frequencies(d, 10000.0, scaling)
     np.testing.assert_allclose(freq, want, rtol=1e-6)
     assert abs(factor - 1.1386294361119891) < 1e-15
-    _, got_factor, got_low, got_high = ref.yarn(d, HAND)
+    _, got_factor, got_low, got_high = adapter.yarn(d, HAND)
     assert (got_low, got_high, got_factor) == (low, high, factor)
     cos, sin = rotary_tables(s, d, 10000.0, scaling)
     angle = np.arange(s)[:, None] * want[None, :]
@@ -147,7 +161,7 @@ def test_the_published_ramp_runs_from_18_to_35():
     from benchmark.harness import spec
 
     rope = spec.cell(CELL)["config"]["rope_parameters"]["full_attention"]
-    freq, factor, low, high = ref.yarn(128, rope)
+    freq, factor, low, high = adapter.yarn(128, rope)
     assert (low, high, factor) == (18, 35, 1.2772588722239782)
     e = 500000.0 ** (-np.arange(64) / 64)
     np.testing.assert_allclose(freq[:19], e[:19], rtol=1e-5)
@@ -188,9 +202,9 @@ def test_rotary_embedding_op_takes_the_scaling():
         feed={"x": data}, fetch_list=[plain, scaled])
     default = {"rope_type": "default", "rope_theta": 10000.0}
     np.testing.assert_allclose(
-        got_plain, highest(ref._rope, jnp.asarray(data), default), atol=2e-5)
+        got_plain, highest(adapter._rope, jnp.asarray(data), default), atol=2e-5)
     np.testing.assert_allclose(
-        got_scaled, highest(ref._rope, jnp.asarray(data), HAND), atol=2e-5)
+        got_scaled, highest(adapter._rope, jnp.asarray(data), HAND), atol=2e-5)
     assert rel(got_scaled, got_plain) > 0.1
 
 
@@ -244,54 +258,7 @@ def test_sigmoid_router_is_the_parents_bit_for_bit():
         assert np.array_equal(idx, want_idx) and np.array_equal(w, want)
 
 
-# ------------------------------------------ the program, mixer by mixer
-
-
-def _mixer_program(which, model, batch=2, seq=80):
-    """The attention mixer or the expert layer alone in a Program: `u` in,
-    `y` out."""
-    import paddle_tpu as fluid
-    from benchmark.models import mellum as adapter
-    from paddle_tpu.models import decoder_parts
-
-    cfg = adapter.config(model)
-    u = fluid.layers.data("u", [batch, seq, cfg.hidden_size],
-                          append_batch_size=False)
-    if which == "experts":
-        y, _ = decoder_parts.expert_ffn(u, cfg, "m")
-    else:
-        kind = {"window": "sliding_attention", "full": "full_attention"}[which]
-        rope = cfg.rope_parameters[kind]
-        y = decoder_parts.attention(
-            u, cfg, "m", window=cfg.sliding_window if which == "window" else 0,
-            rope_theta=rope["rope_theta"], rope_scaling=rope)
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
-    names = [p.name for p in
-             fluid.default_main_program().global_block().all_parameters()]
-    return exe, y, names
-
-
-def _want_mixer(which, p, u, model):
-    rope = model["rope_parameters"]
-    return {"window": lambda: ref.attention_mixer(
-                p, u, "m", model, model["sliding_window"],
-                rope["sliding_attention"]),
-            "full": lambda: ref.attention_mixer(
-                p, u, "m", model, 0, rope["full_attention"]),
-            "experts": lambda: ref.expert_ffn(p, u, "m", model)}[which]
-
-
-@pytest.mark.parametrize("which", ["window", "full", "experts"])
-def test_program_mixer_equals_reference(which):
-    model, _ = cell(**AS_AT_WIDTH)
-    exe, y, names = _mixer_program(which, model)
-    u = np.random.RandomState(1).randn(2, 80, model["hidden_size"]).astype(
-        np.float32)
-    (got,) = exe.run(feed={"u": u}, fetch_list=[y])
-    want = highest(_want_mixer(which, state(names), u, model))
-    assert np.abs(want).max() > 1e-4  # something was computed
-    assert rel(got, want) < 2e-5
+# ------------------------------------------ the kernels, by name
 
 
 @pytest.mark.parametrize("which", ["window", "full"])
@@ -303,127 +270,13 @@ def test_attention_through_the_flash_and_qk_prep_kernels(which, monkeypatch,
     YaRN's tables as the window layer's take the plain ones."""
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
     attn_path("flash")
-    from paddle_tpu import profiler
-
-    before = profiler.counters()
-    model, _ = cell(sliding_window=50, head_dim=128)
-    exe, y, names = _mixer_program(which, model, batch=1, seq=160)
-    u = np.random.RandomState(2).randn(1, 160, model["hidden_size"]).astype(
-        np.float32)
-    (got,) = exe.run(feed={"u": u}, fetch_list=[y])
-    after = profiler.counters()
-
-    def bumped(name):
-        return after.get(name, 0) - before.get(name, 0)
-
-    assert bumped("attn_dispatch_flash") == 1
-    assert bumped("attn_qk_prep_fused") == 1
-    assert bumped("attn_dispatch_flash_window") == (which == "window")
-    assert bumped("attn_rope_scaled") == (which == "full")
-    want = highest(_want_mixer(which, state(names), u, model))
-    assert rel(got, want) < 2e-5
-
-
-# ------------------------------------------------------ the whole model
-
-
-@pytest.mark.parametrize("precision,limit", [("float32", 5e-5),
-                                             ("bf16_amp", None)])
-def test_whole_model_logits_and_loss_equal_reference(precision, limit):
-    from benchmark.models import mellum as adapter
-    from benchmark.runners import train_loop
-
-    model, traffic = cell(precision=precision, **AS_AT_WIDTH)
-    traffic = dict(traffic, seq_len=80)
-    _, eval_prog, built, exe, names = built_model(model, traffic)
-    batch = batch_for(model, traffic)
-    got_loss, got_logits = exe.run(eval_prog, feed=batch,
-                                   fetch_list=built["check"])
-    nll, count, want = highest(ref.reference, state(names), batch, model)
-    check = train_loop.check_reference(
-        got_loss, got_logits, nll / count, want[:adapter.SCORED_SEQUENCES],
-        adapter.TOLERANCE)
-    assert check["ok"], check
-    if limit:
-        assert check["logits_rel_rms"] < limit and check["loss_abs"] < 1e-5
-
-
-@pytest.mark.parametrize("wrong", [
-    {"drop_layers": 1}, {"wrong": ("no_yarn",)},
-    {"wrong": ("no_attention_factor",)}, {"wrong": ("sigmoid_router",)},
-    {"wrong": ("no_renorm",)}, {"wrong": ("all_full",)},
-    {"wrong": ("no_qk_norm",)}])
-def test_a_wrong_model_is_caught_by_the_cells_tolerance(wrong):
-    """The reference with its last layer left out, default tables on the
-    full layer, YaRN's tables without their factor, a sigmoid router, no
-    renormalisation, every layer full, or no QK-norm, against the program
-    in the cell's precision."""
-    from benchmark.models import mellum as adapter
-    from benchmark.runners import train_loop
-
-    model, traffic = cell(**AS_AT_WIDTH)
-    _, eval_prog, built, exe, names = built_model(model, traffic)
-    batch = batch_for(model, traffic)
-    got_loss, got_logits = exe.run(eval_prog, feed=batch,
-                                   fetch_list=built["check"])
-    p = state(names)
-    for kw, ok in ((wrong, False), ({}, True)):
-        nll, count, want = highest(adapter.reference, p, batch, model, **kw)
-        check = train_loop.check_reference(
-            got_loss, got_logits, nll / count,
-            want[:adapter.SCORED_SEQUENCES], adapter.TOLERANCE)
-        assert check["ok"] is ok, (kw, check)
-
-
-# ------------------------------------------------ one step's gradients
-
-KINDS = {
-    "embedding": ("mellum.embed",), "head": ("mellum.head.w_0",),
-    "rms_norm": (".input_norm.w_0", ".post_attn_norm.w_0", "final_norm.w_0"),
-    "qk_norm": (".q_norm.w_0", ".k_norm.w_0"),
-    "attention": (".attn.q.w_0", ".attn.k.w_0", ".attn.v.w_0",
-                  ".attn.o.w_0"),
-    "router": (".moe.gate",),
-    "experts": (".moe.w_gate", ".moe.w_up", ".moe.w_down"),
-}
-
-
-def _grad_of_reference(before, batch, model):
-    import jax
-
-    with jax.default_matmul_precision("highest"):
-        return f32(jax.jit(jax.grad(
-            lambda p: ref.loss(p, batch, model)))(before))
-
-
-def _gradients(model, traffic, place=None, seed=3):
-    """{name: gradient} of the program's train step (one SGD step at rate
-    1: the gradient is what the parameter lost) and of `jax.grad` of the
-    reference's loss, from the same seeded state and batch."""
-    import paddle_tpu as fluid
-    from benchmark.models import mellum as adapter
-    from benchmark.runners import train_loop
-
-    model = dict(model, optimizer={"type": "SGD", "learning_rate": 1.0})
-    main, startup, built, _ = train_loop.build_programs(
-        fluid, adapter, model, traffic, seed)
-    exe = fluid.Executor(place or fluid.CPUPlace())
-    exe.run(startup)
-    names = [p.name for p in main.global_block().all_parameters()]
-    before = state(names)
-    batch = batch_for(model, traffic)
-    exe.run(main, feed=batch, fetch_list=[built["loss"]])
-    got = {n: before[n] - v for n, v in state(names).items()}
-    scope = fluid.global_scope()
-    for n in list(scope.local_names()):  # the device is the reference's now
-        scope.delete(n)
-    return got, _grad_of_reference(before, batch, model), before
-
-
-def test_one_train_steps_gradients_equal_jax_grad_of_the_reference():
-    model, traffic = cell(precision="float32", **AS_AT_WIDTH)
-    check_gradients(*_gradients(model, dict(traffic, seq_len=80)), 2e-4,
-                    kinds=KINDS)
+    m = SUITE.mixer(which, batch=1, seq=160, seed=2,
+                    config={"sliding_window": 50, "head_dim": 128})
+    assert m.bumped("attn_dispatch_flash") == 1
+    assert m.bumped("attn_qk_prep_fused") == 1
+    assert m.bumped("attn_dispatch_flash_window") == (which == "window")
+    assert m.bumped("attn_rope_scaled") == (which == "full")
+    assert rel(m.got, m.want()) < 2e-5
 
 
 # -------------------------------------------------- the expert layer
@@ -472,12 +325,12 @@ def test_the_4_shares_add_up_to_the_uncut_layer(total, held, k):
     assert int(np.sum(loads)) == u.shape[0] * u.shape[1] * k
     layer = {"num_experts_per_tok": k, "num_experts": total, "held_from": 0,
              "norm_topk_prob": True}
-    uncut = highest(ref.expert_ffn, p, u, "m", layer)
+    uncut = highest(adapter.expert_ffn, p, u, "m", layer)
     assert rel(sum(routed), uncut) < 1e-5
     # and one share alone is the reference's share
     p_share = dict(p, **{f"m.moe.{w}": p[f"m.moe.{w}"][held:2 * held]
                          for w in ("w_gate", "w_up", "w_down")})
-    one = highest(ref.expert_ffn, p_share, u, "m",
+    one = highest(adapter.expert_ffn, p_share, u, "m",
                   dict(layer, num_experts=held, held_from=held))
     assert rel(routed[1], one) < 1e-5
 
@@ -531,7 +384,7 @@ def test_a_load_past_the_first_block_is_still_dropless(pushed, blocks):
         return jnp.sum(y * cotangent), (y, load)
 
     def theirs(u, *w):
-        y = ref.expert_ffn(dict(p, **dict(zip(trained, w))), u, "m", model)
+        y = adapter.expert_ffn(dict(p, **dict(zip(trained, w))), u, "m", model)
         return jnp.sum(y * cotangent), y
 
     args = (u, *(p[n] for n in trained))
@@ -548,66 +401,13 @@ def test_a_load_past_the_first_block_is_still_dropless(pushed, blocks):
         assert rel(g, w) < 1e-5, name
 
 
-# --------------------------------- what the other decoders' Programs hold
-
-# the train Programs at the rehearsal size as the parent of PR 37 builds
-# them: op types in order, hashed; and the expert op's attributes
-PROGRAMS = {
-    "kimi_linear_ep32_s4096": (658, "5ea25165bcb3257a"),
-    "trinity_mini_ep16_s8192": (571, "f8f09e607b35f074"),
-}
-
-
-@pytest.mark.parametrize("cell_name", sorted(PROGRAMS))
-def test_the_other_expert_decoders_programs_are_op_for_op_what_they_were(
-        cell_name):
-    """Kimi's and Trinity's train Programs: the op list the parent builds
-    (Trinity's attention now comes from `decoder_parts.attention` with
-    the gate on), a sigmoid router on every expert op, no scaled
-    positions anywhere, and no new counter bumped by their lowering."""
-    import hashlib
-
-    import paddle_tpu as fluid
-    from benchmark.harness import spec
-    from benchmark.runners import train_loop
-    from paddle_tpu import profiler
-
-    c = spec.cell(cell_name, rehearse=True)
-    adapter = spec.plugin("models", c["config"]["adapter"])
-    main, startup, built, _ = train_loop.build_programs(
-        fluid, adapter, c["config"], c["traffic"], 3)
-    ops = main.global_block().ops
-    types = [op.type for op in ops]
-    digest = hashlib.sha256("\n".join(types).encode()).hexdigest()[:16]
-    assert (len(types), digest) == PROGRAMS[cell_name], (len(types), digest)
-    experts = [op for op in ops if op.type == "moe_experts"]
-    assert len(experts) == 4
-    assert all(op.attr("score_func") == "sigmoid" for op in experts)
-    assert not any(op.attrs.get("rope_scaling") for op in ops)
-    assert "rotary_embedding" not in types  # the one other op that scales
-    before = profiler.counters()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup)
-    batch = adapter.make_batch(np.random.RandomState(0), c["config"],
-                               c["traffic"])
-    exe.run(main, feed=batch, fetch_list=[built["loss"]])
-    after = profiler.counters()
-    for counter in ("moe_route_softmax", "attn_rope_scaled"):
-        assert after.get(counter, 0) == before.get(counter, 0), counter
-    # the rehearsal's shares are 1/4 (2 of 8 experts): a block of 7/16
-    tokens = c["traffic"]["batch"] * c["traffic"]["seq_len"]
-    k = experts[0].attr("k")
-    assert after["moe_block_rows"] == math.ceil(1.75 * 0.25 * tokens * k)
-
-
 # ----------------------------------------------- the cell's arithmetic
 
 
 def test_counters_and_flops_of_the_cell():
-    from benchmark.models import mellum as adapter
     from paddle_tpu import profiler
 
-    model, traffic = cell(rehearse=False)
+    model, traffic = SUITE.cell(rehearse=False)
     assert (traffic["batch"], traffic["seq_len"]) == (1, 8192)
     assert adapter.held_layers(model) == [
         (0, 1024, "sliding_attention"), (1, 1024, "sliding_attention"),
@@ -632,9 +432,9 @@ def test_counters_and_flops_of_the_cell():
     assert 12.0e12 < flops < 12.5e12
 
     c0 = profiler.counters()
-    small, small_traffic = cell()
-    main, _, built, exe, _ = built_model(small, small_traffic)
-    batch = batch_for(small, small_traffic)
+    small, small_traffic = SUITE.cell()
+    main, _, built, exe, _ = SUITE.built_model(small, small_traffic)
+    batch = SUITE.batch_for(small, small_traffic)
     loads = exe.run(main, feed=batch, fetch_list=built["loads"])
     c1 = profiler.counters()
 
@@ -655,139 +455,13 @@ def test_counters_and_flops_of_the_cell():
     assert c1["attn_kv_group"] == 2
     # 2 x 48 tokens x 2 a token = 192 assignments, 2 of 8 held: 7/16 of them
     assert c1["moe_block_rows"] == 84
+    # and no counter that is another decoder's: no compressed query, no
+    # rotation by pairs, no convolution at all
+    for other in ("attn_latent_q_lora", "rope_interleaved",
+                  "short_conv_linear_calls"):
+        assert c1.get(other, 0) == c0.get(other, 0), other
     assert len(loads) == 4 and all(x.shape == (2,) for x in loads)
 
 
-# ------------------------------------------------------- on the chip
-
-
-def _fp8(p):
-    """The matrices rounded to fp8 (e4m3), the nearest precision below
-    the bf16 the configuration states; norms' weights as they are."""
-    import jax.numpy as jnp
-
-    return {n: (np.asarray(jnp.asarray(v).astype(jnp.float8_e4m3fn).astype(
-        jnp.float32)) if v.ndim >= 2 else v) for n, v in p.items()}
-
-
-def chip_readings(seeds, few=2):
-    """At the published widths on the attached TPU: the cell's own check
-    (program in bf16 AMP against the float32 reference) at every seed,
-    and at the first `few` the same program against each wrong model and
-    the fp8 reference."""
-    import paddle_tpu as fluid
-    from benchmark.models import mellum as adapter
-    from benchmark.runners import train_loop
-
-    model, traffic = cell(rehearse=False)
-    for at, seed in enumerate(seeds):
-        with fluid.program_guard(fluid.Program(), fluid.Program()), \
-                fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
-            main, startup, built, eval_prog = train_loop.build_programs(
-                fluid, adapter, model, traffic, seed)
-            exe = fluid.Executor(fluid.TPUPlace())
-            exe.run(startup)
-            batch = adapter.make_batch(np.random.RandomState(seed), model,
-                                       traffic)
-            got_loss, got_logits = exe.run(eval_prog, feed=batch,
-                                           fetch_list=built["check"])
-            p = state([v.name for v in main.global_block().all_parameters()])
-        variants = [("reference", p, ())]
-        if at < few:
-            variants += [("fp8", _fp8(p), ())] + [
-                (w, p, (w,)) for w in adapter.WRONG]
-        for label, params, wrong in variants:
-            loss, logits = train_loop.reference_outputs(
-                adapter, params, batch, model, 1, wrong=wrong)
-            check = train_loop.check_reference(
-                got_loss, got_logits, loss, logits, adapter.TOLERANCE)
-            print(f"seed {seed} {label}: logits_rel_rms "
-                  f"{check['logits_rel_rms']:.5f} loss_abs "
-                  f"{check['loss_abs']:.5f} ok {check['ok']}", flush=True)
-
-
-def held_loads(seeds, steps=44, rate=None):
-    """At the published widths on the attached TPU, the cell's train step
-    on the batches its runner would feed (one check batch drawn first,
-    then the pool of 32), `steps` of them: the share of the 65,536
-    assignments that each layer's 16 held experts take, at the first
-    step, the window's first (the fifth) and the last, and the largest
-    over all steps, beside the first block's share."""
-    import paddle_tpu as fluid
-    from benchmark.models import mellum as adapter
-    from benchmark.runners import train_loop
-    from paddle_tpu import profiler
-
-    model, traffic = cell(rehearse=False)
-    if rate:  # the sweep that chose the optimizer's rate
-        model["optimizer"] = dict(model["optimizer"], learning_rate=rate)
-    total = traffic["batch"] * traffic["seq_len"] * model["num_experts_per_tok"]
-    for seed in seeds:
-        with fluid.program_guard(fluid.Program(), fluid.Program()), \
-                fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
-            main, startup, built, _ = train_loop.build_programs(
-                fluid, adapter, model, traffic, seed)
-            exe = fluid.Executor(fluid.TPUPlace())
-            exe.run(startup)
-            rng = np.random.RandomState(seed)
-            adapter.make_batch(rng, model, traffic)  # the check's batch
-            pool = [adapter.make_batch(rng, model, traffic)
-                    for _ in range(traffic["pool_batches"])]
-            shares, losses = [], []
-            for i in range(steps):
-                loss, *loads = exe.run(
-                    main, feed=pool[i % len(pool)],
-                    fetch_list=[built["loss"]] + built["loads"])
-                losses.append(float(np.asarray(loss).reshape(-1)[0]))
-                shares.append([float(np.sum(x)) / total for x in loads])
-        shares = np.array(shares)
-        rows = profiler.counters()["moe_block_rows"]
-
-        def row(values):
-            return " ".join(f"{v:.4f}" for v in values)
-
-        print(f"seed {seed} rate {model['optimizer']['learning_rate']}: "
-              f"block {rows} rows = {rows / total:.4f} of "
-              f"{total}; held share by layer, step 0: {row(shares[0])}; "
-              f"step 4: {row(shares[4])}; step {steps - 1}: "
-              f"{row(shares[-1])}; largest: {row(shares.max(0))}; loss "
-              f"{losses[0]:.4f} -> {losses[-1]:.4f}, fall "
-              f"{np.median(losses[4:14]) - np.median(losses[-10:]):.4f}",
-              flush=True)
-
-
-def chip_gradients():
-    """The gradients of every kind of parameter at the published widths,
-    program against `jax.grad` of the reference, on one 1,024-token row."""
-    import paddle_tpu as fluid
-
-    model, traffic = cell(rehearse=False, precision="float32")
-    traffic = dict(traffic, seq_len=1024)
-    # float32 on a TPU is a bf16 pass a product unless told otherwise, so
-    # the "float32" program is held to 5%, the AMP one to 20%
-    for precision, limit, routed in (("float32", 0.05, 0.3),
-                                     ("bf16_amp", 0.2, 0.6)):
-        with fluid.program_guard(fluid.Program(), fluid.Program()), \
-                fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
-            got, want, before = _gradients(
-                dict(model, precision=precision), traffic,
-                place=fluid.TPUPlace())
-        try:
-            worst = check_gradients(got, want, before, limit, routed, KINDS)
-        except AssertionError as e:
-            print(f"FAIL {precision}: {e}", flush=True)
-            raise
-        print(f"gradients at the published widths, s=1024, {precision}: "
-              "worst relative error by kind "
-              + ", ".join(f"{k} {v:.2e}" for k, v in worst.items()), flush=True)
-
-
 if __name__ == "__main__":
-    import jax
-
-    assert jax.devices()[0].platform == "tpu", jax.devices()
-    what, _, rate = sys.argv[1].partition("@")
-    seeds = [int(a) for a in sys.argv[2:]] or [37001]
-    {"readings": lambda: chip_readings(seeds),
-     "loads": lambda: held_loads(seeds, rate=float(rate) if rate else None),
-     "gradients": chip_gradients}[what]()
+    main(SUITE)

@@ -7,44 +7,13 @@ number of blocks, to `jax.grad` of the plain reference."""
 
 from __future__ import annotations
 
-import re
-
 import numpy as np
 import pytest
 
-import kimi_linear_reference as ref  # beside this file
-from test_kimi_linear_reference import _expert_params, highest, rel
+from decoder_suite import expert_params, highest, rel
+from kernel_cases import compiled, in_and_out_of_whiles, loss_grads
 
-
-def in_and_out_of_whiles(hlo, is_product):
-    """(outside, inside): the instructions of an optimised HLO module that
-    `is_product(line)` admits, in no `while`'s body, and in some body or a
-    computation called from one."""
-    lines, name = {}, None
-    for line in hlo.splitlines():
-        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
-        if head:
-            name = head[1]
-            lines[name] = []
-        elif name:
-            lines[name].append(line)
-    inside, todo = set(), [
-        c for body in lines.values() for line in body if " while(" in line
-        for c in re.findall(r"(?:condition|body)=%?([\w.\-]+)", line)]
-    while todo:
-        c = todo.pop()
-        if c not in inside:
-            inside.add(c)
-            todo += re.findall(
-                r"(?:condition|body|to_apply|calls)=%?([\w.\-]+)",
-                "\n".join(lines[c]))
-            for group in re.findall(r"branch_computations=\{([^}]*)\}",
-                                    "\n".join(lines[c])):
-                todo += [c.strip(" %") for c in group.split(",")]
-    count = {c: sum(bool(is_product(line)) for line in body)
-             for c, body in lines.items()}
-    within = sum(n for c, n in count.items() if c in inside)
-    return sum(count.values()) - within, within
+from benchmark.models import kimi_linear as ref
 
 
 def test_one_layers_train_step_makes_the_first_blocks_products_once():
@@ -121,7 +90,7 @@ def test_value_and_gradients_equal_the_reference_at_every_block_count(
 
     r = np.random.RandomState(7)
     hidden, width = 16, 8
-    p = _expert_params(r, hidden, width, TOTAL)
+    p = expert_params(r, hidden, width, TOTAL)
     p = {n: v[:held] if n.startswith("m.moe.w_") else v for n, v in p.items()}
     p["m.moe.bias"][:held] += correction
     u = r.randn(TOKENS, hidden).astype(np.float32)
@@ -313,13 +282,12 @@ def test_ungated_experts_on_a_second_input_equal_a_loop_over_the_experts(
         return y
 
     args = (c["x"], c["l"], c["gate"], c["w_up"], c["w_down"])
-    with jax.default_matmul_precision("highest"):
-        (y, load), want = layer(*args), loop(*args)
-        assert y.shape == c["l"].shape and int(load.sum()) > (rows or 0)
-        assert rel(y, want) < 1e-5
-        cot = jnp.asarray(np.random.RandomState(1).randn(*y.shape), jnp.float32)
-        grads = [jax.grad(lambda *a: jnp.sum(fn(*a) * cot), argnums=range(5))(
-            *args) for fn in (lambda *a: layer(*a)[0], loop)]
+    (y, load), want = compiled(layer, *args), compiled(loop, *args)
+    assert y.shape == c["l"].shape and int(load.sum()) > (rows or 0)
+    assert rel(y, want) < 1e-5
+    cot = jnp.asarray(np.random.RandomState(1).randn(*y.shape), jnp.float32)
+    grads = [loss_grads(fn, args, cot)
+             for fn in (lambda *a: layer(*a)[0], loop)]
     for name, g, g_want in zip(("x", "latent", "gate", "w_up", "w_down"),
                                *grads):
         assert rel(g, g_want) < 1e-5, name

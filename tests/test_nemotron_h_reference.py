@@ -1,13 +1,17 @@
-"""Nemotron-H against its plain reference (`tests/nemotron_h_reference.py`)
-at the rehearsal size of the cell `nemotron3_super_ep64_s4096`: every kind
-of block alone, the whole model in float32 and under bf16 AMP, one train
-step's gradients for every kind of parameter, that each wrong model is
-caught, the shares against the uncut layers (eight head shares of a
-Mamba-2 mixer and of an attention layer, sixty-four expert shares with the
-shared expert counted once, the vocabulary's slices), the gauges and
-counters, and the cell's arithmetic.
+"""Nemotron-H against its plain reference (`benchmark/models/nemotron_h.py`)
+at the rehearsal size of the cell `nemotron3_super_ep64_s4096`: what every
+decoder suite holds (`tests/decoder_suite.py`: every kind of block alone,
+the whole model in float32 and under bf16 AMP, one train step's gradients
+for every kind of parameter, that each wrong model is caught) on this
+model's data, and its own: the published pattern, the Mamba-2 mixer with
+one group on a ragged chunk, the expert layer through the grouped kernels,
+the shares against the uncut layers (eight head shares of a Mamba-2 mixer
+and of an attention layer, sixty-four expert shares with the shared expert
+counted once, the vocabulary's slices), the gauges and counters, and the
+cell's arithmetic.
 
-Run as a script on the attached TPU, outside any timed window:
+Run as a script on the attached TPU, outside any timed window
+(`tests/decoder_suite.py` has the arguments):
 
     python3 tests/test_nemotron_h_reference.py readings 1 2   # program, wrong models and fp8 reference against the reference
     python3 tests/test_nemotron_h_reference.py loads@3e-6 1 2   # held share by expert layer and the loss over the window's steps at a rate
@@ -16,66 +20,17 @@ Run as a script on the attached TPU, outside any timed window:
 
 from __future__ import annotations
 
-import inspect
-import os
-import sys
 from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from decoder_suite import *  # noqa: F401,F403 — the shared cases, on SUITE
+from decoder_suite import guards, highest, main, rel
 
-import nemotron_h_reference as ref  # noqa: E402 — beside this file
-from test_kimi_linear_reference import (  # noqa: E402 — the shared helpers
-    check_gradients, f32, highest, rel, state)
-from test_mellum_reference import _fp8  # noqa: E402 — the matrices in e4m3
+from benchmark.models import nemotron_h as adapter  # noqa: E402
 
 CELL = "nemotron3_super_ep64_s4096"
-
-
-def cell(rehearse=True, **config):
-    from benchmark.harness import spec
-
-    c = spec.cell(CELL, rehearse=rehearse)
-    c["config"].update(config)
-    return c["config"], c["traffic"]
-
-
-def _move_norms(names, seed):
-    """The norms' weights off their seeded 1 and the skip's `D` off its,
-    so that a norm left out or a weight shared where it is a channel's own
-    shows."""
-    import paddle_tpu as fluid
-
-    scope, r = fluid.global_scope(), np.random.RandomState(seed)
-    for n in names:
-        if "norm" in n or n.endswith(".D"):
-            scope.set(n, r.uniform(0.5, 1.5, np.shape(scope.get(n))).astype(
-                np.float32))
-
-
-def built_model(model, traffic, seed=3):
-    """Programs, executor and the seeded state by name, in a scope of its
-    own (the caller holds the guards)."""
-    import paddle_tpu as fluid
-    from benchmark.models import nemotron_h as adapter
-    from benchmark.runners import train_loop
-
-    main, startup, built, eval_prog = train_loop.build_programs(
-        fluid, adapter, model, traffic, seed)
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup)
-    names = [p.name for p in main.global_block().all_parameters()]
-    _move_norms(names, seed)
-    return main, eval_prog, built, exe, names
-
-
-def batch_for(model, traffic, seed=0):
-    from benchmark.models import nemotron_h as adapter
-
-    return adapter.make_batch(np.random.RandomState(seed), model, traffic)
-
 
 # At 64 wide, seeded as the cell is (matrices Normal(0, 0.02), a block's
 # last product 13 times less), a block adds next to nothing to the residual
@@ -85,26 +40,105 @@ def batch_for(model, traffic, seed=0):
 AS_AT_WIDTH = {"initializer_range": 0.1, "rescale_prenorm_residual": False}
 
 
-# ------------------------------------------------- the copy is a copy
+
+def _mixer_program(which, model, batch, seq):
+    """A mixer or an expert layer alone in a Program: `u` in, `y` out."""
+    import paddle_tpu as fluid
+    from paddle_tpu.models import decoder_parts
+
+    cfg = adapter.config(model)
+    u = fluid.layers.data("u", [batch, seq, cfg.hidden_size],
+                          append_batch_size=False)
+    if which == "mamba2":
+        return decoder_parts.mamba2_mixer(u, cfg, "m")
+    if which == "attention":
+        return decoder_parts.attention(u, cfg, "m", qk_norm=False)
+    return decoder_parts.expert_ffn(u, cfg, "m", norm_eps=1e-20)[0]
 
 
-def test_reference_copy_is_the_adapters_word_for_word():
-    from benchmark.models import nemotron_h as adapter
+def _want_mixer(which, p, feeds, model, wrong=()):
+    fn = {"mamba2": adapter.mamba_mixer, "attention": adapter.attention_mixer,
+          "experts": adapter.expert_layer}[which]
+    return highest(fn, p, feeds["u"], "m", model, wrong)
 
-    for name in ("held_layers", "_rms", "_silu", "_relu2", "_rope", "_conv",
-                 "ssm_recurrence", "mamba_mixer", "attention_mixer",
-                 "expert_layer", "reference"):
-        assert inspect.getsource(getattr(ref, name)) == inspect.getsource(
-            getattr(adapter, name)), name
-    assert (ref.SCORED_EVERY, ref.QUERY_BLOCK, ref.KINDS) == (
-        adapter.SCORED_EVERY, adapter.QUERY_BLOCK, adapter.KINDS)
+
+WRONG_BY_MIXER = {
+    "mamba2": ("no_d_skip", "norm_whole", "gate_after_norm"),
+    "attention": ("positions",),
+    "experts": ("gated_expert", "router_reads_latent", "no_scaling"),
+}
+
+
+KINDS = {
+    "embedding": ("nemotron.embed",),
+    "head": ("nemotron.head.w_0",),
+    "rms_norm": (".norm.w_0", "final_norm.w_0"),
+    "W_in": (".mamba.in_proj.w_0",),
+    "conv_filter": (".mamba.conv.w_0",),
+    "conv_bias": (".mamba.conv.b_0",),
+    "A_log": (".mamba.A_log",),
+    "dt_bias": (".mamba.dt_bias",),
+    "D": (".mamba.D",),
+    "gated_norm": (".norm.group0.w_0", ".norm.group1.w_0"),
+    "W_out": (".mamba.out_proj.w_0",),
+    "attention": (".attn.q.w_0", ".attn.k.w_0", ".attn.v.w_0", ".attn.o.w_0"),
+    "router": (".moe.gate",),
+    "latent": (".latent_in.w_0", ".latent_out.w_0"),
+    "shared_expert": (".shared.up.w_0", ".shared.down.w_0"),
+    "experts": (".moe.w_up", ".moe.w_down"),
+}
+
+
+def _ungated(step):
+    assert not any(n.endswith("w_gate") for n in step.want)
+
+
+# on the chip a rank holds one group's norm, and the latent's two
+# projections see the loss through the routed experts alone, so where
+# rounding flips a selection their gradients change by a whole token's
+# worth, as the experts' do: held to the routed limit
+CHIP_KINDS = dict(KINDS, gated_norm=(".norm.group0.w_0",))
+CHIP_KINDS["experts"] += CHIP_KINDS.pop("latent")
+
+SUITE = Suite(  # noqa: F405
+    CELL, adapter, kinds=KINDS, as_at_width=AS_AT_WIDTH,
+    # the norms' weights off their seeded 1 and the skip's `D` off its, so
+    # that a norm left out or a weight shared where it is a channel's own
+    # shows
+    moved=lambda n: "norm" in n or n.endswith(".D"),
+    # 80 tokens: five chunks of the rehearsal's 16; four Mamba-2 heads in
+    # two groups, so the norm by groups and the group a head reads show
+    mixers=("mamba2", "attention", "experts"), mixer_program=_mixer_program,
+    want_mixer=_want_mixer, wrong_by_mixer=WRONG_BY_MIXER,
+    # the reference with its last block left out or with one departure of
+    # `WRONG`: against the float32 program each reads hundreds of times
+    # its limit, and against the program in the cell's precision each is
+    # refused by the cell's logits' limit
+    wrong={"drop_layers": caught(100, 1, drop_layers=1),  # noqa: F405
+           **{w: caught(100, 1, wrong=(w,))  # noqa: F405
+              for w in adapter.WRONG}},
+    on_gradients=_ungated, seed=53001, gradient_row=512,
+    checkpointed="ssm_recurrence", chip_kinds=CHIP_KINDS,
+    step_counters=("ssd_dispatch_chunked", "short_conv_dispatch_pallas",
+                   "short_conv_dispatch_xla", "attn_dispatch_flash",
+                   "attn_qk_prep_fused", "flash_bwd_fused_calls",
+                   "moe_dispatch_grouped", "moe_dispatch_gmm",
+                   "moe_assignments", "moe_experts_ungated"),
+    gauges=("mamba2_layers", "attention_layers", "expert_layers",
+            "ssd_chunk_len", "ssd_heads", "ssd_groups", "ssd_state_size",
+            "attn_kv_group", "moe_block_rows", "moe_experts_held",
+            "moe_experts_total", "moe_latent_width", "flash_blocks_visited",
+            "flash_blocks_total"))
+
+
+def test_every_wrong_model_belongs_to_a_mixer():
+    assert sorted(sum(WRONG_BY_MIXER.values(), ())) == sorted(adapter.WRONG)
 
 
 def test_block_kinds_follow_the_published_pattern():
-    from benchmark.models import nemotron_h as adapter
     from paddle_tpu.models.nemotron_h import NemotronHConfig
 
-    model, _ = cell(rehearse=False)
+    model, _ = SUITE.cell(rehearse=False)
     kinds = [k for _, k in adapter.held_layers(model)]
     assert "".join(k[0] for k in kinds) == "memememaeme"
     assert (kinds.count("mamba2"), kinds.count("experts"),
@@ -137,265 +171,29 @@ def test_block_kinds_follow_the_published_pattern():
         NemotronHConfig(mamba_num_heads=12, mamba_n_groups=8)
 
 
-# ------------------------------------------ the program, block by block
-
-
-def _mixer_program(which, model, batch=2, seq=80):
-    """A mixer or an expert layer alone in a Program: `u` in, `y` out."""
-    import paddle_tpu as fluid
-    from benchmark.models import nemotron_h as adapter
-    from paddle_tpu.models import decoder_parts
-
-    cfg = adapter.config(model)
-    u = fluid.layers.data("u", [batch, seq, cfg.hidden_size],
-                          append_batch_size=False)
-    if which == "mamba2":
-        y = decoder_parts.mamba2_mixer(u, cfg, "m")
-    elif which == "attention":
-        y = decoder_parts.attention(u, cfg, "m", qk_norm=False)
-    else:
-        y, _ = decoder_parts.expert_ffn(u, cfg, "m", norm_eps=1e-20)
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
-    names = [p.name for p in
-             fluid.default_main_program().global_block().all_parameters()]
-    _move_norms(names, 5)
-    return exe, y, names
-
-
-def _want_mixer(which, p, u, model, wrong=()):
-    return {"mamba2": lambda: ref.mamba_mixer(p, u, "m", model, wrong),
-            "attention": lambda: ref.attention_mixer(p, u, "m", model, wrong),
-            "experts": lambda: ref.expert_layer(p, u, "m", model, wrong)}[which]
-
-
-WRONG_BY_MIXER = {
-    "mamba2": ("no_d_skip", "norm_whole", "gate_after_norm"),
-    "attention": ("positions",),
-    "experts": ("gated_expert", "router_reads_latent", "no_scaling"),
-}
-
-
-def test_every_wrong_model_belongs_to_a_mixer():
-    from benchmark.models.nemotron_h import WRONG
-
-    assert sorted(sum(WRONG_BY_MIXER.values(), ())) == sorted(WRONG)
-
-
-@pytest.mark.parametrize("which", ["mamba2", "attention", "experts"])
-def test_program_mixer_equals_reference(which):
-    """80 tokens: five chunks of the rehearsal's 16; four Mamba-2 heads in
-    two groups, so the norm by groups and the group a head reads show."""
-    model, _ = cell(**AS_AT_WIDTH)
-    exe, y, names = _mixer_program(which, model)
-    u = np.random.RandomState(1).randn(2, 80, model["hidden_size"]).astype(
-        np.float32)
-    (got,) = exe.run(feed={"u": u}, fetch_list=[y])
-    p = state(names)
-    want = highest(_want_mixer(which, p, u, model))
-    assert np.abs(want).max() > 1e-4  # something was computed
-    assert rel(got, want) < 2e-5
-    # and a mixer got wrong is no rounding of the right one
-    for wrong in WRONG_BY_MIXER[which]:
-        other = highest(_want_mixer(which, p, u, model, (wrong,)))
-        assert rel(got, other) > 0.02, wrong
+# ------------------------------------------ the blocks' own
 
 
 def test_mamba_mixer_with_the_cells_one_group_and_a_ragged_chunk():
     """One group, as the cell holds it (no split, one norm), on rows of
     37 tokens: two chunks of 16 and a ragged third."""
-    model, _ = cell(n_groups=1, **AS_AT_WIDTH)
-    exe, y, names = _mixer_program("mamba2", model, batch=1, seq=37)
-    assert sum(".norm.group" in n for n in names) == 1
-    u = np.random.RandomState(2).randn(1, 37, model["hidden_size"]).astype(
-        np.float32)
-    (got,) = exe.run(feed={"u": u}, fetch_list=[y])
-    want = highest(_want_mixer("mamba2", state(names), u, model))
-    assert rel(got, want) < 2e-5
+    m = SUITE.mixer("mamba2", batch=1, seq=37, seed=2,
+                    config=dict(AS_AT_WIDTH, n_groups=1))
+    assert sum(".norm.group" in n for n in m.names) == 1
+    assert rel(m.got, m.want()) < 2e-5
 
 
 def test_expert_layer_through_the_grouped_kernels(monkeypatch):
     """A latent of 128 and experts of 128: the widths `moe_gmm` takes,
     under the interpreter, ungated."""
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    from paddle_tpu import profiler
-
-    before = profiler.counters()
-    model, _ = cell(moe_latent_size=128, moe_intermediate_size=128,
-                    moe_shared_expert_intermediate_size=128, **AS_AT_WIDTH)
-    exe, y, names = _mixer_program("experts", model, batch=1, seq=48)
-    assert not any(n.endswith("w_gate") for n in names)
-    u = np.random.RandomState(2).randn(1, 48, model["hidden_size"]).astype(
-        np.float32)
-    (got,) = exe.run(feed={"u": u}, fetch_list=[y])
-    after = profiler.counters()
-    assert after["moe_dispatch_gmm"] == before.get("moe_dispatch_gmm", 0) + 1
-    assert after["moe_latent_width"] == 128
-    want = highest(_want_mixer("experts", state(names), u, model))
-    assert rel(got, want) < 2e-5
-
-
-# ------------------------------------------------------ the whole model
-
-
-def _run(precision, seq_len=None):
-    import paddle_tpu as fluid
-
-    model, traffic = cell(precision=precision, **AS_AT_WIDTH)
-    if seq_len:
-        traffic = dict(traffic, seq_len=seq_len)
-    with fluid.program_guard(fluid.Program(), fluid.Program()), \
-            fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
-        _, eval_prog, built, exe, names = built_model(model, traffic)
-        batch = batch_for(model, traffic)
-        got = exe.run(eval_prog, feed=batch, fetch_list=built["check"])
-        return model, batch, state(names), got
-
-
-@pytest.fixture(scope="module")
-def amp_run():
-    """The cell's program at the rehearsal size in the cell's precision,
-    built and run once for the tests below: (model, batch, parameters,
-    [loss, scored logits])."""
-    return _run("bf16_amp")
-
-
-@pytest.fixture(scope="module")
-def float32_run():
-    """The same in float32, on rows of 80 tokens."""
-    return _run("float32", seq_len=80)
-
-
-def _check(got, p, batch, model, **kw):
-    from benchmark.models import nemotron_h as adapter
-    from benchmark.runners import train_loop
-
-    nll, count, want = highest(adapter.reference, p, batch, model, **kw)
-    return train_loop.check_reference(
-        got[0], got[1], nll / count, want[:adapter.SCORED_SEQUENCES],
-        adapter.TOLERANCE)
-
-
-# what the float32 program is held to: float32's own rounding through
-# five blocks reads 1e-6
-FLOAT32_LIMITS = {"logits_rel_rms": 5e-5, "loss_abs": 1e-5}
-
-
-def test_whole_model_logits_and_loss_equal_reference_float32(float32_run):
-    from benchmark.models import nemotron_h as adapter
-
-    model, batch, p, got = float32_run
-    assert sorted(batch) == ["labels", "tokens"]
-    np.testing.assert_array_equal(batch["labels"][:, :-1], batch["tokens"][:, 1:])
-    assert np.asarray(got[1]).shape == (
-        adapter.SCORED_SEQUENCES, 80 // adapter.SCORED_EVERY,
-        model["vocab_size"])
-    check = _check(got, p, batch, model)
-    assert check["ok"], check
-    assert all(check[k] < v for k, v in FLOAT32_LIMITS.items()), check
-
-
-def test_whole_model_equals_reference_under_bf16_amp(amp_run):
-    """The logits within the cell's limit. The loss here is a mean of 96
-    bf16 per-token losses where the cell's is one of 4,096, so its
-    rounding is sqrt(4096 / 96) = 6.5 times as coarse: held to that many
-    times the cell's limit."""
-    from benchmark.models.nemotron_h import TOLERANCE
-
-    model, batch, p, got = amp_run
-    check = _check(got, p, batch, model)
-    assert 1e-4 < check["logits_rel_rms"] <= TOLERANCE["logits_rel_rms"], check
-    assert check["loss_abs"] <= 6.5 * TOLERANCE["loss_abs"], check
-
-
-def _wrong_cases():
-    from benchmark.models.nemotron_h import WRONG
-
-    return [{"drop_layers": 1}] + [{"wrong": (w,)} for w in WRONG]
-
-
-@pytest.mark.parametrize("wrong", _wrong_cases(), ids=lambda w: str(
-    w.get("wrong", ["drop_layers"])[0]))
-def test_a_wrong_model_is_caught(wrong, amp_run, float32_run):
-    """The reference with its last block left out or with one departure
-    of `WRONG`: against the float32 program each reads hundreds of times
-    its limit, and against the program in the cell's precision each is
-    refused by the cell's logits' limit (a mean of 96 bf16 losses is too
-    coarse for the loss's limit to say anything here)."""
-    from benchmark.models.nemotron_h import TOLERANCE
-
-    model, batch, p, got = float32_run
-    check = _check(got, p, batch, model, **wrong)
-    assert check["logits_rel_rms"] > 100 * FLOAT32_LIMITS["logits_rel_rms"], (
-        wrong, check)
-    model, batch, p, got = amp_run
-    check = _check(got, p, batch, model, **wrong)
-    assert not check["ok"], (wrong, check)
-    assert check["logits_rel_rms"] > TOLERANCE["logits_rel_rms"], check
-
-
-# ------------------------------------------------ one step's gradients
-
-KINDS = {
-    "embedding": ("nemotron.embed",),
-    "head": ("nemotron.head.w_0",),
-    "rms_norm": (".norm.w_0", "final_norm.w_0"),
-    "W_in": (".mamba.in_proj.w_0",),
-    "conv_filter": (".mamba.conv.w_0",),
-    "conv_bias": (".mamba.conv.b_0",),
-    "A_log": (".mamba.A_log",),
-    "dt_bias": (".mamba.dt_bias",),
-    "D": (".mamba.D",),
-    "gated_norm": (".norm.group0.w_0", ".norm.group1.w_0"),
-    "W_out": (".mamba.out_proj.w_0",),
-    "attention": (".attn.q.w_0", ".attn.k.w_0", ".attn.v.w_0", ".attn.o.w_0"),
-    "router": (".moe.gate",),
-    "latent": (".latent_in.w_0", ".latent_out.w_0"),
-    "shared_expert": (".shared.up.w_0", ".shared.down.w_0"),
-    "experts": (".moe.w_up", ".moe.w_down"),
-}
-
-
-def _gradients(model, traffic, place=None, seed=3):
-    """{name: gradient} of the program's train step (one SGD step at rate
-    1: the gradient is what the parameter lost) and of `jax.grad` of the
-    reference's loss, from the same seeded state and batch."""
-    import jax
-
-    import paddle_tpu as fluid
-    from benchmark.models import nemotron_h as adapter
-    from benchmark.runners import train_loop
-
-    model = dict(model, optimizer={"type": "SGD", "learning_rate": 1.0})
-    main, startup, built, _ = train_loop.build_programs(
-        fluid, adapter, model, traffic, seed)
-    exe = fluid.Executor(place or fluid.CPUPlace())
-    exe.run(startup)
-    names = [p.name for p in main.global_block().all_parameters()]
-    _move_norms(names, seed)
-    before = state(names)
-    batch = batch_for(model, traffic)
-    exe.run(main, feed=batch, fetch_list=[built["loss"]])
-    got = {n: before[n] - v for n, v in state(names).items()}
-    scope = fluid.global_scope()
-    for n in list(scope.local_names()):  # the device is the reference's now
-        scope.delete(n)
-    with jax.default_matmul_precision("highest"):
-        want = f32(jax.jit(jax.grad(
-            lambda p: ref.loss(p, batch, model)))(before))
-    return got, want, before
-
-
-def test_one_train_steps_gradients_equal_jax_grad_of_the_reference():
-    """Every parameter's gradient, by kind: `W_in`, the filter and its
-    bias, `A_log`, `dt_bias`, `D`, the norm of each group, `W_out`, the
-    attention's four, the router, the latent's two, the shared expert,
-    the experts held."""
-    model, traffic = cell(precision="float32", **AS_AT_WIDTH)
-    got, want, before = _gradients(model, dict(traffic, seq_len=80))
-    assert not any(n.endswith("w_gate") for n in want)
-    worst = check_gradients(got, want, before, 2e-4, kinds=KINDS)
-    assert set(worst) == set(KINDS)
+    m = SUITE.mixer("experts", batch=1, seq=48, seed=2, config=dict(
+        AS_AT_WIDTH, moe_latent_size=128, moe_intermediate_size=128,
+        moe_shared_expert_intermediate_size=128))
+    assert not any(n.endswith("w_gate") for n in m.names)
+    assert m.bumped("moe_dispatch_gmm") == 1
+    assert m.counters["moe_latent_width"] == 128
+    assert rel(m.got, m.want()) < 2e-5
 
 
 # ------------------------------------------------ the shares add up
@@ -480,11 +278,11 @@ def test_eight_head_shares_add_up_to_the_whole_mamba_mixer():
     for i in range(shares):
         _set(share(i))
     got = exe.run(feed={"u": u}, fetch_list=outs)
-    uncut = highest(ref.mamba_mixer, p, u, "m", whole)
+    uncut = highest(adapter.mamba_mixer, p, u, "m", whole)
     assert np.abs(uncut).max() > 1e-2
     assert rel(sum(got), uncut) < 1e-5
     # one share alone is the reference's mixer with that share's heads
-    one = highest(ref.mamba_mixer,
+    one = highest(adapter.mamba_mixer,
                   {k.replace("share3", "m"): v for k, v in share(3).items()},
                   u, "m", dict(whole, mamba_num_heads=2, n_groups=1))
     assert rel(got[3], one) < 1e-5
@@ -524,7 +322,7 @@ def test_eight_head_shares_add_up_to_the_whole_attention_layer():
               f"share{i}.v.w_0": p["m.v.w_0"][:, kv * d:(kv + 1) * d],
               f"share{i}.o.w_0": p["m.o.w_0"][i * per:(i + 1) * per]})
     got = exe.run(feed={"u": u}, fetch_list=outs)
-    uncut = highest(ref.attention_mixer, p, u, "m", {
+    uncut = highest(adapter.attention_mixer, p, u, "m", {
         "num_attention_heads": h, "num_key_value_heads": g, "head_dim": d})
     assert np.abs(uncut).max() > 1e-2
     assert rel(sum(got), uncut) < 1e-5
@@ -581,8 +379,8 @@ def test_the_64_expert_shares_add_up_to_the_uncut_layer(total, held, k):
     layer = {"num_experts_per_tok": k, "n_routed_experts": total,
              "held_from": 0, "norm_topk_prob": True,
              "routed_scaling_factor": 5.0}
-    uncut = highest(ref.expert_layer, p, u, "m", layer)
-    routed_only = highest(ref.expert_layer, p, u, "m",
+    uncut = highest(adapter.expert_layer, p, u, "m", layer)
+    routed_only = highest(adapter.expert_layer, p, u, "m",
                           dict(layer, shared_expert=False))
     shared = uncut - routed_only  # what every chip computes alike
     assert np.abs(shared).max() > 1e-3
@@ -594,7 +392,7 @@ def test_the_64_expert_shares_add_up_to_the_uncut_layer(total, held, k):
     # and one share alone is the reference's share
     p_share = dict(p, **{f"m.moe.{w}": p[f"m.moe.{w}"][held:2 * held]
                          for w in ("w_up", "w_down")})
-    one = highest(ref.expert_layer, p_share, u, "m",
+    one = highest(adapter.expert_layer, p_share, u, "m",
                   dict(layer, n_routed_experts=held, held_from=held,
                        shared_expert=False))
     assert rel(routed[1], one) < 1e-5
@@ -623,7 +421,8 @@ def test_the_vocabularys_slices_give_the_whole_logits_columns():
         _set({f"slice{i}.final_norm.w_0": w,
               f"slice{i}.head.w_0": head[:, i * per:(i + 1) * per]})
     got = np.concatenate(exe.run(feed={"x": xs}, fetch_list=outs), -1)
-    whole = highest(lambda: ref._rms(xs, w, 1e-5) @ head)
+    whole = highest(lambda xs, w, head: adapter._rms(xs, w, 1e-5) @ head,
+                    xs, w, head)
     assert rel(got, whole) < 1e-5
 
 
@@ -631,17 +430,15 @@ def test_the_vocabularys_slices_give_the_whole_logits_columns():
 
 
 def test_gauges_and_counters_at_the_rehearsal_size(monkeypatch):
-    import paddle_tpu as fluid
     from paddle_tpu import profiler
 
     # no interpreter, whatever a test file imported before this one set
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
-    model, traffic = cell()
+    model, traffic = SUITE.cell()
     before = profiler.counters()
-    with fluid.program_guard(fluid.Program(), fluid.Program()), \
-            fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
-        main, eval_prog, built, exe, names = built_model(model, traffic)
-        batch = batch_for(model, traffic)
+    with guards():
+        main, eval_prog, built, exe, names = SUITE.built_model(model, traffic)
+        batch = SUITE.batch_for(model, traffic)
         loads = exe.run(main, feed=batch, fetch_list=built["loads"])
     after = profiler.counters()
     assert {n: after[n] for n in (
@@ -693,10 +490,9 @@ def test_gauges_and_counters_at_the_rehearsal_size(monkeypatch):
 
 
 def test_parameters_and_flops_of_the_cell():
-    from benchmark.models import nemotron_h as adapter
     from benchmark.runners import train_loop
 
-    model, traffic = cell(rehearse=False)
+    model, traffic = SUITE.cell(rehearse=False)
     assert (traffic["batch"], traffic["seq_len"]) == (1, 4096)
     # ISSUE 53's arithmetic, redone
     mamba = 4096 * (1024 + 1280 + 16) + 1024 * 4096
@@ -729,7 +525,7 @@ def test_parameters_and_flops_of_the_cell():
     import paddle_tpu as fluid
 
     for rehearse, want in ((True, None), (False, held)):
-        m, t = cell(rehearse=rehearse)
+        m, t = SUITE.cell(rehearse=rehearse)
         blocks = [k for _, k in adapter.held_layers(m)]
         with fluid.program_guard(fluid.Program(), fluid.Program()), \
                 fluid.unique_name.guard():
@@ -747,168 +543,5 @@ def test_parameters_and_flops_of_the_cell():
         assert len(built["loads"]) == blocks.count("experts")
 
 
-# ------------------------------------------------------- on the chip
-
-
-def _on_chip(model, traffic, seed):
-    """The cell's programs on the attached TPU with the seeded state."""
-    import paddle_tpu as fluid
-    from benchmark.models import nemotron_h as adapter
-    from benchmark.runners import train_loop
-
-    main, startup, built, eval_prog = train_loop.build_programs(
-        fluid, adapter, model, traffic, seed)
-    exe = fluid.Executor(fluid.TPUPlace())
-    exe.run(startup)
-    return main, eval_prog, built, exe
-
-
-def chip_readings(seeds, only=(), few=2):
-    """At the published widths on the attached TPU: the cell's own check
-    (program in bf16 AMP against the float32 reference) at every seed,
-    and the same program against the wrong models named in `only` at
-    every seed, or with none named against each wrong model and the fp8
-    reference at the first `few`."""
-    import paddle_tpu as fluid
-    from benchmark.models import nemotron_h as adapter
-    from benchmark.runners import train_loop
-
-    model, traffic = cell(rehearse=False)
-    for at, seed in enumerate(seeds):
-        with fluid.program_guard(fluid.Program(), fluid.Program()), \
-                fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
-            main, eval_prog, built, exe = _on_chip(model, traffic, seed)
-            batch = adapter.make_batch(np.random.RandomState(seed), model,
-                                       traffic)
-            got_loss, got_logits = exe.run(eval_prog, feed=batch,
-                                           fetch_list=built["check"])
-            p = state([v.name for v in main.global_block().all_parameters()])
-        variants = [("reference", p, {})] + [
-            (w, p, {"wrong": (w,)}) for w in only]
-        if not only and at < few:
-            variants += [("fp8", _fp8(p), {}),
-                         ("drop_layers", p, {"drop_layers": 1})] + [
-                (w, p, {"wrong": (w,)}) for w in adapter.WRONG]
-        for label, params, kw in variants:
-            loss, logits = train_loop.reference_outputs(
-                adapter, params, batch, model, 1, **kw)
-            check = train_loop.check_reference(
-                got_loss, got_logits, loss, logits, adapter.TOLERANCE)
-            print(f"seed {seed} {label}: logits_rel_rms "
-                  f"{check['logits_rel_rms']:.5f} loss_abs "
-                  f"{check['loss_abs']:.5f} ok {check['ok']}", flush=True)
-
-
-def held_loads(seeds, steps=44, rate=None):
-    """At the published widths on the attached TPU, the cell's train step
-    on the batches its runner would feed (one check batch drawn first,
-    then the pool of 32), `steps` of them at `rate`: the share of the
-    90,112 assignments that each expert layer's 8 held experts take, at
-    the first step, the window's first (the fifth) and the last, and the
-    largest over all steps, beside the first block's share; the loss, and
-    its fall as the runner takes it."""
-    import paddle_tpu as fluid
-    from benchmark.models import nemotron_h as adapter
-    from paddle_tpu import profiler
-
-    model, traffic = cell(rehearse=False)
-    if rate:  # the sweep that chose the optimizer's rate
-        model["optimizer"] = dict(model["optimizer"], learning_rate=rate)
-    total = traffic["batch"] * traffic["seq_len"] * model["num_experts_per_tok"]
-    c0 = profiler.counters()
-    for seed in seeds:
-        with fluid.program_guard(fluid.Program(), fluid.Program()), \
-                fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
-            main, _, built, exe = _on_chip(model, traffic, seed)
-            rng = np.random.RandomState(seed)
-            adapter.make_batch(rng, model, traffic)  # the check's batch
-            pool = [adapter.make_batch(rng, model, traffic)
-                    for _ in range(traffic["pool_batches"])]
-            shares, losses = [], []
-            for i in range(steps):
-                loss, *loads = exe.run(
-                    main, feed=pool[i % len(pool)],
-                    fetch_list=[built["loss"]] + built["loads"])
-                losses.append(float(np.asarray(loss).reshape(-1)[0]))
-                shares.append([float(np.sum(x)) / total for x in loads])
-        shares = np.array(shares)
-        rows = profiler.counters()["moe_block_rows"]
-
-        def row(values):
-            return " ".join(f"{v:.4f}" for v in values)
-
-        print(f"seed {seed} rate {model['optimizer']['learning_rate']}: "
-              f"block {rows} rows = {rows / total:.4f} of "
-              f"{total}; held share by layer, step 0: {row(shares[0])}; "
-              f"step 4: {row(shares[4])}; step {steps - 1}: "
-              f"{row(shares[-1])}; largest: {row(shares.max(0))}; loss "
-              f"step 0 {losses[0]:.4f}, step 4 {losses[4]:.4f}, step "
-              f"{steps - 1} {losses[-1]:.4f}; fall (median of steps 4-13 "
-              f"less median of the last ten) "
-              f"{np.median(losses[4:14]) - np.median(losses[-10:]):.4f}; "
-              "every tenth: " + " ".join(f"{v:.3f}" for v in losses[::10]),
-              flush=True)
-    c1 = profiler.counters()
-    print("counters of", len(seeds), "train steps' traces:", {
-        n: c1.get(n, 0) - c0.get(n, 0) for n in (
-            "ssd_dispatch_chunked", "short_conv_dispatch_pallas",
-            "short_conv_dispatch_xla", "attn_dispatch_flash",
-            "attn_qk_prep_fused", "flash_bwd_fused_calls",
-            "moe_dispatch_grouped", "moe_dispatch_gmm", "moe_assignments",
-            "moe_experts_ungated")},
-        {n: c1.get(n) for n in (
-            "mamba2_layers", "attention_layers", "expert_layers",
-            "ssd_chunk_len", "ssd_heads", "ssd_groups", "ssd_state_size",
-            "attn_kv_group", "moe_block_rows", "moe_experts_held",
-            "moe_experts_total", "moe_latent_width", "flash_blocks_visited",
-            "flash_blocks_total")}, flush=True)
-
-
-def chip_gradients():
-    """The gradients of every kind of parameter at the published widths,
-    program against `jax.grad` of the reference, on one 512-token row."""
-    import jax
-
-    import paddle_tpu as fluid
-
-    # How the reference is differentiated, not what it computes: the token
-    # recurrence keeps a [16, 64, 128] state a token for its backward;
-    # rebuilt a block at a time it fits.
-    ref.ssm_recurrence = jax.checkpoint(ref.ssm_recurrence)
-    model, traffic = cell(rehearse=False, precision="float32")
-    traffic = dict(traffic, seq_len=512)
-    kinds = dict(KINDS, gated_norm=(".norm.group0.w_0",))
-    # the latent's two projections see the loss through the routed experts
-    # alone, so where rounding flips a selection their gradients change by
-    # a whole token's worth, as the experts' do: held to the routed limit
-    kinds["experts"] += kinds.pop("latent")
-    # float32 on a TPU is a bf16 pass a product unless told otherwise, so
-    # the "float32" program is held to 5%, the AMP one to 20%
-    for precision, limit, routed in (("float32", 0.05, 0.3),
-                                     ("bf16_amp", 0.2, 0.6)):
-        with fluid.program_guard(fluid.Program(), fluid.Program()), \
-                fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
-            got, want, before = _gradients(
-                dict(model, precision=precision), traffic,
-                place=fluid.TPUPlace())
-        try:
-            worst = check_gradients(got, want, before, limit, routed, kinds)
-        except AssertionError as e:
-            print(f"FAIL {precision}: {e}", flush=True)
-            raise
-        print(f"gradients at the published widths, s=512, {precision}: "
-              "worst relative error by kind "
-              + ", ".join(f"{k} {v:.2e}" for k, v in worst.items()), flush=True)
-
-
 if __name__ == "__main__":
-    import jax
-
-    assert jax.devices()[0].platform == "tpu", jax.devices()
-    what, _, rate = sys.argv[1].partition("@")
-    what, _, only = what.partition(":")
-    seeds = [int(a) for a in sys.argv[2:]] or [53001]
-    {"readings": lambda: chip_readings(
-        seeds, tuple(w for w in only.split(",") if w)),
-     "loads": lambda: held_loads(seeds, rate=float(rate) if rate else None),
-     "gradients": chip_gradients}[what]()
+    main(SUITE)

@@ -20,7 +20,7 @@ from paddle_tpu.ops.pallas import on_mesh
 from paddle_tpu.ops.pallas.layer_norm import ln_bwd
 from paddle_tpu.ops.pallas.mha_short import mha_short
 from paddle_tpu.parallel.mesh import build_mesh
-from test_flash_attention import _attn_program  # the op with its gradients
+from kernel_cases import attn_program  # the op with its gradients
 
 KEY = jax.random.key(0)
 
@@ -181,7 +181,7 @@ def test_a_quarter_of_the_batch_declares_a_quarter_of_the_cost(kernel,
 def _run_attn_program(b, mesh, s=32, nh=2, dh=64):
     """The op's values and gradients, and the counters its lowering left."""
     rng = np.random.RandomState(3)
-    main, startup, fetches = _attn_program(b, s, s, nh, dh, "bshd")
+    main, startup, fetches = attn_program(b, s, s, nh, dh, "bshd")
     feed = {n: rng.randn(b, s, nh * dh).astype("float32") for n in "qkv"}
     feed["bias"] = np.where(rng.rand(b, s) > 0.2, 0.0, -1e9).astype("float32")
     feed["bias"][:, 0] = 0.0
@@ -606,7 +606,7 @@ def _grouped_products(text):
     and XLA's `ragged-dot`."""
     import re
 
-    from test_moe_experts import in_and_out_of_whiles
+    from kernel_cases import in_and_out_of_whiles
 
     return (in_and_out_of_whiles(text, re.compile(
         r"%moe_t?gmm[.\d]* = .* custom-call\(").search),

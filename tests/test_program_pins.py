@@ -1,0 +1,136 @@
+"""One table of Program pins: a row a cell of `BENCHMARK.json`, the train
+Program at the rehearsal size as `train_loop.build_programs` builds it.
+A row holds the count of ops; the sha256 (16 hex digits) of the op types in
+order, each with every attribute that is not bookkeeping (`op_role` and
+the gradient ops' copy of their forward op, `fwd_*`); and, so that a reader
+need not decode the digest, how many ops of the types in `OPS` the Program
+has and which of the attributes in `ATTRS` any op of it carries (each is
+one model's: a cell that does not list it has none). Nothing is compiled
+and no step runs; what a cell's step must and must not count is in that
+cell's own counters case (`tests/test_<model>_reference.py`).
+
+The table is taken by running this file as a script on the parent commit
+(`PYTHONPATH=<parent> python tests/test_program_pins.py`, as
+`tests/test_parents_jaxprs.py` is): a PR that adds a cell adds its row, and
+re-takes only the rows it means to change. These are PR 55's parent's
+(commit 006eebc)."""
+
+import hashlib
+import json
+import os
+import sys
+
+import pytest
+
+# after PYTHONPATH: run as a script against a parent, the parent's is found
+sys.path.append(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+OPS = ("fused_multihead_attention", "rotary_embedding", "short_conv1d",
+       "kda_attention", "selective_scan", "ssd_scan", "moe_experts")
+ATTRS = ("rope_scaling", "interleaved", "q_lora_rank", "activation",
+         "norm_eps", "rotary_dim", "expert_form")
+
+PINS = {
+    "bert_base_s128": (
+        304, "5123d1989e7f49f4",
+        {"fused_multihead_attention": 2},
+        ()),
+    "bert_base_s128_dp4": (
+        304, "7ab026a688fe9daa",
+        {"fused_multihead_attention": 2},
+        ()),
+    "bert_base_s512": (
+        304, "5f2e9a39729d70cd",
+        {"fused_multihead_attention": 2},
+        ()),
+    "transformer_base_s64": (
+        628, "7c09445cc15c5358",
+        {"fused_multihead_attention": 6},
+        ()),
+    "resnet50_b128": (
+        346, "efb26064e718b288",
+        {},
+        ()),
+    "kimi_linear_ep32_s4096": (
+        658, "81898544a32310cc",
+        {"fused_multihead_attention": 1, "short_conv1d": 12,
+         "kda_attention": 4, "moe_experts": 4},
+        ()),
+    "trinity_mini_ep16_s8192": (
+        571, "5120aa80f96a99d2",
+        {"fused_multihead_attention": 5, "moe_experts": 4},
+        ()),
+    "mellum2_ep4_s8192": (
+        291, "cae869195b0d37b3",
+        {"fused_multihead_attention": 4, "moe_experts": 4},
+        ("rope_scaling",)),
+    "joyai_flash_ep32_s4096": (
+        827, "dc3429562f3d77b4",
+        {"fused_multihead_attention": 6, "rotary_embedding": 12,
+         "moe_experts": 5},
+        ("interleaved", "q_lora_rank",)),
+    "phi4_mini_flash_vp8_longdoc": (
+        733, "6f79e2c9d1ead6ba",
+        {"fused_multihead_attention": 6, "short_conv1d": 2,
+         "selective_scan": 2},
+        ()),
+    "lfm2_24b_ep8_longdoc": (
+        313, "12a00fa92a58bfa9",
+        {"fused_multihead_attention": 1, "short_conv1d": 4,
+         "moe_experts": 4},
+        ("activation", "norm_eps",)),
+    "qwen3_next_ep16_s4096": (
+        496, "2c66b16e39c5c6e8",
+        {"fused_multihead_attention": 1, "short_conv1d": 3,
+         "kda_attention": 3, "moe_experts": 4},
+        ("rotary_dim",)),
+    "nemotron3_super_ep64_s4096": (
+        296, "9a4b01d2e010ca0d",
+        {"fused_multihead_attention": 1, "short_conv1d": 2,
+         "ssd_scan": 2, "moe_experts": 2},
+        ("norm_eps", "expert_form",)),
+}
+
+
+def cells():
+    from benchmark.harness import spec
+
+    with open(os.path.join(os.path.dirname(spec.BENCH_DIR),
+                           "BENCHMARK.json")) as f:
+        return [w["name"] for w in json.load(f)["workloads"]]
+
+
+def row(cell_name):
+    import paddle_tpu as fluid
+    from benchmark.harness import spec
+    from benchmark.runners import train_loop
+
+    c = spec.cell(cell_name, rehearse=True)
+    adapter = spec.plugin("models", c["config"]["adapter"])
+    with fluid.program_guard(fluid.Program(), fluid.Program()), \
+            fluid.unique_name.guard():
+        main = train_loop.build_programs(fluid, adapter, c["config"],
+                                         c["traffic"], 3)[0]
+    ops = main.global_block().ops
+    lines = [op.type + " " + json.dumps(
+        {k: v for k, v in op.attrs.items()
+         if not k.startswith(("op_", "fwd_"))}, sort_keys=True, default=str)
+        for op in ops]
+    types = [op.type for op in ops]
+    return (len(ops), hashlib.sha256("\n".join(lines).encode()).hexdigest()[:16],
+            {t: types.count(t) for t in OPS if t in types},
+            tuple(a for a in ATTRS if any(a in op.attrs for op in ops)))
+
+
+def test_every_cell_has_its_row():
+    assert sorted(PINS) == sorted(cells())
+
+
+@pytest.mark.parametrize("cell_name", list(PINS))
+def test_the_cells_train_program_is_op_for_op_what_it_was(cell_name):
+    assert row(cell_name) == PINS[cell_name]
+
+
+if __name__ == "__main__":
+    for name in cells():
+        print(f'    "{name}": {row(name)!r},', flush=True)

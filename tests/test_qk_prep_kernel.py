@@ -9,6 +9,8 @@ block that holds position 8,191, and the published widths."""
 import numpy as np
 import pytest
 
+from kernel_cases import written_out
+
 EPS, THETA = 1e-5, 10000.0
 
 
@@ -96,22 +98,6 @@ def test_float32_equals_the_chain_and_its_gradients(b, s, h, g, d, rows,
                           want_grads):
         assert a.shape == w.shape and a.dtype == w.dtype
         assert rel(a, w) < 1e-5, name
-
-
-def written_out(x, theta, lanes):
-    """The rotation of the first `lanes` lanes of [b, s, n, d], the others
-    passed, with a concatenation and no roll."""
-    import jax.numpy as jnp
-
-    s = x.shape[1]
-    turning, passing = x[..., :lanes], x[..., lanes:]
-    inv = 1.0 / theta ** (jnp.arange(0, lanes, 2, dtype=jnp.float32) / lanes)
-    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-    angle = jnp.concatenate([angle, angle], -1)[None, :, None, :]
-    swapped = jnp.concatenate(
-        [-turning[..., lanes // 2:], turning[..., :lanes // 2]], -1)
-    return jnp.concatenate(
-        [turning * jnp.cos(angle) + swapped * jnp.sin(angle), passing], -1)
 
 
 @pytest.mark.parametrize("b,s,h,g,d,rows,lanes", [

@@ -1,13 +1,16 @@
-"""Qwen3-Next against its plain reference (`tests/qwen3_next_reference.py`)
-at the rehearsal size of the cell `qwen3_next_ep16_s4096`: every mixer
-alone, the Gated DeltaNet mixer through the kernel pair and the attention
-layer through `qk_prep` and the flash kernel (interpreted, heads of 128),
-the whole model in float32 and under bf16 AMP, one train step's gradients
-for every kind of parameter, that each wrong model is caught, the sixteen
-shares of an expert layer against the uncut layer with the gated shared
-expert counted once, the gauges and counters, and the cell's arithmetic.
+"""Qwen3-Next against its plain reference (`benchmark/models/qwen3_next.py`)
+at the rehearsal size of the cell `qwen3_next_ep16_s4096`: what every
+decoder suite holds (`tests/decoder_suite.py`: every mixer alone, the
+whole model in float32 and under bf16 AMP, one train step's gradients for
+every kind of parameter, that each wrong model is caught) on this model's
+data, and its own: the Gated DeltaNet mixer through the kernel pair and
+the attention layer through `qk_prep` and the flash kernel (interpreted,
+heads of 128), the sixteen shares of an expert layer against the uncut
+layer with the gated shared expert counted once, the gauges and counters,
+and the cell's arithmetic.
 
-Run as a script on the attached TPU, outside any timed window:
+Run as a script on the attached TPU, outside any timed window
+(`tests/decoder_suite.py` has the arguments):
 
     python3 tests/test_qwen3_next_reference.py readings 1 2   # program, wrong models and fp8 reference against the reference
     python3 tests/test_qwen3_next_reference.py loads@3e-6 1 2   # held share by expert layer and the loss over the window's steps at a rate
@@ -16,66 +19,15 @@ Run as a script on the attached TPU, outside any timed window:
 
 from __future__ import annotations
 
-import inspect
-import os
-import sys
-
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from decoder_suite import *  # noqa: F401,F403 — the shared cases, on SUITE
+from decoder_suite import guards, highest, main, rel
 
-import qwen3_next_reference as ref  # noqa: E402 — beside this file
-from test_kimi_linear_reference import (  # noqa: E402 — the shared helpers
-    check_gradients, f32, highest, rel, state)
-from test_mellum_reference import _fp8  # noqa: E402 — the matrices in e4m3
+from benchmark.models import qwen3_next as adapter  # noqa: E402
 
 CELL = "qwen3_next_ep16_s4096"
-
-
-def cell(rehearse=True, **config):
-    from benchmark.harness import spec
-
-    c = spec.cell(CELL, rehearse=rehearse)
-    c["config"].update(config)
-    return c["config"], c["traffic"]
-
-
-def _move_norms(names, seed):
-    """The norms' weights off their seeded 1, so that a zero-centred norm
-    read as a plain one, a norm left out, or QK-norm after the positions
-    (the same model at 1: a rotation of part of a head keeps its length)
-    shows."""
-    import paddle_tpu as fluid
-
-    scope, r = fluid.global_scope(), np.random.RandomState(seed)
-    for n in names:
-        if n.endswith("norm.w_0"):
-            scope.set(n, r.uniform(0.5, 1.5, np.shape(scope.get(n))).astype(
-                np.float32))
-
-
-def built_model(model, traffic, seed=3):
-    """Programs, executor and the seeded state by name, in a scope of its
-    own (the caller holds the guards)."""
-    import paddle_tpu as fluid
-    from benchmark.models import qwen3_next as adapter
-    from benchmark.runners import train_loop
-
-    main, startup, built, eval_prog = train_loop.build_programs(
-        fluid, adapter, model, traffic, seed)
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup)
-    names = [p.name for p in main.global_block().all_parameters()]
-    _move_norms(names, seed)
-    return main, eval_prog, built, exe, names
-
-
-def batch_for(model, traffic, seed=0):
-    from benchmark.models import qwen3_next as adapter
-
-    return adapter.make_batch(np.random.RandomState(seed), model, traffic)
-
 
 # At 64 wide, seeded as the cell is (matrices Normal(0, 0.02)), a product
 # gives 0.16 of its input and a mixer next to nothing of the residual
@@ -85,79 +37,28 @@ def batch_for(model, traffic, seed=0):
 AS_AT_WIDTH = {"initializer_range": 0.1}
 
 
-# ------------------------------------------------- the copy is a copy
 
-
-def test_reference_copy_is_the_adapters_word_for_word():
-    from benchmark.models import qwen3_next as adapter
-
-    for name in ("held_layers", "_rms", "_silu", "_ffn", "_rope", "_conv",
-                 "delta_recurrence", "delta_mixer", "attention_mixer",
-                 "expert_ffn", "reference"):
-        assert inspect.getsource(getattr(ref, name)) == inspect.getsource(
-            getattr(adapter, name)), name
-    assert (ref.SCORED_EVERY, ref.QUERY_BLOCK) == (
-        adapter.SCORED_EVERY, adapter.QUERY_BLOCK)
-
-
-def test_layer_kinds_follow_the_published_interval():
-    from benchmark.models import qwen3_next as adapter
-    from paddle_tpu.models.qwen3_next import Qwen3NextConfig
-
-    model, _ = cell(rehearse=False)
-    assert adapter.held_layers(model) == [
-        (0, "linear_attention"), (1, "linear_attention"),
-        (2, "linear_attention"), (3, "full_attention")]
-    whole = dict(model, num_hidden_layers=48)
-    kinds = [k for _, k in adapter.held_layers(whole)]
-    assert kinds.count("full_attention") == 12
-    assert [l for l, k in adapter.held_layers(whole)
-            if k == "full_attention"] == list(range(3, 48, 4))
-    cfg = adapter.config(model)
-    assert cfg.layer_kinds() == adapter.held_layers(model)
-    assert (cfg.rotary_dim, cfg.head_dim, cfg.rope_theta) == (64, 256, 1e7)
-    assert (cfg.linear_num_key_heads, cfg.linear_num_value_heads) == (16, 32)
-    assert (cfg.num_experts, cfg.experts_held, cfg.num_experts_per_token,
-            cfg.num_shared_experts, cfg.score_func) == (512, 32, 10, 1,
-                                                        "softmax")
-    assert cfg.shared_expert_gate and cfg.moe_renormalize
-    assert Qwen3NextConfig().layer_kinds()[-1] == (47, "full_attention")
-    with pytest.raises(ValueError, match="multiple"):
-        Qwen3NextConfig(shared_expert_intermediate_size=700)
-
-
-# ------------------------------------------ the program, mixer by mixer
-
-
-def _mixer_program(which, model, batch=2, seq=80):
+def _mixer_program(which, model, batch, seq):
     """A mixer or an expert layer alone in a Program: `u` in, `y` out."""
     import paddle_tpu as fluid
-    from benchmark.models import qwen3_next as adapter
     from paddle_tpu.models import decoder_parts
 
     cfg = adapter.config(model)
     u = fluid.layers.data("u", [batch, seq, cfg.hidden_size],
                           append_batch_size=False)
     if which == "delta":
-        y = decoder_parts.gated_delta_net(u, cfg, "m")
-    elif which == "attention":
-        y = decoder_parts.attention(u, cfg, "m", gated=True,
-                                    rope_theta=cfg.rope_theta,
-                                    rotary_dim=cfg.rotary_dim)
-    else:
-        y, _ = decoder_parts.expert_ffn(u, cfg, "m")
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
-    names = [p.name for p in
-             fluid.default_main_program().global_block().all_parameters()]
-    _move_norms(names, 5)
-    return exe, y, names
+        return decoder_parts.gated_delta_net(u, cfg, "m")
+    if which == "attention":
+        return decoder_parts.attention(u, cfg, "m", gated=True,
+                                       rope_theta=cfg.rope_theta,
+                                       rotary_dim=cfg.rotary_dim)
+    return decoder_parts.expert_ffn(u, cfg, "m")[0]
 
 
-def _want_mixer(which, p, u, model, wrong=()):
-    return {"delta": lambda: ref.delta_mixer(p, u, "m", model, wrong),
-            "attention": lambda: ref.attention_mixer(p, u, "m", model, wrong),
-            "experts": lambda: ref.expert_ffn(p, u, "m", model, wrong)}[which]
+def _want_mixer(which, p, feeds, model, wrong=()):
+    fn = {"delta": adapter.delta_mixer, "attention": adapter.attention_mixer,
+          "experts": adapter.expert_ffn}[which]
+    return highest(fn, p, feeds["u"], "m", model, wrong)
 
 
 WRONG_BY_MIXER = {
@@ -166,196 +67,6 @@ WRONG_BY_MIXER = {
     "experts": ("no_shared_gate", "sigmoid_router", "no_renormalize"),
 }
 
-
-def test_every_wrong_model_belongs_to_a_mixer():
-    from benchmark.models.qwen3_next import WRONG
-
-    assert sorted(sum(WRONG_BY_MIXER.values(), ())) == sorted(WRONG)
-
-
-@pytest.mark.parametrize("which", ["delta", "attention", "experts"])
-def test_program_mixer_equals_reference(which):
-    model, _ = cell(**AS_AT_WIDTH)
-    exe, y, names = _mixer_program(which, model)
-    u = np.random.RandomState(1).randn(2, 80, model["hidden_size"]).astype(
-        np.float32)
-    (got,) = exe.run(feed={"u": u}, fetch_list=[y])
-    p = state(names)
-    want = highest(_want_mixer(which, p, u, model))
-    assert np.abs(want).max() > 1e-4  # something was computed
-    assert rel(got, want) < 2e-5
-    # and a mixer got wrong is no rounding of the right one
-    for wrong in WRONG_BY_MIXER[which]:
-        other = highest(_want_mixer(which, p, u, model, (wrong,)))
-        assert rel(got, other) > 0.02, wrong
-
-
-# heads of 128 lanes, which the kernels take, at the rehearsal's other sizes
-LANES_128 = {"linear_key_head_dim": 128, "linear_value_head_dim": 128,
-             "head_dim": 128}
-
-
-def test_delta_mixer_through_the_kernel_pair(monkeypatch):
-    """Heads of 128, two key heads under four value heads, rows of 200
-    tokens (a ragged last chunk): the mixer's Program takes `gdn_fwd`
-    under the interpreter and agrees with the token-a-step reference."""
-    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    from paddle_tpu import profiler
-
-    before = profiler.counters()
-    model, _ = cell(**AS_AT_WIDTH, **LANES_128)
-    exe, y, names = _mixer_program("delta", model, batch=1, seq=200)
-    u = np.random.RandomState(2).randn(1, 200, model["hidden_size"]).astype(
-        np.float32)
-    (got,) = exe.run(feed={"u": u}, fetch_list=[y])
-    after = profiler.counters()
-    assert after["kda_dispatch_pallas"] == before.get(
-        "kda_dispatch_pallas", 0) + 1
-    assert after["kda_decay_per_head"] == before.get(
-        "kda_decay_per_head", 0) + 1
-    assert after["kda_key_group"] == 2
-    want = highest(_want_mixer("delta", state(names), u, model))
-    assert rel(got, want) < 2e-5
-
-
-def test_attention_through_qk_prep_and_the_flash_kernel(monkeypatch,
-                                                        attn_path):
-    """Heads of 128 lanes of which 32 turn: the blocked kernel and the
-    `qk_prep` pair, interpreted, forced by name since the CPU's dispatch
-    never chooses them."""
-    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    attn_path("flash")
-    from paddle_tpu import profiler
-
-    before = profiler.counters()
-    model, _ = cell(**AS_AT_WIDTH, **LANES_128)
-    exe, y, names = _mixer_program("attention", model, batch=1, seq=160)
-    u = np.random.RandomState(2).randn(1, 160, model["hidden_size"]).astype(
-        np.float32)
-    (got,) = exe.run(feed={"u": u}, fetch_list=[y])
-    after = profiler.counters()
-    assert after["attn_dispatch_flash"] == before.get(
-        "attn_dispatch_flash", 0) + 1
-    assert after["attn_qk_prep_fused"] == before.get(
-        "attn_qk_prep_fused", 0) + 1
-    assert (after["attn_kv_group"], after["attn_rotary_lanes"]) == (2, 32)
-    p = state(names)
-    want = highest(_want_mixer("attention", p, u, model))
-    assert rel(got, want) < 2e-5
-    whole = highest(_want_mixer("attention", p, u, model,
-                                ("rope_whole_head",)))
-    assert rel(got, whole) > 0.02
-
-
-# ------------------------------------------------------ the whole model
-
-
-def _run(precision, seq_len=None):
-    import paddle_tpu as fluid
-
-    model, traffic = cell(precision=precision, **AS_AT_WIDTH)
-    if seq_len:
-        traffic = dict(traffic, seq_len=seq_len)
-    with fluid.program_guard(fluid.Program(), fluid.Program()), \
-            fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
-        _, eval_prog, built, exe, names = built_model(model, traffic)
-        batch = batch_for(model, traffic)
-        got = exe.run(eval_prog, feed=batch, fetch_list=built["check"])
-        return model, batch, state(names), got
-
-
-@pytest.fixture(scope="module")
-def amp_run():
-    """The cell's program at the rehearsal size in the cell's precision,
-    built and run once for the tests below: (model, batch, parameters,
-    [loss, scored logits])."""
-    return _run("bf16_amp")
-
-
-@pytest.fixture(scope="module")
-def float32_run():
-    """The same in float32, on rows of 80 tokens."""
-    return _run("float32", seq_len=80)
-
-
-def _check(got, p, batch, model, **kw):
-    from benchmark.models import qwen3_next as adapter
-    from benchmark.runners import train_loop
-
-    nll, count, want = highest(adapter.reference, p, batch, model, **kw)
-    return train_loop.check_reference(
-        got[0], got[1], nll / count, want[:adapter.SCORED_SEQUENCES],
-        adapter.TOLERANCE)
-
-
-# what the float32 program is held to: float32's own rounding through
-# four layers reads 1e-6
-FLOAT32_LIMITS = {"logits_rel_rms": 5e-5, "loss_abs": 1e-5}
-
-
-def test_whole_model_logits_and_loss_equal_reference_float32(float32_run):
-    from benchmark.models import qwen3_next as adapter
-
-    model, batch, p, got = float32_run
-    assert sorted(batch) == ["labels", "tokens"]
-    np.testing.assert_array_equal(batch["labels"][:, :-1], batch["tokens"][:, 1:])
-    assert np.asarray(got[1]).shape == (
-        adapter.SCORED_SEQUENCES, 80 // adapter.SCORED_EVERY,
-        model["vocab_size"])
-    check = _check(got, p, batch, model)
-    assert check["ok"], check
-    assert all(check[k] < v for k, v in FLOAT32_LIMITS.items()), check
-
-
-def test_whole_model_equals_reference_under_bf16_amp(amp_run):
-    """The logits within the cell's limit. The loss here is a mean of 96
-    bf16 per-token losses where the cell's is one of 4,096, so its
-    rounding is sqrt(4096 / 96) = 6.5 times as coarse: held to that many
-    times the cell's limit."""
-    from benchmark.models.qwen3_next import TOLERANCE
-
-    model, batch, p, got = amp_run
-    check = _check(got, p, batch, model)
-    assert 1e-4 < check["logits_rel_rms"] <= TOLERANCE["logits_rel_rms"], check
-    assert check["loss_abs"] <= 6.5 * TOLERANCE["loss_abs"], check
-
-
-# QK-norm after the positions moves the logits by what bf16 rounding moves
-# them by (with the norms' weights at their seeded 1 it is the same model:
-# a rotation keeps a head's length): the float32 program catches it, the
-# cell's limit cannot and is not asked to
-MILD = (("norm_after_rope",),)
-
-
-def _wrong_cases():
-    from benchmark.models.qwen3_next import WRONG
-
-    return [{"drop_layers": 1}] + [{"wrong": (w,)} for w in WRONG]
-
-
-@pytest.mark.parametrize("wrong", _wrong_cases(), ids=lambda w: str(
-    w.get("wrong", ["drop_layers"])[0]))
-def test_a_wrong_model_is_caught(wrong, amp_run, float32_run):
-    """The reference with its last layer left out or with one departure
-    of `WRONG`: against the float32 program each reads hundreds of times
-    its limit, and against the program in the cell's precision each but
-    `MILD`'s is refused by the cell's logits' limit (a mean of 96 bf16
-    losses is too coarse for the loss's limit to say anything here)."""
-    from benchmark.models.qwen3_next import TOLERANCE
-
-    model, batch, p, got = float32_run
-    check = _check(got, p, batch, model, **wrong)
-    assert check["logits_rel_rms"] > 100 * FLOAT32_LIMITS["logits_rel_rms"], (
-        wrong, check)
-    if wrong.get("wrong") in MILD:
-        return
-    model, batch, p, got = amp_run
-    check = _check(got, p, batch, model, **wrong)
-    assert not check["ok"], (wrong, check)
-    assert check["logits_rel_rms"] > TOLERANCE["logits_rel_rms"], check
-
-
-# ------------------------------------------------ one step's gradients
 
 KINDS = {
     "embedding": ("qwen3next.embed",),
@@ -379,45 +90,102 @@ KINDS = {
     "experts": (".moe.w_gate", ".moe.w_up", ".moe.w_down"),
 }
 
+# QK-norm after the positions moves the logits by what bf16 rounding moves
+# them by (with the norms' weights at their seeded 1 it is the same model:
+# a rotation keeps a head's length): the float32 program catches it, the
+# cell's limit cannot and is not asked to
+MILD = ("norm_after_rope",)
 
-def _gradients(model, traffic, place=None, seed=3):
-    """{name: gradient} of the program's train step (one SGD step at rate
-    1: the gradient is what the parameter lost) and of `jax.grad` of the
-    reference's loss, from the same seeded state and batch."""
-    import jax
-
-    import paddle_tpu as fluid
-    from benchmark.models import qwen3_next as adapter
-    from benchmark.runners import train_loop
-
-    model = dict(model, optimizer={"type": "SGD", "learning_rate": 1.0})
-    main, startup, built, _ = train_loop.build_programs(
-        fluid, adapter, model, traffic, seed)
-    exe = fluid.Executor(place or fluid.CPUPlace())
-    exe.run(startup)
-    names = [p.name for p in main.global_block().all_parameters()]
-    _move_norms(names, seed)
-    before = state(names)
-    batch = batch_for(model, traffic)
-    exe.run(main, feed=batch, fetch_list=[built["loss"]])
-    got = {n: before[n] - v for n, v in state(names).items()}
-    scope = fluid.global_scope()
-    for n in list(scope.local_names()):  # the device is the reference's now
-        scope.delete(n)
-    with jax.default_matmul_precision("highest"):
-        want = f32(jax.jit(jax.grad(
-            lambda p: ref.loss(p, batch, model)))(before))
-    return got, want, before
+SUITE = Suite(  # noqa: F405
+    CELL, adapter, kinds=KINDS, as_at_width=AS_AT_WIDTH,
+    # the norms' weights off their seeded 1, so that a zero-centred norm
+    # read as a plain one, a norm left out, or QK-norm after the positions
+    # shows
+    moved=lambda n: n.endswith("norm.w_0"),
+    mixers=("delta", "attention", "experts"), mixer_program=_mixer_program,
+    want_mixer=_want_mixer, wrong_by_mixer=WRONG_BY_MIXER,
+    # the reference with its last layer left out or with one departure of
+    # `WRONG`: against the float32 program each reads hundreds of times
+    # its limit, and against the program in the cell's precision each but
+    # `MILD`'s is refused by the cell's logits' limit
+    wrong={"drop_layers": caught(100, 1, drop_layers=1),  # noqa: F405
+           **{w: caught(100, None if w in MILD else 1,  # noqa: F405
+                        wrong=(w,)) for w in adapter.WRONG}},
+    seed=51001, gradient_row=512, checkpointed="delta_recurrence",
+    step_counters=("kda_dispatch_pallas", "kda_dispatch_chunked",
+                   "kda_decay_per_head", "short_conv_dispatch_pallas",
+                   "short_conv_dispatch_xla", "attn_dispatch_flash",
+                   "attn_qk_prep_fused", "flash_bwd_fused_calls",
+                   "moe_dispatch_grouped", "moe_dispatch_gmm",
+                   "moe_route_softmax", "moe_shared_expert_gated"),
+    gauges=("gated_delta_layers", "attention_layers", "expert_layers",
+            "kda_key_group", "attn_kv_group", "attn_rotary_lanes",
+            "moe_block_rows", "moe_experts_held", "moe_experts_total",
+            "flash_blocks_visited", "flash_blocks_total"))
 
 
-def test_one_train_steps_gradients_equal_jax_grad_of_the_reference():
-    """Every parameter's gradient, by kind: `W_qkvz`, `W_ba`, the filter,
-    `A_log`, `dt_bias`, the gated norm, `W_q` with its gate half, the two
-    QK-norms, the router, the shared gate, the experts held."""
-    model, traffic = cell(precision="float32", **AS_AT_WIDTH)
-    got, want, before = _gradients(model, dict(traffic, seq_len=80))
-    worst = check_gradients(got, want, before, 2e-4, kinds=KINDS)
-    assert set(worst) == set(KINDS)
+def test_every_wrong_model_belongs_to_a_mixer():
+    assert sorted(sum(WRONG_BY_MIXER.values(), ())) == sorted(adapter.WRONG)
+
+
+def test_layer_kinds_follow_the_published_interval():
+    from paddle_tpu.models.qwen3_next import Qwen3NextConfig
+
+    model, _ = SUITE.cell(rehearse=False)
+    assert adapter.held_layers(model) == [
+        (0, "linear_attention"), (1, "linear_attention"),
+        (2, "linear_attention"), (3, "full_attention")]
+    whole = dict(model, num_hidden_layers=48)
+    kinds = [k for _, k in adapter.held_layers(whole)]
+    assert kinds.count("full_attention") == 12
+    assert [l for l, k in adapter.held_layers(whole)
+            if k == "full_attention"] == list(range(3, 48, 4))
+    cfg = adapter.config(model)
+    assert cfg.layer_kinds() == adapter.held_layers(model)
+    assert (cfg.rotary_dim, cfg.head_dim, cfg.rope_theta) == (64, 256, 1e7)
+    assert (cfg.linear_num_key_heads, cfg.linear_num_value_heads) == (16, 32)
+    assert (cfg.num_experts, cfg.experts_held, cfg.num_experts_per_token,
+            cfg.num_shared_experts, cfg.score_func) == (512, 32, 10, 1,
+                                                        "softmax")
+    assert cfg.shared_expert_gate and cfg.moe_renormalize
+    assert Qwen3NextConfig().layer_kinds()[-1] == (47, "full_attention")
+    with pytest.raises(ValueError, match="multiple"):
+        Qwen3NextConfig(shared_expert_intermediate_size=700)
+
+
+# ------------------------------------------ the kernels, by name
+
+# heads of 128 lanes, which the kernels take, at the rehearsal's other sizes
+LANES_128 = dict(AS_AT_WIDTH, linear_key_head_dim=128,
+                 linear_value_head_dim=128, head_dim=128)
+
+
+def test_delta_mixer_through_the_kernel_pair(monkeypatch):
+    """Heads of 128, two key heads under four value heads, rows of 200
+    tokens (a ragged last chunk): the mixer's Program takes `gdn_fwd`
+    under the interpreter and agrees with the token-a-step reference."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    m = SUITE.mixer("delta", batch=1, seq=200, seed=2, config=LANES_128)
+    assert m.bumped("kda_dispatch_pallas") == 1
+    assert m.bumped("kda_decay_per_head") == 1
+    assert m.counters["kda_key_group"] == 2
+    assert rel(m.got, m.want()) < 2e-5
+
+
+def test_attention_through_qk_prep_and_the_flash_kernel(monkeypatch,
+                                                        attn_path):
+    """Heads of 128 lanes of which 32 turn: the blocked kernel and the
+    `qk_prep` pair, interpreted, forced by name since the CPU's dispatch
+    never chooses them."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    attn_path("flash")
+    m = SUITE.mixer("attention", batch=1, seq=160, seed=2, config=LANES_128)
+    assert m.bumped("attn_dispatch_flash") == 1
+    assert m.bumped("attn_qk_prep_fused") == 1
+    assert (m.counters["attn_kv_group"],
+            m.counters["attn_rotary_lanes"]) == (2, 32)
+    assert rel(m.got, m.want()) < 2e-5
+    assert rel(m.got, m.want(("rope_whole_head",))) > 0.02
 
 
 # -------------------------------------------------- the expert layer
@@ -465,8 +233,8 @@ def test_the_16_shares_add_up_to_the_uncut_layer(total, held, k):
     assert int(np.sum(loads)) == u.shape[0] * u.shape[1] * k
     layer = {"num_experts_per_tok": k, "num_experts": total, "held_from": 0,
              "norm_topk_prob": True}
-    uncut = highest(ref.expert_ffn, p, u, "m", layer)
-    routed_only = highest(ref.expert_ffn, p, u, "m",
+    uncut = highest(adapter.expert_ffn, p, u, "m", layer)
+    routed_only = highest(adapter.expert_ffn, p, u, "m",
                           dict(layer, shared_expert=False))
     shared = uncut - routed_only  # what every chip computes alike
     assert np.abs(shared).max() > 1e-3
@@ -478,7 +246,7 @@ def test_the_16_shares_add_up_to_the_uncut_layer(total, held, k):
     # and one share alone is the reference's share
     p_share = dict(p, **{f"m.moe.{w}": p[f"m.moe.{w}"][held:2 * held]
                          for w in ("w_gate", "w_up", "w_down")})
-    one = highest(ref.expert_ffn, p_share, u, "m",
+    one = highest(adapter.expert_ffn, p_share, u, "m",
                   dict(layer, num_experts=held, held_from=held,
                        shared_expert=False))
     assert rel(routed[1], one) < 1e-5
@@ -489,11 +257,10 @@ def test_the_shared_experts_gate_in_the_program():
     a sigmoid and a product more than without, and the output is the
     reference's with the gate."""
     import paddle_tpu as fluid
-    from benchmark.models import qwen3_next as adapter
     from paddle_tpu import profiler
     from paddle_tpu.models import decoder_parts
 
-    model, _ = cell(**AS_AT_WIDTH)
+    model, _ = SUITE.cell(**AS_AT_WIDTH)
     cfg = adapter.config(model)
     types = {}
     for gated in (True, False):
@@ -520,18 +287,16 @@ def test_the_shared_experts_gate_in_the_program():
 
 
 def test_gauges_and_counters_at_the_rehearsal_size(monkeypatch):
-    import paddle_tpu as fluid
     from paddle_tpu import profiler
 
     # no interpreter, whatever a test file imported before this one set:
     # the convolution's 128 channels would take the kernel under it
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
-    model, traffic = cell()
+    model, traffic = SUITE.cell()
     before = profiler.counters()
-    with fluid.program_guard(fluid.Program(), fluid.Program()), \
-            fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
-        main, eval_prog, built, exe, names = built_model(model, traffic)
-        batch = batch_for(model, traffic)
+    with guards():
+        main, eval_prog, built, exe, names = SUITE.built_model(model, traffic)
+        batch = SUITE.batch_for(model, traffic)
         loads = exe.run(main, feed=batch, fetch_list=built["loads"])
     after = profiler.counters()
     assert {n: after[n] for n in (
@@ -574,10 +339,9 @@ def test_gauges_and_counters_at_the_rehearsal_size(monkeypatch):
 
 
 def test_parameters_and_flops_of_the_cell():
-    from benchmark.models import qwen3_next as adapter
     from benchmark.runners import train_loop
 
-    model, traffic = cell(rehearse=False)
+    model, traffic = SUITE.cell(rehearse=False)
     assert (traffic["batch"], traffic["seq_len"]) == (1, 4096)
     assert model["reduced"] == ["num_hidden_layers", "num_experts",
                                 "vocab_size"]
@@ -611,7 +375,7 @@ def test_parameters_and_flops_of_the_cell():
     import paddle_tpu as fluid
 
     for rehearse, want in ((True, None), (False, held)):
-        m, t = cell(rehearse=rehearse)
+        m, t = SUITE.cell(rehearse=rehearse)
         with fluid.program_guard(fluid.Program(), fluid.Program()), \
                 fluid.unique_name.guard():
             main, _, built, _ = train_loop.build_programs(
@@ -626,163 +390,5 @@ def test_parameters_and_flops_of_the_cell():
         assert len(built["loads"]) == 4
 
 
-# ------------------------------------------------------- on the chip
-
-
-def _on_chip(model, traffic, seed):
-    """The cell's programs on the attached TPU with the seeded state."""
-    import paddle_tpu as fluid
-    from benchmark.models import qwen3_next as adapter
-    from benchmark.runners import train_loop
-
-    main, startup, built, eval_prog = train_loop.build_programs(
-        fluid, adapter, model, traffic, seed)
-    exe = fluid.Executor(fluid.TPUPlace())
-    exe.run(startup)
-    return main, eval_prog, built, exe
-
-
-def chip_readings(seeds, only=(), few=2):
-    """At the published widths on the attached TPU: the cell's own check
-    (program in bf16 AMP against the float32 reference) at every seed,
-    and the same program against the wrong models named in `only` at
-    every seed, or with none named against each wrong model and the fp8
-    reference at the first `few`."""
-    import paddle_tpu as fluid
-    from benchmark.models import qwen3_next as adapter
-    from benchmark.runners import train_loop
-
-    model, traffic = cell(rehearse=False)
-    for at, seed in enumerate(seeds):
-        with fluid.program_guard(fluid.Program(), fluid.Program()), \
-                fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
-            main, eval_prog, built, exe = _on_chip(model, traffic, seed)
-            batch = adapter.make_batch(np.random.RandomState(seed), model,
-                                       traffic)
-            got_loss, got_logits = exe.run(eval_prog, feed=batch,
-                                           fetch_list=built["check"])
-            p = state([v.name for v in main.global_block().all_parameters()])
-        variants = [("reference", p, {})] + [
-            (w, p, {"wrong": (w,)}) for w in only]
-        if not only and at < few:
-            variants += [("fp8", _fp8(p), {}),
-                         ("drop_layers", p, {"drop_layers": 1})] + [
-                (w, p, {"wrong": (w,)}) for w in adapter.WRONG]
-        for label, params, kw in variants:
-            loss, logits = train_loop.reference_outputs(
-                adapter, params, batch, model, 1, **kw)
-            check = train_loop.check_reference(
-                got_loss, got_logits, loss, logits, adapter.TOLERANCE)
-            print(f"seed {seed} {label}: logits_rel_rms "
-                  f"{check['logits_rel_rms']:.5f} loss_abs "
-                  f"{check['loss_abs']:.5f} ok {check['ok']}", flush=True)
-
-
-def held_loads(seeds, steps=44, rate=None):
-    """At the published widths on the attached TPU, the cell's train step
-    on the batches its runner would feed (one check batch drawn first,
-    then the pool of 32), `steps` of them at `rate`: the share of the
-    40,960 assignments that each expert layer's 32 held experts take, at
-    the first step, the window's first (the fifth) and the last, and the
-    largest over all steps, beside the first block's share; the loss, and
-    its fall as the runner takes it."""
-    import paddle_tpu as fluid
-    from benchmark.models import qwen3_next as adapter
-    from paddle_tpu import profiler
-
-    model, traffic = cell(rehearse=False)
-    if rate:  # the sweep that chose the optimizer's rate
-        model["optimizer"] = dict(model["optimizer"], learning_rate=rate)
-    total = traffic["batch"] * traffic["seq_len"] * model["num_experts_per_tok"]
-    c0 = profiler.counters()
-    for seed in seeds:
-        with fluid.program_guard(fluid.Program(), fluid.Program()), \
-                fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
-            main, _, built, exe = _on_chip(model, traffic, seed)
-            rng = np.random.RandomState(seed)
-            adapter.make_batch(rng, model, traffic)  # the check's batch
-            pool = [adapter.make_batch(rng, model, traffic)
-                    for _ in range(traffic["pool_batches"])]
-            shares, losses = [], []
-            for i in range(steps):
-                loss, *loads = exe.run(
-                    main, feed=pool[i % len(pool)],
-                    fetch_list=[built["loss"]] + built["loads"])
-                losses.append(float(np.asarray(loss).reshape(-1)[0]))
-                shares.append([float(np.sum(x)) / total for x in loads])
-        shares = np.array(shares)
-        rows = profiler.counters()["moe_block_rows"]
-
-        def row(values):
-            return " ".join(f"{v:.4f}" for v in values)
-
-        print(f"seed {seed} rate {model['optimizer']['learning_rate']}: "
-              f"block {rows} rows = {rows / total:.4f} of "
-              f"{total}; held share by layer, step 0: {row(shares[0])}; "
-              f"step 4: {row(shares[4])}; step {steps - 1}: "
-              f"{row(shares[-1])}; largest: {row(shares.max(0))}; loss "
-              f"step 0 {losses[0]:.4f}, step 4 {losses[4]:.4f}, step "
-              f"{steps - 1} {losses[-1]:.4f}; fall (median of steps 4-13 "
-              f"less median of the last ten) "
-              f"{np.median(losses[4:14]) - np.median(losses[-10:]):.4f}; "
-              "every tenth: " + " ".join(f"{v:.3f}" for v in losses[::10]),
-              flush=True)
-    c1 = profiler.counters()
-    print("counters of", len(seeds), "train steps' traces:", {
-        n: c1.get(n, 0) - c0.get(n, 0) for n in (
-            "kda_dispatch_pallas", "kda_dispatch_chunked",
-            "kda_decay_per_head", "short_conv_dispatch_pallas",
-            "short_conv_dispatch_xla", "attn_dispatch_flash",
-            "attn_qk_prep_fused", "flash_bwd_fused_calls",
-            "moe_dispatch_grouped", "moe_dispatch_gmm", "moe_route_softmax",
-            "moe_shared_expert_gated")},
-        {n: c1.get(n) for n in (
-            "gated_delta_layers", "attention_layers", "expert_layers",
-            "kda_key_group", "attn_kv_group", "attn_rotary_lanes",
-            "moe_block_rows", "moe_experts_held", "moe_experts_total",
-            "flash_blocks_visited", "flash_blocks_total")}, flush=True)
-
-
-def chip_gradients():
-    """The gradients of every kind of parameter at the published widths,
-    program against `jax.grad` of the reference, on one 512-token row."""
-    import jax
-
-    import paddle_tpu as fluid
-
-    # How the reference is differentiated, not what it computes: the token
-    # recurrence keeps a [32, 128, 128] state a token for its backward;
-    # rebuilt a layer at a time it fits.
-    ref.delta_recurrence = jax.checkpoint(ref.delta_recurrence)
-    model, traffic = cell(rehearse=False, precision="float32")
-    traffic = dict(traffic, seq_len=512)
-    # float32 on a TPU is a bf16 pass a product unless told otherwise, so
-    # the "float32" program is held to 5%, the AMP one to 20%
-    for precision, limit, routed in (("float32", 0.05, 0.3),
-                                     ("bf16_amp", 0.2, 0.6)):
-        with fluid.program_guard(fluid.Program(), fluid.Program()), \
-                fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
-            got, want, before = _gradients(
-                dict(model, precision=precision), traffic,
-                place=fluid.TPUPlace())
-        try:
-            worst = check_gradients(got, want, before, limit, routed, KINDS)
-        except AssertionError as e:
-            print(f"FAIL {precision}: {e}", flush=True)
-            raise
-        print(f"gradients at the published widths, s=512, {precision}: "
-              "worst relative error by kind "
-              + ", ".join(f"{k} {v:.2e}" for k, v in worst.items()), flush=True)
-
-
 if __name__ == "__main__":
-    import jax
-
-    assert jax.devices()[0].platform == "tpu", jax.devices()
-    what, _, rate = sys.argv[1].partition("@")
-    what, _, only = what.partition(":")
-    seeds = [int(a) for a in sys.argv[2:]] or [51001]
-    {"readings": lambda: chip_readings(
-        seeds, tuple(w for w in only.split(",") if w)),
-     "loads": lambda: held_loads(seeds, rate=float(rate) if rate else None),
-     "gradients": chip_gradients}[what]()
+    main(SUITE)

@@ -1,6 +1,7 @@
 """The op `selective_scan` (ops/ssm_ops.py) and its gradient against
-Mamba-1's recurrence taken one token at a time, through the function and
-through a Program; float32 inside under bf16 operands; and that neither
+Mamba-1's recurrence taken one token at a time
+(`tests/kernel_cases.py`; each side and each gradient compiled), through
+the function and through a Program; float32 inside under bf16 operands; and that neither
 the forward nor the backward it lowers to holds an array of the row's
 whole trajectory, `s x d_inner x d_state`."""
 
@@ -13,36 +14,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from kernel_cases import SSM_NAMES as NAMES
+from kernel_cases import compiled, loss_grads, value_and_grads
+from kernel_cases import ssm_operands as operands
+from kernel_cases import ssm_recurrence as recurrence
+
 from paddle_tpu.ops import ssm_ops
 
 
-def recurrence(x, delta, a, bm, cm, dskip):
-    """`h = exp(delta a) h + (delta x) B^T; y = h C + D x`, one
-    `lax.scan` step a token from a zero state. x, delta: [b, s, d];
-    a: [d, n]; bm, cm: [b, s, n]; dskip: [d]."""
-    def token(h, xs):  # h [b, d, n]
-        x, delta, bm, cm = xs
-        h = (jnp.exp(delta[..., None] * a) * h
-             + (delta * x)[..., None] * bm[:, None, :])
-        return h, jnp.einsum("bdn,bn->bd", h, cm) + dskip * x
-
-    _, y = jax.lax.scan(
-        token, jnp.zeros((x.shape[0], *a.shape), jnp.float32),
-        tuple(jnp.moveaxis(t, 1, 0) for t in (x, delta, bm, cm)))
-    return jnp.moveaxis(y, 0, 1)
-
-
-def operands(b, s, d, n, seed=0, step=(-5.0, 0.5)):
-    """Steps log-uniform in `exp(step)`: at 1.6 a token and A = -15 a
-    state is gone in one token, at 0.007 it lasts the row."""
-    r = np.random.RandomState(seed)
-    return tuple(jnp.asarray(t, jnp.float32) for t in (
-        r.randn(b, s, d), np.exp(r.uniform(*step, (b, s, d))),
-        -np.exp(r.uniform(0.0, 2.7, (d, n))), r.randn(b, s, n),
-        r.randn(b, s, n), r.randn(d)))
-
-
-NAMES = ("x", "delta", "a", "b", "c", "d")
 # rows that are a multiple of the chunk (8), that are not, that are shorter
 # than one chunk, and one chunk exactly; batch 2 and batch 1
 CASES = {"six_chunks_b2": (2, 48, 24, 4), "ragged_b2": (2, 37, 24, 4),
@@ -55,37 +34,34 @@ def test_scan_and_gradient_equal_the_recurrence(case):
     args = operands(*CASES[case])
     w = jnp.asarray(np.random.RandomState(1).randn(*args[0].shape),
                     jnp.float32)
-    want = recurrence(*args)
-    got = ssm_ops.selective_scan(*args)
+    (got, grads), (want, grads_want) = (
+        value_and_grads(fn, args, w)
+        for fn in (ssm_ops.selective_scan, recurrence))
     assert got.shape == want.shape and got.dtype == jnp.float32
-    scale = float(jnp.abs(want).max())
-    assert float(jnp.abs(got - want).max()) < 2e-6 * max(scale, 1.0)
-    grads = [jax.grad(lambda *t: jnp.sum(fn(*t) * w), argnums=range(6))(*args)
-             for fn in (ssm_ops.selective_scan, recurrence)]
-    for name, g, g_want in zip(NAMES, *grads):
+    scale = float(np.abs(want).max())
+    assert float(np.abs(got - want).max()) < 2e-6 * max(scale, 1.0)
+    for name, g, g_want in zip(NAMES, grads, grads_want):
         assert g.shape == g_want.shape, name
-        scale = max(float(jnp.abs(g_want).max()), 1.0)
-        assert float(jnp.abs(g - g_want).max()) < 5e-6 * scale, name
+        scale = max(float(np.abs(g_want).max()), 1.0)
+        assert float(np.abs(g - g_want).max()) < 5e-6 * scale, name
 
 
 @pytest.mark.parametrize("chunk", [1, 2, 4, 16, 64])
 def test_the_chunk_changes_nothing_but_rounding(chunk):
     args = operands(2, 40, 16, 4, seed=3)
-    want = recurrence(*args)
-    got = ssm_ops.selective_scan(*args, chunk=chunk)
-    assert float(jnp.abs(got - want).max()) < 1e-4  # of values up to 60
+    want = compiled(recurrence, *args)
+    got = compiled(lambda *t: ssm_ops.selective_scan(*t, chunk=chunk), *args)
+    assert float(np.abs(got - want).max()) < 1e-4  # of values up to 60
 
 
 def test_steps_of_any_size_overflow_nothing():
     """Steps up to e^6 a token under A = -15: every exponent inside is at
     most 0, so a decay underflows to 0 and nothing reaches infinity."""
     args = operands(1, 32, 8, 4, seed=4, step=(-8.0, 6.0))
-    got = ssm_ops.selective_scan(*args)
-    grads = jax.grad(lambda *t: jnp.sum(ssm_ops.selective_scan(*t)),
-                     argnums=range(6))(*args)
-    assert all(bool(jnp.isfinite(t).all()) for t in (got, *grads))
-    want = recurrence(*args)
-    assert float(jnp.abs(got - want).max()) < 1e-4 * float(jnp.abs(want).max())
+    got, grads = value_and_grads(ssm_ops.selective_scan, args, 1.0)
+    assert all(bool(np.isfinite(t).all()) for t in (got, *grads))
+    want = compiled(recurrence, *args)
+    assert float(np.abs(got - want).max()) < 1e-4 * float(np.abs(want).max())
 
 
 def test_float32_inside_under_bf16_operands():
@@ -99,7 +75,7 @@ def test_float32_inside_under_bf16_operands():
     got = ssm_ops.selective_scan(low[0], low[1], a, low[2], low[3], dskip)
     assert got.dtype == jnp.bfloat16
     up = [t.astype(jnp.float32) for t in low]
-    want = recurrence(up[0], up[1], a, up[2], up[3], dskip)
+    want = jax.jit(recurrence)(up[0], up[1], a, up[2], up[3], dskip)
     err = float(jnp.sqrt(jnp.mean((got.astype(jnp.float32) - want) ** 2))
                 / jnp.sqrt(jnp.mean(want ** 2)))
     assert err < 3e-3  # half an ulp of bf16, 2^-9, on average less
@@ -172,9 +148,8 @@ def test_op_in_a_program_value_gradient_shape_and_counters():
             - before.get("ssm_dispatch_chunked", 0)) == 1  # the gradient op reads Starts
     assert (after["ssm_state_size"], after["ssm_chunk_len"]) == (
         n, ssm_ops.CHUNK)
-    np.testing.assert_allclose(got[0], recurrence(*args), atol=1e-5)
-    want = jax.grad(lambda *t: jnp.sum(recurrence(*t) * w),
-                    argnums=range(6))(*args)
+    np.testing.assert_allclose(got[0], jax.jit(recurrence)(*args), atol=1e-5)
+    want = loss_grads(recurrence, args, w)
     for name, g, g_want in zip(NAMES, got[1:], want):
         np.testing.assert_allclose(g, g_want, atol=2e-5, rtol=2e-6,
                                    err_msg=name)

@@ -1,7 +1,7 @@
 """The selective scan's kernel pair (`ops/pallas/selective_scan.py`) under
 the Pallas interpreter: value and all six gradients against Mamba-1's
 recurrence taken one token at a time and against the chunked form of
-`ops/ssm_ops.py`; float32 inside under bf16 operands; the state carried
+`ops/ssm_ops.py` (each side and each gradient compiled); float32 inside under bf16 operands; the state carried
 over grid steps and reset between rows; the declared cost; which path
 the two ops take, what `Starts` they declare and what the counters say;
 and the benchmark's data file for the kernels' time."""
@@ -18,7 +18,10 @@ import pytest
 
 from paddle_tpu.ops import ssm_ops
 from paddle_tpu.ops.pallas import selective_scan as kernel
-from test_selective_scan import NAMES, operands, recurrence
+from kernel_cases import SSM_NAMES as NAMES
+from kernel_cases import loss_grads, value_and_grads
+from kernel_cases import ssm_operands as operands
+from kernel_cases import ssm_recurrence
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -39,9 +42,7 @@ def in_kernels(*args):
     return kernel.selective_scan(*args)[0]
 
 
-def _grads(fn, args, w):
-    return jax.grad(lambda *t: jnp.sum(fn(*t).astype(jnp.float32) * w),
-                    argnums=range(6))(*args)
+recurrence = jax.jit(ssm_recurrence)
 
 
 # (b, s, d_inner, d_state): rows of whole blocks (64), of one block and a
@@ -60,18 +61,16 @@ def test_kernels_equal_the_recurrence_and_the_chunked_form(case, interpreter):
     args = operands(*CASES[case], seed=2)
     w = jnp.asarray(np.random.RandomState(1).randn(*args[0].shape),
                     jnp.float32)
-    want = recurrence(*args)
-    got, starts = kernel.selective_scan(*args)
+    (got, grads), (want, grads_want), (chunked, grads_chunked) = (
+        value_and_grads(fn, args, w)
+        for fn in (in_kernels, ssm_recurrence, ssm_ops.selective_scan))
+    starts = jax.jit(lambda *t: kernel.selective_scan(*t)[1])(*args)
     b, s, d, n = CASES[case]
     assert got.shape == want.shape and got.dtype == jnp.float32
     assert starts.shape == (-(-s // kernel.BLOCK), b, n, d)
     scale = max(float(jnp.abs(want).max()), 1.0)
     assert float(jnp.abs(got - want).max()) < 2e-6 * scale
-    assert float(jnp.abs(got - ssm_ops.selective_scan(*args)).max()) \
-        < 2e-6 * scale
-    grads, grads_want, grads_chunked = (
-        _grads(fn, args, w)
-        for fn in (in_kernels, recurrence, ssm_ops.selective_scan))
+    assert float(jnp.abs(got - chunked).max()) < 2e-6 * scale
     for name, g, g_want, g_chunked in zip(NAMES, grads, grads_want,
                                           grads_chunked):
         assert g.shape == g_want.shape and g.dtype == g_want.dtype, name
@@ -108,8 +107,8 @@ def test_steps_of_any_size_overflow_nothing_in_the_kernels(interpreter):
     """`tests/test_selective_scan.py`'s case on the kernels: steps up to
     e^6 a token under A = -15. The exponent is `Delta A`, at most 0."""
     args = operands(1, 32, 128, 8, seed=4, step=(-8.0, 6.0))
-    got = in_kernels(*args)
-    grads = _grads(in_kernels, args, 1.0)
+    got = jax.jit(in_kernels)(*args)
+    grads = loss_grads(in_kernels, args, 1.0)
     assert all(bool(jnp.isfinite(t).all()) for t in (got, *grads))
     want = recurrence(*args)
     assert float(jnp.abs(got - want).max()) < 1e-4 * float(jnp.abs(want).max())
@@ -135,8 +134,8 @@ def test_float32_inside_the_kernels_under_bf16_operands(interpreter):
                      / jnp.sqrt(jnp.mean(want ** 2)))
 
     assert rms(got, recurrence(*wide)) < 3e-3  # half an ulp of bf16, 2^-9
-    grads = _grads(in_kernels, args, 1.0)
-    grads_want = _grads(recurrence, wide, 1.0)
+    grads = loss_grads(in_kernels, args, 1.0)
+    grads_want = loss_grads(recurrence, wide, 1.0)
     for name, t, g, g_want in zip(NAMES, args, grads, grads_want):
         assert g.dtype == t.dtype, name
         assert rms(g, g_want) < (3e-3 if g.dtype == jnp.bfloat16 else 1e-5), \
@@ -261,8 +260,7 @@ def test_op_in_a_program_takes_the_path_the_call_shows(path, monkeypatch):
     assert bumped == {f"ssm_dispatch_{path}": 1, f"ssm_dispatch_{other}": 0}
     assert (after["ssm_state_size"], after["ssm_chunk_len"]) == (n, kept)
     close(got[0], recurrence(*args))
-    want = jax.grad(lambda *t: jnp.sum(recurrence(*t) * w),
-                    argnums=range(6))(*args)
+    want = loss_grads(ssm_recurrence, args, w)
     for name, g, g_want in zip(NAMES, got[1:], want):
         close(g, g_want, name)
 
@@ -302,8 +300,7 @@ def test_a_batch_mesh_runs_the_kernels_per_shard(interpreter):
     assert bumped == {"ssm_dispatch_pallas": 1, "ssm_dispatch_chunked": 0}
     assert starts.shape == (2, 2, 8, 128)
     close(y, recurrence(*args))
-    want = jax.grad(lambda *t: jnp.sum(recurrence(*t) * dy),
-                    argnums=range(6))(*args)
+    want = loss_grads(ssm_recurrence, args, dy)
     for name, g, g_want in zip(NAMES, grads, want):
         close(g, g_want, name)
 
@@ -323,8 +320,7 @@ def test_a_tensor_parallel_mesh_keeps_the_chunked_form(interpreter):
     _, kept = kernel.selective_scan(*args)
     close(starts, kept, "Starts")
     close(y, recurrence(*args))
-    want = jax.grad(lambda *t: jnp.sum(recurrence(*t) * dy),
-                    argnums=range(6))(*args)
+    want = loss_grads(ssm_recurrence, args, dy)
     for name, g, g_want in zip(NAMES, grads, want):
         close(g, g_want, name)
 

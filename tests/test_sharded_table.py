@@ -24,7 +24,7 @@ from paddle_tpu.incubate.fleet.parameter_server.host_table import (
     save_distributed_persistables,
 )
 
-from test_host_table import _batch, _build_ctr
+from ctr_model import batch, build_ctr
 
 VOCAB, DIM, SEED, LR = 50_000, 8, 11, 0.1
 
@@ -111,7 +111,7 @@ def _spawn_server_procs(n, vocab=VOCAB, dim=DIM):
 def _train_ctr(sess, loss, rng, steps):
     out = []
     for _ in range(steps):
-        feed = _batch(rng, VOCAB)
+        feed = batch(rng, VOCAB)
         (lv,) = sess.run(feed, fetch_list=[loss])
         out.append(float(np.asarray(lv).reshape(-1)[0]))
     return out
@@ -124,7 +124,7 @@ def test_ctr_two_process_loss_exact():
     fleet_wrapper.h:66,100)."""
     # single-process baseline
     main, startup = Program(), Program()
-    loss = _build_ctr(main, startup)
+    loss = build_ctr(main, startup)
     exe = fluid.Executor(fluid.CPUPlace())
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
@@ -141,7 +141,7 @@ def test_ctr_two_process_loss_exact():
         finally:
             del os.environ["PADDLE_PSERVERS_IP_PORT_LIST"]
         main2, startup2 = Program(), Program()
-        loss2 = _build_ctr(main2, startup2)
+        loss2 = build_ctr(main2, startup2)
         # fresh Executor: its functional-PRNG run counter starts at 0, so
         # the dense-tower init draws match the baseline run's exactly
         exe2 = fluid.Executor(fluid.CPUPlace())
@@ -175,7 +175,7 @@ def test_ctr_sharded_kill_resume_loss_exact(tmp_path):
     try:
         dist = DistributedEmbeddingTable(VOCAB, DIM, endpoints=eps)
         main, startup = Program(), Program()
-        loss = _build_ctr(main, startup)
+        loss = build_ctr(main, startup)
         exe = fluid.Executor(fluid.CPUPlace())
         scope = fluid.Scope()
         with fluid.scope_guard(scope):
@@ -195,7 +195,7 @@ def test_ctr_sharded_kill_resume_loss_exact(tmp_path):
     try:
         dist = DistributedEmbeddingTable(VOCAB, DIM, endpoints=eps)
         main, startup = Program(), Program()
-        loss = _build_ctr(main, startup)
+        loss = build_ctr(main, startup)
         exe = fluid.Executor(fluid.CPUPlace())
         scope = fluid.Scope()
         with fluid.scope_guard(scope):

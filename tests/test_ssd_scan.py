@@ -1,6 +1,7 @@
 """The op `ssd_scan` (ops/ssm_ops.py, Mamba-2's recurrence in chunks of
 matrix products) and its gradient op against the recurrence taken one
-token at a time: through the function, forward and the gradients by x,
+token at a time (each side and each gradient compiled): through the
+function, forward and the gradients by x,
 Delta, a, B, C and D, at rows of one chunk, several chunks, a ragged last
 chunk and fewer tokens than a chunk, with one group and with several; and
 through a Program, where the step's softplus and the decay's exponential
@@ -12,6 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+from kernel_cases import compiled, loss_grads, value_and_grads
 
 from paddle_tpu.ops import ssm_ops
 
@@ -75,17 +78,15 @@ def test_chunks_and_gradients_equal_the_recurrence(case):
     def by_token(*t):
         return recurrence(*t, groups=groups)
 
-    with jax.default_matmul_precision("highest"):
-        want = by_token(*args)
-        got, starts = ssm_ops.ssd_scan_with_starts(*args, groups, chunk)
-        assert got.shape == want.shape and got.dtype == jnp.float32
-        assert starts.shape == (-(-s // min(chunk, s)), b, heads, p, n)
-        scale = max(float(jnp.abs(want).max()), 1.0)
-        assert float(jnp.abs(got - want).max()) < 5e-6 * scale
-        grads = [jax.grad(lambda *t: jnp.sum(fn(*t) * w),
-                          argnums=range(6))(*args)
-                 for fn in (chunked, by_token)]
-    for name, g, g_want in zip(NAMES, *grads):
+    (got, grads), (want, grads_want) = (
+        value_and_grads(fn, args, w) for fn in (chunked, by_token))
+    starts = compiled(lambda *t: ssm_ops.ssd_scan_with_starts(
+        *t, groups, chunk)[1], *args)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    assert starts.shape == (-(-s // min(chunk, s)), b, heads, p, n)
+    scale = max(float(jnp.abs(want).max()), 1.0)
+    assert float(jnp.abs(got - want).max()) < 5e-6 * scale
+    for name, g, g_want in zip(NAMES, grads, grads_want):
         assert g.shape == g_want.shape, name
         scale = max(float(jnp.abs(g_want).max()), 1.0)
         assert float(jnp.abs(g - g_want).max()) < 2e-5 * scale, name
@@ -112,11 +113,13 @@ def test_steps_of_any_size_overflow_nothing():
     """Steps up to e^6 a token under a = -15: every exponent inside is at
     most 0, so a decay underflows to 0 and nothing reaches infinity."""
     args = operands(1, 64, 2, 4, 8, seed=4, step=(-8.0, 6.0))
-    got = ssm_ops.ssd_scan(*args, 1, 16)
-    grads = jax.grad(lambda *t: jnp.sum(ssm_ops.ssd_scan(*t, 1, 16)),
-                     argnums=range(6))(*args)
+    def chunked(*t):
+        return ssm_ops.ssd_scan(*t, 1, 16)
+
+    got = jax.jit(chunked)(*args)
+    grads = loss_grads(chunked, args, 1.0)
     assert all(bool(jnp.isfinite(t).all()) for t in (got, *grads))
-    want = recurrence(*args)
+    want = jax.jit(recurrence)(*args)
     assert float(jnp.abs(got - want).max()) < 1e-4 * float(jnp.abs(want).max())
 
 
@@ -127,7 +130,7 @@ def test_float32_inside_under_bf16_operands():
     got = ssm_ops.ssd_scan(low[0], delta, a, low[1], low[2], dskip, 1, 64)
     assert got.dtype == jnp.bfloat16
     up = [t.astype(jnp.float32) for t in low]
-    want = recurrence(up[0], delta, a, up[1], up[2], dskip)
+    want = jax.jit(recurrence)(up[0], delta, a, up[1], up[2], dskip)
     err = float(jnp.sqrt(jnp.mean((got.astype(jnp.float32) - want) ** 2))
                 / jnp.sqrt(jnp.mean(want ** 2)))
     assert err < 3e-3  # half an ulp of bf16, 2^-9, on average less
@@ -208,9 +211,9 @@ def test_op_in_a_program_value_gradient_shape_and_counters():
                           bm, cm, dskip, groups)
 
     args = (x, dt, bm, cm, *(jnp.asarray(t) for t in (dt_bias, a_log, dskip)))
-    np.testing.assert_allclose(got[0], model(*args), atol=1e-5)
-    want = jax.grad(lambda *t: jnp.sum(model(*t) * w),
-                    argnums=range(7))(*args)
+    np.testing.assert_allclose(got[0], jax.jit(model)(*args), atol=1e-5)
+    want = jax.jit(jax.grad(lambda *t: jnp.sum(model(*t) * w),
+                            argnums=range(7)))(*args)
     for name, g, g_want in zip((*shapes, "dt_bias", "A_log", "D"),
                                got[1:], want):
         np.testing.assert_allclose(g, g_want, atol=2e-5, rtol=2e-5,

@@ -1,77 +1,102 @@
-"""Trinity against its plain reference (`tests/trinity_reference.py`) at
-the rehearsal size of the cell `trinity_mini_ep16_s8192`: the attention
-mixer, window and full, and for the whole model; one train step's
-gradients for every kind of parameter; the expert layer's shares against
-the uncut layer at this router's scale; that each wrong model is caught by
-the cell's tolerance; the op `rotary_embedding`; QK-norm and the positions
-inside the attention op against the model built from the separate ops;
-the cell's counters and FLOPs.
+"""Trinity against its plain reference (`benchmark/models/trinity.py`) at
+the rehearsal size of the cell `trinity_mini_ep16_s8192`: what every
+decoder suite holds (`tests/decoder_suite.py`: the attention mixer, window
+and full, the feed-forwards and the whole model; one train step's
+gradients for every kind of parameter; that each wrong model is caught by
+the cell's tolerance) on this model's data, and its own: the op
+`rotary_embedding`; QK-norm and the positions inside the attention op
+against the model built from the separate ops; the expert layer's shares
+against the uncut layer at this router's scale; the cell's counters and
+FLOPs.
 
-Run as a script, the gradient comparison is made at the published widths
-on one 1,024-token row on the attached TPU, outside any timed window:
+Run as a script on the attached TPU (`tests/decoder_suite.py` has the
+arguments; with none, the gradients at the published widths on one
+1,024-token row):
 
     python3 tests/test_trinity_reference.py
 """
 
 from __future__ import annotations
 
-import inspect
+import math
 import os
-import sys
 
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from decoder_suite import *  # noqa: F401,F403 — the shared cases, on SUITE
+from decoder_suite import f32, guards, highest, main, rel, state
 
-import trinity_reference as ref  # noqa: E402 — beside this file
-from test_kimi_linear_reference import (  # noqa: E402 — the shared helpers
-    check_gradients, f32, highest, rel, state)
+from benchmark.models import trinity as adapter  # noqa: E402
 
 CELL = "trinity_mini_ep16_s8192"
 
 
-def cell(rehearse=True, **config):
-    from benchmark.harness import spec
-
-    c = spec.cell(CELL, rehearse=rehearse)
-    c["config"].update(config)
-    return c["config"], c["traffic"]
-
-
-def built_model(model, traffic, seed=3):
-    """Programs, executor and the seeded state by name, in a scope of its
-    own (the caller holds the guards)."""
+def _mixer_program(which, model, batch, seq):
+    """The attention mixer or a feed-forward alone in a Program: `u` in,
+    `y` out."""
     import paddle_tpu as fluid
-    from benchmark.models import trinity as adapter
-    from benchmark.runners import train_loop
+    from paddle_tpu.models import decoder_parts, trinity as zoo
 
-    main, startup, built, eval_prog = train_loop.build_programs(
-        fluid, adapter, model, traffic, seed)
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup)
-    names = [p.name for p in main.global_block().all_parameters()]
-    return main, eval_prog, built, exe, names
-
-
-def batch_for(model, traffic, seed=0):
-    from benchmark.models import trinity as adapter
-
-    return adapter.make_batch(np.random.RandomState(seed), model, traffic)
+    cfg = adapter.config(model)
+    u = fluid.layers.data("u", [batch, seq, cfg.hidden_size],
+                          append_batch_size=False)
+    if which in ("window", "full"):
+        return zoo._attention(u, cfg, "m",
+                              cfg.sliding_window if which == "window" else 0)
+    if which == "dense":
+        return decoder_parts.ffn(u, cfg.intermediate_size, "m.mlp", cfg)
+    return decoder_parts.expert_ffn(u, cfg, "m")[0]
 
 
-# ------------------------------------------------- the copy is a copy
+def _want_mixer(which, p, feeds, model, wrong=()):
+    u = feeds["u"]
+    if which == "dense":
+        return highest(adapter._ffn, p, u, "m.mlp")
+    if which == "experts":
+        return highest(adapter.expert_ffn, p, u, "m", model)
+    return highest(adapter.attention_mixer, p, u, "m", model,
+                   model["sliding_window"] if which == "window" else 0)
 
 
-def test_reference_copy_is_the_adapters_word_for_word():
-    from benchmark.models import trinity as adapter
+KINDS = {
+    "embedding": ("trinity.embed",), "head": ("trinity.head.w_0",),
+    "rms_norm": (".input_norm.w_0", ".post_attn_norm.w_0",
+                 ".pre_mlp_norm.w_0", ".post_mlp_norm.w_0",
+                 "final_norm.w_0"),
+    "qk_norm": (".q_norm.w_0", ".k_norm.w_0"),
+    "attention": (".attn.q.w_0", ".attn.k.w_0", ".attn.v.w_0",
+                  ".attn.o.w_0"),
+    "attention_gate": (".attn.gate.w_0",),
+    "dense_ffn": (".mlp.gate.w_0", ".mlp.up.w_0", ".mlp.down.w_0"),
+    "shared_expert": (".shared.gate.w_0", ".shared.up.w_0", ".shared.down.w_0"),
+    "router": (".moe.gate",),
+    "experts": (".moe.w_gate", ".moe.w_up", ".moe.w_down"),
+}
 
-    for name in ("held_layers", "_rms", "_silu", "_ffn", "_rope",
-                 "attention_mixer", "expert_ffn", "reference"):
-        assert inspect.getsource(getattr(ref, name)) == inspect.getsource(
-            getattr(adapter, name)), name
-    assert (ref.SCORED_EVERY, ref.QUERY_BLOCK) == (
-        adapter.SCORED_EVERY, adapter.QUERY_BLOCK)
+SUITE = Suite(  # noqa: F405
+    CELL, adapter, kinds=KINDS,
+    mixers=("window", "full", "dense", "experts"),
+    mixer_program=_mixer_program, want_mixer=_want_mixer,
+    # the reference with its last layer left out, every layer full, no
+    # positions, no gate, no QK-norm, or the group mapped `n % g`: with
+    # room, the mildest (no QK-norm) reads 11.7% against the cell's 6%
+    wrong={"drop_layers": caught(amp=1.5, drop_layers=1),  # noqa: F405
+           **{w: caught(amp=1.5, wrong=(w,))  # noqa: F405
+              for w in adapter.WRONG}},
+    # at 64 wide the router's logits spread by 0.16 and a correction of
+    # 0.1 would pick the same two experts for every token, none of them
+    # held in some layer; at the published width they spread by 0.9
+    gradients_at={"router_bias_scale": 0.02},
+    # under AMP the cell's own tolerance holds here, loss and all
+    amp_loss_room=1,
+    seed=33001,
+    step_counters=("attn_dispatch_flash", "attn_dispatch_flash_window",
+                   "attn_qk_prep_fused", "moe_dispatch_grouped",
+                   "moe_dispatch_gmm"),
+    gauges=("attn_kv_group", "moe_block_rows", "moe_experts_held",
+            "moe_experts_total", "flash_blocks_visited",
+            "flash_blocks_total"))
 
 
 # ------------------------------------------------ the op rotary_embedding
@@ -110,11 +135,11 @@ def test_rotary_embedding_value_gradient_and_shape(shape):
     data = np.random.RandomState(0).randn(*shape).astype(np.float32)
     exe = fluid.Executor(fluid.CPUPlace())
     got, got_dx = exe.run(feed={"x": data}, fetch_list=[y, dx])
-    want = highest(ref._rope, jnp.asarray(data), 10000.0)
+    want = highest(adapter._rope, jnp.asarray(data), 10000.0)
     # an ulp in a frequency (XLA folds the constant its own way) times the
     # position: 4e-6 rad at position 69
     np.testing.assert_allclose(got, want, atol=2e-5)
-    want_dx = f32(jax.grad(lambda t: jnp.sum(ref._rope(t, 10000.0) * w))(
+    want_dx = f32(jax.grad(lambda t: jnp.sum(adapter._rope(t, 10000.0) * w))(
         jnp.asarray(data)))
     np.testing.assert_allclose(got_dx, want_dx, atol=2e-5)
     d, pos, i = shape[3], shape[1] - 1, 1
@@ -138,57 +163,13 @@ def test_rotary_embedding_is_float32_inside_under_amp():
     x = jnp.asarray(r.randn(1, 8192, 1, 16), jnp.bfloat16)
     got = rotate_half(x, 10000.0)
     assert got.dtype == jnp.bfloat16
-    want = highest(ref._rope, x.astype(jnp.float32), 10000.0)
+    want = highest(adapter._rope, x.astype(jnp.float32), 10000.0)
     # one rounding of the output to bf16 and no more
     assert np.abs(np.asarray(got, np.float32) - want)[0, -64:].max() < 2e-2
     assert rel(np.asarray(got, np.float32)[0, -64:], want[0, -64:]) < 4e-3
 
 
-# ------------------------------------------ the program, mixer by mixer
-
-
-def _mixer_program(which, model, batch=2, seq=80):
-    """The attention mixer or a feed-forward alone in a Program: `u` in,
-    `y` out."""
-    import paddle_tpu as fluid
-    from benchmark.models import trinity as adapter
-    from paddle_tpu.models import decoder_parts, trinity as zoo
-
-    cfg = adapter.config(model)
-    u = fluid.layers.data("u", [batch, seq, cfg.hidden_size],
-                          append_batch_size=False)
-    if which in ("window", "full"):
-        y = zoo._attention(u, cfg, "m",
-                           cfg.sliding_window if which == "window" else 0)
-    elif which == "dense":
-        y = decoder_parts.ffn(u, cfg.intermediate_size, "m.mlp", cfg)
-    else:
-        y, _ = decoder_parts.expert_ffn(u, cfg, "m")
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
-    names = [p.name for p in
-             fluid.default_main_program().global_block().all_parameters()]
-    return exe, y, names
-
-
-def _want_mixer(which, p, u, model):
-    return {"window": lambda: ref.attention_mixer(
-                p, u, "m", model, model["sliding_window"]),
-            "full": lambda: ref.attention_mixer(p, u, "m", model, 0),
-            "dense": lambda: ref._ffn(p, u, "m.mlp"),
-            "experts": lambda: ref.expert_ffn(p, u, "m", model)}[which]
-
-
-@pytest.mark.parametrize("which", ["window", "full", "dense", "experts"])
-def test_program_mixer_equals_reference(which):
-    model, _ = cell()
-    exe, y, names = _mixer_program(which, model)
-    u = np.random.RandomState(1).randn(2, 80, model["hidden_size"]).astype(
-        np.float32)
-    (got,) = exe.run(feed={"u": u}, fetch_list=[y])
-    want = highest(_want_mixer(which, state(names), u, model))
-    assert np.abs(want).max() > 1e-4  # something was computed
-    assert rel(got, want) < 2e-5
+# ------------------------------------------ the flash kernel, by name
 
 
 @pytest.mark.parametrize("which", ["window", "full"])
@@ -198,131 +179,12 @@ def test_attention_through_the_flash_kernel(which, monkeypatch, attn_path):
     CPU's dispatch never chooses it."""
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
     attn_path("flash")
-    from paddle_tpu import profiler
-
-    before = profiler.counters()
-    model, _ = cell(sliding_window=50)
-    exe, y, names = _mixer_program(which, model, batch=1, seq=160)
-    u = np.random.RandomState(2).randn(1, 160, model["hidden_size"]).astype(
-        np.float32)
-    (got,) = exe.run(feed={"u": u}, fetch_list=[y])
-    after = profiler.counters()
-    assert after["attn_dispatch_flash"] == before.get(
-        "attn_dispatch_flash", 0) + 1
-    assert (after.get("attn_dispatch_flash_window", 0)
-            - before.get("attn_dispatch_flash_window", 0)) == (which == "window")
-    assert after["attn_kv_group"] == 2
-    want = highest(_want_mixer(which, state(names), u, model))
-    assert rel(got, want) < 2e-5
-
-
-# ------------------------------------------------------ the whole model
-
-
-@pytest.mark.parametrize("precision,limit", [("float32", 5e-5),
-                                             ("bf16_amp", None)])
-def test_whole_model_logits_and_loss_equal_reference(precision, limit):
-    from benchmark.models import trinity as adapter
-    from benchmark.runners import train_loop
-
-    model, traffic = cell(precision=precision)
-    traffic = dict(traffic, seq_len=80)
-    _, eval_prog, built, exe, names = built_model(model, traffic)
-    batch = batch_for(model, traffic)
-    got_loss, got_logits = exe.run(eval_prog, feed=batch,
-                                   fetch_list=built["check"])
-    nll, count, want = highest(ref.reference, state(names), batch, model)
-    check = train_loop.check_reference(
-        got_loss, got_logits, nll / count, want[:adapter.SCORED_SEQUENCES],
-        adapter.TOLERANCE)
-    assert check["ok"], check
-    if limit:
-        assert check["logits_rel_rms"] < limit and check["loss_abs"] < 1e-5
-
-
-@pytest.mark.parametrize("wrong", [
-    {"drop_layers": 1}, {"wrong": ("all_full",)}, {"wrong": ("no_rope",)},
-    {"wrong": ("no_gate",)}, {"wrong": ("no_qk_norm",)},
-    {"wrong": ("group_mod",)}])
-def test_a_wrong_model_is_caught_by_the_cells_tolerance(wrong):
-    """The reference with its last layer left out, every layer full, no
-    positions, no gate, no QK-norm, or the group mapped `n % g`, against
-    the program in the cell's precision."""
-    from benchmark.models import trinity as adapter
-    from benchmark.runners import train_loop
-
-    model, traffic = cell()
-    _, eval_prog, built, exe, names = built_model(model, traffic)
-    batch = batch_for(model, traffic)
-    got_loss, got_logits = exe.run(eval_prog, feed=batch,
-                                   fetch_list=built["check"])
-    p = state(names)
-    for kw, ok in ((wrong, False), ({}, True)):
-        nll, count, want = highest(adapter.reference, p, batch, model, **kw)
-        check = train_loop.check_reference(
-            got_loss, got_logits, nll / count,
-            want[:adapter.SCORED_SEQUENCES], adapter.TOLERANCE)
-        assert check["ok"] is ok, (kw, check)
-        if not ok:  # with room: the mildest, no QK-norm, reads 11.7% here
-            assert check["logits_rel_rms"] > 1.5 * adapter.TOLERANCE[
-                "logits_rel_rms"]
-
-
-# ------------------------------------------------ one step's gradients
-
-KINDS = {
-    "embedding": ("trinity.embed",), "head": ("trinity.head.w_0",),
-    "rms_norm": (".input_norm.w_0", ".post_attn_norm.w_0",
-                 ".pre_mlp_norm.w_0", ".post_mlp_norm.w_0",
-                 "final_norm.w_0"),
-    "qk_norm": (".q_norm.w_0", ".k_norm.w_0"),
-    "attention": (".attn.q.w_0", ".attn.k.w_0", ".attn.v.w_0",
-                  ".attn.o.w_0"),
-    "attention_gate": (".attn.gate.w_0",),
-    "dense_ffn": (".mlp.gate.w_0", ".mlp.up.w_0", ".mlp.down.w_0"),
-    "shared_expert": (".shared.gate.w_0", ".shared.up.w_0", ".shared.down.w_0"),
-    "router": (".moe.gate",),
-    "experts": (".moe.w_gate", ".moe.w_up", ".moe.w_down"),
-}
-def _grad_of_reference(before, batch, model):
-    import jax
-
-    with jax.default_matmul_precision("highest"):
-        return f32(jax.jit(jax.grad(
-            lambda p: ref.loss(p, batch, model)))(before))
-
-
-def _gradients(model, traffic, place=None, seed=3):
-    """{name: gradient} of the program's train step (one SGD step at rate
-    1: the gradient is what the parameter lost) and of `jax.grad` of the
-    reference's loss, from the same seeded state and batch."""
-    import paddle_tpu as fluid
-    from benchmark.models import trinity as adapter
-    from benchmark.runners import train_loop
-
-    model = dict(model, optimizer={"type": "SGD", "learning_rate": 1.0})
-    main, startup, built, _ = train_loop.build_programs(
-        fluid, adapter, model, traffic, seed)
-    exe = fluid.Executor(place or fluid.CPUPlace())
-    exe.run(startup)
-    names = [p.name for p in main.global_block().all_parameters()]
-    before = state(names)
-    batch = batch_for(model, traffic)
-    exe.run(main, feed=batch, fetch_list=[built["loss"]])
-    got = {n: before[n] - v for n, v in state(names).items()}
-    scope = fluid.global_scope()
-    for n in list(scope.local_names()):  # the device is the reference's now
-        scope.delete(n)
-    return got, _grad_of_reference(before, batch, model), before
-
-
-def test_one_train_steps_gradients_equal_jax_grad_of_the_reference():
-    # at 64 wide the router's logits spread by 0.16 and a correction of
-    # 0.1 would pick the same two experts for every token, none of them
-    # held in some layer; at the published width they spread by 0.9
-    model, traffic = cell(precision="float32", router_bias_scale=0.02)
-    check_gradients(*_gradients(model, dict(traffic, seq_len=80)), 2e-4,
-                    kinds=KINDS)
+    m = SUITE.mixer(which, batch=1, seq=160, seed=2,
+                    config={"sliding_window": 50})
+    assert m.bumped("attn_dispatch_flash") == 1
+    assert m.bumped("attn_dispatch_flash_window") == (which == "window")
+    assert m.counters["attn_kv_group"] == 2
+    assert rel(m.got, m.want()) < 2e-5
 
 
 # ------------------------- QK-norm and positions inside the attention op
@@ -332,8 +194,6 @@ def _attention_separate(u, cfg, name, window):
     """`models/trinity.py::_attention` as it stood before the attention op
     took QK-norm and the positions: an op each, which is what the fused
     op has to mean everywhere."""
-    import math
-
     from paddle_tpu import layers
     from paddle_tpu.models.decoder_parts import norm, proj
 
@@ -359,12 +219,10 @@ def _loss_and_gradients(model, traffic, seed=3):
     """(loss, {name: gradient}, the forward's count of fused lowerings,
     the train program's op types) in programs and a scope of its own."""
     import paddle_tpu as fluid
-    from benchmark.models import trinity as adapter
     from benchmark.runners import train_loop
     from paddle_tpu import profiler
 
-    with fluid.program_guard(fluid.Program(), fluid.Program()), \
-            fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
+    with guards():
         model = dict(model, optimizer={"type": "SGD", "learning_rate": 1.0})
         main, startup, built, eval_prog = train_loop.build_programs(
             fluid, adapter, model, traffic, seed)
@@ -372,7 +230,7 @@ def _loss_and_gradients(model, traffic, seed=3):
         exe.run(startup)
         names = [p.name for p in main.global_block().all_parameters()]
         before = state(names)
-        batch = batch_for(model, traffic)
+        batch = SUITE.batch_for(model, traffic)
         count = profiler.counters().get("attn_qk_prep_fused", 0)
         (loss,) = exe.run(eval_prog, feed=batch, fetch_list=[built["loss"]])
         count = profiler.counters().get("attn_qk_prep_fused", 0) - count
@@ -397,11 +255,11 @@ def test_fused_qk_prep_is_the_separate_ops_model(kernels, monkeypatch,
     if kernels:
         monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
         attn_path("flash")
-    model, traffic = cell(precision="float32", head_dim=128,
+    model, traffic = SUITE.cell(precision="float32", head_dim=128,
                           num_hidden_layers=2, first_layer_held=2,
                           router_bias_scale=0.02)
     traffic = dict(traffic, seq_len=48)
-    assert [w for _, w, _ in ref.held_layers(model)] == [16, 0]
+    assert [w for _, w, _ in adapter.held_layers(model)] == [16, 0]
     loss, grads, count, ops = _loss_and_gradients(model, traffic)
     assert count == (2 if kernels else 0)
     assert "rotary_embedding" not in ops
@@ -418,7 +276,7 @@ def test_fused_qk_prep_is_the_separate_ops_model(kernels, monkeypatch,
             continue
         assert np.abs(want[name]).max() > 0, name
         # a gradient read as before - after carries the parameter's own
-        # float32 rounding (test_kimi_linear_reference.check_gradients)
+        # float32 rounding (decoder_suite.check_gradients)
         room = 1.2e-7 * (1 + np.abs(want[name]).max())
         err = np.abs(grads[name] - want[name]).max()
         assert max(err - room, 0.0) < 1e-5 * np.abs(want[name]).max(), name
@@ -442,8 +300,7 @@ def test_attention_without_the_new_inputs_is_the_op_it_was():
                                      ("bert_base_s128", 80, 2)):
         c = spec.cell(cell_name, rehearse=True)
         adapter = spec.plugin("models", c["config"]["adapter"])
-        with fluid.program_guard(fluid.Program(), fluid.Program()), \
-                fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
+        with guards():
             built = adapter.build(c["config"], c["traffic"])
             main = fluid.default_main_program()
             ops = main.global_block().ops
@@ -534,13 +391,13 @@ def test_the_16_shares_add_up_to_the_uncut_layer(total, held, k):
     layer = {"num_experts_per_tok": k, "num_experts": total, "held_from": 0,
              "route_norm": True, "route_scale": 2.826,
              "num_shared_experts": 1}
-    shared = highest(ref._ffn, p, u, "m.shared")
-    uncut = highest(ref.expert_ffn, p, u, "m", layer)
+    shared = highest(adapter._ffn, p, u, "m.shared")
+    uncut = highest(adapter.expert_ffn, p, u, "m", layer)
     assert rel(shared + sum(routed), uncut) < 1e-5
     # and one share alone is the reference's share
     p_share = dict(p, **{f"m.moe.{w}": p[f"m.moe.{w}"][held:2 * held]
                          for w in ("w_gate", "w_up", "w_down")})
-    one = highest(ref.expert_ffn, p_share, u, "m",
+    one = highest(adapter.expert_ffn, p_share, u, "m",
                   dict(layer, num_experts=held, held_from=held))
     assert rel(shared + routed[1], one) < 1e-5
 
@@ -549,10 +406,9 @@ def test_the_16_shares_add_up_to_the_uncut_layer(total, held, k):
 
 
 def test_counters_and_flops_of_the_cell():
-    from benchmark.models import trinity as adapter
     from paddle_tpu import profiler
 
-    model, traffic = cell(rehearse=False)
+    model, traffic = SUITE.cell(rehearse=False)
     assert (traffic["batch"], traffic["seq_len"]) == (1, 8192)
     assert adapter.held_layers(model) == [
         (1, 2048, True), (2, 2048, False), (3, 0, False), (4, 2048, False),
@@ -583,9 +439,9 @@ def test_counters_and_flops_of_the_cell():
         flops - 3.0 * 2 * 8192 * per_token)
 
     c0 = profiler.counters()
-    small, small_traffic = cell()
-    main, _, built, exe, _ = built_model(small, small_traffic)
-    batch = batch_for(small, small_traffic)
+    small, small_traffic = SUITE.cell()
+    main, _, built, exe, _ = SUITE.built_model(small, small_traffic)
+    batch = SUITE.batch_for(small, small_traffic)
     loads = exe.run(main, feed=batch, fetch_list=built["loads"])
     c1 = profiler.counters()
     grouped = c1["moe_dispatch_grouped"] - c0.get("moe_dispatch_grouped", 0)
@@ -599,34 +455,20 @@ def test_counters_and_flops_of_the_cell():
     assert (c1["moe_experts_held"], c1["moe_experts_total"]) == (2, 8)
     assert c1["attn_kv_group"] == 2
     assert c1["attn_dispatch_xla"] - c0.get("attn_dispatch_xla", 0) >= 5
+    # and no counter that is another decoder's: no softmax router, no
+    # scaled positions, no convolution at all
+    for other in ("moe_route_softmax", "attn_rope_scaled",
+                  "short_conv_linear_calls"):
+        assert c1.get(other, 0) == c0.get(other, 0), other
+    experts = [op for op in main.global_block().ops
+               if op.type == "moe_experts"]
+    assert all(op.attr("score_func") == "sigmoid" for op in experts)
+    # the rehearsal's shares are 1/4 (2 of 8 experts): a block of 7/16
+    tokens = small_traffic["batch"] * small_traffic["seq_len"]
+    assert c1["moe_block_rows"] == math.ceil(
+        1.75 * 0.25 * tokens * experts[0].attr("k"))
     assert len(loads) == 4 and all(x.shape == (2,) for x in loads)
 
 
 if __name__ == "__main__":
-    # On the attached TPU: the gradients of every kind of parameter at the
-    # published widths, float32 program against jax.grad of the reference,
-    # on one 1,024-token row.
-    import jax
-
-    import paddle_tpu as fluid
-
-    assert jax.devices()[0].platform == "tpu", jax.devices()
-    model, traffic = cell(rehearse=False, precision="float32")
-    traffic = dict(traffic, seq_len=1024)
-    # float32 on a TPU is a bf16 pass a product unless told otherwise, so
-    # the "float32" program is held to 5%, the AMP one to 20%
-    for precision, limit, routed in (("float32", 0.05, 0.3),
-                                     ("bf16_amp", 0.2, 0.6)):
-        with fluid.program_guard(fluid.Program(), fluid.Program()), \
-                fluid.unique_name.guard(), fluid.scope_guard(fluid.Scope()):
-            got, want, before = _gradients(
-                dict(model, precision=precision), traffic,
-                place=fluid.TPUPlace())
-        try:
-            worst = check_gradients(got, want, before, limit, routed, KINDS)
-        except AssertionError as e:
-            print(f"FAIL {precision}: {e}", flush=True)
-            raise
-        print(f"gradients at the published widths, s=1024, {precision}: "
-              "worst relative error by kind "
-              + ", ".join(f"{k} {v:.2e}" for k, v in worst.items()), flush=True)
+    main(SUITE)
