@@ -17,6 +17,7 @@ API (executor.py:619,730).
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import time
 
@@ -37,7 +38,7 @@ from .framework import (
 )
 from .ops.registry import JNP_DTYPE, LoweringContext, lower_block, lower_op
 from .place import CPUPlace, Place, TPUPlace
-from . import profiler
+from . import profiler, step_store
 from .profiler import RecordEvent
 from .resilience.faults import fault_point
 from .scope import Scope, global_scope
@@ -179,7 +180,8 @@ def _step_owner(block) -> str:
 @contextlib.contextmanager
 def _first_call(owner):
     """Round the first call of a step's jit, the one that traces, lowers
-    and compiles (or reads the persistent cache): the one place the
+    and compiles (or reads the persistent cache, or finds the step whole
+    in `step_store`): the one place the
     compile path's owner is set. What the call paid by stage lands in
     `compile_*.<owner>` (`jit_compile`), each op's lowering in
     `trace_op_us.<owner>.<scope>` (`ops/registry.py::lower_op`), and its
@@ -191,11 +193,13 @@ def _first_call(owner):
                           int((time.perf_counter() - t0) * 1e6))
 
 
-def _instrument_compiled(compiled, block):
+def _instrument_compiled(compiled, block, store_key=None):
     """Always-on compile-path counters (style of dygraph_jit_*): every
     cache miss bumps program_compile_count and program_traced_ops (ops
     the jit trace will lower), and the first dispatch runs under
-    `_first_call`. Steady-state calls pay one flag check."""
+    `_first_call`, where `step_store` is asked for the step under
+    `store_key` (`_store_key`; None: a step that is not stored).
+    Steady-state calls pay one flag check."""
     profiler.bump_counter("program_compile_count")
     profiler.bump_counter("program_traced_ops", len(block.ops))
     compiled.owner = owner = _step_owner(block)
@@ -204,17 +208,89 @@ def _instrument_compiled(compiled, block):
     # trace+StableHLO without XLA compile (tools/bench_passes.py times
     # the trace/lower phase through this)
     pending = [True]
+    call = [inner]  # after the first call: what `step_store` made of it
 
-    def fn(*args, **kwargs):
+    def fn(*args):
         if not pending:
-            return inner(*args, **kwargs)
+            return call[0](*args)
         with _first_call(owner):
-            result = inner(*args, **kwargs)
+            call[0], result = step_store.first_call(
+                inner, args, store_key, owner)
         pending.clear()
         return result
 
     compiled.fn = fn
     return compiled
+
+
+# What rides on a Program beside `to_dict()` (so beside its fingerprint)
+# and a lowering, a step maker or the state's placement reads: each is in
+# `_store_key`. Whoever teaches a lowering to read another adds it here;
+# `tests/test_step_store.py` reads the package's source for every private
+# attribute of a Program and fails on one that is in neither tuple.
+_THE_LOWERINGS_READ = (
+    "_amp_dtype", "_amp_black_list", "_amp_white_list",  # LoweringContext
+    "_pipeline_microbatches", "_pipeline_loss",  # the microbatched step
+    "_recompute_loss",  # the recompute step
+    "_sharding_specs", "_autoshard_specs",  # placement; `lookup_table`
+    "_is_test_clone",
+)
+# ... and what no trace reads: who built or runs the Program (the mesh a
+# fleet strategy resolves to is in `asked`), and what a compile leaves
+_NOT_THE_LOWERINGS = (
+    "_version", "_cached_fp", "_ckpt_manager", "_fleet_strategy",
+    "_fleet_compiled", "_feed_shardings", "_layout_opt_stats",
+    "_autoshard_plan",
+    "_program",  # a CompiledProgram's: the Program it wraps
+)
+
+
+def _rides_on(program) -> tuple:
+    def said(value):
+        if isinstance(value, (set, frozenset)):
+            return sorted(map(str, value))
+        if isinstance(value, dict):
+            return sorted((str(k), str(v)) for k, v in value.items())
+        return repr(value)
+
+    return tuple((name, said(getattr(program, name, None)))
+                 for name in _THE_LOWERINGS_READ)
+
+
+def _lowered_from_elsewhere(program) -> bool:
+    """A lowering that lives outside this package (`register_op` in a
+    user's module, one patched in place) is in no digest of the store's:
+    a step that takes one is not stored."""
+    from .ops.registry import get_op, has_op
+
+    package = __name__.split(".")[0] + "."
+    return any(
+        t and has_op(t)
+        and not (get_op(t).lower.__module__ or "").startswith(package)
+        for block in program.blocks for op in block.ops
+        # `__auto_grad__` runs its forward op's lowering again
+        for t in (op.type, op.attr("fwd_type")))
+
+
+def _store_key(program, lowered, asked, is_test, jit_kwargs):
+    """What `step_store` files a step under, from what is in hand without
+    tracing it: `_prepare_run`'s key without the scope's identity
+    (`asked`), the Program as it was handed in and as `_compile` lowers it
+    (after the passes, which may have read the scope), each without its
+    seed (an argument of the step: two seeds, one executable) and with
+    what rides on it outside its fingerprint (`_THE_LOWERINGS_READ`: the
+    AMP lists that decide an op's precision, the pipeline's loss, the
+    specs), and what the jit was told. The arguments' avals and
+    shardings, which say the rest of what the scope gave, and the
+    process's surroundings are the store's to add. Everything the three
+    step makers close over follows from these, but a lowering from
+    outside the package: None, not stored."""
+    # both: a pass may fold the authored ops into one of its own
+    if _lowered_from_elsewhere(program) or _lowered_from_elsewhere(lowered):
+        return None
+    return (program.fingerprint(with_seed=False), _rides_on(program),
+            lowered.fingerprint(with_seed=False), _rides_on(lowered),
+            asked, is_test, jit_kwargs)
 
 
 def check_nan_result(result, compiled, scope):
@@ -672,9 +748,13 @@ class Executor:
         batch_axes=("batch",),
         build_strategy=None,
         zero1=False,
+        asked=None,
     ):
+        """`asked` is `_prepare_run`'s key without the Program and the
+        scope, for `step_store`; without it the step is not stored."""
         from .parallel import mesh as mesh_mod
 
+        handed = program  # the passes below put their clone in its name
         feed_names = tuple(n for n, _, _ in feed_sig)
         pipe_n = mesh.shape.get("pipe", 1) if mesh is not None else 1
         use_pp_schedule = pipe_n > 1 and not is_test
@@ -777,6 +857,13 @@ class Executor:
 
             step._nan_names = nan_names
 
+        def finish(compiled, jit_kwargs):
+            compiled.nan_names = getattr(step, "_nan_names", None)
+            compiled.written_only = written_only
+            return _instrument_compiled(
+                compiled, block, asked and functools.partial(
+                    _store_key, handed, program, asked, is_test, jit_kwargs))
+
         if mesh is not None:
             # GSPMD path (CompiledProgram / fleet / dryrun): the
             # spec-assignment layer (parallel/mesh.py) maps every Program
@@ -850,22 +937,19 @@ class Executor:
                 # builder supports it (plain, microbatched AND recompute
                 # all attach _nan_names as of round 3)
                 out_sh.append(NamedSharding(mesh, P()))
-            compiled = _CompiledStep(
-                step,
-                dict(donate_argnums=(0,),
-                     in_shardings=(state_sh, feed_sh, None),
-                     out_shardings=tuple(out_sh)),
-                state_names, feed_names, fetch_names)
+            jit_kwargs = dict(donate_argnums=(0,),
+                              in_shardings=(state_sh, feed_sh, None),
+                              out_shardings=tuple(out_sh))
+            compiled = _CompiledStep(step, jit_kwargs, state_names,
+                                     feed_names, fetch_names)
             # dispatch-side reshard map: a live COMMITTED array whose
             # layout disagrees with this compile's assignment (e.g. a
             # replicated moment from a pre-zero1 run) must be device_put
             # onto the new sharding before the call — jit raises on the
             # mismatch instead of resharding committed args
-            compiled.state_shardings = state_sh
+            compiled.state_shardings = dict(state_sh)
             compiled.feed_shardings = feed_sh
-            compiled.nan_names = getattr(step, "_nan_names", None)
-            compiled.written_only = written_only
-            return _instrument_compiled(compiled, block)
+            return finish(compiled, jit_kwargs)
 
         # The state keeps the default layout. `Layout.AUTO` on it does
         # not survive the persistent compile cache: an executable read
@@ -876,9 +960,7 @@ class Executor:
         jit_kwargs = dict(donate_argnums=(0,))
         compiled = _CompiledStep(step, jit_kwargs, state_names, feed_names,
                                  fetch_names)
-        compiled.nan_names = getattr(step, "_nan_names", None)
-        compiled.written_only = written_only
-        return _instrument_compiled(compiled, block)
+        return finish(compiled, jit_kwargs)
 
     # ------------------------------------------------------------------
     def _unwrap(self, program):
@@ -1035,11 +1117,10 @@ class Executor:
             # the stale executable
             placement = (
                 mesh_signature(mesh, program._sharding_specs), zero1)
-        key = (
-            self._program_key(program),
+        check_nan = os.environ.get("PADDLE_TPU_CHECK_NAN_INF") == "1"
+        asked = (
             feed_sig,
             tuple(fetch_names),
-            id(scope),
             getattr(program, "_pipeline_microbatches", 1),
             getattr(program, "_recompute_loss", None),
             # amp dtype rides on the program WITHOUT bumping _version
@@ -1047,12 +1128,13 @@ class Executor:
             # set it post-build): without it in the key, flipping a
             # program to bf16 after an fp32 run served the fp32 step
             getattr(program, "_amp_dtype", None),
-            os.environ.get("PADDLE_TPU_CHECK_NAN_INF") == "1",
+            check_nan,
             # flipping PADDLE_TPU_PASSES between runs must recompile —
             # a stale step would keep the old pass set's graph
             _resolve_pass_names(strategy),
             placement,
         )
+        key = (self._program_key(program), id(scope)) + asked
         compiled = self._cache.get(key)
         if compiled is None:
             with RecordEvent("pt.exe.compile"):
@@ -1069,6 +1151,9 @@ class Executor:
                     sharding_specs=program._sharding_specs,
                     build_strategy=strategy,
                     zero1=zero1,
+                    # a NaN check's trace leaves the flags' names in this
+                    # process: such a step is not stored
+                    asked=None if check_nan else asked,
                 )
             # _assemble_state lays replicated state over it on a fleet of
             # processes
@@ -1152,12 +1237,20 @@ class Executor:
                 val = jnp.asarray(val)
             elif state_sh:
                 want = state_sh.get(n)
-                if want is not None and val.sharding != want:
+                if want is None or val.sharding is want:
+                    pass  # steady state: the step's own output
+                elif val.sharding != want:
                     # one-time reshard: a committed layout from an
                     # earlier compile (different zero1/pipe specs)
                     # moves onto this compile's assignment; steady
                     # state re-enters already matching (out_shardings)
                     val = jax.device_put(val, want)
+                else:
+                    # equal and another object (a step that `step_store`
+                    # loaded hands out its own): adopted, so that the
+                    # next step's compare is the identity above and not
+                    # a thousand `__eq__`s
+                    state_sh[n] = val.sharding
             if fleet_rep is not None and val.is_fully_addressable:
                 # anything else is already a global (possibly sharded)
                 # array from a previous step — pass through, never fetch

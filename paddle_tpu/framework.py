@@ -698,9 +698,12 @@ class Program:
                 blk.append_op(od["type"], od["inputs"], od["outputs"], attrs)
         return p
 
-    def fingerprint(self) -> str:
+    def fingerprint(self, with_seed: bool = True) -> str:
         """Structural hash for executor compile caching (the role of
-        Fluid's program cache keys, reference executor.py:253)."""
+        Fluid's program cache keys, reference executor.py:253). Without
+        the seed it is what the lowering reads: `random_seed` reaches a
+        step as an argument (`executor._seed_words`), so two Programs
+        that differ in it alone compile to one executable."""
         import hashlib
         import json
 
@@ -711,7 +714,10 @@ class Program:
                 return o.tolist()
             return str(o)
 
-        payload = json.dumps(self.to_dict(), sort_keys=True, default=_default)
+        described = self.to_dict()
+        if not with_seed:
+            del described["random_seed"]
+        payload = json.dumps(described, sort_keys=True, default=_default)
         return hashlib.sha1(payload.encode()).hexdigest()
 
     def __repr__(self):

@@ -11,7 +11,17 @@ It also owns what a compile is seen to cost: JAX reports each stage of
 one (function to jaxpr, jaxpr to StableHLO, backend compile or cache
 read) through `jax.monitoring`, and the listeners below file them as
 always-on `profiler` counters under the owner that `compile_owner` names
-(`PERF.md`, section 3, lists them and the metric each is for)."""
+(`PERF.md`, section 3, lists them and the metric each is for).
+
+Beside that cache, under `<COMPILE_CACHE_DIR>/steps/`, live the Executor's
+compiled steps (`step_store.py`): one file a step, its executable and the
+counts its trace left, found at the step's first call before anything is
+traced, so a warm start pays the read and neither of the two stages in
+front of it. The file's name holds everything the lowering reads, a digest
+of this package's source among it: a changed source file of the package
+makes new entries (code registered from outside it is in no digest), and
+writing one removes the files it replaces. The directory is
+safe to delete at any time."""
 
 from __future__ import annotations
 

@@ -34,6 +34,8 @@ __all__ = [
     "bump_counter",
     "set_counter",
     "counters",
+    "recorded_counters",
+    "replay_counters",
     "time_counter",
 ]
 
@@ -44,6 +46,7 @@ _counters: dict[str, int] = defaultdict(int)  # monotonic named counts
 # increment would make this global roll-up diverge from the per-
 # instance CounterSet totals it promises to equal
 _counters_lock = threading.Lock()
+_recording = threading.local()  # .log: what `recorded_counters` collects
 _active = False
 _trace_dir = None
 
@@ -54,6 +57,9 @@ def bump_counter(name: str, amount: int = 1) -> int:
     dygraph JIT bridge bumps dygraph_jit_cache_hit / _miss /
     _fallback here so the per-op-dispatch-removed speedup is observable
     next to the span table."""
+    log = getattr(_recording, "log", None)
+    if log is not None:
+        log.append(("bump", name, amount))
     with _counters_lock:
         _counters[name] += amount
         return _counters[name]
@@ -199,6 +205,9 @@ def set_counter(name: str, value: int) -> int:
     fleet_deploys / fleet_deploy_failures via bump, plus
     fleet_deploy_rollbacks = workers re-deployed back to the old
     version after a mid-fleet-deploy failure)."""
+    log = getattr(_recording, "log", None)
+    if log is not None:
+        log.append(("set", name, int(value)))
     with _counters_lock:
         _counters[name] = int(value)
         return _counters[name]
@@ -207,6 +216,29 @@ def set_counter(name: str, value: int) -> int:
 def counters() -> dict:
     with _counters_lock:
         return dict(_counters)
+
+
+@contextlib.contextmanager
+def recorded_counters():
+    """Every `bump_counter` and `set_counter` THIS thread makes inside the
+    body, in order, as `("bump" | "set", name, value)`: what a trace said
+    about its program, which `step_store` keeps beside the executable and
+    `replay_counters` says again when the trace is not made. Another
+    thread's bumps (the reader's stager runs beside a first call) are not
+    in it."""
+    before = getattr(_recording, "log", None)
+    _recording.log = log = []
+    try:
+        yield log
+    finally:
+        _recording.log = before
+        if before is not None:
+            before.extend(log)
+
+
+def replay_counters(log) -> None:
+    for kind, name, value in log:
+        (bump_counter if kind == "bump" else set_counter)(name, value)
 
 
 class CounterSet:

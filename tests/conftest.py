@@ -43,6 +43,18 @@ def pytest_configure(config):
     )
 
 
+@pytest.fixture(autouse=True, scope="session")
+def no_step_store():
+    """The store of compiled steps (`paddle_tpu/step_store.py`) has no
+    directory for the session: the suite patches lowerings and kernels in
+    place, which no key can see, so a step found on disk could be another
+    test's. `tests/test_step_store.py` gives it a temporary one."""
+    from paddle_tpu import step_store
+
+    step_store.DIR = None
+    yield
+
+
 @pytest.fixture(autouse=True)
 def fresh_programs():
     """Each test gets fresh default programs + scope (the reference resets

@@ -529,6 +529,9 @@ def test_a_rehearsal_line_carries_the_two_compile_counts():
     assert metrics["setup_other_compiles"]["value"] >= 1
     # the rehearsal turns the persistent cache off: nothing is looked up
     assert metrics["setup_uncached_compiles"] == {"value": 0, "unit": "count"}
+    # and with it the store of compiled steps: nothing is found or written
+    assert metrics["setup_step_store_hits"] == {"value": 0, "unit": "count"}
+    assert metrics["setup_step_store_errors"] == {"value": 0, "unit": "count"}
     assert not any(name.endswith("_s") for name in metrics)  # no time
 
 
