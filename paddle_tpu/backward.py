@@ -10,7 +10,9 @@ Custom grad makers (dropout) emit dedicated grad op types.
 
 from __future__ import annotations
 
+from . import profiler
 from .framework import (
+    Parameter,
     Variable,
     core_op_role,
     grad_var_name,
@@ -50,9 +52,17 @@ def _op_path(block, targets, inputs=None):
     return path
 
 
-def _accumulate(block, partials, target_name, role=core_op_role.Backward):
+def _accumulate(block, partials, target_name, role=core_op_role.Backward,
+                of=None):
     """Sum partial grads into target grad var (reference: backward.py:135
-    _addup_repetitive_outputs_)."""
+    _addup_repetitive_outputs_). `of`: the forward variable whose gradient
+    the target is; where it is a parameter with more than one partial (a
+    weight the model applies more than once) the counters
+    `param_grads_summed` and `param_grad_partials` count it and them, at
+    Program build time. A residual stream's partials do not count."""
+    if isinstance(of, Parameter) and len(partials) > 1:
+        profiler.bump_counter("param_grads_summed")
+        profiler.bump_counter("param_grad_partials", len(partials))
     if len(partials) == 1:
         if partials[0] != target_name:
             block.append_op(
@@ -244,7 +254,7 @@ def _backward_sweep(block, targets, target_grads, no_grad_set, parameter_names=N
             continue
         gname = grad_var_name(n)
         _make_grad_var(block, block.var(n), gname)
-        _accumulate(block, plist, gname)
+        _accumulate(block, plist, gname, of=block.var(n))
         final[n] = gname
     return final
 

@@ -61,8 +61,12 @@ class LayerHelper:
             regularizer=attr.regularizer,
             initializer=initializer,
         )
-        # mirrored in startup program with its init op
+        # mirrored in startup program with its init op, once: a name used
+        # again (a weight a model applies more than once) is seeded by its
+        # first use
         startup_block = self.startup_program.global_block()
+        if name in startup_block.vars:
+            return param
         sp = startup_block.create_parameter(
             name,
             shape,

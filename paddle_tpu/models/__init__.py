@@ -4,8 +4,9 @@ LeNet-5 (MNIST), ResNet (ImageNet), SE-ResNeXt, VGG, Transformer/BERT
 (WMT16 / pretrain), DeepFM (CTR), Kimi Linear, Trinity, Mellum, JoyAI
 Flash, LFM2, Qwen3-Next and Nemotron-H (each a share of an
 expert-parallel decoder; Nemotron-H's mixers hold a share of their heads
-too), and Phi-4-mini-flash (a pipeline stage's share of a
-decoder-hybrid-decoder)."""
+too), Phi-4-mini-flash (a pipeline stage's share of a
+decoder-hybrid-decoder), and Ouro (a pipeline stage's share of a decoder
+that runs its layers several times with one set of weights)."""
 
 from . import (  # noqa: F401
     bert,
@@ -16,6 +17,7 @@ from . import (  # noqa: F401
     lfm2,
     mellum,
     nemotron_h,
+    ouro,
     phi4_flash,
     qwen3_next,
     resnet,
@@ -29,6 +31,7 @@ from .kimi_linear import KimiLinearConfig, build_kimi_linear  # noqa: E402,F401
 from .lfm2 import Lfm2Config, build_lfm2  # noqa: E402,F401
 from .mellum import MellumConfig, build_mellum  # noqa: E402,F401
 from .nemotron_h import NemotronHConfig, build_nemotron_h  # noqa: E402,F401
+from .ouro import OuroConfig, build_ouro  # noqa: E402,F401
 from .phi4_flash import Phi4FlashConfig, build_phi4_flash  # noqa: E402,F401
 from .qwen3_next import Qwen3NextConfig, build_qwen3_next  # noqa: E402,F401
 from .trinity import TrinityConfig, build_trinity  # noqa: E402,F401

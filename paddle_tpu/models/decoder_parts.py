@@ -1,9 +1,9 @@
 """What the decoders of the zoo (`kimi_linear`, `trinity`, `mellum`,
-`joyai_flash`, `phi4_flash`, `lfm2`, `qwen3_next`, `nemotron_h`) build
-their layers from: projections seeded Normal(0, `initializer_range`), with
-a bias where asked, RMSNorm with a learned weight and LayerNorm with
-weight and bias, the SiLU-gated feed-forward as three products or with
-gate and up in one, the squared-ReLU feed-forward without a gate
+`joyai_flash`, `phi4_flash`, `lfm2`, `qwen3_next`, `nemotron_h`, `ouro`)
+build their layers from: projections seeded Normal(0,
+`initializer_range`), with a bias where asked, RMSNorm with a learned
+weight and LayerNorm with weight and bias, the SiLU-gated feed-forward as
+three products or with gate and up in one, the squared-ReLU feed-forward without a gate
 (`nemotron_h`), attention over grouped key/value heads with or without
 QK-norm and with rotary positions on a head, on its first lanes or not at
 all, latent attention (`kimi_linear`, `joyai_flash`), differential
@@ -242,13 +242,14 @@ def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
     """Causal attention of `num_attention_heads` query heads over
     `num_key_value_heads` key/value heads of `head_dim`, u [b, s, hidden]
     to [b, s, hidden]: q and k normed over a head's width (one weight of
-    `head_dim` each; not with `qk_norm` False, and then there are no
-    positions either), turned by rotary positions where `rope_theta` is
-    not 0 (`rope_scaling`: a YaRN group; `rotary_dim` not 0: the first
-    `rotary_dim` lanes of a head alone), `window` keys wide where it is
-    not 0, and with `gated` the output times `sigmoid(W_g u)` before the
-    output projection. The heads are the ones held here, which may be a
-    share of the model's."""
+    `head_dim` each; not with `qk_norm` False), turned by rotary positions
+    where `rope_theta` is not 0 (`rope_scaling`: a YaRN group;
+    `rotary_dim` not 0: the first `rotary_dim` lanes of a head alone;
+    neither without the QK-norm, whose pass they share: there the whole
+    heads are turned by the op `rotary_embedding`), `window` keys wide
+    where it is not 0, and with `gated` the output times `sigmoid(W_g u)`
+    before the output projection. The heads are the ones held here, which
+    may be a share of the model's."""
     b, s, _ = u.shape
     h, g, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     q = layers.reshape(proj(u, h * d, name + ".q", cfg), [b, s, h, d])
@@ -265,8 +266,13 @@ def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
                     qk_norm_epsilon=cfg.rms_norm_eps, rope_theta=rope_theta,
                     rope_scaling=rope_scaling, rotary_dim=rotary_dim)
     elif rope_theta:
-        raise ValueError("attention: positions are turned with the "
-                         "QK-norm's pass, and qk_norm is False")
+        if rope_scaling or rotary_dim:
+            raise ValueError("attention: scaled or partial positions are "
+                             "turned with the QK-norm's pass, and qk_norm "
+                             "is False")
+        # no norm to share a pass with: the op `rotary_embedding` on each
+        q = layers.rotary_embedding(q, theta=rope_theta)
+        k = layers.rotary_embedding(k, theta=rope_theta)
     a = layers.fused_multihead_attention(
         q, k, v, causal=True, sm_scale=1.0 / math.sqrt(d), layout="bshd",
         window=window, **prep)

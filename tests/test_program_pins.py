@@ -89,6 +89,10 @@ PINS = {
         {"fused_multihead_attention": 1, "short_conv1d": 2,
          "ssd_scan": 2, "moe_experts": 2},
         ("norm_eps", "expert_form",)),
+    # PR 57's own tree: the cell it adds
+    "ouro_2p6b_vp8_s4096": (
+        760, "14bed2b3f6a5c0f1",
+        {"fused_multihead_attention": 8, "rotary_embedding": 16}, ()),
 }
 
 
