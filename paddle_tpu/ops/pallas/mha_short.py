@@ -20,11 +20,26 @@ back.
 
 Mathematics as `_xla_attention`, at no lower precision: products
 accumulate in float32; scale, key bias `[b, sk]`, the bottom-right-aligned
-causal mask and softmax in float32; probabilities cast to the input dtype
-for `P @ V`. Dropout comes from a hash of (head, query, key, seed),
-regenerated and never stored. The backward recomputes the probabilities,
-row maxima and sums included, from Q and K: its residuals are the
-operands, and no `[b, h, sq, sk]` array reaches HBM.
+causal mask and softmax in float32 (the exponential as 2**x, with log2(e)
+folded into the scale); the softmax's numerators (the forward) and the
+probabilities and dS (the backward) cast to the input dtype for their
+products.
+What the score tile `[hp*sq, sk]` costs the vector units is kept off it
+where something narrower can carry it: the rows' 1/l comes from the
+approximate reciprocal and two Newton steps (a float32 division without
+its special cases, on a column that fills a register every eight rows),
+the forward normalises its `[rows, 128]` result and not the tile, as
+`flash_attention.py` does, and the backward's constants multiply its
+products' float32 results.
+
+Dropout comes from a hash of (seed, batch row, 128-lane slice, row of the
+stacked tile's upper half, key): one finalised word for the two scores a
+half apart in the rows (at two heads a slice, the same query and key of
+the slice's two heads), the second draw being the word times an odd
+constant. The mask is regenerated and never stored, the same in the
+forward and the backward, whatever the block and the mesh. The backward
+recomputes the probabilities, row maxima and sums included, from Q and K:
+its residuals are the operands, and no `[b, h, sq, sk]` array reaches HBM.
 """
 
 from __future__ import annotations
@@ -45,6 +60,7 @@ from .flash_attention import LANE, NEG_INF, _ceil_to, _interpret, require_pallas
 # Findings, PR 25): a length is inside only if its cell gained.
 MAX_SHORT_SEQ = 512
 
+LOG2E = 1.4426950408889634
 _VMEM_LIMIT = 64 << 20  # of v5e's 128 MiB; the default is 16 MiB
 _VMEM_BUDGET = 24 << 20  # what _pick_bb counts: blocks and score tiles
 
@@ -60,18 +76,26 @@ def _u32(x):
     return jax.lax.convert_element_type(x, jnp.uint32)
 
 
-def _keep3(seed, head, hqk, dropout):
-    """Hash keep-mask over [bb, rows, sk]: the murmur generator of
-    flash_attention._dropout_keep, with `head` the global batch*heads
-    index of each row and `hqk` the query and key part of the hash. The
-    forward and the backward regenerate identical masks."""
-    h = hqk ^ (_u32(seed) + head * jnp.uint32(0xC2B2AE35))
+def _keep3(seed, slab, rk, dropout):
+    """Hash keep-mask over [bb, rows, sk] at one finalised word for two
+    scores: murmur's finaliser (flash_attention._dropout_keep's generator)
+    over [bb, rows/2, sk], from `rk`, the row and key part of the hash
+    [rows/2, sk], and `slab`, each batch row's global index of (batch row,
+    128-lane slice). Rows r and r + rows/2 of the stacked tile share the
+    word: the upper half compares it with the threshold, the lower half
+    its product with an odd constant. That is a bijection of the 32-bit
+    words, so each draw keeps at the one rate 1 - int(p * 2**32) / 2**32;
+    and (h, a*h mod 2**32) is a point of the lattice of a multiplicative
+    generator with a good multiplier, so the pair fills the unit square
+    evenly (tests/test_mha_short.py counts the four joint outcomes)."""
+    h = rk ^ (_u32(seed) + slab * jnp.uint32(0xC2B2AE35))
     h = h ^ (h >> 16)
     h = h * jnp.uint32(0x85EBCA6B)
     h = h ^ (h >> 13)
     h = h * jnp.uint32(0xC2B2AE35)
     h = h ^ (h >> 16)
-    return h >= jnp.uint32(min(int(dropout * 2**32), 2**32 - 1))
+    words = jnp.concatenate([h, h * jnp.uint32(0x9E3779B1)], axis=1)
+    return words >= jnp.uint32(min(int(dropout * 2**32), 2**32 - 1))
 
 
 # batched dot shorthands over a block's batch rows; all accumulate float32
@@ -129,76 +153,105 @@ def _unstack_heads(y, hp):
     return out
 
 
-def _probs(seed_ref, q_ref, k_ref, bias_ref, *, num_heads, hp, sm_scale,
-           causal, causal_offset, dropout):
+def _scores(q_ref, k_ref, bias_ref, *, hp, sm_scale, causal, causal_offset):
     """One 128-lane slice of a block: the stacked heads' queries
-    [bb, hp*sq, 128], the softmax of their scores [bb, hp*sq, sk] in
-    float32, and the dropout keep-mask (None without dropout)."""
-    bb, sq, _ = q_ref.shape
-    sk = k_ref.shape[1]
+    [bb, hp*sq, 128] and their scores [bb, hp*sq, sk] in float32, scaled,
+    biased and masked."""
+    sq, sk = q_ref.shape[1], k_ref.shape[1]
     qs = _stack_heads(q_ref[...], hp)
-    s = _bdot_qkT(qs, k_ref[...]) * sm_scale
+    s = _bdot_qkT(qs, k_ref[...]) * (sm_scale * LOG2E)
     if bias_ref is not None:
-        s = s + bias_ref[...]  # [bb, 1, sk] over the rows
-    # what depends on the row and the key alone is computed once, [hp*sq,
-    # sk], and broadcast over the block's batch rows
-    row, sub = _segment((hp * sq, sk), 0, sq, hp)  # sub: the head in the slice
-    ki = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
-    qi = row - sub * sq
+        s = s + bias_ref[...] * LOG2E  # [bb, 1, sk] over the rows
     if causal:
-        s = s + jnp.where(qi + causal_offset >= ki, 0.0, NEG_INF)
-    # m is clamped so that a fully masked row underflows to p == 0 and not
-    # to the uniform exp(NEG_INF - NEG_INF); partly masked entries
+        # what depends on the row and the key alone is computed once,
+        # [hp*sq, sk], and broadcast over the block's batch rows
+        row, sub = _segment((hp * sq, sk), 0, sq, hp)  # sub: head in slice
+        ki = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+        s = s + jnp.where(row - sub * sq + causal_offset >= ki, 0.0, NEG_INF)
+    return qs, s
+
+
+def _keep(seed_ref, shape, num_heads, hp, dropout):
+    """The dropout keep-mask of a block's score tile `shape`."""
+    bb, rows, sk = shape
+    # seed_ref[1]: the global index of the call's first batch row, so that
+    # the shards of a mesh draw the masks of the whole batch
+    bi = seed_ref[1] + pl.program_id(0) * bb + jax.lax.broadcasted_iota(
+        jnp.int32, (bb, 1, sk), 0)
+    slab = _u32(bi * (num_heads // hp) + pl.program_id(1))
+    half = (rows // 2, sk)
+    rk = (_u32(jax.lax.broadcasted_iota(jnp.int32, half, 0))
+          * jnp.uint32(0x9E3779B1)
+          ^ _u32(jax.lax.broadcasted_iota(jnp.int32, half, 1))
+          * jnp.uint32(0x85EBCA6B))
+    return _keep3(seed_ref[0], slab, rk, dropout)
+
+
+def _softmax(s):
+    """The rows' softmax of the scores as its float32 numerator e
+    [bb, rows, sk] and the rows' factor 1/l [bb, rows, 1]."""
+    # m is clamped so that a fully masked row underflows to e == 0 and not
+    # to the uniform 2**(NEG_INF - NEG_INF); partly masked entries
     # underflow by themselves
     m = jnp.maximum(jnp.max(s, axis=2, keepdims=True), NEG_INF / 8)
-    e = jnp.exp(s - m)
-    l = jnp.sum(e, axis=2, keepdims=True)
-    p = e * (1.0 / jnp.where(l == 0.0, 1.0, l))
-    keep = None
-    if dropout > 0.0:
-        # seed_ref[1]: the global index of the call's first batch row, so
-        # that the shards of a mesh draw the masks of the whole batch
-        bi = seed_ref[1] + pl.program_id(0) * bb + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
-        head = _u32(bi * num_heads + pl.program_id(1) * hp) + _u32(sub)
-        hqk = (_u32(qi) * jnp.uint32(0x9E3779B1)
-               ^ _u32(ki) * jnp.uint32(0x85EBCA6B))
-        keep = _keep3(seed_ref[0], head, hqk, dropout)
-    return qs, p, keep
+    e = jnp.exp2(s - m)  # `_scores` gave the scores in units of log 2
+    # l is 1 (the row's maximum) to sk, or 0 on a fully masked row, whose
+    # e are all 0 and take any finite factor
+    l = jnp.maximum(jnp.sum(e, axis=2, keepdims=True), 1e-30)
+    # A float32 division is the chip's approximate reciprocal, one Newton
+    # step and a division's special cases (LLO's own lowering), a dozen
+    # operations on a column that holds eight rows a register; l has no
+    # special case. Two steps: the chip's instruction gives 12 bits or
+    # more and the interpreter's model of it (a bfloat16 reciprocal) 8,
+    # and either squared twice is past float32's 24
+    rl = pl.reciprocal(l, approx=True)
+    for _ in range(2):
+        rl = rl * (2.0 - l * rl)
+    return e, rl
 
 
-def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, has_bias, hp, dropout,
-                **static):
+def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, has_bias, num_heads,
+                hp, dropout, **static):
     bias_ref, o_ref = rest if has_bias else (None, *rest)
-    _, p, keep = _probs(seed_ref, q_ref, k_ref, bias_ref, hp=hp,
-                        dropout=dropout, **static)
-    if keep is not None:
-        p = jnp.where(keep, p * (1.0 / (1.0 - dropout)), 0.0)
-    o = _bdot_pv(p.astype(v_ref.dtype), v_ref[...])
+    _, s = _scores(q_ref, k_ref, bias_ref, hp=hp, **static)
+    e, rl = _softmax(s)
+    if dropout > 0.0:
+        e = jnp.where(_keep(seed_ref, s.shape, num_heads, hp, dropout), e, 0.0)
+    # normalised after the product, as flash_attention.py does: the rows'
+    # factor meets the [rows, 128] result and never the score tile
+    o = _bdot_pv(e.astype(v_ref.dtype), v_ref[...]) * (
+        rl * (1.0 / (1.0 - dropout)))
     o_ref[...] = _unstack_heads(o, hp).astype(o_ref.dtype)
 
 
-def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, has_bias, hp, dropout,
-                **static):
+def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, has_bias, num_heads,
+                hp, dropout, **static):
     bias_ref, do_ref, dq_ref, dk_ref, dv_ref = (
         rest if has_bias else (None, *rest))
-    qs, p, keep = _probs(seed_ref, q_ref, k_ref, bias_ref, hp=hp,
-                         dropout=dropout, **static)
+    qs, s = _scores(q_ref, k_ref, bias_ref, hp=hp, **static)
+    e, rl = _softmax(s)
+    p = e * rl
     dos = _stack_heads(do_ref[...], hp)
     dp = _bdot_qkT(dos, v_ref[...])
     p_drop = p
-    if keep is not None:
-        inv = 1.0 / (1.0 - dropout)
-        p_drop = jnp.where(keep, p * inv, 0.0)
-        dp = jnp.where(keep, dp * inv, 0.0)
-    # the stacked rows of each head carry zeros in the other head's lanes,
+    if dropout > 0.0:
+        keep = _keep(seed_ref, s.shape, num_heads, hp, dropout)
+        p_drop, dp = jnp.where(keep, p, 0.0), jnp.where(keep, dp, 0.0)
+    # 1/(1-rate) and the scale multiply the products' float32 results and
+    # not the score tile: with c = 1/(1-rate) and delta = rowsum(p * dp)
+    # over the kept pairs, dV = c * p_drop^T.dO and dS = c * scale * p *
+    # (dp - delta).
+    # The stacked rows of each head carry zeros in the other head's lanes,
     # so the products over the rows give dV and dK whole
-    dv_ref[...] = _bdot_pTv(p_drop.astype(dos.dtype), dos).astype(dv_ref.dtype)
-    delta = jnp.sum(p * dp, axis=2, keepdims=True)  # rowsum(dO * O)
-    ds = (p * (dp - delta) * static["sm_scale"]).astype(qs.dtype)
-    dq_ref[...] = _unstack_heads(_bdot_pv(ds, k_ref[...]), hp).astype(
+    c = 1.0 / (1.0 - dropout)
+    dv_ref[...] = (_bdot_pTv(p_drop.astype(dos.dtype), dos) * c).astype(
+        dv_ref.dtype)
+    delta = jnp.sum(p * dp, axis=2, keepdims=True)
+    ds = (p * (dp - delta)).astype(qs.dtype)
+    c = c * static["sm_scale"]
+    dq_ref[...] = _unstack_heads(_bdot_pv(ds, k_ref[...]) * c, hp).astype(
         dq_ref.dtype)
-    dk_ref[...] = _bdot_pTv(ds, qs).astype(dk_ref.dtype)
+    dk_ref[...] = (_bdot_pTv(ds, qs) * c).astype(dk_ref.dtype)
 
 
 def _pick_bb(b, sq, sk, hp, itemsize):

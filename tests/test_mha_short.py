@@ -67,6 +67,12 @@ SHAPES = [
     (2, 2, 1, 24, 64, True, False),     # the decode step
     (2, 1, 16, 64, 128, False, True),   # dh=128, one head a slice, sq < sk
     (3, 2, 32, 32, 128, True, False),
+    # score rows of one lane tile and of several
+    (1, 2, 128, 128, 64, True, False),   # BERT phase 1's
+    (1, 2, 512, 512, 64, True, False),   # BERT phase 2's
+    (2, 2, 48, 144, 64, True, True),     # sq != sk, causal
+    (1, 2, 100, 200, 64, True, False),   # no multiple of 16: padded
+    (2, 1, 40, 136, 128, False, True),   # dh=128, two lane tiles of keys
 ]
 
 
@@ -87,7 +93,7 @@ def _grads(fn, q, k, v):
 
 
 @pytest.mark.parametrize("b,h,sq,sk,d,use_bias,causal", [
-    SHAPES[0], SHAPES[2], SHAPES[3], SHAPES[6], SHAPES[7]])
+    SHAPES[0], SHAPES[2], SHAPES[3], SHAPES[6], SHAPES[7], *SHAPES[8:]])
 def test_grads_match_reference(b, h, sq, sk, d, use_bias, causal):
     q, k, v, bias = _mk(b, h, sq, sk, d, use_bias, causal)
     want = _grads(lambda q, k, v: _reference(q, k, v, bias, causal, h),
@@ -96,6 +102,16 @@ def test_grads_match_reference(b, h, sq, sk, d, use_bias, causal):
                                            causal=causal), q, k, v)
     for a, b_ in zip(want, got):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-5)
+
+
+@pytest.mark.parametrize("sk", [64, 512])
+def test_the_rows_reciprocal_is_a_float32_one(sk):
+    """v == ones: an output row is l * (1/l). The approximate reciprocal
+    is a bfloat16 one here (8 bits; the chip's has 12 or more), and a
+    single Newton step from it would leave 2**-16."""
+    q, k, v, bias = _mk(2, 2, 64, sk, 64, True)
+    out = mha_short(q * 4.0, k, jnp.ones_like(v), 2, bias=bias)
+    assert float(jnp.max(jnp.abs(out - 1.0))) < 2e-6
 
 
 @pytest.mark.parametrize("d,causal", [(64, True), (128, False)])
@@ -119,9 +135,12 @@ def test_bf16_forward_and_grads(d, causal):
                                    np.asarray(b_, np.float32), atol=5e-2)
 
 
-def test_dropout_deterministic_and_unbiased():
-    b, h, s, d = 4, 4, 64, 64
-    q, k, v, _ = _mk(b, h, s, s, d, False)
+# key lengths of one lane tile, of two with padding, and BERT phase 2's,
+# which the benchmark's `correct` runs without dropout
+@pytest.mark.parametrize("b,s,sk", [(4, 64, 64), (4, 48, 144), (2, 64, 512)])
+def test_dropout_deterministic_and_unbiased(b, s, sk):
+    h, d = 4, 64
+    q, k, v, _ = _mk(b, h, s, sk, d, False)
     v = jnp.ones_like(v)
     rng = jax.random.fold_in(KEY, 7)
     o1 = mha_short(q, k, v, h, dropout=0.3, rng_key=rng)
@@ -137,6 +156,56 @@ def test_dropout_deterministic_and_unbiased():
     per_head = np.asarray(o1).reshape(b, s, h, d)[..., 0]
     assert len({per_head[i, :, j].tobytes()
                 for i in range(b) for j in range(h)}) == b * h
+
+
+def _keep_mask(b, h, sq, sk, d, rate, seed):
+    """The kernel's keep-mask [b, h, sq, sk], read off its output: zero
+    queries and keys make every probability 1/sk, and values that are
+    the identity on a head's first sk lanes put pair (q, k)'s kept
+    probability on lane k of row q."""
+    assert sk <= d
+    q = jnp.zeros((b, sq, h * d), jnp.float32)
+    k = jnp.zeros((b, sk, h * d), jnp.float32)
+    v = jnp.tile(jnp.eye(sk, d, dtype=jnp.float32), (b, 1, h))
+    out = mha_short(q, k, v, h, dropout=rate,
+                    rng_key=jax.random.fold_in(KEY, seed))
+    return np.asarray(out).reshape(b, sq, h, d)[..., :sk].transpose(
+        0, 2, 1, 3) > 0.0
+
+
+# 2**20 draws each: (b, h, sq, sk, d), two heads a slice and one
+MASKS = {2: (16, 2, 512, 64, 64), 1: (16, 1, 512, 128, 128)}
+
+
+@pytest.mark.parametrize("rate", [0.1, 0.5])
+@pytest.mark.parametrize("hp", [1, 2])
+def test_dropout_rate_is_the_stated_one(hp, rate):
+    keep = _keep_mask(*MASKS[hp], rate, seed=21)
+    assert keep.size >= 2**20
+    sigma = np.sqrt(rate * (1 - rate) / keep.size)
+    assert abs(keep.mean() - (1 - rate)) < 3 * sigma
+
+
+@pytest.mark.parametrize("rate", [0.1, 0.5])
+@pytest.mark.parametrize("hp", [1, 2])
+def test_the_two_scores_of_a_hash_word_are_independent(hp, rate):
+    """Rows r and r + rows/2 of the stacked tile share a finalised word
+    (at two heads a slice, the slice's two heads at one query and key; at
+    one, queries half the padded length apart): the four joint outcomes
+    come as often as the product of the margins says."""
+    keep = _keep_mask(*MASKS[hp], rate, seed=22)
+    if hp == 2:
+        first, second = keep[:, 0], keep[:, 1]
+    else:
+        half = keep.shape[2] // 2
+        first, second = keep[:, :, :half], keep[:, :, half:]
+    n = first.size
+    for a in (False, True):
+        for b_ in (False, True):
+            want = (first == a).mean() * (second == b_).mean()
+            sigma = np.sqrt(want * (1 - want) / n)
+            assert abs(((first == a) & (second == b_)).mean() - want) \
+                < 3 * sigma
 
 
 def test_dropout_mask_does_not_depend_on_the_block():
@@ -156,9 +225,10 @@ def test_dropout_mask_does_not_depend_on_the_block():
                                atol=1e-6)
 
 
-def test_dropout_grad_uses_the_forwards_mask():
+@pytest.mark.parametrize("sk", [32, 144, 512])
+def test_dropout_grad_uses_the_forwards_mask(sk):
     b, h, s, d = 1, 2, 32, 64
-    q, k, v, _ = _mk(b, h, s, s, d, False)
+    q, k, v, _ = _mk(b, h, s, sk, d, False)
     rng = jax.random.fold_in(KEY, 9)
 
     def loss(q):

@@ -58,6 +58,7 @@ from __future__ import annotations
 
 import argparse
 import collections
+import importlib
 import importlib.util
 import os
 import re
@@ -107,15 +108,27 @@ def count(jaxpr, into):
     return into
 
 
-def load(path):
+def load(path, module="kda_chunk"):
+    """`paddle_tpu.ops.pallas.<module>`, or another copy of its file at
+    `path` (say a parent commit's) under a name beside it."""
     if path is None:
-        from paddle_tpu.ops.pallas import kda_chunk as kernel
-        return kernel
-    name = "paddle_tpu.ops.pallas._counted_kda_chunk"
+        return importlib.import_module(f"paddle_tpu.ops.pallas.{module}")
+    name = f"paddle_tpu.ops.pallas._counted_{module}"
     spec = importlib.util.spec_from_file_location(name, path)
     kernel = sys.modules[name] = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(kernel)
     return kernel
+
+
+def described_chip():
+    """A sharding on one chip of a v5e that is described, not attached:
+    what a compile without the chip lowers for."""
+    jax.config.update("jax_enable_compilation_cache", False)
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
 
 
 def registers_a_chunk(kernel):
@@ -212,12 +225,7 @@ def compile_for_v5e(kernel, chunks):
     """The two pairs in one jit, compiled for a described v5e: libtpu's
     dump aborts the process inside this compile, after the kernels' files
     are written (it looks for a report template that is not installed)."""
-    jax.config.update("jax_enable_compilation_cache", False)
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-
-    chip = SingleDeviceSharding(topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2").devices[0])
+    chip = described_chip()
     b, s, h, d = 1, 4096, 32, 128
 
     def sds(shape, dtype=jnp.float32):
@@ -242,16 +250,23 @@ def compile_for_v5e(kernel, chunks):
         kda_args, gdn_args).compile()
 
 
-def schedule(path, into, chunks):
-    """Compile in a child (`--compile`) with the dump on, then read what
-    it wrote whether or not it left in order."""
+def dump_compile(child, into):
+    """Run `child` (a command that compiles for a described v5e) with
+    libtpu's dump on, into the fresh directory `into`. libtpu aborts the
+    child inside the compile once the kernels' files are written, so its
+    exit code says nothing; `read_dump` says whether the files are there."""
     os.makedirs(into, exist_ok=True)
-    child = [sys.executable, os.path.abspath(__file__), "--compile",
-             "--chunks", str(chunks)] + ([path] if path else [])
     subprocess.run(child, capture_output=True, env=dict(
         os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
         LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={into} "
                          "--xla_jf_dump_llo_text=true"))
+
+
+def read_dump(into):
+    """Kernel name (`<name>_fwd`, `<name>_bwd`) -> what libtpu wrote of a
+    grid step: `bundles`, `chain` (the longest chain of dependent
+    instructions, cycles) and `slots`, each unit's filled slots summed
+    over the final bundles, with `capacity`, its slots a bundle."""
     read = collections.defaultdict(dict)
     for name in sorted(os.listdir(into)):
         found = re.match(r"\d+-(\w+_(?:fwd|bwd))\.\d+-\d+-(.+)\.txt$", name)
@@ -265,9 +280,26 @@ def schedule(path, into, chunks):
         elif found[2] == "schedule-analysis_final_bundles":
             read[found[1]]["bundles"] = int(re.search(
                 r"total scheduled bundles:\s+(\d+)", text)[1])
+        elif found[2] == "final_hlo-static-per-bundle-utilization":
+            head, rows = text.split("== UTILIZATION:")
+            units, capacity = head.strip().splitlines()[1:3]
+            units = [u.strip() for u in units.split(",")]
+            sums = [sum(col) for col in zip(*(
+                map(int, row.split()) for row in rows.strip().splitlines()))]
+            read[found[1]]["slots"] = dict(zip(units, sums))
+            read[found[1]]["capacity"] = dict(
+                zip(units, map(int, capacity.split())))
     if not read:
         sys.exit(f"no kernel's files under {into}: was libtpu free to load?")
-    for name, numbers in read.items():
+    return read
+
+
+def schedule(path, into, chunks):
+    """Compile in a child (`--compile`) with the dump on, then read what
+    it wrote whether or not it left in order."""
+    dump_compile([sys.executable, os.path.abspath(__file__), "--compile",
+                  "--chunks", str(chunks)] + ([path] if path else []), into)
+    for name, numbers in read_dump(into).items():
         print(f"{name}: {numbers.get('bundles')} bundles a grid step of "
               f"{chunks} chunks, longest chain {numbers.get('chain')} cycles")
 
