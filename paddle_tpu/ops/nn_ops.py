@@ -558,12 +558,39 @@ def _layer_norm(ctx, op):
     ctx.out(op, "Variance", var.reshape(lead))
 
 
-@register_op("rms_norm")
+def _rms_norm_grad_maker(op, grad_out_names, block, helpers):
+    # an explicit grad op, as layer_norm's: its lowering can then take one
+    # kernel pass (ops/pallas/layer_norm.py::rms_bwd) where the generic
+    # vjp leaves XLA a pass of its own a norm at a quarter of HBM
+    if grad_out_names.get("Y", [None])[0] is None:
+        return None  # nothing flows into Y: defer to vjp
+    return [
+        {
+            "type": "rms_norm_grad",
+            "inputs": {
+                "X": op.input("X"),
+                "Scale": op.input("Scale"),
+                "GRAD_Y": [grad_out_names["Y"][0]],
+            },
+            "outputs": {
+                "IGRAD_X": [helpers.grad_name(op.input("X")[0])],
+                "IGRAD_Scale": [helpers.grad_name(op.input("Scale")[0])],
+            },
+            "attrs": {
+                "epsilon": op.attr("epsilon", 1e-5),
+                "begin_norm_axis": op.attr("begin_norm_axis", 1),
+            },
+        }
+    ]
+
+
+@register_op("rms_norm", grad=_rms_norm_grad_maker)
 def _rms_norm(ctx, op):
     """`y = x / sqrt(mean(x^2) + epsilon) * scale` over the axes from
     `begin_norm_axis` on (Zhang and Sennrich 2019, arXiv:1910.07467): no
     mean is subtracted and there is no shift. The statistics and the
-    product with the scale are float32; Y has X's dtype."""
+    product with the scale are float32; Y has X's dtype. The forward stays
+    XLA's, which fuses it into its consumer (`_layer_norm`'s note)."""
     ctx.out(op, "Y", rms_norm(
         ctx.in_(op, "X"), ctx.in_(op, "Scale"), op.attr("epsilon", 1e-5),
         op.attr("begin_norm_axis", 1)))
@@ -713,6 +740,59 @@ def _rotary_embedding(ctx, op):
         x, float(op.attr("theta", 10000.0)), rope_scaling_attr(op, "scaling")))
 
 
+def _norm_kernel_admitted(ctx, x, n, k, viable) -> bool:
+    """Whether a norm's gradient over x as [n, k] takes its Pallas kernel:
+    the rule of fused_multihead_attention. A Pallas custom call is
+    something GSPMD cannot partition, so a mesh of several devices keeps
+    the XLA formulation, which shards by propagation, unless it shards
+    the batch alone: there the rows are batch-major, each chip's are a
+    whole problem, and the kernel runs per shard, of which `viable` (the
+    kernel's shape rule) is asked. Counts a lowering that runs per shard
+    (`pallas_on_mesh_calls`)."""
+    from .pallas import on_mesh
+    from .pallas.flash_attention import _use_pallas
+
+    shards = on_mesh.batch_shards(ctx.mesh, x.shape[0])
+    if not (shards and viable(n // shards, k) and _use_pallas()):
+        return False
+    if shards > 1:
+        profiler.bump_counter("pallas_on_mesh_calls")
+    return True
+
+
+@register_op("rms_norm_grad", differentiable=False)
+def _rms_norm_grad(ctx, op):
+    """dX in X's dtype and dScale in Scale's from X, Scale and dY, float32
+    inside; nothing was saved by the forward. One pass of `rms_bwd` where
+    `_layer_norm_grad`'s mesh rule and `rms_bwd_viable` admit the shape (a
+    decoder's block norms at the widths the kernel won at); everywhere
+    else `jax.vjp` of `rms_norm`, which is what the generic grad op
+    lowered before this one existed (the norms over one head's lanes).
+    AMP's lists read neither this op nor `rms_norm`: both are float32
+    inside whatever X arrives in."""
+    x = ctx.in_(op, "X")
+    scale = ctx.in_(op, "Scale")
+    eps = op.attr("epsilon", 1e-5)
+    begin = op.attr("begin_norm_axis", 1)
+    dy = jnp.asarray(ctx.in_(op, "GRAD_Y"), dtype=x.dtype).reshape(x.shape)
+    n = int(np.prod(x.shape[:begin] or (1,)))
+    k = int(np.prod(x.shape[begin:]))
+    from .pallas.layer_norm import rms_bwd, rms_bwd_viable
+
+    if _norm_kernel_admitted(ctx, x, n, k, rms_bwd_viable):
+        profiler.bump_counter("rms_bwd_calls")
+        dx, dscale = rms_bwd(x.reshape(n, k), dy.reshape(n, k),
+                             scale.reshape(-1), eps, mesh=ctx.mesh)
+        dx = dx.reshape(x.shape)
+        dscale = dscale.reshape(scale.shape).astype(scale.dtype)
+    else:
+        _, pullback = jax.vjp(
+            lambda x, scale: rms_norm(x, scale, eps, begin), x, scale)
+        dx, dscale = pullback(dy)
+    ctx.out(op, "IGRAD_X", dx)
+    ctx.out(op, "IGRAD_Scale", dscale)
+
+
 @register_op("layer_norm_grad", differentiable=False)
 def _layer_norm_grad(ctx, op):
     """dX, dScale, dBias from the saved per-row stats; the normalized
@@ -728,19 +808,9 @@ def _layer_norm_grad(ctx, op):
     begin = op.attr("begin_norm_axis", 1)
     n = int(np.prod(x.shape[:begin] or (1,)))
     k = int(np.prod(x.shape[begin:]))
-    from .pallas import on_mesh
-    from .pallas.flash_attention import _use_pallas
     from .pallas.layer_norm import ln_bwd, ln_bwd_viable
 
-    # same rule as fused_multihead_attention: a Pallas custom call is
-    # something GSPMD cannot partition, so a mesh of several devices
-    # keeps the XLA formulation, which shards by propagation, unless it
-    # shards the batch alone: there the rows are batch-major, each chip's
-    # are a whole problem, and the kernel runs per shard
-    shards = on_mesh.batch_shards(ctx.mesh, x.shape[0])
-    if shards and ln_bwd_viable(n // shards, k) and _use_pallas():
-        if shards > 1:
-            profiler.bump_counter("pallas_on_mesh_calls")
+    if _norm_kernel_admitted(ctx, x, n, k, ln_bwd_viable):
         rstd = jax.lax.rsqrt(var.reshape(-1).astype(jnp.float32) + eps)
         sc = (scale if scale is not None
               else jnp.ones((k,), jnp.float32)).reshape(-1)

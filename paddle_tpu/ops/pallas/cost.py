@@ -1,8 +1,8 @@
 """What a Pallas call declares of its work: the one convention.
 
-Every `pallas_call` of this package but `ln_bwd` and `embed_tgmm`
-(`layer_norm.py` and `embedding_grad.py` say what their declarations cost
-on the chip) passes a `cost_estimate` that a
+Every `pallas_call` of this package but `layer_norm.py`'s two (`ln_bwd`,
+`rms_bwd`) and `embed_tgmm` (`layer_norm.py` and `embedding_grad.py` say
+what their declarations cost on the chip) passes a `cost_estimate` that a
 pure function of its module computes from the call's static shapes,
 dtypes and masks, at trace time (a handful of integer products a
 lowering; nothing on the hot path). The count travels inside the

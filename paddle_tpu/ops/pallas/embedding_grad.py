@@ -28,9 +28,9 @@ it takes neither sort nor gather.
 `embedding_grad_viable` says where `ops/nn_ops.py::_lookup_table` takes
 this path; XLA's scatter stays the other and the tests' oracle.
 
-The call declares no cost (`cost.py` has the convention; `ln_bwd` is the
-other kernel without one, for the same reason). What it would declare is
-the `tokens x width` additions of the sums, the 0/1 product being one made
+The call declares no cost (`cost.py` has the convention; `layer_norm.py`'s
+`ln_bwd` and `rms_bwd` are the others without one, for the same reason).
+What it would declare is the `tokens x width` additions of the sums, the 0/1 product being one made
 to get round a layout, and the 0/1 matrix, the cotangent and the float32
 runs once each. With that declared `phi4_mini_flash_vp8_longdoc` read
 6.1565 documents/s and `bwd/mul_grad` 80.74 ms a step, with nothing

@@ -143,21 +143,25 @@ def test_ln_bwd_pallas_kernel_matches_fallback(monkeypatch):
     )
 
 
-def test_ln_bwd_declares_no_cost(monkeypatch):
+def test_neither_norms_backward_declares_a_cost(monkeypatch):
     """ops/pallas/cost.py's convention holds for every other call of the
     package but `embed_tgmm` (tests/test_embedding_grad.py has why).
-    Declared, this one cost `bert_base_s128` 1.0 to 2.7% on the
-    chip (PERF.md, Findings, PR 35), so its call passes no
-    `cost_estimate`: whoever declares it again has that cell to win."""
+    Declared, `ln_bwd` cost `bert_base_s128` 1.0 to 2.7% on the chip
+    (PERF.md, Findings, PR 35) and `rms_bwd` cost Ouro's cell 1.1% (PR
+    59), so neither call passes a `cost_estimate`: whoever declares one
+    again has that cell to win."""
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
     import jax.numpy as jnp
     from pallas_costs import declared
 
-    from paddle_tpu.ops.pallas.layer_norm import ln_bwd
+    from paddle_tpu.ops.pallas.layer_norm import ln_bwd, rms_bwd
 
     x, stat = jnp.zeros((2048, 768), jnp.bfloat16), jnp.zeros((2048,))
     assert declared(ln_bwd, x, x, stat, stat, jnp.ones((768,))) == {
         "ln_bwd": [None]}
+    x = jnp.zeros((2048, 2048), jnp.bfloat16)
+    assert declared(lambda x, s: rms_bwd(x, x, s, 1e-6), x,
+                    jnp.ones((2048,))) == {"rms_bwd": [None]}
 
 
 def test_ln_bwd_pallas_kernel_padded_rows(monkeypatch):

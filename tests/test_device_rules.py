@@ -92,12 +92,15 @@ def test_interpret_follows_only_the_variable(monkeypatch):
                           jnp.ones(128))
 
 
-def test_ln_bwd_kernel_on_a_mesh_runs_per_shard_of_the_batch_or_not_at_all(
-        monkeypatch):
+@pytest.mark.parametrize("norm,kernel,k", [("layer_norm", "ln_bwd", 128),
+                                           ("rms_norm", "rms_bwd", 1024)])
+def test_a_norms_kernel_on_a_mesh_runs_per_shard_of_the_batch_or_not_at_all(
+        norm, kernel, k, monkeypatch):
     """The rule attention follows: GSPMD cannot partition a Pallas custom
-    call, so on a mesh of several devices layer_norm_grad picks the kernel
-    only where each chip's rows are a whole problem of its own: the mesh
-    shards the batch alone, and a shard holds rows enough."""
+    call, so on a mesh of several devices layer_norm_grad and
+    rms_norm_grad pick the kernel only where each chip's rows are a whole
+    problem of its own: the mesh shards the batch alone, and a shard holds
+    rows enough."""
     import jax
     import numpy as np
 
@@ -106,20 +109,20 @@ def test_ln_bwd_kernel_on_a_mesh_runs_per_shard_of_the_batch_or_not_at_all(
 
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
     used = []
-    real = layer_norm.ln_bwd
+    real = getattr(layer_norm, kernel)
 
-    def spy(*a, **k):
+    def spy(*a, **kw):
         used.append(a[0].shape)
-        return real(*a, **k)
+        return real(*a, **kw)
 
-    monkeypatch.setattr(layer_norm, "ln_bwd", spy)
-    x = fluid.layers.data("x", [2048, 128], append_batch_size=False)
-    loss = fluid.layers.mean(fluid.layers.layer_norm(
-        fluid.layers.fc(x, 128), begin_norm_axis=1))
+    monkeypatch.setattr(layer_norm, kernel, spy)
+    x = fluid.layers.data("x", [2048, k], append_batch_size=False)
+    loss = fluid.layers.mean(getattr(fluid.layers, norm)(
+        fluid.layers.fc(x, k), begin_norm_axis=1))
     fluid.optimizer.SGD(0.1).minimize(loss)
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(fluid.default_startup_program())
-    feed = {"x": np.random.RandomState(0).randn(2048, 128).astype("float32")}
+    feed = {"x": np.random.RandomState(0).randn(2048, k).astype("float32")}
     main = fluid.default_main_program()
 
     def step(places=None, mesh=None):
@@ -133,9 +136,9 @@ def test_ln_bwd_kernel_on_a_mesh_runs_per_shard_of_the_batch_or_not_at_all(
         assert np.isfinite(value).all()
         return list(used)
 
-    assert step() == [(2048, 128)]
+    assert step() == [(2048, k)]
     # two shards of 1,024 rows: the kernel, called on the global arrays
-    assert step(places=2) == [(2048, 128)]
+    assert step(places=2) == [(2048, k)]
     # eight shards of 256 rows are under the kernel's 1,024: XLA's
     assert step(places=8) == []
     # tensor parallelism beside the batch axis: XLA's

@@ -109,7 +109,7 @@ SUITE = Suite(  # noqa: F405
     reading_more=_by_step, seed=57001, chip_routed=(None, None),
     step_counters=("attn_dispatch_flash", "attn_dispatch_xla",
                    "attn_qk_prep_fused", "param_grads_summed",
-                   "param_grad_partials"),
+                   "param_grad_partials", "rms_bwd_calls"),
     gauges=("loop_steps", "loop_layers", "loss_terms", "attn_kv_group",
             "flash_blocks_visited", "flash_blocks_total"))
 
@@ -234,6 +234,36 @@ def test_one_uses_gradient_is_no_rounding_of_the_four(float32_run,
     one = compiled(jax.grad(lambda p: SUITE.loss(
         p, batch, model, wrong=("fresh_weights_a_step",))), p)[name]
     assert rel(one, whole) > 0.3
+
+
+def test_every_norms_gradient_is_its_own_op_and_the_kernels_is_the_references(
+        monkeypatch):
+    """The backward holds one `rms_norm_grad` a norm and no generic grad
+    op of `rms_norm`. With the kernel admitted at the rehearsal's 64 lanes
+    (the shape rule steered here, in the test; the interpreter runs it)
+    all 36 sites take `rms_bwd`, a shared weight's four partials are
+    summed as before, and every kind of parameter is `jax.grad` of the
+    reference's within the limit the gradients' case holds the vjp to."""
+    from decoder_suite import check_gradients
+
+    from paddle_tpu.ops.pallas import layer_norm
+
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(layer_norm, "rms_bwd_viable", lambda n, k: True)
+    model, traffic = SUITE.cell(precision="float32", **SUITE.gradients_at)
+    norms = STEPS * (4 * model["num_hidden_layers"] + 1)
+    step = SUITE.gradients(model, dict(traffic, seq_len=80))
+    ops = step.main.global_block().ops
+    assert [op.type for op in ops].count("rms_norm") == norms
+    assert [op.type for op in ops].count("rms_norm_grad") == norms
+    assert not [op for op in ops if op.type == "__auto_grad__"
+                and op.attr("fwd_type") == "rms_norm"]
+    assert step.bumped("rms_bwd_calls") == norms
+    assert step.bumped("param_grad_partials") == (
+        STEPS * (11 * model["num_hidden_layers"] + 2) + (STEPS - 1) * 2)
+    worst = check_gradients(step.got, step.want, step.before, 2e-4,
+                            kinds=SUITE.kinds)
+    assert set(worst) == set(KINDS)
 
 
 # ------------------------------------------------ the vocabulary's slices
@@ -407,6 +437,9 @@ def test_gauges_and_counters_at_the_rehearsal_size(monkeypatch):
     assert bumped("attn_dispatch_xla") == 2 * STEPS * layers_held
     assert bumped("attn_dispatch_flash") == 0
     assert bumped("attn_qk_prep_fused") == 0  # no QK-norm to share a pass
+    # 64 lanes and no Pallas here: the norms' gradients are the vjp's (100
+    # sites take `rms_bwd` at the cell's width on the chip)
+    assert bumped("rms_bwd_calls") == 0
     ops = [op.type for op in main.global_block().ops]
     assert ops.count("fused_multihead_attention") == STEPS * layers_held
     assert ops.count("rotary_embedding") == 2 * STEPS * layers_held

@@ -148,6 +148,23 @@ def test_ln_bwd_per_shard_equals_one_device(n, k):
                                    atol=1e-4)
 
 
+@pytest.mark.parametrize("n,k", [(4 * 512, 1024), (4 * 300, 1152)])
+def test_rms_bwd_per_shard_equals_one_device(n, k):
+    """RMSNorm's side of the same call: dx row for row, dscale as the sum
+    of the shards' partial rows, each shard padding to its own blocks."""
+    from paddle_tpu.ops.pallas.layer_norm import rms_bwd
+
+    x, dy = (jax.random.normal(jax.random.fold_in(KEY, i), (n, k))
+             for i in range(2))
+    scale = jax.random.normal(jax.random.fold_in(KEY, 2), (k,))
+    want = rms_bwd(x, dy, scale, 1e-6)
+    mesh = _mesh()
+    got = jax.jit(lambda *a: rms_bwd(*a, 1e-6, mesh=mesh))(x, dy, scale)
+    np.testing.assert_array_equal(np.asarray(want[0]), np.asarray(got[0]))
+    np.testing.assert_allclose(np.asarray(want[1]), np.asarray(got[1]),
+                               rtol=1e-5, atol=1e-4)
+
+
 # ------------------------------------------------- what a shard declares
 
 

@@ -13,7 +13,9 @@ The table is taken by running this file as a script on the parent commit
 (`PYTHONPATH=<parent> python tests/test_program_pins.py`, as
 `tests/test_parents_jaxprs.py` is): a PR that adds a cell adds its row, and
 re-takes only the rows it means to change. These are PR 55's parent's
-(commit 006eebc)."""
+(commit 006eebc), but for the nine cells that lower `rms_norm`: PR 59 gave
+the op a gradient op of its own (`rms_norm_grad` where `__auto_grad__`
+stood, one a norm), and their rows are that PR's own tree's."""
 
 import hashlib
 import json
@@ -26,7 +28,8 @@ import pytest
 sys.path.append(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 OPS = ("fused_multihead_attention", "rotary_embedding", "short_conv1d",
-       "kda_attention", "selective_scan", "ssd_scan", "moe_experts")
+       "kda_attention", "selective_scan", "ssd_scan", "moe_experts",
+       "rms_norm_grad")
 ATTRS = ("rope_scaling", "interleaved", "q_lora_rank", "activation",
          "norm_eps", "rotary_dim", "expert_form")
 
@@ -52,47 +55,50 @@ PINS = {
         {},
         ()),
     "kimi_linear_ep32_s4096": (
-        658, "81898544a32310cc",
+        658, "f22970285e8a493a",
         {"fused_multihead_attention": 1, "short_conv1d": 12,
-         "kda_attention": 4, "moe_experts": 4},
+         "kda_attention": 4, "moe_experts": 4, "rms_norm_grad": 16},
         ()),
     "trinity_mini_ep16_s8192": (
-        571, "5120aa80f96a99d2",
-        {"fused_multihead_attention": 5, "moe_experts": 4},
+        571, "86fb5cbdd7c57727",
+        {"fused_multihead_attention": 5, "moe_experts": 4,
+         "rms_norm_grad": 21},
         ()),
     "mellum2_ep4_s8192": (
-        291, "cae869195b0d37b3",
-        {"fused_multihead_attention": 4, "moe_experts": 4},
+        291, "9679eedb8fe261f8",
+        {"fused_multihead_attention": 4, "moe_experts": 4,
+         "rms_norm_grad": 9},
         ("rope_scaling",)),
     "joyai_flash_ep32_s4096": (
-        827, "dc3429562f3d77b4",
+        827, "ce76ffcc8dc6405a",
         {"fused_multihead_attention": 6, "rotary_embedding": 12,
-         "moe_experts": 5},
+         "moe_experts": 5, "rms_norm_grad": 28},
         ("interleaved", "q_lora_rank",)),
     "phi4_mini_flash_vp8_longdoc": (
-        733, "6f79e2c9d1ead6ba",
+        733, "ad6c61b955fb9d55",
         {"fused_multihead_attention": 6, "short_conv1d": 2,
-         "selective_scan": 2},
+         "selective_scan": 2, "rms_norm_grad": 3},
         ()),
     "lfm2_24b_ep8_longdoc": (
-        313, "12a00fa92a58bfa9",
+        313, "4a78acef1d49edd3",
         {"fused_multihead_attention": 1, "short_conv1d": 4,
-         "moe_experts": 4},
+         "moe_experts": 4, "rms_norm_grad": 11},
         ("activation", "norm_eps",)),
     "qwen3_next_ep16_s4096": (
-        496, "2c66b16e39c5c6e8",
+        496, "1e149720f8325246",
         {"fused_multihead_attention": 1, "short_conv1d": 3,
-         "kda_attention": 3, "moe_experts": 4},
+         "kda_attention": 3, "moe_experts": 4, "rms_norm_grad": 12},
         ("rotary_dim",)),
     "nemotron3_super_ep64_s4096": (
-        296, "9a4b01d2e010ca0d",
+        296, "1027849616ac328c",
         {"fused_multihead_attention": 1, "short_conv1d": 2,
-         "ssd_scan": 2, "moe_experts": 2},
+         "ssd_scan": 2, "moe_experts": 2, "rms_norm_grad": 10},
         ("norm_eps", "expert_form",)),
     # PR 57's own tree: the cell it adds
     "ouro_2p6b_vp8_s4096": (
-        760, "14bed2b3f6a5c0f1",
-        {"fused_multihead_attention": 8, "rotary_embedding": 16}, ()),
+        760, "8797738d31c12cba",
+        {"fused_multihead_attention": 8, "rotary_embedding": 16,
+         "rms_norm_grad": 36}, ()),
 }
 
 
