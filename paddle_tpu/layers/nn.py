@@ -131,6 +131,9 @@ __all__ = [
     "soft_relu",
     "maxout",
     "fused_multihead_attention",
+    "sparse_index",
+    "sparse_select",
+    "index_kl",
     "rotary_embedding",
     "topk",
     "accuracy",
@@ -1412,6 +1415,9 @@ def fused_multihead_attention(
     rope_scaling=None,
     q_lora_rank=0,
     rotary_dim=0,
+    admit=None,
+    admit_keys=0,
+    return_lse=False,
 ):
     """Flash attention over q/k/v (Pallas kernel on TPU). layout="bhsd"
     (default): [b, nh, s, dh]; layout="bshd": [b, s, nh, dh] — the shape
@@ -1448,6 +1454,14 @@ def fused_multihead_attention(
     that rank (latent attention, `decoder_parts.latent_attention`). The
     op computes nothing differently; its lowering counts the call
     (`attn_latent_q_lora`).
+
+    `admit` is an admission that is data, [b, sq, sk] int8 as
+    `sparse_select` gives it: a pair whose entry is 0 is refused for every
+    head, beside what `causal` and `window` refuse; it carries no
+    gradient. `admit_keys` says how many keys a query admits at most (the
+    selection's K), which the kernels' declared work counts by. With
+    `return_lse` the layer returns (out, lse): each row's log-sum-exp over
+    its admitted scaled scores, [b, heads, sq] float32, with no gradient.
     """
     if layout not in ("bhsd", "bshd"):
         raise ValueError(f"layout must be 'bhsd' or 'bshd', got {layout!r}")
@@ -1465,11 +1479,22 @@ def fused_multihead_attention(
             inputs[slot] = [helper.create_parameter(
                 attr, [int(q.shape[-1])], dtype="float32",
                 default_initializer=Constant(1.0))]
-    return _single_out(
-        helper,
-        "fused_multihead_attention",
-        inputs,
-        {
+    if admit is not None:
+        inputs["Admit"] = [admit]
+    out = helper.create_variable_for_type_inference(
+        q.dtype, list(q.shape[:-1]) + [v.shape[-1]])
+    outputs = {"Out": [out]}
+    if return_lse:
+        s_ax, h_ax = (1, 2) if layout == "bshd" else (2, 1)
+        lse = helper.create_variable_for_type_inference(
+            "float32", [q.shape[0], q.shape[h_ax], q.shape[s_ax]],
+            stop_gradient=True)
+        outputs["Lse"] = [lse]
+    helper.append_op(
+        type="fused_multihead_attention",
+        inputs=inputs,
+        outputs=outputs,
+        attrs={
             "causal": causal,
             "attn_dropout": float(attn_dropout),
             "sm_scale": float(sm_scale or 0.0),
@@ -1484,10 +1509,63 @@ def fused_multihead_attention(
             **({"q_lora_rank": int(q_lora_rank)} if q_lora_rank else {}),
             **({"rotary_dim": int(rotary_dim)}
                if rotary_dim and rotary_dim != q.shape[-1] else {}),
+            **({"admit_keys": int(admit_keys)} if admit is not None else {}),
         },
-        dtype=q.dtype,
-        shape=list(q.shape[:-1]) + [v.shape[-1]],
     )
+    return (out, lse) if return_lse else out
+
+
+def sparse_index(q, k, w, scale, name=None):
+    """The score by which a learned indexer ranks the keys of a query
+    (DeepSeek-V3.2-Exp's lightning indexer): `I[t, s] = scale * sum_j
+    w[t, j] relu(q[t, j] . k[s])` for `s <= t`, and -inf above the
+    diagonal; q [b, s, heads, d], k [b, s, 1, d] (one key head for all),
+    w [b, s, heads]. Returns [b, s, s] float32."""
+    helper = LayerHelper("sparse_index", name=name)
+    b, s = q.shape[0], q.shape[1]
+    return _single_out(helper, "sparse_index",
+                       {"Q": [q], "K": [k], "W": [w]},
+                       {"scale": float(scale)}, dtype="float32",
+                       shape=[b, s, s])
+
+
+def sparse_select(index, k, name=None):
+    """The keys a query keeps of `index` [b, s, s] (`sparse_index`'s): the
+    causal ones whose score is at least the row's `k`-th largest, every
+    causal one where a row has no more than `k` (ties at the threshold
+    are all kept). Returns (admit [b, s, s] int8, 1 where kept; tau
+    [b, s] float32, the threshold, -inf where every causal key is kept).
+    Exact, and no gradient passes."""
+    helper = LayerHelper("sparse_select", name=name)
+    b, s = index.shape[0], index.shape[1]
+    admit = helper.create_variable_for_type_inference(
+        "int8", [b, s, s], stop_gradient=True)
+    tau = helper.create_variable_for_type_inference(
+        "float32", [b, s], stop_gradient=True)
+    helper.append_op(type="sparse_select", inputs={"X": [index]},
+                     outputs={"Admit": [admit], "Tau": [tau]},
+                     attrs={"k": int(k)})
+    return admit, tau
+
+
+def index_kl(q, k, lse, index, admit, sm_scale, admit_keys=0, name=None):
+    """The loss that trains an indexer towards the attention it selects
+    for, a number a query: `KL(p[t] || softmax over the admitted keys of
+    index[t])` with the target `p[t, s]` the mean over the heads of the
+    attention's probabilities `exp(sm_scale q[t, head] . k[s, group] -
+    lse[head, t])` on the admitted pairs. q [b, s, heads, d] and k
+    [b, s, groups, d] as the attention took them (normed and turned), lse
+    as `fused_multihead_attention(return_lse=True)` gives it, `admit` as
+    `sparse_select`'s. The target is rebuilt here, summed head by head
+    and never held for all the heads, and is a constant: the gradient
+    reaches `index` alone. Returns [b, s] float32."""
+    helper = LayerHelper("index_kl", name=name)
+    return _single_out(
+        helper, "index_kl",
+        {"Q": [q], "K": [k], "Lse": [lse], "Index": [index],
+         "Admit": [admit]},
+        {"sm_scale": float(sm_scale), "admit_keys": int(admit_keys)},
+        dtype="float32", shape=[q.shape[0], q.shape[1]])
 
 
 def topk(input, k, name=None):

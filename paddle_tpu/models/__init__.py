@@ -4,7 +4,9 @@ LeNet-5 (MNIST), ResNet (ImageNet), SE-ResNeXt, VGG, Transformer/BERT
 (WMT16 / pretrain), DeepFM (CTR), Kimi Linear, Trinity, Mellum, JoyAI
 Flash, LFM2, Qwen3-Next and Nemotron-H (each a share of an
 expert-parallel decoder; Nemotron-H's mixers hold a share of their heads
-too), Phi-4-mini-flash (a pipeline stage's share of a
+too), Keye-VL-2.0's language model (a share of an expert-parallel
+decoder whose attention keeps the keys a learned indexer chooses),
+Phi-4-mini-flash (a pipeline stage's share of a
 decoder-hybrid-decoder), and Ouro (a pipeline stage's share of a decoder
 that runs its layers several times with one set of weights)."""
 
@@ -12,6 +14,7 @@ from . import (  # noqa: F401
     bert,
     deepfm,
     joyai_flash,
+    keye_vl2,
     kimi_linear,
     lenet,
     lfm2,
@@ -27,6 +30,7 @@ from . import (  # noqa: F401
     vgg,
 )
 from .joyai_flash import JoyAIFlashConfig, build_joyai_flash  # noqa: E402,F401
+from .keye_vl2 import KeyeVL2Config, build_keye_vl2  # noqa: E402,F401
 from .kimi_linear import KimiLinearConfig, build_kimi_linear  # noqa: E402,F401
 from .lfm2 import Lfm2Config, build_lfm2  # noqa: E402,F401
 from .mellum import MellumConfig, build_mellum  # noqa: E402,F401

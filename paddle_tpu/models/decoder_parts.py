@@ -1,13 +1,15 @@
 """What the decoders of the zoo (`kimi_linear`, `trinity`, `mellum`,
-`joyai_flash`, `phi4_flash`, `lfm2`, `qwen3_next`, `nemotron_h`, `ouro`)
-build their layers from: projections seeded Normal(0,
+`joyai_flash`, `phi4_flash`, `lfm2`, `qwen3_next`, `nemotron_h`, `ouro`,
+`keye_vl2`) build their layers from: projections seeded Normal(0,
 `initializer_range`), with a bias where asked, RMSNorm with a learned
 weight and LayerNorm with weight and bias, the SiLU-gated feed-forward as
 three products or with gate and up in one, the squared-ReLU feed-forward without a gate
 (`nemotron_h`), attention over grouped key/value heads with or without
 QK-norm and with rotary positions on a head, on its first lanes or not at
 all, latent attention (`kimi_linear`, `joyai_flash`), differential
-attention (`phi4_flash`), the double-gated short convolution (`lfm2`),
+attention (`phi4_flash`), attention that keeps for each query the keys a
+learned indexer scores highest (`keye_vl2`), the double-gated short
+convolution (`lfm2`),
 Gated DeltaNet (`qwen3_next`: the delta rule with a decay a head and key
 heads shared by groups of value heads), the Mamba-2 mixer (`nemotron_h`:
 a decay a head and a token, a norm by groups behind the gate), and the
@@ -28,6 +30,7 @@ import math
 
 from .. import layers, profiler
 from ..initializer import Normal, Uniform
+from ..ops.pallas import cost
 from ..param_attr import ParamAttr
 
 
@@ -280,6 +283,69 @@ def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
     if gated:
         a = layers.elementwise_mul(a, gate)
     return proj(a, cfg.hidden_size, name + ".o", cfg, std=out_std)
+
+
+def sparse_attention(u, cfg, name, rope_theta):
+    """Causal attention whose keys a learned indexer chooses
+    (DeepSeek-V3.2-Exp's sparse attention), u [b, s, hidden] to
+    ([b, s, hidden], [b, s] float32, [b, s, s] int8): `attention`'s heads
+    with QK-norm and rotary positions, each query reading only the
+    `cfg.topk` keys at or before it that the indexer scores highest (every
+    one where it has no more); beside the output the loss that trains the
+    indexer, a number a query, and the selection itself.
+
+    The indexer reads a detached copy of u: `cfg.indexer_num_heads` query
+    heads of `cfg.indexer_head_dim` (`.indexer.q`) against one key head
+    (`.indexer.k` through a LayerNorm, `.indexer.k_norm`), both turned by
+    rotary positions over all their lanes, and a weight a head
+    (`.indexer.w`); `sparse_index` scores every causal pair,
+    `sparse_select` keeps the K largest a row, and the flash kernels take
+    the selection as an admission for all the heads. `index_kl` holds the
+    indexer's softmax over the kept keys against the attention's own
+    probabilities averaged over the heads, held constant. So the
+    indexer's parameters get `index_kl`'s gradient alone and everything
+    else none of it: the selection passes no gradient.
+
+    q and k are normed and turned by ops of their own here, not inside the
+    attention op: `index_kl` reads them as the attention took them.
+    Counters, once a layer built: `sparse_attn_layers`,
+    `attn_pairs_admitted` (b * sum_t min(t + 1, K)) and
+    `attn_pairs_causal` (b * s (s + 1) / 2)."""
+    b, s, _ = u.shape
+    h, g, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    hi, di, topk = cfg.indexer_num_heads, cfg.indexer_head_dim, cfg.topk
+
+    def heads(t, n, width, norm_name=None):
+        t = layers.reshape(t, [b, s, n, width])
+        if norm_name:
+            t = norm(t, norm_name, cfg, axis=3)
+        return layers.rotary_embedding(t, theta=rope_theta)
+
+    q = heads(proj(u, h * d, name + ".q", cfg), h, d, name + ".q_norm")
+    k = heads(proj(u, g * d, name + ".k", cfg), g, d, name + ".k_norm")
+    v = layers.reshape(proj(u, g * d, name + ".v", cfg), [b, s, g, d])
+
+    detached = layers.assign(u)
+    detached.stop_gradient = True
+    qi = heads(proj(detached, hi * di, name + ".indexer.q", cfg), hi, di)
+    ki = heads(layer_norm(proj(detached, di, name + ".indexer.k", cfg),
+                          name + ".indexer.k_norm", cfg), 1, di)
+    w = proj(detached, hi, name + ".indexer.w", cfg)
+    index = layers.sparse_index(qi, ki, w, scale=(hi * di) ** -0.5)
+    admit, _ = layers.sparse_select(index, topk)
+
+    sm_scale = 1.0 / math.sqrt(d)
+    a, lse = layers.fused_multihead_attention(
+        q, k, v, causal=True, sm_scale=sm_scale, layout="bshd", admit=admit,
+        admit_keys=topk, return_lse=True)
+    kl = layers.index_kl(q, k, lse, index, admit, sm_scale, admit_keys=topk)
+    profiler.bump_counter("sparse_attn_layers")
+    profiler.bump_counter("attn_pairs_admitted", b * cost.admitted_pairs(
+        s, s, causal=True, window=topk))
+    profiler.bump_counter("attn_pairs_causal",
+                          b * cost.admitted_pairs(s, s, causal=True))
+    return proj(layers.reshape(a, [b, s, h * d]), cfg.hidden_size,
+                name + ".o", cfg), kl, admit
 
 
 def latent_attention(u, cfg, name):

@@ -24,6 +24,7 @@ from . import (  # noqa: F401
     rnn_ops,
     scan_ops,
     sequence_ops,
+    sparse_attn_ops,
     ssm_ops,
     tensor_ops,
     vision_ops,

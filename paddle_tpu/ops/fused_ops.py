@@ -94,7 +94,8 @@ def attention_path(q, k, v, *, layout, causal, window, group, mesh) -> str:
     return "xla"
 
 
-@register_op("fused_multihead_attention", no_grad_inputs=("KeyBias",))
+@register_op("fused_multihead_attention",
+             no_grad_inputs=("KeyBias", "Admit"))
 def _fused_mha(ctx, op):
     """Q/K/V: [b, nh, s, dh] (layout attr "bhsd", default) or
     [b, s, nh, dh] ("bshd" — the shape the model's QKV reshape produces,
@@ -123,6 +124,15 @@ def _fused_mha(ctx, op):
     kernel wants are one kernel pair (ops/pallas/qk_prep.py); on every
     other path the two ops' own functions run first, in `jnp`.
 
+    Optional Admit: [b, sq, sk] int8, an admission that is data (a
+    selection's: `sparse_select`): a pair whose entry is 0 is refused for
+    every head, beside what `causal` and `window` refuse; no gradient.
+    Attr `admit_keys`: the keys a query admits at most, which the flash
+    kernels' declarations count by. Optional output Lse: each row's
+    log-sum-exp over its admitted scaled scores, [b, heads, sq] float32
+    in either layout, with no gradient (what `index_kl` rebuilds the
+    probabilities from). Both on the "flash" and "xla" paths alone.
+
     Attr `q_lora_rank` (optional, > 0) labels a latent-attention call
     whose query came through a compressed latent; it changes nothing
     computed and counts `attn_latent_q_lora` once a lowering.
@@ -131,6 +141,8 @@ def _fused_mha(ctx, op):
     k = ctx.in_(op, "K")
     v = ctx.in_(op, "V")
     bias = ctx.in_(op, "KeyBias")
+    admit = ctx.in_(op, "Admit")
+    with_lse = bool(op.output("Lse"))
     q_norm, k_norm = ctx.in_(op, "QNorm"), ctx.in_(op, "KNorm")
     norm_eps = float(op.attr("qk_norm_epsilon", 1e-5))
     rope_theta = float(op.attr("rope_theta", 0.0) or 0.0)
@@ -197,6 +209,10 @@ def _fused_mha(ctx, op):
         raise ValueError(
             "fused_multihead_attention: ring sequence parallelism takes "
             "neither a window nor grouped key/value heads")
+    if path in ("ring", "short") and (admit is not None or with_lse):
+        raise ValueError(
+            f"fused_multihead_attention: the {path!r} path takes no "
+            "admission and gives no log-sum-exp rows")
     profiler.bump_counter(f"attn_dispatch_{path}")
     if path == "flash" and window:
         profiler.bump_counter("attn_dispatch_flash_window")
@@ -234,7 +250,8 @@ def _fused_mha(ctx, op):
         # parameter shardings, head (`model`) parallelism included
         scale = sm_scale or 1.0 / float(np.sqrt(q.shape[-1]))
         out = _xla_attention(q, k, v, bias, causal, scale, dropout, rng,
-                             layout=layout, window=window)
+                             layout=layout, window=window, admit=admit,
+                             with_lse=with_lse)
     elif path == "flash":
         if fused:
             # from the arrays as they came: the kernel pair norms and
@@ -248,9 +265,12 @@ def _fused_mha(ctx, op):
             operands = swap(q), swap(k), swap(v)
         # values narrower or wider than the keys: the kernel takes them at
         # their own width in whole lanes, and so writes the output
-        out = swap(flash_attention(
+        out = flash_attention(
             *operands, bias=bias, causal=causal, sm_scale=sm_scale,
-            dropout=dropout, rng_key=rng, window=window))
+            dropout=dropout, rng_key=rng, window=window, admit=admit,
+            admit_keys=int(op.attr("admit_keys", 0) or 0),
+            with_lse=with_lse)
+        out = (swap(out[0]), out[1]) if with_lse else swap(out)
     else:  # "ring"
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
@@ -275,4 +295,7 @@ def _fused_mha(ctx, op):
             axis_size=mesh.shape["model"], bias=bias, causal=causal,
             sm_scale=sm_scale, dropout=dropout, rng_key=rng,
         ).astype(q.dtype)))
+    if with_lse:
+        out, lse = out
+        ctx.out(op, "Lse", lse)
     ctx.out(op, "Out", out)

@@ -246,8 +246,13 @@ def _block_of(band, i, t, m):
     return jnp.minimum(first + t, last)
 
 
-def _admitted(s, j, kb, m):
-    """The scores of block (j, kb) with what the masks refuse at NEG_INF."""
+def _admitted(s, j, kb, m, admit_ref=None):
+    """The scores of block (j, kb) with what the masks refuse at NEG_INF.
+    `admit_ref`: the block of a call's admission operand ([1, block_q,
+    block_k] int8, one for all the heads of a batch row), whose zeros
+    are refused too."""
+    if admit_ref is not None:
+        s = jnp.where(admit_ref[0].astype(jnp.int32) != 0, s, NEG_INF)
     if not m.causal:
         return s
     qi = j * m.block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -282,7 +287,7 @@ _PRODUCTS = {"flash_fwd": (1, 1), "flash_bwd_dq": (2, 1),
              "flash_bwd_dkv": (2, 2), "flash_bwd_dkv_dq": (3, 2)}
 
 
-def _cost(kernel, q, k, bias, masks, dims):
+def _cost(kernel, q, k, bias, masks, dims, admit=None, admit_keys=0):
     """What the call named `kernel` declares (`cost.py` has the
     convention) from the flattened, padded operands `q` [b*h, ., .] and
     `k` [b*hkv, ., .] and `dims`, the lengths and head widths before any
@@ -290,12 +295,18 @@ def _cost(kernel, q, k, bias, masks, dims):
     pairs `causal` and `window` admit, so a window counts fewer than its
     causal twin by the arithmetic and not by block rounding; masked pairs
     inside a visited block and the padded lanes do not count. One
-    exponential a pair; `flash_fwd` a reciprocal and a logarithm a row."""
+    exponential a pair; `flash_fwd` a reciprocal and a logarithm a row.
+    With an admission operand `admit` ([b, sq, sk] int8) the caller says
+    how many keys a query admits at most (`admit_keys`, what a selection
+    of the K largest leaves: min(visible, K) a query, which is a window's
+    count), and the operand's bytes count once."""
     bh, bhkv = q.shape[0], k.shape[0]
     sq, sk, d, dv = dims or (q.shape[1], k.shape[1], q.shape[2], q.shape[2])
+    # the fewest keys either bound leaves a query (0: that bound is absent)
+    bounds = [n for n in (masks.window, admit_keys) if n]
     pairs = bh * cost.admitted_pairs(
         sq, sk, causal=masks.causal, causal_offset=masks.causal_offset,
-        window=masks.window)
+        window=min(bounds, default=0))
     over_d, over_dv = _PRODUCTS[kernel]
     queries, outputs = ((bh, sq, d), q.dtype), ((bh, sq, dv), q.dtype)
     keys, values = ((bhkv, sk, d), k.dtype), ((bhkv, sk, dv), k.dtype)
@@ -307,6 +318,8 @@ def _cost(kernel, q, k, bias, masks, dims):
                                   values]}[kernel]
     if bias is not None:
         moved.append(((bias.shape[0], sk), bias.dtype))
+    if admit is not None:
+        moved.append(((admit.shape[0], sq, sk), admit.dtype))
     return cost.estimate(
         2 * pairs * (over_d * d + over_dv * dv),
         pairs + (2 * bh * sq if kernel == "flash_fwd" else 0),
@@ -323,18 +336,16 @@ def _fwd_kernel(
     q_ref,
     k_ref,
     v_ref,
-    bias_ref,
-    o_ref,
-    lse_ref,
-    m_scr,
-    l_scr,
-    acc_scr,
-    *,
+    *refs,
     sm_scale,
     dropout,
     masks,
     steps,
+    operands=(True, False),
 ):
+    # `operands`: whether the call passes a key bias, an admission
+    (bias_ref, admit_ref), (o_ref, lse_ref, m_scr, l_scr, acc_scr) = (
+        _optional(refs, operands))
     n = pl.program_id(0)  # read here: the interpreter has none in a branch
     j = pl.program_id(1)
     t = pl.program_id(2)
@@ -359,7 +370,7 @@ def _fwd_kernel(
         s = s * sm_scale
         if bias_ref is not None:
             s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
-        s = _admitted(s, j, kb, masks)
+        s = _admitted(s, j, kb, masks, admit_ref)
 
         m_prev = m_scr[:, :1]
         l_prev = l_scr[:, :1]
@@ -398,10 +409,20 @@ def _fwd_kernel(
         lse_ref[0, 0] = (m_scr[:, 0] + jnp.log(l_safe[:, 0])).astype(jnp.float32)
 
 
-def _fwd_pallas(q, k, v, bias, seed, h, *, sm_scale, causal, causal_offset,
-                dropout, block_q, block_k, window=0, dims=None):
+def _optional(refs, present):
+    """A kernel's references after its fixed ones: the optional operands
+    in order (None where `present` says the call passes none), then the
+    rest."""
+    refs = list(refs)
+    return [refs.pop(0) if there else None for there in present], refs
+
+
+def _fwd_pallas(q, k, v, bias, seed, h, admit=None, *, sm_scale, causal,
+                causal_offset, dropout, block_q, block_k, window=0,
+                dims=None, admit_keys=0):
     """q: [b*h, sq, d], k: [b*hkv, sk, d] and v: [b*hkv, sk, dv], whole
-    blocks and whole lanes; `dims`: what `_cost` reads. Returns the
+    blocks and whole lanes; `admit`: [b, sq, sk] int8 or None; `dims` and
+    `admit_keys`: what `_cost` reads. Returns the
     output, [b*h, sq, dv] in q's dtype, and the log-sum-exp rows,
     [b*h, 1, sq] float32."""
     bh, sq, d = q.shape
@@ -428,13 +449,21 @@ def _fwd_pallas(q, k, v, bias, seed, h, *, sm_scale, causal, causal_offset,
             )
         ]
         bias_args = [bias]
+    if admit is not None:
+        # one block of pairs for all the heads of a batch row
+        bias_spec.append(pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda n, j, t: (n // h, j, _block_of(_key_band, j, t, masks)),
+            memory_space=pltpu.VMEM))
+        bias_args.append(admit)
 
     kernel = functools.partial(
-        _fwd_kernel if bias is not None else _fwd_kernel_nobias,
+        _fwd_kernel,
         sm_scale=sm_scale,
         dropout=dropout,
         masks=masks,
         steps=steps,
+        operands=(bias is not None, admit is not None),
     )
 
     out, lse = pl.pallas_call(
@@ -462,13 +491,10 @@ def _fwd_pallas(q, k, v, bias, seed, h, *, sm_scale, causal, causal_offset,
         ],
         interpret=_interpret(),
         name="flash_fwd",
-        cost_estimate=_cost("flash_fwd", q, k, bias, masks, dims),
+        cost_estimate=_cost("flash_fwd", q, k, bias, masks, dims, admit,
+                            admit_keys),
     )(seed, q, k, v, *bias_args)
     return out, lse
-
-
-def _fwd_kernel_nobias(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *scr, **kw):
-    _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, None, o_ref, lse_ref, *scr, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -484,8 +510,8 @@ def _dot_f32(a, b, contract):
 
 
 def _block_backward(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    delta_ref, bias_ref, head, j, kb, *, sm_scale, dropout,
-                    masks):
+                    delta_ref, bias_ref, admit_ref, head, j, kb, *, sm_scale,
+                    dropout, masks):
     """What every backward kernel forms of block (j, kb) of query head
     `head` (its index over the batch, which the dropout mask hashes):
     q, k, dO, `p` as P^T.dO takes it (with the dropout the forward
@@ -500,7 +526,7 @@ def _block_backward(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     s = _dot_f32(q, k, (1, 1)) * sm_scale
     if bias_ref is not None:
         s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
-    s = _admitted(s, j, kb, masks)
+    s = _admitted(s, j, kb, masks, admit_ref)
     p = jnp.exp(s - lse)  # normalized probs (fp32)
 
     dp = _dot_f32(do, v, (1, 1))
@@ -517,7 +543,8 @@ def _block_backward(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 
 def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   bias_ref, dq_ref, dq_scr, *, steps, masks, **kw):
+                   *refs, steps, masks, operands, **kw):
+    (bias_ref, admit_ref), (dq_ref, dq_scr) = _optional(refs, operands)
     n = pl.program_id(0)
     j = pl.program_id(1)
     t = pl.program_id(2)
@@ -531,7 +558,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _visit():
         _, k, _, _, ds = _block_backward(
             seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            bias_ref, n, j, kb, masks=masks, **kw)
+            bias_ref, admit_ref, n, j, kb, masks=masks, **kw)
         dq_scr[:] = dq_scr[:] + _dot_f32(ds.astype(k.dtype), k, (1, 0))
 
     if masks.causal:
@@ -545,8 +572,9 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    bias_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, steps, group,
-                    masks, **kw):
+                    *refs, steps, group, masks, operands, **kw):
+    (bias_ref, admit_ref), (dk_ref, dv_ref, dk_scr, dv_scr) = _optional(
+        refs, operands)
     # one key/value head and one key block a (i, kb); the innermost axis
     # runs over the group's query heads and, for each, the run of query
     # blocks that can see this key block
@@ -564,7 +592,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _visit():
         q, _, do, p, ds = _block_backward(
             seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            bias_ref, n, j, kb, masks=masks, **kw)
+            bias_ref, admit_ref, n, j, kb, masks=masks, **kw)
         dv_scr[:] = dv_scr[:] + _dot_f32(p.astype(do.dtype), do, (0, 0))
         dk_scr[:] = dk_scr[:] + _dot_f32(ds.astype(q.dtype), q, (0, 0))
 
@@ -580,8 +608,9 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_fused_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                      delta_ref, bias_ref, dq_ref, dk_ref, dv_ref, dq_scr,
-                      dk_scr, dv_scr, *, steps, group, masks, **kw):
+                      delta_ref, *refs, steps, group, masks, operands, **kw):
+    (bias_ref, admit_ref), (dq_ref, dk_ref, dv_ref, dq_scr, dk_scr,
+                            dv_scr) = _optional(refs, operands)
     # one key/value head a row of the grid; axis 1 runs over the group's
     # query heads and, for each, its query blocks; the innermost axis over
     # the run of key blocks that query block can see. dk and dv of the
@@ -606,7 +635,7 @@ def _bwd_fused_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _visit():
         q, k, do, p, ds = _block_backward(
             seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            bias_ref, n, j, kb, masks=masks, **kw)
+            bias_ref, admit_ref, n, j, kb, masks=masks, **kw)
         rows = pl.ds(pl.multiple_of(kb * masks.block_k, masks.block_k),
                      masks.block_k)
         ds = ds.astype(q.dtype)
@@ -630,18 +659,9 @@ def _bwd_fused_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _without_bias(kernel):
-    """`kernel` for a call that passes no bias operand."""
-    def nobias(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               *refs, **kw):
-        kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               None, *refs, **kw)
-    return nobias
-
-
-def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
-                causal_offset, dropout, block_q, block_k, delta=None,
-                window=0, dims=None):
+def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, admit=None, *,
+                sm_scale, causal, causal_offset, dropout, block_q, block_k,
+                delta=None, window=0, dims=None, admit_keys=0):
     bh, sq, d = q.shape
     bhkv, sk, dv = k.shape[0], k.shape[1], v.shape[2]
     group = bh // bhkv
@@ -653,7 +673,8 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
     if delta is None:
         delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)[:, None, :]
 
-    common = dict(sm_scale=sm_scale, dropout=dropout, masks=masks)
+    common = dict(sm_scale=sm_scale, dropout=dropout, masks=masks,
+                  operands=(bias is not None, admit is not None))
 
     # ---- dq: the forward's grid --------------------------------------
     steps = masks.key_steps()
@@ -672,13 +693,21 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
         bias_specs_k = [pl.BlockSpec(
             (1, 1, block_k), lambda i, kb, r: (i // hkv, 0, kb),
             memory_space=pltpu.VMEM)]
+    if admit is not None:
+        bias_in.append(admit)
+        bias_specs_q.append(pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda n, j, t: (n // h, j, _block_of(_key_band, j, t, masks)),
+            memory_space=pltpu.VMEM))
+        query_steps = masks.query_steps()
+        bias_specs_k.append(pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda i, kb, r: (i // hkv, _block_of(
+                _query_band, kb, r % query_steps, masks), kb),
+            memory_space=pltpu.VMEM))
 
     dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel if bias is not None else _without_bias(
-                _bwd_dq_kernel),
-            steps=steps, **common
-        ),
+        functools.partial(_bwd_dq_kernel, steps=steps, **common),
         grid=(bh, masks.nq, steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -695,7 +724,8 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
         name="flash_bwd_dq",
-        cost_estimate=_cost("flash_bwd_dq", q, k, bias, masks, dims),
+        cost_estimate=_cost("flash_bwd_dq", q, k, bias, masks, dims, admit,
+                            admit_keys),
     )(seed, q, k, v, do, lse, delta, *bias_in)
 
     # ---- dk, dv: a key/value head a row of the grid -------------------
@@ -708,11 +738,8 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
     kk = lambda i, kb, r: kv_at(i, kb)
     krow = lambda i, kb, r: (i * group + r // steps, 0, query_block(kb, r))
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel if bias is not None else _without_bias(
-                _bwd_dkv_kernel),
-            steps=steps, group=group, **common
-        ),
+        functools.partial(_bwd_dkv_kernel, steps=steps, group=group,
+                          **common),
         grid=(bhkv, masks.nk, group * steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -738,14 +765,15 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale, causal,
         ],
         interpret=_interpret(),
         name="flash_bwd_dkv",
-        cost_estimate=_cost("flash_bwd_dkv", q, k, bias, masks, dims),
+        cost_estimate=_cost("flash_bwd_dkv", q, k, bias, masks, dims, admit,
+                            admit_keys),
     )(seed, q, k, v, do, lse, delta, *bias_in)
     return dq, dk, dv
 
 
-def _bwd_fused_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale,
-                      causal, causal_offset, dropout, block_q, block_k,
-                      window=0, dims=None):
+def _bwd_fused_pallas(q, k, v, bias, seed, out, lse, do, h, admit=None, *,
+                      sm_scale, causal, causal_offset, dropout, block_q,
+                      block_k, window=0, dims=None, admit_keys=0):
     """`_bwd_pallas`' dq, dk, dv from one call that visits a block of
     scores once: `flash_bwd_dq`'s walk, a query block and its run of key
     blocks, under a key/value head's row of the grid, with that head's dk
@@ -775,13 +803,18 @@ def _bwd_fused_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale,
         bias_spec = [pl.BlockSpec(
             (1, 1, block_k), lambda i, g, t: (i // hkv, 0, key_block(g, t)),
             memory_space=pltpu.VMEM)]
+    if admit is not None:
+        bias_in.append(admit)
+        bias_spec.append(pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda i, g, t: (i // hkv, g % nq, key_block(g, t)),
+            memory_space=pltpu.VMEM))
 
     return pl.pallas_call(
         functools.partial(
-            _bwd_fused_kernel if bias is not None else _without_bias(
-                _bwd_fused_kernel),
-            steps=steps, group=group, sm_scale=sm_scale, dropout=dropout,
-            masks=masks),
+            _bwd_fused_kernel, steps=steps, group=group, sm_scale=sm_scale,
+            dropout=dropout, masks=masks,
+            operands=(bias is not None, admit is not None)),
         grid=(bhkv, group * nq, steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -812,7 +845,8 @@ def _bwd_fused_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale,
             vmem_limit_bytes=_BWD_FUSED_VMEM_LIMIT),
         interpret=_interpret(),
         name="flash_bwd_dkv_dq",
-        cost_estimate=_cost("flash_bwd_dkv_dq", q, k, bias, masks, dims),
+        cost_estimate=_cost("flash_bwd_dkv_dq", q, k, bias, masks, dims,
+                            admit, admit_keys),
     )(seed, q, k, v, do, lse, delta, *bias_in)
 
 
@@ -825,7 +859,7 @@ def _bwd_fused_pallas(q, k, v, bias, seed, out, lse, do, h, *, sm_scale,
 # the two custom calls only if they are the same call (a kernel traced
 # under the vjp rule is named `jvp_flash_fwd_` and is another).
 _STATICS = ("sm_scale", "causal", "causal_offset", "dropout", "block_q",
-            "block_k", "window", "dims")
+            "block_k", "window", "dims", "admit_keys")
 _fwd_call = jax.jit(_fwd_pallas, static_argnums=(5,), static_argnames=_STATICS)
 _bwd_call = jax.jit(_bwd_pallas, static_argnums=(8,), static_argnames=_STATICS)
 _bwd_fused_call = jax.jit(_bwd_fused_pallas, static_argnums=(8,),
@@ -843,24 +877,31 @@ def _statics_of(statics):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _flash_core(q, k, v, bias, seed, h, statics):
-    return _fwd_call(q, k, v, bias, seed, h, **_statics_of(statics)[0])[0]
+def _flash_core(q, k, v, bias, seed, h, statics, admit=None):
+    """(output, log-sum-exp rows): the rows carry no gradient."""
+    return _fwd_call(q, k, v, bias, seed, h, admit,
+                     **_statics_of(statics)[0])
 
 
-def _flash_core_fwd(q, k, v, bias, seed, h, statics):
-    out, lse = _fwd_call(q, k, v, bias, seed, h, **_statics_of(statics)[0])
-    return out, (q, k, v, bias, seed, out, lse)
+def _flash_core_fwd(q, k, v, bias, seed, h, statics, admit=None):
+    out, lse = _fwd_call(q, k, v, bias, seed, h, admit,
+                         **_statics_of(statics)[0])
+    return (out, lse), (q, k, v, bias, seed, out, lse, admit)
 
 
-def _flash_core_bwd(h, statics, res, do):
-    q, k, v, bias, seed, out, lse = res
+def _flash_core_bwd(h, statics, res, cotangents):
+    q, k, v, bias, seed, out, lse, admit = res
+    do, _ = cotangents
     fused = _bwd_fused_viable(k.shape[1], k.shape[2], v.shape[2],
                               k.dtype.itemsize)
     dq, dk, dv = (_bwd_fused_call if fused else _bwd_call)(
-        q, k, v, bias, seed, out, lse, do, h, **_statics_of(statics)[1])
+        q, k, v, bias, seed, out, lse, do, h, admit,
+        **_statics_of(statics)[1])
     dbias = None if bias is None else jnp.zeros_like(bias)
     dseed = np.zeros((1,), dtype=jax.dtypes.float0)
-    return dq, dk, dv, dbias, dseed
+    dadmit = None if admit is None else np.zeros(admit.shape,
+                                                 jax.dtypes.float0)
+    return dq, dk, dv, dbias, dseed, dadmit
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -994,7 +1035,8 @@ def _pad_inputs(q, k, v, bias, block_q, block_k):
 
 
 def _attention_unfused(q, k, v, bias, causal, sm_scale, dropout, rng_key,
-                       f32_residuals, layout="bhsd", window=0):
+                       f32_residuals, layout="bhsd", window=0, admit=None,
+                       with_lse=False):
     """One implementation of the plain-XLA attention semantics (bias /
     bottom-right-aligned causal mask, with `window` > 0 only the last
     `window` keys of it / murmur-hash dropout — the contract the Pallas
@@ -1021,7 +1063,12 @@ def _attention_unfused(q, k, v, bias, causal, sm_scale, dropout, rng_key,
 
     K and V may have fewer heads than Q: query head `n` reads key/value
     head `n // group`. The group is a batch dimension of the products, so
-    K and V are not repeated."""
+    K and V are not repeated.
+
+    `admit`: [b, sq, sk], one for all the heads, whose zeros are pairs
+    refused beside the masks'. `with_lse`: returns (out, the float32
+    log-sum-exp of each row's admitted scores, [b, h, sq]) as the flash
+    kernel's forward leaves them."""
     bshd = layout == "bshd"
     h_ax = 2 if bshd else 1
     h, hkv = q.shape[h_ax], k.shape[h_ax]
@@ -1054,6 +1101,8 @@ def _attention_unfused(q, k, v, bias, causal, sm_scale, dropout, rng_key,
         sf = jnp.where(mask, sf, NEG_INF)
     elif window:
         raise ValueError("attention: a window needs causal=True")
+    if admit is not None:
+        sf = jnp.where(admit[:, None] != 0, sf, NEG_INF)
     p = jax.nn.softmax(sf, axis=-1)
     if not f32_residuals:
         p = p.astype(q.dtype)
@@ -1075,6 +1124,8 @@ def _attention_unfused(q, k, v, bias, causal, sm_scale, dropout, rng_key,
         out = jnp.einsum("bhqk,bkhd->bqhd", p, v)
     else:
         out = jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    if with_lse:
+        return out.astype(q.dtype), jax.nn.logsumexp(sf, axis=-1)
     return out.astype(q.dtype)
 
 
@@ -1086,11 +1137,11 @@ def _reference_attention(q, k, v, bias, causal, sm_scale, dropout, rng_key,
 
 
 def _xla_attention(q, k, v, bias, causal, sm_scale, dropout, rng_key,
-                   layout="bhsd", window=0):
+                   layout="bhsd", window=0, admit=None, with_lse=False):
     """Production below-cutover fallback: input-dtype HBM discipline."""
     return _attention_unfused(q, k, v, bias, causal, sm_scale, dropout,
                               rng_key, f32_residuals=False, layout=layout,
-                              window=window)
+                              window=window, admit=admit, with_lse=with_lse)
 
 
 def flash_attention(
@@ -1105,6 +1156,9 @@ def flash_attention(
     block_q=None,
     block_k=None,
     window=0,
+    admit=None,
+    admit_keys=0,
+    with_lse=False,
 ):
     """Fused multi-head attention.
 
@@ -1118,6 +1172,16 @@ def flash_attention(
     chosen from the call's shape (`_fwd_blocks`). The backward is one
     kernel where a key/value head's dk and dv fit VMEM and the pair
     elsewhere (`_bwd_fused_viable`: the shape decides, no argument).
+
+    `admit`: [b, sq, sk] int8, an admission that is data: a pair whose
+    entry is 0 is refused beside what the masks refuse, for every head
+    alike; it carries no gradient. Every kernel reads a block of it beside
+    a block of scores, once a query head (one byte a pair: at 32 heads
+    and 8,192 causal tokens 1.07 GB a pass), and still visits every block
+    the static masks admit. `admit_keys`: the keys a query admits at most
+    (what a selection of the K largest leaves), for the declared count
+    alone. `with_lse`: returns (out, the rows' log-sum-exp over the
+    admitted scores, [b, h, sq] float32, which carries no gradient).
     """
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
@@ -1168,6 +1232,13 @@ def flash_attention(
     statics = (("sm_scale", float(sm_scale)), ("causal", bool(causal)),
                ("causal_offset", causal_offset), ("dropout", float(dropout)),
                ("block_q", bq), ("block_k", bk), ("fwd_blocks", fwd_blocks),
-               ("window", int(window)), ("dims", (sq, sk, d, dv)))
-    out = _flash_core(qf, kf, vf, biasf, seed, h, statics)
-    return out[:, :sq, :dv].reshape(b, h, sq, dv)
+               ("window", int(window)), ("dims", (sq, sk, d, dv)),
+               ("admit_keys", int(admit_keys)))
+    if admit is not None:
+        admit = jnp.pad(admit.astype(jnp.int8), [
+            (0, 0), (0, qf.shape[1] - sq), (0, kf.shape[1] - sk)])
+    out, lse = _flash_core(qf, kf, vf, biasf, seed, h, statics, admit)
+    out = out[:, :sq, :dv].reshape(b, h, sq, dv)
+    if with_lse:
+        return out, lse[:, 0, :sq].reshape(b, h, sq)
+    return out

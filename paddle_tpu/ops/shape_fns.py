@@ -853,6 +853,14 @@ def _shape_fused_mha(ictx, op):
     if q.shape is not None and v.shape is not None:
         q = VarMeta(tuple(q.shape[:-1]) + (v.shape[-1],), q.dtype)
     ictx.out(op, "Out", q)
+    if op.output("Lse"):
+        if q.shape is None:
+            ictx.out(op, "Lse", VarMeta(None, F32))
+        else:
+            b, s, h = q.shape[:3]
+            if op.attr("layout", "bhsd") != "bshd":
+                s, h = h, s
+            ictx.out(op, "Lse", VarMeta((b, h, s), F32))
 
 
 @register_shape("rms_norm")

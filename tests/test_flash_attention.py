@@ -417,7 +417,7 @@ def _flash_at(q, k, v, fwd_blocks, block=128, bias=None, causal=True,
 
     def call(q, k, v):
         qf, kf, vf = fa._pad_inputs(q, k, v, bias, block, block)[:3]
-        out = fa._flash_core(qf, kf, vf, biasf, seed, h, statics)
+        out = fa._flash_core(qf, kf, vf, biasf, seed, h, statics)[0]
         return out[:, :sq, :dv].reshape(b, h, sq, dv)
 
     out, lse = fa._fwd_call(qf, kf, vf, biasf, seed, h,

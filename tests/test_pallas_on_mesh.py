@@ -488,6 +488,56 @@ def test_flash_kernels_compile_for_a_v5e_chip_at_the_published_widths(
         assert shapes(written) == results[name], name
 
 
+def test_sparse_attention_kernels_compile_for_a_v5e_chip_at_keyes_widths(
+        topo):
+    """What `keye_vl2_ep16_s8192` adds to a step, at its shapes: one
+    8,192-token row, the indexer's 16 heads of 64 on one key head
+    (`sparse_index_fwd`, `sparse_index_bwd`), the selection of 2,048 keys
+    a row (`sparse_select`, 64 rows of float32 scores resident), the
+    flash kernels at 32 heads over 4 of 128 with the admission as an int8
+    operand and their log-sum-exp rows returned, and the loss's target
+    (`index_kl_target`, 32 heads' blocks resident). Nothing runs."""
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas import flash_attention, sparse_index
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    b, s, h, g, d, hi, di, k = 1, 8192, 32, 4, 128, 16, 64, 2048
+
+    def sds(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    def index(q, kk, w):
+        out, pull = jax.vjp(lambda *a: sparse_index.index_scores(*a, 1 / 32),
+                            q, kk, w)
+        admit, tau = sparse_index.select(out, k)
+        return admit, tau, pull(out)
+
+    def attention(q, kk, v, admit):
+        (o, lse), pull = jax.vjp(lambda *a: flash_attention(
+            *a, causal=True, admit=admit, admit_keys=k, with_lse=True),
+            q, kk, v)
+        target = sparse_index.head_mean_probabilities(q, kk, lse, admit,
+                                                      d ** -0.5, k)
+        return target, pull((o, jnp.zeros_like(lse)))
+
+    with _as_on_the_chip():
+        text = jax.jit(index).lower(
+            sds(b, hi, s, di), sds(b, s, di),
+            sds(b, s, hi, dtype=jnp.float32)).compile().as_text()
+        assert all(name in text for name in (
+            "sparse_index_fwd", "sparse_index_bwd", "sparse_select"))
+        assert "f32[1,16,8192,8192]" not in text  # no head's matrix
+        compiled = jax.jit(attention).lower(
+            sds(b, h, s, d), sds(b, g, s, d), sds(b, g, s, d),
+            sds(b, s, s, dtype=jnp.int8)).compile()
+    text = compiled.as_text()
+    assert all(name in text for name in (
+        "flash_fwd", "flash_bwd_dkv_dq", "index_kl_target"))
+    assert "s8[1,8192,8192]" in text and "f32[1,32,8192,8192]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
 @pytest.mark.parametrize("dtype,d_p,dv_p,blocks", [
     (jnp.bfloat16, 256, 256, (1024, 1024)),
     (jnp.bfloat16, 512, 512, (512, 1024)),

@@ -22,7 +22,19 @@ a switch for the QK-norm, `proj` a deviation of its own and
 same way: the expert layer through the grouped kernels, with the
 renormalisation's epsilon (LFM2's) and with the gated shared expert
 (Qwen3-Next's), Phi-4's Mamba-1 mixer on both of its paths, and the
-convolution with bias and SiLU as the Mamba mixers call it."""
+convolution with bias and SiLU as the Mamba mixers call it.
+
+The four `-flash` cases are PR 60's own tree's. That PR gave the flash
+kernels an optional admission operand and made the call return its
+log-sum-exp rows beside the output (`ops/pallas/flash_attention.py`): a
+call without an admission carries one more static (`admit_keys` 0), its
+kernels take their optional operands by a tuple of switches, and the
+custom-vjp returns a pair, so the jaxpr's text moved. What the chip runs
+did not: compiled for a described v5e at Trinity's and Mellum's shape
+(32 heads on 4 of 128 at 8,192 tokens, full causal), the Mosaic modules
+of `flash_fwd` and `flash_bwd_dkv_dq` with their locations stripped and
+the declared costs are the parent's to the byte (PERF.md section 6,
+PR 60, has the digests). The `-xla` cases stand."""
 
 import os
 import sys
@@ -156,10 +168,11 @@ PARENTS_JAXPRS = {
     "trinity_window-xla": "9e6c7e0895897a9a",
     "mellum-xla": "9e7d571745830a91",
     "lfm2-xla": "9f0068051ad85522",
-    "trinity_full-flash": "df9ffd967c9e7bce",
-    "trinity_window-flash": "e6a9aaad617f08aa",
-    "mellum-flash": "f164b6ef665ad574",
-    "lfm2-flash": "d98bb2942185766e",
+    # PR 60's tree: see the docstring
+    "trinity_full-flash": "056160d2639a489a",
+    "trinity_window-flash": "e01967eee0b60f69",
+    "mellum-flash": "beab6dae51f8d3b7",
+    "lfm2-flash": "0847f7ec19b5593b",
     "experts-shared": "0c34ee51cfcbcb61",
     "experts-alone": "eb3b9327f05919f0",
     # as PR 53's parent (commit 55ba533) traces them

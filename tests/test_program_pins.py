@@ -15,7 +15,9 @@ The table is taken by running this file as a script on the parent commit
 re-takes only the rows it means to change. These are PR 55's parent's
 (commit 006eebc), but for the nine cells that lower `rms_norm`: PR 59 gave
 the op a gradient op of its own (`rms_norm_grad` where `__auto_grad__`
-stood, one a norm), and their rows are that PR's own tree's."""
+stood, one a norm), and their rows are that PR's own tree's.
+`keye_vl2_ep16_s8192`'s row is PR 60's own tree's, the PR that added the
+cell and the three op types at `OPS`' end, which no other cell has."""
 
 import hashlib
 import json
@@ -29,7 +31,7 @@ sys.path.append(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 OPS = ("fused_multihead_attention", "rotary_embedding", "short_conv1d",
        "kda_attention", "selective_scan", "ssd_scan", "moe_experts",
-       "rms_norm_grad")
+       "rms_norm_grad", "sparse_index", "sparse_select", "index_kl")
 ATTRS = ("rope_scaling", "interleaved", "q_lora_rank", "activation",
          "norm_eps", "rotary_dim", "expert_form")
 
@@ -99,6 +101,11 @@ PINS = {
         760, "8797738d31c12cba",
         {"fused_multihead_attention": 8, "rotary_embedding": 16,
          "rms_norm_grad": 36}, ()),
+    "keye_vl2_ep16_s8192": (
+        282, "eb6ce5ca205785b7",
+        {"fused_multihead_attention": 2, "rotary_embedding": 8,
+         "moe_experts": 2, "rms_norm_grad": 9, "sparse_index": 2,
+         "sparse_select": 2, "index_kl": 2}, ()),
 }
 
 
