@@ -1418,6 +1418,7 @@ def fused_multihead_attention(
     admit=None,
     admit_keys=0,
     return_lse=False,
+    return_prepared=False,
 ):
     """Flash attention over q/k/v (Pallas kernel on TPU). layout="bhsd"
     (default): [b, nh, s, dh]; layout="bshd": [b, s, nh, dh] — the shape
@@ -1439,16 +1440,16 @@ def fused_multihead_attention(
 
     `q_norm_attr` and `k_norm_attr`, given together, add QK-norm: `q` and
     `k` are normed head by head over `dh` as `rms_norm` does, each with a
-    learned `[dh]` weight seeded at 1 and `qk_norm_epsilon`. With them,
-    `rope_theta` > 0 (layout "bshd") then turns q and k by
+    learned `[dh]` weight seeded at 1 and `qk_norm_epsilon`. With them
+    or without, `rope_theta` > 0 (layout "bshd") then turns q and k by
     `rotary_embedding`'s positions 0..s-1, with a window or without
     one; `rope_scaling` (a published YaRN group, as `rotary_embedding`
     takes it) scales the tables; `rotary_dim` fewer than `dh` (0: the
     whole head) turns only the first `rotary_dim` lanes of a head, as a
-    head of that width, and passes the rest as normed
-    (`partial_rotary_factor`). Inside the op the two share one pass
-    over q and k with the kernel's head-major write, where the kernel
-    runs.
+    head of that width, and passes the rest as they were
+    (`partial_rotary_factor`). Inside the op the norm, the positions and
+    the kernel's head-major write are one pass over q and k, where the
+    kernel runs.
 
     `q_lora_rank` > 0 says that `q` came through a compressed query of
     that rank (latent attention, `decoder_parts.latent_attention`). The
@@ -1462,14 +1463,18 @@ def fused_multihead_attention(
     selection's K), which the kernels' declared work counts by. With
     `return_lse` the layer returns (out, lse): each row's log-sum-exp over
     its admitted scaled scores, [b, heads, sq] float32, with no gradient.
+    With `return_prepared` it returns, after those, q and k as the
+    attention took them (normed and turned, in its dtype): head-major,
+    [b, heads, sq, dh] and [b, groups, sk, dh] in either layout, with no
+    gradient.
     """
     if layout not in ("bhsd", "bshd"):
         raise ValueError(f"layout must be 'bhsd' or 'bshd', got {layout!r}")
     if (q_norm_attr is None) != (k_norm_attr is None):
         raise ValueError("q_norm_attr and k_norm_attr come together")
     scaling = _yarn_attr(rope_scaling)
-    if scaling and not (q_norm_attr is not None and rope_theta):
-        raise ValueError("rope_scaling needs rope_theta and the QK-norms")
+    if scaling and not rope_theta:
+        raise ValueError("rope_scaling needs rope_theta")
     helper = LayerHelper("fused_multihead_attention", name=name)
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if key_bias is not None:
@@ -1484,12 +1489,17 @@ def fused_multihead_attention(
     out = helper.create_variable_for_type_inference(
         q.dtype, list(q.shape[:-1]) + [v.shape[-1]])
     outputs = {"Out": [out]}
+    s_ax, h_ax = (1, 2) if layout == "bshd" else (2, 1)
     if return_lse:
-        s_ax, h_ax = (1, 2) if layout == "bshd" else (2, 1)
         lse = helper.create_variable_for_type_inference(
             "float32", [q.shape[0], q.shape[h_ax], q.shape[s_ax]],
             stop_gradient=True)
         outputs["Lse"] = [lse]
+    if return_prepared:
+        prepared = [helper.create_variable_for_type_inference(
+            q.dtype, [q.shape[0], t.shape[h_ax], t.shape[s_ax], t.shape[3]],
+            stop_gradient=True) for t in (q, k)]
+        outputs["QPrepared"], outputs["KPrepared"] = ([t] for t in prepared)
     helper.append_op(
         type="fused_multihead_attention",
         inputs=inputs,
@@ -1502,9 +1512,10 @@ def fused_multihead_attention(
             "layout": layout,
             "window": int(window),
             # on a Program that asks for neither, the op is as it was
-            **({"qk_norm_epsilon": float(qk_norm_epsilon),
-                "rope_theta": float(rope_theta)}
+            **({"qk_norm_epsilon": float(qk_norm_epsilon)}
                if q_norm_attr is not None else {}),
+            **({"rope_theta": float(rope_theta)}
+               if q_norm_attr is not None or rope_theta else {}),
             **({"rope_scaling": scaling} if scaling else {}),
             **({"q_lora_rank": int(q_lora_rank)} if q_lora_rank else {}),
             **({"rotary_dim": int(rotary_dim)}
@@ -1512,7 +1523,9 @@ def fused_multihead_attention(
             **({"admit_keys": int(admit_keys)} if admit is not None else {}),
         },
     )
-    return (out, lse) if return_lse else out
+    more = ([lse] if return_lse else []) + (
+        prepared if return_prepared else [])
+    return (out, *more) if more else out
 
 
 def sparse_index(q, k, w, scale, name=None):
@@ -1553,9 +1566,10 @@ def index_kl(q, k, lse, index, admit, sm_scale, admit_keys=0, name=None):
     for, a number a query: `KL(p[t] || softmax over the admitted keys of
     index[t])` with the target `p[t, s]` the mean over the heads of the
     attention's probabilities `exp(sm_scale q[t, head] . k[s, group] -
-    lse[head, t])` on the admitted pairs. q [b, s, heads, d] and k
-    [b, s, groups, d] as the attention took them (normed and turned), lse
-    as `fused_multihead_attention(return_lse=True)` gives it, `admit` as
+    lse[head, t])` on the admitted pairs. q [b, heads, s, d] and k
+    [b, groups, s, d] head-major as the attention took them (normed and
+    turned): what `fused_multihead_attention(return_prepared=True)` hands
+    back; lse as its `return_lse=True` gives it, `admit` as
     `sparse_select`'s. The target is rebuilt here, summed head by head
     and never held for all the heads, and is a constant: the gradient
     reaches `index` alone. Returns [b, s] float32."""
@@ -1565,7 +1579,7 @@ def index_kl(q, k, lse, index, admit, sm_scale, admit_keys=0, name=None):
         {"Q": [q], "K": [k], "Lse": [lse], "Index": [index],
          "Admit": [admit]},
         {"sm_scale": float(sm_scale), "admit_keys": int(admit_keys)},
-        dtype="float32", shape=[q.shape[0], q.shape[1]])
+        dtype="float32", shape=[q.shape[0], q.shape[2]])
 
 
 def topk(input, k, name=None):

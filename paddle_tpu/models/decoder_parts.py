@@ -247,12 +247,10 @@ def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
     to [b, s, hidden]: q and k normed over a head's width (one weight of
     `head_dim` each; not with `qk_norm` False), turned by rotary positions
     where `rope_theta` is not 0 (`rope_scaling`: a YaRN group;
-    `rotary_dim` not 0: the first `rotary_dim` lanes of a head alone;
-    neither without the QK-norm, whose pass they share: there the whole
-    heads are turned by the op `rotary_embedding`), `window` keys wide
-    where it is not 0, and with `gated` the output times `sigmoid(W_g u)`
-    before the output projection. The heads are the ones held here, which
-    may be a share of the model's."""
+    `rotary_dim` not 0: the first `rotary_dim` lanes of a head alone),
+    `window` keys wide where it is not 0, and with `gated` the output
+    times `sigmoid(W_g u)` before the output projection. The heads are the
+    ones held here, which may be a share of the model's."""
     b, s, _ = u.shape
     h, g, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     q = layers.reshape(proj(u, h * d, name + ".q", cfg), [b, s, h, d])
@@ -262,20 +260,12 @@ def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
         gate = layers.sigmoid(proj(u, h * d, name + ".gate", cfg))
     # QK-norm and the positions inside the attention op, where they and
     # the kernel's head-major write are one pass over q and k
-    prep = {}
+    prep = dict(rope_theta=rope_theta, rope_scaling=rope_scaling,
+                rotary_dim=rotary_dim)
     if qk_norm:
-        prep = dict(q_norm_attr=ParamAttr(name=name + ".q_norm.w_0"),
+        prep.update(q_norm_attr=ParamAttr(name=name + ".q_norm.w_0"),
                     k_norm_attr=ParamAttr(name=name + ".k_norm.w_0"),
-                    qk_norm_epsilon=cfg.rms_norm_eps, rope_theta=rope_theta,
-                    rope_scaling=rope_scaling, rotary_dim=rotary_dim)
-    elif rope_theta:
-        if rope_scaling or rotary_dim:
-            raise ValueError("attention: scaled or partial positions are "
-                             "turned with the QK-norm's pass, and qk_norm "
-                             "is False")
-        # no norm to share a pass with: the op `rotary_embedding` on each
-        q = layers.rotary_embedding(q, theta=rope_theta)
-        k = layers.rotary_embedding(k, theta=rope_theta)
+                    qk_norm_epsilon=cfg.rms_norm_eps)
     a = layers.fused_multihead_attention(
         q, k, v, causal=True, sm_scale=1.0 / math.sqrt(d), layout="bshd",
         window=window, **prep)
@@ -306,8 +296,9 @@ def sparse_attention(u, cfg, name, rope_theta):
     indexer's parameters get `index_kl`'s gradient alone and everything
     else none of it: the selection passes no gradient.
 
-    q and k are normed and turned by ops of their own here, not inside the
-    attention op: `index_kl` reads them as the attention took them.
+    q and k are normed and turned inside the attention op, which hands
+    them back head-major as it took them: `index_kl` reads those. The
+    indexer's own heads, 64 lanes wide, are turned by `rotary_embedding`.
     Counters, once a layer built: `sparse_attn_layers`,
     `attn_pairs_admitted` (b * sum_t min(t + 1, K)) and
     `attn_pairs_causal` (b * s (s + 1) / 2)."""
@@ -315,30 +306,34 @@ def sparse_attention(u, cfg, name, rope_theta):
     h, g, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     hi, di, topk = cfg.indexer_num_heads, cfg.indexer_head_dim, cfg.topk
 
-    def heads(t, n, width, norm_name=None):
-        t = layers.reshape(t, [b, s, n, width])
-        if norm_name:
-            t = norm(t, norm_name, cfg, axis=3)
-        return layers.rotary_embedding(t, theta=rope_theta)
+    def heads(t, n, width):
+        return layers.reshape(t, [b, s, n, width])
 
-    q = heads(proj(u, h * d, name + ".q", cfg), h, d, name + ".q_norm")
-    k = heads(proj(u, g * d, name + ".k", cfg), g, d, name + ".k_norm")
-    v = layers.reshape(proj(u, g * d, name + ".v", cfg), [b, s, g, d])
+    def turned(t, n, width):
+        return layers.rotary_embedding(heads(t, n, width), theta=rope_theta)
+
+    q = heads(proj(u, h * d, name + ".q", cfg), h, d)
+    k = heads(proj(u, g * d, name + ".k", cfg), g, d)
+    v = heads(proj(u, g * d, name + ".v", cfg), g, d)
 
     detached = layers.assign(u)
     detached.stop_gradient = True
-    qi = heads(proj(detached, hi * di, name + ".indexer.q", cfg), hi, di)
-    ki = heads(layer_norm(proj(detached, di, name + ".indexer.k", cfg),
-                          name + ".indexer.k_norm", cfg), 1, di)
+    qi = turned(proj(detached, hi * di, name + ".indexer.q", cfg), hi, di)
+    ki = turned(layer_norm(proj(detached, di, name + ".indexer.k", cfg),
+                           name + ".indexer.k_norm", cfg), 1, di)
     w = proj(detached, hi, name + ".indexer.w", cfg)
     index = layers.sparse_index(qi, ki, w, scale=(hi * di) ** -0.5)
     admit, _ = layers.sparse_select(index, topk)
 
     sm_scale = 1.0 / math.sqrt(d)
-    a, lse = layers.fused_multihead_attention(
+    a, lse, q_taken, k_taken = layers.fused_multihead_attention(
         q, k, v, causal=True, sm_scale=sm_scale, layout="bshd", admit=admit,
-        admit_keys=topk, return_lse=True)
-    kl = layers.index_kl(q, k, lse, index, admit, sm_scale, admit_keys=topk)
+        admit_keys=topk, return_lse=True, return_prepared=True,
+        q_norm_attr=ParamAttr(name=name + ".q_norm.w_0"),
+        k_norm_attr=ParamAttr(name=name + ".k_norm.w_0"),
+        qk_norm_epsilon=cfg.rms_norm_eps, rope_theta=rope_theta)
+    kl = layers.index_kl(q_taken, k_taken, lse, index, admit, sm_scale,
+                         admit_keys=topk)
     profiler.bump_counter("sparse_attn_layers")
     profiler.bump_counter("attn_pairs_admitted", b * cost.admitted_pairs(
         s, s, causal=True, window=topk))

@@ -113,16 +113,27 @@ def _fused_mha(ctx, op):
 
     Optional QNorm, KNorm ([dh] each, together): q and k are first normed
     head by head as the op `rms_norm` norms the last axis, with attr
-    `qk_norm_epsilon`; attr `rope_theta` > 0 then turns them by the op
+    `qk_norm_epsilon`. Attr `rope_theta` > 0 (layout "bshd", with the
+    norms or without them) then turns q and k by the op
     `rotary_embedding`'s positions, under attr `rope_scaling` (YaRN's five
     numbers, `nn_ops.yarn_frequencies`) by its scaled tables, whether the
     layer has a window or none. Attr `rotary_dim` (absent: the whole
     head) turns the first `rotary_dim` lanes of a head as a head of that
-    width and passes the rest as normed (`partial_rotary_factor`); gauge
+    width and passes the rest as they were (`partial_rotary_factor`); gauge
     `attn_rotary_lanes` then holds it. On the flash path with layout "bshd"
     and heads of whole 128-lane slices, that and the head-major write the
-    kernel wants are one kernel pair (ops/pallas/qk_prep.py); on every
-    other path the two ops' own functions run first, in `jnp`.
+    kernel wants are one kernel pair (ops/pallas/qk_prep.py; counter
+    `attn_qk_prep_fused`, and `attn_qk_prep_rope_only` where it runs
+    without a norm); on every other path the two ops' own functions run
+    first, in `jnp`.
+
+    Optional outputs QPrepared [b, heads, s, dh] and KPrepared [b, groups,
+    s, dh]: q and k as the attention took them, normed and turned, in its
+    dtype and head-major in either layout, with no gradient (what
+    `index_kl` reads). On the fused path they are the
+    kernel pair's own outputs, the arrays the flash kernels read (counter
+    `attn_qk_prep_handed_back`); elsewhere the `jnp` preparation's,
+    transposed.
 
     Optional Admit: [b, sq, sk] int8, an admission that is data (a
     selection's: `sparse_select`): a pair whose entry is 0 is refused for
@@ -143,6 +154,7 @@ def _fused_mha(ctx, op):
     bias = ctx.in_(op, "KeyBias")
     admit = ctx.in_(op, "Admit")
     with_lse = bool(op.output("Lse"))
+    with_prepared = bool(op.output("QPrepared"))
     q_norm, k_norm = ctx.in_(op, "QNorm"), ctx.in_(op, "KNorm")
     norm_eps = float(op.attr("qk_norm_epsilon", 1e-5))
     rope_theta = float(op.attr("rope_theta", 0.0) or 0.0)
@@ -162,10 +174,10 @@ def _fused_mha(ctx, op):
     if (q_norm is None) != (k_norm is None):
         raise ValueError(
             "fused_multihead_attention: QNorm and KNorm come together")
-    if rope_theta and (q_norm is None or not bshd):
+    if rope_theta and not bshd:
         raise ValueError(
-            "fused_multihead_attention: rope_theta needs QNorm and KNorm, "
-            "and layout \"bshd\", whose axis 1 the positions count")
+            "fused_multihead_attention: rope_theta needs layout \"bshd\", "
+            "whose axis 1 the positions count")
     if rope_scaling and not rope_theta:
         raise ValueError(
             "fused_multihead_attention: rope_scaling needs rope_theta")
@@ -178,7 +190,7 @@ def _fused_mha(ctx, op):
             f"rope_theta, and to be even and at most the head's "
             f"{q.shape[-1]} lanes")
 
-    prepare = q_norm is not None
+    prepare = q_norm is not None or bool(rope_theta)
     if prepare:
         raw = q, k, v
 
@@ -186,8 +198,9 @@ def _fused_mha(ctx, op):
             """q and k as the ops `rms_norm` and `rotary_embedding` leave
             them, then in the attention's dtype."""
             q, k, _ = raw
-            q = rms_norm(q, q_norm, norm_eps, 3)
-            k = rms_norm(k, k_norm, norm_eps, 3)
+            if q_norm is not None:
+                q = rms_norm(q, q_norm, norm_eps, 3)
+                k = rms_norm(k, k_norm, norm_eps, 3)
             if rope_theta:
                 q = rotate_half(q, rope_theta, rope_scaling, rotary_dim)
                 k = rotate_half(k, rope_theta, rope_scaling, rotary_dim)
@@ -254,10 +267,14 @@ def _fused_mha(ctx, op):
                              with_lse=with_lse)
     elif path == "flash":
         if fused:
-            # from the arrays as they came: the kernel pair norms and
-            # rotates in float32 and writes the attention's dtype,
-            # head-major (ops/pallas/qk_prep.py)
+            # from the arrays as they came: the kernel pair norms (with
+            # weights) and rotates in float32 and writes the attention's
+            # dtype, head-major (ops/pallas/qk_prep.py)
             profiler.bump_counter("attn_qk_prep_fused")
+            if q_norm is None:
+                profiler.bump_counter("attn_qk_prep_rope_only")
+            if with_prepared:
+                profiler.bump_counter("attn_qk_prep_handed_back")
             operands = qk_prep(*raw, q_norm, k_norm, epsilon=norm_eps,
                                theta=rope_theta, scaling=rope_scaling,
                                out_dtype=q.dtype, rotary_dim=rotary_dim)
@@ -298,4 +315,9 @@ def _fused_mha(ctx, op):
     if with_lse:
         out, lse = out
         ctx.out(op, "Lse", lse)
+    if with_prepared:
+        # the arrays the flash kernels read, where they ran
+        handed = operands[:2] if path == "flash" else (swap(q), swap(k))
+        for slot, t in zip(("QPrepared", "KPrepared"), handed):
+            ctx.out(op, slot, jax.lax.stop_gradient(t))
     ctx.out(op, "Out", out)

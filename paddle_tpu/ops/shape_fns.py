@@ -861,6 +861,13 @@ def _shape_fused_mha(ictx, op):
             if op.attr("layout", "bhsd") != "bshd":
                 s, h = h, s
             ictx.out(op, "Lse", VarMeta((b, h, s), F32))
+    for slot, t in (("QPrepared", "Q"), ("KPrepared", "K")):
+        if op.output(slot):
+            t = _m(ictx.in_(op, t))
+            shape = t.shape
+            if shape is not None and op.attr("layout", "bhsd") == "bshd":
+                shape = (shape[0], shape[2], shape[1], shape[3])
+            ictx.out(op, slot, VarMeta(shape, q.dtype))
 
 
 @register_shape("rms_norm")

@@ -241,7 +241,8 @@ def _kl_block(q, k, lse, index, admit, sm_scale):
 
 
 def index_kl_rows(q, k, lse, index, admit, sm_scale):
-    """`index_kl` on arrays (layout "bshd")."""
+    """`index_kl` on arrays, q [b, s, heads, d] and k [b, s, groups, d]
+    token-major."""
     return jnp.concatenate([
         _kl_block(q[:, lo:hi], k[:, :hi], lse[:, :, lo:hi],
                   index[:, lo:hi, :hi], admit[:, lo:hi, :hi], sm_scale)
@@ -250,11 +251,12 @@ def index_kl_rows(q, k, lse, index, admit, sm_scale):
 
 @register_op("index_kl", no_grad_inputs=("Q", "K", "Lse", "Admit"))
 def _index_kl(ctx, op):
-    """Q [b, s, heads, d], K [b, s, groups, d] (as the attention took
-    them), Lse [b, heads, s] float32 (its output), Index [b, s, s]
-    float32, Admit [b, s, s] int8, attrs `sm_scale` and `admit_keys` (the
-    keys a query admits at most, for the kernel's declared count) -> Out
-    [b, s] float32:
+    """Q [b, heads, s, d], K [b, groups, s, d] (head-major, as the
+    attention took them: its outputs QPrepared and KPrepared), Lse
+    [b, heads, s] float32 (its output), Index [b, s, s] float32, Admit
+    [b, s, s] int8, attrs `sm_scale` and `admit_keys` (the keys a query
+    admits at most, for the kernel's declared count) -> Out [b, s]
+    float32:
     `sum over the admitted s of p (log p - log softmax_admitted(Index))`
     with `p = mean over the heads of exp(sm_scale Q . K - Lse)`, a
     constant. The gradient reaches Index alone: `(softmax_admitted(Index)
@@ -263,15 +265,16 @@ def _index_kl(ctx, op):
     lse, index, admit = (ctx.in_(op, "Lse"), ctx.in_(op, "Index"),
                          ctx.in_(op, "Admit"))
     sm_scale = float(op.attr("sm_scale"))
-    kernels = _kernels(ctx, q.shape[0], q.shape[1])
+    kernels = _kernels(ctx, q.shape[0], q.shape[2])
     if kernels is None:
+        # `index_kl_rows` cuts its blocks along a token-major axis
+        q, k = (jnp.transpose(t, (0, 2, 1, 3)) for t in (q, k))
         out = index_kl_rows(q, k, lse, index, admit, sm_scale)
     else:
-        # head-major, as the flash kernels took them: the same transposes
+        # the arrays the flash kernels read, as they come
         profiler.bump_counter("index_kl_kernel_calls")
         target = kernels.head_mean_probabilities(
-            jnp.transpose(q, (0, 2, 1, 3)), jnp.transpose(k, (0, 2, 1, 3)),
-            lse, admit, sm_scale, int(op.attr("admit_keys", 0) or 0))
+            q, k, lse, admit, sm_scale, int(op.attr("admit_keys", 0) or 0))
         out = kl_from_target(target, index, admit)
     ctx.out(op, "Out", out)
 
@@ -282,4 +285,4 @@ def _shape_index_kl(ictx, op):
 
     q = _m(ictx.in_(op, "Q"))
     ictx.out(op, "Out", VarMeta(
-        None if q.shape is None else tuple(q.shape[:2]), F32))
+        None if q.shape is None else (q.shape[0], q.shape[2]), F32))

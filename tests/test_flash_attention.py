@@ -1553,3 +1553,228 @@ def test_rotary_dim_turns_the_first_lanes_and_passes_the_rest(
     for a, w in zip(got[1:], want_grads):
         np.testing.assert_allclose(a, w, atol=2e-4, rtol=2e-4)
     assert np.abs(np.asarray(whole) - got[0]).max() > 0.05
+
+
+# ------------------- positions without a norm, and the prepared pair (PR 61)
+
+
+def _qkv_program(b, s, h, g, d, build):
+    """A Program over flat q, k, v feeds ([b, s, heads * d], as the
+    projections write them): `build(q, k, v)` on the `[b, s, heads, d]`
+    reshapes returns the vars to fetch, the first of them the attention's
+    output; the gradients of sum(out^2) in the three feeds follow."""
+    import paddle_tpu as fluid
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        feeds = [fluid.layers.data(n, [b, s, heads * d],
+                                   append_batch_size=False)
+                 for n, heads in (("q", h), ("k", g), ("v", g))]
+        for t in feeds:
+            t.stop_gradient = False
+        fetch = build(*(fluid.layers.reshape(t, [b, s, -1, d])
+                        for t in feeds))
+        loss = fluid.layers.reduce_sum(
+            fluid.layers.elementwise_mul(fetch[0], fetch[0]))
+        grads = fluid.backward.calc_gradient(loss, feeds)
+    return main, startup, [*fetch, *grads]
+
+
+def _run_qkv(program, feed, weights=()):
+    import paddle_tpu as fluid
+
+    main, startup, fetch = program
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        for n, w in dict(weights).items():
+            fluid.global_scope().set(n, w)
+        return exe.run(main, feed=feed, fetch_list=fetch)
+
+
+YARN = {"rope_type": "yarn", "factor": 8.0,
+        "original_max_position_embeddings": 64}
+
+
+@pytest.mark.parametrize("path,d,kw", [
+    ("xla", 64, {}), ("flash", 64, {}), ("flash", 128, {}),
+    ("xla", 128, {"rope_scaling": YARN}),
+    ("flash", 128, {"rope_scaling": YARN}),
+], ids=["xla-64", "flash-64", "flash-128", "xla-128-yarn", "flash-128-yarn"])
+def test_positions_without_a_norm_are_the_two_rotary_ops_in_front(
+        monkeypatch, attn_path, path, d, kw):
+    """`rope_theta` and no `QNorm`: the op gives the outputs and the
+    gradients of two `rotary_embedding` ops and the op without positions,
+    on the plain path, through the flash kernel with `rotate_half` in
+    front (heads of 64) and through `qk_prep` without weights (heads of
+    128: counters `attn_qk_prep_fused` and `attn_qk_prep_rope_only`, the
+    forward op's lowering and the gradient op's replay)."""
+    import paddle_tpu as fluid
+    from paddle_tpu import profiler
+
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    attn_path(path)
+    b, s, h, g, theta = 1, 96, 4, 2, 1e4
+    call = dict(causal=True, sm_scale=d ** -0.5, layout="bshd")
+
+    def inside(q, k, v):
+        return [fluid.layers.fused_multihead_attention(
+            q, k, v, rope_theta=theta, **kw, **call)]
+
+    def in_front(q, k, v):
+        q, k = (fluid.layers.rotary_embedding(t, theta=theta, **kw)
+                for t in (q, k))
+        return [fluid.layers.fused_multihead_attention(q, k, v, **call)]
+
+    programs = [_qkv_program(b, s, h, g, d, fn) for fn in (inside, in_front)]
+    ops = [[op.type for op in p[0].global_block().ops] for p in programs]
+    assert "rotary_embedding" not in ops[0]
+    assert ops[1].count("rotary_embedding") == 2
+    (attn,) = [op for op in programs[0][0].global_block().ops
+               if op.type == "fused_multihead_attention"]
+    assert attn.attr("rope_theta") == theta and not attn.input("QNorm")
+    assert "qk_norm_epsilon" not in attn.attrs
+    r = np.random.RandomState(d)
+    feed = {n: r.randn(b, s, heads * d).astype("float32")
+            for n, heads in (("q", h), ("k", g), ("v", g))}
+    before = profiler.counters()
+    got = _run_qkv(programs[0], feed)
+    after = profiler.counters()
+    want = _run_qkv(programs[1], feed)
+    fused = 2 if path == "flash" and d % 128 == 0 else 0
+    for name in ("attn_qk_prep_fused", "attn_qk_prep_rope_only"):
+        assert after.get(name, 0) - before.get(name, 0) == fused, name
+    assert after.get("attn_qk_prep_handed_back", 0) == before.get(
+        "attn_qk_prep_handed_back", 0)
+    assert after.get("attn_rope_scaled", 0) - before.get(
+        "attn_rope_scaled", 0) == (2 if kw else 0)
+    np.testing.assert_allclose(got[0], want[0], atol=2e-5)
+    for a, w in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(a, w, atol=2e-4, rtol=2e-4)
+    # and the positions bite
+    plain = _run_qkv(_qkv_program(
+        b, s, h, g, d, lambda q, k, v: [
+            fluid.layers.fused_multihead_attention(q, k, v, **call)]), feed)
+    assert np.abs(plain[0] - got[0]).max() > 0.05
+
+
+def test_positions_need_the_token_major_layout_and_scaling_needs_positions():
+    import paddle_tpu as fluid
+
+    q = fluid.layers.data("q", [1, 2, 16, 64], append_batch_size=False)
+    with pytest.raises(ValueError, match="rope_scaling needs rope_theta"):
+        fluid.layers.fused_multihead_attention(q, q, q, rope_scaling=YARN)
+    out = fluid.layers.fused_multihead_attention(q, q, q, rope_theta=1e4)
+    exe = fluid.Executor(fluid.CPUPlace())
+    with pytest.raises(Exception, match="bshd"):
+        exe.run(feed={"q": np.zeros((1, 2, 16, 64), "float32")},
+                fetch_list=[out])
+
+
+@pytest.mark.parametrize("path,d,normed", [
+    ("xla", 64, True), ("flash", 64, True), ("flash", 128, True),
+    ("flash", 128, False), ("xla", 128, False),
+], ids=["xla-64", "flash-64", "flash-128", "flash-128-no_norm",
+        "xla-128-no_norm"])
+def test_the_prepared_pair_is_what_the_attention_took_and_index_kl_reads_it(
+        monkeypatch, attn_path, path, d, normed):
+    """`return_prepared`: QPrepared and KPrepared are the `jnp`
+    preparation (`rms_norm`, `rotate_half`) transposed head-major, on the
+    plain path, on the flash path with the two functions in front and as
+    `qk_prep`'s own outputs (heads of 128: counter
+    `attn_qk_prep_handed_back`); asking for them changes neither the
+    output nor a gradient; and `index_kl` on the pair is `index_kl_rows`
+    on the same pair token-major."""
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu import profiler
+    from paddle_tpu.ops.nn_ops import rms_norm, rotate_half
+    from paddle_tpu.ops.sparse_attn_ops import index_kl_rows
+
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    attn_path(path)
+    b, s, h, g, theta, eps = 1, 96, 4, 2, 1e4, 1e-6
+    call = dict(causal=True, sm_scale=d ** -0.5, layout="bshd",
+                rope_theta=theta)
+    if normed:
+        call.update(q_norm_attr=fluid.ParamAttr(name="qn"),
+                    k_norm_attr=fluid.ParamAttr(name="kn"),
+                    qk_norm_epsilon=eps)
+    r = np.random.RandomState(d)
+    feed = {n: r.randn(b, s, heads * d).astype("float32")
+            for n, heads in (("q", h), ("k", g), ("v", g))}
+    feed["index"] = np.where(np.tril(np.ones((s, s), bool)),
+                             r.randn(b, s, s), -np.inf).astype("float32")
+    feed["admit"] = np.tril(np.ones((b, s, s), "int8"))
+    weights = {n: r.uniform(0.5, 1.5, d).astype("float32")
+               for n in ("qn", "kn")} if normed else {}
+
+    def asked(q, k, v):
+        out, lse, qp, kp = fluid.layers.fused_multihead_attention(
+            q, k, v, return_lse=True, return_prepared=True, **call)
+        assert qp.stop_gradient and kp.stop_gradient
+        assert tuple(qp.shape) == (b, h, s, d)
+        assert tuple(kp.shape) == (b, g, s, d)
+        index = fluid.layers.data("index", [b, s, s],
+                                  append_batch_size=False)
+        admit = fluid.layers.data("admit", [b, s, s], dtype="int8",
+                                  append_batch_size=False)
+        kl = fluid.layers.index_kl(qp, kp, lse, index, admit, d ** -0.5)
+        assert tuple(kl.shape) == (b, s)
+        return [out, qp, kp, lse, kl]
+
+    def not_asked(q, k, v):
+        return [fluid.layers.fused_multihead_attention(q, k, v, **call)]
+
+    before = profiler.counters()
+    out, qp, kp, lse, kl, *grads = _run_qkv(
+        _qkv_program(b, s, h, g, d, asked), feed, weights)
+    after = profiler.counters()
+    want_out, *want_grads = _run_qkv(
+        _qkv_program(b, s, h, g, d, not_asked), feed, weights)
+    fused = 2 if path == "flash" and d % 128 == 0 else 0
+    assert after.get("attn_qk_prep_handed_back", 0) - before.get(
+        "attn_qk_prep_handed_back", 0) == fused
+    assert after.get("attn_qk_prep_rope_only", 0) - before.get(
+        "attn_qk_prep_rope_only", 0) == (0 if normed else fused)
+    # the kernels' outputs to the bit; where XLA prepares, it fuses the
+    # preparation otherwise once its result is an output too
+    tol = dict(rtol=0, atol=0) if fused else dict(rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(out, want_out, **tol)
+    for a, w in zip(grads, want_grads):
+        np.testing.assert_allclose(a, w, **tol)
+
+    def prepared(t, w):
+        t = jnp.asarray(t).reshape(b, s, -1, d)
+        if normed:
+            t = rms_norm(t, jnp.asarray(weights[w]), eps, 3)
+        return np.transpose(rotate_half(t, theta), (0, 2, 1, 3))
+
+    # outside a jit XLA folds the frequencies another way (5e-4 rad at
+    # position 8,191; here 96 positions)
+    np.testing.assert_allclose(qp, prepared(feed["q"], "qn"), atol=5e-5)
+    np.testing.assert_allclose(kp, prepared(feed["k"], "kn"), atol=5e-5)
+    assert np.isfinite(kl).all() and kl.max() > 0
+    rows = index_kl_rows(
+        *(jnp.transpose(jnp.asarray(t), (0, 2, 1, 3)) for t in (qp, kp)),
+        jnp.asarray(lse), jnp.asarray(feed["index"]),
+        jnp.asarray(feed["admit"]), d ** -0.5)
+    np.testing.assert_allclose(kl, rows, rtol=1e-5, atol=1e-6)
+
+
+def test_a_loss_on_the_prepared_pair_reaches_no_input():
+    """QPrepared and KPrepared carry no gradient, as Lse does not: a loss
+    made of them alone leaves q and k without one."""
+    import paddle_tpu as fluid
+
+    b, s, h, d = 1, 16, 2, 64
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        q = fluid.layers.data("q", [b, s, h, d], append_batch_size=False)
+        q.stop_gradient = False
+        _, qp, kp = fluid.layers.fused_multihead_attention(
+            q, q, q, causal=True, layout="bshd", rope_theta=1e4,
+            return_prepared=True)
+        loss = fluid.layers.reduce_sum(fluid.layers.elementwise_add(qp, kp))
+        assert fluid.backward.calc_gradient(loss, [q]) == [None]

@@ -141,7 +141,9 @@ SUITE = Suite(  # noqa: F405
     amp_loss_room=1,
     on_gradients=_split_gradients,
     seed=60001,
-    step_counters=("attn_dispatch_flash", "moe_dispatch_grouped",
+    step_counters=("attn_dispatch_flash", "attn_qk_prep_fused",
+                   "attn_qk_prep_handed_back", "attn_qk_prep_rope_only",
+                   "moe_dispatch_grouped",
                    "moe_dispatch_gmm", "moe_route_softmax",
                    "sparse_attn_layers", "attn_pairs_admitted",
                    "attn_pairs_causal"),
@@ -547,13 +549,18 @@ def test_the_kernels_declare_the_admitted_pairs_and_the_operands_bytes(
         "flash_fwd", q, kk, None, masks, (s, s, d, d), admit, k).flops
 
 
+@pytest.mark.parametrize("head_dim", [None, 128],
+                         ids=["rehearsal_heads", "heads_of_128"])
 def test_the_path_the_chip_takes_in_the_interpreter_is_the_reference(
-        attn_path, monkeypatch):
+        attn_path, monkeypatch, head_dim):
     """One 512-token row with the interpreter on and the attention op on
     its flash path: the four kernels of `ops/pallas/sparse_index.py` and
     the flash kernels with their admission and their log-sum-exp rows, as
     on the chip; the block's output and the indexer's loss against the
-    reference's, and the counters that say the kernels ran."""
+    reference's, and the counters that say the kernels ran. At the
+    published 128 lanes a head q and k are normed and turned by `qk_prep`
+    and `index_kl` reads the pair it hands back; at the rehearsal's 64
+    the `jnp` preparation's, transposed."""
     import paddle_tpu as fluid
     from paddle_tpu import profiler
     from paddle_tpu.models import decoder_parts
@@ -561,6 +568,9 @@ def test_the_path_the_chip_takes_in_the_interpreter_is_the_reference(
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
     attn_path("flash")
     model, _ = SUITE.cell(**AS_AT_WIDTH)
+    if head_dim:  # and the published sections of its 64 frequencies
+        model.update(head_dim=head_dim, rope_scaling={
+            **model["rope_scaling"], "mrope_section": [16, 24, 24]})
     cfg = adapter.config(model)
     b, s = 1, 512
     u = fluid.layers.data("u", [b, s, cfg.hidden_size],
@@ -578,6 +588,15 @@ def test_the_path_the_chip_takes_in_the_interpreter_is_the_reference(
     for name in ("sparse_index_kernel_calls", "index_kl_kernel_calls",
                  "attn_dispatch_flash"):
         assert c1.get(name, 0) - c0.get(name, 0) == 1, name
+    # 64 lanes a head at the rehearsal's widths: `qk_prep` refuses them
+    for name in ("attn_qk_prep_fused", "attn_qk_prep_handed_back"):
+        assert c1.get(name, 0) - c0.get(name, 0) == (head_dim == 128), name
+    assert c1.get("attn_qk_prep_rope_only", 0) == c0.get(
+        "attn_qk_prep_rope_only", 0)
+    ops = [op.type for op in
+           fluid.default_main_program().global_block().ops]
+    assert ops.count("rotary_embedding") == 2  # the indexer's own
+    assert ops.count("rms_norm") == 0 and ops.count("transpose2") == 0
     want, want_kl, kept = highest(
         adapter.sparse_attention, state(names), feed["u"], "m", model,
         _positions(b, s))

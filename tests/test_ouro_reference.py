@@ -108,7 +108,8 @@ SUITE = Suite(  # noqa: F405
            **{w: caught(amp=0, wrong=(w,)) for w in OF_THE_LOSS}},  # noqa: F405
     reading_more=_by_step, seed=57001, chip_routed=(None, None),
     step_counters=("attn_dispatch_flash", "attn_dispatch_xla",
-                   "attn_qk_prep_fused", "param_grads_summed",
+                   "attn_qk_prep_fused", "attn_qk_prep_rope_only",
+                   "param_grads_summed",
                    "param_grad_partials", "rms_bwd_calls"),
     gauges=("loop_steps", "loop_layers", "loss_terms", "attn_kv_group",
             "flash_blocks_visited", "flash_blocks_total"))
@@ -436,19 +437,45 @@ def test_gauges_and_counters_at_the_rehearsal_size(monkeypatch):
     # (`falls` prints the counters there)
     assert bumped("attn_dispatch_xla") == 2 * STEPS * layers_held
     assert bumped("attn_dispatch_flash") == 0
-    assert bumped("attn_qk_prep_fused") == 0  # no QK-norm to share a pass
+    # `qk_prep` runs where the flash kernels do: without weights on the
+    # chip (`attn_qk_prep_rope_only`), not at all here
+    assert bumped("attn_qk_prep_fused") == 0
+    assert bumped("attn_qk_prep_rope_only") == 0
     # 64 lanes and no Pallas here: the norms' gradients are the vjp's (100
     # sites take `rms_bwd` at the cell's width on the chip)
     assert bumped("rms_bwd_calls") == 0
     ops = [op.type for op in main.global_block().ops]
     assert ops.count("fused_multihead_attention") == STEPS * layers_held
-    assert ops.count("rotary_embedding") == 2 * STEPS * layers_held
+    # the positions are the attention op's, with no norm in front of them
+    assert ops.count("rotary_embedding") == 0
+    calls = [op for op in main.global_block().ops
+             if op.type == "fused_multihead_attention"]
+    assert all(op.attr("rope_theta") == model["rope_theta"]
+               and not op.input("QNorm") for op in calls)
     assert ops.count("softmax_with_cross_entropy") == STEPS
     assert ops.count("lookup_table") == 1
     # and no counter that is another decoder's
     for other in ("moe_dispatch_grouped", "attn_rope_scaled",
                   "short_conv_linear_calls", "ssm_dispatch_chunked"):
         assert bumped(other) == 0, other
+
+
+def test_attention_through_the_flash_kernels_and_qk_prep_without_a_norm(
+        monkeypatch, attn_path):
+    """The path the chip takes, interpreted and forced by name since the
+    CPU's dispatch never chooses it: at a head of 128 lanes the positions
+    and the head-major write are `qk_prep`'s pass without weights, in
+    front of the blocked kernel, and the mixer is the reference's."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    attn_path("flash")
+    m = SUITE.mixer("attention", batch=1, seq=160, seed=2,
+                    config={"head_dim": 128, **AS_AT_WIDTH})
+    for name in ("attn_dispatch_flash", "attn_qk_prep_fused",
+                 "attn_qk_prep_rope_only"):
+        assert m.bumped(name) == 1, name
+    assert m.bumped("attn_qk_prep_handed_back") == 0
+    assert rel(m.got, m.want()) < 2e-5
+    assert rel(m.got, m.want(("no_rope",))) > 0.05
 
 
 def test_a_model_with_every_weight_used_once_counts_nothing():

@@ -6,6 +6,7 @@ on four of the suite's eight virtual CPU devices."""
 
 import contextlib
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -341,59 +342,74 @@ def test_kda_kernels_compile_for_a_v5e_chip_at_the_published_widths(
     (500000.0, (16.0, 8192.0, 32.0, 1.0, 1.2772588722239782))],
     ids=["no_positions", "rope", "yarn"])
 def test_qk_prep_kernels_compile_for_a_v5e_chip_at_the_published_widths(
-        topo, theta, scaling):
+        topo, monkeypatch, theta, scaling):
     """The kernel pair between the projections and the flash kernels
     (ops/pallas/qk_prep.py) at the shape of the cells that run it: one
     8,192-token row, 32 query heads over 4 key/value heads of 128, bf16 in
     and out; Trinity's full layer's call and its window layer's, and
     Mellum's full layer's, whose tables are YaRN's (the same kernels: the
     tables are inputs). Nothing runs."""
-    from jax.sharding import SingleDeviceSharding
-
-    from paddle_tpu.ops.pallas import qk_prep
-
-    chip = SingleDeviceSharding(topo.devices[0])
-    b, s, h, g, d = 1, 8192, 32, 4, 128
-    bf16 = jnp.dtype(jnp.bfloat16)
-    statics = (h, g, 1e-5, theta, qk_prep.ROWS, bf16, bf16, scaling, 0,
-               False)
-    text, memory = _qk_prep_compiled(chip, statics, b, s, h, g, d)
+    text, memory = _qk_prep_compiled(
+        topo, monkeypatch, 1, 8192, 32, 4, 128, epsilon=1e-5, theta=theta,
+        scaling=scaling)
     assert "qk_prep_fwd" in text and "qk_prep_bwd" in text
     # q, k, v in, out and back, and nothing float32 of their size between
     assert "f32[1,8192" not in text and "f32[1,32,8192" not in text
     assert memory.temp_size_in_bytes < 1 << 20
 
 
-def _qk_prep_compiled(chip, statics, b, s, h, g, d):
-    from paddle_tpu.ops.pallas import qk_prep
+def _qk_prep_compiled(topo, monkeypatch, b, s, h, g, d, normed=True, **kw):
+    """`qk_prep` and its backward on bf16 operands, compiled for the
+    topology's first chip as the chip would (no interpreter)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas.qk_prep import qk_prep
+
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET")
+    monkeypatch.setattr(importlib.import_module(
+        "paddle_tpu.ops.pallas.flash_attention"), "_use_pallas", lambda: True)
+    chip = SingleDeviceSharding(topo.devices[0])
 
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    def both(q, k, v, wq, wk):
-        o, pull = jax.vjp(lambda *a: qk_prep._core(*a, statics),
-                          q, k, v, wq, wk)
+    def both(*a):
+        # flat as the projections leave them, cut into heads on the way in
+        o, pull = jax.vjp(lambda q, k, v, *w: qk_prep(
+            *(t.reshape(b, s, -1, d) for t in (q, k, v)), *w, **kw), *a)
         return o, pull(o)
 
     compiled = jax.jit(both).lower(
         sds((b, s, h * d)), sds((b, s, g * d)), sds((b, s, g * d)),
-        sds((d,), jnp.float32), sds((d,), jnp.float32)).compile()
+        *[sds((d,), jnp.float32)] * (2 if normed else 0)).compile()
     return compiled.as_text(), compiled.memory_analysis()
 
 
+def test_qk_prep_kernels_compile_for_a_v5e_chip_without_a_norm(
+        topo, monkeypatch):
+    """Ouro's call: one 4,096-token row, 16 query heads over 16 key/value
+    heads of 128, positions and no QK-norm, bf16 in and out. The backward
+    reads the three cotangents and the tables alone: its custom call has
+    five operands, and no weight's partial sums come out. Nothing runs."""
+    text, memory = _qk_prep_compiled(topo, monkeypatch, 1, 4096, 16, 16, 128,
+                                     normed=False, theta=10000.0)
+    assert "qk_prep_fwd" in text and "qk_prep_bwd" in text
+    assert "f32[1,4096,2048]" not in text and "f32[1,16,4096" not in text
+    (bwd,) = re.findall(r"qk_prep_bwd[.\d]* = \((.*?)\) custom-call\((.*?)\),",
+                        text)
+    assert bwd[0].count("bf16[1,4096,2048]") == 3 and "f32" not in bwd[0]
+    assert len(bwd[1].split(", ")) == 5  # nothing of the forward's
+    assert memory.temp_size_in_bytes < 5 << 20  # the two tables
+
+
 def test_qk_prep_kernels_compile_for_a_v5e_chip_with_a_part_of_the_head_turned(
-        topo):
+        topo, monkeypatch):
     """Qwen3-Next's call: one 4,096-token row, 16 query heads over 2
     key/value heads of 256 lanes of which the first 64 turn, two rolls a
     block and three tables. Nothing runs."""
-    from jax.sharding import SingleDeviceSharding
-
-    from paddle_tpu.ops.pallas import qk_prep
-
-    chip = SingleDeviceSharding(topo.devices[0])
-    bf16 = jnp.dtype(jnp.bfloat16)
-    statics = (16, 2, 1e-6, 1e7, qk_prep.ROWS, bf16, bf16, None, 64, False)
-    text, memory = _qk_prep_compiled(chip, statics, 1, 4096, 16, 2, 256)
+    text, memory = _qk_prep_compiled(
+        topo, monkeypatch, 1, 4096, 16, 2, 256, epsilon=1e-6, theta=1e7,
+        rotary_dim=64)
     assert "qk_prep_fwd" in text and "qk_prep_bwd" in text
     assert "f32[1,4096,4096]" not in text and "f32[1,16,4096" not in text
     # the three [4096, 256] float32 tables, 4 MB each, and the weights'

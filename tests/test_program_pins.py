@@ -16,8 +16,10 @@ re-takes only the rows it means to change. These are PR 55's parent's
 (commit 006eebc), but for the nine cells that lower `rms_norm`: PR 59 gave
 the op a gradient op of its own (`rms_norm_grad` where `__auto_grad__`
 stood, one a norm), and their rows are that PR's own tree's.
-`keye_vl2_ep16_s8192`'s row is PR 60's own tree's, the PR that added the
-cell and the three op types at `OPS`' end, which no other cell has."""
+PR 60 added `keye_vl2_ep16_s8192` and the three op types at `OPS`' end,
+which no other cell has. Its row and `ouro_2p6b_vp8_s4096`'s are PR 61's
+own tree's: that PR took the positions of both and the QK-norm of Keye's
+into `fused_multihead_attention`; the thirteen other rows stood."""
 
 import hashlib
 import json
@@ -96,15 +98,16 @@ PINS = {
         {"fused_multihead_attention": 1, "short_conv1d": 2,
          "ssd_scan": 2, "moe_experts": 2, "rms_norm_grad": 10},
         ("norm_eps", "expert_form",)),
-    # PR 57's own tree: the cell it adds
+    # PR 61's own tree: the two cells whose q and k it moved into the
+    # attention op (Ouro's 16 `rotary_embedding` ops and their gradient
+    # ops gone; of Keye's 8 the indexer's 4 left, its 4 QK-norms gone)
     "ouro_2p6b_vp8_s4096": (
-        760, "8797738d31c12cba",
-        {"fused_multihead_attention": 8, "rotary_embedding": 16,
-         "rms_norm_grad": 36}, ()),
+        712, "4f1654fba37bd39b",
+        {"fused_multihead_attention": 8, "rms_norm_grad": 36}, ()),
     "keye_vl2_ep16_s8192": (
-        282, "eb6ce5ca205785b7",
-        {"fused_multihead_attention": 2, "rotary_embedding": 8,
-         "moe_experts": 2, "rms_norm_grad": 9, "sparse_index": 2,
+        258, "5708863fa152acb3",
+        {"fused_multihead_attention": 2, "rotary_embedding": 4,
+         "moe_experts": 2, "rms_norm_grad": 5, "sparse_index": 2,
          "sparse_select": 2, "index_kl": 2}, ()),
 }
 

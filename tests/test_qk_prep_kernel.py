@@ -66,7 +66,7 @@ def _both(fn, args, cotangents):
 
     with jax.default_matmul_precision("highest"):
         return (jax.jit(fn)(*args),
-                jax.jit(jax.grad(loss, argnums=range(5)))(*args))
+                jax.jit(jax.grad(loss, argnums=range(len(args))))(*args))
 
 
 # b, s, heads, key/value heads, d, rows a block
@@ -291,3 +291,160 @@ def test_declared_cost_against_a_count_by_hand(kernel, theta):
     assert numbers(got) == (
         ((11 if backward else 4) + (3 if rope else 0)) * normed * d,
         normed, moved)
+
+
+# ------------------------------------------------- without a norm (PR 61)
+
+
+def turned(q, k, v, theta, scaling=None, lanes=0):
+    """`rotate_half` and a transpose in `jnp`, float32 throughout."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.nn_ops import rotate_half
+
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    if theta:
+        q, k = (rotate_half(t, theta, scaling, lanes) for t in (q, k))
+    return tuple(jnp.transpose(t, (0, 2, 1, 3)) for t in (q, k, v))
+
+
+YARN = (8.0, 4096.0, 32.0, 1.0, 1.2079441541679836)
+
+
+@pytest.mark.parametrize("b,s,h,g,d,rows,kw", [
+    pytest.param(1, 96, 16, 16, 128, 32, {}, id="16_over_16"),
+    pytest.param(1, 64, 32, 4, 128, 64, {}, id="32_over_4"),
+    pytest.param(2, 200, 4, 2, 128, 64, {},
+                 id="rows_not_a_multiple_of_the_block"),
+    pytest.param(1, 8192, 2, 1, 128, 1024, {}, id="position_8191"),
+    pytest.param(1, 96, 4, 2, 128, 32, {"scaling": YARN}, id="yarn"),
+    pytest.param(1, 96, 2, 1, 256, 32, {"rotary_dim": 64},
+                 id="a_quarter_of_256_turned"),
+    pytest.param(2, 200, 4, 2, 128, 64, {"rotary_dim": 32},
+                 id="a_quarter_of_128_turned"),
+    pytest.param(1, 50, 2, 1, 128, 1024, {"theta": 0.0}, id="only_moved"),
+])
+def test_without_weights_it_turns_and_moves_and_so_does_its_backward(
+        b, s, h, g, d, rows, kw):
+    """No norm: forward `rotate_half` and the transpose, backward the
+    rotation by the negative angle of the cotangents alone, against
+    `jax.grad` of the `jnp` chain in float32."""
+    from paddle_tpu.ops.pallas.qk_prep import qk_prep
+
+    kw = {"theta": THETA, **kw}
+    (*acts, _, _), cotangents = _args(b, s, h, g, d, "float32")
+    got, got_grads = _both(
+        lambda *a: qk_prep(*a, rows=rows, **kw), acts, cotangents)
+    want, want_grads = _both(
+        lambda *a: turned(*a, kw["theta"], kw.get("scaling"),
+                          kw.get("rotary_dim", 0)), acts, cotangents)
+    for name, a, w in zip("qkv", got, want):
+        assert a.shape == w.shape and a.dtype == w.dtype
+        assert rel(a, w) < 1e-5, name
+    np.testing.assert_array_equal(got[2], want[2])  # v is only moved
+    for name, a, w in zip(("dq", "dk", "dv"), got_grads, want_grads):
+        assert a.shape == w.shape and a.dtype == w.dtype
+        assert rel(a, w) < 1e-5, name
+    if not kw["theta"]:  # and q and k are
+        np.testing.assert_array_equal(got[0], want[0])
+
+
+def test_without_weights_bf16_is_one_rounding_from_the_float32_chain():
+    """Ouro's call under AMP, the row cut to 256: bf16 in and out, float32
+    inside; the gradients come back in bf16, q's and k's each in its own
+    dtype."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.qk_prep import qk_prep
+
+    (*acts, _, _), cotangents = _args(1, 256, 16, 16, 128, jnp.bfloat16,
+                                      seed=1)
+    got, got_grads = _both(lambda *a: qk_prep(*a, theta=THETA, rows=128),
+                            acts, cotangents)
+    want, want_grads = _both(lambda *a: turned(*a, THETA), acts, cotangents)
+    for a, w in zip(got, want):
+        assert a.dtype == jnp.bfloat16
+        assert rel(a.astype(jnp.float32), w) < 2.0 ** -8
+    for a, w in zip(got_grads, want_grads):
+        assert a.dtype == w.dtype == jnp.bfloat16
+        assert rel(a.astype(jnp.float32), w.astype(jnp.float32)) < 2.0 ** -7
+    mixed = [acts[0].astype(jnp.float32), *acts[1:]]
+    _, grads = _both(lambda *a: qk_prep(*a, theta=THETA, rows=128,
+                                         out_dtype=jnp.bfloat16),
+                      mixed, cotangents)
+    assert [g.dtype for g in grads] == [jnp.float32, jnp.bfloat16,
+                                        jnp.bfloat16]
+
+
+def test_one_weight_alone_is_refused():
+    from paddle_tpu.ops.pallas.qk_prep import qk_prep
+
+    args, _ = _args(1, 32, 2, 1, 128, "float32")
+    with pytest.raises(ValueError, match="together"):
+        qk_prep(*args[:4], None, epsilon=EPS)
+
+
+@pytest.mark.parametrize("lanes", [0, 64], ids=["whole_head", "a_part"])
+def test_without_weights_the_backward_reads_and_declares_no_forward_operand(
+        lanes):
+    """The custom-vjp keeps nothing: `qk_prep_bwd`'s operands are the
+    three cotangents and the tables, its results the three gradients;
+    both calls declare the turning's FLOPs alone (3 an element of q and
+    k, 5 where a part turns), no rsqrt, q, k and v once in and once out
+    and the tables."""
+    import jax
+    import jax.numpy as jnp
+    from pallas_costs import declared, numbers, operand_shapes
+
+    from paddle_tpu.ops.pallas.qk_prep import qk_prep
+
+    b, s, h, g, d, rows = 2, 200, 4, 1, 256, 64
+    (*acts, _, _), cotangents = _args(b, s, h, g, d, jnp.bfloat16)
+
+    def fn(*a):
+        out, pull = jax.vjp(lambda *a: qk_prep(
+            *a, theta=THETA, rows=rows, rotary_dim=lanes), *a)
+        return pull(tuple(c.astype(jnp.bfloat16) for c in cotangents))
+
+    tables = [(s, d)] * (3 if lanes else 2)
+    flat = [(b, s, n * d) for n in (h, g, g)]
+    major = [(b, n, s, d) for n in (h, g, g)]
+    shapes = operand_shapes(fn, *acts)
+    assert shapes["qk_prep_fwd"] == [(flat + tables, major)]
+    assert shapes["qk_prep_bwd"] == [(major + tables, flat)]
+    found = declared(fn, *acts)
+    qkv = 2 * b * s * (h + 2 * g) * d  # bytes of q, k and v together
+    for name in ("qk_prep_fwd", "qk_prep_bwd"):
+        assert numbers(found[name][0]) == (
+            (5 if lanes else 3) * b * s * (h + g) * d, 0,
+            2 * qkv + len(tables) * 4 * s * d)
+
+
+def test_the_normed_modes_jaxpr_at_trinitys_shape_is_the_parents():
+    """What PR 61 added is chosen by the weights' absence: with weights,
+    at the shape of Trinity's, Mellum's and Keye's calls (32 query heads
+    over 4 key/value heads of 128, 8,192 tokens, bf16), the pair under
+    `jax.vjp` traces the equations PR 61's parent (commit f0576ce) traced,
+    the full layer's and the window layer's; taken by running this test's
+    body against that commit (`pallas_costs.jaxpr_digest`)."""
+    import jax
+    import jax.numpy as jnp
+    from pallas_costs import jaxpr_digest
+
+    from paddle_tpu.ops.pallas.qk_prep import qk_prep
+
+    b, s, h, g, d = 1, 8192, 32, 4, 128
+    shapes = [jax.ShapeDtypeStruct((b, s, n, d), jnp.bfloat16)
+              for n in (h, g, g)] + [jax.ShapeDtypeStruct((d,), jnp.float32)] * 2
+
+    def pair(theta):
+        def fn(*a):
+            out, pull = jax.vjp(lambda *a: qk_prep(
+                *a, epsilon=EPS, theta=theta), *a)
+            return out, pull(out)
+        return jaxpr_digest(fn, *shapes)
+
+    assert (pair(0.0), pair(THETA)) == PARENTS
+
+
+PARENTS = ("dbf91ca1f6a7a973", "5b2ea252ef69473c")
