@@ -804,7 +804,7 @@ def ssd_scan(x, dt, b, c, num_heads, n_groups=1, chunk_size=128,
 
 def kda_attention(q, k, v, g, beta, num_heads, l2norm_epsilon=1e-6,
                   a_log_attr=None, dt_bias_attr=None, name=None,
-                  num_key_heads=None):
+                  num_key_heads=None, beta_scale=1.0):
     """The gated delta rule over one sequence a row (ops/linear_attn_ops.py
     has the equations): Kimi Delta Attention with q, k, g [b, s, h*dk], a
     decay a channel, or Gated DeltaNet with g [b, s, h], a decay a head;
@@ -812,7 +812,8 @@ def kda_attention(q, k, v, g, beta, num_heads, l2norm_epsilon=1e-6,
     of h, q and k are [b, s, h_k*dk] and value head n reads key head
     n // (h / h_k). `q` and `k` are L2-normalised per head, `g` goes
     through `-exp(A_log) * softplus(g + dt_bias)` to the log of the decay
-    and `beta` through a sigmoid, all in float32 inside the op; the
+    and `beta` through a sigmoid times `beta_scale` (2: beta in (0, 2), the
+    public `allow_neg_eigval`), all in float32 inside the op; the
     output is scaled by `dk^-1/2` and the state is zero at the start of a
     row. Creates `A_log` [h] and `dt_bias`, as wide as g. Returns
     [b, s, h*dv]."""
@@ -832,7 +833,8 @@ def kda_attention(q, k, v, g, beta, num_heads, l2norm_epsilon=1e-6,
         {"num_heads": num_heads, "l2norm_epsilon": l2norm_epsilon,
          # on a Program with a key head a value head the op is as it was
          **({"num_key_heads": int(num_key_heads)}
-            if num_key_heads and num_key_heads != num_heads else {})},
+            if num_key_heads and num_key_heads != num_heads else {}),
+         **({"beta_scale": float(beta_scale)} if beta_scale != 1.0 else {})},
         dtype=v.dtype, shape=v.shape)
 
 

@@ -7,8 +7,11 @@ expert-parallel decoder; Nemotron-H's mixers hold a share of their heads
 too), Keye-VL-2.0's language model (a share of an expert-parallel
 decoder whose attention keeps the keys a learned indexer chooses),
 Phi-4-mini-flash (a pipeline stage's share of a
-decoder-hybrid-decoder), and Ouro (a pipeline stage's share of a decoder
-that runs its layers several times with one set of weights)."""
+decoder-hybrid-decoder), Ouro (a pipeline stage's share of a decoder
+that runs its layers several times with one set of weights), and
+Olmo-Hybrid (a pipeline stage's share of a dense decoder whose mixers are
+Gated DeltaNet layers with key and value heads of two widths and
+attention without positions, every sublayer's output normed)."""
 
 from . import (  # noqa: F401
     bert,
@@ -20,6 +23,7 @@ from . import (  # noqa: F401
     lfm2,
     mellum,
     nemotron_h,
+    olmo_hybrid,
     ouro,
     phi4_flash,
     qwen3_next,
@@ -35,6 +39,7 @@ from .kimi_linear import KimiLinearConfig, build_kimi_linear  # noqa: E402,F401
 from .lfm2 import Lfm2Config, build_lfm2  # noqa: E402,F401
 from .mellum import MellumConfig, build_mellum  # noqa: E402,F401
 from .nemotron_h import NemotronHConfig, build_nemotron_h  # noqa: E402,F401
+from .olmo_hybrid import OlmoHybridConfig, build_olmo_hybrid  # noqa: E402,F401
 from .ouro import OuroConfig, build_ouro  # noqa: E402,F401
 from .phi4_flash import Phi4FlashConfig, build_phi4_flash  # noqa: E402,F401
 from .qwen3_next import Qwen3NextConfig, build_qwen3_next  # noqa: E402,F401

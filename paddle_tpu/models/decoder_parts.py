@@ -1,17 +1,18 @@
 """What the decoders of the zoo (`kimi_linear`, `trinity`, `mellum`,
 `joyai_flash`, `phi4_flash`, `lfm2`, `qwen3_next`, `nemotron_h`, `ouro`,
-`keye_vl2`) build their layers from: projections seeded Normal(0,
-`initializer_range`), with a bias where asked, RMSNorm with a learned
+`keye_vl2`, `olmo_hybrid`) build their layers from: projections seeded
+Normal(0, `initializer_range`), with a bias where asked, RMSNorm with a learned
 weight and LayerNorm with weight and bias, the SiLU-gated feed-forward as
 three products or with gate and up in one, the squared-ReLU feed-forward without a gate
 (`nemotron_h`), attention over grouped key/value heads with or without
-QK-norm and with rotary positions on a head, on its first lanes or not at
-all, latent attention (`kimi_linear`, `joyai_flash`), differential
+QK-norm (a head at a time or over the whole projection) and with rotary
+positions on a head, on its first lanes or not at all, latent attention (`kimi_linear`, `joyai_flash`), differential
 attention (`phi4_flash`), attention that keeps for each query the keys a
 learned indexer scores highest (`keye_vl2`), the double-gated short
 convolution (`lfm2`),
-Gated DeltaNet (`qwen3_next`: the delta rule with a decay a head and key
-heads shared by groups of value heads), the Mamba-2 mixer (`nemotron_h`:
+Gated DeltaNet (`qwen3_next`, `olmo_hybrid`: the delta rule with a decay a
+head, key heads shared by groups of value heads or key and value heads
+of two widths), the Mamba-2 mixer (`nemotron_h`:
 a decay a head and a token, a norm by groups behind the gate), and the
 expert layer that holds a share of the experts, with a shared expert that
 a token may gate, and with experts that may read a latent of the token
@@ -30,6 +31,7 @@ import math
 
 from .. import layers, profiler
 from ..initializer import Normal, Uniform
+from ..ops.linear_attn_ops import delta_rule_lanes
 from ..ops.pallas import cost
 from ..param_attr import ParamAttr
 
@@ -101,23 +103,33 @@ def gated_short_conv(u, cfg, name):
 
 
 def gated_delta_net(u, cfg, name):
-    """Qwen3-Next's linear mixer (Gated DeltaNet, arXiv:2412.06464), u
-    [b, s, hidden] to [b, s, hidden]: `linear_num_key_heads` heads of
-    `linear_key_head_dim` for q and k under `linear_num_value_heads` heads
-    of `linear_value_head_dim` for v and the output gate z, value head n
-    reading key head n // group; `[q ; k ; v ; z] = W_qkvz u` and
+    """Qwen3-Next's and Olmo-Hybrid's linear mixer (Gated DeltaNet,
+    arXiv:2412.06464), u [b, s, hidden] to [b, s, hidden]:
+    `linear_num_key_heads` heads of `linear_key_head_dim` for q and k under
+    `linear_num_value_heads` heads of `linear_value_head_dim` for v and the
+    output gate z (the two widths need not agree: the state of a head is
+    key lanes by value lanes), value head n reading key head n // group;
+    `[q ; k ; v ; z] = W_qkvz u` and
     `[b ; a] = W_ba u`, one number a value head each; q, k and v pass
     together through one causal depthwise convolution of
     `linear_conv_kernel_dim` taps and a SiLU (the filter uniform in
     +-taps^-1/2, no bias); the op `kda_attention` norms q and k, makes
-    `beta = sigmoid(b)` and the head's log decay `-exp(A_log) *
+    `beta = cfg.linear_beta_scale * sigmoid(b)` (absent: 1; 2 is the
+    public `allow_neg_eigval`) and the head's log decay `-exp(A_log) *
     softplus(a + dt_bias)`, and runs the delta rule; each head's output
     is RMS-normed over its lanes with one learned weight of
     `linear_value_head_dim` and multiplied by `SiLU(z)` before `W_out`.
-    Every piece is one of the Program's ordinary ops."""
+    Every piece is one of the Program's ordinary ops. Counters, once a
+    layer built: `delta_rule_lanes_published`, the heads x key lanes x
+    value lanes of the states, and `delta_rule_lanes_computed`, the same
+    over the lanes the lowering this backend will take multiplies
+    (`ops/linear_attn_ops.py::delta_rule_lanes`)."""
     b, s, _ = u.shape
     hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    published, computed = delta_rule_lanes(s, hv, hk, dk, dv, per_head=True)
+    profiler.bump_counter("delta_rule_lanes_published", published)
+    profiler.bump_counter("delta_rule_lanes_computed", computed)
     qkv, z = layers.split(
         proj(u, 2 * hk * dk + 2 * hv * dv, name + ".in_proj_qkvz", cfg),
         [2 * hk * dk + hv * dv, hv * dv], dim=2)
@@ -133,7 +145,8 @@ def gated_delta_net(u, cfg, name):
         q, k, v, a, beta, num_heads=hv, num_key_heads=hk,
         l2norm_epsilon=cfg.l2norm_epsilon,
         a_log_attr=ParamAttr(name=name + ".A_log"),
-        dt_bias_attr=ParamAttr(name=name + ".dt_bias"))
+        dt_bias_attr=ParamAttr(name=name + ".dt_bias"),
+        beta_scale=getattr(cfg, "linear_beta_scale", 1.0))
     o = norm(layers.reshape(o, [b, s, hv, dv]), name + ".norm", cfg, axis=3)
     o = layers.elementwise_mul(layers.reshape(o, [b, s, hv * dv]),
                                layers.swish(z))
@@ -245,7 +258,10 @@ def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
     """Causal attention of `num_attention_heads` query heads over
     `num_key_value_heads` key/value heads of `head_dim`, u [b, s, hidden]
     to [b, s, hidden]: q and k normed over a head's width (one weight of
-    `head_dim` each; not with `qk_norm` False), turned by rotary positions
+    `head_dim` each; not with `qk_norm` False; with `qk_norm`
+    "projection" over the whole projection before the heads are cut, one
+    weight and one statistic over all the heads' lanes, as OLMo's norm:
+    two ordinary `rms_norm` ops), turned by rotary positions
     where `rope_theta` is not 0 (`rope_scaling`: a YaRN group;
     `rotary_dim` not 0: the first `rotary_dim` lanes of a head alone),
     `window` keys wide where it is not 0, and with `gated` the output
@@ -253,16 +269,21 @@ def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
     ones held here, which may be a share of the model's."""
     b, s, _ = u.shape
     h, g, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    q = layers.reshape(proj(u, h * d, name + ".q", cfg), [b, s, h, d])
-    k = layers.reshape(proj(u, g * d, name + ".k", cfg), [b, s, g, d])
-    v = layers.reshape(proj(u, g * d, name + ".v", cfg), [b, s, g, d])
+
+    def heads(which, n):
+        t = proj(u, n * d, f"{name}.{which}", cfg)
+        if qk_norm == "projection" and which != "v":
+            t = norm(t, f"{name}.{which}_norm", cfg)
+        return layers.reshape(t, [b, s, n, d])
+
+    q, k, v = heads("q", h), heads("k", g), heads("v", g)
     if gated:
         gate = layers.sigmoid(proj(u, h * d, name + ".gate", cfg))
     # QK-norm and the positions inside the attention op, where they and
     # the kernel's head-major write are one pass over q and k
     prep = dict(rope_theta=rope_theta, rope_scaling=rope_scaling,
                 rotary_dim=rotary_dim)
-    if qk_norm:
+    if qk_norm is True:
         prep.update(q_norm_attr=ParamAttr(name=name + ".q_norm.w_0"),
                     k_norm_attr=ParamAttr(name=name + ".k_norm.w_0"),
                     qk_norm_epsilon=cfg.rms_norm_eps)

@@ -40,7 +40,9 @@ state the chunk starts from:
     S_C = Diag(exp(G_C)) S_0 + (K * exp(G_C - G))^T U
 
 **Two lowerings of one algorithm**, chosen by what `kda_mixer_core` can
-observe (`ops/pallas/kda_chunk.py::kda_chunk_viable`: heads of 128 lanes
+observe (`ops/pallas/kda_chunk.py::kda_chunk_viable`: heads of 128 lanes,
+or with a decay a head and a key head a value head key heads of up to 128
+and value heads of up to 256 lanes, Olmo-Hybrid's 96 and 192,
 and a backend that runs Pallas kernels, so a TPU, or the interpreter in
 tests): the kernel pair `kda_chunk` of `ops/pallas/kda_chunk.py` (PR 32),
 which holds a chunk and the state in VMEM and reads the `[b, s, h*d]`
@@ -289,29 +291,33 @@ def kda_chunked(q, k, v, g, beta):
 
 
 def _prologue(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps,
-              key_heads=None):
+              key_heads=None, beta_scale=1.0):
     """The float32 part in front of the chunks: q and k L2-normalised per
-    key head, [b, s, h_k, dk], the log decay, beta; v as it arrived,
-    [b, s, h, dv]."""
+    key head, [b, s, h_k, dk], the log decay, beta = `beta_scale` x
+    sigmoid; v as it arrived, [b, s, h, dv]."""
     b, s, _ = q.shape
 
     def heads(t, n=num_heads):
         return t.reshape(b, s, n, -1)
 
     key_heads = key_heads or num_heads
-    return (l2norm(heads(q, key_heads), eps), l2norm(heads(k, key_heads), eps),
-            heads(v), kda_gate(g_raw, a_log, dt_bias, num_heads),
-            jax.nn.sigmoid(beta_raw.astype(jnp.float32)))
+    q, k, v, g, beta = (
+        l2norm(heads(q, key_heads), eps), l2norm(heads(k, key_heads), eps),
+        heads(v), kda_gate(g_raw, a_log, dt_bias, num_heads),
+        jax.nn.sigmoid(beta_raw.astype(jnp.float32)))
+    if beta_scale != 1.0:  # without it the equations are what they were
+        beta = beta_scale * beta
+    return q, k, v, g, beta
 
 
-@functools.partial(jax.checkpoint, static_argnums=(7, 8, 9))
+@functools.partial(jax.checkpoint, static_argnums=(7, 8, 9, 10))
 def _mixer_plain(*args):
     q, k, v, g, beta = _prologue(*args)
     return kda_chunked(q, k, v.astype(jnp.float32), g, beta)
 
 
 def kda_mixer_core(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps,
-                   key_heads=None):
+                   key_heads=None, beta_scale=1.0):
     """From the convolved projections to the heads' outputs, float32
     inside: the L2 norms, the decay, beta, then the chunked delta rule,
     in the Pallas kernels where `kda_chunk_viable` admits the shape and
@@ -322,6 +328,11 @@ def kda_mixer_core(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps,
     [b, s, key_heads*dk] and value head n reads key head n // group
     (gauge `kda_key_group`). With the kernels, gauge `kda_lockstep_chunks`:
     the chunks a grid step holds, whose solves run in lockstep.
+    `beta_scale` 2: beta = 2 sigmoid(b) in (0, 2), so that `I - beta k k^T`
+    has an eigenvalue in (-1, 1) along k (arXiv:2411.12537; the public
+    `allow_neg_eigval`). The chunk's system `I + Diag(beta) A` stays unit
+    lower triangular, so the solve is as exact as before; its entries are
+    up to twice as large.
 
     What the backward keeps. With the kernels: their float32 operands
     (q, k, g: 67 MB each a layer at 4,096 tokens; v stays bf16) and the
@@ -342,21 +353,37 @@ def kda_mixer_core(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps,
         raise ValueError(f"kda_attention: {key_heads} key heads do not "
                          f"divide {num_heads} value heads")
     args = (q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps,
-            key_heads)
-    if g_raw.shape[2] == num_heads:
+            key_heads, beta_scale)
+    per_head = g_raw.shape[2] == num_heads
+    if per_head:
         profiler.bump_counter("kda_decay_per_head")
     profiler.set_counter("kda_key_group", num_heads // key_heads)
     b, s, _ = q.shape
-    if kda_kernel.kda_chunk_viable(s, q.shape[2] // key_heads,
-                                   v.shape[2] // num_heads):
+    dk, dv = q.shape[2] // key_heads, v.shape[2] // num_heads
+    if kda_kernel.kda_chunk_viable(s, dk, dv, num_heads, key_heads, per_head):
         profiler.bump_counter("kda_dispatch_pallas")
-        profiler.set_counter("kda_lockstep_chunks",
-                             kda_kernel.lockstep_chunks(s))
+        held = kda_kernel.layout(num_heads, dk, dv)[0]
+        profiler.set_counter("kda_lockstep_chunks", max(held, 1)
+                             * kda_kernel.lockstep_chunks(s, held))
         o = kda_kernel.kda_chunk(*_prologue(*args))
     else:
         profiler.bump_counter("kda_dispatch_chunked")
         o = _mixer_plain(*args)
     return o.reshape(b, s, -1)
+
+
+def delta_rule_lanes(s, num_heads, key_heads, d_k, d_v, per_head):
+    """(published, computed): heads x key lanes x value lanes of the
+    states of one `kda_attention` over rows of `s` tokens, as the shapes
+    give them, and as the lowering `kda_mixer_core` will take multiplies
+    them: the kernels' whole tiles a head and whole grid steps of heads
+    (`kda_chunk.layout`), the shapes' own on the plain path. It asks the
+    backend, as the lowering will."""
+    published = num_heads * d_k * d_v
+    if kda_kernel.kda_chunk_viable(s, d_k, d_v, num_heads, key_heads,
+                                   per_head):
+        return published, kda_kernel.layout(num_heads, d_k, d_v)[1]
+    return published, published
 
 
 @register_op("kda_attention")
@@ -366,12 +393,14 @@ def _kda_attention(ctx, op):
     [b, s, h*dk] and [h*dk], a decay a channel, or [b, s, h] and [h], a
     decay a head; BetaRaw: [b, s, h] logits; ALog: [h]. Attr `num_heads`
     is h, the value heads; `num_key_heads` (absent: h) is h_k, a divisor
-    of h, and value head n reads key head n // (h / h_k). Out:
-    [b, s, h*dv] in V's dtype. The L2 norm of q and k, the decay and beta
+    of h, and value head n reads key head n // (h / h_k); `beta_scale`
+    (absent: 1) multiplies the sigmoid of BetaRaw. Out: [b, s, h*dv] in
+    V's dtype. The L2 norm of q and k, the decay and beta
     are computed here in float32, whatever the AMP dtype of the inputs."""
     q, k, v = ctx.in_(op, "Q"), ctx.in_(op, "K"), ctx.in_(op, "V")
     out = kda_mixer_core(
         q, k, v, ctx.in_(op, "GRaw"), ctx.in_(op, "BetaRaw"),
         ctx.in_(op, "ALog"), ctx.in_(op, "DtBias"), op.attr("num_heads"),
-        op.attr("l2norm_epsilon", 1e-6), op.attr("num_key_heads", None))
+        op.attr("l2norm_epsilon", 1e-6), op.attr("num_key_heads", None),
+        op.attr("beta_scale", 1.0))
     ctx.out(op, "Out", out.astype(v.dtype))

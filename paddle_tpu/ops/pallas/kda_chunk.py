@@ -101,6 +101,26 @@ dependent 64-row products bounds the kernels either way, and it is not
 built. With a decay a channel and a key head a value head the calls, the
 kernels and their declarations are what they were.
 
+**Heads that are no tile** (PR 63: Olmo-Hybrid's 96 key lanes and 192 value
+lanes; with a decay a head and a key head a value head alone, `gdn_fwd`
+and `gdn_bwd`). A block of 96 lanes is no block Mosaic takes, so a grid
+step holds `step_heads` heads side by side, the fewest whose key lanes and
+value lanes both end on a tile's edge (4: blocks of 384 and 768 lanes of
+the same `[b, s, h*d]` arrays, nothing relaid or padded in HBM), cuts each
+head's lanes out in VMEM and pads them with zeros to whole tiles
+(`_narrow_operands`), and stacks the heads along the rows: `_rows`,
+`_state_free` and the solve's lockstep then run over the step's heads as
+they run over a step's chunks above, each head with a state of its own in
+the scratch (`_fwd_narrow`, `_bwd_narrow`). Zeros in the padded lanes stay
+zeros through every product, lane sum, solve and state, so a head's
+results are the unpadded mathematics; they, the gradients and the chunks'
+states leave at the lanes the head has, and `_cost` declares those. Where
+the heads are no multiple of the step's, the last step's blocks hang over
+the arrays' edge: the empty head slots compute on what lies in VMEM and
+are stored nowhere. With heads of 128 lanes the calls, the kernels and
+their declarations are what they were (`tests/test_gdn_kernel.py` pins
+their jaxprs).
+
 Precision is the op's: every `exp`, mask, sum and the state are float32.
 The cumulative log-decay `G` is summed in the kernel in float32, by
 shifted adds over the chunk's rows (and `dG` back by the same adds
@@ -148,19 +168,64 @@ _NT = ((1,), (1,))  # [m, k] x [n, k]
 _TN = ((0,), (0,))  # [k, m] x [k, n]
 
 
-def kda_chunk_viable(s, d_k, d_v):
-    """The shapes and backends the kernels are built for: a head is one
-    128-lane slice of q, k, g and one of v, and Mosaic (or the
-    interpreter) is there to run them. Any length: rows are padded to
-    whole grid steps."""
-    return s >= 1 and d_k == LANE and d_v == LANE and _use_pallas()
+# The most chunks a grid step may hold where it holds several heads
+# (`step_heads`): what the host lowers grows with them.
+MAX_UNITS = 8
 
 
-def lockstep_chunks(s):
-    """The chunks a grid step of a call over `s` tokens a row holds and
-    solves together: 1 where the row is one chunk and nothing can be
-    hidden (gauge `kda_lockstep_chunks`)."""
-    return min(CHUNKS_PER_STEP, -(-s // CHUNK))
+def step_heads(heads, d_k, d_v):
+    """The heads a grid step holds where a head is no tile: the fewest
+    whose key lanes and whose value lanes both end on a tile's edge, so
+    that the step's blocks are whole tiles of the `[b, s, h*d]` arrays (4
+    at 96 and 192 lanes: 384 and 768), or all of them where they are
+    fewer (a block as wide as its array is any width). Where the heads
+    are no multiple of it the last step's block hangs over the arrays'
+    edge, and what it computes there is stored nowhere."""
+    whole = next(n for n in range(1, LANE + 1)
+                 if n * d_k % LANE == 0 and n * d_v % LANE == 0)
+    return min(whole, heads)
+
+
+def kda_chunk_viable(s, d_k, d_v, heads=None, key_heads=None,
+                     per_head=False):
+    """The shapes and backends the kernels are built for, with Mosaic (or
+    the interpreter) there to run them: a head that is one 128-lane slice
+    of q, k, g and one of v, with a decay a channel or a head and key
+    heads by groups; or, with a decay a head and a key head a value head
+    (`gdn_fwd`, `gdn_bwd` alone), key heads of up to 128 lanes and value
+    heads of up to 256, both multiples of 8 (96 and 192: a head's lanes
+    are cut out of the step's block in VMEM and padded there with zeros to
+    whole tiles), as long as `step_heads` of them are at most `MAX_UNITS`.
+    Any length: rows are padded to whole grid steps."""
+    if s < 1 or not _use_pallas():
+        return False
+    if d_k == LANE and d_v == LANE:
+        return True
+    return bool(per_head and heads and key_heads == heads
+                and d_k % TILE == 0 and d_v % TILE == 0
+                and d_k <= LANE and d_v <= 2 * LANE
+                and step_heads(heads, d_k, d_v) <= MAX_UNITS)
+
+
+def layout(heads, d_k, d_v):
+    """(the heads a grid step holds side by side, 0 where a head is a tile
+    and the step holds one; heads x key lanes x value lanes of the states
+    as the kernels' layout multiplies them: whole tiles a head, and whole
+    grid steps of heads)."""
+    if (d_k, d_v) == (LANE, LANE):
+        return 0, heads * d_k * d_v
+    held = step_heads(heads, d_k, d_v)
+    return held, -(-heads // held) * held * _tiles(d_k) * _tiles(d_v)
+
+
+def lockstep_chunks(s, narrow=0):
+    """The chunks of one head that a grid step of a call over `s` tokens a
+    row holds: 1 where the row is one chunk and nothing can be hidden.
+    `narrow`: the heads the step holds side by side (`step_heads`; 0 where
+    a head is a tile and the step holds one), which share the width of the
+    lockstep. The chunks solved together are this times the heads (gauge
+    `kda_lockstep_chunks`)."""
+    return min(max(1, CHUNKS_PER_STEP // max(narrow, 1)), -(-s // CHUNK))
 
 
 def _product_dtype():
@@ -480,10 +545,12 @@ def _state_free(rows, masks, dtype, backward=False):
     return free
 
 
-@functools.partial(jax.jit, static_argnames=("dtype",))
-def _chunk_fwd(free, St, *, dtype):
+@functools.partial(jax.jit, static_argnames=("dtype", "scale"))
+def _chunk_fwd(free, St, *, dtype, scale):
     """One chunk from the state it starts with, `St` = S^T [dv, dk], and
-    its state-free half: its outputs [C, dv] and the state it leaves."""
+    its state-free half: its outputs [C, dv] and the state it leaves.
+    `scale`: dk^-1/2 of the key lanes the head has, which padded lanes
+    are not."""
     chunk, _, Aq, _, W = free
     c, dk = chunk.qE.shape
     dv = W.shape[1] - dk
@@ -491,7 +558,7 @@ def _chunk_fwd(free, St, *, dtype):
     by_state = _mm(jnp.concatenate([W[:, dv:], chunk.qE], axis=0), St, _NT,
                    dtype)
     U = W[:, :dv] - by_state[:c]
-    o = dk ** -0.5 * (by_state[c:] + _mm(Aq, U, _NN, dtype))
+    o = scale * (by_state[c:] + _mm(Aq, U, _NN, dtype))
     return o, St * chunk.decay_end + _mm(U, chunk.k_end, _TN, dtype)
 
 
@@ -528,9 +595,13 @@ def _chunk_bwd(free, masks, St, dSt, dO, *, dtype):
     # the solve's gradient is the transposed solve
     lam = mm(T, jnp.concatenate([dU, dWk], axis=1), _TN)
     lam_w = jnp.where(masks.below, mm(lam, W, _NT), masks.zero)
-    dbeta = (jnp.sum(lam[:, :dv] * chunk.back.v + lam[:, dv:] * chunk.kE, 1,
-                     keepdims=True)
-             - jnp.sum(lam_w * A, 1, keepdims=True))
+    by_v, by_k = lam[:, :dv] * chunk.back.v, lam[:, dv:] * chunk.kE
+    if by_v.shape == by_k.shape:  # one sum over the lanes, as it was
+        by_w = jnp.sum(by_v + by_k, 1, keepdims=True)
+    else:
+        by_w = (jnp.sum(by_v, 1, keepdims=True)
+                + jnp.sum(by_k, 1, keepdims=True))
+    dbeta = by_w - jnp.sum(lam_w * A, 1, keepdims=True)
     dSt_new = dSt * decay_end + mm(dO_, chunk.qE, _TN) - mm(dU, Wk, _TN)
     dg_end = (jnp.sum(dSt * St, 0, keepdims=True) * decay_end
               + jnp.sum(d_ke * ke, 0, keepdims=True))
@@ -582,33 +653,112 @@ def _operands(q_ref, k_ref, v_ref, g_ref, beta_ref, heads, per_head=False):
     return q, k, v, g, column(beta_ref)
 
 
+# Heads that are no tile (`step_heads`): how many a grid step holds, of
+# how many groups of them a row has, and the key and value lanes a head
+# has in HBM.
+_Narrow = collections.namedtuple("_Narrow", "heads groups dk dv")
+
+
+def _padded(x, rows=None, lanes=None):
+    """`x` with zeros behind it, up to `rows` rows and `lanes` lanes."""
+    if lanes and lanes > x.shape[1]:
+        x = jnp.concatenate(
+            [x, jnp.zeros((x.shape[0], lanes - x.shape[1]), x.dtype)], axis=1)
+    if rows and rows > x.shape[0]:
+        x = jnp.concatenate(
+            [x, jnp.zeros((rows - x.shape[0], x.shape[1]), x.dtype)], axis=0)
+    return x
+
+
+def _tiles(d):
+    """`d` lanes as whole tiles."""
+    return -(-d // LANE) * LANE
+
+
+def _narrow_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, narrow):
+    """`_operands` where a head is no tile and the decay is a head's: the
+    step's heads one under the other, `[heads*R, tiles]` float32, each
+    cut out of the step's block at its own lanes and padded with zeros to
+    whole tiles. A padded key lane of q and k is 0 in every product and
+    every lane sum; a padded value lane of v stays 0 through the solve,
+    the state and the output: what a head computes is the unpadded
+    mathematics. A head past the row's last (the last group's, where the
+    heads are no multiple of the step's) reads what lies in VMEM and
+    takes beta 0; its results are stored nowhere."""
+    first = pl.program_id(0) % narrow.groups * narrow.heads
+
+    def lanes(ref, d):
+        return jnp.concatenate([
+            _padded(ref[0, :, n * d:(n + 1) * d].astype(jnp.float32),
+                    lanes=_tiles(d)) for n in range(narrow.heads)], axis=0)
+
+    def columns(ref):
+        blk = ref[0].astype(jnp.float32)
+        at = _iota(blk.shape, 1)
+        return jnp.concatenate([
+            jnp.sum(jnp.where(at == first + n, blk, 0.0), 1, keepdims=True)
+            for n in range(narrow.heads)], axis=0)
+
+    q, k, v = lanes(q_ref, narrow.dk), lanes(k_ref, narrow.dk), lanes(
+        v_ref, narrow.dv)
+    return (q, k, v, jnp.broadcast_to(columns(g_ref), q.shape),
+            columns(beta_ref))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref, s_ref, *,
-                heads, steps, dtype, per_head=False):
+                heads, steps, dtype, per_head=False, narrow=None):
     @pl.when(pl.program_id(1) == 0)
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
+    refs = (q_ref, k_ref, v_ref, g_ref, beta_ref)
+    if narrow:
+        _fwd_narrow(refs, o_ref, st_ref, s_ref, steps, dtype, narrow)
+        return
     # the rows' arithmetic once over the step's stacked rows; then copies
     # of the chunk and not a loop: a `pl.loop` over four chunks measured
     # 0.8 ms a call slower than four copies (1.3 ms backward)
-    rows = _rows(*_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, heads,
-                            per_head))
+    rows = _rows(*_operands(*refs, heads, per_head))
     free = _state_free(rows, _pair_masks(CHUNK), dtype)
+    scale = q_ref.shape[2] ** -0.5
     for t in range(steps):
         St = st_ref[0, t] = s_ref[...]  # the state the chunk starts from
-        o, s_ref[...] = _chunk_fwd(free[t], St, dtype=dtype)
+        o, s_ref[...] = _chunk_fwd(free[t], St, dtype=dtype, scale=scale)
         o_ref[0, pl.ds(t * CHUNK, CHUNK), :] = o.astype(o_ref.dtype)
+
+
+def _fwd_narrow(refs, o_ref, st_ref, s_ref, steps, dtype, narrow):
+    """The forward grid step over `narrow.heads` heads of `steps` chunks
+    each: the same rows' arithmetic, solves in lockstep and chunks, each
+    head with a state of its own in the scratch, `[heads, dv, dk]` in
+    whole tiles; a head's outputs and states leave at the lanes it has."""
+    dk, dv = narrow.dk, narrow.dv
+    rows = _rows(*_narrow_operands(*refs, narrow))
+    free = _state_free(rows, _pair_masks(CHUNK), dtype)
+    for n in range(narrow.heads):
+        for t in range(steps):
+            St = s_ref[n]
+            st_ref[0, n, t] = St[:dv, :dk]
+            o, s_ref[n] = _chunk_fwd(free[n * steps + t], St, dtype=dtype,
+                                     scale=dk ** -0.5)
+            o_ref[0, pl.ds(t * CHUNK, CHUNK), n * dv:(n + 1) * dv] = (
+                o[:, :dv].astype(o_ref.dtype))
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref, dq_ref,
                 dk_ref, dv_ref, dg_ref, db_ref, ds_ref, *, heads, steps,
-                dtype, per_head=False):
+                dtype, per_head=False, narrow=None):
     @pl.when(pl.program_id(1) == 0)
     def _():
         ds_ref[...] = jnp.zeros_like(ds_ref)
 
-    rows = _rows(*_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, heads,
-                            per_head))
+    refs = (q_ref, k_ref, v_ref, g_ref, beta_ref)
+    if narrow:
+        _bwd_narrow(refs, st_ref, do_ref,
+                    (dq_ref, dk_ref, dv_ref, dg_ref, db_ref), ds_ref, steps,
+                    dtype, narrow)
+        return
+    rows = _rows(*_operands(*refs, heads, per_head))
     masks = _pair_masks(CHUNK)
     free = _state_free(rows, masks, dtype, backward=True)
     dO = q_ref.shape[2] ** -0.5 * do_ref[0].astype(jnp.float32)
@@ -626,6 +776,37 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref, dq_ref,
         wide.append((dg_ref, dg))
     for ref, d in wide:
         ref[0] = d.astype(ref.dtype)
+
+
+def _bwd_narrow(refs, st_ref, do_ref, outs, ds_ref, steps, dtype, narrow):
+    """The reverse sweep's grid step over `narrow.heads` heads (see
+    `_fwd_narrow`): a head's states and cotangents are padded with zeros
+    to whole tiles as they are read, and its gradients leave at the lanes
+    it has."""
+    dq_ref, dk_ref, dv_ref, dg_ref, db_ref = outs
+    dk, dv = narrow.dk, narrow.dv
+    rows = _rows(*_narrow_operands(*refs, narrow))
+    masks = _pair_masks(CHUNK)
+    free = _state_free(rows, masks, dtype, backward=True)
+    parts = [None] * (narrow.heads * steps)
+    for n in range(narrow.heads):
+        dO = dk ** -0.5 * _padded(
+            do_ref[0, :, n * dv:(n + 1) * dv].astype(jnp.float32),
+            lanes=_tiles(dv))
+        for t in reversed(range(steps)):
+            St = _padded(st_ref[0, n, t], _tiles(dv), _tiles(dk))
+            parts[n * steps + t], db_ref[0, n, t], ds_ref[n] = _chunk_bwd(
+                free[n * steps + t], masks, St, ds_ref[n], _cut(dO, t),
+                dtype=dtype)
+    grads = _sweep_tail(parts, per_head=True)
+    span = steps * CHUNK
+    for n in range(narrow.heads):
+        for ref, d, lanes in zip((dq_ref, dk_ref, dv_ref), grads,
+                                 (dk, dk, dv)):
+            ref[0, :, n * lanes:(n + 1) * lanes] = (
+                d[n * span:(n + 1) * span, :lanes].astype(ref.dtype))
+        for t in range(steps):
+            dg_ref[0, n, t] = _as_row(_cut(grads[3], n * steps + t), masks)
 
 
 def _cost(backward, b, s, heads, dk, dv, dtypes, group=1, per_head=False):
@@ -682,10 +863,12 @@ def _cost(backward, b, s, heads, dk, dv, dtypes, group=1, per_head=False):
 
 # What a call is built from beside its operands: the value heads, the
 # chunks a grid step, what a product reads, the interpreter, the unpadded
-# length, the value heads to a key head, and whether the decay is a head's.
+# length, the value heads to a key head, whether the decay is a head's, and
+# the heads a grid step holds where a head is no tile (`step_heads`; 0: a
+# head is a tile and the step holds one).
 _Statics = collections.namedtuple(
-    "_Statics", "heads steps dtype interpret s group per_head",
-    defaults=(1, False))
+    "_Statics", "heads steps dtype interpret s group per_head narrow",
+    defaults=(1, False, 0))
 
 
 def _specs(statics, chunk_of):
@@ -710,6 +893,29 @@ def _specs(statics, chunk_of):
     return head, key_head, shared
 
 
+def _narrow_specs(statics, chunk_of, dk, dv):
+    """`_specs` where a grid step holds `statics.narrow` heads of `dk` and
+    `dv` lanes side by side: (of the step's heads' lanes of a
+    `[b, S, h*d]` array, of the `[rows, h]` block, of an array
+    `[b, h, S/C, ...]` that has `tail` a chunk), and the step's `_Narrow`:
+    grid step (i, j) is row i // groups, group i % groups."""
+    heads, rows, held = statics.heads, statics.steps * CHUNK, statics.narrow
+    groups = -(-heads // held)
+
+    def lanes(d):
+        return pl.BlockSpec((1, rows, held * d), lambda i, j: (
+            i // groups, chunk_of(j), i % groups))
+
+    shared = pl.BlockSpec((1, rows, heads),
+                          lambda i, j: (i // groups, chunk_of(j), 0))
+
+    def by_chunk(*tail):
+        return pl.BlockSpec((1, held, statics.steps, *tail), lambda i, j: (
+            i // groups, i % groups, chunk_of(j), *(0,) * len(tail)))
+
+    return lanes, shared, by_chunk, _Narrow(held, groups, dk, dv)
+
+
 def _names(statics):
     """The pair's names in a trace: a decay a head has its own, so that
     the metrics of one never read the other's events."""
@@ -730,6 +936,8 @@ def _call_fwd(q, k, v, g, beta, *, statics):
     heads, steps, dtype, interpret, s = statics[:5]
     b, S, _ = q.shape
     dk, dv = q.shape[2] * statics.group // heads, v.shape[2] // heads
+    if statics.narrow:
+        return _call_fwd_narrow(q, k, v, g, beta, statics, dk, dv)
     spec, key_spec, shared = _specs(statics, lambda j: j)
 
     return pl.pallas_call(
@@ -753,6 +961,70 @@ def _call_fwd(q, k, v, g, beta, *, statics):
     )(q, k, v, g, beta)
 
 
+def _call_fwd_narrow(q, k, v, g, beta, statics, dk, dv):
+    """`_call_fwd` where a head is no tile: `statics.narrow` heads a grid
+    step, the states `[b, h, S/C, dv, dk]` at the lanes the heads have,
+    and the declaration that of `dk` and `dv` lanes, whatever the tiles
+    in VMEM multiply."""
+    heads, steps = statics.heads, statics.steps
+    b, S, _ = q.shape
+    lanes, shared, by_chunk, narrow = _narrow_specs(statics, lambda j: j, dk,
+                                                    dv)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, heads=heads, steps=steps,
+                          dtype=statics.dtype, per_head=True, narrow=narrow),
+        grid=(b * narrow.groups, S // (steps * CHUNK)),
+        in_specs=[lanes(dk), lanes(dk), lanes(dv), shared, shared],
+        out_specs=[lanes(dv), by_chunk(dv, dk)],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((b, heads, S // CHUNK, dv, dk),
+                                        jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((narrow.heads, _tiles(dv), _tiles(dk)),
+                                   jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=statics.interpret,
+        name=_names(statics)[0],
+        cost_estimate=_cost(False, b, statics.s, heads, dk, dv,
+                            (q.dtype, v.dtype), 1, True),
+    )(q, k, v, g, beta)
+
+
+def _call_bwd_narrow(q, k, v, g, beta, states, do, statics, dk, dv):
+    """`_call_bwd` where a head is no tile (see `_call_fwd_narrow`)."""
+    heads, steps = statics.heads, statics.steps
+    b, S, _ = q.shape
+    last = S // (steps * CHUNK) - 1
+    lanes, shared, by_chunk, narrow = _narrow_specs(
+        statics, lambda j: last - j, dk, dv)
+    rows = jax.ShapeDtypeStruct((b, heads, S // CHUNK, 1, CHUNK), jnp.float32)
+    dq, dk_, dv_, dg, dbeta = pl.pallas_call(
+        functools.partial(_bwd_kernel, heads=heads, steps=steps,
+                          dtype=statics.dtype, per_head=True, narrow=narrow),
+        grid=(b * narrow.groups, last + 1),
+        in_specs=[lanes(dk), lanes(dk), lanes(dv), shared, shared,
+                  by_chunk(dv, dk), lanes(dv)],
+        out_specs=[lanes(dk), lanes(dk), lanes(dv), by_chunk(1, CHUNK),
+                   by_chunk(1, CHUNK)],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype), rows, rows],
+        scratch_shapes=[pltpu.VMEM((narrow.heads, _tiles(dv), _tiles(dk)),
+                                   jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=statics.interpret,
+        name=_names(statics)[1],
+        cost_estimate=_cost(True, b, statics.s, heads, dk, dv,
+                            (q.dtype, v.dtype), 1, True),
+    )(q, k, v, g, beta, states, do)
+
+    def by_token(t):  # [b, h, n, 1, C] -> [b, S, h]
+        return t.reshape(b, heads, S).transpose(0, 2, 1)
+
+    return dq, dk_, dv_, by_token(dg), by_token(dbeta)
+
+
 @functools.partial(jax.jit, static_argnames=("statics",))
 def _call_bwd(q, k, v, g, beta, states, do, *, statics):
     """The reverse sweep: grid step j holds the chunks of step last - j.
@@ -767,6 +1039,8 @@ def _call_bwd(q, k, v, g, beta, states, do, *, statics):
     group = statics.group
     b, S, _ = q.shape
     dk, dv = q.shape[2] * group // heads, v.shape[2] // heads
+    if statics.narrow:
+        return _call_bwd_narrow(q, k, v, g, beta, states, do, statics, dk, dv)
     last = S // (steps * CHUNK) - 1
     spec, key_spec, shared = _specs(statics, lambda j: last - j)
     row = pl.BlockSpec((1, steps, 1, CHUNK), lambda i, j: (i, last - j, 0, 0))
@@ -841,11 +1115,15 @@ def kda_chunk(q, k, v, g, beta):
     require_pallas("kda_chunk")
     b, s, h_k, dk = q.shape
     h, dv = v.shape[2:]
-    if not kda_chunk_viable(s, dk, dv) or h % h_k:
+    per_head = beta.shape == g.shape
+    if not kda_chunk_viable(s, dk, dv, h, h_k, per_head) or h % h_k:
         raise ValueError(
             f"kda_chunk: q {q.shape}, v {v.shape}: needs head widths of "
-            f"{LANE} and key heads that divide the value heads")
-    steps = lockstep_chunks(s)
+            f"{LANE} and key heads that divide the value heads, or a decay "
+            "a head, a key head a value head and the widths "
+            "`kda_chunk_viable` states")
+    narrow = layout(h, dk, dv)[0]
+    steps = lockstep_chunks(s, narrow)
     pad = -s % (steps * CHUNK)
     # heads side by side on the lanes, as the projections write them
     q, k, v, g = (t.reshape(b, s, -1) for t in (q, k, v, g))
@@ -854,6 +1132,6 @@ def kda_chunk(q, k, v, g, beta):
                       for t in (q, k, v, g))
         beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
     o = _core(q, k, v, g, beta, _Statics(
-        h, steps, _product_dtype(), _interpret(), s, h // h_k,
-        beta.shape == g.shape))
+        h, steps, _product_dtype(), _interpret(), s, h // h_k, per_head,
+        narrow))
     return o[:, :s].reshape(b, s, h, dv)

@@ -5,7 +5,12 @@ against the token-a-step recurrence of `benchmark/models/qwen3_next.py`,
 outputs and the five gradients (each side compiled:
 `tests/kernel_cases.py`), with key groups of 2 and at decays down to 0.01
 a token; that nothing is written out in front of the kernels; the
-names, the declaration and the counters."""
+names, the declaration and the counters. Since PR 63 also heads that are
+no tile (Olmo-Hybrid's 96 key and 192 value lanes, the rehearsal's 24 and
+48): the same comparisons with beta in (0, 1) and in (0, 2), a row of
+repeated keys with beta within 1e-3 of 2, what the kernels read and
+declare at the cell's shape, and that the 128/128 calls of Kimi's and
+Qwen3-Next's cells trace the jaxpr they traced before."""
 
 import numpy as np
 import pytest
@@ -67,17 +72,24 @@ REGIMES = [
 @pytest.mark.parametrize("length,g_lo,g_hi,kind", REGIMES)
 def test_kernels_and_plain_path_equal_the_recurrence(
         interpreter, length, g_lo, g_hi, kind):
+    args = _args(length, g_lo, g_hi, parallel=kind == "parallel")
+    assert args[2].shape == (B, length, HV, D)
+    _held_to_the_recurrence(args, recurrence, atol=2e-6)
+
+
+def _held_to_the_recurrence(args, want_fn=ref.delta_recurrence, atol=4e-6):
+    """The kernel pair and the plain path against `want_fn`, the
+    recurrence: outputs to `atol`, the five gradients to 1e-4 of their
+    size."""
     from paddle_tpu.ops.linear_attn_ops import kda_chunked
     from paddle_tpu.ops.pallas.kda_chunk import kda_chunk
 
-    args = _args(length, g_lo, g_hi, parallel=kind == "parallel")
     (got, g_got), (want, g_want), (plain, g_plain) = (
-        value_and_grads(fn, args)
-        for fn in (kda_chunk, recurrence, kda_chunked))
-    assert got.shape == want.shape == (B, length, HV, D)
+        value_and_grads(fn, args) for fn in (kda_chunk, want_fn, kda_chunked))
+    assert got.shape == want.shape == args[2].shape
     assert np.isfinite(got).all()
-    np.testing.assert_allclose(got, want, atol=2e-6)
-    np.testing.assert_allclose(plain, want, atol=2e-6)
+    np.testing.assert_allclose(got, want, atol=atol)
+    np.testing.assert_allclose(plain, want, atol=atol)
     for name, a, w, p, like in zip("q k v g beta".split(), g_got, g_want,
                                    g_plain, args):
         assert a.shape == like.shape == p.shape, name
@@ -279,3 +291,243 @@ def test_the_sweeps_tail_is_each_chunks_own_to_the_bit(chunks, per_head):
     want = dG.reshape(chunks, 64, -1)[:, ::-1].cumsum(1)[:, ::-1]
     np.testing.assert_allclose(np.asarray(together[3]),
                                want.reshape(dG.shape), rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------------- heads that are no tile (PR 63)
+
+
+def _narrow_args(length, h, dk, dv, beta_hi, seed=0, b=B, g=(-2.0, -0.01),
+                 repeated=False, beta_lo=0.0):
+    """`_args` at `h` heads of `dk` key and `dv` value lanes, a key head a
+    value head, beta uniform in (`beta_lo`, `beta_hi`). `repeated`: every
+    token of a head has the same key, so that `A`'s entries are the
+    decays alone and the solve is as far from `I` as it gets."""
+    import jax.numpy as jnp
+
+    r = np.random.RandomState(seed)
+
+    def unit(t):
+        return t / np.linalg.norm(t, axis=-1, keepdims=True)
+
+    k = unit(r.randn(b, 1 if repeated else length, h, dk))
+    return [jnp.asarray(t, jnp.float32) for t in (
+        unit(r.randn(b, length, h, dk)),
+        np.broadcast_to(k, (b, length, h, dk)), r.randn(b, length, h, dv),
+        r.uniform(*g, (b, length, h)),
+        r.uniform(beta_lo, beta_hi, (b, length, h)))]
+
+
+@pytest.mark.parametrize("beta_hi", [1.0, 2.0], ids=["beta1", "beta2"])
+@pytest.mark.parametrize("length,h,dk,dv", [
+    (130, 4, 96, 192),  # the published lanes, one grid step of four heads
+    (100, 6, 96, 192),  # the last step's block hangs over the arrays' edge
+    (70, 4, 24, 48),  # the rehearsal's: every head in one block
+], ids=["96x192", "96x192-six", "24x48"])
+def test_heads_that_are_no_tile_equal_the_recurrence(interpreter, length, h,
+                                                     dk, dv, beta_hi):
+    """Key heads of 96 lanes and value heads of 192 (and 24 and 48), a key
+    head a value head, beta in (0, 1) as `sigmoid` gives it and in (0, 2)
+    as `beta_scale` 2 does: the kernel pair, whose grid step cuts four
+    heads out of whole tiles and pads each with zeros in VMEM, and the
+    plain path against the recurrence on the state `[dk, dv]`, outputs and
+    the five gradients."""
+    _held_to_the_recurrence(_narrow_args(length, h, dk, dv, beta_hi,
+                                         seed=length))
+
+
+@pytest.mark.parametrize("dk,dv", [(96, 192), (128, 128)])
+def test_repeated_keys_with_beta_next_to_2(interpreter, dk, dv):
+    """Where the solve is worst: one key a head for the whole row, decays
+    of 0.9999 a token, so that `A` is all ones below the diagonal, and
+    beta within 1e-3 of 2, where `I - beta k k^T` all but reflects along
+    k and `(I + beta A)^-1` has entries near +-2 in every place. Nothing
+    damps here, so the outputs are twenty times the size they have at
+    beta near 1 (0.2 where 0.01) and the rounding of 64 rows' worth of
+    +-2s shows: against the recurrence in float64 the kernels read 2.6e-5
+    of the outputs' size, the plain path's triangular solve 1.6e-5, the
+    float32 recurrence itself 2.4e-6 (at beta near 1 and over (0, 2) all
+    three read 2e-6 or less). Held to 1e-4 of the outputs' size, and the
+    gradients to 1e-3 of the recurrence's."""
+    args = _narrow_args(192, 4, dk, dv, 2.0, seed=7, g=(-2e-4, -1e-6),
+                        repeated=True, beta_lo=2.0 - 1e-3)
+    from paddle_tpu.ops.pallas.kda_chunk import kda_chunk
+
+    (got, g_got), (want, g_want) = (
+        value_and_grads(fn, args) for fn in (kda_chunk, ref.delta_recurrence))
+    assert np.isfinite(got).all() and np.abs(want).max() > 0.1
+    assert rel(got, want) < 1e-4, rel(got, want)
+    for name, a, w in zip("q k v g beta".split(), g_got, g_want):
+        assert np.isfinite(a).all(), name
+        assert rel(a, w) < 1e-3, (name, rel(a, w))
+
+
+@pytest.mark.parametrize("scale", [1.0, 2.0])
+def test_beta_scale_is_the_ops_on_both_paths(monkeypatch, scale):
+    """`kda_mixer_core(beta_scale=...)` on the plain path and through the
+    kernels against the recurrence with beta = scale x sigmoid(b), from
+    the projections' arrays at 24 and 48 lanes; without the scale the
+    output is another model's."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.linear_attn_ops import kda_mixer_core, l2norm, kda_gate
+
+    b, s, h, dk, dv = 1, 80, 4, 24, 48
+    r = np.random.RandomState(int(scale))
+    q, k, v, a, raw = (jnp.asarray(r.randn(b, s, n), jnp.float32) for n in (
+        h * dk, h * dk, h * dv, h, h))
+    a_log = jnp.asarray(r.uniform(0, 2.7, h), jnp.float32)
+    dt_bias = jnp.asarray(r.uniform(-6.9, -2.25, h), jnp.float32)
+
+    def want(scale):
+        return ref.delta_recurrence(
+            l2norm(q.reshape(b, s, h, dk), 1e-6),
+            l2norm(k.reshape(b, s, h, dk), 1e-6), v.reshape(b, s, h, dv),
+            kda_gate(a, a_log, dt_bias, h),
+            scale * jax.nn.sigmoid(raw)).reshape(b, s, h * dv)
+
+    right, other = compiled(want, scale), compiled(want, 3.0 - scale)
+    assert rel(other, right) > 0.05
+    for interpret in ("", "1"):
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", interpret)
+        got = compiled(lambda *t: kda_mixer_core(
+            *t, h, 1e-6, None, scale), q, k, v, a, raw, a_log, dt_bias)
+        np.testing.assert_allclose(got, right, atol=4e-6)
+
+
+def test_what_the_kernels_admit_and_how_they_lay_the_heads(interpreter):
+    from paddle_tpu.ops.pallas import kda_chunk as kernel
+
+    assert kernel.kda_chunk_viable(4096, 128, 128)
+    # 96 and 192 lanes: a decay a head and a key head a value head
+    assert kernel.kda_chunk_viable(4096, 96, 192, 30, 30, True)
+    assert not kernel.kda_chunk_viable(4096, 96, 192)
+    assert not kernel.kda_chunk_viable(4096, 96, 192, 30, 30, False)
+    assert not kernel.kda_chunk_viable(4096, 96, 192, 30, 15, True)
+    assert not kernel.kda_chunk_viable(4096, 96, 320, 30, 30, True)
+    assert not kernel.kda_chunk_viable(4096, 100, 192, 30, 30, True)
+    # sixteen heads of 24 and 48 lanes end on a tile's edge: too many
+    assert kernel.kda_chunk_viable(4096, 24, 48, 4, 4, True)
+    assert not kernel.kda_chunk_viable(4096, 24, 48, 32, 32, True)
+    assert kernel.step_heads(30, 96, 192) == 4  # 384 and 768 lanes
+    assert kernel.step_heads(2, 96, 192) == 2
+    # eight grid steps of four heads at 128 x 256 lanes for thirty heads
+    assert kernel.layout(30, 96, 192) == (4, 32 * 128 * 256)
+    assert kernel.layout(32, 128, 128) == (0, 32 * 128 * 128)
+    # four heads a step share the lockstep's width: a chunk each
+    assert kernel.lockstep_chunks(4096, 4) == 1
+    assert kernel.lockstep_chunks(4096) == kernel.CHUNKS_PER_STEP
+
+
+def test_the_published_lanes_are_read_written_and_declared(interpreter):
+    """At Olmo-Hybrid's shape, forward and backward of the op's core from
+    the projections' arrays: the kernels read q and k at `[1, 4096, 2880]`
+    and v at `[1, 4096, 5760]` and write the output and the gradients at
+    those widths, and the chunks' states at `[96 x 192]`: no array padded
+    to whole tiles is among their operands or results. The declaration
+    is that of 96 and 192 lanes, whatever the tiles in VMEM multiply."""
+    import jax
+    import jax.numpy as jnp
+    from pallas_costs import block_shapes, declared, numbers, operand_shapes
+
+    from paddle_tpu import profiler
+    from paddle_tpu.ops.linear_attn_ops import kda_mixer_core
+    from paddle_tpu.ops.pallas import kda_chunk as kernel
+
+    b, s, h, dk, dv = 1, 4096, 30, 96, 192
+    sds = jax.ShapeDtypeStruct
+    args = (sds((b, s, h * dk), jnp.bfloat16), sds((b, s, h * dk),
+                                                   jnp.bfloat16),
+            sds((b, s, h * dv), jnp.bfloat16), sds((b, s, h), jnp.bfloat16),
+            sds((b, s, h), jnp.bfloat16), sds((h,), jnp.float32),
+            sds((h,), jnp.float32))
+
+    def both(*a):
+        out, pull = jax.vjp(
+            lambda *a: kda_mixer_core(*a, h, 1e-6, None, 2.0), *a)
+        return pull(out)
+
+    before = profiler.counters()
+    calls = operand_shapes(both, *args)
+    after = profiler.counters()
+    assert set(calls) == {"gdn_fwd", "gdn_bwd"}
+    assert after["kda_dispatch_pallas"] - before.get(
+        "kda_dispatch_pallas", 0) >= 1
+    assert after["kda_lockstep_chunks"] == 4
+    keys, values, heads = (b, s, h * dk), (b, s, h * dv), (b, s, h)
+    states, rows = (b, h, s // 64, dv, dk), (b, h, s // 64, 1, 64)
+    for ins, outs in calls["gdn_fwd"]:
+        assert ins == [keys, keys, values, heads, heads]
+        assert outs == [values, states]
+    ((ins, outs),) = calls["gdn_bwd"]
+    assert ins == [keys, keys, values, heads, heads, states, values]
+    assert outs == [keys, keys, values, rows, rows]
+    # a grid step's blocks: four heads' lanes, whole tiles of the arrays
+    grid, blocks = block_shapes(both, *args)["gdn_fwd"][0]
+    assert grid == (8, 64)
+    assert blocks[:3] == [(1, 64, 384), (1, 64, 384), (1, 64, 768)]
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    found = declared(both, *args)
+    for name, backward in (("gdn_fwd", False), ("gdn_bwd", True)):
+        got = numbers(found[name][0])
+        assert got == numbers(kernel._cost(backward, b, s, h, dk, dv,
+                                           (f32, bf16), 1, True))
+        # under what heads of 128 and 256 lanes would declare
+        padded = numbers(kernel._cost(backward, b, s, h, 128, 256,
+                                      (f32, bf16), 1, True))
+        assert all(x < y for x, y in zip(got, padded))
+    # by hand, forward: q, k float32 and v, o bf16 at the published lanes,
+    # the decay and beta [b, s, 30], the chunks' states
+    moved = (2 * 4 * b * s * h * dk + 2 * 2 * b * s * h * dv
+             + 2 * 4 * b * s * h + 4 * b * h * (s // 64) * dk * dv)
+    assert numbers(found["gdn_fwd"][0])[2] == moved
+
+
+# as the parent of PR 63 (commit 8e345f2) traces them, under jax 0.9.0:
+# `python tests/test_gdn_kernel.py` prints them
+PARENTS_CALLS = {
+    # (b, s, key heads, value heads, a decay a head): the digest
+    (1, 4096, 16, 32, True): "d2c82933b367f763",  # Qwen3-Next's cell
+    (2, 200, 2, 4, True): "715381137a9c880e",
+    (1, 4096, 32, 32, False): "e0630acb73994872",  # Kimi's cell
+    (2, 200, 4, 4, False): "81b5d2be5a520600",
+}
+
+
+def _call_digest(b, s, hk, hv, per_head):
+    import jax
+    import jax.numpy as jnp
+    from pallas_costs import jaxpr_digest
+
+    from paddle_tpu.ops.linear_attn_ops import kda_mixer_core
+
+    sds, d = jax.ShapeDtypeStruct, D
+    wide = hv if per_head else hv * d
+    args = (sds((b, s, hk * d), jnp.bfloat16), sds((b, s, hk * d),
+                                                   jnp.bfloat16),
+            sds((b, s, hv * d), jnp.bfloat16), sds((b, s, wide), jnp.bfloat16),
+            sds((b, s, hv), jnp.bfloat16), sds((hv,), jnp.float32),
+            sds((wide,), jnp.float32))
+
+    def both(*a):
+        out, pull = jax.vjp(lambda *a: kda_mixer_core(*a, hv, 1e-6, hk), *a)
+        return pull(out)
+
+    return jaxpr_digest(both, *args)
+
+
+@pytest.mark.parametrize("call", list(PARENTS_CALLS), ids=str)
+def test_calls_at_heads_of_128_trace_the_jaxpr_they_had(interpreter, call):
+    """What PR 63 added for heads that are no tile is off where a head is
+    one: Kimi's and Qwen3-Next's calls, forward and backward from the
+    projections' arrays, trace equation for equation what the parent
+    commit traced, so the kernels those cells run are the ones they ran."""
+    assert _call_digest(*call) == PARENTS_CALLS[call]
+
+
+if __name__ == "__main__":
+    import os
+
+    os.environ["PADDLE_TPU_PALLAS_INTERPRET"] = "1"
+    for call in PARENTS_CALLS:
+        print(f"    {call!r}: \"{_call_digest(*call)}\",", flush=True)

@@ -337,6 +337,42 @@ def test_kda_kernels_compile_for_a_v5e_chip_at_the_published_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def test_gdn_kernels_compile_for_a_v5e_chip_at_heads_that_are_no_tile(topo):
+    """The pair at Olmo-Hybrid's shape: one 4,096-token row, 30 heads of 96
+    key lanes and 192 value lanes, four heads a grid step (blocks of 384
+    and 768 lanes, the eighth step's hanging over the arrays' edge), each
+    head's lanes cut out and padded to whole tiles in VMEM: Mosaic takes
+    the slices at lanes 96, 192 and 288 and the stores back to them.
+    Nothing runs."""
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas import kda_chunk
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    b, s, h, dk, dv = 1, 4096, 30, 96, 192
+    held = kda_chunk.layout(h, dk, dv)[0]
+    statics = kda_chunk._Statics(h, kda_chunk.lockstep_chunks(s, held),
+                                 jnp.bfloat16, False, s, 1, True, held)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    x, v, g = sds((b, s, h * dk)), sds((b, s, h * dv), jnp.bfloat16), sds(
+        (b, s, h))
+
+    def both(q, k, v, g, beta):
+        o, pull = jax.vjp(
+            lambda *a: kda_chunk._core(*a, statics), q, k, v, g, beta)
+        return o, pull(o)
+
+    compiled = jax.jit(both).lower(x, x, v, g, g).compile()
+    text = compiled.as_text()
+    assert "gdn_fwd" in text and "gdn_bwd" in text and "kda_fwd" not in text
+    # the operands, their gradients and the chunks' states (a state's 96
+    # lanes lie in whole tiles in HBM: 189 MB where 141 are its numbers)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 @pytest.mark.parametrize("theta,scaling", [
     (0.0, None), (10000.0, None),
     (500000.0, (16.0, 8192.0, 32.0, 1.0, 1.2772588722239782))],

@@ -19,7 +19,9 @@ stood, one a norm), and their rows are that PR's own tree's.
 PR 60 added `keye_vl2_ep16_s8192` and the three op types at `OPS`' end,
 which no other cell has. Its row and `ouro_2p6b_vp8_s4096`'s are PR 61's
 own tree's: that PR took the positions of both and the QK-norm of Keye's
-into `fused_multihead_attention`; the thirteen other rows stood."""
+into `fused_multihead_attention`; the thirteen other rows stood. PR 63
+added `olmo_hybrid_7b_vp8_longdoc` (its `kda_attention` ops carry
+`beta_scale`, which no other cell's do: the fifteen other rows stood)."""
 
 import hashlib
 import json
@@ -109,6 +111,11 @@ PINS = {
         {"fused_multihead_attention": 2, "rotary_embedding": 4,
          "moe_experts": 2, "rms_norm_grad": 5, "sparse_index": 2,
          "sparse_select": 2, "index_kl": 2}, ()),
+    # PR 63's own tree: the cell it added
+    "olmo_hybrid_7b_vp8_longdoc": (
+        391, "b77c60bd17d83405",
+        {"fused_multihead_attention": 1, "short_conv1d": 3,
+         "kda_attention": 3, "rms_norm_grad": 14}, ()),
 }
 
 
