@@ -23,10 +23,11 @@ it out in HBM (it is the oracle), the kernels in VMEM, from the
 `[b, s, h]` array (a `[64, 1]` column fills as many registers as
 `[64, 128]`, so nothing is spent on the copies). With grouped key heads
 the kernels' index maps read key head n // group for value head n, so q
-and k stay `[b, s, h_k*dk]` in HBM, are normed once a key head, and the
-backward keeps them at that size (34 MB each a layer at 4,096 tokens and
-16 key heads, where 32 repeated heads would be 67); the kernels write dq
-and dk a value head and XLA adds each group's.
+and k stay `[b, s, h_k*dk]` in HBM as the convolution wrote them, are
+normed in VMEM, and the backward keeps them at that size and dtype
+(17 MB each a layer at 4,096 tokens, 16 key heads and bf16, where 32
+repeated float32 heads would be 67); the kernels write dq and dk a value
+head and XLA adds each group's.
 
 `kda_chunked` computes it chunk by chunk. Inside a chunk of C = 64 tokens, with
 `G_t = sum_{i<=t} log alpha_i` (per channel, <= 0, falling) and `S_0` the
@@ -45,10 +46,13 @@ or with a decay a head and a key head a value head key heads of up to 128
 and value heads of up to 256 lanes, Olmo-Hybrid's 96 and 192,
 and a backend that runs Pallas kernels, so a TPU, or the interpreter in
 tests): the kernel pair `kda_chunk` of `ops/pallas/kda_chunk.py` (PR 32),
-which holds a chunk and the state in VMEM and reads the `[b, s, h*d]`
-arrays as they arrive; and `kda_chunked` below, plain XLA, which runs
-everywhere else (the CPU, the rehearsal's heads of 16) and is the
-kernel's test oracle. No switch selects between them.
+which holds a chunk and the state in VMEM, reads the `[b, s, h*d]`
+arrays as the projections wrote them and, since PR 65, makes the L2
+norms, beta and a head's log decay of them in VMEM (a channel's log
+decay, Kimi's, is XLA's: `kda_gate` in front of the kernels, float32 in
+HBM as before); and `_prologue` and `kda_chunked` below, plain XLA, which
+run everywhere else (the CPU, the rehearsal's heads of 16) and are the
+kernels' test oracle. No switch selects between them.
 
 In `kda_chunked`, `[Wv, Wk]` (the WY representation), `A` and `Aq` depend
 on no state, so they are computed for every chunk at once; only the three
@@ -321,8 +325,13 @@ def kda_mixer_core(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps,
     """From the convolved projections to the heads' outputs, float32
     inside: the L2 norms, the decay, beta, then the chunked delta rule,
     in the Pallas kernels where `kda_chunk_viable` admits the shape and
-    the backend and in `kda_chunked` otherwise; a counter says which, once
-    a lowering (`kda_dispatch_pallas`, `kda_dispatch_chunked`). `g_raw`
+    the backend (they take q, k, beta's logits and a head's decay logits
+    as the projections wrote them and make the norms, beta and that decay
+    in VMEM; a decay a channel alone is gated here, by `kda_gate` in XLA,
+    and handed over as the float32 log decay, which measured faster:
+    `kda_chunk.py`'s docstring), and in `_prologue` and `kda_chunked`
+    otherwise; a counter says which, once a lowering
+    (`kda_dispatch_pallas`, `kda_dispatch_chunked`). `g_raw`
     [b, s, h] is a decay a head (counter `kda_decay_per_head`, once a
     lowering); `key_heads` fewer than `num_heads`: q and k arrive
     [b, s, key_heads*dk] and value head n reads key head n // group
@@ -334,11 +343,18 @@ def kda_mixer_core(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps,
     lower triangular, so the solve is as exact as before; its entries are
     up to twice as large.
 
-    What the backward keeps. With the kernels: their float32 operands
-    (q, k, g: 67 MB each a layer at 4,096 tokens; v stays bf16) and the
-    state each chunk starts from, which `kda_fwd` writes (134 MB a
-    layer); `kda_bwd` rebuilds the chunk's `G`, `A`, `Aq`, `T` and WY
-    factors in VMEM. No `jax.checkpoint` there: it cost 14.5 ms of a
+    What the backward keeps. With the kernels: the op's own inputs as
+    the projections wrote them (q, k, v: 34 MB each a layer at 4,096
+    tokens, 32 heads and bf16; before PR 65 float32 copies of q and k
+    beside them, 67 MB each), of the decay a head's `[b, s, h]` logits
+    or, with a decay a channel, those logits and the float32 log decay
+    `kda_gate` made of them (67 MB, as before PR 65), and the state each
+    chunk starts from, which `kda_fwd` writes (134 MB a layer); `kda_bwd`
+    makes the norms, beta and a head's decay again and rebuilds the
+    chunk's `G`, `A`, `Aq`, `T` and WY factors in VMEM, and takes its
+    gradients back through the norms, the sigmoid and a head's gate
+    before they leave (a channel's gate's gradient is XLA's). No
+    `jax.checkpoint` there: it cost 14.5 ms of a
     263 ms step to save 1.4 GB that the cell has (PERF.md, PR 32). With
     `kda_chunked`, under `jax.checkpoint`, the arguments alone: the chunk
     states, the WY factors and the decays it would keep are several
@@ -365,7 +381,14 @@ def kda_mixer_core(q, k, v, g_raw, beta_raw, a_log, dt_bias, num_heads, eps,
         held = kda_kernel.layout(num_heads, dk, dv)[0]
         profiler.set_counter("kda_lockstep_chunks", max(held, 1)
                              * kda_kernel.lockstep_chunks(s, held))
-        o = kda_kernel.kda_chunk(*_prologue(*args))
+        if per_head:
+            g, gate = g_raw, (a_log, dt_bias)
+        else:  # a channel's gate stays XLA's: PERF.md, PR 65
+            g, gate = kda_gate(g_raw, a_log, dt_bias, num_heads), ()
+        o = kda_kernel.kda_chunk(
+            q.reshape(b, s, key_heads, dk), k.reshape(b, s, key_heads, dk),
+            v.reshape(b, s, num_heads, dv), g, beta_raw, gate, eps,
+            beta_scale)
     else:
         profiler.bump_counter("kda_dispatch_chunked")
         o = _mixer_plain(*args)

@@ -13,6 +13,44 @@ call), and the state `S^T` `[dv, dk]` float32 in a VMEM scratch that
 lives across the sequential chunk axis of the grid and is zeroed at
 chunk 0.
 
+**The op's float32 prologue is the kernels' too** (PR 65), as far as it is
+a row's: they read q, k and beta's logits in the dtype and layout the
+projections wrote them (bf16 under AMP) and make
+`x * rsqrt(sum(x*x) + eps)` over a head's lanes and
+`beta_scale * sigmoid(b)` in VMEM, float32, once over the grid step's
+stacked rows (`_gate_rows`: `linear_attn_ops._prologue` formula for
+formula, which stays the plain path's and the oracle's); a head's decay
+too, `-exp(A_log) * softplus(a + dt_bias)` on its column, from its logits
+and two `[1, h]` float32 blocks. The backward makes them again from the
+same arrays and takes the sweep's gradients back through them before
+they leave (`_gate_grads`). No float32 copy of q, k, beta or a head's
+decay crosses HBM in either direction, and what the backward keeps is
+the projections' arrays and the chunks' states. The gradients of q and k
+leave in the dtype those arrived in, which is the cast XLA's `astype`
+gradient made on the same float32 numbers (float32 a value head under
+groups); a head's decay logits' and beta's leave as float32 rows a
+chunk, and A_log's terms (`dg * g`) as a third, so that the parameters'
+gradients stay float32 sums of float32 terms, which XLA sums. **A decay a
+channel is not the kernels': it stays gated by XLA** (`kda_gate` in
+`kda_mixer_core`, which hands over the float32 log decay `[b, s, h*dk]`
+as an operand, 67 MB a layer at Kimi's shape, kept for the backward,
+and its gradient leaves float32 the same way: in Kimi's lowering the
+kernels make the norms and beta and nothing of the decay). Decided by
+measurement: gated in VMEM (softplus over `[256, 128]` a grid step, the
+sigmoid again backward, the parameters' terms as a row of 128 lanes a
+grid step) Kimi's cell read 6.5617 and 6.5319 documents/s and the pair
+19.04 ms a step, with XLA's gate 6.5875 and 6.5883 and 18.25 ms plus
+0.41 ms of XLA's, for 0.28 GB more at the peak (the chip runs of refused
+PR 64's builder, one call, `chiprun_out/pr64b/`; PERF.md, PR 65): the
+kernels' vector units pay more for the gate than HBM does for one 67 MB
+array a layer each way. So the decay operand is a head's logits with
+`(A_log, dt_bias)` beside it, or a channel's float32 log decay with
+nothing beside it, and `kda_chunk` raises on any other pairing. Rows
+padded up to a whole grid step have logits of 0, so a beta of
+`beta_scale / 2` and a head's decay of its own: they stand behind the
+row's last token with q = k = v = 0, and change no output and no
+gradient.
+
 A grid step is stated in three parts. **What is a row's** (`_rows`): the
 cumulative log-decay `G` (no sum crosses a chunk), `exp(G)`,
 `exp(G_C - G)`, `exp(G_C)`, each level's `e`, `k*e` and `q*e`, the blocks
@@ -34,10 +72,11 @@ follows the backward sweep's last product and is sums alone (dG's last
 row, the lanes' sum, the reversed cumulative sum, the casts and stores)
 runs once over the stacked rows again (`_sweep_tail`). The host lowers
 every equation of the body at every start of a job, compile cache or not:
-a further chunk a step adds 127 equations forward and 311 backward (317
+a further chunk a step adds 127 equations forward and 312 backward (324
 with a decay a head) where a whole copy added 344 and 609
 (`tools/kda_vreg_count.py`; `tests/test_kda_kernel.py` holds them to 230
-and 500).
+and 500, and what is stated once, the prologue since PR 65 included, to
+400).
 
 Per chunk, all in VMEM:
 
@@ -86,9 +125,11 @@ reversed adds and leaves as a row a chunk, as beta's does. A `[64, 1]`
 column fills the registers `[64, 128]` fills, so the exponentials cost
 what they would on the column. With `h_k` key heads under `h` value
 heads the blocks of q and k are cut at lane slice `(n % h) // group` of
-`[b, s, h_k*128]` arrays: a key head's block is read once for each of its
-value heads and nothing is repeated in HBM. dq and dk are written a value
-head, `[b, s, h*128]` float32, and XLA adds each group's (measured
+`[b, s, h_k*128]` arrays: a key head's block is read (and normed) once for
+each of its value heads and nothing is repeated in HBM. dq and dk are
+written a value head, `[b, s, h*128]` float32, the norm's gradient already
+applied (it is linear in them), and XLA adds each group's and casts
+(measured
 against nothing: accumulating them in VMEM across a group's grid steps
 would want the group innermost in the grid and a state a group member in
 the scratch; the two arrays are 0.27 GB written and read once a layer at
@@ -611,8 +652,8 @@ def _chunk_bwd(free, masks, St, dSt, dO, *, dtype):
     dkl = dkl + by_beta[:, dv:] * back.E
     dkr = dkr + d_ke * back.e_end
     dG = back.q * dq + back.k * (dkl - dkr)
-    return ((dq, dkl + dkr, by_beta[:, :dv], dG, dg_end[None]),
-            _as_row(dbeta, masks), dSt_new)
+    return ((dq, dkl + dkr, by_beta[:, :dv], dG, dg_end[None]), dbeta,
+            dSt_new)
 
 
 @functools.partial(jax.jit, static_argnames=("per_head",))
@@ -636,21 +677,94 @@ def _sweep_tail(parts, *, per_head=False):
     return dq, dk, dv, _cumsum(dG, reverse=True)
 
 
-def _operands(q_ref, k_ref, v_ref, g_ref, beta_ref, heads, per_head=False):
-    """The grid step's blocks whole, float32 `[R, 128]`, and this head's
-    column `[R, 1]` of the `[R, h]` block of beta. `per_head`: `g_ref` is
-    an `[R, h]` block too, and this head's column is written along the
-    lanes here, in VMEM: a column fills as many registers as `[R, 128]`."""
-    def column(ref):
-        blk = ref[0]
-        head = pl.program_id(0) % heads
-        return jnp.sum(jnp.where(_iota(blk.shape, 1) == head, blk, 0.0), 1,
-                       keepdims=True)
+# What the prologue keeps of a grid step's rows for its own gradient:
+# rsqrt(sum x^2 + eps) of q's and k's rows, [R, 1]; sigmoid of beta's
+# logit, [R, 1]; and of a head's decay the log decay as the gate made it,
+# its logit plus its bias, both columns [R, 1], and -exp(A_log), [1, 1] (a
+# row each where heads are stacked, [R, 1]): None with a decay a channel,
+# whose gate is XLA's.
+_Gates = collections.namedtuple("_Gates", "rq rk sig g x rate")
 
-    q, k, v = (r[0].astype(jnp.float32) for r in (q_ref, k_ref, v_ref))
-    g = (jnp.broadcast_to(column(g_ref), q.shape) if per_head
-         else g_ref[0].astype(jnp.float32))
-    return q, k, v, g, column(beta_ref)
+
+@functools.partial(jax.jit, static_argnames=("eps", "beta_scale"))
+def _gate_rows(q, k, b, x, a_log, *, eps, beta_scale):
+    """The op's float32 prologue (`linear_attn_ops._prologue`, formula
+    for formula) over a grid step's stacked rows, in VMEM: q and k
+    `[R, lanes]` as the convolution wrote them, cast, become
+    `x * rsqrt(sum(x * x) + eps)` over the head's lanes (a padded lane is
+    0, adds 0 to the sum and stays 0); beta's logits `b` `[R, 1]` become
+    `beta_scale * sigmoid(b)`; a head's decay logit plus its bias `x`
+    `[R, 1]` becomes `-exp(A_log) * softplus(x)`. With a decay a channel
+    `a_log` is None and `x` `[R, 128]` is the log decay already (XLA's
+    gate made it: the module docstring says why). Returns q, k, the log
+    decay, beta and the `_Gates`. Stated once whatever the step's width,
+    and a row's own."""
+    def unit(t):
+        r = jax.lax.rsqrt(jnp.sum(t * t, 1, keepdims=True) + eps)
+        return t * r, r
+
+    q, rq = unit(q)
+    k, rk = unit(k)
+    sig = jax.nn.sigmoid(b)
+    beta = sig if beta_scale == 1.0 else beta_scale * sig
+    if a_log is None:
+        return q, k, x, beta, _Gates(rq, rk, sig, None, None, None)
+    rate = -jnp.exp(a_log)
+    # softplus as `jnp.logaddexp(x, 0)` evaluates it
+    g = rate * (jnp.maximum(x, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(x))))
+    return q, k, g, beta, _Gates(rq, rk, sig, g, x, rate)
+
+
+@functools.partial(jax.jit, static_argnames=("beta_scale",))
+def _gate_grads(dq, dk, dg, dbeta, q, k, gates, *, beta_scale):
+    """`_gate_rows` backwards over the step's stacked rows, float32: the
+    norm's own gradient `rsqrt * (d - x_n * sum(d * x_n))` on the dq and
+    dk the sweep holds (`q`, `k`: the normed rows), beta's through the
+    sigmoid and, with a decay a head, the decay's gradient back onto its
+    logits (`dg * -exp(A_log) * sigmoid(x)`: DtBias's gradient is their
+    sum) and, last, what A_log's gradient sums (`dg * g`: its derivative
+    is the log decay itself; None with a decay a channel, where `dg` is
+    the log decay's and leaves as it is)."""
+    def unit_grad(d, t, r):
+        return r * (d - t * jnp.sum(d * t, 1, keepdims=True))
+
+    sig, da = gates.sig, None
+    if beta_scale != 1.0:
+        dbeta = beta_scale * dbeta
+    if gates.rate is not None:
+        dg, da = dg * gates.rate * jax.nn.sigmoid(gates.x), dg * gates.g
+    return (unit_grad(dq, q, gates.rq), unit_grad(dk, k, gates.rk), dg,
+            dbeta * sig * (1.0 - sig), da)
+
+
+def _column(blk, mine):
+    """The column `[R, 1]` that the mask `mine` picks of each row of an
+    `[R, h]` block: a sum over the lanes of what it leaves."""
+    return jnp.sum(jnp.where(mine, blk, 0.0), 1, keepdims=True)
+
+
+def _operands(refs, heads, per_head, eps, beta_scale):
+    """The grid step's blocks whole, float32 `[R, 128]`, from the arrays
+    the projections wrote: q and k normed and beta made of its logits in
+    VMEM (`_gate_rows`), this head's column `[R, 1]` taken out of the
+    `[R, h]` block of beta's logits by a mask and a sum over the lanes.
+    `per_head`: `g_ref` is an `[R, h]` block of logits too, with A_log
+    and the decay's bias as `[1, h]` blocks behind beta's; the bias is
+    added before the column is taken, the gate is made on the column, and
+    this head's decay is written along the lanes here: a column fills as
+    many registers as `[R, 128]`. Returns (q, k, v, g, beta) and the
+    `_Gates`."""
+    head = pl.program_id(0) % heads
+    q, k, v, x, logits = (r[0].astype(jnp.float32) for r in refs[:5])
+    mine = _iota(logits.shape, 1) == head
+    a_log = None
+    if per_head:
+        a_log, dt_bias = (r[...] for r in refs[5:])
+        x = _column(x + dt_bias, mine)
+        a_log = _column(a_log, _iota(a_log.shape, 1) == head)
+    q, k, g, beta, gates = _gate_rows(q, k, _column(logits, mine), x, a_log,
+                                      eps=eps, beta_scale=beta_scale)
+    return (q, k, v, jnp.broadcast_to(g, q.shape), beta), gates
 
 
 # Heads that are no tile (`step_heads`): how many a grid step holds, of
@@ -675,65 +789,75 @@ def _tiles(d):
     return -(-d // LANE) * LANE
 
 
-def _narrow_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, narrow):
+def _narrow_operands(refs, narrow, eps, beta_scale):
     """`_operands` where a head is no tile and the decay is a head's: the
     step's heads one under the other, `[heads*R, tiles]` float32, each
     cut out of the step's block at its own lanes and padded with zeros to
-    whole tiles. A padded key lane of q and k is 0 in every product and
-    every lane sum; a padded value lane of v stays 0 through the solve,
-    the state and the output: what a head computes is the unpadded
-    mathematics. A head past the row's last (the last group's, where the
-    heads are no multiple of the step's) reads what lies in VMEM and
-    takes beta 0; its results are stored nowhere."""
+    whole tiles, then the prologue once over the stacked rows
+    (`_gate_rows`: the zeros add nothing to a row's sum of squares, so the
+    norm is over the lanes the head has, and a padded lane stays 0). A
+    padded key lane of q and k is 0 in every product and every lane sum;
+    a padded value lane of v stays 0 through the solve, the state and the
+    output: what a head computes is the unpadded mathematics. A head past
+    the row's last (the last group's, where the heads are no multiple of
+    the step's) reads what lies in VMEM and takes logits and an A_log of
+    0; its results are stored nowhere."""
+    q_ref, k_ref, v_ref, g_ref, beta_ref, a_ref, dt_ref = refs
     first = pl.program_id(0) % narrow.groups * narrow.heads
+    rows = q_ref.shape[1]
 
     def lanes(ref, d):
         return jnp.concatenate([
             _padded(ref[0, :, n * d:(n + 1) * d].astype(jnp.float32),
                     lanes=_tiles(d)) for n in range(narrow.heads)], axis=0)
 
-    def columns(ref):
-        blk = ref[0].astype(jnp.float32)
+    def columns(blk):  # of an `[R, h]` block, or a `[1, h]` for every row
         at = _iota(blk.shape, 1)
         return jnp.concatenate([
-            jnp.sum(jnp.where(at == first + n, blk, 0.0), 1, keepdims=True)
+            jnp.broadcast_to(_column(blk, at == first + n), (rows, 1))
             for n in range(narrow.heads)], axis=0)
 
-    q, k, v = lanes(q_ref, narrow.dk), lanes(k_ref, narrow.dk), lanes(
-        v_ref, narrow.dv)
-    return (q, k, v, jnp.broadcast_to(columns(g_ref), q.shape),
-            columns(beta_ref))
+    q, k, g, beta, gates = _gate_rows(
+        lanes(q_ref, narrow.dk), lanes(k_ref, narrow.dk),
+        columns(beta_ref[0].astype(jnp.float32)),
+        columns(g_ref[0].astype(jnp.float32) + dt_ref[...]),
+        columns(a_ref[...]), eps=eps, beta_scale=beta_scale)
+    return ((q, k, lanes(v_ref, narrow.dv), jnp.broadcast_to(g, q.shape),
+             beta), gates)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref, s_ref, *,
-                heads, steps, dtype, per_head=False, narrow=None):
+def _fwd_kernel(*refs, heads, steps, dtype, per_head, gate, narrow=None):
+    """`refs`: q, k, v, the decay's and beta's blocks, with a decay a head
+    A_log's and the bias's, then the output's, the chunks' states' and the
+    scratch. `gate`: the prologue's `eps` and `beta_scale`."""
+    *refs, o_ref, st_ref, s_ref = refs
+
     @pl.when(pl.program_id(1) == 0)
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    refs = (q_ref, k_ref, v_ref, g_ref, beta_ref)
     if narrow:
-        _fwd_narrow(refs, o_ref, st_ref, s_ref, steps, dtype, narrow)
+        _fwd_narrow(refs, o_ref, st_ref, s_ref, steps, dtype, narrow, gate)
         return
     # the rows' arithmetic once over the step's stacked rows; then copies
     # of the chunk and not a loop: a `pl.loop` over four chunks measured
     # 0.8 ms a call slower than four copies (1.3 ms backward)
-    rows = _rows(*_operands(*refs, heads, per_head))
+    rows = _rows(*_operands(refs, heads, per_head, *gate)[0])
     free = _state_free(rows, _pair_masks(CHUNK), dtype)
-    scale = q_ref.shape[2] ** -0.5
+    scale = refs[0].shape[2] ** -0.5
     for t in range(steps):
         St = st_ref[0, t] = s_ref[...]  # the state the chunk starts from
         o, s_ref[...] = _chunk_fwd(free[t], St, dtype=dtype, scale=scale)
         o_ref[0, pl.ds(t * CHUNK, CHUNK), :] = o.astype(o_ref.dtype)
 
 
-def _fwd_narrow(refs, o_ref, st_ref, s_ref, steps, dtype, narrow):
+def _fwd_narrow(refs, o_ref, st_ref, s_ref, steps, dtype, narrow, gate):
     """The forward grid step over `narrow.heads` heads of `steps` chunks
     each: the same rows' arithmetic, solves in lockstep and chunks, each
     head with a state of its own in the scratch, `[heads, dv, dk]` in
     whole tiles; a head's outputs and states leave at the lanes it has."""
     dk, dv = narrow.dk, narrow.dv
-    rows = _rows(*_narrow_operands(*refs, narrow))
+    rows = _rows(*_narrow_operands(refs, narrow, *gate)[0])
     free = _state_free(rows, _pair_masks(CHUNK), dtype)
     for n in range(narrow.heads):
         for t in range(steps):
@@ -745,76 +869,101 @@ def _fwd_narrow(refs, o_ref, st_ref, s_ref, steps, dtype, narrow):
                 o[:, :dv].astype(o_ref.dtype))
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref, dq_ref,
-                dk_ref, dv_ref, dg_ref, db_ref, ds_ref, *, heads, steps,
-                dtype, per_head=False, narrow=None):
+def _sweep_gates(parts, dbetas, rows, gates, beta_scale, per_head):
+    """What leaves a reverse sweep's grid step, over its stacked rows:
+    `_sweep_tail`'s sums, then the prologue's own gradient
+    (`_gate_grads`). Returns the gradients of q, k and v as they arrived,
+    of the decay (its logits', a column `[R, 1]`, with a decay a head),
+    of beta's logits `[R, 1]` and, with a decay a head, what A_log's
+    gradient sums, `[R, 1]`."""
+    dq, dk, dv, dg = _sweep_tail(parts, per_head=per_head)
+    dq, dk, *decay = _gate_grads(
+        dq, dk, dg, jnp.concatenate(dbetas, axis=0), rows.back.q, rows.back.k,
+        gates, beta_scale=beta_scale)
+    return (dq, dk, dv, *decay)
+
+
+def _bwd_kernel(*refs, heads, steps, dtype, per_head, gate, narrow=None):
+    """`refs`: the forward's operands, the chunks' states and the
+    output's cotangent; then the gradients of q, k, v, the decay and
+    beta's logits, with a decay a head A_log's terms; the scratch."""
+    ins = 9 if per_head else 7
+    (*refs, st_ref, do_ref), outs, ds_ref = refs[:ins], refs[ins:-1], refs[-1]
+
     @pl.when(pl.program_id(1) == 0)
     def _():
         ds_ref[...] = jnp.zeros_like(ds_ref)
 
-    refs = (q_ref, k_ref, v_ref, g_ref, beta_ref)
     if narrow:
-        _bwd_narrow(refs, st_ref, do_ref,
-                    (dq_ref, dk_ref, dv_ref, dg_ref, db_ref), ds_ref, steps,
-                    dtype, narrow)
+        _bwd_narrow(refs, st_ref, do_ref, outs, ds_ref, steps, dtype, narrow,
+                    gate)
         return
-    rows = _rows(*_operands(*refs, heads, per_head))
+    operands, gates = _operands(refs, heads, per_head, *gate)
+    rows = _rows(*operands)
     masks = _pair_masks(CHUNK)
     free = _state_free(rows, masks, dtype, backward=True)
-    dO = q_ref.shape[2] ** -0.5 * do_ref[0].astype(jnp.float32)
-    parts = [None] * steps
+    dO = refs[0].shape[2] ** -0.5 * do_ref[0].astype(jnp.float32)
+    parts, dbetas = [None] * steps, [None] * steps
     for t in reversed(range(steps)):
-        parts[t], db_ref[0, t], ds_ref[...] = _chunk_bwd(
+        parts[t], dbetas[t], ds_ref[...] = _chunk_bwd(
             free[t], masks, st_ref[0, t], ds_ref[...], _cut(dO, t),
             dtype=dtype)
-    dq, dk, dv, dg = _sweep_tail(parts, per_head=per_head)
-    wide = [(dq_ref, dq), (dk_ref, dk), (dv_ref, dv)]
-    if per_head:  # a row a chunk, as beta's
-        for t in range(steps):
-            dg_ref[0, t] = _as_row(_cut(dg, t), masks)
-    else:
-        wide.append((dg_ref, dg))
-    for ref, d in wide:
+    grads = _sweep_gates(parts, dbetas, rows, gates, gate[1], per_head)
+    # with a decay a head its gradient and A_log's terms leave as beta's
+    # does, a row a chunk
+    wide = 3 if per_head else 4
+    for ref, d in zip(outs[:wide], grads):
         ref[0] = d.astype(ref.dtype)
+    for ref, d in zip(outs[wide:], grads[wide:]):
+        for t in range(steps):
+            ref[0, t] = _as_row(_cut(d, t), masks)
 
 
-def _bwd_narrow(refs, st_ref, do_ref, outs, ds_ref, steps, dtype, narrow):
+def _bwd_narrow(refs, st_ref, do_ref, outs, ds_ref, steps, dtype, narrow,
+                gate):
     """The reverse sweep's grid step over `narrow.heads` heads (see
     `_fwd_narrow`): a head's states and cotangents are padded with zeros
     to whole tiles as they are read, and its gradients leave at the lanes
     it has."""
-    dq_ref, dk_ref, dv_ref, dg_ref, db_ref = outs
     dk, dv = narrow.dk, narrow.dv
-    rows = _rows(*_narrow_operands(*refs, narrow))
+    operands, gates = _narrow_operands(refs, narrow, *gate)
+    rows = _rows(*operands)
     masks = _pair_masks(CHUNK)
     free = _state_free(rows, masks, dtype, backward=True)
     parts = [None] * (narrow.heads * steps)
+    dbetas = [None] * len(parts)
     for n in range(narrow.heads):
         dO = dk ** -0.5 * _padded(
             do_ref[0, :, n * dv:(n + 1) * dv].astype(jnp.float32),
             lanes=_tiles(dv))
         for t in reversed(range(steps)):
+            at = n * steps + t
             St = _padded(st_ref[0, n, t], _tiles(dv), _tiles(dk))
-            parts[n * steps + t], db_ref[0, n, t], ds_ref[n] = _chunk_bwd(
-                free[n * steps + t], masks, St, ds_ref[n], _cut(dO, t),
-                dtype=dtype)
-    grads = _sweep_tail(parts, per_head=True)
+            parts[at], dbetas[at], ds_ref[n] = _chunk_bwd(
+                free[at], masks, St, ds_ref[n], _cut(dO, t), dtype=dtype)
+    grads = _sweep_gates(parts, dbetas, rows, gates, gate[1], True)
     span = steps * CHUNK
     for n in range(narrow.heads):
-        for ref, d, lanes in zip((dq_ref, dk_ref, dv_ref), grads,
-                                 (dk, dk, dv)):
+        for ref, d, lanes in zip(outs[:3], grads, (dk, dk, dv)):
             ref[0, :, n * lanes:(n + 1) * lanes] = (
                 d[n * span:(n + 1) * span, :lanes].astype(ref.dtype))
-        for t in range(steps):
-            dg_ref[0, n, t] = _as_row(_cut(grads[3], n * steps + t), masks)
+        for ref, d in zip(outs[3:], grads[3:]):
+            for t in range(steps):
+                ref[0, n, t] = _as_row(_cut(d, n * steps + t), masks)
 
 
 def _cost(backward, b, s, heads, dk, dv, dtypes, group=1, per_head=False):
     """What one call declares (`cost.py` has the convention) for `s`
-    unpadded tokens a row; `dtypes`: of q, k, g and of v, o. With `group`
+    unpadded tokens a row; `dtypes`: of q and k, of v and o, of the
+    decay's array and of beta's logits, as they arrive. With `group`
     value heads to a key head q and k are moved once a key head in, and
-    their gradients leave a value head each (XLA adds the group's);
-    `per_head`: g and its gradient are `[b, s, h]` float32, as beta. The
+    their gradients leave float32 a value head each (XLA adds the
+    group's); with a key head a value head they leave in q's dtype.
+    `per_head`: the decay's logits are `[b, s, h]` as beta's, with A_log
+    and the bias read once, and the gradients of both leave as float32
+    rows, with a third of them for what A_log's gradient sums; with a
+    decay a channel the log decay arrives `[b, s, h*dk]` and its gradient
+    leaves in its dtype. The
     products and the exponentials are a value head's either way: the
     decay is written along the lanes in VMEM and evaluated there. Products
     counted a chunk of `c` rows (the last may be short) of one head, in
@@ -832,12 +981,15 @@ def _cost(backward, b, s, heads, dk, dv, dtypes, group=1, per_head=False):
       as column (2*c*c*dk).
 
     Not counted: the ten [c, c] products that build the inverse (a solve
-    needs none), and what the four levels' products hold outside their
+    needs none), what the four levels' products hold outside their
     own pairs (each is a whole [c, c] of which a level keeps c*c/4 entries
-    or fewer). Exponentials as the kernels evaluate them a chunk: exp(G),
+    or fewer), and the prologue's sums and multiplies (no product).
+    Exponentials as the kernels evaluate them a chunk: exp(G),
     exp(G_c - G), exp(G_c), the rows' factors at each of the four levels
     and the decays at the three distances inside a block of LEAF rows,
-    over the keys' lanes."""
+    over the keys' lanes; and the prologue's a token of a value head: two
+    rsqrt and beta's sigmoid, and with a decay a head the softplus' exp
+    and log1p (backward the sigmoid of the same logit on top)."""
     def chunk(c):
         lower, strict, state = c * (c + 1) // 2, c * (c - 1) // 2, c * dk * dv
         if backward:
@@ -849,26 +1001,35 @@ def _cost(backward, b, s, heads, dk, dv, dtypes, group=1, per_head=False):
     macs = (s // CHUNK) * chunk(CHUNK) + chunk(s % CHUNK)
     exps = chunks * dk * (2 * CHUNK + 1
                           + (len(_levels(CHUNK)) + LEAF - 1) * CHUNK)
-    wide, narrow = dtypes
-    keys, values = ((b, s, heads * dk), wide), ((b, s, heads * dv), narrow)
-    beta = ((b, s, heads), jnp.float32)
-    states = ((b * heads, chunks, dv, dk), jnp.float32)
+    exps += s * (3 + ((3 if backward else 2) if per_head else 0))
+    f32 = jnp.float32
+    wide, narrow, decays, betas = dtypes
+    keys, values = (b, s, heads * dk), ((b, s, heads * dv), narrow)
+    row = (b, s, heads)
+    decay = (row if per_head else keys, decays)
+    states = ((b * heads, chunks, dv, dk), f32)
     held = ((b, s, heads // group * dk), wide)  # q, k as they arrive
-    decay = beta if per_head else keys
-    moved = [held] * 2 + [decay, values, beta, states, values]  # .., o or dO
-    if backward:  # dq, dk, dg, dv, dbeta
-        moved += [keys] * 2 + [decay, values, beta]
+    moved = [held] * 2 + [decay, values, (row, betas), states,
+                          values]  # .., o or dO
+    if per_head:  # A_log and the bias
+        moved += [((heads,), f32)] * 2
+    if backward:  # dq, dk, dv, dbeta, then the decay's
+        moved += [(keys, wide if group == 1 else f32)] * 2 + [
+            values, (row, f32)]
+        moved += [(row, f32)] * 2 if per_head else [decay]
     return cost.estimate(2 * b * heads * macs, b * heads * exps, *moved)
 
 
 # What a call is built from beside its operands: the value heads, the
 # chunks a grid step, what a product reads, the interpreter, the unpadded
-# length, the value heads to a key head, whether the decay is a head's, and
+# length, the value heads to a key head, whether the decay is a head's,
 # the heads a grid step holds where a head is no tile (`step_heads`; 0: a
-# head is a tile and the step holds one).
+# head is a tile and the step holds one), and the prologue's `eps` and
+# `beta_scale`.
 _Statics = collections.namedtuple(
-    "_Statics", "heads steps dtype interpret s group per_head narrow",
-    defaults=(1, False, 0))
+    "_Statics",
+    "heads steps dtype interpret s group per_head narrow eps beta_scale",
+    defaults=(1, False, 0, 1e-6, 1.0))
 
 
 def _specs(statics, chunk_of):
@@ -916,6 +1077,12 @@ def _narrow_specs(statics, chunk_of, dk, dv):
     return lanes, shared, by_chunk, _Narrow(held, groups, dk, dv)
 
 
+def _numbers(heads):
+    """Of a `[1, h]` array of the heads' numbers (A_log, a head's decay's
+    bias), whole at every grid step."""
+    return pl.BlockSpec((1, heads), lambda i, j: (0, 0))
+
+
 def _names(statics):
     """The pair's names in a trace: a decay a head has its own, so that
     the metrics of one never read the other's events."""
@@ -923,29 +1090,46 @@ def _names(statics):
                                                             "kda_bwd")
 
 
+def _body(kernel, statics, narrow=None):
+    return functools.partial(
+        kernel, heads=statics.heads, steps=statics.steps,
+        dtype=statics.dtype, per_head=statics.per_head,
+        gate=(statics.eps, statics.beta_scale), narrow=narrow)
+
+
+def _declared(backward, operands, statics, dk, dv):
+    q, _, v, g, beta = operands[:5]
+    return _cost(backward, q.shape[0], statics.s, statics.heads, dk, dv,
+                 (q.dtype, v.dtype, g.dtype, beta.dtype), statics.group,
+                 statics.per_head)
+
+
 @functools.partial(jax.jit, static_argnames=("statics",))
-def _call_fwd(q, k, v, g, beta, *, statics):
-    """q, k: [b, S, h_k*dk]; v: [b, S, h*dv]; g: [b, S, h*dk], or
-    [b, S, h] with a decay a head; beta: [b, S, h]; S whole grid steps.
-    Returns o [b, S, h*dv] in v's dtype and the state each chunk starts
-    from, [b*h, S/C, dv, dk] float32. One call for a forward that is
+def _call_fwd(q, k, v, g, beta, gate, *, statics):
+    """q, k: [b, S, h_k*dk] and v: [b, S, h*dv] as the convolution wrote
+    them; beta's logits: [b, S, h], as their projection did. With a decay
+    a head g: [b, S, h], its logits, and `gate`: (A_log, the decay's
+    bias), [1, h] float32 each; with a decay a channel g: [b, S, h*dk],
+    the log decay, and `gate`: (). S whole grid steps. Returns o
+    [b, S, h*dv] in v's dtype and the state each chunk starts from,
+    [b*h, S/C, dv, dk] float32. One call for a forward that is
     differentiated and one that is not: a Program's gradient op lowers
     its forward op again, and XLA merges the two calls only if they are
     the same call."""
     statics = _Statics(*statics)  # a caller may pass the first five alone
-    heads, steps, dtype, interpret, s = statics[:5]
+    heads, steps = statics.heads, statics.steps
+    operands = (q, k, v, g, beta, *gate)
     b, S, _ = q.shape
     dk, dv = q.shape[2] * statics.group // heads, v.shape[2] // heads
     if statics.narrow:
-        return _call_fwd_narrow(q, k, v, g, beta, statics, dk, dv)
+        return _call_fwd_narrow(operands, statics, dk, dv)
     spec, key_spec, shared = _specs(statics, lambda j: j)
-
+    decay = ([shared, shared, _numbers(heads), _numbers(heads)]
+             if statics.per_head else [spec(dk), shared])
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=heads, steps=steps, dtype=dtype,
-                          per_head=statics.per_head),
+        _body(_fwd_kernel, statics),
         grid=(b * heads, S // (steps * CHUNK)),
-        in_specs=[key_spec(dk), key_spec(dk), spec(dv),
-                  shared if statics.per_head else spec(dk), shared],
+        in_specs=[key_spec(dk), key_spec(dk), spec(dv)] + decay,
         out_specs=[spec(dv), pl.BlockSpec((1, steps, dv, dk),
                                           lambda i, j: (i, j, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -954,27 +1138,28 @@ def _call_fwd(q, k, v, g, beta, *, statics):
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=statics.interpret,
         name=_names(statics)[0],
-        cost_estimate=_cost(False, b, s, heads, dk, dv, (q.dtype, v.dtype),
-                            statics.group, statics.per_head),
-    )(q, k, v, g, beta)
+        cost_estimate=_declared(False, operands, statics, dk, dv),
+    )(*operands)
 
 
-def _call_fwd_narrow(q, k, v, g, beta, statics, dk, dv):
+def _call_fwd_narrow(operands, statics, dk, dv):
     """`_call_fwd` where a head is no tile: `statics.narrow` heads a grid
     step, the states `[b, h, S/C, dv, dk]` at the lanes the heads have,
     and the declaration that of `dk` and `dv` lanes, whatever the tiles
     in VMEM multiply."""
     heads, steps = statics.heads, statics.steps
-    b, S, _ = q.shape
+    v = operands[2]
+    b, S, _ = v.shape
     lanes, shared, by_chunk, narrow = _narrow_specs(statics, lambda j: j, dk,
                                                     dv)
+    numbers = _numbers(heads)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=heads, steps=steps,
-                          dtype=statics.dtype, per_head=True, narrow=narrow),
+        _body(_fwd_kernel, statics, narrow),
         grid=(b * narrow.groups, S // (steps * CHUNK)),
-        in_specs=[lanes(dk), lanes(dk), lanes(dv), shared, shared],
+        in_specs=[lanes(dk), lanes(dk), lanes(dv), shared, shared, numbers,
+                  numbers],
         out_specs=[lanes(dv), by_chunk(dv, dk)],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((b, heads, S // CHUNK, dv, dk),
@@ -985,113 +1170,145 @@ def _call_fwd_narrow(q, k, v, g, beta, statics, dk, dv):
             dimension_semantics=("parallel", "arbitrary")),
         interpret=statics.interpret,
         name=_names(statics)[0],
-        cost_estimate=_cost(False, b, statics.s, heads, dk, dv,
-                            (q.dtype, v.dtype), 1, True),
-    )(q, k, v, g, beta)
+        cost_estimate=_declared(False, operands, statics, dk, dv),
+    )(*operands)
 
 
-def _call_bwd_narrow(q, k, v, g, beta, states, do, statics, dk, dv):
-    """`_call_bwd` where a head is no tile (see `_call_fwd_narrow`)."""
+def _by_token(rows, like):
+    """The kernel's rows `[..., n, 1, C]`, heads before chunks, by token:
+    `[b, S, h]` float32, `like`'s shape."""
+    b, S, heads = like.shape
+    return rows.reshape(b, heads, S).transpose(0, 2, 1)
+
+
+def _logit_grads(dg, dbeta, da, operands):
+    """The op's gradients of GRaw, BetaRaw, ALog and DtBias with a decay a
+    head, from the kernel's rows (the decay's logits', beta's logits',
+    what A_log's sums): by token in the logits' dtypes, and the float32
+    sums over the tokens that the two parameters' gradients are."""
+    g, beta, a_log, dt_bias = operands[3:]
+    dg = _by_token(dg, g)
+    return (dg.astype(g.dtype), _by_token(dbeta, beta).astype(beta.dtype),
+            (_by_token(da, g).sum((0, 1)).reshape(a_log.shape),
+             dg.sum((0, 1)).reshape(dt_bias.shape)))
+
+
+def _sweep_narrow(operands, states, do, statics, dk, dv):
+    """`_sweep` where a head is no tile (see `_call_fwd_narrow`)."""
     heads, steps = statics.heads, statics.steps
+    q, _, v = operands[:3]
     b, S, _ = q.shape
     last = S // (steps * CHUNK) - 1
     lanes, shared, by_chunk, narrow = _narrow_specs(
         statics, lambda j: last - j, dk, dv)
+    numbers = _numbers(heads)
     rows = jax.ShapeDtypeStruct((b, heads, S // CHUNK, 1, CHUNK), jnp.float32)
-    dq, dk_, dv_, dg, dbeta = pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads, steps=steps,
-                          dtype=statics.dtype, per_head=True, narrow=narrow),
+    return pl.pallas_call(
+        _body(_bwd_kernel, statics, narrow),
         grid=(b * narrow.groups, last + 1),
-        in_specs=[lanes(dk), lanes(dk), lanes(dv), shared, shared,
-                  by_chunk(dv, dk), lanes(dv)],
-        out_specs=[lanes(dk), lanes(dk), lanes(dv), by_chunk(1, CHUNK),
-                   by_chunk(1, CHUNK)],
+        in_specs=[lanes(dk), lanes(dk), lanes(dv), shared, shared, numbers,
+                  numbers, by_chunk(dv, dk), lanes(dv)],
+        out_specs=[lanes(dk), lanes(dk), lanes(dv)] + [by_chunk(1, CHUNK)] * 3,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype), rows, rows],
+                   jax.ShapeDtypeStruct(v.shape, v.dtype), rows, rows, rows],
         scratch_shapes=[pltpu.VMEM((narrow.heads, _tiles(dv), _tiles(dk)),
                                    jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=statics.interpret,
         name=_names(statics)[1],
-        cost_estimate=_cost(True, b, statics.s, heads, dk, dv,
-                            (q.dtype, v.dtype), 1, True),
-    )(q, k, v, g, beta, states, do)
-
-    def by_token(t):  # [b, h, n, 1, C] -> [b, S, h]
-        return t.reshape(b, heads, S).transpose(0, 2, 1)
-
-    return dq, dk_, dv_, by_token(dg), by_token(dbeta)
+        cost_estimate=_declared(True, operands, statics, dk, dv),
+    )(*operands, states, do)
 
 
-@functools.partial(jax.jit, static_argnames=("statics",))
-def _call_bwd(q, k, v, g, beta, states, do, *, statics):
-    """The reverse sweep: grid step j holds the chunks of step last - j.
-    The kernel writes dq and dk a value head, `[b, S, h*dk]`; with grouped
-    key heads XLA adds each group's to the `[b, S, h_k*dk]` the op
-    returns (two float32 arrays of q's width times the group written and
-    read once: 0.27 GB a layer at 4,096 tokens, 32 heads and groups of 2).
-    With a decay a head dg leaves as beta's gradient does, a row a
-    chunk."""
-    statics = _Statics(*statics)
-    heads, steps, dtype, interpret, s = statics[:5]
-    group = statics.group
+def _sweep(operands, states, do, statics):
+    """The reverse sweep's call alone, and what the kernel itself writes:
+    grid step j holds the chunks of step last - j. `operands`: those of
+    `_call_fwd`, the gate's two flat behind beta's. Returns dq, dk, dv,
+    then with a decay a head three arrays of rows a chunk
+    `[.., S/C, 1, C]` float32 (the gradients of the decay's logits and of
+    beta's, and what A_log's gradient sums), with a decay a channel the
+    log decay's gradient in its shape and dtype and beta's logits' rows.
+    `_call_bwd` makes the op's gradients of these; the tests of a grid
+    step's width (`tests/kernel_cases.py::pair_at_widths`) hold these,
+    before XLA sums, casts or lays anything by token."""
+    heads, steps, group = statics.heads, statics.steps, statics.group
+    q, _, v, g = operands[:4]
     b, S, _ = q.shape
     dk, dv = q.shape[2] * group // heads, v.shape[2] // heads
     if statics.narrow:
-        return _call_bwd_narrow(q, k, v, g, beta, states, do, statics, dk, dv)
+        return _sweep_narrow(operands, states, do, statics, dk, dv)
     last = S // (steps * CHUNK) - 1
     spec, key_spec, shared = _specs(statics, lambda j: last - j)
     row = pl.BlockSpec((1, steps, 1, CHUNK), lambda i, j: (i, last - j, 0, 0))
     rows = jax.ShapeDtypeStruct((b * heads, S // CHUNK, 1, CHUNK),
                                 jnp.float32)
-    wide = jax.ShapeDtypeStruct((b, S, heads * dk), q.dtype)
-
-    dq, dk_, dv_, dg, dbeta = pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads, steps=steps, dtype=dtype,
-                          per_head=statics.per_head),
+    wide = jax.ShapeDtypeStruct((b, S, heads * dk),
+                                q.dtype if group == 1 else jnp.float32)
+    if statics.per_head:
+        decay = [shared, shared, _numbers(heads), _numbers(heads)]
+        grad_specs, grad_shapes = [row] * 3, [rows] * 3
+    else:
+        decay = [spec(dk), shared]
+        grad_specs = [spec(dk), row]
+        grad_shapes = [jax.ShapeDtypeStruct(g.shape, g.dtype), rows]
+    return pl.pallas_call(
+        _body(_bwd_kernel, statics),
         grid=(b * heads, last + 1),
-        in_specs=[key_spec(dk), key_spec(dk), spec(dv),
-                  shared if statics.per_head else spec(dk), shared,
-                  pl.BlockSpec((1, steps, dv, dk),
-                               lambda i, j: (i, last - j, 0, 0)),
-                  spec(dv)],
-        out_specs=[spec(dk), spec(dk), spec(dv),
-                   row if statics.per_head else spec(dk), row],
-        out_shape=[wide, wide,
-                   jax.ShapeDtypeStruct(v.shape, v.dtype),
-                   rows if statics.per_head
-                   else jax.ShapeDtypeStruct(g.shape, g.dtype),
-                   rows],
+        in_specs=[key_spec(dk), key_spec(dk), spec(dv)] + decay + [
+            pl.BlockSpec((1, steps, dv, dk),
+                         lambda i, j: (i, last - j, 0, 0)),
+            spec(dv)],
+        out_specs=[spec(dk), spec(dk), spec(dv)] + grad_specs,
+        out_shape=[wide, wide, jax.ShapeDtypeStruct(v.shape, v.dtype)]
+        + grad_shapes,
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=statics.interpret,
         name=_names(statics)[1],
-        cost_estimate=_cost(True, b, s, heads, dk, dv, (q.dtype, v.dtype),
-                            statics.group, statics.per_head),
-    )(q, k, v, g, beta, states, do)
+        cost_estimate=_declared(True, operands, statics, dk, dv),
+    )(*operands, states, do)
 
-    def by_token(t):  # [b*h, n, 1, C] -> [b, S, h]
-        return t.reshape(b, heads, S).transpose(0, 2, 1)
 
+@functools.partial(jax.jit, static_argnames=("statics",))
+def _call_bwd(q, k, v, g, beta, gate, states, do, *, statics):
+    """The reverse sweep (`_sweep`) and what XLA makes of its arrays: the
+    gradients of the operands of `_call_fwd`, each in its operand's shape
+    and dtype. With a key head a value head the kernel writes dq and dk
+    as they arrived; with grouped key heads it writes them float32 a
+    value head, `[b, S, h*dk]`, and XLA adds each group's to the
+    `[b, S, h_k*dk]` the op returns (two float32 arrays of q's width
+    times the group written and read once: 0.27 GB a layer at 4,096
+    tokens, 32 heads and groups of 2). The gradient of beta's logits
+    leaves as a row a chunk; with a decay a head so do its logits' and, a
+    third, what A_log's gradient sums, and XLA lays them by token and
+    sums the parameters'; with a decay a channel the log decay's leaves
+    in its dtype."""
+    statics = _Statics(*statics)
+    heads, group = statics.heads, statics.group
+    operands = (q, k, v, g, beta, *gate)
+    b, S, _ = q.shape
+    dk = q.shape[2] * group // heads
+    dq, dk_, dv_, *decay = _sweep(operands, states, do, statics)
     if group > 1:
         dq, dk_ = (t.reshape(b, S, heads // group, group, dk).sum(3)
-                   .reshape(q.shape) for t in (dq, dk_))
+                   .reshape(q.shape).astype(q.dtype) for t in (dq, dk_))
     if statics.per_head:
-        dg = by_token(dg)
-    return dq, dk_, dv_, dg, by_token(dbeta)
+        return (dq, dk_, dv_, *_logit_grads(*decay, operands))
+    dg, dbeta = decay
+    return dq, dk_, dv_, dg, _by_token(dbeta, beta).astype(beta.dtype), ()
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _core(q, k, v, g, beta, statics):
-    return _call_fwd(q, k, v, g, beta, statics=statics)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _core(q, k, v, g, beta, gate, statics):
+    return _call_fwd(q, k, v, g, beta, gate, statics=statics)[0]
 
 
-def _core_fwd(q, k, v, g, beta, statics):
-    o, states = _call_fwd(q, k, v, g, beta, statics=statics)
-    return o, (q, k, v, g, beta, states)
+def _core_fwd(q, k, v, g, beta, gate, statics):
+    o, states = _call_fwd(q, k, v, g, beta, gate, statics=statics)
+    return o, (q, k, v, g, beta, gate, states)
 
 
 def _core_bwd(statics, res, do):
@@ -1101,17 +1318,34 @@ def _core_bwd(statics, res, do):
 _core.defvjp(_core_fwd, _core_bwd)
 
 
-def kda_chunk(q, k, v, g, beta):
-    """`kda_chunked`'s contract, in the kernels. q, k: [b, s, h_k, dk]
-    float32; g, the log decay: [b, s, h, dk] float32, or [b, s, h] where
-    a head has one decay; v: [b, s, h, dv] in the dtype it arrives in
-    (the kernels read and write it as it is and compute in float32);
-    beta: [b, s, h] float32; `h_k` divides `h`, and value head n reads key
-    head n // (h / h_k). Returns o: [b, s, h, dv] in v's dtype. Nothing is
-    repeated or written out in front of the kernels: they read a key
-    head's block for each of its value heads and a head's decay as a
+def kda_chunk(q, k, v, g, beta, gate=(), eps=1e-6, beta_scale=1.0):
+    """`kda_chunked` behind the op's float32 prologue, in the kernels,
+    from the arrays the projections wrote, in the dtypes they wrote them
+    (the kernels compute in float32 whatever arrives). q, k:
+    [b, s, h_k, dk], not normed; v: [b, s, h, dv]; beta, its logits:
+    [b, s, h]; `h_k` divides `h`, and value head n reads key head
+    n // (h / h_k). Where a head has one decay, g: [b, s, h], its logits,
+    and `gate`: (a_log, dt_bias), [h] each; with a decay a channel g:
+    [b, s, h, dk] float32, the log decay itself as `kda_gate` makes it,
+    and no `gate`. Returns o: [b, s, h, dv] in v's dtype. Nothing is
+    normed, repeated or written out in front of the kernels: they read a
+    key head's block for each of its value heads, a head's decay as a
     column (`gdn_fwd`, `gdn_bwd` in a trace; with a decay a channel
-    `kda_fwd`, `kda_bwd`)."""
+    `kda_fwd`, `kda_bwd`), and make the L2 norms over `dk` lanes
+    (+ `eps`), `beta_scale * sigmoid(beta)` and a head's
+    `-exp(a_log) softplus(g + dt_bias)` in VMEM; the gradients of all
+    come back in their dtypes."""
+    operands, statics = step_operands(q, k, v, g, beta, gate, eps,
+                                      beta_scale)
+    b, s, h, dv = v.shape
+    return _core(*operands, statics)[:, :s].reshape(b, s, h, dv)
+
+
+def step_operands(q, k, v, g, beta, gate=(), eps=1e-6, beta_scale=1.0):
+    """`kda_chunk`'s arguments as its calls take them: (q, k, v, the
+    decay's array and beta's logits `[b, S, ...]`, heads side by side on
+    the lanes and rows padded to whole grid steps, and the gate's two
+    `[1, h]` float32), and the calls' `_Statics`."""
     require_pallas("kda_chunk")
     b, s, h_k, dk = q.shape
     h, dv = v.shape[2:]
@@ -1122,16 +1356,21 @@ def kda_chunk(q, k, v, g, beta):
             f"{LANE} and key heads that divide the value heads, or a decay "
             "a head, a key head a value head and the widths "
             "`kda_chunk_viable` states")
+    if per_head != bool(gate):
+        raise ValueError("kda_chunk: a head's decay comes as logits with "
+                         "`gate` = (a_log, dt_bias), a channel's as the log "
+                         "decay without")
     narrow = layout(h, dk, dv)[0]
     steps = lockstep_chunks(s, narrow)
     pad = -s % (steps * CHUNK)
     # heads side by side on the lanes, as the projections write them
     q, k, v, g = (t.reshape(b, s, -1) for t in (q, k, v, g))
-    if pad:  # k = v = beta = 0 and no decay: padded tokens change nothing
-        q, k, v, g = (jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
-                      for t in (q, k, v, g))
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
-    o = _core(q, k, v, g, beta, _Statics(
+    if pad:  # q = k = v = 0 behind the row's last token: a padded token
+        # decays the state it leaves to no one and changes nothing (logits
+        # of 0 are a beta of beta_scale / 2 and a head's decay, not 0)
+        q, k, v, g, beta = (jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
+                            for t in (q, k, v, g, beta))
+    gate = tuple(t.astype(jnp.float32).reshape(1, h) for t in gate)
+    return (q, k, v, g, beta, gate), _Statics(
         h, steps, _product_dtype(), _interpret(), s, h // h_k, per_head,
-        narrow))
-    return o[:, :s].reshape(b, s, h, dv)
+        narrow, float(eps), float(beta_scale))

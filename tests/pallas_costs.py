@@ -2,7 +2,8 @@
 kernels' `cost_estimate` (ops/pallas/cost.py has the convention) read the
 `pallas_call` equations of a jaxpr, nested ones included, and hold them
 to counts written out by hand. `operand_shapes` reads the same equations'
-operands and results, `block_shapes` their grids and blocks;
+operands and results (`operand_types` with their dtypes),
+`block_shapes` their grids and blocks;
 `jaxpr_digest` hashes a traced jaxpr's text, for the tests that hold a
 function's default path to what a parent commit traced."""
 
@@ -51,6 +52,14 @@ def operand_shapes(fn, *args) -> dict:
     return _calls(fn, args, lambda eqn: (
         [v.aval.shape for v in eqn.invars],
         [v.aval.shape for v in eqn.outvars]))
+
+
+def operand_types(fn, *args) -> dict:
+    """As `operand_shapes`, each array as (shape, dtype's name): what
+    crosses HBM at a call, and in which dtype."""
+    return _calls(fn, args, lambda eqn: tuple(
+        [(v.aval.shape, v.aval.dtype.name) for v in vs]
+        for vs in (eqn.invars, eqn.outvars)))
 
 
 def block_shapes(fn, *args) -> dict:

@@ -1,8 +1,13 @@
 """The KDA chunk kernels (`ops/pallas/kda_chunk.py`) under the Pallas
-interpreter, at the published head width of 128: outputs and the five
-gradients against the token recurrence of `benchmark/models/kimi_linear.py`
-and against `kda_chunked`, the plain path, each side and each gradient
-compiled (`tests/kernel_cases.py`); the state carried over grid
+interpreter, at the published head width of 128, through the op's core
+from the arrays the projections write (since PR 65 the kernels make the
+L2 norms and beta themselves, in VMEM; a channel's decay is gated by XLA
+in front of them): outputs and the seven gradients, A_log's and the
+decay's bias's among them, against the token recurrence of
+`benchmark/models/kimi_linear.py` and against `kda_chunked`, the plain
+path, each behind the op's float32 prologue (`_prologue`), each side and
+each gradient compiled (`tests/kernel_cases.py`), float32 and bf16
+inputs, rows of q and k of any length; the state carried over grid
 steps; a grid step's stacked rows and lockstep solve against each chunk's
 own, bit for bit, and the pair at any width against one chunk a step;
 what a further chunk of a grid step costs the host in equations; the
@@ -11,39 +16,51 @@ dispatch and its counters."""
 import numpy as np
 import pytest
 
-from kernel_cases import compiled, pair_at_widths, rel, value_and_grads
+from kernel_cases import (OPERANDS, after_prologue, compiled,
+                          gradients_held, kernel_path, logits_of,
+                          one_cotangent, oracles, pair_at_widths, rel,
+                          value_and_grads)
 
 from benchmark.models import kimi_linear as ref
 
 B, H, D = 2, 2, 128
 
 
-def _args(length, g_lo, g_hi, seed=None, parallel=False, alternate=False):
-    """`parallel`: every q and k is one direction a head plus 0.3 of
-    noise, and beta is near 1, as a trained or freshly SiLU-ed projection
-    gives them: A's entries are then near 1, not near 128^-1/2.
-    `alternate`: the log decay is `g_lo` or `g_hi` and nothing between,
-    changing from each row to the next and from each channel to the next,
-    so every split of the kernel's levels, and every pair of rows inside
-    its smallest blocks, has both extremes on both of its sides."""
+def _args(length, g_lo, g_hi, seed=None, parallel=False, alternate=False,
+          dtype="float32"):
+    """What `kernel_path` takes: q, k (unit rows times a length of their
+    own in (1/e, e): the kernels norm them), v, the decay's logits a
+    channel, beta's logits, A_log and the decay's bias, the logits such
+    that the log decay is uniform in (`g_lo`, `g_hi`) and beta in (0, 1)
+    (`logits_of`). `parallel`: every q and k is one direction a head plus
+    0.3 of noise, and beta is near 1, as a trained or freshly SiLU-ed
+    projection gives them: A's entries are then near 1, not near
+    128^-1/2. `alternate`: the log decay is `g_lo` or `g_hi` and nothing
+    between, changing from each row to the next and from each channel to
+    the next, so every split of the kernel's levels, and every pair of
+    rows inside its smallest blocks, has both extremes on both of its
+    sides. `dtype`: of q, k, v and the logits (A_log and the bias are
+    parameters, float32)."""
     import jax.numpy as jnp
 
     r = np.random.RandomState(length if seed is None else seed)
 
-    def unit(t):
-        return t / np.linalg.norm(t, axis=-1, keepdims=True)
-
     def direction():
         noise = r.randn(B, length, H, D)
-        return unit(r.randn(1, 1, H, D) + 0.3 * noise if parallel else noise)
+        rows = r.randn(1, 1, H, D) + 0.3 * noise if parallel else noise
+        return (rows / np.linalg.norm(rows, axis=-1, keepdims=True)
+                * np.exp(r.uniform(-1, 1, (B, length, H, 1))))
 
     g = r.uniform(g_lo, g_hi, (B, length, H, D))
     if alternate:
         even = (np.arange(length)[:, None, None] + np.arange(D)) % 2 == 0
         g = np.broadcast_to(np.where(even, g_lo, g_hi), g.shape)
-    return [jnp.asarray(t, jnp.float32) for t in (
-        direction(), direction(), r.randn(B, length, H, D), g,
-        r.uniform(0.9 if parallel else 0, 1, (B, length, H)))]
+    q, k, v = direction(), direction(), r.randn(B, length, H, D)
+    beta = r.uniform(0.9 if parallel else 0, 1, (B, length, H))
+    a_log, dt_bias = r.uniform(-0.5, 0.5, H), r.uniform(-1, 1, (H, D))
+    raw, logits = logits_of(g, beta, a_log, dt_bias)
+    return [jnp.asarray(t, dtype) for t in (q, k, v, raw, logits)] + [
+        jnp.asarray(t, jnp.float32) for t in (a_log, dt_bias)]
 
 
 @pytest.fixture
@@ -64,40 +81,51 @@ REGIMES = [
     (127, -0.01, -1e-4, "parallel"),
     # both extremes on both sides of every split of the kernel's levels
     # and between any two rows of its blocks of 4: a factor that left its
-    # split's side would read exp(+20) there
-    (64, -20.0, 0.0, "alternate"),
+    # split's side would read exp(+20) there (-0.05 where the log decay
+    # itself was an argument and this regime had 0: a log decay of 0 is a
+    # logit of -inf, through which no gradient of the decay comes back,
+    # and all that is left to compare is the 1e-9s of the erased half)
+    (64, -20.0, -0.05, "alternate"),
     # A's entries near 1 where they are not 0: the pairs a few rows apart
     # are all that is left of them, the ones the lowest levels and the
     # blocks of 4 form
     (100, -8.0, -3.0, "parallel"),
+    # q, k, v and the logits bf16, as the projections write them under AMP
+    # (the kernels cast a block in VMEM and compute in float32), at a
+    # length that is no whole grid step and at one that is
+    (100, -1.0, -0.01, "bf16"),
+    (256, -2.0, -0.01, "bf16"),
 ]
 
 
 @pytest.mark.parametrize("length,g_lo,g_hi,kind", REGIMES)
 def test_kernel_equals_the_recurrence_and_the_plain_path(
         interpreter, length, g_lo, g_hi, kind):
-    from paddle_tpu.ops.linear_attn_ops import kda_chunked
-    from paddle_tpu.ops.pallas.kda_chunk import kda_chunk
-
+    """The kernels from the projections' arrays against the recurrence
+    and `kda_chunked`, both behind `_prologue`: outputs to 2e-6 and the
+    seven gradients as `gradients_held` holds them, the limits this test
+    had on normed q and k, the log decay and beta. The bf16 cases add the
+    one rounding of every side's outputs, 2^-9 an entry (a relative 2^-7
+    between two of them at the most), and of the gradients that leave in
+    bf16. A padded token (100, 37, 130, 127 are no whole grid steps) has
+    logits of 0, so a beta of 0.5, where the plain path pads with a beta
+    of 0: it stands behind the row's last token with q = k = v = 0 and
+    changes nothing, the sums over every row that A_log's and the bias's
+    gradients are included."""
+    bf16 = kind == "bf16"
     args = _args(length, g_lo, g_hi, parallel=kind == "parallel",
-                 alternate=kind == "alternate")
-    (got, g_got), (want, g_want), (plain, g_plain) = (
-        value_and_grads(fn, args)
-        for fn in (kda_chunk, ref.kda_recurrence, kda_chunked))
-    assert got.shape == want.shape and np.isfinite(got).all()
-    np.testing.assert_allclose(got, want, atol=2e-6)
-    np.testing.assert_allclose(got, plain, atol=2e-6)
-    for name, a, w, p in zip("q k v g beta".split(), g_got, g_want, g_plain):
-        assert np.isfinite(a).all(), name
-        # Where a token all but erases the state (the third regime) the
-        # decay's gradient is 1e-10 to 1e-8 and is what float32 leaves of
-        # q*dq + k*(dk_row - dk_col), summed back over the chunk: at this
-        # width the plain path itself reads 2.4e-4 against the recurrence
-        # there. The kernel is held to twice the plain path's own distance
-        # for `g`, and to 1e-4 wherever the plain path keeps it.
-        limit = max(1e-4, 2 * rel(p, w)) if name == "g" else 1e-4
-        assert rel(a, w) < limit, (name, rel(a, w), rel(p, w))
-        assert rel(a, p) < limit, (name, rel(a, p))
+                 alternate=kind == "alternate",
+                 dtype="bfloat16" if bf16 else "float32")
+    weight = one_cotangent(args)
+    got, g_got = value_and_grads(kernel_path, args, weight)
+    (want, g_want), (plain, g_plain) = oracles(ref.kda_recurrence, args,
+                                               weight)
+    assert got.shape == want.shape and got.dtype == want.dtype == args[2].dtype
+    got, want, plain = (np.asarray(t, np.float32) for t in (got, want, plain))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=2 ** -7 * bf16)
+    np.testing.assert_allclose(got, plain, atol=2e-6, rtol=2 ** -7 * bf16)
+    gradients_held(g_got, g_want, g_plain, args, bf16)
 
 
 @pytest.mark.parametrize("per_step", [1, 2, 4])
@@ -111,11 +139,12 @@ def test_the_state_is_carried_from_chunk_to_chunk(
 
     monkeypatch.setattr(kernel, "CHUNKS_PER_STEP", per_step)
     args = _args(320, -1e-3, -1e-5, seed=per_step)
-    got, g_got = value_and_grads(kernel.kda_chunk, args)
-    want, g_want = value_and_grads(ref.kda_recurrence, args)
+    recurrence = after_prologue(ref.kda_recurrence)
+    got, g_got = value_and_grads(kernel_path, args)
+    want, g_want = value_and_grads(recurrence, args)
     # the same rows with the first four chunks cut off: what a chunk
     # that started from a zero state would compute
-    fresh = compiled(ref.kda_recurrence, *(a[:, 256:] for a in args))
+    fresh = compiled(recurrence, *(a[:, 256:] for a in args[:5]), *args[5:])
     np.testing.assert_allclose(got, want, atol=2e-6)
     assert rel(fresh, want[:, 256:]) > 0.3  # the state matters here
     for a, w in zip(g_got, g_want):
@@ -185,19 +214,20 @@ def test_declared_cost_against_a_count_by_hand(interpreter, kernel, length):
     """ops/pallas/cost.py's convention at dk = dv = 128: the multiply-adds
     a chunk of c rows needs, listed in `kda_chunk._cost`, here written out
     for c = 64 and for the 36 rows of a short last chunk (the rows padded
-    up to whole grid steps count nothing); float32 q, k, g and beta,
-    values and output bf16, the chunks' states float32."""
+    up to whole grid steps count nothing); q and k float32 here, values,
+    output and beta's logits bf16, each moved in the dtype it arrives in
+    and its gradient in the same (beta's logits' as float32 rows); the log
+    decay, which XLA's gate hands over, its gradient and the chunks'
+    states float32."""
     import jax
     import jax.numpy as jnp
     from pallas_costs import declared
 
-    from paddle_tpu.ops.pallas.kda_chunk import kda_chunk
-
-    q, k, v, g, beta = _args(length, -1.0, -0.01)
-    v = v.astype(jnp.bfloat16)
+    q, k, v, g, beta, a_log, dt_bias = _args(length, -1.0, -0.01)
+    v, beta = v.astype(jnp.bfloat16), beta.astype(jnp.bfloat16)
     found = declared(jax.grad(lambda *a: jnp.sum(
-        kda_chunk(*a).astype(jnp.float32)), argnums=range(5)),
-        q, k, v, g, beta)
+        kernel_path(*a).astype(jnp.float32)), argnums=range(7)),
+        q, k, v, g, beta, a_log, dt_bias)
     (got,) = found[kernel]
 
     def macs(c):
@@ -217,14 +247,15 @@ def test_declared_cost_against_a_count_by_hand(interpreter, kernel, length):
     # a chunk's exponentials over the 128 lanes: exp(G) and exp(G_c - G)
     # 64 rows each, exp(G_c) 1, the rows' factors at each of the four
     # levels (blocks of 64, 32, 16, 8 rows) and the decays at the three
-    # distances inside a block of 4 rows, 64 rows each
-    assert got.transcendentals == B * H * 2 * D * (64 + 64 + 1 + 4 * 64
-                                                   + 3 * 64)
+    # distances inside a block of 4 rows, 64 rows each; and the prologue's
+    # a token of a head: two rsqrt and beta's sigmoid
+    assert got.transcendentals == B * H * (
+        2 * D * (64 + 64 + 1 + 4 * 64 + 3 * 64) + 3 * length)
     wide, narrow = 4 * B * length * H * D, 2 * B * length * H * D
-    beta_bytes, states = 4 * B * length * H, 4 * B * H * 2 * D * D
-    moved = 3 * wide + narrow + beta_bytes + states + narrow  # ..., o or dO
-    if kernel == "kda_bwd":  # dq, dk, dg, dv, dbeta
-        moved += 3 * wide + narrow + beta_bytes
+    logits, states = 2 * B * length * H, 4 * B * H * 2 * D * D
+    moved = 3 * wide + narrow + logits + states + narrow  # ..., o or dO
+    if kernel == "kda_bwd":  # dq, dk, dg, dv, dbeta (float32 rows)
+        moved += 3 * wide + narrow + 2 * logits
     assert got.bytes_accessed == moved
 
 
@@ -236,7 +267,11 @@ def test_bf16_products_against_the_float32_recurrence(interpreter):
     nearly parallel keys, so A's entries are near 1 and the solve
     amplifies what they lost.
 
-    Distances read here, relative, in the order o, dq, dk, dv, dg, dbeta.
+    Distances read here, relative, in the order o, dq, dk, dv, dg, dbeta
+    (before PR 65, on normed q and k, the log decay and beta themselves;
+    since, from the projections' arrays, with A_log's and the bias's
+    behind them: 0.0116, 0.0111, 0.0112, 0.0139, 0.0107, 0.0100, 0.0107,
+    0.0107).
     The sub-chunk scheme of PR 32 to 49, whose diagonal blocks of 16 were
     float32 sums: 0.0115, 0.0099, 0.0101, 0.0138, 0.0097, 0.0103. The
     levels, where every pair outside a block of 4 is a product: 0.0116,
@@ -245,30 +280,30 @@ def test_bf16_products_against_the_float32_recurrence(interpreter):
     import jax
     import jax.numpy as jnp
 
+    from paddle_tpu.ops.linear_attn_ops import kda_gate
     from paddle_tpu.ops.pallas import kda_chunk as kernel
 
-    q, k, v, g, beta = _args(127, -0.01, -1e-4, parallel=True)
-    flat = [t.reshape(B, 127, -1) for t in (q, k, v, g)]
-    flat = [jnp.pad(t, ((0, 0), (0, 1), (0, 0))) for t in flat]
-    beta_p = jnp.pad(beta, ((0, 0), (0, 1), (0, 0)))
+    args = _args(127, -0.01, -1e-4, parallel=True)
     statics = (H, 2, jnp.bfloat16, True, 127)
 
-    def bf16(q, k, v, g, beta):
-        return kernel._core(q, k, v, g, beta, statics)
+    def bf16(q, k, v, g, beta, a_log, dt_bias):
+        # as `kda_mixer_core` hands a decay a channel over: gated by XLA
+        flat = [jnp.pad(t.reshape(B, 127, -1), ((0, 0), (0, 1), (0, 0)))
+                for t in (q, k, v, kda_gate(g.reshape(B, 127, -1), a_log,
+                                            dt_bias.reshape(-1), H), beta)]
+        return kernel._core(*flat, (), statics)[:, :127].reshape(v.shape)
 
     def grads(fn, args):
         return jax.grad(lambda *a: jnp.sum(fn(*a) ** 2),
-                        argnums=range(5))(*args)
+                        argnums=range(7))(*args)
 
-    got = (bf16(*flat, beta_p), *grads(bf16, (*flat, beta_p)))
-    out, gradients = value_and_grads(ref.kda_recurrence, (q, k, v, g, beta))
-    want = (out, *gradients)
+    got = (bf16(*args), *grads(bf16, args))
+    out, gradients = value_and_grads(after_prologue(ref.kda_recurrence), args)
     read = []
-    for a, w in zip(got, want):
-        a = np.asarray(a)[:, :127].reshape(w.shape)
-        assert np.isfinite(a).all()
+    for a, w in zip(got, (out, *gradients)):
+        assert a.shape == w.shape and np.isfinite(np.asarray(a)).all()
         read.append(rel(a, w))
-    for name, distance in zip("o q k v g beta".split(), read):
+    for name, distance in zip(("o", *OPERANDS), read):
         assert distance < 0.028, (name, read)
 
 
@@ -412,11 +447,26 @@ def test_any_width_of_the_lockstep_is_the_pair_at_one_chunk_a_step(
     three; 192 + 1: one row of a fourth; 5 x 64 + 7: a sixth chunk of
     seven rows, two grid steps of four with two padded chunks), with 1, 2
     or 4 chunks a grid step the same products read the same operands and
-    the stacked rows are each chunk's own: the outputs and the five
-    gradients are equal bit for bit, whichever chunks share a step and
-    however many padded ones follow."""
+    the stacked rows are each chunk's own, whichever chunks share a step
+    and however many padded ones follow (`pair_at_widths` says how each
+    width is traced afresh and shown to differ from the other).
+
+    Equal to the bit across the widths (`np.array_equal`): the output and
+    every array the backward kernel writes, as its call returns them, on
+    the float32 log decay `kda_gate` made once in front of both widths:
+    dq and dk (the norm's gradient applied in the kernel), dv, dg (the
+    log decay's, `[b, S, h*128]` float32) and the rows a chunk of beta's
+    logits' gradient (through the sigmoid, in the kernel).
+
+    Held at each width to the recurrence and to `kda_chunked` behind
+    `_prologue`, at this file's limits (`gradients_held`: 1e-4, the
+    decay's three twice the plain path's own distance where that is
+    more), and not to the other width: the seven gradients as the op
+    returns them, of which XLA forms, after the kernels, beta's logits' by
+    token and the decay's logits', A_log's and the bias's through
+    `kda_gate`'s backward and its sums over the tokens."""
     pair_at_widths(_args(length, -1.0, -0.01, seed=length), per_step,
-                   monkeypatch)
+                   monkeypatch, ref.kda_recurrence)
 
 
 @pytest.mark.parametrize("seq,chunks", [(8, 1), (64, 1), (65, 2), (150, 3),

@@ -296,17 +296,55 @@ def test_kernels_per_shard_compile_for_four_v5e_chips(topo, monkeypatch):
     assert "ln_bwd" in text and "all-reduce" in text
 
 
+def _custom_call_types(text, name):
+    """Of each custom call named `name` in an optimised HLO module: (its
+    operands', its results') types as the module prints them, in order,
+    `bf16[1,4096,2048]` (the operands' from the call's
+    `operand_layout_constraints`, where the line has them by type)."""
+    def types(part):
+        return [f"{kind}[{shape}]" for kind, shape in
+                re.findall(r"\b([a-z]+[0-9]+)\[([0-9,]*)\]", part)]
+
+    found = []
+    for line in text.splitlines():
+        head, call, rest = line.partition(" custom-call(")
+        if not call or name not in head:
+            continue
+        operands = re.search(
+            r"operand_layout_constraints=\{(.*?)\}, [a-z_]+=", rest)
+        found.append((types(operands[1]), types(head.split("=", 1)[1])))
+    return found
+
+
+def _delta_rule_pair(statics, operands):
+    """The delta-rule pair under `jax.vjp` on `operands`, compiled for
+    their chip: (the optimised module's text, its memory analysis)."""
+    from paddle_tpu.ops.pallas import kda_chunk
+
+    def both(*operands):
+        o, pull = jax.vjp(lambda *a: kda_chunk._core(*a, statics), *operands)
+        return o, pull(o)
+
+    compiled = jax.jit(both).lower(*operands).compile()
+    return compiled.as_text(), compiled.memory_analysis()
+
+
 @pytest.mark.parametrize("hk,per_head", [(32, False), (16, True)],
                          ids=["kda", "gdn"])
 def test_kda_kernels_compile_for_a_v5e_chip_at_the_published_widths(
         topo, hk, per_head):
     """The delta-rule chunk kernels (ops/pallas/kda_chunk.py) at the
     shapes of the cells that run them: one 4,096-token row, 32 heads of
-    128, bf16 products and values, forward and backward; Kimi's call (a
-    decay a channel, a key head a value head) and Qwen3-Next's (a decay a
-    head, 16 key heads, q and k read at `[1, 4096, 2048]`). Here with this
-    file's topology because one process of a test run can describe it (the
-    kernels' mathematics is tests/test_kda_kernel.py's and
+    128, bf16 products, q, k, v and the logits bf16 as the projections
+    write them under AMP (since PR 65 the kernels make the norms, beta
+    and a head's decay of them in VMEM), forward and backward; Kimi's
+    call (a decay a channel, gated by XLA: the float32 log decay is the
+    operand; a key head a value head) and Qwen3-Next's (a decay a head,
+    its logits under A_log and the bias `[1, 32]` float32; 16 key heads,
+    q and k read at `[1, 4096, 2048]`). The compiled calls' operands and
+    results are read back: what crosses HBM, and in which dtype. Here with
+    this file's topology because one process of a test run can describe
+    it (the kernels' mathematics is tests/test_kda_kernel.py's and
     tests/test_gdn_kernel.py's). Nothing runs."""
     from jax.sharding import SingleDeviceSharding
 
@@ -317,32 +355,44 @@ def test_kda_kernels_compile_for_a_v5e_chip_at_the_published_widths(
     statics = kda_chunk._Statics(h, kda_chunk.CHUNKS_PER_STEP, jnp.bfloat16,
                                  False, s, h // hk, per_head)
 
-    def sds(shape, dtype=jnp.float32):
+    def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    x, v = sds((b, s, hk * d)), sds((b, s, h * d), jnp.bfloat16)
-    g = sds((b, s, h)) if per_head else sds((b, s, h * d))
-
-    def both(q, k, v, g, beta):
-        o, pull = jax.vjp(
-            lambda *a: kda_chunk._core(*a, statics), q, k, v, g, beta)
-        return o, pull(o)
-
-    compiled = jax.jit(both).lower(x, x, v, g, sds((b, s, h))).compile()
-    text = compiled.as_text()
+    x, v, logits = sds((b, s, hk * d)), sds((b, s, h * d)), sds((b, s, h))
+    numbers = sds((1, h), jnp.float32)
+    g, gate = (logits, (numbers, numbers)) if per_head else (
+        sds((b, s, h * d), jnp.float32), ())
+    text, memory = _delta_rule_pair(statics, (x, x, v, g, logits, gate))
     names = ("gdn_fwd", "gdn_bwd") if per_head else ("kda_fwd", "kda_bwd")
     assert all(name in text for name in names)
     assert ("kda_fwd" in text) != per_head
+    keys, values, heads = (f"bf16[{b},{s},{hk * d}]", f"bf16[{b},{s},{h * d}]",
+                           f"bf16[{b},{s},{h}]")
+    wide, each = f"f32[{b},{s},{h * d}]", f"f32[1,{h}]"
+    states, rows = (f"f32[{b * h},{s // 64},{d},{d}]",
+                    f"f32[{b * h},{s // 64},1,64]")
+    if per_head:  # dq, dk float32 a value head: XLA adds a group's
+        decay, grads = [heads, heads, each, each], [wide, wide, values,
+                                                    rows, rows, rows]
+    else:  # dq, dk bf16 as q, k arrived; the log decay's float32
+        decay, grads = [wide, heads], [keys, keys, values, wide, rows]
+    ((ins, outs),) = _custom_call_types(text, names[0])
+    assert ins == [keys, keys, values] + decay and outs == [values, states]
+    ((ins, outs),) = _custom_call_types(text, names[1])
+    assert ins == [keys, keys, values] + decay + [states, values]
+    assert outs == grads
     # the operands, their gradients and the 134 MB of chunk states
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert memory.temp_size_in_bytes < 1 << 30
 
 
 def test_gdn_kernels_compile_for_a_v5e_chip_at_heads_that_are_no_tile(topo):
     """The pair at Olmo-Hybrid's shape: one 4,096-token row, 30 heads of 96
     key lanes and 192 value lanes, four heads a grid step (blocks of 384
     and 768 lanes, the eighth step's hanging over the arrays' edge), each
-    head's lanes cut out and padded to whole tiles in VMEM: Mosaic takes
-    the slices at lanes 96, 192 and 288 and the stores back to them.
+    head's lanes cut out, padded to whole tiles and normed over its 96
+    lanes in VMEM, beta 2 sigmoid: Mosaic takes the slices at lanes 96,
+    192 and 288 and the stores back to them, and every operand and
+    gradient by token is bf16 as the projections wrote it (PR 65).
     Nothing runs."""
     from jax.sharding import SingleDeviceSharding
 
@@ -352,25 +402,31 @@ def test_gdn_kernels_compile_for_a_v5e_chip_at_heads_that_are_no_tile(topo):
     b, s, h, dk, dv = 1, 4096, 30, 96, 192
     held = kda_chunk.layout(h, dk, dv)[0]
     statics = kda_chunk._Statics(h, kda_chunk.lockstep_chunks(s, held),
-                                 jnp.bfloat16, False, s, 1, True, held)
+                                 jnp.bfloat16, False, s, 1, True, held, 1e-6,
+                                 2.0)
 
-    def sds(shape, dtype=jnp.float32):
+    def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    x, v, g = sds((b, s, h * dk)), sds((b, s, h * dv), jnp.bfloat16), sds(
-        (b, s, h))
-
-    def both(q, k, v, g, beta):
-        o, pull = jax.vjp(
-            lambda *a: kda_chunk._core(*a, statics), q, k, v, g, beta)
-        return o, pull(o)
-
-    compiled = jax.jit(both).lower(x, x, v, g, g).compile()
-    text = compiled.as_text()
+    x, v, g = sds((b, s, h * dk)), sds((b, s, h * dv)), sds((b, s, h))
+    numbers = sds((1, h), jnp.float32)
+    text, memory = _delta_rule_pair(statics, (x, x, v, g, g,
+                                              (numbers, numbers)))
     assert "gdn_fwd" in text and "gdn_bwd" in text and "kda_fwd" not in text
+    keys, values, heads = (f"bf16[{b},{s},{h * dk}]",
+                           f"bf16[{b},{s},{h * dv}]", f"bf16[{b},{s},{h}]")
+    each, rows = f"f32[1,{h}]", f"f32[{b},{h},{s // 64},1,64]"
+    states = f"f32[{b},{h},{s // 64},{dv},{dk}]"
+    ((ins, outs),) = _custom_call_types(text, "gdn_fwd")
+    assert ins == [keys, keys, values, heads, heads, each, each]
+    assert outs == [values, states]
+    ((ins, outs),) = _custom_call_types(text, "gdn_bwd")
+    assert ins == [keys, keys, values, heads, heads, each, each, states,
+                   values]
+    assert outs == [keys, keys, values, rows, rows, rows]
     # the operands, their gradients and the chunks' states (a state's 96
     # lanes lie in whole tiles in HBM: 189 MB where 141 are its numbers)
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert memory.temp_size_in_bytes < 1 << 30
 
 
 @pytest.mark.parametrize("theta,scaling", [
@@ -976,10 +1032,12 @@ def test_qwen3_next_step_compiled_for_v5e_takes_its_kernels_and_fits(
         qwen3_next_step):
     """`qwen3_next_ep16_s4096`'s whole train step: the three Gated
     DeltaNet layers run `gdn_fwd` and `gdn_bwd` on q and k as the
-    convolution wrote them, `[1, 4096, 2048]` for 16 key heads, and on a
-    `[1, 4096, 32]` decay, with no float32 decay a channel anywhere in the
-    step; each layer's convolution backward is one kernel call over the
-    8,192 channels; the attention layer's 256-lane heads go through
+    convolution wrote them, bf16 `[1, 4096, 2048]` for 16 key heads, and
+    on the decay's and beta's bf16 `[1, 4096, 32]` logits (PR 65: no
+    float32 copy of q, k, beta or the decay is among the kernels'
+    operands), with no float32 decay a channel anywhere in the step;
+    each layer's convolution backward is one kernel call over the 8,192
+    channels; the attention layer's 256-lane heads go through
     `qk_prep` with 64 lanes turned and the flash kernels' one-visit
     backward; the four expert layers' products at 2,048 x 512 are the
     Pallas pair under a softmax router; and the step is under 14 GB of a
@@ -1009,9 +1067,18 @@ def test_qwen3_next_step_compiled_for_v5e_takes_its_kernels_and_fits(
     assert "flash_bwd_dq" not in text  # the backward is the one kernel
     calls = [line for line in text.splitlines()
              if " custom-call(" in line and "gdn_" in line]
-    assert len(calls) == 6 and all("f32[1,4096,2048]" in line
-                                   and "f32[1,4096,32]" in line
-                                   for line in calls)
+    assert len(calls) == 6
+    for name, behind in (("gdn_fwd", []), ("gdn_bwd", [
+            "f32[32,64,128,128]", "bf16[1,4096,4096]"])):
+        found = _custom_call_types(text, name)
+        assert len(found) == 3
+        for ins, outs in found:
+            assert ins == ["bf16[1,4096,2048]"] * 2 + [
+                "bf16[1,4096,4096]"] + ["bf16[1,4096,32]"] * 2 + [
+                "f32[1,32]"] * 2 + behind, (name, ins)
+            if behind:  # dq, dk float32 a value head, dv, three of rows
+                assert outs == ["f32[1,4096,4096]"] * 2 + [
+                    "bf16[1,4096,4096]"] + ["f32[32,64,1,64]"] * 3, outs
     # under the op's scopes no decay a channel, as a head's array or side
     # by side, and no repeated q or k: the only float32 arrays as wide as
     # the value heads are dq and dk as `gdn_bwd` writes them (the gated

@@ -12,8 +12,14 @@ the kernels' body (the solve in lockstep over the chunks of a grid
 step: `ops/pallas/kda_chunk.py`), and again at PR 54 (a grid step's row
 arithmetic once over its stacked rows, four chunks a step); each re-took
 that digest on its finished tree; the results are the parent's bit for
-bit (`tests/test_kda_kernel.py`), the jaxpr is not. `kda-chunked` and the
-ten others are PR 51's parent's.
+bit (`tests/test_kda_kernel.py`), the jaxpr is not. PR 65 re-took it
+once more, on purpose: the kernels now take q, k and beta's logits as the
+projections wrote them and make the L2 norms and beta in VMEM
+(`_gate_rows`; the channel's decay stays XLA's `kda_gate`), so the call's
+operands and body moved; the results are the plain path's at the
+distances `tests/test_kda_kernel.py` holds. `kda-chunked` stands
+untouched by it (the op, `_prologue` and the plain path did not change),
+and it and the ten others are PR 51's parent's.
 
 PR 53 gave `moe_experts` a second input and an expert form, `attention`
 a switch for the QK-norm, `proj` a deviation of its own and
@@ -163,7 +169,7 @@ CASES = {
 # as PR 51's parent (commit 6f8ecfe) traces them
 PARENTS_JAXPRS = {
     "kda-chunked": "ef5c1d772c23b254",
-    "kda-kernels": "481ae08507f6846f",  # PR 54's tree: see the docstring
+    "kda-kernels": "6b405d73f7deeba2",  # PR 65's tree: see the docstring
     "trinity_full-xla": "1160994003f6c88b",
     "trinity_window-xla": "9e6c7e0895897a9a",
     "mellum-xla": "9e7d571745830a91",

@@ -23,11 +23,14 @@ PR 54): the count ranks two bodies, it does not predict seconds.
     JAX_PLATFORMS=cpu python tools/kda_vreg_count.py [path/to/kda_chunk.py]
 
 (the path: another copy of the kernel file, say a parent commit's, as long
-as it has this tree's functions.)
+as it has this tree's functions: a copy from before PR 65, whose kernels
+took q, k, the log decay and beta float32 behind XLA's prologue, wants
+that commit's copy of this tool.)
 
 The compiler's own schedule of a grid step, `--schedule <dir>`: the pair
-at the cells' shapes (one row of 4,096 tokens, 32 heads of 128, bf16
-values; a decay a channel, then a decay a head under 16 key heads)
+at the cells' shapes (one row of 4,096 tokens, 32 heads of 128, q, k, v
+and the logits bf16 as the projections write them under AMP; a decay a
+channel, gated float32 by XLA, then a decay a head under 16 key heads)
 compiled for a described v5e with
 
     LIBTPU_INIT_ARGS="--xla_jf_dump_to=<dir> --xla_jf_dump_llo_text=true"
@@ -145,7 +148,7 @@ def registers_a_chunk(kernel):
 
     def chunk_fwd(q, k, v, g, beta, St):
         free, _ = state_free(q, k, v, g, beta, False)
-        return kernel._chunk_fwd(free, St, dtype=dtype)
+        return kernel._chunk_fwd(free, St, dtype=dtype, scale=128 ** -0.5)
 
     def chunk_bwd(q, k, v, g, beta, St, dSt, dO):
         free, masks = state_free(q, k, v, g, beta, True)
@@ -174,6 +177,26 @@ def equations(jaxpr):
     return total
 
 
+def _pair(kernel, statics, sds, b, s, key_heads, heads, d):
+    """The pair under `jax.vjp` from the arrays `kda_mixer_core` hands it
+    under AMP (q, k, v and the logits bf16; with a decay a head A_log and
+    the decay's bias float32 beside its logits, with a decay a channel
+    the float32 log decay that XLA's gate made: since PR 65 the kernels
+    make the norms, beta and a head's decay themselves), and those arrays'
+    shapes by `sds(shape, dtype)`."""
+    def both(*operands):
+        o, pull = jax.vjp(lambda *a: kernel._core(*a, statics), *operands)
+        return o, pull(o)
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    keys, values = sds((b, s, key_heads * d), bf16), sds((b, s, heads * d),
+                                                         bf16)
+    logits, numbers = sds((b, s, heads), bf16), sds((1, heads), f32)
+    if statics.per_head:
+        return both, (keys, keys, values, logits, logits, (numbers, numbers))
+    return both, (keys, keys, values, sds((b, s, heads * d), f32), logits, ())
+
+
 def kernel_equations(kernel, steps, per_head=False):
     """Kernel name -> `equations` of its body with `steps` chunks a grid
     step: the pair traced once, a row of two grid steps, two heads (under
@@ -183,14 +206,8 @@ def kernel_equations(kernel, steps, per_head=False):
     s = 2 * steps * kernel.CHUNK
     statics = kernel._Statics(heads, steps, jnp.bfloat16, False, s,
                               heads // key_heads, per_head)
-    x = jax.ShapeDtypeStruct((1, s, key_heads * d), jnp.float32)
-    column = jax.ShapeDtypeStruct((1, s, heads), jnp.float32)
-    wide = jax.ShapeDtypeStruct((1, s, heads * d), jnp.float32)
-
-    def both(*args):
-        o, pull = jax.vjp(lambda *a: kernel._core(*a, statics), *args)
-        return pull(o)
-
+    both, args = _pair(kernel, statics, jax.ShapeDtypeStruct, 1, s,
+                       key_heads, heads, d)
     found = {}
 
     def walk(jaxpr):
@@ -201,9 +218,7 @@ def kernel_equations(kernel, steps, per_head=False):
                 for sub in _inner(eqn):
                     walk(sub)
 
-    walk(jax.make_jaxpr(both)(
-        x, x, wide.update(dtype=jnp.bfloat16), column if per_head else wide,
-        column).jaxpr)
+    walk(jax.make_jaxpr(both)(*args).jaxpr)
     return found
 
 
@@ -228,22 +243,13 @@ def compile_for_v5e(kernel, chunks):
     chip = described_chip()
     b, s, h, d = 1, 4096, 32, 128
 
-    def sds(shape, dtype=jnp.float32):
+    def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
     def pair(hk, per_head):
         statics = kernel._Statics(h, chunks, jnp.bfloat16, False, s, h // hk,
                                   per_head)
-
-        def both(q, k, v, g, beta):
-            o, pull = jax.vjp(lambda *a: kernel._core(*a, statics), q, k, v,
-                              g, beta)
-            return o, pull(o)
-
-        x = sds((b, s, hk * d))
-        return both, (x, x, sds((b, s, h * d), jnp.bfloat16),
-                      sds((b, s, h)) if per_head else sds((b, s, h * d)),
-                      sds((b, s, h)))
+        return _pair(kernel, statics, sds, b, s, hk, h, d)
 
     (kda, kda_args), (gdn, gdn_args) = pair(h, False), pair(h // 2, True)
     jax.jit(lambda a, b: (kda(*a), gdn(*b))).lower(
