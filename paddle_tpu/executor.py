@@ -161,9 +161,25 @@ class _CompiledStep:
         self.state_names = state_names
         self.feed_names = feed_names
         self.fetch_names = fetch_names
+        # the device counts the step returns, as one int32 array after its
+        # last fetch (`_device_count_names`); () where it makes none
+        self.count_names: tuple = ()
         # name -> the NamedSharding the step reads that feed with; a mesh
         # compile fills it, one device has none to state
         self.feed_shardings: dict = {}
+
+
+def _device_count_names(block) -> tuple:
+    """The device counts the block's own ops declare
+    (`register_op(..., device_counts=...)`), sorted: what the plain step
+    returns after its fetches, known from the Program alone, so that a
+    step loaded from `step_store` has its names too. An op inside a loop's
+    or a scan's body is not among them: its context drops what it counts
+    (`LoweringContext.count`)."""
+    from .ops.registry import get_op, has_op
+
+    return tuple(sorted({name for op in block.ops if has_op(op.type)
+                         for name in get_op(op.type).device_counts}))
 
 
 def _step_owner(block) -> str:
@@ -818,6 +834,10 @@ class Executor:
             # batch over batch x pipe (pipe-sharded training params are
             # re-gathered by GSPMD automatically)
             batch_axes = tuple(dict.fromkeys(tuple(batch_axes) + ("pipe",)))
+        # the plain step alone carries its device counts out; in the two
+        # other forms a context has none to add to, and says so
+        # (`device_counts_dropped`)
+        count_names = ()
         if micro > 1:
             step = self._make_microbatched_step(
                 program, block, feed_names, fetch_names, state_names,
@@ -830,6 +850,8 @@ class Executor:
             )
         else:
             check_nan = os.environ.get("PADDLE_TPU_CHECK_NAN_INF") == "1"
+            if not check_nan:  # whose third result is the flags
+                count_names = _device_count_names(block)
 
             nan_names: list = []  # filled at trace time, execution order
 
@@ -840,10 +862,22 @@ class Executor:
                 if check_nan:
                     # FLAGS_check_nan_inf analog (operator.cc:949-961)
                     ctx.nan_flags = {}
+                if count_names:
+                    ctx.device_counts = {}
                 ctx.values.update(state)
                 ctx.values.update(feeds)
                 lower_block(ctx, block)
                 fetches = [ctx.get(n) for n in fetch_names]
+                if count_names:
+                    undeclared = set(ctx.device_counts) - set(count_names)
+                    if undeclared:
+                        raise RuntimeError(
+                            f"device counts {sorted(undeclared)} are counted "
+                            "by a lowering whose op does not declare them "
+                            "(register_op(..., device_counts=...))")
+                    fetches.append(jnp.stack([
+                        jnp.asarray(ctx.device_counts.get(n, 0), jnp.int32)
+                        for n in count_names]))
                 new_state = {
                     n: ctx.values[n] if n in ctx.values else state[n]
                     for n in state_names
@@ -860,6 +894,7 @@ class Executor:
         def finish(compiled, jit_kwargs):
             compiled.nan_names = getattr(step, "_nan_names", None)
             compiled.written_only = written_only
+            compiled.count_names = count_names
             return _instrument_compiled(
                 compiled, block, asked and functools.partial(
                     _store_key, handed, program, asked, is_test, jit_kwargs))
@@ -926,7 +961,8 @@ class Executor:
             profiler.set_counter("collective_bytes_estimate", est)
 
             out_sh = [
-                [NamedSharding(mesh, P())] * len(fetch_names),
+                [NamedSharding(mesh, P())] * (
+                    len(fetch_names) + bool(count_names)),
                 state_sh,
             ]
             if (
@@ -1077,6 +1113,8 @@ class Executor:
             fetches, new_state = check_nan_result(result, compiled, scope)
         else:
             fetches, new_state = result
+        if compiled.count_names:
+            profiler.hold_device_counts(compiled.count_names, fetches.pop())
         self._step_boundary(program, scope, new_state, mgr)
         if return_numpy:
             return [np.asarray(f) for f in fetches]
@@ -1341,6 +1379,9 @@ class Executor:
         if first:  # kept once it has run: a scan whose first call raised
             # is built, and filed under its owner, again
             self._multi_cache[multi_key] = multi
+        if compiled.count_names:  # stacked like a fetch: [steps, counts]
+            *stacked, counts = stacked
+            profiler.hold_device_counts(compiled.count_names, counts)
         # advance only on success: a failed trace must not skip PRNG
         # counters (the N-consecutive-run() equivalence contract)
         self._seed_counter += steps

@@ -38,7 +38,9 @@ def _moe_ffn(ctx, op):
     ctx.out(op, "AuxLoss", aux.reshape(1))
 
 
-@register_op("moe_experts", no_grad_inputs=("Bias",))
+@register_op("moe_experts", no_grad_inputs=("Bias",),
+             device_counts=("moe_rows_routed", "moe_rows_live",
+                            "moe_blocks_run"))
 def _moe_experts(ctx, op):
     """X: [..., D]; Gate: [D, experts_total]; Bias: [experts_total], the
     router's correction (selection only, no gradient); attr `score_func`
@@ -54,9 +56,18 @@ def _moe_experts(ctx, op):
     Attr `expert_form` (optional): absent, the experts are SiLU-gated,
     `W_down (silu(W_gate x) * W_up x)`; "relu2": there is no WGate and an
     expert is `W_down relu(W_up x)^2`. `moe_assignments` counts the
-    tokens times k of each lowering."""
+    tokens times k of each lowering.
+
+    Three counts the step itself makes (`ctx.count`: data, read from
+    `profiler.counters()` once the step has run), once an op and not in
+    its gradient op's replay: `moe_rows_routed`, tokens times k;
+    `moe_rows_live`, the assignments the held experts took (`sum(Load)`);
+    `moe_blocks_run`, the first block and every trip of the overflow
+    loop."""
+    import jax.numpy as jnp
+
     from .. import profiler
-    from ..parallel.moe import _block_rows, moe_experts
+    from ..parallel.moe import _block_count, _block_rows, moe_experts, stage
     from .pallas.grouped_matmul import grouped_matmul_viable
     from .pallas.on_mesh import batch_shards
 
@@ -84,22 +95,20 @@ def _moe_experts(ctx, op):
             f"moe_experts: WUp {w_up.shape} does not take the experts' "
             f"input {read.shape} (the router's X is {x.shape})")
     profiler.bump_counter("moe_dispatch_grouped")
-    # the first block is straight-line in every lowering, so that XLA merges
-    # the forward op's with the one the gradient op replays
-    profiler.bump_counter("moe_first_block_shared")
     profiler.set_counter("moe_experts_held", held)
     profiler.set_counter("moe_experts_total", total)
     score_func = op.attr("score_func", "sigmoid")
     if score_func == "softmax":
         profiler.bump_counter("moe_route_softmax")
     k = op.attr("k")
-    profiler.bump_counter("moe_assignments", x.size // x.shape[-1] * k)
+    routed = x.size // x.shape[-1] * k
+    profiler.bump_counter("moe_assignments", routed)
     if experts_x is not None:
         profiler.set_counter("moe_latent_width", int(experts_x.shape[-1]))
     # rows of the first, straight-line block of this layer's N*k sorted
     # assignments: it follows the share held
-    profiler.set_counter(
-        "moe_block_rows", _block_rows(x.size // x.shape[-1] * k, held / total))
+    block_rows = _block_rows(routed, held / total)
+    profiler.set_counter("moe_block_rows", block_rows)
     # the router is float32 inside moe_route; the grouped products ride
     # the amp dtype, cast inside (both operands), as in moe_ffn
     compute_dtype = ctx.amp_dtype_for(op)
@@ -119,3 +128,8 @@ def _moe_experts(ctx, op):
         norm_eps=op.attr("norm_eps", 0.0), experts_x=experts_x)
     ctx.out(op, "Out", y)
     ctx.out(op, "Load", load)
+    with stage("moe.sort"):  # where the load is made
+        ctx.count("moe_rows_routed", routed)
+        ctx.count("moe_rows_live", lambda: jnp.sum(load))
+        ctx.count("moe_blocks_run", lambda: jnp.maximum(
+            _block_count(load, block_rows), 1))

@@ -68,6 +68,7 @@ class OpDef:
         stateful_outputs=(),
         differentiable=True,
         name_attrs=(),
+        device_counts=(),
     ):
         self.type = type
         self.lower = lower
@@ -88,6 +89,11 @@ class OpDef:
         # current case (rng_name keys mask regeneration, never a value
         # read)
         self.name_attrs = tuple(name_attrs)
+        # the device counts its lowering adds to (`LoweringContext.count`):
+        # the Executor reads them off the block before it traces anything,
+        # so a step knows its counts' names, and that it has any, on a warm
+        # start too
+        self.device_counts = tuple(device_counts)
         # static shape/dtype inference function (register_shape), or None.
         # Signature mirrors the lowering: fn(ictx, op) sets output VarMetas
         # on an analysis.shape_infer.InferContext instead of JAX values.
@@ -196,6 +202,14 @@ class LoweringContext:
         # enabled, every float op output contributes an all-finite flag the
         # executor checks host-side after the step
         self.nan_flags: dict[str, object] | None = None
+        # counts the step makes on the device (`count`): name -> traced
+        # int32 scalar. None, as here, in every context whose tracers
+        # nobody carries out of the step: a loop's or a scan's body, a
+        # recompute segment, a micro-batch, a NaN-checked step. The plain
+        # step (executor.py) gives its own context a dict and returns it.
+        self.device_counts: dict[str, object] | None = None
+        # `__auto_grad__`'s replay of a forward op that has counted already
+        self.replays = False
 
     # -- value access -------------------------------------------------------
     def get(self, name):
@@ -233,6 +247,28 @@ class LoweringContext:
                 jnp.issubdtype(value.dtype, jnp.floating)
             ):
                 self.nan_flags[names[idx]] = jnp.all(jnp.isfinite(value))
+
+    def count(self, name, value):
+        """Add `value` to the step's device count `name`, which the op has
+        to declare (`register_op(..., device_counts=...)`): an integer, or
+        a function that makes an integer scalar of data (an expert layer's
+        load), called only where the count is carried, so that a step
+        which drops it traces nothing for it. The step hands the sums back
+        beside its fetches and the Executor folds them into
+        `profiler.counters()` under the same name once the step has run:
+        `profiler.bump_counter` runs while tracing and cannot see data. A
+        gradient op's replay counts nothing (its forward op has, and a
+        tracer may not leave the `vjp`); any other context that cannot
+        carry a count out says so, once a count: `device_counts_dropped`."""
+        if self.replays:
+            return
+        if self.device_counts is None:
+            profiler.bump_counter("device_counts_dropped")
+            return
+        if callable(value):
+            value = value()
+        self.device_counts[name] = (self.device_counts.get(name, 0)
+                                    + jnp.asarray(value, jnp.int32))
 
     def next_rng(self):
         if self.rng_key is None:
@@ -475,6 +511,7 @@ def _auto_grad_lower(ctx, op):
 
     def fwd_fn(*dvals):
         sub = ctx.child()
+        sub.replays = True  # `count`: the forward op has counted
         for (slot, i, n, v) in all_in:
             sub.set(n, v)
         for (slot, i, n), dv in zip(diff_in, dvals):

@@ -184,6 +184,29 @@ def moe_ffn(params, x, capacity_factor=1.25, k=2, compute_dtype=None):
 BLOCK_FLOOR = 4  # at least 1/4 of the N*k assignments
 BLOCK_ROOM = 1.75  # times the balanced load of the share held
 
+# What a device trace calls the parts of `moe_experts`, one level below the
+# op's scope: `fwd/moe_experts/moe.route/...`,
+# `bwd/moe_experts_grad/transpose(jvp(moe.gather))/...`. Every operation
+# traced from here lies under one of them; inside `_overflow`'s loops the
+# innermost counts (`moe.combine/while/body/moe.gather/...` is the gather's).
+# PERF.md, section 3, says which metric reads them.
+STAGES = (
+    "moe.route",     # the float32 logits, the scores, top-k, the weights
+    "moe.sort",      # held mask, keys, argsort, the load, the weights permuted
+    "moe.gather",    # a block's part of each group, its rows of x and weight
+    "moe.products",  # the grouped products and the activation between them
+    "moe.combine",   # the weighting and the scatter-add onto the tokens; the
+                     # overflow loops' own counting and sums
+)
+
+
+def stage(name):
+    """The `jax.named_scope` of one of `STAGES`: names in the HLO's
+    metadata, and no computation."""
+    if name not in STAGES:
+        raise ValueError(f"no stage {name!r} of moe_experts: {STAGES}")
+    return jax.named_scope(name)
+
 
 def moe_route(x, gate, bias, k, scaling, renormalize=True,
               score_func="sigmoid", norm_eps=0.0):
@@ -236,15 +259,16 @@ def _block(j, rows, x, w_gate, w_up, w_down, token, weight, sizes, dtype,
     gives: the `where` below keeps it from every token); without,
     `jax.lax.ragged_dot`'s, for which the last group is stretched over
     the dead rows so that every row lies in a group."""
-    lo = j * rows
-    ends = jnp.cumsum(sizes)
-    # this block's part of each group
-    part = (jnp.clip(ends - lo, 0, rows)
-            - jnp.clip(ends - sizes - lo, 0, rows)).astype(jnp.int32)
-    live = (lo + jnp.arange(rows) < ends[-1])[:, None]
-    token = jax.lax.dynamic_slice_in_dim(token, lo, rows)
-    weight = jax.lax.dynamic_slice_in_dim(weight, lo, rows)
-    xs = jnp.where(live, x[token], jnp.zeros((), x.dtype)).astype(dtype)
+    with stage("moe.gather"):
+        lo = j * rows
+        ends = jnp.cumsum(sizes)
+        # this block's part of each group
+        part = (jnp.clip(ends - lo, 0, rows)
+                - jnp.clip(ends - sizes - lo, 0, rows)).astype(jnp.int32)
+        live = (lo + jnp.arange(rows) < ends[-1])[:, None]
+        token = jax.lax.dynamic_slice_in_dim(token, lo, rows)
+        weight = jax.lax.dynamic_slice_in_dim(weight, lo, rows)
+        xs = jnp.where(live, x[token], jnp.zeros((), x.dtype)).astype(dtype)
 
     if kernel:
         from ..ops.pallas.grouped_matmul import grouped_matmul
@@ -253,7 +277,8 @@ def _block(j, rows, x, w_gate, w_up, w_down, token, weight, sizes, dtype,
         def dot(a, w):
             return grouped_matmul(a, w, part)
     else:
-        part = part.at[-1].add(rows - jnp.sum(part))
+        with stage("moe.gather"):
+            part = part.at[-1].add(rows - jnp.sum(part))
 
         @jax.checkpoint
         def dot(a, w):
@@ -264,11 +289,14 @@ def _block(j, rows, x, w_gate, w_up, w_down, token, weight, sizes, dtype,
             return jax.lax.ragged_dot(a, w.astype(dtype), part,
                                       preferred_element_type=jnp.float32)
 
-    h = (jnp.square(jax.nn.relu(dot(xs, w_up))) if w_gate is None
-         else jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up))
-    y = dot(h.astype(dtype), w_down) * weight[:, None]
-    return jnp.zeros(x.shape, jnp.float32).at[token].add(
-        jnp.where(live, y, 0.0))
+    with stage("moe.products"):
+        h = (jnp.square(jax.nn.relu(dot(xs, w_up))) if w_gate is None
+             else jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up))
+        y = dot(h.astype(dtype), w_down)
+    with stage("moe.combine"):
+        y = y * weight[:, None]
+        return jnp.zeros(x.shape, jnp.float32).at[token].add(
+            jnp.where(live, y, 0.0))
 
 
 def _held_experts(x, w_gate, w_up, w_down, token, weight, sizes, dtype,
@@ -283,11 +311,14 @@ def _held_experts(x, w_gate, w_up, w_down, token, weight, sizes, dtype,
     # its slice moved back over rows already done
     short = -token.shape[0] % rows
     if short:
-        token, weight = jnp.pad(token, (0, short)), jnp.pad(weight, (0, short))
+        with stage("moe.sort"):
+            token = jnp.pad(token, (0, short))
+            weight = jnp.pad(weight, (0, short))
     first = _block(0, rows, x, w_gate, w_up, w_down, token, weight, sizes,
                    dtype, kernel)
-    return _overflow(first, x, w_gate, w_up, w_down, token, weight, sizes,
-                     dtype, rows, kernel)
+    with stage("moe.combine"):
+        return _overflow(first, x, w_gate, w_up, w_down, token, weight, sizes,
+                         dtype, rows, kernel)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
@@ -325,9 +356,10 @@ def _overflow_bwd(dtype, rows, kernel, res, g):
                                          sizes, dtype, kernel), *diff)
         return jax.tree.map(jnp.add, grads, pull(g))
 
-    zeros = jax.tree.map(lambda t: jnp.zeros(t.shape, t.dtype), diff)
-    dx, da, db, dc, dw = jax.lax.fori_loop(
-        1, _block_count(sizes, rows), body, zeros)
+    with stage("moe.combine"):
+        zeros = jax.tree.map(lambda t: jnp.zeros(t.shape, t.dtype), diff)
+        dx, da, db, dc, dw = jax.lax.fori_loop(
+            1, _block_count(sizes, rows), body, zeros)
     return g, dx, da, db, dc, None, dw, None
 
 
@@ -355,22 +387,26 @@ def moe_experts(x, gate, bias, w_gate, w_up, w_down, k, scaling,
     widths and the dtype here (the op's lowering asks, and counts):
     the product is then the Pallas kernels', else `jax.lax.ragged_dot`."""
     shape = x.shape
-    tokens = x.reshape(-1, shape[-1])
-    idx, weights = moe_route(tokens, gate, bias, k, scaling, renormalize,
-                             score_func, norm_eps)
-    local = idx.reshape(-1) - held_from
-    held = (local >= 0) & (local < experts_held)
-    key = jnp.where(held, local, experts_held)
-    order = jnp.argsort(key, stable=True)
-    load = jnp.bincount(key, length=experts_held + 1)[:experts_held].astype(
-        jnp.int32)
-    token = (order // k).astype(jnp.int32)
-    weight = jnp.where(held, weights.reshape(-1), 0.0)[order]
+    with stage("moe.route"):
+        tokens = x.reshape(-1, shape[-1])
+        idx, weights = moe_route(tokens, gate, bias, k, scaling, renormalize,
+                                 score_func, norm_eps)
+    with stage("moe.sort"):
+        local = idx.reshape(-1) - held_from
+        held = (local >= 0) & (local < experts_held)
+        key = jnp.where(held, local, experts_held)
+        order = jnp.argsort(key, stable=True)
+        load = jnp.bincount(key, length=experts_held + 1)[
+            :experts_held].astype(jnp.int32)
+        token = (order // k).astype(jnp.int32)
+        weight = jnp.where(held, weights.reshape(-1), 0.0)[order]
     if experts_x is not None:
         x, shape = experts_x, experts_x.shape
-        tokens = x.reshape(-1, shape[-1])
+        with stage("moe.gather"):
+            tokens = x.reshape(-1, shape[-1])
     y = _held_experts(tokens, w_gate, w_up, w_down, token, weight, load,
                       compute_dtype or tokens.dtype,
                       _block_rows(token.shape[0],
                                   experts_held / gate.shape[1]), kernel)
-    return y.astype(x.dtype).reshape(shape), load
+    with stage("moe.combine"):
+        return y.astype(x.dtype).reshape(shape), load

@@ -18,9 +18,12 @@ README, Profiling, says how to read them against the chip's peaks."""
 from __future__ import annotations
 
 import contextlib
+import logging
 import threading
 import time
-from collections import defaultdict
+from collections import defaultdict, deque
+
+import numpy as np
 
 import jax
 
@@ -37,6 +40,7 @@ __all__ = [
     "recorded_counters",
     "replay_counters",
     "time_counter",
+    "hold_device_counts",
 ]
 
 _events: dict[str, list[float]] = defaultdict(list)
@@ -214,8 +218,58 @@ def set_counter(name: str, value: int) -> int:
 
 
 def counters() -> dict:
+    """Every counter as it stands, the device counts of the steps that
+    have finished among them; a step still running is not waited for."""
+    with _device_counts_lock:
+        _fold_device_counts()
     with _counters_lock:
         return dict(_counters)
+
+
+# Counts a compiled step makes on the device (`LoweringContext.count`:
+# data, which `bump_counter`, running while the step is traced, cannot
+# see). The Executor hands each dispatch's small int32 array here; it is
+# added to the counters above, as exact Python ints, once the step that
+# made it has finished: at a later dispatch or at `counters()`, whichever
+# comes first, and never by waiting, unless the host has run
+# `DEVICE_COUNTS_IN_FLIGHT` steps ahead of the device (the oldest step is
+# then waited for: a bounded queue that loses nothing).
+DEVICE_COUNTS_IN_FLIGHT = 64
+_device_counts: deque = deque()  # (names, array) by dispatch, oldest first
+_device_counts_lock = threading.Lock()
+
+
+def hold_device_counts(names, array) -> None:
+    """`array`: int32 `[len(names)]` of one step, or `[steps, len(names)]`
+    of a `run_repeated` window, as the dispatch returned it."""
+    with _device_counts_lock:
+        _device_counts.append((tuple(names), array))
+        _fold_device_counts(len(_device_counts) - DEVICE_COUNTS_IN_FLIGHT)
+
+
+def _fold_device_counts(waiting_for=0) -> None:
+    """The finished steps' counts into `_counters`, and the `waiting_for`
+    oldest whether finished or not; steps finish in the order they were
+    dispatched. Called with `_device_counts_lock` held."""
+    while _device_counts and (waiting_for > 0
+                              or _device_counts[0][1].is_ready()):
+        names, array = _device_counts.popleft()
+        waiting_for -= 1
+        try:
+            # replicated on a mesh, and on a fleet of processes not wholly
+            # here: this process's first copy
+            sums = np.asarray(array.addressable_data(0), np.int64).reshape(
+                -1, len(names)).sum(axis=0)
+        except Exception:  # noqa: BLE001 — a step that failed on the device
+            # raises where its outputs are read: the training loop's to
+            # meet, not a reader of counters'
+            logging.getLogger(__name__).warning(
+                "a step's device counts %s could not be read", names,
+                exc_info=True)
+            sums, names = [1], ("device_counts_dropped",)
+        with _counters_lock:
+            for name, total in zip(names, sums):
+                _counters[name] += int(total)
 
 
 @contextlib.contextmanager
@@ -363,6 +417,8 @@ def stop_profiler(sorted_key="total", profile_path=None):
 def reset_profiler():
     """reference: profiler.py:105."""
     _events.clear()
+    with _device_counts_lock:
+        _device_counts.clear()
     with _counters_lock:
         _counters.clear()
 

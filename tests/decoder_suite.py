@@ -17,7 +17,7 @@ Run as a script on the attached TPU, outside any timed window, a suite's
 file ends in `main(SUITE)`:
 
     python3 tests/test_<model>_reference.py readings[:wrong,wrong] [seed ...]   # program, wrong models and the fp8 reference against the reference
-    python3 tests/test_<model>_reference.py loads[@rate] [seed ...]   # held share by expert layer, the loss over a window's steps, a train step's counters (`falls`: the same)
+    python3 tests/test_<model>_reference.py loads[/steps][@rate] [seed ...]   # held share by expert layer and over all of them from the fifth step on (what a benchmark window of steps - 4 steps reads as `moe_held_load_pct`) beside the steps' own device counts, the loss over a window's steps, a train step's counters (`falls`: the same)
     python3 tests/test_<model>_reference.py gradients   # at the published widths on one short row (no argument: the same)
 """
 
@@ -77,6 +77,18 @@ def rel(got, want):
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     return float(np.sqrt(np.mean((got - want) ** 2))
                  / (np.sqrt(np.mean(want ** 2)) + 1e-30))
+
+
+def settled_counters():
+    """`profiler.counters()` with the device counts of every step
+    dispatched so far in it. `counters()` itself never waits for a step,
+    and a step whose fetch the host has just read may still be
+    microseconds from saying `is_ready()` of its counts."""
+    from paddle_tpu import profiler
+
+    with profiler._device_counts_lock:
+        profiler._fold_device_counts(len(profiler._device_counts))
+    return profiler.counters()
 
 
 def state(names):
@@ -482,7 +494,7 @@ def _reading(suite, seeds, only=(), few=2):
                   f"{check['loss_abs']:.5f} ok {check['ok']}", flush=True)
 
 
-def _loads(suite, seeds, rate=None):
+def _loads(suite, seeds, rate=None, steps=None):
     """At the published widths on the attached TPU, the cell's train step
     on the batches its runner would feed (one check batch drawn first,
     then the pool), `suite.steps` of them at `rate`: the share of a step's
@@ -494,18 +506,21 @@ def _loads(suite, seeds, rate=None):
     import paddle_tpu as fluid
     from paddle_tpu import profiler
 
-    adapter, steps = suite.adapter, suite.steps
+    adapter, steps = suite.adapter, steps or suite.steps
     model, traffic = suite.cell(rehearse=False)
     if rate:  # the sweep that chose the optimizer's rate
         model["optimizer"] = dict(model["optimizer"], learning_rate=rate)
+    # a layer's assignments a step: Kimi's configuration spells the key
+    # out, a dense model has neither
     total = traffic["batch"] * traffic["seq_len"] * model.get(
-        "num_experts_per_tok", 0)
+        "num_experts_per_tok", model.get("num_experts_per_token", 0))
     c0 = profiler.counters()
 
     def row(values):
         return " ".join(f"{v:.4f}" for v in values)
 
     for seed in seeds:
+        counted = profiler.counters()
         with guards():
             main, _, built, exe, _ = suite.built_model(
                 model, traffic, seed, fluid.TPUPlace(), as_seeded=True)
@@ -525,11 +540,23 @@ def _loads(suite, seeds, rate=None):
         shares, losses = np.array(shares), np.array(losses)
         held = ""
         if loads:
-            rows = profiler.counters()["moe_block_rows"]
+            now = profiler.counters()
+            rows = now["moe_block_rows"]
+            # the steps' own device counts (ops/moe_ops.py) beside the
+            # fetched loads: equal to the row, and `moe_held_load_pct` of
+            # a benchmark window of `steps - 4` steps at this seed
+            live, routed, blocks = (now.get(n, 0) - counted.get(n, 0) for n in (
+                "moe_rows_live", "moe_rows_routed", "moe_blocks_run"))
+            fetched = int(round(shares.sum() * total))
             held = (f"block {rows} rows = {rows / total:.4f} of {total}; "
                     f"held share by layer, step 0: {row(shares[0])}; step 4: "
                     f"{row(shares[4])}; step {steps - 1}: {row(shares[-1])}; "
-                    f"largest: {row(shares.max(0))}; ")
+                    f"largest: {row(shares.max(0))}; all layers, steps 4 to "
+                    f"{steps - 1}: {100 * shares[4:].mean():.4f}%; the "
+                    f"{steps} steps' own counts: moe_rows_live {live} "
+                    f"(fetched loads {fetched}), moe_rows_routed {routed} "
+                    f"(layers x steps x tokens x k {shares.size * total}), "
+                    f"moe_blocks_run {blocks} of {shares.size}; ")
         fall = np.median(losses[4:14], 0) - np.median(losses[-10:], 0)
         print(f"seed {seed} rate {model['optimizer']['learning_rate']}: "
               f"{held}loss{' and its terms' if terms else ''}, step 0 "
@@ -579,15 +606,17 @@ def _chip_gradients(suite):
 
 def main(suite, argv=None):
     """A suite's file run as a script: `readings[:wrong,...]`,
-    `loads[@rate]` (or `falls`), `gradients` (or nothing), then seeds."""
+    `loads[/steps][@rate]` (or `falls`), `gradients` (or nothing), then
+    seeds."""
     argv = sys.argv[1:] if argv is None else argv
     what, _, rate = (argv[0] if argv else "gradients").partition("@")
+    what, _, steps = what.partition("/")
     what, _, only = what.partition(":")
     only = tuple(w for w in only.split(",") if w)
     unknown = [w for w in only if w not in suite.wrong]
     if what not in ("readings", "loads", "falls", "gradients") or unknown:
         raise SystemExit(f"{what!r} {unknown}: readings[:wrong,...] (of "
-                         f"{list(suite.wrong)}), loads[@rate], gradients")
+                         f"{list(suite.wrong)}), loads[/steps][@rate], gradients")
     seeds = [int(a) for a in argv[1:]] or [suite.seed]
     import jax
 
@@ -597,4 +626,5 @@ def main(suite, argv=None):
     elif what == "gradients":
         _chip_gradients(suite)
     else:
-        _loads(suite, seeds, float(rate) if rate else None)
+        _loads(suite, seeds, float(rate) if rate else None,
+               int(steps) if steps else None)

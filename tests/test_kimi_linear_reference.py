@@ -23,7 +23,8 @@ import numpy as np
 import pytest
 
 from decoder_suite import *  # noqa: F401,F403 — the shared cases, on SUITE
-from decoder_suite import expert_params, highest, main, rel, state
+from decoder_suite import (expert_params, highest, main, rel,
+                           settled_counters, state)
 from kernel_cases import value_and_grads
 
 from benchmark.models import kimi_linear as adapter  # noqa: E402
@@ -459,7 +460,7 @@ def test_counters_and_flops_of_the_cell():
     exe.run(eval_prog, feed=batch, fetch_list=built["check"])
     _, *loads = exe.run(main, feed=batch,
                         fetch_list=[built["loss"]] + built["loads"])
-    c1 = profiler.counters()
+    c1 = settled_counters()
     # a set-up traces 1,010 ops (startup, the `for_test` clone, the train
     # step: `traced_ops` on the chip), and bumps no counter that is another
     # decoder's: no softmax router, no scaled or paired positions, no
@@ -479,9 +480,11 @@ def test_counters_and_flops_of_the_cell():
     assert c1["kda_dispatch_chunked"] - c0.get("kda_dispatch_chunked", 0) >= 4
     grouped = c1["moe_dispatch_grouped"] - c0.get("moe_dispatch_grouped", 0)
     assert grouped >= 4
-    # every lowering of the layer takes its first block straight-line
-    assert c1["moe_first_block_shared"] - c0.get(
-        "moe_first_block_shared", 0) == grouped
+    # the steps' own count of the rows the held experts took: the
+    # evaluation clone's and the train step's forward on one batch and one
+    # state, and nothing for the gradient ops' replays
+    assert c1["moe_rows_live"] - c0.get("moe_rows_live", 0) == 2 * sum(
+        int(np.sum(load)) for load in loads)
     # on the plain path: the rehearsal's widths are no lane multiple, and
     # there is no Mosaic here (ops/pallas/grouped_matmul.py)
     assert c1.get("moe_dispatch_gmm", 0) == c0.get("moe_dispatch_gmm", 0)

@@ -27,7 +27,7 @@ import numpy as np
 import pytest
 
 from decoder_suite import *  # noqa: F401,F403 — the shared cases, on SUITE
-from decoder_suite import highest, main, rel
+from decoder_suite import highest, main, rel, settled_counters
 
 from benchmark.models import mellum as adapter  # noqa: E402
 
@@ -436,14 +436,15 @@ def test_counters_and_flops_of_the_cell():
     main, _, built, exe, _ = SUITE.built_model(small, small_traffic)
     batch = SUITE.batch_for(small, small_traffic)
     loads = exe.run(main, feed=batch, fetch_list=built["loads"])
-    c1 = profiler.counters()
+    c1 = settled_counters()
 
     def bumped(name):
         return c1.get(name, 0) - c0.get(name, 0)
 
     # four layers, the forward op's lowering and the gradient op's replay
     assert bumped("moe_dispatch_grouped") == 8
-    assert bumped("moe_first_block_shared") == 8
+    # ... and the step's own count of the rows the held experts took
+    assert bumped("moe_rows_live") == sum(int(np.sum(load)) for load in loads)
     # on the plain path: the rehearsal's widths are no lane multiple, and
     # there is no Mosaic here (ops/pallas/grouped_matmul.py)
     assert bumped("moe_dispatch_gmm") == 0

@@ -10,7 +10,7 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
-from decoder_suite import expert_params, highest, rel
+from decoder_suite import expert_params, highest, rel, settled_counters
 from kernel_cases import compiled, in_and_out_of_whiles, loss_grads
 
 from benchmark.models import kimi_linear as ref
@@ -51,8 +51,8 @@ def test_one_layers_train_step_makes_the_first_blocks_products_once():
         jax.random.key(0)).compile().as_text()
     after = profiler.counters()
     # once a lowering: the forward op's, and the gradient op's replay
-    for counter in ("moe_first_block_shared", "moe_dispatch_grouped"):
-        assert after[counter] - before.get(counter, 0) == 2, counter
+    assert after["moe_dispatch_grouped"] - before.get(
+        "moe_dispatch_grouped", 0) == 2
     # neither took the Pallas kernels: no Mosaic on the CPU, widths of 16
     assert after.get("moe_dispatch_gmm", 0) == before.get(
         "moe_dispatch_gmm", 0)
@@ -328,3 +328,309 @@ def test_the_op_refuses_matrices_that_do_not_take_their_inputs():
         L.moe_experts(L.data("x", [4, 16], append_batch_size=False),
                       experts_total=8, experts_held=2, d_ff=8, k=2,
                       expert_form="gelu")
+
+
+# ------------------------------- the layer read from inside: stages, counts
+
+STAGES = ("moe.route", "moe.sort", "moe.gather", "moe.products", "moe.combine")
+COUNTS = ("moe_rows_routed", "moe_rows_live", "moe_blocks_run")
+ROWS, WIDTH, EXPERTS = 48, 16, 8
+
+
+def _one_layer(held, experts=True):
+    """`fc`, one expert layer of `held` of eight experts (none: a second
+    `fc`), a loss, SGD: (Executor, loss, load or None)."""
+    import paddle_tpu as fluid
+
+    L = fluid.layers
+    x = L.data("x", [ROWS, WIDTH], append_batch_size=False)
+    h = L.fc(x, WIDTH, bias_attr=False, param_attr=fluid.ParamAttr(name="in"))
+    if experts:
+        y, load = L.moe_experts(h, experts_total=EXPERTS, experts_held=held,
+                                d_ff=8, k=K, scaling=2.446,
+                                param_attr=fluid.ParamAttr(name="m"))
+    else:
+        y, load = L.fc(h, WIDTH, bias_attr=False), None
+    loss = L.reduce_mean(L.square(y))
+    fluid.optimizer.SGD(0.1).minimize(loss)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    return exe, loss, load
+
+
+def _counted(before):
+    after = settled_counters()
+    return {n: after.get(n, 0) - before.get(n, 0)
+            for n in (*COUNTS, "device_counts_dropped")}
+
+
+@pytest.mark.parametrize("held,blocks", [(8, 1), (2, 3)])
+def test_every_operation_the_layer_traces_lies_in_one_of_five_stages(
+        held, blocks):
+    """The lowered train step of one layer, whose sorted assignments fill
+    at most `blocks` blocks: under `fwd/moe_experts` and under
+    `bwd/moe_experts_grad` alike every operation's name carries a stage
+    (no AMP here: the pre-cast of the op's inputs is `lower_op`'s, under
+    the bare op scope), each of the five is there under either, and what
+    the benchmark reads a trace with (`xplane_meta.scope`, the metric's
+    pattern, `trace_stage_share.stage_of`) finds the innermost stage in a
+    transposed name and inside the overflow loops' bodies."""
+    import json
+    import re
+
+    import jax
+
+    import paddle_tpu as fluid
+    from paddle_tpu.parallel import moe
+
+    from benchmark.harness import spec, xplane_meta
+    from benchmark.harness.sources import trace_stage_share
+
+    assert moe.STAGES == STAGES
+    assert -(-ROWS * K // moe._block_rows(ROWS * K, held / EXPERTS)) == blocks
+    exe, loss, _ = _one_layer(held)
+    scope = fluid.global_scope()
+    compiled, feeds, _ = exe._prepare_run(
+        fluid.default_main_program(),
+        {"x": np.zeros((ROWS, WIDTH), np.float32)}, [loss], scope)
+    text = compiled.jit_fn.lower(
+        exe._assemble_state(compiled, scope), feeds,
+        jax.random.key(0)).as_text(debug_info=True)
+    metric = spec.load("layer_metrics", "moe_dispatch_device_pct")
+    assert metric["kind"] == "trace_stage_share", json.dumps(metric)
+    assert set(metric["args"]["stages"]) < set(STAGES)
+    assert metric["args"]["counts"] == list(COUNTS)
+    pattern = metric["args"]["scope"]
+
+    names = {n for n in re.findall(r'loc\("(jit\(step\)/[^"]*)"', text)
+             if "moe_experts" in n}
+    found = {}  # phase/op -> {stage}
+    for name in names:
+        scope_ = xplane_meta.scope(name + ":")
+        assert re.search(pattern, scope_), name
+        stage = trace_stage_share.stage_of(scope_)
+        assert stage in STAGES, f"outside every stage: {name}"
+        found.setdefault(xplane_meta.phase_op(scope_), set()).add(stage)
+    assert found == {"fwd/moe_experts": set(STAGES),
+                     "bwd/moe_experts_grad": set(STAGES)}
+
+    def innermost(fragment):
+        return {trace_stage_share.stage_of(xplane_meta.scope(n))
+                for n in names if fragment in n}
+
+    assert innermost("transpose(jvp(moe.gather))/") == {"moe.gather"}
+    assert innermost("/while/body/moe.gather/") == {"moe.gather"}
+    # the loops' own counting and sums are the combine's, fwd and bwd
+    assert innermost("/while/cond/") == {"moe.combine"}
+    assert {"moe.gather", "moe.products", "moe.combine"} <= innermost(
+        "bwd/moe_experts_grad/transpose(bwd/moe_experts_grad)")
+    # no stage for an op without one, nor for a dotted primitive's type
+    assert trace_stage_share.stage_of("fwd/matmul/dot_general") == ""
+    assert trace_stage_share.stage_of("bwd/mul_grad/transpose(jvp())/mul") == ""
+    with pytest.raises(ValueError, match="no stage"):
+        moe.stage("moe.top_k")
+
+
+@pytest.mark.parametrize("held,correction,blocks,places", [
+    (2, 0.0, 1, None),  # a quarter held, a block of 7/16 of the 96 rows
+    (2, 10.0, 3, None),  # every assignment lands on the quarter held: two trips
+    (2, 0.0, 1, 2),     # the rows over a mesh of two: one count, the whole step's
+])
+def test_the_step_counts_its_rows_and_blocks_as_numpy_counts_them(
+        held, correction, blocks, places):
+    """Three steps on three inputs: `profiler.counters()` gains exactly
+    what a numpy router over the same weights counts, the gradient op's
+    replay counts nothing, nothing is dropped, and `run_repeated`'s
+    window sums its steps."""
+    import paddle_tpu as fluid
+    from paddle_tpu.parallel import moe
+
+    exe, loss, load = _one_layer(held)
+    program = fluid.default_main_program()
+    if places:
+        program = fluid.CompiledProgram(program).with_data_parallel(
+            loss_name=loss.name, places=places)
+    scope = fluid.global_scope()
+    bias = np.zeros(EXPERTS, np.float32)
+    bias[:held] += correction
+    scope.set("m.bias", bias)
+    rows = moe._block_rows(ROWS * K, held / EXPERTS)
+    before = settled_counters()
+    want = dict.fromkeys(COUNTS, 0)
+    for seed in range(3):
+        x = np.random.RandomState(seed).randn(ROWS, WIDTH).astype(np.float32)
+        scores = 1 / (1 + np.exp(-(x @ np.asarray(scope.get("in"))
+                                   @ np.asarray(scope.get("m.gate")))))
+        chosen = np.argsort(-(scores + bias), axis=1, kind="stable")[:, :K]
+        live = int((chosen < held).sum())
+        (got,) = exe.run(program, feed={"x": x}, fetch_list=[load])
+        assert int(got.sum()) == live
+        want["moe_rows_routed"] += ROWS * K
+        want["moe_rows_live"] += live
+        want["moe_blocks_run"] += max(1, -(-live // rows))
+    assert want["moe_blocks_run"] == 3 * blocks
+    assert _counted(before) == {**want, "device_counts_dropped": 0}
+
+    before = settled_counters()
+    stacked_loss, stacked_load = exe.run_repeated(
+        program, feed={"x": x}, fetch_list=[loss, load], steps=4)
+    assert stacked_loss.shape[0] == 4 and stacked_load.shape == (4, held)
+    assert _counted(before) == {
+        "moe_rows_routed": 4 * ROWS * K,
+        "moe_rows_live": int(stacked_load.sum()),
+        "moe_blocks_run": int(np.maximum(
+            1, -(-stacked_load.sum(axis=1) // rows)).sum()),
+        "device_counts_dropped": 0}
+
+
+def test_a_program_without_experts_returns_what_it_returned_and_counts_nothing():
+    import jax
+
+    import paddle_tpu as fluid
+    from paddle_tpu import profiler
+
+    exe, loss, _ = _one_layer(0, experts=False)
+    scope = fluid.global_scope()
+    before = settled_counters()
+    compiled, feeds, _ = exe._prepare_run(
+        fluid.default_main_program(),
+        {"x": np.ones((ROWS, WIDTH), np.float32)}, [loss], scope)
+    assert compiled.count_names == ()
+    state = exe._assemble_state(compiled, scope)
+    out = jax.eval_shape(compiled.jit_fn, state, feeds, jax.random.key(0))
+    # (the fetches, the state): one loss and nothing beside it
+    assert jax.tree.structure(out) == jax.tree.structure(
+        ([0], dict.fromkeys(compiled.state_names, 0)))
+    exe.run(feed={"x": np.ones((ROWS, WIDTH), np.float32)}, fetch_list=[loss])
+    assert not any(_counted(before).values())
+    assert not profiler._device_counts
+
+
+@pytest.mark.parametrize("form", ["nan_checked", "micro_batched", "in_a_loop"])
+def test_a_step_that_cannot_carry_its_counts_says_so(form, monkeypatch):
+    """The NaN-checked step, the micro-batched step and an op inside a
+    `while` body drop their counts loudly: `device_counts_dropped` moves
+    with the compile, the three counts do not, the step runs."""
+    import paddle_tpu as fluid
+
+    L = fluid.layers
+    before = settled_counters()
+    x = np.ones((ROWS, WIDTH), np.float32)
+    if form == "in_a_loop":
+        u = L.data("x", [ROWS, WIDTH], append_batch_size=False)
+        i = L.fill_constant([1], "int64", 0)
+        acc = L.fill_constant([ROWS, WIDTH], "float32", 0.0)
+        cond = L.less_than(i, L.fill_constant([1], "int64", 2))
+        loop = L.While(cond)
+        with loop.block():
+            y, _ = L.moe_experts(u, experts_total=EXPERTS, experts_held=2,
+                                 d_ff=8, k=K)
+            L.assign(acc + y, acc)
+            L.increment(i, in_place=True)
+            L.less_than(i, L.fill_constant([1], "int64", 2), cond=cond)
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(fluid.default_startup_program())
+        exe.run(feed={"x": x}, fetch_list=[acc])
+    else:
+        if form == "nan_checked":
+            monkeypatch.setenv("PADDLE_TPU_CHECK_NAN_INF", "1")
+        exe, loss, _ = _one_layer(2)
+        if form == "micro_batched":
+            main = fluid.default_main_program()
+            main._pipeline_microbatches = 2
+        exe.run(feed={"x": x}, fetch_list=[loss])
+    counted = _counted(before)
+    assert counted.pop("device_counts_dropped") >= len(COUNTS)
+    assert not any(counted.values())
+
+
+def test_counters_never_waits_for_a_step_in_flight():
+    """`profiler.counters()` folds the steps that have finished and leaves
+    the others in the queue; the next call has them. Past
+    `DEVICE_COUNTS_IN_FLIGHT` held steps the oldest is folded at the next
+    dispatch, finished or not: the queue is bounded and loses nothing."""
+    from paddle_tpu import profiler
+
+    class Step:
+        """What the Executor hands over: one step's counts, ready or not."""
+
+        def __init__(self, *counts, ready=False):
+            self.counts, self.ready = np.array(counts, np.int32), ready
+
+        def is_ready(self):
+            return self.ready
+
+        def addressable_data(self, index):
+            return self.counts
+
+    names = ("test_rows", "test_blocks")
+    before = profiler.counters()
+
+    def gained():
+        now = profiler.counters()
+        return tuple(now.get(n, 0) - before.get(n, 0) for n in names)
+
+    first, second = Step(5, 1, ready=True), Step(2_000_000_000, 3)
+    profiler.hold_device_counts(names, first)
+    profiler.hold_device_counts(names, second)
+    assert gained() == (5, 1)  # ... and came back with `second` running
+    assert gained() == (5, 1)
+    second.ready = True
+    # exact, past what an int32 sum on the device would hold
+    assert gained() == (2_000_000_005, 4)
+    assert not profiler._device_counts
+    # a window of `run_repeated`: [steps, counts], summed on the host
+    profiler.hold_device_counts(
+        names, Step([2_000_000_000, 1], [2_000_000_000, 2], ready=True))
+    assert gained() == (6_000_000_005, 7)
+    # a host that far ahead: the oldest is waited for, none is lost
+    running = [Step(1, 0) for _ in range(profiler.DEVICE_COUNTS_IN_FLIGHT + 2)]
+    for step in running:
+        profiler.hold_device_counts(names, step)
+    assert len(profiler._device_counts) == profiler.DEVICE_COUNTS_IN_FLIGHT
+    assert gained() == (6_000_000_007, 7)
+    for step in running:
+        step.ready = True
+    assert gained() == (6_000_000_005 + len(running), 7)
+    # an array that cannot be read (its step failed) is counted as dropped
+    broken = Step(1, 1, ready=True)
+    broken.addressable_data = None
+    dropped = profiler.counters().get("device_counts_dropped", 0)
+    profiler.hold_device_counts(names, broken)
+    assert profiler.counters()["device_counts_dropped"] == dropped + 1
+
+    # dispatching threads and readers at once: no count lost or doubled
+    import sys
+    import threading
+
+    before, each, threads = profiler.counters(), 300, 12
+
+    def dispatch():
+        for _ in range(each):
+            profiler.hold_device_counts(names, Step(3, 1, ready=True))
+            profiler.counters()
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        workers = [threading.Thread(target=dispatch) for _ in range(threads)]
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(w.is_alive() for w in workers)
+    assert gained() == (3 * each * threads, each * threads)
+
+
+def test_a_lowering_that_counts_what_its_op_did_not_declare_is_refused(
+        monkeypatch):
+    from paddle_tpu.ops import registry
+
+    opdef = registry.get_op("moe_experts")
+    monkeypatch.setattr(opdef, "device_counts", COUNTS[:2])
+    exe, loss, _ = _one_layer(2)
+    with pytest.raises(RuntimeError, match="moe_blocks_run.*does not declare"):
+        exe.run(feed={"x": np.ones((ROWS, WIDTH), np.float32)},
+                fetch_list=[loss])

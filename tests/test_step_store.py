@@ -16,6 +16,8 @@ import paddle_tpu as fluid
 from paddle_tpu import profiler, step_store
 from paddle_tpu.scope import Scope
 
+from decoder_suite import settled_counters
+
 STAGES = ("compile_trace_us.train", "compile_lower_us.train")
 
 
@@ -100,6 +102,31 @@ def test_a_second_executor_hits_and_fetches_what_the_miss_fetched(store):
             == now["compile_backend_us.train"]
             - before["compile_backend_us.train"])
     assert now["program_compile_count"] == before["program_compile_count"] + 2
+
+
+def test_a_loaded_step_hands_its_device_counts_back(store):
+    """The counts a step makes on the device are named by the Program
+    (`executor._device_count_names`), not by the trace a hit skips: a
+    second Executor's loaded step folds the same counts the miss did."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = fluid.layers.data("x", [24, 16], append_batch_size=False)
+        out, load = fluid.layers.moe_experts(
+            x, experts_total=4, experts_held=2, d_ff=8, k=2)
+        loss = fluid.layers.reduce_mean(fluid.layers.square(out))
+        fluid.optimizer.SGD(0.1).minimize(loss)
+    main.random_seed = startup.random_seed = 7
+    batch = {"x": np.random.RandomState(0).randn(24, 16).astype("float32")}
+    counts = ("moe_rows_routed", "moe_rows_live", "moe_blocks_run")
+    gained = []
+    for said in ("step_store_writes.train", "step_store_hits.train"):
+        before = settled_counters()
+        loads = train(main, startup, load, batch)
+        assert since(before)[said] == 1
+        now = settled_counters()
+        gained.append([now[n] - before.get(n, 0) for n in counts])
+        assert gained[-1] == [3 * 24 * 2, sum(int(v.sum()) for v in loads), 3]
+    assert gained[0] == gained[1]
 
 
 def test_another_seed_is_the_same_step(store):

@@ -599,3 +599,85 @@ def test_a_roofline_metric_names_what_it_reads_and_the_cells_that_have_it(
             spec.cell(w["name"]))}) == cells
     assert all(re.search(m["args"][key], text) for text in hits)
     assert not any(re.search(m["args"][key], text) for text in misses)
+
+
+# ------------------------------------------- the stages of one Program op
+
+def _stage_reading(tables):
+    return {"trace": object(), "scope_ns": tables, "notes": [],
+            "traced": {"steps": 2},
+            "counters": {"window": {"moe_rows_routed": 960,
+                                    "moe_rows_live": 240,
+                                    "moe_blocks_run": 10}}}
+
+
+STAGED = {  # (XLA group, scope): self ns; 1,000 in all
+    ("fusion/kCustom", "fwd/moe_experts/moe.sort/sort"): 100.0,
+    ("fusion/kLoop",
+     "bwd/moe_experts_grad/transpose(jvp(moe.gather))/scatter-add"): 50.0,
+    ("fusion/kCustom",
+     "fwd/moe_experts/moe.combine/while/body/moe.gather/gather"): 25.0,
+    ("fusion/kCustom", "fwd/moe_experts/moe.route/top_k"): 200.0,
+    ("convert", "fwd/moe_experts/convert_element_type"): 10.0,
+    ("fusion/kOutput", "fwd/matmul/dot_general"): 615.0,
+}
+
+
+@pytest.mark.parametrize("metric", ["moe_dispatch_device_pct",
+                                    "moe_held_load_pct"])
+def test_the_expert_layers_two_metrics_on_a_hand_made_reading(metric):
+    """`trace_stage_share` on a `scope_ns` table with known answers (the
+    innermost stage counts, the share is over all busy time, mean over
+    the devices, None without a stage), and `moe_held_load_pct` on the
+    window's counts; both declared for the eight expert cells."""
+    from benchmark.harness import spec
+    from benchmark.harness.sources import counter_ratio, trace_stage_share
+
+    with open(os.path.join(os.path.dirname(spec.BENCH_DIR),
+                           "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    declared = {m["name"]: m for m in bench["per_layer"]}
+    m = spec.load("layer_metrics", metric)
+    assert declared[metric]["workloads"] == declared["moe_device_pct"][
+        "workloads"] and len(declared[metric]["workloads"]) == 8
+    assert m["where"] == {"config.mechanisms": ["experts"]}
+    assert (m["unit"], m["layer"], m["source"], m["better"]) == tuple(
+        declared[metric][k] for k in ("unit", "layer", "source", "better"))
+
+    other = {key: 2 * ns for key, ns in STAGED.items()}
+    other["fusion/kCustom", "fwd/moe_experts/moe.sort/sort"] = 500.0
+    r = _stage_reading([STAGED, other])  # 17.5% and 650 of 2,300
+    if metric == "moe_held_load_pct":
+        assert counter_ratio.read(m["args"], r) == 25.0
+        r["counters"]["window"] = {}
+        assert counter_ratio.read(m["args"], r) is None  # the parent's
+        return
+    assert m["kind"] == "trace_stage_share"
+    want = 100 * (0.175 + 650 / 2300) / 2
+    assert trace_stage_share.read(m["args"], r) == pytest.approx(want)
+    notes = "\n".join(r["notes"])
+    for line in ("moe.sort: fwd 0.000 bwd 0.000", "moe.route:", "moe.gather:",
+                 "(no stage):", "where XLA booked it",
+                 "moe_rows_routed 960, moe_rows_live 240, moe_blocks_run 10"):
+        assert line in notes, line
+    assert "moe.combine:" not in notes  # the loop's body was the gather's
+    bare_op = {k: v for k, v in STAGED.items() if k[0] != "convert"}
+    staged = _stage_reading([bare_op])
+    trace_stage_share.read(m["args"], staged)
+    assert ("  (no stage): fwd 0.000 bwd 0.000 (0.00% of busy time); nothing"
+            in staged["notes"])
+    # once a run, however many metrics read the scope
+    noted = len(r["notes"])
+    assert trace_stage_share.read(m["args"], r) == pytest.approx(want)
+    assert len(r["notes"]) == noted
+    # ms a step, forward and backward, and the stage's largest XLA groups
+    big = _stage_reading([{k: v * 1e6 for k, v in STAGED.items()}])
+    trace_stage_share.read(m["args"], big)
+    assert ("  moe.gather: fwd 12.500 bwd 25.000 (7.50% of busy time); "
+            "fusion/kLoop (scatter-add) 25.000, fusion/kCustom (gather) "
+            "12.500") in big["notes"]
+    # a program from before the stages, a trace without scopes, no trace
+    bare = {k: v for k, v in STAGED.items() if "moe." not in k[1]}
+    assert trace_stage_share.read(m["args"], _stage_reading([bare])) is None
+    assert trace_stage_share.read(m["args"], _stage_reading(None)) is None
+    assert trace_stage_share.read(m["args"], {"trace": None}) is None

@@ -25,7 +25,8 @@ import numpy as np
 import pytest
 
 from decoder_suite import *  # noqa: F401,F403 — the shared cases, on SUITE
-from decoder_suite import f32, guards, highest, main, rel, state
+from decoder_suite import (f32, guards, highest, main, rel,
+                           settled_counters, state)
 
 from benchmark.models import trinity as adapter  # noqa: E402
 
@@ -443,12 +444,13 @@ def test_counters_and_flops_of_the_cell():
     main, _, built, exe, _ = SUITE.built_model(small, small_traffic)
     batch = SUITE.batch_for(small, small_traffic)
     loads = exe.run(main, feed=batch, fetch_list=built["loads"])
-    c1 = profiler.counters()
+    c1 = settled_counters()
     grouped = c1["moe_dispatch_grouped"] - c0.get("moe_dispatch_grouped", 0)
     assert grouped >= 4
-    # every lowering of the layer takes its first block straight-line
-    assert c1["moe_first_block_shared"] - c0.get(
-        "moe_first_block_shared", 0) == grouped
+    # the step's own count of the rows the held experts took: the
+    # forward's, and nothing for the gradient ops' replays
+    assert c1["moe_rows_live"] - c0.get("moe_rows_live", 0) == sum(
+        int(np.sum(load)) for load in loads)
     # on the plain path: the rehearsal's widths are no lane multiple, and
     # there is no Mosaic here (ops/pallas/grouped_matmul.py)
     assert c1.get("moe_dispatch_gmm", 0) == c0.get("moe_dispatch_gmm", 0)
