@@ -191,8 +191,12 @@ BLOCK_ROOM = 1.75  # times the balanced load of the share held
 # innermost counts (`moe.combine/while/body/moe.gather/...` is the gather's).
 # PERF.md, section 3, says which metric reads them.
 STAGES = (
-    "moe.route",     # the float32 logits, the scores, top-k, the weights
-    "moe.sort",      # held mask, keys, argsort, the load, the weights permuted
+    "moe.route",     # the float32 logits, the scores, top-k, the k selected
+                     # scores by a compare over the E lanes, the weights
+    "moe.sort",      # held mask, keys, one sort of (key, place, weight): the
+                     # order and the weights permuted; the load counted off
+                     # the keys. Nothing in these two addresses one
+                     # assignment at a time: no gather, no scatter
     "moe.gather",    # a block's part of each group, its rows of x and weight
     "moe.products",  # the grouped products and the activation between them
     "moe.combine",   # the weighting and the scatter-add onto the tokens; the
@@ -217,7 +221,9 @@ def moe_route(x, gate, bias, k, scaling, renormalize=True,
     `softmax(x gate)` over all E. Returns (idx [N, k] int32, weights
     [N, k] float32): the k largest of `s + bias`, weighted
     `scaling * s_i / (sum_selected s_j + norm_eps)` (without
-    `renormalize`, `scaling * s_i`). float32 throughout."""
+    `renormalize`, `scaling * s_i`). float32 throughout. The selected
+    scores are read by `_selected`, a compare and a reduce over the E
+    lanes: no gather here, and no scatter-add in the transpose."""
     if score_func not in ("sigmoid", "softmax"):
         raise ValueError(f"moe_route: score_func {score_func!r}: expected "
                          "'sigmoid' or 'softmax'")
@@ -227,11 +233,82 @@ def moe_route(x, gate, bias, k, scaling, renormalize=True,
               else jax.nn.softmax(logits, axis=-1))
     _, idx = jax.lax.top_k(
         jax.lax.stop_gradient(scores + bias.astype(jnp.float32)), k)
-    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = _selected(scores, idx)
     if renormalize:
         total = jnp.sum(w, axis=-1, keepdims=True)
         w = w / (total + norm_eps if norm_eps else total)
     return idx.astype(jnp.int32), scaling * w
+
+
+def _selected(scores, idx):
+    """`take_along_axis(scores, idx, -1)` for rows of distinct indices
+    (top-k's), scores [N, E], idx [N, k], without a gather: each of the k
+    compares its index with the lane's and sums the one lane that
+    matches. Its transpose is the same compare summed over k, where a lane
+    matches one index at most: value and gradient hold one non-zero term a
+    sum, so both are the gather's bit for bit.
+
+    The barrier keeps the k scores a value of their own, as a gather's
+    are. Without it XLA folds this sum over E into the renormalisation's
+    sum over k, one reduce in another order: on the v5e the weights then
+    differ from the gather's in the last bit of up to six in a hundred
+    and the router's gradient in most elements, and a training run
+    leaves the parent's after a step. With it weights and gradients are
+    the parent's bit for bit at every cell's shape, at no cost (PERF.md,
+    PR 67)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, scores.shape[-1]), 2)
+    return jax.lax.optimization_barrier(
+        jnp.sum(jnp.where(idx[:, :, None] == lane, scores[:, None, :],
+                          jnp.zeros((), scores.dtype)), axis=-1))
+
+
+@jax.custom_vjp
+def _sorted_by(key, weights):
+    """`key` int32 [M] and `weights` [M] through one stable sort of
+    (key, place, weight) on the key. Returns (`order` int32 [M], which is
+    `argsort(key, stable=True)`; `weights[order]`)."""
+    place = jax.lax.iota(jnp.int32, key.shape[0])
+    _, order, weight = jax.lax.sort((key, place, weights), num_keys=1,
+                                    is_stable=True)
+    return order, weight
+
+
+def _sorted_by_fwd(key, weights):
+    order, weight = _sorted_by(key, weights)
+    return (order, weight), order
+
+
+def _sorted_by_bwd(order, g):
+    # JAX's rule for a sort's operand is a gather and its transpose a
+    # scatter-add of M elements; `order` is a permutation, so sorting the
+    # cotangent on it puts each element back where it came from: an exact
+    # inverse, no sum, and no two keys for a stable sort to keep apart
+    # (XLA's stable sort carries a place of its own along). The stage is
+    # named here because a custom backward is traced outside the
+    # forward's scope.
+    with stage("moe.sort"):
+        _, dweights = jax.lax.sort((order, g[1]), num_keys=1,
+                                   is_stable=False)
+    return None, dweights
+
+
+_sorted_by.defvjp(_sorted_by_fwd, _sorted_by_bwd)
+
+
+def _held_first(idx, weights, experts_held, held_from):
+    """The router's [N, k] choices in the order the grouped product takes
+    them: by held expert, a token's place deciding between equals, those
+    to experts held elsewhere last with a weight of zero. Returns (token
+    [N*k] int32, weight [N*k], load [experts_held] int32: how many each
+    held expert drew, a compare and a count over the keys)."""
+    local = idx.reshape(-1) - held_from
+    held = (local >= 0) & (local < experts_held)
+    key = jnp.where(held, local, experts_held)
+    order, weight = _sorted_by(key, jnp.where(held, weights.reshape(-1), 0.0))
+    load = jnp.sum(
+        key == jax.lax.broadcasted_iota(jnp.int32, (experts_held, 1), 0),
+        axis=1, dtype=jnp.int32)
+    return (order // idx.shape[1]).astype(jnp.int32), weight, load
 
 
 def _block_rows(total, share):
@@ -380,9 +457,12 @@ def moe_experts(x, gate, bias, w_gate, w_up, w_down, k, scaling,
     matrices are then D_e wide and y is like `experts_x`.
 
     The N*k assignments are sorted by held expert, those to experts held
-    elsewhere last, and the sorted rows go through the grouped product
+    elsewhere last (one sort that carries the weights along: `_sorted_by`),
+    the load is a count of each held expert's keys, and the sorted rows go
+    through the grouped product
     with the load as the group sizes, a block of `_block_rows` of them a
-    time: no `[N, E, capacity]` tensor, no capacity, no loop over k.
+    time: no `[N, E, capacity]` tensor, no capacity, no loop over k, and
+    up to the rows of x no gather or scatter of single assignments.
     `kernel` is the caller's word that `grouped_matmul_viable` admits the
     widths and the dtype here (the op's lowering asks, and counts):
     the product is then the Pallas kernels', else `jax.lax.ragged_dot`."""
@@ -392,14 +472,8 @@ def moe_experts(x, gate, bias, w_gate, w_up, w_down, k, scaling,
         idx, weights = moe_route(tokens, gate, bias, k, scaling, renormalize,
                                  score_func, norm_eps)
     with stage("moe.sort"):
-        local = idx.reshape(-1) - held_from
-        held = (local >= 0) & (local < experts_held)
-        key = jnp.where(held, local, experts_held)
-        order = jnp.argsort(key, stable=True)
-        load = jnp.bincount(key, length=experts_held + 1)[
-            :experts_held].astype(jnp.int32)
-        token = (order // k).astype(jnp.int32)
-        weight = jnp.where(held, weights.reshape(-1), 0.0)[order]
+        token, weight, load = _held_first(idx, weights, experts_held,
+                                          held_from)
     if experts_x is not None:
         x, shape = experts_x, experts_x.shape
         with stage("moe.gather"):

@@ -217,9 +217,11 @@ def _route_digests(route=None):
                             argnums=(0, 1, 2, 3, 4)), *operands))
 
 
-# as the parent of PR 47 traces them under jax 0.9.0 (taken by running
-# `_route_digests` against a copy of that commit)
-PARENTS_JAXPRS = ("c9ab9fc20ef9c8b6", "c5dbb24e9790627b", "d5e703e7acf42309")
+# as PR 67's tree traces them under jax 0.9.0 (taken by running
+# `_route_digests` on that commit: the router's selection by compare, the
+# one sort with its weights and the counted load changed the jaxpr by
+# design; up to PR 66 these were the digests of PR 47's parent)
+PARENTS_JAXPRS = ("ab66d574550ff698", "8b219f67b28c6656", "56c6abc24d4bbaac")
 
 
 def test_the_default_epsilons_jaxpr_is_the_parents():
@@ -328,6 +330,164 @@ def test_the_op_refuses_matrices_that_do_not_take_their_inputs():
         L.moe_experts(L.data("x", [4, 16], append_batch_size=False),
                       experts_total=8, experts_held=2, d_ff=8, k=2,
                       expert_form="gelu")
+
+
+# -------------------- the bookkeeping without a gather or a scatter (PR 67)
+
+
+def _bookkeeping(selected, held_first, score_func, k, held, held_from, total):
+    """The router's choices and what the sort stage makes of them, as a
+    function of the logits: `selected(scores, idx)` reads the k scores,
+    `held_first(idx, weights, held, held_from)` orders them. One held
+    expert, the second of them, is never drawn."""
+    import jax
+    import jax.numpy as jnp
+
+    bias = np.zeros(total, np.float32)
+    if held_from + 1 < total:
+        bias[held_from + 1] = -10.0
+
+    def run(logits, cot_w, cot_s):
+        scores = (jax.nn.sigmoid(logits) if score_func == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
+        _, idx = jax.lax.top_k(jax.lax.stop_gradient(scores + bias), k)
+        idx = idx.astype(jnp.int32)
+        w = selected(scores, idx)
+        token, weight, load = held_first(idx, 2.5 * w, held, held_from)
+        return (jnp.sum(w * cot_s) + jnp.sum(weight * cot_w),
+                (w, token, weight, load))
+
+    return jax.jit(jax.value_and_grad(run, has_aux=True))
+
+
+@pytest.mark.parametrize("k", [2, 8])
+@pytest.mark.parametrize("held_from,held", [
+    (5, 4),    # inside the experts
+    (0, 4),    # at their start
+    (14, 4),   # two of the four keys past the last expert: never drawn
+    (16, 2),   # all of them past the end: nothing is held
+])
+@pytest.mark.parametrize("score_func", ["sigmoid", "softmax"])
+def test_the_bookkeeping_equals_a_gather_an_argsort_and_a_bincount_bitwise(
+        score_func, held_from, held, k):
+    """The k selected scores, `token`, the permuted `weight` and `load`,
+    and the gradient by the logits through both the scores and the
+    weights, against the forms `moe_route` and `moe_experts` had up to
+    PR 66: `take_along_axis`, `argsort`, `[order]`, `bincount`. Equal
+    bit for bit: a sum of one non-zero term, a permutation and a count."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.parallel import moe
+
+    total, tokens = 16, 96
+
+    def held_first(idx, weights, held, held_from):
+        local = idx.reshape(-1) - held_from
+        here = (local >= 0) & (local < held)
+        key = jnp.where(here, local, held)
+        order = jnp.argsort(key, stable=True)
+        load = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        return ((order // idx.shape[1]).astype(jnp.int32),
+                jnp.where(here, weights.reshape(-1), 0.0)[order], load)
+
+    r = np.random.RandomState(5)
+    args = (jnp.asarray(r.randn(tokens, total), jnp.float32),
+            jnp.asarray(r.randn(tokens * k), jnp.float32),
+            jnp.asarray(r.randn(tokens, k), jnp.float32))
+    shape = (score_func, k, held, held_from, total)
+    (_, got), got_grad = _bookkeeping(
+        moe._selected, moe._held_first, *shape)(*args)
+    (_, want), want_grad = _bookkeeping(
+        lambda s, i: jnp.take_along_axis(s, i, axis=-1), held_first,
+        *shape)(*args)
+    for name, g, w in zip(("selected", "token", "weight", "load", "grad"),
+                          (*got, got_grad), (*want, want_grad)):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert np.array_equal(g, w), name
+    load = np.asarray(got[3])
+    assert load.dtype == np.int32 and np.abs(want_grad).max() > 0
+    if held_from < total:
+        assert load[0] > 0 and load[1] == 0  # the expert left out
+        assert np.abs(np.asarray(got[2])).max() > 0
+    else:
+        assert not load.any() and not np.asarray(got[2]).any()
+
+
+def _stage_equations(jaxpr, outer=""):
+    """(innermost stage or "", primitive's name) of every equation of a
+    jaxpr and of the jaxprs in its equations' parameters, a name stack
+    read under those of the equations around it."""
+    import re
+
+    from pallas_costs import _jaxprs
+
+    for eqn in jaxpr.eqns:
+        stack = f"{outer}/{eqn.source_info.name_stack}"
+        stages = re.findall(r"moe\.\w+", stack)
+        yield (stages[-1] if stages else ""), eqn.primitive.name
+        for sub in _jaxprs(list(eqn.params.values())):
+            yield from _stage_equations(sub, stack)
+
+
+def test_no_equation_of_the_route_or_the_sort_addresses_one_assignment():
+    """The mechanism's counter: it always engages. In the jaxpr of
+    `moe_experts`, value and gradients (the overflow loops' bodies
+    included), no `gather` and no `scatter` of any kind lies under
+    `moe.route` or `moe.sort`, whose sort is one and whose backward is
+    one more; the rows of x are still gathered and scattered
+    (`moe.gather`, `moe.combine`), which also shows that the walk sees
+    them, as it sees the forms of PR 66 under a stage."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.parallel import moe
+
+    c = _latent_layer(np.random.RandomState(3))
+
+    def layer(x, l, gate, w_up, w_down):
+        return moe.moe_experts(x, gate, c["bias"], None, w_up, w_down,
+                               k=c["k"], scaling=5.0, experts_held=c["held"],
+                               held_from=c["held_from"], experts_x=l)
+
+    args = (c["x"], c["l"], c["gate"], c["w_up"], c["w_down"])
+
+    def equations(fn, *args):
+        return list(_stage_equations(jax.make_jaxpr(fn)(*args).jaxpr))
+
+    def addressed(equations):
+        found = {}
+        for stage, primitive in equations:
+            if primitive == "gather" or primitive.startswith("scatter"):
+                found.setdefault(stage, set()).add(primitive)
+        return found
+
+    both = {"gather", "scatter-add"}
+    for fn, sorts in ((layer, 1),
+                      (jax.grad(lambda *a: jnp.sum(layer(*a)[0]),
+                                argnums=range(5)), 2)):
+        traced = equations(fn, *args)
+        found = addressed(traced)
+        assert set(found) == {"moe.gather", "moe.combine"}, found
+        assert "gather" in found["moe.gather"]
+        assert "scatter-add" in found["moe.combine"]
+        # the one sort, and the backward's under the stage it names itself
+        assert traced.count(("moe.sort", "sort")) == sorts
+    # the walk finds PR 66's forms where they stand under a stage
+
+    def before(scores, idx):
+        with moe.stage("moe.route"):
+            w = jnp.take_along_axis(scores, idx, axis=-1)
+        with moe.stage("moe.sort"):
+            key = idx.reshape(-1)
+            load = jnp.bincount(key, length=4)
+            return jnp.sum(w.reshape(-1)[jnp.argsort(key)]) + load[0]
+
+    scores, idx = jnp.ones((6, 4)), jnp.zeros((6, 2), jnp.int32)
+    assert addressed(equations(before, scores, idx)) == {
+        "moe.route": {"gather"}, "moe.sort": both}
+    assert addressed(equations(jax.grad(before), scores, idx))[
+        "moe.sort"] == both
 
 
 # ------------------------------- the layer read from inside: stages, counts

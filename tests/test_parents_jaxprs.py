@@ -40,7 +40,17 @@ did not: compiled for a described v5e at Trinity's and Mellum's shape
 (32 heads on 4 of 128 at 8,192 tokens, full causal), the Mosaic modules
 of `flash_fwd` and `flash_bwd_dkv_dq` with their locations stripped and
 the declared costs are the parent's to the byte (PERF.md section 6,
-PR 60, has the digests). The `-xla` cases stand."""
+PR 60, has the digests). The `-xla` cases stand.
+
+The five `experts-` cases are PR 67's own tree's. That PR restated the
+expert layer's bookkeeping (`parallel/moe.py`: the k selected scores by a
+compare over the lanes, one sort that carries the weights, the load
+counted off the keys), so `moe_experts`' jaxpr moved by design in every
+caller; what the pieces compute is held bit for bit, value and
+gradients, against the forms these digests had pinned
+(`tests/test_moe_experts.py`). Up to then `experts-shared` and
+`experts-alone` were PR 51's parent's and the three others PR 53's
+parent's; the two Mamba-1 cases and the convolution's still are."""
 
 import os
 import sys
@@ -179,12 +189,13 @@ PARENTS_JAXPRS = {
     "trinity_window-flash": "e01967eee0b60f69",
     "mellum-flash": "beab6dae51f8d3b7",
     "lfm2-flash": "0847f7ec19b5593b",
-    "experts-shared": "0c34ee51cfcbcb61",
-    "experts-alone": "eb3b9327f05919f0",
+    # the five expert cases: PR 67's tree (see the docstring)
+    "experts-shared": "53549bc5f876910a",
+    "experts-alone": "8e9175990e4374b6",
+    "experts-gmm": "a58bb749002ec53c",
+    "experts-norm_eps": "2b3d5cd41d2da9df",
+    "experts-shared_gate": "a5b4baca8bfe7d27",
     # as PR 53's parent (commit 55ba533) traces them
-    "experts-gmm": "884e05046e67ab0d",
-    "experts-norm_eps": "f560fc572924fc52",
-    "experts-shared_gate": "cacf6e6fdb8b2493",
     "mamba1-chunked": "95497628e7a473b7",
     "mamba1-kernels": "9013a4410a37b2fa",
     "short_conv-bias": "60ee9c916a5b65eb",
