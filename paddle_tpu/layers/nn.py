@@ -1421,6 +1421,7 @@ def fused_multihead_attention(
     admit_keys=0,
     return_lse=False,
     return_prepared=False,
+    diffusion_block=0,
 ):
     """Flash attention over q/k/v (Pallas kernel on TPU). layout="bhsd"
     (default): [b, nh, s, dh]; layout="bshd": [b, s, nh, dh] — the shape
@@ -1469,6 +1470,14 @@ def fused_multihead_attention(
     attention took them (normed and turned, in its dtype): head-major,
     [b, heads, sq, dh] and [b, groups, sk, dh] in either layout, with no
     gradient.
+
+    `diffusion_block` B > 0 (layout "bshd", in place of `causal`): block
+    diffusion's training mask. The sequence axis holds each sequence
+    twice, its L noisy rows and then its L clean rows, both at positions
+    0..L-1, cut in blocks of B: a noisy row sees its own noisy block,
+    both ways, and the clean blocks before it; a clean row the clean
+    blocks up to and with its own (`ops/fused_ops.py::
+    block_diffusion_mask`).
     """
     if layout not in ("bhsd", "bshd"):
         raise ValueError(f"layout must be 'bhsd' or 'bshd', got {layout!r}")
@@ -1523,6 +1532,8 @@ def fused_multihead_attention(
             **({"rotary_dim": int(rotary_dim)}
                if rotary_dim and rotary_dim != q.shape[-1] else {}),
             **({"admit_keys": int(admit_keys)} if admit is not None else {}),
+            **({"diffusion_block": int(diffusion_block)}
+               if diffusion_block else {}),
         },
     )
     more = ([lse] if return_lse else []) + (

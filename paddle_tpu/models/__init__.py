@@ -6,6 +6,9 @@ Flash, LFM2, Qwen3-Next and Nemotron-H (each a share of an
 expert-parallel decoder; Nemotron-H's mixers hold a share of their heads
 too), Keye-VL-2.0's language model (a share of an expert-parallel
 decoder whose attention keeps the keys a learned indexer chooses),
+SDAR's language model (the same share of the same decoder trained as a
+block-diffusion denoiser: a noisy and a clean copy of each row under one
+block-granular attention mask),
 Phi-4-mini-flash (a pipeline stage's share of a
 decoder-hybrid-decoder), Ouro (a pipeline stage's share of a decoder
 that runs its layers several times with one set of weights), and
@@ -28,6 +31,7 @@ from . import (  # noqa: F401
     phi4_flash,
     qwen3_next,
     resnet,
+    sdar,
     se_resnext,
     transformer,
     trinity,
@@ -43,4 +47,5 @@ from .olmo_hybrid import OlmoHybridConfig, build_olmo_hybrid  # noqa: E402,F401
 from .ouro import OuroConfig, build_ouro  # noqa: E402,F401
 from .phi4_flash import Phi4FlashConfig, build_phi4_flash  # noqa: E402,F401
 from .qwen3_next import Qwen3NextConfig, build_qwen3_next  # noqa: E402,F401
+from .sdar import SdarConfig, build_sdar  # noqa: E402,F401
 from .trinity import TrinityConfig, build_trinity  # noqa: E402,F401

@@ -254,7 +254,8 @@ def differential_attention(u, cfg, name, window=0, kv=None, lam0=0.8):
 
 
 def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
-              gated=False, rotary_dim=0, qk_norm=True, out_std=None):
+              gated=False, rotary_dim=0, qk_norm=True, out_std=None,
+              diffusion_block=0):
     """Causal attention of `num_attention_heads` query heads over
     `num_key_value_heads` key/value heads of `head_dim`, u [b, s, hidden]
     to [b, s, hidden]: q and k normed over a head's width (one weight of
@@ -266,7 +267,16 @@ def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
     `rotary_dim` not 0: the first `rotary_dim` lanes of a head alone),
     `window` keys wide where it is not 0, and with `gated` the output
     times `sigmoid(W_g u)` before the output projection. The heads are the
-    ones held here, which may be a share of the model's."""
+    ones held here, which may be a share of the model's.
+
+    `diffusion_block` B > 0: u holds each sequence twice, L = s / 2 noisy
+    rows and then its L clean rows, and the mask is block diffusion's
+    over blocks of B (`layers.fused_multihead_attention`) in place of the
+    causal one; both copies count positions 0..L-1. Counters, once a
+    layer built: `diffusion_layers`, `attn_pairs_admitted` (the three
+    rectangles' pairs: b (L B + (L^2 - L B) / 2 + (L^2 + L B) / 2)) and
+    `attn_pairs_causal` (b s (s + 1) / 2, the doubled row's); gauge
+    `diffusion_block_length`."""
     b, s, _ = u.shape
     h, g, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
 
@@ -287,9 +297,18 @@ def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
         prep.update(q_norm_attr=ParamAttr(name=name + ".q_norm.w_0"),
                     k_norm_attr=ParamAttr(name=name + ".k_norm.w_0"),
                     qk_norm_epsilon=cfg.rms_norm_eps)
+    if diffusion_block:
+        profiler.bump_counter("diffusion_layers")
+        profiler.set_counter("diffusion_block_length", diffusion_block)
+        profiler.bump_counter(
+            "attn_pairs_admitted",
+            b * cost.block_diffusion_pairs(s // 2, diffusion_block))
+        profiler.bump_counter("attn_pairs_causal",
+                              b * cost.admitted_pairs(s, s, causal=True))
+        prep.update(diffusion_block=diffusion_block)
     a = layers.fused_multihead_attention(
-        q, k, v, causal=True, sm_scale=1.0 / math.sqrt(d), layout="bshd",
-        window=window, **prep)
+        q, k, v, causal=not diffusion_block, sm_scale=1.0 / math.sqrt(d),
+        layout="bshd", window=window, **prep)
     a = layers.reshape(a, [b, s, h * d])
     if gated:
         a = layers.elementwise_mul(a, gate)

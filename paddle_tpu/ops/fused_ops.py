@@ -13,7 +13,7 @@ import numpy as np
 
 from .. import profiler
 from .nn_ops import rms_norm, rope_scaling_attr, rotate_half
-from .pallas import on_mesh
+from .pallas import cost, on_mesh
 from .pallas.flash_attention import _xla_attention, flash_attention
 from .pallas.mha_short import mha_short, mha_short_viable
 from .pallas.qk_prep import qk_prep, qk_prep_viable
@@ -94,6 +94,52 @@ def attention_path(q, k, v, *, layout, causal, window, group, mesh) -> str:
     return "xla"
 
 
+def block_diffusion_mask(length, block):
+    """Block diffusion's training mask (BD3-LM, arXiv:2503.09573, its
+    vectorised training) over the 2 x `length` rows `[noisy ; clean]` of
+    one sequence cut in blocks of `block`, as a [2L, 2L] boolean array:
+    `cost.block_diffusion_rules`' three rectangles, each `cost.admits`
+    under a granule, which is how the flash kernels take them, and
+    nothing where a clean row would see a noisy one; the whole array is
+    for the XLA path at small sizes and for the tests."""
+    i, j = np.arange(length)[:, None], np.arange(length)[None, :]
+    part = {name: cost.admits(i, j, *rule, block)
+            for name, rule in cost.block_diffusion_rules(block).items()}
+    return np.block([[part["own"], part["past"]],
+                     [np.zeros_like(part["own"]), part["clean"]]])
+
+
+def _block_diffusion_flash(q, k, v, block, sm_scale):
+    """q, k, v head-major, `[2b, heads, L, d]`, a sequence's noisy copy
+    and then its clean copy along the first axis. Three calls of the
+    flash kernels under a granule, none of which visits a block of scores
+    the mask empties: the clean copy on itself; the noisy copy on the
+    clean blocks before each query's own (`attn.noisy_past`) and on its
+    own noisy block (`attn.noisy_own`), one softmax over both key sets,
+    joined by the two calls' log-sum-exp rows in float32. The first
+    noisy block has no past: its rows of that call weigh exp(-1e30)."""
+    (qn, qc), (kn, kc), (vn, vc) = (
+        (t[0::2], t[1::2]) for t in (q, k, v))
+    def call(q, k, v, rule, **rows):
+        offset, window = cost.block_diffusion_rules(block)[rule]
+        return flash_attention(q, k, v, causal=True, sm_scale=sm_scale,
+                               granule=block, causal_offset=offset,
+                               window=window, **rows)
+
+    with jax.named_scope("attn.clean"):
+        clean = call(qc, kc, vc, "clean")
+    with jax.named_scope("attn.noisy_past"):
+        past, lse_past = call(qn, kc, vc, "past", with_lse=True,
+                              lse_grad=True)
+    with jax.named_scope("attn.noisy_own"):
+        own, lse_own = call(qn, kn, vn, "own", with_lse=True, lse_grad=True)
+    with jax.named_scope("attn.join"):
+        lse = jnp.logaddexp(lse_past, lse_own)
+        noisy = (past * jnp.exp(lse_past - lse)[..., None]
+                 + own * jnp.exp(lse_own - lse)[..., None]).astype(q.dtype)
+    return jnp.stack([noisy, clean], 1).reshape(q.shape[:3] + v.shape[3:])
+
+
 @register_op("fused_multihead_attention",
              no_grad_inputs=("KeyBias", "Admit"))
 def _fused_mha(ctx, op):
@@ -144,6 +190,17 @@ def _fused_mha(ctx, op):
     in either layout, with no gradient (what `index_kl` rebuilds the
     probabilities from). Both on the "flash" and "xla" paths alone.
 
+    Attr `diffusion_block` (optional, B > 0; layout "bshd"): block
+    diffusion's training mask in place of `causal`. Axis 1 holds a
+    sequence twice, its L noisy rows and then its L clean rows, both at
+    positions 0..L-1 (the norm and the rotation see them as two rows of
+    the batch), under `block_diffusion_mask(L, B)`: on the flash path
+    three calls of the kernels under a granule of B, told apart in a
+    device trace by the scopes `attn.clean`, `attn.noisy_past` and
+    `attn.noisy_own` under the op's (`_block_diffusion_flash`), on the
+    "xla" path the mask as an admission. No bias, window, dropout,
+    admission or extra output with it. Gauge `attn_diffusion_block`.
+
     Attr `q_lora_rank` (optional, > 0) labels a latent-attention call
     whose query came through a compressed latent; it changes nothing
     computed and counts `attn_latent_q_lora` once a lowering.
@@ -169,8 +226,16 @@ def _fused_mha(ctx, op):
     window = int(op.attr("window", 0) or 0)
     h_ax = 2 if bshd else 1
     group = q.shape[h_ax] // k.shape[h_ax]
+    block = int(op.attr("diffusion_block", 0) or 0)
     if window and not causal:
         raise ValueError("fused_multihead_attention: a window needs causal")
+    if block and (not bshd or window or dropout or bias is not None
+                  or admit is not None or with_lse or with_prepared
+                  or q.shape[1] % (2 * block)):
+        raise ValueError(
+            "fused_multihead_attention: diffusion_block takes layout "
+            "\"bshd\" with 2 x L rows, L a multiple of the block, and no "
+            "bias, window, dropout, admission, Lse or prepared output")
     if (q_norm is None) != (k_norm is None):
         raise ValueError(
             "fused_multihead_attention: QNorm and KNorm come together")
@@ -190,6 +255,12 @@ def _fused_mha(ctx, op):
             f"rope_theta, and to be even and at most the head's "
             f"{q.shape[-1]} lanes")
 
+    shapes = q.shape, k.shape, v.shape
+    if block:
+        # the noisy and the clean copy as two rows of the batch: each
+        # counts its own positions 0..L-1
+        q, k, v = (t.reshape(2 * t.shape[0], t.shape[1] // 2, *t.shape[2:])
+                   for t in (q, k, v))
     prepare = q_norm is not None or bool(rope_theta)
     if prepare:
         raw = q, k, v
@@ -215,17 +286,17 @@ def _fused_mha(ctx, op):
     rng = ctx.rng_for(op.output("Out")[0]) if dropout > 0.0 else None
 
     mesh = ctx.mesh
-    path = attention_path(q.shape, k.shape, v.shape, layout=layout,
-                          causal=causal, window=window, group=group,
-                          mesh=mesh)
+    path = attention_path(*shapes, layout=layout, causal=causal,
+                          window=window, group=group, mesh=mesh)
     if path == "ring" and (window or group != 1):
         raise ValueError(
             "fused_multihead_attention: ring sequence parallelism takes "
             "neither a window nor grouped key/value heads")
-    if path in ("ring", "short") and (admit is not None or with_lse):
+    if path in ("ring", "short") and (admit is not None or with_lse
+                                      or block):
         raise ValueError(
             f"fused_multihead_attention: the {path!r} path takes no "
-            "admission and gives no log-sum-exp rows")
+            "admission or diffusion_block and gives no log-sum-exp rows")
     profiler.bump_counter(f"attn_dispatch_{path}")
     if path == "flash" and window:
         profiler.bump_counter("attn_dispatch_flash_window")
@@ -236,6 +307,8 @@ def _fused_mha(ctx, op):
         profiler.set_counter("attn_rotary_lanes", rotary_dim)
     if op.attr("q_lora_rank", 0):
         profiler.bump_counter("attn_latent_q_lora")
+    if block:
+        profiler.set_counter("attn_diffusion_block", block)
     fused = (prepare and path == "flash" and bshd
              and qk_prep_viable(q.shape[-1], v.shape[-1]))
     if prepare and not fused:
@@ -262,9 +335,16 @@ def _fused_mha(ctx, op):
         # plain traced code: GSPMD partitions it from the feed and
         # parameter shardings, head (`model`) parallelism included
         scale = sm_scale or 1.0 / float(np.sqrt(q.shape[-1]))
-        out = _xla_attention(q, k, v, bias, causal, scale, dropout, rng,
-                             layout=layout, window=window, admit=admit,
-                             with_lse=with_lse)
+        if block:
+            mask = block_diffusion_mask(shapes[0][1] // 2, block)
+            out = _xla_attention(
+                q.reshape(shapes[0]), k.reshape(shapes[1]),
+                v.reshape(shapes[2]), None, False, scale, 0.0, None,
+                layout=layout, admit=jnp.asarray(mask[None], jnp.int8))
+        else:
+            out = _xla_attention(q, k, v, bias, causal, scale, dropout, rng,
+                                 layout=layout, window=window, admit=admit,
+                                 with_lse=with_lse)
     elif path == "flash":
         if fused:
             # from the arrays as they came: the kernel pair norms (with
@@ -282,12 +362,16 @@ def _fused_mha(ctx, op):
             operands = swap(q), swap(k), swap(v)
         # values narrower or wider than the keys: the kernel takes them at
         # their own width in whole lanes, and so writes the output
-        out = flash_attention(
-            *operands, bias=bias, causal=causal, sm_scale=sm_scale,
-            dropout=dropout, rng_key=rng, window=window, admit=admit,
-            admit_keys=int(op.attr("admit_keys", 0) or 0),
-            with_lse=with_lse)
-        out = (swap(out[0]), out[1]) if with_lse else swap(out)
+        if block:
+            out = _block_diffusion_flash(*operands, block, sm_scale)
+            out = swap(out).reshape(shapes[0][:3] + v.shape[3:])
+        else:
+            out = flash_attention(
+                *operands, bias=bias, causal=causal, sm_scale=sm_scale,
+                dropout=dropout, rng_key=rng, window=window, admit=admit,
+                admit_keys=int(op.attr("admit_keys", 0) or 0),
+                with_lse=with_lse)
+            out = (swap(out[0]), out[1]) if with_lse else swap(out)
     else:  # "ring"
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P

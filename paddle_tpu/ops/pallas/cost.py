@@ -62,16 +62,103 @@ def nbytes(*arrays) -> int:
                for shape, dtype in arrays)
 
 
-def admitted_pairs(sq, sk, causal=False, causal_offset=0, window=0) -> int:
+# The static masks' predicate, stated here once: the flash kernels mask a
+# block of scores by `admits`, their grids skip the blocks outside
+# `first_key` .. `last_key` of a query block's ends and `first_query` ..
+# `last_query` of a key block's, and `admitted_pairs` counts by the keys'
+# pair. The queries' pair is the keys' read along the other axis
+# (tests/test_flash_attention.py holds the two against each other by
+# brute force).
+#
+# A query `qi` sees the keys up to the end of its own granule of `granule`
+# rows, shifted by `causal_offset`, and with a window only the last
+# `window` of them:
+#
+#     last_key(qi) = qi // granule * granule + granule - 1 + causal_offset
+#     admitted iff ki <= last_key(qi)
+#                  and (no window or last_key(qi) - ki < window)
+#
+# Granule 1 is the causal mask (`ki <= qi + causal_offset`), a granule B
+# a mask causal by blocks of B rows and full inside a block (block
+# diffusion's: `block_diffusion_rules`). Every argument may be a Python
+# int, a NumPy array or a traced value; the granule and the window are
+# static. None is clipped to the rows there are. At granule 1 each is the
+# expression the kernels and the bands had before there was a granule,
+# term for term, so such a call traces the text it traced
+# (`tests/test_parents_jaxprs.py`).
+
+
+def last_key(qi, causal_offset=0, granule=1):
+    """The last key query `qi` admits."""
+    if granule == 1:
+        return qi + causal_offset
+    if granule & (granule - 1) == 0:  # no division inside a kernel
+        return (qi | (granule - 1)) + causal_offset
+    return qi // granule * granule + granule - 1 + causal_offset
+
+
+def first_key(qi, causal_offset, window, granule=1):
+    """The first key query `qi` admits under a window."""
+    return last_key(qi, causal_offset, granule) - window + 1
+
+
+def first_query(ki, causal_offset=0, granule=1):
+    """The first query that admits key `ki`: the first row of the first
+    granule whose end reaches `ki - causal_offset` (floor division)."""
+    if granule == 1:
+        return ki - causal_offset
+    return -((granule - 1 - (ki - causal_offset)) // granule) * granule
+
+
+def last_query(ki, causal_offset, window, granule=1):
+    """The last query that admits key `ki` under a window: the last row
+    of the last granule whose end stays under `ki - causal_offset +
+    window`."""
+    if granule == 1:
+        return ki - causal_offset + window - 1
+    return ((ki - causal_offset + window - granule) // granule * granule
+            + granule - 1)
+
+
+def admits(qi, ki, causal_offset=0, window=0, granule=1):
+    """Whether query `qi` admits key `ki` (arrays that broadcast)."""
+    keep = last_key(qi, causal_offset, granule) >= ki
+    if window:  # the end formed again, as ever: the compilers merge the two
+        keep = keep & (last_key(qi, causal_offset, granule) - ki < window)
+    return keep
+
+
+def admitted_pairs(sq, sk, causal=False, causal_offset=0, window=0,
+                   granule=1) -> int:
     """(query, key) pairs of one head's [sq, sk] rectangle that the static
-    masks admit: query `qi` sees the keys `ki <= qi + causal_offset` and,
-    with a window, only the last `window` of them (the predicate of
-    `flash_attention._admitted`, counted and not rounded to blocks)."""
+    masks admit (`admits`, counted and not rounded to blocks); every pair
+    without `causal`."""
     if not causal:
         return sq * sk
-    end = np.arange(sq) + causal_offset + 1  # one past the last key seen
-    first = np.clip(end - window, 0, sk) if window else 0
-    return int(np.sum(np.clip(end, 0, sk) - first))
+    qi = np.arange(sq)
+    first = (np.clip(first_key(qi, causal_offset, window, granule), 0, sk)
+             if window else 0)
+    return int(np.sum(
+        np.clip(last_key(qi, causal_offset, granule) + 1, 0, sk) - first))
+
+
+def block_diffusion_rules(block) -> dict:
+    """Block diffusion's training mask over a sequence's noisy and clean
+    copy, cut in blocks of `block`, as three rules of granule `block`,
+    each `(causal_offset, window)` over an [L, L] rectangle: `own`, a
+    noisy row on its own noisy block, both ways; `past`, a noisy row on
+    the clean blocks before its own, whole; `clean`, a clean row on the
+    clean blocks up to and with its own. No row sees a noisy block that
+    is not its own. What the attention op masks by, calls the flash
+    kernels under and counts (`ops/fused_ops.py`)."""
+    return {"own": (0, block), "past": (-block, 0), "clean": (0, 0)}
+
+
+def block_diffusion_pairs(length, block) -> int:
+    """Pairs a head admits under `block_diffusion_rules` over the
+    2 x `length` rows: L B + (L^2 - L B) / 2 + (L^2 + L B) / 2."""
+    return sum(admitted_pairs(length, length, True, offset, window, block)
+               for offset, window in block_diffusion_rules(block).values())
 
 
 def estimate(flops, transcendentals, *arrays) -> pl.CostEstimate:

@@ -13,7 +13,11 @@ head `n` are indexed `n // group`, the backward kernels that write dk and
 dv run over the key/value heads and sum over each one's group of query
 heads, and K and V are never repeated), optional additive key bias
 [b, sk] (the padding-mask case), `causal` flag, `window` (with `causal`:
-the last `window` keys a query may see). Each head width is zero-padded
+the last `window` keys a query may see), `granule` (with `causal`: the
+mask is causal by blocks of `granule` rows and full inside one, which
+with an offset and a window gives block diffusion's three rectangles;
+`cost.py` states the predicate once, for the scores masked, the blocks
+visited and the pairs declared alike). Each head width is zero-padded
 to a lane multiple (128) of its own: q, k, dq and dk travel at the keys'
 padded width, v, the output, dO and dv at the values', so values narrower
 than the keys (latent attention's 128 beside 192) cost P.V, dO.V^T and
@@ -62,6 +66,10 @@ block masked pairs are still computed and thrown away: with blocks of
 512 the causal kernel visits 53% of the rectangle at 16 x 16 blocks
 where the mask admits 50%, and a 2,048-key window on 8,192 tokens 27.3%
 where it admits 21.9%; the forward at blocks of 1,024 56% and 32.8%.
+Under a granule of 4 on 4,096 rows the clean copy's call visits the 36
+blocks of the lower triangle, a noisy block's call on the clean past
+the same 36 and its call on its own keys the diagonal's 8 (a visit
+there holds 2,048 admitted pairs of 262,144).
 Leaving the mask's arithmetic out of the blocks that lie wholly inside
 the band was measured in `flash_bwd_dkv_dq` and gave nothing (10.95 and
 11.04 ms at 8,192 causal tokens: PERF.md, PR 48). Without `causal`
@@ -145,12 +153,13 @@ def _dropout_keep(seed, bh_idx, q0, k0, shape, dropout):
 # ---------------------------------------------------------------------------
 #
 # One predicate, "this [block_q, block_k] block of scores can hold a pair
-# the masks admit", from `causal`, `causal_offset` and `window`. A query
-# `qi` admits the key `ki` iff `ki <= qi + causal_offset` (causal) and
-# `qi + causal_offset - ki < window` (window; 0: none), so the blocks a
-# query block can see are a run of key blocks, and the blocks that can see
-# a key block a run of query blocks: a band. The two functions below give
-# the run's ends; they are the same predicate read along either axis.
+# the masks admit", from `causal`, `causal_offset`, `window` and
+# `granule`: `cost.py` states it (`admits`, and its readings along either
+# axis, `first_key`, `last_key`, `first_query`, `last_query`). The last
+# key a query sees does not fall as the query rises, nor the first, so
+# the blocks a query block can see are a run of key blocks, and the
+# blocks that can see a key block a run of query blocks: a band. The two
+# functions below give the run's ends from a block's first and last row.
 # Each kernel's innermost grid axis is as long as the longest run, a step
 # past a run's end points at the run's last block again (the pipeline
 # copies nothing when the block index stays) and computes nothing. `xp`
@@ -162,13 +171,12 @@ def _key_band(j, m, xp=jnp):
     """(first, last) key block of query block `j`."""
     first, last = 0 * j, 0 * j + (m.nk - 1)
     if m.window:
-        first = xp.minimum(xp.maximum(
-            j * m.block_q + m.causal_offset - m.window + 1, 0) // m.block_k,
-            m.nk - 1)
+        lo = cost.first_key(j * m.block_q, *m.rule)
+        first = xp.minimum(xp.maximum(lo, 0) // m.block_k, m.nk - 1)
     if m.causal:
-        last = xp.minimum(xp.maximum(
-            (j + 1) * m.block_q - 1 + m.causal_offset, 0) // m.block_k,
-            m.nk - 1)
+        hi = cost.last_key((j + 1) * m.block_q - 1, m.causal_offset,
+                           m.granule)
+        last = xp.minimum(xp.maximum(hi, 0) // m.block_k, m.nk - 1)
     return first, xp.maximum(last, first)
 
 
@@ -176,12 +184,11 @@ def _query_band(kb, m, xp=jnp):
     """(first, last) query block of key block `kb`."""
     first, last = 0 * kb, 0 * kb + (m.nq - 1)
     if m.causal:
-        first = xp.minimum(xp.maximum(
-            kb * m.block_k - m.causal_offset, 0) // m.block_q, m.nq - 1)
+        lo = cost.first_query(kb * m.block_k, m.causal_offset, m.granule)
+        first = xp.minimum(xp.maximum(lo, 0) // m.block_q, m.nq - 1)
     if m.window:
-        last = xp.minimum(xp.maximum(
-            (kb + 1) * m.block_k - 1 - m.causal_offset + m.window - 1, 0)
-            // m.block_q, m.nq - 1)
+        hi = cost.last_query((kb + 1) * m.block_k - 1, *m.rule)
+        last = xp.minimum(xp.maximum(hi, 0) // m.block_q, m.nq - 1)
     return first, xp.maximum(last, first)
 
 
@@ -198,11 +205,18 @@ class _Masks(typing.NamedTuple):
     block_k: int
     nq: int
     nk: int
+    granule: int = 1
 
     @classmethod
-    def of(cls, sq, sk, *, causal, causal_offset, window, block_q, block_k):
+    def of(cls, sq, sk, *, causal, causal_offset, window, block_q, block_k,
+           granule=1):
         return cls(causal, causal_offset, window, block_q, block_k,
-                   sq // block_q, sk // block_k)
+                   sq // block_q, sk // block_k, granule)
+
+    @property
+    def rule(self):
+        """What `cost.py`'s predicate takes after the row."""
+        return self.causal_offset, self.window, self.granule
 
     def key_steps(self):
         """Length of the key axis of `flash_fwd` and `flash_bwd_dq`."""
@@ -218,7 +232,8 @@ class _Masks(typing.NamedTuple):
         """The same masks over the same rows at other blocks."""
         return self.of(self.nq * self.block_q, self.nk * self.block_k,
                        causal=self.causal, causal_offset=self.causal_offset,
-                       window=self.window, block_q=block_q, block_k=block_k)
+                       window=self.window, block_q=block_q, block_k=block_k,
+                       granule=self.granule)
 
     def visited(self, fwd_blocks=None, fused=False):
         """Blocks one head's grids compute, and the rectangles', in units
@@ -257,11 +272,7 @@ def _admitted(s, j, kb, m, admit_ref=None):
         return s
     qi = j * m.block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     ki = kb * m.block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    # bottom-right aligned: query row qi sees keys up to qi + offset
-    keep = qi + m.causal_offset >= ki
-    if m.window:
-        keep = keep & (qi + m.causal_offset - ki < m.window)
-    return jnp.where(keep, s, NEG_INF)
+    return jnp.where(cost.admits(qi, ki, *m.rule), s, NEG_INF)
 
 
 def _head_maps(group):
@@ -292,7 +303,7 @@ def _cost(kernel, q, k, bias, masks, dims, admit=None, admit_keys=0):
     convention) from the flattened, padded operands `q` [b*h, ., .] and
     `k` [b*hkv, ., .] and `dims`, the lengths and head widths before any
     padding, `(sq, sk, d, dv)` (None: the arrays' own). FLOPs follow the
-    pairs `causal` and `window` admit, so a window counts fewer than its
+    pairs `causal`, `window` and `granule` admit, so a window counts fewer than its
     causal twin by the arithmetic and not by block rounding; masked pairs
     inside a visited block and the padded lanes do not count. One
     exponential a pair; `flash_fwd` a reciprocal and a logarithm a row.
@@ -306,7 +317,7 @@ def _cost(kernel, q, k, bias, masks, dims, admit=None, admit_keys=0):
     bounds = [n for n in (masks.window, admit_keys) if n]
     pairs = bh * cost.admitted_pairs(
         sq, sk, causal=masks.causal, causal_offset=masks.causal_offset,
-        window=min(bounds, default=0))
+        window=min(bounds, default=0), granule=masks.granule)
     over_d, over_dv = _PRODUCTS[kernel]
     queries, outputs = ((bh, sq, d), q.dtype), ((bh, sq, dv), q.dtype)
     keys, values = ((bhkv, sk, d), k.dtype), ((bhkv, sk, dv), k.dtype)
@@ -419,7 +430,7 @@ def _optional(refs, present):
 
 def _fwd_pallas(q, k, v, bias, seed, h, admit=None, *, sm_scale, causal,
                 causal_offset, dropout, block_q, block_k, window=0,
-                dims=None, admit_keys=0):
+                dims=None, admit_keys=0, granule=1):
     """q: [b*h, sq, d], k: [b*hkv, sk, d] and v: [b*hkv, sk, dv], whole
     blocks and whole lanes; `admit`: [b, sq, sk] int8 or None; `dims` and
     `admit_keys`: what `_cost` reads. Returns the
@@ -428,7 +439,8 @@ def _fwd_pallas(q, k, v, bias, seed, h, admit=None, *, sm_scale, causal,
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
     masks = _Masks.of(sq, sk, causal=causal, causal_offset=causal_offset,
-                      window=window, block_q=block_q, block_k=block_k)
+                      window=window, block_q=block_q, block_k=block_k,
+                      granule=granule)
     steps = masks.key_steps()
     q_at, _, k_at = _head_maps(bh // k.shape[0])
 
@@ -659,19 +671,27 @@ def _bwd_fused_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+def _delta_rows(out, do):
+    """`sum over the lanes of out * dO`, [b*h, 1, sq] float32: what a
+    row's `dS` subtracts from `dP`."""
+    return jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
+                   axis=-1)[:, None, :]
+
+
 def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, admit=None, *,
                 sm_scale, causal, causal_offset, dropout, block_q, block_k,
-                delta=None, window=0, dims=None, admit_keys=0):
+                delta=None, window=0, dims=None, admit_keys=0, granule=1):
     bh, sq, d = q.shape
     bhkv, sk, dv = k.shape[0], k.shape[1], v.shape[2]
     group = bh // bhkv
     hkv = h // group
     masks = _Masks.of(sq, sk, causal=causal, causal_offset=causal_offset,
-                      window=window, block_q=block_q, block_k=block_k)
+                      window=window, block_q=block_q, block_k=block_k,
+                      granule=granule)
     q_at, kv_at, k_at = _head_maps(group)
 
     if delta is None:
-        delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)[:, None, :]
+        delta = _delta_rows(out, do)
 
     common = dict(sm_scale=sm_scale, dropout=dropout, masks=masks,
                   operands=(bias is not None, admit is not None))
@@ -773,7 +793,8 @@ def _bwd_pallas(q, k, v, bias, seed, out, lse, do, h, admit=None, *,
 
 def _bwd_fused_pallas(q, k, v, bias, seed, out, lse, do, h, admit=None, *,
                       sm_scale, causal, causal_offset, dropout, block_q,
-                      block_k, window=0, dims=None, admit_keys=0):
+                      block_k, delta=None, window=0, dims=None,
+                      admit_keys=0, granule=1):
     """`_bwd_pallas`' dq, dk, dv from one call that visits a block of
     scores once: `flash_bwd_dq`'s walk, a query block and its run of key
     blocks, under a key/value head's row of the grid, with that head's dk
@@ -784,10 +805,11 @@ def _bwd_fused_pallas(q, k, v, bias, seed, out, lse, do, h, admit=None, *,
     group = bh // bhkv
     hkv = h // group
     masks = _Masks.of(sq, sk, causal=causal, causal_offset=causal_offset,
-                      window=window, block_q=block_q, block_k=block_k)
+                      window=window, block_q=block_q, block_k=block_k,
+                      granule=granule)
     steps, nq = masks.key_steps(), masks.nq
-    delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
-                    axis=-1)[:, None, :]
+    if delta is None:
+        delta = _delta_rows(out, do)
 
     def key_block(g, t):
         return _block_of(_key_band, g % nq, t, masks)
@@ -859,7 +881,7 @@ def _bwd_fused_pallas(q, k, v, bias, seed, out, lse, do, h, admit=None, *,
 # the two custom calls only if they are the same call (a kernel traced
 # under the vjp rule is named `jvp_flash_fwd_` and is another).
 _STATICS = ("sm_scale", "causal", "causal_offset", "dropout", "block_q",
-            "block_k", "window", "dims", "admit_keys")
+            "block_k", "window", "dims", "admit_keys", "granule")
 _fwd_call = jax.jit(_fwd_pallas, static_argnums=(5,), static_argnames=_STATICS)
 _bwd_call = jax.jit(_bwd_pallas, static_argnums=(8,), static_argnames=_STATICS)
 _bwd_fused_call = jax.jit(_bwd_fused_pallas, static_argnums=(8,),
@@ -870,15 +892,20 @@ def _statics_of(statics):
     """(`flash_fwd`'s statics, the backward's) from the one tuple a
     call carries: the same but for the blocks, the forward's own under
     `fwd_blocks`. The output and the log-sum-exp rows do not depend on
-    the blocks that made them, so the backward reads them at its own."""
+    the blocks that made them, so the backward reads them at its own.
+    `lse_grad` is the vjp rule's alone."""
     bwd = dict(statics)
     block_q, block_k = bwd.pop("fwd_blocks")
+    bwd.pop("lse_grad", None)
     return {**bwd, "block_q": block_q, "block_k": block_k}, bwd
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _flash_core(q, k, v, bias, seed, h, statics, admit=None):
-    """(output, log-sum-exp rows): the rows carry no gradient."""
+    """(output, log-sum-exp rows). The rows carry a gradient where the
+    call asks for one (`lse_grad`): a row's log-sum-exp moves with its
+    scores by their probabilities, so its cotangent joins `dS = p (dP -
+    delta)` as `delta - dlse`, and the kernels are the same."""
     return _fwd_call(q, k, v, bias, seed, h, admit,
                      **_statics_of(statics)[0])
 
@@ -891,11 +918,13 @@ def _flash_core_fwd(q, k, v, bias, seed, h, statics, admit=None):
 
 def _flash_core_bwd(h, statics, res, cotangents):
     q, k, v, bias, seed, out, lse, admit = res
-    do, _ = cotangents
+    do, dlse = cotangents
     fused = _bwd_fused_viable(k.shape[1], k.shape[2], v.shape[2],
                               k.dtype.itemsize)
+    delta = (_delta_rows(out, do) - dlse if dict(statics).get("lse_grad")
+             else None)
     dq, dk, dv = (_bwd_fused_call if fused else _bwd_call)(
-        q, k, v, bias, seed, out, lse, do, h, admit,
+        q, k, v, bias, seed, out, lse, do, h, admit, delta=delta,
         **_statics_of(statics)[1])
     dbias = None if bias is None else jnp.zeros_like(bias)
     dseed = np.zeros((1,), dtype=jax.dtypes.float0)
@@ -1159,6 +1188,9 @@ def flash_attention(
     admit=None,
     admit_keys=0,
     with_lse=False,
+    granule=1,
+    causal_offset=None,
+    lse_grad=False,
 ):
     """Fused multi-head attention.
 
@@ -1181,7 +1213,25 @@ def flash_attention(
     the static masks admit. `admit_keys`: the keys a query admits at most
     (what a selection of the K largest leaves), for the declared count
     alone. `with_lse`: returns (out, the rows' log-sum-exp over the
-    admitted scores, [b, h, sq] float32, which carries no gradient).
+    admitted scores, [b, h, sq] float32, which carries no gradient
+    unless `lse_grad`, for a caller that joins two calls' softmaxes over
+    two key sets by their rows: `out = sum_i out_i exp(lse_i - lse)`).
+
+    `granule` > 1 (with `causal`): a mask causal by blocks of `granule`
+    rows and full inside one. Query `qi` sees the keys up to
+    `qi // granule * granule + granule - 1 + causal_offset`, the end of
+    its own granule shifted, and with a `window` only the last `window`
+    of those. `causal_offset`: None aligns the last query with the last
+    key (`sk - sq`); a caller names another. Block diffusion's training
+    mask over a noisy and a clean copy of a row cut in blocks of B is
+    three such calls: clean on clean granule B offset 0, noisy on clean
+    granule B offset -B, noisy on its own block granule B offset 0
+    window B. The grids skip the blocks of scores such a rule empties as
+    they skip those above the diagonal, and the declared work counts the
+    pairs it admits (`cost.admitted_pairs`). A query row that admits no
+    key (the first noisy block on the clean copy) returns a log-sum-exp
+    of about -1e30 and an output that is no one's: the caller's join
+    weighs it by exp(-1e30 - lse) = 0.
     """
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
@@ -1192,8 +1242,9 @@ def flash_attention(
     if h % k.shape[1]:
         raise ValueError(f"flash_attention: {h} query heads over "
                          f"{k.shape[1]} key/value heads")
-    if window and not causal:
-        raise ValueError("flash_attention: a window needs causal=True")
+    if (window or granule != 1 or causal_offset is not None) and not causal:
+        raise ValueError("flash_attention: a window needs causal=True, and "
+                         "so do a granule and an offset")
     if dropout > 0.0 and rng_key is None:
         raise ValueError("dropout requires rng_key")
     if dropout > 0.0:
@@ -1206,7 +1257,8 @@ def flash_attention(
     # bottom-right-aligned causal offset in ORIGINAL coords (matches the
     # XLA reference path when sq != sk); padding doesn't shift it because
     # padded q rows are sliced away and padded keys are bias-masked
-    causal_offset = sk - sq
+    if causal_offset is None:
+        causal_offset = sk - sq
     qf, kf, vf, biasf, bq, bk = _pad_inputs(q, k, v, bias, block_q, block_k)
     fwd_blocks = bq, bk  # blocks passed by hand serve every kernel
     if block_q is None and block_k is None:
@@ -1214,7 +1266,7 @@ def flash_attention(
                                  kf.shape[2], vf.shape[2], qf.dtype.itemsize)
     masks = _Masks.of(qf.shape[1], kf.shape[1], causal=bool(causal),
                       causal_offset=causal_offset, window=int(window),
-                      block_q=bq, block_k=bk)
+                      block_q=bq, block_k=bk, granule=int(granule))
     fused = _bwd_fused_viable(kf.shape[1], kf.shape[2], vf.shape[2],
                               kf.dtype.itemsize)
     visited, total = masks.visited(fwd_blocks, fused)
@@ -1233,7 +1285,8 @@ def flash_attention(
                ("causal_offset", causal_offset), ("dropout", float(dropout)),
                ("block_q", bq), ("block_k", bk), ("fwd_blocks", fwd_blocks),
                ("window", int(window)), ("dims", (sq, sk, d, dv)),
-               ("admit_keys", int(admit_keys)))
+               ("admit_keys", int(admit_keys)), ("granule", int(granule)),
+               ("lse_grad", bool(lse_grad)))
     if admit is not None:
         admit = jnp.pad(admit.astype(jnp.int8), [
             (0, 0), (0, qf.shape[1] - sq), (0, kf.shape[1] - sk)])

@@ -191,7 +191,7 @@ class Suite:
                  amp_loss_room=6.5, gradients_at=None, on_gradients=None,
                  seed=1, gradient_row=1024, checkpointed=None,
                  chip_kinds=None, chip_routed=(0.3, 0.6), reading_more=None,
-                 steps=44, step_counters=(), gauges=()):
+                 steps=44, step_counters=(), gauges=(), rows_per_token=1):
         self.name, self.adapter, self.kinds = cell, adapter, kinds
         # the configuration at which a layer weighs in the residual stream
         # as at the published width, so that a wrong model shows
@@ -224,6 +224,9 @@ class Suite:
         self.chip_routed, self.reading_more = chip_routed, reading_more
         self.steps, self.step_counters, self.gauges = (
             steps, tuple(step_counters), tuple(gauges))
+        # rows of a layer's input a token of the traffic (2 where a step
+        # runs a noisy and a clean copy of every row)
+        self.rows_per_token = rows_per_token
 
     def cell(self, rehearse=True, **config):
         from benchmark.harness import spec
@@ -404,8 +407,9 @@ def test_program_mixer_equals_reference(suite, which):
 def test_whole_model_logits_and_loss_equal_reference_float32(suite,
                                                              float32_run):
     model, batch, _, got = float32_run
-    np.testing.assert_array_equal(batch["labels"][:, :-1],
-                                  batch["tokens"][:, 1:])
+    if "labels" in batch:  # a next-token model's; a denoiser has none
+        np.testing.assert_array_equal(batch["labels"][:, :-1],
+                                      batch["tokens"][:, 1:])
     rows, scored, vocab = np.asarray(got[1]).shape
     assert (rows, vocab) == (suite.adapter.SCORED_SEQUENCES,
                              model["vocab_size"])
@@ -512,8 +516,9 @@ def _loads(suite, seeds, rate=None, steps=None):
         model["optimizer"] = dict(model["optimizer"], learning_rate=rate)
     # a layer's assignments a step: Kimi's configuration spells the key
     # out, a dense model has neither
-    total = traffic["batch"] * traffic["seq_len"] * model.get(
-        "num_experts_per_tok", model.get("num_experts_per_token", 0))
+    total = (traffic["batch"] * traffic["seq_len"] * suite.rows_per_token
+             * model.get("num_experts_per_tok",
+                         model.get("num_experts_per_token", 0)))
     c0 = profiler.counters()
 
     def row(values):

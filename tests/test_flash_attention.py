@@ -1778,3 +1778,222 @@ def test_a_loss_on_the_prepared_pair_reaches_no_input():
             return_prepared=True)
         loss = fluid.layers.reduce_sum(fluid.layers.elementwise_add(qp, kp))
         assert fluid.backward.calc_gradient(loss, [q]) == [None]
+
+
+# ------------------------------------------------- a mask causal by blocks
+#
+# `granule` B: a query sees the keys up to the end of its own block of B
+# rows, shifted by `causal_offset`, and with a window the last `window`
+# of them. Block diffusion's training mask is three such rules
+# (`ops/fused_ops.py::block_diffusion_mask`).
+
+cost = importlib.import_module("paddle_tpu.ops.pallas.cost")
+
+
+def _dense(sq, sk, offset, window, granule):
+    """The rule a pair at a time, from its sentence and not from
+    `cost.py`: the end of the query's granule, shifted."""
+    last = np.arange(sq) // granule * granule + granule - 1 + offset
+    ki = np.arange(sk)[None, :]
+    keep = ki <= last[:, None]
+    if window:
+        keep &= last[:, None] - ki < window
+    return keep
+
+
+# (granule, causal_offset, window): the clean copy on itself, a noisy
+# block on the clean blocks before it, a noisy block on itself
+_GRANULE_RULES = {
+    "g4_clean": (4, 0, 0), "g4_past": (4, -4, 0), "g4_own": (4, 0, 4),
+    "g8_clean": (8, 0, 0), "g8_past": (8, -8, 0), "g8_own": (8, 0, 8),
+    # no power of two: the division stays
+    "g6_clean": (6, 0, 0), "g6_own": (6, 0, 6),
+}
+
+
+@pytest.mark.parametrize("rule", list(_GRANULE_RULES))
+def test_a_granule_matches_the_plain_path_under_the_dense_mask(
+        rng, rule, backward):
+    """The output, the log-sum-exp rows and all three gradients, the rows'
+    own cotangent among them (`lse_grad`), at sq != sk and eight query
+    heads on one key/value head, against `_attention_unfused` under the
+    rule as an admission. A query that admits no key (offset -g: the first
+    granule) is no one's and carries no weight."""
+    granule, offset, window = _GRANULE_RULES[rule]
+    b, h, hkv, sq, sk, d = 1, 8, 1, 264, 384, 32
+    q, k, v, w = _band_case(rng, b, h, hkv, sq, sk, d)
+    mask = _dense(sq, sk, offset, window, granule)
+    seen = jnp.asarray(mask.any(1))
+    w = w * seen[None, None, :, None]
+    w_lse = jnp.asarray(rng.randn(b, h, sq), jnp.float32) * seen
+    admit = jnp.asarray(mask[None], jnp.int8)
+
+    def flash(q, k, v):
+        return fa.flash_attention(
+            q, k, v, causal=True, granule=granule, causal_offset=offset,
+            window=window, block_q=128, block_k=128, with_lse=True,
+            lse_grad=True)
+
+    def plain(q, k, v):
+        return fa._attention_unfused(q, k, v, None, False, d ** -0.5, 0.0,
+                                     None, True, admit=admit, with_lse=True)
+
+    def weighted(fn):
+        def loss(q, k, v):
+            out, lse = fn(q, k, v)
+            return jnp.sum(out * w) + jnp.sum(jnp.where(seen, lse, 0.0)
+                                              * w_lse)
+        return loss
+
+    (out, lse), (want, want_lse) = flash(q, k, v), plain(q, k, v)
+    assert float(jnp.abs((out - want) * seen[:, None]).max()) < 2e-6
+    assert float(jnp.abs(jnp.where(seen, lse - want_lse, 0.0)).max()) < 2e-6
+    if offset < 0:  # the rows with no key say so in their log-sum-exp
+        assert float(lse[..., :granule].max()) < -1e29
+    got = jax.grad(weighted(flash), (0, 1, 2))(q, k, v)
+    for g, want in zip(got, jax.grad(weighted(plain), (0, 1, 2))(q, k, v)):
+        assert float(jnp.abs(g - want).max()) < 2e-5
+
+
+@pytest.mark.parametrize("rule", list(_GRANULE_RULES))
+@pytest.mark.parametrize("sq,sk", [(37, 41), (64, 48)])
+def test_admitted_pairs_and_both_spans_against_a_count(rule, sq, sk):
+    """`cost.admitted_pairs`, `admits`, and the queries' pair of ends as
+    the keys' read along the other axis, keys and queries outside the rectangle
+    among them (the bands ask for them before they clip)."""
+    granule, offset, window = _GRANULE_RULES[rule]
+    mask = _dense(sq, sk, offset, window, granule)
+    assert cost.admitted_pairs(sq, sk, True, offset, window, granule) == (
+        mask.sum())
+    np.testing.assert_array_equal(cost.admits(
+        np.arange(sq)[:, None], np.arange(sk)[None, :], offset, window,
+        granule), mask)
+    queries = np.arange(-4 * granule, max(sq, sk) + 6 * granule)
+    last = cost.last_key(queries, offset, granule)
+    if window:
+        np.testing.assert_array_equal(
+            cost.first_key(queries, offset, window, granule),
+            last - window + 1)
+    for ki in range(-2 * granule, sk + 2 * granule):
+        sees = last >= ki
+        if window:
+            sees &= last - ki < window
+        first_q = cost.first_query(ki, offset, granule)
+        last_q = cost.last_query(ki, offset, window, granule)
+        if sees.any():
+            assert queries[sees].min() == first_q
+            assert not window or queries[sees].max() == last_q
+        else:
+            assert window and last_q < first_q
+
+
+def test_granule_one_is_the_causal_mask_and_its_spans():
+    for offset, window in ((0, 0), (5, 0), (-3, 7), (2, 4)):
+        qi, ki = np.arange(40)[:, None], np.arange(50)[None, :]
+        keep = ki <= qi + offset
+        if window:
+            keep &= qi + offset - ki < window
+        np.testing.assert_array_equal(cost.admits(qi, ki, offset, window),
+                                      keep)
+        assert cost.last_key(9, offset) == 9 + offset
+        assert cost.first_query(9, offset) == 9 - offset
+        if window:
+            assert cost.first_key(9, offset, window) == (
+                9 + offset - window + 1)
+            assert cost.last_query(9, offset, window) == (
+                9 - offset + window - 1)
+
+
+@pytest.mark.parametrize("rule,length,block,fwd_blocks,by_hand", [
+    # the cell's three calls, L = 4,096 in blocks of 4, 8 x 8 blocks of
+    # 512: the clean copy's lower triangle 36; the past the same 36 (the
+    # diagonal block holds the block's earlier granules); a noisy block's
+    # own keys the diagonal, 8. The forward at 1,024: 10, 10 and 4, each
+    # four of the backward's
+    ((4, 0, 0), 4096, 512, None, (36, 36)),
+    ((4, -4, 0), 4096, 512, None, (36, 36)),
+    ((4, 0, 4), 4096, 512, None, (8, 8)),
+    ((4, 0, 0), 4096, 512, (1024, 1024), (4 * 10, 36)),
+    ((4, -4, 0), 4096, 512, (1024, 1024), (4 * 10, 36)),
+    ((4, 0, 4), 4096, 512, (1024, 1024), (4 * 4, 8)),
+    # a granule as long as a block: the past leaves the diagonal out
+    ((128, 0, 0), 512, 128, None, (10, 10)),
+    ((128, -128, 0), 512, 128, None, (6 + 1, 6 + 1)),  # + the ragged edge
+    ((128, 0, 128), 512, 128, None, (4, 4)),
+])
+def test_granule_grids_skip_every_empty_tile(rule, length, block, fwd_blocks,
+                                             by_hand):
+    """What `flash_fwd`'s grid (at its own blocks) and the backward's
+    visit a head, and that every tile visited admits a pair but where a
+    run would otherwise be empty (the first query block of a past that
+    starts a whole block later)."""
+    granule, offset, window = rule
+    masks = fa._Masks.of(length, length, causal=True, causal_offset=offset,
+                         window=window, block_q=block, block_k=block,
+                         granule=granule)
+    fwd, bwd = by_hand
+    assert masks.visited(fwd_blocks, fused=True) == (
+        fwd + bwd, 2 * masks.nq * masks.nk)
+    dense = _dense(length, length, offset, window, granule)
+    for m in (masks, masks.at(*fwd_blocks) if fwd_blocks else masks):
+        tiles = dense.reshape(m.nq, m.block_q, m.nk, m.block_k).any((1, 3))
+        first, last = fa._key_band(np.arange(m.nq), m, np)
+        qfirst, qlast = fa._query_band(np.arange(m.nk), m, np)
+        for j in range(m.nq):
+            for kb in range(m.nk):
+                by_keys = first[j] <= kb <= last[j]
+                by_queries = qfirst[kb] <= j <= qlast[kb]
+                if tiles[j, kb]:
+                    assert by_keys and by_queries, (j, kb)
+                elif tiles[j].any():  # no empty tile in a run that has one
+                    assert not by_keys, (j, kb)
+                elif tiles[:, kb].any():
+                    assert not by_queries, (j, kb)
+
+
+def test_the_block_diffusion_calls_declare_the_masks_pairs(monkeypatch):
+    """The three calls of `fused_ops._block_diffusion_flash` declare, in
+    `flash_fwd` and in `flash_bwd_dkv_dq`, the pairs the mask admits and
+    no other: L B + (L^2 - L B) / 2 + (L^2 + L B) / 2 a head."""
+    from pallas_costs import declared
+
+    from paddle_tpu.ops import fused_ops
+
+    length, block, h, d = 512, 4, 8, 128
+    q = jnp.zeros((2, h, length, d), jnp.bfloat16)
+    kv = jnp.zeros((2, 1, length, d), jnp.bfloat16)
+
+    def grads():
+        return jax.grad(lambda *a: jnp.sum(fused_ops._block_diffusion_flash(
+            *a, block, None).astype(jnp.float32)), argnums=(0, 1, 2))
+
+    found = declared(grads(), q, kv, kv)
+    assert {n: len(c) for n, c in found.items()} == {
+        "flash_fwd": 3, "flash_bwd_dkv_dq": 3}
+    pairs = h * (length * block + (length * length - length * block) // 2
+                 + (length * length + length * block) // 2)
+    assert pairs == h * fused_ops.block_diffusion_mask(length, block).sum()
+    assert sum(c.flops for c in found["flash_fwd"]) == 4 * d * pairs
+    assert sum(c.flops for c in found["flash_bwd_dkv_dq"]) == 10 * d * pairs
+    assert sum(c.transcendentals for c in found["flash_bwd_dkv_dq"]) == pairs
+
+
+def test_a_granule_needs_causal_and_the_op_refuses_what_it_cannot_join(rng):
+    q, k, v = _rand_qkv(rng, 1, 2, 128, 32)
+    with pytest.raises(ValueError, match="window needs causal"):
+        fa.flash_attention(q, k, v, granule=4)
+    with pytest.raises(ValueError, match="window needs causal"):
+        fa.flash_attention(q, k, v, causal_offset=-4)
+    import paddle_tpu as fluid
+
+    for rows, more in ((64, {"window": 8, "causal": True}), (60, {}),
+                       (64, {"layout": "bhsd"})):
+        with fluid.program_guard(fluid.Program(), fluid.Program()):
+            x = fluid.layers.data("x", [1, rows, 2, 16],
+                                  append_batch_size=False)
+            out = fluid.layers.fused_multihead_attention(
+                x, x, x, **{"layout": "bshd", "diffusion_block": 4, **more})
+            with pytest.raises(Exception, match="diffusion_block takes"):
+                fluid.Executor(fluid.CPUPlace()).run(
+                    feed={"x": np.zeros((1, rows, 2, 16), np.float32)},
+                    fetch_list=[out])

@@ -15,7 +15,8 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 ADAPTERS = ("kimi_linear", "trinity", "mellum", "joyai_flash", "phi4_flash",
-            "lfm2", "qwen3_next", "nemotron_h", "keye_vl2", "olmo_hybrid")
+            "lfm2", "qwen3_next", "nemotron_h", "keye_vl2", "olmo_hybrid",
+            "sdar")
 
 
 def names_used(code):

@@ -21,7 +21,10 @@ which no other cell has. Its row and `ouro_2p6b_vp8_s4096`'s are PR 61's
 own tree's: that PR took the positions of both and the QK-norm of Keye's
 into `fused_multihead_attention`; the thirteen other rows stood. PR 63
 added `olmo_hybrid_7b_vp8_longdoc` (its `kda_attention` ops carry
-`beta_scale`, which no other cell's do: the fifteen other rows stood)."""
+`beta_scale`, which no other cell's do: the fifteen other rows stood).
+PR 68 added `sdar_30b_a3b_ep8_s4096` (its `fused_multihead_attention` ops
+carry `diffusion_block`, the attribute at `ATTRS`' end, which no other
+cell's do: the sixteen other rows stood)."""
 
 import hashlib
 import json
@@ -37,7 +40,7 @@ OPS = ("fused_multihead_attention", "rotary_embedding", "short_conv1d",
        "kda_attention", "selective_scan", "ssd_scan", "moe_experts",
        "rms_norm_grad", "sparse_index", "sparse_select", "index_kl")
 ATTRS = ("rope_scaling", "interleaved", "q_lora_rank", "activation",
-         "norm_eps", "rotary_dim", "expert_form")
+         "norm_eps", "rotary_dim", "expert_form", "diffusion_block")
 
 PINS = {
     "bert_base_s128": (
@@ -116,6 +119,11 @@ PINS = {
         391, "b77c60bd17d83405",
         {"fused_multihead_attention": 1, "short_conv1d": 3,
          "kda_attention": 3, "rms_norm_grad": 14}, ()),
+    # PR 68's own tree: the cell it added
+    "sdar_30b_a3b_ep8_s4096": (
+        172, "dbc029f9df8d353d",
+        {"fused_multihead_attention": 2, "moe_experts": 2,
+         "rms_norm_grad": 5}, ("diffusion_block",)),
 }
 
 

@@ -629,7 +629,7 @@ def test_the_expert_layers_two_metrics_on_a_hand_made_reading(metric):
     """`trace_stage_share` on a `scope_ns` table with known answers (the
     innermost stage counts, the share is over all busy time, mean over
     the devices, None without a stage), and `moe_held_load_pct` on the
-    window's counts; both declared for the eight expert cells."""
+    window's counts; both declared for the nine expert cells."""
     from benchmark.harness import spec
     from benchmark.harness.sources import counter_ratio, trace_stage_share
 
@@ -639,7 +639,7 @@ def test_the_expert_layers_two_metrics_on_a_hand_made_reading(metric):
     declared = {m["name"]: m for m in bench["per_layer"]}
     m = spec.load("layer_metrics", metric)
     assert declared[metric]["workloads"] == declared["moe_device_pct"][
-        "workloads"] and len(declared[metric]["workloads"]) == 8
+        "workloads"] and len(declared[metric]["workloads"]) == 9
     assert m["where"] == {"config.mechanisms": ["experts"]}
     assert (m["unit"], m["layer"], m["source"], m["better"]) == tuple(
         declared[metric][k] for k in ("unit", "layer", "source", "better"))
