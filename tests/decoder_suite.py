@@ -120,6 +120,37 @@ def expert_params(r, hidden, width, total, bias_scale=0.1):
     }
 
 
+def routed_shares(layer, hold, feed, shares, total, held):
+    """Every share's (routed part, load) of an expert layer of `total`
+    experts cut into `shares` of `held`, from one Program of four layers
+    where stating all the shares in one took up to a minute and a half to
+    compile. `layer(i)` states share `i` with its own `held_from` and
+    returns its two outputs; `hold(i, order, lo)` gives layer `i` the
+    router's columns in `order` and the experts `order[lo:lo + held]`.
+    Three shares are stated so and in the experts' own order (the second,
+    one in the middle, the last); every other share is layer 0, which
+    holds the first `held`, run on the experts turned so that the share's
+    stand first (the router's columns and bias with them: a top-k is
+    indifferent to the order of its candidates)."""
+    import paddle_tpu as fluid
+
+    stated = (1, shares // 2 - 1, shares - 1)
+    outs = [out for i in (0, *stated) for out in layer(i)]
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    for i in stated:
+        hold(i, np.arange(total), i * held)
+    routed, loads = [None] * shares, [None] * shares
+    for i in range(shares):
+        if i not in stated:
+            hold(0, np.roll(np.arange(total), -i * held), 0)
+            got = exe.run(feed=feed, fetch_list=outs)
+            routed[i], loads[i] = got[:2]
+    for at, i in enumerate(stated, 1):
+        routed[i], loads[i] = got[2 * at:2 * at + 2]
+    return routed, loads
+
+
 ROUTED = ("router", "experts")
 
 
@@ -380,17 +411,45 @@ def pytest_generate_tests(metafunc):
         metafunc.parametrize("suite", [suite], ids=[suite.name])
 
 
+def kept(run_dir, name, build):
+    """`build()`'s result, its device arrays as numpy, made once a run of
+    the tests and kept in the run's own directory for the workers that
+    come after: the
+    driver's command spreads a module's cases over six processes, and
+    each used to build and compile the module's Programs again (4 to 9 s
+    a fixture, up to six times a run). A worker that finds the file
+    being made waits for it."""
+    import fcntl
+    import pickle
+
+    import jax
+
+    path = run_dir / f"{name}.pickle"
+    with open(run_dir / f"{name}.lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if path.exists():
+            return pickle.loads(path.read_bytes())
+        made = jax.tree.map(
+            lambda v: np.asarray(v) if isinstance(v, jax.Array) else v,
+            build())
+        path.with_suffix(".tmp").write_bytes(pickle.dumps(made))
+        path.with_suffix(".tmp").replace(path)
+        return made
+
+
 @pytest.fixture(scope="module")
-def amp_run(request):
+def amp_run(request, run_dir):
     """The cell's program at the rehearsal size in the cell's precision,
-    built and run once for the module's cases."""
-    return request.module.SUITE.run("bf16_amp")
+    built and run once a run of the tests for the module's cases."""
+    return kept(run_dir, request.module.__name__ + "-bf16_amp",
+                lambda: request.module.SUITE.run("bf16_amp"))
 
 
 @pytest.fixture(scope="module")
-def float32_run(request):
+def float32_run(request, run_dir):
     """The same in float32, on rows of 80 tokens."""
-    return request.module.SUITE.run("float32", seq_len=80)
+    return kept(run_dir, request.module.__name__ + "-float32",
+                lambda: request.module.SUITE.run("float32", seq_len=80))
 
 
 def test_program_mixer_equals_reference(suite, which):

@@ -136,9 +136,14 @@ def _band_case(rng, b, h, hkv, sq, sk, d, layout="bhsd", dv=None,
 
 
 def _out_and_grads(fn, q, k, v, w):
-    out = fn(q, k, v)
-    grads = jax.grad(lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2))(q, k, v)
-    return (out, *grads)
+    """`fn`'s output and the gradients of its sum under `w`, from one
+    compiled function: op by op, each primitive of the interpreter's walk
+    and of the plain path is a module of its own to lower and compile."""
+    def both(q, k, v):
+        grads = jax.grad(lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2))
+        return (fn(q, k, v), *grads(q, k, v))
+
+    return jax.jit(both)(q, k, v)
 
 
 @pytest.fixture
@@ -1845,13 +1850,16 @@ def test_a_granule_matches_the_plain_path_under_the_dense_mask(
                                               * w_lse)
         return loss
 
-    (out, lse), (want, want_lse) = flash(q, k, v), plain(q, k, v)
+    # each side compiled whole, not op by op
+    (out, lse), (want, want_lse) = (jax.jit(flash)(q, k, v),
+                                    jax.jit(plain)(q, k, v))
     assert float(jnp.abs((out - want) * seen[:, None]).max()) < 2e-6
     assert float(jnp.abs(jnp.where(seen, lse - want_lse, 0.0)).max()) < 2e-6
     if offset < 0:  # the rows with no key say so in their log-sum-exp
         assert float(lse[..., :granule].max()) < -1e29
-    got = jax.grad(weighted(flash), (0, 1, 2))(q, k, v)
-    for g, want in zip(got, jax.grad(weighted(plain), (0, 1, 2))(q, k, v)):
+    got = jax.jit(jax.grad(weighted(flash), (0, 1, 2)))(q, k, v)
+    for g, want in zip(got, jax.jit(jax.grad(weighted(plain), (0, 1, 2)))(
+            q, k, v)):
         assert float(jnp.abs(g - want).max()) < 2e-5
 
 

@@ -79,17 +79,19 @@ SHAPES = [
 @pytest.mark.parametrize("b,h,sq,sk,d,use_bias,causal", SHAPES)
 def test_matches_reference(b, h, sq, sk, d, use_bias, causal):
     q, k, v, bias = _mk(b, h, sq, sk, d, use_bias, causal)
-    out = mha_short(q, k, v, h, bias=bias, causal=causal)
+    out = jax.jit(lambda q, k, v: mha_short(q, k, v, h, bias=bias,
+                                            causal=causal))(q, k, v)
     assert out.shape == q.shape and out.dtype == q.dtype
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(_reference(q, k, v, bias, causal, h)),
-        atol=2e-5)
+    want = jax.jit(lambda q, k, v: _reference(q, k, v, bias, causal, h))(
+        q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
 def _grads(fn, q, k, v):
-    return jax.grad(
+    # compiled whole: op by op, every primitive is a module of its own
+    return jax.jit(jax.grad(
         lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v).astype(jnp.float32))),
-        argnums=(0, 1, 2))(q, k, v)
+        argnums=(0, 1, 2)))(q, k, v)
 
 
 @pytest.mark.parametrize("b,h,sq,sk,d,use_bias,causal", [

@@ -858,8 +858,11 @@ def test_a_blocks_sum_onto_its_tokens_equals_the_scatter_add(
     live = (jnp.arange(rows) < count)[:, None]
     clean = jnp.asarray(r.randn(rows, 128) * np.exp(r.randn(rows, 1)), dtype)
     values = jnp.where(live, clean, jnp.nan)
-    got = moe._onto_tokens(values, token, live, n, jnp.float32)
-    want = _scatter(clean, token, live, n)
+    # compiled whole, each side: op by op every primitive of the
+    # interpreter's walk is a module of its own
+    onto = jax.jit(lambda v: moe._onto_tokens(v, token, live, n, jnp.float32))
+    got = onto(values)
+    want = jax.jit(lambda v: _scatter(v, token, live, n))(clean)
     assert got.shape == want.shape and got.dtype == jnp.float32
     assert np.isfinite(np.asarray(got)).all()
     exact = np.zeros((n, 128))
@@ -875,9 +878,7 @@ def test_a_blocks_sum_onto_its_tokens_equals_the_scatter_add(
     assert not np.asarray(got)[~hit].any()
 
     ct = jnp.asarray(r.randn(n, 128), jnp.float32)
-    (grad,) = jax.vjp(
-        lambda v: moe._onto_tokens(v, token, live, n, jnp.float32),
-        clean)[1](ct)
+    (grad,) = jax.jit(lambda v, ct: jax.vjp(onto, v)[1](ct))(clean, ct)
     assert grad.dtype == clean.dtype
     np.testing.assert_array_equal(
         np.asarray(grad.astype(jnp.float32)),

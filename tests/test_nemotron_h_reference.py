@@ -26,7 +26,7 @@ import numpy as np
 import pytest
 
 from decoder_suite import *  # noqa: F401,F403 — the shared cases, on SUITE
-from decoder_suite import guards, highest, main, rel
+from decoder_suite import guards, highest, main, rel, routed_shares
 
 from benchmark.models import nemotron_h as adapter  # noqa: E402
 
@@ -353,27 +353,27 @@ def test_the_64_expert_shares_add_up_to_the_uncut_layer(total, held, k):
     p = {n: v.astype(np.float32) for n, v in p.items()}
     u = r.randn(2, 24, hidden).astype(np.float32)
     x = fluid.layers.data("u", list(u.shape), append_batch_size=False)
-    outs = []
-    for i in range(shares):
+
+    def share(i):
         cfg = _cfg(num_experts=total, experts_held=held, held_from=i * held,
                    moe_intermediate_size=width, num_experts_per_token=k,
                    routed_scaling_factor=5.0, moe_renormalize=True,
                    router_bias_scale=0.1, score_func="sigmoid",
                    num_shared_experts=0, moe_latent_size=latent,
                    expert_form="relu2")
-        outs += decoder_parts.expert_ffn(x, cfg, f"share{i}", norm_eps=1e-20)
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
-    for i in range(shares):
-        lo = i * held
-        _set({f"share{i}.moe.gate": p["m.moe.gate"],
-              f"share{i}.moe.bias": p["m.moe.bias"],
-              f"share{i}.moe.w_up": p["m.moe.w_up"][lo:lo + held],
-              f"share{i}.moe.w_down": p["m.moe.w_down"][lo:lo + held],
+        return decoder_parts.expert_ffn(x, cfg, f"share{i}", norm_eps=1e-20)
+
+    def hold(i, order, lo):
+        # layer `i` holds `held` experts from `lo` of the experts in `order`
+        mine = order[lo:lo + held]
+        _set({f"share{i}.moe.gate": p["m.moe.gate"][:, order],
+              f"share{i}.moe.bias": p["m.moe.bias"][order],
+              f"share{i}.moe.w_up": p["m.moe.w_up"][mine],
+              f"share{i}.moe.w_down": p["m.moe.w_down"][mine],
               f"share{i}.latent_in.w_0": p["m.latent_in.w_0"],
               f"share{i}.latent_out.w_0": p["m.latent_out.w_0"]})
-    got = exe.run(feed={"u": u}, fetch_list=outs)
-    routed, loads = got[0::2], got[1::2]
+
+    routed, loads = routed_shares(share, hold, {"u": u}, shares, total, held)
     assert len(routed) == shares
     assert int(np.sum(loads)) == u.shape[0] * u.shape[1] * k
     layer = {"num_experts_per_tok": k, "n_routed_experts": total,

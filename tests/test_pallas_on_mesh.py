@@ -749,9 +749,14 @@ def _step_for_v5e(topo, cell_name):
 
 
 @pytest.fixture(scope="module")
-def trinity_step(topo):
-    """Once for the tests that read it."""
-    return _step_for_v5e(topo, "trinity_mini_ep16_s8192")[:2]
+def trinity_step(topo, run_dir):
+    """Once a run of the tests for the two cases that read it, whichever
+    workers they fall to (`decoder_suite.kept`): the module's text and
+    the counters of a compile of a minute and more."""
+    from decoder_suite import kept
+
+    return kept(run_dir, "trinity_step_for_v5e",
+                lambda: _step_for_v5e(topo, "trinity_mini_ep16_s8192")[:2])
 
 
 def test_trinity_step_compiled_for_v5e_holds_no_float32_array_of_q(

@@ -61,12 +61,14 @@ def _both(fn, args, cotangents):
     import jax.numpy as jnp
 
     def loss(*a):
+        outs = fn(*a)
         return sum(jnp.sum(o.astype(jnp.float32) * c)
-                   for o, c in zip(fn(*a), cotangents))
+                   for o, c in zip(outs, cotangents)), outs
 
-    with jax.default_matmul_precision("highest"):
-        return (jax.jit(fn)(*args),
-                jax.jit(jax.grad(loss, argnums=range(len(args))))(*args))
+    with jax.default_matmul_precision("highest"):  # one trace, one compile
+        (_, outs), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=range(len(args)), has_aux=True))(*args)
+    return outs, grads
 
 
 # b, s, heads, key/value heads, d, rows a block

@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 
 from decoder_suite import *  # noqa: F401,F403 — the shared cases, on SUITE
-from decoder_suite import guards, highest, main, rel
+from decoder_suite import guards, highest, main, rel, routed_shares
 
 from benchmark.models import qwen3_next as adapter  # noqa: E402
 
@@ -214,21 +214,21 @@ def test_the_16_shares_add_up_to_the_uncut_layer(total, held, k):
         p[f"m.{w}.w_0"] = r.randn(*shape).astype(np.float32) * 0.3
     u = r.randn(2, 24, hidden).astype(np.float32)
     x = fluid.layers.data("u", list(u.shape), append_batch_size=False)
-    outs = []
-    for lo in range(0, total, held):
-        outs += fluid.layers.moe_experts(
+    def share(i):
+        return fluid.layers.moe_experts(
             x, experts_total=total, experts_held=held, d_ff=width, k=k,
-            held_from=lo, score_func="softmax",
-            param_attr=fluid.ParamAttr(name=f"share{lo}"))
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(fluid.default_startup_program())
+            held_from=i * held, score_func="softmax",
+            param_attr=fluid.ParamAttr(name=f"share{i}"))
+
     scope = fluid.global_scope()
-    for lo in range(0, total, held):
-        scope.set(f"share{lo}.gate", p["m.moe.gate"])
+
+    def hold(i, order, lo):
+        # layer `i` holds `held` experts from `lo` of the experts in `order`
+        scope.set(f"share{i}.gate", p["m.moe.gate"][:, order])
         for w in ("w_gate", "w_up", "w_down"):
-            scope.set(f"share{lo}.{w}", p[f"m.moe.{w}"][lo:lo + held])
-    got = exe.run(feed={"u": u}, fetch_list=outs)
-    routed, loads = got[0::2], got[1::2]
+            scope.set(f"share{i}.{w}", p[f"m.moe.{w}"][order[lo:lo + held]])
+
+    routed, loads = routed_shares(share, hold, {"u": u}, shares, total, held)
     assert len(routed) == shares
     assert int(np.sum(loads)) == u.shape[0] * u.shape[1] * k
     layer = {"num_experts_per_tok": k, "num_experts": total, "held_from": 0,

@@ -52,33 +52,47 @@ def config(precision, classes=CLASSES):
                           "momentum": 0.9}}
 
 
-def program_steps(precision, batches, place=None, classes=CLASSES, seed=5):
-    """The startup program, then one `Executor.run` of the train program
-    a batch. Returns the seeded state (parameters and moving statistics
-    by name) and, for each step, the fetched loss and the state after."""
+@functools.cache
+def _program(precision, batch, image, classes, seed, place):
+    """The cell's Programs, built once a process for a precision and a
+    shape: the seeds of `BATCH_SEEDS` feed other images to one train step,
+    and building it again compiled it again (14 s of a case's 30)."""
     import paddle_tpu as fluid
     from benchmark.models import resnet50_v1_5 as adapter
     from benchmark.runners import train_loop
 
-    traffic = {"batch": len(batches[0]["label"]),
-               "image_size": batches[0]["img"].shape[-1]}
     scope = fluid.Scope()
     with fluid.program_guard(fluid.Program(), fluid.Program()), \
             fluid.unique_name.guard(), fluid.scope_guard(scope):
         main, startup, built, _ = train_loop.build_programs(
-            fluid, adapter, config(precision, classes), traffic, seed)
-        exe = fluid.Executor(place or fluid.CPUPlace())
-        exe.run(startup)
-        block = main.global_block()
-        names = [p.name for p in block.all_parameters()] + [
-            n for n in block.vars if n.endswith(("_bn.mean", "_bn.var"))]
+            fluid, adapter, config(precision, classes),
+            {"batch": batch, "image_size": image}, seed)
+        exe = fluid.Executor(getattr(fluid, place)())
+    block = main.global_block()
+    names = [p.name for p in block.all_parameters()] + [
+        n for n in block.vars if n.endswith(("_bn.mean", "_bn.var"))]
+    return scope, main, startup, exe, built["loss"], names
 
-        def snapshot():
-            return {n: np.array(scope.get(n), np.float32) for n in names}
 
+def program_steps(precision, batches, place="CPUPlace", classes=CLASSES,
+                  seed=5):
+    """The startup program, then one `Executor.run` of the train program
+    a batch. Returns the seeded state (parameters and moving statistics
+    by name) and, for each step, the fetched loss and the state after."""
+    import paddle_tpu as fluid
+
+    scope, main, startup, exe, loss_var, names = _program(
+        precision, len(batches[0]["label"]), batches[0]["img"].shape[-1],
+        classes, seed, place)
+
+    def snapshot():
+        return {n: np.array(scope.get(n), np.float32) for n in names}
+
+    with fluid.scope_guard(scope):
+        exe.run(startup)  # every call starts from the seeded state
         state0, steps = snapshot(), []
         for batch in batches:
-            (loss,) = exe.run(main, feed=batch, fetch_list=[built["loss"]])
+            (loss,) = exe.run(main, feed=batch, fetch_list=[loss_var])
             steps.append((float(np.asarray(loss, np.float32).reshape(-1)[0]),
                           snapshot()))
     return state0, steps
@@ -351,7 +365,7 @@ def main():
     got = {}
     for precision in ("float32", "bf16_amp"):
         state0, got[precision] = program_steps(
-            precision, batches, place=fluid.TPUPlace(), classes=1000)
+            precision, batches, place="TPUPlace", classes=1000)
     want = {p: reference_steps(state0, batches, precision=p)
             for p in ref.PRECISIONS}
     for what, a, b in (

@@ -68,14 +68,25 @@ state = os.path.join(wd, f"state.{rank}")
 start = int(open(state).read()) + 1 if os.path.exists(state) else 0
 steps = int(os.environ.get("SIM_STEPS", "10"))
 dt = float(os.environ.get("SIM_DT", "0.05"))
+# SIM_HOLD: the step a drill pins. The first attempt writes it and waits
+# there to be killed, so the supervisor's poll reads the step however late
+# it is scheduled (no tick is long enough under six workers' compiles).
+hold = int(os.environ.get("SIM_HOLD", "-1"))
+# SIM_SEEN: a file a respawned attempt waits for before it exits; the test
+# writes it once the supervisor has read a step of that attempt
+seen = os.environ.get("SIM_SEEN")
 for step in range(start, steps):
+    # the state whole and first: the supervisor may kill on the very read
+    # of the progress file, and the respawn resumes from what is on disk
+    with open(state + ".tmp", "w") as f:
+        f.write(str(step))
+    os.replace(state + ".tmp", state)
     if pf:
         tmp = pf + ".tmp"
         with open(tmp, "w") as f:
             json.dump({"step": step, "tick": step + 1,
                        "pid": os.getpid()}, f)
         os.replace(tmp, pf)
-    open(state, "w").write(str(step))
     if mode in ("crash", "crashmate") and att == 0 and rank == 0 \\
             and step == 4:
         sys.exit(7)
@@ -83,7 +94,13 @@ for step in range(start, steps):
         time.sleep(600)
     if mode == "crashmate" and att == 0 and rank == 1 and step == 2:
         time.sleep(600)
+    if att == 0 and step == hold:
+        time.sleep(600)
     time.sleep(dt)
+if seen and att > 0:
+    deadline = time.monotonic() + 60
+    while not os.path.exists(seen) and time.monotonic() < deadline:
+        time.sleep(0.01)
 print("DONE", flush=True)
 """
 
@@ -134,6 +151,33 @@ def _sup(tmp_path, argv, **kw):
     kw.setdefault("respawn_max_delay_s", 0.05)
     kw.setdefault("workdir", str(tmp_path / "supwd"))
     return TrainSupervisor(argv, **kw)
+
+
+def _run_seen(sup, tmp_path):
+    """`sup.run()` of a supervisor whose sims were given `_seen_env`: a
+    respawned sim stays alive until the supervisor has read a step of it
+    (the gauge `trainer_resume_step` is set on that read), so an assertion
+    on the resume step or on a time to recover does not race the few ticks
+    the respawned attempt has left to run."""
+    done = threading.Event()
+
+    def release():
+        while ("trainer_resume_step" not in sup.counters.snapshot()
+               and not done.wait(0.02)):
+            pass
+        (tmp_path / "seen").write_text("1")
+
+    releaser = threading.Thread(target=release, daemon=True)
+    releaser.start()
+    try:
+        return sup.run()
+    finally:
+        done.set()
+        releaser.join()
+
+
+def _seen_env(tmp_path, **env):
+    return dict(env, SIM_SEEN=str(tmp_path / "seen"))
 
 
 # ------------------------------------------------------------- launch.py
@@ -219,9 +263,10 @@ def test_wait_group_first_nonzero_in_death_order(tmp_path):
 
 
 def test_supervisor_crash_respawn_resume_and_counters(tmp_path):
-    sup = _sup(tmp_path, [_sim(tmp_path), str(tmp_path), "crash"])
+    sup = _sup(tmp_path, [_sim(tmp_path), str(tmp_path), "crash"],
+               extra_env=_seen_env(tmp_path))
     try:
-        assert sup.run() == 0
+        assert _run_seen(sup, tmp_path) == 0
     finally:
         sup.close()
     stats = sup.stats()
@@ -295,9 +340,9 @@ def test_supervisor_chaos_kill_at_pinned_step(tmp_path):
         "fleet.kill_trainer", raises="FaultError", nth=6)
     with faults.active(plan):
         sup = _sup(tmp_path, [_sim(tmp_path), str(tmp_path), "full"],
-                   extra_env={"SIM_DT": "0.08"})
+                   extra_env=_seen_env(tmp_path, SIM_HOLD="6"))
         try:
-            assert sup.run() == 0
+            assert _run_seen(sup, tmp_path) == 0
         finally:
             sup.close()
     c = sup.stats()["counters"]
@@ -369,9 +414,10 @@ def test_supervisor_host_loss_shrinks_world(tmp_path):
         sup = _sup(tmp_path, [_sim(tmp_path), str(tmp_path), "full"],
                    nproc_per_node=2, started_port=6470,
                    allow_shrink=True,
-                   extra_env={"SIM_STEPS": "8", "SIM_DT": "0.08"})
+                   extra_env=_seen_env(tmp_path, SIM_STEPS="8",
+                                       SIM_HOLD="3"))
         try:
-            assert sup.run() == 0
+            assert _run_seen(sup, tmp_path) == 0
         finally:
             sup.close()
     stats = sup.stats()
@@ -433,7 +479,7 @@ def test_supervisor_host_loss_without_shrink_respawns_full(tmp_path):
     with faults.active(plan):
         sup = _sup(tmp_path, [_sim(tmp_path), str(tmp_path), "full"],
                    nproc_per_node=2, started_port=6490,
-                   extra_env={"SIM_STEPS": "6", "SIM_DT": "0.08"})
+                   extra_env={"SIM_STEPS": "6", "SIM_HOLD": "3"})
         try:
             assert sup.run() == 0
         finally:
