@@ -1585,7 +1585,13 @@ def index_kl(q, k, lse, index, admit, sm_scale, admit_keys=0, name=None):
     back; lse as its `return_lse=True` gives it, `admit` as
     `sparse_select`'s. The target is rebuilt here, summed head by head
     and never held for all the heads, and is a constant: the gradient
-    reaches `index` alone. Returns [b, s] float32."""
+    reaches `index` alone, `(softmax over the admitted of index[t]) *
+    sum(p[t]) - p[t]` times the row's cotangent, the row's sum of p used
+    and not taken for 1. Where the Pallas kernels run (one device, rows of
+    whole 512 blocks) the kernel that makes the target sums the
+    divergence's rows in the same visit and the gradient is one
+    elementwise pass; elsewhere both are `jnp`, a block of queries at a
+    time (`ops/sparse_attn_ops.py`). Returns [b, s] float32."""
     helper = LayerHelper("index_kl", name=name)
     return _single_out(
         helper, "index_kl",

@@ -12,7 +12,11 @@ replace where Mosaic compiles):
                      and the admission written from it: I is read once
   index_kl_target    p[t, s] = mean over the heads of exp(scale q . k -
                      lse) on the admitted pairs, the heads summed in VMEM:
-                     the target of the indexer's loss, never held a head
+                     the target of the indexer's loss, never held a head;
+                     and in the same visit the loss's row sums: KL(p ||
+                     softmax over the admitted of I), the sum of p and the
+                     log-sum-exp of the admitted I, so the loss walks no
+                     [s, s] array again and its gradient is one pass
 
 All four walk [queries, keys] blocks of one batch row under the causal
 diagonal; a block above it is written (-inf, 0) and not computed. What
@@ -34,12 +38,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import cost
-from .flash_attention import _interpret
+from .flash_attention import NEG_INF, _interpret
 
 BLOCK = 512
 SELECT_ROWS = 64
 VMEM_LIMIT = 64 << 20
 SIGN = np.int32(-2 ** 31)
+TINY = float(np.finfo(np.float32).tiny)
 
 
 def viable(s):
@@ -290,13 +295,21 @@ def select(index, k):
 # ------------------------------------------------------------ the target
 
 
-def _target_kernel(q_ref, k_ref, lse_ref, admit_ref, o_ref, *, sm_scale,
-                   heads, group, block):
+def _target_kernel(q_ref, k_ref, lse_ref, index_ref, admit_ref, p_ref, kl_ref,
+                   sp_ref, lq_ref, m_scr, l_scr, kl_scr, sp_scr, *, sm_scale,
+                   heads, group, block, n):
     j, kb = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(kb == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        kl_scr[:] = jnp.zeros_like(kl_scr)
+        sp_scr[:] = jnp.zeros_like(sp_scr)
 
     @pl.when(kb > j)
     def _above():
-        o_ref[0] = jnp.zeros((block, block), jnp.float32)
+        p_ref[0] = jnp.zeros((block, block), jnp.float32)
 
     @pl.when(kb <= j)
     def _visit():
@@ -304,50 +317,124 @@ def _target_kernel(q_ref, k_ref, lse_ref, admit_ref, o_ref, *, sm_scale,
         for h in range(heads):
             s = _dot(q_ref[0, h], k_ref[0, h // group], (1, 1)) * sm_scale
             acc = acc + jnp.exp(s - lse_ref[0, h][:, None])
-        o_ref[0] = jnp.where(admit_ref[0].astype(jnp.int32) != 0,
-                             acc * (1.0 / heads), 0.0)
+        kept = admit_ref[0].astype(jnp.int32) != 0
+        p = jnp.where(kept, acc * (1.0 / heads), 0.0)
+        p_ref[0] = p
+        # the indexer's softmax over the admitted, a key block at a time
+        # as `flash_fwd` makes its own: the row's largest score so far and
+        # the sum against it. A row that has admitted nothing yet sums
+        # ones against NEG_INF, which its first admitted key wipes
+        # (exp(NEG_INF - m) = 0); every row admits a key by its diagonal.
+        m_prev, sp_prev = m_scr[:], sp_scr[:]
+        x = jnp.where(kept, index_ref[0], NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(x, axis=1, keepdims=True))
+        x = x - m_new
+        l_scr[:] = (jnp.exp(m_prev - m_new) * l_scr[:]
+                    + jnp.sum(jnp.exp(x), axis=1, keepdims=True))
+        # sum of p (log p - (index - m)), held against the same maximum
+        # (so no term grows with the scores' scale). 0 log 0 = 0 as
+        # `kl_from_target` has it: p is 0 on a refused pair and where the
+        # exponentials underflow, and the other factor stays finite
+        term = p * (jnp.log(jnp.maximum(p, TINY)) - x)
+        kl_scr[:] = (kl_scr[:] + (m_new - m_prev) * sp_prev
+                     + jnp.sum(term, axis=1, keepdims=True))
+        sp_scr[:] = sp_prev + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[:] = m_new
+
+    @pl.when(kb == n - 1)
+    def _finalize():
+        log_l = jnp.log(l_scr[:])
+        kl_ref[0] = kl_scr[:] + log_l * sp_scr[:]
+        sp_ref[0] = sp_scr[:]
+        lq_ref[0] = m_scr[:] + log_l
 
 
-def _target(q, k, lse, admit, sm_scale, admit_keys, block):
+def _target(q, k, lse, index, admit, sm_scale, admit_keys, block):
     b, heads, s, d = q.shape
     groups = k.shape[1]
     n = s // block
     pairs = b * cost.admitted_pairs(s, s, causal=True, window=admit_keys)
-    return pl.pallas_call(
+    visited = lambda i, j, kb: (i, j, jnp.minimum(kb, j))
+    row = pl.BlockSpec((1, block, 1), lambda i, j, kb: (i, j, 0))
+    p, *rows = pl.pallas_call(
         functools.partial(_target_kernel, sm_scale=sm_scale, heads=heads,
-                          group=heads // groups, block=block),
+                          group=heads // groups, block=block, n=n),
         grid=(b, n, n),
         in_specs=[
             pl.BlockSpec((1, heads, block, d), lambda i, j, kb: (i, 0, j, 0)),
             pl.BlockSpec((1, groups, block, d),
                          lambda i, j, kb: (i, 0, jnp.minimum(kb, j), 0)),
             pl.BlockSpec((1, heads, block), lambda i, j, kb: (i, 0, j)),
-            pl.BlockSpec((1, block, block),
-                         lambda i, j, kb: (i, j, jnp.minimum(kb, j))),
+            pl.BlockSpec((1, block, block), visited),
+            pl.BlockSpec((1, block, block), visited),
         ],
-        out_specs=pl.BlockSpec((1, block, block), lambda i, j, kb: (i, j, kb)),
-        out_shape=jax.ShapeDtypeStruct((b, s, s), jnp.float32),
+        out_specs=[
+            pl.BlockSpec((1, block, block), lambda i, j, kb: (i, j, kb)),
+            row, row, row],
+        out_shape=[jax.ShapeDtypeStruct((b, s, s), jnp.float32)]
+        + [jax.ShapeDtypeStruct((b, s, 1), jnp.float32)] * 3,
+        scratch_shapes=[pltpu.VMEM((block, 1), jnp.float32)] * 4,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=_interpret(),
         name="index_kl_target",
-        # q . k for every head on the admitted pairs, an exponential each
+        # q . k for every head on the admitted pairs, an exponential each;
+        # the divergence's exponential and logarithm a pair, a logarithm
+        # a row
         cost_estimate=cost.estimate(
-            2 * d * heads * pairs, heads * pairs,
+            2 * d * heads * pairs, (heads + 2) * pairs + b * s,
             (q.shape, q.dtype), (k.shape, k.dtype), (lse.shape, lse.dtype),
-            (admit.shape, admit.dtype), ((b, s, s), jnp.float32)),
-    )(q, k, lse, admit)
+            (index.shape, index.dtype), (admit.shape, admit.dtype),
+            ((b, s, s), jnp.float32), ((3, b, s), jnp.float32)),
+    )(q, k, lse, index, admit)
+    return (p, *(r[:, :, 0] for r in rows))
 
 
-_target_call = jax.jit(_target, static_argnums=(4, 5, 6))
+_target_call = jax.jit(_target, static_argnums=(5, 6, 7))
 
 
-def head_mean_probabilities(q, k, lse, admit, sm_scale, admit_keys=0):
+def head_mean_probabilities(q, k, lse, index, admit, sm_scale, admit_keys=0):
     """q [b, heads, s, d], k [b, groups, s, d] (as the flash kernels take
-    them), lse [b, heads, s] float32, admit [b, s, s] int8 -> [b, s, s]
-    float32: the attention's probabilities averaged over the heads on the
-    admitted pairs, 0 elsewhere, and a constant: no gradient passes.
+    them), lse [b, heads, s] float32, index [b, s, s] float32, admit
+    [b, s, s] int8 -> (p, kl, sp, lq), constants all (no gradient passes;
+    `index_kl` has the rule). p [b, s, s] float32: the attention's
+    probabilities averaged over the heads on the admitted pairs, 0
+    elsewhere. And three [b, s] float32 rows, summed over a query block's
+    key blocks in the same visit, so that no XLA pass walks an [s, s]
+    array for the divergence: `kl`, `kl_from_target(p, index, admit)`;
+    `sp`, the row's sum of p (1 only up to the rounding of the bf16
+    products); `lq`, the log-sum-exp of the admitted scores of `index`.
     `admit_keys`: the keys a query admits at most, for the declared count
     (0: every causal one)."""
     return jax.lax.stop_gradient(_target_call(
-        jax.lax.stop_gradient(q), jax.lax.stop_gradient(k), lse, admit,
-        float(sm_scale), int(admit_keys), BLOCK))
+        jax.lax.stop_gradient(q), jax.lax.stop_gradient(k), lse,
+        jax.lax.stop_gradient(index), admit, float(sm_scale),
+        int(admit_keys), BLOCK))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def index_kl(q, k, lse, index, admit, sm_scale, admit_keys):
+    """`sparse_attn_ops.index_kl_rows` with the heads first, [b, s]
+    float32: the divergence as `index_kl_target` sums it
+    (`head_mean_probabilities`' `kl`). The gradient reaches `index` alone
+    and is autodiff's of `kl_from_target`, `(softmax_admitted(index) *
+    sp - p) * dOut` on the admitted pairs, from the rows the forward
+    kept: one elementwise pass over p, index and admit, with `sp` the
+    kernel's sum of p and not 1."""
+    return head_mean_probabilities(q, k, lse, index, admit, sm_scale,
+                                   admit_keys)[1]
+
+
+def _index_kl_fwd(q, k, lse, index, admit, sm_scale, admit_keys):
+    p, kl, sp, lq = head_mean_probabilities(q, k, lse, index, admit,
+                                            sm_scale, admit_keys)
+    return kl, (p, sp, lq, index, admit)
+
+
+def _index_kl_bwd(sm_scale, admit_keys, res, g):
+    p, sp, lq, index, admit = res
+    soft = jnp.exp(index - lq[..., None]) * sp[..., None]
+    grad = jnp.where(admit != 0, soft - p, 0.0) * g[..., None]
+    return None, None, None, grad, np.zeros(admit.shape, jax.dtypes.float0)
+
+
+index_kl.defvjp(_index_kl_fwd, _index_kl_bwd)

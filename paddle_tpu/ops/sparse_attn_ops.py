@@ -19,9 +19,13 @@ and backward, and neither a head's [s, s] matrix nor the target for all
 the heads is ever held. That is what the CPU runs, and any row a kernel
 does not take. Where Pallas runs, on one device, and the row is whole
 blocks (`_kernels`), `ops/pallas/sparse_index.py` makes the score and its
-gradient, the selection and the target, a kernel each; the loss from the
-target (a softmax over a row and a sum, elementwise on [s, s]) stays
-XLA's, which fuses it into one pass forward and one backward.
+gradient, the selection and the target, a kernel each; the target's
+kernel also sums the loss's rows as it visits a row's blocks (the
+divergence, the sum of p, the log-sum-exp of the admitted scores), and
+the loss's gradient is one elementwise XLA pass from those rows. The
+loss from the target in `jnp` (`kl_from_target`: a softmax over a row
+and a sum) is the plain path's, and what the kernel's rows are tested
+against.
 """
 
 from __future__ import annotations
@@ -224,7 +228,12 @@ def kl_from_target(p, index, admit):
     """[b, n] float32 from p, index and admit [b, n, keys]: `sum over the
     admitted of p (log p - log softmax_admitted(index))`, p a constant.
     Under `jax.checkpoint`: the backward makes the row's softmax again and
-    keeps no [n, keys] array for it."""
+    keeps no [n, keys] array for it. The plain path's divergence (a block
+    of queries at a time, `_kl_block`) and the reference of the kernel
+    path's, where `index_kl_target` sums these rows itself and the
+    gradient is this function's by autodiff, written out:
+    `(softmax_admitted(index) * sum(p) - p) * dOut`, the row's sum of p
+    not taken for 1."""
     kept = admit != 0
     p = jax.lax.stop_gradient(p)
     logq = jax.nn.log_softmax(jnp.where(kept, index, -jnp.inf), axis=-1)
@@ -260,7 +269,16 @@ def _index_kl(ctx, op):
     `sum over the admitted s of p (log p - log softmax_admitted(Index))`
     with `p = mean over the heads of exp(sm_scale Q . K - Lse)`, a
     constant. The gradient reaches Index alone: `(softmax_admitted(Index)
-    - p) * dOut` on the admitted pairs."""
+    * sum(p) - p) * dOut` on the admitted pairs (the row's sum of p is 1
+    only up to the products' rounding, and is used).
+
+    Where the kernels run (`_kernels`; counters `index_kl_kernel_calls`
+    and `index_kl_fused`), `index_kl_target` makes p and, in the same
+    visit, the divergence, the sum of p and the log-sum-exp of the
+    admitted scores a row; the gradient is one elementwise pass from
+    those rows (`sparse_index.index_kl`'s rule). Elsewhere
+    `index_kl_rows`: the target and `kl_from_target` a block of queries
+    at a time, differentiated by JAX."""
     q, k = ctx.amp_cast(op, ctx.in_(op, "Q"), ctx.in_(op, "K"))
     lse, index, admit = (ctx.in_(op, "Lse"), ctx.in_(op, "Index"),
                          ctx.in_(op, "Admit"))
@@ -273,9 +291,9 @@ def _index_kl(ctx, op):
     else:
         # the arrays the flash kernels read, as they come
         profiler.bump_counter("index_kl_kernel_calls")
-        target = kernels.head_mean_probabilities(
-            q, k, lse, admit, sm_scale, int(op.attr("admit_keys", 0) or 0))
-        out = kl_from_target(target, index, admit)
+        profiler.bump_counter("index_kl_fused")
+        out = kernels.index_kl(q, k, lse, index, admit, sm_scale,
+                               int(op.attr("admit_keys", 0) or 0))
     ctx.out(op, "Out", out)
 
 

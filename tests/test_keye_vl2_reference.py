@@ -146,7 +146,8 @@ SUITE = Suite(  # noqa: F405
                    "moe_dispatch_grouped",
                    "moe_dispatch_gmm", "moe_route_softmax",
                    "sparse_attn_layers", "attn_pairs_admitted",
-                   "attn_pairs_causal"),
+                   "attn_pairs_causal", "index_kl_kernel_calls",
+                   "index_kl_fused"),
     gauges=("attn_kv_group", "sparse_attn_topk", "sparse_index_heads",
             "loss_terms", "moe_block_rows", "moe_experts_held",
             "moe_experts_total", "flash_blocks_visited",
@@ -586,7 +587,7 @@ def test_the_path_the_chip_takes_in_the_interpreter_is_the_reference(
     got, got_kl = exe.run(feed=feed, fetch_list=[y, kl])
     c1 = profiler.counters()
     for name in ("sparse_index_kernel_calls", "index_kl_kernel_calls",
-                 "attn_dispatch_flash"):
+                 "index_kl_fused", "attn_dispatch_flash"):
         assert c1.get(name, 0) - c0.get(name, 0) == 1, name
     # 64 lanes a head at the rehearsal's widths: `qk_prep` refuses them
     for name in ("attn_qk_prep_fused", "attn_qk_prep_handed_back"):
@@ -738,8 +739,10 @@ def test_counters_gauges_and_flops_of_the_cell():
     assert bumped("moe_dispatch_grouped") == 4
     assert bumped("moe_route_softmax") == 4
     assert (c1["moe_experts_held"], c1["moe_experts_total"]) == (2, 8)
+    # (and no kernel at the rehearsal's rows: the plain path's loss)
     for other in ("attn_latent_q_lora", "rope_interleaved",
-                  "attn_qk_prep_fused", "attn_rope_scaled"):
+                  "attn_qk_prep_fused", "attn_rope_scaled",
+                  "index_kl_kernel_calls", "index_kl_fused"):
         assert c1.get(other, 0) == c0.get(other, 0), other
     loss, lm, index = (float(np.asarray(x).reshape(-1)[0]) for x in got[:3])
     assert abs(loss - (lm + index)) < 1e-5 and index > 0

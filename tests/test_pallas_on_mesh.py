@@ -621,13 +621,13 @@ def test_sparse_attention_kernels_compile_for_a_v5e_chip_at_keyes_widths(
         admit, tau = sparse_index.select(out, k)
         return admit, tau, pull(out)
 
-    def attention(q, kk, v, admit):
+    def attention(q, kk, v, scores, admit, rows):
         (o, lse), pull = jax.vjp(lambda *a: flash_attention(
             *a, causal=True, admit=admit, admit_keys=k, with_lse=True),
             q, kk, v)
-        target = sparse_index.head_mean_probabilities(q, kk, lse, admit,
-                                                      d ** -0.5, k)
-        return target, pull((o, jnp.zeros_like(lse)))
+        kl, loss_pull = jax.vjp(lambda i: sparse_index.index_kl(
+            q, kk, lse, i, admit, d ** -0.5, k), scores)
+        return kl, loss_pull(rows), pull((o, jnp.zeros_like(lse)))
 
     with _as_on_the_chip():
         text = jax.jit(index).lower(
@@ -638,12 +638,23 @@ def test_sparse_attention_kernels_compile_for_a_v5e_chip_at_keyes_widths(
         assert "f32[1,16,8192,8192]" not in text  # no head's matrix
         compiled = jax.jit(attention).lower(
             sds(b, h, s, d), sds(b, g, s, d), sds(b, g, s, d),
-            sds(b, s, s, dtype=jnp.int8)).compile()
+            sds(b, s, s, dtype=jnp.float32), sds(b, s, s, dtype=jnp.int8),
+            sds(b, s, dtype=jnp.float32)).compile()
     text = compiled.as_text()
     assert all(name in text for name in (
         "flash_fwd", "flash_bwd_dkv_dq", "index_kl_target"))
     assert "s8[1,8192,8192]" in text and "f32[1,32,8192,8192]" not in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+    # the loss walks an [s, s] float32 array in the target's kernel and
+    # in one fusion, the gradient's: p, the scores and the admission in
+    walks = re.findall(r"^%?fused_computation\S* \((.*?f32\[1,8192,8192\].*?)"
+                       r"\) -> (\S+)", text, re.M)
+    assert len(walks) == 1 and walks[0][1] == "f32[1,8192,8192]", walks
+    assert walks[0][0].count("f32[1,8192,8192]") == 2
+    assert walks[0][0].count("s8[1,8192,8192]") == 1
+    # p is written over by the gradient; what is left is the rows' three
+    # [s, 1] outputs in tiles of 128 lanes (12 MB) and the admission's
+    # 64 MB where XLA fetches it into VMEM ahead of that fusion
+    assert compiled.memory_analysis().temp_size_in_bytes < 80 << 20
 
 
 @pytest.mark.parametrize("dtype,d_p,dv_p,blocks", [
