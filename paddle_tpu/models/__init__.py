@@ -14,11 +14,15 @@ decoder-hybrid-decoder), Ouro (a pipeline stage's share of a decoder
 that runs its layers several times with one set of weights), and
 Olmo-Hybrid (a pipeline stage's share of a dense decoder whose mixers are
 Gated DeltaNet layers with key and value heads of two widths and
-attention without positions, every sublayer's output normed)."""
+attention without positions, every sublayer's output normed), and
+Granite 4.0-H (a pipeline stage's share of a dense decoder of whole-width
+Mamba-2 mixers and attention without positions under the family's four
+multipliers, on one tied table)."""
 
 from . import (  # noqa: F401
     bert,
     deepfm,
+    granite_hybrid,
     joyai_flash,
     keye_vl2,
     kimi_linear,
@@ -37,6 +41,7 @@ from . import (  # noqa: F401
     trinity,
     vgg,
 )
+from .granite_hybrid import GraniteHybridConfig, build_granite_hybrid  # noqa: E402,F401
 from .joyai_flash import JoyAIFlashConfig, build_joyai_flash  # noqa: E402,F401
 from .keye_vl2 import KeyeVL2Config, build_keye_vl2  # noqa: E402,F401
 from .kimi_linear import KimiLinearConfig, build_kimi_linear  # noqa: E402,F401

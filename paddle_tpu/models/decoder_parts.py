@@ -1,6 +1,7 @@
 """What the decoders of the zoo (`kimi_linear`, `trinity`, `mellum`,
 `joyai_flash`, `phi4_flash`, `lfm2`, `qwen3_next`, `nemotron_h`, `ouro`,
-`keye_vl2`, `olmo_hybrid`) build their layers from: projections seeded
+`keye_vl2`, `olmo_hybrid`, `granite_hybrid`) build their layers from:
+projections seeded
 Normal(0, `initializer_range`), with a bias where asked, RMSNorm with a learned
 weight and LayerNorm with weight and bias, the SiLU-gated feed-forward as
 three products or with gate and up in one, the squared-ReLU feed-forward without a gate
@@ -12,7 +13,7 @@ learned indexer scores highest (`keye_vl2`), the double-gated short
 convolution (`lfm2`),
 Gated DeltaNet (`qwen3_next`, `olmo_hybrid`: the delta rule with a decay a
 head, key heads shared by groups of value heads or key and value heads
-of two widths), the Mamba-2 mixer (`nemotron_h`:
+of two widths), the Mamba-2 mixer (`nemotron_h`, `granite_hybrid`:
 a decay a head and a token, a norm by groups behind the gate), and the
 expert layer that holds a share of the experts, with a shared expert that
 a token may gate, and with experts that may read a latent of the token
@@ -255,7 +256,7 @@ def differential_attention(u, cfg, name, window=0, kv=None, lam0=0.8):
 
 def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
               gated=False, rotary_dim=0, qk_norm=True, out_std=None,
-              diffusion_block=0):
+              diffusion_block=0, scale=None):
     """Causal attention of `num_attention_heads` query heads over
     `num_key_value_heads` key/value heads of `head_dim`, u [b, s, hidden]
     to [b, s, hidden]: q and k normed over a head's width (one weight of
@@ -266,7 +267,9 @@ def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
     where `rope_theta` is not 0 (`rope_scaling`: a YaRN group;
     `rotary_dim` not 0: the first `rotary_dim` lanes of a head alone),
     `window` keys wide where it is not 0, and with `gated` the output
-    times `sigmoid(W_g u)` before the output projection. The heads are the
+    times `sigmoid(W_g u)` before the output projection. The scores are
+    multiplied by `scale` (None: `head_dim ** -0.5`; Granite's
+    `attention_multiplier` is a number of its own). The heads are the
     ones held here, which may be a share of the model's.
 
     `diffusion_block` B > 0: u holds each sequence twice, L = s / 2 noisy
@@ -307,7 +310,8 @@ def attention(u, cfg, name, window=0, rope_theta=0.0, rope_scaling=None,
                               b * cost.admitted_pairs(s, s, causal=True))
         prep.update(diffusion_block=diffusion_block)
     a = layers.fused_multihead_attention(
-        q, k, v, causal=not diffusion_block, sm_scale=1.0 / math.sqrt(d),
+        q, k, v, causal=not diffusion_block,
+        sm_scale=1.0 / math.sqrt(d) if scale is None else float(scale),
         layout="bshd", window=window, **prep)
     a = layers.reshape(a, [b, s, h * d])
     if gated:

@@ -598,6 +598,18 @@ def _ssd_step(ctx, op):
              bm, cm, dskip), jax.nn.sigmoid(raw))
 
 
+def _ssd_count(x, heads, chunk_size):
+    """Once a lowering of the forward or of the gradient op:
+    `ssd_scan_calls`, and `ssd_chunk_pairs`, the `[t, j]` pairs a call's
+    decays and masked products walk: batch x heads x padded tokens x chunk
+    length (what a roofline share of the op counts its work from)."""
+    b, s = int(x.shape[0]), int(x.shape[1])
+    c = ssd_chunk_len(s, chunk_size)
+    profiler.bump_counter("ssd_scan_calls")
+    profiler.bump_counter("ssd_chunk_pairs",
+                          b * int(heads) * ssd_n_chunks(s, chunk_size) * c * c)
+
+
 @register_op("ssd_scan", grad=_ssd_grad_maker)
 def _ssd_scan_op(ctx, op):
     """X: [b, s, H * P]; Dt: [b, s, H], the step's projection before its
@@ -616,6 +628,7 @@ def _ssd_scan_op(ctx, op):
             f"{x.shape} and B {bm.shape}: the groups divide the heads and "
             "B's width, the heads X's")
     profiler.bump_counter("ssd_dispatch_chunked")
+    _ssd_count(x, heads, chunk_size)
     profiler.set_counter("ssd_chunk_len", ssd_chunk_len(x.shape[1],
                                                         chunk_size))
     profiler.set_counter("ssd_heads", int(heads))
@@ -629,9 +642,11 @@ def _ssd_scan_op(ctx, op):
 @register_op("ssd_scan_grad", differentiable=False)
 def _ssd_scan_grad_op(ctx, op):
     operands, dsoftplus = _ssd_step(ctx, op)
+    chunk_size = op.attr("chunk_size", 128)
+    _ssd_count(operands[0], operands[2].shape[0], chunk_size)
     dx, ddelta, da, dbm, dcm, dd = ssd_scan_grads(
         *operands, ctx.in_(op, "Starts"), ctx.in_(op, "GRAD_Y"),
-        op.attr("n_groups", 1), op.attr("chunk_size", 128))
+        op.attr("n_groups", 1), chunk_size)
     dt, dt_bias, a_log = (ctx.in_(op, slot)
                           for slot in ("Dt", "DtBias", "ALog"))
     ddt = ddelta * dsoftplus

@@ -16,9 +16,9 @@ it). What only one model has stays in that model's file.
 Run as a script on the attached TPU, outside any timed window, a suite's
 file ends in `main(SUITE)`:
 
-    python3 tests/test_<model>_reference.py readings[:wrong,wrong] [seed ...]   # program, wrong models and the fp8 reference against the reference
+    python3 tests/test_<model>_reference.py readings[:wrong,wrong] [seed ...]   # program, wrong models (`fp8`: the fp8 reference) against the reference
     python3 tests/test_<model>_reference.py loads[/steps][@rate] [seed ...]   # held share by expert layer and over all of them from the fifth step on (what a benchmark window of steps - 4 steps reads as `moe_held_load_pct`) beside the steps' own device counts, the loss over a window's steps, a train step's counters (`falls`: the same)
-    python3 tests/test_<model>_reference.py gradients   # at the published widths on one short row (no argument: the same)
+    python3 tests/test_<model>_reference.py gradients[:wrong,wrong]   # at the published widths on one short row (no argument: the same), then against each wrong model named
 """
 
 from __future__ import annotations
@@ -331,11 +331,12 @@ class Suite:
         return train_loop.check_reference(
             got[0], got[1], *self.reference(run, **kw), self.adapter.TOLERANCE)
 
-    def gradients(self, model, traffic, place=None, seed=3):
+    def gradients(self, model, traffic, place=None, seed=3, **kw):
         """One train step (SGD at rate 1: the gradient is what a parameter
-        lost) beside `jax.grad` of the reference's loss from the same
-        seeded state and batch: `got`, `want` and `before` by name, `main`,
-        `batch`, `model`, and what the step's trace `bumped`."""
+        lost) beside `jax.grad` of the reference's loss (of a wrong model's,
+        with its `kw`) from the same seeded state and batch: `got`, `want`
+        and `before` by name, `main`, `batch`, `model`, and what the step's
+        trace `bumped`."""
         import jax
 
         import paddle_tpu as fluid
@@ -353,8 +354,8 @@ class Suite:
         scope = fluid.global_scope()
         for n in list(scope.local_names()):  # the device is the reference's now
             scope.delete(n)
-        want = f32(compiled(jax.grad(lambda p: self.loss(p, batch, model)),
-                            before))
+        want = f32(compiled(jax.grad(
+            lambda p: self.loss(p, batch, model, **kw)), before))
         return SimpleNamespace(
             got=got, want=want, before=before, main=main, batch=batch,
             model=model, counters=c1,
@@ -525,8 +526,8 @@ def _reading(suite, seeds, only=(), few=2):
     """At the published widths on the attached TPU: the cell's own check
     (program in bf16 AMP against the float32 reference) at every seed,
     and the same program against the wrong models named in `only` at
-    every seed, or with none named against each wrong model and the fp8
-    reference at the first `few`."""
+    every seed (`fp8` among them: the fp8 reference), or with none named
+    against each wrong model and the fp8 reference at the first `few`."""
     import paddle_tpu as fluid
     from benchmark.runners import train_loop
 
@@ -541,7 +542,8 @@ def _reading(suite, seeds, only=(), few=2):
                                            fetch_list=built["check"])
             p = state(names)
         variants = [("reference", p, {})] + [
-            (w, p, suite.wrong[w][0]) for w in only]
+            ("fp8", fp8(p), {}) if w == "fp8" else (w, p, suite.wrong[w][0])
+            for w in only]
         if not only and at < few:
             variants += [("fp8", fp8(p), {})] + [
                 (w, p, kw) for w, (kw, _, _) in suite.wrong.items()]
@@ -634,9 +636,11 @@ def _loads(suite, seeds, rate=None, steps=None):
           {n: c1.get(n) for n in suite.gauges}, flush=True)
 
 
-def _chip_gradients(suite):
+def _chip_gradients(suite, only=()):
     """The gradients of every kind of parameter at the published widths,
-    program against `jax.grad` of the reference, on one short row."""
+    program against `jax.grad` of the reference, on one short row; then,
+    in float32, against each wrong model named in `only`, whose reading is
+    printed beside the limit and held to nothing."""
     import jax
 
     import paddle_tpu as fluid
@@ -666,21 +670,31 @@ def _chip_gradients(suite):
         print(f"gradients at the published widths, s={suite.gradient_row}, "
               f"{precision}: worst relative error by kind "
               + ", ".join(f"{k} {v:.2e}" for k, v in worst.items()), flush=True)
+    for w in only:
+        with guards():
+            step = suite.gradients(dict(model, precision="float32"), traffic,
+                                   place=fluid.TPUPlace(), **suite.wrong[w][0])
+        worst = check_gradients(step.got, step.want, step.before, np.inf,
+                                kinds=suite.chip_kinds)
+        print(f"gradients against the wrong model {w}, float32 (limit 5e-02): "
+              + ", ".join(f"{k} {v:.2e}" for k, v in worst.items()), flush=True)
 
 
 def main(suite, argv=None):
     """A suite's file run as a script: `readings[:wrong,...]`,
-    `loads[/steps][@rate]` (or `falls`), `gradients` (or nothing), then
-    seeds."""
+    `loads[/steps][@rate]` (or `falls`), `gradients[:wrong,...]` (or
+    nothing), then seeds."""
     argv = sys.argv[1:] if argv is None else argv
     what, _, rate = (argv[0] if argv else "gradients").partition("@")
     what, _, steps = what.partition("/")
     what, _, only = what.partition(":")
     only = tuple(w for w in only.split(",") if w)
-    unknown = [w for w in only if w not in suite.wrong]
+    unknown = [w for w in only if w not in suite.wrong
+               and (w, what) != ("fp8", "readings")]
     if what not in ("readings", "loads", "falls", "gradients") or unknown:
         raise SystemExit(f"{what!r} {unknown}: readings[:wrong,...] (of "
-                         f"{list(suite.wrong)}), loads[/steps][@rate], gradients")
+                         f"{list(suite.wrong)}), loads[/steps][@rate], "
+                         f"gradients[:wrong,...]")
     seeds = [int(a) for a in argv[1:]] or [suite.seed]
     import jax
 
@@ -688,7 +702,7 @@ def main(suite, argv=None):
     if what == "readings":
         _reading(suite, seeds, only)
     elif what == "gradients":
-        _chip_gradients(suite)
+        _chip_gradients(suite, only)
     else:
         _loads(suite, seeds, float(rate) if rate else None,
                int(steps) if steps else None)

@@ -16,7 +16,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 ADAPTERS = ("kimi_linear", "trinity", "mellum", "joyai_flash", "phi4_flash",
             "lfm2", "qwen3_next", "nemotron_h", "keye_vl2", "olmo_hybrid",
-            "sdar")
+            "sdar", "granite_hybrid")
 
 
 def names_used(code):

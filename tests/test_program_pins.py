@@ -24,7 +24,11 @@ added `olmo_hybrid_7b_vp8_longdoc` (its `kda_attention` ops carry
 `beta_scale`, which no other cell's do: the fifteen other rows stood).
 PR 68 added `sdar_30b_a3b_ep8_s4096` (its `fused_multihead_attention` ops
 carry `diffusion_block`, the attribute at `ATTRS`' end, which no other
-cell's do: the sixteen other rows stood)."""
+cell's do: the sixteen other rows stood). PR 72 added
+`granite4_h_micro_vp8_longdoc` and gave `decoder_parts.attention` a
+`scale` argument whose default is what the function did: the seventeen
+other rows stood, which is the test that the default changes no
+Program."""
 
 import hashlib
 import json
@@ -124,6 +128,11 @@ PINS = {
         172, "dbc029f9df8d353d",
         {"fused_multihead_attention": 2, "moe_experts": 2,
          "rms_norm_grad": 5}, ("diffusion_block",)),
+    # PR 72's own tree: the cell it added (the rehearsal's three layers)
+    "granite4_h_micro_vp8_longdoc": (
+        283, "101ac4c56cd2b498",
+        {"fused_multihead_attention": 1, "short_conv1d": 2, "ssd_scan": 2,
+         "rms_norm_grad": 9}, ()),
 }
 
 
