@@ -18,8 +18,8 @@ on are sorted by expert and run through one grouped product (the experts
 SiLU-gated, or with no `w_gate` `W_down relu(W_up x)^2`; reading the
 router's rows, or rows of another width given beside them)
 (`jax.lax.ragged_dot`, or where the widths and the backend allow
-`ops/pallas/grouped_matmul.py`, whose forward product and weight
-gradient are Pallas kernels that skip the dead rows of a block),
+`ops/pallas/grouped_matmul.py`, whose product and both of its
+gradients are Pallas kernels that skip the dead rows of a block),
 whatever the skew, at static shapes. What the
 experts held elsewhere would add is left out: on one chip the layer runs
 without its exchange. Gradients flow through the combine weights in both."""
@@ -402,9 +402,10 @@ def _block(j, rows, x, w_gate, w_up, w_down, token, weight, sizes, dtype,
     [N, D] float32. Rows past the held assignments carry a zero input and
     weight. With `kernel` the three products are `grouped_matmul`'s
     (ops/pallas/grouped_matmul.py), which is told the groups' true sizes
-    and whose kernels visit no row tile past them (its `dx` is a
-    `ragged_dot` all the same, and a dead row's holds what its cotangent
-    gives), and the rows come from their tokens and go back onto them
+    and whose kernels visit no row tile past them, forward and backward:
+    a dead row's product and its `dx` are zeros, and nothing of a block's
+    three kinds of product (`y`, `dx`, `dw`) is XLA's; and the rows come
+    from their tokens and go back onto them
     through `_from_tokens` and `_onto_tokens`, each the other's backward:
     the sums are grouped 0/1 products over the live rows, in which a dead
     row lies in no group, so nothing masks what it holds. Without,

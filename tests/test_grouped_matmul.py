@@ -1,12 +1,13 @@
 """The expert layer's grouped products as Pallas kernels
-(`ops/pallas/grouped_matmul.py`) under the interpreter: the product and
-`dw` against `jax.lax.ragged_dot` and its `jax.vjp` at the three expert
-cells' groups and widths with the rows cut down, over loads with an empty
-group, a group that ends inside a tile, one group that holds everything, a
-block filled exactly and no row at all; the dead rows of the input and of
-the cotangent hold NaN throughout, and reach nothing (`dx`, which stays
-XLA's `ragged_dot`, is held to the oracle's through `grouped_matmul`'s
-`jax.vjp`, and a dead row's NaN stays in its row). Then the layer that
+(`ops/pallas/grouped_matmul.py`) under the interpreter: the product, `dx`
+(the same kernel on the weights read transposed, out in the rows' dtype)
+and `dw` against `jax.lax.ragged_dot` and its `jax.vjp` at the three
+expert cells' groups and widths with the rows cut down, over loads with
+an empty group, a group that ends inside a tile, one group that holds
+everything, a block filled exactly and no row at all; the dead rows of
+the input and of the cotangent hold NaN throughout, and reach nothing (a
+dead row's `y` and `dx` are zeros), alone and through `grouped_matmul`'s
+`jax.vjp`. Then the layer that
 calls them (`parallel/moe.py::moe_experts`) with and without the kernels at
 every block count, what the calls declare, and where the kernels are
 taken."""
@@ -103,10 +104,12 @@ def _ours(x, w, ct, sizes, tm):
     from paddle_tpu.ops.pallas import grouped_matmul as gm
 
     # the kernel calls of `grouped_matmul` and its backward, at small tiles
-    # so that 192 rows are six of them and the widths are cut (not 896
-    # into its seven lane slices: the interpreter walks every step)
+    # so that 192 rows are six of them and the widths are cut in two where
+    # a half is whole lane slices (not 896 into its seven: the interpreter
+    # walks every step), so `dx` contracts the down product's 2,048 or
+    # 2,304 in two visits of a tile and its sum waits in VMEM between them
     def cut(width):
-        return max(d for d in gm._divisors(width)[:2] if d >= 256)
+        return min(d for d in gm._divisors(width)[:2] if d >= 256)
 
     def tile(c, o):
         return tm, cut(c), cut(o)
@@ -114,12 +117,14 @@ def _ours(x, w, ct, sizes, tm):
     k, n = w.shape[1:]
 
     @jax.jit
-    def both(x, w, ct, sizes):
+    def three(x, w, ct, sizes):
         return (gm.moe_gmm(x, w, sizes, tiling=tile(k, n)),
+                gm.moe_gmm(ct, w, sizes, tiling=tile(n, k),
+                           transpose_rhs=True, out_dtype=x.dtype),
                 gm.moe_tgmm(x, ct, sizes, tiling=tile(k, n)))
 
-    return both(x, w.astype(x.dtype), ct.astype(x.dtype),
-                jax.numpy.asarray(sizes))
+    return three(x, w.astype(x.dtype), ct.astype(x.dtype),
+                 jax.numpy.asarray(sizes))
 
 
 @pytest.mark.parametrize("pattern", PATTERNS)
@@ -131,16 +136,18 @@ def test_product_and_weight_gradient_equal_ragged_dot(cell, pattern):
     sizes = load(pattern, groups)
     w, clean, poisoned, live = _operands(ROWS, groups, k, n, sizes,
                                          jnp.float32)
-    y, _, dw = _oracle(clean[0], w, clean[1], sizes)
+    want = _oracle(clean[0], w, clean[1], sizes)
     got = _ours(poisoned[0], w, poisoned[1], sizes, TM)
-    for name, g, o in zip(("y", "dw"), got, (y, dw)):
+    assert [g.shape for g in got] == [(ROWS, n), (ROWS, k), (groups, k, n)]
+    for name, g, o in zip(("y", "dx", "dw"), got, want):
         assert np.isfinite(np.asarray(g)).all(), name
         if pattern == "no_row_at_all":
             assert not np.asarray(g).any(), name
         else:
             assert rel(g, o) < 2e-5, name
-    # the dead rows are exactly zero, not small
-    assert not np.asarray(got[0])[~live[:, 0]].any()
+    # the dead rows are exactly zero, not small: the product's and dx's
+    for g in got[:2]:
+        assert not np.asarray(g)[~live[:, 0]].any()
 
 
 @pytest.mark.parametrize("pattern", PATTERNS)
@@ -200,9 +207,9 @@ def test_grouped_matmul_and_its_vjp_with_nan_in_the_dead_rows(rows, dtype):
     """`grouped_matmul` itself, through `jax.vjp`, at the tile sizes it
     picks (256 rows; the ids say what each row count meets there): NaN in
     the dead rows of x and of the cotangent gives, to the last bit, what
-    zeros there give (dx, XLA's `ragged_dot`: in the live rows; a dead
-    row's is its own cotangent's); bf16 operands as the cells send them,
-    float32 out; dx in x's dtype and dw in the weights'."""
+    zeros there give, and a dead row's y and dx are zeros; bf16 operands
+    as the cells send them, float32 out; dx in x's dtype, written by the
+    kernel and rounded once, and dw in the weights'."""
     import jax
     import jax.numpy as jnp
 
@@ -222,12 +229,17 @@ def test_grouped_matmul_and_its_vjp_with_nan_in_the_dead_rows(rows, dtype):
     y, dx, dw = got
     assert (y.dtype, dx.dtype, dw.dtype) == (jnp.float32, jnp.dtype(dtype),
                                              jnp.float32)
-    for name, g, s in zip(("y", "dx", "dw"), got, same):
-        held = live[:, 0] if name == "dx" else slice(None)
-        np.testing.assert_array_equal(np.asarray(g, np.float32)[held],
-                                      np.asarray(s, np.float32)[held])
+    for g, s in zip(got, same):
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(s, np.float32))
     assert not np.asarray(y)[~live[:, 0]].any()
-    assert not np.asarray(same[1], np.float32)[~live[:, 0]].any()
+    assert not np.asarray(dx, np.float32)[~live[:, 0]].any()
+    # rounded once: the float32 sums of the same call, rounded here (a
+    # tile two groups share is written twice, and read back in between)
+    once = gm.moe_gmm(clean[1], w.astype(dtype), jnp.asarray(sizes),
+                      transpose_rhs=True).astype(dtype)
+    np.testing.assert_array_equal(np.asarray(same[1], np.float32),
+                                  np.asarray(once, np.float32))
     assert not np.asarray(dw)[1].any()  # the empty group's gradient
     want = _oracle(clean[0], w, clean[1], sizes)
     # dx is rounded to bf16 on one side; dw was on the other
@@ -310,10 +322,14 @@ def test_declared_cost_against_a_count_by_hand(kernel):
     flops = 2 * 96 * 256 * 128
     weights = 4 * 256 * 128 * 2  # read in bf16, all four groups
     if kernel == "moe_gmm":
-        (forward,) = found[kernel]  # dx is XLA's `ragged_dot`
+        forward, dx = found[kernel]
         # x bf16, the weights, y float32
         assert numbers(forward) == (flops, 0,
                                     96 * 256 * 2 + weights + 96 * 128 * 4)
+        # the widths swapped: dy bf16, the same weights as they lie, dx
+        # bf16 (the kernel's own output, no float32 [rows, K] beside it)
+        assert numbers(dx) == (flops, 0,
+                               96 * 128 * 2 + weights + 96 * 256 * 2)
     else:
         (dw,) = found[kernel]
         # x and dy bf16, the gradient float32
@@ -347,6 +363,8 @@ def test_a_kernel_asked_for_where_it_cannot_run_raises(monkeypatch):
     x = jnp.zeros((16, 128), jnp.float32)
     with pytest.raises(ValueError, match="128-lane"):
         gm.moe_gmm(x[:, :64], jnp.zeros((2, 64, 128)), sizes)
+    with pytest.raises(ValueError, match="moe_gmm: x"):  # w is [G, O, C]
+        gm.moe_gmm(x, jnp.zeros((2, 128, 256)), sizes, transpose_rhs=True)
     with pytest.raises(ValueError, match="moe_tgmm: x"):
         gm.moe_tgmm(x, jnp.zeros((8, 128)), sizes)
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET")

@@ -923,10 +923,10 @@ def test_the_kernels_lowering_sums_by_products_and_counts_what_it_counted(
     lowering (the forward op's, the gradient op's replay) that takes the
     kernels bumps `moe_onto_tokens_grouped` beside `moe_dispatch_gmm`; the
     step's jaxpr holds the sums' call, `onto_tokens_tgmm`, and no
-    scatter-add under `moe.gather` or `moe.combine`; the three device
-    counts are the plain path's. Without
-    the kernels (no interpreter) neither counter moves and the scatter
-    stays."""
+    scatter-add at all (`dx` takes the groups' true sizes like the two
+    other products, so not even the G integers of a stretched last group);
+    the three device counts are the plain path's. Without the kernels (no
+    interpreter) neither counter moves and the scatter stays."""
     import jax
 
     import paddle_tpu as fluid
@@ -960,11 +960,11 @@ def test_the_kernels_lowering_sums_by_products_and_counts_what_it_counted(
         # once a lowering: the forward op's, the gradient op's replay
         assert set(bumped.values()) == {2 if kernel else 0}, bumped
         assert ("onto_tokens_tgmm" in str(jaxpr)) is kernel
-        # (`sizes.at[-1].add`, a scatter-add of G integers, is the
-        # products')
+        # (the plain path's `part.at[-1].add`, a scatter-add of G integers,
+        # is under `moe.gather` too; the kernels take the true sizes)
         scatters = {stage for stage, primitive in _stage_equations(
             jaxpr.jaxpr) if primitive == "scatter-add"}
-        assert scatters == ({"moe.products"} if kernel else
+        assert scatters == (set() if kernel else
                             {"moe.gather", "moe.combine"}), scatters
         before = settled_counters()
         got = exe.run(main, feed=feed, fetch_list=[loss, load], scope=scope)

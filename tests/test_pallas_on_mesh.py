@@ -734,9 +734,11 @@ def test_grouped_matmul_kernels_compile_for_a_v5e_chip_at_kimis_widths(topo):
                 sds((groups,), jnp.int32)).compile()
             text = compiled.as_text()
             assert "moe_gmm" in text and "moe_tgmm" in text
-            assert "ragged-dot-none" in text  # dx stays XLA's
+            # dx is the kernel's too, on the weights as they lie
+            assert "ragged-dot-none" not in text
             # the weights' cast and the steps' lists: no [rows, .] array
-            # beside the operands and the three results
+            # beside the operands and the three results, and no
+            # transposed copy of the weights
             assert compiled.memory_analysis().temp_size_in_bytes < (
                 groups * k * n * 2 + (1 << 20))
 
@@ -794,7 +796,7 @@ def test_trinity_step_compiled_for_v5e_holds_no_float32_array_of_q(
 def _grouped_products(text):
     """((outside, inside) every `while`) of the expert layers' grouped
     products in an optimised HLO module: the Pallas kernels' custom calls,
-    and XLA's `ragged-dot`."""
+    and XLA's `ragged-dot` (the plain path's)."""
     import re
 
     from kernel_cases import in_and_out_of_whiles
@@ -823,9 +825,10 @@ def test_trinity_step_compiled_for_v5e_makes_each_first_block_once(
     for the forward op and the gradient op's replay, and the six of its
     backward); the overflow loops hold 3 and 9 a layer, for the trips a
     load past a quarter of the assignments costs. Every lowering took the
-    kernels: the forward products and the weight gradients are `moe_gmm`'s
-    and `moe_tgmm`'s custom calls, and a third, the three `dx` of a
-    backward, stay `ragged-dot` (ops/pallas/grouped_matmul.py says why).
+    kernels, and every product is one's: the forward products and the
+    three `dx` of a backward are `moe_gmm`'s custom calls (`dx` on the
+    weights read transposed: PR 73), the weight gradients `moe_tgmm`'s,
+    and no `ragged-dot` is left in the step.
     A layer's first block sums its rows onto the tokens twice, once each
     way (the combine, and the gather's transpose, whose sort XLA shares
     with the combine's), by the call `onto_tokens_tgmm` and not by a
@@ -835,7 +838,7 @@ def test_trinity_step_compiled_for_v5e_makes_each_first_block_once(
     # forward and replay
     assert bumped["moe_dispatch_grouped"] == bumped["moe_dispatch_gmm"] == 8
     assert bumped["moe_onto_tokens_grouped"] == 8
-    assert _grouped_products(text) == ((24, 36), (12, 12))
+    assert _grouped_products(text) == ((36, 48), (0, 0))
     assert _sums_onto_tokens(text) == (8, 8)
     assert not [line for line in text.split("\n")
                 if " scatter(" in line and "f32[8192,2048]" in line]
@@ -843,13 +846,16 @@ def test_trinity_step_compiled_for_v5e_makes_each_first_block_once(
 
 def test_mellum_step_compiled_for_v5e_makes_each_first_block_once(topo):
     """`mellum2_ep4_s8192`, where the expert layer is most of the step:
-    the same 36 and 48 products over 16 groups of 2,304 x 896, and
-    temporaries no larger than the step held with `ragged-dot` (8.448 GB
-    by this compile of PR 37's tree; the chip's peak stood at 15.667 of
-    16.9 GB): the kernels add metadata and no `[rows, .]` array."""
+    the same 36 and 48 products over 16 groups of 2,304 x 896, all the
+    kernels', and temporaries no larger than the step held with
+    `ragged-dot` (8.448 GB by this compile of PR 37's tree; the chip's
+    peak stood at 15.667 of 16.9 GB): the kernels add metadata and no
+    `[rows, .]` array, `dx` leaves its kernel in the rows' dtype (a
+    float32 `[28672, 2304]` would be 264 MB a product) and no transposed
+    copy of a weight is made."""
     text, bumped, memory = _step_for_v5e(topo, "mellum2_ep4_s8192")
     assert bumped["moe_dispatch_grouped"] == bumped["moe_dispatch_gmm"] == 8
-    assert _grouped_products(text) == ((24, 36), (12, 12))
+    assert _grouped_products(text) == ((36, 48), (0, 0))
     # the sums onto the tokens: a float32 `[28672, 2304]` in token order is
     # a new transient of each, inside the same bound
     assert _sums_onto_tokens(text) == (8, 8)
@@ -963,8 +969,8 @@ def test_one_layers_step_compiled_for_v5e_makes_nine_products_and_twelve(
     widths of one lane slice, trained and compiled for the chip with the
     kernels and with `grouped_matmul_viable` saying no: nine grouped
     products outside every `while` and twelve inside either way: custom
-    calls but for the three `dx` of each backward on one path, `ragged-dot`
-    all on the other, and the counter that says which."""
+    calls all on one path, `ragged-dot` all on the other, and the counter
+    that says which."""
     from jax.sharding import SingleDeviceSharding
 
     from paddle_tpu.ops.pallas import grouped_matmul
@@ -999,7 +1005,7 @@ def test_one_layers_step_compiled_for_v5e_makes_nine_products_and_twelve(
                       "moe_dispatch_gmm": 2 if kernel else 0,
                       "moe_onto_tokens_grouped": 2 if kernel else 0}
     assert _grouped_products(text) == (
-        ((6, 9), (3, 3)) if kernel else ((0, 0), (9, 12)))
+        ((9, 12), (0, 0)) if kernel else ((0, 0), (9, 12)))
     assert _sums_onto_tokens(text) == ((2, 2) if kernel else (0, 0))
 
 

@@ -52,11 +52,15 @@ gradients, against the forms these digests had pinned
 `experts-alone` were PR 51's parent's and the three others PR 53's
 parent's; the two Mamba-1 cases and the convolution's still are.
 
-`experts-gmm` is PR 69's own tree's. With the kernels a block's rows are
-summed onto their tokens by grouped 0/1 products (`parallel/moe.py::
-_onto_tokens`) and not by `scatter-add`, so that path's jaxpr moved by
-design; the four cases on the plain path stand, and what the sums compute
-is held against the scatter's in `tests/test_moe_experts.py`."""
+`experts-gmm` is PR 73's own tree's, and was PR 69's before. With the
+kernels a block's rows are summed onto their tokens by grouped 0/1
+products (`parallel/moe.py::_onto_tokens`) and not by `scatter-add`
+(PR 69), and the backward's `dx` is `moe_gmm` on the weights read
+transposed and not `ragged_dot` on the last group stretched (PR 73:
+`ops/pallas/grouped_matmul.py`), so that path's jaxpr moved by design each
+time; the four cases on the plain path stand, what the sums compute is
+held against the scatter's in `tests/test_moe_experts.py`, and `dx`
+against `ragged_dot`'s in `tests/test_grouped_matmul.py`."""
 
 import os
 import sys
@@ -198,7 +202,7 @@ PARENTS_JAXPRS = {
     # the expert cases: PR 67's tree (see the docstring)
     "experts-shared": "53549bc5f876910a",
     "experts-alone": "8e9175990e4374b6",
-    "experts-gmm": "4c84db0eb2a9a85b",  # PR 69's tree: see the docstring
+    "experts-gmm": "c67d05977d1000f2",  # PR 73's tree: see the docstring
     "experts-norm_eps": "2b3d5cd41d2da9df",
     "experts-shared_gate": "a5b4baca8bfe7d27",
     # as PR 53's parent (commit 55ba533) traces them
