@@ -6,7 +6,7 @@ shard_propagation): per-op output shapes/dtypes over the IR without
 tracing, plus the correctness tooling (IR verifier between passes,
 sharding checker, repo lints in tools/provlint.py) that keeps the six
 rewrite passes honest. Analysis never mutates programs — compile-cache
-fingerprints and passes.cache_signature() are unaffected.
+fingerprints are unaffected.
 
 Entry points:
   verify_program / check_program  — structural IR invariants

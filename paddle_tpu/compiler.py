@@ -89,17 +89,6 @@ class BuildStrategy:
         # assignment for the compile's mesh instead of the zero1 flag /
         # hand-written extra specs. PADDLE_TPU_AUTOSHARD overrides.
         self.auto_shard = False
-        # OPT-IN fused-step compilation (passes/fuse_layer_scan.py):
-        # collapse repeated layer blocks — forward and their backward
-        # closures — into single lax.scan ops, shrinking traced-op
-        # count and compile time on deep stacked models.
-        # PADDLE_TPU_FUSE_LAYER_SCAN overrides.
-        self.fuse_layer_scan = False
-        # OPT-IN optimizer/backward overlap (passes/optimizer_overlap.py):
-        # split each fused optimizer wave by grad-finalization order so
-        # updates schedule under the backward tail instead of after it.
-        # PADDLE_TPU_OPTIMIZER_OVERLAP overrides.
-        self.optimizer_overlap = False
         self.num_trainers = 1
         self.trainer_id = 0
         self.sync_batch_norm = False

@@ -22,7 +22,6 @@ from . import (  # noqa: F401
     quant_ops,
     registry,
     rnn_ops,
-    scan_ops,
     sequence_ops,
     sparse_attn_ops,
     ssm_ops,

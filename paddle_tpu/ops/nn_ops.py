@@ -1006,7 +1006,7 @@ def _dropout(ctx, op):
     ctx.out(op, "Mask", keep.astype(jnp.uint8))
 
 
-@register_op("dropout_grad", differentiable=False, name_attrs=("rng_name",))
+@register_op("dropout_grad", differentiable=False)
 def _dropout_grad(ctx, op):
     dy = ctx.in_(op, "GRAD_Out")
     p = op.attr("dropout_prob", 0.5)

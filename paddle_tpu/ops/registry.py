@@ -67,7 +67,6 @@ class OpDef:
         no_grad_inputs=(),
         stateful_outputs=(),
         differentiable=True,
-        name_attrs=(),
         device_counts=(),
     ):
         self.type = type
@@ -80,15 +79,6 @@ class OpDef:
         # accumulators); excluded from differentiation
         self.stateful_outputs = frozenset(stateful_outputs)
         self.differentiable = differentiable
-        # attrs whose VALUES are variable names (dropout_grad's rng_name):
-        # invisible dataflow that name-rewriting analyses — in particular
-        # passes/fuse_layer_scan.py's segment-renaming maps — must treat
-        # like input slots. An op whose attrs reference var names but does
-        # not declare them here is ineligible for scan fusion only if the
-        # pass has no other way to see the name; dropout_grad is the one
-        # current case (rng_name keys mask regeneration, never a value
-        # read)
-        self.name_attrs = tuple(name_attrs)
         # the device counts its lowering adds to (`LoweringContext.count`):
         # the Executor reads them off the block before it traces anything,
         # so a step knows its counts' names, and that it has any, on a warm
@@ -368,8 +358,8 @@ _clock = _LoweringClock()
 
 def lower_op(ctx: LoweringContext, op):
     scope = op_scope(op)
-    # the op's own lowering time, at trace time only: what `layer_scan`'s
-    # block or a `while` body lowers through here inside it is theirs
+    # the op's own lowering time, at trace time only: what a `while`
+    # body lowers through here inside it is its own
     t0 = time.perf_counter()
     outer, _clock.inner = _clock.inner, 0.0
     try:

@@ -26,23 +26,9 @@ before the jit trace:
                      transposes only at graph edges (the reference's
                      MKLDNN/cuDNN layout-assignment passes;
                      passes/layout_opt.py)
-  * fuse_layer_scan — OPT-IN (PADDLE_TPU_FUSE_LAYER_SCAN=1 or
-                     BuildStrategy.fuse_layer_scan): collapse runs of
-                     structurally-identical layer blocks (forward AND
-                     their backward closures) into single `layer_scan`
-                     ops lowered as one lax.scan body each, shrinking
-                     traced-op count and XLA compile time on deep
-                     stacked models (passes/fuse_layer_scan.py)
   * fuse_optimizer — coalesce per-param sgd/momentum/adam/adamw ops into
                      one grouped multi-tensor update (reference
                      fuse_all_optimizer_ops; passes/fuse_optimizer.py)
-  * optimizer_overlap — OPT-IN (PADDLE_TPU_OPTIMIZER_OVERLAP=1 or
-                     BuildStrategy.optimizer_overlap): split each fused
-                     optimizer wave by the backward position where each
-                     member's grad finalizes and emit every group right
-                     after its last producer, so XLA overlaps updates
-                     with the remaining backward
-                     (passes/optimizer_overlap.py)
   * shard_propagation — OPT-IN (PADDLE_TPU_AUTOSHARD=1 or
                      BuildStrategy.auto_shard): run the autoshard
                      planner for the compile's mesh shape and attach
@@ -50,9 +36,8 @@ before the jit trace:
                      executor to emit through
                      mesh.assign_state_shardings extra-specs
                      (passes/shard_propagation.py). Unlike the knob-
-                     gated passes it is absent from the resolved set —
-                     and therefore from cache_signature() — unless
-                     enabled, so flipping autoshard recompiles.
+                     gated passes it is absent from the resolved set
+                     unless enabled, so flipping autoshard recompiles.
 
 Selection: BuildStrategy knobs (compiler.py) choose the default set;
 the PADDLE_TPU_PASSES env var overrides both ("all", "none"/"", or a
@@ -61,10 +46,6 @@ user's Program (and its fingerprint, which keys the compile cache) is
 never mutated. Per-pass wall time and op counts are always-on profiler
 counters (pass_<name>_us, pass_<name>_ops_removed, program_ops_before/
 _after) in the style of the dygraph_jit_* counters.
-
-`cache_signature()` names the resolved pass set plus each pass's
-implementation version, so that numbers measured under different rewrite
-semantics can be told apart.
 
 Verifier contract (PADDLE_TPU_VERIFY): when the env var is truthy
 (default-on under pytest via tests/conftest.py; any of ""/"0"/"off"/
@@ -80,8 +61,8 @@ Interaction with PADDLE_TPU_PASSES: verification follows the RESOLVED
 pass set — with passes disabled ("none") the input program is still
 verified once; unknown pass names still raise before any verification.
 The verifier only reads the program clone; it never mutates it, so
-`cache_signature()` and the program fingerprint that key the compile
-caches are unaffected by PADDLE_TPU_VERIFY in either state.
+the program fingerprint that keys the compile caches is unaffected by
+PADDLE_TPU_VERIFY in either state.
 """
 
 from __future__ import annotations
@@ -95,7 +76,6 @@ __all__ = [
     "register_pass",
     "resolve_pass_names",
     "apply_program_passes",
-    "cache_signature",
     "verify_enabled",
     "PassContext",
     "PASS_REGISTRY",
@@ -103,8 +83,7 @@ __all__ = [
 
 # name -> (fn(program, block, feed_names, fetch_names, ctx) -> int removed,
 #          strategy_knob: BuildStrategy attr gating the pass, or None,
-#          version: int bumped whenever the pass's OUTPUT may change for
-#          the same input program — part of cache_signature())
+#          gate: enabled(build_strategy) of a default-OFF pass, or None)
 PASS_REGISTRY: dict[str, tuple] = {}
 _PASS_ORDER: list[str] = []  # registration order == execution order
 
@@ -116,7 +95,7 @@ class PassContext:
     `feed_sig` ride along for shard_propagation (the planner needs the
     compile's mesh shape and concrete feed shapes). Passes must
     tolerate all of them being None — direct apply_program_passes
-    callers (tests, bench_passes --guard) run scopeless and meshless."""
+    callers (tests) run scopeless and meshless."""
 
     def __init__(self, scope=None, build_strategy=None, mesh=None,
                  feed_sig=None):
@@ -130,44 +109,21 @@ class PassContext:
         self.mutated = False
 
 
-def register_pass(name: str, strategy_knob: str = None, version: int = 1):
+def register_pass(name: str, strategy_knob: str = None, gate=None):
     """Decorator. A pass takes (program, block, feed_names, fetch_names,
     ctx), mutates `block` (of an executor-private program clone) in
     place, and returns the number of ops it removed (net; may be
     negative for passes that insert boundary ops). A pass that rewrites
-    the program without changing the op count must set ctx.mutated."""
+    the program without changing the op count must set ctx.mutated.
+    `gate` makes the pass opt-in: it runs only where
+    `gate(build_strategy)` is true."""
 
     def deco(fn):
-        PASS_REGISTRY[name] = (fn, strategy_knob, int(version))
+        PASS_REGISTRY[name] = (fn, strategy_knob, gate)
         _PASS_ORDER.append(name)
         return fn
 
     return deco
-
-
-def _opt_in_gates():
-    """name -> enabled(build_strategy) for the default-OFF passes. Looked
-    up lazily: the gate modules are the pass modules themselves, which
-    import this package."""
-    from .fuse_layer_scan import enabled as _scan_on
-    from .optimizer_overlap import enabled as _overlap_on
-    from .shard_propagation import autoshard_enabled as _autoshard_on
-
-    return {
-        "fuse_layer_scan": _scan_on,
-        "optimizer_overlap": _overlap_on,
-        "shard_propagation": _autoshard_on,
-    }
-
-
-class _LazyGates(dict):
-    def get(self, name, default=None):
-        if not self:
-            self.update(_opt_in_gates())
-        return dict.get(self, name, default)
-
-
-_OPT_IN_GATES = _LazyGates()
 
 
 def resolve_pass_names(build_strategy=None) -> tuple:
@@ -192,15 +148,12 @@ def resolve_pass_names(build_strategy=None) -> tuple:
         return tuple(p for p in _PASS_ORDER if p in requested)
     enabled = []
     for name in _PASS_ORDER:
-        _, knob, _ = PASS_REGISTRY[name]
-        gate = _OPT_IN_GATES.get(name)
+        _, knob, gate = PASS_REGISTRY[name]
         if gate is not None:
             # opt-in, env-or-strategy gated (default OFF — the inverse
-            # of the knob passes) and therefore absent from cache
-            # signatures until enabled: flipping PADDLE_TPU_AUTOSHARD /
-            # PADDLE_TPU_FUSE_LAYER_SCAN / PADDLE_TPU_OPTIMIZER_OVERLAP
-            # must MISS both the executor cache and the persistent XLA
-            # cache instead of serving a stale executable
+            # of the knob passes) and therefore absent from the resolved
+            # set until enabled: flipping PADDLE_TPU_AUTOSHARD must MISS
+            # the executor cache instead of serving a stale executable
             if not gate(build_strategy):
                 continue
         elif (
@@ -211,16 +164,6 @@ def resolve_pass_names(build_strategy=None) -> tuple:
             continue
         enabled.append(name)
     return tuple(enabled)
-
-
-def cache_signature(build_strategy=None) -> str:
-    """Stable name of the resolved pass configuration: ordered pass
-    names, each with its implementation version ("const_fold:1,dce:2").
-    An empty pass set signs as "nopass"."""
-    names = resolve_pass_names(build_strategy)
-    if not names:
-        return "nopass"
-    return ",".join(f"{n}:{PASS_REGISTRY[n][2]}" for n in names)
 
 
 # program attrs the executor reads post-transform that Program.clone()
@@ -325,14 +268,7 @@ from . import copy_prop as _copy_prop  # noqa: E402,F401
 from . import dce as _dce  # noqa: E402,F401
 from . import fuse_conv_bn as _fuse_conv_bn  # noqa: E402,F401
 from . import layout_opt as _layout_opt  # noqa: E402,F401
-# fuse_layer_scan BEFORE fuse_optimizer: scanning the backward region
-# must see the raw per-param grad producers; the optimizer wave is
-# fused (and then overlap-split) afterwards on the collapsed graph
-from . import fuse_layer_scan as _fuse_layer_scan  # noqa: E402,F401
 from . import fuse_optimizer as _fuse_optimizer  # noqa: E402,F401
-# optimizer_overlap AFTER fuse_optimizer: it splits the fused waves by
-# grad-finalization order
-from . import optimizer_overlap as _optimizer_overlap  # noqa: E402,F401
 # shard_propagation LAST: it plans on the graph the other rewrites
 # produced (post-DCE state set), and only participates when autoshard
 # is enabled (see resolve_pass_names)

@@ -38,8 +38,8 @@ inference programs, where only the exact forward runs).
 Stats ride on the program as `program._layout_opt_stats`
 {removed, inserted, remaining, converted_ops} and the always-on
 gauges `transpose_ops_before`, `transpose_ops_after` (their difference
-is what the pass removed; tools/bench_passes.py --guard pins the
-elimination fraction >= 80% on a canned ResNet block).
+is what the pass removed; tests/test_passes.py pins the elimination
+fraction >= 80% on a canned ResNet block).
 """
 
 from __future__ import annotations

@@ -18,10 +18,9 @@ Contract notes:
   clone when specs were attached), so the per-pass verifier sees an
   unchanged op graph and `analysis.check_sharding` has already
   validated the specs inside the planner.
-* It participates in `cache_signature()` / `resolve_pass_names()` ONLY
-  while autoshard is enabled (passes/__init__ gates it), so flipping
-  `PADDLE_TPU_AUTOSHARD` recompiles — the executor cache and the
-  persistent XLA cache both key on the resolved pass set.
+* It is in `resolve_pass_names()` ONLY while autoshard is enabled
+  (`register_pass`'s `gate`), so flipping `PADDLE_TPU_AUTOSHARD`
+  recompiles — the executor cache keys on the resolved pass set.
 * A plan failure (unknown-shape state var, no feasible placement)
   degrades to the manual behavior with one loud warning per program —
   opting into autoshard must never turn a compilable program into an
@@ -55,7 +54,7 @@ def autoshard_enabled(build_strategy=None) -> bool:
     return bool(getattr(build_strategy, "auto_shard", False))
 
 
-@register_pass("shard_propagation", version=1)
+@register_pass("shard_propagation", gate=autoshard_enabled)
 def shard_propagation_pass(program, block, feed_names, fetch_names, ctx):
     if not autoshard_enabled(getattr(ctx, "build_strategy", None)):
         return 0

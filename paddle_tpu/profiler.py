@@ -186,13 +186,7 @@ def set_counter(name: str, value: int) -> int:
     fleet_handoff_ms = summed router-side handoff overhead (stage-2
     wall minus the replica's own X-Decode-Ms), fleet_prefill_ms_ewma
     / fleet_decode_ms_ewma as router-observed stage gauges), and the
-    round-20 fused-step counters (all via bump, per compile:
-    scan_fused_runs = layer runs the fuse_layer_scan pass collapsed
-    into a single layer_scan op, scan_fused_layers = layers absorbed
-    across those runs, scan_fused_ops_removed = net IR ops the
-    collapse deleted; optimizer_overlap_groups = extra fused_adam
-    waves the optimizer_overlap pass emitted beyond the first, each
-    scheduled right after its member grads finalize; cross_kv_reuse =
+    round-20 counter (via bump: cross_kv_reuse =
     decoder cross-attention calls that consumed a precomputed
     encoder K/V pair instead of re-projecting it — one per layer per
     decode-step program build), and the round-21 multi-model serving

@@ -284,7 +284,7 @@ def _with_corrupting_pass(breaker):
     @contextlib.contextmanager
     def guard():
         name = "_test_corruptor"
-        passes_mod.PASS_REGISTRY[name] = (breaker, None, 1)
+        passes_mod.PASS_REGISTRY[name] = (breaker, None, None)
         passes_mod._PASS_ORDER.append(name)
         old = os.environ.get("PADDLE_TPU_PASSES")
         os.environ["PADDLE_TPU_PASSES"] = name

@@ -398,16 +398,14 @@ def test_autoshard_pass_bitwise_equal_and_plans_moments():
     assert profiler.counters().get("autoshard_planned_vars", 0) >= 4
 
 
-def test_autoshard_flip_changes_cache_signature(monkeypatch):
+def test_autoshard_flip_changes_the_resolved_pass_set(monkeypatch):
     import paddle_tpu as fluid
-    from paddle_tpu.passes import cache_signature, resolve_pass_names
+    from paddle_tpu.passes import resolve_pass_names
 
     monkeypatch.delenv("PADDLE_TPU_AUTOSHARD", raising=False)
     assert "shard_propagation" not in resolve_pass_names(None)
-    base_sig = cache_signature(None)
     monkeypatch.setenv("PADDLE_TPU_AUTOSHARD", "1")
     assert "shard_propagation" in resolve_pass_names(None)
-    assert cache_signature(None) != base_sig
     # resolved LAST: plans on the graph the other rewrites produced
     assert resolve_pass_names(None)[-1] == "shard_propagation"
     monkeypatch.setenv("PADDLE_TPU_AUTOSHARD", "0")
@@ -417,7 +415,6 @@ def test_autoshard_flip_changes_cache_signature(monkeypatch):
     bs = fluid.BuildStrategy()
     bs.auto_shard = True
     assert "shard_propagation" in resolve_pass_names(bs)
-    assert cache_signature(bs) != base_sig
 
 
 def test_pass_is_noop_without_mesh_or_when_disabled():

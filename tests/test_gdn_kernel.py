@@ -256,8 +256,9 @@ def test_nothing_is_written_out_in_front_of_the_kernels(interpreter, decay):
 
 
 def test_names_declaration_and_counters(interpreter):
-    """`gdn_fwd`/`gdn_bwd` match this cell's metrics and not Kimi's
-    `^%?kda_(fwd|bwd)`, and the other way round; the declaration moves q
+    """`gdn_fwd`/`gdn_bwd` match the delta rule's metric as Kimi's
+    `kda_fwd`/`kda_bwd` do, and what reads a kernel's output or another
+    layer's kernel does not; the declaration moves q
     and k once a key head and the decay as beta; the lowering counts."""
     import json
     import os
@@ -278,12 +279,12 @@ def test_names_declaration_and_counters(interpreter):
                                metric + ".json")) as f:
             return json.load(f)["args"]["name"]
 
-    mine, theirs = (pattern("qwen3next_gdn_kernel_ms_per_step"),
-                    pattern("kda_kernel_ms_per_step"))
-    for name in ("gdn_fwd", "gdn_bwd"):
-        assert re.search(mine, name) and not re.search(theirs, name)
-    for name in ("kda_fwd", "kda_bwd"):
-        assert re.search(theirs, name) and not re.search(mine, name)
+    pair = pattern("delta_rule_kernel_ms_per_step")
+    for name in ("gdn_fwd", "gdn_bwd", "kda_fwd", "kda_bwd"):
+        assert re.search(pair, name) and re.search(pair, f"%{name}.3 = (")
+    for other in ("short_conv_bwd", "flash_fwd",
+                  "%fusion.1 = f32[8] fusion(%gdn_fwd.2)"):
+        assert not re.search(pair, other)
 
     b, s, hk, hv, d = 1, 200, 2, 4, 128
     r = np.random.RandomState(0)

@@ -233,9 +233,8 @@ def test_a_head_that_is_no_lane_slice_is_refused():
 
 
 def test_the_kernels_names_are_not_the_flash_kernels():
-    """`flash_gqa_ms_per_step` and `flash_gqa_roofline_pct` match
-    `^%?flash_`; `qk_prep_ms_per_step` matches these two and nothing of
-    theirs."""
+    """`flash_ms_per_step` matches the four flash kernels by name;
+    `qk_prep_hbm_roofline_pct` matches these two and nothing of theirs."""
     import json
     import os
     import re
@@ -250,10 +249,10 @@ def test_the_kernels_names_are_not_the_flash_kernels():
             tuple(cotangents)))(*args))
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "layer_metrics",
-                           "qk_prep_ms_per_step.json")) as f:
+                           "qk_prep_hbm_roofline_pct.json")) as f:
         mine = json.load(f)["args"]["name"]
     with open(os.path.join(root, "benchmark", "layer_metrics",
-                           "flash_gqa_ms_per_step.json")) as f:
+                           "flash_ms_per_step.json")) as f:
         theirs = json.load(f)["args"]["name"]
     for name in ("qk_prep_fwd", "qk_prep_bwd"):
         assert name in text

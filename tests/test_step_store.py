@@ -209,7 +209,7 @@ def _variant(case, monkeypatch):
     elif case == "a feed's shape":
         batch = feed(rows=6)
     elif case == "a PADDLE_TPU_ variable":
-        monkeypatch.setenv("PADDLE_TPU_SCAN_MIN_OPS", "9")
+        monkeypatch.setenv("PADDLE_TPU_JIT_CACHE_CAP", "9")
     elif case == "the source digest":
         monkeypatch.setattr(step_store, "_source_digest", lambda: "edited")
     elif case == "the JAX version string":
@@ -218,6 +218,39 @@ def _variant(case, monkeypatch):
     if case == "the AMP dtype":
         main._amp_dtype = "bfloat16"
     return main, startup, loss, batch
+
+
+def test_an_edit_under_passes_changes_the_source_digest(tmp_path, monkeypatch):
+    """The digest in every key is of the package's files as they are: a
+    copy reads the same, and an edited pass, a new one and a renamed one
+    each read otherwise, where a file that is no source reads the same.
+    A pass's rewrite is in no other part of a key."""
+    import shutil
+
+    copy = tmp_path / "paddle_tpu"
+    shutil.copytree(step_store._PACKAGE, copy,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+
+    def digest():
+        step_store._source_digest.cache_clear()
+        return step_store._source_digest()
+
+    try:
+        real = digest()
+        monkeypatch.setattr(step_store, "_PACKAGE", str(copy))
+        assert digest() == real
+        (copy / "passes" / "notes.txt").write_text("no source\n")
+        assert digest() == real
+        with open(copy / "passes" / "dce.py", "a") as f:
+            f.write("# edited\n")
+        edited = digest()
+        (copy / "passes" / "another.py").write_text("")
+        added = digest()
+        (copy / "passes" / "another.py").rename(copy / "passes" / "other.py")
+        assert len({real, edited, added, digest()}) == 4
+        assert step_store._surroundings()[-1] == digest()
+    finally:  # the next caller reads the package itself again
+        step_store._source_digest.cache_clear()
 
 
 @pytest.mark.parametrize("case", [

@@ -537,68 +537,116 @@ def test_a_rehearsal_line_carries_the_two_compile_counts():
 
 # ---------------------------------------- what reads the declared costs
 
-EXPERT_CELLS = ["kimi_linear_ep32_s4096", "trinity_mini_ep16_s8192"]
-FC_CELLS = ["bert_base_s128", "bert_base_s128_dp4", "bert_base_s512",
-            "kimi_linear_ep32_s4096", "transformer_base_s64",
-            "trinity_mini_ep16_s8192"]
 FLASH_CALL = ("%flash_fwd.12 = (bf16[32,8192,128]{2,1,0}, f32[32,1,8192]) "
               "custom-call(%seed, %q, %k, %v)")
 
 
-@pytest.mark.parametrize("metric,layer,key,bound,cells,hits,misses", [
-    ("fc_roofline_pct", "Op lowerings", "scope", "bf16_flops", FC_CELLS,
-     ["fwd/mul/dot_general", "bwd/matmul_grad/transpose(jvp())/dot_general"],
-     ["fwd/elementwise_mul/mul", "opt/fused_adam/mul", ""]),
-    ("conv_roofline_pct", "Op lowerings", "scope", "bf16_flops",
-     ["resnet50_b128"],
-     ["fwd/conv2d/conv_general_dilated", "bwd/conv2d_grad/transpose(jvp())"],
-     ["fwd/batch_norm/mul"]),
-    ("moe_grouped_roofline_pct", "Op lowerings", "name", "bf16_flops",
-     EXPERT_CELLS, ["%ragged-dot-none.37 = f32[8,2048,1024]{2,1,0}"],
-     ["%fusion.3 = f32[8] fusion(%ragged-dot-none.37)"]),
-    ("attn_short_roofline_pct", "Pallas kernels", "name", "bf16_flops",
-     ["bert_base_s128", "bert_base_s128_dp4", "bert_base_s512",
-      "transformer_base_s64"],
-     ["%mha_short_bwd.7 = (bf16[256,128,768]) custom-call(%a)",
-      "mha_short_fwd = bf16[256,128,768] custom-call(%a)"],
-     ["%copy.1 = bf16[256,128,768] copy(%mha_short_fwd.3)"]),
-    ("flash_roofline_pct", "Pallas kernels", "name", "bf16_flops",
-     EXPERT_CELLS, [FLASH_CALL, FLASH_CALL.replace("fwd", "bwd_dkv")],
-     ["%fusion.9 = bf16[8,8] fusion(%flash_fwd.12), kind=kLoop"]),
-    ("kda_roofline_pct", "Pallas kernels", "name", "bf16_flops",
-     ["kimi_linear_ep32_s4096"], ["%kda_bwd.2 = (f32[1,4096,4096])"],
-     ["%fusion.1 = f32[8] fusion(%kda_fwd.2)"]),
-    ("qk_prep_hbm_pct", "Pallas kernels", "name", "hbm_bytes_per_s",
-     ["trinity_mini_ep16_s8192"], ["%qk_prep_fwd.13 = (bf16[1,32,8192,128])"],
-     [FLASH_CALL]),
-])
-def test_a_roofline_metric_names_what_it_reads_and_the_cells_that_have_it(
-        metric, layer, key, bound, cells, hits, misses):
-    """PR 35's per-layer metrics: declared in `BENCHMARK.json` for the
-    cells whose traces hold what they read, read by `trace_roofline` from
-    the events' own `flops` or `bytes_accessed`, by an expression that
-    finds the kernel (or the Program op's scope) and not what reads its
-    output."""
-    import json
-
+def _bench():
     from benchmark.harness import spec
 
     with open(os.path.join(os.path.dirname(spec.BENCH_DIR),
                            "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    (declared,) = [m for m in bench["per_layer"] if m["name"] == metric]
+        return json.load(f)
+
+
+def _selected(where, bench):
+    """The cells of `BENCHMARK.json` that a metric's `where` selects, read
+    here against each cell's own files: every key names a value of the
+    cell, one of the listed ones, or a list (a configuration's
+    `mechanisms`) that holds one of them."""
+    from benchmark.harness import spec
+
+    def has(cell, key, allowed):
+        for part in key.split("."):
+            cell = cell.get(part) if isinstance(cell, dict) else None
+        held = cell if isinstance(cell, list) else [cell]
+        return bool(set(held) & set(allowed))
+
+    return [w["name"] for w in bench["workloads"]
+            if all(has(spec.cell(w["name"]), key, allowed)
+                   for key, allowed in where.items())]
+
+
+@pytest.mark.parametrize("metric,layer,key,bound,selects,hits,misses", [
+    ("fc_mxu_roofline_pct", "Op lowerings", "scope", "bf16_flops",
+     ("config.mechanisms", "fc"),
+     ["fwd/mul/dot_general", "bwd/matmul_grad/transpose(jvp())/dot_general"],
+     ["fwd/elementwise_mul/mul", "opt/fused_adam/mul", ""]),
+    ("conv_roofline_pct", "Op lowerings", "scope", "bf16_flops",
+     ("config.adapter", "resnet50_v1_5"),
+     ["fwd/conv2d/conv_general_dilated", "bwd/conv2d_grad/transpose(jvp())"],
+     ["fwd/batch_norm/mul"]),
+    ("nemotron_ssd_roofline_pct", "Op lowerings", "scope", "bf16_flops",
+     ("config.adapter", "nemotron_h"),
+     ["fwd/ssd_scan/dot_general",
+      "bwd/ssd_scan_grad/transpose(jvp())/dot_general"],
+     ["fwd/selective_scan/mul", "fwd/short_conv1d/ssd_scan/add"]),
+    ("attn_short_roofline_pct", "Pallas kernels", "name", "bf16_flops",
+     ("config.adapter", "bert"),
+     ["%mha_short_bwd.7 = (bf16[256,128,768]) custom-call(%a)",
+      "mha_short_fwd = bf16[256,128,768] custom-call(%a)"],
+     ["%copy.1 = bf16[256,128,768] copy(%mha_short_fwd.3)"]),
+    ("flash_kernels_roofline_pct", "Pallas kernels", "name", "bf16_flops",
+     ("config.mechanisms", "flash"),
+     [FLASH_CALL, FLASH_CALL.replace("fwd", "bwd_dkv"),
+      FLASH_CALL.replace("fwd", "bwd_dkv_dq")],
+     ["%fusion.9 = bf16[8,8] fusion(%flash_fwd.12), kind=kLoop",
+      FLASH_CALL.replace("fwd", "fwd_too")]),
+    ("delta_rule_roofline_pct", "Pallas kernels", "name", "bf16_flops",
+     ("config.mechanisms", "delta_rule"),
+     ["%kda_bwd.2 = (f32[1,4096,4096])", "%gdn_fwd.5 = (bf16[1,4096,4096])"],
+     ["%fusion.1 = f32[8] fusion(%kda_fwd.2)"]),
+    ("qk_prep_hbm_roofline_pct", "Pallas kernels", "name", "hbm_bytes_per_s",
+     ("config.mechanisms", "qk_prep"),
+     ["%qk_prep_fwd.13 = (bf16[1,32,8192,128])"], [FLASH_CALL]),
+])
+def test_a_roofline_metric_names_what_it_reads_and_the_cells_that_have_it(
+        metric, layer, key, bound, selects, hits, misses):
+    """PR 35's per-layer metrics: declared in `BENCHMARK.json` for the
+    cells whose configuration has what they read (the mechanism, or the
+    adapter where one model alone has it: the file's own `where`, read
+    here against the configurations, and no list of cells), read by
+    `trace_roofline` from the events' own `flops` or `bytes_accessed`, by
+    an expression that finds the kernel (or the Program op's scope) and
+    not what reads its output."""
+    from benchmark.harness import spec
+
+    bench = _bench()
+    m = spec.load("layer_metrics", metric)
+    assert m["kind"] == "trace_roofline" and m["args"]["bound"] == bound
+    where, word = selects
+    assert set(m["where"]) == {where} and word in m["where"][where]
+    cells = _selected(m["where"], bench)
+    assert cells, metric
+    (declared,) = [x for x in bench["per_layer"] if x["name"] == metric]
+    assert sorted(declared.pop("workloads")) == sorted(cells)
     assert declared == {
         "name": metric, "unit": "%", "better": "higher",
         "source": "device_trace", "layer": layer,
-        "moves": "train_examples_per_s", "workloads": cells}
-    m = spec.load("layer_metrics", metric)
-    assert m["kind"] == "trace_roofline" and m["args"]["bound"] == bound
-    assert sorted(
-        w["name"] for w in bench["workloads"]
-        if metric in {x["name"] for x in spec.layer_metrics(
-            spec.cell(w["name"]))}) == cells
+        "moves": "train_examples_per_s"}
+    assert [w["name"] for w in bench["workloads"]
+            if metric in {x["name"] for x in spec.layer_metrics(
+                spec.cell(w["name"]))}] == cells
     assert all(re.search(m["args"][key], text) for text in hits)
     assert not any(re.search(m["args"][key], text) for text in misses)
+
+
+def test_every_roofline_metrics_where_selects_a_cell():
+    """Whatever files `benchmark/layer_metrics/` holds: a `trace_roofline`
+    metric that no cell of `BENCHMARK.json` can read is a file nothing
+    measures, and one that `per_layer` lists for other cells than its
+    `where` selects is read where it is not declared."""
+    from benchmark.harness import spec
+
+    bench = _bench()
+    declared = {m["name"]: m for m in bench["per_layer"]}
+    files = [spec.load("layer_metrics", n) for n in spec.names("layer_metrics")]
+    rooflines = [m for m in files if m["kind"] == "trace_roofline"]
+    assert rooflines
+    for m in rooflines:
+        cells = _selected(m.get("where", {}), bench)
+        assert cells, m["name"]
+        assert sorted(declared[m["name"]]["workloads"]) == sorted(cells)
 
 
 # ------------------------------------------- the stages of one Program op

@@ -322,27 +322,30 @@ def test_attention_without_the_new_inputs_is_the_op_it_was():
 
 
 def test_the_cell_declares_the_kernel_pairs_metric():
-    """`qk_prep_ms_per_step` in `BENCHMARK.json` and beside the other
-    metrics' files, for this cell and the Trinity adapter alone."""
+    """`qk_prep_hbm_roofline_pct` in `BENCHMARK.json` and beside the other
+    metrics' files, for this cell and whichever other names the mechanism
+    `qk_prep`."""
     import json
 
     from benchmark.harness import spec
 
+    metric = "qk_prep_hbm_roofline_pct"
     with open(os.path.join(os.path.dirname(spec.BENCH_DIR),
                            "BENCHMARK.json")) as f:
         bench = json.load(f)
-    declared = {m["name"]: m for m in bench["per_layer"]}
-    assert declared["qk_prep_ms_per_step"] == {
-        "name": "qk_prep_ms_per_step", "unit": "ms", "better": "lower",
+    (declared,) = [m for m in bench["per_layer"] if m["name"] == metric]
+    assert CELL in declared.pop("workloads")
+    assert declared == {
+        "name": metric, "unit": "%", "better": "higher",
         "source": "device_trace", "layer": "Pallas kernels",
-        "moves": "train_examples_per_s", "workloads": [CELL]}
-    m = spec.load("layer_metrics", "qk_prep_ms_per_step")
-    assert (m["kind"], m["where"]) == ("trace_kernel",
-                                       {"config.adapter": ["trinity"]})
-    assert "qk_prep_ms_per_step" in {
-        x["name"] for x in spec.layer_metrics(spec.cell(CELL))}
+        "moves": "train_examples_per_s"}
+    m = spec.load("layer_metrics", metric)
+    assert (m["kind"], m["where"]) == ("trace_roofline",
+                                       {"config.mechanisms": ["qk_prep"]})
+    assert "qk_prep" in spec.cell(CELL)["config"]["mechanisms"]
+    assert metric in {x["name"] for x in spec.layer_metrics(spec.cell(CELL))}
     for other in ("kimi_linear_ep32_s4096", "bert_base_s128"):
-        assert "qk_prep_ms_per_step" not in {
+        assert metric not in {
             x["name"] for x in spec.layer_metrics(spec.cell(other))}
 
 
@@ -406,7 +409,32 @@ def test_the_16_shares_add_up_to_the_uncut_layer(total, held, k):
 # ----------------------------------------------- the cell's arithmetic
 
 
-def test_counters_and_flops_of_the_cell():
+def _flash_kernels_declared(model, traffic) -> dict:
+    """Kernel name -> the FLOPs each of its calls declares
+    (`ops/pallas/cost.py`, what `flash_kernels_roofline_pct` reads) over
+    the held layers of one train step at the cell's shapes, a call of
+    `flash_attention` and its backward a layer."""
+    import jax
+    import jax.numpy as jnp
+    from pallas_costs import declared
+
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    b, s, d = traffic["batch"], traffic["seq_len"], model["head_dim"]
+    q, kv = (jax.ShapeDtypeStruct((b, heads, s, d), jnp.bfloat16)
+             for heads in (model["num_attention_heads"],
+                           model["num_key_value_heads"]))
+    found = {}
+    for _, window, _ in adapter.held_layers(model):
+        grads = jax.grad(lambda *a: jnp.sum(flash_attention(
+            *a, causal=True, window=window).astype(jnp.float32)),
+            argnums=(0, 1, 2))
+        for name, calls in declared(grads, q, kv, kv).items():
+            found.setdefault(name, []).extend(c.flops for c in calls)
+    return found
+
+
+def test_counters_and_flops_of_the_cell(monkeypatch):
     from paddle_tpu import profiler
 
     model, traffic = SUITE.cell(rehearse=False)
@@ -430,13 +458,17 @@ def test_counters_and_flops_of_the_cell():
     flops = adapter.flops_per_example(model, traffic)
     assert flops == 3.0 * (2 * 8192 * per_token + pairs * 32 * 4 * 128)
     assert 17.0e12 < flops < 18.0e12
-    kernels = adapter.flash_flops_per_step(model, traffic)
-    assert [len(v) for v in kernels.values()] == [5, 5, 5]
-    assert sum(map(sum, kernels.values())) == 18 * 32 * 128 * pairs
+    with monkeypatch.context() as patch:  # a trace alone: nothing runs
+        patch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+        kernels = _flash_kernels_declared(model, traffic)
+    assert {name: len(calls) for name, calls in kernels.items()} == {
+        "flash_fwd": 5, "flash_bwd_dkv_dq": 5}
+    assert sum(kernels["flash_fwd"]) == 4 * 32 * 128 * pairs
+    assert sum(kernels["flash_bwd_dkv_dq"]) == 10 * 32 * 128 * pairs
     # the attention part of flops_per_example, forward and backward, is
-    # 12 a pair a lane; the kernels do 18 because two of them compute the
-    # scores again
-    assert sum(map(sum, kernels.values())) * 12 == 18 * (
+    # 12 a pair a lane; the kernels declare 14 because the backward
+    # computes the scores again
+    assert sum(map(sum, kernels.values())) * 12 == 14 * (
         flops - 3.0 * 2 * 8192 * per_token)
 
     c0 = profiler.counters()

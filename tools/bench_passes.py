@@ -9,9 +9,6 @@ summary line.
   python tools/bench_passes.py                   # transformer + resnet
   python tools/bench_passes.py --models transformer
   python tools/bench_passes.py --full            # bench-sized batch/seq
-  python tools/bench_passes.py --guard           # ci.sh regression guard:
-      canned BERT-layer train program, assert DCE+fusion+copy-prop
-      remove at least MIN_GUARD_FRACTION of ops (no execution, fast)
 
 The pass-on/pass-off fetches are compared numerically (rtol 1e-5) from
 identical initial state — the same contract tests/test_passes.py pins
@@ -29,23 +26,6 @@ import time
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-# the canned BERT-layer guard program must shed at least this fraction
-# of its ops under the full pass set (measured 0.47 at pinning; guard
-# trips well below to catch real regressions, not noise)
-MIN_GUARD_FRACTION = 0.30
-
-# the canned ResNet-block train program must have at least this fraction
-# of its conv-adjacent activation transposes eliminated by layout_opt
-# (measured 0.9231 at pinning — 39 removed, 3 boundary transposes
-# inserted, 0 remaining; the ISSUE-9 acceptance floor is 0.80)
-MIN_LAYOUT_FRACTION = 0.80
-
-# the canned 4-layer transformer train program must shed at least this
-# fraction of its traced ops when fuse_layer_scan is on vs off, with
-# bitwise-equal losses over 3 Adam steps (measured 0.83 at pinning —
-# 591 -> 100 ops; the round-20 acceptance floor is 0.60)
-MIN_SCAN_FRACTION = 0.60
 
 
 def log(*a):
@@ -181,213 +161,13 @@ def bench_model(name, full, steps):
     return result
 
 
-def _guard_program():
-    """Canned BERT-layer train program for the op-count regression guard:
-    one encoder layer + MLM-style head + Adam, passes applied directly
-    (no execution, no device)."""
-    import paddle_tpu as fluid
-    from paddle_tpu.models.bert import BertConfig, build_bert_pretrain
-
-    _fresh()
-    cfg = BertConfig.base()
-    cfg.num_layers = 1
-    b, s = 2, 16
-    handles = build_bert_pretrain(cfg, b, s, mlm_only=True, max_preds=4)
-    fluid.optimizer.Adam(1e-4).minimize(handles["loss"])
-    prog = fluid.default_main_program()
-    feed_names = tuple(
-        n for n in (
-            "src_ids", "pos_ids", "sent_ids", "input_mask",
-            "mask_pos", "mask_label", "mask_weight",
-        ) if prog.global_block().has_var(n)
-    )
-    return prog, feed_names, (handles["loss"].name,)
-
-
-def _resnet_block_program():
-    """Canned ResNet block (stem conv + bottleneck-ish residual + pool +
-    fc head + Momentum) for the layout-elimination pin: small enough to
-    build in milliseconds, representative enough to exercise conv/bn/
-    relu/residual-add/pool/fc-boundary — the exact op mix layout_opt
-    targets — through forward AND backward."""
-    import paddle_tpu as fluid
-
-    _fresh()
-    img = fluid.layers.data("img", [2, 3, 32, 32], append_batch_size=False)
-    label = fluid.layers.data("label", [2, 1], dtype="int64",
-                              append_batch_size=False)
-
-    def conv_bn(x, c, k, s=1, act=None, name=None):
-        conv = fluid.layers.conv2d(
-            x, num_filters=c, filter_size=k, stride=s,
-            padding=(k - 1) // 2, bias_attr=False, name=name)
-        return fluid.layers.batch_norm(conv, act=act,
-                                       name=(name or "") + "_bn")
-
-    x = conv_bn(img, 8, 7, s=2, act="relu", name="c1")  # s2d-shaped stem
-    y = conv_bn(x, 8, 3, act="relu", name="c2a")
-    y = conv_bn(y, 8, 3, name="c2b")
-    x = fluid.layers.elementwise_add(x, y, act="relu")
-    x = fluid.layers.pool2d(x, pool_size=2, pool_type="max", pool_stride=2)
-    pool = fluid.layers.pool2d(x, pool_type="avg", global_pooling=True)
-    pred = fluid.layers.fc(pool, 10, act="softmax")
-    loss = fluid.layers.mean(fluid.layers.cross_entropy(pred, label))
-    fluid.optimizer.Momentum(0.1, 0.9).minimize(loss)
-    return fluid.default_main_program(), ("img", "label"), (loss.name,)
-
-
-def _scan_guard():
-    """Round-20 pin: on the canned 4-layer transformer train program,
-    fuse_layer_scan (+ optimizer_overlap) must cut the traced op count
-    by >= MIN_SCAN_FRACTION with BITWISE-equal losses over 3 Adam steps.
-    This is the one guard that executes (two small CPU compiles,
-    ~60-90 s) — the scan claim is about what XLA traces, so a static
-    diff alone can't pin it."""
-    import paddle_tpu as fluid
-    from paddle_tpu.models.transformer import (
-        TransformerConfig,
-        build_transformer,
-    )
-    from paddle_tpu.passes import apply_program_passes
-
-    b, s = 2, 8
-    rng = np.random.RandomState(0)
-    pos = np.tile(np.arange(s), (b, 1)).astype("int64")
-    feed_base = {
-        "src_ids": rng.randint(1, 64, (b, s)).astype("int64"),
-        "trg_ids": rng.randint(1, 64, (b, s)).astype("int64"),
-        "lbl_ids": rng.randint(1, 64, (b, s)).astype("int64"),
-        "src_mask": np.ones((b, s), "float32"),
-        "trg_mask": np.ones((b, s), "float32"),
-    }
-    counts, losses = {}, {}
-    for mode in ("off", "on"):
-        _fresh()
-        fluid.default_main_program().random_seed = 9
-        fluid.default_startup_program().random_seed = 9
-        if mode == "on":
-            os.environ["PADDLE_TPU_FUSE_LAYER_SCAN"] = "1"
-            os.environ["PADDLE_TPU_OPTIMIZER_OVERLAP"] = "1"
-        try:
-            cfg = TransformerConfig(
-                src_vocab=64, trg_vocab=64, d_model=16, n_heads=2,
-                d_ff=32, n_layers=4, max_len=16, dropout=0.1,
-            )
-            handles = build_transformer(cfg, b, s, s)
-            fluid.optimizer.Adam(1e-3).minimize(handles["loss"])
-            feed = dict(feed_base)
-            feed[handles["src_pos_name"]] = pos
-            feed[handles["trg_pos_name"]] = pos
-            prog = fluid.default_main_program()
-            _, blk, _ = apply_program_passes(
-                prog, tuple(feed.keys()), (handles["loss"].name,)
-            )
-            counts[mode] = len(blk.ops)
-            exe = fluid.Executor(fluid.TPUPlace())
-            exe.run(fluid.default_startup_program())
-            losses[mode] = [
-                np.asarray(
-                    exe.run(feed=feed, fetch_list=[handles["loss"]])[0]
-                ).copy()
-                for _ in range(3)
-            ]
-        finally:
-            os.environ.pop("PADDLE_TPU_FUSE_LAYER_SCAN", None)
-            os.environ.pop("PADDLE_TPU_OPTIMIZER_OVERLAP", None)
-    frac = 1.0 - counts["on"] / counts["off"]
-    bitwise = all(
-        np.array_equal(a, b) for a, b in zip(losses["off"], losses["on"])
-    )
-    line = {
-        "guard": "transformer_scan_fusion",
-        "ops_off": counts["off"],
-        "ops_on": counts["on"],
-        "reduction": round(frac, 4),
-        "min_required": MIN_SCAN_FRACTION,
-        "bitwise_equal": bitwise,
-    }
-    print(json.dumps(line), flush=True)
-    if frac < MIN_SCAN_FRACTION:
-        log(
-            f"GUARD FAIL: fuse_layer_scan cut {frac:.1%} of the "
-            f"transformer train ops (< pinned {MIN_SCAN_FRACTION:.0%})"
-        )
-        return 1
-    if not bitwise:
-        log("GUARD FAIL: scan-on losses are not bitwise-equal to scan-off")
-        return 1
-    log(f"guard OK: scan cut {frac:.1%} of ops, losses bitwise-equal")
-    return 0
-
-
-def run_guard():
-    from paddle_tpu.passes import apply_program_passes
-
-    prog, feed_names, fetch_names = _guard_program()
-    _, _, stats = apply_program_passes(prog, feed_names, fetch_names)
-    frac = 1.0 - stats["ops_after"] / stats["ops_before"]
-    line = {
-        "guard": "bert_layer_pass_reduction",
-        "ops_before": stats["ops_before"],
-        "ops_after": stats["ops_after"],
-        "per_pass": stats["passes"],
-        "reduction": round(frac, 4),
-        "min_required": MIN_GUARD_FRACTION,
-    }
-    print(json.dumps(line), flush=True)
-    if frac < MIN_GUARD_FRACTION:
-        log(
-            f"GUARD FAIL: passes removed {frac:.1%} of the BERT-layer "
-            f"train ops (< pinned {MIN_GUARD_FRACTION:.0%})"
-        )
-        return 1
-    if not stats["passes"].get("fuse_optimizer"):
-        log("GUARD FAIL: fuse_optimizer removed no ops")
-        return 1
-    log(f"guard OK: {frac:.1%} of ops removed")
-
-    # -- layout pin: canned ResNet block, >= 80% of conv-adjacent
-    # activation transposes eliminated by layout_opt (ISSUE-9 gate)
-    prog, feed_names, fetch_names = _resnet_block_program()
-    p2, _, stats = apply_program_passes(prog, feed_names, fetch_names)
-    lo = getattr(p2, "_layout_opt_stats", None)
-    if not lo:
-        log("GUARD FAIL: layout_opt left no stats on the ResNet block")
-        return 1
-    denom = max(lo["removed"] + lo["remaining"], 1)
-    frac = (lo["removed"] - lo["inserted"]) / denom
-    line = {
-        "guard": "resnet_block_layout_elimination",
-        **lo,
-        "eliminated_fraction": round(frac, 4),
-        "min_required": MIN_LAYOUT_FRACTION,
-    }
-    print(json.dumps(line), flush=True)
-    if frac < MIN_LAYOUT_FRACTION:
-        log(
-            f"GUARD FAIL: layout_opt eliminated {frac:.1%} of the ResNet "
-            f"block's conv-adjacent transposes (< pinned "
-            f"{MIN_LAYOUT_FRACTION:.0%})"
-        )
-        return 1
-    log(f"guard OK: {frac:.1%} of conv-adjacent transposes eliminated")
-
-    # -- round-20 scan pin: 4-layer transformer, fuse_layer_scan on/off
-    return _scan_guard()
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--models", default="transformer,resnet")
     ap.add_argument("--full", action="store_true",
                     help="bench-sized batch/seq (chip-scale)")
     ap.add_argument("--steps", type=int, default=3)
-    ap.add_argument("--guard", action="store_true",
-                    help="ci.sh op-count regression guard only")
     args = ap.parse_args()
-
-    if args.guard:
-        sys.exit(run_guard())
 
     summary = {"ok": True}
     for name in [m.strip() for m in args.models.split(",") if m.strip()]:

@@ -49,16 +49,6 @@ echo "== locksan lane: threaded test subset under the runtime lock sanitizer =="
 # Budget <= 120 s (measured ~70 s).
 python tools/locksan_gate.py
 
-echo "== pass-manager smoke + op-count & layout regression guards =="
-# canned BERT-layer train program: DCE + copy-prop + optimizer fusion must
-# keep removing at least the pinned fraction of ops; canned ResNet block:
-# layout_opt must keep eliminating >= 80% of the conv-adjacent activation
-# transposes; canned 4-layer transformer: fuse_layer_scan must keep
-# cutting >= 60% of the traced train ops with bitwise-equal losses
-# (round 20; the one guard that executes — two small CPU compiles)
-# (tools/bench_passes.py — all three pins in one invocation)
-JAX_PLATFORMS=cpu python tools/bench_passes.py --guard
-
 echo "== resilience smoke: train -> SIGKILL mid-save -> resume -> loss continuity =="
 # the crash-consistency gate (resilience subsystem): a worker is SIGKILLed
 # while an async snapshot flush is mid-write; discovery must fall back to
